@@ -5,8 +5,9 @@
 explicit in-body collective everywhere else in the tree, for two
 load-bearing reasons:
 
-1. **AD correctness on the pinned runtime.** jax 0.4.x ships the
-   pmap-era ``transpose(psum) = psum`` rule, which silently scales
+1. **AD correctness in unchecked manual regions.** Under
+   ``check_vma=False`` jax 0.9 still binds the pmap-era
+   ``transpose(psum) = psum`` rule, which silently scales
    gradients by the axis size when the collective is differentiated
    INSIDE a shard_map body — exactly what the 1f1b pipeline schedule
    does to every stage function. ``mesh_psum`` pins the modern
@@ -27,10 +28,9 @@ What fires: any call whose callee resolves to a ``jax.lax`` /
 hand-scheduled kernels; everywhere else routes through
 ``parallel.collectives.mesh_*``.
 
-Legitimate exceptions (the AD-repair substrate in
-``common/jax_compat.py``, which the helpers are themselves built on)
-carry ``# edlint: disable=perf-bare-collective`` with the reason on
-the suppression line.
+A legitimate exception carries
+``# edlint: disable=perf-bare-collective`` with the reason on the
+suppression line.
 """
 
 import ast
@@ -107,9 +107,9 @@ def run(units):
                         "bare lax.%s outside parallel/+ops/: use "
                         "parallel.collectives.mesh_%s — the helper "
                         "pins the correct psum transpose for vjp "
-                        "inside shard_map on the pinned jax (bare "
-                        "spelling silently scales grads by the axis "
-                        "size) and records the bytes the dense-plane "
+                        "inside shard_map (under check_vma=False the "
+                        "bare spelling silently scales grads by the "
+                        "axis size) and records the bytes the dense-plane "
                         "telemetry reports"
                         % (leaf, "psum" if leaf == "all_reduce" else leaf)
                     ),

@@ -1,174 +1,34 @@
-"""Version bridge for the JAX surface the parallel/ops stack sits on.
+"""The two shard_map spellings the parallel/ops stack routes through
+one place, for the installed runtime (jax 0.9.0, pinned in
+pyproject.toml).
 
-The SPMD trainers, pipeline schedules, and attention collectives were
-written against the current JAX API (``jax.shard_map`` with
-``check_vma``, ``pltpu.CompilerParams``); the pinned runtime in this
-image is jax 0.4.x, where the same machinery lives under
-``jax.experimental.shard_map.shard_map`` (with ``check_rep``) and
-``pltpu.TPUCompilerParams``. Every call site routes through this
-module so the version probe happens exactly once, at import — not
-per-trace — and upgrading the pin later is a no-op here (the new-API
-branch is preferred whenever it exists).
-
-Nothing in here changes semantics: ``shard_map`` forwards to
-whichever implementation the installed JAX ships, and ``check_vma``
-(the new name for per-output replication checking) maps onto
-``check_rep`` (the old one).
+No version probing: support for a JAX that is not installed is not
+kept. What remains is here because each call site would otherwise
+repeat a detail — when ``check_vma`` may be switched off, and the
+empty-axes no-op of a vary-cast.
 """
 
 import jax
 
-__all__ = [
-    "anchor_replicated", "shard_map", "pvary", "tpu_compiler_params",
-]
-
-_NEW_SHARD_MAP = getattr(jax, "shard_map", None)
-
-if _NEW_SHARD_MAP is None:
-    from jax.experimental.shard_map import shard_map as _OLD_SHARD_MAP
-
-    def _register_pallas_rep_rule():
-        """0.4.x ``check_rep`` has no replication rule for pallas_call,
-        so a checked manual region containing a flash kernel dies with
-        "No replication rule". A pallas kernel never communicates
-        across devices, so the standard elementwise-style rule (output
-        replication = the shared input replication) is sound — register
-        it once so the checked path keeps working, because the
-        UNchecked path is worse: check_rep=False lowers axis_index
-        through PartitionId, which XLA rejects on CPU."""
-        try:
-            from jax._src.pallas.pallas_call import pallas_call_p
-            from jax.experimental import shard_map as _sm_mod
-
-            from functools import partial
-
-            _sm_mod.register_standard_check(pallas_call_p)
-            # the STANDARD rewrite (not norewrite): it pbroadcasts
-            # mismatched input replications down to their meet, so a
-            # kernel fed both device-varying blocks and literal-init
-            # (fully replicated) carries still traces under the check
-            _sm_mod.register_rewrite(pallas_call_p)(
-                partial(_sm_mod._standard_rewrite_rule, pallas_call_p)
-            )
-        except (ImportError, AttributeError, TypeError):
-            pass  # internal layout moved; unchecked fallback still works
-
-    _register_pallas_rep_rule()
-else:
-    _OLD_SHARD_MAP = None
+__all__ = ["shard_map", "pvary"]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` on new JAX; the ``jax.experimental`` spelling
-    (with ``check_vma`` mapped to its old name ``check_rep``) on the
-    pinned 0.4.x runtime."""
-    if _NEW_SHARD_MAP is not None:
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return _NEW_SHARD_MAP(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            **kwargs
-        )
-    # 0.4.x: always TRY the checked path, even when the caller asked
-    # for check_vma=False — the old ``check_rep`` inference accepts
-    # programs the new VMA annotation checker rejects (e.g. pallas_call
-    # outputs carrying no vma), and its False mode lowers axis_index
-    # through PartitionId, which XLA SPMD rejects on CPU. Where the old
-    # inference is instead too WEAK (it cannot see replication through
-    # a scanned custom_vjp the way VMA typing can), it raises a
-    # "can't be statically inferred" ValueError at trace time — only
-    # then retrace unchecked.
-    checked = _OLD_SHARD_MAP(
-        f, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=True
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map``. ``check_vma=False`` is for manual regions that
+    contain a ``pallas_call`` (its out ShapeDtypeStructs carry no vma,
+    which the checker rejects)."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma,
     )
-    unchecked = _OLD_SHARD_MAP(
-        f, mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
-
-    def _apply(*args, **kw):
-        try:
-            return checked(*args, **kw)
-        except Exception as e:
-            message = str(e)
-            if (
-                "statically inferred" not in message
-                and "check_rep=False" not in message
-                and "No replication rule" not in message
-            ):
-                raise
-            return unchecked(*args, **kw)
-
-    return _apply
 
 
 def pvary(x, axes):
     """Cast ``x`` to device-varying over ``axes`` inside a manual
-    region. New JAX's VMA typing requires the explicit cast to mix
-    literal (unvarying) inits with per-device scan state; 0.4.x
-    shard_map has no VMA lattice — its ``check_rep`` tracks
-    replication without demanding casts — so this is the identity
-    there."""
+    region (VMA typing needs the explicit cast to mix literal inits
+    with per-device scan state). No axes, no cast."""
     axes = tuple(axes)
     if not axes:
         return x
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to="varying")
-    lax_pvary = getattr(jax.lax, "pvary", None)
-    if lax_pvary is not None:
-        return lax_pvary(x, axes)
-    # 0.4.x: the same operation is spelled pbroadcast in shard_map's
-    # internal replication lattice (it marks a replicated value as
-    # device-varying over ``axes``; its transpose is psum — which is
-    # what keeps vjp transposes of invarying params from psumming a
-    # cotangent once per scan tick). Identity as a last resort.
-    try:
-        from jax.experimental.shard_map import pbroadcast
-    except ImportError:
-        return x
-    return pbroadcast(x, axes)
+    return jax.lax.pcast(x, axes, to="varying")
 
-
-def cotangent_psum(x, axes):
-    """Sum per-shard partial cotangents over ``axes``. A ``jax.vjp``
-    taken INSIDE a shard_map body on 0.4.x never materializes the
-    transpose of the implicit vary-cast that promotes a replicated
-    input into an axis-varying computation (on new JAX that transpose
-    is a psum over the axis), so the input cotangent comes back as
-    this shard's partial; summing the partials reconstitutes it.
-    Identity on new JAX, where the vjp already contains the psum."""
-    axes = tuple(axes)
-    if not axes or _NEW_SHARD_MAP is not None:
-        return x
-    # AD-repair substrate; mesh_psum is itself built on this module
-    # edlint: disable=perf-bare-collective
-    return jax.lax.psum(x, axes)
-
-
-def anchor_replicated(x, axes):
-    """Assert-by-construction that ``x`` is replicated over ``axes``
-    inside a manual region. New JAX's VMA typing proves this from the
-    program; 0.4.x ``check_rep`` inference gives up inside a scanned
-    custom_vjp, and its unchecked fallback mis-transposes in-body
-    psums — so on old JAX anchor the fact with a pmean, which is the
-    identity on a value that is already replicated (what the out_spec
-    demands) and gives the checker a reduction it understands."""
-    axes = tuple(axes)
-    if not axes or _NEW_SHARD_MAP is not None:
-        return x
-    # replication anchor for the 0.4.x rep-checker, identity by contract
-    # edlint: disable=perf-bare-collective
-    return jax.lax.pmean(x, axes)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (new spelling) or ``TPUCompilerParams``
-    (0.4.x) — the Mosaic kwargs are identical across the rename."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

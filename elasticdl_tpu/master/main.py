@@ -13,9 +13,6 @@ from elasticdl_tpu.master.master import Master
 def main(argv=None):
     import os
 
-    from elasticdl_tpu.common.platform import apply_platform_overrides
-
-    apply_platform_overrides()
     args = parse_master_args(argv)
 
     from elasticdl_tpu.common.args import symbol_overrides_from_args

@@ -366,9 +366,11 @@ class Master:
             while True:
                 if self.task_dispatcher.finished():
                     logger.info("Job finished")
+                    self._serve_until_workers_told()
                     return 0
                 if self.task_dispatcher.job_failed():
                     logger.error("Job failed (task retries exhausted)")
+                    self._serve_until_workers_told()
                     return 1
                 if (
                     self.pod_manager is not None
@@ -382,6 +384,20 @@ class Master:
                 time.sleep(poll_secs)
         finally:
             self.stop()
+
+    def _serve_until_workers_told(self, timeout_secs=6.0):
+        """Keep serving until every live worker has been handed the
+        job-over task. A worker whose next poll finds the server gone
+        cannot tell "job over" from "master crashed": it retries for
+        its whole reconnect budget (two minutes, holding its chip)
+        before giving up. A waiting worker polls every ~2 s, so this
+        normally returns within one poll; the bound covers workers
+        that died without deregistering."""
+        deadline = time.time() + timeout_secs
+        while time.time() < deadline:
+            if not self.servicer.workers_awaiting_job_over(timeout_secs):
+                return
+            time.sleep(0.1)
 
     def stop(self):
         self._serving = False

@@ -54,6 +54,9 @@ class MasterServicer:
         # worker_id -> host (from get_comm_info); lets the task monitor
         # evict a dead worker's host from the mesh rendezvous
         self._worker_hosts = {}
+        # workers already answered "job over" by get_task (see
+        # Master.run: the server outlives the job until all have been)
+        self._told_job_over = set()
         # worker_id -> reset_worker count: the logical relaunch epoch a
         # worker stamps onto its gradient pushes as its incarnation.
         # Master-assigned and monotonic per worker_id, so the sync PS
@@ -130,6 +133,18 @@ class MasterServicer:
         with self._lock:
             return dict(self._worker_liveness)
 
+    def workers_awaiting_job_over(self, seen_within_secs):
+        """Workers (ids >= 0; the PS poll under negative ids) heard
+        from within ``seen_within_secs`` that have not yet been handed
+        the job-over task."""
+        horizon = time.time() - seen_within_secs
+        with self._lock:
+            return sorted(
+                w for w, seen in self._worker_liveness.items()
+                if w >= 0 and seen >= horizon
+                and w not in self._told_job_over
+            )
+
     def forget_worker(self, worker_id):
         with self._lock:
             self._worker_liveness.pop(worker_id, None)
@@ -202,6 +217,8 @@ class MasterServicer:
             # Default Task (task_id=0, type=TRAINING): the job is over
             # (success or terminal failure) and the worker should exit.
             # The master distinguishes the two via job_failed().
+            with self._lock:
+                self._told_job_over.add(request.worker_id)
             return pb.Task(master_epoch=self._master_epoch)
         # Queue temporarily empty (e.g. between epochs or during an eval
         # pass): tell the worker to wait and re-poll.

@@ -89,7 +89,8 @@ class Attention(nn.Module):
             out = ulysses_attention(q, k, v, self.mesh, causal=True)
         else:
             out = dot_product_attention(
-                q, k, v, causal=True, impl=self.attention_impl
+                q, k, v, causal=True, impl=self.attention_impl,
+                mesh=self.mesh,
             )
         out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
         out = nn.DenseGeneral(

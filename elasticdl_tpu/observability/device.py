@@ -22,10 +22,13 @@ runtime through three instruments:
    backend reports none, so the ``hbm_pressure`` fleet alert is
    drillable on any box.
 3. **Cost-model step attribution** — on a compile the wrapper
-   opportunistically AOT-relowers the function
-   (``jitted.lower(*args).compile()`` — cheap after the real compile
-   warmed XLA, measured ~25 ms vs ~130 ms cold on CPU) and keeps the
-   executable's ``cost_analysis()`` FLOPs/bytes. The worker's MFU
+   AOT-relowers the function (``jitted.lower(*args).compile()``) and
+   keeps the executable's ``cost_analysis()`` FLOPs/bytes. jax serves
+   that relower from the compilation the call just did — 0.04 s next
+   to a 36 s first call for the zoo transformer on a v5e (PERF.md,
+   PR 21) — so it is not a second compile; the compile log line
+   carries both figures so a jax that stops doing so is noticed.
+   The worker's MFU
    bridge consumes these instead of the hand-coded per-model table,
    and host↔device ``transfer`` counters/spans let
    ``scripts/critical_path.py`` attribute a ``transfer`` segment.
@@ -62,9 +65,9 @@ DEVICE_OBS_ENV = "EDL_DEVICE_OBS"
 COST_ANALYSIS_ENV = "EDL_DEVICE_COST_ANALYSIS"
 HBM_LIMIT_ENV = "EDL_HBM_LIMIT_BYTES"
 
-# AOT cost-analysis relowers per wrapper: each fetch costs one extra
-# (warm) XLA compile, so a shape-churning wrapper must not turn the
-# sentinel into a compile amplifier
+# AOT cost-analysis relowers per wrapper: each fetch re-traces the
+# function, so a shape-churning wrapper must not turn the sentinel
+# into a tracing amplifier
 _COST_FETCH_CAP = 8
 # provenance payload bounds: journal lines are read by humans and the
 # postmortem, not parsed exhaustively
@@ -230,6 +233,8 @@ class _InstrumentedJit:
     def __call__(self, *args, **kwargs):
         t0 = time.time()
         out = self._jitted(*args, **kwargs)
+        # private jax API (works on the pinned 0.9.0): the one probe
+        # that tells a compile from a cache hit at C++ cost
         size = self._jitted._cache_size()
         if size == self._cache_size:
             self.cache_hits += 1
@@ -294,14 +299,23 @@ class _InstrumentedJit:
                     "%s=%s" % kv for kv in sig.items()
                 )[:_PROVENANCE_SIG_MAX],
             )
+        fetch_secs = 0.0
         if self._cost_on and self._cost_fetches < _COST_FETCH_CAP:
+            t1 = time.time()
             self._fetch_cost(args, kwargs)
+            fetch_secs = time.time() - t1
+        # both figures on one line: whether the cost fetch's relower is
+        # a compile-cache hit or a second cold compile reads off it
+        logger.info(
+            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs",
+            self.compiles, self.name, elapsed, fetch_secs,
+        )
 
     def _fetch_cost(self, args, kwargs):
         """Executable-reported FLOPs/bytes for the signature that just
-        compiled. ``lower().compile()`` after the real call re-runs
-        tracing + compilation against a warm XLA (~25 ms on CPU, not a
-        second cold compile) and never touches the jit call cache;
+        compiled. ``lower().compile()`` after the real call re-traces
+        and is handed the executable the call compiled (module
+        docstring), and never touches the jit call cache;
         donated-and-consumed arguments are fine (lowering reads only
         avals). Unavailable backends simply leave the table fallback
         in charge."""

@@ -9,12 +9,19 @@ is picked by backend (or forced via ``impl=``):
 - ``"auto"``    — pallas on TPU when shapes allow, else xla
 """
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common import jax_compat
+from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.ops import flash_attention as _flash
+from elasticdl_tpu.parallel.mesh import DATA_AXES
+
+logger = _logger_factory("elasticdl_tpu.ops.attention")
 
 
 def _check_layout(layout):
@@ -45,22 +52,74 @@ def xla_attention(q, k, v, causal=False, sm_scale=None, layout="bhsd"):
     return jnp.einsum(pv, p, v)
 
 
-def _pallas_ok(q, k, block_q, block_k, layout):
+def _pallas_refusal(q, k, block_q, block_k, layout):
+    """Why the flash kernel cannot take these shapes; "" when it can."""
     seq_axis = 2 if layout == "bhsd" else 1
     seq_q, seq_k = q.shape[seq_axis], k.shape[seq_axis]
     if layout == "bshd" and q.shape[-1] % 128:
-        return False  # fused-head addressing needs lane-aligned heads
+        return "bshd layout needs head_dim %% 128 == 0 (got %d)" % (
+            q.shape[-1],
+        )
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
     if block_q is None:
         block_q = _flash._auto_block(seq_q, 512)
     if block_k is None:
         block_k = _flash._auto_block(seq_k, 1024)
-    return (
-        seq_q % min(block_q, seq_q) == 0
-        and seq_k % min(block_k, seq_k) == 0
-        and seq_q >= 8
-        and seq_k >= 128  # below one lane tile the kernel buys nothing
+    if seq_q % min(block_q, seq_q) or seq_k % min(block_k, seq_k):
+        return "seq (%d, %d) not divisible by blocks (%d, %d)" % (
+            seq_q, seq_k, block_q, block_k,
+        )
+    if seq_q < 8 or seq_k < 128:
+        # below one lane tile the kernel buys nothing
+        return "seq (%d, %d) below one (8, 128) tile" % (seq_q, seq_k)
+    return ""
+
+
+@functools.lru_cache(maxsize=None)
+def _log_auto_once(backend, impl, reason, q_shape, layout):
+    """One line per distinct resolution (this runs at trace time, once
+    per attention layer per trace). A TPU backend that resolves to the
+    XLA reference is a warning: the O(S^2) path is running where the
+    kernel was expected."""
+    log = (
+        logger.warning if backend == "tpu" and impl == "xla"
+        else logger.info
+    )
+    log(
+        "attention impl=auto resolved to %s (backend=%s, q=%s %s%s)",
+        impl, backend, q_shape, layout,
+        ", reason: %s" % reason if reason else "",
+    )
+
+
+def _shard_over_mesh(kernel, mesh, q, layout):
+    """Run ``kernel(q, k, v)`` per shard: batch over the data axes,
+    heads over tp. A ``pallas_call`` has no GSPMD partitioning rule:
+    in a jit over more than one device jax refuses it ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — first met on four v5e chips, PR 21), so the manual
+    region is what lets the kernel run on a mesh at all, and each chip
+    then computes only its shard's attention."""
+    if mesh.size == 1:
+        return kernel
+    head_axis = 1 if layout == "bhsd" else 2
+    data = math.prod(mesh.shape[a] for a in DATA_AXES)
+    tp = mesh.shape["tp"]
+    if q.shape[0] % data or q.shape[head_axis] % tp:
+        raise ValueError(
+            "flash attention over mesh %s: q=%s (%s) must divide its "
+            "batch over data=%d and its heads over tp=%d; pick "
+            "impl='xla' for shapes that do not"
+            % (dict(mesh.shape), q.shape, layout, data, tp)
+        )
+    spec = [DATA_AXES, None, None, None]
+    spec[head_axis] = "tp"
+    spec = P(*spec)
+    # check_vma=False: pallas_call outputs carry no vma annotation
+    return jax_compat.shard_map(
+        kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
 
 
@@ -75,44 +134,46 @@ def dot_product_attention(
     block_k=None,
     interpret=False,
     layout="bhsd",
+    mesh=None,
 ):
+    """``mesh``: the mesh the caller's step is sharded over; the Pallas
+    kernel then runs inside a shard_map over its data and tp axes."""
     _check_layout(layout)
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        impl = (
-            "pallas"
-            if on_tpu and _pallas_ok(q, k, block_q, block_k, layout)
-            else "xla"
+        backend = jax.default_backend()
+        reason = (
+            _pallas_refusal(q, k, block_q, block_k, layout)
+            if backend == "tpu"
+            else "the Pallas kernel needs a TPU backend"
         )
+        impl = "xla" if reason else "pallas"
+        _log_auto_once(backend, impl, reason, tuple(q.shape), layout)
     if impl == "pallas":
-        if layout == "bshd" and q.shape[-1] % 128:
-            # fused-head addressing needs lane-aligned head_dim; honor
-            # the explicit pallas request through a transpose adapter
-            to_bhsd = lambda t: t.transpose(0, 2, 1, 3)
-            out = _flash.flash_attention(
-                to_bhsd(q),
-                to_bhsd(k),
-                to_bhsd(v),
-                causal=causal,
-                sm_scale=sm_scale,
-                block_q=block_q,
-                block_k=block_k,
-                interpret=interpret,
-            )
-            return out.transpose(0, 2, 1, 3)
-        return _flash.flash_attention(
-            q,
-            k,
-            v,
-            causal=causal,
-            sm_scale=sm_scale,
-            block_q=block_q,
-            block_k=block_k,
-            interpret=interpret,
+        kernel = functools.partial(
+            _pallas_attention, causal=causal, sm_scale=sm_scale,
+            block_q=block_q, block_k=block_k, interpret=interpret,
             layout=layout,
         )
+        if mesh is not None:
+            kernel = _shard_over_mesh(kernel, mesh, q, layout)
+        return kernel(q, k, v)
     if impl == "xla":
         return xla_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, layout=layout
         )
     raise ValueError("unknown attention impl %r" % (impl,))
+
+
+def _pallas_attention(q, k, v, *, causal, sm_scale, block_q, block_k,
+                      interpret, layout):
+    # fused-head addressing needs lane-aligned head_dim; honor the
+    # explicit pallas request through a transpose adapter
+    adapt = layout == "bshd" and q.shape[-1] % 128
+    if adapt:
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+        layout = "bhsd"
+    out = _flash.flash_attention(
+        q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
+        block_k=block_k, interpret=interpret, layout=layout,
+    )
+    return out.transpose(0, 2, 1, 3) if adapt else out

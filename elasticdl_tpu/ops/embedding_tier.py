@@ -20,12 +20,25 @@ fused ops make the tier free of host round trips on the hit path:
 
 Two implementations share every call site: a Pallas TPU kernel pair
 (one grid step per row, slot indices scalar-prefetched so the block
-index map does the gather/scatter addressing) and a pure-jnp fallback
-built on XLA gather/scatter (``.at[].set``), which is what CPU CI runs
-— both paths produce identical results, asserted by
-tests/test_device_tier.py. Kernel choice: ``EDL_TIER_KERNEL`` =
-``jnp`` (default everywhere but TPU) | ``pallas`` | ``auto`` (pallas on
-a TPU backend, jnp elsewhere).
+index map does the gather/scatter addressing) and a pure-jnp
+implementation built on XLA gather/scatter (``.at[].set``). Both
+produce identical results, asserted by tests/test_device_tier.py.
+Kernel choice: ``EDL_TIER_KERNEL`` = ``auto`` | ``pallas`` | ``jnp``.
+``auto`` is pallas on a TPU backend when the tier lives on one device,
+and jnp elsewhere — the CPU, and a tier laid out over a multi-device
+mesh, where jax refuses an unwrapped ``pallas_call`` ("Mosaic kernels
+cannot be automatically partitioned") and these kernels have no
+shard_map of their own yet. Nothing stands between the chosen kernel
+and the compiler: a kernel Mosaic refuses fails the step.
+
+Block layout: a ``(1, dim)`` row block of a ``[rows, dim]`` table
+violates Mosaic's rule that a block's last two dims be multiples of
+(8, 128) or equal the array's, so every kernel addresses the 3-D view
+``[rows, 1, dim]`` with ``(1, 1, dim)`` blocks. In that form all
+three compile on a v5e (libtpu 0.0.34) at DeepFM's shapes — dim 8 and
+dim 1, capacity 65,536 + pad — and match the jnp path exactly
+(PERF.md, PR 21). One grid step per row is slow; making ``auto`` the
+faster of the two is a measured perf change, not done here.
 
 Uniqueness contract: ``slots`` entries are unique per call except the
 scratch sentinel, which may repeat — every op writes the scratch row
@@ -33,15 +46,10 @@ with set-semantics only, so duplicate scratch writes race benignly into
 a row nothing ever reads.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.common.env_utils import env_str
-from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-
-logger = _logger_factory("elasticdl_tpu.ops.embedding_tier")
 
 KERNEL_ENV = "EDL_TIER_KERNEL"
 
@@ -56,16 +64,19 @@ TIER_OPT_SLOTS = {
 }
 
 
-def resolve_kernel(kind=None):
-    """-> "pallas" | "jnp". ``auto`` picks pallas only on a TPU
-    backend; CPU CI exercises the jnp path (same call sites)."""
+def resolve_kernel(kind=None, mesh=None):
+    """-> "pallas" | "jnp". ``auto`` picks pallas only where it can
+    run: a TPU backend and a tier on one device (``mesh`` None or of
+    size 1; see the module docstring)."""
     kind = (kind or env_str(KERNEL_ENV, "auto")).strip().lower()
     if kind not in ("auto", "pallas", "jnp"):
         raise ValueError(
             "%s must be auto|pallas|jnp (got %r)" % (KERNEL_ENV, kind)
         )
     if kind == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        one_device = mesh is None or mesh.size == 1
+        on_tpu = jax.default_backend() == "tpu"
+        return "pallas" if on_tpu and one_device else "jnp"
     return kind
 
 
@@ -160,6 +171,21 @@ def _jnp_scatter_apply(state, slots, grads, opt_type, lr, momentum,
 # the BlockSpec index maps over scalar-prefetched slot arrays.
 
 
+def _rows3(x):
+    """[n, dim] -> [n, 1, dim]: the view whose (1, 1, dim) row blocks
+    satisfy the TPU block rule (see module docstring)."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def _row_block(dim, index):
+    """One row of a _rows3 view; ``index(i, prefetched)`` -> row."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec(
+        (1, 1, dim), lambda i, ref: (index(i, ref), 0, 0)
+    )
+
+
 def _pallas_gather(table, slots, miss_rows):
     """combined[i] = slots[i] >= 0 ? table[slots[i]] : miss_rows[i]."""
     from jax.experimental import pallas as pl
@@ -170,7 +196,7 @@ def _pallas_gather(table, slots, miss_rows):
     def kernel(slots_ref, table_blk, miss_blk, out_ref):
         i = pl.program_id(0)
         hit = slots_ref[i] >= 0
-        out_ref[:] = jnp.where(hit, table_blk[:], miss_blk[:])
+        out_ref[...] = jnp.where(hit, table_blk[...], miss_blk[...])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -178,20 +204,17 @@ def _pallas_gather(table, slots, miss_rows):
         in_specs=[
             # the gather: block row = the slot (clamped to 0 on miss;
             # the select above discards the garbage row)
-            pl.BlockSpec(
-                (1, dim),
-                lambda i, slots: (jnp.maximum(slots[i], 0), 0),
-            ),
-            pl.BlockSpec((1, dim), lambda i, slots: (i, 0)),
+            _row_block(dim, lambda i, s: jnp.maximum(s[i], 0)),
+            _row_block(dim, lambda i, s: i),
         ],
-        out_specs=pl.BlockSpec((1, dim), lambda i, slots: (i, 0)),
+        out_specs=_row_block(dim, lambda i, s: i),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, dim), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, dim), table.dtype),
         interpret=INTERPRET,
-    )(slots, table, miss_rows)
+    )(slots, _rows3(table), _rows3(miss_rows)).reshape(n, dim)
 
 
 def _pallas_set_rows(table, slots, rows):
@@ -204,7 +227,7 @@ def _pallas_set_rows(table, slots, rows):
 
     def kernel(slots_ref, table_blk, rows_blk, out_blk):
         del slots_ref, table_blk
-        out_blk[:] = rows_blk[:]
+        out_blk[...] = rows_blk[...]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -212,20 +235,20 @@ def _pallas_set_rows(table, slots, rows):
         in_specs=[
             # the aliased table rides along so unvisited rows keep
             # their values (in-place update via the alias below)
-            pl.BlockSpec((1, dim), lambda i, slots: (slots[i], 0)),
-            pl.BlockSpec((1, dim), lambda i, slots: (i, 0)),
+            _row_block(dim, lambda i, s: s[i]),
+            _row_block(dim, lambda i, s: i),
         ],
-        out_specs=pl.BlockSpec(
-            (1, dim), lambda i, slots: (slots[i], 0)
-        ),
+        out_specs=_row_block(dim, lambda i, s: s[i]),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (table.shape[0], 1, dim), table.dtype
+        ),
         input_output_aliases={1: 0},
         interpret=INTERPRET,
-    )(slots, table, rows)
+    )(slots, _rows3(table), _rows3(rows)).reshape(table.shape)
 
 
 def _pallas_insert_gather(state, ins_slots, ins_rows, evict_slots, slots,
@@ -265,55 +288,57 @@ def _pallas_scatter_apply(state, slots, grads, opt_type, lr, momentum,
     stepf = jnp.take(step, target).astype(jnp.float32)
     n_slots = sum(1 for k in state if k.startswith("slot"))
 
-    def row_spec():
-        return pl.BlockSpec((1, dim), lambda i, tgt: (i, 0))
-
-    def slot_spec():
-        return pl.BlockSpec((1, dim), lambda i, tgt: (tgt[i], 0))
+    row_spec = lambda: _row_block(dim, lambda i, tgt: i)
+    slot_spec = lambda: _row_block(dim, lambda i, tgt: tgt[i])
 
     def kernel(tgt_ref, *refs):
-        i = pl.program_id(0)
+        del tgt_ref
         grad_blk = refs[0]
         step_blk = refs[1]
         in_w = refs[2]
         in_slots = refs[3:3 + n_slots]
         out_w = refs[3 + n_slots]
         out_slots = refs[4 + n_slots:4 + 2 * n_slots]
-        del tgt_ref, i
-        g = grad_blk[:]
-        w = in_w[:]
+        g = grad_blk[...]
+        w = in_w[...]
         if opt_type == "sgd":
-            out_w[:] = w - lr * g
+            out_w[...] = w - lr * g
         elif opt_type in ("momentum", "nesterov"):
-            m = momentum * in_slots[0][:] + g
+            m = momentum * in_slots[0][...] + g
             if opt_type == "nesterov":
-                out_w[:] = w - lr * (g + momentum * m)
+                out_w[...] = w - lr * (g + momentum * m)
             else:
-                out_w[:] = w - lr * m
-            out_slots[0][:] = m
+                out_w[...] = w - lr * m
+            out_slots[0][...] = m
         elif opt_type == "adagrad":
-            s = in_slots[0][:] + g * g
-            out_w[:] = w - lr * g / (jnp.sqrt(s) + epsilon)
-            out_slots[0][:] = s
+            s = in_slots[0][...] + g * g
+            out_w[...] = w - lr * g / (jnp.sqrt(s) + epsilon)
+            out_slots[0][...] = s
         else:  # adam
-            t = step_blk[0, 0]
-            m = beta1 * in_slots[0][:] + (1.0 - beta1) * g
-            v = beta2 * in_slots[1][:] + (1.0 - beta2) * g * g
+            t = step_blk[...]
+            m = beta1 * in_slots[0][...] + (1.0 - beta1) * g
+            v = beta2 * in_slots[1][...] + (1.0 - beta2) * g * g
             mhat = m / (1.0 - jnp.power(beta1, t))
             vhat = v / (1.0 - jnp.power(beta2, t))
-            out_w[:] = w - lr * mhat / (jnp.sqrt(vhat) + epsilon)
-            out_slots[0][:] = m
-            out_slots[1][:] = v
+            out_w[...] = w - lr * mhat / (jnp.sqrt(vhat) + epsilon)
+            out_slots[0][...] = m
+            out_slots[1][...] = v
 
     slot_keys = sorted(k for k in state if k.startswith("slot"))
-    inputs = [grads, stepf[:, None], state["rows"]]
-    inputs += [state[k] for k in slot_keys]
+    # step counts ride as a full [n, dim] row buffer so every operand
+    # shares the one row-block shape
+    inputs = [
+        _rows3(grads),
+        _rows3(jnp.broadcast_to(stepf[:, None], (n, dim))),
+        _rows3(state["rows"]),
+    ]
+    inputs += [_rows3(state[k]) for k in slot_keys]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
             row_spec(),                       # grads
-            pl.BlockSpec((1, 1), lambda i, tgt: (i, 0)),  # step counts
+            row_spec(),                       # step counts
             slot_spec(),                      # weights (read)
         ] + [slot_spec() for _ in slot_keys],
         out_specs=[slot_spec()] + [slot_spec() for _ in slot_keys],
@@ -322,10 +347,7 @@ def _pallas_scatter_apply(state, slots, grads, opt_type, lr, momentum,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct(state["rows"].shape, state["rows"].dtype)
-        ] + [
-            jax.ShapeDtypeStruct(state[k].shape, state[k].dtype)
-            for k in slot_keys
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in inputs[2:]
         ],
         # weights/slot buffers update in place (alias input -> output);
         # input index offsets: [slots(prefetch), grads, step, rows, ...]
@@ -334,7 +356,7 @@ def _pallas_scatter_apply(state, slots, grads, opt_type, lr, momentum,
         ),
         interpret=INTERPRET,
     )(target, *inputs)
-    outs = [outs] if not isinstance(outs, (list, tuple)) else list(outs)
+    outs = [o.reshape(state["rows"].shape) for o in outs]
     new_state = dict(state)
     new_state["rows"] = outs[0]
     for j, key in enumerate(slot_keys):
@@ -385,28 +407,3 @@ def gather_rows(state, slots, kernel="jnp"):
             ),
         )
     return jnp.take(state["rows"], jnp.maximum(slots, 0), axis=0)
-
-
-@functools.lru_cache(maxsize=None)
-def _warn_fallback_once(reason):
-    logger.warning(
-        "Pallas TPU kernels unavailable (%s); device tier falling "
-        "back to the jnp gather/scatter path", reason,
-    )
-
-
-def checked_kernel(kind):
-    """Resolve the configured kernel, degrading pallas->jnp (with one
-    warning) when the Pallas TPU stack is unimportable — the tier must
-    train on any backend the rest of the framework supports."""
-    kind = resolve_kernel(kind)
-    if kind != "pallas":
-        return kind
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    # logged (once) by _warn_fallback_once before degrading
-    except Exception as e:  # edlint: disable=ft-swallowed-except
-        _warn_fallback_once(repr(e))
-        return "jnp"
-    return kind

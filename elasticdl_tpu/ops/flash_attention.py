@@ -27,8 +27,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from elasticdl_tpu.common import jax_compat
-
 NEG_INF = -1e30
 # Lane width of the m/l scratch rows (min f32 tile is (8, 128)).
 _STATS_LANES = 128
@@ -213,7 +211,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
         ],
         out_shape=out_shape,
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -420,7 +418,7 @@ def _bwd(
         out_specs=pl.BlockSpec((1, block_q, head_dim), q_idx),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -455,7 +453,7 @@ def _bwd(
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ),
-        compiler_params=jax_compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -5,18 +5,19 @@ Two reasons every in-body collective routes through here instead of
 bare ``jax.lax.psum``/``all_gather`` (enforced by the edlint rule
 ``perf-bare-collective``):
 
-1. **Correct AD on the pinned runtime.** jax 0.4.x still ships the
-   pmap-era transpose rule ``transpose(psum) = psum``. That convention
-   is right under ``pmap`` (cotangents are per-device partials) but
-   wrong for a ``jax.vjp`` taken *inside* a shard_map body: there the
-   cotangent of a psum output is already replicated over the reduced
-   axes, so psumming it again scales gradients by the axis size. The
-   1f1b pipeline schedule takes exactly such an in-body vjp of the
-   user's stage function, which is how a Megatron-style
-   ``psum(h @ W2, "tp")`` stage silently produced 2x gradients for
-   every tp-sharded leaf on tp=2. Newer JAX fixed the transpose to
-   ``pvary`` (numerically the identity); ``mesh_psum`` pins that
-   convention on every runtime via a custom_vjp.
+1. **Correct AD in unchecked manual regions.** With ``check_vma=True``
+   jax types a psum's output as invariant over the reduced axes and
+   transposes it to a vary-cast. With ``check_vma=False`` — which every
+   shard_map around a ``pallas_call`` needs — ``jax.lax.psum`` still
+   binds the pmap-era primitive whose transpose is another psum. That
+   convention is wrong for a ``jax.vjp`` taken *inside* a shard_map
+   body: the cotangent of a psum output is already replicated over the
+   reduced axes, so psumming it again scales gradients by the axis
+   size. The 1f1b pipeline schedule takes exactly such an in-body vjp
+   of the user's stage function, which is how a Megatron-style
+   ``psum(h @ W2, "tp")`` stage once produced 2x gradients for every
+   tp-sharded leaf on tp=2. ``mesh_psum`` pins the vary-cast transpose
+   in both modes via a custom_vjp.
 
 2. **Byte accounting.** The dense-plane telemetry (collective bytes
    per step) needs to know how much traffic a step puts on the ICI.
@@ -123,45 +124,35 @@ def _normalize_axes(axes):
 
 
 def axis_size_product(axes, mesh=None):
-    """Product of the named axis sizes, from ``mesh`` when given, else
-    from the innermost ambient ``jax.sharding.Mesh`` / physical mesh
-    context. Returns 1 for axes it cannot resolve (size-1 axes and
-    out-of-context tracing are equivalent for byte accounting)."""
-    axes = _normalize_axes(axes)
+    """Product of the named axis sizes: from ``mesh`` when given, else
+    from the enclosing manual region (``jax.lax.axis_size``). An axis
+    neither resolves is an error — a silent 1 would turn ``mesh_pmean``
+    into a sum."""
     n = 1
-    for axis in axes:
-        size = None
-        if mesh is not None:
-            try:
-                size = mesh.shape[axis]
-            except (KeyError, TypeError):
-                size = None
-        if size is None:
-            try:
-                size = jax.core.get_axis_env().axis_size(axis)  # type: ignore[attr-defined]
-            except (AttributeError, KeyError, NameError, ValueError):
-                size = None  # no axis env on this jax, or axis unbound
-        if size is None:
-            try:
-                from jax._src import mesh as _mesh_lib
-
-                ambient = _mesh_lib.thread_resources.env.physical_mesh
-                size = dict(
-                    zip(ambient.axis_names, ambient.devices.shape)
-                ).get(axis)
-            except (ImportError, AttributeError, KeyError, TypeError):
-                size = None  # internal layout moved; size-1 fallback
-        n *= int(size) if size else 1
+    for axis in _normalize_axes(axes):
+        if mesh is not None and axis in mesh.shape:
+            n *= int(mesh.shape[axis])
+            continue
+        try:
+            n *= int(jax.lax.axis_size(axis))
+        except NameError:
+            raise ValueError(
+                "mesh axis %r is bound by neither mesh=%s nor an "
+                "enclosing shard_map" % (
+                    axis,
+                    None if mesh is None else dict(mesh.shape),
+                )
+            ) from None
     return n
 
 
 def mesh_psum(x, axes, *, mesh=None):
-    """All-reduce ``x`` over the named mesh ``axes`` with the modern
-    cotangent convention on every runtime: the transpose of an
-    all-reduce whose output is replicated over ``axes`` is the
+    """All-reduce ``x`` over the named mesh ``axes``; the transpose of
+    an all-reduce whose output is replicated over ``axes`` is the
     identity (a vary-cast), NOT another psum. Safe to call from code
     that is differentiated inside a shard_map body — which bare
-    ``jax.lax.psum`` is not on jax 0.4.x (see module docstring)."""
+    ``jax.lax.psum`` is not under ``check_vma=False`` (see module
+    docstring)."""
     axes = _normalize_axes(axes)
     if mesh is not None:
         # size-1 axes reduce over nothing; dropping them here makes the

@@ -456,25 +456,11 @@ def _1f1b_apply(stage_fn, stacked_params, x, M, mesh, axis, spec,
             b, (axis,) + _spec_axes(spec)
         )
         perm_bwd = [(j, (j - 1) % S) for j in range(S)]
-        # Axes the stage params vary over beyond the stage/batch axes
-        # (e.g. tp in a Megatron-style stage): the vjp's input
-        # cotangent is a per-shard PARTIAL over these — each shard saw
-        # only its slice of the in-stage matmuls — and must be summed
-        # to become the true dx. Contract: a stage that shards params
-        # over such an axis must consume its input through them (the
-        # Megatron layout does); purely-replicated side paths would
-        # make this sum an overcount.
-        _pspec_axes = set()
-        for s in jax.tree_util.tree_leaves(
-            param_specs, is_leaf=lambda s: isinstance(s, P)
-        ):
-            _pspec_axes |= set(_spec_axes(s))
-        partial_axes = tuple(
-            a for a in mesh.axis_names
-            if a in _pspec_axes
-            and a != axis
-            and a not in _spec_axes(spec)
-        )
+        # A stage that shards params over a further axis (tp in a
+        # Megatron-style stage) sees only its slice of the in-stage
+        # matmuls; VMA typing makes the vjp below psum that partial
+        # input cotangent itself (the transpose of the implicit
+        # vary-cast), so no explicit sum follows it.
 
         def pick_chunk(v):
             # pcast to varying over the data axes BEFORE the vjp: with
@@ -515,7 +501,6 @@ def _1f1b_apply(stage_fn, stacked_params, x, M, mesh, axis, spec,
             chunk_params = pick_chunk(v)
             _, vjp = jax.vjp(stage_fn, chunk_params, inp)
             dp, dinp = vjp(g_in)
-            dinp = jax_compat.cotangent_psum(dinp, partial_axes)
             gate = jnp.where(active, 1.0, 0.0).astype(g_loc.dtype)
             dparams = jax.tree_util.tree_map(
                 lambda acc, g: jax.lax.dynamic_update_index_in_dim(
@@ -569,27 +554,6 @@ def _1f1b_apply(stage_fn, stacked_params, x, M, mesh, axis, spec,
         dx = jax.lax.psum(
             dx_mb.reshape((batch_loc,) + g_loc.shape[1:]), axis
         )
-        # mesh axes the out_specs never mention (e.g. tp when a stage
-        # psums over it internally) must be provably replicated; anchor
-        # that for the 0.4.x checker, which cannot infer it through
-        # the scanned vjp (identity on new JAX, see jax_compat)
-        def _missing(spec_like, extra=()):
-            mentioned = set(_spec_axes(spec_like)) | set(extra)
-            return tuple(
-                a for a in mesh.axis_names if a not in mentioned
-            )
-
-        spec_leaves, treedef = jax.tree_util.tree_flatten(
-            param_specs, is_leaf=lambda s: isinstance(s, P)
-        )
-        grad_leaves = treedef.flatten_up_to(dparams)
-        dparams = treedef.unflatten([
-            jax_compat.anchor_replicated(
-                g, _missing(s, DATA_AXES + (axis,))
-            )
-            for g, s in zip(grad_leaves, spec_leaves)
-        ])
-        dx = jax_compat.anchor_replicated(dx, _missing(spec, (axis,)))
         return dparams, dx
 
     # params_layout="device": the caller's stack is already device-

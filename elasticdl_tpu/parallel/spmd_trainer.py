@@ -109,7 +109,31 @@ class SpmdTrainer:
             )(init_rng, sample_features)
         self._train_step = None
         self._eval_step = None
+        self._log_placement(state)
         return state
+
+    def _log_placement(self, state):
+        """Where the state landed: leaves and bytes per PartitionSpec,
+        and what each device's allocator holds — the multi-chip
+        counterpart of the worker's ``devices:`` line (everything on
+        device 0 is the failure this makes visible)."""
+        by_spec = {}
+        for leaf in jax.tree_util.tree_leaves(state):
+            spec = str(getattr(leaf.sharding, "spec", leaf.sharding))
+            count, nbytes = by_spec.get(spec, (0, 0))
+            by_spec[spec] = (count + 1, nbytes + leaf.nbytes)
+        in_use = [
+            (device.memory_stats() or {}).get("bytes_in_use")
+            for device in self.mesh.devices.flat
+        ]
+        logger.info(
+            "SPMD state placement: %s; per-device bytes in use: %s",
+            "; ".join(
+                "%s x%d %.1f MB" % (spec, count, nbytes / 1e6)
+                for spec, (count, nbytes) in sorted(by_spec.items())
+            ),
+            in_use,
+        )
 
     def _set_dense_plan(self, abstract_params):
         self.dense_plan = plan_dense_plane(
@@ -160,6 +184,16 @@ class SpmdTrainer:
         # shardings are per-leaf (rank-dependent) when a batch_spec is
         # set.
         replicated = NamedSharding(self.mesh, P())
+        for leaf in jax.tree_util.tree_leaves(batch["features"]):
+            shape = np.shape(leaf)
+            sharding = self._leaf_sharding(leaf)
+            shard = sharding.shard_shape(shape)
+            logger.info(
+                "SPMD batch: features %s %s split into %d shards of %s",
+                shape, sharding.spec,
+                int(np.prod(shape)) // max(1, int(np.prod(shard))),
+                shard,
+            )
         # recompile sentinels (ISSUE 18): the SPMD step carries the
         # same instrumentation as the single-chip JaxTrainer — compile
         # ledger, cost model, signature provenance — so the worker's

@@ -161,12 +161,17 @@ def _load_native():
 
 
 def _load_native_checked():
-    if not os.path.exists(_SO_PATH):
-        try:
-            _build_native()
-        except Exception as e:
-            logger.warning("Native embedding store build failed: %s", e)
+    # Always run make: a no-op when the .so is newer than its source,
+    # a rebuild when a stale binary was left in the tree (the .so is
+    # git-ignored, so nothing else ties it to this checkout's source).
+    try:
+        _build_native()
+    except Exception as e:
+        logger.warning("Native embedding store build failed: %s", e)
+        if not os.path.exists(_SO_PATH):
             return None
+        # no toolchain at run time but a prebuilt .so (container
+        # image): load it; the ABI check below still guards it
     try:
         lib = ctypes.CDLL(_SO_PATH)
     except OSError as e:

@@ -426,9 +426,6 @@ class ParameterServer:
 
 
 def main(argv=None):
-    from elasticdl_tpu.common.platform import apply_platform_overrides
-
-    apply_platform_overrides()
     args = parse_ps_args(argv)
     from elasticdl_tpu.testing import faults
 

@@ -152,6 +152,13 @@ class PserverServicer:
             ("push_gradients_blob", "lookup_blob", "import_blob")
         )
         self._backend = "native" if self._native_store else "numpy"
+        # said out loud, not only as the edl_ps_native_active gauge:
+        # the numpy store is the tests' reference and slower on apply,
+        # so a PS that fell back to it (failed native build) must be
+        # visible in its log (chip_smoke.py reads this line)
+        logger.info(
+            "PS %d embedding store backend: %s", ps_id, self._backend
+        )
         # Per-table apply fan-out for the async path: with the GIL
         # released inside the native applies, a small pool turns a
         # multi-table push into parallel per-table applies (each

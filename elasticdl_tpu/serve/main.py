@@ -440,9 +440,9 @@ class ServeRole:
 
 
 def main(argv=None):
-    from elasticdl_tpu.common.platform import apply_platform_overrides
+    from elasticdl_tpu.common.platform import configure_compile_cache
 
-    apply_platform_overrides()
+    configure_compile_cache()
     args = parse_serve_args(argv)
     from elasticdl_tpu.testing import faults
 

@@ -228,9 +228,6 @@ class RouterRole:
 
 
 def main(argv=None):
-    from elasticdl_tpu.common.platform import apply_platform_overrides
-
-    apply_platform_overrides()
     args = parse_router_args(argv)
     from elasticdl_tpu.testing import faults
 

@@ -184,7 +184,7 @@ class DeviceEmbeddingTier:
                 " (eviction/flush writeback); %r has none"
                 % type(ps_client).__name__
             )
-        self._kernel = tier_ops.checked_kernel(config.kernel)
+        self._kernel = tier_ops.resolve_kernel(config.kernel, mesh)
         self._opt_type = config.opt_type.lower()
         if self._opt_type not in tier_ops.TIER_OPT_SLOTS:
             raise ValueError(
@@ -1012,6 +1012,10 @@ class DeviceEmbeddingTier:
         except Exception:
             logger.exception("device-tier flush failed at close")
         self._writeback_pool.shutdown(wait=True)
+        logger.info(
+            "device tier closed: hits=%d misses=%d evictions=%d",
+            self.hits, self.misses, self.evictions,
+        )
 
     # -- reporting ------------------------------------------------------
     def stats(self):

@@ -8,6 +8,7 @@ distributed job uses.
 import numpy as np
 
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
+from elasticdl_tpu.common.platform import configure_compile_cache
 from elasticdl_tpu.data.pipeline import (
     Dataset,
     batch_real_count,
@@ -44,6 +45,8 @@ class LocalExecutor:
         )
         self._minibatch_size = minibatch_size
         self._num_epochs = num_epochs
+        # this process compiles the steps itself (no worker entry ran)
+        configure_compile_cache()
         reader_params = data_reader_params or {}
         self._train_reader = (
             create_data_reader(training_data, **reader_params)

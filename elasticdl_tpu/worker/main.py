@@ -28,13 +28,16 @@ def main(argv=None):
         import signal
 
         faulthandler.register(signal.SIGUSR1, all_threads=True)
-    from elasticdl_tpu.common.platform import apply_platform_overrides
+    from elasticdl_tpu.common import platform
 
-    apply_platform_overrides()
+    platform.configure_compile_cache()
     import jax
 
     args = parse_worker_args(argv)
     configure_logging(args.log_level, args.log_file_path)
+    from elasticdl_tpu.common.log_utils import default_logger
+
+    logger = default_logger("elasticdl_tpu.worker.main")
     from elasticdl_tpu.observability import (
         events,
         http_server,
@@ -100,6 +103,10 @@ def main(argv=None):
             master_client, coordinator_port=args.coordinator_port
         )
         multihost_runtime.ensure_runtime()
+    # which device this worker trains on — the first question of any
+    # chip run (chip_smoke.py reads this line); after the multihost
+    # runtime, which must initialize before the backend does
+    logger.info("devices: %s", platform.describe_devices())
     # an elastic restart must resume from the freshest state: default
     # the init dir to the worker's own checkpoint dir, so the relaunch
     # (same command line) picks up everything checkpointed so far
@@ -174,14 +181,12 @@ def main(argv=None):
     )
     # SIGTERM now triggers the graceful drain instead of a bare exit
     drain_hook.bind(worker)
-    from elasticdl_tpu.common.log_utils import default_logger
     from elasticdl_tpu.train.health import HealthSentinelError
     from elasticdl_tpu.worker.worker import (
         EPOCH_RESTART_EXIT_CODE,
         MeshEpochChanged,
     )
 
-    logger = default_logger("elasticdl_tpu.worker.main")
     try:
         worker.run()
         if multihost_runtime is not None:

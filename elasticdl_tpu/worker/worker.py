@@ -216,14 +216,16 @@ class Worker:
                 import jax
 
                 mesh_config = self.spec.mesh_config(jax.device_count())
-            if mesh_config is not None:
-                if "mesh" in factory_params:
-                    from elasticdl_tpu.parallel.mesh import build_mesh
+            if "mesh" in factory_params:
+                from elasticdl_tpu.parallel.mesh import build_mesh
 
-                    mesh = build_mesh(mesh_config)
-                    trainer_kwargs["mesh"] = mesh
-                else:
-                    trainer_kwargs["mesh_config"] = mesh_config
+                # built here even without a mesh flag (every device on
+                # dp) so a mesh-aware model always receives the mesh
+                # its trainer shards over
+                mesh = build_mesh(mesh_config)
+                trainer_kwargs["mesh"] = mesh
+            elif mesh_config is not None:
+                trainer_kwargs["mesh_config"] = mesh_config
         # Mesh-aware models (pipeline stages over pp, ring attention over
         # sp) take the mesh at construction so their internal shard_map
         # schedules target the same mesh the trainer shards over.

@@ -215,7 +215,7 @@ def test_flash_bshd_small_heads_fall_back():
     import numpy as np
 
     from elasticdl_tpu.ops.attention import (
-        _pallas_ok,
+        _pallas_refusal,
         dot_product_attention,
     )
 
@@ -224,7 +224,7 @@ def test_flash_bshd_small_heads_fall_back():
         jnp.asarray(rng.randn(2, 256, 2, 16), jnp.float32)
         for _ in range(3)
     ]
-    assert not _pallas_ok(q, k, None, None, "bshd")
+    assert "head_dim" in _pallas_refusal(q, k, None, None, "bshd")
     out = dot_product_attention(
         q, k, v, causal=True, impl="pallas", layout="bshd",
         interpret=True,
@@ -266,3 +266,55 @@ def test_attention_rejects_unknown_layout():
     q = jnp.asarray(np.zeros((1, 2, 16, 8)), jnp.float32)
     with pytest.raises(ValueError, match="layout"):
         dot_product_attention(q, q, q, layout="BHSD")
+
+
+def test_pallas_attention_sharded_over_mesh_matches_oracle():
+    """With mesh= the kernel runs per shard (batch over the data axes,
+    heads over tp) inside a shard_map — a pallas_call in a plain jit
+    has no GSPMD rule and would run replicated. Values and gradients
+    must equal the unsharded oracle."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from elasticdl_tpu.ops.attention import dot_product_attention
+    from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(dp=2, tp=2, devices=jax.devices()[:4]))
+    rng = np.random.RandomState(5)
+    q, k, v = [
+        jnp.asarray(rng.randn(4, 2, 128, 16), jnp.float32)
+        for _ in range(3)
+    ]
+
+    def loss(impl, **kw):
+        def fn(q, k, v):
+            out = dot_product_attention(
+                q, k, v, causal=True, impl=impl, **kw
+            )
+            return jnp.sum(out * out), out
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, out), grads = loss("pallas", interpret=True, mesh=mesh)(q, k, v)
+    (_, ref), ref_grads = loss("xla")(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref), atol=1e-5
+    )
+    for g, g_ref in zip(grads, ref_grads):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(g_ref), atol=1e-4
+        )
+    # the output leaves the manual region sharded the way it went in
+    # (size-1 fsdp is normalized out of the spec)
+    assert out.sharding.spec[0] in ("dp", ("dp", "fsdp"))
+    assert out.sharding.spec[1] == "tp"
+    # a batch the data axes do not divide is an error, not a silent
+    # replicated (on a chip: refused) kernel
+    import pytest
+
+    with pytest.raises(ValueError, match="must divide"):
+        dot_product_attention(
+            q[:3], k[:3], v[:3], causal=True, impl="pallas",
+            interpret=True, mesh=mesh,
+        )

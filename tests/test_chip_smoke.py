@@ -1,0 +1,251 @@
+"""chip_smoke.py and what ISSUE 21 put under it: the compile-cache
+placement, the peaks table, bench.py's exit code, the smoke's refusal
+to run without a chip, and a CPU rehearsal of both smoke phases at a
+tiny size through the same code the chip run uses."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from elasticdl_tpu.common import platform  # noqa: E402
+
+
+# ---------------------------------------------------------------------
+# compile cache placement
+
+
+def _cache_dir_in_child(env):
+    """(helper's return, jax's configured dir) in a fresh process."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import json, jax\n"
+         "from elasticdl_tpu.common.platform import "
+         "configure_compile_cache\n"
+         "print(json.dumps([configure_compile_cache(), "
+         "jax.config.jax_compilation_cache_dir]))"],
+        capture_output=True, text=True, cwd=REPO, env=env, timeout=120,
+        check=True,
+    )
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_env_set_sets_nothing_in_code(tmp_path, monkeypatch):
+    import jax
+
+    monkeypatch.setenv(platform.COMPILE_CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert platform.configure_compile_cache() == str(tmp_path)
+    # this process imported jax before the variable existed, so any
+    # change here could only have come from the helper
+    assert jax.config.jax_compilation_cache_dir == before
+    # and a fresh process finds jax itself reading the variable
+    env = dict(os.environ, **{platform.COMPILE_CACHE_ENV: str(tmp_path)})
+    assert _cache_dir_in_child(env) == [str(tmp_path), str(tmp_path)]
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(monkeypatch):
+    import jax
+
+    monkeypatch.delenv(platform.COMPILE_CACHE_ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        first = platform.configure_compile_cache()
+        second = platform.configure_compile_cache()
+        assert first == second == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    env = {
+        k: v for k, v in os.environ.items()
+        if k != platform.COMPILE_CACHE_ENV
+    }
+    # two more processes, same answer: nothing in it moves
+    assert _cache_dir_in_child(env) == [first, first]
+    assert _cache_dir_in_child(env) == [first, first]
+
+
+def test_no_cache_dir_literal_outside_the_helper():
+    hits = subprocess.run(
+        ["grep", "-rlI", "jax_compilation_cache_dir", "--include=*.py",
+         "elasticdl_tpu", "scripts", "tests", "bench.py",
+         "chip_smoke.py", "__graft_entry__.py"],
+        capture_output=True, text=True, cwd=REPO,
+    ).stdout.split()
+    assert sorted(hits) == [
+        "elasticdl_tpu/common/platform.py",
+        "tests/test_chip_smoke.py",
+    ]
+
+
+# ---------------------------------------------------------------------
+# peaks table
+
+
+def test_peak_flops_known_and_unknown_device():
+    assert platform.peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(KeyError, match="cpu"):
+        platform.peak_flops("cpu")
+
+
+# ---------------------------------------------------------------------
+# bench.py fails when a configuration fails
+
+
+def _run_bench_main(monkeypatch, capsys, failing):
+    import bench
+
+    monkeypatch.setattr(
+        bench, "_probe_device",
+        lambda: {"platform": "tpu", "kind": "fake", "count": 1},
+    )
+    monkeypatch.setattr(
+        platform, "configure_compile_cache", lambda: None
+    )
+    for name in ("bench_transformer_mfu", "bench_gradaccum_mfu",
+                 "bench_s16k_flash_mfu", "bench_moe_mfu",
+                 "bench_deepfm", "bench_deepfm_latency_ab"):
+        def fn(name=name):
+            if name == failing:
+                raise RuntimeError("mosaic said no")
+            return {name: 1.0}
+        monkeypatch.setattr(bench, name, fn)
+    monkeypatch.setattr(bench, "bench_resnet", lambda: 10.0)
+    try:
+        bench.main()
+        code = 0
+    except SystemExit as e:
+        code = e.code
+    line = capsys.readouterr().out.strip().splitlines()[-1]
+    return code, json.loads(line)
+
+
+def test_bench_exits_nonzero_when_a_sub_bench_raises(monkeypatch, capsys):
+    code, result = _run_bench_main(monkeypatch, capsys, "bench_moe_mfu")
+    assert code not in (0, None)
+    assert "mosaic said no" in result["extra"]["moe_error"]
+    # the configurations after the failed one still ran
+    assert result["extra"]["bench_deepfm"] == 1.0
+    assert result["value"] == 10.0
+
+
+def test_bench_exits_zero_when_all_configurations_run(monkeypatch, capsys):
+    code, result = _run_bench_main(monkeypatch, capsys, None)
+    assert code == 0
+    assert not [k for k in result["extra"] if k.endswith("_error")]
+    assert result["extra"]["device"]["kind"] == "fake"
+
+
+# ---------------------------------------------------------------------
+# chip_smoke.py
+
+
+def test_chip_smoke_refuses_to_run_without_a_chip():
+    """Under JAX_PLATFORMS=cpu: non-zero, fast, names the missing
+    chip, prints no result line."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, cwd=REPO, timeout=120,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert out.returncode != 0
+    assert "no accelerator" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
+    import shutil
+
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py"], capture_output=True,
+        text=True, cwd=tmp_path, timeout=60,
+        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"},
+    )
+    assert out.returncode != 0
+    assert "no elasticdl_tpu package" in out.stderr
+    assert '"ok"' not in out.stdout
+
+
+TINY_TRANSFORMER = '''
+from elasticdl_tpu.models.transformer import *  # noqa: F401,F403
+from elasticdl_tpu.models.transformer import TransformerLM
+
+
+def custom_model(mesh=None):
+    return TransformerLM(
+        vocab_size=512, num_layers=1, num_heads=2, embed_dim=32,
+        mesh=mesh,
+    )
+'''
+
+
+def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
+    """Both phases, tiny, worker on the CPU — the same run_*_phase code
+    (processes, gRPC, log parsing, checks) the chip run executes."""
+    import chip_smoke
+
+    zoo = tmp_path / "tiny_transformer.py"
+    zoo.write_text(TINY_TRANSFORMER)
+    # the children place their compile cache through the environment
+    monkeypatch.setenv(
+        platform.COMPILE_CACHE_ENV, str(tmp_path / "jax_cache")
+    )
+    # one device, as on the one-chip machine (conftest gives THIS
+    # process eight virtual ones, and children would inherit them)
+    monkeypatch.delenv("XLA_FLAGS")
+    on_cpu = dict(
+        platform="cpu", worker_platforms="cpu", attention="xla",
+        tier_kernel="jnp",
+    )
+    dense = dict(
+        chip_smoke.DENSE, model_zoo=str(zoo), seq=128, vocab=512,
+        minibatch=4, steps_per_task=4, tasks=2,
+    )
+    sparse = dict(
+        chip_smoke.SPARSE, minibatch=64, steps_per_task=4, tasks=2,
+    )
+    so_mtime = os.path.getmtime(chip_smoke.NATIVE_SO)
+    children = chip_smoke.Children()
+    try:
+        problems, report, _ = chip_smoke.run_dense_phase(
+            children, str(tmp_path / "dense"), dense, on_cpu
+        )
+        assert not problems, problems
+        assert report["steps"] == 8
+        assert report["attention"] == ["xla"]
+        assert "train_step" in report["compiles"]
+        children.stop_all()
+        problems, report, _ = chip_smoke.run_sparse_phase(
+            children, str(tmp_path / "sparse"), sparse, on_cpu, so_mtime
+        )
+        assert not problems, problems
+        assert report["steps"] == 8
+        assert report["store_backend"] == ["native", "native"]
+        assert report["tier_kernel"] == "jnp" and report["tier_hits"] > 0
+        children.stop_all()
+        # four devices, as on the four-chip host: the worker picks the
+        # SPMD trainer and the model receives its mesh. And the checks
+        # do bite: a chip run must not pass on a CPU worker's log.
+        monkeypatch.setenv(
+            "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
+        )
+        problems, report, logs = chip_smoke.run_dense_phase(
+            children, str(tmp_path / "dense4"), dense,
+            chip_smoke.ON_CHIP | dict(worker_platforms="cpu"),
+        )
+        assert report["steps"] == 8 and report["device_count"] == 4
+        assert "spmd_train_step" in report["compiles"]
+        assert len(problems) == 2, problems
+        assert any("platform" in p for p in problems)
+        assert any("attention" in p for p in problems)
+        worker_log = chip_smoke.read(logs["worker"])
+        assert "SPMD state placement" in worker_log
+        assert "split into 4 shards of (1, 128)" in worker_log
+    finally:
+        children.stop_all()

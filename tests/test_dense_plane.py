@@ -24,12 +24,13 @@ from jax.sharding import PartitionSpec as P
 from elasticdl_tpu.common import jax_compat
 
 
-def test_mesh_psum_values_and_grad_inside_shard_map():
+@pytest.mark.parametrize("check_vma", [True, False])
+def test_mesh_psum_values_and_grad_inside_shard_map(check_vma):
     """mesh_psum reduces like lax.psum AND its vjp taken INSIDE the
     manual region is correct — the transpose of an all-reduce whose
     cotangent is replicated is the identity, not another psum (bare
-    lax.psum gets this wrong by a factor of the axis size on the
-    pinned jax; see parallel/collectives.py)."""
+    lax.psum gets this wrong by a factor of the axis size under
+    check_vma=False; see parallel/collectives.py)."""
     mesh = build_mesh(MeshConfig(dp=1, tp=4, devices=jax.devices()[:4]))
 
     def body(w, x):
@@ -47,6 +48,7 @@ def test_mesh_psum_values_and_grad_inside_shard_map():
         mesh=mesh,
         in_specs=(P("tp"), P()),
         out_specs=(P(), P("tp")),
+        check_vma=check_vma,
     )
     w = jnp.arange(4, dtype=jnp.float32) + 1.0  # shards: 1,2,3,4
     x = jnp.ones((), jnp.float32)
@@ -73,8 +75,11 @@ def test_mesh_pmean_and_gather_scatter_roundtrip():
         gathered = mesh_all_gather(scattered, "dp")
         return mean, gathered
 
+    # an all_gather result is typed VARYING over the gathered axis
+    # (every device holds its own copy), so it leaves the region
+    # stacked over dp — which also shows each device's copy is whole
     wrapped = jax_compat.shard_map(
-        body, mesh=mesh, in_specs=(P("dp"),), out_specs=(P(), P(None))
+        body, mesh=mesh, in_specs=(P("dp"),), out_specs=(P(), P("dp"))
     )
     x = jnp.arange(8, dtype=jnp.float32).reshape(4, 2)
     mean, gathered = jax.jit(wrapped)(x)
@@ -82,10 +87,37 @@ def test_mesh_pmean_and_gather_scatter_roundtrip():
         np.asarray(mean), np.asarray(x.sum(0, keepdims=True) / 4.0)
     )
     # reduce-scatter sums the 4 scalings (1+2+3+4 = 10) and leaves each
-    # device its slice; the all-gather re-materializes the full sum
+    # device its slice; the all-gather re-materializes the full sum on
+    # every device
     np.testing.assert_allclose(
-        np.asarray(gathered), 10.0 * np.arange(8, dtype=np.float32)
+        np.asarray(gathered).reshape(4, 8),
+        np.tile(10.0 * np.arange(8, dtype=np.float32), (4, 1)),
     )
+
+
+def test_axis_size_product_resolves_or_raises():
+    """Sizes come from mesh= or the enclosing manual region; an axis
+    neither binds is an error, not a silent 1 (mesh_pmean would
+    otherwise return the SUM)."""
+    from elasticdl_tpu.parallel.collectives import axis_size_product
+
+    mesh = build_mesh(MeshConfig(dp=4, devices=jax.devices()[:4]))
+    assert axis_size_product(("dp", "tp"), mesh) == 4
+
+    def body(x):
+        # no mesh= : the size must come from the manual region
+        return mesh_pmean(x, "dp")
+
+    wrapped = jax_compat.shard_map(
+        body, mesh=mesh, in_specs=(P("dp"),), out_specs=P()
+    )
+    x = jnp.arange(8, dtype=jnp.float32).reshape(4, 2)
+    np.testing.assert_allclose(
+        np.asarray(jax.jit(wrapped)(x)),
+        np.asarray(x.sum(0, keepdims=True) / 4.0),
+    )
+    with pytest.raises(ValueError, match="nope"):
+        axis_size_product("nope")
 
 
 def test_track_collective_bytes_ring_costs():

@@ -559,3 +559,23 @@ def test_telemetry_blob_tier_fields_reach_statusz():
     assert entry["tier_hit_rate"] == pytest.approx(0.93, abs=1e-4)
     assert entry["tier_hits"] == 930
     assert entry["tier_evictions"] == 3
+
+
+def test_auto_kernel_is_pallas_only_on_one_tpu_device(monkeypatch):
+    """``auto`` follows what the process can observe: pallas on a TPU
+    backend with the tier on one device; jnp on the CPU and on a
+    multi-device mesh (jax refuses an unwrapped pallas_call in a
+    partitioned jit — met on four v5e chips, PR 21)."""
+    import jax
+
+    from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    one = build_mesh(MeshConfig(dp=1, devices=jax.devices()[:1]))
+    four = build_mesh(MeshConfig(dp=4, devices=jax.devices()[:4]))
+    assert tier_ops.resolve_kernel("auto") == "jnp"  # CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert tier_ops.resolve_kernel("auto") == "pallas"
+    assert tier_ops.resolve_kernel("auto", one) == "pallas"
+    assert tier_ops.resolve_kernel("auto", four) == "jnp"
+    # an explicit choice is never second-guessed
+    assert tier_ops.resolve_kernel("pallas", four) == "pallas"
