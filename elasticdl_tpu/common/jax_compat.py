@@ -14,9 +14,10 @@ __all__ = ["shard_map", "pvary"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map``. ``check_vma=False`` is for manual regions that
-    contain a ``pallas_call`` (its out ShapeDtypeStructs carry no vma,
-    which the checker rejects)."""
+    """``jax.shard_map``. A ``pallas_call`` inside a checked region must
+    declare its outputs' vma (ops/flash_attention.py ``_out_struct``);
+    ``check_vma=False`` is left for the ring-attention flash fold, which
+    the checker still refuses (ops/ring_attention.py)."""
     return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=check_vma,

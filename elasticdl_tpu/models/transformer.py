@@ -88,9 +88,11 @@ class Attention(nn.Module):
         elif self.attention_impl == "ulysses":
             out = ulysses_attention(q, k, v, self.mesh, causal=True)
         else:
+            # (B, H, S, d) on the mesh: batch over the data axes, heads
+            # over tp (the layout sharding_rules() gives the projections)
             out = dot_product_attention(
                 q, k, v, causal=True, impl=self.attention_impl,
-                mesh=self.mesh,
+                mesh=self.mesh, spec=P(DATA_AXES, "tp", None, None),
             )
         out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
         out = nn.DenseGeneral(

@@ -14,12 +14,10 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.ops import flash_attention as _flash
-from elasticdl_tpu.parallel.mesh import DATA_AXES
 
 logger = _logger_factory("elasticdl_tpu.ops.attention")
 
@@ -93,33 +91,33 @@ def _log_auto_once(backend, impl, reason, q_shape, layout):
     )
 
 
-def _shard_over_mesh(kernel, mesh, q, layout):
-    """Run ``kernel(q, k, v)`` per shard: batch over the data axes,
-    heads over tp. A ``pallas_call`` has no GSPMD partitioning rule:
-    in a jit over more than one device jax refuses it ("Mosaic kernels
-    cannot be automatically partitioned. Please wrap the call in a
-    shard_map" — first met on four v5e chips, PR 21), so the manual
-    region is what lets the kernel run on a mesh at all, and each chip
-    then computes only its shard's attention."""
-    if mesh.size == 1:
+def _shard_over_mesh(kernel, mesh, spec, q):
+    """Run ``kernel(q, k, v)`` per shard of ``spec`` (the caller's
+    layout of q/k/v and of the output over ``mesh``). A ``pallas_call``
+    has no GSPMD partitioning rule: in a jit over more than one device
+    jax refuses it ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map" — first met on
+    four v5e chips, PR 21), so the manual region is what lets the
+    kernel run on a mesh at all, and each chip then computes only its
+    shard's attention. Inside a region that is already manual over the
+    whole mesh (the pipeline's stage body) q/k/v are one shard and the
+    kernel runs on them as is: a second shard_map cannot open there."""
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    if mesh.size == 1 or set(mesh.axis_names) <= set(manual):
         return kernel
-    head_axis = 1 if layout == "bhsd" else 2
-    data = math.prod(mesh.shape[a] for a in DATA_AXES)
-    tp = mesh.shape["tp"]
-    if q.shape[0] % data or q.shape[head_axis] % tp:
-        raise ValueError(
-            "flash attention over mesh %s: q=%s (%s) must divide its "
-            "batch over data=%d and its heads over tp=%d; pick "
-            "impl='xla' for shapes that do not"
-            % (dict(mesh.shape), q.shape, layout, data, tp)
-        )
-    spec = [DATA_AXES, None, None, None]
-    spec[head_axis] = "tp"
-    spec = P(*spec)
-    # check_vma=False: pallas_call outputs carry no vma annotation
+    for dim, axes in enumerate(spec):
+        if axes is None:
+            continue
+        axes = (axes,) if isinstance(axes, str) else axes
+        ways = math.prod(mesh.shape[a] for a in axes)
+        if q.shape[dim] % ways:
+            raise ValueError(
+                "flash attention over mesh %s: dim %d of q=%s does not "
+                "divide over %s=%d; pick impl='xla' for shapes that do "
+                "not" % (dict(mesh.shape), dim, q.shape, axes, ways)
+            )
     return jax_compat.shard_map(
         kernel, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False,
     )
 
 
@@ -135,9 +133,11 @@ def dot_product_attention(
     interpret=False,
     layout="bhsd",
     mesh=None,
+    spec=None,
 ):
-    """``mesh``: the mesh the caller's step is sharded over; the Pallas
-    kernel then runs inside a shard_map over its data and tp axes."""
+    """``mesh`` and ``spec``: the mesh the caller's step is sharded
+    over and the PartitionSpec of q/k/v on it; the Pallas kernel then
+    runs inside a shard_map over them."""
     _check_layout(layout)
     if impl == "auto":
         backend = jax.default_backend()
@@ -155,7 +155,7 @@ def dot_product_attention(
             layout=layout,
         )
         if mesh is not None:
-            kernel = _shard_over_mesh(kernel, mesh, q, layout)
+            kernel = _shard_over_mesh(kernel, mesh, spec, q)
         return kernel(q, k, v)
     if impl == "xla":
         return xla_attention(

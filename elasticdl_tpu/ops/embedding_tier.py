@@ -18,27 +18,27 @@ fused ops make the tier free of host round trips on the hit path:
   adagrad / adam so a row trains the same whichever tier holds it.
 - ``gather_rows`` — plain slot gather (flush/writeback reads).
 
-Two implementations share every call site: a Pallas TPU kernel pair
-(one grid step per row, slot indices scalar-prefetched so the block
-index map does the gather/scatter addressing) and a pure-jnp
-implementation built on XLA gather/scatter (``.at[].set``). Both
-produce identical results, asserted by tests/test_device_tier.py.
-Kernel choice: ``EDL_TIER_KERNEL`` = ``auto`` | ``pallas`` | ``jnp``.
-``auto`` is pallas on a TPU backend when the tier lives on one device,
-and jnp elsewhere — the CPU, and a tier laid out over a multi-device
-mesh, where jax refuses an unwrapped ``pallas_call`` ("Mosaic kernels
-cannot be automatically partitioned") and these kernels have no
-shard_map of their own yet. Nothing stands between the chosen kernel
-and the compiler: a kernel Mosaic refuses fails the step.
+Two implementations share every call site: a pure-jnp one built on
+XLA gather/scatter (``.at[].set``), and a Pallas TPU kernel pair (one
+grid step per row, slot indices scalar-prefetched so the block index
+map does the gather/scatter addressing). Both produce identical
+results, asserted by tests/test_device_tier.py. Kernel choice:
+``EDL_TIER_KERNEL`` = ``auto`` | ``jnp`` | ``pallas``, and ``auto``
+means jnp on every backend. The Pallas pair is an explicit opt-in: on
+the v5e it compiles and matches jnp exactly but was 1.6x to 5x slower
+in isolation at DeepFM's shapes, it has no shard_map of its own, so
+jax refuses it on a multi-device mesh ("Mosaic kernels cannot be
+automatically partitioned"), and it cannot run on the CPU (PERF.md,
+PR 21; ``scripts/probe_kernels.py`` is the probe). Whether it is made
+faster or deleted is ROADMAP Speed work. Nothing stands between the
+chosen kernel and the compiler: a kernel Mosaic refuses fails the step.
 
-Block layout: a ``(1, dim)`` row block of a ``[rows, dim]`` table
-violates Mosaic's rule that a block's last two dims be multiples of
-(8, 128) or equal the array's, so every kernel addresses the 3-D view
-``[rows, 1, dim]`` with ``(1, 1, dim)`` blocks. In that form all
-three compile on a v5e (libtpu 0.0.34) at DeepFM's shapes — dim 8 and
-dim 1, capacity 65,536 + pad — and match the jnp path exactly
-(PERF.md, PR 21). One grid step per row is slow; making ``auto`` the
-faster of the two is a measured perf change, not done here.
+Block layout of the Pallas pair: a ``(1, dim)`` row block of a
+``[rows, dim]`` table violates Mosaic's rule that a block's last two
+dims be multiples of (8, 128) or equal the array's, so every kernel
+addresses the 3-D view ``[rows, 1, dim]`` with ``(1, 1, dim)`` blocks.
+In that form all three compile on a v5e (libtpu 0.0.34) at DeepFM's
+shapes — dim 8 and dim 1, capacity 65,536 + pad.
 
 Uniqueness contract: ``slots`` entries are unique per call except the
 scratch sentinel, which may repeat — every op writes the scratch row
@@ -64,20 +64,15 @@ TIER_OPT_SLOTS = {
 }
 
 
-def resolve_kernel(kind=None, mesh=None):
-    """-> "pallas" | "jnp". ``auto`` picks pallas only where it can
-    run: a TPU backend and a tier on one device (``mesh`` None or of
-    size 1; see the module docstring)."""
+def resolve_kernel(kind=None):
+    """-> "jnp" | "pallas". ``auto`` is jnp everywhere; pallas runs only
+    when asked for by name (see the module docstring)."""
     kind = (kind or env_str(KERNEL_ENV, "auto")).strip().lower()
     if kind not in ("auto", "pallas", "jnp"):
         raise ValueError(
             "%s must be auto|pallas|jnp (got %r)" % (KERNEL_ENV, kind)
         )
-    if kind == "auto":
-        one_device = mesh is None or mesh.size == 1
-        on_tpu = jax.default_backend() == "tpu"
-        return "pallas" if on_tpu and one_device else "jnp"
-    return kind
+    return "jnp" if kind == "auto" else kind
 
 
 def init_table_state(capacity, dim, opt_type, dtype=jnp.float32):
@@ -97,7 +92,7 @@ def init_table_state(capacity, dim, opt_type, dtype=jnp.float32):
 
 
 # ---------------------------------------------------------------------
-# pure-jnp implementations (XLA gather/scatter; the CPU-CI path)
+# pure-jnp implementations (XLA gather/scatter; what ``auto`` runs)
 
 
 def _jnp_insert_gather(state, ins_slots, ins_rows, evict_slots, slots,

@@ -164,6 +164,15 @@ def _q_specs(heads):
     return q_idx, k_idx, stat_idx
 
 
+def _out_struct(shape, dtype, *operands):
+    """A pallas_call output that varies over every mesh axis any operand
+    varies over: inside a VMA-checked ``shard_map`` (the pipeline's
+    manual region) a pallas_call must say so itself; anywhere else the
+    set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
          heads=None):
     if heads is None:
@@ -190,8 +199,8 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     # second-minor dim equal the full array dim, satisfying the TPU
     # (8, 128) tiling rule that a 2-D (1, block_q) block violates
     out_shape = (
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
-        jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
+        _out_struct(q.shape, q.dtype, q, k, v),
+        _out_struct((bh, 1, seq_q), jnp.float32, q, k, v),
     )
     o, lse = pl.pallas_call(
         kernel,
@@ -393,6 +402,7 @@ def _bwd(
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     q_idx, k_idx, stat_idx = _q_specs(heads)
+    operands = (q, k, v, do, lse, delta)
 
     def swapped(idx):
         # the dkv grid iterates (bh, k-block, q-block)
@@ -417,12 +427,12 @@ def _bwd(
         ],
         out_specs=pl.BlockSpec((1, block_q, head_dim), q_idx),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=_out_struct(q.shape, q.dtype, *operands),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(*operands)
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -450,14 +460,14 @@ def _bwd(
             pltpu.VMEM((block_k, head_dim), jnp.float32),
         ],
         out_shape=(
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            _out_struct(k.shape, k.dtype, *operands),
+            _out_struct(v.shape, v.dtype, *operands),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(*operands)
     return dq, dk, dv
 
 

@@ -288,6 +288,7 @@ def ring_attention(
         return dot_product_attention(
             q, k, v, causal=causal, sm_scale=sm_scale, impl=impl,
             block_q=block_q, block_k=block_k, interpret=interpret,
+            mesh=mesh, spec=spec,
         )
 
     spec_axes = _spec_axis_names(spec)
@@ -334,9 +335,10 @@ def ring_attention(
             o = fold(merge(q_loc), merge(k_loc), merge(v_loc))
             return o.reshape(b, h, s, d)
 
-        # check_vma=False: pallas_call's out ShapeDtypeStructs carry no
-        # vma annotation, which the VMA checker rejects inside a
-        # checked manual region; the specs here mirror the (long
+        # check_vma=False: under the checker jax 0.9.0 refuses the
+        # kernel inside this fold in interpret mode ("dynamic_slice
+        # requires varying manual axes to match"), although the kernel's
+        # outputs declare their vma; the specs here mirror the (long
         # VMA-checked) einsum path below
         return jax_compat.shard_map(
             flash_local_fn,

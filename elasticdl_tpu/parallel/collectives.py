@@ -7,8 +7,8 @@ bare ``jax.lax.psum``/``all_gather`` (enforced by the edlint rule
 
 1. **Correct AD in unchecked manual regions.** With ``check_vma=True``
    jax types a psum's output as invariant over the reduced axes and
-   transposes it to a vary-cast. With ``check_vma=False`` — which every
-   shard_map around a ``pallas_call`` needs — ``jax.lax.psum`` still
+   transposes it to a vary-cast. With ``check_vma=False`` — which the
+   ring-attention flash fold still needs — ``jax.lax.psum`` still
    binds the pmap-era primitive whose transpose is another psum. That
    convention is wrong for a ``jax.vjp`` taken *inside* a shard_map
    body: the cotangent of a psum output is already replicated over the

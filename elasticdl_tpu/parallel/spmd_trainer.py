@@ -116,23 +116,30 @@ class SpmdTrainer:
         """Where the state landed: leaves and bytes per PartitionSpec,
         and what each device's allocator holds — the multi-chip
         counterpart of the worker's ``devices:`` line (everything on
-        device 0 is the failure this makes visible)."""
+        device 0 is the failure this makes visible). On a mesh that
+        spans processes only this process's devices can be asked:
+        ``memory_stats`` raises for a device another process owns."""
         by_spec = {}
         for leaf in jax.tree_util.tree_leaves(state):
             spec = str(getattr(leaf.sharding, "spec", leaf.sharding))
             count, nbytes = by_spec.get(spec, (0, 0))
             by_spec[spec] = (count + 1, nbytes + leaf.nbytes)
+        local = [
+            device for device in self.mesh.devices.flat
+            if device.process_index == jax.process_index()
+        ]
         in_use = [
             (device.memory_stats() or {}).get("bytes_in_use")
-            for device in self.mesh.devices.flat
+            for device in local
         ]
         logger.info(
-            "SPMD state placement: %s; per-device bytes in use: %s",
+            "SPMD state placement: %s; bytes in use on this process's "
+            "%d of %d devices: %s",
             "; ".join(
                 "%s x%d %.1f MB" % (spec, count, nbytes / 1e6)
                 for spec, (count, nbytes) in sorted(by_spec.items())
             ),
-            in_use,
+            len(local), self.mesh.size, in_use,
         )
 
     def _set_dense_plan(self, abstract_params):
@@ -184,16 +191,6 @@ class SpmdTrainer:
         # shardings are per-leaf (rank-dependent) when a batch_spec is
         # set.
         replicated = NamedSharding(self.mesh, P())
-        for leaf in jax.tree_util.tree_leaves(batch["features"]):
-            shape = np.shape(leaf)
-            sharding = self._leaf_sharding(leaf)
-            shard = sharding.shard_shape(shape)
-            logger.info(
-                "SPMD batch: features %s %s split into %d shards of %s",
-                shape, sharding.spec,
-                int(np.prod(shape)) // max(1, int(np.prod(shard))),
-                shard,
-            )
         # recompile sentinels (ISSUE 18): the SPMD step carries the
         # same instrumentation as the single-chip JaxTrainer — compile
         # ledger, cost model, signature provenance — so the worker's
@@ -273,9 +270,24 @@ class SpmdTrainer:
 
     def train_step(self, state, batch):
         state = self.ensure_state(state, batch)
+        sharded = self.shard_batch(batch)
         if self._train_step is None:
             self._build_steps(batch)
-        return self._train_step(state, self.shard_batch(batch))
+            self._log_batch_split(sharded["features"])
+        return self._train_step(state, sharded)
+
+    @staticmethod
+    def _log_batch_split(features):
+        """How the first GLOBAL batch was split (read off the sharded
+        arrays: on a mesh that spans processes the host batch is only
+        this process's rows)."""
+        for leaf in jax.tree_util.tree_leaves(features):
+            shard = leaf.sharding.shard_shape(leaf.shape)
+            logger.info(
+                "SPMD batch: features %s %s split into %d shards of %s",
+                leaf.shape, leaf.sharding.spec,
+                leaf.size // max(1, int(np.prod(shard))), shard,
+            )
 
     def eval_step(self, state, batch):
         if self._eval_step is None:

@@ -184,7 +184,7 @@ class DeviceEmbeddingTier:
                 " (eviction/flush writeback); %r has none"
                 % type(ps_client).__name__
             )
-        self._kernel = tier_ops.resolve_kernel(config.kernel, mesh)
+        self._kernel = tier_ops.resolve_kernel(config.kernel)
         self._opt_type = config.opt_type.lower()
         if self._opt_type not in tier_ops.TIER_OPT_SLOTS:
             raise ValueError(

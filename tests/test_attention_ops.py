@@ -277,10 +277,15 @@ def test_pallas_attention_sharded_over_mesh_matches_oracle():
     import jax.numpy as jnp
     import numpy as np
 
+    from jax.sharding import PartitionSpec as P
+
     from elasticdl_tpu.ops.attention import dot_product_attention
-    from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
+    from elasticdl_tpu.parallel.mesh import (
+        DATA_AXES, MeshConfig, build_mesh,
+    )
 
     mesh = build_mesh(MeshConfig(dp=2, tp=2, devices=jax.devices()[:4]))
+    spec = P(DATA_AXES, "tp", None, None)
     rng = np.random.RandomState(5)
     q, k, v = [
         jnp.asarray(rng.randn(4, 2, 128, 16), jnp.float32)
@@ -296,7 +301,9 @@ def test_pallas_attention_sharded_over_mesh_matches_oracle():
         return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
                                           has_aux=True))
 
-    (_, out), grads = loss("pallas", interpret=True, mesh=mesh)(q, k, v)
+    (_, out), grads = loss(
+        "pallas", interpret=True, mesh=mesh, spec=spec
+    )(q, k, v)
     (_, ref), ref_grads = loss("xla")(q, k, v)
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), atol=1e-5
@@ -309,12 +316,22 @@ def test_pallas_attention_sharded_over_mesh_matches_oracle():
     # (size-1 fsdp is normalized out of the spec)
     assert out.sharding.spec[0] in ("dp", ("dp", "fsdp"))
     assert out.sharding.spec[1] == "tp"
+    # the ring of one (sp=1) hands its mesh and spec to the same wrap
+    from elasticdl_tpu.ops.ring_attention import ring_attention
+
+    ring_out = jax.jit(lambda q, k, v: ring_attention(
+        q, k, v, mesh, causal=True, block_impl="flash", interpret=True
+    ))(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(ring_out), np.asarray(ref), atol=1e-5
+    )
+    assert ring_out.sharding.spec[1] == "tp"
     # a batch the data axes do not divide is an error, not a silent
     # replicated (on a chip: refused) kernel
     import pytest
 
-    with pytest.raises(ValueError, match="must divide"):
+    with pytest.raises(ValueError, match="does not divide"):
         dot_product_attention(
             q[:3], k[:3], v[:3], causal=True, impl="pallas",
-            interpret=True, mesh=mesh,
+            interpret=True, mesh=mesh, spec=spec,
         )

@@ -201,7 +201,6 @@ def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
     monkeypatch.delenv("XLA_FLAGS")
     on_cpu = dict(
         platform="cpu", worker_platforms="cpu", attention="xla",
-        tier_kernel="jnp",
     )
     dense = dict(
         chip_smoke.DENSE, model_zoo=str(zoo), seq=128, vocab=512,

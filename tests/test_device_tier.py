@@ -561,21 +561,17 @@ def test_telemetry_blob_tier_fields_reach_statusz():
     assert entry["tier_evictions"] == 3
 
 
-def test_auto_kernel_is_pallas_only_on_one_tpu_device(monkeypatch):
-    """``auto`` follows what the process can observe: pallas on a TPU
-    backend with the tier on one device; jnp on the CPU and on a
-    multi-device mesh (jax refuses an unwrapped pallas_call in a
-    partitioned jit — met on four v5e chips, PR 21)."""
+def test_auto_kernel_is_jnp_on_every_backend(monkeypatch):
+    """``auto`` means jnp wherever the tier runs — the Pallas pair
+    matched it exactly on the v5e but was slower, and cannot run on a
+    mesh or the CPU (ops/embedding_tier.py) — and pallas runs only when
+    named."""
     import jax
 
-    from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    one = build_mesh(MeshConfig(dp=1, devices=jax.devices()[:1]))
-    four = build_mesh(MeshConfig(dp=4, devices=jax.devices()[:4]))
     assert tier_ops.resolve_kernel("auto") == "jnp"  # CPU backend
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert tier_ops.resolve_kernel("auto") == "pallas"
-    assert tier_ops.resolve_kernel("auto", one) == "pallas"
-    assert tier_ops.resolve_kernel("auto", four) == "jnp"
-    # an explicit choice is never second-guessed
-    assert tier_ops.resolve_kernel("pallas", four) == "pallas"
+    assert tier_ops.resolve_kernel("auto") == "jnp"
+    assert tier_ops.resolve_kernel() == "jnp"
+    assert tier_ops.resolve_kernel("pallas") == "pallas"
+    with pytest.raises(ValueError, match="must be auto"):
+        tier_ops.resolve_kernel("mosaic")

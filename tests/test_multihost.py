@@ -2,6 +2,12 @@
 (reference allreduce_trainer.py:94-118 re-init semantics), driven
 against the real MeshRendezvous."""
 
+import json
+import os
+import socket
+import subprocess
+import sys
+
 import pytest
 
 from elasticdl_tpu.master.rendezvous import MeshRendezvous
@@ -163,3 +169,41 @@ def test_failed_init_retries_with_fresh_membership():
     # final successful init targets the POST-change membership
     assert fake.calls[-1] == ("init", "hostB:5000", 2, 0)
     assert ("init-failed",) in fake.calls
+
+
+def test_create_state_on_a_mesh_spanning_processes():
+    """Two real jax.distributed CPU processes, dp over both: state
+    creation (whose placement log may only ask this process's devices
+    for memory_stats — a device another process owns raises) and one
+    lockstep step agree on the loss."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with socket.socket() as sock:
+        sock.bind(("127.0.0.1", 0))
+        port = sock.getsockname()[1]
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-m", "tests.drivers.multihost_state_driver",
+             "--coordinator", "127.0.0.1:%d" % port, "--rank", str(rank)],
+            env=dict(os.environ, PYTHONPATH=repo),
+            cwd=repo,
+            stdout=subprocess.PIPE,
+            stderr=subprocess.PIPE,
+            text=True,
+        )
+        for rank in (0, 1)
+    ]
+    try:
+        results = []
+        for proc in procs:
+            out, err = proc.communicate(timeout=180)
+            assert proc.returncode == 0, err[-3000:]
+            assert "this process's 2 of 4 devices" in err
+            assert "features (8, 8, 8)" in err  # the GLOBAL batch
+            results.append(json.loads(out.strip().splitlines()[-1]))
+    finally:
+        for proc in procs:
+            if proc.poll() is None:
+                proc.kill()
+    assert [r["rank"] for r in results] == [0, 1]
+    assert all(r["devices"] == 4 and r["local"] == 2 for r in results)
+    assert results[0]["loss"] == results[1]["loss"]

@@ -159,6 +159,57 @@ def test_pipelined_lm_matches_sequential_fallback():
     )
 
 
+def test_pipelined_lm_runs_the_flash_kernel_in_its_manual_region(
+        monkeypatch):
+    """attention_impl="pallas" (what "auto" resolves to on a TPU) under
+    pp=4: the stage body is already inside pipeline_apply's VMA-checked
+    manual region, so the dispatcher must run the kernel there as is —
+    no nested shard_map over the block's mesh — with pallas_call
+    outputs that declare their own vma.
+    Loss and gradients equal the meshless XLA-attention model's.
+    Pallas runs in interpret mode on the CPU."""
+    import functools
+
+    monkeypatch.setattr(
+        transformer,
+        "dot_product_attention",
+        functools.partial(
+            transformer.dot_product_attention, interpret=True
+        ),
+    )
+    mesh = build_mesh(MeshConfig(dp=2, pp=4))
+    kwargs = dict(
+        vocab_size=64, num_layers=4, num_stages=4, num_heads=2,
+        embed_dim=16, num_microbatches=2,
+    )
+    tokens = jnp.asarray(_lm_batch(batch=8, seq=128)["features"])
+
+    def loss_and_grads(impl, mesh):
+        model = pipeline_transformer.PipelinedTransformerLM(
+            attention_impl=impl, mesh=mesh, **kwargs
+        )
+        variables = model.init(jax.random.PRNGKey(0), tokens)
+
+        def loss_fn(params):
+            logits = model.apply({"params": params}, tokens)
+            return jnp.mean(
+                transformer.loss(tokens, logits).astype(jnp.float32)
+            )
+
+        return jax.jit(jax.value_and_grad(loss_fn))(variables["params"])
+
+    ref_loss, ref_grads = loss_and_grads("xla", None)
+    loss, grads = loss_and_grads("pallas", mesh)
+    assert np.isclose(float(loss), float(ref_loss), rtol=1e-5)
+    for got, ref in zip(
+        jax.tree_util.tree_leaves(grads),
+        jax.tree_util.tree_leaves(ref_grads),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(ref), rtol=2e-3, atol=2e-5
+        )
+
+
 def test_zoo_contract_mesh_injection():
     """The model-zoo entry must build a pipeline matching the mesh's pp
     extent when given a mesh (the worker passes its trainer mesh), and a
