@@ -20,12 +20,17 @@ the chip at a time: this parent never imports jax, the master and the
 PS are pinned to the CPU, only the worker gets the chip, and each
 phase's processes have exited before the next phase starts.
 
-Exit code 0 and a last stdout line
-``{"ok": true, "device": {...}, ...}`` only when every check of both
-phases held on an accelerator. No accelerator (or no program next to
-this script): non-zero exit and no result line. The per-phase figures
-in that line (seconds to first step, losses) are facts about what ran,
-not performance results.
+Exit code 0 and the last stdout line
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+— those keys and no others, the device as jax reports it — only when
+every check of both phases held on an accelerator. A failed phase on an
+accelerator: exit 1 and the same line with ``"ok": false``. No
+accelerator (or no program next to this script): non-zero exit and no
+result line. The line before the result, ``chip_smoke: summary: {...}``
+(also ``chiprun_out/chip_smoke/summary.json``), carries the package
+versions and the per-phase figures (seconds to first step, losses,
+what attention / the tier / the store resolved to): facts about what
+ran, not performance results.
 
 tests/test_chip_smoke.py drives the same phase functions at a tiny
 size with the worker on the CPU, so this command is debugged before
@@ -171,7 +176,7 @@ def probe_device():
         "except metadata.PackageNotFoundError:\n"
         "    libtpu = None\n"
         "print(json.dumps({'device': {'platform': d.platform,"
-        " 'kind': d.device_kind, 'count': jax.device_count()},"
+        " 'kind': d.device_kind, 'count': len(jax.devices())},"
         " 'versions': {'jax': jax.__version__,"
         " 'jaxlib': jaxlib.__version__, 'libtpu': libtpu}}))\n"
     )
@@ -185,6 +190,20 @@ def probe_device():
             % out.stderr[-2000:]
         )
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def result_line(ok, device):
+    """The last stdout line: exactly ``ok`` and ``device`` with exactly
+    ``platform``, ``kind`` and ``count``. Everything else the run
+    learned goes in the summary line before it."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    })
 
 
 def build_native_store():
@@ -581,9 +600,10 @@ def main():
         summary["ok"] = not failed
         with open(os.path.join(WORK_DIR, "summary.json"), "w") as f:
             json.dump(summary, f, indent=1, sort_keys=True)
+    print("chip_smoke: summary: %s" % json.dumps(summary, sort_keys=True))
+    print(result_line(not failed, device), flush=True)
     if failed:
         sys.exit(1)
-    print(json.dumps(summary, sort_keys=True))
 
 
 if __name__ == "__main__":

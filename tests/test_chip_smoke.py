@@ -172,6 +172,73 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert '"ok"' not in out.stdout
 
 
+def _run_smoke_main(monkeypatch, capsys, tmp_path, sparse_problems):
+    """main() with the chip and both phases faked: what it prints."""
+    import chip_smoke
+
+    probe = {
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+        "versions": {"jax": "0", "jaxlib": "0", "libtpu": "0"},
+    }
+    monkeypatch.setattr(chip_smoke, "WORK_DIR", str(tmp_path / "work"))
+    monkeypatch.setattr(chip_smoke, "probe_device", lambda: probe)
+    monkeypatch.setattr(chip_smoke, "build_native_store", lambda: 0.0)
+    monkeypatch.setattr(
+        chip_smoke, "run_dense_phase",
+        lambda *a: ([], {"steps": 32}, {}),
+    )
+    monkeypatch.setattr(
+        chip_smoke, "run_sparse_phase",
+        lambda *a: (sparse_problems, {"steps": 40}, {}),
+    )
+    try:
+        chip_smoke.main()
+        code = 0
+    except SystemExit as e:
+        code = e.code
+    lines = capsys.readouterr().out.strip().splitlines()
+    with open(tmp_path / "work" / "summary.json") as f:
+        summary = json.load(f)
+    return code, lines, summary
+
+
+def test_chip_smoke_last_line_is_exactly_the_contract(
+    monkeypatch, capsys, tmp_path
+):
+    """The last stdout line is ``{"ok", "device": {"platform", "kind",
+    "count"}}`` and nothing else; versions and per-phase facts ride
+    the summary line before it."""
+    code, lines, summary = _run_smoke_main(
+        monkeypatch, capsys, tmp_path, []
+    )
+    assert code == 0
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    assert lines[-2].startswith("chip_smoke: summary: ")
+    detail = json.loads(lines[-2].split("summary: ", 1)[1])
+    assert detail == summary and detail["ok"] is True
+    assert detail["phases"] == {
+        "dense": {"steps": 32}, "sparse": {"steps": 40},
+    }
+    assert "versions" in detail
+
+
+def test_chip_smoke_failed_phase_exits_nonzero_with_ok_false(
+    monkeypatch, capsys, tmp_path
+):
+    code, lines, summary = _run_smoke_main(
+        monkeypatch, capsys, tmp_path, ["tier reported no hits"]
+    )
+    assert code not in (0, None)
+    assert summary["ok"] is False
+    assert json.loads(lines[-1]) == {
+        "ok": False,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+
+
 TINY_TRANSFORMER = '''
 from elasticdl_tpu.models.transformer import *  # noqa: F401,F403
 from elasticdl_tpu.models.transformer import TransformerLM
