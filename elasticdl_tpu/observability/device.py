@@ -489,6 +489,13 @@ def compile_stats():
     return stats
 
 
+def compile_count():
+    """Compiles this process's wrapped functions have made so far: what
+    the phase ledger asks after every step, so that a step which
+    carried a compile is not judged slow."""
+    return _totals["compiles"]
+
+
 def telemetry():
     """The device section of a role's TelemetryBlob: cumulative
     process-lifetime compile/transfer totals + a fresh memory

@@ -190,6 +190,27 @@ EVENT_TYPES = frozenset({
                              #   [leaf: old -> new provenance],
                              #   signature) — the journal line the
                              #   recompile_storm postmortem reads
+    # the worker's phase ledger (ISSUE 23); durations in nanoseconds
+    # on perf_counter_ns, ``ts`` places the event in wall time
+    "loop_phases",           # every --log_loss_steps steps: the loop
+                             #   thread's time by phase (+ first_step,
+                             #   last_step, steps, wall_ns, phases{},
+                             #   slowest_step, slowest_wall_ns,
+                             #   invol_ctx_switches, major_faults)
+    "slow_step",             # a step far above the running median, at
+                             #   once (+ step, task, steps [the run
+                             #   it closes: 1 where every step
+                             #   fetches], wall_ns and phases{} of
+                             #   the run, median_ns a step,
+                             #   invol_ctx_switches, major_faults
+                             #   since the last loop_phases)
+    "worker_startup",        # after the first step returned: process
+                             #   start to there by phase (+ wall_ns,
+                             #   phases{imports, backend_init,
+                             #   master_connect, first_task,
+                             #   state_init, restore, first_step, ...})
+    "worker_teardown",       # from the last exit hook (+ wall_ns,
+                             #   phases{drain, teardown, exit, other})
 })
 
 

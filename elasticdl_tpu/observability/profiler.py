@@ -101,6 +101,14 @@ _SEGMENT_BY_SPAN = {
     # spans and explicit host<->device transfer spans
     "compile": "compile",
     "transfer": "transfer",
+    # the worker's phase ledger (ISSUE 23)
+    "edl/input_wait": "input_wait",
+    "edl/h2d": "transfer",
+    "edl/checkpoint": "bookkeeping",
+    "edl/report": "bookkeeping",
+    "edl/mesh_check": "bookkeeping",
+    "edl/log": "bookkeeping",
+    "edl/callbacks": "bookkeeping",
 }
 
 

@@ -549,13 +549,15 @@ def current_task_id():
 
 
 @contextlib.contextmanager
-def root_span(name, **args):
+def root_span(name, start=None, **args):
     """Open a trace: one per worker train step / serve predict request.
     Yields the new SpanContext (None when tracing is off or sampling is
     0 — the caller can branch on it, but needn't). If a context is
     ALREADY active (a propagated parent adopted by the server handler),
     the "root" degrades to a child span so the caller's trace stays
-    whole instead of forking a second trace_id."""
+    whole instead of forking a second trace_id. ``start``
+    (``time.time()``) back-dates the root: the worker's loop iteration
+    opens its trace only once it knows the iteration trains."""
     writer = _writer
     if writer is None:
         yield None
@@ -582,7 +584,8 @@ def root_span(name, **args):
     if published:
         _prof_spans[threading.get_ident()] = (ctx.trace_id, name)
     _push_open(args)
-    start = time.time()
+    if start is None:
+        start = time.time()
     error = None
     try:
         yield ctx
@@ -696,9 +699,10 @@ def span(name, **args):
               ctx=child, parent=ctx)
 
 
-def complete(name, start, **args):
+def complete(name, start, end=None, **args):
     """Emit a complete event for a block timed by the caller (``start``
-    from ``time.time()``); for sites where the span name/args are only
+    and, if the block ended earlier than now, ``end`` from
+    ``time.time()``); for sites where the span name/args are only
     known at the end — e.g. the dispatcher learns the task_id when the
     pop returns. Under an active context the event is a child of the
     current span."""
@@ -709,7 +713,8 @@ def complete(name, start, **args):
         return
     ctx = getattr(_tls, "ctx", None)
     child = ctx.child() if ctx is not None else None
-    _emit(writer, name, start, time.time(), args, ctx=child, parent=ctx)
+    _emit(writer, name, start, time.time() if end is None else end,
+          args, ctx=child, parent=ctx)
 
 
 def instant(name, **args):

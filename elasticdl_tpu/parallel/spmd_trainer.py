@@ -16,6 +16,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.common import timing_utils
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.observability import device as device_obs
 from elasticdl_tpu.parallel.dense_plane import plan_dense_plane
@@ -265,16 +266,25 @@ class SpmdTrainer:
 
     def ensure_state(self, state, batch):
         if state is None:
-            return self.create_state(batch["features"])
+            with timing_utils.current().phase("state_init"):
+                return self.create_state(batch["features"])
         return state
 
     def train_step(self, state, batch):
+        """One step, in the phases of the loop thread's ledger: the
+        batch's transfer (``h2d``) and the call of the jitted step
+        until it returns (``dispatch``). Nothing is fetched here, so
+        the loop runs ahead of the device and the runtime's
+        back-pressure is time inside ``dispatch``."""
+        phase = timing_utils.current().phase
         state = self.ensure_state(state, batch)
-        sharded = self.shard_batch(batch)
+        with phase("h2d"):
+            sharded = self.shard_batch(batch)
         if self._train_step is None:
             self._build_steps(batch)
             self._log_batch_split(sharded["features"])
-        return self._train_step(state, sharded)
+        with phase("dispatch"):
+            return self._train_step(state, sharded)
 
     @staticmethod
     def _log_batch_split(features):

@@ -130,27 +130,40 @@ class LocalExecutor:
 
     # ------------------------------------------------------------------
     def train(self):
-        from elasticdl_tpu.observability import trace
+        from elasticdl_tpu.common import timing_utils
 
+        ledger = self._timing
+        previous_ledger = timing_utils.bind(ledger)
+        try:
+            return self._train(ledger)
+        finally:
+            timing_utils.bind(previous_ledger)
+
+    def _train(self, ledger):
         losses = []
         step = 0
         for epoch in range(self._num_epochs):
-            for batch in self._batches(self._train_reader, "training"):
-                t0 = self._timing.start()
+            batches = iter(self._batches(self._train_reader, "training"))
+            while True:
                 # the local run traces like the distributed one
-                # (ISSUE 9): each step is a root span, and the
-                # in-process LocalPSClient's apply/pull spans (tagged
-                # role="ps") chain under it through the thread-local
-                # context — so merge_trace + critical_path report the
-                # same worker/PS attribution a real topology yields
-                with trace.root_span(
-                    "train_batch", role="worker", step=step
-                ):
+                # (ISSUE 9): each iteration is a ledger step and so a
+                # ``train_batch`` root span, and the in-process
+                # LocalPSClient's apply/pull spans (tagged role="ps")
+                # chain under it through the thread-local context — so
+                # merge_trace + critical_path report the same
+                # worker/PS attribution a real topology yields
+                with ledger.step(step + 1, step=step) as iteration:
+                    with ledger.phase("input_wait"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        iteration.cancel()
+                        break
+                    iteration.has_batch()
                     self.state, loss = self.trainer.train_step(
                         self.state, batch
                     )
-                losses.append(float(loss))
-                self._timing.end_record("batch_process", t0)
+                    with ledger.phase("device_wait"):
+                        losses.append(float(loss))
                 step += 1
             logger.info(
                 "Epoch %d done; last-batch loss %.4f", epoch, losses[-1]

@@ -738,9 +738,10 @@ class SparseTrainer:
         # memo of the last prepared batch, so ensure_state followed by
         # eval_step/train_step on the same batch pulls rows once
         self._prep_memo = None
-        # per-phase wall-clock (EDL_TIMING=1): sparse_pull/sparse_push
-        # are this design's analogues of the reference's get_model /
-        # report_gradient phases (common/timing_utils.py, worker.py:298)
+        # per-phase wall-clock, in a ledger of the trainer's own:
+        # sparse_pull/sparse_push are this design's analogues of the
+        # reference's get_model / report_gradient phases
+        # (common/timing_utils.py, worker.py:298)
         from elasticdl_tpu.common.timing_utils import Timing
 
         self.timing = Timing()

@@ -106,28 +106,40 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         if compute_dtype is not None:
             compute_params = cast_floating(params, compute_dtype)
             compute_features = cast_floating(features, compute_dtype)
-        outputs, new_model_state = _apply_model(
-            model,
-            compute_params,
-            model_state,
-            compute_features,
-            training=True,
-            rngs=rngs,
-        )
-        per_sample = loss_fn(labels, outputs).astype(jnp.float32)
-        # same row-collapse masked_mean applies (multi-dim per-sample
-        # losses average over their trailing dims first)
-        per_sample = per_sample.reshape(mask.shape[0], -1).mean(axis=1)
-        return jnp.sum(per_sample * mask), (jnp.sum(mask), new_model_state)
+        # names only (ISSUE 23): the scopes go into every operation's
+        # ``op_name``, where a device trace reads forward, loss and
+        # optimizer apart; JAX itself writes ``transpose(jvp(forward))``
+        # on the backward. The program computes the same values
+        with jax.named_scope("forward"):
+            outputs, new_model_state = _apply_model(
+                model,
+                compute_params,
+                model_state,
+                compute_features,
+                training=True,
+                rngs=rngs,
+            )
+        with jax.named_scope("loss"):
+            per_sample = loss_fn(labels, outputs).astype(jnp.float32)
+            # same row-collapse masked_mean applies (multi-dim
+            # per-sample losses average over their trailing dims first)
+            per_sample = per_sample.reshape(
+                mask.shape[0], -1
+            ).mean(axis=1)
+            return jnp.sum(per_sample * mask), (
+                jnp.sum(mask), new_model_state
+            )
 
     def _apply_update(state, grads, loss, new_model_state):
-        grads = cast_floating(grads, jnp.float32)
-        updates, new_opt_state = tx.update(
-            grads, state.opt_state, state.params
-        )
-        new_params = jax.tree_util.tree_map(
-            lambda p, u: (p + u).astype(p.dtype), state.params, updates
-        )
+        with jax.named_scope("optimizer"):
+            grads = cast_floating(grads, jnp.float32)
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = jax.tree_util.tree_map(
+                lambda p, u: (p + u).astype(p.dtype),
+                state.params, updates,
+            )
         return (
             TrainState(
                 step=state.step + 1,

@@ -5,22 +5,64 @@ Usage: python -m elasticdl_tpu.worker.main --master_addr=... --worker_id=0 \
     --model_zoo=... --training_data=...
 """
 
-import os
-import sys
+import atexit
+import time
 
-from elasticdl_tpu.common.args import (
+# the first statement this module runs: where ``imports`` starts if the
+# operating system cannot say when the process did
+_MODULE_START_NS = time.perf_counter_ns()
+
+# (ledger, when main returned) of a worker that ran to its end. The
+# hook below is registered before anything else is imported, so it is
+# the LAST exit hook to run: after the interpreter joined the threads
+# and jax, grpc and orbax tore down what they hold. Those seconds are
+# the ``exit`` phase of ``worker_teardown``.
+_exiting = []
+
+
+def _close_teardown():
+    for ledger, returned_ns in _exiting:
+        ledger.end_record("exit", returned_ns)
+        ledger.end_teardown()
+
+
+atexit.register(_close_teardown)
+
+import os  # noqa: E402
+import sys  # noqa: E402
+
+from elasticdl_tpu.common import timing_utils  # noqa: E402
+from elasticdl_tpu.common.args import (  # noqa: E402
     parse_params_string,
     parse_worker_args,
     symbol_overrides_from_args,
 )
-from elasticdl_tpu.common.env_utils import env_str
-from elasticdl_tpu.common.log_utils import configure as configure_logging
-from elasticdl_tpu.data.readers import create_data_reader
-from elasticdl_tpu.worker.master_client import MasterClient
-from elasticdl_tpu.worker.worker import Worker
+from elasticdl_tpu.common.env_utils import env_str  # noqa: E402
+from elasticdl_tpu.common.log_utils import (  # noqa: E402
+    configure as configure_logging,
+)
+from elasticdl_tpu.data.readers import create_data_reader  # noqa: E402
+from elasticdl_tpu.worker.master_client import MasterClient  # noqa: E402
+from elasticdl_tpu.worker.worker import Worker  # noqa: E402
+
+
+def _start_ledger(main_start_ns, interval):
+    """The loop thread's ledger, its start-up record opened at the
+    process's start and holding ``imports``: from there to the first
+    statement of ``main``."""
+    ledger = timing_utils.Timing(interval=interval)
+    age_ns = timing_utils.process_age_ns()
+    now_ns = time.perf_counter_ns()
+    start_ns = _MODULE_START_NS if age_ns is None else now_ns - age_ns
+    # the operating system counts in 10 ms ticks: never after main began
+    start_ns = min(start_ns, main_start_ns)
+    ledger.begin_startup(start_ns)
+    ledger.end_record("imports", start_ns, end=main_start_ns)
+    return ledger
 
 
 def main(argv=None):
+    main_start_ns = time.perf_counter_ns()
     if env_str("EDL_FAULTHANDLER", ""):
         # stack dumps on demand (kill -USR1 <pid>): lockstep multi-host
         # hangs are otherwise invisible
@@ -52,6 +94,8 @@ def main(argv=None):
         os.environ[http_server.PORT_ENV] = str(args.metrics_port)
     trace.configure("worker-%d" % args.worker_id)
     events.configure("worker-%d" % args.worker_id)
+    # after the metrics knob and the journal: the ledger asks both
+    ledger = _start_ledger(main_start_ns, args.log_loss_steps)
     # continuous profiler (ISSUE 14): always-on when EDL_PROF_HZ is
     # set, served as /profilez on the observability port below
     profiler.maybe_start("worker-%d" % args.worker_id)
@@ -71,11 +115,12 @@ def main(argv=None):
 
     drain_hook = install_sigterm_drain()
     events.install_crash_hooks()
-    master_client = MasterClient(
-        args.master_addr,
-        worker_id=args.worker_id,
-        worker_host=args.worker_host or None,
-    )
+    with ledger.phase("master_connect"):
+        master_client = MasterClient(
+            args.master_addr,
+            worker_id=args.worker_id,
+            worker_host=args.worker_host or None,
+        )
     observability = http_server.maybe_start(
         "worker-%d" % args.worker_id, cli_port=args.metrics_port
     )
@@ -89,24 +134,27 @@ def main(argv=None):
     # with this worker_id still holds (it can't have requeued them).
     # The response carries this worker_id's master-assigned relaunch
     # epoch — the push incarnation the sync PS orders relaunches by.
-    master_client.reset_worker()
+    with ledger.phase("master_connect"):
+        master_client.reset_worker()
     events.emit(
         "role_start", worker=args.worker_id,
         epoch=master_client.incarnation or 0,
     )
     multihost_runtime = None
-    if args.multihost:
-        # must run BEFORE any jax backend initialization
-        from elasticdl_tpu.parallel.multihost import MultiHostRuntime
+    with ledger.phase("backend_init"):
+        if args.multihost:
+            # must run BEFORE any jax backend initialization
+            from elasticdl_tpu.parallel.multihost import MultiHostRuntime
 
-        multihost_runtime = MultiHostRuntime(
-            master_client, coordinator_port=args.coordinator_port
-        )
-        multihost_runtime.ensure_runtime()
-    # which device this worker trains on — the first question of any
-    # chip run (chip_smoke.py reads this line); after the multihost
-    # runtime, which must initialize before the backend does
-    logger.info("devices: %s", platform.describe_devices())
+            multihost_runtime = MultiHostRuntime(
+                master_client, coordinator_port=args.coordinator_port
+            )
+            multihost_runtime.ensure_runtime()
+        # which device this worker trains on — the first question of
+        # any chip run (chip_smoke.py reads this line); after the
+        # multihost runtime, which must initialize before the backend
+        # does. Asking for the devices is what starts the backend
+        logger.info("devices: %s", platform.describe_devices())
     # an elastic restart must resume from the freshest state: default
     # the init dir to the worker's own checkpoint dir, so the relaunch
     # (same command line) picks up everything checkpointed so far
@@ -178,6 +226,7 @@ def main(argv=None):
         # the elastic fallback dir is empty on first launch; only an
         # explicit operator resume request is strict
         resume_optional=not args.checkpoint_dir_for_init,
+        ledger=ledger,
     )
     # SIGTERM now triggers the graceful drain instead of a bare exit
     drain_hook.bind(worker)
@@ -194,7 +243,8 @@ def main(argv=None):
             # process that just exits makes peers' shutdown fail and
             # their runtime abort them even though the job completed
             try:
-                multihost_runtime.shutdown()
+                with ledger.phase("teardown"):
+                    multihost_runtime.shutdown()
             except Exception:
                 logger.warning(
                     "distributed shutdown barrier failed (peers gone?)"
@@ -235,6 +285,8 @@ def main(argv=None):
         os._exit(EPOCH_RESTART_EXIT_CODE)
     events.emit("role_stop", worker=args.worker_id)
     events.flush()
+    # ``worker_teardown`` leaves with the last exit hook
+    _exiting.append((ledger, ledger.start()))
     return 0
 
 

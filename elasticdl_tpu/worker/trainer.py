@@ -11,6 +11,7 @@ parallel/spmd_trainer.py — both wrap the same step functions
 import jax
 import numpy as np
 
+from elasticdl_tpu.common import timing_utils
 from elasticdl_tpu.observability import device as device_obs
 from elasticdl_tpu.train.step_fns import make_eval_step, make_train_step
 from elasticdl_tpu.train.train_state import (
@@ -84,25 +85,37 @@ class JaxTrainer:
 
     def ensure_state(self, state, batch):
         if state is None:
-            return self.create_state(batch["features"])
+            with timing_utils.current().phase("state_init"):
+                return self.create_state(batch["features"])
         return state
 
     def train_step(self, state, batch):
+        """One step, in the phases of the loop thread's ledger: the
+        call of the jitted step until it returns (``dispatch``; the
+        batch's transfer to the device is implicit in it), the fetch of
+        the health scalars, which waits for the device to finish the
+        step (``device_wait``), and the sentinels (``health``)."""
+        phase = timing_utils.current().phase
         state = self.ensure_state(state, batch)
         from elasticdl_tpu.testing import faults
 
         batch = faults.maybe_poison_batch(batch)
         if not self._health_on:
-            return self._train_step(state, batch)
-        state, loss, scalars = self._train_step(state, batch)
+            with phase("dispatch"):
+                return self._train_step(state, batch)
+        with phase("dispatch"):
+            state, loss, scalars = self._train_step(state, batch)
         # one small host transfer per batch; a skip-sentinel batch
         # already kept its state in-graph (nothing else to drop on
         # the dense path — there is no PS push); halt raises
-        self.health.observe(
-            float(loss),
-            float(scalars["grad_norm"]),
-            bool(scalars["nonfinite"]),
-        )
+        with phase("device_wait"):
+            observed = (
+                float(loss),
+                float(scalars["grad_norm"]),
+                bool(scalars["nonfinite"]),
+            )
+        with phase("health"):
+            self.health.observe(*observed)
         return state, loss
 
     @property

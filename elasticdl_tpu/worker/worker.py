@@ -17,7 +17,7 @@ import time
 
 import numpy as np
 
-from elasticdl_tpu.common import overload
+from elasticdl_tpu.common import overload, timing_utils
 from elasticdl_tpu.common.constants import Mode
 from elasticdl_tpu.common.env_utils import env_float, env_str
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
@@ -137,6 +137,7 @@ class Worker:
         model_params="",
         symbol_overrides=None,
         log_loss_steps=100,
+        ledger=None,
     ):
         self._mc = master_client
         self.spec = get_model_spec(
@@ -318,23 +319,25 @@ class Worker:
         ):
             self._callbacks.append(SavedModelExporter())
         self._multihost = multihost_runtime
-        # opt-in per-phase wall-clock accounting (EDL_TIMING=1),
-        # reference worker.py:298-812 / common/timing_utils.py
-        from elasticdl_tpu.common.timing_utils import Timing
-
-        self._timing = Timing()
-        # domain gauges fed off the Timing clock (no second timer):
-        # examples/sec from the step phase + real batch count; MFU when
-        # the trainer knows its per-step FLOPs and the operator told us
-        # the hardware peak. No-op instruments when metrics are off.
+        # the phase ledger of this worker's loop thread (reference
+        # worker.py:298-812 / common/timing_utils.py): worker.main
+        # hands over the one that already holds its start-up phases
+        self._timing = ledger or timing_utils.Timing(
+            interval=log_loss_steps
+        )
+        # domain gauges fed off the ledger's clock (no second timer):
+        # examples/sec from the last iteration + real batch count;
+        # hardware utilisation when the trainer knows its per-step
+        # FLOPs and the operator told us the hardware peak. No-op
+        # instruments when metrics are off.
         self._m_examples_per_sec = obs_metrics.gauge(
             "edl_worker_examples_per_second",
             "Real (unpadded) examples trained per second, last step",
         )
         self._m_mfu = obs_metrics.gauge(
             "edl_worker_mfu_ratio",
-            "Model FLOPs utilization: trainer step_flops / "
-            "(step_time * EDL_PEAK_FLOPS_PER_SEC)",
+            "Hardware FLOPs utilisation (recompute counted): executed "
+            "step FLOPs / (iteration time * EDL_PEAK_FLOPS_PER_SEC)",
         )
         self._m_version = obs_metrics.gauge(
             "edl_worker_model_version", "This worker's model version"
@@ -383,7 +386,6 @@ class Worker:
         self._step_ewma = 0.0
         self._dense_share_ewma = 0.0
         self._last_examples_per_sec = 0.0
-        self._prev_batch_end = 0.0
         self._telemetry_samples = 0
         self._ewma_outlier_streak = 0
         if self._telemetry_on and hasattr(
@@ -499,26 +501,24 @@ class Worker:
         return blob
 
     def _update_step_telemetry(self, real_count):
-        """Fold one finished batch into the telemetry EWMAs. Prefers
-        the Timing bridge's exact step duration (present when metrics
-        collection is on); falls back to the inter-batch wall delta —
-        every worker measures the same way, which is all the
-        straggler's fleet-relative comparison needs.
+        """Fold one finished batch into the telemetry EWMAs. The step
+        time is the ledger's last whole loop iteration (in steady state
+        the device paces the loop, so that is the step time) — every
+        worker measures the same way, which is all the straggler's
+        fleet-relative comparison needs.
 
         Outlier discipline: the first measured batch carries the jit
-        compile (20-40 s on TPU) and fallback deltas can swallow idle
-        task-boundary gaps; seeding/folding those would trip the fleet
+        compile (20-40 s on TPU) and an iteration can swallow an idle
+        task-boundary gap; seeding/folding those would trip the fleet
         straggler detector against a healthy worker. The first sample
         is skipped outright; later samples >10x the EWMA are skipped
         unless three arrive consecutively — a worker that is GENUINELY
         10x degraded re-anchors after three steps, a one-off spike
         never lands."""
-        now = time.time()
-        step_secs = self._timing.last_seconds.get("batch_process")
-        if step_secs is None and self._prev_batch_end > 0.0:
-            step_secs = now - self._prev_batch_end
-        self._prev_batch_end = now
-        if step_secs is None or step_secs <= 0:
+        step_secs = self._timing.last_seconds.get(
+            timing_utils.STEP_PHASE
+        )
+        if not step_secs:
             return
         self._telemetry_samples += 1
         if self._telemetry_samples == 1:
@@ -541,7 +541,7 @@ class Worker:
         self._ewma_outlier_streak = 0
         # dense-step share (ISSUE 20): fraction of the batch spent in
         # the jitted device step. Sparse trainers time their device
-        # portion in their own Timing bridge ("batch_process" there
+        # portion in their own ledger ("batch_process" there
         # excludes PS pull/push); a trainer without one (JaxTrainer,
         # SpmdTrainer) IS the device step end-to-end, share 1.0.
         trainer_timing = getattr(self.trainer, "timing", None)
@@ -647,6 +647,11 @@ class Worker:
         individually guarded: a dead PS must not stop the deregister,
         and a dead master must not stop the exit (old masters without
         the RPC just miss the ack; their liveness fallback requeues)."""
+        self._timing.begin_teardown()
+        with self._timing.phase("drain"):
+            self._drain()
+
+    def _drain(self):
         self._draining = True
         self.tds.draining = True
         reason = self._drain_reason or "master_drain"
@@ -775,46 +780,26 @@ class Worker:
         )
         return True
 
-    def _traced_train_step(self, batch):
-        """One train step, timed (Timing bridge feeds the step-time
-        gauge) and — when EDL_TRACE_DIR is set — the ROOT SPAN of a
-        distributed trace (ISSUE 9): the PS client's pull/push spans
-        become its children, the propagated context crosses the gRPC
-        hop, and the PS-side apply lands in the same trace. The
-        task_id context rides along as the coarse correlation key."""
-        t0 = self._timing.start()
-        if not trace.enabled():
-            self.state, loss = self.trainer.train_step(self.state, batch)
-            self._timing.end_record_sync("batch_process", t0, loss)
-            return loss
-        with trace.task_context(self.tds.current_task_id()):
-            with trace.root_span(
-                "train_batch", role="worker", version=self._version
-            ):
-                self.state, loss = self.trainer.train_step(
-                    self.state, batch
-                )
-                # sync inside the span: async dispatch would otherwise
-                # record device-bound steps as near-zero slices
-                self._timing.end_record_sync("batch_process", t0, loss)
-        return loss
-
     def _after_train_batch(self, batch, loss):
         """Per-batch bookkeeping shared by every loop shape: version,
-        checkpoint, record accounting, liveness, callbacks."""
+        checkpoint, record accounting, liveness, callbacks. Each part
+        is a phase of the ledger; the gauges between them read the
+        last whole iteration and fall to ``other``."""
+        phase = self._timing.phase
         self._version += 1
-        if (
-            self._checkpoint_mgr is not None
-            and self._version % self._checkpoint_steps == 0
-        ):
-            self._save_checkpoint()
-        self.maybe_stream_checkpoint()
+        with phase("checkpoint"):
+            if (
+                self._checkpoint_mgr is not None
+                and self._version % self._checkpoint_steps == 0
+            ):
+                self._save_checkpoint()
+            self.maybe_stream_checkpoint()
         real = batch_real_count(batch)
         if self._telemetry_on:
             self._update_step_telemetry(real)
-        with self._timing.timeit("report_record"):
-            self.tds.report_record_done(real)
-        step_secs = self._timing.last_seconds.get("batch_process")
+        step_secs = self._timing.last_seconds.get(
+            timing_utils.STEP_PHASE
+        )
         if step_secs:
             self._m_examples_per_sec.set(real / step_secs)
             if self._peak_flops:
@@ -830,23 +815,32 @@ class Worker:
                         flops / (step_secs * self._peak_flops)
                     )
         self._m_version.set(self._version)
-        if (
-            self._report_version_steps
-            and self._version % self._report_version_steps == 0
-        ):
-            self._mc.report_version(self._version)
-        self._check_mesh_epoch()
+        with phase("report"):
+            self.tds.report_record_done(real)
+            if (
+                self._report_version_steps
+                and self._version % self._report_version_steps == 0
+            ):
+                self._mc.report_version(self._version)
+        with phase("mesh_check"):
+            self._check_mesh_epoch()
         if (
             self._log_loss_steps
             and self._version % self._log_loss_steps == 0
         ):
-            # reference --log_loss_steps; the float() fetch only syncs
-            # on these steps
-            logger.info(
-                "step %d loss %.6f", self._version, float(loss)
-            )
-        for cb in self._callbacks:
-            cb.on_batch_end(self._version, loss)
+            # reference --log_loss_steps. Where the trainer fetched
+            # nothing (SpmdTrainer) this is the loop's first read of a
+            # device value and waits for the step; after JaxTrainer's
+            # health fetch the value is already on the host
+            with phase("device_wait"):
+                loss_value = float(loss)
+            with phase("log"):
+                logger.info(
+                    "step %d loss %.6f", self._version, loss_value
+                )
+        with phase("callbacks"):
+            for cb in self._callbacks:
+                cb.on_batch_end(self._version, loss)
 
     def _train_batches_pipelined(self, batches):
         """Drive the sparse trainer's pipelined stream: batch N+1's PS
@@ -870,18 +864,44 @@ class Worker:
         # deterministic close: the stream's finally drains the in-flight
         # background push even when we break or an exception unwinds
         with contextlib.closing(stream):
+            # the trainer's own ledger splits its pipelined step; this
+            # one only keeps the iteration for the rates and gauges
+            start = self._timing.start()
             for state, loss, batch in stream:
                 self.state = state
                 self._after_train_batch(batch, loss)
+                self._timing.end_record(timing_utils.STEP_PHASE, start)
+                start = self._timing.start()
                 if self.stop_training:
                     break
 
-    def _train_batches_sequential(self, batches):
-        for batch in batches:
-            if not self._restore_attempted:
+    def _train_step(self, step, batch):
+        """The part of a loop iteration from the batch to the
+        bookkeeping, inside the iteration's ledger step (and so, when
+        EDL_TRACE_DIR is set, inside the ``train_batch`` root span of a
+        distributed trace, ISSUE 9: the PS client's pull/push spans
+        become its children, the propagated context crosses the gRPC
+        hop, and the PS-side apply lands in the same trace)."""
+        step.has_batch(self.tds.current_task_id())
+        if not self._restore_attempted:
+            with self._timing.phase("restore"):
                 self._restore_from_checkpoint(batch)
-            loss = self._traced_train_step(batch)
-            self._after_train_batch(batch, loss)
+        self.state, loss = self.trainer.train_step(self.state, batch)
+        return loss
+
+    def _train_batches_sequential(self, batches):
+        batches = iter(batches)
+        while True:
+            with self._timing.step(
+                self._version + 1, version=self._version
+            ) as step:
+                with self._timing.phase("input_wait"):
+                    batch = next(batches, None)
+                if batch is None:
+                    step.cancel()
+                    break
+                loss = self._train_step(step, batch)
+                self._after_train_batch(batch, loss)
             if self.stop_training:
                 break
 
@@ -933,87 +953,101 @@ class Worker:
         window = max(1, self._consensus_interval)
         round_in_window = 0
         while True:
-            boundary = round_in_window == 0
-            if self.stop_training and not stopping:
-                # MaxSteps (or any host-side stop) under lockstep must
-                # NOT break out process-locally: a relaunched peer whose
-                # restored step counter lags would keep issuing
-                # collectives against departed workers (deadlock).
-                # Instead convert the stop into a stream-end VOTE: hand
-                # fetched-but-untrained tasks back (the post-loop
-                # _drain_fast completes them without training), feed
-                # zero batches, and leave at the synchronized all-ended
-                # boundary like any other stream end.
-                stopping = True
-                exhausted = True
-                self.tds.report_pending_failed(
-                    "requeue: stopped at max steps"
-                )
-            if exhausted and not stopping and self.tds.out_of_band_tasks:
-                # my stream ended because eval/predict tasks were
-                # parked: drain them INLINE, between consensus rounds,
-                # and reopen the stream — all local work, so the
-                # collective cadence is preserved (peers' next
-                # consensus simply blocks a few seconds). Leaving the
-                # loop instead would be unsound: a peer mid-round runs
-                # its STEP collective while we issue a CONSENSUS on
-                # re-entry — mismatched collectives, observed deadlock.
-                self._drain_out_of_band()
-                if self.tds.train_end_task is None:
-                    poller = _BatchPoller(
-                        self._batches(
-                            self.tds.training_record_stream(),
-                            Mode.TRAINING,
-                        )
+            with self._timing.step(
+                self._version + 1, version=self._version
+            ) as step:
+                boundary = round_in_window == 0
+                if self.stop_training and not stopping:
+                    # MaxSteps (or any host-side stop) under lockstep must
+                    # NOT break out process-locally: a relaunched peer whose
+                    # restored step counter lags would keep issuing
+                    # collectives against departed workers (deadlock).
+                    # Instead convert the stop into a stream-end VOTE: hand
+                    # fetched-but-untrained tasks back (the post-loop
+                    # _drain_fast completes them without training), feed
+                    # zero batches, and leave at the synchronized all-ended
+                    # boundary like any other stream end.
+                    stopping = True
+                    exhausted = True
+                    self.tds.report_pending_failed(
+                        "requeue: stopped at max steps"
                     )
-                    exhausted = False
-                # (with a parked train-end task the job is over bar the
-                # export: keep voting ended; the outer loop handles it)
-            batch = None
-            if not exhausted:
-                # mid-window polls wait just like boundary ones: peers'
-                # dispatched steps simply queue behind ours, and a real
-                # batch a moment late beats burning a zero-batch step
-                # on it (measured: a 0.02s mid-window poll turned every
-                # transient prefetch gap into wasted full steps and
-                # REGRESSED the scaling bench 253 -> 188 ex/s)
-                batch, exhausted = poller.poll(self._lockstep_poll_secs)
-            have = batch is not None
-            if have:
-                batch = pad_batch(batch, self._minibatch_size)
-                template = batch
-            if boundary:
-                alive, ended = self.trainer.consensus(have, exhausted)
-                if ended == self.trainer.process_count:
-                    # every process's stream is permanently over: the
-                    # ONLY loop exit, taken by everyone here together
-                    break
-                if alive == 0:
-                    # transient: everyone is between tasks (epoch
-                    # boundary, master mid-eval); keep polling — the
-                    # poll timeout paces the consensus rounds (an
-                    # exhausted worker has no poll to pace it, so
-                    # sleep explicitly). ``have`` is False for every
-                    # process here, so no polled batch is dropped.
-                    if exhausted:
-                        time.sleep(self._lockstep_poll_secs)
+                if exhausted and not stopping and self.tds.out_of_band_tasks:
+                    # my stream ended because eval/predict tasks were
+                    # parked: drain them INLINE, between consensus rounds,
+                    # and reopen the stream — all local work, so the
+                    # collective cadence is preserved (peers' next
+                    # consensus simply blocks a few seconds). Leaving the
+                    # loop instead would be unsound: a peer mid-round runs
+                    # its STEP collective while we issue a CONSENSUS on
+                    # re-entry — mismatched collectives, observed deadlock.
+                    self._drain_out_of_band()
+                    if self.tds.train_end_task is None:
+                        poller = _BatchPoller(
+                            self._batches(
+                                self.tds.training_record_stream(),
+                                Mode.TRAINING,
+                            )
+                        )
+                        exhausted = False
+                    # (with a parked train-end task the job is over bar the
+                    # export: keep voting ended; the outer loop handles it)
+                batch = None
+                if not exhausted:
+                    # mid-window polls wait just like boundary ones: peers'
+                    # dispatched steps simply queue behind ours, and a real
+                    # batch a moment late beats burning a zero-batch step
+                    # on it (measured: a 0.02s mid-window poll turned every
+                    # transient prefetch gap into wasted full steps and
+                    # REGRESSED the scaling bench 253 -> 188 ex/s)
+                    with self._timing.phase("input_wait"):
+                        batch, exhausted = poller.poll(
+                            self._lockstep_poll_secs
+                        )
+                have = batch is not None
+                if have:
+                    batch = pad_batch(batch, self._minibatch_size)
+                    template = batch
+                if boundary:
+                    # a collective and a fetch of its result: the loop
+                    # waits for every earlier step on the device here
+                    with self._timing.phase("device_wait"):
+                        alive, ended = self.trainer.consensus(
+                            have, exhausted
+                        )
+                    if ended == self.trainer.process_count:
+                        # every process's stream is permanently over: the
+                        # ONLY loop exit, taken by everyone here together
+                        step.cancel()
+                        break
+                    if alive == 0:
+                        # transient: everyone is between tasks (epoch
+                        # boundary, master mid-eval); keep polling — the
+                        # poll timeout paces the consensus rounds (an
+                        # exhausted worker has no poll to pace it, so
+                        # sleep explicitly). ``have`` is False for every
+                        # process here, so no polled batch is dropped.
+                        if exhausted:
+                            time.sleep(self._lockstep_poll_secs)
+                        step.cancel()
+                        continue
+                if not have:
+                    if template is None:
+                        # in a live round without ever having seen a batch
+                        # (joined mid-epoch while peers hold every task):
+                        # fabricate the shapes from the reader
+                        with self._timing.phase("state_init"):
+                            template = self._fabricate_template_batch()
+                    batch = zero_batch_like(template)
+                round_in_window = (round_in_window + 1) % window
+                loss = self._train_step(step, batch)
+                if stopping:
+                    # zero-batch participation rounds while peers finish:
+                    # no version/checkpoint/record bookkeeping, and no
+                    # step in the ledger
+                    step.cancel()
                     continue
-            if not have:
-                if template is None:
-                    # in a live round without ever having seen a batch
-                    # (joined mid-epoch while peers hold every task):
-                    # fabricate the shapes from the reader
-                    template = self._fabricate_template_batch()
-                batch = zero_batch_like(template)
-            round_in_window = (round_in_window + 1) % window
-            if not self._restore_attempted:
-                self._restore_from_checkpoint(batch)
-            loss = self._traced_train_step(batch)
-            if stopping:
-                # zero-batch participation rounds while peers finish:
-                # no version/checkpoint/record bookkeeping
-                continue
-            self._after_train_batch(batch, loss)
+                self._after_train_batch(batch, loss)
 
     def _read_template_batch(self):
         """One correctly-shaped batch read straight from the reader's
@@ -1364,21 +1398,27 @@ class Worker:
 
     # ------------------------------------------------------------------
     def run(self):
+        # the trainers reach this loop's ledger through the thread
+        previous_ledger = timing_utils.bind(self._timing)
         self._start_heartbeat()
         try:
             self._run()
         finally:
-            self._stop_heartbeat()
-            # release the sparse trainer's async-push executor (joins
-            # its in-flight push; failures were already surfaced at the
-            # stream boundary, so close only logs)
-            close = getattr(self.trainer, "close", None)
-            if close is not None:
-                close()
-            if self._checkpoint_mgr is not None:
-                # Flush any in-flight orbax commit before process exit.
-                self._checkpoint_mgr.close()
-                self._checkpoint_mgr = None
+            self._timing.begin_teardown()
+            with self._timing.phase("teardown"):
+                self._stop_heartbeat()
+                # release the sparse trainer's async-push executor
+                # (joins its in-flight push; failures were already
+                # surfaced at the stream boundary, so close only logs)
+                close = getattr(self.trainer, "close", None)
+                if close is not None:
+                    close()
+                if self._checkpoint_mgr is not None:
+                    # Flush any in-flight orbax commit before process
+                    # exit.
+                    self._checkpoint_mgr.close()
+                    self._checkpoint_mgr = None
+            timing_utils.bind(previous_ledger)
 
     def _run(self):
         if self._mode == Mode.EVALUATION:

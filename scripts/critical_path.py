@@ -23,14 +23,25 @@ apply               ``ps_apply_push`` and ``Pserver/push_*`` handler
                     spans (server-side deserialize + optimizer apply)
 compute             the ``train_batch`` root's self time (forward /
                     backward / device step) and ``serve_batch_run``
-                    (the batched forward)
+                    (the batched forward); the worker ledger's
+                    ``edl/dispatch``, ``edl/device_wait``,
+                    ``edl/health``, ``edl/state_init`` and
+                    ``edl/restore`` children are left unmapped and
+                    inherit it, so ``compute`` is what it was before
+                    the root had phase children
+input_wait          ``edl/input_wait``: the loop waiting for its batch
+bookkeeping         ``edl/checkpoint``, ``edl/report``,
+                    ``edl/mesh_check``, ``edl/log``, ``edl/callbacks``:
+                    the loop's work after the step (the root covers
+                    the whole iteration since ISSUE 23; before, this
+                    time lay outside every trace)
 compile             ``compile`` spans from the ISSUE-18 recompile
                     sentinel — XLA compiles caught on the step path;
                     a steady-state trace showing this segment IS the
                     recompile storm, attributed to the step it stalled
 transfer            ``transfer`` spans (ISSUE 18): explicit host<->
                     device movement — output fetches, device-tier
-                    gradient extraction
+                    gradient extraction — and the ledger's ``edl/h2d``
 shed                the full duration of a predict trace whose root
                     failed with RESOURCE_EXHAUSTED / DEADLINE_EXCEEDED
 other               anything unrecognized (kept visible, never dropped)
@@ -91,6 +102,16 @@ _SEGMENT_BY_NAME = {
     # spans and explicit host<->device transfer spans
     "compile": "compile",
     "transfer": "transfer",
+    # the worker's phase ledger (ISSUE 23): children of train_batch
+    # that are not the step itself; the step's own phases stay
+    # unmapped and inherit the root's compute
+    "edl/input_wait": "input_wait",
+    "edl/h2d": "transfer",
+    "edl/checkpoint": "bookkeeping",
+    "edl/report": "bookkeeping",
+    "edl/mesh_check": "bookkeeping",
+    "edl/log": "bookkeeping",
+    "edl/callbacks": "bookkeeping",
 }
 
 # root-span name -> segment its SELF time belongs to

@@ -113,6 +113,13 @@ def percentile(values, q):
     return ordered[index]
 
 
+# the worker ledger's phases (ISSUE 23) are children of the step's
+# root on the root's own thread: the timeline already draws them nested
+# in it, and an arrow through each would bury the arrows between
+# processes that the flows are for
+LEDGER_PHASE_PREFIX = "edl/"
+
+
 def context_flow_events(events):
     """Flow (s/t/f) events threading every span of one TRACE (same
     propagated ``trace_id``) across processes, in timestamp order —
@@ -120,6 +127,8 @@ def context_flow_events(events):
     by_trace = {}
     for event in events:
         if event.get("ph") != "X":
+            continue
+        if event.get("name", "").startswith(LEDGER_PHASE_PREFIX):
             continue
         trace_id = (event.get("args") or {}).get("trace_id")
         if not trace_id:

@@ -1060,20 +1060,22 @@ def test_dispatcher_stats_track_lifecycle():
 
 def test_timing_bridge_feeds_phase_metrics(monkeypatch):
     monkeypatch.setenv("EDL_METRICS", "1")
-    monkeypatch.delenv("EDL_TIMING", raising=False)
     obs_metrics.reset_default_registry()
     try:
         from elasticdl_tpu.common.timing_utils import Timing
 
-        timing = Timing()
-        assert not timing.enabled  # EDL_TIMING logging stays off
-        t0 = timing.start()
-        timing.end_record("batch_process", t0)
-        assert timing.last_seconds["batch_process"] >= 0
+        ledger = Timing()
+        with ledger.step(1):
+            with ledger.phase("dispatch"):
+                pass
+        # the step series is the whole loop iteration
+        assert ledger.last_seconds["batch_process"] >= (
+            ledger.last_seconds["dispatch"])
         text = obs_metrics.default_registry().render()
         assert (
             'edl_phase_seconds_count{phase="batch_process"} 1' in text
         )
+        assert 'edl_phase_seconds_count{phase="dispatch"} 1' in text
         assert "edl_step_time_seconds" in text
     finally:
         obs_metrics.reset_default_registry()

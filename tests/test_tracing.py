@@ -681,6 +681,65 @@ def test_critical_path_attribution_math(tmp_path):
     assert shares == pytest.approx(1.0, abs=1e-3)
 
 
+def test_critical_path_keeps_its_answers_with_phase_children(tmp_path):
+    """ISSUE 23: the root now covers the whole loop iteration and has
+    the ledger's phases as children. The same step as above, with 1 ms
+    of input wait before it and 2 ms of bookkeeping after: compute,
+    pull, push and apply read what they read without the phases, and
+    the iteration's new time has segments of its own."""
+    _scripts()
+    import critical_path
+    import merge_trace
+
+    tid = "bd" * 16
+    root = "01" * 8
+    _write_trace_file(tmp_path, "worker-0", 1, [
+        _span_event("train_batch", 0, 13000, 1, tid, root,
+                    role="worker"),
+        _span_event("edl/input_wait", 0, 1000, 1, tid, "05" * 8,
+                    parent_id=root),
+        # the step's own phases: unmapped, they inherit compute
+        _span_event("edl/dispatch", 1000, 1000, 1, tid, "06" * 8,
+                    parent_id=root),
+        _span_event("ps_pull_batch", 2000, 2000, 1, tid, "02" * 8,
+                    parent_id=root),
+        _span_event("edl/device_wait", 4000, 2000, 1, tid, "07" * 8,
+                    parent_id=root),
+        _span_event("ps_push", 6000, 3000, 1, tid, "03" * 8,
+                    parent_id=root),
+        _span_event("edl/health", 9000, 2000, 1, tid, "08" * 8,
+                    parent_id=root),
+        _span_event("edl/report", 11000, 1500, 1, tid, "09" * 8,
+                    parent_id=root),
+        _span_event("edl/callbacks", 12500, 500, 1, tid, "0a" * 8,
+                    parent_id=root),
+    ])
+    _write_trace_file(tmp_path, "ps-0", 2, [
+        _span_event("Pserver/push_gradients", 6500, 2000, 2, tid,
+                    "04" * 8, parent_id="03" * 8),
+    ])
+    report = critical_path.build_report(
+        critical_path.load_events(str(tmp_path))
+    )
+    step = report["step"]
+    assert step["count"] == 1 and step["roles"] == ["ps", "worker"]
+    segments = step["segments"]
+    assert segments["compute"]["p50_ms"] == pytest.approx(5.0)
+    assert segments["pull"]["p50_ms"] == pytest.approx(2.0)
+    assert segments["push"]["p50_ms"] == pytest.approx(1.0)
+    assert segments["apply"]["p50_ms"] == pytest.approx(2.0)
+    assert segments["input_wait"]["p50_ms"] == pytest.approx(1.0)
+    assert segments["bookkeeping"]["p50_ms"] == pytest.approx(2.0)
+    assert "other" not in segments
+    # merge_trace threads the spans it threaded without the phases:
+    # the root, the pull, the push and the PS's apply
+    merged, _names = merge_trace.merge(str(tmp_path))
+    flows = [e for e in merged["traceEvents"]
+             if e.get("ph") in ("s", "t", "f") and e["cat"] == "trace"]
+    assert [f["ph"] for f in flows] == ["s", "t", "t", "f"]
+    assert [f["ts"] for f in flows] == [0, 2000, 6000, 6500]
+
+
 def test_critical_path_classifies_shed_predicts(tmp_path):
     _scripts()
     import critical_path
