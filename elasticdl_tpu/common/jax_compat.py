@@ -1,16 +1,17 @@
-"""The two shard_map spellings the parallel/ops stack routes through
+"""The shard_map spellings the parallel/ops stack routes through
 one place, for the installed runtime (jax 0.9.0, pinned in
 pyproject.toml).
 
 No version probing: support for a JAX that is not installed is not
 kept. What remains is here because each call site would otherwise
 repeat a detail — when ``check_vma`` may be switched off, and the
-empty-axes no-op of a vary-cast.
+empty-axes no-op of a vary-cast, how to ask whether a region is
+already manual.
 """
 
 import jax
 
-__all__ = ["shard_map", "pvary"]
+__all__ = ["shard_map", "pvary", "manual_over"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
@@ -33,3 +34,13 @@ def pvary(x, axes):
         return x
     return jax.lax.pcast(x, axes, to="varying")
 
+
+
+def manual_over(mesh):
+    """Whether the caller is being traced inside a region that is
+    already manual over every axis of ``mesh`` (the pipeline's stage
+    body): arrays are one shard there, a second ``shard_map`` over the
+    mesh cannot open, and a sharding constraint that names its axes is
+    refused (they are of type Manual)."""
+    manual = jax.sharding.get_abstract_mesh().manual_axes
+    return set(mesh.axis_names) <= set(manual)

@@ -11,10 +11,9 @@ the FFN is still tensor-parallel over ``tp``.
 from typing import Any, Optional
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.models.transformer import Attention, Block
@@ -29,19 +28,10 @@ from elasticdl_tpu.ops.moe import (
     top_k_routing_compact,
 )
 from elasticdl_tpu.parallel.mesh import DATA_AXES
-from elasticdl_tpu.parallel.sharding import ShardingRules
+from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
 from elasticdl_tpu.train.optimizers import create_optimizer
-
-
-def _constrain(x, mesh, spec):
-    """Sharding hint, skipped when no mesh is in play (single device)."""
-    if mesh is None or mesh.empty:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, spec)
-    )
 
 
 class MoeMlp(nn.Module):
@@ -103,7 +93,7 @@ class MoeMlp(nn.Module):
             )
             # (E, G, C, M): the dispatch einsum is the dp→ep all-to-all.
             expert_in = moe_dispatch(x, dispatch)
-        expert_in = _constrain(
+        expert_in = constrain(
             expert_in, self.mesh, P("ep", DATA_AXES, None, None)
         )
         w_up = self.param(
@@ -119,7 +109,7 @@ class MoeMlp(nn.Module):
         h = jnp.einsum("egcm,emf->egcf", expert_in, w_up.astype(x.dtype))
         h = nn.gelu(h)
         out = jnp.einsum("egcf,efm->egcm", h, w_down.astype(x.dtype))
-        out = _constrain(
+        out = constrain(
             out, self.mesh, P("ep", DATA_AXES, None, None)
         )
         if compact:

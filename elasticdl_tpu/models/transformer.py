@@ -15,6 +15,10 @@ Design notes (TPU-first):
   output dim over ``tp``, out-proj/mlp-down split their input dim, so
   XLA inserts one psum per block (Megatron layout, expressed as GSPMD
   rules instead of hand-written collectives).
+- the activations' layout is stated too (``constrain``): batch over the
+  data axes, sequence over ``sp``, features over ``tp`` where a kernel
+  splits them. Parameter rules alone leave it to propagation, which
+  under ``fsdp`` moved the batch instead of the weights.
 """
 
 from typing import Any, Optional
@@ -31,10 +35,20 @@ from elasticdl_tpu.ops.ring_attention import (
     ulysses_attention,
 )
 from elasticdl_tpu.parallel.mesh import DATA_AXES
-from elasticdl_tpu.parallel.sharding import ShardingRules
+from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
 from elasticdl_tpu.train.optimizers import create_optimizer
+
+
+# Where the activations live (parallel/sharding.py:constrain): the
+# batch over the data axes and the sequence over sp, as batch_spec()
+# has the tokens. The residual stream (B, S, D) keeps its features
+# whole; the MLP hidden and the logits (B, S, F) split theirs over tp
+# like the kernels that produce them. Under fsdp the weights then move
+# to the activations and never the other way round.
+RESIDUAL_SPEC = P(DATA_AXES, "sp", None)
+HIDDEN_SPEC = P(DATA_AXES, "sp", "tp")
 
 
 def rotary_embedding(x, base=10000.0, seq_axis=2):
@@ -76,7 +90,15 @@ class Attention(nn.Module):
         # best-MFU config): XLA's transposes already run near the HBM
         # roofline, and removing them shifts cost into strided kernel
         # DMA and worse qkv-matmul layouts. docs/PERF_TRANSFORMER.md.
-        to_bhsd = lambda t: t.transpose(0, 2, 1, 3)
+        # q/k/v are pinned to the layout the attention call declares,
+        # (B, H, S, d) with batch over the data axes and heads over tp
+        # (ring / ulysses: the sequence over sp as well), so its
+        # shard_map meets operands that are already where it wants them
+        sp = "sp" if self.attention_impl in ("ring", "ulysses") else None
+        spec = P(DATA_AXES, "tp", sp, None)
+        to_bhsd = lambda t: constrain(
+            t.transpose(0, 2, 1, 3), self.mesh, spec
+        )
         q = to_bhsd(dense("query")(x))
         k = to_bhsd(dense("key")(x))
         v = to_bhsd(dense("value")(x))
@@ -88,11 +110,9 @@ class Attention(nn.Module):
         elif self.attention_impl == "ulysses":
             out = ulysses_attention(q, k, v, self.mesh, causal=True)
         else:
-            # (B, H, S, d) on the mesh: batch over the data axes, heads
-            # over tp (the layout sharding_rules() gives the projections)
             out = dot_product_attention(
                 q, k, v, causal=True, impl=self.attention_impl,
-                mesh=self.mesh, spec=P(DATA_AXES, "tp", None, None),
+                mesh=self.mesh, spec=spec,
             )
         out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
         out = nn.DenseGeneral(
@@ -115,6 +135,7 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
+        x = constrain(x, self.mesh, RESIDUAL_SPEC)
         h = nn.LayerNorm(name="ln_attn")(x)
         x = x + Attention(
             self.num_heads,
@@ -125,11 +146,12 @@ class Block(nn.Module):
         )(h, training)
         h = nn.LayerNorm(name="ln_mlp")(x)
         h = nn.Dense(dim * self.mlp_ratio, use_bias=False, name="mlp_up")(h)
+        h = constrain(h, self.mesh, HIDDEN_SPEC)
         h = nn.gelu(h)
         h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
         if self.dropout:
             h = nn.Dropout(self.dropout, deterministic=not training)(h)
-        return x + h
+        return constrain(x + h, self.mesh, RESIDUAL_SPEC)
 
 
 class TransformerLM(nn.Module):
@@ -159,6 +181,7 @@ class TransformerLM(nn.Module):
         x = nn.Embed(
             self.vocab_size, self.embed_dim, name="wte"
         )(tokens.astype(jnp.int32))
+        x = constrain(x, self.mesh, RESIDUAL_SPEC)
         if self.remat:
             import jax
 
@@ -222,8 +245,11 @@ class TransformerLM(nn.Module):
                 dropout=self.dropout,
                 name="block_%d" % i,
             )(x, training)
-        x = nn.LayerNorm(name="ln_f")(x)
-        return nn.Dense(self.vocab_size, use_bias=False, name="lm_head")(x)
+        x = constrain(nn.LayerNorm(name="ln_f")(x), self.mesh, RESIDUAL_SPEC)
+        logits = nn.Dense(
+            self.vocab_size, use_bias=False, name="lm_head"
+        )(x)
+        return constrain(logits, self.mesh, HIDDEN_SPEC)
 
 
 # ---------------------------------------------------------------------------
@@ -237,6 +263,17 @@ def transformer_sharding_rules():
     qkv and mlp-up split output features over tp (their matmuls become
     local); out-proj and mlp-down split input features, after which XLA
     inserts a single psum per block. Embedding and lm_head split vocab.
+
+    ``fsdp`` only says where a kernel is STORED. The model pins every
+    activation's batch to the data axes (``RESIDUAL_SPEC``,
+    ``HIDDEN_SPEC``, the attention call's spec), so whichever dimension
+    of a kernel ``fsdp`` splits, the partitioner brings the weight to
+    the activation: gathered whole (cast to the compute dtype first)
+    before its matmul, its gradient reduced back onto the shard
+    (ZeRO-3). Without those pins the split on a contracted dimension
+    propagated into the residual stream: the batch was replicated and
+    a full-batch activation all-reduced after every contraction
+    (PERF.md, PR 24).
     """
     return ShardingRules(
         rules=[

@@ -32,6 +32,12 @@ runtime through three instruments:
    bridge consumes these instead of the hand-coded per-model table,
    and host↔device ``transfer`` counters/spans let
    ``scripts/critical_path.py`` attribute a ``transfer`` segment.
+   The same executable's HLO text is read once for the collectives
+   the partitioner put into the program (``collective_stats``): count
+   and result bytes by kind and the largest single one, on the compile
+   log line and the ``xla_compile`` journal event, so that what the
+   dense plane's cost model expects (``parallel/dense_plane.py``) can
+   be read against what the program does.
 
 Disabled path (``EDL_DEVICE_OBS=0``): ``instrumented_jit`` returns the
 **raw ``jax.jit`` product, unchanged** — no wrapper frame, no per-call
@@ -49,6 +55,8 @@ Knobs (all via common/env_utils, documented in docs/OBSERVABILITY.md):
 """
 
 import contextlib
+import math
+import re
 import threading
 import time
 import weakref
@@ -141,6 +149,94 @@ def device_obs_enabled():
     return env_bool(DEVICE_OBS_ENV, True)
 
 
+COLLECTIVE_KINDS = (
+    "all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+    "collective-permute",
+)
+# one HLO instruction: ``%name = <shape> <opcode>(<rest of line>``. An
+# asynchronous pair counts once, at its ``-done`` (whose result is the
+# collective's own; a ``-start`` returns a tuple that repeats the
+# operand), and a collective wrapped in ``async-start`` /
+# ``async-done`` counts at the instruction inside the wrapped
+# computation.
+_COLLECTIVE_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?\S+ = (\(.*?\)|\S+) (%s)(-done)?\((.*)$"
+    % "|".join(COLLECTIVE_KINDS),
+    re.MULTILINE,
+)
+_RESULT_ARRAYS_MAX = 3
+_CHANNEL_RE = re.compile(r"\bchannel_id=(\d+)")
+_HLO_ARRAY_RE = re.compile(r"\b([a-z]+\d*\w*)\[([\d,]*)\]")
+
+
+def _hlo_array_bytes(dtype, dims):
+    bits = re.search(r"\d+", dtype)
+    # pred is a byte; sub-byte types round up per array
+    return -(-math.prod(dims) * (int(bits.group()) if bits else 8) // 8)
+
+
+def hlo_collectives(hlo_text):
+    """The collectives of a compiled program's HLO text: one ``(kind,
+    arrays, bytes)`` each, ``arrays`` the ``(dtype, dims)`` of its
+    result (several for a tuple) and ``bytes`` their size on one
+    device. Instructions that share a ``channel_id`` are one
+    collective: the TPU compiler repeats an asynchronous all-gather in
+    every fused computation it continues through. A static count: a
+    collective inside a loop body counts once."""
+    found = []
+    channels = set()
+    for match in _COLLECTIVE_RE.finditer(hlo_text):
+        shape, kind, done, rest = match.groups()
+        channel = None if done else _CHANNEL_RE.search(rest)
+        if channel:
+            if (kind, channel.group(1)) in channels:
+                continue
+            channels.add((kind, channel.group(1)))
+        arrays = [
+            (dtype, tuple(int(d) for d in dims.split(",") if d))
+            for dtype, dims in _HLO_ARRAY_RE.findall(shape)
+        ]
+        found.append((
+            kind, arrays,
+            sum(_hlo_array_bytes(dtype, dims) for dtype, dims in arrays),
+        ))
+    return found
+
+
+def _result_text(arrays):
+    """``bf16[2048,8192]``; of a tuple the first arrays and how many
+    follow (a combined all-reduce returns every small gradient)."""
+    shown = [
+        "%s[%s]" % (dtype, ",".join(map(str, dims)))
+        for dtype, dims in arrays[:_RESULT_ARRAYS_MAX]
+    ]
+    if len(arrays) > _RESULT_ARRAYS_MAX:
+        shown.append("+%d more" % (len(arrays) - _RESULT_ARRAYS_MAX))
+    return ", ".join(shown)
+
+
+def collective_stats(hlo_text):
+    """``hlo_collectives`` folded for the journal: ``{kind: {"count",
+    "bytes"}}`` for every kind (zeros included, so a one-device program
+    says so), ``bytes`` over all of them and the ``largest`` single one
+    (``{"kind", "result", "bytes"}``, None without any)."""
+    by_kind = {kind: {"count": 0, "bytes": 0} for kind in COLLECTIVE_KINDS}
+    largest = None
+    for kind, arrays, nbytes in hlo_collectives(hlo_text):
+        by_kind[kind]["count"] += 1
+        by_kind[kind]["bytes"] += nbytes
+        if largest is None or nbytes > largest["bytes"]:
+            largest = {
+                "kind": kind, "result": _result_text(arrays),
+                "bytes": nbytes,
+            }
+    return {
+        "by_kind": by_kind,
+        "bytes": sum(entry["bytes"] for entry in by_kind.values()),
+        "largest": largest,
+    }
+
+
 def _leaf_spec(leaf):
     """``f32[32,10]``-style spec for one argument leaf; scalars and
     static oddities render as their type name (they still churn the
@@ -214,6 +310,9 @@ class _InstrumentedJit:
         self.last_compile_secs = 0.0
         self.cost_flops = 0.0
         self.cost_bytes = 0.0
+        # collective_stats() of the last-compiled signature; None until
+        # a cost fetch has read a program
+        self.collectives = None
         self._cost_fetches = 0
         self._cost_on = env_bool(COST_ANALYSIS_ENV, True)
         self._cache_size = 0
@@ -307,8 +406,18 @@ class _InstrumentedJit:
         # both figures on one line: whether the cost fetch's relower is
         # a compile-cache hit or a second cold compile reads off it
         logger.info(
-            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs",
+            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs%s",
             self.compiles, self.name, elapsed, fetch_secs,
+            "" if self.collectives is None
+            else "; collectives " + collectives_text(self.collectives),
+        )
+        events.emit(
+            "xla_compile",
+            fn=self.name,
+            compiles=self.compiles,
+            seconds=round(elapsed, 4),
+            cost_fetch_seconds=round(fetch_secs, 4),
+            collectives=self.collectives,
         )
 
     def _fetch_cost(self, args, kwargs):
@@ -329,9 +438,26 @@ class _InstrumentedJit:
             self.cost_bytes = float(
                 cost.get("bytes accessed", 0.0) or 0.0
             )
+            self.collectives = collective_stats(compiled.as_text())
         except Exception as e:
             logger.debug("cost analysis unavailable for %s: %s",
                          self.name, e)
+
+
+def collectives_text(stats):
+    """``collective_stats`` on one line: ``412.3 MB: all-gather x98
+    310.0 MB, ... (largest all-gather bf16[50304,2048] 206.0 MB)``."""
+    if not stats["largest"]:
+        return "none"
+    return "%.1f MB: %s (largest %s %s %.1f MB)" % (
+        stats["bytes"] / 1e6,
+        ", ".join(
+            "%s x%d %.1f MB" % (kind, entry["count"], entry["bytes"] / 1e6)
+            for kind, entry in stats["by_kind"].items() if entry["count"]
+        ),
+        stats["largest"]["kind"], stats["largest"]["result"],
+        stats["largest"]["bytes"] / 1e6,
+    )
 
 
 def instrumented_jit(fn, name=None, **jit_kwargs):

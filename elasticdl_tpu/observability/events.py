@@ -190,6 +190,14 @@ EVENT_TYPES = frozenset({
                              #   [leaf: old -> new provenance],
                              #   signature) — the journal line the
                              #   recompile_storm postmortem reads
+    "xla_compile",           # every compile of a wrapped step fn, the
+                             #   first included (+ fn, compiles,
+                             #   seconds, cost_fetch_seconds,
+                             #   collectives {by_kind{kind: count,
+                             #   bytes}, bytes, largest}: the compiled
+                             #   program's collective instructions,
+                             #   result bytes on one device; null when
+                             #   the program was not read)
     # the worker's phase ledger (ISSUE 23); durations in nanoseconds
     # on perf_counter_ns, ``ts`` places the event in wall time
     "loop_phases",           # every --log_loss_steps steps: the loop

@@ -16,9 +16,11 @@ defaults below implement:
 
 import re
 
+import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 
 logger = _logger_factory("elasticdl_tpu.parallel.sharding")
@@ -35,6 +37,30 @@ class ShardingRules:
             if pattern.search(path):
                 return spec
         return self._default
+
+
+def constrain(x, mesh, spec):
+    """Pin the layout of an ACTIVATION: ``x`` with
+    ``with_sharding_constraint(NamedSharding(mesh, spec))``.
+
+    The rules above say where parameters live; nothing but this says
+    where an activation lives, and GSPMD otherwise propagates the
+    weights' layout into it: under ``fsdp`` that replicated the batch
+    and all-reduced a full-batch activation after every contraction
+    (tensor parallelism over an axis named fsdp; PERF.md, PR 24). With
+    the batch pinned to the data axes the partitioner moves the
+    weights instead (ZeRO-3: all-gather before use, reduce-scatter of
+    the gradients).
+
+    The identity where there is nothing to say: no mesh, one device,
+    or inside a region that is already manual over the mesh's axes
+    (``pipeline_transformer`` runs ``transformer.Block`` in one; a
+    constraint there names axes that are of type Manual and is
+    refused; the test ``ops/attention.py:_shard_over_mesh`` makes).
+    """
+    if mesh is None or mesh.size == 1 or jax_compat.manual_over(mesh):
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def _tree_paths(tree, prefix=""):
@@ -82,8 +108,6 @@ def infer_state_shardings(state, mesh, rules: ShardingRules = None):
     inherits its parameter's spec (ZeRO: momentum/variance shard with the
     weight).
     """
-    import jax
-
     param_specs = {}
     for path, value in _tree_paths(state.params):
         if rules is not None:

@@ -160,6 +160,25 @@ class SpmdTrainer:
             summary["collective_bytes_per_step"] / 1e6,
         )
 
+    def _log_program_collectives(self):
+        """The plan's modelled traffic beside what the compiled step
+        holds (the compile ledger's count of the program's collective
+        instructions, ``observability/device.py:collective_stats``):
+        the model assumes ZeRO (gradients reduce-scatter, float32) and
+        the program is what the partitioner made of the activations'
+        and parameters' layouts, so a program that moves activations
+        instead of weights reads off this line."""
+        stats = self.program_collectives
+        if stats is None:
+            return
+        logger.info(
+            "dense plane: modelled ~%.1f MB collective traffic per "
+            "step; the compiled train step holds, in collective "
+            "results a device, %s",
+            self.collective_bytes_per_step / 1e6,
+            device_obs.collectives_text(stats),
+        )
+
     def abstract_state(self, sample_features):
         """Shape/dtype skeleton of create_state without materializing any
         buffers — the restore template for checkpoint resume. Also
@@ -224,6 +243,12 @@ class SpmdTrainer:
     def cost_step_bytes(self):
         return float(getattr(self._train_step, "cost_bytes", 0.0))
 
+    @property
+    def program_collectives(self):
+        """``collective_stats`` of the last-compiled train step (None
+        before the first compile or with device obs off)."""
+        return getattr(self._train_step, "collectives", None)
+
     # dense-plane telemetry (this PR): the worker folds these into the
     # TelemetryBlob so FleetMonitor /statusz and postmortem timelines
     # can show what the dense plane looks like per worker
@@ -280,11 +305,15 @@ class SpmdTrainer:
         state = self.ensure_state(state, batch)
         with phase("h2d"):
             sharded = self.shard_batch(batch)
-        if self._train_step is None:
+        first = self._train_step is None
+        if first:
             self._build_steps(batch)
             self._log_batch_split(sharded["features"])
         with phase("dispatch"):
-            return self._train_step(state, sharded)
+            out = self._train_step(state, sharded)
+        if first:
+            self._log_program_collectives()
+        return out
 
     @staticmethod
     def _log_batch_split(features):

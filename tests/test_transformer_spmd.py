@@ -6,6 +6,8 @@ attention and (b) GSPMD-sharded over a dp x tp x sp mesh with ring (and
 ulysses) attention, must produce the same losses.
 """
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -38,6 +40,13 @@ def _batch(batch=4, seq=32, vocab=128, seed=0):
     }
 
 
+@functools.lru_cache(maxsize=None)
+def _reference_losses():
+    """The single-device losses of ``_batch()``: computed once for the
+    parametrised cases that compare against them."""
+    return _single_device_losses(_batch())
+
+
 def _single_device_losses(batch, steps=3):
     model = _small_lm(attention_impl="xla")
     tx = create_optimizer("Adam", learning_rate=0.01)
@@ -53,12 +62,10 @@ def _single_device_losses(batch, steps=3):
     return losses
 
 
-@pytest.mark.parametrize("impl", ["ring", "ulysses"])
-def test_spmd_tp_sp_matches_single_device(impl):
-    batch = _batch()
-    expected = _single_device_losses(batch)
-
-    mesh = build_mesh(MeshConfig(dp=2, tp=2, sp=2))
+def _spmd_losses(batch, axes, impl, steps=3):
+    mesh = build_mesh(
+        MeshConfig(**axes), num_devices=int(np.prod(list(axes.values())))
+    )
     model = _small_lm(attention_impl=impl, mesh=mesh)
     trainer = SpmdTrainer(
         model=model,
@@ -71,10 +78,33 @@ def test_spmd_tp_sp_matches_single_device(impl):
     )
     state = trainer.create_state(batch["features"])
     losses = []
-    for _ in range(3):
+    for _ in range(steps):
         state, loss = trainer.train_step(state, batch)
         losses.append(float(loss))
-    np.testing.assert_allclose(losses, expected, atol=1e-4, rtol=1e-4)
+    return losses
+
+
+@pytest.mark.parametrize("impl", ["ring", "ulysses"])
+def test_spmd_tp_sp_matches_single_device(impl):
+    np.testing.assert_allclose(
+        _spmd_losses(_batch(), dict(dp=2, tp=2, sp=2), impl),
+        _reference_losses(), atol=1e-4, rtol=1e-4,
+    )
+
+
+@pytest.mark.parametrize(
+    "axes",
+    [dict(dp=1, fsdp=4), dict(dp=2, fsdp=2), dict(dp=1, fsdp=2, tp=2)],
+    ids=["fsdp4", "dp2-fsdp2", "fsdp2-tp2"],
+)
+def test_spmd_fsdp_matches_single_device(axes):
+    """Under an fsdp extent the activations are pinned to the data
+    axes and the weights move (ZeRO-3): where an array lives changes,
+    the mathematics does not."""
+    np.testing.assert_allclose(
+        _spmd_losses(_batch(), axes, "xla"),
+        _reference_losses(), atol=1e-4, rtol=1e-4,
+    )
 
 
 def test_spmd_fsdp_transformer_runs():
