@@ -1,57 +1,88 @@
 """Mixture-of-experts transformer LM — the expert-parallel model family.
 
 No reference counterpart (SURVEY.md §2.12: EP absent from the reference);
-this family exercises the ``ep`` mesh axis. Every other block swaps the
-dense MLP for a top-k-routed expert MLP (ops/moe.py): expert weight
-tensors carry a leading expert dim sharded over ``ep``, the dispatch/
-combine einsums become all-to-alls under GSPMD, and within each expert
-the FFN is still tensor-parallel over ``tp``.
+this family exercises the ``ep`` mesh axis. Some blocks (every other one
+by default, every one for OLMoE) swap the dense MLP for a top-k-routed
+expert MLP (ops/moe.py). Two dispatches:
+
+- one-hot with a capacity (the default): expert weight tensors carry a
+  leading expert dim sharded over ``ep``, the dispatch/combine einsums
+  become all-to-alls under GSPMD, and within each expert the FFN is
+  still tensor-parallel over ``tp``;
+- sorted and dropless (``dispatch_impl="sorted"``): the (token, choice)
+  pairs ordered by expert around a grouped matmul, on one device's
+  tokens. This is what a published small-expert model (OLMoE-1B-7B: 64
+  SwiGLU experts of width 1024, top-8, nothing dropped) needs; the
+  benchmark's ``olmoe-1b-7b-1chip`` configuration builds it.
 """
 
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
-from elasticdl_tpu.models.transformer import Attention, Block
-from elasticdl_tpu.ops.moe import (
-    expert_capacity,
-    invert_slots,
-    moe_combine,
-    moe_combine_compact,
-    moe_dispatch,
-    moe_dispatch_compact,
-    top_k_routing,
-    top_k_routing_compact,
+from elasticdl_tpu.models.transformer import (
+    RESIDUAL_SPEC,
+    Attention,
+    Block,
+    make_norm,
+    remat_block,
 )
+from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.parallel.mesh import DATA_AXES
 from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
 from elasticdl_tpu.train.optimizers import create_optimizer
 
+logger = _logger_factory("elasticdl_tpu.models.moe_transformer")
+
+
+@functools.lru_cache(maxsize=None)
+def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
+                       act):
+    """One line per distinct expert layer shape (this runs at trace
+    time), beside the compile ledger's line of the step, as
+    ``ops/attention.py`` names the attention it resolved to."""
+    logger.info(
+        "moe dispatch resolved to %s (tokens=%d experts=%d top_k=%d "
+        "expert_width=%d act=%s, experts' matmul=%s)",
+        impl, tokens, num_experts, top_k, width, act, matmul,
+    )
+
 
 class MoeMlp(nn.Module):
-    """Top-k routed expert FFN (GShard dispatch, Switch aux loss).
+    """Top-k routed expert FFN. Returns ``(y, aux)``: ``aux`` holds the
+    layer's ``load_balancing`` and ``router_z`` losses (unweighted) and,
+    on the sorted path, its ``routing`` counters (ops/moe.py
+    ``routing_stats``; None on the one-hot path).
 
-    Two dispatch implementations with identical semantics
-    (``tests/test_moe.py::test_compact_dispatch_matches_onehot``):
+    ``dispatch_impl``:
 
-    - ``"onehot"`` (= ``"auto"``, the measured default) — GShard
-      dispatch/combine einsums. The one-hot contraction is MXU work,
-      so it scales with batch (59.8% MFU at the docs/PERF_MOE.md
-      B=16 config), and under GSPMD with tokens dp-sharded and
-      experts ep-sharded these einsums ARE the dp→ep all-to-alls.
-    - ``"compact"`` — slot-index gathers with gather-only custom
-      backwards (ops/moe.py). No (G, S, E, C) one-hots and ~10% fewer
-      executed FLOPs, but XLA lowers TPU row-gathers at ~200 GB/s, so
-      it measured SLOWER end-to-end than the einsums at every batch
-      tried — kept as an explicit option and a measured negative
-      (docs/PERF_MOE.md round 5); a Pallas gather kernel is the known
-      path to make it win.
+    - ``"onehot"`` (= ``"auto"``) — GShard dispatch/combine einsums
+      over a static capacity; tokens over it are dropped. The one-hot
+      contraction is MXU work, and under GSPMD with tokens dp-sharded
+      and experts ep-sharded these einsums ARE the dp→ep all-to-alls.
+      Switch's auxiliary loss over first choices, gates renormalised.
+    - ``"sorted"`` — dropless: sort the pairs by expert, gather, a
+      grouped matmul over the ragged groups, gather back
+      (ops/moe.py). No capacity, no one-hot, every token reaches
+      exactly ``top_k`` experts. The load-balancing loss counts all
+      ``top_k`` choices (OLMoE's). One device's tokens: a mesh whose
+      ``ep`` axis is larger than 1 is refused, never served by a
+      silent fallback.
+
+    Experts are ``expert_act`` = ``"gelu"`` (up, GELU, down) or
+    ``"swiglu"`` (silu(gate) x up, down) of width ``expert_dim``
+    (``mlp_ratio x dim`` when None). ``normalize_gates=False`` (the
+    kept gates stay the softmax's own, OLMoE's ``norm_topk_prob``
+    false) is the sorted path's; the one-hot path refuses it.
     """
 
     num_experts: int
@@ -60,65 +91,146 @@ class MoeMlp(nn.Module):
     capacity_factor: float = 1.25
     dispatch_impl: str = "auto"
     mesh: Optional[Any] = None
+    expert_dim: Optional[int] = None
+    expert_act: str = "gelu"
+    normalize_gates: bool = True
 
-    def _use_compact(self):
-        return self.dispatch_impl == "compact"
+    def _expert_param(self, name, rows, cols):
+        # the expert axis is a batch of kernels, not fan-in: without
+        # batch_axis every expert started sqrt(E) times too small
+        return self.param(
+            name,
+            nn.initializers.lecun_normal(batch_axis=(0,)),
+            (self.num_experts, rows, cols),
+        )
+
+    def _weights(self, dim, dtype):
+        """The experts' kernels in the compute dtype, input side first:
+        (w_up, w_down) for GELU, (w_gate, w_up, w_down) for SwiGLU."""
+        if self.expert_act not in ("gelu", "swiglu"):
+            raise ValueError(
+                "expert_act must be 'gelu' or 'swiglu', got %r"
+                % (self.expert_act,)
+            )
+        ff = self.expert_dim or dim * self.mlp_ratio
+        names = ["w_up"] if self.expert_act == "gelu" else ["w_gate", "w_up"]
+        return [
+            self._expert_param(name, dim, ff).astype(dtype) for name in names
+        ] + [self._expert_param("w_down", ff, dim).astype(dtype)]
+
+    def _act(self, hidden):
+        if self.expert_act == "gelu":
+            return nn.gelu(hidden[0])
+        return nn.silu(hidden[0]) * hidden[1]
 
     @nn.compact
     def __call__(self, x):
+        impl = self.dispatch_impl
+        if impl not in ("auto", "onehot", "sorted"):
+            raise ValueError(
+                "dispatch_impl must be 'auto', 'onehot' or 'sorted', "
+                "got %r" % (impl,)
+            )
         groups, seq, dim = x.shape
-        ff = dim * self.mlp_ratio
-        capacity = expert_capacity(
+        with jax.named_scope("moe/router"):
+            router_logits = nn.Dense(
+                self.num_experts, use_bias=False, name="router"
+            )(x)
+        weights = self._weights(dim, x.dtype)
+        one_device = self.mesh is None or self.mesh.size == 1
+        _log_dispatch_once(
+            "sorted" if impl == "sorted" else "onehot",
+            moe_ops.resolve_grouped_matmul(
+                groups * seq * self.top_k, x.dtype, one_device
+            ) if impl == "sorted" else "einsum",
+            groups * seq, self.num_experts, self.top_k,
+            weights[0].shape[-1], self.expert_act,
+        )
+        if impl == "sorted":
+            y, aux = self._sorted(x, router_logits, weights, one_device)
+        else:
+            y, aux = self._onehot(x, router_logits, weights)
+        with jax.named_scope("moe/router"):
+            aux["router_z"] = moe_ops.router_z_loss(router_logits)
+        return y, aux
+
+    def _onehot(self, x, router_logits, weights):
+        if not self.normalize_gates:
+            raise ValueError(
+                "normalize_gates=False needs dispatch_impl=\"sorted\": "
+                "the one-hot routing (ops/moe.py:top_k_routing) always "
+                "renormalises the gates it keeps"
+            )
+        _, seq, _ = x.shape
+        capacity = moe_ops.expert_capacity(
             seq, self.num_experts, self.top_k, self.capacity_factor
         )
-        router_logits = nn.Dense(
-            self.num_experts, use_bias=False, name="router"
-        )(x)
-        compact = self._use_compact()
-        if compact:
-            gates, slot, aux_loss = top_k_routing_compact(
+        with jax.named_scope("moe/router"):
+            combine, dispatch, balance = moe_ops.top_k_routing(
                 router_logits, self.top_k, capacity
             )
-            # one inversion scatter shared by dispatch AND combine
-            j_for_slot = invert_slots(
-                slot, self.num_experts * capacity
-            )
-            expert_in = moe_dispatch_compact(
-                x, slot, self.num_experts, capacity,
-                j_for_slot=j_for_slot,
-            )
-        else:
-            combine, dispatch, aux_loss = top_k_routing(
-                router_logits, self.top_k, capacity
-            )
+        with jax.named_scope("moe/dispatch"):
             # (E, G, C, M): the dispatch einsum is the dp→ep all-to-all.
-            expert_in = moe_dispatch(x, dispatch)
-        expert_in = constrain(
-            expert_in, self.mesh, P("ep", DATA_AXES, None, None)
-        )
-        w_up = self.param(
-            "w_up",
-            nn.initializers.lecun_normal(),
-            (self.num_experts, dim, ff),
-        )
-        w_down = self.param(
-            "w_down",
-            nn.initializers.lecun_normal(),
-            (self.num_experts, ff, dim),
-        )
-        h = jnp.einsum("egcm,emf->egcf", expert_in, w_up.astype(x.dtype))
-        h = nn.gelu(h)
-        out = jnp.einsum("egcf,efm->egcm", h, w_down.astype(x.dtype))
-        out = constrain(
-            out, self.mesh, P("ep", DATA_AXES, None, None)
-        )
-        if compact:
-            y = moe_combine_compact(
-                out, slot, gates, j_for_slot=j_for_slot
+            expert_in = constrain(
+                moe_ops.moe_dispatch(x, dispatch),
+                self.mesh, P("ep", DATA_AXES, None, None),
             )
-        else:
-            y = moe_combine(out, combine)  # ep→dp all-to-all back
-        return y, aux_loss
+        with jax.named_scope("moe/experts"):
+            hidden = [
+                jnp.einsum("egcm,emf->egcf", expert_in, w)
+                for w in weights[:-1]
+            ]
+            out = jnp.einsum("egcf,efm->egcm", self._act(hidden), weights[-1])
+            out = constrain(
+                out, self.mesh, P("ep", DATA_AXES, None, None)
+            )
+        with jax.named_scope("moe/combine"):
+            y = moe_ops.moe_combine(out, combine)  # ep→dp all-to-all back
+        return y, {"load_balancing": balance, "routing": None}
+
+    def _sorted(self, x, router_logits, weights, one_device):
+        if self.mesh is not None and self.mesh.shape.get("ep", 1) > 1:
+            raise ValueError(
+                'dispatch_impl="sorted" sorts one device\'s tokens and '
+                "has no all-to-all over ep yet (this mesh has ep=%d): "
+                "that is ROADMAP.md Reach 2. Use the one-hot dispatch "
+                "on an ep mesh." % self.mesh.shape["ep"]
+            )
+        groups, seq, dim = x.shape
+        tokens = x.reshape(groups * seq, dim)
+        logits = router_logits.reshape(groups * seq, self.num_experts)
+        with jax.named_scope("moe/router"):
+            gates, experts, probs = moe_ops.route_top_k(
+                logits, self.top_k, normalize=self.normalize_gates
+            )
+        # for whoever asks with mutable=["intermediates"] (the
+        # benchmark's reference check); nothing otherwise
+        self.sow("intermediates", "experts", experts.reshape(groups, seq, -1))
+        with jax.named_scope("moe/dispatch"):
+            order, inverse, group_sizes = moe_ops.sort_by_expert(
+                experts, self.num_experts
+            )
+            rows = moe_ops.dispatch_sorted(tokens, order, inverse)
+        with jax.named_scope("moe/experts"):
+            hidden = [
+                moe_ops.grouped_matmul(rows, w, group_sizes, one_device)
+                for w in weights[:-1]
+            ]
+            out = moe_ops.grouped_matmul(
+                self._act(hidden), weights[-1], group_sizes, one_device
+            )
+        with jax.named_scope("moe/combine"):
+            y = moe_ops.combine_sorted(out, gates, order, inverse)
+        with jax.named_scope("moe/router"):
+            aux = {
+                "load_balancing": moe_ops.load_balancing_loss(
+                    probs, group_sizes
+                ),
+                "routing": moe_ops.routing_stats(
+                    probs, group_sizes, self.top_k
+                ),
+            }
+        return y.reshape(x.shape), aux
 
 
 class MoeBlock(nn.Module):
@@ -130,35 +242,72 @@ class MoeBlock(nn.Module):
     attention_impl: str = "auto"
     dispatch_impl: str = "auto"
     mesh: Optional[Any] = None
+    expert_dim: Optional[int] = None
+    expert_act: str = "gelu"
+    normalize_gates: bool = True
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, x, training=False):
-        h = nn.LayerNorm(name="ln_attn")(x)
+        x = constrain(x, self.mesh, RESIDUAL_SPEC)
+        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
         x = x + Attention(
             self.num_heads,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
             name="attn",
         )(h, training)
-        h = nn.LayerNorm(name="ln_mlp")(x)
-        y, aux_loss = MoeMlp(
+        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
+        y, aux = MoeMlp(
             self.num_experts,
             mlp_ratio=self.mlp_ratio,
             top_k=self.top_k,
             capacity_factor=self.capacity_factor,
             dispatch_impl=self.dispatch_impl,
             mesh=self.mesh,
+            expert_dim=self.expert_dim,
+            expert_act=self.expert_act,
+            normalize_gates=self.normalize_gates,
             name="moe_mlp",
         )(h)
-        return x + y, aux_loss
+        return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
+
+
+def merge_routing(layers):
+    """One set of ``moe_routing`` counters from the expert layers' own:
+    the largest load of any expert in any layer, the mean load, the
+    mean entropy and all dropped pairs."""
+    return {
+        "load_max": jnp.stack([r["load_max"] for r in layers]).max(),
+        "load_mean": jnp.stack([r["load_mean"] for r in layers]).mean(),
+        "entropy": jnp.stack([r["entropy"] for r in layers]).mean(),
+        "dropped": jnp.stack([r["dropped"] for r in layers]).sum(),
+    }
 
 
 class MoeTransformerLM(nn.Module):
-    """Decoder-only LM with MoE FFNs in every other block.
+    """Decoder-only LM with an MoE FFN in every ``moe_every``-th block
+    (block i is an expert block when ``i % moe_every == moe_every - 1``:
+    every other one by default, every one at 1).
 
-    Training call returns ``{"logits", "aux_loss"}`` (the router
-    load-balance penalty must reach the loss); eval returns bare logits
-    so metrics and export see the same surface as the dense LM.
+    Training call returns ``{"logits", "aux_loss"}`` (the router's
+    penalties must reach the loss: ``aux_loss_weight`` x load balancing
+    + ``z_loss_weight`` x router z-loss, each summed over the expert
+    layers) and, on the sorted path, ``"routing"`` (counters for the
+    ``moe_routing`` journal event; train/step_fns.py hands them out of
+    the jitted step); eval returns bare logits so metrics and export see
+    the same surface as the dense LM.
+
+    The defaults are the legacy zoo model (LayerNorm, GELU experts of
+    ``mlp_ratio x embed_dim``, one-hot dispatch, renormalised gates);
+    OLMoE-1B-7B is ``norm="rmsnorm"``, ``qk_norm``, ``expert_act=
+    "swiglu"``, ``expert_dim=1024``, ``moe_every=1``,
+    ``dispatch_impl="sorted"``, ``normalize_gates=False``,
+    ``z_loss_weight=0.001``.
     """
 
     vocab_size: int = 32000
@@ -173,45 +322,76 @@ class MoeTransformerLM(nn.Module):
     attention_impl: str = "auto"
     dispatch_impl: str = "auto"
     mesh: Optional[Any] = None
+    expert_dim: Optional[int] = None
+    expert_act: str = "gelu"
+    normalize_gates: bool = True
+    z_loss_weight: float = 0.0
+    moe_every: int = 2
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
+    # per-block rematerialization, as TransformerLM has it
+    # (models/transformer.py:remat_block)
+    remat: bool = False
+    remat_policy: str = "full"
 
     @nn.compact
     def __call__(self, tokens, training: bool = False):
         x = nn.Embed(
             self.vocab_size, self.embed_dim, name="wte"
         )(tokens.astype(jnp.int32))
-        aux_total = jnp.float32(0.0)
+        wrap = (
+            functools.partial(
+                remat_block, remat_policy=self.remat_policy,
+                attention_impl=self.attention_impl,
+            )
+            if self.remat else (lambda cls: cls)
+        )
+        shared = dict(
+            mlp_ratio=self.mlp_ratio,
+            attention_impl=self.attention_impl,
+            mesh=self.mesh,
+            norm=self.norm,
+            norm_eps=self.norm_eps,
+            qk_norm=self.qk_norm,
+        )
+        balance = z_loss = jnp.float32(0.0)
+        routing = []
         for i in range(self.num_layers):
-            if i % 2 == 1:
-                x, aux = MoeBlock(
+            if i % self.moe_every == self.moe_every - 1:
+                x, aux = wrap(MoeBlock)(
                     self.num_heads,
                     self.num_experts,
-                    mlp_ratio=self.mlp_ratio,
                     top_k=self.top_k,
                     capacity_factor=self.capacity_factor,
-                    attention_impl=self.attention_impl,
                     dispatch_impl=self.dispatch_impl,
-                    mesh=self.mesh,
+                    expert_dim=self.expert_dim,
+                    expert_act=self.expert_act,
+                    normalize_gates=self.normalize_gates,
                     name="block_%d" % i,
+                    **shared,
                 )(x, training)
-                aux_total = aux_total + aux
+                balance = balance + aux["load_balancing"]
+                z_loss = z_loss + aux["router_z"]
+                if aux["routing"] is not None:
+                    routing.append(aux["routing"])
             else:
-                x = Block(
-                    self.num_heads,
-                    mlp_ratio=self.mlp_ratio,
-                    attention_impl=self.attention_impl,
-                    mesh=self.mesh,
-                    name="block_%d" % i,
+                x = wrap(Block)(
+                    self.num_heads, name="block_%d" % i, **shared
                 )(x, training)
-        x = nn.LayerNorm(name="ln_f")(x)
+        x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         logits = nn.Dense(
             self.vocab_size, use_bias=False, name="lm_head"
         )(x)
-        if training:
-            return {
-                "logits": logits,
-                "aux_loss": self.aux_loss_weight * aux_total,
-            }
-        return logits
+        if not training:
+            return logits
+        aux_loss = self.aux_loss_weight * balance
+        if self.z_loss_weight:
+            aux_loss = aux_loss + self.z_loss_weight * z_loss
+        outputs = {"logits": logits, "aux_loss": aux_loss}
+        if routing:
+            outputs["routing"] = merge_routing(routing)
+        return outputs
 
 
 # ---------------------------------------------------------------------------
@@ -222,14 +402,15 @@ class MoeTransformerLM(nn.Module):
 def moe_sharding_rules():
     """Dense-block rules plus expert weights over (ep, fsdp/tp).
 
-    w_up (E, M, F): experts over ep, FFN dim over tp (Megatron within
-    the expert); w_down (E, F, M) transposed to match. The router stays
+    w_up and, in a SwiGLU expert, w_gate (E, M, F): experts over ep,
+    FFN dim over tp (Megatron within the expert); w_down (E, F, M)
+    transposed to match. The router stays
     replicated — it is tiny and on the critical path of every token.
     """
     return ShardingRules(
         rules=[
             (r"router/kernel$", P()),
-            (r"w_up$", P("ep", "fsdp", "tp")),
+            (r"w_(gate|up)$", P("ep", "fsdp", "tp")),
             (r"w_down$", P("ep", "tp", "fsdp")),
             (r"(query|key|value)/kernel$", P("fsdp", "tp", None)),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),

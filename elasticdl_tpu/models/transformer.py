@@ -67,22 +67,47 @@ def rotary_embedding(x, base=10000.0, seq_axis=2):
     ).astype(x.dtype)
 
 
+def make_norm(kind, eps, name):
+    """The block's normalisation by name: ``layernorm`` (scale and
+    bias, the GPT-NeoX block's) or ``rmsnorm`` (scale only, OLMoE's).
+    Both compute their statistics in float32."""
+    if kind == "layernorm":
+        return nn.LayerNorm(epsilon=eps, name=name)
+    if kind == "rmsnorm":
+        return nn.RMSNorm(epsilon=eps, name=name)
+    raise ValueError(
+        "norm must be 'layernorm' or 'rmsnorm', got %r" % (kind,)
+    )
+
+
 class Attention(nn.Module):
     num_heads: int
     attention_impl: str = "auto"  # auto | xla | pallas | ring | ulysses
     mesh: Optional[Any] = None
     dropout: float = 0.0
+    # RMSNorm over the WHOLE query and key projections (all heads
+    # together, one scale of the model's width each) before the heads
+    # are split and rotated: OLMoE's QK-norm (arXiv:2409.02060, 4.2.5)
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
         head_dim = dim // self.num_heads
-        dense = lambda name: nn.DenseGeneral(
-            (self.num_heads, head_dim),
-            axis=-1,
-            use_bias=False,
-            name=name,
-        )
+
+        def dense(name, norm=None):
+            out = nn.DenseGeneral(
+                (self.num_heads, head_dim),
+                axis=-1,
+                use_bias=False,
+                name=name,
+            )(x)
+            if self.qk_norm and norm:
+                out = nn.RMSNorm(epsilon=self.norm_eps, name=norm)(
+                    out.reshape(x.shape)
+                ).reshape(out.shape)
+            return out
         # (B, S, H, d) -> (B, H, S, d). A transpose-free path exists
         # (dot_product_attention(layout="bshd") — the flash kernel can
         # address heads as lane-aligned blocks of the fused minor dim)
@@ -99,9 +124,9 @@ class Attention(nn.Module):
         to_bhsd = lambda t: constrain(
             t.transpose(0, 2, 1, 3), self.mesh, spec
         )
-        q = to_bhsd(dense("query")(x))
-        k = to_bhsd(dense("key")(x))
-        v = to_bhsd(dense("value")(x))
+        q = to_bhsd(dense("query", "q_norm"))
+        k = to_bhsd(dense("key", "k_norm"))
+        v = to_bhsd(dense("value"))
         q = rotary_embedding(q)
         k = rotary_embedding(k)
 
@@ -131,20 +156,25 @@ class Block(nn.Module):
     attention_impl: str = "auto"
     mesh: Optional[Any] = None
     dropout: float = 0.0
+    norm: str = "layernorm"
+    norm_eps: float = 1e-6
+    qk_norm: bool = False
 
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
-        h = nn.LayerNorm(name="ln_attn")(x)
+        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
         x = x + Attention(
             self.num_heads,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             dropout=self.dropout,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
             name="attn",
         )(h, training)
-        h = nn.LayerNorm(name="ln_mlp")(x)
+        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
         h = nn.Dense(dim * self.mlp_ratio, use_bias=False, name="mlp_up")(h)
         h = constrain(h, self.mesh, HIDDEN_SPEC)
         h = nn.gelu(h)
@@ -152,6 +182,65 @@ class Block(nn.Module):
         if self.dropout:
             h = nn.Dropout(self.dropout, deterministic=not training)(h)
         return constrain(x + h, self.mesh, RESIDUAL_SPEC)
+
+
+def remat_block(block_cls, remat_policy, attention_impl):
+    """``block_cls`` under per-block rematerialization (jax.checkpoint)
+    with the policy of that name; shared by the dense and the MoE LM.
+
+    "full" recomputes everything. "dots" saves matmul outputs and
+    recomputes only elementwise work; it also saves the flash kernel's
+    (o, lse) named outputs, without which remat re-runs the forward
+    flash pass inside every block's backward (flash_attention.py
+    "custom_vjp wrapper" note), and the sorted MoE path's named values
+    (ops/moe.py ``MOE_SAVE_NAMES``: the grouped matmuls' outputs and
+    the routing, which are no ``dot_general`` and would otherwise be
+    recomputed). "flash" saves ONLY the flash kernel's named outputs:
+    the projections/mlp recompute like "full", but the O(S^2)
+    attention forward never re-runs, the middle ground for lengths
+    where "dots" exceeds HBM (docs/PERF_TRANSFORMER.md, S=16k)."""
+    import jax
+
+    from elasticdl_tpu.ops.flash_attention import (
+        FLASH_LSE_NAME,
+        FLASH_OUT_NAME,
+    )
+    from elasticdl_tpu.ops.moe import MOE_SAVE_NAMES
+
+    if remat_policy not in ("full", "dots", "flash"):
+        raise ValueError(
+            "remat_policy must be 'full', 'dots' or 'flash', "
+            "got %r" % (remat_policy,)
+        )
+    if remat_policy == "dots":
+        policy = jax.checkpoint_policies.save_from_both_policies(
+            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+            jax.checkpoint_policies.save_only_these_names(
+                FLASH_OUT_NAME, FLASH_LSE_NAME, *MOE_SAVE_NAMES
+            ),
+        )
+    elif remat_policy == "flash":
+        # only the pallas flash kernel tags its outputs with these
+        # checkpoint_names (flash_attention.py:522-523); under any
+        # other attention impl the policy would match nothing and
+        # silently degrade to "full" — reject the contradiction
+        # instead. "auto" stays allowed: it resolves to pallas on TPU
+        # (the regime this policy exists for) and its CPU fallback to
+        # xla is the documented degradation for tests.
+        if attention_impl not in ("auto", "pallas"):
+            raise ValueError(
+                'remat_policy="flash" saves the pallas flash '
+                "kernel's named outputs; attention_impl=%r "
+                "never produces them (the policy would match "
+                "nothing and degrade to \"full\")"
+                % (attention_impl,)
+            )
+        policy = jax.checkpoint_policies.save_only_these_names(
+            FLASH_OUT_NAME, FLASH_LSE_NAME
+        )
+    else:
+        policy = None
+    return nn.remat(block_cls, static_argnums=(2,), policy=policy)
 
 
 class TransformerLM(nn.Module):
@@ -183,57 +272,9 @@ class TransformerLM(nn.Module):
         )(tokens.astype(jnp.int32))
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
         if self.remat:
-            import jax
-
-            from elasticdl_tpu.ops.flash_attention import (
-                FLASH_LSE_NAME,
-                FLASH_OUT_NAME,
+            block_cls = remat_block(
+                Block, self.remat_policy, self.attention_impl
             )
-
-            if self.remat_policy not in ("full", "dots", "flash"):
-                raise ValueError(
-                    "remat_policy must be 'full', 'dots' or 'flash', "
-                    "got %r" % (self.remat_policy,)
-                )
-            # "dots" also saves the flash kernel's (o, lse) named
-            # outputs: without them remat re-runs the forward flash
-            # pass inside every block's backward (flash_attention.py
-            # "custom_vjp wrapper" note). "flash" saves ONLY those
-            # named outputs — the projections/mlp recompute like
-            # "full", but the O(S^2) attention forward never re-runs —
-            # the middle ground for lengths where "dots" exceeds HBM
-            # (docs/PERF_TRANSFORMER.md, S=16k).
-            if self.remat_policy == "dots":
-                policy = jax.checkpoint_policies.save_from_both_policies(
-                    jax.checkpoint_policies
-                    .dots_with_no_batch_dims_saveable,
-                    jax.checkpoint_policies.save_only_these_names(
-                        FLASH_OUT_NAME, FLASH_LSE_NAME
-                    ),
-                )
-            elif self.remat_policy == "flash":
-                # only the pallas flash kernel tags its outputs with
-                # these checkpoint_names (flash_attention.py:522-523);
-                # under any other attention impl the policy would match
-                # nothing and silently degrade to "full" — reject the
-                # contradiction instead. "auto" stays allowed: it
-                # resolves to pallas on TPU (the regime this policy
-                # exists for) and its CPU fallback to xla is the
-                # documented degradation for tests.
-                if self.attention_impl not in ("auto", "pallas"):
-                    raise ValueError(
-                        'remat_policy="flash" saves the pallas flash '
-                        "kernel's named outputs; attention_impl=%r "
-                        "never produces them (the policy would match "
-                        "nothing and degrade to \"full\")"
-                        % (self.attention_impl,)
-                    )
-                policy = jax.checkpoint_policies.save_only_these_names(
-                    FLASH_OUT_NAME, FLASH_LSE_NAME
-                )
-            else:
-                policy = None
-            block_cls = nn.remat(Block, static_argnums=(2,), policy=policy)
         else:
             block_cls = Block
         for i in range(self.num_layers):

@@ -219,6 +219,13 @@ EVENT_TYPES = frozenset({
                              #   state_init, restore, first_step, ...})
     "worker_teardown",       # from the last exit hook (+ wall_ns,
                              #   phases{drain, teardown, exit, other})
+    "moe_routing",           # every --log_loss_steps steps of a model
+                             #   whose step returns routing counters
+                             #   (the sorted MoE dispatch), read with
+                             #   the logged loss (+ step,
+                             #   tokens_per_expert_max and _mean over
+                             #   the expert layers, router_entropy in
+                             #   nats, dropped_pairs)
 })
 
 

@@ -1,10 +1,12 @@
-"""Mixture-of-experts routing: top-k router with static capacity.
+"""Mixture-of-experts routing and dispatch: two formulations.
 
 No reference counterpart (SURVEY.md §2.12: expert parallelism is absent
-from the reference); this is a new TPU-first capability. The design is
-the GShard/Switch dispatch formulation expressed entirely as static-shape
-einsums so XLA can lay expert compute out over an ``ep`` mesh axis and
-insert the all-to-alls itself:
+from the reference); this is a new TPU-first capability.
+
+**One-hot, with a static capacity** (``top_k_routing``, ``moe_dispatch``,
+``moe_combine``): the GShard/Switch formulation expressed entirely as
+static-shape einsums so XLA can lay expert compute out over an ``ep``
+mesh axis and insert the all-to-alls itself:
 
 - every token picks its top-k experts from router logits;
 - each expert has a fixed per-group capacity C (static shape!), tokens
@@ -13,14 +15,37 @@ insert the all-to-alls itself:
 - dispatch/combine are (G, S, E, C) tensors contracted against the token
   stream, so "send token to expert" is an einsum — exactly the shape
   GSPMD turns into an all-to-all when tokens are dp-sharded and experts
-  ep-sharded.
+  ep-sharded. Those einsums cost (2/3) x (S / expert width) of the
+  expert layer's own FLOPs, which is why the other formulation exists.
 
-Everything is shape-static and jit-friendly: k is a Python int (unrolled
-loop), capacity is computed from static dims.
+**Sorted, dropless** (``route_top_k``, ``sort_by_expert``,
+``dispatch_sorted``, ``grouped_matmul``, ``combine_sorted``): no
+capacity and no one-hot. The (token, choice) pairs are ordered by
+expert, the rows gathered in that order, each expert multiplies its own
+contiguous group of rows (a grouped matmul over ragged groups), and the
+rows are gathered back and summed under their gates. Every token
+reaches exactly ``k`` experts. Dispatch and combine are permutations,
+so their backward passes are gathers too (custom VJPs below): nothing
+on this path is a scatter, and nothing costs a matmul FLOP that the
+experts themselves do not need. One device's tokens only: there is no
+``ep`` all-to-all on this path yet (ROADMAP.md Reach 2).
+
+Everything is shape-static and jit-friendly: k is a Python int, the
+sorted path's only data-dependent quantity is ``group_sizes``, an
+(E,) array that the grouped matmul takes as an operand.
 """
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+# checkpoint_names of the sorted path: the routing (a few integers a
+# token) and the outputs of the three grouped matmuls. A remat policy
+# that saves matmul outputs names these, because a grouped matmul is no
+# ``dot_general`` (models/transformer.py:remat_block).
+MOE_ROUTE_NAME = "moe_route"
+MOE_MATMUL_NAME = "moe_expert_matmul"
+MOE_SAVE_NAMES = (MOE_ROUTE_NAME, MOE_MATMUL_NAME)
 
 
 def expert_capacity(seq_len, num_experts, k=1, capacity_factor=1.25):
@@ -88,228 +113,6 @@ def top_k_routing(router_logits, k, capacity):
     return combine, dispatch, aux_loss
 
 
-def top_k_routing_compact(router_logits, k, capacity):
-    """Slot-index routing: the same assignment policy as
-    ``top_k_routing`` (choice-rank-major priority, cumsum order within
-    a rank, capacity overflow dropped) but WITHOUT materializing the
-    (G, S, E, C) one-hot tensors — it returns flat slot ids instead.
-
-    The on-chip trace of the einsum formulation
-    (docs/traces/moe_v5e_summary.txt) showed the one-hot dispatch/
-    combine einsums and their (G, S, E, C) operands dragging the
-    matmul-fusion bandwidth to 404 GB/s; this form replaces them with
-    O(S·k) index arithmetic so dispatch/combine become gathers.
-
-    Returns:
-      gates: (G, k, S) float32, rank-major combine weights (zero is
-        NOT forced for dropped tokens — the combine gather reads a
-        zero row for them instead).
-      slot: (G, k*S) int32 — flat ``expert * capacity + position``
-        slot id per (rank, token), rank-major; dropped tokens get the
-        out-of-range id ``E * capacity`` (the zero-pad row).
-      aux_loss: identical to ``top_k_routing``.
-    """
-    num_groups, seq, num_experts = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gates, indices = jax.lax.top_k(probs, k)  # (G, S, k)
-    gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-9)
-
-    first_choice = jax.nn.one_hot(indices[..., 0], num_experts)
-    tokens_per_expert = first_choice.mean(axis=(0, 1))
-    prob_per_expert = probs.mean(axis=(0, 1))
-    aux_loss = num_experts * jnp.sum(tokens_per_expert * prob_per_expert)
-
-    # Rank-major flat order (rank 0 of every token precedes rank 1, and
-    # within a rank earlier tokens win) — the priority top_k_routing's
-    # per-rank cumsum loop implements. Position = number of prior
-    # assignments to the same expert in this order; counting dropped
-    # priors too is equivalent (a prior overflow forces >= C either
-    # way), so no per-rank clamped-occupancy carry is needed.
-    e_flat = indices.transpose(0, 2, 1).reshape(
-        num_groups, k * seq
-    )  # (G, kS)
-    onehot = jax.nn.one_hot(e_flat, num_experts, dtype=jnp.int32)
-    prior = jnp.cumsum(onehot, axis=1) - onehot  # (G, kS, E)
-    position = jnp.take_along_axis(
-        prior, e_flat[:, :, None], axis=2
-    )[..., 0]  # (G, kS)
-    slot = jnp.where(
-        position < capacity,
-        e_flat * capacity + position,
-        num_experts * capacity,
-    ).astype(jnp.int32)
-    return gates.transpose(0, 2, 1), slot, aux_loss
-
-
-def invert_slots(slot, n_slots):
-    """(G, kS) slot ids → (G, n_slots) flat FILLER index per slot
-    (sentinel kS for empty slots). Valid slot ids are unique by
-    construction; only the dummy slot n_slots collides, and that
-    column is sliced off. This tiny int32 scatter is the ONLY scatter
-    in the compact formulation — because the slot mapping is
-    invertible, every M-wide data movement (including both autodiff
-    backwards, see the custom VJPs below) is a gather, which the TPU
-    streams at memory bandwidth where XLA's scatter-add lowering was
-    measured at 93 GB/s (docs/PERF_MOE.md trace)."""
-    num_groups, flat = slot.shape
-    j_ids = jnp.broadcast_to(
-        jnp.arange(flat, dtype=jnp.int32), (num_groups, flat)
-    )
-    j_for_slot = jnp.full(
-        (num_groups, n_slots + 1), flat, dtype=jnp.int32
-    )
-    return j_for_slot.at[
-        jnp.arange(num_groups)[:, None], slot
-    ].set(j_ids)[:, :n_slots]
-
-
-@jax.custom_vjp
-def _dispatch_gather(x, slot, j_for_slot):
-    num_groups, seq, dim = x.shape
-    flat = slot.shape[1]
-    token = jnp.where(j_for_slot == flat, seq, j_for_slot % seq)
-    x_pad = jnp.concatenate(
-        [x, jnp.zeros((num_groups, 1, dim), x.dtype)], axis=1
-    )
-    return jnp.take_along_axis(
-        x_pad, token[:, :, None], axis=1
-    )  # (G, E*C, M)
-
-
-def _dispatch_gather_fwd(x, slot, j_for_slot):
-    return _dispatch_gather(x, slot, j_for_slot), (slot, x.shape)
-
-
-def _dispatch_gather_bwd(res, d_out):
-    """dx[g,s] = Σ_r d_out[g, slot[g, r·S+s]] — a GATHER through the
-    forward index (dropped ranks hit the zero pad row), where plain
-    autodiff of take_along_axis would emit a scatter-add."""
-    slot, (num_groups, seq, dim) = res
-    k = slot.shape[1] // seq
-    d_out_pad = jnp.concatenate(
-        [d_out, jnp.zeros((num_groups, 1, dim), d_out.dtype)], axis=1
-    )
-    rows = jnp.take_along_axis(d_out_pad, slot[:, :, None], axis=1)
-    dx = rows.reshape(num_groups, k, seq, dim).sum(axis=1)
-    return (dx, None, None)
-
-
-_dispatch_gather.defvjp(_dispatch_gather_fwd, _dispatch_gather_bwd)
-
-
-def moe_dispatch_compact(x, slot, num_experts, capacity,
-                         j_for_slot=None):
-    """Token stream → per-expert buffers via an inverse-permutation
-    gather (no (G, S, E, C) one-hot, no dispatch matmul FLOPs).
-
-    x: (G, S, M); slot: (G, k*S) from ``top_k_routing_compact``
-    → (E, G, C, M). Same semantics as ``moe_dispatch(x, dispatch)``:
-    a slot holds its token's embedding, empty slots are zero.
-    ``j_for_slot``: pass ``invert_slots(slot, E*C)`` when the caller
-    also combines (MoeMlp does) so the inversion scatter runs once.
-    """
-    num_groups, _, dim = x.shape
-    if j_for_slot is None:
-        j_for_slot = invert_slots(slot, num_experts * capacity)
-    out = _dispatch_gather(x, slot, j_for_slot)
-    return out.reshape(
-        num_groups, num_experts, capacity, dim
-    ).transpose(1, 0, 2, 3)
-
-
-@jax.custom_vjp
-def _combine_gather(eo_flat, gates, slot, j_for_slot):
-    """eo_flat: (G, E*C, M); gates: (G, k, S) → y (G, S, M)."""
-    num_groups, _, dim = eo_flat.shape
-    k = gates.shape[1]
-    seq = slot.shape[1] // k
-    eo_pad = jnp.concatenate(
-        [eo_flat, jnp.zeros((num_groups, 1, dim), eo_flat.dtype)],
-        axis=1,
-    )
-    rows = jnp.take_along_axis(eo_pad, slot[:, :, None], axis=1)
-    rows = rows.reshape(num_groups, k, seq, dim)
-    return (rows * gates[..., None].astype(rows.dtype)).sum(axis=1)
-
-
-def _combine_gather_fwd(eo_flat, gates, slot, j_for_slot):
-    return (
-        _combine_gather(eo_flat, gates, slot, j_for_slot),
-        (eo_flat, gates, slot, j_for_slot),
-    )
-
-
-def _combine_gather_bwd(res, dy):
-    """Both cotangents are gathers:
-    d_eo[g,n] = gate_of_filler(n) · dy[g, token_of_filler(n)] (each
-    slot has at most ONE filler — the inverse index j_for_slot), and
-    d_gates[g,r,s] = <dy[g,s], eo[g, slot[g,r·S+s]]> (re-gather of the
-    forward rows). Plain autodiff would scatter-add gate-weighted dy
-    rows into the expert buffers instead."""
-    eo_flat, gates, slot, j_for_slot = res
-    num_groups, _, dim = eo_flat.shape
-    k = gates.shape[1]
-    flat = slot.shape[1]
-    seq = flat // k
-
-    # d_gates: recompute the forward row gather (cheap; saves keeping
-    # the (G, kS, M) rows tensor alive as a residual)
-    eo_pad = jnp.concatenate(
-        [eo_flat, jnp.zeros((num_groups, 1, dim), eo_flat.dtype)],
-        axis=1,
-    )
-    rows = jnp.take_along_axis(eo_pad, slot[:, :, None], axis=1)
-    rows = rows.reshape(num_groups, k, seq, dim)
-    d_gates = (
-        rows.astype(jnp.float32) * dy[:, None].astype(jnp.float32)
-    ).sum(axis=-1).astype(gates.dtype)
-
-    # d_eo: gather dy by each slot's filler token, weighted by the
-    # filler's gate (empty slots: sentinel j = kS hits the zero pads)
-    token = jnp.where(j_for_slot == flat, seq, j_for_slot % seq)
-    dy_pad = jnp.concatenate(
-        [dy, jnp.zeros((num_groups, 1, dim), dy.dtype)], axis=1
-    )
-    gate_flat_pad = jnp.concatenate(
-        [
-            gates.reshape(num_groups, flat),
-            jnp.zeros((num_groups, 1), gates.dtype),
-        ],
-        axis=1,
-    )
-    d_rows = jnp.take_along_axis(dy_pad, token[:, :, None], axis=1)
-    gate_for_slot = jnp.take_along_axis(
-        gate_flat_pad, j_for_slot, axis=1
-    )
-    d_eo = (
-        d_rows * gate_for_slot[:, :, None].astype(d_rows.dtype)
-    ).astype(eo_flat.dtype)
-    return (d_eo, d_gates, None, None)
-
-
-_combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
-
-
-def moe_combine_compact(expert_out, slot, gates, j_for_slot=None):
-    """Per-expert buffers → token stream: gather each (rank, token)'s
-    slot row back and sum over ranks weighted by the gates.
-
-    expert_out: (E, G, C, M); slot: (G, k*S); gates: (G, k, S)
-    → (G, S, M). Dropped tokens point at the zero pad row, so their
-    contribution is zero — identical to ``moe_combine``'s zero combine
-    weights (including the zero gate-gradient for dropped tokens:
-    d(gate) = <dy, zero row> = 0 on both paths). ``j_for_slot`` as in
-    ``moe_dispatch_compact``.
-    """
-    num_experts, num_groups, capacity, dim = expert_out.shape
-    eo_flat = expert_out.transpose(1, 0, 2, 3).reshape(
-        num_groups, num_experts * capacity, dim
-    )
-    if j_for_slot is None:
-        j_for_slot = invert_slots(slot, num_experts * capacity)
-    return _combine_gather(eo_flat, gates, slot, j_for_slot)
-
-
 def moe_dispatch(x, dispatch):
     """Token stream → per-expert buffers.
 
@@ -330,3 +133,201 @@ def moe_combine(expert_out, combine):
     return jnp.einsum(
         "gsec,egcm->gsm", combine.astype(expert_out.dtype), expert_out
     )
+
+
+# ---------------------------------------------------------------------
+# the sorted, dropless formulation
+
+
+def route_top_k(router_logits, k, normalize=False):
+    """Token-choice routing without a capacity.
+
+    router_logits: (T, E), any float dtype. The softmax and the top-k
+    run in float32 whatever the logits' dtype (the published OLMoE
+    implementation's ``softmax(..., dtype=float)``).
+
+    Returns ``(gates, experts, probs)``: (T, k) float32 gate values
+    (the chosen experts' probabilities; renormalised to sum to one only
+    under ``normalize``), (T, k) int32 expert ids, (T, E) float32
+    probabilities."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    gates, experts = jax.lax.top_k(probs, k)
+    if normalize:
+        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-9)
+    gates, experts = checkpoint_name(
+        (gates, experts.astype(jnp.int32)), MOE_ROUTE_NAME
+    )
+    return gates, experts, probs
+
+
+def sort_by_expert(experts, num_experts):
+    """The permutation that groups the (token, choice) pairs by expert.
+
+    experts: (T, k) int32. Pair ``p = t * k + j`` is token t's j-th
+    choice. Returns
+
+    - ``order`` (T*k,): the pair at each sorted position (stable, so a
+      group keeps its tokens in order);
+    - ``inverse`` (T*k,): the sorted position of each pair;
+    - ``group_sizes`` (E,) int32: pairs per expert, in expert order;
+      they sum to T*k, whatever the routing."""
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    # the inverse of a permutation by a second sort: no scatter
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    # a compare fused into its reduction, not a stored one-hot
+    group_sizes = jnp.sum(
+        flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype)[None],
+        axis=0, dtype=jnp.int32,
+    )
+    return checkpoint_name((order, inverse, group_sizes), MOE_ROUTE_NAME)
+
+
+@jax.custom_vjp
+def dispatch_sorted(x, order, inverse):
+    """x (T, M) -> (T*k, M): row s is the token of the pair at sorted
+    position s, so expert e's rows are contiguous."""
+    k = order.shape[0] // x.shape[0]
+    return jnp.take(x, order // k, axis=0)
+
+
+def _dispatch_sorted_fwd(x, order, inverse):
+    return dispatch_sorted(x, order, inverse), (inverse, x.shape[0])
+
+
+def _dispatch_sorted_bwd(res, d_rows):
+    """dx[t] = sum_j d_rows[inverse[t*k + j]]: a gather through the
+    inverse permutation, where plain autodiff of ``take`` would emit a
+    scatter-add."""
+    inverse, tokens = res
+    k = inverse.shape[0] // tokens
+    back = jnp.take(d_rows, inverse, axis=0)
+    dx = back.reshape(tokens, k, -1).astype(jnp.float32).sum(axis=1)
+    return dx.astype(d_rows.dtype), None, None
+
+
+dispatch_sorted.defvjp(_dispatch_sorted_fwd, _dispatch_sorted_bwd)
+
+
+def _gathered_back(rows, inverse, tokens):
+    return jnp.take(rows, inverse, axis=0).reshape(tokens, -1, rows.shape[-1])
+
+
+@jax.custom_vjp
+def combine_sorted(rows, gates, order, inverse):
+    """rows (T*k, M) in sorted order, gates (T, k) -> (T, M): each
+    token's k expert outputs, weighted by their gates and summed (in
+    float32, rounded once)."""
+    back = _gathered_back(rows, inverse, gates.shape[0])
+    y = (back.astype(jnp.float32) * gates[..., None]).sum(axis=1)
+    return y.astype(rows.dtype)
+
+
+def _combine_sorted_fwd(rows, gates, order, inverse):
+    return (
+        combine_sorted(rows, gates, order, inverse),
+        (rows, gates, order, inverse),
+    )
+
+
+def _combine_sorted_bwd(res, dy):
+    """Both cotangents are gathers: d_rows[s] = gate(order[s]) x
+    dy[token(order[s])], and d_gates[t, j] = <dy[t], rows[inverse[t*k +
+    j]]> over a re-gather of the forward rows (cheaper than keeping the
+    (T, k, M) copy alive)."""
+    rows, gates, order, inverse = res
+    tokens, k = gates.shape
+    back = _gathered_back(rows, inverse, tokens)
+    d_gates = jnp.einsum(
+        "tkm,tm->tk", back.astype(jnp.float32), dy.astype(jnp.float32)
+    ).astype(gates.dtype)
+    gate_of = jnp.take(gates.reshape(-1), order)
+    d_rows = (
+        jnp.take(dy, order // k, axis=0).astype(jnp.float32)
+        * gate_of[:, None]
+    ).astype(rows.dtype)
+    return d_rows, d_gates, None, None
+
+
+combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
+
+
+# (rows, K, N) tile of the Pallas grouped matmul: the largest that fits
+# the v5e's 16 MB of scoped VMEM at bfloat16 (1024 rows, or 2048 of K or
+# N, are refused by the TPU compiler); in the OLMoE step it ran 3%
+# ahead of (512, 512, 1024) (PERF.md, PR 25).
+GMM_TILING = (512, 1024, 1024)
+
+
+def resolve_grouped_matmul(num_rows, dtype, one_device=True):
+    """``"pallas_gmm"`` or ``"ragged_dot"`` for a grouped matmul over
+    ``num_rows`` rows of ``dtype``. The Pallas kernel takes bfloat16
+    rows in whole row tiles on a TPU, and like every ``pallas_call`` it
+    cannot be partitioned automatically: on a mesh of several devices
+    the caller would have to run it inside a ``shard_map``, which the
+    sorted path does not do yet (ROADMAP.md Reach 2)."""
+    fits = (
+        one_device
+        and jax.default_backend() == "tpu"
+        and dtype == jnp.bfloat16
+        and num_rows % GMM_TILING[0] == 0
+    )
+    return "pallas_gmm" if fits else "ragged_dot"
+
+
+def grouped_matmul(rows, weights, group_sizes, one_device=True):
+    """rows (N, K) grouped contiguously, weights (E, K, F),
+    group_sizes (E,) summing to N -> (N, F): group e of the rows times
+    ``weights[e]``.
+
+    Two implementations, chosen from what the call can see. On one TPU
+    device, for bfloat16 rows in whole tiles: the Pallas grouped matmul
+    that ships with jax (``megablox.gmm``, with ``tgmm`` for the
+    kernels' gradient through its custom VJP). Otherwise
+    ``jax.lax.ragged_dot``, which XLA compiles to Mosaic kernels of its
+    own on a TPU and to plain dots on the CPU. Timed in the OLMoE cell's
+    step on a v5e, not alone: 31.9 samples/s against 29.1 (PERF.md,
+    PR 25)."""
+    impl = resolve_grouped_matmul(rows.shape[0], rows.dtype, one_device)
+    if impl == "pallas_gmm":
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        out = gmm(
+            rows, weights, group_sizes,
+            preferred_element_type=rows.dtype, tiling=GMM_TILING,
+        )
+    else:
+        out = jax.lax.ragged_dot(rows, weights, group_sizes)
+    return checkpoint_name(out, MOE_MATMUL_NAME)
+
+
+def load_balancing_loss(probs, group_sizes):
+    """E x sum_e f_e P_e (OLMoE, arXiv:2409.02060 eq. 3; Switch's form
+    over all k choices): f_e the share of the tokens that chose expert
+    e among their k (so the f sum to k), P_e the mean router
+    probability of e. 1 x k for a uniform router."""
+    tokens, num_experts = probs.shape
+    share = group_sizes.astype(jnp.float32) / tokens
+    return num_experts * jnp.sum(share * probs.mean(axis=0))
+
+
+def router_z_loss(router_logits):
+    """Mean over tokens of logsumexp(logits)^2 (ST-MoE, arXiv:2202.08906
+    eq. 5; OLMoE eq. 4), in float32."""
+    z = jax.nn.logsumexp(router_logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(jnp.square(z))
+
+
+def routing_stats(probs, group_sizes, k):
+    """What the ``moe_routing`` journal event reports of one expert
+    layer, as device scalars: pairs per expert (largest and mean), the
+    router's mean entropy in nats, and the pairs that reached no expert
+    (counted from the group sizes; the sorted path drops none)."""
+    tokens = probs.shape[0]
+    entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1).mean()
+    return {
+        "load_max": group_sizes.max().astype(jnp.float32),
+        "load_mean": group_sizes.astype(jnp.float32).mean(),
+        "entropy": entropy,
+        "dropped": (tokens * k - group_sizes.sum()).astype(jnp.float32),
+    }

@@ -53,6 +53,14 @@ def guard_nonfinite_state(old_state, new_state, nonfinite):
     )
 
 
+def _routing_of(outputs):
+    """The expert layers' routing counters where a model's training
+    outputs carry them (``models/moe_transformer.py``), else None: an
+    empty pytree, so a model without them compiles the program it
+    compiled before."""
+    return outputs.get("routing") if isinstance(outputs, dict) else None
+
+
 def _apply_model(model, params, model_state, features, training, rngs):
     variables = {"params": params, **model_state}
     if model_state:
@@ -84,6 +92,11 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
     flag); with ``guard_nonfinite`` a nonfinite batch keeps the
     previous state in-graph (the skip sentinel). ``health=False`` is
     the exact pre-health program: no extra outputs (test-asserted).
+    Where the model's training outputs carry ``"routing"`` (an MoE
+    LM's expert-load counters), the dict has them under ``"routing"``
+    as device scalars: they leave the step with the health scalars and
+    cost no fetch until someone reads them (the worker does on the
+    steps it logs).
 
     ``grad_accum_steps=k`` splits the batch into k equal microbatches
     scanned sequentially, accumulating MASK-WEIGHTED gradient sums and
@@ -99,8 +112,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         )
 
     def _loss_sum(params, model_state, features, labels, mask, rngs):
-        """(masked loss SUM, mask weight, new model state) — summed
-        (not averaged) so microbatch grads add linearly."""
+        """(masked loss SUM, (mask weight, new model state, routing
+        counters or None)) — summed (not averaged) so microbatch grads
+        add linearly."""
         compute_params = params
         compute_features = features
         if compute_dtype is not None:
@@ -127,7 +141,7 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 mask.shape[0], -1
             ).mean(axis=1)
             return jnp.sum(per_sample * mask), (
-                jnp.sum(mask), new_model_state
+                jnp.sum(mask), new_model_state, _routing_of(outputs)
             )
 
     def _apply_update(state, grads, loss, new_model_state):
@@ -162,10 +176,12 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
             )
         }
 
-        def finish(new_state, loss, grads):
+        def finish(new_state, loss, grads, routing):
             if not health:
                 return new_state, loss
             scalars = health_scalars(loss, global_grad_norm(grads))
+            if routing is not None:
+                scalars["routing"] = routing
             if guard_nonfinite:
                 new_state = guard_nonfinite_state(
                     state, new_state, scalars["nonfinite"]
@@ -174,21 +190,21 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
 
         if grad_accum_steps == 1:
             def compute_loss(params):
-                loss_sum, (weight, new_model_state) = _loss_sum(
+                loss_sum, (weight, new_model_state, routing) = _loss_sum(
                     params, state.model_state, features, labels, mask,
                     rngs,
                 )
                 return loss_sum / jnp.maximum(weight, 1.0), (
-                    new_model_state
+                    new_model_state, routing
                 )
 
-            (loss, new_model_state), grads = jax.value_and_grad(
+            (loss, (new_model_state, routing)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True
             )(state.params)
             new_state, loss = _apply_update(
                 state, grads, loss, new_model_state
             )
-            return finish(new_state, loss, grads)
+            return finish(new_state, loss, grads, routing)
 
         k = int(grad_accum_steps)
 
@@ -224,7 +240,7 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
             micro_rngs = {
                 "dropout": jax.random.fold_in(rngs["dropout"], i)
             }
-            (loss_sum, (weight, model_state)), grads = grad_fn(
+            (loss_sum, (weight, model_state, routing)), grads = grad_fn(
                 state.params, model_state, m_features, m_labels, m_mask,
                 micro_rngs,
             )
@@ -239,9 +255,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 weight_acc + weight,
                 model_state,
                 i + 1,
-            ), None
+            ), routing
 
-        (grads_sum, loss_sum, weight, new_model_state, _), _ = (
+        (grads_sum, loss_sum, weight, new_model_state, _), routing = (
             jax.lax.scan(
                 body,
                 (zero_grads, 0.0, 0.0, state.model_state, 0),
@@ -255,7 +271,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         new_state, loss = _apply_update(
             state, grads, loss_sum / weight, new_model_state
         )
-        return finish(new_state, loss, grads)
+        # the counters of the last microbatch stand for the step
+        routing = jax.tree_util.tree_map(lambda leaf: leaf[-1], routing)
+        return finish(new_state, loss, grads, routing)
 
     return train_step
 

@@ -832,12 +832,26 @@ class Worker:
             # nothing (SpmdTrainer) this is the loop's first read of a
             # device value and waits for the step; after JaxTrainer's
             # health fetch the value is already on the host
+            routing = getattr(self.trainer, "routing", None)
             with phase("device_wait"):
                 loss_value = float(loss)
+                if routing:
+                    # the expert layers' counters come with the loss,
+                    # on the steps that log and on no other
+                    routing = {k: float(v) for k, v in routing.items()}
             with phase("log"):
                 logger.info(
                     "step %d loss %.6f", self._version, loss_value
                 )
+                if routing:
+                    events.emit(
+                        "moe_routing",
+                        step=self._version,
+                        tokens_per_expert_max=routing["load_max"],
+                        tokens_per_expert_mean=routing["load_mean"],
+                        router_entropy=routing["entropy"],
+                        dropped_pairs=routing["dropped"],
+                    )
         with phase("callbacks"):
             for cb in self._callbacks:
                 cb.on_batch_end(self._version, loss)

@@ -37,13 +37,14 @@ def dense_flops(d, layers, seq, batch, vocab, mlp_ratio):
 
 
 def moe_flops(d, layers, seq, batch, vocab, mlp_ratio, num_experts, k,
-              capacity_factor, compact_dispatch):
+              capacity_factor, sorted_dispatch):
     """Exact matmul FLOPs of MoeTransformerLM: MoE FFN in every other
     block (models/moe_transformer.py), static capacity C per group.
 
-    The compact (slot-index gather) dispatch executes NO dispatch/
-    combine matmuls — those terms only exist on the one-hot einsum
-    path, so each arm's MFU divides by the FLOPs it actually runs."""
+    The sorted (dropless) dispatch executes NO dispatch/combine
+    matmuls and has no capacity: its experts compute tokens x k rows.
+    Those terms only exist on the one-hot einsum path, so each arm's
+    MFU divides by the FLOPs it actually runs."""
     from elasticdl_tpu.ops.moe import expert_capacity
 
     tokens = batch * seq
@@ -57,12 +58,13 @@ def moe_flops(d, layers, seq, batch, vocab, mlp_ratio, num_experts, k,
     # dense-block FFNs
     ffn_dense = 2 * tokens * (2 * mlp_ratio * d * d) * dense_layers
     # expert FFNs: every (expert, slot) computes, full or not
-    slots = batch * num_experts * capacity
+    slots = tokens * k if sorted_dispatch else (
+        batch * num_experts * capacity)
     ffn_moe = 2 * slots * (2 * d * ff) * moe_layers
     # router; dispatch/combine einsums (gsec,gsm->egcm and back) are
-    # matmuls only on the one-hot path — the compact path gathers
+    # matmuls only on the one-hot path — the sorted path gathers
     router = 2 * tokens * d * num_experts * moe_layers
-    if compact_dispatch:
+    if sorted_dispatch:
         dispatch = 0
     else:
         dispatch = (
@@ -166,9 +168,9 @@ def main():
     )
     p.add_argument(
         "--dispatch", default="auto",
-        choices=["auto", "compact", "onehot"],
-        help="MoE dispatch impl (auto = the one-hot einsums, the "
-             "measured default; compact = the slot-index gather path)",
+        choices=["auto", "onehot", "sorted"],
+        help="MoE dispatch impl (auto = the one-hot einsums with a "
+             "capacity; sorted = the dropless sort + grouped matmul)",
     )
     p.add_argument(
         "--profile", default=None,
@@ -196,15 +198,15 @@ def main():
         dispatch_impl=args.dispatch,
     )
     # "auto" resolves to the one-hot einsums (models/moe_transformer.py:
-    # the measured default); only an explicit --dispatch compact drops
+    # the legacy default); only an explicit --dispatch sorted drops
     # the dispatch-einsum FLOPs from the count
-    compact = args.dispatch == "compact"
+    sorted_dispatch = args.dispatch == "sorted"
     moe = run_arm(
         moe_model,
         moe_transformer.loss,
         moe_flops(args.d, args.layers, args.seq, args.batch, args.vocab,
                   args.mlp_ratio, args.experts, args.top_k,
-                  args.capacity_factor, compact),
+                  args.capacity_factor, sorted_dispatch),
         batch_tokens,
         args,
         profile_dir=args.profile,

@@ -69,8 +69,6 @@ SLOW_BY_DURATION = {
         "test_expert_parallel_matches_single_device",
         "test_expert_balance_holds_over_a_real_run",
         "test_moe_eval_returns_bare_logits",
-        "test_moe_lm_compact_matches_onehot_losses",
-        "test_compact_dispatch_under_dp_mesh_matches_single_device",
     ),
     "test_sparse_spmd.py": (
         "test_sparse_spmd_matches_single_device",

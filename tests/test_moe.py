@@ -3,7 +3,10 @@
 Correctness ladder mirroring the transformer SPMD tests: (1) routing
 invariants, (2) dispatch/combine against a brute-force per-token loop,
 (3) the MoE LM trained GSPMD-sharded over a dp x tp x ep mesh matches
-single-device losses.
+single-device losses, (4) the sorted dropless dispatch: its invariants,
+its gradients against a brute-force loop, against the one-hot dispatch
+where that drops nothing, in the LM, under a dp mesh, and what it must
+never hold (a capacity, a one-hot) or do (fall back on an ep mesh).
 """
 
 import os
@@ -16,6 +19,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 from elasticdl_tpu.models import moe_transformer
+from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.ops.moe import (
     expert_capacity,
     moe_combine,
@@ -226,101 +230,187 @@ def test_expert_balance_holds_over_a_real_run():
     assert result["max_expert_share"] < 0.4
 
 
-def test_compact_dispatch_matches_onehot():
-    """The slot-index (gather) dispatch must be semantically identical
-    to the one-hot einsum dispatch — outputs AND gradients — including
-    when capacity drops tokens."""
-    from elasticdl_tpu.ops.moe import (
-        moe_combine_compact,
-        moe_dispatch_compact,
-        top_k_routing_compact,
+# ---------------------------------------------------------------------
+# the sorted, dropless dispatch
+
+
+def _sorted_path(x, logits, weights, k, normalize=False):
+    """Tokens (T, M) through ``weights`` (E, M, N) experts, sorted."""
+    gates, experts, _ = moe_ops.route_top_k(logits, k, normalize)
+    order, inverse, sizes = moe_ops.sort_by_expert(experts, weights.shape[0])
+    rows = moe_ops.dispatch_sorted(x, order, inverse)
+    out = moe_ops.grouped_matmul(rows, weights, sizes)
+    return moe_ops.combine_sorted(out, gates, order, inverse)
+
+
+def _loop_path(x, logits, weights, k):
+    """The same by a loop over tokens' choices: every expert computes
+    every token, each token picks its k."""
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, experts = jax.lax.top_k(probs, k)
+    every = jnp.einsum("tm,emn->ten", x, weights)
+    return sum(
+        gates[:, j, None]
+        * jnp.take_along_axis(every, experts[:, j, None, None], 1)[:, 0]
+        for j in range(k)
     )
 
+
+@pytest.mark.parametrize("tokens, experts, k", [(40, 4, 2), (33, 8, 3),
+                                                (16, 8, 8)])
+def test_sorted_dispatch_invariants(tokens, experts, k):
+    """Every token is in exactly k groups, the group sizes sum to
+    tokens x k whatever the routing, groups are contiguous and in expert
+    order, and combine(dispatch(x)) under unit gates is k x identity."""
+    rng = np.random.RandomState(tokens)
+    # a skewed router: some experts get most tokens, some may get none
+    logits = jnp.asarray(
+        rng.randn(tokens, experts) * 3 + np.linspace(2, -2, experts),
+        jnp.float32)
+    x = jnp.asarray(rng.randn(tokens, 5), jnp.float32)
+    gates, chosen, _ = moe_ops.route_top_k(logits, k)
+    order, inverse, sizes = moe_ops.sort_by_expert(chosen, experts)
+    order, inverse, sizes = map(np.asarray, (order, inverse, sizes))
+    assert sizes.sum() == tokens * k
+    np.testing.assert_array_equal(
+        sizes, np.bincount(np.asarray(chosen).ravel(), minlength=experts))
+    np.testing.assert_array_equal(np.sort(order), np.arange(tokens * k))
+    np.testing.assert_array_equal(inverse[order], np.arange(tokens * k))
+    # each token appears k times among the sorted rows
+    np.testing.assert_array_equal(
+        np.bincount(order // k, minlength=tokens), np.full(tokens, k))
+    # the sorted pairs' experts are non-decreasing: groups are contiguous
+    sorted_experts = np.asarray(chosen).ravel()[order]
+    assert (np.diff(sorted_experts) >= 0).all()
+    rows = moe_ops.dispatch_sorted(x, order, inverse)
+    np.testing.assert_array_equal(
+        np.asarray(rows), np.asarray(x)[order // k])
+    back = moe_ops.combine_sorted(
+        rows, jnp.ones_like(gates), order, inverse)
+    np.testing.assert_allclose(np.asarray(back), k * np.asarray(x), rtol=1e-6)
+    stats = moe_ops.routing_stats(
+        jax.nn.softmax(logits), jnp.asarray(sizes), k)
+    assert float(stats["dropped"]) == 0.0
+    assert float(stats["load_max"]) == sizes.max()
+    assert float(stats["load_mean"]) == pytest.approx(tokens * k / experts)
+
+
+def test_sorted_dispatch_matches_bruteforce_with_gradients():
+    rng = np.random.RandomState(11)
+    tokens, experts, dim, out_dim, k = 48, 6, 10, 7, 3
+    x = jnp.asarray(rng.randn(tokens, dim), jnp.float32)
+    logits = jnp.asarray(rng.randn(tokens, experts), jnp.float32)
+    w = jnp.asarray(rng.randn(experts, dim, out_dim), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(_sorted_path(x, logits, w, k)),
+        np.asarray(_loop_path(x, logits, w, k)), atol=1e-5)
+    # unnormalised gates sum to less than one; normalised ones to one
+    gates, _, _ = moe_ops.route_top_k(logits, k)
+    assert float(gates.sum(-1).max()) < 1.0
+    normed, _, _ = moe_ops.route_top_k(logits, k, normalize=True)
+    np.testing.assert_allclose(np.asarray(normed.sum(-1)), 1.0, atol=1e-5)
+
+    def loss(path):
+        return lambda *a: (path(*a, k) ** 2).sum()
+
+    got = jax.grad(loss(_sorted_path), argnums=(0, 1, 2))(x, logits, w)
+    want = jax.grad(loss(_loop_path), argnums=(0, 1, 2))(x, logits, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+def test_sorted_dispatch_matches_onehot_where_nothing_is_dropped():
+    """With a capacity that holds every pair the one-hot formulation
+    drops nothing, and the two compute the same function: outputs and
+    the gradients through the tokens, the router logits and the
+    experts (gates renormalised on both sides, as one-hot does)."""
     rng = np.random.RandomState(7)
     g, s, e, m, k = 2, 16, 4, 6, 2
     w = jnp.asarray(rng.randn(e, m, m).astype(np.float32))
+    x = jnp.asarray(rng.randn(g, s, m).astype(np.float32))
+    logits = jnp.asarray(rng.randn(g, s, e).astype(np.float32))
 
-    def onehot_path(x, logits, capacity):
-        combine, dispatch, aux = top_k_routing(logits, k, capacity)
+    def onehot_path(x, logits, w):
+        combine, dispatch, _ = top_k_routing(logits, k, capacity=s * k)
         expert_out = jnp.einsum(
-            "egcm,emn->egcn", moe_dispatch(x, dispatch), w
-        )
-        return moe_combine(expert_out, combine), aux
+            "egcm,emn->egcn", moe_dispatch(x, dispatch), w)
+        return moe_combine(expert_out, combine)
 
-    def compact_path(x, logits, capacity):
-        gates, slot, aux = top_k_routing_compact(logits, k, capacity)
-        expert_in = moe_dispatch_compact(x, slot, e, capacity)
-        expert_out = jnp.einsum("egcm,emn->egcn", expert_in, w)
-        return moe_combine_compact(expert_out, slot, gates), aux
+    def sorted_path(x, logits, w):
+        return _sorted_path(
+            x.reshape(g * s, m), logits.reshape(g * s, e), w, k,
+            normalize=True).reshape(g, s, m)
 
-    # capacity=3 forces drops; capacity=s*k drops nothing
-    for capacity in (3, s * k):
-        x = jnp.asarray(rng.randn(g, s, m).astype(np.float32))
-        logits = jnp.asarray(rng.randn(g, s, e).astype(np.float32))
-        y1, aux1 = onehot_path(x, logits, capacity)
-        y2, aux2 = compact_path(x, logits, capacity)
-        np.testing.assert_allclose(
-            np.asarray(y1), np.asarray(y2), atol=1e-5
-        )
-        np.testing.assert_allclose(float(aux1), float(aux2), rtol=1e-6)
-
-        # gradients through both x and the router logits must agree
-        def loss1(x, lg):
-            y, aux = onehot_path(x, lg, capacity)
-            return (y ** 2).sum() + aux
-
-        def loss2(x, lg):
-            y, aux = compact_path(x, lg, capacity)
-            return (y ** 2).sum() + aux
-
-        gx1, gl1 = jax.grad(loss1, argnums=(0, 1))(x, logits)
-        gx2, gl2 = jax.grad(loss2, argnums=(0, 1))(x, logits)
-        np.testing.assert_allclose(
-            np.asarray(gx1), np.asarray(gx2), atol=1e-4
-        )
-        np.testing.assert_allclose(
-            np.asarray(gl1), np.asarray(gl2), atol=1e-4
-        )
-
-
-def test_moe_lm_compact_matches_onehot_losses():
-    """Full MoeTransformerLM trained with dispatch_impl="compact" vs
-    "onehot" produces the same loss curve on one device."""
-    batch = _batch()
-    losses = {}
-    for impl in ("onehot", "compact"):
-        model = _small_moe(attention_impl="xla", dispatch_impl=impl)
-        tx = create_optimizer("Adam", learning_rate=0.01)
-        init_rng, _ = jax.random.split(jax.random.PRNGKey(0))
-        state = create_train_state(
-            model, tx, init_rng, batch["features"]
-        )
-        step = jax.jit(make_train_step(model, moe_transformer.loss, tx))
-        arm = []
-        for _ in range(3):
-            state, loss = step(state, batch)
-            arm.append(float(loss))
-        losses[impl] = arm
     np.testing.assert_allclose(
-        losses["compact"], losses["onehot"], rtol=1e-4
-    )
+        np.asarray(onehot_path(x, logits, w)),
+        np.asarray(sorted_path(x, logits, w)), atol=1e-5)
+    got = jax.grad(
+        lambda *a: (sorted_path(*a) ** 2).sum(), argnums=(0, 1, 2)
+    )(x, logits, w)
+    want = jax.grad(
+        lambda *a: (onehot_path(*a) ** 2).sum(), argnums=(0, 1, 2)
+    )(x, logits, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
 
-def test_compact_dispatch_under_dp_mesh_matches_single_device():
-    """The compact (gather) path must also compile and stay correct
-    when tokens are dp-sharded over a mesh with ep=1 (the gather and
-    its custom gather-only backward are per-group, so GSPMD keeps
-    them local to each dp shard)."""
+def test_load_balancing_and_z_loss_by_hand():
+    # 4 tokens, 2 experts, k=1: three choose expert 0
+    probs = jnp.asarray([[0.9, 0.1], [0.8, 0.2], [0.6, 0.4], [0.3, 0.7]])
+    sizes = jnp.asarray([3, 1])
+    want = 2 * (3 / 4 * np.mean([0.9, 0.8, 0.6, 0.3])
+                + 1 / 4 * np.mean([0.1, 0.2, 0.4, 0.7]))
+    assert float(moe_ops.load_balancing_loss(probs, sizes)) == (
+        pytest.approx(want, rel=1e-6))
+    # a uniform router over E experts with k choices scores k
+    uniform = jnp.full((8, 4), 0.25)
+    assert float(moe_ops.load_balancing_loss(
+        uniform, jnp.asarray([4, 4, 4, 4]))) == pytest.approx(2.0)
+    logits = jnp.asarray([[0.0, 0.0], [1.0, -1.0]])
+    z = np.mean([np.log(2.0) ** 2, np.log(np.e + 1 / np.e) ** 2])
+    assert float(moe_ops.router_z_loss(logits)) == pytest.approx(z, rel=1e-6)
+
+
+def _lm_losses(model, batch, steps=3):
+    tx = create_optimizer("Adam", learning_rate=0.01)
+    init_rng, _ = jax.random.split(jax.random.PRNGKey(0))
+    state = create_train_state(model, tx, init_rng, batch["features"])
+    step = jax.jit(make_train_step(model, moe_transformer.loss, tx))
+    losses = []
+    for _ in range(steps):
+        state, loss = step(state, batch)
+        losses.append(float(loss))
+    return losses
+
+
+def test_moe_lm_sorted_matches_onehot_losses():
+    """Full MoeTransformerLM trained with dispatch_impl="sorted" vs
+    "onehot" (ample capacity: nothing dropped) gives the same loss
+    curve on one device. The auxiliary loss is left out: one-hot
+    balances first choices (Switch), sorted all k (OLMoE)."""
+    batch = _batch()
+    losses = {
+        impl: _lm_losses(
+            _small_moe(attention_impl="xla", dispatch_impl=impl,
+                       aux_loss_weight=0.0),
+            batch)
+        for impl in ("onehot", "sorted")
+    }
+    np.testing.assert_allclose(
+        losses["sorted"], losses["onehot"], rtol=1e-4)
+    assert losses["sorted"][-1] < losses["sorted"][0]
+
+
+def test_sorted_dispatch_under_dp_mesh_matches_single_device():
+    """The sorted path must also compile and stay correct when tokens
+    are dp-sharded over a mesh with ep=1 (one global sort: the
+    partitioner gathers what it needs)."""
     batch = _batch(batch=8)
-    # the onehot single-device baseline is a valid reference: the two
-    # impls agree to float tolerance (test_compact_dispatch_matches_onehot)
-    expected = _single_device_losses(batch)
+    kwargs = dict(attention_impl="xla", dispatch_impl="sorted")
+    expected = _lm_losses(_small_moe(**kwargs), batch)
     mesh = build_mesh(MeshConfig(dp=8))
-    model = _small_moe(
-        attention_impl="xla", mesh=mesh, dispatch_impl="compact"
-    )
     trainer = SpmdTrainer(
-        model=model,
+        model=_small_moe(mesh=mesh, **kwargs),
         loss_fn=moe_transformer.loss,
         optimizer=create_optimizer("Adam", learning_rate=0.01),
         mesh=mesh,
@@ -334,3 +424,100 @@ def test_compact_dispatch_under_dp_mesh_matches_single_device():
         state, loss = trainer.train_step(state, batch)
         got.append(float(loss))
     np.testing.assert_allclose(got, expected, atol=1e-4, rtol=1e-4)
+
+
+def test_sorted_dispatch_refuses_an_ep_mesh():
+    mesh = build_mesh(MeshConfig(dp=2, tp=2, ep=2))
+    model = _small_moe(
+        attention_impl="xla", mesh=mesh, dispatch_impl="sorted")
+    with pytest.raises(ValueError, match="ROADMAP.md Reach 2"):
+        model.init(jax.random.PRNGKey(0), _batch()["features"])
+    with pytest.raises(ValueError, match="dispatch_impl"):
+        _small_moe(dispatch_impl="compact").init(
+            jax.random.PRNGKey(0), _batch()["features"])
+
+
+def test_onehot_dispatch_refuses_unnormalised_gates():
+    """``top_k_routing`` always renormalises: the field must not be
+    taken and ignored."""
+    with pytest.raises(ValueError, match="normalize_gates=False needs"):
+        _small_moe(normalize_gates=False).init(
+            jax.random.PRNGKey(0), _batch()["features"])
+    _small_moe(normalize_gates=False, dispatch_impl="sorted").init(
+        jax.random.PRNGKey(0), _batch()["features"])
+
+
+def _avals(jaxpr):
+    """Every array shape a jaxpr computes, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            if hasattr(var.aval, "shape"):
+                yield tuple(var.aval.shape)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _avals(inner)
+
+
+def test_no_capacity_and_no_onehot_in_the_sorted_step():
+    """The sorted path's train step holds no array over (tokens or
+    sequence, experts, anything more): no (G, S, E, C) dispatch tensor
+    and no capacity. The one-hot path's does, which is what the test can see."""
+    # sizes that occur nowhere else: 5 experts, sequence 24, batch 3
+    batch = _batch(batch=3, seq=24)
+    experts, tokens = 5, 3 * 24
+
+    def step_shapes(impl):
+        model = moe_transformer.MoeTransformerLM(
+            vocab_size=128, num_layers=2, num_heads=4, embed_dim=32,
+            num_experts=experts, top_k=2, attention_impl="xla",
+            dispatch_impl=impl)
+        tx = create_optimizer("Adam", learning_rate=0.01)
+        state = create_train_state(
+            model, tx, jax.random.PRNGKey(0), batch["features"])
+        step = make_train_step(model, moe_transformer.loss, tx)
+        return set(_avals(jax.make_jaxpr(step)(state, batch).jaxpr))
+
+    def over_tokens_and_experts(shape):
+        # larger than the router's own (tokens, experts) probabilities
+        return experts in shape and (24 in shape or tokens in shape) and (
+            np.prod(shape) > tokens * experts)
+
+    assert any(map(over_tokens_and_experts, step_shapes("onehot")))
+    assert not any(map(over_tokens_and_experts, step_shapes("sorted")))
+
+
+def test_routing_counters_leave_the_step_only_for_a_model_that_has_them():
+    """``make_train_step(health=True)`` hands the sorted MoE LM's
+    routing counters out beside the health scalars (``JaxTrainer``
+    keeps them on the device for the worker's logged steps); a model
+    without them gets the scalars it always got."""
+    from elasticdl_tpu.models import transformer
+    from elasticdl_tpu.worker.trainer import JaxTrainer
+
+    batch = _batch()
+    tx = create_optimizer("Adam", learning_rate=0.01)
+    for accum in (1, 2):
+        trainer = JaxTrainer(
+            _small_moe(attention_impl="xla", dispatch_impl="sorted"),
+            moe_transformer.loss, tx, grad_accum_steps=accum)
+        trainer.train_step(None, batch)
+        routing = {k: float(v) for k, v in trainer.routing.items()}
+        assert set(routing) == {
+            "load_max", "load_mean", "entropy", "dropped"}
+        assert routing["dropped"] == 0.0
+        # 2 expert layers... each sees every token twice over 4 experts
+        tokens = batch["features"].size // accum
+        assert routing["load_mean"] == tokens * 2 / 4
+        assert routing["load_max"] >= routing["load_mean"]
+        assert 0.0 < routing["entropy"] <= np.log(4) + 1e-6
+    for model, loss in (
+        (_small_moe(attention_impl="xla"), moe_transformer.loss),
+        (transformer.TransformerLM(
+            vocab_size=128, num_layers=1, num_heads=4, embed_dim=32,
+            attention_impl="xla"), transformer.loss),
+    ):
+        trainer = JaxTrainer(model, loss, tx)
+        trainer.train_step(None, batch)
+        assert trainer.routing is None

@@ -1,0 +1,113 @@
+"""The sorted MoE path compiled at OLMoE's published widths for a v5e
+that is described, not attached (the TPU compiler is installed here):
+what the CPU's interpret-free tests cannot see. Both grouped matmuls
+must be accepted by the chip's compiler (tiles inside its VMEM, no
+unaligned slice), the Pallas one must be what a TPU backend gets, and
+the compiled expert layer must hold no capacity and no one-hot.
+
+One file, one fixture: only the process that runs this file loads the
+TPU's library (on-chip-measurement guide, section 2)."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elasticdl_tpu.models.moe_transformer import MoeMlp
+from elasticdl_tpu.ops import moe as moe_ops
+
+# OLMoE-1B-7B's expert layer; a quarter of the cell's 32,768 tokens
+TOKENS, DIM, WIDTH, EXPERTS, TOP_K = 8192, 2048, 1024, 64, 8
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says "no compiler"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def compile_layer(chip):
+    """HLO text of the expert layer's loss and gradients."""
+    layer = MoeMlp(
+        EXPERTS, top_k=TOP_K, dispatch_impl="sorted", expert_dim=WIDTH,
+        expert_act="swiglu", normalize_gates=False)
+    x = jax.ShapeDtypeStruct((2, TOKENS // 2, DIM), jnp.bfloat16,
+                             sharding=chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(
+            x.shape, x.dtype)))["params"]
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=chip),
+        params)
+
+    def loss(params, x):
+        y, aux = layer.apply({"params": params}, x)
+        return (y.astype(jnp.float32) ** 2).mean() + aux["load_balancing"]
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    return compiled.as_text()
+
+
+def kernels(hlo):
+    """op_name of every Mosaic kernel in the program."""
+    return [
+        re.search(r'op_name="([^"]*)"', line).group(1)
+        for line in hlo.splitlines() if "tpu_custom_call" in line
+    ]
+
+
+def assert_no_capacity(hlo):
+    """No array over (experts, more than the router's own columns) with
+    a token axis: shapes like [.., 64, C] beside 4096 or 8192."""
+    for shape in set(re.findall(r"\[([\d,]+)\]", hlo)):
+        dims = [int(d) for d in shape.split(",")]
+        over_tokens = TOKENS in dims or TOKENS // 2 in dims
+        if EXPERTS in dims and over_tokens:
+            assert len(dims) <= 3 and max(
+                d for d in dims if d not in (TOKENS, TOKENS // 2)
+            ) == EXPERTS, shape
+
+
+def test_pallas_grouped_matmul_is_what_a_tpu_backend_compiles(
+        chip, monkeypatch):
+    # here the default backend is the CPU: steer the one question the
+    # code asks (the guide: "it does so in the test")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = compile_layer(chip)
+    names = kernels(hlo)
+    # gate, up, down: forward, the rows' gradient, the kernels' gradient
+    assert len(names) == 9, names
+    assert all("moe/experts" in n and "gmm" in n for n in names), names
+    assert sum("transpose(" in n for n in names) == 6
+    assert "ragged-dot" not in hlo
+    assert_no_capacity(hlo)
+
+
+def test_ragged_dot_is_the_other_path_and_compiles_too(chip):
+    hlo = compile_layer(chip)
+    names = [n for n in kernels(hlo) if "metadata" not in n]
+    # XLA's own kernels keep no scope in op_name (lib/moe_trace.py
+    # charges them to the expert layer by their name)
+    assert len(names) == 9 and all(
+        n.startswith("ragged-dot") for n in names), names
+    assert_no_capacity(hlo)
+
+
+def test_the_pallas_kernel_is_not_chosen_where_it_cannot_run(monkeypatch):
+    resolve = moe_ops.resolve_grouped_matmul
+    assert resolve(1024, jnp.bfloat16) == "ragged_dot"  # a CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve(1024, jnp.bfloat16) == "pallas_gmm"
+    # a mesh of several devices, float32 rows, a ragged row tile
+    assert resolve(1024, jnp.bfloat16, one_device=False) == "ragged_dot"
+    assert resolve(1024, jnp.float32) == "ragged_dot"
+    assert resolve(1000, jnp.bfloat16) == "ragged_dot"
