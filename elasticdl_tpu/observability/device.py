@@ -37,7 +37,10 @@ runtime through three instruments:
    and result bytes by kind and the largest single one, on the compile
    log line and the ``xla_compile`` journal event, so that what the
    dense plane's cost model expects (``parallel/dense_plane.py``) can
-   be read against what the program does.
+   be read against what the program does; and for the Pallas kernels
+   it holds, by name (``pallas_kernels``), in the same two places: a
+   step whose flash backward fell back from ``flash_bwd`` to
+   ``flash_dq`` + ``flash_dkv`` says so there.
 
 Disabled path (``EDL_DEVICE_OBS=0``): ``instrumented_jit`` returns the
 **raw ``jax.jit`` product, unchanged** — no wrapper frame, no per-call
@@ -54,6 +57,7 @@ Knobs (all via common/env_utils, documented in docs/OBSERVABILITY.md):
   for backends whose ``memory_stats()`` reports none
 """
 
+import collections
 import contextlib
 import math
 import re
@@ -165,6 +169,13 @@ _COLLECTIVE_RE = re.compile(
     re.MULTILINE,
 )
 _RESULT_ARRAYS_MAX = 3
+# a Pallas kernel in a TPU program: a ``tpu_custom_call`` whose op_name
+# ends in the kernel's ``name=`` (inside ``jvp(`` / ``transpose(`` where
+# autodiff put it) and ``/pallas_call``
+_PALLAS_KERNEL_RE = re.compile(
+    r'custom_call_target="tpu_custom_call".*?'
+    r'op_name="[^"]*?(\w+)\)*/pallas_call"'
+)
 _CHANNEL_RE = re.compile(r"\bchannel_id=(\d+)")
 _HLO_ARRAY_RE = re.compile(r"\b([a-z]+\d*\w*)\[([\d,]*)\]")
 
@@ -235,6 +246,13 @@ def collective_stats(hlo_text):
         "bytes": sum(entry["bytes"] for entry in by_kind.values()),
         "largest": largest,
     }
+
+
+def pallas_kernels(hlo_text):
+    """``{name: count}`` of the Pallas kernels in a compiled TPU
+    program's HLO text, in the program's order. A static count, like
+    the collectives': a kernel inside a loop body counts once."""
+    return dict(collections.Counter(_PALLAS_KERNEL_RE.findall(hlo_text)))
 
 
 def _leaf_spec(leaf):
@@ -313,6 +331,8 @@ class _InstrumentedJit:
         # collective_stats() of the last-compiled signature; None until
         # a cost fetch has read a program
         self.collectives = None
+        # pallas_kernels() of the same program
+        self.kernels = {}
         self._cost_fetches = 0
         self._cost_on = env_bool(COST_ANALYSIS_ENV, True)
         self._cache_size = 0
@@ -406,10 +426,13 @@ class _InstrumentedJit:
         # both figures on one line: whether the cost fetch's relower is
         # a compile-cache hit or a second cold compile reads off it
         logger.info(
-            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs%s",
+            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs%s%s",
             self.compiles, self.name, elapsed, fetch_secs,
             "" if self.collectives is None
             else "; collectives " + collectives_text(self.collectives),
+            "" if not self.kernels
+            else "; kernels " + ", ".join(
+                "%s x%d" % item for item in self.kernels.items()),
         )
         events.emit(
             "xla_compile",
@@ -418,6 +441,7 @@ class _InstrumentedJit:
             seconds=round(elapsed, 4),
             cost_fetch_seconds=round(fetch_secs, 4),
             collectives=self.collectives,
+            kernels=self.kernels,
         )
 
     def _fetch_cost(self, args, kwargs):
@@ -438,7 +462,9 @@ class _InstrumentedJit:
             self.cost_bytes = float(
                 cost.get("bytes accessed", 0.0) or 0.0
             )
-            self.collectives = collective_stats(compiled.as_text())
+            hlo_text = compiled.as_text()
+            self.collectives = collective_stats(hlo_text)
+            self.kernels = pallas_kernels(hlo_text)
         except Exception as e:
             logger.debug("cost analysis unavailable for %s: %s",
                          self.name, e)

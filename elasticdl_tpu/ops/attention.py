@@ -60,11 +60,8 @@ def _pallas_refusal(q, k, block_q, block_k, layout):
         )
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
-    if block_q is None:
-        block_q = _flash._auto_block(seq_q, 512)
-    if block_k is None:
-        block_k = _flash._auto_block(seq_k, 1024)
-    if seq_q % min(block_q, seq_q) or seq_k % min(block_k, seq_k):
+    block_q, block_k = _flash._blocks(seq_q, seq_k, block_q, block_k)
+    if seq_q % block_q or seq_k % block_k:
         return "seq (%d, %d) not divisible by blocks (%d, %d)" % (
             seq_q, seq_k, block_q, block_k,
         )
@@ -75,19 +72,24 @@ def _pallas_refusal(q, k, block_q, block_k, layout):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_auto_once(backend, impl, reason, q_shape, layout):
+def _log_auto_once(backend, impl, reason, q_shape, q_dtype, layout,
+                   backward):
     """One line per distinct resolution (this runs at trace time, once
     per attention layer per trace). A TPU backend that resolves to the
     XLA reference is a warning: the O(S^2) path is running where the
-    kernel was expected."""
+    kernel was expected. ``backward``: which backward the flash kernel
+    gives these shapes (``flash_attention.backward_schedule``; a model's
+    float32 init trace may read ``split`` where its bfloat16 step reads
+    ``fused``: the dtype is on the line for that)."""
     log = (
         logger.warning if backend == "tpu" and impl == "xla"
         else logger.info
     )
     log(
-        "attention impl=auto resolved to %s (backend=%s, q=%s %s%s)",
-        impl, backend, q_shape, layout,
+        "attention impl=auto resolved to %s (backend=%s, q=%s %s %s%s%s)",
+        impl, backend, q_shape, q_dtype, layout,
         ", reason: %s" % reason if reason else "",
+        ", flash backward=%s" % backward if backward else "",
     )
 
 
@@ -146,7 +148,14 @@ def dot_product_attention(
             else "the Pallas kernel needs a TPU backend"
         )
         impl = "xla" if reason else "pallas"
-        _log_auto_once(backend, impl, reason, tuple(q.shape), layout)
+        seq_axis = 2 if layout == "bhsd" else 1
+        _log_auto_once(
+            backend, impl, reason, tuple(q.shape), q.dtype.name, layout,
+            "" if reason else _flash.backward_schedule(
+                q.shape[seq_axis], k.shape[seq_axis], q.shape[-1],
+                q.dtype, block_q, block_k,
+            ),
+        )
     if impl == "pallas":
         kernel = functools.partial(
             _pallas_attention, causal=causal, sm_scale=sm_scale,

@@ -9,6 +9,22 @@ No reference counterpart — the reference's models are CTR/vision Keras
 nets with no attention anywhere (SURVEY.md §5 "long-context: absent");
 this is a new TPU-first capability.
 
+Work, in score-sized matmuls (one is 2 x seq_q x seq_k x head_dim FLOPs
+a head, halved by the causal skip): the forward runs 2 (``q k^T``,
+``p v``); the backward needs 5 (the scores again, ``dp``, ``dv``,
+``dq``, ``dk``) and ``flash_bwd`` runs exactly those, once a (q-block,
+k-block) pair: dk and dv accumulate in float32 scratch across the
+q-blocks of one k-block, and dq, whose sum runs across the k-blocks,
+in a float32 scratch of one head's whole ``(seq_q, head_dim)`` that
+stays in VMEM for that head's sweep. That scratch is 16 MiB at
+16,384 x 256, so the call states its own VMEM limit
+(``_FUSED_VMEM_BYTES``), and ``backward_schedule`` keeps the older pair
+for shapes whose count (``fused_bwd_vmem_bytes``) is over it:
+``flash_dq`` + ``flash_dkv``, 3 + 4 matmuls, the scores rebuilt twice,
+no state that grows with the sequence. One algorithm under two
+schedules, chosen by shape: gradients agree to the last bit in
+interpret mode (tests/test_attention_ops.py).
+
 Layouts: (batch, heads, seq, head_dim) — "BHSD", kernels flatten
 batch*heads into one parallel grid axis — or "bshd"
 (batch, seq, heads, head_dim), where the kernels address each head as
@@ -44,6 +60,23 @@ def _auto_block(seq, cap):
     while block > 128 and seq % block:
         block //= 2
     return block if seq % block == 0 else min(seq, 128)
+
+
+def _blocks(seq_q, seq_k, block_q, block_k):
+    """The blocks a call runs with: ``None`` is the largest power of
+    two (up to 512 / 1024) that divides the sequence."""
+    if block_q is None:
+        block_q = _auto_block(seq_q, 512)
+    if block_k is None:
+        # Smaller causal k-blocks (512) look 30-40% faster in an
+        # ISOLATED kernel fwd+bwd micro-bench (above-diagonal blocks
+        # skip compute), but inside the full jitted train step the
+        # effect is noise at S<=2k and a 1-2% REGRESSION at S=4-8k —
+        # XLA's surrounding schedule absorbs the skip and the extra
+        # k-iterations cost loop overhead. Defaults follow the in-model
+        # measurement; pass block_k explicitly to retune.
+        block_k = _auto_block(seq_k, 1024)
+    return min(block_q, seq_q), min(block_k, seq_k)
 
 
 def _causal_mask(s, q_block, k_block, block_q, block_k):
@@ -236,6 +269,35 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
+def _p_and_ds(q, k, v, do, lse_ref, delta_ref, q_block, k_block,
+              sm_scale, causal, block_q, block_k):
+    """``p = exp(s - lse)`` and ``ds = p * (dp - delta) * sm_scale`` of
+    one (q-block, k-block) pair, both float32: the two score-sized
+    matmuls (``q k^T``, ``do v^T``) every backward kernel starts from.
+    Native-dtype matmul inputs, f32 accumulation (see _fwd_kernel)."""
+    lse = lse_ref[0, 0][:, None]
+    delta = delta_ref[0, 0][:, None]
+    s = (
+        jax.lax.dot_general(
+            q,
+            k,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        * sm_scale
+    )
+    if causal:
+        s = _causal_mask(s, q_block, k_block, block_q, block_k)
+    p = jnp.exp(s - lse)
+    dp = jax.lax.dot_general(
+        do,
+        v,
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta) * sm_scale
+
+
 def _dq_kernel(
     q_ref,
     k_ref,
@@ -267,33 +329,14 @@ def _dq_kernel(
 
     @pl.when(diag_ok)
     def _compute():
-        # Native-dtype matmul inputs, f32 accumulation (see _fwd_kernel)
-        q = q_ref[0]
         k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * sm_scale
+        _, ds = _p_and_ds(
+            q_ref[0], k, v_ref[0], do_ref[0], lse_ref, delta_ref,
+            q_block, k_block, sm_scale, causal, block_q, block_k,
         )
-        if causal:
-            s = _causal_mask(s, q_block, k_block, block_q, block_k)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do,
-            v,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        dq_acc_ref[:] += jnp.dot(
+            ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_acc_ref[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     @pl.when(k_block == num_k - 1)
     def _finalize():
@@ -307,24 +350,46 @@ def _dkv_kernel(
     do_ref,
     lse_ref,
     delta_ref,
-    dk_ref,
-    dv_ref,
-    dk_acc_ref,
-    dv_acc_ref,
-    *,
+    *out_and_scratch,
     sm_scale,
     causal,
     block_q,
     block_k,
+    with_dq,
 ):
+    """dk and dv of one k-block, accumulated in float32 over the
+    q-blocks (grid ``(bh, k-block, q-block)``).
+
+    ``with_dq`` (the fused backward, ``flash_bwd``): dq is accumulated
+    from the same ``ds``. Its accumulator must outlive the k-blocks, so
+    it is the whole ``(seq_q, head_dim)`` of this ``bh`` in float32,
+    and ``dq_ref`` the whole dq of this ``bh`` (an output block whose
+    index depends on ``bh`` alone stays in VMEM until ``bh`` moves on).
+    A q-block's rows are zeroed when the first k-block meets them and
+    cast to the output, once, when the last one has; in between the
+    ``ds @ k`` terms arrive in ascending k, as in ``_dq_kernel``."""
+    if with_dq:
+        (dk_ref, dv_ref, dq_ref,
+         dk_acc_ref, dv_acc_ref, dq_acc_ref) = out_and_scratch
+    else:
+        dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = out_and_scratch
     k_block = pl.program_id(1)
     q_block = pl.program_id(2)
+    num_k = pl.num_programs(1)
     num_q = pl.num_programs(2)
+    rows = pl.ds(pl.multiple_of(q_block * block_q, block_q), block_q)
 
     @pl.when(q_block == 0)
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+
+    if with_dq:
+        @pl.when(k_block == 0)
+        def _init_dq():
+            dq_acc_ref[rows, :] = jnp.zeros(
+                (block_q, dq_acc_ref.shape[1]), jnp.float32
+            )
 
     diag_ok = (
         (q_block + 1) * block_q - 1 >= k_block * block_k
@@ -334,49 +399,74 @@ def _dkv_kernel(
 
     @pl.when(diag_ok)
     def _compute():
-        # Native-dtype matmul inputs, f32 accumulation (see _fwd_kernel)
         q = q_ref[0]
         k = k_ref[0]
-        v = v_ref[0]
         do = do_ref[0]
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * sm_scale
+        p, ds = _p_and_ds(
+            q, k, v_ref[0], do, lse_ref, delta_ref,
+            q_block, k_block, sm_scale, causal, block_q, block_k,
         )
-        if causal:
-            s = _causal_mask(s, q_block, k_block, block_q, block_k)
-        p = jnp.exp(s - lse)
         dv_acc_ref[:] += jax.lax.dot_general(
             p.astype(do.dtype),
             do,
             (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dp = jax.lax.dot_general(
-            do,
-            v,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        ds = ds.astype(q.dtype)
         dk_acc_ref[:] += jax.lax.dot_general(
             ds,
             q,
             (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+        if with_dq:
+            dq_acc_ref[rows, :] += jnp.dot(
+                ds, k, preferred_element_type=jnp.float32
+            )
 
     @pl.when(q_block == num_q - 1)
     def _finalize():
         dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+    if with_dq:
+        @pl.when(k_block == num_k - 1)
+        def _finalize_dq():
+            dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
+
+
+# VMEM the fused backward may hold, and the limit its pallas_call states
+# (the default scoped limit, 16 MiB, is the size of its accumulator at
+# 16,384 x 256). A v5e or v6e core has 128 MiB, a v7x core 64.
+_FUSED_VMEM_BYTES = 64 * 2**20
+
+
+def fused_bwd_vmem_bytes(seq_q, head_dim, block_q, block_k, itemsize):
+    """VMEM of ``flash_bwd`` from its shapes, counted generously: dq of
+    one ``bh`` as float32 accumulator and double-buffered output block;
+    the float32 dk / dv accumulators; q, do, k, v, dk, dv blocks, two
+    buffers each; four score-sized float32 temporaries (s, p, dp, ds).
+    The v5e compiler takes 16,384 x 256 (blocks 512 / 1024, bfloat16)
+    under a limit of 36 MiB and refuses it under 32; this says 47."""
+    dq = seq_q * head_dim * (4 + 2 * itemsize)
+    kv = block_k * head_dim * (2 * 4 + 4 * 2 * itemsize)
+    q_do = block_q * head_dim * 2 * 2 * itemsize
+    scores = 4 * block_q * block_k * 4
+    return dq + kv + q_do + scores
+
+
+def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
+                      block_k=None):
+    """Which backward these shapes get: ``"fused"`` (one kernel,
+    ``flash_bwd``: the scores rebuilt once) where dq's accumulator fits
+    the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``: rebuilt
+    twice, no state that grows with the sequence) above it. ``_bwd``
+    decides by this and ``ops/attention.py`` logs it."""
+    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
+    held = fused_bwd_vmem_bytes(
+        seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize
+    )
+    return "fused" if held <= _FUSED_VMEM_BYTES else "split"
 
 
 def _bwd(
@@ -389,6 +479,7 @@ def _bwd(
         delta = jnp.sum(
             o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
         )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
+        dq_idx = lambda b, j, i: (b, 0, 0)
     else:
         batch, seq_q, fused = q.shape
         head_dim = fused // heads
@@ -402,23 +493,70 @@ def _bwd(
             ),
             axis=-1,
         ).transpose(0, 2, 1).reshape(bh, 1, seq_q)
+        dq_idx = lambda g, j, i: (g // heads, 0, g % heads)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     q_idx, k_idx, stat_idx = _q_specs(heads)
     operands = (q, k, v, do, lse, delta)
+    statics = dict(
+        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
+    )
+    fuse = backward_schedule(
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k
+    ) == "fused"
 
     def swapped(idx):
         # the dkv grid iterates (bh, k-block, q-block)
         return lambda b, j, i: idx(b, i, j)
 
+    dq_struct = _out_struct(q.shape, q.dtype, *operands)
+    dkv_structs = (
+        _out_struct(k.shape, k.dtype, *operands),
+        _out_struct(v.shape, v.dtype, *operands),
+    )
+    dkv_in_specs = [
+        pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
+        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
+        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
+        pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
+        pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
+        pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
+    ]
+    dkv_out_specs = (
+        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
+        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
+    )
+    dkv_scratch = [
+        pltpu.VMEM((block_k, head_dim), jnp.float32),
+        pltpu.VMEM((block_k, head_dim), jnp.float32),
+    ]
+
+    if fuse:
+        dk, dv, dq = pl.pallas_call(
+            functools.partial(_dkv_kernel, with_dq=True, **statics),
+            grid=(bh, num_k, num_q),
+            in_specs=dkv_in_specs,
+            out_specs=dkv_out_specs + (
+                pl.BlockSpec((1, seq_q, head_dim), dq_idx),
+            ),
+            scratch_shapes=dkv_scratch + [
+                pltpu.VMEM((seq_q, head_dim), jnp.float32),
+            ],
+            out_shape=dkv_structs + (dq_struct,),
+            compiler_params=pltpu.CompilerParams(
+                # dq gathers over the k-blocks too: only bh is parallel
+                dimension_semantics=(
+                    "parallel", "arbitrary", "arbitrary"
+                ),
+                vmem_limit_bytes=_FUSED_VMEM_BYTES,
+            ),
+            interpret=interpret,
+            name="flash_bwd",
+        )(*operands)
+        return dq, dk, dv
+
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel,
-            sm_scale=sm_scale,
-            causal=causal,
-            block_q=block_q,
-            block_k=block_k,
-        ),
+        functools.partial(_dq_kernel, **statics),
         grid=(bh, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
@@ -430,7 +568,7 @@ def _bwd(
         ],
         out_specs=pl.BlockSpec((1, block_q, head_dim), q_idx),
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
-        out_shape=_out_struct(q.shape, q.dtype, *operands),
+        out_shape=dq_struct,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -439,34 +577,12 @@ def _bwd(
     )(*operands)
 
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel,
-            sm_scale=sm_scale,
-            causal=causal,
-            block_q=block_q,
-            block_k=block_k,
-        ),
+        functools.partial(_dkv_kernel, with_dq=False, **statics),
         grid=(bh, num_k, num_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
-            pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-            pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-            pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
-            pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
-            pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-            pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-            pltpu.VMEM((block_k, head_dim), jnp.float32),
-        ],
-        out_shape=(
-            _out_struct(k.shape, k.dtype, *operands),
-            _out_struct(v.shape, v.dtype, *operands),
-        ),
+        in_specs=dkv_in_specs,
+        out_specs=dkv_out_specs,
+        scratch_shapes=dkv_scratch,
+        out_shape=dkv_structs,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -490,7 +606,11 @@ def _bwd(
 # PERF_TRANSFORMER.md). Here (o, lse) are ordinary named primal values
 # (checkpoint_name "flash_out"/"flash_lse"): a policy that saves them
 # lets remat DCE the forward kernel in the backward re-trace, while
-# ``_attach``'s own primal is a free identity.
+# ``_attach``'s own primal is a free identity. Its backward is ``_bwd``:
+# 5 score-sized matmuls in one kernel (``flash_bwd``) where dq's
+# accumulator fits the VMEM budget, 7 in two (``flash_dq``,
+# ``flash_dkv``) where it does not; ``delta = o . do`` is computed
+# outside either, from the saved ``o``.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -600,19 +720,7 @@ def flash_attention(
             )
     else:
         raise ValueError("layout must be 'bhsd' or 'bshd'")
-    if block_q is None:
-        block_q = _auto_block(seq_q, 512)
-    if block_k is None:
-        # Smaller causal k-blocks (512) look 30-40% faster in an
-        # ISOLATED kernel fwd+bwd micro-bench (above-diagonal blocks
-        # skip compute), but inside the full jitted train step the
-        # effect is noise at S<=2k and a 1-2% REGRESSION at S=4-8k —
-        # XLA's surrounding schedule absorbs the skip and the extra
-        # k-iterations cost dq/dkv loop overhead. Defaults follow the
-        # in-model measurement; pass block_k explicitly to retune.
-        block_k = _auto_block(seq_k, 1024)
-    block_q = min(block_q, seq_q)
-    block_k = min(block_k, seq_k)
+    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
     if seq_q % block_q or seq_k % block_k:
         raise ValueError(
             "seq lengths (%d, %d) must be multiples of the block sizes "

@@ -335,3 +335,145 @@ def test_pallas_attention_sharded_over_mesh_matches_oracle():
             q[:3], k[:3], v[:3], causal=True, impl="pallas",
             interpret=True, mesh=mesh, spec=spec,
         )
+
+
+# The fused backward (flash_bwd: dq accumulated beside dk and dv) against
+# XLA's autodiff of the plain attention and against the split
+# flash_dq / flash_dkv pair it falls back to above its VMEM budget.
+# (layout, causal, heads' width, seq_q, seq_k, block_q, block_k, dtype)
+FUSED_BACKWARD_CASES = {
+    "causal-several-blocks-d128":
+        ("bhsd", True, 128, 512, 512, 128, 256, jnp.float32),
+    "full-several-blocks-d128":
+        ("bhsd", False, 128, 512, 512, 128, 256, jnp.float32),
+    "causal-one-block-each-d256":
+        ("bhsd", True, 256, 256, 256, 256, 256, jnp.float32),
+    "full-several-blocks-d256":
+        ("bhsd", False, 256, 256, 256, 128, 128, jnp.float32),
+    # the ring's call: a block of another rank's keys, never causal
+    "full-seq-q-shorter-than-seq-k":
+        ("bhsd", False, 128, 256, 512, 128, 128, jnp.float32),
+    "full-seq-q-longer-than-seq-k":
+        ("bhsd", False, 128, 512, 256, 128, 256, jnp.float32),
+    "bshd-causal-several-blocks-d128":
+        ("bshd", True, 128, 512, 512, 128, 256, jnp.float32),
+    "bshd-full-d256":
+        ("bshd", False, 256, 256, 256, 128, 128, jnp.float32),
+    "causal-bfloat16-d128":
+        ("bhsd", True, 128, 512, 512, 128, 256, jnp.bfloat16),
+    "causal-block-k-below-block-q":
+        ("bhsd", True, 128, 512, 512, 256, 128, jnp.float32),
+}
+
+
+def _flash_grads(case):
+    from elasticdl_tpu.ops.attention import dot_product_attention
+
+    layout, causal, dim, seq_q, seq_k, block_q, block_k, dtype = case
+    rng = np.random.RandomState(7)
+
+    def mk(seq):
+        shape = (2, 2, seq, dim) if layout == "bhsd" else (2, seq, 2, dim)
+        return jnp.asarray(rng.normal(size=shape, scale=0.5), dtype)
+
+    q, k, v = mk(seq_q), mk(seq_k), mk(seq_k)
+
+    def loss(impl, **kw):
+        def fn(q, k, v):
+            out = dot_product_attention(
+                q, k, v, causal=causal, impl=impl, layout=layout, **kw
+            ).astype(jnp.float32)
+            return jnp.sum(out * jnp.cos(out))
+        return jax.grad(fn, argnums=(0, 1, 2))
+
+    flash = loss(
+        "pallas", block_q=block_q, block_k=block_k, interpret=True
+    )
+    return flash, loss("xla"), (q, k, v)
+
+
+def _kernel_names(fn, args):
+    """Names of the pallas_calls a function traces to."""
+    import re
+
+    return sorted(set(re.findall(
+        r"name=(flash_(?:fwd|bwd|dq|dkv))\b",
+        str(jax.make_jaxpr(fn)(*args)),
+    )))
+
+
+@pytest.mark.parametrize(
+    "case", list(FUSED_BACKWARD_CASES.values()),
+    ids=list(FUSED_BACKWARD_CASES),
+)
+def test_fused_backward_matches_xla_and_the_split_pair(case, monkeypatch):
+    from elasticdl_tpu.ops import flash_attention as F
+
+    flash, xla, args = _flash_grads(case)
+    assert _kernel_names(flash, args) == ["flash_bwd", "flash_fwd"]
+    fused = flash(*args)
+    bfloat16 = case[-1] == jnp.bfloat16
+    for got, ref in zip(fused, xla(*args)):
+        assert got.dtype == ref.dtype and got.shape == ref.shape
+        tol = 5e-2 if bfloat16 else 3e-4
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(ref, np.float32),
+            atol=tol, rtol=tol,
+        )
+    # a budget nothing fits: the same call falls back to the pair (a
+    # new function: jax keeps the traces of the old one)
+    monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
+    flash, _, _ = _flash_grads(case)
+    assert _kernel_names(flash, args) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+    dq, dk, dv = (np.asarray(g, np.float32) for g in flash(*args))
+    # dk and dv are the pair's statements unchanged; dq's terms arrive
+    # in ascending k in both schedules
+    np.testing.assert_array_equal(np.asarray(fused[1], np.float32), dk)
+    np.testing.assert_array_equal(np.asarray(fused[2], np.float32), dv)
+    np.testing.assert_allclose(
+        np.asarray(fused[0], np.float32), dq, atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("shape,dtype,schedule", [
+    # the benchmark's cells: pythia-1b at 2k and 16k, OLMoE at 4k
+    ((2048, 2048, 256), jnp.bfloat16, "fused"),
+    ((16384, 16384, 256), jnp.bfloat16, "fused"),
+    ((4096, 4096, 128), jnp.bfloat16, "fused"),
+    # dq's accumulator alone is the whole budget
+    ((32768, 32768, 256), jnp.bfloat16, "split"),
+    ((65536, 65536, 128), jnp.bfloat16, "split"),
+    # a ring block: dq's size follows seq_q, not seq_k
+    ((4096, 65536, 128), jnp.bfloat16, "fused"),
+    # float32 doubles dq's output block (a model's init trace at 16k)
+    ((16384, 16384, 256), jnp.float32, "split"),
+    ((2048, 2048, 256), jnp.float32, "fused"),
+])
+def test_backward_schedule_is_chosen_from_the_shapes(shape, dtype, schedule):
+    from elasticdl_tpu.ops import flash_attention as F
+
+    assert F.backward_schedule(*shape, dtype) == schedule
+
+
+def test_attention_log_line_says_which_backward(monkeypatch, caplog):
+    """``benchmark/lib/logs.py:ATTENTION_RE`` reads the first word after
+    "resolved to"; the backward's schedule rides inside the
+    parentheses."""
+    import logging
+
+    from elasticdl_tpu.ops import attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        attention, "_pallas_attention", lambda q, k, v, **kw: q)
+    attention._log_auto_once.cache_clear()
+    q = jnp.zeros((1, 2, 2048, 128), jnp.bfloat16)
+    with caplog.at_level(logging.INFO, logger=attention.logger.name):
+        attention.dot_product_attention(q, q, q, causal=True)
+        attention.dot_product_attention(q, q[:, :, :1000], q[:, :, :1000])
+    attention._log_auto_once.cache_clear()
+    lines = [r.getMessage() for r in caplog.records]
+    assert lines[0] == (
+        "attention impl=auto resolved to pallas (backend=tpu, "
+        "q=(1, 2, 2048, 128) bfloat16 bhsd, flash backward=fused)")
+    assert "resolved to xla" in lines[1] and "backward" not in lines[1]

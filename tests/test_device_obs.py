@@ -153,6 +153,53 @@ def test_cost_analysis_knob_off(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the program's Pallas kernels, by name
+
+# lines as the TPU compiler leaves them (tests/test_flash_tpu_compile.py
+# reads real ones): autodiff's wrappers around the name, a shard_map
+# above it, XLA's own Mosaic kernel (no pallas_call: not one of ours)
+KERNEL_HLO = """
+  %jvp_flash_fwd_.1 = (bf16[32,2048,256]{2,1,0}, f32[32,1,2048]{2,1,0}) custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(flash_fwd)/pallas_call" stack_frame_id=5}
+  %flash_fwd.2 = bf16[32,2048,256]{2,1,0} custom-call(%a, %b, %c), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/checkpoint/shard_map/flash_fwd/pallas_call"}
+  %bwd = (bf16[32,2048,256]{2,1,0}) custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(flash_bwd))/pallas_call" stack_frame_id=7}
+  %gmm = bf16[512,1024]{1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/moe/experts/gmm/pallas_call"}
+  %rd = bf16[512,1024]{1,0} custom-call(%a), custom_call_target="tpu_custom_call", metadata={op_name="ragged-dot.3"}
+  %add = f32[8]{0} add(%x, %y), metadata={op_name="jit(step)/flash_dq/pallas_call"}
+"""
+
+
+def test_pallas_kernels_counts_by_name():
+    assert device_obs.pallas_kernels(KERNEL_HLO) == {
+        "flash_fwd": 2, "flash_bwd": 1, "gmm": 1}
+    assert device_obs.pallas_kernels("ENTRY %main { }") == {}
+
+
+def test_compile_line_and_event_name_the_kernels(
+        monkeypatch, tmp_path, caplog):
+    import logging
+
+    # a CPU program holds none: say what a TPU's would
+    monkeypatch.setattr(
+        device_obs, "pallas_kernels",
+        lambda hlo: {"flash_fwd": 8, "flash_bwd": 8})
+    monkeypatch.setenv(events.EVENTS_DIR_ENV, str(tmp_path))
+    journal = events.configure("worker-0")
+    try:
+        with caplog.at_level(logging.INFO, logger=device_obs.logger.name):
+            device_obs.instrumented_jit(_matmul, name="kernel_step")(
+                jnp.ones((4, 4)))
+        with open(journal.path, encoding="utf-8") as f:
+            records = [json.loads(line) for line in f if line.strip()]
+    finally:
+        events._reset_for_tests()
+    compiled = [r for r in records if r["event"] == "xla_compile"]
+    assert compiled[0]["kernels"] == {"flash_fwd": 8, "flash_bwd": 8}
+    line = [r.getMessage() for r in caplog.records
+            if "xla compile #1 of kernel_step" in r.getMessage()][0]
+    assert line.endswith("; kernels flash_fwd x8, flash_bwd x8")
+
+
+# ---------------------------------------------------------------------------
 # transfers
 
 

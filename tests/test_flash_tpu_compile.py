@@ -1,0 +1,78 @@
+"""``jax.grad`` of the flash kernel compiled at the benchmark's four
+shapes for a v5e that is described, not attached (the TPU compiler is
+installed here): what interpret mode cannot see. The chip's compiler
+must accept the fused backward (its dq accumulator and whole-``bh`` dq
+block need more VMEM than the default scoped limit), the program must
+hold one forward and one backward kernel, both named ``flash...``
+(``benchmark/metrics/flash_time_share.py`` finds them by that word),
+and a shape over the budget must compile to the split pair.
+
+One file, one fixture: only the process that runs this file loads the
+TPU's library (on-chip-measurement guide, section 2)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from elasticdl_tpu.observability import device as device_obs
+from elasticdl_tpu.ops import flash_attention as F
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever says "no compiler"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def flash_kernels(chip, shape):
+    """The Mosaic kernels, as the compile ledger names and counts them,
+    of the compiled causal attention's gradient at (batch, heads, seq,
+    head width), bfloat16."""
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    assert hlo.count("tpu_custom_call") == len(
+        device_obs._PALLAS_KERNEL_RE.findall(hlo))
+    return device_obs.pallas_kernels(hlo)
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 8, 2048, 256),    # pythia1b-s2k
+    (1, 8, 16384, 256),   # pythia1b-s16k: 16 MB of dq accumulator
+    (3, 8, 2048, 256),    # pythia1b-fsdp4-s2k, a chip's shard
+    (8, 16, 4096, 128),   # olmoe1b7b-s4k
+], ids=["s2k-b4", "s16k-b1", "s2k-b3", "s4k-b8"])
+def test_the_cells_compile_to_one_forward_and_one_backward(chip, shape):
+    assert F.backward_schedule(
+        shape[2], shape[2], shape[3], jnp.bfloat16) == "fused"
+    assert flash_kernels(chip, shape) == {"flash_fwd": 1, "flash_bwd": 1}
+
+
+def test_over_the_budget_compiles_to_the_split_pair(chip):
+    shape = (1, 2, 32768, 256)
+    assert F.backward_schedule(
+        shape[2], shape[2], shape[3], jnp.bfloat16) == "split"
+    assert flash_kernels(chip, shape) == {
+        "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+
+
+def test_the_count_is_above_what_the_compiler_needs(chip, monkeypatch):
+    """``fused_bwd_vmem_bytes`` counts generously: with the stated
+    limit just above its count for the largest cell, the compiler
+    still accepts the kernel."""
+    monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 48 * 2**20)
+    assert F.fused_bwd_vmem_bytes(16384, 256, 512, 1024, 2) < 48 * 2**20
+    assert flash_kernels(chip, (1, 1, 16384, 256)) == {
+        "flash_fwd": 1, "flash_bwd": 1}
