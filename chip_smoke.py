@@ -29,7 +29,7 @@ accelerator (or no program next to this script): non-zero exit and no
 result line. The line before the result, ``chip_smoke: summary: {...}``
 (also ``chiprun_out/chip_smoke/summary.json``), carries the package
 versions and the per-phase figures (seconds to first step, losses,
-what attention / the tier / the store resolved to): facts about what
+what attention and the store resolved to, the tier's hits): facts about what
 ran, not performance results.
 
 tests/test_chip_smoke.py drives the same phase functions at a tiny
@@ -249,7 +249,7 @@ def write_token_records(data_dir, cfg, seed=0):
 
 
 def write_ctr_records(data_dir, cfg, seed=0):
-    """bench.py's CTR id stream — Zipf(1.2) ids over the vocabulary —
+    """A CTR id stream — Zipf(1.2) ids over the vocabulary —
     with a planted linear signal in the label so the loss can fall."""
     import numpy as np
 
@@ -285,8 +285,8 @@ def log_seconds(line):
 
 
 def parse_worker_log(text):
-    """Steps (timestamp, loss), the device line, what attention and the
-    tier resolved to, and the compile ledger lines."""
+    """Steps (timestamp, loss), the device line, what attention
+    resolved to, the tier's hits, and the compile ledger lines."""
     facts = {"steps": [], "compiles": {}}
     for line in text.splitlines():
         m = re.search(r"step (\d+) loss (\S+)", line)
@@ -306,10 +306,6 @@ def parse_worker_log(text):
         m = re.search(r"attention impl=auto resolved to (\w+)", line)
         if m:
             facts.setdefault("attention", set()).add(m.group(1))
-            continue
-        m = re.search(r"device embedding tier: .*\((\w+) kernel", line)
-        if m:
-            facts["tier_kernel"] = m.group(1)
             continue
         m = re.search(r"device tier closed: hits=(\d+) misses=(\d+)",
                       line)
@@ -521,15 +517,8 @@ def run_sparse_phase(children, workdir, cfg, expect, so_mtime):
         )
     if os.path.getmtime(NATIVE_SO) != so_mtime:
         problems.append("the native store was rebuilt behind the run")
-    report["tier_kernel"] = facts.get("tier_kernel")
     report["tier_hits"] = facts.get("tier_hits")
     report["tier_misses"] = facts.get("tier_misses")
-    # the default, EDL_TIER_KERNEL=auto, means jnp on every backend
-    # (ops/embedding_tier.py)
-    if facts.get("tier_kernel") != "jnp":
-        problems.append(
-            "tier kernel %r, expected 'jnp'" % facts.get("tier_kernel")
-        )
     if not facts.get("tier_hits"):
         problems.append(
             "device tier reported no hits (%r)" % facts.get("tier_hits")

@@ -63,12 +63,11 @@ def optimizer():
 # feature_config.py groups 39 raw columns). The models are field-count
 # agnostic at apply time; this default sizes the id buffers.
 NUM_FIELDS = 39
-# Measured ceiling on the padded unique-id buffer for ZIPFIAN id
-# streams (docs/PERF_SPARSE.md round-2 addendum): a CTR batch carries
-# far fewer unique ids than batch*fields, and right-sizing the buffer
-# was +22% steps/s on chip. This is an opt-in deployment tuning (the
-# bench config uses it); the library default below stays the always-
-# safe worst case so near-uniform id streams never hit the capacity
+# Ceiling on the padded unique-id buffer for ZIPFIAN id streams: a CTR
+# batch carries far fewer unique ids than batch*fields, so a buffer of
+# this size moves fewer padded rows a step. This is an opt-in
+# deployment tuning; the library default below stays the always-safe
+# worst case so near-uniform id streams never hit the capacity
 # ValueError out of the box.
 MAX_ID_CAPACITY = 8192
 
@@ -87,10 +86,10 @@ def sparse_embedding_specs(num_features=NUM_FIELDS, batch_size=64,
     model introspection, model_handler.py:98-102; here the module
     declares them). The capacity default is the always-safe worst case
     ``batch_size * num_features`` — any id stream fits. Zipfian CTR
-    streams should opt into the measured perf cap (+22% steps/s on
-    chip) via ``capacity=min(batch*fields, MAX_ID_CAPACITY)`` or
-    EDL_SPARSE_ID_CAPACITY, as the bench config does; overflow raises
-    a clear ValueError naming the knob (train/sparse.py)."""
+    streams can opt into a smaller buffer via
+    ``capacity=min(batch*fields, MAX_ID_CAPACITY)`` or
+    EDL_SPARSE_ID_CAPACITY (docs/PERFORMANCE.md); overflow raises a
+    clear ValueError naming the knob (train/sparse.py)."""
     from elasticdl_tpu.common.env_utils import env_int
 
     if capacity is None:

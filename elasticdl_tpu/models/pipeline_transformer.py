@@ -12,8 +12,9 @@ as one flat ``(num_layers, ...)`` stack regardless of the mesh, and
 the jitted step. A checkpoint written on a pp=4 mesh therefore restores
 bit-for-bit onto pp=2 or a single chip (the elastic-resume contract the
 dense checkpoint path promises). EXCEPTION: ``device_major_params=True``
-(the interleaved-schedule perf opt-in, docs/PERF_PIPELINE.md) stores
-the stack in device-placement order pinned to the current
+(the interleaved schedule's opt-in: no per-step cross-shard
+permutation of the stage stack; not measured on a chip) stores the
+stack in device-placement order pinned to the current
 ``(num_stages, num_chunks)``; such state lives under the pytree key
 ``blocks_device_major`` instead of ``blocks``, so restoring it into a
 job with the other layout setting fails LOUDLY on pytree structure

@@ -5,10 +5,9 @@ model_zoo/resnet50_subclass/ (Keras applications-based). Fresh TPU-first
 implementation: NHWC layout (TPU conv-native), TpuBatchNorm
 (ops/batch_norm.py: f32 single-pass statistics, residual stream stays
 in the compute dtype — a BN that forced f32 outputs would promote every
-downstream conv to f32 and halve the MXU rate, measured 1.8x step-time
-cost on v5e; the single-pass stats + fused-multiply-add normalize are
-worth another ~8% of step time over flax's nn.BatchNorm, see
-docs/PERF_RESNET.md);
+downstream conv to f32 and halve the MXU rate; the single-pass stats
+and the fused multiply-add normalize read the activation once where
+flax's nn.BatchNorm reads it twice);
 zero-init on the last BN scale of each block (standard trick: the
 residual branch starts as identity, which stabilizes large-batch
 training), and channel counts that are multiples of 128 in the deep

@@ -108,13 +108,10 @@ class Attention(nn.Module):
                     out.reshape(x.shape)
                 ).reshape(out.shape)
             return out
-        # (B, S, H, d) -> (B, H, S, d). A transpose-free path exists
-        # (dot_product_attention(layout="bshd") — the flash kernel can
-        # address heads as lane-aligned blocks of the fused minor dim)
-        # but measured net-NEGATIVE on v5e (+1.4% device time at the
-        # best-MFU config): XLA's transposes already run near the HBM
-        # roofline, and removing them shifts cost into strided kernel
-        # DMA and worse qkv-matmul layouts. docs/PERF_TRANSFORMER.md.
+        # (B, S, H, d) -> (B, H, S, d). The model transposes because a
+        # kernel that addressed heads inside the fused (H*d) minor dim
+        # lost on the v5e: XLA's transposes run near the HBM roofline,
+        # strided kernel DMA and the qkv matmuls' layouts cost more.
         # q/k/v are pinned to the layout the attention call declares,
         # (B, H, S, d) with batch over the data axes and heads over tp
         # (ring / ulysses: the sequence over sp as well), so its
@@ -198,7 +195,7 @@ def remat_block(block_cls, remat_policy, attention_impl):
     recomputed). "flash" saves ONLY the flash kernel's named outputs:
     the projections/mlp recompute like "full", but the O(S^2)
     attention forward never re-runs, the middle ground for lengths
-    where "dots" exceeds HBM (docs/PERF_TRANSFORMER.md, S=16k)."""
+    where "dots" exceeds HBM (16k on one chip: PERF.md Section 4)."""
     import jax
 
     from elasticdl_tpu.ops.flash_attention import (
@@ -262,7 +259,7 @@ class TransformerLM(nn.Module):
     # what keeps MFU high on memory-tight configs; "flash" saves only the
     # attention kernel's (o, lse) outputs — between the two: projections
     # recompute, the O(S^2) attention forward does not, for lengths where
-    # "dots" exceeds HBM (docs/PERF_TRANSFORMER.md)
+    # "dots" exceeds HBM (PERF.md Section 4, `pythia1b-s16k`)
     remat_policy: str = "full"
 
     @nn.compact

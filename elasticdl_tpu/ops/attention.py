@@ -22,42 +22,25 @@ from elasticdl_tpu.ops import flash_attention as _flash
 logger = _logger_factory("elasticdl_tpu.ops.attention")
 
 
-def _check_layout(layout):
-    if layout not in ("bhsd", "bshd"):
-        raise ValueError("layout must be 'bhsd' or 'bshd', got %r"
-                         % (layout,))
-
-
-def xla_attention(q, k, v, causal=False, sm_scale=None, layout="bhsd"):
-    """Reference O(S^2) attention ((batch, heads, seq, dim) or, with
-    layout="bshd", (batch, seq, heads, dim) — no transposes either
-    way, einsum handles both)."""
-    _check_layout(layout)
+def xla_attention(q, k, v, causal=False, sm_scale=None):
+    """Reference O(S^2) attention over (batch, heads, seq, dim)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    qk, pv = (
-        ("bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd")
-        if layout == "bhsd"
-        else ("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd")
-    )
-    s = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.einsum(
+        "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
+    ) * sm_scale
     if causal:
         seq_q, seq_k = s.shape[-2], s.shape[-1]
         q_pos = jnp.arange(seq_q)[:, None]
         k_pos = jnp.arange(seq_k)[None, :]
         s = jnp.where(q_pos >= k_pos, s, _flash.NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum(pv, p, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _pallas_refusal(q, k, block_q, block_k, layout):
+def _pallas_refusal(q, k, block_q, block_k):
     """Why the flash kernel cannot take these shapes; "" when it can."""
-    seq_axis = 2 if layout == "bhsd" else 1
-    seq_q, seq_k = q.shape[seq_axis], k.shape[seq_axis]
-    if layout == "bshd" and q.shape[-1] % 128:
-        return "bshd layout needs head_dim %% 128 == 0 (got %d)" % (
-            q.shape[-1],
-        )
+    seq_q, seq_k = q.shape[2], k.shape[2]
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
     block_q, block_k = _flash._blocks(seq_q, seq_k, block_q, block_k)
@@ -72,8 +55,7 @@ def _pallas_refusal(q, k, block_q, block_k, layout):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_auto_once(backend, impl, reason, q_shape, q_dtype, layout,
-                   backward):
+def _log_auto_once(backend, impl, reason, q_shape, q_dtype, backward):
     """One line per distinct resolution (this runs at trace time, once
     per attention layer per trace). A TPU backend that resolves to the
     XLA reference is a warning: the O(S^2) path is running where the
@@ -86,8 +68,8 @@ def _log_auto_once(backend, impl, reason, q_shape, q_dtype, layout,
         else logger.info
     )
     log(
-        "attention impl=auto resolved to %s (backend=%s, q=%s %s %s%s%s)",
-        impl, backend, q_shape, q_dtype, layout,
+        "attention impl=auto resolved to %s (backend=%s, q=%s %s%s%s)",
+        impl, backend, q_shape, q_dtype,
         ", reason: %s" % reason if reason else "",
         ", flash backward=%s" % backward if backward else "",
     )
@@ -132,56 +114,36 @@ def dot_product_attention(
     block_q=None,
     block_k=None,
     interpret=False,
-    layout="bhsd",
     mesh=None,
     spec=None,
 ):
-    """``mesh`` and ``spec``: the mesh the caller's step is sharded
-    over and the PartitionSpec of q/k/v on it; the Pallas kernel then
-    runs inside a shard_map over them."""
-    _check_layout(layout)
+    """q/k/v are (batch, heads, seq, dim). ``mesh`` and ``spec``: the
+    mesh the caller's step is sharded over and the PartitionSpec of
+    q/k/v on it; the Pallas kernel then runs inside a shard_map over
+    them."""
     if impl == "auto":
         backend = jax.default_backend()
         reason = (
-            _pallas_refusal(q, k, block_q, block_k, layout)
+            _pallas_refusal(q, k, block_q, block_k)
             if backend == "tpu"
             else "the Pallas kernel needs a TPU backend"
         )
         impl = "xla" if reason else "pallas"
-        seq_axis = 2 if layout == "bhsd" else 1
         _log_auto_once(
-            backend, impl, reason, tuple(q.shape), q.dtype.name, layout,
+            backend, impl, reason, tuple(q.shape), q.dtype.name,
             "" if reason else _flash.backward_schedule(
-                q.shape[seq_axis], k.shape[seq_axis], q.shape[-1],
+                q.shape[2], k.shape[2], q.shape[-1],
                 q.dtype, block_q, block_k,
             ),
         )
     if impl == "pallas":
         kernel = functools.partial(
-            _pallas_attention, causal=causal, sm_scale=sm_scale,
+            _flash.flash_attention, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
-            layout=layout,
         )
         if mesh is not None:
             kernel = _shard_over_mesh(kernel, mesh, spec, q)
         return kernel(q, k, v)
     if impl == "xla":
-        return xla_attention(
-            q, k, v, causal=causal, sm_scale=sm_scale, layout=layout
-        )
+        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
     raise ValueError("unknown attention impl %r" % (impl,))
-
-
-def _pallas_attention(q, k, v, *, causal, sm_scale, block_q, block_k,
-                      interpret, layout):
-    # fused-head addressing needs lane-aligned head_dim; honor the
-    # explicit pallas request through a transpose adapter
-    adapt = layout == "bshd" and q.shape[-1] % 128
-    if adapt:
-        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        layout = "bhsd"
-    out = _flash.flash_attention(
-        q, k, v, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, interpret=interpret, layout=layout,
-    )
-    return out.transpose(0, 2, 1, 3) if adapt else out

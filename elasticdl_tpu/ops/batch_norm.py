@@ -1,8 +1,7 @@
 """TPU-first BatchNorm.
 
-Profiling the ResNet50 train step on a v5e chip (docs/PERF_RESNET.md)
-showed the step is HBM-bandwidth-bound and that flax's ``nn.BatchNorm``
-costs an extra ~8% of step time: its mean/variance are computed as two
+The ResNet50 train step is HBM-bandwidth-bound, and flax's
+``nn.BatchNorm`` spends bandwidth: its mean/variance are computed as two
 dependent passes (``mean`` then ``mean((x - mean)**2)``), which XLA
 cannot fuse into one read of the activation, and its normalize applies
 ``(x - mean) * inv * scale + bias`` as several elementwise ops.

@@ -25,13 +25,8 @@ no state that grows with the sequence. One algorithm under two
 schedules, chosen by shape: gradients agree to the last bit in
 interpret mode (tests/test_attention_ops.py).
 
-Layouts: (batch, heads, seq, head_dim) — "BHSD", kernels flatten
-batch*heads into one parallel grid axis — or "bshd"
-(batch, seq, heads, head_dim), where the kernels address each head as
-a lane-aligned d-wide block of the fused (heads*head_dim) minor dim so
-callers skip the BHSD transposes (``flash_attention(layout=...)``;
-measured net-negative for the stock TransformerLM on v5e but available
-for shapes where it wins — docs/PERF_TRANSFORMER.md §6).
+Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
+into one parallel grid axis and see one head's (seq, head_dim) rows.
 """
 
 import functools
@@ -132,8 +127,7 @@ def _fwd_kernel(
         # accumulation: for bf16 inputs bf16xbf16->f32 is bit-identical
         # to upcasting first (bf16 products are exact in f32), while an
         # f32xf32 matmul the MXU must emulate in multiple passes runs
-        # ~4-6x slower — this was 19% of transformer step time
-        # (docs/PERF_TRANSFORMER.md). Softmax statistics stay in f32.
+        # several times slower. Softmax statistics stay in f32.
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
@@ -173,27 +167,12 @@ def _fwd_kernel(
         )
 
 
-def _q_specs(heads):
-    """(q-ish spec, k-ish spec, lse-ish spec) index maps for the two
-    kernel views.
-
-    - ``heads is None``: the merged "(bh, seq, d)" view — batch*heads
-      flattened into grid axis 0, arrays carry one head each.
-    - ``heads = H``: the fused-BSHD "(B, seq, H*d)" view — grid axis 0
-      is still B*H, but the head selects a d-wide block of the fused
-      minor dim instead of a row of a transposed array. This is what
-      lets the model skip the BHSD transposes entirely: the kernel sees
-      the exact (block, d) tiles either way (d is a lane multiple), so
-      the bodies are shared.
-    """
-    if heads is None:
-        q_idx = lambda b, i, j: (b, i, 0)
-        k_idx = lambda b, i, j: (b, j, 0)
-        stat_idx = lambda b, i, j: (b, 0, i)
-    else:
-        q_idx = lambda g, i, j: (g // heads, i, g % heads)
-        k_idx = lambda g, i, j: (g // heads, j, g % heads)
-        stat_idx = lambda g, i, j: (g, 0, i)
+def _q_specs():
+    """(q-ish spec, k-ish spec, lse-ish spec) index maps of the merged
+    "(bh, seq, d)" view on a ``(bh, q-block, k-block)`` grid."""
+    q_idx = lambda b, i, j: (b, i, 0)
+    k_idx = lambda b, i, j: (b, j, 0)
+    stat_idx = lambda b, i, j: (b, 0, i)
     return q_idx, k_idx, stat_idx
 
 
@@ -206,16 +185,9 @@ def _out_struct(shape, dtype, *operands):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-         heads=None):
-    if heads is None:
-        bh, seq_q, head_dim = q.shape
-        seq_k = k.shape[1]
-    else:
-        batch, seq_q, fused = q.shape
-        head_dim = fused // heads
-        seq_k = k.shape[1]
-        bh = batch * heads
+def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
+    bh, seq_q, head_dim = q.shape
+    seq_k = k.shape[1]
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     grid = (bh, num_q, num_k)
@@ -227,7 +199,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         block_q=block_q,
         block_k=block_k,
     )
-    q_idx, k_idx, stat_idx = _q_specs(heads)
+    q_idx, k_idx, stat_idx = _q_specs()
     # lse rides in (bh, 1, seq) — the singleton axis makes the block's
     # second-minor dim equal the full array dim, satisfying the TPU
     # (8, 128) tiling rule that a 2-D (1, block_q) block violates
@@ -471,32 +443,16 @@ def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
 
 def _bwd(
     q, k, v, o, lse, do, sm_scale, causal, block_q, block_k, interpret,
-    heads=None,
 ):
-    if heads is None:
-        bh, seq_q, head_dim = q.shape
-        seq_k = k.shape[1]
-        delta = jnp.sum(
-            o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
-        )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
-        dq_idx = lambda b, j, i: (b, 0, 0)
-    else:
-        batch, seq_q, fused = q.shape
-        head_dim = fused // heads
-        seq_k = k.shape[1]
-        bh = batch * heads
-        # per-head dot(o, do): (B, S, H) -> (B*H, 1, S)
-        delta = jnp.sum(
-            o.astype(jnp.float32).reshape(batch, seq_q, heads, head_dim)
-            * do.astype(jnp.float32).reshape(
-                batch, seq_q, heads, head_dim
-            ),
-            axis=-1,
-        ).transpose(0, 2, 1).reshape(bh, 1, seq_q)
-        dq_idx = lambda g, j, i: (g // heads, 0, g % heads)
+    bh, seq_q, head_dim = q.shape
+    seq_k = k.shape[1]
+    delta = jnp.sum(
+        o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
+    )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
+    dq_idx = lambda b, j, i: (b, 0, 0)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
-    q_idx, k_idx, stat_idx = _q_specs(heads)
+    q_idx, k_idx, stat_idx = _q_specs()
     operands = (q, k, v, do, lse, delta)
     statics = dict(
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
@@ -602,8 +558,7 @@ def _bwd(
 # custom_vjp, its lse output exists only as a hidden residual, so a
 # rematerialization policy (jax.checkpoint) can never mark it saveable —
 # every rematted transformer block then pays a SECOND forward flash pass
-# during backward (~5% of train-step time at S=2k, docs/
-# PERF_TRANSFORMER.md). Here (o, lse) are ordinary named primal values
+# during backward. Here (o, lse) are ordinary named primal values
 # (checkpoint_name "flash_out"/"flash_lse"): a policy that saves them
 # lets remat DCE the forward kernel in the backward re-trace, while
 # ``_attach``'s own primal is a free identity. Its backward is ``_bwd``:
@@ -613,23 +568,22 @@ def _bwd(
 # outside either, from the saved ``o``.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _attach(q, k, v, o, lse, sm_scale, causal, block_q, block_k,
-            interpret, heads):
+            interpret):
     return o
 
 
 def _attach_fwd(q, k, v, o, lse, sm_scale, causal, block_q, block_k,
-                interpret, heads):
+                interpret):
     return o, (q, k, v, o, lse)
 
 
-def _attach_bwd(sm_scale, causal, block_q, block_k, interpret, heads,
-                res, do):
+def _attach_bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     q, k, v, o, lse = res
     dq, dk, dv = _bwd(
         q, k, v, o, lse, do, sm_scale, causal, block_q, block_k,
-        interpret, heads,
+        interpret,
     )
     # o/lse arrive behind stop_gradient; their cotangents are discarded.
     return dq, dk, dv, jnp.zeros_like(o), jnp.zeros_like(lse)
@@ -638,8 +592,7 @@ def _attach_bwd(sm_scale, causal, block_q, block_k, interpret, heads,
 _attach.defvjp(_attach_fwd, _attach_bwd)
 
 
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-           heads=None):
+def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     # stop_gradient on the kernel inputs keeps AD linearization out of
     # the forward pallas_call (it has no JVP rule and needs none — all
     # gradients flow through _attach's bwd kernels).
@@ -652,7 +605,6 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         block_q,
         block_k,
         interpret,
-        heads,
     )
     o = checkpoint_name(o, FLASH_OUT_NAME)
     lse = checkpoint_name(lse, FLASH_LSE_NAME)
@@ -667,7 +619,6 @@ def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         block_q,
         block_k,
         interpret,
-        heads,
     )
 
 
@@ -680,24 +631,13 @@ def flash_attention(
     block_q=None,
     block_k=None,
     interpret=False,
-    layout="bhsd",
 ):
-    """Blockwise attention.
-
-    layout selects the input/output convention:
-    - "bhsd" (default): (batch, heads, seq, head_dim).
-    - "bshd": (batch, seq, heads, head_dim) — the layout qkv
-      projections naturally produce. The kernel addresses each head as
-      a d-wide block of the fused trailing (heads*head_dim) dim, so NO
-      transpose is ever materialized; measured ~3% of transformer step
-      time on v5e was BHSD<->BSHD "data formatting"
-      (docs/PERF_TRANSFORMER.md). Requires head_dim to be a multiple of
-      128 lanes (the auto dispatcher checks).
+    """Blockwise attention over (batch, heads, seq, head_dim) inputs.
 
     Sequence lengths must be multiples of the block sizes (the auto
     dispatcher in ops/attention.py falls back to the XLA impl when they
     are not); head_dim should be a multiple of 128 lanes for best MXU
-    utilisation but any size compiles in the "bhsd" layout.
+    utilisation but any size compiles.
 
     block_q/block_k default to the largest power-of-two blocks (up to
     512/1024) dividing the sequence: measured on v5e at S=16k, (512,
@@ -706,20 +646,8 @@ def flash_attention(
     """
     if q.ndim != 4:
         raise ValueError("expected 4-D q/k/v")
-    if layout == "bhsd":
-        batch, heads, seq_q, head_dim = q.shape
-        seq_k = k.shape[2]
-    elif layout == "bshd":
-        batch, seq_q, heads, head_dim = q.shape
-        seq_k = k.shape[1]
-        if head_dim % 128:
-            raise ValueError(
-                "layout='bshd' needs head_dim %% 128 == 0 (got %d): "
-                "the head is addressed as a lane-aligned block of the "
-                "fused minor dim" % head_dim
-            )
-    else:
-        raise ValueError("layout must be 'bhsd' or 'bshd'")
+    batch, heads, seq_q, head_dim = q.shape
+    seq_k = k.shape[2]
     block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
     if seq_q % block_q or seq_k % block_k:
         raise ValueError(
@@ -728,20 +656,6 @@ def flash_attention(
         )
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    if layout == "bshd":
-        fuse = lambda t: t.reshape(batch, t.shape[1], heads * head_dim)
-        o = _flash(
-            fuse(q),
-            fuse(k),
-            fuse(v),
-            sm_scale,
-            causal,
-            block_q,
-            block_k,
-            interpret,
-            heads,
-        )
-        return o.reshape(batch, seq_q, heads, head_dim)
     merge = lambda t: t.reshape(batch * heads, t.shape[2], head_dim)
     o = _flash(
         merge(q),

@@ -53,9 +53,8 @@ def parse_ps_args(argv=None):
     # (reference go/cmd/elasticdl_ps/main.go lr_staleness_modulation)
     add_bool_argument(parser, "--lr_staleness_modulation", default=0)
     # benchmarking knob: sleep this long at the top of every RPC handler
-    # to emulate network RTT between worker and PS pods (the
-    # controlled-latency experiment behind docs/PERF_SPARSE.md — a
-    # localhost PS otherwise measures at ~0 RTT)
+    # to emulate network RTT between worker and PS pods (a localhost
+    # PS otherwise measures at ~0 RTT)
     parser.add_argument("--inject_rpc_delay_ms", type=float, default=0.0)
     # observability: /metrics + /healthz + /readyz on this port
     # (0/unset = disabled; falls back to EDL_METRICS_PORT)

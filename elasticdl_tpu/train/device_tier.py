@@ -77,7 +77,6 @@ class DeviceTierConfig:
     opt_type: str = "adam"       # tier-side sparse optimizer
     opt_args: dict = field(default_factory=dict)
     writeback_steps: int = 256   # dirty-row writeback cadence (steps)
-    kernel: str = None           # EDL_TIER_KERNEL override
 
     @classmethod
     def from_env(cls):
@@ -184,7 +183,6 @@ class DeviceEmbeddingTier:
                 " (eviction/flush writeback); %r has none"
                 % type(ps_client).__name__
             )
-        self._kernel = tier_ops.resolve_kernel(config.kernel)
         self._opt_type = config.opt_type.lower()
         if self._opt_type not in tier_ops.TIER_OPT_SLOTS:
             raise ValueError(
@@ -270,10 +268,10 @@ class DeviceEmbeddingTier:
         self._t_hits = {}    # per-table cumulative (for the hit-rate
         self._t_misses = {}  # gauge with metrics off -> stats())
         logger.info(
-            "device embedding tier: %d tables x %d rows (%s kernel, "
-            "%s optimizer, promote@%d, ttl=%d, writeback every %d "
+            "device embedding tier: %d tables x %d rows "
+            "(%s optimizer, promote@%d, ttl=%d, writeback every %d "
             "steps%s)",
-            len(self._tables), config.capacity, self._kernel,
+            len(self._tables), config.capacity,
             self._opt_type, config.promote_hits, config.ttl,
             config.writeback_steps,
             ", ep=%d sharded" % self._ep if self._ep > 1 else "",
@@ -301,16 +299,9 @@ class DeviceEmbeddingTier:
         }
 
     def _jit_insert_gather(self, table):
-        import functools
-
-        import jax
-
         key = ("ig", table.name)
         fn = self._jit_cache.get(key)
         if fn is None:
-            base = functools.partial(
-                tier_ops.fused_insert_gather, kernel=self._kernel
-            )
             kwargs = {}
             if self._mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -322,7 +313,8 @@ class DeviceEmbeddingTier:
                     replicated,
                 )
             fn = device_obs.instrumented_jit(
-                base, name="tier_insert_gather:%s" % table.name,
+                tier_ops.fused_insert_gather,
+                name="tier_insert_gather:%s" % table.name,
                 donate_argnums=(0,), **kwargs
             )
             self._jit_cache[key] = fn
@@ -373,7 +365,6 @@ class DeviceEmbeddingTier:
                 beta1=float(args.get("beta1", 0.9)),
                 beta2=float(args.get("beta2", 0.999)),
                 epsilon=float(args.get("epsilon", 1e-8)),
-                kernel=self._kernel,
             )
             kwargs = {}
             if self._mesh is not None:

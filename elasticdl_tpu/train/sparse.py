@@ -1283,7 +1283,7 @@ class SparseTrainer:
                 # begin the transfer after the lookahead pull returns,
                 # putting fetch and pull in series. The fetch is a long
                 # leg of the step, so overlapping it with the pull
-                # matters at non-zero PS RTT (docs/PERF_SPARSE.md).
+                # matters at non-zero PS RTT.
                 for leaf in jax.tree_util.tree_leaves(row_grads):
                     leaf.copy_to_host_async()
                 in_flight = (row_grads, pull_info, loss, scalars)

@@ -326,26 +326,15 @@ class Worker:
             interval=log_loss_steps
         )
         # domain gauges fed off the ledger's clock (no second timer):
-        # examples/sec from the last iteration + real batch count;
-        # hardware utilisation when the trainer knows its per-step
-        # FLOPs and the operator told us the hardware peak. No-op
-        # instruments when metrics are off.
+        # examples/sec from the last iteration + real batch count.
+        # No-op instruments when metrics are off.
         self._m_examples_per_sec = obs_metrics.gauge(
             "edl_worker_examples_per_second",
             "Real (unpadded) examples trained per second, last step",
         )
-        self._m_mfu = obs_metrics.gauge(
-            "edl_worker_mfu_ratio",
-            "Hardware FLOPs utilisation (recompute counted): executed "
-            "step FLOPs / (iteration time * EDL_PEAK_FLOPS_PER_SEC)",
-        )
         self._m_version = obs_metrics.gauge(
             "edl_worker_model_version", "This worker's model version"
         )
-        self._step_flops = float(
-            getattr(self.trainer, "step_flops", 0) or 0
-        )
-        self._peak_flops = env_float("EDL_PEAK_FLOPS_PER_SEC", 0.0)
         for cb in self._callbacks:
             cb.set_worker(self)
         # Heartbeat keeps master-side liveness fresh while the worker is
@@ -802,18 +791,6 @@ class Worker:
         )
         if step_secs:
             self._m_examples_per_sec.set(real / step_secs)
-            if self._peak_flops:
-                # cost-model attribution (ISSUE 18): prefer XLA's own
-                # cost_analysis() of the compiled step (exact for the
-                # program actually running) over the trainer's static
-                # step_flops table
-                flops = float(
-                    getattr(self.trainer, "cost_step_flops", 0.0) or 0.0
-                ) or self._step_flops
-                if flops:
-                    self._m_mfu.set(
-                        flops / (step_secs * self._peak_flops)
-                    )
         self._m_version.set(self._version)
         with phase("report"):
             self.tds.report_record_done(real)

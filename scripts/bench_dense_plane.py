@@ -21,7 +21,7 @@ asserts that split MECHANICALLY, not by code inspection:
   dense-plane telemetry (mesh_shape=dp=2, collective_bytes_per_step)
   — the same fields /statusz and postmortem.py surface;
 - the mesh epoch must not have moved: this is the steady-state lane
-  (elastic reshape correctness is bench_elastic_makespan's job).
+  (a reshape mid-job is the elastic tests' subject, not this one's).
 
 Prints one JSON line. CPU backend; runs in ~1-3 min.
 """

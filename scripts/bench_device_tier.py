@@ -7,8 +7,7 @@ pull/push/writeback legs charge an EMULATED per-row wire cost
 deepfm wire path: ~20 steps/s at ~10k rows/step each way). Without
 the emulation an in-process A-B is a strawman — there is no gRPC wire
 to skip, which is the entire point of the tier — while spawning live
-PS processes is too slow for a CI smoke (that comparison lives in
-bench.py's deepfm A-B).
+PS processes is too slow for a CI smoke.
 
 Absolute numbers are REPORT-ONLY (journaled by scripts/ci.sh, never
 gated — timings flake across boxes); the script hard-fails only when

@@ -13,8 +13,8 @@ Metrics per arm:
   collapsed), f_e = first-choice token fraction, p_e = mean router prob;
 - max_share: largest single expert's first-choice share (uniform = 1/E).
 
-Prints one JSON line. CPU-runnable (tiny shapes); the companion perf
-bench (scripts/bench_moe.py) needs the chip.
+Prints one JSON line. CPU-runnable (tiny shapes); the expert layer's
+speed is the benchmark's cell `olmoe1b7b-s4k` (PERF.md).
 """
 
 import json

@@ -8,10 +8,7 @@ training paths use, runs it, and compares it with its reference:
 
 - flash attention forward + backward (``impl="pallas"``) against the XLA
   attention, at head width 64 (the zoo transformer) and 256, S=1024,
-  and alone at S=16384;
-- the device-tier insert-gather and scatter-apply pair
-  (``kernel="pallas"``) against the jnp pair at DeepFM's two tables
-  (dim 8 and dim 1, capacity 65,536 + pad).
+  and alone at S=16384.
 
 A probe, not a benchmark: the ms it prints are a handful of iterations
 of the isolated kernel, enough to tell 2x from 1x and nothing finer.
@@ -33,7 +30,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from elasticdl_tpu.ops import embedding_tier  # noqa: E402
 from elasticdl_tpu.ops.attention import dot_product_attention  # noqa: E402
 
 LINES = []
@@ -113,93 +109,6 @@ def flash_case(batch, heads, seq, head_dim, check=True):
                for d, s in zip(grad_diffs, grad_scale))
 
 
-def tier_case(dim, capacity=65536, rows=19968, staged=1024):
-    """``rows`` = 512 x 39 ids a DeepFM step touches; ``staged`` = the
-    tier's promotions/evictions per step (EDL_DEVICE_TIER_STAGE)."""
-    rng = np.random.RandomState(1)
-    alloc = capacity + 1  # + the scratch row
-
-    def fresh_state():
-        state = embedding_tier.init_table_state(alloc, dim, "adam")
-        for seed, key, scale in ((2, "rows", 1.0), (3, "slot0", 0.1),
-                                 (4, "slot1", 0.1)):
-            state[key] = jnp.asarray(
-                np.random.RandomState(seed).rand(alloc, dim)
-                .astype(np.float32) * scale
-            )
-        state["steps"] = jnp.asarray(
-            np.random.RandomState(5).randint(0, 5, alloc).astype(np.int32)
-        )
-        return state
-
-    perm = rng.permutation(capacity).astype(np.int32)
-    slots = perm[:rows].copy()
-    slots[rng.rand(rows) < 0.3] = -1  # misses
-    ins_slots = perm[rows:rows + staged].copy()
-    ins_slots[staged // 2:] = capacity  # padded with the scratch slot
-    evict_slots = perm[rows + staged:rows + 2 * staged].copy()
-    live_evictions = staged // 3
-    evict_slots[live_evictions:] = capacity
-    ins_rows = rng.rand(staged, dim).astype(np.float32)
-    miss_rows = rng.rand(rows, dim).astype(np.float32)
-    grads = jnp.asarray(rng.rand(rows, dim).astype(np.float32))
-    gather_args = [jnp.asarray(x) for x in (
-        ins_slots, ins_rows, evict_slots, slots, miss_rows
-    )]
-    slots = jnp.asarray(slots)
-    results = {}
-    for kernel in ("jnp", "pallas"):
-        insert_gather = jax.jit(
-            lambda state, *args, kernel=kernel:
-            embedding_tier.fused_insert_gather(
-                state, *args, kernel=kernel
-            ),
-            donate_argnums=(0,),
-        )
-        scatter_apply = jax.jit(
-            lambda state, slots, grads, kernel=kernel:
-            embedding_tier.fused_scatter_apply(
-                state, slots, grads, opt_type="adam", lr=0.001,
-                kernel=kernel,
-            ),
-            donate_argnums=(0,),
-        )
-        state, combined, evicted = insert_gather(
-            fresh_state(), *gather_args
-        )
-        state = scatter_apply(state, slots, grads)
-        jax.block_until_ready(state)
-        start = time.perf_counter()
-        for _ in range(3):
-            state = scatter_apply(state, slots, grads)
-        jax.block_until_ready(state)
-        say("  %s dim=%d: scatter_apply %.3f ms"
-            % (kernel, dim, (time.perf_counter() - start) / 3 * 1e3))
-        start = time.perf_counter()
-        for _ in range(3):
-            state, combined_again, _ = insert_gather(state, *gather_args)
-        jax.block_until_ready(combined_again)
-        say("  %s dim=%d: insert_gather %.3f ms"
-            % (kernel, dim, (time.perf_counter() - start) / 3 * 1e3))
-        results[kernel] = (
-            jax.tree_util.tree_map(np.asarray, state),
-            np.asarray(combined),
-            np.asarray(evicted)[:live_evictions],
-            np.asarray(combined_again),
-        )
-    ref, got = results["jnp"], results["pallas"]
-    diffs = [float(np.abs(a - b).max()) for a, b in zip(ref[1:], got[1:])]
-    say("  combined / evicted / combined-after-training diffs %s" % diffs)
-    assert max(diffs) < 1e-4
-    for key in ref[0]:
-        diff = float(np.abs(
-            ref[0][key][:capacity].astype(np.float64)
-            - got[0][key][:capacity].astype(np.float64)
-        ).max())
-        say("  state[%s] diff %.3g" % (key, diff))
-        assert diff < 1e-4, key
-
-
 def main():
     device = jax.devices()[0]
     say("device %s %s x%d, jax %s" % (
@@ -211,8 +120,6 @@ def main():
     section("flash head 256, B2 H8 S1024", flash_case, 2, 8, 1024, 256)
     section("flash head 256, B1 H8 S16384 (no reference)",
             flash_case, 1, 8, 16384, 256, check=False)
-    section("tier kernels dim 8, capacity 65536", tier_case, 8)
-    section("tier kernels dim 1, capacity 65536", tier_case, 1)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/probe_kernels.txt", "w") as out:
         out.write("\n".join(LINES) + "\n")

@@ -3,9 +3,8 @@
 Two halves:
 
 - the original per-HLO-category breakdown of a ``jax.profiler``
-  capture, used by scripts/profile_resnet.py and
-  scripts/bench_transformer_mfu.py (the evidence generators behind
-  docs/PERF_RESNET.md and docs/PERF_TRANSFORMER.md);
+  capture (``summarize_trace``; the benchmark's own reduction is
+  benchmark/lib/trace_reduce.py);
 - ISSUE 9: a summary of an ``EDL_TRACE_DIR`` capture grouped by the
   propagated ``trace_id`` — per-span-name stats (count / p50 / p99)
   plus a per-trace duration table with the slowest-N traces, each

@@ -164,79 +164,6 @@ def test_flash_ring_agrees_with_einsum_ring():
     np.testing.assert_allclose(a, b, atol=3e-5, rtol=3e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_bshd_layout_matches_bhsd(causal):
-    """The fused-head BSHD layout (no transposes) must agree with the
-    BHSD kernel and the XLA oracle, forward and backward."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from elasticdl_tpu.ops.attention import dot_product_attention
-
-    rng = np.random.RandomState(0)
-    B, H, S, D = 2, 2, 256, 128  # D lane-aligned: the bshd requirement
-    q_bshd, k_bshd, v_bshd = [
-        jnp.asarray(rng.randn(B, S, H, D), jnp.float32) for _ in range(3)
-    ]
-    to_bhsd = lambda t: t.transpose(0, 2, 1, 3)
-
-    def loss(q, k, v, impl, layout):
-        out = dot_product_attention(
-            q, k, v, causal=causal, impl=impl, layout=layout,
-            interpret=True,
-        )
-        return (out.astype(jnp.float32) ** 2).sum()
-
-    val_ref, grads_ref = jax.value_and_grad(
-        lambda q, k, v: loss(q, k, v, "xla", "bhsd"), argnums=(0, 1, 2)
-    )(to_bhsd(q_bshd), to_bhsd(k_bshd), to_bhsd(v_bshd))
-    val_bshd, grads_bshd = jax.value_and_grad(
-        lambda q, k, v: loss(q, k, v, "pallas", "bshd"),
-        argnums=(0, 1, 2),
-    )(q_bshd, k_bshd, v_bshd)
-
-    np.testing.assert_allclose(
-        float(val_ref), float(val_bshd), rtol=1e-5
-    )
-    for g_ref, g_bshd in zip(grads_ref, grads_bshd):
-        np.testing.assert_allclose(
-            np.asarray(to_bhsd(g_bshd)),
-            np.asarray(g_ref),
-            atol=2e-2, rtol=1e-3,
-        )
-
-
-def test_flash_bshd_small_heads_fall_back():
-    """head_dim not lane-aligned: auto must not pick the fused path,
-    and an explicit pallas request goes through the transpose adapter
-    and still matches the oracle."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from elasticdl_tpu.ops.attention import (
-        _pallas_refusal,
-        dot_product_attention,
-    )
-
-    rng = np.random.RandomState(1)
-    q, k, v = [
-        jnp.asarray(rng.randn(2, 256, 2, 16), jnp.float32)
-        for _ in range(3)
-    ]
-    assert "head_dim" in _pallas_refusal(q, k, None, None, "bshd")
-    out = dot_product_attention(
-        q, k, v, causal=True, impl="pallas", layout="bshd",
-        interpret=True,
-    )
-    ref = dot_product_attention(
-        q, k, v, causal=True, impl="xla", layout="bshd"
-    )
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(ref), atol=1e-5
-    )
-
-
 def test_rotary_seq_axis_variants_agree():
     """rotary_embedding(seq_axis=1) on (B, S, H, d) must equal the
     transposed seq_axis=2 result on (B, H, S, d)."""
@@ -245,27 +172,24 @@ def test_rotary_seq_axis_variants_agree():
 
     from elasticdl_tpu.models.transformer import rotary_embedding
 
-    x_bshd = jnp.asarray(
+    x = jnp.asarray(
         np.random.RandomState(3).randn(2, 32, 4, 16), jnp.float32
     )
-    via_bshd = rotary_embedding(x_bshd, seq_axis=1)
-    via_bhsd = rotary_embedding(
-        x_bshd.transpose(0, 2, 1, 3), seq_axis=2
+    seq_first = rotary_embedding(x, seq_axis=1)
+    heads_first = rotary_embedding(
+        x.transpose(0, 2, 1, 3), seq_axis=2
     ).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(
-        np.asarray(via_bshd), np.asarray(via_bhsd), atol=1e-6
+        np.asarray(seq_first), np.asarray(heads_first), atol=1e-6
     )
 
 
-def test_attention_rejects_unknown_layout():
-    import jax.numpy as jnp
-    import numpy as np
-
+def test_attention_rejects_unknown_impl():
     from elasticdl_tpu.ops.attention import dot_product_attention
 
-    q = jnp.asarray(np.zeros((1, 2, 16, 8)), jnp.float32)
-    with pytest.raises(ValueError, match="layout"):
-        dot_product_attention(q, q, q, layout="BHSD")
+    q = jnp.zeros((1, 2, 16, 8), jnp.float32)
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        dot_product_attention(q, q, q, impl="flash")
 
 
 def test_pallas_attention_sharded_over_mesh_matches_oracle():
@@ -340,48 +264,49 @@ def test_pallas_attention_sharded_over_mesh_matches_oracle():
 # The fused backward (flash_bwd: dq accumulated beside dk and dv) against
 # XLA's autodiff of the plain attention and against the split
 # flash_dq / flash_dkv pair it falls back to above its VMEM budget.
-# (layout, causal, heads' width, seq_q, seq_k, block_q, block_k, dtype)
+# (causal, heads' width, seq_q, seq_k, block_q, block_k, dtype)
 FUSED_BACKWARD_CASES = {
     "causal-several-blocks-d128":
-        ("bhsd", True, 128, 512, 512, 128, 256, jnp.float32),
+        (True, 128, 512, 512, 128, 256, jnp.float32),
     "full-several-blocks-d128":
-        ("bhsd", False, 128, 512, 512, 128, 256, jnp.float32),
+        (False, 128, 512, 512, 128, 256, jnp.float32),
     "causal-one-block-each-d256":
-        ("bhsd", True, 256, 256, 256, 256, 256, jnp.float32),
+        (True, 256, 256, 256, 256, 256, jnp.float32),
     "full-several-blocks-d256":
-        ("bhsd", False, 256, 256, 256, 128, 128, jnp.float32),
+        (False, 256, 256, 256, 128, 128, jnp.float32),
     # the ring's call: a block of another rank's keys, never causal
     "full-seq-q-shorter-than-seq-k":
-        ("bhsd", False, 128, 256, 512, 128, 128, jnp.float32),
+        (False, 128, 256, 512, 128, 128, jnp.float32),
     "full-seq-q-longer-than-seq-k":
-        ("bhsd", False, 128, 512, 256, 128, 256, jnp.float32),
-    "bshd-causal-several-blocks-d128":
-        ("bshd", True, 128, 512, 512, 128, 256, jnp.float32),
-    "bshd-full-d256":
-        ("bshd", False, 256, 256, 256, 128, 128, jnp.float32),
+        (False, 128, 512, 256, 128, 256, jnp.float32),
+    # the pythia cells' class of shape: causal, head 256, several blocks
+    "causal-several-blocks-d256":
+        (True, 256, 512, 512, 128, 256, jnp.float32),
+    "causal-several-blocks-bfloat16-d256":
+        (True, 256, 512, 512, 128, 256, jnp.bfloat16),
     "causal-bfloat16-d128":
-        ("bhsd", True, 128, 512, 512, 128, 256, jnp.bfloat16),
+        (True, 128, 512, 512, 128, 256, jnp.bfloat16),
     "causal-block-k-below-block-q":
-        ("bhsd", True, 128, 512, 512, 256, 128, jnp.float32),
+        (True, 128, 512, 512, 256, 128, jnp.float32),
 }
 
 
 def _flash_grads(case):
     from elasticdl_tpu.ops.attention import dot_product_attention
 
-    layout, causal, dim, seq_q, seq_k, block_q, block_k, dtype = case
+    causal, dim, seq_q, seq_k, block_q, block_k, dtype = case
     rng = np.random.RandomState(7)
 
     def mk(seq):
-        shape = (2, 2, seq, dim) if layout == "bhsd" else (2, seq, 2, dim)
-        return jnp.asarray(rng.normal(size=shape, scale=0.5), dtype)
+        return jnp.asarray(
+            rng.normal(size=(2, 2, seq, dim), scale=0.5), dtype)
 
     q, k, v = mk(seq_q), mk(seq_k), mk(seq_k)
 
     def loss(impl, **kw):
         def fn(q, k, v):
             out = dot_product_attention(
-                q, k, v, causal=causal, impl=impl, layout=layout, **kw
+                q, k, v, causal=causal, impl=impl, **kw
             ).astype(jnp.float32)
             return jnp.sum(out * jnp.cos(out))
         return jax.grad(fn, argnums=(0, 1, 2))
@@ -465,7 +390,7 @@ def test_attention_log_line_says_which_backward(monkeypatch, caplog):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
-        attention, "_pallas_attention", lambda q, k, v, **kw: q)
+        attention._flash, "flash_attention", lambda q, k, v, **kw: q)
     attention._log_auto_once.cache_clear()
     q = jnp.zeros((1, 2, 2048, 128), jnp.bfloat16)
     with caplog.at_level(logging.INFO, logger=attention.logger.name):
@@ -475,5 +400,5 @@ def test_attention_log_line_says_which_backward(monkeypatch, caplog):
     lines = [r.getMessage() for r in caplog.records]
     assert lines[0] == (
         "attention impl=auto resolved to pallas (backend=tpu, "
-        "q=(1, 2, 2048, 128) bfloat16 bhsd, flash backward=fused)")
+        "q=(1, 2, 2048, 128) bfloat16, flash backward=fused)")
     assert "resolved to xla" in lines[1] and "backward" not in lines[1]

@@ -1,5 +1,5 @@
 """chip_smoke.py and what ISSUE 21 put under it: the compile-cache
-placement, the peaks table, bench.py's exit code, the smoke's refusal
+placement, the peaks table, the smoke's refusal
 to run without a chip, and a CPU rehearsal of both smoke phases at a
 tiny size through the same code the chip run uses."""
 
@@ -73,7 +73,7 @@ def test_compile_cache_default_is_fixed_inside_checkout(monkeypatch):
 def test_no_cache_dir_literal_outside_the_helper():
     hits = subprocess.run(
         ["grep", "-rlI", "jax_compilation_cache_dir", "--include=*.py",
-         "elasticdl_tpu", "scripts", "tests", "bench.py",
+         "elasticdl_tpu", "scripts", "tests",
          "chip_smoke.py", "__graft_entry__.py"],
         capture_output=True, text=True, cwd=REPO,
     ).stdout.split()
@@ -91,54 +91,6 @@ def test_peak_flops_known_and_unknown_device():
     assert platform.peak_flops("TPU v5 lite") == 197e12
     with pytest.raises(KeyError, match="cpu"):
         platform.peak_flops("cpu")
-
-
-# ---------------------------------------------------------------------
-# bench.py fails when a configuration fails
-
-
-def _run_bench_main(monkeypatch, capsys, failing):
-    import bench
-
-    monkeypatch.setattr(
-        bench, "_probe_device",
-        lambda: {"platform": "tpu", "kind": "fake", "count": 1},
-    )
-    monkeypatch.setattr(
-        platform, "configure_compile_cache", lambda: None
-    )
-    for name in ("bench_transformer_mfu", "bench_gradaccum_mfu",
-                 "bench_s16k_flash_mfu", "bench_moe_mfu",
-                 "bench_deepfm", "bench_deepfm_latency_ab"):
-        def fn(name=name):
-            if name == failing:
-                raise RuntimeError("mosaic said no")
-            return {name: 1.0}
-        monkeypatch.setattr(bench, name, fn)
-    monkeypatch.setattr(bench, "bench_resnet", lambda: 10.0)
-    try:
-        bench.main()
-        code = 0
-    except SystemExit as e:
-        code = e.code
-    line = capsys.readouterr().out.strip().splitlines()[-1]
-    return code, json.loads(line)
-
-
-def test_bench_exits_nonzero_when_a_sub_bench_raises(monkeypatch, capsys):
-    code, result = _run_bench_main(monkeypatch, capsys, "bench_moe_mfu")
-    assert code not in (0, None)
-    assert "mosaic said no" in result["extra"]["moe_error"]
-    # the configurations after the failed one still ran
-    assert result["extra"]["bench_deepfm"] == 1.0
-    assert result["value"] == 10.0
-
-
-def test_bench_exits_zero_when_all_configurations_run(monkeypatch, capsys):
-    code, result = _run_bench_main(monkeypatch, capsys, None)
-    assert code == 0
-    assert not [k for k in result["extra"] if k.endswith("_error")]
-    assert result["extra"]["device"]["kind"] == "fake"
 
 
 # ---------------------------------------------------------------------
@@ -293,7 +245,7 @@ def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
         assert not problems, problems
         assert report["steps"] == 8
         assert report["store_backend"] == ["native", "native"]
-        assert report["tier_kernel"] == "jnp" and report["tier_hits"] > 0
+        assert report["tier_hits"] > 0
         children.stop_all()
         # four devices, as on the four-chip host: the worker picks the
         # SPMD trainer and the model receives its mesh. And the checks

@@ -1,5 +1,5 @@
 """ISSUE 6 device-resident embedding tier, end to end: fused
-gather/scatter-apply kernel parity (jnp vs Pallas-interpret),
+gather/scatter-apply against the PS store's update math,
 promotion-after-k-hits, LFU/TTL demotion with eviction writeback,
 miss-path pull parity (never-promote config bit-exact vs tier-off),
 flush-before-checkpoint ordering, PS-restart flush-then-invalidate,
@@ -91,7 +91,7 @@ def test_jnp_insert_gather_semantics():
     ev = jnp.asarray(np.array([1, 8], np.int32))
     new_state, combined, evicted = tier_ops.fused_insert_gather(
         state, ins_slots, jnp.asarray(ins_rows), ev, slots,
-        jnp.asarray(miss), kernel="jnp",
+        jnp.asarray(miss),
     )
     # victims read out BEFORE inserts land
     assert np.allclose(np.asarray(evicted)[0], rows0[1])
@@ -132,61 +132,12 @@ def test_jnp_scatter_apply_matches_store_math(opt_type):
         store.push_gradients("t", ids, grads)
         state = tier_ops.fused_scatter_apply(
             state, slots, jnp.asarray(grads), opt_type=opt_type,
-            lr=0.05, kernel="jnp",
+            lr=0.05,
         )
     np.testing.assert_allclose(
         np.asarray(state["rows"])[:n], store.lookup("t", ids),
         rtol=1e-5, atol=1e-6,
     )
-
-
-def test_pallas_interpret_matches_jnp():
-    """The Pallas kernels (interpret mode on CPU — same code path as
-    TPU minus the Mosaic lowering) agree with the jnp fallback on
-    everything but the scratch row (whose contents are garbage by
-    contract)."""
-    import jax.numpy as jnp
-
-    old = tier_ops.INTERPRET
-    tier_ops.INTERPRET = True
-    try:
-        rng = np.random.RandomState(2)
-        state = _rand_state(rng, 9, 8, "adam")
-        slots = jnp.asarray(np.array([0, 3, -1, 5, -1], np.int32))
-        miss = jnp.asarray(rng.rand(5, 8).astype(np.float32))
-        ins_slots = jnp.asarray(np.array([7, 8], np.int32))
-        ins_rows = jnp.asarray(rng.rand(2, 8).astype(np.float32))
-        ev = jnp.asarray(np.array([1, 8], np.int32))
-        a = tier_ops.fused_insert_gather(
-            dict(state), ins_slots, ins_rows, ev, slots, miss,
-            kernel="jnp",
-        )
-        b = tier_ops.fused_insert_gather(
-            dict(state), ins_slots, ins_rows, ev, slots, miss,
-            kernel="pallas",
-        )
-        assert np.allclose(np.asarray(a[1]), np.asarray(b[1]))
-        assert np.allclose(np.asarray(a[2]), np.asarray(b[2]))
-        for key in a[0]:
-            assert np.allclose(
-                np.asarray(a[0][key])[:8], np.asarray(b[0][key])[:8]
-            ), key
-        grads = jnp.asarray(rng.rand(5, 8).astype(np.float32))
-        sa = tier_ops.fused_scatter_apply(
-            dict(state), slots, grads, opt_type="adam", lr=0.01,
-            kernel="jnp",
-        )
-        sb = tier_ops.fused_scatter_apply(
-            dict(state), slots, grads, opt_type="adam", lr=0.01,
-            kernel="pallas",
-        )
-        for key in sa:
-            assert np.allclose(
-                np.asarray(sa[key])[:8], np.asarray(sb[key])[:8],
-                atol=1e-6,
-            ), key
-    finally:
-        tier_ops.INTERPRET = old
 
 
 # ---------------------------------------------------------------------
@@ -559,19 +510,3 @@ def test_telemetry_blob_tier_fields_reach_statusz():
     assert entry["tier_hit_rate"] == pytest.approx(0.93, abs=1e-4)
     assert entry["tier_hits"] == 930
     assert entry["tier_evictions"] == 3
-
-
-def test_auto_kernel_is_jnp_on_every_backend(monkeypatch):
-    """``auto`` means jnp wherever the tier runs — the Pallas pair
-    matched it exactly on the v5e but was slower, and cannot run on a
-    mesh or the CPU (ops/embedding_tier.py) — and pallas runs only when
-    named."""
-    import jax
-
-    assert tier_ops.resolve_kernel("auto") == "jnp"  # CPU backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert tier_ops.resolve_kernel("auto") == "jnp"
-    assert tier_ops.resolve_kernel() == "jnp"
-    assert tier_ops.resolve_kernel("pallas") == "pallas"
-    with pytest.raises(ValueError, match="must be auto"):
-        tier_ops.resolve_kernel("mosaic")

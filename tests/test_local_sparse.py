@@ -9,7 +9,10 @@ def test_deepfm_local_executor(tmp_path):
     valid_dir = tmp_path / "valid"
     train_dir.mkdir()
     valid_dir.mkdir()
-    create_ctr_recordio(str(train_dir / "f0.rec"), num_records=512, seed=0)
+    # 2048 records: 512 show each of the 1000 ids about five times, too
+    # few to learn its planted weight (validation AUC stays under 0.8
+    # and falls with every further epoch)
+    create_ctr_recordio(str(train_dir / "f0.rec"), num_records=2048, seed=0)
     create_ctr_recordio(str(valid_dir / "f0.rec"), num_records=128, seed=1)
     executor = LocalExecutor(
         "elasticdl_tpu.models.deepfm",

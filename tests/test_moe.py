@@ -211,7 +211,7 @@ def test_expert_balance_holds_over_a_real_run():
     trained from a deliberately COLLAPSED router (expert 0 hoards >55%
     of first choices), the run must both fit the task and return to
     near-uniform routing. Full experiment (incl. the no-aux arm):
-    scripts/convergence_moe.py, docs/PERF_MOE.md."""
+    scripts/convergence_moe.py."""
     import sys
 
     sys.path.insert(0, os.path.join(REPO, "scripts"))

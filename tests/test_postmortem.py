@@ -1,7 +1,7 @@
 """Postmortem tooling (ISSUE 3): journal/dump merge + ordering +
 correlation threading in scripts/postmortem.py, and first-ever coverage
-for scripts/trace_summary.py (the per-HLO-category breakdown the perf
-docs are generated from)."""
+for scripts/trace_summary.py (the per-HLO-category breakdown of a
+``jax.profiler`` capture)."""
 
 import gzip
 import json

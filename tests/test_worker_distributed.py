@@ -363,9 +363,8 @@ def test_job_completes_when_dataset_not_batch_divisible(tmp_path):
     deadlock the job — the elastic stream WAIT-loops (never "ends"),
     so batch() held the tail forever while the master waited for its
     task to be reported. The WAIT now emits a pipeline.FLUSH that
-    forces the partial (masked) batch out. Found by the co-location
-    harness (scripts/bench_utilization.py), whose digits dataset is
-    1,797 records."""
+    forces the partial (masked) batch out. Found on a digits dataset
+    of 1,797 records."""
     train_dir = tmp_path / "train"
     valid_dir = tmp_path / "valid"
     train_dir.mkdir()
