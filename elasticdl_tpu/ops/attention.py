@@ -43,7 +43,8 @@ def _pallas_refusal(q, k, block_q, block_k):
     seq_q, seq_k = q.shape[2], k.shape[2]
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
-    block_q, block_k = _flash._blocks(seq_q, seq_k, block_q, block_k)
+    block_q, block_k = _flash._blocks(
+        seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k)
     if seq_q % block_q or seq_k % block_k:
         return "seq (%d, %d) not divisible by blocks (%d, %d)" % (
             seq_q, seq_k, block_q, block_k,
@@ -54,15 +55,38 @@ def _pallas_refusal(q, k, block_q, block_k):
     return ""
 
 
+def _flash_facts(q, k, causal, block_q, block_k):
+    """What the flash kernel does with these shapes, for the log line:
+    which backward (``flash_attention.backward_schedule``; a model's
+    float32 init trace may read ``split`` where its bfloat16 step reads
+    ``fused``: the dtype is on the line for that), and how many of one
+    head's (q-block, k-block) grid steps compute a tile, how many of
+    those apply the causal mask, and how many are skipped
+    (``flash_attention.causal_pairs``): the forward's, and the
+    backward's where ``_blocks`` gives it other blocks."""
+    shapes = (q.shape[2], k.shape[2], q.shape[-1], q.dtype)
+
+    def pairs(backward):
+        blocks = _flash._blocks(
+            *shapes, block_q, block_k, backward=backward)
+        return "run=%d masked=%d skipped=%d" % _flash.causal_pairs(
+            *shapes[:2], *blocks, causal=causal)
+
+    forward, backward = pairs(False), pairs(True)
+    return "flash backward=%s, pairs %s%s" % (
+        _flash.backward_schedule(*shapes, block_q, block_k),
+        forward,
+        " (backward %s)" % backward if backward != forward else "",
+    )
+
+
 @functools.lru_cache(maxsize=None)
-def _log_auto_once(backend, impl, reason, q_shape, q_dtype, backward):
+def _log_auto_once(backend, impl, reason, q_shape, q_dtype, flash):
     """One line per distinct resolution (this runs at trace time, once
     per attention layer per trace). A TPU backend that resolves to the
     XLA reference is a warning: the O(S^2) path is running where the
-    kernel was expected. ``backward``: which backward the flash kernel
-    gives these shapes (``flash_attention.backward_schedule``; a model's
-    float32 init trace may read ``split`` where its bfloat16 step reads
-    ``fused``: the dtype is on the line for that)."""
+    kernel was expected. ``flash``: ``_flash_facts`` where the kernel
+    runs."""
     log = (
         logger.warning if backend == "tpu" and impl == "xla"
         else logger.info
@@ -71,7 +95,7 @@ def _log_auto_once(backend, impl, reason, q_shape, q_dtype, backward):
         "attention impl=auto resolved to %s (backend=%s, q=%s %s%s%s)",
         impl, backend, q_shape, q_dtype,
         ", reason: %s" % reason if reason else "",
-        ", flash backward=%s" % backward if backward else "",
+        ", %s" % flash if flash else "",
     )
 
 
@@ -131,10 +155,8 @@ def dot_product_attention(
         impl = "xla" if reason else "pallas"
         _log_auto_once(
             backend, impl, reason, tuple(q.shape), q.dtype.name,
-            "" if reason else _flash.backward_schedule(
-                q.shape[2], k.shape[2], q.shape[-1],
-                q.dtype, block_q, block_k,
-            ),
+            "" if reason else _flash_facts(
+                q, k, causal, block_q, block_k),
         )
     if impl == "pallas":
         kernel = functools.partial(

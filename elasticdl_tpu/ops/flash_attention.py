@@ -25,6 +25,28 @@ no state that grows with the sequence. One algorithm under two
 schedules, chosen by shape: gradients agree to the last bit in
 interpret mode (tests/test_attention_ops.py).
 
+A causal call's grid still has a step for every (q-block, k-block)
+pair, and each pair is one of three classes, decided from its two block
+indices and the two block sizes (``_causal_pair``), each paying only for
+what it needs. A SKIPPED pair (wholly above the diagonal) computes
+nothing and fetches nothing: the index maps clamp the moving block index
+to the nearest pair that runs, so the pipeline finds the block already
+in VMEM and issues no DMA; what is left of it is the grid step itself.
+An INTERIOR pair (wholly below) runs the tile without the mask, whose
+select would keep every element: the two iotas, the compare and the
+select are about 6 of the ~15 vector operations an element of the
+forward's tile costs, on a vector unit with no bfloat16 path. A DIAGONAL
+pair runs the tile with the mask. At 16,384 with blocks 1024 / 1024 a
+head has 136 pairs that run, 16 of them diagonal, and 120 skipped; at
+2048 the forward (512 / 1024) has 6, 4 and 2, the backward (512 / 512)
+10, 4 and 6 (``causal_pairs``; ``ops/attention.py`` logs the counts;
+``_blocks`` chooses the blocks, for each kernel apart, and says what
+was read on the chip). A call that is not causal (the ring's
+off-diagonal shards) has the identity index maps and one unmasked
+body. On a v5e, 8 x 16,384 x 256 bfloat16 at blocks 512 / 1024, the
+three classes took the forward from 10.54 to 8.48 ms and ``flash_bwd``
+from 17.02 to 16.47, every output bit unchanged (PERF.md, PR 28).
+
 Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
 into one parallel grid axis and see one head's (seq, head_dim) rows.
 """
@@ -34,6 +56,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -57,20 +80,56 @@ def _auto_block(seq, cap):
     return block if seq % block == 0 else min(seq, 128)
 
 
-def _blocks(seq_q, seq_k, block_q, block_k):
-    """The blocks a call runs with: ``None`` is the largest power of
-    two (up to 512 / 1024) that divides the sequence."""
+def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
+            backward=False):
+    """The blocks a kernel runs with: ``None`` is the largest power of
+    two, up to a cap chosen from the shapes, that divides the sequence.
+    The forward and the backward of one call each ask for their own.
+
+    The caps are 512 q-rows by 1024 k-rows, with two departures. What
+    a block pair costs decides them (module docstring): a skipped pair
+    costs its grid step (~0.4 us) and an interior pair no mask, so a
+    smaller block buys less skipped work than it pays in steps and in
+    rescales of the accumulator, except where the diagonal is most of
+    the grid. Read IN THE STEP (the isolated kernel gives the sign, not
+    the size), v5e, bfloat16, PR 28 (PERF.md Section 6):
+
+    - 1024 q-rows from 8192 tokens on. 16,384 x 256
+      (``pythia1b-s16k``): 1024 / 1024 against 512 / 1024 is +2.0%
+      samples/s (``flash_fwd`` 66.3 -> 60.4 ms a step, ``flash_bwd``
+      129.1 -> 127.0): 136 pairs run and 120 are skipped a head where
+      272 and 240 were. Not where a q-row is over 512 bytes (256 x
+      float32: the v5e compiler refuses the forward, its blocks and
+      score temporaries pass the 16 MiB scoped VMEM), and not where the
+      taller block would push ``flash_bwd`` over its budget (24,576 x
+      256: the fused backward is worth more than the block; at 16,384 x
+      256 ``fused_bwd_vmem_bytes`` says 56 of 64 MiB). At 4096 x 128
+      (``olmoe1b7b-s4k``) 1024 / 1024 read inside the cell's spread:
+      left.
+    - 512 k-rows for the BACKWARD up to 2048 tokens. 2048 x 256
+      (``pythia1b-s2k``): 10 half-size tiles for 8 halves' needed work
+      where 512 / 1024 computes 6 whole ones for 4 (a ceiling of 80%
+      where that one is 67%); ``flash_bwd`` 11.77 -> 10.59 ms a step,
+      +0.54% samples/s. The forward loses by the same blocks (0.86 ->
+      0.96 ms a layer alone: twice the rescales of its accumulator),
+      so with 512 / 512 in both the step gains half as much (+0.26%);
+      at 4096 x 128 they cost the forward 58%.
+    """
+    if block_k is None:
+        short = backward and seq_k <= 2048
+        block_k = _auto_block(seq_k, 512 if short else 1024)
     if block_q is None:
         block_q = _auto_block(seq_q, 512)
-    if block_k is None:
-        # Smaller causal k-blocks (512) look 30-40% faster in an
-        # ISOLATED kernel fwd+bwd micro-bench (above-diagonal blocks
-        # skip compute), but inside the full jitted train step the
-        # effect is noise at S<=2k and a 1-2% REGRESSION at S=4-8k —
-        # XLA's surrounding schedule absorbs the skip and the extra
-        # k-iterations cost loop overhead. Defaults follow the in-model
-        # measurement; pass block_k explicitly to retune.
-        block_k = _auto_block(seq_k, 1024)
+        itemsize = jnp.dtype(dtype).itemsize
+        tall = _auto_block(seq_q, 1024)
+        if (
+            seq_q >= 8192
+            and head_dim * itemsize <= 512
+            and fused_bwd_vmem_bytes(
+                seq_q, head_dim, tall, block_k, itemsize
+            ) <= _FUSED_VMEM_BYTES
+        ):
+            block_q = tall
     return min(block_q, seq_q), min(block_k, seq_k)
 
 
@@ -82,6 +141,65 @@ def _causal_mask(s, q_block, k_block, block_q, block_k):
         jnp.int32, s.shape, 1
     )
     return jnp.where(q_pos >= k_pos, s, NEG_INF)
+
+
+def _causal_pair(q_block, k_block, block_q, block_k):
+    """What the causal diagonal means for the pair (q-block, k-block),
+    from the two block indices and the two block sizes alone:
+    ``(last_k, first_q, masked)``.
+
+    ``last_k`` is the last k-block that ``q_block`` needs and
+    ``first_q`` the first q-block that ``k_block`` needs (neither held
+    to the grid: a sequence may end before it). The pair RUNS when
+    ``k_block <= last_k``, which is ``q_block >= first_q``; every other
+    pair lies wholly above the diagonal. ``masked``: some element of the
+    tile has ``q_pos < k_pos``, so a pair that runs needs
+    ``_causal_mask``; where it is false the select would keep every
+    element. The kernels' ``pl.when`` predicates, the index maps' clamps
+    and ``causal_pairs`` all read this one function."""
+    # non-negative operands: the truncating division is the floor, and
+    # one scalar instruction where a traced ``//`` is five
+    div = (
+        jax.lax.div if isinstance(q_block, jax.Array)
+        else lambda a, b: a // b
+    )
+    last_k = div((q_block + 1) * block_q - 1, block_k)
+    first_q = div(k_block * block_k, block_q)
+    masked = (k_block + 1) * block_k - 1 > q_block * block_q
+    return last_k, first_q, masked
+
+
+def causal_pairs(seq_q, seq_k, block_q, block_k, causal=True):
+    """``(run, masked, skipped)``: of one head's (q-block, k-block)
+    pairs, how many compute a tile, how many of those apply the causal
+    mask, and how many are grid steps that compute and fetch nothing.
+    A function of shapes (``ops/attention.py`` logs it)."""
+    num_q, num_k = seq_q // block_q, seq_k // block_k
+    if not causal:
+        return num_q * num_k, 0, 0
+    q_block = np.arange(num_q)[:, None]
+    k_block = np.arange(num_k)[None, :]
+    last_k, _, masked = _causal_pair(q_block, k_block, block_q, block_k)
+    run = k_block <= last_k
+    return (
+        int(run.sum()), int((run & masked).sum()), int((~run).sum())
+    )
+
+
+def _each_class(causal, q_block, k_block, block_q, block_k, tile):
+    """``tile(masked)`` once for the pair's class: a diagonal pair with
+    the mask, an interior one without (a body of its own, so the iotas,
+    the compare and the select are not in it), nothing for a skipped
+    pair. A call that is not causal has the one unmasked body."""
+    if not causal:
+        tile(False)
+        return
+    last_k, _, masked = _causal_pair(q_block, k_block, block_q, block_k)
+    run = k_block <= last_k
+    pl.when(jnp.logical_and(run, masked))(
+        functools.partial(tile, True))
+    pl.when(jnp.logical_and(run, jnp.logical_not(masked)))(
+        functools.partial(tile, False))
 
 
 # ---------------------------------------------------------------------------
@@ -114,15 +232,7 @@ def _fwd_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # Causal: blocks strictly above the diagonal contribute nothing.
-    diag_ok = (
-        (q_block + 1) * block_q - 1 >= k_block * block_k
-        if causal
-        else True
-    )
-
-    @pl.when(diag_ok)
-    def _compute():
+    def _tile(masked):
         # Matmuls run on inputs in their NATIVE dtype with f32 MXU
         # accumulation: for bf16 inputs bf16xbf16->f32 is bit-identical
         # to upcasting first (bf16 products are exact in f32), while an
@@ -140,7 +250,7 @@ def _fwd_kernel(
             )
             * sm_scale
         )
-        if causal:
+        if masked:
             s = _causal_mask(s, q_block, k_block, block_q, block_k)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -155,6 +265,8 @@ def _fwd_kernel(
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
+
     @pl.when(k_block == num_k - 1)
     def _finalize():
         l_final = l_ref[:, :1]
@@ -167,12 +279,39 @@ def _fwd_kernel(
         )
 
 
-def _q_specs():
-    """(q-ish spec, k-ish spec, lse-ish spec) index maps of the merged
-    "(bh, seq, d)" view on a ``(bh, q-block, k-block)`` grid."""
-    q_idx = lambda b, i, j: (b, i, 0)
-    k_idx = lambda b, i, j: (b, j, 0)
-    stat_idx = lambda b, i, j: (b, 0, i)
+def _index_maps(causal, block_q, block_k, num_q, k_outer=False):
+    """(q-ish, k-ish, lse-ish) index maps of the merged "(bh, seq, d)"
+    view, on a ``(bh, q-block, k-block)`` grid or, ``k_outer``, on
+    ``(bh, k-block, q-block)``.
+
+    A causal grid's skipped steps name the block of the nearest step
+    that runs, so the pipeline, which copies a block only when its
+    index changes, fetches nothing for them: the inner axis is clamped
+    to ``_causal_pair``'s bound (k-blocks from above by ``last_k``;
+    q-blocks from below by ``first_q``, held to the grid where seq_q
+    ends before the k-block starts). Only inputs move with the inner
+    axis; outputs follow the outer one, which is never clamped. A call
+    that is not causal gets the identity."""
+
+    def q_block(outer, inner):
+        if not k_outer:
+            return outer
+        if not causal:
+            return inner
+        _, first_q, _ = _causal_pair(inner, outer, block_q, block_k)
+        return jnp.minimum(jnp.maximum(inner, first_q), num_q - 1)
+
+    def k_block(outer, inner):
+        if k_outer:
+            return outer
+        if not causal:
+            return inner
+        last_k, _, _ = _causal_pair(outer, inner, block_q, block_k)
+        return jnp.minimum(inner, last_k)
+
+    q_idx = lambda b, outer, inner: (b, q_block(outer, inner), 0)
+    k_idx = lambda b, outer, inner: (b, k_block(outer, inner), 0)
+    stat_idx = lambda b, outer, inner: (b, 0, q_block(outer, inner))
     return q_idx, k_idx, stat_idx
 
 
@@ -188,6 +327,8 @@ def _out_struct(shape, dtype, *operands):
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     bh, seq_q, head_dim = q.shape
     seq_k = k.shape[1]
+    block_q, block_k = _blocks(
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     grid = (bh, num_q, num_k)
@@ -199,7 +340,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         block_q=block_q,
         block_k=block_k,
     )
-    q_idx, k_idx, stat_idx = _q_specs()
+    q_idx, k_idx, stat_idx = _index_maps(causal, block_q, block_k, num_q)
     # lse rides in (bh, 1, seq) — the singleton axis makes the block's
     # second-minor dim equal the full array dim, satisfying the TPU
     # (8, 128) tiling rule that a 2-D (1, block_q) block violates
@@ -242,7 +383,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
 
 
 def _p_and_ds(q, k, v, do, lse_ref, delta_ref, q_block, k_block,
-              sm_scale, causal, block_q, block_k):
+              sm_scale, masked, block_q, block_k):
     """``p = exp(s - lse)`` and ``ds = p * (dp - delta) * sm_scale`` of
     one (q-block, k-block) pair, both float32: the two score-sized
     matmuls (``q k^T``, ``do v^T``) every backward kernel starts from.
@@ -258,7 +399,7 @@ def _p_and_ds(q, k, v, do, lse_ref, delta_ref, q_block, k_block,
         )
         * sm_scale
     )
-    if causal:
+    if masked:
         s = _causal_mask(s, q_block, k_block, block_q, block_k)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
@@ -293,22 +434,17 @@ def _dq_kernel(
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    diag_ok = (
-        (q_block + 1) * block_q - 1 >= k_block * block_k
-        if causal
-        else True
-    )
-
-    @pl.when(diag_ok)
-    def _compute():
+    def _tile(masked):
         k = k_ref[0]
         _, ds = _p_and_ds(
             q_ref[0], k, v_ref[0], do_ref[0], lse_ref, delta_ref,
-            q_block, k_block, sm_scale, causal, block_q, block_k,
+            q_block, k_block, sm_scale, masked, block_q, block_k,
         )
         dq_acc_ref[:] += jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
+
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
 
     @pl.when(k_block == num_k - 1)
     def _finalize():
@@ -363,20 +499,13 @@ def _dkv_kernel(
                 (block_q, dq_acc_ref.shape[1]), jnp.float32
             )
 
-    diag_ok = (
-        (q_block + 1) * block_q - 1 >= k_block * block_k
-        if causal
-        else True
-    )
-
-    @pl.when(diag_ok)
-    def _compute():
+    def _tile(masked):
         q = q_ref[0]
         k = k_ref[0]
         do = do_ref[0]
         p, ds = _p_and_ds(
             q, k, v_ref[0], do, lse_ref, delta_ref,
-            q_block, k_block, sm_scale, causal, block_q, block_k,
+            q_block, k_block, sm_scale, masked, block_q, block_k,
         )
         dv_acc_ref[:] += jax.lax.dot_general(
             p.astype(do.dtype),
@@ -395,6 +524,8 @@ def _dkv_kernel(
             dq_acc_ref[rows, :] += jnp.dot(
                 ds, k, preferred_element_type=jnp.float32
             )
+
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
 
     @pl.when(q_block == num_q - 1)
     def _finalize():
@@ -434,7 +565,8 @@ def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
     the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``: rebuilt
     twice, no state that grows with the sequence) above it. ``_bwd``
     decides by this and ``ops/attention.py`` logs it."""
-    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
+    block_q, block_k = _blocks(
+        seq_q, seq_k, head_dim, dtype, block_q, block_k, backward=True)
     held = fused_bwd_vmem_bytes(
         seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize
     )
@@ -446,13 +578,14 @@ def _bwd(
 ):
     bh, seq_q, head_dim = q.shape
     seq_k = k.shape[1]
+    block_q, block_k = _blocks(
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, backward=True)
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
     dq_idx = lambda b, j, i: (b, 0, 0)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
-    q_idx, k_idx, stat_idx = _q_specs()
     operands = (q, k, v, do, lse, delta)
     statics = dict(
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
@@ -461,9 +594,9 @@ def _bwd(
         seq_q, seq_k, head_dim, q.dtype, block_q, block_k
     ) == "fused"
 
-    def swapped(idx):
-        # the dkv grid iterates (bh, k-block, q-block)
-        return lambda b, j, i: idx(b, i, j)
+    # the dkv grid iterates (bh, k-block, q-block)
+    q_idx, k_idx, stat_idx = _index_maps(
+        causal, block_q, block_k, num_q, k_outer=True)
 
     dq_struct = _out_struct(q.shape, q.dtype, *operands)
     dkv_structs = (
@@ -471,16 +604,16 @@ def _bwd(
         _out_struct(v.shape, v.dtype, *operands),
     )
     dkv_in_specs = [
-        pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
-        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-        pl.BlockSpec((1, block_q, head_dim), swapped(q_idx)),
-        pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
-        pl.BlockSpec((1, 1, block_q), swapped(stat_idx)),
+        pl.BlockSpec((1, block_q, head_dim), q_idx),
+        pl.BlockSpec((1, block_k, head_dim), k_idx),
+        pl.BlockSpec((1, block_k, head_dim), k_idx),
+        pl.BlockSpec((1, block_q, head_dim), q_idx),
+        pl.BlockSpec((1, 1, block_q), stat_idx),
+        pl.BlockSpec((1, 1, block_q), stat_idx),
     ]
     dkv_out_specs = (
-        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
-        pl.BlockSpec((1, block_k, head_dim), swapped(k_idx)),
+        pl.BlockSpec((1, block_k, head_dim), k_idx),
+        pl.BlockSpec((1, block_k, head_dim), k_idx),
     )
     dkv_scratch = [
         pltpu.VMEM((block_k, head_dim), jnp.float32),
@@ -511,6 +644,8 @@ def _bwd(
         )(*operands)
         return dq, dk, dv
 
+    # the split pair's dq grid iterates (bh, q-block, k-block)
+    q_idx, k_idx, stat_idx = _index_maps(causal, block_q, block_k, num_q)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **statics),
         grid=(bh, num_q, num_k),
@@ -639,20 +774,25 @@ def flash_attention(
     are not); head_dim should be a multiple of 128 lanes for best MXU
     utilisation but any size compiles.
 
-    block_q/block_k default to the largest power-of-two blocks (up to
-    512/1024) dividing the sequence: measured on v5e at S=16k, (512,
-    1024) runs 4.6x faster than (128, 128) — bigger k-blocks amortize
-    the online-softmax rescale and keep the MXU fed.
+    block_q/block_k default (``None``) to the largest power-of-two
+    blocks dividing the sequence, up to caps ``_blocks`` chooses from
+    the shapes for the forward and for the backward apart (512 / 1024;
+    1024 / 1024 for long sequences; 512 / 512 for a short one's
+    backward): measured on v5e at S=16k, (512, 1024) runs 4.6x faster
+    than (128, 128) — bigger k-blocks amortize the online-softmax
+    rescale and keep the MXU fed.
     """
     if q.ndim != 4:
         raise ValueError("expected 4-D q/k/v")
     batch, heads, seq_q, head_dim = q.shape
     seq_k = k.shape[2]
-    block_q, block_k = _blocks(seq_q, seq_k, block_q, block_k)
-    if seq_q % block_q or seq_k % block_k:
+    # the forward's blocks; the backward's are these or their halves
+    fwd_q, fwd_k = _blocks(
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k)
+    if seq_q % fwd_q or seq_k % fwd_k:
         raise ValueError(
             "seq lengths (%d, %d) must be multiples of the block sizes "
-            "(%d, %d)" % (seq_q, seq_k, block_q, block_k)
+            "(%d, %d)" % (seq_q, seq_k, fwd_q, fwd_k)
         )
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)

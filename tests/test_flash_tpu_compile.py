@@ -68,11 +68,19 @@ def test_over_the_budget_compiles_to_the_split_pair(chip):
         "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
 
 
-def test_the_count_is_above_what_the_compiler_needs(chip, monkeypatch):
+@pytest.mark.parametrize("mib,blocks", [
+    (57, (1024, 1024)),  # what _blocks gives the cell: it counts 56
+    (48, (512, 1024)),   # a budget the tall block is over: it counts 47
+])
+def test_the_count_is_above_what_the_compiler_needs(
+        chip, monkeypatch, mib, blocks):
     """``fused_bwd_vmem_bytes`` counts generously: with the stated
     limit just above its count for the largest cell, the compiler
-    still accepts the kernel."""
-    monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 48 * 2**20)
-    assert F.fused_bwd_vmem_bytes(16384, 256, 512, 1024, 2) < 48 * 2**20
+    still accepts the kernel; and ``_blocks`` takes 1024 q-rows only
+    where the fused backward fits its budget with them."""
+    monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", mib * 2**20)
+    assert F._blocks(16384, 16384, 256, jnp.bfloat16, None, None) == blocks
+    assert (mib - 1) * 2**20 <= F.fused_bwd_vmem_bytes(
+        16384, 256, *blocks, 2) < mib * 2**20
     assert flash_kernels(chip, (1, 1, 16384, 256)) == {
         "flash_fwd": 1, "flash_bwd": 1}
