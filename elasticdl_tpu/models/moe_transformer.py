@@ -28,9 +28,11 @@ from jax.sharding import PartitionSpec as P
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.models.transformer import (
+    HIDDEN_SPEC,
     RESIDUAL_SPEC,
-    Attention,
     Block,
+    LatentDims,
+    make_attention,
     make_norm,
     remat_block,
 )
@@ -46,14 +48,15 @@ logger = _logger_factory("elasticdl_tpu.models.moe_transformer")
 
 @functools.lru_cache(maxsize=None)
 def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
-                       act):
+                       act, scoring, shared):
     """One line per distinct expert layer shape (this runs at trace
     time), beside the compile ledger's line of the step, as
     ``ops/attention.py`` names the attention it resolved to."""
     logger.info(
         "moe dispatch resolved to %s (tokens=%d experts=%d top_k=%d "
-        "expert_width=%d act=%s, experts' matmul=%s)",
-        impl, tokens, num_experts, top_k, width, act, matmul,
+        "expert_width=%d act=%s score=%s shared=%d, experts' matmul=%s)",
+        impl, tokens, num_experts, top_k, width, act, scoring, shared,
+        matmul,
     )
 
 
@@ -83,6 +86,20 @@ class MoeMlp(nn.Module):
     (``mlp_ratio x dim`` when None). ``normalize_gates=False`` (the
     kept gates stay the softmax's own, OLMoE's ``norm_topk_prob``
     false) is the sorted path's; the one-hot path refuses it.
+
+    The sorted path also serves DeepSeek-V3's layer (Moonlight-16B-A3B,
+    arXiv:2412.19437 2.1.2): ``scoring="sigmoid"`` with ``gate_scale``
+    (``routed_scaling_factor``); ``bias_update_speed`` not None keeps
+    the balancing bias ``e_score_correction_bias`` (E,) in the
+    non-gradient collection ``moe_state`` (``TrainState.model_state``
+    carries it), adds it to the scores for the SELECTION and, in a
+    training call that may write the collection, moves it by that
+    speed toward the under-loaded experts, from the group sizes the
+    step has just counted (``ops/moe.py:balancing_bias_update``);
+    ``seq_aux`` makes ``aux["load_balancing"]`` the sequence-wise
+    balance loss; ``shared_experts`` n adds one SwiGLU MLP of width ``n
+    x expert_dim`` that every token passes, under the scope
+    ``moe/shared``.
     """
 
     num_experts: int
@@ -94,6 +111,11 @@ class MoeMlp(nn.Module):
     expert_dim: Optional[int] = None
     expert_act: str = "gelu"
     normalize_gates: bool = True
+    scoring: str = "softmax"
+    gate_scale: float = 1.0
+    bias_update_speed: Optional[float] = None
+    seq_aux: bool = False
+    shared_experts: int = 0
 
     def _expert_param(self, name, rows, cols):
         # the expert axis is a batch of kernels, not fan-in: without
@@ -124,13 +146,20 @@ class MoeMlp(nn.Module):
         return nn.silu(hidden[0]) * hidden[1]
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, training=False):
         impl = self.dispatch_impl
         if impl not in ("auto", "onehot", "sorted"):
             raise ValueError(
                 "dispatch_impl must be 'auto', 'onehot' or 'sorted', "
                 "got %r" % (impl,)
             )
+        if impl != "sorted" and (
+            self.scoring != "softmax" or self.seq_aux
+            or self.bias_update_speed is not None
+        ):
+            raise ValueError(
+                "sigmoid scoring, the balancing bias and the "
+                "sequence-wise balance loss need dispatch_impl=\"sorted\"")
         groups, seq, dim = x.shape
         with jax.named_scope("moe/router"):
             router_logits = nn.Dense(
@@ -144,15 +173,34 @@ class MoeMlp(nn.Module):
                 groups * seq * self.top_k, x.dtype, one_device
             ) if impl == "sorted" else "einsum",
             groups * seq, self.num_experts, self.top_k,
-            weights[0].shape[-1], self.expert_act,
+            weights[0].shape[-1], self.expert_act, self.scoring,
+            self.shared_experts,
         )
         if impl == "sorted":
-            y, aux = self._sorted(x, router_logits, weights, one_device)
+            y, aux = self._sorted(
+                x, router_logits, weights, one_device, training)
         else:
             y, aux = self._onehot(x, router_logits, weights)
         with jax.named_scope("moe/router"):
             aux["router_z"] = moe_ops.router_z_loss(router_logits)
+        if self.shared_experts:
+            with jax.named_scope("moe/shared"):
+                y = y + self._shared(
+                    x, self.shared_experts * weights[0].shape[-1])
         return y, aux
+
+    def _shared(self, x, width):
+        """The shared experts: one SwiGLU MLP of their summed width on
+        every token (n experts of one width side by side are one MLP
+        of n times the width)."""
+        if self.expert_act != "swiglu":
+            raise ValueError("shared experts are SwiGLU experts")
+        dense = lambda features, name: nn.Dense(
+            features, use_bias=False, name=name)
+        gate = constrain(
+            dense(width, "shared_gate")(x), self.mesh, HIDDEN_SPEC)
+        up = constrain(dense(width, "shared_up")(x), self.mesh, HIDDEN_SPEC)
+        return dense(x.shape[-1], "shared_down")(nn.silu(gate) * up)
 
     def _onehot(self, x, router_logits, weights):
         if not self.normalize_gates:
@@ -188,7 +236,7 @@ class MoeMlp(nn.Module):
             y = moe_ops.moe_combine(out, combine)  # ep→dp all-to-all back
         return y, {"load_balancing": balance, "routing": None}
 
-    def _sorted(self, x, router_logits, weights, one_device):
+    def _sorted(self, x, router_logits, weights, one_device, training):
         if self.mesh is not None and self.mesh.shape.get("ep", 1) > 1:
             raise ValueError(
                 'dispatch_impl="sorted" sorts one device\'s tokens and '
@@ -199,9 +247,18 @@ class MoeMlp(nn.Module):
         groups, seq, dim = x.shape
         tokens = x.reshape(groups * seq, dim)
         logits = router_logits.reshape(groups * seq, self.num_experts)
+        bias = None
+        if self.bias_update_speed is not None:
+            bias = self.variable(
+                "moe_state", "e_score_correction_bias",
+                lambda: jnp.zeros((self.num_experts,), jnp.float32),
+            )
         with jax.named_scope("moe/router"):
             gates, experts, probs = moe_ops.route_top_k(
-                logits, self.top_k, normalize=self.normalize_gates
+                logits, self.top_k, normalize=self.normalize_gates,
+                scoring=self.scoring,
+                bias=None if bias is None else bias.value,
+                scale=self.gate_scale,
             )
         # for whoever asks with mutable=["intermediates"] (the
         # benchmark's reference check); nothing otherwise
@@ -223,13 +280,25 @@ class MoeMlp(nn.Module):
             y = moe_ops.combine_sorted(out, gates, order, inverse)
         with jax.named_scope("moe/router"):
             aux = {
-                "load_balancing": moe_ops.load_balancing_loss(
-                    probs, group_sizes
+                "load_balancing": (
+                    moe_ops.sequence_balance_loss(probs, experts, groups)
+                    if self.seq_aux
+                    else moe_ops.load_balancing_loss(probs, group_sizes)
                 ),
                 "routing": moe_ops.routing_stats(
                     probs, group_sizes, self.top_k
                 ),
             }
+            if bias is not None:
+                # selection and update are one step's work: the bias
+                # that chose this step's experts moves by the load they
+                # got, where the call may write the collection
+                if (training and not self.is_initializing()
+                        and self.is_mutable_collection("moe_state")):
+                    bias.value = moe_ops.balancing_bias_update(
+                        bias.value, group_sizes, self.bias_update_speed
+                    )
+                aux["routing"]["bias_abs_max"] = jnp.abs(bias.value).max()
         return y.reshape(x.shape), aux
 
 
@@ -248,18 +317,26 @@ class MoeBlock(nn.Module):
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     qk_norm: bool = False
+    rope_theta: float = 10000.0
+    latent: Optional[LatentDims] = None
+    scoring: str = "softmax"
+    gate_scale: float = 1.0
+    bias_update_speed: Optional[float] = None
+    seq_aux: bool = False
+    shared_experts: int = 0
 
     @nn.compact
     def __call__(self, x, training=False):
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
         h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        x = x + Attention(
+        x = x + make_attention(
             self.num_heads,
+            self.latent,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
-            name="attn",
+            rope_theta=self.rope_theta,
         )(h, training)
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
         y, aux = MoeMlp(
@@ -272,21 +349,32 @@ class MoeBlock(nn.Module):
             expert_dim=self.expert_dim,
             expert_act=self.expert_act,
             normalize_gates=self.normalize_gates,
+            scoring=self.scoring,
+            gate_scale=self.gate_scale,
+            bias_update_speed=self.bias_update_speed,
+            seq_aux=self.seq_aux,
+            shared_experts=self.shared_experts,
             name="moe_mlp",
-        )(h)
+        )(h, training)
         return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
 
 
 def merge_routing(layers):
     """One set of ``moe_routing`` counters from the expert layers' own:
     the largest load of any expert in any layer, the mean load, the
-    mean entropy and all dropped pairs."""
-    return {
+    mean entropy, all dropped pairs and, where the layers keep a
+    balancing bias, its largest magnitude."""
+    merged = {
         "load_max": jnp.stack([r["load_max"] for r in layers]).max(),
         "load_mean": jnp.stack([r["load_mean"] for r in layers]).mean(),
         "entropy": jnp.stack([r["entropy"] for r in layers]).mean(),
         "dropped": jnp.stack([r["dropped"] for r in layers]).sum(),
     }
+    if "bias_abs_max" in layers[0]:
+        # the largest |balancing bias| of any expert in any layer
+        merged["bias_abs_max"] = jnp.stack(
+            [r["bias_abs_max"] for r in layers]).max()
+    return merged
 
 
 class MoeTransformerLM(nn.Module):
@@ -307,7 +395,12 @@ class MoeTransformerLM(nn.Module):
     OLMoE-1B-7B is ``norm="rmsnorm"``, ``qk_norm``, ``expert_act=
     "swiglu"``, ``expert_dim=1024``, ``moe_every=1``,
     ``dispatch_impl="sorted"``, ``normalize_gates=False``,
-    ``z_loss_weight=0.001``.
+    ``z_loss_weight=0.001``. Moonlight-16B-A3B's (DeepSeek-V3's) block
+    is ``latent`` (latent attention in every block), ``first_k_dense=1``
+    with ``dense_act="swiglu"`` and ``dense_dim=11264``, and an expert
+    layer of ``scoring="sigmoid"``, ``gate_scale=2.446``,
+    ``bias_update_speed``, ``seq_aux`` and ``shared_experts=2``
+    (``MoeMlp``).
     """
 
     vocab_size: int = 32000
@@ -330,6 +423,25 @@ class MoeTransformerLM(nn.Module):
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     qk_norm: bool = False
+    rope_theta: float = 10000.0
+    latent: Optional[LatentDims] = None
+    # the first k blocks are dense whatever ``moe_every`` says
+    # (``first_k_dense_replace``); a dense block's MLP is ``dense_act``
+    # of width ``dense_dim`` (``mlp_ratio x embed_dim`` when None)
+    first_k_dense: int = 0
+    dense_act: str = "gelu"
+    dense_dim: Optional[int] = None
+    scoring: str = "softmax"
+    gate_scale: float = 1.0
+    bias_update_speed: Optional[float] = None
+    seq_aux: bool = False
+    shared_experts: int = 0
+    # standard deviation of the token embedding's init (None: flax's,
+    # 1 / sqrt(embed_dim)). At 1 / sqrt(embed_dim) a block's output is
+    # ~10 times the embedding it is added to, and at init that output is
+    # the context's mean: every token's router then sees one direction
+    # and picks the same experts (PERF.md Section 6, PR 29)
+    embed_init_std: Optional[float] = None
     # per-block rematerialization, as TransformerLM has it
     # (models/transformer.py:remat_block)
     remat: bool = False
@@ -337,8 +449,11 @@ class MoeTransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, training: bool = False):
+        embed_init = (
+            {} if self.embed_init_std is None else
+            {"embedding_init": nn.initializers.normal(self.embed_init_std)})
         x = nn.Embed(
-            self.vocab_size, self.embed_dim, name="wte"
+            self.vocab_size, self.embed_dim, name="wte", **embed_init
         )(tokens.astype(jnp.int32))
         wrap = (
             functools.partial(
@@ -354,11 +469,14 @@ class MoeTransformerLM(nn.Module):
             norm=self.norm,
             norm_eps=self.norm_eps,
             qk_norm=self.qk_norm,
+            rope_theta=self.rope_theta,
+            latent=self.latent,
         )
         balance = z_loss = jnp.float32(0.0)
         routing = []
         for i in range(self.num_layers):
-            if i % self.moe_every == self.moe_every - 1:
+            if (i >= self.first_k_dense
+                    and i % self.moe_every == self.moe_every - 1):
                 x, aux = wrap(MoeBlock)(
                     self.num_heads,
                     self.num_experts,
@@ -368,6 +486,11 @@ class MoeTransformerLM(nn.Module):
                     expert_dim=self.expert_dim,
                     expert_act=self.expert_act,
                     normalize_gates=self.normalize_gates,
+                    scoring=self.scoring,
+                    gate_scale=self.gate_scale,
+                    bias_update_speed=self.bias_update_speed,
+                    seq_aux=self.seq_aux,
+                    shared_experts=self.shared_experts,
                     name="block_%d" % i,
                     **shared,
                 )(x, training)
@@ -377,7 +500,8 @@ class MoeTransformerLM(nn.Module):
                     routing.append(aux["routing"])
             else:
                 x = wrap(Block)(
-                    self.num_heads, name="block_%d" % i, **shared
+                    self.num_heads, mlp_act=self.dense_act,
+                    mlp_dim=self.dense_dim, name="block_%d" % i, **shared
                 )(x, training)
         x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         logits = nn.Dense(
@@ -413,11 +537,19 @@ def moe_sharding_rules():
             (r"w_(gate|up)$", P("ep", "fsdp", "tp")),
             (r"w_down$", P("ep", "tp", "fsdp")),
             (r"(query|key|value)/kernel$", P("fsdp", "tp", None)),
+            # latent attention: heads over tp where a kernel has them;
+            # kv_down makes the one latent all heads share, so its
+            # output stays whole
+            (r"(q_proj|kv_up)/kernel$", P("fsdp", "tp", None)),
+            (r"kv_down/kernel$", P("fsdp", None)),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),
-            (r"mlp_up/kernel$", P("fsdp", "tp")),
-            (r"mlp_down/kernel$", P("tp", "fsdp")),
+            # the shared experts are a dense MLP (Megatron over tp)
+            (r"(mlp|shared)_(gate|up)/kernel$", P("fsdp", "tp")),
+            (r"(mlp|shared)_down/kernel$", P("tp", "fsdp")),
             (r"wte/embedding$", P("tp", "fsdp")),
             (r"lm_head/kernel$", P("fsdp", "tp")),
+            # the norms' scales and biases: small, replicated
+            (r"(scale|bias)$", P()),
             (r".*", P()),
         ],
         default_spec=P(),

@@ -21,9 +21,11 @@ Design notes (TPU-first):
   under ``fsdp`` moved the batch instead of the weights.
 """
 
+import dataclasses
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -85,6 +87,7 @@ class Attention(nn.Module):
     attention_impl: str = "auto"  # auto | xla | pallas | ring | ulysses
     mesh: Optional[Any] = None
     dropout: float = 0.0
+    rope_theta: float = 10000.0
     # RMSNorm over the WHOLE query and key projections (all heads
     # together, one scale of the model's width each) before the heads
     # are split and rotated: OLMoE's QK-norm (arXiv:2409.02060, 4.2.5)
@@ -124,8 +127,8 @@ class Attention(nn.Module):
         q = to_bhsd(dense("query", "q_norm"))
         k = to_bhsd(dense("key", "k_norm"))
         v = to_bhsd(dense("value"))
-        q = rotary_embedding(q)
-        k = rotary_embedding(k)
+        q = rotary_embedding(q, base=self.rope_theta)
+        k = rotary_embedding(k, base=self.rope_theta)
 
         if self.attention_impl == "ring":
             out = ring_attention(q, k, v, self.mesh, causal=True)
@@ -147,6 +150,115 @@ class Attention(nn.Module):
         return out
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentDims:
+    """The widths of multi-head latent attention (DeepSeek-V2/V3,
+    arXiv:2412.19437 2.1.1) as a model's ``config.json`` names them. No
+    q latent (``q_lora_rank`` null): Moonlight-16B-A3B's form."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention, the training form (nothing is
+    absorbed). For one token x, in the compute dtype, the norm's
+    statistics in float32:
+
+        q            = x W_q                 -> H heads x (nope | rope)
+        c            = x W_kva               -> kv_lora_rank | rope
+        c_kv, k_rope = RMSNorm(c[:rank]), c[rank:]
+        k_nope | v   = c_kv W_kvb            -> H heads x (nope | v)
+        q_rope, k_rope rotated over their ``rope`` lanes; k_rope is ONE
+        head, shared by all H
+        q = [q_nope | q_rope], k = [k_nope | k_rope]   (nope + rope wide)
+        o = causal softmax(q k^T / sqrt(nope + rope)) v -> H x v -> W_o
+
+    The flash kernel takes q and k of one width and v of another
+    (``ops/flash_attention.py``). The scopes ``mla/q_proj``,
+    ``mla/kv_down``, ``mla/kv_up``, ``mla/out_proj`` hold the five
+    matmuls, ``mla/assemble`` what needs no FLOPs: the partial rotary,
+    the broadcast of ``k_rope`` over the heads, the concatenations and
+    the transposes. ``rotary_embedding`` rotates halves where the
+    published code rotates interleaved pairs: with seeded weights a
+    fixed permutation of the rope columns of ``W_q`` and ``W_kva``."""
+
+    num_heads: int
+    dims: LatentDims
+    attention_impl: str = "auto"  # auto | xla | pallas
+    mesh: Optional[Any] = None
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        if self.attention_impl in ("ring", "ulysses"):
+            raise ValueError(
+                "latent attention runs on one device's sequence; "
+                "attention_impl=%r shards it" % (self.attention_impl,))
+        heads, dims = self.num_heads, self.dims
+        rank, nope = dims.kv_lora_rank, dims.qk_nope_head_dim
+        rope = dims.qk_rope_head_dim
+        spec = P(DATA_AXES, "tp", None, None)
+        with jax.named_scope("mla/q_proj"):
+            q = nn.DenseGeneral(
+                (heads, nope + rope), use_bias=False, name="q_proj"
+            )(x)
+        with jax.named_scope("mla/kv_down"):
+            c = nn.Dense(rank + rope, use_bias=False, name="kv_down")(x)
+            c_kv = nn.RMSNorm(epsilon=self.norm_eps, name="kv_norm")(
+                c[..., :rank])
+        with jax.named_scope("mla/kv_up"):
+            kv = nn.DenseGeneral(
+                (heads, nope + dims.v_head_dim), use_bias=False,
+                name="kv_up",
+            )(c_kv)
+        with jax.named_scope("mla/assemble"):
+            q = q.transpose(0, 2, 1, 3)  # (B, H, S, nope + rope)
+            q = jnp.concatenate([
+                q[..., :nope],
+                rotary_embedding(q[..., nope:], base=self.rope_theta),
+            ], axis=-1)
+            kv = kv.transpose(0, 2, 1, 3)
+            # one rotated head of keys, (B, 1, S, rope), for all H
+            k_rope = rotary_embedding(
+                c[:, None, :, rank:], base=self.rope_theta)
+            k = jnp.concatenate([
+                kv[..., :nope],
+                jnp.broadcast_to(
+                    k_rope, kv.shape[:3] + (rope,)),
+            ], axis=-1)
+            q = constrain(q, self.mesh, spec)
+            k = constrain(k, self.mesh, spec)
+            v = constrain(kv[..., nope:], self.mesh, spec)
+        out = dot_product_attention(
+            q, k, v, causal=True, impl=self.attention_impl,
+            mesh=self.mesh, spec=spec,
+        )
+        with jax.named_scope("mla/assemble"):
+            out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, v)
+        with jax.named_scope("mla/out_proj"):
+            return nn.DenseGeneral(
+                x.shape[-1], axis=(-2, -1), use_bias=False,
+                name="out_proj",
+            )(out)
+
+
+def make_attention(num_heads, latent=None, **fields):
+    """The block's attention, ``name="attn"``: ``LatentAttention`` where
+    the model names latent widths (``LatentDims``), else ``Attention``.
+    ``fields``: what both take, and ``qk_norm`` / ``dropout``, which
+    only ``Attention`` has."""
+    if latent is None:
+        return Attention(num_heads, name="attn", **fields)
+    for name in ("qk_norm", "dropout"):
+        if fields.pop(name, None):
+            raise ValueError("latent attention has no %s" % name)
+    return LatentAttention(num_heads, latent, name="attn", **fields)
+
+
 class Block(nn.Module):
     num_heads: int
     mlp_ratio: int = 4
@@ -156,25 +268,40 @@ class Block(nn.Module):
     norm: str = "layernorm"
     norm_eps: float = 1e-6
     qk_norm: bool = False
+    rope_theta: float = 10000.0
+    latent: Optional[LatentDims] = None
+    # the MLP: "gelu" (up, GELU, down) or "swiglu" (silu(gate) x up,
+    # down), of width ``mlp_dim`` (``mlp_ratio x dim`` when None)
+    mlp_act: str = "gelu"
+    mlp_dim: Optional[int] = None
 
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
+        if self.mlp_act not in ("gelu", "swiglu"):
+            raise ValueError(
+                "mlp_act must be 'gelu' or 'swiglu', got %r"
+                % (self.mlp_act,))
+        width = self.mlp_dim or dim * self.mlp_ratio
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
         h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        x = x + Attention(
+        x = x + make_attention(
             self.num_heads,
+            self.latent,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             dropout=self.dropout,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
-            name="attn",
+            rope_theta=self.rope_theta,
         )(h, training)
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
-        h = nn.Dense(dim * self.mlp_ratio, use_bias=False, name="mlp_up")(h)
+        if self.mlp_act == "swiglu":
+            gate = nn.Dense(width, use_bias=False, name="mlp_gate")(h)
+            gate = constrain(gate, self.mesh, HIDDEN_SPEC)
+        h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
         h = constrain(h, self.mesh, HIDDEN_SPEC)
-        h = nn.gelu(h)
+        h = nn.silu(gate) * h if self.mlp_act == "swiglu" else nn.gelu(h)
         h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
         if self.dropout:
             h = nn.Dropout(self.dropout, deterministic=not training)(h)
@@ -317,7 +444,7 @@ def transformer_sharding_rules():
         rules=[
             (r"(query|key|value)/kernel$", P("fsdp", "tp", None)),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),
-            (r"mlp_up/kernel$", P("fsdp", "tp")),
+            (r"mlp_(gate|up)/kernel$", P("fsdp", "tp")),
             (r"mlp_down/kernel$", P("tp", "fsdp")),
             (r"wte/embedding$", P("tp", "fsdp")),
             (r"lm_head/kernel$", P("fsdp", "tp")),

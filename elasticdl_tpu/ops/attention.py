@@ -23,7 +23,8 @@ logger = _logger_factory("elasticdl_tpu.ops.attention")
 
 
 def xla_attention(q, k, v, causal=False, sm_scale=None):
-    """Reference O(S^2) attention over (batch, heads, seq, dim)."""
+    """Reference O(S^2) attention over (batch, heads, seq, dim); v, and
+    so the output, may have a width of its own (the scale is q's)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     s = jnp.einsum(
@@ -38,13 +39,14 @@ def xla_attention(q, k, v, causal=False, sm_scale=None):
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _pallas_refusal(q, k, block_q, block_k):
+def _pallas_refusal(q, k, v, block_q, block_k):
     """Why the flash kernel cannot take these shapes; "" when it can."""
     seq_q, seq_k = q.shape[2], k.shape[2]
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
     block_q, block_k = _flash._blocks(
-        seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k)
+        seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k,
+        v_dim=v.shape[-1])
     if seq_q % block_q or seq_k % block_k:
         return "seq (%d, %d) not divisible by blocks (%d, %d)" % (
             seq_q, seq_k, block_q, block_k,
@@ -55,26 +57,34 @@ def _pallas_refusal(q, k, block_q, block_k):
     return ""
 
 
-def _flash_facts(q, k, causal, block_q, block_k):
+def _flash_facts(q, k, v, causal, block_q, block_k):
     """What the flash kernel does with these shapes, for the log line:
-    which backward (``flash_attention.backward_schedule``; a model's
-    float32 init trace may read ``split`` where its bfloat16 step reads
-    ``fused``: the dtype is on the line for that), and how many of one
-    head's (q-block, k-block) grid steps compute a tile, how many of
-    those apply the causal mask, and how many are skipped
+    where v has a width of its own, both widths and how the q / k one
+    is laid on the lanes (``head q/k=192 v=128 layout=whole``:
+    ``flash_attention.QK_LAYOUT``); which backward
+    (``flash_attention.backward_schedule``; a model's float32 init
+    trace may read ``split`` where its bfloat16 step reads ``fused``:
+    the dtype is on the line for that); and how many of one head's
+    (q-block, k-block) grid steps compute a tile, how many of those
+    apply the causal mask, and how many are skipped
     (``flash_attention.causal_pairs``): the forward's, and the
     backward's where ``_blocks`` gives it other blocks."""
     shapes = (q.shape[2], k.shape[2], q.shape[-1], q.dtype)
+    v_dim = v.shape[-1]
 
     def pairs(backward):
         blocks = _flash._blocks(
-            *shapes, block_q, block_k, backward=backward)
+            *shapes, block_q, block_k, backward=backward, v_dim=v_dim)
         return "run=%d masked=%d skipped=%d" % _flash.causal_pairs(
             *shapes[:2], *blocks, causal=causal)
 
     forward, backward = pairs(False), pairs(True)
-    return "flash backward=%s, pairs %s%s" % (
-        _flash.backward_schedule(*shapes, block_q, block_k),
+    widths = "" if v_dim == q.shape[-1] else (
+        "head q/k=%d v=%d layout=%s, " % (
+            q.shape[-1], v_dim, _flash.QK_LAYOUT))
+    return "%sflash backward=%s, pairs %s%s" % (
+        widths,
+        _flash.backward_schedule(*shapes, block_q, block_k, v_dim),
         forward,
         " (backward %s)" % backward if backward != forward else "",
     )
@@ -148,7 +158,7 @@ def dot_product_attention(
     if impl == "auto":
         backend = jax.default_backend()
         reason = (
-            _pallas_refusal(q, k, block_q, block_k)
+            _pallas_refusal(q, k, v, block_q, block_k)
             if backend == "tpu"
             else "the Pallas kernel needs a TPU backend"
         )
@@ -156,7 +166,7 @@ def dot_product_attention(
         _log_auto_once(
             backend, impl, reason, tuple(q.shape), q.dtype.name,
             "" if reason else _flash_facts(
-                q, k, causal, block_q, block_k),
+                q, k, v, causal, block_q, block_k),
         )
     if impl == "pallas":
         kernel = functools.partial(

@@ -49,6 +49,11 @@ from 17.02 to 16.47, every output bit unchanged (PERF.md, PR 28).
 
 Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
 into one parallel grid axis and see one head's (seq, head_dim) rows.
+v may have a width of its own (latent attention: q and k of 192, v of
+128): q, k, dq and dk are ``head_dim`` wide, v, o, do and dv ``v_dim``,
+the scores one (block_q, block_k) tile either way; the blocks, the VMEM
+count and the schedule take both widths, and a call of equal widths
+traces what it always traced (``QK_LAYOUT``).
 """
 
 import functools
@@ -80,11 +85,37 @@ def _auto_block(seq, cap):
     return block if seq % block == 0 else min(seq, 128)
 
 
+_LANES = 128
+# How a q / k width that is not whole lane tiles (latent attention's 192
+# = 128 nope + 64 rope) is laid on the 128 lanes: as it is. The block's
+# last dimension is the array's, Mosaic pads the last tile itself and
+# the contraction runs over 192. Of the three layouts measured alone on
+# a v5e (16 and 32 heads x 8192 x 192 / 128, bfloat16, causal; PERF.md
+# Section 6, PR 29) this one and "zero-padded to 256 in VMEM" tied
+# (forward 3.587 and 3.584 ms, ``flash_bwd`` 7.503 and 7.500 at 16
+# heads) and "the score as two contractions, 128 + 64" lost 1.6% and
+# 1.0%: the MXU pays two passes for 192 however they are asked for. So
+# the kernels have ONE body for every width; ``ops/attention.py`` puts
+# the word on the attention line.
+QK_LAYOUT = "whole"
+
+
+def _lanes(width):
+    """What VMEM holds of a row of ``width`` elements: whole 128-lane
+    tiles from one tile on (192 -> 256). A narrower row is counted as
+    it is, as the counts below always did."""
+    if width <= _LANES:
+        return width
+    return -(-width // _LANES) * _LANES
+
+
 def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
-            backward=False):
+            backward=False, v_dim=None):
     """The blocks a kernel runs with: ``None`` is the largest power of
     two, up to a cap chosen from the shapes, that divides the sequence.
     The forward and the backward of one call each ask for their own.
+    ``head_dim`` is the width of q and k, ``v_dim`` that of v and o
+    (``None``: the same).
 
     The caps are 512 q-rows by 1024 k-rows, with two departures. What
     a block pair costs decides them (module docstring): a skipped pair
@@ -124,9 +155,9 @@ def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
         tall = _auto_block(seq_q, 1024)
         if (
             seq_q >= 8192
-            and head_dim * itemsize <= 512
+            and max(head_dim, v_dim or head_dim) * itemsize <= 512
             and fused_bwd_vmem_bytes(
-                seq_q, head_dim, tall, block_k, itemsize
+                seq_q, head_dim, tall, block_k, itemsize, v_dim
             ) <= _FUSED_VMEM_BYTES
         ):
             block_q = tall
@@ -326,9 +357,9 @@ def _out_struct(shape, dtype, *operands):
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     bh, seq_q, head_dim = q.shape
-    seq_k = k.shape[1]
+    seq_k, v_dim = k.shape[1], v.shape[2]
     block_q, block_k = _blocks(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k)
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim)
     num_q = seq_q // block_q
     num_k = seq_k // block_k
     grid = (bh, num_q, num_k)
@@ -345,7 +376,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     # second-minor dim equal the full array dim, satisfying the TPU
     # (8, 128) tiling rule that a 2-D (1, block_q) block violates
     out_shape = (
-        _out_struct(q.shape, q.dtype, q, k, v),
+        _out_struct((bh, seq_q, v_dim), q.dtype, q, k, v),
         _out_struct((bh, 1, seq_q), jnp.float32, q, k, v),
     )
     o, lse = pl.pallas_call(
@@ -354,14 +385,14 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
             pl.BlockSpec((1, block_k, head_dim), k_idx),
-            pl.BlockSpec((1, block_k, head_dim), k_idx),
+            pl.BlockSpec((1, block_k, v_dim), k_idx),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, head_dim), q_idx),
+            pl.BlockSpec((1, block_q, v_dim), q_idx),
             pl.BlockSpec((1, 1, block_q), stat_idx),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, head_dim), jnp.float32),
+            pltpu.VMEM((block_q, v_dim), jnp.float32),
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
             pltpu.VMEM((block_q, _STATS_LANES), jnp.float32),
         ],
@@ -544,31 +575,39 @@ def _dkv_kernel(
 _FUSED_VMEM_BYTES = 64 * 2**20
 
 
-def fused_bwd_vmem_bytes(seq_q, head_dim, block_q, block_k, itemsize):
+def fused_bwd_vmem_bytes(seq_q, head_dim, block_q, block_k, itemsize,
+                         v_dim=None):
     """VMEM of ``flash_bwd`` from its shapes, counted generously: dq of
     one ``bh`` as float32 accumulator and double-buffered output block;
     the float32 dk / dv accumulators; q, do, k, v, dk, dv blocks, two
     buffers each; four score-sized float32 temporaries (s, p, dp, ds).
-    The v5e compiler takes 16,384 x 256 (blocks 512 / 1024, bfloat16)
-    under a limit of 36 MiB and refuses it under 32; this says 47."""
+    q, k, dq, dk are ``head_dim`` wide, v, do, dv ``v_dim`` (``None``:
+    the same), each rounded up to whole 128-lane tiles, which is what
+    VMEM holds of a 192-wide row. The v5e compiler takes 16,384 x 256
+    (blocks 512 / 1024, bfloat16) under a limit of 36 MiB and refuses
+    it under 32; this says 47."""
+    head_dim = _lanes(head_dim)
+    v_dim = head_dim if v_dim is None else _lanes(v_dim)
     dq = seq_q * head_dim * (4 + 2 * itemsize)
-    kv = block_k * head_dim * (2 * 4 + 4 * 2 * itemsize)
-    q_do = block_q * head_dim * 2 * 2 * itemsize
+    kv = block_k * (head_dim + v_dim) * (4 + 2 * 2 * itemsize)
+    q_do = block_q * (head_dim + v_dim) * 2 * itemsize
     scores = 4 * block_q * block_k * 4
     return dq + kv + q_do + scores
 
 
 def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
-                      block_k=None):
+                      block_k=None, v_dim=None):
     """Which backward these shapes get: ``"fused"`` (one kernel,
     ``flash_bwd``: the scores rebuilt once) where dq's accumulator fits
     the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``: rebuilt
     twice, no state that grows with the sequence) above it. ``_bwd``
     decides by this and ``ops/attention.py`` logs it."""
     block_q, block_k = _blocks(
-        seq_q, seq_k, head_dim, dtype, block_q, block_k, backward=True)
+        seq_q, seq_k, head_dim, dtype, block_q, block_k, backward=True,
+        v_dim=v_dim)
     held = fused_bwd_vmem_bytes(
-        seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize
+        seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize,
+        v_dim,
     )
     return "fused" if held <= _FUSED_VMEM_BYTES else "split"
 
@@ -577,9 +616,10 @@ def _bwd(
     q, k, v, o, lse, do, sm_scale, causal, block_q, block_k, interpret,
 ):
     bh, seq_q, head_dim = q.shape
-    seq_k = k.shape[1]
+    seq_k, v_dim = k.shape[1], v.shape[2]
     block_q, block_k = _blocks(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, backward=True)
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, backward=True,
+        v_dim=v_dim)
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
@@ -591,7 +631,7 @@ def _bwd(
         sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
     )
     fuse = backward_schedule(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim
     ) == "fused"
 
     # the dkv grid iterates (bh, k-block, q-block)
@@ -606,18 +646,18 @@ def _bwd(
     dkv_in_specs = [
         pl.BlockSpec((1, block_q, head_dim), q_idx),
         pl.BlockSpec((1, block_k, head_dim), k_idx),
-        pl.BlockSpec((1, block_k, head_dim), k_idx),
-        pl.BlockSpec((1, block_q, head_dim), q_idx),
+        pl.BlockSpec((1, block_k, v_dim), k_idx),
+        pl.BlockSpec((1, block_q, v_dim), q_idx),
         pl.BlockSpec((1, 1, block_q), stat_idx),
         pl.BlockSpec((1, 1, block_q), stat_idx),
     ]
     dkv_out_specs = (
         pl.BlockSpec((1, block_k, head_dim), k_idx),
-        pl.BlockSpec((1, block_k, head_dim), k_idx),
+        pl.BlockSpec((1, block_k, v_dim), k_idx),
     )
     dkv_scratch = [
         pltpu.VMEM((block_k, head_dim), jnp.float32),
-        pltpu.VMEM((block_k, head_dim), jnp.float32),
+        pltpu.VMEM((block_k, v_dim), jnp.float32),
     ]
 
     if fuse:
@@ -652,8 +692,8 @@ def _bwd(
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
             pl.BlockSpec((1, block_k, head_dim), k_idx),
-            pl.BlockSpec((1, block_k, head_dim), k_idx),
-            pl.BlockSpec((1, block_q, head_dim), q_idx),
+            pl.BlockSpec((1, block_k, v_dim), k_idx),
+            pl.BlockSpec((1, block_q, v_dim), q_idx),
             pl.BlockSpec((1, 1, block_q), stat_idx),
             pl.BlockSpec((1, 1, block_q), stat_idx),
         ],
@@ -768,6 +808,10 @@ def flash_attention(
     interpret=False,
 ):
     """Blockwise attention over (batch, heads, seq, head_dim) inputs.
+    ``v`` may have a width of its own (latent attention trains with q
+    and k of 192 and v of 128): q, k and their gradients are
+    ``head_dim`` wide, v, the output and their gradients ``v.shape[-1]``;
+    ``sm_scale`` defaults to q's width.
 
     Sequence lengths must be multiples of the block sizes (the auto
     dispatcher in ops/attention.py falls back to the XLA impl when they
@@ -785,10 +829,14 @@ def flash_attention(
     if q.ndim != 4:
         raise ValueError("expected 4-D q/k/v")
     batch, heads, seq_q, head_dim = q.shape
-    seq_k = k.shape[2]
+    seq_k, v_dim = k.shape[2], v.shape[3]
+    if k.shape[3] != head_dim:
+        raise ValueError(
+            "q and k must share a width, got %d and %d"
+            % (head_dim, k.shape[3]))
     # the forward's blocks; the backward's are these or their halves
     fwd_q, fwd_k = _blocks(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k)
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim)
     if seq_q % fwd_q or seq_k % fwd_k:
         raise ValueError(
             "seq lengths (%d, %d) must be multiples of the block sizes "
@@ -796,7 +844,7 @@ def flash_attention(
         )
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
-    merge = lambda t: t.reshape(batch * heads, t.shape[2], head_dim)
+    merge = lambda t: t.reshape(batch * heads, t.shape[2], t.shape[3])
     o = _flash(
         merge(q),
         merge(k),
@@ -807,4 +855,4 @@ def flash_attention(
         block_k,
         interpret,
     )
-    return o.reshape(batch, heads, seq_q, head_dim)
+    return o.reshape(batch, heads, seq_q, v_dim)

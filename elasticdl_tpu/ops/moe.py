@@ -139,21 +139,51 @@ def moe_combine(expert_out, combine):
 # the sorted, dropless formulation
 
 
-def route_top_k(router_logits, k, normalize=False):
+def route_top_k(router_logits, k, normalize=False, scoring="softmax",
+                bias=None, scale=1.0):
     """Token-choice routing without a capacity.
 
-    router_logits: (T, E), any float dtype. The softmax and the top-k
+    router_logits: (T, E), any float dtype. The scores and the top-k
     run in float32 whatever the logits' dtype (the published OLMoE
     implementation's ``softmax(..., dtype=float)``).
 
-    Returns ``(gates, experts, probs)``: (T, k) float32 gate values
-    (the chosen experts' probabilities; renormalised to sum to one only
-    under ``normalize``), (T, k) int32 expert ids, (T, E) float32
-    probabilities."""
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gates, experts = jax.lax.top_k(probs, k)
-    if normalize:
-        gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-9)
+    ``scoring="softmax"`` (OLMoE): the k largest probabilities are the
+    gates. ``scoring="sigmoid"`` (DeepSeek-V3's ``noaux_tc`` with one
+    group, arXiv:2412.19437 eq. 12-16, Moonlight's): ``scores =
+    sigmoid(logits)``; the experts are the k largest of ``scores +
+    bias`` (``bias`` (E,): the balancing bias, which steers the
+    SELECTION only and receives no gradient); the gates are the chosen
+    experts' ``scores`` without it. Under ``normalize`` the gates are
+    divided by their sum; ``scale`` (``routed_scaling_factor``)
+    multiplies them last.
+
+    Returns ``(gates, experts, probs)``: (T, k) float32 gate values,
+    (T, k) int32 expert ids, (T, E) float32 scores: the softmax's
+    probabilities, or the sigmoid's scores normalised to sum to one
+    over the experts (what the sequence-wise balance loss and the
+    entropy counter read)."""
+    if scoring == "softmax":
+        if bias is not None or scale != 1.0:
+            raise ValueError(
+                "a selection bias and a scaling factor belong to "
+                "scoring=\"sigmoid\"")
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        gates, experts = jax.lax.top_k(probs, k)
+        if normalize:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-9)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(router_logits.astype(jnp.float32))
+        biased = scores if bias is None else (
+            scores + jax.lax.stop_gradient(bias.astype(jnp.float32)))
+        _, experts = jax.lax.top_k(biased, k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+        if normalize:
+            gates = gates / (gates.sum(axis=-1, keepdims=True) + 1e-20)
+        gates = gates * scale
+        probs = scores / (scores.sum(axis=-1, keepdims=True) + 1e-20)
+    else:
+        raise ValueError(
+            "scoring must be 'softmax' or 'sigmoid', got %r" % (scoring,))
     gates, experts = checkpoint_name(
         (gates, experts.astype(jnp.int32)), MOE_ROUTE_NAME
     )
@@ -309,6 +339,37 @@ def load_balancing_loss(probs, group_sizes):
     tokens, num_experts = probs.shape
     share = group_sizes.astype(jnp.float32) / tokens
     return num_experts * jnp.sum(share * probs.mean(axis=0))
+
+
+def sequence_balance_loss(probs, experts, num_sequences):
+    """DeepSeek-V3's complementary sequence-wise balance loss
+    (arXiv:2412.19437 eq. 17-20), the mean over the sequences of
+    ``sum_e f_e P_e``: within ONE sequence of T tokens ``f_e = E / (k
+    T) x`` the (token, choice) pairs that chose expert e and ``P_e`` the
+    mean over its tokens of the normalised scores. 1 for a uniform
+    router. probs (B*T, E) float32, experts (B*T, k)."""
+    num_experts, k = probs.shape[-1], experts.shape[-1]
+    probs = probs.reshape(num_sequences, -1, num_experts)
+    tokens = probs.shape[1]
+    chosen = experts.reshape(num_sequences, tokens * k)
+    # a compare fused into its reduction, as ``sort_by_expert`` counts
+    counts = jnp.sum(
+        chosen[:, :, None]
+        == jnp.arange(num_experts, dtype=chosen.dtype)[None, None],
+        axis=1, dtype=jnp.float32,
+    )
+    share = counts * (num_experts / (k * tokens))
+    return jnp.sum(share * probs.mean(axis=1), axis=-1).mean()
+
+
+def balancing_bias_update(bias, group_sizes, speed):
+    """DeepSeek-V3's auxiliary-loss-free balancing (arXiv:2412.19437
+    2.1.2; arXiv:2408.15664): after a step's routing, an overloaded
+    expert's selection bias falls by ``speed`` and an underloaded one's
+    rises, ``bias += speed x sign(mean_load - load_e)``. No gradient
+    reaches it; the optimizer never sees it."""
+    load = group_sizes.astype(jnp.float32)
+    return bias + speed * jnp.sign(load.mean() - load)
 
 
 def router_z_loss(router_logits):

@@ -828,6 +828,10 @@ class Worker:
                         tokens_per_expert_mean=routing["load_mean"],
                         router_entropy=routing["entropy"],
                         dropped_pairs=routing["dropped"],
+                        # only a layer that keeps a balancing bias
+                        # (sigmoid scoring) reports its magnitude
+                        **{k: routing[k] for k in ("bias_abs_max",)
+                           if k in routing},
                     )
         with phase("callbacks"):
             for cb in self._callbacks:
