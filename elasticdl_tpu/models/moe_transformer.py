@@ -60,6 +60,29 @@ def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
     )
 
 
+@functools.lru_cache(maxsize=None)
+def _log_tiles_once(rows, num_experts, dim, width, dtype, act):
+    """The Pallas grouped matmul's tiles, call by call, and the share
+    of their tile work that is needed work: one line per distinct
+    expert layer, after the dispatch's, only where that path runs."""
+    into = moe_ops.projection_tiles(rows, dim, width, dtype)
+    out = moe_ops.projection_tiles(rows, width, dim, dtype)
+    num_in = 2 if act == "swiglu" else 1
+    fill = (num_in + 1) / (
+        num_in / moe_ops.projection_fill(dim, width, into)
+        + 1 / moe_ops.projection_fill(width, dim, out))
+    show = lambda tiles: " ".join(
+        "%s=%s" % (call, tiles[call])
+        for call in ("fwd", "d_rows", "d_weights"))
+    logger.info(
+        "moe experts' matmul tiles (rows=%d experts=%d dim=%d width=%d): "
+        "%s %s, down %s, fill=%.2f%%",
+        rows, num_experts, dim, width,
+        "gate/up" if act == "swiglu" else "up", show(into), show(out),
+        100 * fill,
+    )
+
+
 class MoeMlp(nn.Module):
     """Top-k routed expert FFN. Returns ``(y, aux)``: ``aux`` holds the
     layer's ``load_balancing`` and ``router_z`` losses (unweighted) and,
@@ -167,15 +190,18 @@ class MoeMlp(nn.Module):
             )(x)
         weights = self._weights(dim, x.dtype)
         one_device = self.mesh is None or self.mesh.size == 1
+        rows, width = groups * seq * self.top_k, weights[0].shape[-1]
+        matmul = moe_ops.resolve_grouped_matmul(
+            rows, x.dtype, one_device) if impl == "sorted" else "einsum"
         _log_dispatch_once(
-            "sorted" if impl == "sorted" else "onehot",
-            moe_ops.resolve_grouped_matmul(
-                groups * seq * self.top_k, x.dtype, one_device
-            ) if impl == "sorted" else "einsum",
-            groups * seq, self.num_experts, self.top_k,
-            weights[0].shape[-1], self.expert_act, self.scoring,
-            self.shared_experts,
+            "sorted" if impl == "sorted" else "onehot", matmul,
+            groups * seq, self.num_experts, self.top_k, width,
+            self.expert_act, self.scoring, self.shared_experts,
         )
+        if matmul == "pallas_gmm":
+            _log_tiles_once(
+                rows, self.num_experts, dim, width, x.dtype,
+                self.expert_act)
         if impl == "sorted":
             y, aux = self._sorted(
                 x, router_logits, weights, one_device, training)

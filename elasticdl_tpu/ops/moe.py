@@ -35,6 +35,8 @@ sorted path's only data-dependent quantity is ``group_sizes``, an
 (E,) array that the grouped matmul takes as an operand.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
@@ -282,11 +284,127 @@ def _combine_sorted_bwd(res, dy):
 combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
-# (rows, K, N) tile of the Pallas grouped matmul: the largest that fits
-# the v5e's 16 MB of scoped VMEM at bfloat16 (1024 rows, or 2048 of K or
-# N, are refused by the TPU compiler); in the OLMoE step it ran 3%
-# ahead of (512, 512, 1024) (PERF.md, PR 25).
-GMM_TILING = (512, 1024, 1024)
+# The Pallas grouped matmul's tiles (tm rows, tk of K, tn of N), chosen
+# for each call from that call's own shapes (``gmm_tiles``).
+# Rows: 512 (1024 are refused by the TPU compiler at any K / N tile
+# worth having; 256 halve a weight tile's reuse and lost in both expert
+# cells' ``gmm`` calls, PERF.md, PR 30). ``tgmm`` alone may fall back to
+# 256: it contracts over its rows, so a step's FLOPs a byte are tk x tn
+# / (tk + tn) whatever the row tile, and the row tile gives way where
+# that lets the output tile grow. K and N: 1024 where 1024 divides the
+# dimension (in the OLMoE step (512, 1024, 1024) ran 3% ahead of (512,
+# 512, 1024), PERF.md, PR 25), never under 512 (a weight tile's reuse
+# then falls under the v5e's ridge), always whole lane tiles.
+GMM_ROW_TILE = 512
+TGMM_ROW_TILES = (512, 256)
+GMM_TILE = 1024
+GMM_MIN_TILE = 512
+_LANES = 128
+# what the TPU compiler grants one kernel's buffers (scoped VMEM, v5e)
+GMM_VMEM_BYTES = 16 * 2**20
+GMM_CALLS = ("gmm", "gmm_transposed", "tgmm")
+
+
+def gmm_vmem_bytes(call, tiles, dtype):
+    """Bytes of VMEM one grid step of the backend's ``gmm`` (plain or
+    with a transposed rhs) or ``tgmm`` holds at ``tiles``: the two
+    operand tiles and the output tile, double-buffered by the pipeline,
+    the float32 accumulator (output-tile sized), and the kernel's own
+    copy of its lhs tile (cast, masked, and in ``tgmm`` transposed),
+    counted at 1.2 of its bytes. Fitted to what the compiler says when
+    it refuses a tile ("Scoped allocation with size 16.79M and limit
+    16.00M": 60 tiles compiled for a described v5e, PR 30): it reads
+    1.04-1.2 of that copy at row tiles up to 512 (all the rule asks
+    for; more at 768 and 1024), less where ``gmm`` has a single K tile,
+    so the count errs high, never low.
+    ``tests/test_moe_tpu_compile.py`` holds it against the compiler."""
+    tm, tk, tn = tiles
+    itemsize = jnp.dtype(dtype).itemsize
+    lhs = tm * tk
+    if call == "tgmm":  # (rows, k)^T (rows, n) -> (k, n)
+        rhs, out = tm * tn, tk * tn
+    else:  # (rows, k) (k, n) -> (rows, n)
+        rhs, out = tk * tn, tm * tn
+    return int(
+        2 * itemsize * (lhs + rhs + out) + 4 * out + 1.2 * itemsize * lhs)
+
+
+def _covered(dim, tile):
+    """What ``dim`` costs in whole tiles."""
+    return -(-dim // tile) * tile
+
+
+def gmm_fill(k, n, tiles):
+    """The share of a call's tile work that is needed work."""
+    _, tk, tn = tiles
+    return k * n / (_covered(k, tk) * _covered(n, tn))
+
+
+def _tile_candidates(dim):
+    if dim % GMM_TILE == 0:
+        return (GMM_TILE, GMM_MIN_TILE)
+    whole = _covered(dim, _LANES)
+    return tuple(range(whole, min(whole, GMM_MIN_TILE) - 1, -_LANES))
+
+
+def gmm_tiles(rows, k, n, dtype, call):
+    """(tm, tk, tn) for one call of the backend's grouped matmul, from
+    the call's own shapes. ``call`` is one of ``GMM_CALLS``: ``"gmm"``
+    (rows (rows, k) x weights (E, k, n)), ``"gmm_transposed"`` (the
+    same product through weights stored (E, n, k): the rows' gradient)
+    or ``"tgmm"`` ((rows, k)^T x (rows, n) -> (E, k, n): the weights'
+    gradient).
+
+    A dimension that 1024 divides keeps 1024 (512 where that alone
+    fits). Any other takes the multiple of 128 that covers it with the
+    least padded work: the whole dimension where the call's buffers fit
+    the compiler's 16 MiB (``gmm_vmem_bytes``), else the best of the
+    smaller ones; among tiles of equal padded work, the largest (K x
+    N), and among those the most rows. The MXU computes every tile in
+    full, so Moonlight's 1408 under tiles of 1024 did 2048 / 1408 =
+    1.45 times the work it needed."""
+    if call not in GMM_CALLS:
+        raise ValueError("call must be one of %r, got %r" % (GMM_CALLS, call))
+    if rows % GMM_ROW_TILE:
+        raise ValueError(
+            "the Pallas grouped matmul takes whole row tiles of %d, got "
+            "%d rows" % (GMM_ROW_TILE, rows))
+    row_tiles = TGMM_ROW_TILES if call == "tgmm" else (GMM_ROW_TILE,)
+    fitting = [
+        (tm, tk, tn) for tm in row_tiles
+        for tk in _tile_candidates(k) for tn in _tile_candidates(n)
+        if gmm_vmem_bytes(call, (tm, tk, tn), dtype) <= GMM_VMEM_BYTES
+    ]
+    if not fitting:
+        raise ValueError(
+            "no tile of the %s (%d x %d, %s) fits %d bytes of VMEM"
+            % (call, k, n, jnp.dtype(dtype).name, GMM_VMEM_BYTES))
+    return min(
+        fitting,
+        key=lambda t: (_covered(k, t[1]) * _covered(n, t[2]), -t[1] * t[2],
+                       -t[0]),
+    )
+
+
+def projection_tiles(rows, k, n, dtype):
+    """The three calls of one expert projection (rows, k) x (E, k, n):
+    ``{"fwd", "d_rows", "d_weights"}`` -> (tm, tk, tn). The rows'
+    gradient contracts over n; the weights' gradient is (k, n)."""
+    return {
+        "fwd": gmm_tiles(rows, k, n, dtype, "gmm"),
+        "d_rows": gmm_tiles(rows, n, k, dtype, "gmm_transposed"),
+        "d_weights": gmm_tiles(rows, k, n, dtype, "tgmm"),
+    }
+
+
+def projection_fill(k, n, tiles):
+    """Needed work over tile work of a projection's three calls."""
+    spent = (
+        1 / gmm_fill(k, n, tiles["fwd"])
+        + 1 / gmm_fill(n, k, tiles["d_rows"])
+        + 1 / gmm_fill(k, n, tiles["d_weights"])
+    )
+    return 3 / spent
 
 
 def resolve_grouped_matmul(num_rows, dtype, one_device=True):
@@ -300,9 +418,63 @@ def resolve_grouped_matmul(num_rows, dtype, one_device=True):
         one_device
         and jax.default_backend() == "tpu"
         and dtype == jnp.bfloat16
-        and num_rows % GMM_TILING[0] == 0
+        and num_rows % GMM_ROW_TILE == 0
     )
     return "pallas_gmm" if fits else "ragged_dot"
+
+
+def _gmm_backend():
+    # the package's ``gmm`` attribute is its one-tiling custom VJP; the
+    # kernels are the module of the same name
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+
+def _pallas_forward(rows, weights, group_sizes, interpret):
+    tiles = projection_tiles(
+        rows.shape[0], weights.shape[1], weights.shape[2], rows.dtype)
+    return _gmm_backend().gmm(
+        rows, weights, group_sizes, rows.dtype, tiles["fwd"],
+        interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def pallas_grouped_matmul(rows, weights, group_sizes, interpret=False):
+    """``grouped_matmul`` through the Pallas kernels that ship with jax
+    (``megablox``'s ``gmm``, and ``tgmm`` for the weights' gradient),
+    each of the three calls at tiles of its own (``projection_tiles``).
+    The package's custom VJP hands ONE tiling to all three, whose K and
+    N are swapped against each other. Same residuals as that one: the
+    rows, the weights, the group sizes."""
+    return _pallas_forward(rows, weights, group_sizes, interpret)
+
+
+def _pallas_grouped_matmul_fwd(rows, weights, group_sizes, interpret):
+    out = _pallas_forward(rows, weights, group_sizes, interpret)
+    return out, (rows, weights, group_sizes)
+
+
+def _pallas_grouped_matmul_bwd(interpret, res, d_out):
+    rows, weights, group_sizes = res
+    backend = _gmm_backend()
+    tiles = projection_tiles(
+        rows.shape[0], weights.shape[1], weights.shape[2], rows.dtype)
+    d_rows = backend.gmm(
+        d_out, weights, group_sizes, rows.dtype, tiles["d_rows"],
+        transpose_rhs=True, interpret=interpret)
+    # ``tgmm`` takes its lhs (k, rows) and swaps it back before the
+    # kernel: the pair cancels, the kernel transposes a tile
+    d_weights = backend.tgmm(
+        rows.swapaxes(0, 1), d_out, group_sizes, weights.dtype,
+        tiles["d_weights"], num_actual_groups=weights.shape[0],
+        interpret=interpret)
+    return d_rows, d_weights, None
+
+
+pallas_grouped_matmul.defvjp(
+    _pallas_grouped_matmul_fwd, _pallas_grouped_matmul_bwd)
 
 
 def grouped_matmul(rows, weights, group_sizes, one_device=True):
@@ -311,21 +483,14 @@ def grouped_matmul(rows, weights, group_sizes, one_device=True):
     ``weights[e]``.
 
     Two implementations, chosen from what the call can see. On one TPU
-    device, for bfloat16 rows in whole tiles: the Pallas grouped matmul
-    that ships with jax (``megablox.gmm``, with ``tgmm`` for the
-    kernels' gradient through its custom VJP). Otherwise
-    ``jax.lax.ragged_dot``, which XLA compiles to Mosaic kernels of its
-    own on a TPU and to plain dots on the CPU. Timed in the OLMoE cell's
-    step on a v5e, not alone: 31.9 samples/s against 29.1 (PERF.md,
-    PR 25)."""
+    device, for bfloat16 rows in whole tiles: ``pallas_grouped_matmul``.
+    Otherwise ``jax.lax.ragged_dot``, which XLA compiles to Mosaic
+    kernels of its own on a TPU and to plain dots on the CPU. Timed in
+    the OLMoE cell's step on a v5e, not alone: 31.9 samples/s against
+    29.1 (PERF.md, PR 25)."""
     impl = resolve_grouped_matmul(rows.shape[0], rows.dtype, one_device)
     if impl == "pallas_gmm":
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-        out = gmm(
-            rows, weights, group_sizes,
-            preferred_element_type=rows.dtype, tiling=GMM_TILING,
-        )
+        out = pallas_grouped_matmul(rows, weights, group_sizes)
     else:
         out = jax.lax.ragged_dot(rows, weights, group_sizes)
     return checkpoint_name(out, MOE_MATMUL_NAME)

@@ -1,9 +1,11 @@
-"""The sorted MoE path compiled at OLMoE's published widths for a v5e
-that is described, not attached (the TPU compiler is installed here):
-what the CPU's interpret-free tests cannot see. Both grouped matmuls
-must be accepted by the chip's compiler (tiles inside its VMEM, no
-unaligned slice), the Pallas one must be what a TPU backend gets, and
-the compiled expert layer must hold no capacity and no one-hot.
+"""The sorted MoE path compiled at OLMoE's and at Moonlight's published
+widths for a v5e that is described, not attached (the TPU compiler is
+installed here): what the CPU's interpret-free tests cannot see. Both
+grouped matmuls must be accepted by the chip's compiler (the tiles
+``ops/moe.py:gmm_tiles`` chose inside its VMEM, no unaligned slice), a
+tile its byte count calls too large must be refused by the compiler
+too, the Pallas one must be what a TPU backend gets, and the compiled
+expert layer must hold no capacity and no one-hot.
 
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
@@ -20,6 +22,9 @@ from elasticdl_tpu.ops import moe as moe_ops
 
 # OLMoE-1B-7B's expert layer; a quarter of the cell's 32,768 tokens
 TOKENS, DIM, WIDTH, EXPERTS, TOP_K = 8192, 2048, 1024, 64, 8
+# (expert width, top-k): OLMoE-1B-7B's and Moonlight-16B-A3B's, whose
+# 1408 = 11 x 128 no tile of 1024 divides
+LAYERS = {"olmoe": (WIDTH, TOP_K), "moonlight": (1408, 6)}
 
 
 @pytest.fixture(scope="module")
@@ -34,10 +39,10 @@ def chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def compile_layer(chip):
+def compile_layer(chip, width=WIDTH, top_k=TOP_K):
     """HLO text of the expert layer's loss and gradients."""
     layer = MoeMlp(
-        EXPERTS, top_k=TOP_K, dispatch_impl="sorted", expert_dim=WIDTH,
+        EXPERTS, top_k=top_k, dispatch_impl="sorted", expert_dim=width,
         expert_act="swiglu", normalize_gates=False)
     x = jax.ShapeDtypeStruct((2, TOKENS // 2, DIM), jnp.bfloat16,
                              sharding=chip)
@@ -77,19 +82,56 @@ def assert_no_capacity(hlo):
             ) == EXPERTS, shape
 
 
+@pytest.mark.parametrize("model", sorted(LAYERS))
 def test_pallas_grouped_matmul_is_what_a_tpu_backend_compiles(
-        chip, monkeypatch):
+        chip, monkeypatch, model):
     # here the default backend is the CPU: steer the one question the
     # code asks (the guide: "it does so in the test")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    hlo = compile_layer(chip)
+    width, top_k = LAYERS[model]
+    hlo = compile_layer(chip, width, top_k)
     names = kernels(hlo)
     # gate, up, down: forward, the rows' gradient, the kernels' gradient
     assert len(names) == 9, names
     assert all("moe/experts" in n and "gmm" in n for n in names), names
     assert sum("transpose(" in n for n in names) == 6
+    assert sum("jit(tgmm)" in n for n in names) == 3
     assert "ragged-dot" not in hlo
-    assert_no_capacity(hlo)
+    # ``tgmm`` is handed its lhs swapped and swaps it back: the pair
+    # cancels, no row-sized array is transposed ahead of the kernel
+    rows = TOKENS * top_k
+    assert "[%d,%d]" % (DIM, rows) not in hlo
+    assert "[%d,%d]" % (width, rows) not in hlo
+    if model == "olmoe":
+        assert_no_capacity(hlo)
+
+
+def test_a_tile_the_byte_count_refuses_the_compiler_refuses_too(chip):
+    """(512, 1024, 1408) for the gate's weight gradient pads nothing
+    and is what the rule would take if it fitted: 16.8 MiB by
+    ``gmm_vmem_bytes``, "Scoped allocation with size 16.79M and limit
+    16.00M" by the compiler. The tile the rule takes in its place
+    compiles."""
+    rows, k, n = 98304, DIM, 1408
+    shape = lambda *dims: jax.ShapeDtypeStruct(
+        dims, jnp.bfloat16, sharding=chip)
+    sizes = jax.ShapeDtypeStruct((EXPERTS,), jnp.int32, sharding=chip)
+    backend = moe_ops._gmm_backend()
+
+    def compile_at(tiles):
+        jax.jit(lambda x, dy, sizes: backend.tgmm(
+            x.swapaxes(0, 1), dy, sizes, jnp.bfloat16, tiles,
+            num_actual_groups=EXPERTS,
+        )).lower(shape(rows, k), shape(rows, n), sizes).compile()
+
+    too_large = (512, 1024, 1408)
+    assert moe_ops.gmm_vmem_bytes(
+        "tgmm", too_large, jnp.bfloat16) > moe_ops.GMM_VMEM_BYTES
+    with pytest.raises(Exception, match="vmem"):
+        compile_at(too_large)
+    chosen = moe_ops.gmm_tiles(rows, k, n, jnp.bfloat16, "tgmm")
+    assert chosen != too_large and moe_ops.gmm_fill(k, n, chosen) == 1.0
+    compile_at(chosen)
 
 
 def test_ragged_dot_is_the_other_path_and_compiles_too(chip):
