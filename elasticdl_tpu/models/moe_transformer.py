@@ -31,6 +31,7 @@ from elasticdl_tpu.models.transformer import (
     HIDDEN_SPEC,
     RESIDUAL_SPEC,
     Block,
+    GatedDeltaDims,
     LatentDims,
     make_attention,
     make_norm,
@@ -48,15 +49,17 @@ logger = _logger_factory("elasticdl_tpu.models.moe_transformer")
 
 @functools.lru_cache(maxsize=None)
 def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
-                       act, scoring, shared):
+                       act, scoring, shared, held=""):
     """One line per distinct expert layer shape (this runs at trace
     time), beside the compile ledger's line of the step, as
-    ``ops/attention.py`` names the attention it resolved to."""
+    ``ops/attention.py`` names the attention it resolved to. ``held``:
+    what a layer that holds a share of its experts adds (`` held=0-31
+    rows=65536 shared_gate=sigmoid``)."""
     logger.info(
         "moe dispatch resolved to %s (tokens=%d experts=%d top_k=%d "
-        "expert_width=%d act=%s score=%s shared=%d, experts' matmul=%s)",
+        "expert_width=%d act=%s score=%s shared=%d%s, experts' matmul=%s)",
         impl, tokens, num_experts, top_k, width, act, scoring, shared,
-        matmul,
+        held, matmul,
     )
 
 
@@ -123,6 +126,19 @@ class MoeMlp(nn.Module):
     balance loss; ``shared_experts`` n adds one SwiGLU MLP of width ``n
     x expert_dim`` that every token passes, under the scope
     ``moe/shared``.
+
+    ``held_experts`` ``(first, count)`` makes this one chip's share of
+    a layer whose experts lie over several (the first half of expert
+    parallelism; Qwen3-Next's 512 experts, 32 held): the router, the
+    scores, the top-k and the gates' normalisation run over all
+    ``num_experts``; ``w_gate / w_up / w_down`` have ``count`` rows;
+    the pairs whose expert lives here are sorted and gathered into a
+    buffer of ``held_rows`` rows (static; a held pair without a row is
+    counted in ``routing["dropped"]``), and the combine adds this
+    chip's experts' part and nothing for the absent ones. No exchange,
+    and nothing that stands in for one. ``shared_gate``: the shared
+    experts' output passes ``sigmoid(x . w)``, a gate of one output
+    (``shared_expert_gate``).
     """
 
     num_experts: int
@@ -139,14 +155,19 @@ class MoeMlp(nn.Module):
     bias_update_speed: Optional[float] = None
     seq_aux: bool = False
     shared_experts: int = 0
+    held_experts: Optional[Any] = None
+    held_rows: Optional[int] = None
+    shared_gate: bool = False
 
     def _expert_param(self, name, rows, cols):
         # the expert axis is a batch of kernels, not fan-in: without
         # batch_axis every expert started sqrt(E) times too small
+        held = (self.num_experts if self.held_experts is None
+                else self.held_experts[1])
         return self.param(
             name,
             nn.initializers.lecun_normal(batch_axis=(0,)),
-            (self.num_experts, rows, cols),
+            (held, rows, cols),
         )
 
     def _weights(self, dim, dtype):
@@ -191,16 +212,31 @@ class MoeMlp(nn.Module):
         weights = self._weights(dim, x.dtype)
         one_device = self.mesh is None or self.mesh.size == 1
         rows, width = groups * seq * self.top_k, weights[0].shape[-1]
+        held = ""
+        if self.held_experts is not None:
+            if impl != "sorted" or not self.held_rows:
+                raise ValueError(
+                    "held_experts needs dispatch_impl=\"sorted\" and the "
+                    "row buffer's size, held_rows")
+            first, count = self.held_experts
+            if not 0 <= first <= first + count <= self.num_experts:
+                raise ValueError(
+                    "held_experts=%r lies outside %d experts"
+                    % (self.held_experts, self.num_experts))
+            rows = min(self.held_rows, rows)
+            held = " held=%d-%d rows=%d" % (first, first + count - 1, rows)
+        if self.shared_gate:
+            held += " shared_gate=sigmoid"
         matmul = moe_ops.resolve_grouped_matmul(
             rows, x.dtype, one_device) if impl == "sorted" else "einsum"
         _log_dispatch_once(
             "sorted" if impl == "sorted" else "onehot", matmul,
             groups * seq, self.num_experts, self.top_k, width,
-            self.expert_act, self.scoring, self.shared_experts,
+            self.expert_act, self.scoring, self.shared_experts, held,
         )
         if matmul == "pallas_gmm":
             _log_tiles_once(
-                rows, self.num_experts, dim, width, x.dtype,
+                rows, weights[0].shape[0], dim, width, x.dtype,
                 self.expert_act)
         if impl == "sorted":
             y, aux = self._sorted(
@@ -226,7 +262,10 @@ class MoeMlp(nn.Module):
         gate = constrain(
             dense(width, "shared_gate")(x), self.mesh, HIDDEN_SPEC)
         up = constrain(dense(width, "shared_up")(x), self.mesh, HIDDEN_SPEC)
-        return dense(x.shape[-1], "shared_down")(nn.silu(gate) * up)
+        y = dense(x.shape[-1], "shared_down")(nn.silu(gate) * up)
+        if self.shared_gate:
+            y = y * jax.nn.sigmoid(dense(1, "shared_expert_gate")(x))
+        return y
 
     def _onehot(self, x, router_logits, weights):
         if not self.normalize_gates:
@@ -289,11 +328,20 @@ class MoeMlp(nn.Module):
         # for whoever asks with mutable=["intermediates"] (the
         # benchmark's reference check); nothing otherwise
         self.sow("intermediates", "experts", experts.reshape(groups, seq, -1))
+        share = {}
         with jax.named_scope("moe/dispatch"):
-            order, inverse, group_sizes = moe_ops.sort_by_expert(
-                experts, self.num_experts
-            )
-            rows = moe_ops.dispatch_sorted(tokens, order, inverse)
+            if self.held_experts is None:
+                order, inverse, group_sizes = moe_ops.sort_by_expert(
+                    experts, self.num_experts
+                )
+                rows = moe_ops.dispatch_sorted(tokens, order, inverse)
+                loads = group_sizes
+            else:
+                (pairs, valid, group_sizes, loads, share["held"],
+                 share["dropped"]) = moe_ops.sort_held(
+                    experts, self.num_experts, *self.held_experts,
+                    self.held_rows)
+                rows = moe_ops.dispatch_held(tokens, pairs, self.top_k)
         with jax.named_scope("moe/experts"):
             hidden = [
                 moe_ops.grouped_matmul(rows, w, group_sizes, one_device)
@@ -303,16 +351,19 @@ class MoeMlp(nn.Module):
                 self._act(hidden), weights[-1], group_sizes, one_device
             )
         with jax.named_scope("moe/combine"):
-            y = moe_ops.combine_sorted(out, gates, order, inverse)
+            if self.held_experts is None:
+                y = moe_ops.combine_sorted(out, gates, order, inverse)
+            else:
+                y = moe_ops.combine_held(out, gates, pairs, valid)
         with jax.named_scope("moe/router"):
             aux = {
                 "load_balancing": (
                     moe_ops.sequence_balance_loss(probs, experts, groups)
                     if self.seq_aux
-                    else moe_ops.load_balancing_loss(probs, group_sizes)
+                    else moe_ops.load_balancing_loss(probs, loads)
                 ),
                 "routing": moe_ops.routing_stats(
-                    probs, group_sizes, self.top_k
+                    probs, loads, self.top_k, **share
                 ),
             }
             if bias is not None:
@@ -322,7 +373,7 @@ class MoeMlp(nn.Module):
                 if (training and not self.is_initializing()
                         and self.is_mutable_collection("moe_state")):
                     bias.value = moe_ops.balancing_bias_update(
-                        bias.value, group_sizes, self.bias_update_speed
+                        bias.value, loads, self.bias_update_speed
                     )
                 aux["routing"]["bias_abs_max"] = jnp.abs(bias.value).max()
         return y.reshape(x.shape), aux
@@ -350,6 +401,17 @@ class MoeBlock(nn.Module):
     bias_update_speed: Optional[float] = None
     seq_aux: bool = False
     shared_experts: int = 0
+    held_experts: Optional[Any] = None
+    held_rows: Optional[int] = None
+    shared_gate: bool = False
+    # the mixer: a Gated DeltaNet of these sizes where given, else
+    # softmax attention with ``Attention``'s own fields of these names
+    linear: Optional[GatedDeltaDims] = None
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    head_norm: Optional[str] = None
+    rotary_dim: Optional[int] = None
+    output_gate: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -358,11 +420,17 @@ class MoeBlock(nn.Module):
         x = x + make_attention(
             self.num_heads,
             self.latent,
+            self.linear,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
             rope_theta=self.rope_theta,
+            head_dim=self.head_dim,
+            num_kv_heads=self.num_kv_heads,
+            head_norm=self.head_norm,
+            rotary_dim=self.rotary_dim,
+            output_gate=self.output_gate,
         )(h, training)
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
         y, aux = MoeMlp(
@@ -380,6 +448,9 @@ class MoeBlock(nn.Module):
             bias_update_speed=self.bias_update_speed,
             seq_aux=self.seq_aux,
             shared_experts=self.shared_experts,
+            held_experts=self.held_experts,
+            held_rows=self.held_rows,
+            shared_gate=self.shared_gate,
             name="moe_mlp",
         )(h, training)
         return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
@@ -400,6 +471,10 @@ def merge_routing(layers):
         # the largest |balancing bias| of any expert in any layer
         merged["bias_abs_max"] = jnp.stack(
             [r["bias_abs_max"] for r in layers]).max()
+    if "held" in layers[0]:
+        # the pairs of the layer whose held experts got the most: what
+        # the row buffer has to hold
+        merged["held"] = jnp.stack([r["held"] for r in layers]).max()
     return merged
 
 
@@ -426,7 +501,14 @@ class MoeTransformerLM(nn.Module):
     with ``dense_act="swiglu"`` and ``dense_dim=11264``, and an expert
     layer of ``scoring="sigmoid"``, ``gate_scale=2.446``,
     ``bias_update_speed``, ``seq_aux`` and ``shared_experts=2``
-    (``MoeMlp``).
+    (``MoeMlp``). Qwen3-Next-80B-A3B's is ``norm=
+    "zero_centred_rmsnorm"``, ``layer_kinds=("linear", "linear",
+    "linear", "full")`` with ``linear`` (a Gated DeltaNet mixer's
+    sizes) and, for the full layers, ``head_dim=256``, ``num_kv_heads=
+    2``, ``head_norm``, ``rotary_dim=64``, ``output_gate="sigmoid"``;
+    an expert layer of 512 softmax-routed experts of which this chip
+    holds ``held_experts=(0, 32)`` in ``held_rows`` rows, and one
+    shared expert behind ``shared_gate``.
     """
 
     vocab_size: int = 32000
@@ -462,6 +544,21 @@ class MoeTransformerLM(nn.Module):
     bias_update_speed: Optional[float] = None
     seq_aux: bool = False
     shared_experts: int = 0
+    held_experts: Optional[Any] = None
+    held_rows: Optional[int] = None
+    shared_gate: bool = False
+    # the mixers' kinds as a pattern with a period: layer i is
+    # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
+    # of ``linear``'s sizes) or "full" (softmax attention). None: every
+    # layer "full". The five fields after ``linear`` are ``Attention``'s
+    # own of those names, for the "full" layers
+    layer_kinds: Optional[Any] = None
+    linear: Optional[GatedDeltaDims] = None
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    head_norm: Optional[str] = None
+    rotary_dim: Optional[int] = None
+    output_gate: Optional[str] = None
     # standard deviation of the token embedding's init (None: flax's,
     # 1 / sqrt(embed_dim)). At 1 / sqrt(embed_dim) a block's output is
     # ~10 times the embedding it is added to, and at init that output is
@@ -498,9 +595,25 @@ class MoeTransformerLM(nn.Module):
             rope_theta=self.rope_theta,
             latent=self.latent,
         )
+        kinds = tuple(self.layer_kinds or ("full",))
+        if set(kinds) - {"full", "linear"} or (
+                "linear" in kinds and self.linear is None):
+            raise ValueError(
+                "layer_kinds=%r: each is 'full' or 'linear', and 'linear' "
+                "needs the mixer's sizes (linear)" % (self.layer_kinds,))
+        # what only an expert block's mixer takes
+        mixer = dict(
+            head_dim=self.head_dim,
+            num_kv_heads=self.num_kv_heads,
+            head_norm=self.head_norm,
+            rotary_dim=self.rotary_dim,
+            output_gate=self.output_gate,
+        )
         balance = z_loss = jnp.float32(0.0)
         routing = []
         for i in range(self.num_layers):
+            linear = (
+                self.linear if kinds[i % len(kinds)] == "linear" else None)
             if (i >= self.first_k_dense
                     and i % self.moe_every == self.moe_every - 1):
                 x, aux = wrap(MoeBlock)(
@@ -517,14 +630,24 @@ class MoeTransformerLM(nn.Module):
                     bias_update_speed=self.bias_update_speed,
                     seq_aux=self.seq_aux,
                     shared_experts=self.shared_experts,
+                    held_experts=self.held_experts,
+                    held_rows=self.held_rows,
+                    shared_gate=self.shared_gate,
+                    linear=linear,
                     name="block_%d" % i,
                     **shared,
+                    **mixer,
                 )(x, training)
                 balance = balance + aux["load_balancing"]
                 z_loss = z_loss + aux["router_z"]
                 if aux["routing"] is not None:
                     routing.append(aux["routing"])
             else:
+                if linear is not None or any(mixer.values()):
+                    raise ValueError(
+                        "a dense block's mixer is Attention at its "
+                        "defaults or LatentAttention; layer %d asks for "
+                        "more" % i)
                 x = wrap(Block)(
                     self.num_heads, mlp_act=self.dense_act,
                     mlp_dim=self.dense_dim, name="block_%d" % i, **shared
@@ -568,6 +691,17 @@ def moe_sharding_rules():
             # output stays whole
             (r"(q_proj|kv_up)/kernel$", P("fsdp", "tp", None)),
             (r"kv_down/kernel$", P("fsdp", None)),
+            # Gated DeltaNet: the two input projections split their
+            # output features over tp like every up-projection, the
+            # depthwise conv follows its channels (annotation only:
+            # q | k | v | z lie side by side, so over tp > 1 the split
+            # into heads moves data; no cell runs that yet); the
+            # per-head decay parameters and the shared expert's gate
+            # are tiny
+            (r"in_proj_(qkvz|ba)/kernel$", P("fsdp", "tp")),
+            (r"conv_kernel$", P(None, "tp")),
+            (r"(A_log|dt_bias)$", P()),
+            (r"shared_expert_gate/kernel$", P()),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),
             # the shared experts are a dense MLP (Megatron over tp)
             (r"(mlp|shared)_(gate|up)/kernel$", P("fsdp", "tp")),

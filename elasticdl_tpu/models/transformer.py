@@ -22,6 +22,7 @@ Design notes (TPU-first):
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -30,7 +31,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
+from elasticdl_tpu.ops import gated_delta
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
     ring_attention,
@@ -41,6 +44,9 @@ from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
 from elasticdl_tpu.train.optimizers import create_optimizer
+
+
+logger = _logger_factory("elasticdl_tpu.models.transformer")
 
 
 # Where the activations live (parallel/sharding.py:constrain): the
@@ -69,16 +75,39 @@ def rotary_embedding(x, base=10000.0, seq_axis=2):
     ).astype(x.dtype)
 
 
+class ZeroCentredRMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + w)`` over the last axis, ``w``
+    starting at 0 (Qwen3-Next's norm: the scale is stored as its
+    distance from 1, so weight decay pulls it toward 1 and not toward
+    0). Statistics and the product in float32, rounded once."""
+
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        wide = x.astype(jnp.float32)
+        var = jnp.mean(wide * wide, axis=-1, keepdims=True)
+        return (
+            wide * jax.lax.rsqrt(var + self.epsilon) * (1.0 + scale)
+        ).astype(x.dtype)
+
+
 def make_norm(kind, eps, name):
     """The block's normalisation by name: ``layernorm`` (scale and
-    bias, the GPT-NeoX block's) or ``rmsnorm`` (scale only, OLMoE's).
-    Both compute their statistics in float32."""
+    bias, the GPT-NeoX block's), ``rmsnorm`` (scale only, OLMoE's) or
+    ``zero_centred_rmsnorm`` (scale ``1 + w``, Qwen3-Next's). All
+    compute their statistics in float32."""
     if kind == "layernorm":
         return nn.LayerNorm(epsilon=eps, name=name)
     if kind == "rmsnorm":
         return nn.RMSNorm(epsilon=eps, name=name)
+    if kind == "zero_centred_rmsnorm":
+        return ZeroCentredRMSNorm(epsilon=eps, name=name)
     raise ValueError(
-        "norm must be 'layernorm' or 'rmsnorm', got %r" % (kind,)
+        "norm must be 'layernorm', 'rmsnorm' or 'zero_centred_rmsnorm', "
+        "got %r" % (kind,)
     )
 
 
@@ -93,15 +122,54 @@ class Attention(nn.Module):
     # are split and rotated: OLMoE's QK-norm (arXiv:2409.02060, 4.2.5)
     qk_norm: bool = False
     norm_eps: float = 1e-6
+    # Each of the five below defaults to what the GPT-NeoX and OLMoE
+    # blocks get, whose programs lower as they did without them.
+    # A head width of its own (None: model width / heads); Qwen3-Next:
+    # 16 heads of 256 over a model width of 2048
+    head_dim: Optional[int] = None
+    # grouped-query attention: k and v have this many heads (None: as
+    # many as q), query head h reads kv head h // (heads / kv heads);
+    # the flash kernel reads them uncopied
+    num_kv_heads: Optional[int] = None
+    # a norm of this kind (``make_norm``) over the lanes of every query
+    # head and of every key head, one scale each shared by the heads,
+    # before the rotation (``q_norm`` / ``k_norm``, as ``qk_norm``,
+    # which norms the whole projection, names its own)
+    head_norm: Optional[str] = None
+    # rotary on the first ``rotary_dim`` lanes of a head (None: all)
+    rotary_dim: Optional[int] = None
+    # "sigmoid": the query projection is twice as wide and its second
+    # half, a gate of the head's width, multiplies the attention's
+    # output through a sigmoid before the output projection
+    output_gate: Optional[str] = None
+
+    def _rotate(self, t):
+        if self.rotary_dim is None:
+            return rotary_embedding(t, base=self.rope_theta)
+        return jnp.concatenate([
+            rotary_embedding(
+                t[..., :self.rotary_dim], base=self.rope_theta),
+            t[..., self.rotary_dim:],
+        ], axis=-1)
 
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
-        head_dim = dim // self.num_heads
+        head_dim = self.head_dim or dim // self.num_heads
+        kv_heads = self.num_kv_heads or self.num_heads
+        if self.output_gate not in (None, "sigmoid"):
+            raise ValueError(
+                "output_gate must be None or 'sigmoid', got %r"
+                % (self.output_gate,))
+        if self.qk_norm and (
+                self.head_norm or self.head_dim or self.num_kv_heads):
+            raise ValueError(
+                "qk_norm norms a projection of the model's width; a head "
+                "width or kv head count of its own takes head_norm")
 
-        def dense(name, norm=None):
+        def dense(name, norm=None, heads=self.num_heads, width=head_dim):
             out = nn.DenseGeneral(
-                (self.num_heads, head_dim),
+                (heads, width),
                 axis=-1,
                 use_bias=False,
                 name=name,
@@ -111,6 +179,11 @@ class Attention(nn.Module):
                     out.reshape(x.shape)
                 ).reshape(out.shape)
             return out
+
+        def head_norm(t, name):
+            if self.head_norm is None:
+                return t
+            return make_norm(self.head_norm, self.norm_eps, name)(t)
         # (B, S, H, d) -> (B, H, S, d). The model transposes because a
         # kernel that addressed heads inside the fused (H*d) minor dim
         # lost on the v5e: XLA's transposes run near the HBM roofline,
@@ -124,22 +197,40 @@ class Attention(nn.Module):
         to_bhsd = lambda t: constrain(
             t.transpose(0, 2, 1, 3), self.mesh, spec
         )
-        q = to_bhsd(dense("query", "q_norm"))
-        k = to_bhsd(dense("key", "k_norm"))
-        v = to_bhsd(dense("value"))
-        q = rotary_embedding(q, base=self.rope_theta)
-        k = rotary_embedding(k, base=self.rope_theta)
-
-        if self.attention_impl == "ring":
-            out = ring_attention(q, k, v, self.mesh, causal=True)
-        elif self.attention_impl == "ulysses":
-            out = ulysses_attention(q, k, v, self.mesh, causal=True)
+        gate = None
+        if self.output_gate:
+            q = dense("query", width=2 * head_dim)
+            q, gate = q[..., :head_dim], q[..., head_dim:]
         else:
+            q = dense("query", "q_norm")
+        q = to_bhsd(head_norm(q, "q_norm"))
+        k = to_bhsd(head_norm(
+            dense("key", "k_norm", heads=kv_heads), "k_norm"))
+        v = to_bhsd(dense("value", heads=kv_heads))
+        q = self._rotate(q)
+        k = self._rotate(k)
+
+        if self.attention_impl in ("ring", "ulysses"):
+            if kv_heads != self.num_heads:
+                raise ValueError(
+                    "attention_impl=%r takes equal head counts"
+                    % (self.attention_impl,))
+            schedule = (ring_attention if self.attention_impl == "ring"
+                        else ulysses_attention)
+            out = schedule(q, k, v, self.mesh, causal=True)
+        else:
+            note = " ".join(filter(None, (
+                self.output_gate and "gate=%s" % self.output_gate,
+                self.rotary_dim and "rotary=%d/%d" % (
+                    self.rotary_dim, head_dim),
+            )))
             out = dot_product_attention(
                 q, k, v, causal=True, impl=self.attention_impl,
-                mesh=self.mesh, spec=spec,
+                mesh=self.mesh, spec=spec, note=note,
             )
         out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
+        if gate is not None:
+            out = out * jax.nn.sigmoid(gate)
         out = nn.DenseGeneral(
             dim, axis=(-2, -1), use_bias=False, name="out_proj"
         )(out)
@@ -246,14 +337,144 @@ class LatentAttention(nn.Module):
             )(out)
 
 
-def make_attention(num_heads, latent=None, **fields):
-    """The block's attention, ``name="attn"``: ``LatentAttention`` where
-    the model names latent widths (``LatentDims``), else ``Attention``.
-    ``fields``: what both take, and ``qk_norm`` / ``dropout``, which
-    only ``Attention`` has."""
+@dataclasses.dataclass(frozen=True)
+class GatedDeltaDims:
+    """The sizes of a Gated DeltaNet mixer as Qwen3-Next's
+    ``config.json`` names them (``linear_*``), and the chunk of the
+    chunked rule (``ops/gated_delta.py``)."""
+
+    num_key_heads: int
+    num_value_heads: int
+    key_head_dim: int
+    value_head_dim: int
+    conv_kernel_dim: int
+    chunk: int = gated_delta.DEFAULT_CHUNK
+
+
+@functools.lru_cache(maxsize=None)
+def _log_linear_once(dims, tokens):
+    """One line per distinct linear-attention layer (this runs at trace
+    time), beside the attention line of ``ops/attention.py``."""
+    logger.info(
+        "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
+        "(tokens=%d)",
+        dims.num_key_heads, dims.num_value_heads, dims.key_head_dim,
+        dims.chunk, gated_delta.IMPL, tokens,
+    )
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """log of a uniform draw in (0, 16): the published code's."""
+    return jnp.log(jax.random.uniform(
+        key, shape, dtype, minval=1e-4, maxval=16.0))
+
+
+class GatedDeltaNet(nn.Module):
+    """The Gated DeltaNet mixer (arXiv:2412.06464), Qwen3-Next's
+    ``linear_attention`` layer, for one token x:
+
+        q | k | v | z = x W_qkvz       (Hk x Dk, Hk x Dk, Hv x Dv, Hv x Dv)
+        b | a         = x W_ba         (Hv each)
+        [q | k | v]   = silu(causal depthwise conv over ``conv_kernel_dim``
+                        tokens, no bias)
+        beta = sigmoid(b);  g = -exp(A_log) softplus(a + dt_bias)  (float32)
+        q, k l2-normalised over their lanes (eps 1e-6), q scaled Dk^-1/2
+        o = gated delta rule (``ops/gated_delta.py``: value head h reads
+            key head h // (Hv / Hk))
+        o = RMSNorm_Dv(o) w silu(z)    (per head, w starts at 1, float32)
+        y = o W_o
+
+    Scopes: ``gdn/in_proj`` (the two input matmuls), ``gdn/conv``,
+    ``gdn/gates`` (beta, g, the l2 norms, the transposes), ``gdn/scan``
+    (the chunked rule, whole), ``gdn/out_norm``, ``gdn/out_proj``. The
+    columns of ``in_proj_qkvz`` lie q | k | v | z where the published
+    code interleaves them by key head: with seeded weights a fixed
+    permutation."""
+
+    dims: GatedDeltaDims
+    norm_eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        dims = self.dims
+        hk, hv = dims.num_key_heads, dims.num_value_heads
+        dk, dv = dims.key_head_dim, dims.value_head_dim
+        batch, seq, dim = x.shape
+        _log_linear_once(dims, batch * seq)
+        key_dim, value_dim = hk * dk, hv * dv
+        with jax.named_scope("gdn/in_proj"):
+            qkvz = nn.Dense(
+                2 * key_dim + 2 * value_dim, use_bias=False,
+                name="in_proj_qkvz")(x)
+            ba = nn.Dense(2 * hv, use_bias=False, name="in_proj_ba")(x)
+        conv_dim = 2 * key_dim + value_dim
+        with jax.named_scope("gdn/conv"):
+            taps = self.param(
+                "conv_kernel",
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                (dims.conv_kernel_dim, conv_dim),
+            ).astype(x.dtype)
+            qkv = qkvz[..., :conv_dim]
+            padded = jnp.pad(
+                qkv, ((0, 0), (dims.conv_kernel_dim - 1, 0), (0, 0)))
+            # y_t = sum_j taps[j] x_(t - (taps - 1) + j): four shifted
+            # multiply-adds, one fusion
+            qkv = nn.silu(sum(
+                taps[j] * padded[:, j:j + seq]
+                for j in range(dims.conv_kernel_dim)))
+        with jax.named_scope("gdn/gates"):
+            a_log = self.param("A_log", _a_log_init, (hv,))
+            dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
+            ba = ba.astype(jnp.float32)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+
+            def heads(t, num, width, normalise=None):
+                """(B, S, num x width) -> (B, num, S, width); with
+                ``normalise``, l2-normalised over the lanes (float32,
+                eps 1e-6) and scaled by it."""
+                t = t.reshape(batch, seq, num, width)
+                if normalise is not None:
+                    lanes = t.astype(jnp.float32)
+                    t = (lanes * jax.lax.rsqrt(
+                        jnp.sum(lanes * lanes, axis=-1, keepdims=True) + 1e-6
+                    ) * normalise).astype(t.dtype)
+                return t.transpose(0, 2, 1, 3)
+
+            q = heads(qkv[..., :key_dim], hk, dk, dk ** -0.5)
+            k = heads(qkv[..., key_dim:2 * key_dim], hk, dk, 1.0)
+            v = heads(qkv[..., 2 * key_dim:], hv, dv)
+            g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)
+        with jax.named_scope("gdn/scan"):
+            o = gated_delta.gated_delta_rule(
+                q, k, v, g, beta, chunk=dims.chunk)
+        with jax.named_scope("gdn/out_norm"):
+            z = qkvz[..., conv_dim:].reshape(batch, seq, hv, dv)
+            o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
+                o.transpose(0, 2, 1, 3))  # (B, S, Hv, Dv)
+            o = (o.astype(jnp.float32)
+                 * nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+        with jax.named_scope("gdn/out_proj"):
+            return nn.DenseGeneral(
+                dim, axis=(-2, -1), use_bias=False, name="out_proj")(o)
+
+
+def make_attention(num_heads, latent=None, linear=None, **fields):
+    """The block's mixer, ``name="attn"``, by the layer's kind:
+    ``GatedDeltaNet`` where the layer is a linear-attention one
+    (``linear``: its ``GatedDeltaDims``), ``LatentAttention`` where the
+    model names latent widths (``LatentDims``), else ``Attention``.
+    ``fields``: ``norm_eps``, which all take, what the two softmax ones
+    take, and what only ``Attention`` has (``qk_norm``, ``dropout`` and
+    the grouped-query fields)."""
+    if linear is not None:
+        return GatedDeltaNet(
+            linear, norm_eps=fields["norm_eps"], name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
-    for name in ("qk_norm", "dropout"):
+    for name in ("qk_norm", "dropout", "head_dim", "num_kv_heads",
+                 "head_norm", "rotary_dim", "output_gate"):
         if fields.pop(name, None):
             raise ValueError("latent attention has no %s" % name)
     return LatentAttention(num_heads, latent, name="attn", **fields)
@@ -322,7 +543,10 @@ def remat_block(block_cls, remat_policy, attention_impl):
     recomputed). "flash" saves ONLY the flash kernel's named outputs:
     the projections/mlp recompute like "full", but the O(S^2)
     attention forward never re-runs, the middle ground for lengths
-    where "dots" exceeds HBM (16k on one chip: PERF.md Section 4)."""
+    where "dots" exceeds HBM (16k on one chip: PERF.md Section 4). The
+    chunked gated delta rule names its output too
+    (``ops/gated_delta.py:GDN_OUT_NAME``) and no policy here keeps it:
+    its backward rebuilds its segments' residuals either way."""
     import jax
 
     from elasticdl_tpu.ops.flash_attention import (

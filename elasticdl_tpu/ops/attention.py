@@ -24,9 +24,14 @@ logger = _logger_factory("elasticdl_tpu.ops.attention")
 
 def xla_attention(q, k, v, causal=False, sm_scale=None):
     """Reference O(S^2) attention over (batch, heads, seq, dim); v, and
-    so the output, may have a width of its own (the scale is q's)."""
+    so the output, may have a width of its own (the scale is q's); k
+    and v may have a head for every ``group`` query heads, and are
+    repeated here (the kernel reads them uncopied)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    group = q.shape[1] // k.shape[1]
+    if group > 1:
+        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * sm_scale
@@ -59,7 +64,8 @@ def _pallas_refusal(q, k, v, block_q, block_k):
 
 def _flash_facts(q, k, v, causal, block_q, block_k):
     """What the flash kernel does with these shapes, for the log line:
-    where v has a width of its own, both widths and how the q / k one
+    where k and v have fewer heads than q, their count and the group
+    (``kv_heads=2 group=8``); where v has a width of its own, both widths and how the q / k one
     is laid on the lanes (``head q/k=192 v=128 layout=whole``:
     ``flash_attention.QK_LAYOUT``); which backward
     (``flash_attention.backward_schedule``; a model's float32 init
@@ -82,6 +88,9 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     widths = "" if v_dim == q.shape[-1] else (
         "head q/k=%d v=%d layout=%s, " % (
             q.shape[-1], v_dim, _flash.QK_LAYOUT))
+    if k.shape[1] != q.shape[1]:
+        widths = "kv_heads=%d group=%d, %s" % (
+            k.shape[1], q.shape[1] // k.shape[1], widths)
     return "%sflash backward=%s, pairs %s%s" % (
         widths,
         _flash.backward_schedule(*shapes, block_q, block_k, v_dim),
@@ -150,11 +159,14 @@ def dot_product_attention(
     interpret=False,
     mesh=None,
     spec=None,
+    note="",
 ):
-    """q/k/v are (batch, heads, seq, dim). ``mesh`` and ``spec``: the
-    mesh the caller's step is sharded over and the PartitionSpec of
-    q/k/v on it; the Pallas kernel then runs inside a shard_map over
-    them."""
+    """q/k/v are (batch, heads, seq, dim); k and v may have a head for
+    every ``group`` query heads. ``mesh`` and ``spec``: the mesh the
+    caller's step is sharded over and the PartitionSpec of q/k/v on it;
+    the Pallas kernel then runs inside a shard_map over them. ``note``:
+    what the caller wants on the resolution's log line beside the
+    kernel's own facts (``gate=sigmoid rotary=64/256``)."""
     if impl == "auto":
         backend = jax.default_backend()
         reason = (
@@ -165,8 +177,8 @@ def dot_product_attention(
         impl = "xla" if reason else "pallas"
         _log_auto_once(
             backend, impl, reason, tuple(q.shape), q.dtype.name,
-            "" if reason else _flash_facts(
-                q, k, v, causal, block_q, block_k),
+            "" if reason else ", ".join(filter(None, (
+                note, _flash_facts(q, k, v, causal, block_q, block_k)))),
         )
     if impl == "pallas":
         kernel = functools.partial(

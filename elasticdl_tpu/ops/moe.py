@@ -28,7 +28,13 @@ reaches exactly ``k`` experts. Dispatch and combine are permutations,
 so their backward passes are gathers too (custom VJPs below): nothing
 on this path is a scatter, and nothing costs a matmul FLOP that the
 experts themselves do not need. One device's tokens only: there is no
-``ep`` all-to-all on this path yet (ROADMAP.md Reach 2).
+``ep`` all-to-all on this path yet (ROADMAP.md Reach 2). What there is
+of expert parallelism is its first half (``sort_held``,
+``dispatch_held``, ``combine_held``): a layer that holds a stated share
+of the experts routes over all of them, gives only the pairs of its own
+experts a row (in a buffer of a static size) and returns its own
+experts' part of the result; nothing stands in for the absent chips or
+their traffic.
 
 Everything is shape-static and jit-friendly: k is a Python int, the
 sorted path's only data-dependent quantity is ``group_sizes``, an
@@ -213,6 +219,74 @@ def sort_by_expert(experts, num_experts):
         axis=0, dtype=jnp.int32,
     )
     return checkpoint_name((order, inverse, group_sizes), MOE_ROUTE_NAME)
+
+
+def sort_held(experts, num_experts, first, count, buffer_rows):
+    """The sort of an expert layer that holds experts ``first`` to
+    ``first + count - 1`` of ``num_experts`` (one chip's share under
+    expert parallelism): only the (token, choice) pairs whose expert
+    lives here get a row, in a buffer of the static size
+    ``buffer_rows``.
+
+    experts: (T, k) int32 over ALL experts. Returns
+
+    - ``pairs`` (buffer_rows,): the pair at each row, held pairs first,
+      grouped by expert, a group's tokens in order;
+    - ``valid`` (buffer_rows,) bool: the row carries a held pair;
+    - ``group_sizes`` (count,) int32 summing to ``buffer_rows``: the
+      rows of each held expert, the rows past the last held pair
+      counted to the last expert (the grouped matmul computes whole
+      buffers; their result is never added: ``combine_held``);
+    - ``loads`` (num_experts,) int32: pairs per expert over all
+      experts, what the balance loss and the counters read;
+    - ``held``, ``dropped`` (int32 scalars): the pairs whose expert
+      lives here, and those of them that found no row (0 unless the
+      buffer is too small).
+
+    A buffer larger than T x k (a short sequence under a configuration
+    sized for a long one) is cut to it: no pair can lack a row then."""
+    flat = experts.reshape(-1)
+    buffer_rows = min(buffer_rows, flat.shape[0])
+    loads = jnp.sum(
+        flat[:, None] == jnp.arange(num_experts, dtype=flat.dtype)[None],
+        axis=0, dtype=jnp.int32,
+    )
+    local = flat - first
+    here = (local >= 0) & (local < count)
+    # pairs of absent experts sort behind every held one
+    pairs = jnp.argsort(
+        jnp.where(here, local, count), stable=True
+    ).astype(jnp.int32)[:buffer_rows]
+    sizes = jax.lax.dynamic_slice_in_dim(loads, first, count)
+    held = sizes.sum()
+    ends = jnp.minimum(jnp.cumsum(sizes), buffer_rows)
+    valid = jnp.arange(buffer_rows) < ends[-1]
+    ends = ends.at[-1].set(buffer_rows)
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    dropped = jnp.maximum(held - buffer_rows, 0)
+    return checkpoint_name(
+        (pairs, valid, group_sizes, loads, held, dropped), MOE_ROUTE_NAME)
+
+
+def dispatch_held(x, pairs, k):
+    """x (T, M) -> (buffer_rows, M): row r is the token of pair
+    ``pairs[r]``. A gather; its transpose is a scatter-add of
+    ``buffer_rows`` rows (a sixteenth of the pairs where a sixteenth
+    of the experts is held), where the dropless path's custom VJPs
+    gather through all T x k."""
+    return jnp.take(x, pairs // k, axis=0)
+
+
+def combine_held(rows, gates, pairs, valid):
+    """rows (buffer_rows, M), gates (T, k) -> (T, M): each token's held
+    experts' outputs under their gates, summed in float32 and rounded
+    once; nothing for a pair whose expert lives elsewhere, and nothing
+    from a row that carries no pair."""
+    tokens, k = gates.shape
+    gate_of = jnp.where(valid, jnp.take(gates.reshape(-1), pairs), 0.0)
+    y = jnp.zeros((tokens, rows.shape[-1]), jnp.float32).at[pairs // k].add(
+        rows.astype(jnp.float32) * gate_of[:, None])
+    return y.astype(rows.dtype)
 
 
 @jax.custom_vjp
@@ -544,16 +618,25 @@ def router_z_loss(router_logits):
     return jnp.mean(jnp.square(z))
 
 
-def routing_stats(probs, group_sizes, k):
+def routing_stats(probs, group_sizes, k, held=None, dropped=None):
     """What the ``moe_routing`` journal event reports of one expert
-    layer, as device scalars: pairs per expert (largest and mean), the
-    router's mean entropy in nats, and the pairs that reached no expert
-    (counted from the group sizes; the sorted path drops none)."""
+    layer, as device scalars: pairs per expert (largest and mean, over
+    ALL experts), the router's mean entropy in nats, and the pairs that
+    reached no expert (counted from the group sizes; the sorted path
+    drops none). A layer that holds a share of the experts
+    (``sort_held``) also reports ``held``, the pairs whose expert lives
+    here, and its ``dropped`` are those of them its buffer had no row
+    for."""
     tokens = probs.shape[0]
     entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1).mean()
-    return {
+    stats = {
         "load_max": group_sizes.max().astype(jnp.float32),
         "load_mean": group_sizes.astype(jnp.float32).mean(),
         "entropy": entropy,
-        "dropped": (tokens * k - group_sizes.sum()).astype(jnp.float32),
+        "dropped": (
+            tokens * k - group_sizes.sum() if dropped is None else dropped
+        ).astype(jnp.float32),
     }
+    if held is not None:
+        stats["held"] = held.astype(jnp.float32)
+    return stats

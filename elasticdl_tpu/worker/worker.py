@@ -829,9 +829,12 @@ class Worker:
                         router_entropy=routing["entropy"],
                         dropped_pairs=routing["dropped"],
                         # only a layer that keeps a balancing bias
-                        # (sigmoid scoring) reports its magnitude
-                        **{k: routing[k] for k in ("bias_abs_max",)
-                           if k in routing},
+                        # (sigmoid scoring) reports its magnitude, and
+                        # only one that holds a share of its experts
+                        # the pairs that share got
+                        **{name: routing[k] for k, name in (
+                            ("bias_abs_max", "bias_abs_max"),
+                            ("held", "held_pairs")) if k in routing},
                     )
         with phase("callbacks"):
             for cb in self._callbacks:

@@ -84,3 +84,60 @@ def test_the_count_is_above_what_the_compiler_needs(
         16384, 256, *blocks, 2) < mib * 2**20
     assert flash_kernels(chip, (1, 1, 16384, 256)) == {
         "flash_fwd": 1, "flash_bwd": 1}
+
+
+@pytest.mark.parametrize("shape,kv_heads,kernels", [
+    # qwen3next80b-s32k: 16 query heads over 2 kv heads, the split pair
+    ((1, 16, 32768, 256), 2,
+     {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}),
+    # a grouped call under the fused backward's budget
+    ((2, 8, 4096, 128), 2, {"flash_fwd": 1, "flash_bwd": 1}),
+], ids=["s32k-16-over-2", "s4k-8-over-2"])
+def test_grouped_query_heads_compile_without_a_copy_of_k_or_v(
+        chip, shape, kv_heads, kernels):
+    """k and v keep their own head count up to the kernels' operands:
+    the compiled program holds no array of the kv width at the query
+    heads' count in the kernels' dtype but q, o, do and dq themselves
+    (dk and dv leave a query head in float32 and are summed after)."""
+    batch, heads, seq, dim = shape
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct(
+        (batch, kv_heads, seq, dim), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert device_obs.pallas_kernels(hlo) == kernels
+    merged = "bf16[%d,%d,%d]" % (batch * kv_heads, seq, dim)
+    forward = [line for line in hlo.splitlines()
+               if "flash_fwd" in line and "custom-call(" in line]
+    assert len(forward) == 1 and forward[0].count(merged) >= 2
+    # the gradients come back at the kv heads' count
+    assert "f32[%d,%d,%d]" % (batch * heads, seq, dim) in hlo
+
+
+def test_the_chunked_rule_compiles_at_the_cell_s_shape(chip):
+    """``gated_delta_rule``'s gradient at 32,768 tokens, 16 key and 32
+    value heads of 128, chunk 64, for a described v5e: the segments'
+    ``jax.checkpoint`` keeps its temporaries under 2.5 GB (4 GB and a
+    refused step without it, PERF.md Section 6)."""
+    from elasticdl_tpu.ops import gated_delta
+
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    args = (
+        struct((1, 16, 32768, 128), jnp.bfloat16),
+        struct((1, 16, 32768, 128), jnp.bfloat16),
+        struct((1, 32, 32768, 128), jnp.bfloat16),
+        struct((1, 32, 32768), jnp.float32),
+        struct((1, 32, 32768), jnp.float32),
+    )
+    compiled = jax.jit(jax.grad(
+        lambda *a: gated_delta.gated_delta_rule(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))
+    ).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
+    assert "while" in compiled.as_text()

@@ -1,0 +1,240 @@
+"""The gated delta rule in chunked form (``ops/gated_delta.py``, ISSUE
+31) against the per-token recurrence, and ``GatedDeltaNet`` against its
+equations, at small sizes on the CPU with seeded inputs. Float64 where
+the comparison is exact (the chunked form reorders sums, nothing else),
+bfloat16 where the precision rules are the subject."""
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.models import transformer
+from elasticdl_tpu.models.transformer import GatedDeltaDims, GatedDeltaNet
+from elasticdl_tpu.ops import gated_delta
+from elasticdl_tpu.ops.gated_delta import (
+    gated_delta_recurrence,
+    gated_delta_rule,
+    unit_lower_inverse,
+)
+
+
+@pytest.fixture
+def x64():
+    jax.config.update("jax_enable_x64", True)
+    yield
+    jax.config.update("jax_enable_x64", False)
+
+
+def _inputs(seq, dtype, decay=1.0, seed=0, batch=2, hk=2, hv=4, dim=16):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(keys[0], (batch, hk, seq, dim))) * dim ** -0.5
+    k = unit(jax.random.normal(keys[1], (batch, hk, seq, dim)))
+    v = jax.random.normal(keys[2], (batch, hv, seq, dim))
+    g = -decay * jax.random.uniform(keys[3], (batch, hv, seq))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, hv, seq)))
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (
+        g.astype(jnp.promote_types(dtype, jnp.float32)),
+        beta.astype(jnp.promote_types(dtype, jnp.float32)))
+
+
+def _value_and_grads(rule, args):
+    loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
+    return (rule(*args),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+
+@pytest.mark.parametrize("decay", [1e-3, 1.0, 30.0],
+                         ids=["g-near-0", "g-1", "g-strongly-negative"])
+@pytest.mark.parametrize("seq,chunk,segment", [
+    (256, 64, 128),   # whole chunks, one segment
+    (200, 64, 128),   # the chunk does not divide the length
+    (130, 32, 128),
+    (256, 16, 4),     # four segments of four chunks, each rematerialised
+    (200, 16, 4),     # and a length the segment does not divide
+], ids=["256-64", "200-64", "130-32", "256-16-seg4", "200-16-seg4"])
+def test_chunked_rule_is_the_recurrence(x64, seq, chunk, segment, decay):
+    """Values and all five gradients, in float64: equal to rounding."""
+    args = _inputs(seq, jnp.float64, decay)
+    got = _value_and_grads(
+        lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment), args)
+    want = _value_and_grads(gated_delta_recurrence, args)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-11 * (
+            1 + float(jnp.abs(b).max())))
+
+
+def test_strongly_negative_decay_is_finite_in_float32():
+    """exp of every decay difference is taken of a number <= 0: a state
+    that decays by e^-50 a token underflows to 0 and never to inf or
+    nan, in the values and in the gradients."""
+    args = _inputs(128, jnp.float32, decay=50.0)
+    for out in _value_and_grads(
+            lambda *a: gated_delta_rule(*a, chunk=64), args):
+        assert bool(jnp.isfinite(out).all())
+
+
+def test_key_heads_are_shared_and_never_repeated():
+    """Value head h reads key head h // 2; the rule's jaxpr holds no
+    array with q's or k's lanes at the value heads' count but the
+    decayed copies the algorithm itself needs."""
+    args = _inputs(64, jnp.float32)
+    q, k, v, g, beta = args
+    rep = lambda x: jnp.repeat(x, 2, axis=1)
+    np.testing.assert_allclose(
+        gated_delta_rule(*args, chunk=16),
+        gated_delta_rule(rep(q), rep(k), v, g, beta, chunk=16),
+        atol=1e-5)
+    with pytest.raises(ValueError, match="do not divide"):
+        gated_delta_rule(q, k, v[:, :3], g[:, :3], beta[:, :3])
+
+
+@pytest.mark.parametrize("size", [2, 16, 64])
+def test_the_inverse_by_its_product_form(x64, size):
+    rng = np.random.RandomState(size)
+    a = jnp.asarray(np.tril(0.2 * rng.randn(3, size, size), -1))
+    inverse = unit_lower_inverse(a)
+    eye = np.eye(size)
+    np.testing.assert_allclose(
+        inverse @ (eye + a), np.broadcast_to(eye, a.shape), atol=1e-9)
+    # its own VJP against autodiff of a solve
+    weight = jnp.asarray(rng.randn(3, size, size))
+    got = jax.grad(lambda a: jnp.sum(unit_lower_inverse(a) * weight))(a)
+    want = jax.grad(
+        lambda a: jnp.sum(jnp.linalg.inv(eye + a) * weight))(a)
+    np.testing.assert_allclose(got, want, atol=1e-8)
+
+
+def test_the_inverse_takes_a_power_of_two():
+    with pytest.raises(ValueError, match="power of two"):
+        unit_lower_inverse(jnp.zeros((48, 48)))
+
+
+def test_precision_rules_in_bfloat16():
+    """bfloat16 operands, float32 decay, inverse and state: the rule
+    stays within bfloat16's rounding of the float32 recurrence. The
+    decay CUMULATED in bfloat16 is several times worse (the benchmark's
+    check has to tell the two apart, PERF.md Section 6). The state
+    CARRIED in bfloat16 is not: it is rounded to bfloat16 wherever it is
+    a matmul operand, so a second rounding of the carry changes
+    little."""
+    args = _inputs(512, jnp.float32, decay=2.0, batch=1)
+    want = gated_delta_recurrence(*args)
+    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
+    err = lambda out: float(
+        jnp.sqrt(jnp.mean((out.astype(jnp.float32) - want) ** 2)
+                 / jnp.mean(want ** 2)))
+    stated = err(gated_delta_rule(*low, chunk=64))
+    assert stated < 0.01
+    assert err(gated_delta_rule(
+        *low, chunk=64, decay_dtype=jnp.bfloat16)) > 4 * stated
+    carried = err(gated_delta_rule(
+        *low, chunk=64, state_dtype=jnp.bfloat16))
+    assert 0.9 * stated < carried < 1.5 * stated
+    assert gated_delta_rule(*low, chunk=64).dtype == jnp.bfloat16
+
+
+def test_the_output_is_named_for_remat_policies():
+    args = _inputs(64, jnp.float32)
+    text = str(jax.make_jaxpr(
+        lambda *a: gated_delta_rule(*a, chunk=16))(*args))
+    assert "name=%s" % gated_delta.GDN_OUT_NAME in text
+
+
+# ---------------------------------------------------------- the module
+
+DIMS = GatedDeltaDims(
+    num_key_heads=2, num_value_heads=4, key_head_dim=16, value_head_dim=8,
+    conv_kernel_dim=4, chunk=16)
+
+
+def _by_the_equations(x, p, dims):
+    """ISSUE 31's equations for one sequence, one token a step, numpy
+    float64."""
+    hk, hv = dims.num_key_heads, dims.num_value_heads
+    dk, dv = dims.key_head_dim, dims.value_head_dim
+    p = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float64), p)
+    seq = x.shape[0]
+    silu = lambda t: t / (1 + np.exp(-t))
+    qkvz = x @ p["in_proj_qkvz"]["kernel"]
+    ba = x @ p["in_proj_ba"]["kernel"]
+    conv_dim = 2 * hk * dk + hv * dv
+    taps = p["conv_kernel"]
+    padded = np.concatenate(
+        [np.zeros((len(taps) - 1, conv_dim)), qkvz[:, :conv_dim]])
+    qkv = silu(sum(taps[j] * padded[j:j + seq] for j in range(len(taps))))
+    beta = 1 / (1 + np.exp(-ba[:, :hv]))
+    g = -np.exp(p["A_log"]) * np.log1p(np.exp(ba[:, hv:] + p["dt_bias"]))
+    q = qkv[:, :hk * dk].reshape(seq, hk, dk)
+    k = qkv[:, hk * dk:2 * hk * dk].reshape(seq, hk, dk)
+    v = qkv[:, 2 * hk * dk:].reshape(seq, hv, dv)
+    z = qkvz[:, conv_dim:].reshape(seq, hv, dv)
+    l2 = lambda t: t / np.sqrt((t * t).sum(-1, keepdims=True) + 1e-6)
+    q, k = l2(q) * dk ** -0.5, l2(k)
+    out = np.zeros((seq, hv, dv))
+    for h in range(hv):
+        state = np.zeros((dk, dv))
+        for t in range(seq):
+            state = np.exp(g[t, h]) * state
+            u = beta[t, h] * (v[t, h] - state.T @ k[t, h // (hv // hk)])
+            state = state + np.outer(k[t, h // (hv // hk)], u)
+            out[t, h] = state.T @ q[t, h // (hv // hk)]
+    out = out / np.sqrt((out * out).mean(-1, keepdims=True) + 1e-6)
+    out = out * p["out_norm"]["scale"] * silu(z)
+    return np.einsum("shv,hvd->sd", out, p["out_proj"]["kernel"])
+
+
+def test_gated_delta_net_against_its_equations():
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32))
+    layer = GatedDeltaNet(DIMS)
+    variables = layer.init(jax.random.PRNGKey(2), x)
+    params = variables["params"]
+    assert {k: v.shape for k, v in params.items() if hasattr(v, "shape")} == {
+        "conv_kernel": (4, 96), "A_log": (4,), "dt_bias": (4,)}
+    assert params["in_proj_qkvz"]["kernel"].shape == (32, 128)
+    assert params["in_proj_ba"]["kernel"].shape == (32, 8)
+    assert params["out_proj"]["kernel"].shape == (4, 8, 32)
+    # as the published code initialises them
+    assert bool((params["dt_bias"] == 1).all())
+    assert bool((params["out_norm"]["scale"] == 1).all())
+    assert bool((jnp.exp(params["A_log"]) < 16).all())
+    got = layer.apply(variables, x)
+    for row in range(2):
+        np.testing.assert_allclose(
+            got[row], _by_the_equations(
+                np.asarray(x[row], np.float64), params, DIMS),
+            atol=2e-5)
+
+
+def test_the_convolution_is_causal():
+    """Token t's output depends on tokens <= t alone (the conv pads on
+    the left, the rule is a recurrence)."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 24, 32))
+    layer = GatedDeltaNet(DIMS)
+    variables = layer.init(jax.random.PRNGKey(4), x)
+    base = layer.apply(variables, x)
+    moved = layer.apply(variables, x.at[:, 12:].add(1.0))
+    np.testing.assert_allclose(base[:, :12], moved[:, :12], atol=1e-6)
+    assert float(jnp.abs(base[:, 12:] - moved[:, 12:]).max()) > 1e-3
+
+
+def test_the_scopes_and_the_line(caplog):
+    transformer._log_linear_once.cache_clear()
+    x = jnp.zeros((1, 32, 32))
+    layer = GatedDeltaNet(DIMS)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    with caplog.at_level(logging.INFO):
+        transformer._log_linear_once.cache_clear()
+        text = jax.jit(
+            lambda v, x: jax.grad(
+                lambda v: layer.apply(v, x).sum())(v)
+        ).lower(variables, x).as_text(debug_info=True)
+    for scope in ("in_proj", "conv", "gates", "scan", "out_norm",
+                  "out_proj"):
+        assert "gdn/%s" % scope in text, scope
+    assert (
+        "linear attention heads k=2 v=4 dim=16 chunk=16 impl=xla"
+        in caplog.text)
