@@ -22,7 +22,6 @@ Design notes (TPU-first):
 """
 
 import dataclasses
-import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -31,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.ops import gated_delta
 from elasticdl_tpu.ops.attention import dot_product_attention
@@ -44,9 +42,6 @@ from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
 from elasticdl_tpu.train.optimizers import create_optimizer
-
-
-logger = _logger_factory("elasticdl_tpu.models.transformer")
 
 
 # Where the activations live (parallel/sharding.py:constrain): the
@@ -351,18 +346,6 @@ class GatedDeltaDims:
     chunk: int = gated_delta.DEFAULT_CHUNK
 
 
-@functools.lru_cache(maxsize=None)
-def _log_linear_once(dims, tokens):
-    """One line per distinct linear-attention layer (this runs at trace
-    time), beside the attention line of ``ops/attention.py``."""
-    logger.info(
-        "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
-        "(tokens=%d)",
-        dims.num_key_heads, dims.num_value_heads, dims.key_head_dim,
-        dims.chunk, gated_delta.IMPL, tokens,
-    )
-
-
 def _a_log_init(key, shape, dtype=jnp.float32):
     """log of a uniform draw in (0, 16): the published code's."""
     return jnp.log(jax.random.uniform(
@@ -393,6 +376,9 @@ class GatedDeltaNet(nn.Module):
 
     dims: GatedDeltaDims
     norm_eps: float = 1e-6
+    # the mesh the step is sharded over, if any: the rule's kernels
+    # run on one device (``ops/gated_delta.py:inverse_impl``)
+    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -400,7 +386,6 @@ class GatedDeltaNet(nn.Module):
         hk, hv = dims.num_key_heads, dims.num_value_heads
         dk, dv = dims.key_head_dim, dims.value_head_dim
         batch, seq, dim = x.shape
-        _log_linear_once(dims, batch * seq)
         key_dim, value_dim = hk * dk, hv * dv
         with jax.named_scope("gdn/in_proj"):
             qkvz = nn.Dense(
@@ -448,7 +433,7 @@ class GatedDeltaNet(nn.Module):
             g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)
         with jax.named_scope("gdn/scan"):
             o = gated_delta.gated_delta_rule(
-                q, k, v, g, beta, chunk=dims.chunk)
+                q, k, v, g, beta, chunk=dims.chunk, mesh=self.mesh)
         with jax.named_scope("gdn/out_norm"):
             z = qkvz[..., conv_dim:].reshape(batch, seq, hv, dv)
             o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
@@ -470,7 +455,8 @@ def make_attention(num_heads, latent=None, linear=None, **fields):
     the grouped-query fields)."""
     if linear is not None:
         return GatedDeltaNet(
-            linear, norm_eps=fields["norm_eps"], name="attn")
+            linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
+            name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
     for name in ("qk_norm", "dropout", "head_dim", "num_kv_heads",

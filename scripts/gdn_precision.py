@@ -1,6 +1,7 @@
 """The Qwen3-Next configuration's reference check over several seeds and
-under two lower precisions of the chunked gated delta rule, and the rule
-alone against the clock (one chip, ~20 min).
+under two lower precisions of the chunked gated delta rule, and the
+inverse alone and the rule alone against the clock, as this backend runs
+them and by XLA's product form (one chip, ~20 min).
 
     python scripts/gdn_precision.py --seeds 8 --variant-seeds 2
 
@@ -34,8 +35,47 @@ def load(path):
         return json.load(f)
 
 
+def _clock(jax, fn, args, repeats):
+    jax.block_until_ready(fn(*args))
+    t0 = time.time()
+    for _ in range(repeats):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.time() - t0) / repeats * 1e3
+
+
+def time_inverse(jax, jnp, gated_delta, chunk, count=4096, repeats=30):
+    """The inverse alone at a segment's batch (``count`` matrices with
+    entries of the cell's size), ms: what ``unit_lower_inverse`` runs on
+    this backend (the ``gdn_inverse_*`` kernels on a TPU) beside XLA's
+    product form, forward and VJP, and the largest difference."""
+    a = jnp.tril(0.2 * jax.random.normal(
+        jax.random.PRNGKey(0), (count, chunk, chunk)), -1)
+    d = jax.random.normal(jax.random.PRNGKey(1), a.shape)
+    impl = gated_delta.inverse_impl(a.dtype, chunk)
+    out = {"impl": impl}
+    forward = jax.jit(gated_delta.unit_lower_inverse)
+    xla = jax.jit(gated_delta._inverse_product)
+    vjp = lambda impl: jax.jit(
+        lambda t, d: gated_delta._inverse_vjp_bwd(impl, t, d)[0])
+    t = xla(a)
+    out["forward_ms"] = _clock(jax, forward, (a,), repeats)
+    out["forward_xla_ms"] = _clock(jax, xla, (a,), repeats)
+    out["forward_max_abs_difference"] = float(
+        jnp.abs(forward(a) - t).max())
+    out["backward_ms"] = _clock(jax, vjp(impl), (t, d), repeats)
+    out["backward_xla_ms"] = _clock(jax, vjp("xla"), (t, d), repeats)
+    got, want = vjp(impl)(t, d), vjp("xla")(t, d)
+    out["backward_max_relative_difference"] = float(
+        jnp.abs(got - want).max() / jnp.abs(want).max())
+    return out
+
+
 def time_rule(jax, jnp, gated_delta, chunk, repeats=3):
-    """The rule alone at the cell's shape, forward + backward, ms."""
+    """The rule alone at the cell's shape, forward and forward +
+    backward, ms: as this backend runs it (``chosen``: the inverse's
+    kernels on a TPU), and with the inverse by XLA's product form at
+    matmul precision highest and high."""
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(keys[0], (1, 16, 32768, 128))) * 128 ** -0.5
@@ -45,11 +85,15 @@ def time_rule(jax, jnp, gated_delta, chunk, repeats=3):
         jax.random.uniform(keys[4], (1, 32, 1), minval=-4.0, maxval=3.0))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 32, 32768)))
     args = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta)
-    out = {}
-    for name, precision in (("highest", jax.lax.Precision.HIGHEST),
-                            ("high", jax.lax.Precision.HIGH)):
+    out = {"impl": gated_delta.inverse_impl(jnp.float32, chunk)}
+    chosen = gated_delta.inverse_impl
+    for name, impl, precision in (
+            ("chosen", chosen, jax.lax.Precision.HIGHEST),
+            ("highest", lambda *a: "xla", jax.lax.Precision.HIGHEST),
+            ("high", lambda *a: "xla", jax.lax.Precision.HIGH)):
         exact = lambda x, y, p=precision: jnp.matmul(x, y, precision=p)
-        saved, gated_delta._exact = gated_delta._exact, exact
+        saved = gated_delta._exact
+        gated_delta._exact, gated_delta.inverse_impl = exact, impl
         try:
             grad = jax.jit(jax.grad(
                 lambda *a: gated_delta.gated_delta_rule(
@@ -58,14 +102,10 @@ def time_rule(jax, jnp, gated_delta, chunk, repeats=3):
             forward = jax.jit(functools.partial(
                 gated_delta.gated_delta_rule, chunk=chunk))
             for fn, label in ((forward, "forward"), (grad, "grad")):
-                jax.block_until_ready(fn(*args))
-                t0 = time.time()
-                for _ in range(repeats):
-                    jax.block_until_ready(fn(*args))
-                out["%s_%s_ms" % (label, name)] = (
-                    (time.time() - t0) / repeats * 1e3)
+                out["%s_%s_ms" % (label, name)] = _clock(
+                    jax, fn, args, repeats)
         finally:
-            gated_delta._exact = saved
+            gated_delta._exact, gated_delta.inverse_impl = saved, chosen
     return out
 
 
@@ -106,8 +146,11 @@ def main(argv=None):
     device = jax.devices()[0]
     report = {"device": [device.platform, device.device_kind], "runs": []}
     if not args.no_timing:
-        report["rule_alone"] = time_rule(
-            jax, jnp, gated_delta, config["assumed"]["gdn_chunk"])
+        chunk = config["assumed"]["gdn_chunk"]
+        report["inverse_alone"] = time_inverse(jax, jnp, gated_delta, chunk)
+        print("inverse alone:", json.dumps(report["inverse_alone"]),
+              flush=True)
+        report["rule_alone"] = time_rule(jax, jnp, gated_delta, chunk)
         print("rule alone:", json.dumps(report["rule_alone"]), flush=True)
     variants = (
         ("stated", {}, args.seeds),

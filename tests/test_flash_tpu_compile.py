@@ -10,6 +10,8 @@ and a shape over the budget must compile to the split pair.
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -20,15 +22,19 @@ from elasticdl_tpu.ops import flash_attention as F
 
 
 @pytest.fixture(scope="module")
-def chip():
+def topology():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - whatever says "no compiler"
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 def flash_kernels(chip, shape):
@@ -119,13 +125,41 @@ def test_grouped_query_heads_compile_without_a_copy_of_k_or_v(
     assert "f32[%d,%d,%d]" % (batch * heads, seq, dim) in hlo
 
 
-def test_the_chunked_rule_compiles_at_the_cell_s_shape(chip):
+def _square_float32_dots(hlo, size=64):
+    """The dots of a compiled program whose operands and result are all
+    float32 [..., size, size]: the product form's and its VJP's."""
+    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
+    square = re.compile(r"f32\[(?:\d+,)*%d,%d\]$" % (size, size))
+    found = []
+    for out, operands in re.findall(
+            r"= (\w+\[[\d,]*\])\S* convolution\(([^)]*)\)", hlo):
+        names = re.findall(r"%([\w.\-]+)", operands)
+        if all(square.match(t) for t in
+               [out] + [types.get(n, "") for n in names]):
+            found.append(out)
+    return found
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_chunked_rule_compiles_at_the_cell_s_shape(
+        chip, monkeypatch, impl):
     """``gated_delta_rule``'s gradient at 32,768 tokens, 16 key and 32
     value heads of 128, chunk 64, for a described v5e: the segments'
     ``jax.checkpoint`` keeps its temporaries under 2.5 GB (4 GB and a
-    refused step without it, PERF.md Section 6)."""
+    refused step without it, PERF.md Section 6). With the backend a TPU
+    (``pallas``: what the chip gets, ISSUE 32) the inverses are the two
+    kernels, every ``tpu_custom_call`` named, no float32 64 x 64 dot is
+    left outside them, and the VMEM they ask for is under the limit
+    they state. The kernels' operands are row-major, 64 lanes padded to
+    128, where XLA kept some of its own matrices with the chunks on the
+    lanes: the rule alone reads 2.67 GiB for 2.45 and is held just
+    above that (the cell's whole step counts 4 MB less than the
+    parent's: PERF.md Section 6, PR 32)."""
     from elasticdl_tpu.ops import gated_delta
 
+    if impl == "pallas":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gated_delta.inverse_impl(jnp.float32, 64) == impl
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
     args = (
@@ -139,5 +173,83 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(chip):
         lambda *a: gated_delta.gated_delta_rule(*a).astype(
             jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))
     ).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5 * 2**30
-    assert "while" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "while" in hlo
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    if impl == "xla":
+        assert temporaries < 2.5 * 2**30
+        assert "tpu_custom_call" not in hlo
+        # the step's forward, the segment's again, and the VJP's two
+        assert len(_square_float32_dots(hlo)) == 22
+        return
+    assert temporaries < 2.7 * 2**30
+    # the forward in the step and in the segment's recompute; a kernel
+    # inside a loop body counts once
+    assert device_obs.pallas_kernels(hlo) == {
+        "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1}
+    assert hlo.count("tpu_custom_call") == 3
+    assert not _square_float32_dots(hlo)
+    # the compiler held each kernel to the limit it states (it refuses
+    # a body that needs more), and the blocks of a segment's 4,096
+    # matrices, operands and result double-buffered, count under it
+    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    assert all('"size":"%d"' % gated_delta._INVERSE_VMEM_LIMIT in line
+               for line in calls)
+    for arrays in (2, 3):
+        block = gated_delta.inverse_block(4096, 64, arrays)
+        assert gated_delta.inverse_vmem_bytes(
+            block, 64, arrays) < gated_delta._INVERSE_VMEM_LIMIT
+
+
+def test_the_chunked_rule_stays_partitionable_over_a_mesh(
+        topology, monkeypatch):
+    """A ``pallas_call`` has no GSPMD partitioning rule, and the rule
+    opens no ``shard_map``: with its inputs sharded by the batch over
+    the four chips of a described v5e:2x2 (data parallel or FSDP) the
+    inverses stay XLA's product form, and the gradient compiles with
+    nothing gathered. Told of no mesh the same call takes the kernels,
+    which jax refuses to partition. The model's layer hands the rule
+    its mesh."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from elasticdl_tpu.models.transformer import (
+        GatedDeltaDims,
+        make_attention,
+    )
+    from elasticdl_tpu.ops import gated_delta
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topology.devices), ("data",))
+    assert gated_delta.inverse_impl(jnp.float32, 64, mesh) == "xla"
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=NamedSharding(mesh, P("data")))
+    args = (
+        struct((4, 2, 1024, 128), jnp.bfloat16),
+        struct((4, 2, 1024, 128), jnp.bfloat16),
+        struct((4, 4, 1024, 128), jnp.bfloat16),
+        struct((4, 4, 1024), jnp.float32),
+        struct((4, 4, 1024), jnp.float32),
+    )
+    grad = lambda mesh: jax.jit(jax.grad(
+        lambda *a: gated_delta.gated_delta_rule(*a, mesh=mesh).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
+    hlo = grad(mesh).lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in hlo
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        grad(None).lower(*args)
+
+    layer = make_attention(4, linear=GatedDeltaDims(
+        num_key_heads=2, num_value_heads=4, key_head_dim=128,
+        value_head_dim=128, conv_kernel_dim=4), norm_eps=1e-6, mesh=mesh)
+    x = struct((4, 1024, 256), jnp.bfloat16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    hlo = jax.jit(jax.grad(
+        lambda v, x: layer.apply(v, x).astype(jnp.float32).sum())).lower(
+            jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
+                variables), x).compile().as_text()
+    assert "tpu_custom_call" not in hlo
