@@ -23,9 +23,15 @@ never waits for the device:
   ``interval`` steps one ``loop_phases`` event leaves the process.
 - ``begin_startup`` / ``begin_teardown`` open the two records that
   cover the process outside the loop (``worker_startup``,
-  ``worker_teardown``); the loop's first iteration lands in the
-  start-up record under start-up names (``first_task``,
-  ``first_step``).
+  ``worker_teardown``; the master's are ``master_startup`` and
+  ``master_teardown``, filled through ``end_record`` alone); the
+  loop's first iteration lands in the start-up record under start-up
+  names (``first_task``, ``first_step``). Both carry ``start_ts``,
+  their start on the epoch clock of the journal's ``ts``. While the
+  start-up record is open, and only then, a phase that closes also
+  takes what jax traced, lowered, compiled or loaded from its cache
+  since the last one did (``device_obs.compile_totals``): the
+  record's ``compiles``.
 
 The trainers reach the loop thread's ledger through ``current()``.
 ``end_record_sync`` is what is left of the old blocking clock: the
@@ -68,6 +74,11 @@ DEFAULT_INTERVAL = 100
 # the names start-up has for it
 _STARTUP_NAMES = {"input_wait": "first_task", "dispatch": "first_step"}
 
+# what a start-up phase's ``compiles`` entry holds, of the process
+# totals of the compile split
+_COMPILE_KEYS = ("requests", "hits", "misses", "trace_s", "lower_s",
+                 "backend_s")
+
 _tls = threading.local()
 
 
@@ -102,6 +113,25 @@ def process_age_ns():
     except (OSError, ValueError, IndexError):
         return None
     return max(0, int((uptime - started) * 1e9))
+
+
+def start_ledger(module_start_ns, main_start_ns, interval=0,
+                 event="worker_startup"):
+    """A role's ledger with its start-up record (``event``) opened at
+    the process's start and holding ``imports``: from there to the
+    first statement of the role's ``main`` (``main_start_ns``).
+    ``module_start_ns`` is the first statement the role's module ran:
+    where ``imports`` starts if the operating system cannot say when
+    the process did."""
+    ledger = Timing(interval=interval)
+    age_ns = process_age_ns()
+    now_ns = time.perf_counter_ns()
+    start_ns = module_start_ns if age_ns is None else now_ns - age_ns
+    # the operating system counts in 10 ms ticks: never after main began
+    start_ns = min(start_ns, main_start_ns)
+    ledger.begin_startup(start_ns, event)
+    ledger.end_record("imports", start_ns, end=main_start_ns)
+    return ledger
 
 
 def _faults():
@@ -251,6 +281,13 @@ class Timing:
         self._record = None
         self._startup = None
         self._startup_start = 0
+        # (journal event, epoch seconds of its start) of the open
+        # start-up and teardown records
+        self._startup_event = self._teardown_event = None
+        # start-up only: {phase: compile split} and the totals at the
+        # last phase's close
+        self._compiles = {}
+        self._compile_mark = None
         self._teardown_start = None
         self._walls = collections.deque(maxlen=SLOW_WINDOW)
         self._compiles_seen = self._compile_count()
@@ -296,6 +333,7 @@ class Timing:
         if record is not None:
             if record is self._startup:
                 name = _STARTUP_NAMES.get(name, name)
+                self._take_compiles(name)
             record[name] = record.get(name, 0) + self_ns
         self._count(name, self_ns)
         self._observe(name, elapsed_ns / 1e9)
@@ -438,16 +476,54 @@ class Timing:
 
     # -- outside the loop ----------------------------------------------
 
-    def begin_startup(self, start_ns):
-        """Opens the start-up record, back-dated to ``start_ns`` on
-        the ``perf_counter_ns`` clock (the process's start)."""
+    def begin_startup(self, start_ns, event="worker_startup"):
+        """Opens the start-up record, journaled as ``event`` and
+        back-dated to ``start_ns`` on the ``perf_counter_ns`` clock
+        (the process's start)."""
         self._startup = self._record = {}
         self._startup_start = start_ns
+        # one reading of each clock puts the record on the journal's
+        self._startup_event = (
+            event,
+            time.time() - (time.perf_counter_ns() - start_ns) / 1e9,
+        )
+        self._compiles = {}
+        self._compile_mark = device_obs.compile_totals()
+        device_obs.set_phase_source(self._open_phase)
+
+    def _open_phase(self):
+        """The innermost phase open on the calling thread, by its
+        start-up name; None between phases."""
+        stack = self._stack()
+        if not stack:
+            return None
+        name = stack[-1]._name
+        return _STARTUP_NAMES.get(name, name)
+
+    def _take_compiles(self, name):
+        """Charges ``name`` with what the process totals of the
+        compile split gained since a start-up phase last closed (an
+        inner phase closes, and takes, before the one around it)."""
+        totals = device_obs.compile_totals()
+        if totals is None:
+            # no listener in this process: nothing was observed
+            return
+        mark = self._compile_mark or dict.fromkeys(_COMPILE_KEYS, 0)
+        self._compile_mark = totals
+        gained = {key: totals[key] - mark[key] for key in _COMPILE_KEYS}
+        if not any(gained.values()):
+            return
+        entry = self._compiles.setdefault(name, dict.fromkeys(
+            _COMPILE_KEYS, 0))
+        for key, value in gained.items():
+            entry[key] += value
 
     def end_startup(self):
         """Closes the start-up record after the first step returned:
-        one ``worker_startup`` event, every phase in it and ``other``
-        for the rest of the wall time."""
+        one ``worker_startup`` event (or what ``begin_startup``
+        named), every phase in it and ``other`` for the rest of the
+        wall time, and under ``compiles`` what jax compiled or loaded
+        in each."""
         record, self._startup = self._startup, None
         if record is None:
             return
@@ -455,32 +531,57 @@ class Timing:
             self._record = None
         wall = time.perf_counter_ns() - self._startup_start
         record["other"] = wall - sum(record.values())
+        self._take_compiles("other")
+        device_obs.set_phase_source(None)
         self._compiles_seen = self._compile_count()
-        events.emit("worker_startup", wall_ns=wall, phases=record)
+        event, start_ts = self._startup_event
+        observed = {}
+        if self._compile_mark is not None:
+            # only where the compile split's listeners are installed:
+            # an absent field says nothing was observed, an empty one
+            # that nothing compiled. ``listener_calls`` is what they
+            # were called so far: the instrument's cost is this many
+            # short Python calls
+            observed = {
+                "compiles": {
+                    name: {key: round(value, 4)
+                           for key, value in entry.items()}
+                    for name, entry in self._compiles.items()
+                },
+                "listener_calls": self._compile_mark["listener_calls"],
+            }
+        events.emit(event, start_ts=start_ts, wall_ns=wall, phases=record,
+                    **observed)
         logger.info(
-            "worker start-up %.3fs: %s", wall / 1e9,
+            "%s %.3fs: %s; compiles %s (%s listener calls)",
+            event.replace("_startup", " start-up"), wall / 1e9,
             {name: round(ns / 1e9, 3) for name, ns in record.items()},
+            observed.get("compiles", "not observed"),
+            observed.get("listener_calls", "no"),
         )
 
-    def begin_teardown(self):
-        """Opens the teardown record (idempotent); a start-up that
-        never saw a step is closed first."""
+    def begin_teardown(self, event="worker_teardown"):
+        """Opens the teardown record, journaled as ``event``
+        (idempotent); a start-up that never saw a step is closed
+        first."""
         if self._teardown_start is not None:
             return
         self.end_startup()
         self._teardown_start = time.perf_counter_ns()
+        self._teardown_event = (event, time.time())
         self._record = {}
 
     def end_teardown(self):
-        """One ``worker_teardown`` event, just before the process
-        exits."""
+        """One ``worker_teardown`` event (or what ``begin_teardown``
+        named), just before the process exits."""
         if self._teardown_start is None:
             return
         record, self._record = self._record, None
         wall = time.perf_counter_ns() - self._teardown_start
         self._teardown_start = None
         record["other"] = wall - sum(record.values())
-        events.emit("worker_teardown", wall_ns=wall, phases=record)
+        event, start_ts = self._teardown_event
+        events.emit(event, start_ts=start_ts, wall_ns=wall, phases=record)
 
     # -- totals --------------------------------------------------------
 

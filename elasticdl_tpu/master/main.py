@@ -4,13 +4,37 @@ Reference parity: elasticdl/python/master/main.py:20-24.
 Usage: python -m elasticdl_tpu.master.main --model_zoo=... --training_data=...
 """
 
-import sys
+import atexit
+import time
 
-from elasticdl_tpu.common.args import parse_master_args
-from elasticdl_tpu.master.master import Master
+# the first statement this module runs: where ``imports`` starts if the
+# operating system cannot say when the process did
+_MODULE_START_NS = time.perf_counter_ns()
+
+# (ledger, when main returned), as in worker/main.py: the hook below
+# is registered before anything else is imported, so it is the LAST
+# exit hook to run, and the seconds to it are the ``exit`` phase of
+# ``master_teardown``
+_exiting = []
+
+
+def _close_teardown():
+    for ledger, returned_ns in _exiting:
+        ledger.end_record("exit", returned_ns)
+        ledger.end_teardown()
+
+
+atexit.register(_close_teardown)
+
+import sys  # noqa: E402
+
+from elasticdl_tpu.common import timing_utils  # noqa: E402
+from elasticdl_tpu.common.args import parse_master_args  # noqa: E402
+from elasticdl_tpu.master.master import Master  # noqa: E402
 
 
 def main(argv=None):
+    main_start_ns = time.perf_counter_ns()
     import os
 
     args = parse_master_args(argv)
@@ -36,6 +60,12 @@ def main(argv=None):
         from elasticdl_tpu.observability.http_server import PORT_ENV
 
         os.environ[PORT_ENV] = str(args.metrics_port)
+    # after the metrics knob: the ledger asks it. ``master_startup``
+    # leaves once ``role_start`` has (Master.prepare)
+    ledger = timing_utils.start_ledger(
+        _MODULE_START_NS, main_start_ns, event="master_startup"
+    )
+    ledger.end_record("configure", main_start_ns)
     records_per_task = args.records_per_task
     if args.num_minibatches_per_task > 0:
         # reference task sizing (master.py:152)
@@ -60,6 +90,7 @@ def main(argv=None):
         model_params=args.model_params,
         symbol_overrides=symbol_overrides_from_args(args),
         metrics_port=args.metrics_port,
+        ledger=ledger,
     )
     if args.job_name and os.environ.get("KUBERNETES_SERVICE_HOST"):
         # in-cluster: provision and heal worker/PS pods
@@ -72,8 +103,13 @@ def main(argv=None):
             master.rendezvous,
             envs=parse_envs_string(args.envs),
         )
-    master.prepare()
-    return master.run()
+    try:
+        master.prepare()
+        return master.run()
+    finally:
+        # ``master_teardown`` (Master.stop opened it) leaves with the
+        # last exit hook
+        _exiting.append((ledger, ledger.start()))
 
 
 if __name__ == "__main__":

@@ -10,6 +10,7 @@ rendezvous and task monitor.
 
 import time
 
+from elasticdl_tpu.common import timing_utils
 from elasticdl_tpu.common.constants import JobType
 from elasticdl_tpu.common.grpc_utils import build_server
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
@@ -52,6 +53,7 @@ class Master:
         model_params="",
         symbol_overrides=None,
         metrics_port=0,
+        ledger=None,
     ):
         if metrics_port:
             # programmatic construction (no CLI entry ran): publish the
@@ -63,11 +65,20 @@ class Master:
 
             os.environ.setdefault(http_server.PORT_ENV,
                                   str(metrics_port))
+        # the process's start-up and teardown records
+        # (``master_startup``, ``master_teardown``; master/main.py
+        # opens the first). The master has no loop: it fills them by
+        # ``end_record`` alone, and a master built without a main
+        # journals neither
+        self._ledger = ledger or timing_utils.Timing()
+        part_start = self._ledger.start()
         self.spec = get_model_spec(
             model_zoo_module, model_def=model_def,
             model_params=model_params,
             symbol_overrides=symbol_overrides,
         )
+        self._ledger.end_record("zoo", part_start)
+        part_start = self._ledger.start()
         reader_params = data_reader_params or {}
 
         def shards_of(origin):
@@ -195,6 +206,8 @@ class Master:
         self._serving = False
         self.observability = None
         self._register_domain_gauges()
+        # the shards, the dispatcher and what watches it
+        self._ledger.end_record("tasks", part_start)
 
     def _register_domain_gauges(self):
         """Master-side gauges: pending/doing/done task counts, per-stage
@@ -273,6 +286,7 @@ class Master:
 
     # ------------------------------------------------------------------
     def prepare(self):
+        part_start = self._ledger.start()
         if self.autoscaler is None and self.pod_manager is not None:
             # EDL_AUTOSCALE gate: None on static fleets or when the pod
             # manager can't scale (maybe_create checks both)
@@ -300,6 +314,9 @@ class Master:
         trace.configure("master")
         events.configure("master")
         events.emit("role_start", port=self._port)
+        # the port is listening: what a worker's launch waits for
+        self._ledger.end_record("serve", part_start)
+        self._ledger.end_startup()
         # continuous profiler (ISSUE 14): always-on when EDL_PROF_HZ is
         # set, served as /profilez on the observability port below
         profiler.maybe_start("master")
@@ -400,6 +417,9 @@ class Master:
             time.sleep(0.1)
 
     def stop(self):
+        ledger = self._ledger
+        ledger.begin_teardown("master_teardown")
+        part_start = ledger.start()
         self._serving = False
         if self.observability is not None:
             self.observability.stop()
@@ -407,6 +427,8 @@ class Master:
         events.emit("role_stop")
         events.flush()
         trace.flush()
+        ledger.end_record("stop_observability", part_start)
+        part_start = ledger.start()
         if self.stream_feeder is not None:
             self.stream_feeder.stop()
         self.task_monitor.stop()
@@ -416,7 +438,10 @@ class Master:
             self.tensorboard_service.stop()
         if self.pod_manager is not None:
             self.pod_manager.stop()
+        ledger.end_record("stop_services", part_start)
+        part_start = ledger.start()
         if self._server is not None:
             self._server.stop(grace=1.0)
         if self.state_journal is not None:
             self.state_journal.close()
+        ledger.end_record("stop_server", part_start)

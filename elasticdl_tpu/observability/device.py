@@ -41,6 +41,16 @@ runtime through three instruments:
    it holds, by name (``pallas_kernels``), in the same two places: a
    step whose flash backward fell back from ``flash_bwd`` to
    ``flash_dq`` + ``flash_dkv`` says so there.
+4. **The compile split** (ISSUE 33) -- jax times its own tracing,
+   lowering and backend compile, and says whether the persistent
+   compilation cache was asked, hit or missed (``jax.monitoring``).
+   ``install_listeners`` registers for those once a process; they
+   are called only when jax traces, lowers, compiles or reads its
+   cache, never by a call that hits the jit cache. A wrapped
+   function's compile carries its ``stages`` (``_call_stages``), the
+   process keeps totals over ALL programs, the eager ones included
+   (``compile_totals``), and a program the cache did not hold is one
+   ``xla_cache_miss`` journal event.
 
 Disabled path (``EDL_DEVICE_OBS=0``): ``instrumented_jit`` returns the
 **raw ``jax.jit`` product, unchanged** — no wrapper frame, no per-call
@@ -101,6 +111,48 @@ _totals = {
 }
 _hbm_peak = 0  # host-side watermark across memory_snapshot() polls
 
+# jax's own account of a compile (jax.monitoring, jax 0.9.0): each of
+# the three is reported as a scalar when it starts (its epoch start
+# time) and as a duration and a time span, epoch start and end, when
+# it ends
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+# inside the backend stage, when the program has a cache key: asked,
+# then on a hit the seconds of the read and what the compile had cost
+_CACHE_ANSWER = {
+    # asked: a miss until the cache says otherwise
+    "/jax/compilation_cache/compile_requests_use_cache": "miss",
+    "/jax/compilation_cache/cache_hits": "hit",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved",
+}
+# what ``_call_stages`` looks back over: one wrapped call leaves three
+# outermost spans, an eager op between two calls three more
+_SPANS_KEPT = 64
+
+# process totals over ALL programs, wrapped or not. ``requests`` are
+# programs handed to the backend, ``hits`` / ``misses`` those of them
+# with a cache key (the rest had none: no cache directory, or a
+# program jax does not cache); seconds count outermost stages only (a
+# jit traced inside a trace is its parent's time); ``listener_calls``
+# is what this instrument itself was called
+_stage_totals = {
+    "requests": 0, "hits": 0, "misses": 0,
+    "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+    "retrieval_s": 0.0, "listener_calls": 0,
+}
+_listeners_installed = False
+# open stages, the cache's answer and the outermost spans, by thread:
+# jax calls a listener on the thread that compiles
+_stage_tls = threading.local()
+# the start-up record's open phase, while there is one (timing_utils)
+_phase_source = None
+
 # instruments hoisted to module scope (obs-hot-path discipline): the
 # registry returns NOOPs when metrics collection is off. LAZY: the
 # trainers import this module before a role's main() publishes
@@ -116,10 +168,14 @@ _m_recompiles = obs_metrics.lazy_counter(
     "XLA compiles beyond each wrapped step fn's first",
     ("fn",),
 )
-_m_cache_hits = obs_metrics.lazy_counter(
-    "edl_xla_cache_hits_total",
-    "Calls served by the jit executable cache per wrapped step fn",
-    ("fn",),
+_m_persistent_hits = obs_metrics.lazy_counter(
+    "edl_xla_persistent_cache_hits_total",
+    "Programs loaded from the persistent compilation cache",
+)
+_m_persistent_misses = obs_metrics.lazy_counter(
+    "edl_xla_persistent_cache_misses_total",
+    "Programs the persistent compilation cache was asked for and did "
+    "not hold (compiled)",
 )
 _m_compile_secs = obs_metrics.lazy_histogram(
     "edl_xla_compile_seconds",
@@ -255,6 +311,187 @@ def pallas_kernels(hlo_text):
     return dict(collections.Counter(_PALLAS_KERNEL_RE.findall(hlo_text)))
 
 
+# ---------------------------------------------------------------------------
+# the compile split: what jax says of its own stages and of its cache
+
+def install_listeners():
+    """Registers the ``jax.monitoring`` listeners, once a process and
+    only where device obs is on. ``instrumented_jit`` calls it, and
+    the start-up record does before the backend starts, so that the
+    totals hold the programs of start-up too."""
+    global _listeners_installed
+    if _listeners_installed or not device_obs_enabled():
+        return
+    with _lock:
+        if _listeners_installed:
+            return
+        _listeners_installed = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_stage_start)
+    monitoring.register_event_time_span_listener(_on_stage_span)
+    monitoring.register_event_listener(_on_cache_event)
+    monitoring.register_event_duration_secs_listener(_on_cache_seconds)
+
+
+def set_phase_source(source):
+    """``source()`` names the start-up phase open on the calling
+    thread; an ``xla_cache_miss`` carries it. None once start-up is
+    over."""
+    global _phase_source
+    _phase_source = source
+
+
+def _count_call():
+    with _lock:
+        _stage_totals["listener_calls"] += 1
+
+
+def _on_stage_start(event, value, **kwargs):
+    stage = _STAGE_OF.get(event)
+    if stage is None:
+        return
+    _count_call()
+    tls = _stage_tls
+    tls.depth = getattr(tls, "depth", 0) + 1
+    if stage == "backend":
+        # the cache's answer belongs to this program alone (a compile
+        # that raised never reported its span)
+        for fact in ("cache", "retrieval", "saved"):
+            tls.__dict__.pop(fact, None)
+
+
+def _on_cache_event(event, **kwargs):
+    answer = _CACHE_ANSWER.get(event)
+    if answer is not None:
+        _count_call()
+        _stage_tls.cache = answer
+
+
+def _on_cache_seconds(event, duration, **kwargs):
+    fact = _CACHE_SECONDS.get(event)
+    if fact is not None:
+        _count_call()
+        setattr(_stage_tls, fact, duration)
+
+
+def _on_stage_span(event, start, end, **kwargs):
+    """One stage ended on this thread. Only an outermost stage's
+    seconds count (jax traces a nested ``jit`` inside its caller's
+    trace, and an eager op run while tracing compiles inside it); a
+    backend stage is one program whatever encloses it."""
+    stage = _STAGE_OF.get(event)
+    if stage is None:
+        return
+    tls = _stage_tls
+    depth = tls.depth = max(0, getattr(tls, "depth", 1) - 1)
+    seconds = end - start
+    span = {"stage": stage, "start": start, "end": end}
+    if stage == "backend":
+        facts = tls.__dict__
+        span["cache"] = facts.pop("cache", "off")
+        retrieval = facts.pop("retrieval", 0.0)
+        saved = facts.pop("saved", 0.0)
+        if span["cache"] == "hit":
+            span["retrieval_s"] = retrieval
+            span["saved_s"] = saved
+    with _lock:
+        totals = _stage_totals
+        totals["listener_calls"] += 1
+        if depth == 0:
+            totals[stage + "_s"] += seconds
+        if stage == "backend":
+            totals["requests"] += 1
+            if span["cache"] == "hit":
+                totals["hits"] += 1
+                totals["retrieval_s"] += span["retrieval_s"]
+            elif span["cache"] == "miss":
+                totals["misses"] += 1
+    if depth == 0:
+        spans = getattr(tls, "spans", None)
+        if spans is None:
+            spans = tls.spans = collections.deque(maxlen=_SPANS_KEPT)
+        spans.append(span)
+    if stage != "backend":
+        return
+    if span["cache"] == "hit":
+        _m_persistent_hits.inc()
+    elif span["cache"] == "miss":
+        # a warm start that compiles is the finding, and the
+        # program's name is the lead
+        _m_persistent_misses.inc()
+        source = _phase_source
+        try:
+            events.emit(
+                "xla_cache_miss", module=kwargs.get("fun_name"),
+                backend_s=round(seconds, 4),
+                phase=source() if source is not None else None,
+            )
+        except Exception as e:
+            # jax calls this inside its compile: the journal's trouble
+            # must not become the program's
+            logger.debug("xla_cache_miss not journaled: %s", e)
+
+
+def _call_stages(t0, elapsed):
+    """The ``stages`` of a wrapped call that compiled: the outermost
+    spans that STARTED on the calling thread inside the call,
+    ``[t0, t0 + elapsed]`` on the epoch clock. What compiled before
+    the call (an eager op) or after it (the cost fetch's relower) is
+    not its, and another thread's compile is in another thread's
+    list. ``first_run_s`` is the rest of the call: dispatch and what
+    the runtime does before it returns. The result is not awaited, so
+    it is not the first execution."""
+    spans = getattr(_stage_tls, "spans", None) or ()
+    mine = [s for s in spans if t0 <= s["start"] <= t0 + elapsed]
+    if spans:
+        spans.clear()
+    stages = {"trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0}
+    caches = set()
+    for span in mine:
+        stages[span["stage"] + "_s"] += span["end"] - span["start"]
+        if span["stage"] == "backend":
+            caches.add(span["cache"])
+            for key in ("retrieval_s", "saved_s"):
+                if key in span:
+                    stages[key] = stages.get(key, 0.0) + span[key]
+    stages["first_run_s"] = elapsed - sum(
+        stages[stage + "_s"] for stage in ("trace", "lower", "backend"))
+    stages = {key: round(value, 4) for key, value in stages.items()}
+    # several programs in one call: a miss among them is the call's
+    stages["cache"] = next(
+        (c for c in ("miss", "hit") if c in caches), "off")
+    stages["spans"] = [
+        {"stage": s["stage"], "start": round(s["start"], 6),
+         "end": round(s["end"], 6)}
+        for s in mine
+    ]
+    return stages
+
+
+def stages_text(stages):
+    """``stages`` on the compile log line: ``trace 1.20s lower 0.81s
+    backend 2.10s (cache hit, retrieval 0.31s, saved 41.20s) first run
+    0.42s``."""
+    cache = "cache " + stages["cache"]
+    if stages["cache"] == "hit":
+        cache += ", retrieval %.2fs, saved %.2fs" % (
+            stages.get("retrieval_s", 0.0), stages.get("saved_s", 0.0))
+    return "trace %.2fs lower %.2fs backend %.2fs (%s) first run %.2fs" % (
+        stages["trace_s"], stages["lower_s"], stages["backend_s"], cache,
+        stages["first_run_s"])
+
+
+def compile_totals():
+    """The process totals of the compile split (a copy); None where
+    the listeners are not installed: nothing was observed, which is
+    not the same as nothing compiled."""
+    if not _listeners_installed:
+        return None
+    with _lock:
+        return dict(_stage_totals)
+
+
 def _leaf_spec(leaf):
     """``f32[32,10]``-style spec for one argument leaf; scalars and
     static oddities render as their type name (they still churn the
@@ -309,10 +546,10 @@ class _InstrumentedJit:
     """One ``jax.jit`` product plus its sentinel books.
 
     Per call the steady-state cost is one clock read, the jit call
-    itself, one C++ ``_cache_size()`` probe, a counter inc, and two
-    integer adds — the 2 % overhead contract in
-    scripts/bench_device_obs_overhead.py rides on that list staying
-    exactly this short. Signature flattening, provenance diffs, trace
+    itself, one C++ ``_cache_size()`` probe, and two integer adds —
+    the 2 % overhead contract in scripts/bench_device_obs_overhead.py
+    rides on that list staying exactly this short. Signature
+    flattening, provenance diffs, the stages jax reported, trace
     emission, and the AOT cost fetch all happen only on calls that
     compiled.
     """
@@ -328,6 +565,8 @@ class _InstrumentedJit:
         self.last_compile_secs = 0.0
         self.cost_flops = 0.0
         self.cost_bytes = 0.0
+        # _call_stages() of the last compile
+        self.stages = None
         # collective_stats() of the last-compiled signature; None until
         # a cost fetch has read a program
         self.collectives = None
@@ -341,7 +580,6 @@ class _InstrumentedJit:
         self.last_changed = []
         self._m_compiles = _m_compiles.labels(fn=name)
         self._m_recompiles = _m_recompiles.labels(fn=name)
-        self._m_hits = _m_cache_hits.labels(fn=name)
         with _lock:
             _wrappers.append(weakref.ref(self))
 
@@ -357,7 +595,6 @@ class _InstrumentedJit:
         size = self._jitted._cache_size()
         if size == self._cache_size:
             self.cache_hits += 1
-            self._m_hits.inc()
             if self._sig_host_bytes:
                 with _lock:
                     _totals["h2d_bytes"] += self._sig_host_bytes
@@ -387,6 +624,7 @@ class _InstrumentedJit:
         )
         self._last_sig = sig
         self.last_changed = changed
+        stages = self.stages = _call_stages(t0, elapsed)
         self._m_compiles.inc()
         _m_compile_secs.observe(elapsed)
         with _lock:
@@ -426,8 +664,10 @@ class _InstrumentedJit:
         # both figures on one line: whether the cost fetch's relower is
         # a compile-cache hit or a second cold compile reads off it
         logger.info(
-            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs%s%s",
+            "xla compile #%d of %s: call %.2fs, cost fetch %.2fs; "
+            "stages %s%s%s",
             self.compiles, self.name, elapsed, fetch_secs,
+            stages_text(stages),
             "" if self.collectives is None
             else "; collectives " + collectives_text(self.collectives),
             "" if not self.kernels
@@ -440,6 +680,7 @@ class _InstrumentedJit:
             compiles=self.compiles,
             seconds=round(elapsed, 4),
             cost_fetch_seconds=round(fetch_secs, 4),
+            stages=stages,
             collectives=self.collectives,
             kernels=self.kernels,
         )
@@ -495,6 +736,7 @@ def instrumented_jit(fn, name=None, **jit_kwargs):
         import jax
 
         return jax.jit(fn, **jit_kwargs)
+    install_listeners()
     return _InstrumentedJit(
         fn, name or getattr(fn, "__name__", "step_fn"), jit_kwargs
     )
@@ -614,13 +856,16 @@ def _live_wrappers():
 
 def compile_stats():
     """Per-wrapper sentinel books: {name: {...}} for live wrappers.
-    Same-named wrappers (the SPMD per-structure jit caches) fold."""
+    Same-named wrappers (the SPMD per-structure jit caches) fold;
+    ``stages`` is the split of a name's last compile (the process
+    totals over all programs are ``compile_totals``)."""
     stats = {}
     for wrapper in _live_wrappers():
         entry = stats.setdefault(wrapper.name, {
             "compiles": 0, "recompiles": 0, "cache_hits": 0,
             "compile_secs": 0.0, "last_compile_secs": 0.0,
             "cost_flops": 0.0, "cost_bytes": 0.0, "last_changed": [],
+            "stages": None,
         })
         entry["compiles"] += wrapper.compiles
         entry["recompiles"] += wrapper.recompiles
@@ -638,6 +883,8 @@ def compile_stats():
             entry["last_changed"] = wrapper.last_changed[
                 :_PROVENANCE_CHANGED_MAX
             ]
+        if wrapper.stages is not None:
+            entry["stages"] = wrapper.stages
     return stats
 
 
@@ -678,4 +925,7 @@ def reset_for_tests():
         _wrappers[:] = []
         for key in _totals:
             _totals[key] = 0.0 if key == "compile_secs" else 0
+        for key, value in _stage_totals.items():
+            _stage_totals[key] = type(value)()
         _hbm_peak = 0
+    _stage_tls.__dict__.clear()

@@ -80,6 +80,12 @@ EVENT_TYPES = frozenset({
                              #   worker-side record of the drain
     "drain_expired",         # drain deadline passed; requeue-on-death
                              #   fallback fired (+worker)
+    "drain_requested",       # journaled by the worker once the task it
+                             #   was in is finished, not by the signal
+                             #   handler (+ worker, reason, signal_ts:
+                             #   epoch seconds the request arrived,
+                             #   step the loop was in then,
+                             #   finished_step)
     # task lifecycle (+ task, worker)
     "task_dispatch",
     "task_report",           # + ok, err
@@ -197,7 +203,19 @@ EVENT_TYPES = frozenset({
                              #   bytes}, bytes, largest}: the compiled
                              #   program's collective instructions,
                              #   result bytes on one device; null when
-                             #   the program was not read)
+                             #   the program was not read; stages
+                             #   {trace_s, lower_s, backend_s,
+                             #   first_run_s, cache hit|miss|off,
+                             #   retrieval_s and saved_s on a hit,
+                             #   spans [{stage, start, end}] on the
+                             #   epoch clock}: the call as jax split
+                             #   it)
+    "xla_cache_miss",        # a program, wrapped or eager, that the
+                             #   persistent compilation cache was asked
+                             #   for and did not hold (+ module,
+                             #   backend_s: its compile, phase: the
+                             #   start-up phase open at the time, null
+                             #   after start-up)
     # the worker's phase ledger (ISSUE 23); durations in nanoseconds
     # on perf_counter_ns, ``ts`` places the event in wall time
     "loop_phases",           # every --log_loss_steps steps: the loop
@@ -213,12 +231,31 @@ EVENT_TYPES = frozenset({
                              #   invol_ctx_switches, major_faults
                              #   since the last loop_phases)
     "worker_startup",        # after the first step returned: process
-                             #   start to there by phase (+ wall_ns,
-                             #   phases{imports, backend_init,
-                             #   master_connect, first_task,
-                             #   state_init, restore, first_step, ...})
-    "worker_teardown",       # from the last exit hook (+ wall_ns,
-                             #   phases{drain, teardown, exit, other})
+                             #   start to there by phase (+ start_ts:
+                             #   epoch seconds of the record's start,
+                             #   wall_ns, phases{imports, configure,
+                             #   master_connect, backend_init,
+                             #   worker_init, first_task, state_init,
+                             #   restore, first_step, ...},
+                             #   compiles{phase: {requests, hits,
+                             #   misses, trace_s, lower_s,
+                             #   backend_s}}: what jax compiled or
+                             #   loaded from its cache in each,
+                             #   listener_calls: what the compile
+                             #   split's listeners were called so
+                             #   far; both only where the listeners
+                             #   are installed)
+    "worker_teardown",       # from the last exit hook (+ start_ts,
+                             #   wall_ns, phases{drain, teardown, exit,
+                             #   other})
+    "master_startup",        # once role_start is journaled: process
+                             #   start to the port listening (+
+                             #   start_ts, wall_ns, phases{imports,
+                             #   configure, zoo, tasks, serve, other})
+    "master_teardown",       # from the last exit hook (+ start_ts,
+                             #   wall_ns, phases{stop_observability,
+                             #   stop_services, stop_server, exit,
+                             #   other})
     "moe_routing",           # every --log_loss_steps steps of a model
                              #   whose step returns routing counters
                              #   (the sorted MoE dispatch), read with

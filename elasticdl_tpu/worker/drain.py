@@ -22,6 +22,7 @@ hook composes with it instead of replacing it:
 
 import signal
 import sys
+import time
 
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 
@@ -55,8 +56,10 @@ class SigtermDrain:
         worker = self._worker
         if worker is not None:
             # flags only — safe at any interrupt point; the run loop
-            # does the flushing, the watchdog bounds it
-            worker.begin_drain("sigterm")
+            # does the flushing, the watchdog bounds it. When the
+            # signal arrived is only noted here: the loop journals it
+            # (``drain_requested``) once the task is finished
+            worker.begin_drain("sigterm", signal_ts=time.time())
             return
         if callable(self._previous):
             self._previous(signum, frame)

@@ -46,21 +46,6 @@ from elasticdl_tpu.worker.master_client import MasterClient  # noqa: E402
 from elasticdl_tpu.worker.worker import Worker  # noqa: E402
 
 
-def _start_ledger(main_start_ns, interval):
-    """The loop thread's ledger, its start-up record opened at the
-    process's start and holding ``imports``: from there to the first
-    statement of ``main``."""
-    ledger = timing_utils.Timing(interval=interval)
-    age_ns = timing_utils.process_age_ns()
-    now_ns = time.perf_counter_ns()
-    start_ns = _MODULE_START_NS if age_ns is None else now_ns - age_ns
-    # the operating system counts in 10 ms ticks: never after main began
-    start_ns = min(start_ns, main_start_ns)
-    ledger.begin_startup(start_ns)
-    ledger.end_record("imports", start_ns, end=main_start_ns)
-    return ledger
-
-
 def main(argv=None):
     main_start_ns = time.perf_counter_ns()
     if env_str("EDL_FAULTHANDLER", ""):
@@ -94,8 +79,16 @@ def main(argv=None):
         os.environ[http_server.PORT_ENV] = str(args.metrics_port)
     trace.configure("worker-%d" % args.worker_id)
     events.configure("worker-%d" % args.worker_id)
+    # before the ledger takes its first reading of them: jax's own
+    # account of every program this process traces, compiles or loads
+    # from the compile cache, the eager ones of start-up included
+    from elasticdl_tpu.observability import device as device_obs
+
+    device_obs.install_listeners()
     # after the metrics knob and the journal: the ledger asks both
-    ledger = _start_ledger(main_start_ns, args.log_loss_steps)
+    ledger = timing_utils.start_ledger(
+        _MODULE_START_NS, main_start_ns, args.log_loss_steps
+    )
     # continuous profiler (ISSUE 14): always-on when EDL_PROF_HZ is
     # set, served as /profilez on the observability port below
     profiler.maybe_start("worker-%d" % args.worker_id)
@@ -115,6 +108,9 @@ def main(argv=None):
 
     drain_hook = install_sigterm_drain()
     events.install_crash_hooks()
+    # the compile cache's place, arguments, logging, the journal, the
+    # hooks: main's first statement to here
+    ledger.end_record("configure", main_start_ns)
     with ledger.phase("master_connect"):
         master_client = MasterClient(
             args.master_addr,
@@ -155,79 +151,81 @@ def main(argv=None):
         # multihost runtime, which must initialize before the backend
         # does. Asking for the devices is what starts the backend
         logger.info("devices: %s", platform.describe_devices())
-    # an elastic restart must resume from the freshest state: default
-    # the init dir to the worker's own checkpoint dir, so the relaunch
-    # (same command line) picks up everything checkpointed so far
-    checkpoint_dir_for_init = args.checkpoint_dir_for_init or (
-        args.checkpoint_dir if args.multihost else ""
-    )
-    if args.multihost and not checkpoint_dir_for_init:
-        import warnings
-
-        warnings.warn(
-            "--multihost without --checkpoint_dir: a mesh-epoch restart "
-            "will lose all training progress",
-            stacklevel=1,
+    # the reader, the model zoo and the worker around them
+    with ledger.phase("worker_init"):
+        # an elastic restart must resume from the freshest state: default
+        # the init dir to the worker's own checkpoint dir, so the relaunch
+        # (same command line) picks up everything checkpointed so far
+        checkpoint_dir_for_init = args.checkpoint_dir_for_init or (
+            args.checkpoint_dir if args.multihost else ""
         )
-    reader_params = parse_params_string(args.data_reader_params)
-    data_origin = (
-        args.training_data or args.validation_data or args.prediction_data
-    )
-    reader = create_data_reader(data_origin, **reader_params)
-    # More than one local device: run the SPMD trainer over the chip mesh
-    # (gradients ride ICI inside the compiled step). A jax.distributed
-    # world of >1 processes gets the lockstep multi-host trainer — the
-    # mesh spans the processes and dp psums ride DCN.
-    trainer_factory = None
-    if jax.process_count() > 1:
-        from elasticdl_tpu.parallel.multihost_trainer import (
-            MultiHostSpmdTrainer,
+        if args.multihost and not checkpoint_dir_for_init:
+            import warnings
+
+            warnings.warn(
+                "--multihost without --checkpoint_dir: a mesh-epoch restart "
+                "will lose all training progress",
+                stacklevel=1,
+            )
+        reader_params = parse_params_string(args.data_reader_params)
+        data_origin = (
+            args.training_data or args.validation_data or args.prediction_data
         )
+        reader = create_data_reader(data_origin, **reader_params)
+        # More than one local device: run the SPMD trainer over the chip mesh
+        # (gradients ride ICI inside the compiled step). A jax.distributed
+        # world of >1 processes gets the lockstep multi-host trainer — the
+        # mesh spans the processes and dp psums ride DCN.
+        trainer_factory = None
+        if jax.process_count() > 1:
+            from elasticdl_tpu.parallel.multihost_trainer import (
+                MultiHostSpmdTrainer,
+            )
 
-        trainer_factory = MultiHostSpmdTrainer
-    elif jax.device_count() > 1:
-        from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
+            trainer_factory = MultiHostSpmdTrainer
+        elif jax.device_count() > 1:
+            from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
 
-        trainer_factory = SpmdTrainer
-    # --mesh "fsdp=4" etc: explicit axis sizes; dp=-1 absorbs whatever
-    # devices remain, so the same flag survives elastic world-size
-    # changes (a relaunch at a smaller world just gets a smaller dp).
-    mesh_config = None
-    if args.mesh:
-        from elasticdl_tpu.parallel.mesh import parse_mesh_spec
+            trainer_factory = SpmdTrainer
+        # --mesh "fsdp=4" etc: explicit axis sizes; dp=-1 absorbs whatever
+        # devices remain, so the same flag survives elastic world-size
+        # changes (a relaunch at a smaller world just gets a smaller dp).
+        mesh_config = None
+        if args.mesh:
+            from elasticdl_tpu.parallel.mesh import parse_mesh_spec
 
-        mesh_config = parse_mesh_spec(args.mesh)
-    worker = Worker(
-        master_client,
-        args.model_zoo,
-        reader,
-        mesh_config=mesh_config,
-        grad_accum_steps=args.grad_accum_steps,
-        minibatch_size=args.minibatch_size,
-        mode=args.mode,
-        compute_dtype=args.compute_dtype or None,
-        report_version_steps=args.report_version_steps,
-        trainer_factory=trainer_factory,
-        ps_addrs=args.ps_addrs or None,
-        checkpoint_dir=args.checkpoint_dir,
-        checkpoint_steps=args.checkpoint_steps,
-        async_checkpoint=bool(args.async_checkpoint),
-        keep_checkpoint_max=args.keep_checkpoint_max,
-        checkpoint_dir_for_init=checkpoint_dir_for_init,
-        multihost_runtime=multihost_runtime,
-        sparse_pipeline=bool(args.sparse_pipeline),
-        sparse_cache_staleness=args.sparse_cache_staleness,
-        sparse_push_interval=args.sparse_push_interval,
-        model_def=args.model_def,
-        model_params=args.model_params,
-        symbol_overrides=symbol_overrides_from_args(args),
-        log_loss_steps=args.log_loss_steps,
-        consensus_interval=args.consensus_interval,
-        # the elastic fallback dir is empty on first launch; only an
-        # explicit operator resume request is strict
-        resume_optional=not args.checkpoint_dir_for_init,
-        ledger=ledger,
-    )
+            mesh_config = parse_mesh_spec(args.mesh)
+        worker = Worker(
+            master_client,
+            args.model_zoo,
+            reader,
+            mesh_config=mesh_config,
+            grad_accum_steps=args.grad_accum_steps,
+            minibatch_size=args.minibatch_size,
+            mode=args.mode,
+            compute_dtype=args.compute_dtype or None,
+            report_version_steps=args.report_version_steps,
+            trainer_factory=trainer_factory,
+            ps_addrs=args.ps_addrs or None,
+            checkpoint_dir=args.checkpoint_dir,
+            checkpoint_steps=args.checkpoint_steps,
+            async_checkpoint=bool(args.async_checkpoint),
+            keep_checkpoint_max=args.keep_checkpoint_max,
+            checkpoint_dir_for_init=checkpoint_dir_for_init,
+            multihost_runtime=multihost_runtime,
+            sparse_pipeline=bool(args.sparse_pipeline),
+            sparse_cache_staleness=args.sparse_cache_staleness,
+            sparse_push_interval=args.sparse_push_interval,
+            model_def=args.model_def,
+            model_params=args.model_params,
+            symbol_overrides=symbol_overrides_from_args(args),
+            log_loss_steps=args.log_loss_steps,
+            consensus_interval=args.consensus_interval,
+            # the elastic fallback dir is empty on first launch; only an
+            # explicit operator resume request is strict
+            resume_optional=not args.checkpoint_dir_for_init,
+            ledger=ledger,
+        )
     # SIGTERM now triggers the graceful drain instead of a bare exit
     drain_hook.bind(worker)
     from elasticdl_tpu.train.health import HealthSentinelError

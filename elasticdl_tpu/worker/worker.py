@@ -256,6 +256,9 @@ class Worker:
         self._draining = False
         self._drain_reason = ""
         self._drain_done = False
+        # (epoch seconds the request arrived, the step the loop was
+        # in), noted by begin_drain and journaled by _finish_drain
+        self._drain_requested = None
         self._version = 0
         # Dense full-state checkpoints (params + model_state + optimizer
         # slots + step; the reference drops slot state,
@@ -576,17 +579,22 @@ class Worker:
     # ------------------------------------------------------------------
     # graceful drain (ISSUE 7)
 
-    def begin_drain(self, reason="sigterm"):
+    def begin_drain(self, reason="sigterm", signal_ts=None):
         """Request a graceful drain: finish the current task, then
         flush and deregister instead of fetching more work. Called from
         the SIGTERM hook (worker/drain.py) on the main thread — it only
         flips flags and arms the deadline watchdog, so it is safe at
         any interrupt point; the run loop does the actual flushing at
-        its next task boundary. Idempotent."""
+        its next task boundary. ``signal_ts`` is when the request
+        arrived (epoch seconds; now, if the caller noted nothing).
+        Idempotent."""
         if self._draining:
             return
         self._draining = True
         self._drain_reason = reason
+        self._drain_requested = (
+            time.time() if signal_ts is None else signal_ts, self._version
+        )
         # The sequential/pipelined loops drain via the record stream:
         # tds.draining ends it AFTER the current task's records, so the
         # last task completes (reported done, never requeued). They must
@@ -636,6 +644,16 @@ class Worker:
         individually guarded: a dead PS must not stop the deregister,
         and a dead master must not stop the exit (old masters without
         the RPC just miss the ack; their liveness fallback requeues)."""
+        requested, self._drain_requested = self._drain_requested, None
+        if requested is not None:
+            # here and not in the signal handler, where a journal write
+            # can be lost: the seconds from the request to this line
+            # are the task the loop finished first
+            events.emit(
+                "drain_requested", worker=self._mc.worker_id,
+                reason=self._drain_reason, signal_ts=requested[0],
+                step=requested[1], finished_step=self._version,
+            )
         self._timing.begin_teardown()
         with self._timing.phase("drain"):
             self._drain()
