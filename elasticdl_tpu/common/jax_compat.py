@@ -44,3 +44,13 @@ def manual_over(mesh):
     refused (they are of type Manual)."""
     manual = jax.sharding.get_abstract_mesh().manual_axes
     return set(mesh.axis_names) <= set(manual)
+
+
+def out_struct(shape, dtype, *operands):
+    """The ``out_shape`` of a ``pallas_call`` result that varies over
+    every mesh axis any of ``operands`` varies over: inside a
+    VMA-checked ``shard_map`` (a region manual over the mesh) a
+    ``pallas_call`` must say so itself; anywhere else the set is
+    empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

@@ -22,10 +22,12 @@ the chunk's first token, ``D[i, j] = exp(G_i - G_j)`` for ``i >= j`` and
     V' = U - W S                 O = (Q . exp(G)) S + lower(Q K^T . D) V'
     S <- exp(G_last) S + (K . exp(G_last - G))^T V'
 
-Everything but ``V'`` and ``S`` is independent of the state, so it is
-batched matmuls over all chunks at once; a ``lax.scan`` over the chunks
-carries ``S`` and runs two small matmuls a step; ``O`` is batched again
-from the states the scan hands back. Every decay factor is an ``exp`` of
+Everything but ``V'``, ``O`` and ``S`` is independent of the state, so
+it is batched matmuls over all chunks at once; the last two lines are
+the chunk-to-chunk recurrence, which carries ``S``: a Pallas kernel
+with the state in VMEM, or a ``lax.scan`` that runs two small matmuls a
+step and hands every chunk's ``V'`` and entering state back for two
+batched products of ``O`` (below). Every decay factor is an ``exp`` of
 a difference that is ``<= 0``, so nothing overflows however negative
 ``g`` is. Checked against the per-token loop in float64: equal to 1e-15.
 
@@ -71,11 +73,54 @@ or a mesh of several devices -> the XLA product form below
 (``impl=xla``). A ``pallas_call`` has no GSPMD partitioning rule
 (``ops/attention.py:_shard_over_mesh``), and the rule opens no
 ``shard_map`` of its own yet, so on a mesh it stays what GSPMD can
-partition. Everything else of the rule is XLA: building ``A``,
-applying ``T``, the chunk-to-chunk scan.
+partition.
 
-Memory: autodiff through the scan keeps one state a chunk (in the
-compute dtype, as its matmul operand) and the chunk's ``W``, ``V'``,
+The chunk-to-chunk recurrence is the kernel pair ``gdn_scan_fwd`` /
+``gdn_scan_bwd`` (PR 34). As a ``lax.scan`` it is 128 dependent steps a
+segment of two batched 64 x 128 x 128 matmuls, each step a loop
+iteration of its own with a decay, a cast and two dynamic-update-slices
+around them; the operands are copied chunks-first for it, and it
+writes one state a chunk to HBM for ``Q~ S`` to read back once. The
+forward kernel's grid runs over (blocks of value heads: parallel; the
+segment's chunks, a few a grid step: arbitrary) with one float32
+``(Dk, Dv)`` state a head in a VMEM scratch from the segment's first
+chunk to its last; a grid step reads its chunks' ``U`` (float32), ``W``,
+decayed keys, ``Q~``, ``P`` (compute dtype) and ``exp(G_last)`` from the
+``(B, Hk, R, N, C, .)`` arrays where they lie and writes ``O``: the
+lines above, the same four products at the same precision (``[W; Q~]
+S`` is one product, the two sharing their right operand; ``Q~`` and
+``P`` are rounded to the compute dtype by the fusion that makes them,
+as ``_matmul`` rounded them; ``O`` leaves rounded to the compute dtype,
+the cast the rule ends with). ``_SCAN_HEADS`` heads' chains are
+interleaved in one body as ``_CHAINS`` are for the inverses; on the
+chip the kernels move their bytes at the HBM's rate (0.49 GB a segment
+in 0.82 ms, PERF.md Section 6, PR 34). Called under differentiation
+(the segment's recompute, one forward in three) it also writes ``V'``
+and the float32 state every chunk met. ``gdn_scan_bwd`` reads those and
+runs the reverse recurrence with ``dS`` carried the same way::
+
+    dV' = P^T dO + K~ dS            dS <- exp(G_last) dS + Q~^T dO - W^T dV'
+    dU = dV'     dW = -dV' S^T      dQ~ = dO S^T      dK~ = V' dS^T
+    dP = dO V'^T                    d exp(G_last) = <dS, S>
+
+(``dS`` the leaving state's gradient on the left of the arrow, the
+entering state's on its right). Cotangents are rounded to the compute
+dtype where they are matmul operands, as the TPU's default precision
+rounds them for autodiff of the scan; the kernel sums ``dV'`` and
+``dS`` in float32 and rounds once where autodiff rounds each term: equal
+to the operands' rounding and not bit for bit. ``scan_impl`` decides
+beside ``inverse_impl``, with no switch either: a TPU, operands
+bfloat16 or float32 with ``v`` in the same dtype, the float32 state and
+decay (``state_dtype`` / ``decay_dtype`` at their defaults), key and
+value widths in whole 128-lane rows, chunk 64 or 128, one device or a
+region already manual over the mesh -> the kernels (``scan=pallas`` on
+the line); anything else -> the ``lax.scan`` (``scan=xla``). Everything
+else of the rule is XLA: building ``A``, applying ``T``, the decays.
+
+Memory: the backward keeps one state a chunk (autodiff through the
+``lax.scan`` one in the compute dtype, as its matmul operand, and a
+float32 one for the decay's gradient; the kernels the float32 one, 256
+MB a segment at the shape below) and the chunk's ``W``, ``V'``,
 decayed keys and C x C matrices (float32 ones, which the TPU pads from
 64 to 128 lanes): about 4 GB a layer at 32,768 tokens, 32 heads of 128 x
 128 and chunk 64, too much beside 10 GB of optimizer state. So a
@@ -122,6 +167,14 @@ _INVERSE_VMEM_LIMIT = 16 * 2**20
 # independent lane rows (pairs of 64 x 64 matrices) a loop iteration
 # interleaves
 _CHAINS = 8
+# the scan's kernels: the value heads whose chains a grid step
+# interleaves, the most chunks it takes of each, the VMEM its blocks may
+# take (every operand and result double-buffered, the carried states
+# beside them) and the limit the pallas_calls state
+_SCAN_HEADS = 8
+_SCAN_CHUNKS = 4
+_SCAN_BLOCK_BYTES = 16 * 2**20
+_SCAN_VMEM_LIMIT = 32 * 2**20
 
 
 def _matmul(a, b, dtype):
@@ -155,13 +208,42 @@ def inverse_impl(dtype, size, mesh=None):
     partitioned automatically, so they run where there is nothing to
     partition: on one device, or inside a region that is already manual
     over the whole mesh (module docstring)."""
-    one_device = (
-        mesh is None or mesh.size == 1 or jax_compat.manual_over(mesh))
     fits = (
-        one_device
-        and jax.default_backend() == "tpu"
+        _kernels_can_run(mesh)
         and dtype == jnp.float32
         and size in _KERNEL_CHUNKS
+    )
+    return "pallas" if fits else "xla"
+
+
+def _kernels_can_run(mesh):
+    """A TPU backend with nothing to partition: one device, or a region
+    already manual over the whole mesh."""
+    one_device = (
+        mesh is None or mesh.size == 1 or jax_compat.manual_over(mesh))
+    return one_device and jax.default_backend() == "tpu"
+
+
+def scan_impl(dtype, chunk, dk, dv, state_dtype=jnp.float32,
+              decay_dtype=jnp.float32, out_dtype=None, mesh=None):
+    """``"pallas"`` or ``"xla"``: what carries the state from chunk to
+    chunk, from what ``inverse_impl`` sees and the rule's own shapes:
+    the ``gdn_scan_*`` kernels take operands of ``dtype`` bfloat16 or
+    float32 with the float32 state and decay, key and value widths in
+    whole 128-lane rows and a chunk of 64 or 128, and write ``o`` in
+    ``dtype`` (``out_dtype``, ``v``'s, has to be it: the rounding is
+    then the cast the rule ends with). Everything else, the tests'
+    ``state_dtype`` / ``decay_dtype`` experiments among it, is the
+    ``lax.scan``."""
+    fits = (
+        _kernels_can_run(mesh)
+        and dtype in (jnp.bfloat16, jnp.float32)
+        and out_dtype in (None, dtype)
+        and state_dtype == jnp.float32
+        and decay_dtype == jnp.float32
+        and chunk in _KERNEL_CHUNKS
+        and dk % _LANES == 0
+        and dv % _LANES == 0
     )
     return "pallas" if fits else "xla"
 
@@ -283,8 +365,8 @@ def _inverse_call(kernel, name, operands, interpret):
         grid=((count + pad) // block,),
         in_specs=[spec] * len(operands),
         out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (count + pad, size, size), jnp.float32),
+        out_shape=jax_compat.out_struct(
+            (count + pad, size, size), jnp.float32, *operands),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_INVERSE_VMEM_LIMIT,
@@ -360,22 +442,320 @@ def _inverse_vjp_bwd(impl, inverse, d_inverse):
 _inverse.defvjp(_inverse_vjp_fwd, _inverse_vjp_bwd)
 
 
+# ------------------------------------------------ the scan's kernels
+# The chunk-to-chunk recurrence with the state in VMEM (PR 34), over the
+# (B, Hk, R, N, C, .) arrays ``_chunk_operands`` builds, read where they
+# lie: a reshape between the fusion that makes an operand and the
+# kernel would keep XLA from fusing the key heads' broadcast into it.
+
+
+def _divisor(n, most):
+    """The largest divisor of ``n`` that is at most ``most``."""
+    return max(d for d in range(1, max(1, min(n, most)) + 1) if n % d == 0)
+
+
+def _tile_bytes(rows, cols, itemsize):
+    """VMEM of a (rows, cols) block: whole tiles of 128 lanes by 8
+    float32 (16 bfloat16) rows."""
+    sublanes = 8 * 4 // itemsize
+    return (rows + -rows % sublanes) * (cols + -cols % _LANES) * itemsize
+
+
+def scan_vmem_bytes(heads, chunks, chunk, dk, dv, itemsize, kind):
+    """VMEM of a grid step of the scan's kernel ``kind`` (``fwd``,
+    ``fwd_residuals`` or ``bwd``) over ``heads`` x ``chunks``: every
+    operand's and result's block double-buffered, the entering and the
+    leaving state's too, and the carried states' scratch."""
+    value = _tile_bytes(chunk, dv, itemsize)
+    key = _tile_bytes(chunk, dk, itemsize)
+    square = _tile_bytes(chunk, chunk, itemsize)
+    decay = _tile_bytes(1, dv, 4)
+    state = _tile_bytes(dk, dv, 4)
+    a_chunk = {
+        # decay, (w, k, q), p, u -> o
+        "fwd": decay + _tile_bytes(chunk, dv, 4) + 3 * key + square + value,
+        # ... -> v', states
+        "fwd_residuals": (
+            decay + _tile_bytes(chunk, dv, 4) + 3 * key + square
+            + 2 * value + state),
+        # decay, (w, k, q), p, states, v', do -> du, (dw, dk, dq), dp,
+        # d decay
+        "bwd": 2 * decay + 6 * key + 2 * square + state + 3 * value,
+    }[kind]
+    return heads * (2 * chunks * a_chunk + 5 * state)
+
+
+def scan_block(heads, chunks, chunk, dk, dv, itemsize, kind, rep=1):
+    """(value heads, chunks) a grid step takes of ``heads`` x
+    ``chunks``: up to ``_SCAN_HEADS`` heads, whose independent chains
+    one body interleaves, and as many chunks of each, up to
+    ``_SCAN_CHUNKS``, as ``_SCAN_BLOCK_BYTES`` holds (fewer heads where
+    that many do not fit with a chunk each). The heads are whole groups
+    of the ``rep`` value heads of a key head, or a part of one group;
+    everything divides what it is taken of."""
+    size = lambda block, step: scan_vmem_bytes(
+        block, step, chunk, dk, dv, itemsize, kind)
+
+    def fewer(most):
+        groups = _divisor(heads // rep, most // rep) * rep
+        return groups if groups <= most else _divisor(rep, most)
+
+    block = fewer(_SCAN_HEADS)
+    while block > 1 and size(block, 1) > _SCAN_BLOCK_BYTES:
+        block = fewer(block - 1)
+    fit = (_SCAN_BLOCK_BYTES - size(block, 0)) // (
+        size(block, 1) - size(block, 0))
+    return block, _divisor(chunks, min(_SCAN_CHUNKS, fit))
+
+
+def _mxu(x, y, contract=((1,), (0,))):
+    """``x @ y`` (or, by ``contract``, a transposed operand's), float32
+    out: the operands arrive in the compute dtype."""
+    return jax.lax.dot_general(
+        x, y, (contract, ((), ())), preferred_element_type=jnp.float32)
+
+
+_TN = ((0,), (0,))  # x^T y
+_NT = ((1,), (1,))  # x y^T
+
+
+def _block_heads(ref):
+    """The index in its block of every head of a (1, key heads, their
+    value heads, ...) block; less its first entry, the head's in the
+    carried states' scratch."""
+    _, keys, reps = ref.shape[:3]
+    return [(0, a, r) for a in range(keys) for r in range(reps)]
+
+
+def _segment_ends():
+    """Whether this grid step is the first, the last of its heads'
+    chunks (the grid's last axis)."""
+    step = pl.program_id(3)
+    return step == 0, step == pl.num_programs(3) - 1
+
+
+def _scan_fwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, u_ref, s0_ref,
+                     o_ref, *rest, residuals):
+    """A block of heads over a block of chunks: V' = U - W S, O = Q~ S +
+    P V', S <- exp(G_last) S + K~^T V'. ``[W; Q~] S`` is one product
+    (the two share their right operand, as the inverse's stacked rows
+    do); the heads' chains are interleaved stage by stage."""
+    if residuals:
+        v_ref, states_ref, s1_ref, s_scr = rest
+    else:
+        s1_ref, s_scr = rest
+    chunks, chunk = u_ref.shape[3:5]
+    dtype = w_ref.dtype
+    heads = _block_heads(u_ref)
+    first, last = _segment_ends()
+
+    @pl.when(first)
+    def _():
+        s_scr[...] = s0_ref[0]
+
+    for c in range(chunks):
+        states = [s_scr[at[1:]] for at in heads]
+        both = [
+            _mxu(jnp.concatenate([w_ref[at + (c,)], q_ref[at + (c,)]],
+                                 axis=0), state.astype(dtype))
+            for at, state in zip(heads, states)]
+        new_v = [(u_ref[at + (c,)] - b[:chunk]).astype(dtype)
+                 for at, b in zip(heads, both)]
+        for at, state, v in zip(heads, states, new_v):
+            s_scr[at[1:]] = e_ref[at + (c,)] * state + _mxu(
+                k_ref[at + (c,)], v, _TN)
+        for at, state, b, v in zip(heads, states, both, new_v):
+            o_ref[at + (c,)] = (
+                b[chunk:] + _mxu(p_ref[at + (c,)], v)).astype(o_ref.dtype)
+            if residuals:
+                v_ref[at + (c,)] = v
+                states_ref[at + (c,)] = state
+
+    @pl.when(last)
+    def _():
+        s1_ref[0] = s_scr[...]
+
+
+def _scan_bwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, s_ref, v_ref,
+                     do_ref, ds1_ref, du_ref, dw_ref, dk_ref, dq_ref,
+                     dp_ref, de_ref, ds0_ref, ds_scr):
+    """The reverse recurrence, the chunks last to first, ``dS`` carried
+    as the forward carries ``S``: dV' = P^T dO + K~ dS; dW = -dV' S^T
+    and dQ~ = dO S^T (one product, ``[dV'; dO] S^T``); dK~ = V' dS^T;
+    dP = dO V'^T; d exp(G_last) = <dS, S> (summed over the rows here,
+    over the lanes by the caller); dS <- exp(G_last) dS + Q~^T dO -
+    W^T dV' (one product, ``[Q~; W]^T [dO; -dV']``). Cotangents are
+    rounded to the compute dtype where they are operands, ``dV'`` once
+    after its float32 sum."""
+    chunks, chunk = do_ref.shape[3:5]
+    dtype = w_ref.dtype
+    heads = _block_heads(do_ref)
+    first, last = _segment_ends()
+
+    @pl.when(first)
+    def _():
+        ds_scr[...] = ds1_ref[0]
+
+    for c in reversed(range(chunks)):
+        grads = [ds_scr[at[1:]] for at in heads]
+        lows = [g.astype(dtype) for g in grads]
+        d_v = [
+            (_mxu(p_ref[at + (c,)], do_ref[at + (c,)], _TN)
+             + _mxu(k_ref[at + (c,)], low)).astype(dtype)
+            for at, low in zip(heads, lows)]
+        for at, grad, d in zip(heads, grads, d_v):
+            ds_scr[at[1:]] = e_ref[at + (c,)] * grad + _mxu(
+                jnp.concatenate([q_ref[at + (c,)], w_ref[at + (c,)]], axis=0),
+                jnp.concatenate([do_ref[at + (c,)], -d], axis=0), _TN)
+        for at, grad, low, d in zip(heads, grads, lows, d_v):
+            at = at + (c,)
+            state = s_ref[at]
+            both = _mxu(jnp.concatenate([d, do_ref[at]], axis=0),
+                        state.astype(dtype), _NT)
+            du_ref[at] = d
+            dw_ref[at] = (-both[:chunk]).astype(dtype)
+            dq_ref[at] = both[chunk:].astype(dtype)
+            dk_ref[at] = _mxu(v_ref[at], low, _NT).astype(dtype)
+            dp_ref[at] = _mxu(do_ref[at], v_ref[at], _NT).astype(dtype)
+            de_ref[at] = jnp.sum(grad * state, axis=0, keepdims=True)
+
+    @pl.when(last)
+    def _():
+        ds0_ref[0] = ds_scr[...]
+
+
+def _scan_call(kernel, name, kind, a_chunk, a_head, out_chunk, reverse,
+               interpret):
+    """``kernel`` over the grid (batch, blocks of key heads, blocks of
+    their value heads: parallel; blocks of chunks: arbitrary, last to
+    first if ``reverse``), every array read and written where it lies.
+    ``a_chunk``: the operands with a block a head and chunk, (B, Hk, R,
+    N, rows, cols), the second of them ``w``; ``a_head``: the
+    state-like one, (B, Hk, R, Dk, Dv), followed in the results by its
+    like; ``out_chunk``: (rows, cols, dtype) of the results a head and
+    chunk."""
+    batch, hk, rep, chunks, chunk, dk = a_chunk[1].shape
+    dv = a_head.shape[-1]
+    block, step = scan_block(
+        hk * rep, chunks, chunk, dk, dv, a_chunk[1].dtype.itemsize, kind,
+        rep)
+    # whole groups of a key head's value heads, or a part of one group
+    keys, reps = max(1, block // rep), min(block, rep)
+    steps = chunks // step
+    at = (lambda n: steps - 1 - n) if reverse else (lambda n: n)
+    chunk_spec = lambda rows, cols: pl.BlockSpec(
+        (1, keys, reps, step, rows, cols),
+        lambda b, a, r, n: (b, a, r, at(n), 0, 0))
+    head_spec = pl.BlockSpec(
+        (1, keys, reps, dk, dv), lambda b, a, r, n: (b, a, r, 0, 0))
+    struct = lambda shape, dtype: jax_compat.out_struct(
+        shape, dtype, *a_chunk, a_head)
+    return pl.pallas_call(
+        kernel,
+        grid=(batch, hk // keys, rep // reps, steps),
+        in_specs=[chunk_spec(*x.shape[4:]) for x in a_chunk] + [head_spec],
+        out_specs=(
+            [chunk_spec(rows, cols) for rows, cols, _ in out_chunk]
+            + [head_spec]),
+        out_shape=[
+            struct((batch, hk, rep, chunks, rows, cols), dtype)
+            for rows, cols, dtype in out_chunk
+        ] + [struct(a_head.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((keys, reps, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3 + ("arbitrary",),
+            vmem_limit_bytes=_SCAN_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name=name,
+    )(*a_chunk, a_head)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("residuals", "interpret"))
+def gdn_scan_fwd(state, decay, w, k, q, p, u, residuals=False,
+                 interpret=False):
+    """The segment's recurrence from ``state`` (B, Hk, R, Dk, Dv)
+    float32: decay (B, Hk, R, N, 1, Dv) float32, exp(G_last) on every
+    lane of its row; w, k, q (B, Hk, R, N, C, Dk) and p (B, Hk, R, N, C,
+    C) in the compute dtype; u (B, Hk, R, N, C, Dv) float32 -> (the
+    leaving state, o (B, Hk, R, N, C, Dv) in the compute dtype) and,
+    with ``residuals`` (the call under differentiation), V' in the
+    compute dtype and the float32 state every chunk met, which
+    ``gdn_scan_bwd`` reads."""
+    chunk, dv = u.shape[4:]
+    dk, dtype = w.shape[5], w.dtype
+    out = [(chunk, dv, dtype)]
+    if residuals:
+        out += [(chunk, dv, dtype), (dk, dv, jnp.float32)]
+    *outs, state = _scan_call(
+        functools.partial(_scan_fwd_kernel, residuals=residuals),
+        "gdn_scan_fwd", "fwd_residuals" if residuals else "fwd",
+        [decay, w, k, q, p, u], state, out, False, interpret)
+    return (state, *outs)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("interpret",))
+def gdn_scan_bwd(decay, w, k, q, p, states, new_v, d_o, d_state,
+                 interpret=False):
+    """The VJP of ``gdn_scan_fwd`` from its residuals, ``d_o`` in the
+    compute dtype and the leaving state's float32 ``d_state``: ->
+    (the entering state's gradient, d decay, dw, dk, dq, dp, du), ``du``
+    in the compute dtype (V' was rounded to it), d decay float32, the
+    others in their operands'."""
+    chunk, dv = d_o.shape[4:]
+    dk, dtype = w.shape[5], w.dtype
+    du, dw, d_k, dq, dp, de, d_state = _scan_call(
+        _scan_bwd_kernel, "gdn_scan_bwd", "bwd",
+        [decay, w, k, q, p, states, new_v, d_o], d_state,
+        [(chunk, dv, dtype)] + [(chunk, dk, dtype)] * 3
+        + [(chunk, chunk, dtype), (1, dv, jnp.float32)], True, interpret)
+    return d_state, de, dw, d_k, dq, dp, du
+
+
+@jax.custom_vjp
+def _scan(state, decay, w, k, q, p, u):
+    """The chunk-to-chunk recurrence by the kernels, shapes as
+    ``gdn_scan_fwd``: -> (the leaving state, o)."""
+    return gdn_scan_fwd(state, decay, w, k, q, p, u)
+
+
+def _scan_vjp_fwd(state, decay, w, k, q, p, u):
+    leaving, o, new_v, states = gdn_scan_fwd(
+        state, decay, w, k, q, p, u, residuals=True)
+    # u is no residual: the backward reads V' in its place
+    return (leaving, o), (decay, w, k, q, p, states, new_v)
+
+
+def _scan_vjp_bwd(residuals, cotangents):
+    d_state, d_o = cotangents
+    *grads, du = gdn_scan_bwd(*residuals, d_o, d_state)
+    return (*grads, du.astype(jnp.float32))
+
+
+_scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
+
+
 @functools.lru_cache(maxsize=None)
-def _log_once(hk, hv, dk, chunk, impl, tokens):
+def _log_once(hk, hv, dk, chunk, impl, scan, tokens):
     """One line per distinct call of the rule (this runs at trace time),
     beside the attention line of ``ops/attention.py``, from where the
-    path is chosen. ``impl``: what runs the chunks' inverses, ``pallas``
-    (the ``gdn_inverse_*`` kernels) or ``xla``."""
+    paths are chosen. ``impl``: what runs the chunks' inverses,
+    ``pallas`` (the ``gdn_inverse_*`` kernels) or ``xla``; ``scan``:
+    what carries the state from chunk to chunk, ``pallas`` (the
+    ``gdn_scan_*`` kernels) or ``xla`` (a ``lax.scan``)."""
     logger.info(
         "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
-        "(tokens=%d)", hk, hv, dk, chunk, impl, tokens)
+        "scan=%s (tokens=%d)", hk, hv, dk, chunk, impl, scan, tokens)
 
 
-def _chunks(state, q, k, v, g, beta, state_dtype, decay_dtype, impl):
-    """The rule over whole chunks from the state ``state``: q, k (B, Hk,
-    1, N, C, Dk), v (B, Hk, R, N, C, Dv), g, beta (B, Hk, R, N, C)
-    float32 -> (the state after them, o (B, Hk, R, N, C, Dv) float32).
-    ``impl``: what runs the inverses."""
+def _chunk_operands(q, k, v, g, beta, decay_dtype, impl):
+    """Everything of the rule that does not meet the state, batched
+    over all chunks: q, k (B, Hk, 1, N, C, Dk), v (B, Hk, R, N, C, Dv),
+    g, beta (B, Hk, R, N, C) float32 -> (G_last (B, Hk, R, N, 1), W, the
+    decayed keys, Q~, P, U), float32 but ``W`` and the keys, which are
+    matmul operands only. ``impl``: what runs the inverses."""
     dtype, chunk = q.dtype, q.shape[-2]
     # G, from the chunk's first token
     cum = jnp.cumsum(g.astype(decay_dtype), axis=-1).astype(g.dtype)
@@ -389,8 +769,8 @@ def _chunks(state, q, k, v, g, beta, state_dtype, decay_dtype, impl):
     last = cum[..., -1:]
     onto = jnp.exp(last - cum)  # what is left of token i at the end
 
-    swap = lambda x: jnp.swapaxes(x, -1, -2)
-    kk = _matmul(k, swap(k), dtype)  # (B, Hk, 1, N, C, C)
+    kt = jnp.swapaxes(k, -1, -2)
+    kk = _matmul(k, kt, dtype)  # (B, Hk, 1, N, C, C)
     a = jnp.where(row > col, kk * beta[..., :, None] * decay, 0.0)
     t = _inverse(a, impl)
     u = _matmul(t, beta[..., None] * v, dtype)
@@ -398,7 +778,17 @@ def _chunks(state, q, k, v, g, beta, state_dtype, decay_dtype, impl):
     w = _matmul(t, (beta * into)[..., None] * k, dtype).astype(dtype)
     k_onto = (onto[..., None] * k).astype(dtype)  # (B, Hk, R, N, C, Dk)
     q_into = into[..., None] * q
-    attn = jnp.where(row >= col, _matmul(q, swap(k), dtype) * decay, 0.0)
+    attn = jnp.where(row >= col, _matmul(q, kt, dtype) * decay, 0.0)
+    return last, w, k_onto, q_into, attn, u
+
+
+def _scan_xla(state, last, w, k_onto, q_into, attn, u, dtype):
+    """The chunk-to-chunk recurrence as a ``lax.scan`` over the chunks,
+    which hands back every chunk's V' and entering state for the two
+    batched products of ``O``: operands as ``_chunk_operands`` gives
+    them, ``state`` (B, Hk, R, Dk, Dv) in the dtype it is carried in ->
+    (the leaving state, o float32)."""
+    swap = lambda x: jnp.swapaxes(x, -1, -2)
 
     def step(state, xs):
         u_n, w_n, k_n, end = xs
@@ -407,7 +797,7 @@ def _chunks(state, q, k, v, g, beta, state_dtype, decay_dtype, impl):
         state = (
             jnp.exp(end)[..., None] * state
             + _matmul(swap(k_n), new_v, dtype)
-        ).astype(state_dtype)
+        ).astype(state.dtype)
         return state, (new_v.astype(dtype), held.astype(dtype))
 
     chunks_first = lambda x: jnp.moveaxis(x, 3, 0)
@@ -417,6 +807,30 @@ def _chunks(state, q, k, v, g, beta, state_dtype, decay_dtype, impl):
     states = jnp.moveaxis(states, 0, 3)  # (B, Hk, R, N, Dk, Dv)
     return state, (
         _matmul(q_into, states, dtype) + _matmul(attn, new_v, dtype))
+
+
+def _scan_pallas(state, last, w, k_onto, q_into, attn, u, dtype):
+    """The same recurrence by the ``gdn_scan_*`` kernels, the operands
+    read where they lie (no chunks-first copy, no stacked states): Q~
+    and P rounded to the compute dtype here as ``_matmul`` would round
+    them, exp(G_last) on the lanes of a row a chunk; o comes back
+    rounded to the compute dtype, as the rule's caller would round it
+    next."""
+    leaves = jnp.broadcast_to(
+        jnp.exp(last)[..., None], last.shape + u.shape[-1:])
+    return _scan(state, leaves, w, k_onto, q_into.astype(dtype),
+                 attn.astype(dtype), u)
+
+
+def _chunks(state, q, k, v, g, beta, decay_dtype, impl, scan):
+    """The rule over whole chunks from the state ``state`` (in the
+    dtype it is carried in): -> (the state after them, o (B, Hk, R, N,
+    C, Dv)). ``impl``: what runs the inverses; ``scan``: what carries
+    the state."""
+    carry = _scan_pallas if scan == "pallas" else _scan_xla
+    return carry(
+        state, *_chunk_operands(q, k, v, g, beta, decay_dtype, impl),
+        q.dtype)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
@@ -451,7 +865,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     decay_dtype = decay_dtype or wide
     # the chunks' matrices are ``wide`` whatever q is
     impl = inverse_impl(wide, chunk, mesh)
-    _log_once(hk, hv, dk, chunk, impl, batch * seq)
+    scan = scan_impl(
+        q.dtype, chunk, dk, dv, state_dtype, decay_dtype, v.dtype, mesh)
+    _log_once(hk, hv, dk, chunk, impl, scan, batch * seq)
     span = chunk if seq <= chunk * segment else chunk * segment
     pad = -seq % span
     if pad:
@@ -471,7 +887,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
         split(g.astype(wide), (hk, rep)), split(beta.astype(wide), (hk, rep)),
     )
     run = lambda state, xs: _chunks(
-        state, *xs, state_dtype, decay_dtype, impl)
+        state, *xs, decay_dtype, impl, scan)
     state0 = jnp.zeros((batch, hk, rep, dk, dv), state_dtype)
     if segments == 1:
         _, o = run(state0, tuple(x[0] for x in xs))

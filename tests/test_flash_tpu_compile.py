@@ -147,10 +147,11 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     value heads of 128, chunk 64, for a described v5e: the segments'
     ``jax.checkpoint`` keeps its temporaries under 2.5 GB (4 GB and a
     refused step without it, PERF.md Section 6). With the backend a TPU
-    (``pallas``: what the chip gets, ISSUE 32) the inverses are the two
-    kernels, every ``tpu_custom_call`` named, no float32 64 x 64 dot is
-    left outside them, and the VMEM they ask for is under the limit
-    they state. The kernels' operands are row-major, 64 lanes padded to
+    (``pallas``: what the chip gets, ISSUE 32 and 34) the inverses and
+    the chunk-to-chunk scan are the four kernels, every
+    ``tpu_custom_call`` named, no float32 64 x 64 dot and no loop over a
+    segment's chunks is left outside them, and the VMEM they ask for is
+    under the limits they state. The kernels' operands are row-major, 64 lanes padded to
     128, where XLA kept some of its own matrices with the chunks on the
     lanes: the rule alone reads 2.67 GiB for 2.45 and is held just
     above that (the cell's whole step counts 4 MB less than the
@@ -160,6 +161,7 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     if impl == "pallas":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert gated_delta.inverse_impl(jnp.float32, 64) == impl
+    assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == impl
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
     args = (
@@ -186,19 +188,31 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     # the forward in the step and in the segment's recompute; a kernel
     # inside a loop body counts once
     assert device_obs.pallas_kernels(hlo) == {
-        "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1}
-    assert hlo.count("tpu_custom_call") == 3
+        "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1,
+        "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
+    assert hlo.count("tpu_custom_call") == 6
     assert not _square_float32_dots(hlo)
+    # the chunk-to-chunk recurrence is inside the scan's kernels (ISSUE
+    # 34): the two loops left are the forward's and the backward's over
+    # the four segments, none over a segment's 128 chunks
+    assert hlo.count(" while(") == 2
     # the compiler held each kernel to the limit it states (it refuses
     # a body that needs more), and the blocks of a segment's 4,096
-    # matrices, operands and result double-buffered, count under it
+    # matrices and of a grid step's heads and chunks, operands and
+    # results double-buffered, count under it
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
-    assert all('"size":"%d"' % gated_delta._INVERSE_VMEM_LIMIT in line
-               for line in calls)
+    for line in calls:
+        limit = (gated_delta._SCAN_VMEM_LIMIT if "gdn_scan" in line
+                 else gated_delta._INVERSE_VMEM_LIMIT)
+        assert '"size":"%d"' % limit in line
     for arrays in (2, 3):
         block = gated_delta.inverse_block(4096, 64, arrays)
         assert gated_delta.inverse_vmem_bytes(
             block, 64, arrays) < gated_delta._INVERSE_VMEM_LIMIT
+    for kind in ("fwd", "fwd_residuals", "bwd"):
+        block, step = gated_delta.scan_block(32, 128, 64, 128, 128, 2, kind)
+        assert gated_delta.scan_vmem_bytes(
+            block, step, 64, 128, 128, 2, kind) < gated_delta._SCAN_VMEM_LIMIT
 
 
 def test_the_chunked_rule_stays_partitionable_over_a_mesh(
