@@ -37,6 +37,7 @@ from elasticdl_tpu.models.transformer import (
     make_norm,
     remat_block,
 )
+from elasticdl_tpu.ops import block_diffusion, flash_attention
 from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.parallel.mesh import DATA_AXES
 from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
@@ -412,11 +413,16 @@ class MoeBlock(nn.Module):
     head_norm: Optional[str] = None
     rotary_dim: Optional[int] = None
     output_gate: Optional[str] = None
+    # the attention mask's layout where it is not causal, and with it
+    # the call's ``positions`` (``Attention``'s own of those names)
+    mask: Optional[Any] = None
 
     @nn.compact
-    def __call__(self, x, training=False):
+    def __call__(self, x, training=False, positions=None):
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
         h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
+        # only ``Attention`` takes the rows' positions
+        where = () if positions is None else (positions,)
         x = x + make_attention(
             self.num_heads,
             self.latent,
@@ -431,7 +437,8 @@ class MoeBlock(nn.Module):
             head_norm=self.head_norm,
             rotary_dim=self.rotary_dim,
             output_gate=self.output_gate,
-        )(h, training)
+            mask=self.mask,
+        )(h, training, *where)
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
         y, aux = MoeMlp(
             self.num_experts,
@@ -509,6 +516,22 @@ class MoeTransformerLM(nn.Module):
     an expert layer of 512 softmax-routed experts of which this chip
     holds ``held_experts=(0, 32)`` in ``held_rows`` rows, and one
     shared expert behind ``shared_gate``.
+
+    ``objective="block_diffusion"`` (SDAR's: ``ops/block_diffusion.py``)
+    trains the same blocks to denoise and not to predict the next
+    token: a training call draws the step's noise from the ``noise``
+    random stream (``train/step_fns.py`` folds it from the step),
+    assembles ``[x_t ; x_0]``, 2 L positions that rotate by ``p mod L``,
+    runs every layer under the mask ``BlockDiffusion(L, bd_block)``,
+    puts ``ln_f`` and ``lm_head`` on the NOISY half alone (the clean
+    half's logits enter no loss) and returns ``logits`` (L positions),
+    ``weights``, ``noise`` (the ``bd_noise`` event's facts),
+    ``aux_loss``, ``routing``; ``loss`` reads ``weights`` where the
+    outputs carry them. ``noisy`` and ``weights`` passed in take the
+    draw's place (eval, the reference check); an eval call without them
+    draws from a fixed key and returns bare logits. Every layer is then
+    an expert block with softmax attention: a dense block, a latent or
+    a linear mixer under this objective is refused.
     """
 
     vocab_size: int = 32000
@@ -569,15 +592,74 @@ class MoeTransformerLM(nn.Module):
     # (models/transformer.py:remat_block)
     remat: bool = False
     remat_policy: str = "full"
+    # "next_token" or "block_diffusion" with its block length, the id
+    # that stands for a masked token and the least noise level
+    objective: str = "next_token"
+    bd_block: int = 4
+    bd_mask_id: Optional[int] = None
+    bd_t_min: float = 1e-3
+
+    def _block_diffusion_inputs(self, tokens, training, noisy, weights):
+        """``(inputs (B, 2 L), positions, mask layout, weights, the
+        noise's facts or None)`` of a call under the block-diffusion
+        objective."""
+        if self.bd_mask_id is None or not (
+                0 <= self.bd_mask_id < self.vocab_size):
+            raise ValueError(
+                "objective=\"block_diffusion\" needs bd_mask_id, an id "
+                "of the vocabulary; got %r" % (self.bd_mask_id,))
+        if (self.first_k_dense or self.moe_every != 1
+                or self.latent is not None or self.linear is not None):
+            raise ValueError(
+                "objective=\"block_diffusion\" runs expert blocks with "
+                "softmax attention: no dense block, no latent and no "
+                "linear mixer carries its mask")
+        if (noisy is None) != (weights is None):
+            raise ValueError("noisy and weights come together")
+        facts = None
+        if noisy is None:
+            # the step's own stream when training; one fixed draw for an
+            # eval call that brings none, so that it is a function of
+            # its tokens
+            key = (self.make_rng("noise") if self.has_rng("noise")
+                   else jax.random.PRNGKey(0))
+            noisy, weights = block_diffusion.noise(
+                key, tokens, self.bd_block, self.bd_mask_id, self.bd_t_min)
+            # for whoever asks with mutable=["intermediates"] (the
+            # benchmark's reference check draws the same noise from
+            # the same key); nothing otherwise
+            self.sow("intermediates", "noise_key", key)
+            self.sow("intermediates", "noisy", noisy)
+            if training:
+                facts = block_diffusion.noise_facts(
+                    key, weights, self.bd_block, self.bd_t_min)
+        inputs, positions = block_diffusion.assemble(noisy, tokens)
+        layout = flash_attention.BlockDiffusion(
+            tokens.shape[-1], self.bd_block)
+        return inputs, positions, layout, weights, facts
 
     @nn.compact
-    def __call__(self, tokens, training: bool = False):
+    def __call__(self, tokens, training: bool = False, noisy=None,
+                 weights=None):
+        if self.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(
+                "objective must be 'next_token' or 'block_diffusion', "
+                "got %r" % (self.objective,))
+        denoise = self.objective == "block_diffusion"
+        tokens = tokens.astype(jnp.int32)
+        positions = layout = facts = None
+        if denoise:
+            tokens, positions, layout, weights, facts = (
+                self._block_diffusion_inputs(
+                    tokens, training, noisy, weights))
+        elif noisy is not None or weights is not None:
+            raise ValueError("only block diffusion takes noisy and weights")
         embed_init = (
             {} if self.embed_init_std is None else
             {"embedding_init": nn.initializers.normal(self.embed_init_std)})
         x = nn.Embed(
             self.vocab_size, self.embed_dim, name="wte", **embed_init
-        )(tokens.astype(jnp.int32))
+        )(tokens)
         wrap = (
             functools.partial(
                 remat_block, remat_policy=self.remat_policy,
@@ -608,6 +690,7 @@ class MoeTransformerLM(nn.Module):
             head_norm=self.head_norm,
             rotary_dim=self.rotary_dim,
             output_gate=self.output_gate,
+            mask=layout,
         )
         balance = z_loss = jnp.float32(0.0)
         routing = []
@@ -637,7 +720,7 @@ class MoeTransformerLM(nn.Module):
                     name="block_%d" % i,
                     **shared,
                     **mixer,
-                )(x, training)
+                )(x, training, positions)
                 balance = balance + aux["load_balancing"]
                 z_loss = z_loss + aux["router_z"]
                 if aux["routing"] is not None:
@@ -652,6 +735,8 @@ class MoeTransformerLM(nn.Module):
                     self.num_heads, mlp_act=self.dense_act,
                     mlp_dim=self.dense_dim, name="block_%d" % i, **shared
                 )(x, training)
+        if denoise:
+            x = block_diffusion.noisy_half(x)
         x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         logits = nn.Dense(
             self.vocab_size, use_bias=False, name="lm_head"
@@ -664,6 +749,10 @@ class MoeTransformerLM(nn.Module):
         outputs = {"logits": logits, "aux_loss": aux_loss}
         if routing:
             outputs["routing"] = merge_routing(routing)
+        if denoise:
+            outputs["weights"] = weights
+            if facts is not None:
+                outputs["noise"] = facts
         return outputs
 
 
@@ -740,6 +829,11 @@ def loss(labels, predictions):
     if isinstance(predictions, dict):
         logits = predictions["logits"]
         aux = predictions["aux_loss"]
+        if "weights" in predictions:
+            # block diffusion: position-aligned, weighted by 1 / t on
+            # the masked positions (ops/block_diffusion.py)
+            return block_diffusion.weighted_loss(
+                labels, logits, predictions["weights"]) + aux
     else:
         logits, aux = predictions, 0.0
     per_token = sparse_softmax_cross_entropy(

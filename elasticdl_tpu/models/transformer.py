@@ -22,6 +22,7 @@ Design notes (TPU-first):
 """
 
 import dataclasses
+import functools
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -54,12 +55,17 @@ RESIDUAL_SPEC = P(DATA_AXES, "sp", None)
 HIDDEN_SPEC = P(DATA_AXES, "sp", "tp")
 
 
-def rotary_embedding(x, base=10000.0, seq_axis=2):
-    """Apply RoPE; seq_axis=2 for (B, H, S, d), 1 for (B, S, H, d)."""
+def rotary_embedding(x, base=10000.0, seq_axis=2, positions=None):
+    """Apply RoPE; seq_axis=2 for (B, H, S, d), 1 for (B, S, H, d).
+    ``positions`` (S,): the position each row rotates by where it is
+    not its index (block diffusion's two copies of one sequence)."""
     seq, dim = x.shape[seq_axis], x.shape[-1]
     half = dim // 2
     freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = jnp.arange(seq, dtype=jnp.float32)[:, None] * freqs[None, :]
+    positions = (
+        jnp.arange(seq, dtype=jnp.float32) if positions is None
+        else positions.astype(jnp.float32))
+    angles = positions[:, None] * freqs[None, :]
     shape = [1] * x.ndim
     shape[seq_axis], shape[-1] = seq, half
     cos = jnp.cos(angles).reshape(shape)
@@ -137,18 +143,22 @@ class Attention(nn.Module):
     # half, a gate of the head's width, multiplies the attention's
     # output through a sigmoid before the output projection
     output_gate: Optional[str] = None
+    # the mask's layout (``ops/flash_attention.py``: ``BlockDiffusion``)
+    # where it is not the causal diagonal; ``__call__``'s ``positions``
+    # then say what each row rotates by
+    mask: Optional[Any] = None
 
-    def _rotate(self, t):
+    def _rotate(self, t, positions=None):
+        rotate = functools.partial(
+            rotary_embedding, base=self.rope_theta, positions=positions)
         if self.rotary_dim is None:
-            return rotary_embedding(t, base=self.rope_theta)
+            return rotate(t)
         return jnp.concatenate([
-            rotary_embedding(
-                t[..., :self.rotary_dim], base=self.rope_theta),
-            t[..., self.rotary_dim:],
+            rotate(t[..., :self.rotary_dim]), t[..., self.rotary_dim:],
         ], axis=-1)
 
     @nn.compact
-    def __call__(self, x, training=False):
+    def __call__(self, x, training=False, positions=None):
         dim = x.shape[-1]
         head_dim = self.head_dim or dim // self.num_heads
         kv_heads = self.num_kv_heads or self.num_heads
@@ -202,13 +212,15 @@ class Attention(nn.Module):
         k = to_bhsd(head_norm(
             dense("key", "k_norm", heads=kv_heads), "k_norm"))
         v = to_bhsd(dense("value", heads=kv_heads))
-        q = self._rotate(q)
-        k = self._rotate(k)
+        q = self._rotate(q, positions)
+        k = self._rotate(k, positions)
 
         if self.attention_impl in ("ring", "ulysses"):
-            if kv_heads != self.num_heads:
+            if (kv_heads != self.num_heads or self.mask is not None
+                    or positions is not None):
                 raise ValueError(
-                    "attention_impl=%r takes equal head counts"
+                    "attention_impl=%r takes equal head counts, the "
+                    "causal mask and the rows' own positions"
                     % (self.attention_impl,))
             schedule = (ring_attention if self.attention_impl == "ring"
                         else ulysses_attention)
@@ -221,7 +233,7 @@ class Attention(nn.Module):
             )))
             out = dot_product_attention(
                 q, k, v, causal=True, impl=self.attention_impl,
-                mesh=self.mesh, spec=spec, note=note,
+                mesh=self.mesh, spec=spec, note=note, mask=self.mask,
             )
         out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
         if gate is not None:
@@ -454,13 +466,15 @@ def make_attention(num_heads, latent=None, linear=None, **fields):
     take, and what only ``Attention`` has (``qk_norm``, ``dropout`` and
     the grouped-query fields)."""
     if linear is not None:
+        if fields.get("mask") is not None:
+            raise ValueError("a Gated DeltaNet mixer has no mask")
         return GatedDeltaNet(
             linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
             name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
     for name in ("qk_norm", "dropout", "head_dim", "num_kv_heads",
-                 "head_norm", "rotary_dim", "output_gate"):
+                 "head_norm", "rotary_dim", "output_gate", "mask"):
         if fields.pop(name, None):
             raise ValueError("latent attention has no %s" % name)
     return LatentAttention(num_heads, latent, name="attn", **fields)

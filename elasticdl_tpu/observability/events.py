@@ -263,6 +263,13 @@ EVENT_TYPES = frozenset({
                              #   tokens_per_expert_max and _mean over
                              #   the expert layers, router_entropy in
                              #   nats, dropped_pairs)
+    "bd_noise",              # the same steps of a model trained by
+                             #   block diffusion
+                             #   (ops/block_diffusion.py): what the
+                             #   step's noise was (+ step,
+                             #   masked_share of the tokens, mean_t
+                             #   over the blocks, weight_mean = sum(w)
+                             #   / L, which averages 1)
 })
 
 

@@ -22,11 +22,13 @@ from elasticdl_tpu.ops import flash_attention as _flash
 logger = _logger_factory("elasticdl_tpu.ops.attention")
 
 
-def xla_attention(q, k, v, causal=False, sm_scale=None):
+def xla_attention(q, k, v, causal=False, sm_scale=None, mask=None):
     """Reference O(S^2) attention over (batch, heads, seq, dim); v, and
     so the output, may have a width of its own (the scale is q's); k
     and v may have a head for every ``group`` query heads, and are
-    repeated here (the kernel reads them uncopied)."""
+    repeated here (the kernel reads them uncopied). ``mask``: a layout
+    of ``ops/flash_attention.py`` in ``causal``'s place; the dense mask
+    is the layout's ``keep``, the function the kernels' tiles apply."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     group = q.shape[1] // k.shape[1]
@@ -35,23 +37,28 @@ def xla_attention(q, k, v, causal=False, sm_scale=None):
     s = jnp.einsum(
         "bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32
     ) * sm_scale
-    if causal:
+    layout = _flash.as_layout(causal if mask is None else mask)
+    if layout != _flash.FULL:
         seq_q, seq_k = s.shape[-2], s.shape[-1]
         q_pos = jnp.arange(seq_q)[:, None]
         k_pos = jnp.arange(seq_k)[None, :]
-        s = jnp.where(q_pos >= k_pos, s, _flash.NEG_INF)
+        s = jnp.where(layout.keep(q_pos, k_pos), s, _flash.NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
-def _pallas_refusal(q, k, v, block_q, block_k):
-    """Why the flash kernel cannot take these shapes; "" when it can."""
+def _pallas_refusal(q, k, v, block_q, block_k, layout=_flash.CAUSAL):
+    """Why the flash kernel cannot take these shapes under this
+    layout; "" when it can."""
     seq_q, seq_k = q.shape[2], k.shape[2]
     # None = flash_attention's auto-tuner picks the block; ask it what
     # it would pick so this gate can't drift from the tuner's fallback
-    block_q, block_k = _flash._blocks(
-        seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k,
-        v_dim=v.shape[-1])
+    tiles = [
+        _flash._blocks(
+            seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k,
+            backward=backward, v_dim=v.shape[-1])
+        for backward in (False, True)]
+    block_q, block_k = tiles[0]
     if seq_q % block_q or seq_k % block_k:
         return "seq (%d, %d) not divisible by blocks (%d, %d)" % (
             seq_q, seq_k, block_q, block_k,
@@ -59,7 +66,8 @@ def _pallas_refusal(q, k, v, block_q, block_k):
     if seq_q < 8 or seq_k < 128:
         # below one lane tile the kernel buys nothing
         return "seq (%d, %d) below one (8, 128) tile" % (seq_q, seq_k)
-    return ""
+    return next(filter(None, (
+        layout.refusal(seq_q, seq_k, *pair) for pair in tiles)), "")
 
 
 def _flash_facts(q, k, v, causal, block_q, block_k):
@@ -77,12 +85,17 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     backward's where ``_blocks`` gives it other blocks."""
     shapes = (q.shape[2], k.shape[2], q.shape[-1], q.dtype)
     v_dim = v.shape[-1]
+    layout = _flash.as_layout(causal)
+    # the diagonal's line is what it always was; another layout's says
+    # which, and the tiles its counts are of
+    other = layout not in (_flash.CAUSAL, _flash.FULL)
 
     def pairs(backward):
         blocks = _flash._blocks(
             *shapes, block_q, block_k, backward=backward, v_dim=v_dim)
-        return "run=%d masked=%d skipped=%d" % _flash.causal_pairs(
-            *shapes[:2], *blocks, causal=causal)
+        counts = "run=%d masked=%d skipped=%d" % _flash.causal_pairs(
+            *shapes[:2], *blocks, causal=layout)
+        return counts + (" blocks=%dx%d" % blocks if other else "")
 
     forward, backward = pairs(False), pairs(True)
     widths = "" if v_dim == q.shape[-1] else (
@@ -91,9 +104,10 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     if k.shape[1] != q.shape[1]:
         widths = "kv_heads=%d group=%d, %s" % (
             k.shape[1], q.shape[1] // k.shape[1], widths)
-    return "%sflash backward=%s, pairs %s%s" % (
+    return "%sflash backward=%s, %spairs %s%s" % (
         widths,
         _flash.backward_schedule(*shapes, block_q, block_k, v_dim),
+        "mask=%s " % layout if other else "",
         forward,
         " (backward %s)" % backward if backward != forward else "",
     )
@@ -160,17 +174,21 @@ def dot_product_attention(
     mesh=None,
     spec=None,
     note="",
+    mask=None,
 ):
     """q/k/v are (batch, heads, seq, dim); k and v may have a head for
-    every ``group`` query heads. ``mesh`` and ``spec``: the mesh the
+    every ``group`` query heads. ``mask``: a layout of
+    ``ops/flash_attention.py`` (``BlockDiffusion(half_len, block)``) in
+    the place of the boolean ``causal``; both implementations read it. ``mesh`` and ``spec``: the mesh the
     caller's step is sharded over and the PartitionSpec of q/k/v on it;
     the Pallas kernel then runs inside a shard_map over them. ``note``:
     what the caller wants on the resolution's log line beside the
     kernel's own facts (``gate=sigmoid rotary=64/256``)."""
+    layout = _flash.as_layout(causal if mask is None else mask)
     if impl == "auto":
         backend = jax.default_backend()
         reason = (
-            _pallas_refusal(q, k, v, block_q, block_k)
+            _pallas_refusal(q, k, v, block_q, block_k, layout)
             if backend == "tpu"
             else "the Pallas kernel needs a TPU backend"
         )
@@ -178,16 +196,18 @@ def dot_product_attention(
         _log_auto_once(
             backend, impl, reason, tuple(q.shape), q.dtype.name,
             "" if reason else ", ".join(filter(None, (
-                note, _flash_facts(q, k, v, causal, block_q, block_k)))),
+                note, _flash_facts(q, k, v, layout, block_q, block_k)))),
         )
     if impl == "pallas":
         kernel = functools.partial(
             _flash.flash_attention, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, interpret=interpret,
+            mask=mask,
         )
         if mesh is not None:
             kernel = _shard_over_mesh(kernel, mesh, spec, q)
         return kernel(q, k, v)
     if impl == "xla":
-        return xla_attention(q, k, v, causal=causal, sm_scale=sm_scale)
+        return xla_attention(
+            q, k, v, causal=causal, sm_scale=sm_scale, mask=mask)
     raise ValueError("unknown attention impl %r" % (impl,))

@@ -47,6 +47,23 @@ body. On a v5e, 8 x 16,384 x 256 bfloat16 at blocks 512 / 1024, the
 three classes took the forward from 10.54 to 8.48 ms and ``flash_bwd``
 from 17.02 to 16.47, every output bit unchanged (PERF.md, PR 28).
 
+The diagonal is one LAYOUT of the mask among several (``Causal``,
+``Full``, ``BlockDiffusion``; ``as_layout`` takes the boolean
+``causal`` every caller had). A layout is a small static description
+that says, of two positions, whether the query may see the key
+(``keep``: the kernels' select on a masked tile and the XLA path's
+dense mask are this one function) and, of a (q-block, k-block) pair
+and the two block sizes, its class (``pair``) and which block a
+skipped grid step names (``k_named``, ``q_named``). The kernels, the
+index maps and ``causal_pairs`` read nothing else, so a band or
+document boundaries are further layouts and no further kernels.
+``BlockDiffusion(half_len, block)`` is block diffusion's training mask
+over ``[noisy copy ; clean copy]`` of a sequence (BD3-LMs,
+arXiv:2503.09573): a noisy query sees its own noisy block, both
+directions, and the clean blocks before it; a clean query the clean
+blocks up to its own; L^2 + L B of the (2 L)^2 score entries, with
+tiles that divide L the clean -> noisy quadrant skipped whole.
+
 Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
 into one parallel grid axis and see one head's (seq, head_dim) rows.
 v may have a width of its own (latent attention: q and k of 192, v of
@@ -61,6 +78,7 @@ backward kernel (``_bwd`` says what that costs); a call of equal head
 counts gets the index maps it always had.
 """
 
+import dataclasses
 import functools
 import math
 
@@ -191,8 +209,9 @@ def _causal_pair(q_block, k_block, block_q, block_k):
     pair lies wholly above the diagonal. ``masked``: some element of the
     tile has ``q_pos < k_pos``, so a pair that runs needs
     ``_causal_mask``; where it is false the select would keep every
-    element. The kernels' ``pl.when`` predicates, the index maps' clamps
-    and ``causal_pairs`` all read this one function."""
+    element. ``Causal``'s class, its clamps and through them the
+    kernels' ``pl.when`` predicates, the index maps and ``causal_pairs``
+    all read this one function."""
     # non-negative operands: the truncating division is the floor, and
     # one scalar instruction where a traced ``//`` is five
     div = (
@@ -205,37 +224,270 @@ def _causal_pair(q_block, k_block, block_q, block_k):
     return last_k, first_q, masked
 
 
+# ---------------------------------------------------------------------------
+# Mask layouts
+# ---------------------------------------------------------------------------
+#
+# A layout answers four questions, the first of positions, the others
+# of block indices and block sizes (numpy integers or traced scalars
+# alike, never data):
+#
+#   keep(q_pos, k_pos)                        may the query see the key
+#   pair(q_block, k_block, block_q, block_k)  (runs, masked): does the
+#       tile keep any element; does a tile that runs drop some
+#   k_named(q_block, k_block, ...)            on a (q-block, k-block)
+#       grid, the k-block step ``k_block`` names: its own if the pair
+#       runs, else that of a neighbouring step that runs
+#   q_named(q_block, k_block, ..., num_q)     the same of a (k-block,
+#       q-block) grid's q-blocks
+#
+# and ``refusal(seq_q, seq_k, block_q, block_k)``: why these tiles
+# cannot carry it, "" when they can.
+
+
+def _ops(*values):
+    """(array namespace, floor division of non-negative integers) for
+    block indices that are traced scalars inside a kernel or an index
+    map and numpy arrays in ``causal_pairs`` and the tests."""
+    if any(isinstance(v, jax.Array) for v in values):
+        return jnp, jax.lax.div
+    return np, lambda a, b: a // b
+
+
+def _clip(xp, x, lo, hi):
+    return xp.minimum(xp.maximum(x, lo), hi)
+
+
+@dataclasses.dataclass(frozen=True)
+class Full:
+    """Every query sees every key (the ring's off-diagonal shards):
+    the identity index maps and one unmasked body."""
+
+    def __str__(self):
+        return "full"
+
+    def keep(self, q_pos, k_pos):
+        return (q_pos >= 0) & (k_pos >= 0)
+
+    def pair(self, q_block, k_block, block_q, block_k):
+        always = (q_block >= 0) & (k_block >= 0)
+        return always, ~always
+
+    def k_named(self, q_block, k_block, block_q, block_k):
+        return k_block
+
+    def q_named(self, q_block, k_block, block_q, block_k, num_q):
+        return q_block
+
+    def refusal(self, seq_q, seq_k, block_q, block_k):
+        return ""
+
+
+@dataclasses.dataclass(frozen=True)
+class Causal:
+    """Position ``q`` sees the keys up to itself (``_causal_pair``)."""
+
+    def __str__(self):
+        return "causal"
+
+    def keep(self, q_pos, k_pos):
+        return q_pos >= k_pos
+
+    def pair(self, q_block, k_block, block_q, block_k):
+        last_k, _, masked = _causal_pair(q_block, k_block, block_q, block_k)
+        return k_block <= last_k, masked
+
+    def k_named(self, q_block, k_block, block_q, block_k):
+        last_k, _, _ = _causal_pair(q_block, k_block, block_q, block_k)
+        return jnp.minimum(k_block, last_k)
+
+    def q_named(self, q_block, k_block, block_q, block_k, num_q):
+        # held to the grid where seq_q ends before the k-block starts
+        _, first_q, _ = _causal_pair(q_block, k_block, block_q, block_k)
+        return jnp.minimum(jnp.maximum(q_block, first_q), num_q - 1)
+
+    def refusal(self, seq_q, seq_k, block_q, block_k):
+        return ""
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """Block diffusion's training mask over ``2 x half_len`` positions,
+    the noisy copy of a sequence and then the clean one, in blocks of
+    ``block`` tokens. With ``half(p) = p // half_len`` (0 noisy, 1
+    clean) and ``blk(p) = (p mod half_len) // block``, q sees k iff
+
+        noisy q, noisy k:   blk(k) == blk(q)   its own block, both ways
+        noisy q, clean k:   blk(k) <  blk(q)   the clean blocks before
+        clean q, noisy k:   never
+        clean q, clean k:   blk(k) <= blk(q)   block-causal
+
+    Every row keeps a key (a noisy token its own block, a clean token
+    itself). Tiles divide ``half_len`` (``refusal``), so a tile lies in
+    one quadrant and its class follows from the first and the last
+    block its rows and its columns touch. A row of tiles runs on up to
+    two runs of k-blocks (its own noisy blocks, then the clean ones up
+    to its bound), a column on up to two runs of q-blocks; a skipped
+    step names the block of the run's nearest end."""
+
+    half_len: int
+    block: int
+
+    def __str__(self):
+        return "block_diffusion(%d, %d)" % (self.half_len, self.block)
+
+    def _block_of(self, pos, div):
+        """The block of a position inside its half; a shift where the
+        block length is a power of two (the kernel's vector path)."""
+        if self.block & (self.block - 1) == 0:
+            return pos >> (self.block.bit_length() - 1)
+        return div(pos, self.block)
+
+    def keep(self, q_pos, k_pos):
+        xp, div = _ops(q_pos, k_pos)
+        q_clean, k_clean = q_pos >= self.half_len, k_pos >= self.half_len
+        q_blk = self._block_of(
+            q_pos - xp.where(q_clean, self.half_len, 0), div)
+        k_blk = self._block_of(
+            k_pos - xp.where(k_clean, self.half_len, 0), div)
+        # a row's bound on clean blocks and the one noisy block it
+        # sees; a key's block in the half it lies in, and in the other
+        # a number no row's bound or block meets. Integers until the
+        # last two compares: Mosaic selects no booleans
+        upto = xp.where(q_clean, q_blk, q_blk - 1)
+        own = xp.where(q_clean, -1, q_blk)
+        clean_blk = xp.where(k_clean, k_blk, self.half_len)
+        noisy_blk = xp.where(k_clean, -2, k_blk)
+        return (clean_blk <= upto) | (noisy_blk == own)
+
+    def _tile(self, index, size, xp, div):
+        """(in the clean half, first block, last block) of the tile
+        ``index`` of ``size`` positions."""
+        per_half = self.half_len // size
+        clean = index >= per_half
+        start = (index - xp.where(clean, per_half, 0)) * size
+        return clean, div(start, self.block), div(
+            start + size - 1, self.block)
+
+    def pair(self, q_block, k_block, block_q, block_k):
+        xp, div = _ops(q_block, k_block)
+        q_clean, q0, q1 = self._tile(q_block, block_q, xp, div)
+        k_clean, k0, k1 = self._tile(k_block, block_k, xp, div)
+        noisy = ~q_clean
+        # clean keys: some row's bound reaches the first; every row's
+        # the last. Noisy keys: the block ranges meet; are one block
+        some = xp.where(
+            k_clean, k0 <= xp.where(q_clean, q1, q1 - 1),
+            noisy & (k0 <= q1) & (q0 <= k1))
+        every = xp.where(
+            k_clean, k1 <= xp.where(q_clean, q0, q0 - 1),
+            noisy & (q1 <= k0) & (k1 <= q0))
+        return some, ~every
+
+    def k_named(self, q_block, k_block, block_q, block_k):
+        xp, div = _ops(q_block, k_block)
+        q_clean, q0, q1 = self._tile(q_block, block_q, xp, div)
+        half = self.half_len // block_k
+        # the noisy k-blocks a noisy row meets: those of its own blocks
+        own = _clip(
+            xp, k_block, div(q0 * self.block, block_k),
+            div((q1 + 1) * self.block - 1, block_k))
+        # the clean ones: blocks 0 .. upto, none for a row in block 0
+        upto = xp.where(q_clean, q1, q1 - 1)
+        tiles = div((upto + 1) * self.block + block_k - 1, block_k)
+        clean = _clip(xp, k_block, half, half + tiles - 1)
+        return xp.where(
+            q_clean | ((k_block >= half) & (tiles > 0)), clean, own)
+
+    def q_named(self, q_block, k_block, block_q, block_k, num_q):
+        xp, div = _ops(q_block, k_block)
+        k_clean, k0, k1 = self._tile(k_block, block_k, xp, div)
+        half = self.half_len // block_q
+        # noisy keys: the noisy rows of their own blocks
+        own = _clip(
+            xp, q_block, div(k0 * self.block, block_q),
+            div((k1 + 1) * self.block - 1, block_q))
+        # clean keys: the noisy rows of a later block (none for the
+        # last clean blocks), then the clean rows from their own on
+        later = div((k0 + 1) * self.block, block_q)
+        noisy = _clip(xp, q_block, later, half - 1)
+        clean = xp.maximum(q_block, half + div(k0 * self.block, block_q))
+        return xp.where(
+            k_clean,
+            xp.where((q_block < half) & (later < half), noisy, clean), own)
+
+    def refusal(self, seq_q, seq_k, block_q, block_k):
+        if seq_q != 2 * self.half_len or seq_k != 2 * self.half_len:
+            return "%s covers %d positions, q and k have (%d, %d)" % (
+                self, 2 * self.half_len, seq_q, seq_k)
+        if self.half_len % self.block:
+            return "%s: the block does not divide the half" % (self,)
+        if self.half_len % block_q or self.half_len % block_k:
+            return "%s: tiles (%d, %d) do not divide the half" % (
+                self, block_q, block_k)
+        return ""
+
+
+FULL, CAUSAL = Full(), Causal()
+
+
+def as_layout(causal):
+    """The layout of a call's ``causal`` argument: a layout, or the
+    boolean it always was (True: the diagonal; False: no mask)."""
+    if causal is True:
+        return CAUSAL
+    if causal is False or causal is None:
+        return FULL
+    return causal
+
+
+def _mask_tile(layout, s, q_block, k_block, block_q, block_k):
+    """The scores ``s`` of one masked tile with what the layout drops
+    at ``NEG_INF``, the mask computed from the two block indices. The
+    diagonal's is ``_causal_mask`` as it always was; another layout's
+    positions are a column and a row, so what ``keep`` computes of one
+    side costs a vector, and only its last compare the tile."""
+    if layout == CAUSAL:
+        return _causal_mask(s, q_block, k_block, block_q, block_k)
+    q_pos = q_block * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (s.shape[0], 1), 0)
+    k_pos = k_block * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, s.shape[1]), 1)
+    return jnp.where(layout.keep(q_pos, k_pos), s, NEG_INF)
+
+
 def causal_pairs(seq_q, seq_k, block_q, block_k, causal=True):
     """``(run, masked, skipped)``: of one head's (q-block, k-block)
-    pairs, how many compute a tile, how many of those apply the causal
-    mask, and how many are grid steps that compute and fetch nothing.
-    A function of shapes (``ops/attention.py`` logs it)."""
+    pairs, how many compute a tile, how many of those apply the mask,
+    and how many are grid steps that compute and fetch nothing, under
+    the layout ``causal`` (``as_layout``). A function of shapes
+    (``ops/attention.py`` logs it)."""
     num_q, num_k = seq_q // block_q, seq_k // block_k
-    if not causal:
-        return num_q * num_k, 0, 0
     q_block = np.arange(num_q)[:, None]
     k_block = np.arange(num_k)[None, :]
-    last_k, _, masked = _causal_pair(q_block, k_block, block_q, block_k)
-    run = k_block <= last_k
+    run, masked = as_layout(causal).pair(q_block, k_block, block_q, block_k)
+    run = np.broadcast_to(run, (num_q, num_k))
     return (
         int(run.sum()), int((run & masked).sum()), int((~run).sum())
     )
 
 
 def _each_class(causal, q_block, k_block, block_q, block_k, tile):
-    """``tile(masked)`` once for the pair's class: a diagonal pair with
-    the mask, an interior one without (a body of its own, so the iotas,
-    the compare and the select are not in it), nothing for a skipped
-    pair. A call that is not causal has the one unmasked body."""
-    if not causal:
-        tile(False)
+    """``tile(masked)`` once for the pair's class under the layout
+    ``causal``: a masked pair with the select (``masked`` is the layout
+    whose mask the tile takes), an interior one without (``None``: a
+    body of its own, so the iotas, the compare and the select are not
+    in it), nothing for a skipped pair. A call without a mask has the
+    one unmasked body."""
+    layout = as_layout(causal)
+    if layout == FULL:
+        tile(None)
         return
-    last_k, _, masked = _causal_pair(q_block, k_block, block_q, block_k)
-    run = k_block <= last_k
+    run, masked = layout.pair(q_block, k_block, block_q, block_k)
     pl.when(jnp.logical_and(run, masked))(
-        functools.partial(tile, True))
+        functools.partial(tile, layout))
     pl.when(jnp.logical_and(run, jnp.logical_not(masked)))(
-        functools.partial(tile, False))
+        functools.partial(tile, None))
 
 
 # ---------------------------------------------------------------------------
@@ -286,8 +538,8 @@ def _fwd_kernel(
             )
             * sm_scale
         )
-        if masked:
-            s = _causal_mask(s, q_block, k_block, block_q, block_k)
+        if masked is not None:
+            s = _mask_tile(masked, s, q_block, k_block, block_q, block_k)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -318,32 +570,27 @@ def _fwd_kernel(
 def _index_maps(causal, block_q, block_k, num_q, k_outer=False):
     """(q-ish, k-ish, lse-ish) index maps of the merged "(bh, seq, d)"
     view, on a ``(bh, q-block, k-block)`` grid or, ``k_outer``, on
-    ``(bh, k-block, q-block)``.
+    ``(bh, k-block, q-block)``, under the layout ``causal``.
 
-    A causal grid's skipped steps name the block of the nearest step
-    that runs, so the pipeline, which copies a block only when its
+    A masked grid's skipped steps name the block of a neighbouring
+    step that runs, so the pipeline, which copies a block only when its
     index changes, fetches nothing for them: the inner axis is clamped
-    to ``_causal_pair``'s bound (k-blocks from above by ``last_k``;
-    q-blocks from below by ``first_q``, held to the grid where seq_q
-    ends before the k-block starts). Only inputs move with the inner
-    axis; outputs follow the outer one, which is never clamped. A call
-    that is not causal gets the identity."""
+    by the layout (``k_named``, ``q_named``; the diagonal's are
+    ``_causal_pair``'s bounds: k-blocks from above by ``last_k``,
+    q-blocks from below by ``first_q``). Only inputs move with the
+    inner axis; outputs follow the outer one, which is never clamped.
+    A call without a mask gets the identity (``Full``'s)."""
+    layout = as_layout(causal)
 
     def q_block(outer, inner):
         if not k_outer:
             return outer
-        if not causal:
-            return inner
-        _, first_q, _ = _causal_pair(inner, outer, block_q, block_k)
-        return jnp.minimum(jnp.maximum(inner, first_q), num_q - 1)
+        return layout.q_named(inner, outer, block_q, block_k, num_q)
 
     def k_block(outer, inner):
         if k_outer:
             return outer
-        if not causal:
-            return inner
-        last_k, _, _ = _causal_pair(outer, inner, block_q, block_k)
-        return jnp.minimum(inner, last_k)
+        return layout.k_named(outer, inner, block_q, block_k)
 
     q_idx = lambda b, outer, inner: (b, q_block(outer, inner), 0)
     k_idx = lambda b, outer, inner: (b, k_block(outer, inner), 0)
@@ -440,7 +687,9 @@ def _p_and_ds(q, k, v, do, lse_ref, delta_ref, q_block, k_block,
     """``p = exp(s - lse)`` and ``ds = p * (dp - delta) * sm_scale`` of
     one (q-block, k-block) pair, both float32: the two score-sized
     matmuls (``q k^T``, ``do v^T``) every backward kernel starts from.
-    Native-dtype matmul inputs, f32 accumulation (see _fwd_kernel)."""
+    ``masked``: the layout whose mask the tile takes, None for an
+    interior one. Native-dtype matmul inputs, f32 accumulation (see
+    _fwd_kernel)."""
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
     s = (
@@ -452,8 +701,8 @@ def _p_and_ds(q, k, v, do, lse_ref, delta_ref, q_block, k_block,
         )
         * sm_scale
     )
-    if masked:
-        s = _causal_mask(s, q_block, k_block, block_q, block_k)
+    if masked is not None:
+        s = _mask_tile(masked, s, q_block, k_block, block_q, block_k)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do,
@@ -851,8 +1100,11 @@ def flash_attention(
     block_q=None,
     block_k=None,
     interpret=False,
+    mask=None,
 ):
     """Blockwise attention over (batch, heads, seq, head_dim) inputs.
+    ``mask``: a layout (``BlockDiffusion(half_len, block)``) in the
+    place of the boolean ``causal``; tiles it cannot carry are refused.
     k and v may have fewer heads than q (grouped-query attention: a
     head count that divides q's; query head ``h`` reads kv head ``h //
     group``, through the index maps, no copy; dk and dv are summed over
@@ -895,6 +1147,13 @@ def flash_attention(
             "seq lengths (%d, %d) must be multiples of the block sizes "
             "(%d, %d)" % (seq_q, seq_k, fwd_q, fwd_k)
         )
+    layout = as_layout(causal if mask is None else mask)
+    for backward in (False, True):
+        refusal = layout.refusal(seq_q, seq_k, *_blocks(
+            seq_q, seq_k, head_dim, q.dtype, block_q, block_k,
+            backward=backward, v_dim=v_dim))
+        if refusal:
+            raise ValueError(refusal)
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(head_dim)
     # (batch, heads) merged, heads minor: with ``group`` query heads a
@@ -905,7 +1164,8 @@ def flash_attention(
         merge(k),
         merge(v),
         sm_scale,
-        causal,
+        # the boolean a call always passed, where it passed one
+        causal if mask is None else layout,
         block_q,
         block_k,
         interpret,

@@ -53,12 +53,32 @@ def guard_nonfinite_state(old_state, new_state, nonfinite):
     )
 
 
-def _routing_of(outputs):
-    """The expert layers' routing counters where a model's training
-    outputs carry them (``models/moe_transformer.py``), else None: an
-    empty pytree, so a model without them compiles the program it
-    compiled before."""
-    return outputs.get("routing") if isinstance(outputs, dict) else None
+# what a model's training outputs may carry besides logits and losses,
+# as device scalars the step hands out beside the health scalars: an
+# MoE LM's expert-load counters and a block-diffusion LM's noise facts
+# (``models/moe_transformer.py``)
+COUNTER_KEYS = ("routing", "noise")
+
+
+def _counters_of(outputs):
+    """``(routing, noise)``: each the model's own dict of device
+    scalars or None, an empty pytree, so a model without them compiles
+    the program it compiled before."""
+    if not isinstance(outputs, dict):
+        return (None,) * len(COUNTER_KEYS)
+    return tuple(outputs.get(key) for key in COUNTER_KEYS)
+
+
+def step_rngs(step):
+    """The random streams a training call may draw from, each folded
+    from the step so that a resumed job draws what it would have drawn:
+    ``dropout``, and ``noise`` (a diffusion objective's corruption of
+    its inputs, ``ops/block_diffusion.py``). A stream no model draws
+    from is dead code, which the compiler drops."""
+    return {
+        "dropout": jax.random.fold_in(jax.random.PRNGKey(0), step),
+        "noise": jax.random.fold_in(jax.random.PRNGKey(1), step),
+    }
 
 
 def _apply_model(model, params, model_state, features, training, rngs):
@@ -93,10 +113,11 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
     previous state in-graph (the skip sentinel). ``health=False`` is
     the exact pre-health program: no extra outputs (test-asserted).
     Where the model's training outputs carry ``"routing"`` (an MoE
-    LM's expert-load counters), the dict has them under ``"routing"``
-    as device scalars: they leave the step with the health scalars and
-    cost no fetch until someone reads them (the worker does on the
-    steps it logs).
+    LM's expert-load counters) or ``"noise"`` (a block-diffusion LM's
+    noise facts), the dict has them under those names as device
+    scalars: they leave the step with the health scalars and cost no
+    fetch until someone reads them (the worker does on the steps it
+    logs).
 
     ``grad_accum_steps=k`` splits the batch into k equal microbatches
     scanned sequentially, accumulating MASK-WEIGHTED gradient sums and
@@ -112,9 +133,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         )
 
     def _loss_sum(params, model_state, features, labels, mask, rngs):
-        """(masked loss SUM, (mask weight, new model state, routing
-        counters or None)) — summed (not averaged) so microbatch grads
-        add linearly."""
+        """(masked loss SUM, (mask weight, new model state, the model's
+        counters: ``_counters_of``)) — summed (not averaged) so
+        microbatch grads add linearly."""
         compute_params = params
         compute_features = features
         if compute_dtype is not None:
@@ -141,7 +162,7 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 mask.shape[0], -1
             ).mean(axis=1)
             return jnp.sum(per_sample * mask), (
-                jnp.sum(mask), new_model_state, _routing_of(outputs)
+                jnp.sum(mask), new_model_state, _counters_of(outputs)
             )
 
     def _apply_update(state, grads, loss, new_model_state):
@@ -170,18 +191,15 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
             batch["labels"],
             batch[MASK_KEY],
         )
-        rngs = {
-            "dropout": jax.random.fold_in(
-                jax.random.PRNGKey(0), state.step
-            )
-        }
+        rngs = step_rngs(state.step)
 
-        def finish(new_state, loss, grads, routing):
+        def finish(new_state, loss, grads, counters):
             if not health:
                 return new_state, loss
             scalars = health_scalars(loss, global_grad_norm(grads))
-            if routing is not None:
-                scalars["routing"] = routing
+            scalars.update(
+                (key, value) for key, value in zip(COUNTER_KEYS, counters)
+                if value is not None)
             if guard_nonfinite:
                 new_state = guard_nonfinite_state(
                     state, new_state, scalars["nonfinite"]
@@ -190,21 +208,21 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
 
         if grad_accum_steps == 1:
             def compute_loss(params):
-                loss_sum, (weight, new_model_state, routing) = _loss_sum(
+                loss_sum, (weight, new_model_state, counters) = _loss_sum(
                     params, state.model_state, features, labels, mask,
                     rngs,
                 )
                 return loss_sum / jnp.maximum(weight, 1.0), (
-                    new_model_state, routing
+                    new_model_state, counters
                 )
 
-            (loss, (new_model_state, routing)), grads = jax.value_and_grad(
+            (loss, (new_model_state, counters)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True
             )(state.params)
             new_state, loss = _apply_update(
                 state, grads, loss, new_model_state
             )
-            return finish(new_state, loss, grads, routing)
+            return finish(new_state, loss, grads, counters)
 
         k = int(grad_accum_steps)
 
@@ -238,9 +256,10 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
             grads_acc, loss_acc, weight_acc, model_state, i = carry
             m_features, m_labels, m_mask = micro_slice
             micro_rngs = {
-                "dropout": jax.random.fold_in(rngs["dropout"], i)
+                name: jax.random.fold_in(key, i)
+                for name, key in rngs.items()
             }
-            (loss_sum, (weight, model_state, routing)), grads = grad_fn(
+            (loss_sum, (weight, model_state, counters)), grads = grad_fn(
                 state.params, model_state, m_features, m_labels, m_mask,
                 micro_rngs,
             )
@@ -255,9 +274,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 weight_acc + weight,
                 model_state,
                 i + 1,
-            ), routing
+            ), counters
 
-        (grads_sum, loss_sum, weight, new_model_state, _), routing = (
+        (grads_sum, loss_sum, weight, new_model_state, _), counters = (
             jax.lax.scan(
                 body,
                 (zero_grads, 0.0, 0.0, state.model_state, 0),
@@ -272,8 +291,8 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
             state, grads, loss_sum / weight, new_model_state
         )
         # the counters of the last microbatch stand for the step
-        routing = jax.tree_util.tree_map(lambda leaf: leaf[-1], routing)
-        return finish(new_state, loss, grads, routing)
+        counters = jax.tree_util.tree_map(lambda leaf: leaf[-1], counters)
+        return finish(new_state, loss, grads, counters)
 
     return train_step
 

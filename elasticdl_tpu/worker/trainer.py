@@ -52,6 +52,9 @@ class JaxTrainer:
         # an MoE model's routing counters of the newest step, still on
         # the device (train/step_fns.py); None for any other model
         self.routing = None
+        # a block-diffusion model's noise facts of the newest step, the
+        # same way
+        self.noise = None
         compute_dtype = resolve_dtype(compute_dtype)
         # recompile sentinels (ISSUE 18): instrumented_jit IS jax.jit
         # when EDL_DEVICE_OBS=0; on, each compile is counted, timed,
@@ -109,6 +112,7 @@ class JaxTrainer:
         with phase("dispatch"):
             state, loss, scalars = self._train_step(state, batch)
         self.routing = scalars.get("routing")
+        self.noise = scalars.get("noise")
         # one small host transfer per batch; a skip-sentinel batch
         # already kept its state in-graph (nothing else to drop on
         # the dense path — there is no PS push); halt raises

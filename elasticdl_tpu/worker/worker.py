@@ -828,12 +828,16 @@ class Worker:
             # device value and waits for the step; after JaxTrainer's
             # health fetch the value is already on the host
             routing = getattr(self.trainer, "routing", None)
+            noise = getattr(self.trainer, "noise", None)
             with phase("device_wait"):
                 loss_value = float(loss)
                 if routing:
                     # the expert layers' counters come with the loss,
                     # on the steps that log and on no other
                     routing = {k: float(v) for k, v in routing.items()}
+                if noise:
+                    # and a block-diffusion step's noise facts
+                    noise = {k: float(v) for k, v in noise.items()}
             with phase("log"):
                 logger.info(
                     "step %d loss %.6f", self._version, loss_value
@@ -854,6 +858,8 @@ class Worker:
                             ("bias_abs_max", "bias_abs_max"),
                             ("held", "held_pairs")) if k in routing},
                     )
+                if noise:
+                    events.emit("bd_noise", step=self._version, **noise)
         with phase("callbacks"):
             for cb in self._callbacks:
                 cb.on_batch_end(self._version, loss)

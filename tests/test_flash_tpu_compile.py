@@ -125,6 +125,33 @@ def test_grouped_query_heads_compile_without_a_copy_of_k_or_v(
     assert "f32[%d,%d,%d]" % (batch * heads, seq, dim) in hlo
 
 
+@pytest.mark.parametrize("half_len,block,blocks", [
+    (8192, 4, (None, None)),   # sdar30b-bd-s8k: 1024 x 1024 tiles
+    (8192, 4, (512, 512)),     # tiles under the default
+    (6144, 6, (None, None)),   # a block length that is no power of two
+], ids=["the-cell", "512-512", "blocks-of-6"])
+def test_the_block_diffusion_layout_compiles(chip, half_len, block, blocks):
+    """The mask as a layout (PR 35) under Mosaic, which interpret mode
+    cannot stand in for (a select between two boolean vectors was
+    refused here: ``BlockDiffusion.keep`` compares integers): 32 query
+    heads of 128 over 4 kv heads, 2 x ``half_len`` positions, one
+    forward and one fused backward."""
+    q = jax.ShapeDtypeStruct(
+        (1, 32, 2 * half_len, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct(
+        (1, 4, 2 * half_len, 128), jnp.bfloat16, sharding=chip)
+    layout = F.BlockDiffusion(half_len, block)
+
+    def loss(q, k, v):
+        out = F.flash_attention(
+            q, k, v, mask=layout, block_q=blocks[0], block_k=blocks[1])
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert device_obs.pallas_kernels(hlo) == {"flash_fwd": 1, "flash_bwd": 1}
+
+
 def _square_float32_dots(hlo, size=64):
     """The dots of a compiled program whose operands and result are all
     float32 [..., size, size]: the product form's and its VJP's."""

@@ -22,16 +22,6 @@ from elasticdl_tpu.ops import moe as moe_ops
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _reference():
-    path = os.path.join(
-        REPO, "benchmark", "configs", "qwen3-next-80b-a3b-1chip",
-        "reference.py")
-    spec = importlib.util.spec_from_file_location("qwen3next_ref", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
 def test_sort_held_groups_the_held_pairs_and_counts_all_loads():
     experts = jnp.asarray(
         [[0, 5], [6, 1], [5, 7], [2, 5], [4, 6], [5, 4]], jnp.int32)
@@ -94,12 +84,6 @@ def test_dispatch_and_combine_are_each_other_s_transpose():
                            x.shape), atol=1e-6)
 
 
-CONFIG = {
-    "num_experts_per_tok": 3, "norm_topk_prob": True,
-    "published": {"num_experts": 16},
-}
-
-
 def _layer(held, rows=64, **kw):
     return MoeMlp(
         16, top_k=3, dispatch_impl="sorted", expert_dim=8,
@@ -107,34 +91,72 @@ def _layer(held, rows=64, **kw):
         shared_gate=True, held_experts=held, held_rows=rows, **kw)
 
 
-def test_the_sixteen_shares_add_up_to_the_uncut_layer():
-    """16 experts over 16 / 4 / 2 chips: each chip's ``MoeMlp`` holds its
-    own experts' kernels (rows of ONE seeded stack), routes over all 16
-    and returns its part; the parts, the shared expert counted once, sum
-    to what the uncut reference gives for the whole layer."""
-    ref = _reference()
+def _reference_of(configuration):
+    path = os.path.join(
+        REPO, "benchmark", "configs", configuration, "reference.py")
+    spec = importlib.util.spec_from_file_location(
+        configuration.replace("-", "_") + "_ref", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# (the configuration whose reference gives the uncut layer, experts,
+# top-k, shared experts behind a gate, the ways the layer is shared)
+SHARE_CASES = {
+    # Qwen3-Next's layer at a small size: 16 experts, top-3, one
+    # shared expert behind its gate, over 16 / 4 / 2 chips
+    "qwen3next-16-top3-shared": (
+        "qwen3-next-80b-a3b-1chip", 16, 3, 1, (16, 4, 2)),
+    # SDAR's layer at its published counts: 128 experts, top-8, no
+    # shared expert, the deployment's eight shares (16 experts a chip)
+    "sdar-128-top8-eight-shares": (
+        "sdar-30b-a3b-1chip", 128, 8, 0, (8,)),
+}
+
+
+@pytest.mark.parametrize(
+    "case", list(SHARE_CASES.values()), ids=list(SHARE_CASES))
+def test_the_sixteen_shares_add_up_to_the_uncut_layer(case):
+    """Each chip's ``MoeMlp`` holds its own experts' kernels (rows of
+    ONE seeded stack), routes over all the experts and returns its
+    part; the parts, a shared expert counted once, sum to what the
+    uncut reference gives for the whole layer."""
+    configuration, experts, top_k, shared_experts, ways = case
+    ref = _reference_of(configuration)
+    config = {"num_experts_per_tok": top_k, "norm_topk_prob": True,
+              "published": {"num_experts": experts}}
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 16))
-    whole = _layer(None)
+    flat = x.reshape(24, 16)
+
+    def layer(held):
+        return MoeMlp(
+            experts, top_k=top_k, dispatch_impl="sorted", expert_dim=8,
+            expert_act="swiglu", normalize_gates=True,
+            shared_experts=shared_experts, shared_gate=bool(shared_experts),
+            held_experts=held, held_rows=24 * top_k)
+
+    whole = layer(None)
     params = whole.init(jax.random.PRNGKey(1), x)["params"]
-    assert params["w_gate"].shape == (16, 16, 8)
+    assert params["w_gate"].shape == (experts, 16, 8)
     want, want_balance, _ = ref.expert_layer(
-        x.reshape(24, 16), params, CONFIG, (0, 16))
+        flat, params, config, (0, experts))
     got, aux = whole.apply({"params": params}, x)
     np.testing.assert_allclose(got.reshape(24, 16), want, atol=1e-5)
-    shared = ref.shared_expert(x.reshape(24, 16), params)
-    for chips in (16, 4, 2):
-        count = 16 // chips
+    shared = ref.shared_expert(flat, params) if shared_experts else 0.0
+    for chips in ways:
+        count = experts // chips
         total = 0.0
         for chip in range(chips):
             first = chip * count
             mine = dict(params, **{
                 name: params[name][first:first + count]
                 for name in ("w_gate", "w_up", "w_down")})
-            part, part_aux = _layer((first, count)).apply(
+            part, part_aux = layer((first, count)).apply(
                 {"params": mine}, x)
             # the reference is given the same share
             ref_part, _, _ = ref.expert_layer(
-                x.reshape(24, 16), mine, CONFIG, (first, count))
+                flat, mine, config, (first, count))
             np.testing.assert_allclose(
                 part.reshape(24, 16), ref_part, atol=1e-5)
             # every chip sees every expert's load and the same loss
