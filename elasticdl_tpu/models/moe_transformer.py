@@ -50,17 +50,21 @@ logger = _logger_factory("elasticdl_tpu.models.moe_transformer")
 
 @functools.lru_cache(maxsize=None)
 def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
-                       act, scoring, shared, held=""):
+                       act, scoring, shared, held="", run=""):
     """One line per distinct expert layer shape (this runs at trace
     time), beside the compile ledger's line of the step, as
     ``ops/attention.py`` names the attention it resolved to. ``held``:
     what a layer that holds a share of its experts adds (`` held=0-31
-    rows=65536 shared_gate=sigmoid``)."""
+    rows=65536 shared_gate=sigmoid``), and ``run`` after the line's
+    bracket what it runs of that buffer (``; a step runs the rows that
+    carry a held pair, up to rows=65536``: written at trace time, the
+    line can name no count; the ``moe_routing`` event's
+    ``held_rows_run`` does)."""
     logger.info(
         "moe dispatch resolved to %s (tokens=%d experts=%d top_k=%d "
-        "expert_width=%d act=%s score=%s shared=%d%s, experts' matmul=%s)",
+        "expert_width=%d act=%s score=%s shared=%d%s, experts' matmul=%s)%s",
         impl, tokens, num_experts, top_k, width, act, scoring, shared,
-        held, matmul,
+        held, matmul, run,
     )
 
 
@@ -136,8 +140,15 @@ class MoeMlp(nn.Module):
     the pairs whose expert lives here are sorted and gathered into a
     buffer of ``held_rows`` rows (static; a held pair without a row is
     counted in ``routing["dropped"]``), and the combine adds this
-    chip's experts' part and nothing for the absent ones. No exchange,
-    and nothing that stands in for one. ``shared_gate``: the shared
+    chip's experts' part and nothing for the absent ones. The buffer's
+    size is memory and shapes only: a step RUNS the rows that carry a
+    pair (the grouped matmuls to the last held row tile, the gathers
+    and scatter-adds to the last chunk that holds one;
+    ``routing["rows_run"]`` of ``routing["rows_buffer"]``), and a row
+    past them may hold anything, a NaN too: ``hidden`` and ``out`` are
+    read only through ``valid`` (``ops/moe.py:combine_held``, and
+    ``dispatch_held`` for the gradient). No exchange, and nothing that
+    stands in for one. ``shared_gate``: the shared
     experts' output passes ``sigmoid(x . w)``, a gate of one output
     (``shared_expert_gate``).
     """
@@ -213,7 +224,7 @@ class MoeMlp(nn.Module):
         weights = self._weights(dim, x.dtype)
         one_device = self.mesh is None or self.mesh.size == 1
         rows, width = groups * seq * self.top_k, weights[0].shape[-1]
-        held = ""
+        held = run = ""
         if self.held_experts is not None:
             if impl != "sorted" or not self.held_rows:
                 raise ValueError(
@@ -226,6 +237,8 @@ class MoeMlp(nn.Module):
                     % (self.held_experts, self.num_experts))
             rows = min(self.held_rows, rows)
             held = " held=%d-%d rows=%d" % (first, first + count - 1, rows)
+            run = ("; a step runs the rows that carry a held pair, up to "
+                   "rows=%d" % rows)
         if self.shared_gate:
             held += " shared_gate=sigmoid"
         matmul = moe_ops.resolve_grouped_matmul(
@@ -233,7 +246,7 @@ class MoeMlp(nn.Module):
         _log_dispatch_once(
             "sorted" if impl == "sorted" else "onehot", matmul,
             groups * seq, self.num_experts, self.top_k, width,
-            self.expert_act, self.scoring, self.shared_experts, held,
+            self.expert_act, self.scoring, self.shared_experts, held, run,
         )
         if matmul == "pallas_gmm":
             _log_tiles_once(
@@ -342,7 +355,9 @@ class MoeMlp(nn.Module):
                  share["dropped"]) = moe_ops.sort_held(
                     experts, self.num_experts, *self.held_experts,
                     self.held_rows)
-                rows = moe_ops.dispatch_held(tokens, pairs, self.top_k)
+                share["buffer_rows"] = pairs.shape[0]
+                rows = moe_ops.dispatch_held(
+                    tokens, pairs, valid, self.top_k)
         with jax.named_scope("moe/experts"):
             hidden = [
                 moe_ops.grouped_matmul(rows, w, group_sizes, one_device)
@@ -482,6 +497,9 @@ def merge_routing(layers):
         # the pairs of the layer whose held experts got the most: what
         # the row buffer has to hold
         merged["held"] = jnp.stack([r["held"] for r in layers]).max()
+        # and of all the layers' buffers' rows, those the step ran
+        for name in ("rows_run", "rows_buffer"):
+            merged[name] = jnp.stack([r[name] for r in layers]).sum()
     return merged
 
 

@@ -32,9 +32,10 @@ experts themselves do not need. One device's tokens only: there is no
 of expert parallelism is its first half (``sort_held``,
 ``dispatch_held``, ``combine_held``): a layer that holds a stated share
 of the experts routes over all of them, gives only the pairs of its own
-experts a row (in a buffer of a static size) and returns its own
-experts' part of the result; nothing stands in for the absent chips or
-their traffic.
+experts a row (in a buffer of a static size, of which a step runs the
+rows that carry a pair and no more) and returns its own experts' part
+of the result; nothing stands in for the absent chips or their
+traffic.
 
 Everything is shape-static and jit-friendly: k is a Python int, the
 sorted path's only data-dependent quantity is ``group_sizes``, an
@@ -233,10 +234,13 @@ def sort_held(experts, num_experts, first, count, buffer_rows):
     - ``pairs`` (buffer_rows,): the pair at each row, held pairs first,
       grouped by expert, a group's tokens in order;
     - ``valid`` (buffer_rows,) bool: the row carries a held pair;
-    - ``group_sizes`` (count,) int32 summing to ``buffer_rows``: the
-      rows of each held expert, the rows past the last held pair
-      counted to the last expert (the grouped matmul computes whole
-      buffers; their result is never added: ``combine_held``);
+    - ``group_sizes`` (count,) int32: the rows of each held expert,
+      summing to ``min(held, buffer_rows)``, the rows that carry a
+      pair, and NOT to the buffer. The layer's work follows them: the
+      grouped matmul visits no row tile past the last held row, and
+      what a row past it holds is whatever the memory held (on a TPU;
+      zeros from ``ragged_dot``). Every exit of the buffer selects
+      with ``valid`` (``dispatch_held``'s transpose, ``combine_held``);
     - ``loads`` (num_experts,) int32: pairs per expert over all
       experts, what the balance loss and the counters read;
     - ``held``, ``dropped`` (int32 scalars): the pairs whose expert
@@ -261,32 +265,192 @@ def sort_held(experts, num_experts, first, count, buffer_rows):
     held = sizes.sum()
     ends = jnp.minimum(jnp.cumsum(sizes), buffer_rows)
     valid = jnp.arange(buffer_rows) < ends[-1]
-    ends = ends.at[-1].set(buffer_rows)
     group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
     dropped = jnp.maximum(held - buffer_rows, 0)
     return checkpoint_name(
         (pairs, valid, group_sizes, loads, held, dropped), MOE_ROUTE_NAME)
 
 
-def dispatch_held(x, pairs, k):
+# What a held layer runs of its buffer follows the step's held pairs,
+# each stage in the way it won alone on the chip (PERF.md, PR 36).
+# The dispatch's gather runs chunks of this many rows, as many as hold a
+# pair (a loop with a traced trip count: a gather costs its rows).
+HELD_CHUNK_ROWS = 4096
+# The two scatter-adds run a PREFIX of the buffer, the shortest of this
+# many equal steps that holds every held pair (a ``lax.switch`` over the
+# static lengths: XLA's scatter-add costs 0.6 ms a call before its first
+# row, so chunks lost there): eight for the combine's, four for the
+# dispatch's transpose. XLA's rematerialisation counts a conditional as
+# if every branch ran (it sums the branches' peaks, their operands
+# included), and in the backward of a rematerialised block, where a
+# step's memory peaks, eight branches made it recompute 800 MB
+# projections it had room for.
+HELD_PREFIXES = 8
+HELD_BACKWARD_PREFIXES = 4
+
+
+def held_chunk_rows(buffer_rows):
+    """The rows of one chunk of a ``buffer_rows`` buffer:
+    ``HELD_CHUNK_ROWS`` where that divides it, else the buffer whole."""
+    if buffer_rows % HELD_CHUNK_ROWS:
+        return buffer_rows
+    return HELD_CHUNK_ROWS
+
+
+def held_prefixes(buffer_rows, steps):
+    """The static prefix lengths of a ``buffer_rows`` buffer: ``steps``
+    equal steps up to the buffer, or the buffer alone where ``steps``
+    does not divide it."""
+    if buffer_rows % steps:
+        return (buffer_rows,)
+    step = buffer_rows // steps
+    return tuple(range(step, buffer_rows + 1, step))
+
+
+def _prefix_index(filled, lengths):
+    """Which of ``lengths`` (``held_prefixes``) holds ``filled`` rows
+    (the first where there are none)."""
+    return jnp.clip(-(-filled // lengths[0]) - 1, 0, len(lengths) - 1)
+
+
+def rows_run(held, buffer_rows):
+    """The rows of a ``buffer_rows`` buffer that a layer with ``held``
+    pairs runs: the prefix its combine's scatter-add visits, an eighth
+    of the buffer a step (the dispatch's gather stops at the last chunk
+    of ``held_chunk_rows`` that holds a pair, the grouped matmuls at
+    the last row tile, the dispatch's transpose at the next quarter of
+    the buffer; the combine's backward gathers the whole buffer)."""
+    lengths = held_prefixes(buffer_rows, HELD_PREFIXES)
+    return jnp.asarray(lengths, jnp.int32)[_prefix_index(held, lengths)]
+
+
+def _over_held_prefix(valid, lengths, fn, *operands):
+    """``fn(n, *operands)`` at the one of the prefix ``lengths`` that
+    holds every row with a pair: one branch a length, the step picks."""
+    return jax.lax.switch(
+        _prefix_index(jnp.sum(valid, dtype=jnp.int32), lengths),
+        [functools.partial(fn, n) for n in lengths], *operands)
+
+
+# Each of the four permutes is jitted on its own: the layers of a model
+# share its trace and its lowering (a scatter-add's branches are traced
+# once a shape and not once a layer); always inside the step's own
+# trace, where the recompile sentinel's host bookkeeping cannot run.
+# What they read of this module's constants reaches them as a static
+# argument.
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit
+    jax.jit, static_argnums=(3, 4))
+def _gather_held(x, pairs, valid, k, chunk):
+    def gather(i, buffer):
+        at = jax.lax.dynamic_slice_in_dim(pairs, i * chunk, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            buffer, jnp.take(x, at // k, axis=0), i * chunk, 0)
+
+    # a loop whose trip count the step decides: nothing differentiates
+    # through it (this is a custom VJP's forward)
+    return jax.lax.fori_loop(
+        0, -(-jnp.sum(valid, dtype=jnp.int32) // chunk), gather,
+        jnp.zeros((pairs.shape[0], x.shape[1]), x.dtype))
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as above)
+    jax.jit, static_argnums=(3, 4, 5))
+def _scatter_held(d_rows, pairs, valid, k, tokens, lengths):
+    def scatter(n, d_rows, pairs, valid):
+        kept = jnp.where(valid[:n, None], d_rows[:n], 0)
+        return jnp.zeros((tokens, d_rows.shape[1]), d_rows.dtype).at[
+            pairs[:n] // k].add(kept)
+
+    return _over_held_prefix(valid, lengths, scatter, d_rows, pairs, valid)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def dispatch_held(x, pairs, valid, k):
     """x (T, M) -> (buffer_rows, M): row r is the token of pair
-    ``pairs[r]``. A gather; its transpose is a scatter-add of
-    ``buffer_rows`` rows (a sixteenth of the pairs where a sixteenth
-    of the experts is held), where the dropless path's custom VJPs
-    gather through all T x k."""
-    return jnp.take(x, pairs // k, axis=0)
+    ``pairs[r]``. A gather that stops at the last chunk that holds a
+    pair (the rows past it are zeros; a spare row before it holds the
+    token of some pair of an absent expert). Its transpose is a
+    scatter-add of the rows ``valid`` selects, over the prefix (in
+    quarters of the buffer) that holds them: the incoming gradient's
+    spare rows may hold anything (the grouped matmuls write no row
+    past the last held tile)."""
+    return _gather_held(x, pairs, valid, k, held_chunk_rows(pairs.shape[0]))
 
 
+def _dispatch_held_fwd(x, pairs, valid, k):
+    return dispatch_held(x, pairs, valid, k), (pairs, valid, x.shape[0])
+
+
+def _dispatch_held_bwd(k, res, d_rows):
+    pairs, valid, tokens = res
+    lengths = held_prefixes(pairs.shape[0], HELD_BACKWARD_PREFIXES)
+    return _scatter_held(d_rows, pairs, valid, k, tokens, lengths), None, None
+
+
+dispatch_held.defvjp(_dispatch_held_fwd, _dispatch_held_bwd)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as above)
+    jax.jit, static_argnums=(4,))
+def _combine_held(rows, gates, pairs, valid, lengths):
+    tokens, k = gates.shape
+
+    def scatter(n, rows, flat, pairs, valid):
+        weighted = (
+            rows[:n].astype(jnp.float32) * jnp.take(flat, pairs[:n])[:, None])
+        kept = jnp.where(valid[:n, None], weighted, 0)
+        return jnp.zeros((tokens, rows.shape[1]), jnp.float32).at[
+            pairs[:n] // k].add(kept)
+
+    y = _over_held_prefix(
+        valid, lengths, scatter, rows, gates.reshape(-1), pairs, valid)
+    return y.astype(rows.dtype)
+
+
+@jax.jit  # edlint: disable=obs-bare-jit (as above)
+def _combine_held_grads(rows, gates, pairs, valid, dy):
+    """d_rows[r] = gate(pairs[r]) x dy[token(pairs[r])] and d_gates at
+    pair ``pairs[r]`` = <dy[token], rows[r]>, both from ONE bfloat16
+    gather of ``dy`` over the whole buffer, in float32 as autodiff of
+    the forward has them (which gathers a float32 ``dy``, at twice the
+    time). ``valid`` selects: a spare row of ``rows`` may hold a NaN."""
+    k = gates.shape[1]
+    flat = gates.reshape(-1)
+    back = jnp.take(dy, pairs // k, axis=0).astype(jnp.float32)
+    d_rows = jnp.where(
+        valid[:, None], back * jnp.take(flat, pairs)[:, None], 0)
+    d_gate = jnp.where(
+        valid, jnp.sum(back * rows.astype(jnp.float32), axis=-1), 0)
+    # a row's pair is its own: nothing is added twice
+    d_flat = jnp.zeros_like(flat).at[pairs].add(d_gate.astype(flat.dtype))
+    return d_rows.astype(rows.dtype), d_flat.reshape(gates.shape)
+
+
+@jax.custom_vjp
 def combine_held(rows, gates, pairs, valid):
     """rows (buffer_rows, M), gates (T, k) -> (T, M): each token's held
     experts' outputs under their gates, summed in float32 and rounded
     once; nothing for a pair whose expert lives elsewhere, and nothing
-    from a row that carries no pair."""
-    tokens, k = gates.shape
-    gate_of = jnp.where(valid, jnp.take(gates.reshape(-1), pairs), 0.0)
-    y = jnp.zeros((tokens, rows.shape[-1]), jnp.float32).at[pairs // k].add(
-        rows.astype(jnp.float32) * gate_of[:, None])
-    return y.astype(rows.dtype)
+    from a row that carries no pair: ``valid`` SELECTS the products (a
+    spare row may hold a NaN, and 0 x NaN is NaN), over the prefix of
+    the buffer that holds the pairs. The rows' gradient is zero in
+    every spare row (``_combine_held_grads``)."""
+    return _combine_held(
+        rows, gates, pairs, valid,
+        held_prefixes(rows.shape[0], HELD_PREFIXES))
+
+
+def _combine_held_fwd(rows, gates, pairs, valid):
+    return combine_held(rows, gates, pairs, valid), (rows, gates, pairs, valid)
+
+
+def _combine_held_bwd(res, dy):
+    return _combine_held_grads(*res, dy) + (None, None)
+
+
+combine_held.defvjp(_combine_held_fwd, _combine_held_bwd)
 
 
 @jax.custom_vjp
@@ -618,15 +782,18 @@ def router_z_loss(router_logits):
     return jnp.mean(jnp.square(z))
 
 
-def routing_stats(probs, group_sizes, k, held=None, dropped=None):
+def routing_stats(probs, group_sizes, k, held=None, dropped=None,
+                  buffer_rows=None):
     """What the ``moe_routing`` journal event reports of one expert
     layer, as device scalars: pairs per expert (largest and mean, over
     ALL experts), the router's mean entropy in nats, and the pairs that
     reached no expert (counted from the group sizes; the sorted path
     drops none). A layer that holds a share of the experts
     (``sort_held``) also reports ``held``, the pairs whose expert lives
-    here, and its ``dropped`` are those of them its buffer had no row
-    for."""
+    here, its ``dropped`` are those of them its buffer had no row for,
+    and of the ``rows_buffer`` rows of its buffer (``buffer_rows``) the
+    ``rows_run`` that this step ran (``rows_run``: the prefix that
+    holds the pairs)."""
     tokens = probs.shape[0]
     entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1).mean()
     stats = {
@@ -639,4 +806,6 @@ def routing_stats(probs, group_sizes, k, held=None, dropped=None):
     }
     if held is not None:
         stats["held"] = held.astype(jnp.float32)
+        stats["rows_run"] = rows_run(held, buffer_rows).astype(jnp.float32)
+        stats["rows_buffer"] = jnp.float32(buffer_rows)
     return stats

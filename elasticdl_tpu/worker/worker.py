@@ -106,6 +106,27 @@ class _BatchPoller:
         return item, False
 
 
+def emit_moe_routing(step, routing):
+    """The ``moe_routing`` journal event from a step's merged routing
+    counters (host floats). Only a layer that keeps a balancing bias
+    (sigmoid scoring) reports its magnitude, and only one that holds a
+    share of its experts the pairs that share got, the rows of all its
+    layers' buffers and those of them the step ran."""
+    events.emit(
+        "moe_routing",
+        step=step,
+        tokens_per_expert_max=routing["load_max"],
+        tokens_per_expert_mean=routing["load_mean"],
+        router_entropy=routing["entropy"],
+        dropped_pairs=routing["dropped"],
+        **{name: routing[k] for k, name in (
+            ("bias_abs_max", "bias_abs_max"),
+            ("held", "held_pairs"),
+            ("rows_run", "held_rows_run"),
+            ("rows_buffer", "held_rows_buffer")) if k in routing},
+    )
+
+
 class Worker:
     def __init__(
         self,
@@ -843,21 +864,7 @@ class Worker:
                     "step %d loss %.6f", self._version, loss_value
                 )
                 if routing:
-                    events.emit(
-                        "moe_routing",
-                        step=self._version,
-                        tokens_per_expert_max=routing["load_max"],
-                        tokens_per_expert_mean=routing["load_mean"],
-                        router_entropy=routing["entropy"],
-                        dropped_pairs=routing["dropped"],
-                        # only a layer that keeps a balancing bias
-                        # (sigmoid scoring) reports its magnitude, and
-                        # only one that holds a share of its experts
-                        # the pairs that share got
-                        **{name: routing[k] for k, name in (
-                            ("bias_abs_max", "bias_abs_max"),
-                            ("held", "held_pairs")) if k in routing},
-                    )
+                    emit_moe_routing(self._version, routing)
                 if noise:
                     events.emit("bd_noise", step=self._version, **noise)
         with phase("callbacks"):

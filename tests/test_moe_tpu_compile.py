@@ -5,7 +5,10 @@ grouped matmuls must be accepted by the chip's compiler (the tiles
 ``ops/moe.py:gmm_tiles`` chose inside its VMEM, no unaligned slice), a
 tile its byte count calls too large must be refused by the compiler
 too, the Pallas one must be what a TPU backend gets, and the compiled
-expert layer must hold no capacity and no one-hot.
+expert layer must hold no capacity and no one-hot. And a layer that
+holds a share of its experts (``sdar30b-bd-s8k``'s, at its real size)
+must compile with the loop and the branches that let a step run the
+rows that carry a pair.
 
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
@@ -104,6 +107,54 @@ def test_pallas_grouped_matmul_is_what_a_tpu_backend_compiles(
     assert "[%d,%d]" % (width, rows) not in hlo
     if model == "olmoe":
         assert_no_capacity(hlo)
+
+
+def test_a_held_layer_compiles_with_its_loop_and_its_prefixes(
+        chip, monkeypatch):
+    """``sdar30b-bd-s8k``'s expert layer at its real size (16,384
+    positions, 128 experts top-8, 16 held of width 768, a buffer of
+    49,152 rows): the chip's compiler takes the dispatch's gather as a
+    loop with a trip count the step decides, the two scatter-adds as
+    branches over the buffer's eighths and quarters, and the same nine
+    kernels."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = MoeMlp(
+        128, top_k=8, dispatch_impl="sorted", expert_dim=768,
+        expert_act="swiglu", normalize_gates=True, held_experts=(0, 16),
+        held_rows=49152)
+    x = jax.ShapeDtypeStruct((1, 16384, DIM), jnp.bfloat16, sharding=chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(
+            x.shape, x.dtype)))["params"]
+    assert params["w_gate"].shape == (16, DIM, 768)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=chip),
+        params)
+
+    def loss(params, x):
+        y, aux = layer.apply({"params": params}, x)
+        return (y.astype(jnp.float32) ** 2).mean() + aux["load_balancing"]
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    names = kernels(hlo)
+    assert len(names) == 9, names
+    assert all("moe/experts" in n and "gmm" in n for n in names), names
+    # the combine's scatter-add and the dispatch's transpose: a branch
+    # for each eighth, and for each quarter, of the buffer
+    branches = {
+        re.search(r"moe/(dispatch|combine)", line).group(0): len(
+            re.search(r"branch_computations=\{([^}]*)\}", line).group(1)
+            .split(","))
+        for line in hlo.splitlines()
+        if " conditional(" in line and "branch_computations" in line}
+    assert branches == {
+        "moe/combine": moe_ops.HELD_PREFIXES,
+        "moe/dispatch": moe_ops.HELD_BACKWARD_PREFIXES}, branches
+    # and the gather's loop under the dispatch's scope
+    assert any(
+        " while(" in line and "moe/dispatch" in line
+        for line in hlo.splitlines())
 
 
 def test_a_tile_the_byte_count_refuses_the_compiler_refuses_too(chip):
