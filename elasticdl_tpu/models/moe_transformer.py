@@ -30,11 +30,16 @@ from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.models.transformer import (
     HIDDEN_SPEC,
     RESIDUAL_SPEC,
+    STREAMS_SPEC,
     Block,
     GatedDeltaDims,
+    HyperConnection,
+    HyperDims,
     LatentDims,
+    YarnScaling,
     make_attention,
     make_norm,
+    merge_hyper_facts,
     remat_block,
 )
 from elasticdl_tpu.ops import block_diffusion, flash_attention
@@ -431,14 +436,18 @@ class MoeBlock(nn.Module):
     # the attention mask's layout where it is not causal, and with it
     # the call's ``positions`` (``Attention``'s own of those names)
     mask: Optional[Any] = None
+    # ``Block``'s fields of these names: YaRN for a latent mixer, and
+    # the hyper-connected residual path (``x`` the n streams; the
+    # block's facts under ``aux["mhc"]``)
+    rope_scaling: Optional[YarnScaling] = None
+    hc: Optional[HyperDims] = None
+    layer_index: int = 0
 
     @nn.compact
     def __call__(self, x, training=False, positions=None):
-        x = constrain(x, self.mesh, RESIDUAL_SPEC)
-        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
         # only ``Attention`` takes the rows' positions
         where = () if positions is None else (positions,)
-        x = x + make_attention(
+        attention = make_attention(
             self.num_heads,
             self.latent,
             self.linear,
@@ -453,9 +462,9 @@ class MoeBlock(nn.Module):
             rotary_dim=self.rotary_dim,
             output_gate=self.output_gate,
             mask=self.mask,
-        )(h, training, *where)
-        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
-        y, aux = MoeMlp(
+            rope_scaling=self.rope_scaling,
+        )
+        experts = MoeMlp(
             self.num_experts,
             mlp_ratio=self.mlp_ratio,
             top_k=self.top_k,
@@ -474,7 +483,25 @@ class MoeBlock(nn.Module):
             held_rows=self.held_rows,
             shared_gate=self.shared_gate,
             name="moe_mlp",
-        )(h, training)
+        )
+        if self.hc is not None:
+            x = constrain(x, self.mesh, STREAMS_SPEC)
+            u, write, attn_facts = HyperConnection(
+                self.hc, 2 * self.layer_index, name="hc_attn")(x)
+            x = write(attention(
+                make_norm(self.norm, self.norm_eps, "ln_attn")(u),
+                training, *where))
+            u, write, mlp_facts = HyperConnection(
+                self.hc, 2 * self.layer_index + 1, name="hc_mlp")(x)
+            y, aux = experts(
+                make_norm(self.norm, self.norm_eps, "ln_mlp")(u), training)
+            aux["mhc"] = merge_hyper_facts([attn_facts, mlp_facts])
+            return constrain(write(y), self.mesh, STREAMS_SPEC), aux
+        x = constrain(x, self.mesh, RESIDUAL_SPEC)
+        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
+        x = x + attention(h, training, *where)
+        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
+        y, aux = experts(h, training)
         return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
 
 
@@ -616,6 +643,12 @@ class MoeTransformerLM(nn.Module):
     bd_block: int = 4
     bd_mask_id: Optional[int] = None
     bd_t_min: float = 1e-3
+    # a latent mixer's YaRN, the hyper-connected residual path and the
+    # multi-token-prediction module (depth 0 or 1) with its loss weight
+    rope_scaling: Optional[YarnScaling] = None
+    hc: Optional[HyperDims] = None
+    mtp_layers: int = 0
+    mtp_loss_weight: float = 0.1
 
     def _block_diffusion_inputs(self, tokens, training, noisy, weights):
         """``(inputs (B, 2 L), positions, mask layout, weights, the
@@ -664,6 +697,17 @@ class MoeTransformerLM(nn.Module):
                 "objective must be 'next_token' or 'block_diffusion', "
                 "got %r" % (self.objective,))
         denoise = self.objective == "block_diffusion"
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                "mtp_layers=%r: one prediction module or none"
+                % (self.mtp_layers,))
+        for what, asked in (("hyper-connections (hc)", self.hc is not None),
+                            ("the prediction module (mtp_layers)",
+                             bool(self.mtp_layers))):
+            if asked and (denoise or self.linear is not None):
+                raise ValueError(
+                    "%s under objective=\"block_diffusion\" or beside a "
+                    "linear mixer (linear): not built, so not run" % what)
         tokens = tokens.astype(jnp.int32)
         positions = layout = facts = None
         if denoise:
@@ -675,9 +719,17 @@ class MoeTransformerLM(nn.Module):
         embed_init = (
             {} if self.embed_init_std is None else
             {"embedding_init": nn.initializers.normal(self.embed_init_std)})
-        x = nn.Embed(
-            self.vocab_size, self.embed_dim, name="wte", **embed_init
-        )(tokens)
+        embed = nn.Embed(
+            self.vocab_size, self.embed_dim, name="wte", **embed_init)
+
+        def expand_streams(x):
+            """Expand by copy: every stream starts as ``x``."""
+            if self.hc is None:
+                return x
+            return jnp.broadcast_to(
+                x[:, None], (x.shape[0], self.hc.streams) + x.shape[1:])
+
+        x = expand_streams(embed(tokens))
         wrap = (
             functools.partial(
                 remat_block, remat_policy=self.remat_policy,
@@ -694,6 +746,8 @@ class MoeTransformerLM(nn.Module):
             qk_norm=self.qk_norm,
             rope_theta=self.rope_theta,
             latent=self.latent,
+            rope_scaling=self.rope_scaling,
+            hc=self.hc,
         )
         kinds = tuple(self.layer_kinds or ("full",))
         if set(kinds) - {"full", "linear"} or (
@@ -711,38 +765,51 @@ class MoeTransformerLM(nn.Module):
             mask=layout,
         )
         balance = z_loss = jnp.float32(0.0)
-        routing = []
+        routing, mhc = [], []
+
+        def expert_block(name, index, linear=None):
+            return wrap(MoeBlock)(
+                self.num_heads,
+                self.num_experts,
+                top_k=self.top_k,
+                capacity_factor=self.capacity_factor,
+                dispatch_impl=self.dispatch_impl,
+                expert_dim=self.expert_dim,
+                expert_act=self.expert_act,
+                normalize_gates=self.normalize_gates,
+                scoring=self.scoring,
+                gate_scale=self.gate_scale,
+                bias_update_speed=self.bias_update_speed,
+                seq_aux=self.seq_aux,
+                shared_experts=self.shared_experts,
+                held_experts=self.held_experts,
+                held_rows=self.held_rows,
+                shared_gate=self.shared_gate,
+                linear=linear,
+                layer_index=index,
+                name=name,
+                **shared,
+                **mixer,
+            )
+
+        def count(aux):
+            """An expert block's losses and facts into the model's."""
+            nonlocal balance, z_loss
+            balance = balance + aux["load_balancing"]
+            z_loss = z_loss + aux["router_z"]
+            if aux["routing"] is not None:
+                routing.append(aux["routing"])
+            if "mhc" in aux:
+                mhc.append(aux["mhc"])
+
         for i in range(self.num_layers):
             linear = (
                 self.linear if kinds[i % len(kinds)] == "linear" else None)
             if (i >= self.first_k_dense
                     and i % self.moe_every == self.moe_every - 1):
-                x, aux = wrap(MoeBlock)(
-                    self.num_heads,
-                    self.num_experts,
-                    top_k=self.top_k,
-                    capacity_factor=self.capacity_factor,
-                    dispatch_impl=self.dispatch_impl,
-                    expert_dim=self.expert_dim,
-                    expert_act=self.expert_act,
-                    normalize_gates=self.normalize_gates,
-                    scoring=self.scoring,
-                    gate_scale=self.gate_scale,
-                    bias_update_speed=self.bias_update_speed,
-                    seq_aux=self.seq_aux,
-                    shared_experts=self.shared_experts,
-                    held_experts=self.held_experts,
-                    held_rows=self.held_rows,
-                    shared_gate=self.shared_gate,
-                    linear=linear,
-                    name="block_%d" % i,
-                    **shared,
-                    **mixer,
-                )(x, training, positions)
-                balance = balance + aux["load_balancing"]
-                z_loss = z_loss + aux["router_z"]
-                if aux["routing"] is not None:
-                    routing.append(aux["routing"])
+                x, aux = expert_block("block_%d" % i, i, linear)(
+                    x, training, positions)
+                count(aux)
             else:
                 if linear is not None or any(mixer.values()):
                     raise ValueError(
@@ -751,22 +818,62 @@ class MoeTransformerLM(nn.Module):
                         "more" % i)
                 x = wrap(Block)(
                     self.num_heads, mlp_act=self.dense_act,
-                    mlp_dim=self.dense_dim, name="block_%d" % i, **shared
+                    mlp_dim=self.dense_dim, layer_index=i,
+                    name="block_%d" % i, **shared
                 )(x, training)
+                if self.hc is not None:
+                    x, block_facts = x
+                    mhc.append(block_facts)
         if denoise:
             x = block_diffusion.noisy_half(x)
-        x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
-        logits = nn.Dense(
-            self.vocab_size, use_bias=False, name="lm_head"
-        )(x)
+
+        def reduce_streams(streams):
+            """The n streams' sum, in float32, rounded once."""
+            if self.hc is None:
+                return streams
+            return streams.astype(jnp.float32).sum(axis=1).astype(
+                streams.dtype)
+
+        x = reduce_streams(x)
+        head = nn.Dense(self.vocab_size, use_bias=False, name="lm_head")
+        logits = head(make_norm(self.norm, self.norm_eps, "ln_f")(x))
+        mtp_logits = None
+        # the module's parameters are made by ``init``, which is no
+        # training call
+        if self.mtp_layers and (training or self.is_initializing()):
+            with jax.named_scope("mtp/proj"):
+                # position i reads t_(i+1); the last one the wrap
+                merged = jnp.concatenate([
+                    make_norm(self.norm, self.norm_eps, "mtp_hnorm")(x),
+                    make_norm(self.norm, self.norm_eps, "mtp_enorm")(
+                        embed(jnp.roll(tokens, -1, axis=-1))),
+                ], axis=-1)
+                h = expand_streams(nn.Dense(
+                    self.embed_dim, use_bias=False, name="mtp_proj")(merged))
+            with jax.named_scope("mtp/block"):
+                h, aux = expert_block("mtp_block", self.num_layers)(
+                    h, training, positions)
+                count(aux)
+                h = reduce_streams(h)
+            with jax.named_scope("mtp/head"):
+                mtp_logits = head(
+                    make_norm(self.norm, self.norm_eps, "mtp_norm")(h))
         if not training:
             return logits
         aux_loss = self.aux_loss_weight * balance
         if self.z_loss_weight:
             aux_loss = aux_loss + self.z_loss_weight * z_loss
         outputs = {"logits": logits, "aux_loss": aux_loss}
+        if mtp_logits is not None:
+            outputs["mtp_logits"] = mtp_logits
+            outputs["mtp_loss_weight"] = self.mtp_loss_weight
         if routing:
             outputs["routing"] = merge_routing(routing)
+        if mhc:
+            # one fact a block, the prediction module's last
+            outputs["mhc"] = {
+                name: jnp.stack([block_facts[name] for block_facts in mhc])
+                for name in ("row_err", "diag_mean")}
         if denoise:
             outputs["weights"] = weights
             if facts is not None:
@@ -798,6 +905,18 @@ def moe_sharding_rules():
             # output stays whole
             (r"(q_proj|kv_up)/kernel$", P("fsdp", "tp", None)),
             (r"kv_down/kernel$", P("fsdp", None)),
+            # the q latent: its down-projection makes one latent all
+            # heads read, as kv_down does
+            (r"q_down/kernel$", P("fsdp", None)),
+            # hyper-connections: the coefficient kernels are (n, D, 4
+            # to 16) and read the whole residual, so they follow it
+            # (replicated but for fsdp's storage); the gates and biases
+            # are a few floats
+            (r"hc_(attn|mlp)/p_(pre|post|res)$", P(None, "fsdp", None)),
+            (r"hc_(attn|mlp)/(a|b)_(pre|post|res)$", P()),
+            # the prediction module's projection (2 D x D): an
+            # up-projection's layout
+            (r"mtp_proj/kernel$", P("fsdp", "tp")),
             # Gated DeltaNet: the two input projections split their
             # output features over tp like every up-projection, the
             # depthwise conv follows its channels (annotation only:
@@ -852,6 +971,17 @@ def loss(labels, predictions):
             # the masked positions (ops/block_diffusion.py)
             return block_diffusion.weighted_loss(
                 labels, logits, predictions["weights"]) + aux
+        if "mtp_logits" in predictions:
+            # a multi-token-prediction module: over the positions that
+            # have both targets, CE(main, t_(i+1)) + weight x CE(module,
+            # t_(i+2)); the second term goes out by name as well
+            main = sparse_softmax_cross_entropy(
+                labels[:, 1:-1], logits[:, :-2]).mean(axis=-1)
+            mtp = sparse_softmax_cross_entropy(
+                labels[:, 2:], predictions["mtp_logits"][:, :-2]
+            ).mean(axis=-1)
+            return (main + predictions["mtp_loss_weight"] * mtp + aux,
+                    {"mtp_loss": mtp})
     else:
         logits, aux = predictions, 0.0
     per_token = sparse_softmax_cross_entropy(

@@ -23,6 +23,7 @@ Design notes (TPU-first):
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -55,13 +56,65 @@ RESIDUAL_SPEC = P(DATA_AXES, "sp", None)
 HIDDEN_SPEC = P(DATA_AXES, "sp", "tp")
 
 
-def rotary_embedding(x, base=10000.0, seq_axis=2, positions=None):
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A ``rope_scaling`` of ``type`` ``yarn`` as DeepSeek-V3's
+    ``config.json`` names its keys (arXiv:2309.00071)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor, mscale):
+    """``0.1 mscale ln(factor) + 1`` over a factor above 1, else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim, base, scaling):
+    """The ``dim // 2`` rotary frequencies under YaRN, as the published
+    DeepSeek-V3 code builds them (``DeepseekV3YarnRotaryEmbedding``):
+    pair i keeps ``base^(-2i/dim)`` below the correction dimension of
+    ``beta_fast`` rotations over the original context, takes that over
+    ``factor`` above the one of ``beta_slow``, and a linear blend of
+    the two between them."""
+    half = dim // 2
+
+    def correction_dim(rotations):
+        return dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extrapolated = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extrapolated / scaling.factor * ramp + extrapolated * (1 - ramp)
+
+
+def rotary_embedding(x, base=10000.0, seq_axis=2, positions=None,
+                     scaling=None):
     """Apply RoPE; seq_axis=2 for (B, H, S, d), 1 for (B, S, H, d).
     ``positions`` (S,): the position each row rotates by where it is
-    not its index (block diffusion's two copies of one sequence)."""
+    not its index (block diffusion's two copies of one sequence).
+    ``scaling`` (``YarnScaling``): YaRN's frequency table in place of
+    ``base^(-2i/d)``; cos and sin are multiplied by ``mscale`` over
+    ``mscale_all_dim``'s (1 where the two are equal, DeepSeek-V3's)."""
     seq, dim = x.shape[seq_axis], x.shape[-1]
     half = dim // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if scaling is None:
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        amplitude = 1.0
+    else:
+        freqs = yarn_frequencies(dim, base, scaling)
+        amplitude = (yarn_mscale(scaling.factor, scaling.mscale)
+                     / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
     positions = (
         jnp.arange(seq, dtype=jnp.float32) if positions is None
         else positions.astype(jnp.float32))
@@ -70,6 +123,8 @@ def rotary_embedding(x, base=10000.0, seq_axis=2, positions=None):
     shape[seq_axis], shape[-1] = seq, half
     cos = jnp.cos(angles).reshape(shape)
     sin = jnp.sin(angles).reshape(shape)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -251,13 +306,17 @@ class Attention(nn.Module):
 @dataclasses.dataclass(frozen=True)
 class LatentDims:
     """The widths of multi-head latent attention (DeepSeek-V2/V3,
-    arXiv:2412.19437 2.1.1) as a model's ``config.json`` names them. No
-    q latent (``q_lora_rank`` null): Moonlight-16B-A3B's form."""
+    arXiv:2412.19437 2.1.1) as a model's ``config.json`` names them.
+    ``q_lora_rank`` None (``null`` in the file) is the form without a q
+    latent, Moonlight-16B-A3B's: one ``q_proj`` from the model's width.
+    A rank makes the query ``RMSNorm(x W_qa) W_qb`` (DeepSeek-V3's own,
+    Xing4.0's at 768)."""
 
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    q_lora_rank: Optional[int] = None
 
 
 class LatentAttention(nn.Module):
@@ -266,6 +325,8 @@ class LatentAttention(nn.Module):
     statistics in float32:
 
         q            = x W_q                 -> H heads x (nope | rope)
+                       (with a q latent: c_q = RMSNorm(x W_qa), q_lora_rank
+                       wide, and q = c_q W_qb)
         c            = x W_kva               -> kv_lora_rank | rope
         c_kv, k_rope = RMSNorm(c[:rank]), c[rank:]
         k_nope | v   = c_kv W_kvb            -> H heads x (nope | v)
@@ -274,10 +335,16 @@ class LatentAttention(nn.Module):
         q = [q_nope | q_rope], k = [k_nope | k_rope]   (nope + rope wide)
         o = causal softmax(q k^T / sqrt(nope + rope)) v -> H x v -> W_o
 
+    ``rope_scaling`` (``YarnScaling``) rotates by YaRN's frequency
+    table and, as the published DeepSeek-V3 code does whenever a
+    scaling is set, multiplies the softmax scale by ``yarn_mscale(
+    factor, mscale_all_dim)`` squared.
+
     The flash kernel takes q and k of one width and v of another
     (``ops/flash_attention.py``). The scopes ``mla/q_proj``,
     ``mla/kv_down``, ``mla/kv_up``, ``mla/out_proj`` hold the five
-    matmuls, ``mla/assemble`` what needs no FLOPs: the partial rotary,
+    matmuls (``mla/q_down`` the q latent's down-projection and its
+    norm, where there is one), ``mla/assemble`` what needs no FLOPs: the partial rotary,
     the broadcast of ``k_rope`` over the heads, the concatenations and
     the transposes. ``rotary_embedding`` rotates halves where the
     published code rotates interleaved pairs: with seeded weights a
@@ -289,6 +356,7 @@ class LatentAttention(nn.Module):
     mesh: Optional[Any] = None
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
+    rope_scaling: Optional[YarnScaling] = None
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -300,10 +368,24 @@ class LatentAttention(nn.Module):
         rank, nope = dims.kv_lora_rank, dims.qk_nope_head_dim
         rope = dims.qk_rope_head_dim
         spec = P(DATA_AXES, "tp", None, None)
+        rotate = functools.partial(
+            rotary_embedding, base=self.rope_theta,
+            scaling=self.rope_scaling)
+        sm_scale = None
+        if self.rope_scaling is not None:
+            sm_scale = (nope + rope) ** -0.5 * yarn_mscale(
+                self.rope_scaling.factor,
+                self.rope_scaling.mscale_all_dim) ** 2
+        q_in = x
+        if dims.q_lora_rank is not None:
+            with jax.named_scope("mla/q_down"):
+                q_in = nn.RMSNorm(epsilon=self.norm_eps, name="q_norm")(
+                    nn.Dense(dims.q_lora_rank, use_bias=False,
+                             name="q_down")(x))
         with jax.named_scope("mla/q_proj"):
             q = nn.DenseGeneral(
                 (heads, nope + rope), use_bias=False, name="q_proj"
-            )(x)
+            )(q_in)
         with jax.named_scope("mla/kv_down"):
             c = nn.Dense(rank + rope, use_bias=False, name="kv_down")(x)
             c_kv = nn.RMSNorm(epsilon=self.norm_eps, name="kv_norm")(
@@ -316,13 +398,11 @@ class LatentAttention(nn.Module):
         with jax.named_scope("mla/assemble"):
             q = q.transpose(0, 2, 1, 3)  # (B, H, S, nope + rope)
             q = jnp.concatenate([
-                q[..., :nope],
-                rotary_embedding(q[..., nope:], base=self.rope_theta),
+                q[..., :nope], rotate(q[..., nope:]),
             ], axis=-1)
             kv = kv.transpose(0, 2, 1, 3)
             # one rotated head of keys, (B, 1, S, rope), for all H
-            k_rope = rotary_embedding(
-                c[:, None, :, rank:], base=self.rope_theta)
+            k_rope = rotate(c[:, None, :, rank:])
             k = jnp.concatenate([
                 kv[..., :nope],
                 jnp.broadcast_to(
@@ -333,7 +413,7 @@ class LatentAttention(nn.Module):
             v = constrain(kv[..., nope:], self.mesh, spec)
         out = dot_product_attention(
             q, k, v, causal=True, impl=self.attention_impl,
-            mesh=self.mesh, spec=spec,
+            mesh=self.mesh, spec=spec, sm_scale=sm_scale,
         )
         with jax.named_scope("mla/assemble"):
             out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, v)
@@ -457,6 +537,155 @@ class GatedDeltaNet(nn.Module):
                 dim, axis=(-2, -1), use_bias=False, name="out_proj")(o)
 
 
+@dataclasses.dataclass(frozen=True)
+class HyperDims:
+    """A residual path of ``streams`` streams mixed by manifold-
+    constrained hyper-connections, as Xing4.0's ``config.json`` names
+    the sizes: ``hc_mult``, ``hc_sinkhorn_iters``, ``hc_eps`` and the
+    clamp of ``H~_res`` (``mhc_h_res_clamp_min`` / ``_max``)."""
+
+    streams: int
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    res_clamp: Any = (-30.0, 30.0)
+
+
+# The streams (B, n, S, D): a token's n copies of the residual lie in
+# n slabs, each laid out as the plain residual stream is
+STREAMS_SPEC = P(DATA_AXES, None, "sp", None)
+# the initial bias of the stream a sublayer reads and writes, and of
+# the others (sigmoid(4) = 0.98, sigmoid(-4) = 0.018); the off-diagonal
+# bias of H~_res (exp(-8) = 3e-4: H_res starts at the identity to 1e-3)
+HC_BIAS_ON, HC_BIAS_OFF, HC_RES_OFF = 4.0, -4.0, -8.0
+HC_GATE_INIT = 0.01
+
+
+def sinkhorn(matrix, iters, eps):
+    """``matrix`` (n, n, ...): positive entries, a matrix for every
+    index of the trailing axes. ``iters`` times: every row over its sum
+    + eps, then every column over its sum + eps. A ``lax.scan`` over the
+    iterations: unrolled, the compilers' fusion passes duplicate the
+    shared sums of twenty dependent steps without bound (the CPU's did
+    not finish a 4 x 4 in 40 minutes), and on the chip the scan's
+    ``unroll`` moves nothing (``scripts/mhc_coef.py``)."""
+
+    def step(m, _):
+        m = m / (m.sum(axis=1, keepdims=True) + eps)
+        return m / (m.sum(axis=0, keepdims=True) + eps), None
+
+    return jax.lax.scan(step, matrix, None, length=iters)[0]
+
+
+class HyperConnection(nn.Module):
+    """One sublayer's manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880, over hyper-connections, arXiv:2409.19606). For a
+    token's streams X (n x C) around a sublayer F:
+
+        x~      = RMSNorm(vec(X))          over n C lanes, no weight
+        H~_pre  = a_pre  (x~ P_pre)  + b_pre           (n)
+        H~_post = a_post (x~ P_post) + b_post          (n)
+        H~_res  = a_res  mat(x~ P_res) + b_res         (n x n)
+        H_pre = sigmoid(H~_pre);  H_post = 2 sigmoid(H~_post)
+        H_res = Sinkhorn(exp(clip(H~_res)))  rows, then columns, iters times
+        u  = H_pre X;   y = F(norm(u))   (the block's own norm and F)
+        X' = H_res X + H_post^T y
+
+    ``__call__(streams)`` returns ``(u, write, facts)``: ``write(y)`` is
+    ``X'``; ``facts`` the largest ``|row sum - 1|`` of ``H_res`` over
+    the tokens and its mean diagonal. The coefficients are float32 from
+    the matmul's accumulator on (the norm's division follows the
+    matmul: the same value, one pass over X less); the two mixes read
+    and write the streams' dtype and multiply-add in float32. The
+    kernels ``p_pre`` / ``p_post`` / ``p_res`` are (n, C, .): stream m's
+    rows of the (n C)-row matrix are ``p[m]``. Scopes: ``mhc/coef``,
+    ``mhc/pre``, ``mhc/post``. ``select``: the stream this sublayer
+    reads and writes at initialisation."""
+
+    dims: HyperDims
+    select: int = 0
+
+    @nn.compact
+    def __call__(self, streams):
+        dims, n = self.dims, self.dims.streams
+        batch, held, seq, dim = streams.shape
+        if held != n:
+            raise ValueError(
+                "a hyper-connection over %d streams got %d" % (n, held))
+        f32 = jnp.float32
+        kernel_init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=(0, 1), out_axis=2)
+        one_hot = lambda on, off: (
+            lambda key, shape, dtype=f32: jnp.where(
+                jnp.arange(n) == self.select % n, on, off).astype(dtype))
+        res_init = lambda key, shape, dtype=f32: (
+            HC_RES_OFF * (1.0 - jnp.eye(n))).astype(dtype)
+        gate = nn.initializers.constant(HC_GATE_INIT)
+        with jax.named_scope("mhc/coef"):
+            kernel = jnp.concatenate([
+                self.param("p_pre", kernel_init, (n, dim, n)),
+                self.param("p_post", kernel_init, (n, dim, n)),
+                self.param("p_res", kernel_init, (n, dim, n * n)),
+            ], axis=-1).astype(streams.dtype)
+            a_pre, a_post, a_res = (
+                self.param(name, gate, ()).astype(f32)
+                for name in ("a_pre", "a_post", "a_res"))
+            b_pre = self.param(
+                "b_pre", one_hot(HC_BIAS_ON, HC_BIAS_OFF), (n,)).astype(f32)
+            b_post = self.param(
+                "b_post", one_hot(0.0, HC_BIAS_OFF), (n,)).astype(f32)
+            b_res = self.param("b_res", res_init, (n, n)).astype(f32)
+            wide = [streams[:, m].astype(f32) for m in range(n)]
+            mean_square = sum(
+                jnp.mean(w * w, axis=-1) for w in wide) / n  # (B, S)
+            raw = sum(
+                jnp.einsum("bsc,ck->bsk", streams[:, m], kernel[m],
+                           preferred_element_type=f32)
+                for m in range(n))
+            raw = raw * jax.lax.rsqrt(mean_square + dims.eps)[..., None]
+            # (n (n + 2), B, S): an entry of a coefficient is one small
+            # array over the tokens, the sequence in the lanes
+            raw = raw.transpose(2, 0, 1)
+            spread = lambda b: b.reshape(b.shape + (1, 1))
+            h_pre = jax.nn.sigmoid(a_pre * raw[:n] + spread(b_pre))
+            h_post = 2.0 * jax.nn.sigmoid(
+                a_post * raw[n:2 * n] + spread(b_post))
+            h_res = sinkhorn(jnp.exp(jnp.clip(
+                a_res * raw[2 * n:].reshape(n, n, batch, seq)
+                + spread(b_res), *dims.res_clamp)),
+                dims.sinkhorn_iters, dims.eps)
+            # for whoever asks with mutable=["intermediates"] (the
+            # benchmark's reference check); nothing otherwise
+            self.sow("intermediates", "h_res", h_res)
+            facts = jax.lax.stop_gradient({
+                "row_err": jnp.abs(h_res.sum(axis=1) - 1.0).max(),
+                "diag_mean": jnp.mean(
+                    jnp.stack([h_res[m, m] for m in range(n)])),
+            })
+        with jax.named_scope("mhc/pre"):
+            u = sum(
+                h_pre[m][..., None] * wide[m] for m in range(n)
+            ).astype(streams.dtype)
+
+        def write(y):
+            with jax.named_scope("mhc/post"):
+                y_wide = y.astype(f32)
+                return jnp.stack([
+                    h_post[i][..., None] * y_wide + sum(
+                        h_res[i, j][..., None] * wide[j] for j in range(n))
+                    for i in range(n)], axis=1).astype(streams.dtype)
+
+        return u, write, facts
+
+
+def merge_hyper_facts(sublayers):
+    """One block's ``mhc`` facts from its sublayers': the largest row
+    error, the mean diagonal."""
+    return {
+        "row_err": jnp.stack([f["row_err"] for f in sublayers]).max(),
+        "diag_mean": jnp.stack([f["diag_mean"] for f in sublayers]).mean(),
+    }
+
+
 def make_attention(num_heads, latent=None, linear=None, **fields):
     """The block's mixer, ``name="attn"``, by the layer's kind:
     ``GatedDeltaNet`` where the layer is a linear-attention one
@@ -464,7 +693,11 @@ def make_attention(num_heads, latent=None, linear=None, **fields):
     model names latent widths (``LatentDims``), else ``Attention``.
     ``fields``: ``norm_eps``, which all take, what the two softmax ones
     take, and what only ``Attention`` has (``qk_norm``, ``dropout`` and
-    the grouped-query fields)."""
+    the grouped-query fields); ``rope_scaling`` (``YarnScaling``) is a
+    latent mixer's alone."""
+    scaling = fields.pop("rope_scaling", None)
+    if scaling is not None and (linear is not None or latent is None):
+        raise ValueError("only latent attention takes a rope_scaling")
     if linear is not None:
         if fields.get("mask") is not None:
             raise ValueError("a Gated DeltaNet mixer has no mask")
@@ -477,7 +710,8 @@ def make_attention(num_heads, latent=None, linear=None, **fields):
                  "head_norm", "rotary_dim", "output_gate", "mask"):
         if fields.pop(name, None):
             raise ValueError("latent attention has no %s" % name)
-    return LatentAttention(num_heads, latent, name="attn", **fields)
+    return LatentAttention(
+        num_heads, latent, name="attn", rope_scaling=scaling, **fields)
 
 
 class Block(nn.Module):
@@ -495,6 +729,15 @@ class Block(nn.Module):
     # down), of width ``mlp_dim`` (``mlp_ratio x dim`` when None)
     mlp_act: str = "gelu"
     mlp_dim: Optional[int] = None
+    # YaRN, for a latent mixer (``LatentAttention.rope_scaling``)
+    rope_scaling: Optional[YarnScaling] = None
+    # a hyper-connected residual path: ``x`` is then the n streams
+    # (B, n, S, D), each sublayer goes through a ``HyperConnection``
+    # (``hc_attn``, ``hc_mlp``) and the block returns ``(streams, its
+    # mhc facts)``. None: ``x + f(norm(x))``, no module, the tree and
+    # the program the block always had
+    hc: Optional[HyperDims] = None
+    layer_index: int = 0
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -504,9 +747,20 @@ class Block(nn.Module):
                 "mlp_act must be 'gelu' or 'swiglu', got %r"
                 % (self.mlp_act,))
         width = self.mlp_dim or dim * self.mlp_ratio
-        x = constrain(x, self.mesh, RESIDUAL_SPEC)
-        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        x = x + make_attention(
+
+        def mlp(h):
+            if self.mlp_act == "swiglu":
+                gate = nn.Dense(width, use_bias=False, name="mlp_gate")(h)
+                gate = constrain(gate, self.mesh, HIDDEN_SPEC)
+            h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
+            h = constrain(h, self.mesh, HIDDEN_SPEC)
+            h = nn.silu(gate) * h if self.mlp_act == "swiglu" else nn.gelu(h)
+            h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
+            if self.dropout:
+                h = nn.Dropout(self.dropout, deterministic=not training)(h)
+            return h
+
+        attention = make_attention(
             self.num_heads,
             self.latent,
             attention_impl=self.attention_impl,
@@ -515,18 +769,24 @@ class Block(nn.Module):
             qk_norm=self.qk_norm,
             norm_eps=self.norm_eps,
             rope_theta=self.rope_theta,
-        )(h, training)
+            rope_scaling=self.rope_scaling,
+        )
+        if self.hc is not None:
+            x = constrain(x, self.mesh, STREAMS_SPEC)
+            u, write, attn_facts = HyperConnection(
+                self.hc, 2 * self.layer_index, name="hc_attn")(x)
+            x = write(attention(
+                make_norm(self.norm, self.norm_eps, "ln_attn")(u), training))
+            u, write, mlp_facts = HyperConnection(
+                self.hc, 2 * self.layer_index + 1, name="hc_mlp")(x)
+            x = write(mlp(make_norm(self.norm, self.norm_eps, "ln_mlp")(u)))
+            return constrain(x, self.mesh, STREAMS_SPEC), merge_hyper_facts(
+                [attn_facts, mlp_facts])
+        x = constrain(x, self.mesh, RESIDUAL_SPEC)
+        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
+        x = x + attention(h, training)
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
-        if self.mlp_act == "swiglu":
-            gate = nn.Dense(width, use_bias=False, name="mlp_gate")(h)
-            gate = constrain(gate, self.mesh, HIDDEN_SPEC)
-        h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
-        h = constrain(h, self.mesh, HIDDEN_SPEC)
-        h = nn.silu(gate) * h if self.mlp_act == "swiglu" else nn.gelu(h)
-        h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
-        if self.dropout:
-            h = nn.Dropout(self.dropout, deterministic=not training)(h)
-        return constrain(x + h, self.mesh, RESIDUAL_SPEC)
+        return constrain(x + mlp(h), self.mesh, RESIDUAL_SPEC)
 
 
 def remat_block(block_cls, remat_policy, attention_impl):
@@ -546,7 +806,12 @@ def remat_block(block_cls, remat_policy, attention_impl):
     where "dots" exceeds HBM (16k on one chip: PERF.md Section 4). The
     chunked gated delta rule names its output too
     (``ops/gated_delta.py:GDN_OUT_NAME``) and no policy here keeps it:
-    its backward rebuilds its segments' residuals either way."""
+    its backward rebuilds its segments' residuals either way.
+
+    A hyper-connected block's input is the n streams, ``(B, n, S, D)``:
+    what a policy saves a layer is n times as wide as a plain block's
+    (117 MB at 4 x 4,096 x 3,584 bfloat16), and "dots" also keeps the
+    hyper-connections' 24-wide projections (a few MB)."""
     import jax
 
     from elasticdl_tpu.ops.flash_attention import (

@@ -270,6 +270,18 @@ EVENT_TYPES = frozenset({
                              #   masked_share of the tokens, mean_t
                              #   over the blocks, weight_mean = sum(w)
                              #   / L, which averages 1)
+    "mhc",                   # the same steps of a model whose residual
+                             #   path is hyper-connected
+                             #   (models/transformer.py:
+                             #   HyperConnection): a list a fact, one
+                             #   entry a block, a prediction module's
+                             #   last (+ step, row_err: the largest
+                             #   |row sum - 1| of H_res over the
+                             #   tokens; diag_mean: its mean diagonal)
+    "loss_terms",            # the same steps where the loss function
+                             #   names parts of its sum (+ step, loss,
+                             #   mtp_loss: a multi-token-prediction
+                             #   module's cross-entropy, unweighted)
 })
 
 

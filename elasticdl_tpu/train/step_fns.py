@@ -55,18 +55,29 @@ def guard_nonfinite_state(old_state, new_state, nonfinite):
 
 # what a model's training outputs may carry besides logits and losses,
 # as device scalars the step hands out beside the health scalars: an
-# MoE LM's expert-load counters and a block-diffusion LM's noise facts
+# MoE LM's expert-load counters, a block-diffusion LM's noise facts and
+# a hyper-connected LM's facts, one a block
 # (``models/moe_transformer.py``)
-COUNTER_KEYS = ("routing", "noise")
+OUTPUT_KEYS = ("routing", "noise", "mhc")
+# and what the loss function may name of its own sum: a second loss
+# (a multi-token-prediction module's), by name
+LOSS_TERMS = "loss_terms"
+COUNTER_KEYS = OUTPUT_KEYS + (LOSS_TERMS,)
 
 
-def _counters_of(outputs):
-    """``(routing, noise)``: each the model's own dict of device
-    scalars or None, an empty pytree, so a model without them compiles
+def _counters_of(outputs, terms=None):
+    """``(routing, noise, mhc, loss terms)``: each a dict of device
+    values or None, an empty pytree, so a model without them compiles
     the program it compiled before."""
     if not isinstance(outputs, dict):
-        return (None,) * len(COUNTER_KEYS)
-    return tuple(outputs.get(key) for key in COUNTER_KEYS)
+        return (None,) * len(OUTPUT_KEYS) + (terms,)
+    return tuple(outputs.get(key) for key in OUTPUT_KEYS) + (terms,)
+
+
+def _split_terms(value):
+    """A loss function returns the per-sample losses, or those and
+    ``{name: per-sample term}`` for the parts of them it names."""
+    return value if isinstance(value, tuple) else (value, None)
 
 
 def step_rngs(step):
@@ -113,9 +124,12 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
     previous state in-graph (the skip sentinel). ``health=False`` is
     the exact pre-health program: no extra outputs (test-asserted).
     Where the model's training outputs carry ``"routing"`` (an MoE
-    LM's expert-load counters) or ``"noise"`` (a block-diffusion LM's
-    noise facts), the dict has them under those names as device
-    scalars: they leave the step with the health scalars and cost no
+    LM's expert-load counters), ``"noise"`` (a block-diffusion LM's
+    noise facts) or ``"mhc"`` (a hyper-connected LM's, an array a
+    fact, one entry a block), or the loss function returns named terms
+    beside its per-sample losses (``"loss_terms"``: a prediction
+    module's ``mtp_loss``), the dict has them under those names as
+    device values: they leave the step with the health scalars and cost no
     fetch until someone reads them (the worker does on the steps it
     logs).
 
@@ -155,14 +169,22 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 rngs=rngs,
             )
         with jax.named_scope("loss"):
-            per_sample = loss_fn(labels, outputs).astype(jnp.float32)
+            per_sample, terms = _split_terms(loss_fn(labels, outputs))
             # same row-collapse masked_mean applies (multi-dim
             # per-sample losses average over their trailing dims first)
-            per_sample = per_sample.reshape(
-                mask.shape[0], -1
-            ).mean(axis=1)
-            return jnp.sum(per_sample * mask), (
-                jnp.sum(mask), new_model_state, _counters_of(outputs)
+            collapse = lambda t: t.astype(jnp.float32).reshape(
+                mask.shape[0], -1).mean(axis=1)
+            loss_sum = jnp.sum(collapse(per_sample) * mask)
+            weight = jnp.sum(mask)
+            if terms is not None:
+                # a named term leaves as this (micro)batch's masked mean
+                terms = {
+                    name: jax.lax.stop_gradient(
+                        jnp.sum(collapse(term) * mask)
+                        / jnp.maximum(weight, 1.0))
+                    for name, term in terms.items()}
+            return loss_sum, (
+                weight, new_model_state, _counters_of(outputs, terms)
             )
 
     def _apply_update(state, grads, loss, new_model_state):

@@ -53,8 +53,11 @@ class JaxTrainer:
         # the device (train/step_fns.py); None for any other model
         self.routing = None
         # a block-diffusion model's noise facts of the newest step, the
-        # same way
+        # same way; a hyper-connected model's facts; and what the loss
+        # function named of its sum (a prediction module's loss)
         self.noise = None
+        self.mhc = None
+        self.loss_terms = None
         compute_dtype = resolve_dtype(compute_dtype)
         # recompile sentinels (ISSUE 18): instrumented_jit IS jax.jit
         # when EDL_DEVICE_OBS=0; on, each compile is counted, timed,
@@ -113,6 +116,8 @@ class JaxTrainer:
             state, loss, scalars = self._train_step(state, batch)
         self.routing = scalars.get("routing")
         self.noise = scalars.get("noise")
+        self.mhc = scalars.get("mhc")
+        self.loss_terms = scalars.get("loss_terms")
         # one small host transfer per batch; a skip-sentinel batch
         # already kept its state in-graph (nothing else to drop on
         # the dense path — there is no PS push); halt raises

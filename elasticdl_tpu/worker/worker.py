@@ -850,6 +850,8 @@ class Worker:
             # health fetch the value is already on the host
             routing = getattr(self.trainer, "routing", None)
             noise = getattr(self.trainer, "noise", None)
+            mhc = getattr(self.trainer, "mhc", None)
+            terms = getattr(self.trainer, "loss_terms", None)
             with phase("device_wait"):
                 loss_value = float(loss)
                 if routing:
@@ -859,14 +861,29 @@ class Worker:
                 if noise:
                     # and a block-diffusion step's noise facts
                     noise = {k: float(v) for k, v in noise.items()}
+                if mhc:
+                    # a hyper-connected path's facts, one a block
+                    mhc = {k: [float(x) for x in np.asarray(v)]
+                           for k, v in mhc.items()}
+                if terms:
+                    # what the loss function named of its sum
+                    terms = {k: float(v) for k, v in terms.items()}
             with phase("log"):
                 logger.info(
-                    "step %d loss %.6f", self._version, loss_value
+                    "step %d loss %.6f%s", self._version, loss_value,
+                    "".join(" %s %.6f" % item
+                            for item in sorted((terms or {}).items())),
                 )
                 if routing:
                     emit_moe_routing(self._version, routing)
                 if noise:
                     events.emit("bd_noise", step=self._version, **noise)
+                if mhc:
+                    events.emit("mhc", step=self._version, **mhc)
+                if terms:
+                    events.emit(
+                        "loss_terms", step=self._version, loss=loss_value,
+                        **terms)
         with phase("callbacks"):
             for cb in self._callbacks:
                 cb.on_batch_end(self._version, loss)
