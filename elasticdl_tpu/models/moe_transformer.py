@@ -487,12 +487,13 @@ class MoeBlock(nn.Module):
         if self.hc is not None:
             x = constrain(x, self.mesh, STREAMS_SPEC)
             u, write, attn_facts = HyperConnection(
-                self.hc, 2 * self.layer_index, name="hc_attn")(x)
+                self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)
             x = write(attention(
                 make_norm(self.norm, self.norm_eps, "ln_attn")(u),
                 training, *where))
             u, write, mlp_facts = HyperConnection(
-                self.hc, 2 * self.layer_index + 1, name="hc_mlp")(x)
+                self.hc, 2 * self.layer_index + 1, self.mesh,
+                name="hc_mlp")(x)
             y, aux = experts(
                 make_norm(self.norm, self.norm_eps, "ln_mlp")(u), training)
             aux["mhc"] = merge_hyper_facts([attn_facts, mlp_facts])

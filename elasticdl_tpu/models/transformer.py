@@ -33,7 +33,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.data.example import decode_example
-from elasticdl_tpu.ops import gated_delta
+from elasticdl_tpu.ops import gated_delta, hyper_connection
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
     ring_attention,
@@ -599,10 +599,20 @@ class HyperConnection(nn.Module):
     kernels ``p_pre`` / ``p_post`` / ``p_res`` are (n, C, .): stream m's
     rows of the (n C)-row matrix are ``p[m]``. Scopes: ``mhc/coef``,
     ``mhc/pre``, ``mhc/post``. ``select``: the stream this sublayer
-    reads and writes at initialisation."""
+    reads and writes at initialisation.
+
+    Where ``ops/hyper_connection.py:mix_impl`` says ``pallas`` (a TPU,
+    bfloat16 or float32 streams in whole 128-lane rows, a sequence the
+    tile divides, one device or a region manual over ``mesh``; no
+    switch) the same equations run as its ``mhc_...`` kernels: ``pre``
+    makes the coefficients and ``u`` in one read of X and hands X
+    through to ``write``'s ``post``, so that one backward kernel owns
+    dX (that module's docstring). Everywhere else the lines below run
+    as XLA fuses them. One log line says which."""
 
     dims: HyperDims
     select: int = 0
+    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, streams):
@@ -634,6 +644,15 @@ class HyperConnection(nn.Module):
             b_post = self.param(
                 "b_post", one_hot(0.0, HC_BIAS_OFF), (n,)).astype(f32)
             b_res = self.param("b_res", res_init, (n, n)).astype(f32)
+            impl = hyper_connection.mix_impl(
+                streams.dtype, n, dim, seq, self.mesh)
+            hyper_connection.log_choice(
+                n, dim, dims.sinkhorn_iters, impl, seq)
+        if impl == "pallas":
+            return self._by_kernels(
+                streams, kernel, (a_pre, a_post, a_res),
+                (b_pre, b_post, b_res))
+        with jax.named_scope("mhc/coef"):
             wide = [streams[:, m].astype(f32) for m in range(n)]
             mean_square = sum(
                 jnp.mean(w * w, axis=-1) for w in wide) / n  # (B, S)
@@ -653,14 +672,7 @@ class HyperConnection(nn.Module):
                 a_res * raw[2 * n:].reshape(n, n, batch, seq)
                 + spread(b_res), *dims.res_clamp)),
                 dims.sinkhorn_iters, dims.eps)
-            # for whoever asks with mutable=["intermediates"] (the
-            # benchmark's reference check); nothing otherwise
-            self.sow("intermediates", "h_res", h_res)
-            facts = jax.lax.stop_gradient({
-                "row_err": jnp.abs(h_res.sum(axis=1) - 1.0).max(),
-                "diag_mean": jnp.mean(
-                    jnp.stack([h_res[m, m] for m in range(n)])),
-            })
+            facts = self._facts(h_res)
         with jax.named_scope("mhc/pre"):
             u = sum(
                 h_pre[m][..., None] * wide[m] for m in range(n)
@@ -675,6 +687,37 @@ class HyperConnection(nn.Module):
                     for i in range(n)], axis=1).astype(streams.dtype)
 
         return u, write, facts
+
+    def _by_kernels(self, streams, kernel, gates, biases):
+        """``__call__``'s results by ``ops/hyper_connection.py``'s
+        kernels, under the same three scopes."""
+        dims, n = self.dims, self.dims.streams
+        with jax.named_scope("mhc/coef"):
+            kt, gb = hyper_connection.operands(kernel, gates, biases)
+        with jax.named_scope("mhc/pre"):
+            u, carrier, coef = hyper_connection.pre(
+                streams, kt, gb, (n, dims.sinkhorn_iters, dims.eps,
+                                  tuple(dims.res_clamp)))
+        with jax.named_scope("mhc/coef"):
+            facts = self._facts(hyper_connection.h_res_of(coef, n))
+
+        def write(y):
+            with jax.named_scope("mhc/post"):
+                return hyper_connection.post(carrier, y, coef)
+
+        return u, write, facts
+
+    def _facts(self, h_res):
+        """Sows ``h_res`` (n, n, B, S) for whoever asks with
+        ``mutable=["intermediates"]`` (the benchmark's reference check;
+        nothing otherwise) and returns the sublayer's facts."""
+        n = self.dims.streams
+        self.sow("intermediates", "h_res", h_res)
+        return jax.lax.stop_gradient({
+            "row_err": jnp.abs(h_res.sum(axis=1) - 1.0).max(),
+            "diag_mean": jnp.mean(
+                jnp.stack([h_res[m, m] for m in range(n)])),
+        })
 
 
 def merge_hyper_facts(sublayers):
@@ -774,11 +817,12 @@ class Block(nn.Module):
         if self.hc is not None:
             x = constrain(x, self.mesh, STREAMS_SPEC)
             u, write, attn_facts = HyperConnection(
-                self.hc, 2 * self.layer_index, name="hc_attn")(x)
+                self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)
             x = write(attention(
                 make_norm(self.norm, self.norm_eps, "ln_attn")(u), training))
             u, write, mlp_facts = HyperConnection(
-                self.hc, 2 * self.layer_index + 1, name="hc_mlp")(x)
+                self.hc, 2 * self.layer_index + 1, self.mesh,
+                name="hc_mlp")(x)
             x = write(mlp(make_norm(self.norm, self.norm_eps, "ln_mlp")(u)))
             return constrain(x, self.mesh, STREAMS_SPEC), merge_hyper_facts(
                 [attn_facts, mlp_facts])

@@ -15,7 +15,10 @@ tolerances) with the SYSTEM side changed:
   by the compiler as excess precision) after the cast to bfloat16;
 - ``mantissa5``: the same with two bits less than bfloat16's mantissa;
 - ``sinkhorn3``: three Sinkhorn iterations in the place of twenty;
-- ``bfloat16_coefficients``: the Sinkhorn iterations in bfloat16.
+- ``bfloat16_coefficients``: the Sinkhorn iterations in bfloat16 (a
+  patch of ``models/transformer.py:sinkhorn``, which only the XLA lines
+  call: since PR 38 a TPU runs the iterations inside ``mhc_pre_fwd`` and
+  this variant reads as ``stated`` there).
 
 Prints one JSON line a run (every name's error beside its tolerance)
 and leaves all of them in ``chiprun_out/xing_precision.json``.

@@ -357,6 +357,28 @@ def test_the_worker_logs_the_terms_beside_the_loss():
     assert {"mhc", "loss_terms"} <= set(events.EVENT_TYPES)
 
 
+@pytest.mark.parametrize("hc,lines", [(T.HyperDims(4), 1), (None, 0)],
+                         ids=["hyper-connected", "plain"])
+def test_a_model_with_hc_logs_its_path_once(caplog, tokens, hc, lines):
+    """The counter that says which path a program got (PR 38), from
+    where ``ops/hyper_connection.py:mix_impl`` chooses: six sublayers
+    (two blocks' and the prediction module's), one line; none from a
+    model without ``hc``."""
+    import logging
+
+    from elasticdl_tpu.ops import hyper_connection
+
+    hyper_connection.log_choice.cache_clear()
+    with caplog.at_level(logging.INFO):
+        jax.eval_shape(lambda t: tiny_model(hc=hc).init(
+            jax.random.PRNGKey(0), t, training=False), tokens)
+    hyper_connection.log_choice.cache_clear()
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("hyper-connections")]
+    assert said == ["hyper-connections streams=4 dim=16 iters=20 impl=xla "
+                    "(tokens=32)"] * lines
+
+
 # ------------------------------------------- the older configurations
 
 # (leaves, sha256 of the sorted (path, shape, dtype) list) of every
@@ -372,8 +394,15 @@ OLDER_TREES = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(OLDER_TREES))
-def test_the_older_configurations_parameter_trees_are_unchanged(name):
+# and Xing4.0's own at PR 37 (691b774): PR 38 moved the hyper-
+# connection's work into kernels and not a leaf of its tree, so a
+# checkpoint of PR 37 restores
+XING_TREE = {"xing4.0-29b-a4b-1chip": (212, "e869de3eb4a386c1")}
+
+
+def parameter_tree(name):
+    """(leaves, digest) of configuration ``name``'s parameter tree and
+    the sorted (path, shape, dtype) list itself."""
     from benchmark.lib.refcheck import load_by_path
 
     with open(os.path.join(
@@ -390,6 +419,20 @@ def test_the_older_configurations_parameter_trees_are_unchanged(name):
         ("/".join(str(getattr(k, "key", k)) for k in path),
          tuple(leaf.shape), str(leaf.dtype))
         for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0])
+    return (len(flat), hashlib.sha256(
+        repr(flat).encode()).hexdigest()[:16]), flat
+
+
+@pytest.mark.parametrize("name", sorted(OLDER_TREES))
+def test_the_older_configurations_parameter_trees_are_unchanged(name):
+    digest, flat = parameter_tree(name)
     assert not any("hc_" in path or "mtp_" in path for path, _, _ in flat)
-    assert (len(flat), hashlib.sha256(
-        repr(flat).encode()).hexdigest()[:16]) == OLDER_TREES[name]
+    assert digest == OLDER_TREES[name]
+
+
+@pytest.mark.parametrize("name", sorted(XING_TREE))
+def test_the_hyper_connected_configuration_s_tree_is_pr_37_s(name):
+    digest, flat = parameter_tree(name)
+    # five blocks' and the module's two hyper-connections, nine leaves each
+    assert sum(1 for path, _, _ in flat if "/hc_" in path) == 12 * 9
+    assert digest == XING_TREE[name]
