@@ -45,32 +45,92 @@ squarings and log2(chunk) products of chunk x chunk matrices, 0.3% of
 the layer's FLOPs, where a triangular solve would be ``chunk``
 dependent steps.
 
-What is a kernel and what is not. The inverse and its VJP are Pallas
-TPU kernels, ``gdn_inverse_fwd`` and ``gdn_inverse_bwd`` (PR 32): XLA
-runs each of the ten products as one batched matmul over all of a
-segment's matrices and writes every square and every partial product
-to HBM, 64 lanes padded to 128; the kernel reads a block of ``A``,
-keeps it in VMEM through the same ten products at the same precision
-and writes ``T``. Two 64 x 64 matrices lie side by side on the 128
-lanes and meet a block-diagonal right operand, so a pass fills the
-MXU's depth (each output element stays the same sum of the same
-products; the other matrix's lanes meet zeros, so a nan or inf in
-one matrix reaches its lane neighbour's result too, where XLA kept it
-to its own: the step's health check sees either); ``inverse`` and
+What is a kernel and what is not. On a TPU a segment of the rule is
+four Pallas kernels under one VJP (``_chunks_pallas``) and nothing of
+XLA's between them: ``gdn_prepare_fwd`` makes a chunk's operands,
+``gdn_scan_fwd`` carries the state over them, and ``gdn_scan_bwd`` and
+``gdn_prepare_bwd`` are their VJPs (``prep=pallas scan=pallas`` on the
+rule's linear-attention line; ``impl=`` names what runs the inverses
+where the operands are XLA's). Everywhere else the same lines are jnp:
+``_chunk_operands`` with ``unit_lower_inverse`` in it, and
+``_scan_xla``.
+
+The operands (PR 39). Left to XLA, everything above that does not meet
+the state is a dozen fusions around the inverse, each writing a
+chunk-sized array to HBM for the next to read: ``K K^T``, the decay
+matrix, ``A``, ``T``, ``beta V``, ``beta K e^G`` and the float32 ``P``,
+the 64 x 64 float32 ones padded to 128 lanes (134 MB each a segment of
+the cell), and autodiff of them again. ``gdn_prepare_fwd``'s grid runs
+over (batch, key heads, blocks of the segment's chunks), all parallel;
+a grid step reads its chunks' q and k once, the ``R`` value heads' v, g
+and beta, and between the loads and the stores everything stays in
+VMEM: G cumulated in float32 (a masked sum over the lanes where XLA
+calls ``cumsum``: equal to float32 rounding, not bit for bit), ``K
+K^T`` and ``Q K^T`` once a key head (operands in the compute dtype,
+float32 out), ``A`` a value head, ``T`` by the product form above at
+precision highest, ``[U | W] = T [beta V | beta K e^G]`` (one product,
+``T`` and the right operands rounded to the compute dtype as
+``_matmul`` rounds them), the decayed keys and queries, ``P`` rounded to
+the compute dtype and ``exp(G_last)``. It writes exactly what
+``gdn_scan_fwd`` reads, in the ``(B, Hk, R, N, C, .)`` layout where
+that kernel reads it (a reshape between a producer and a
+``pallas_call`` cost more than the kernel won, PR 34) and, called under
+differentiation, ``T`` with two 64 x 64 matrices (one of 128) a 128-lane
+row, which DMAs at twice the rate of a 64-lane row. ``gdn_prepare_bwd``
+reads q, k, v, g, beta, ``T`` and the six cotangents (``dU`` in the
+compute dtype as ``gdn_scan_bwd`` leaves it: no cast between the two),
+makes the decays and the forward's products again and writes dq and dk
+(summed over the key head's ``R`` value heads in VMEM), dv, dg and
+dbeta::
+
+    dT = [dU | dW] [beta V | beta K e^G]^T     [dX | dY] = T^T [dU | dW]
+    dA = -T^T dT T^T  (float32, highest)       Z = dA . A + dP . P
+    dG = rowsum(Z) - colsum(Z) + (terms of e^G, e^(G_last - G))
+    dg = the reverse cumulated sum of dG
+
+``K K^T``, the decay matrix, ``A``, ``beta V``, ``beta K e^G`` and the
+float32 ``P`` never reach HBM, forward or backward. Cotangents are
+matmul operands in the compute dtype, as the TPU's default precision
+rounds them for autodiff of ``_chunk_operands``; the kernel keeps
+``dX``, ``dY``, ``dA`` and every row sum in float32 where autodiff
+rounds each transposed product's result to its operand's dtype: equal
+to the operands' rounding and not bit for bit (on the chip, against
+the XLA lines: dq 0.2%, dk 0.4%, dv 0.001%, dg 0.15%, dbeta 0.01% rms,
+PERF.md Section 6, PR 39). A segment's operands take 1.84 ms where the
+XLA lines took 3.79, their VJP 1.61 where autodiff took 4.71.
+``prepare_impl`` decides beside the two choosers below, from the same
+things and with no switch for a user: wherever ``scan_impl`` says
+``pallas`` (the kernel's results are laid out for ``gdn_scan_fwd``) and
+a block of the segment's chunks in whole 8-row tiles of ``g`` fits
+``_PREPARE_BLOCK_BYTES`` -> the kernels; anything else ->
+``_chunk_operands``, which stays as the path of the CPU, float64, other
+chunks, widths and meshes and as the tests' oracle.
+
+The inverse inside the kernel is the loop body ``gdn_inverse_fwd`` has
+(PR 32; ``_inverse_rows``, shared). Two 64 x 64 matrices lie side by
+side on the 128 lanes and meet a block-diagonal right operand, so a
+pass fills the MXU's depth (each output element stays the same sum of
+the same products; the other matrix's lanes meet zeros, so a nan or inf
+in one matrix reaches its lane neighbour's result too, where XLA kept
+it to its own: the step's health check sees either); ``inverse`` and
 ``power`` of one span, which share their right operand, are stacked on
 the rows (``inverse + inverse P`` and ``P P`` are ``[inverse; P] @ P``);
-and ``_CHAINS`` independent pairs are interleaved in one loop body,
-because one pair's five dependent spans alone leave the MXU waiting
-(3.9 ms for 4,096 matrices where eight chains take 2.1 and XLA 6.6;
-PERF.md Section 6, PR 32). The kernel's VJP runs ``T^T (dT T^T)``
+and ``_CHAINS`` independent pairs are interleaved span by span, because
+one pair's five dependent spans alone leave the MXU waiting (3.9 ms for
+4,096 matrices where eight chains take 2.1 and XLA 6.6; PERF.md Section
+6, PR 32). Its VJP (``_inverse_grad_rows``) runs ``T^T (dT T^T)``
 where the XLA path runs ``(T^T dT) T^T``: the same two products at the
 same precision in the other association, equal to float32 rounding and
-not bit for bit. ``inverse_impl`` decides from the backend, the dtype,
-the chunk and the mesh which runs, with no switch for a user: a TPU,
-float32, chunk 64 or 128, one device -> the kernels (``impl=pallas``
-on the rule's linear-attention line); the CPU, float64, another chunk
-or a mesh of several devices -> the XLA product form below
-(``impl=xla``). A ``pallas_call`` has no GSPMD partitioning rule
+not bit for bit. ``gdn_inverse_fwd`` / ``gdn_inverse_bwd`` are that body
+over ``A`` and ``T`` in HBM, for ``unit_lower_inverse`` inside
+``_chunk_operands``: ``inverse_impl`` says ``pallas`` for a TPU,
+float32, chunk 64 or 128 and one device, ``xla`` (the product form in
+jnp) for the CPU, float64, another chunk or a mesh of several devices.
+Since PR 39 the rule itself calls them only where ``inverse_impl`` says
+``pallas`` and ``prepare_impl`` does not: key or value widths that are
+no whole 128-lane rows, a ``state_dtype`` / ``decay_dtype`` experiment,
+a ``v`` of another dtype than ``q``, or a segment whose chunks fit no
+block. A ``pallas_call`` has no GSPMD partitioning rule
 (``ops/attention.py:_shard_over_mesh``), and the rule opens no
 ``shard_map`` of its own yet, so on a mesh it stays what GSPMD can
 partition.
@@ -89,8 +149,8 @@ decayed keys, ``Q~``, ``P`` (compute dtype) and ``exp(G_last)`` from the
 ``(B, Hk, R, N, C, .)`` arrays where they lie and writes ``O``: the
 lines above, the same four products at the same precision (``[W; Q~]
 S`` is one product, the two sharing their right operand; ``Q~`` and
-``P`` are rounded to the compute dtype by the fusion that makes them,
-as ``_matmul`` rounded them; ``O`` leaves rounded to the compute dtype,
+``P`` are rounded to the compute dtype by what makes them, as
+``_matmul`` rounded them; ``O`` leaves rounded to the compute dtype,
 the cast the rule ends with). ``_SCAN_HEADS`` heads' chains are
 interleaved in one body as ``_CHAINS`` are for the inverses; on the
 chip the kernels move their bytes at the HBM's rate (0.49 GB a segment
@@ -114,22 +174,23 @@ bfloat16 or float32 with ``v`` in the same dtype, the float32 state and
 decay (``state_dtype`` / ``decay_dtype`` at their defaults), key and
 value widths in whole 128-lane rows, chunk 64 or 128, one device or a
 region already manual over the mesh -> the kernels (``scan=pallas`` on
-the line); anything else -> the ``lax.scan`` (``scan=xla``). Everything
-else of the rule is XLA: building ``A``, applying ``T``, the decays.
+the line); anything else -> the ``lax.scan`` (``scan=xla``).
 
 Memory: the backward keeps one state a chunk (autodiff through the
 ``lax.scan`` one in the compute dtype, as its matmul operand, and a
 float32 one for the decay's gradient; the kernels the float32 one, 256
 MB a segment at the shape below) and the chunk's ``W``, ``V'``,
-decayed keys and C x C matrices (float32 ones, which the TPU pads from
-64 to 128 lanes): about 4 GB a layer at 32,768 tokens, 32 heads of 128 x
-128 and chunk 64, too much beside 10 GB of optimizer state. So a
-sequence runs in segments of ``segment`` chunks, each under
-``jax.checkpoint``, with the state carried from one to the next: the
-backward rebuilds one segment's forward at a time and holds a
-``1 / segments`` part of that (a recompute by groups of chunks; its cost
-is one more forward of the rule, 0.5% of the cell's FLOPs). The inverse
-has a VJP of its own from ``T`` alone. The output carries
+decayed keys, ``Q~``, ``P`` and ``T`` (by XLA also ``A``, the decay
+matrix and the float32 ``P``, C x C float32 matrices which the TPU pads
+from 64 to 128 lanes, 134 MB each a segment; the operands' kernel keeps
+``T`` alone, two matrices a lane row, 67 MB): about 4 GB a layer at
+32,768 tokens, 32 heads of 128 x 128 and chunk 64, too much beside 10
+GB of optimizer state. So a sequence runs in segments of ``segment``
+chunks, each under ``jax.checkpoint``, with the state carried from one
+to the next: the backward rebuilds one segment's forward at a time and
+holds a ``1 / segments`` part of that (a recompute by groups of chunks;
+its cost is one more forward of the rule, 0.5% of the cell's FLOPs).
+The inverse has a VJP of its own from ``T`` alone. The output carries
 ``checkpoint_name`` ``GDN_OUT_NAME`` so that a remat policy can name it
 as it names flash's (today's policies do not: the backward rebuilds the
 segments' residuals whether or not ``o`` was kept).
@@ -175,6 +236,16 @@ _SCAN_HEADS = 8
 _SCAN_CHUNKS = 4
 _SCAN_BLOCK_BYTES = 16 * 2**20
 _SCAN_VMEM_LIMIT = 32 * 2**20
+# the operands' kernels: the VMEM a grid step's blocks may take (double-
+# buffered) and the limit the pallas_calls state, which the unrolled
+# body's spilled values stay under too
+_PREPARE_BLOCK_BYTES = 12 * 2**20
+_PREPARE_VMEM_LIMIT = 32 * 2**20
+# groups of ``_CHAINS`` lane rows a grid step's straight-line body holds:
+# with two the scheduler has one group's elementwise work to put beside
+# the other's inverses (the cell's segment: 1.92 -> 1.83 ms forward, 1.71
+# -> 1.58 backward; four do not fit the VMEM; PERF.md Section 6, PR 39)
+_PREPARE_GROUPS = 2
 
 
 def _matmul(a, b, dtype):
@@ -291,30 +362,59 @@ def _block_diagonal(x, size):
         [jnp.where(block == p, x, 0.0) for p in range(pack)], axis=0)
 
 
-def _inverse_fwd_kernel(a_ref, t_ref, *, size):
-    pack = _LANES // size
+def _inverse_rows(rows, size):
+    """``(I + a)^-1`` of every lane row of ``rows`` (each ``pack``
+    strictly lower matrices side by side, (size, 128) float32) by the
+    product form, the rows' independent chains interleaved span by
+    span."""
     row = jax.lax.broadcasted_iota(jnp.int32, (size, _LANES), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (size, _LANES), 1)
     eye = (col % size == row).astype(jnp.float32)
+    inverses = [eye - a for a in rows]
+    powers = [_dot(a, _block_diagonal(a, size)) for a in rows]  # a^2
+    span = 2
+    while 2 * span < size:
+        # [inverse; a^span] @ a^span: inverse (I + a^span) less
+        # inverse, and a^(2 span)
+        both = [
+            _dot(jnp.concatenate([i, p], axis=0), _block_diagonal(p, size))
+            for i, p in zip(inverses, powers)]
+        inverses = [i + b[:size] for i, b in zip(inverses, both)]
+        powers = [b[size:] for b in both]
+        span *= 2
+    return [i + _dot(i, _block_diagonal(p, size))
+            for i, p in zip(inverses, powers)]
+
+
+def _inverse_grad_rows(ts, ds, size):
+    """``-T^T dT T^T`` for every lane row of ``ts`` and its cotangents'
+    ``ds``: a list a lane row of its ``pack`` (size, size) matrices.
+    ``T dT^T``, then ``(T dT^T) T = -(da)^T``, matrix by matrix."""
+    pack = _LANES // size
+    ys = [_dot(t, _block_diagonal(d, size), ((1,), (1,)))
+          for t, d in zip(ts, ds)]
+    ys = [_dot(y, _block_diagonal(t, size)) for y, t in zip(ys, ts)]
+    grads = []
+    for y in ys:
+        if pack > 1:
+            # rows padded to the lanes: one square transpose lays
+            # matrix m's own transpose on rows m x size.., lanes
+            # 0..size
+            y = jnp.concatenate(
+                [y, jnp.zeros((_LANES - size, _LANES), y.dtype)], axis=0)
+        da = -y.T
+        grads.append(
+            [da[m * size:(m + 1) * size, :size] for m in range(pack)])
+    return grads
+
+
+def _inverse_fwd_kernel(a_ref, t_ref, *, size):
+    pack = _LANES // size
 
     def body(step, carry):
         first = [(step * _CHAINS + c) * pack for c in range(_CHAINS)]
-        powers = [_lane_row(a_ref, f, pack) for f in first]
-        inverses = [eye - a for a in powers]
-        powers = [_dot(a, _block_diagonal(a, size)) for a in powers]  # a^2
-        span = 2
-        while 2 * span < size:
-            # [inverse; a^span] @ a^span: inverse (I + a^span) less
-            # inverse, and a^(2 span)
-            both = [
-                _dot(jnp.concatenate([i, p], axis=0),
-                     _block_diagonal(p, size))
-                for i, p in zip(inverses, powers)]
-            inverses = [i + b[:size] for i, b in zip(inverses, both)]
-            powers = [b[size:] for b in both]
-            span *= 2
-        for f, i, p in zip(first, inverses, powers):
-            i = i + _dot(i, _block_diagonal(p, size))
+        rows = _inverse_rows([_lane_row(a_ref, f, pack) for f in first], size)
+        for f, i in zip(first, rows):
             for m in range(pack):
                 t_ref[f + m] = i[:, m * size:(m + 1) * size]
         return carry
@@ -327,23 +427,12 @@ def _inverse_bwd_kernel(t_ref, d_ref, da_ref, *, size):
 
     def body(step, carry):
         first = [(step * _CHAINS + c) * pack for c in range(_CHAINS)]
-        ts = [_lane_row(t_ref, f, pack) for f in first]
-        # T dT^T, then (T dT^T) T = -(da)^T, matrix by matrix
-        ys = [
-            _dot(t, _block_diagonal(_lane_row(d_ref, f, pack), size),
-                 ((1,), (1,)))
-            for f, t in zip(first, ts)]
-        ys = [_dot(y, _block_diagonal(t, size)) for y, t in zip(ys, ts)]
-        for f, y in zip(first, ys):
-            if pack > 1:
-                # rows padded to the lanes: one square transpose lays
-                # matrix m's own transpose on rows m x size.., lanes
-                # 0..size
-                y = jnp.concatenate(
-                    [y, jnp.zeros((_LANES - size, _LANES), y.dtype)], axis=0)
-            da = -y.T
-            for m in range(pack):
-                da_ref[f + m] = da[m * size:(m + 1) * size, :size]
+        grads = _inverse_grad_rows(
+            [_lane_row(t_ref, f, pack) for f in first],
+            [_lane_row(d_ref, f, pack) for f in first], size)
+        for f, das in zip(first, grads):
+            for m, da in enumerate(das):
+                da_ref[f + m] = da
         return carry
 
     jax.lax.fori_loop(0, t_ref.shape[0] // (_CHAINS * pack), body, 0)
@@ -737,17 +826,409 @@ def _scan_vjp_bwd(residuals, cotangents):
 _scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
 
 
+# --------------------------------------------- the chunks' operands
+# Everything of the rule that does not meet the state, a block of a key
+# head's chunks in VMEM from the loads to the stores (PR 39): what
+# ``_chunk_operands`` and autodiff of it do below, with ``K K^T``, the
+# decay matrix, ``A``, ``beta V``, ``beta K e^G`` and the float32 ``P``
+# never in HBM. The results are ``gdn_scan_fwd``'s operands in the (B,
+# Hk, R, N, C, .) layout where it reads them.
+
+
+def prepare_vmem_bytes(rep, chunks, chunk, dk, dv, itemsize, kind):
+    """VMEM of a grid step of the operands' kernel ``kind`` (``fwd``,
+    ``fwd_residuals`` or ``bwd``) over ``chunks`` chunks of one key head
+    and its ``rep`` value heads: every operand's and result's block
+    double-buffered."""
+    key = _tile_bytes(chunk, dk, itemsize)
+    value = _tile_bytes(chunk, dv, itemsize)
+    square = _tile_bytes(chunk, chunk, itemsize)
+    wide = _tile_bytes(chunk, dv, 4)
+    decay = _tile_bytes(1, dv, 4)
+    inverse = chunk * chunk * 4  # two of 64 a 128-lane row
+    # g, beta and their gradients: a row a chunk of a (chunks, chunk)
+    # float32 block
+    rows = 4 * _tile_bytes(chunks, chunk, 4)
+    a_chunk = {
+        # q, k; v -> decay, (w, k, q), p, u
+        "fwd": 2 * key + rep * (value + decay + 3 * key + square + wide),
+        # ... -> T
+        "fwd_residuals": 2 * key + rep * (
+            value + decay + 3 * key + square + wide + inverse),
+        # q, k; v, T, d decay, (dw, dk, dq), dp, du -> dq, dk; dv
+        "bwd": 4 * key + rep * (
+            3 * value + inverse + decay + 3 * key + square),
+    }[kind]
+    return 2 * (chunks * a_chunk + rep * rows)
+
+
+def prepare_block(rep, chunks, chunk, dk, dv, itemsize):
+    """Chunks a grid step of the operands' kernels takes of a segment's
+    ``chunks`` (one key head's, with its ``rep`` value heads), the same
+    for the forward and the backward, which share ``T``'s layout: a
+    divisor of ``chunks`` in whole 8-row tiles of the (chunks, chunk)
+    blocks of ``g`` and ``beta`` (or all of them), the smallest that
+    gives the inverses ``_PREPARE_GROUPS`` groups of ``_CHAINS`` lane
+    rows, inside ``_PREPARE_BLOCK_BYTES``. None where no such block
+    fits: the rule then stays XLA's."""
+    size = lambda step: max(
+        prepare_vmem_bytes(rep, step, chunk, dk, dv, itemsize, kind)
+        for kind in ("fwd_residuals", "bwd"))
+    steps = [d for d in range(1, chunks + 1)
+             if chunks % d == 0 and (d % 8 == 0 or d == chunks)
+             and size(d) <= _PREPARE_BLOCK_BYTES]
+    if not steps:
+        return None
+    enough = [d for d in steps
+              if rep * d >= _PREPARE_GROUPS * _CHAINS * (_LANES // chunk)]
+    return enough[0] if enough else steps[-1]
+
+
+def prepare_impl(dtype, chunk, dk, dv, rep, chunks, state_dtype=jnp.float32,
+                 decay_dtype=jnp.float32, out_dtype=None, mesh=None):
+    """``"pallas"`` or ``"xla"``: what makes a chunk's operands (``A``,
+    its inverse, ``U``, ``W``, the decayed keys and queries, ``P``),
+    from what ``inverse_impl`` and ``scan_impl`` see: the
+    ``gdn_prepare_*`` kernels write what ``gdn_scan_fwd`` reads where it
+    reads it, so they run where the scan's kernels do (a TPU, one device
+    or a manual region, operands bfloat16 or float32, float32 decay and
+    state, chunk 64 or 128, whole 128-lane rows) and a block of the
+    segment's ``chunks`` chunks of ``rep`` value heads fits their VMEM;
+    anything else is ``_chunk_operands`` by XLA."""
+    fits = (
+        scan_impl(dtype, chunk, dk, dv, state_dtype, decay_dtype,
+                  out_dtype, mesh) == "pallas"
+        and prepare_block(
+            rep, chunks, chunk, dk, dv, jnp.dtype(dtype).itemsize) is not None
+    )
+    return "pallas" if fits else "xla"
+
+
+def _chunk_masks(chunk):
+    """(row >= col, row > col, row == col) of a chunk's square."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return row >= col, row > col, row == col
+
+
+def _turned(x, eye):
+    """A (1, C) row as a (C, 1) column, a column as a row: the entry on
+    the diagonal of its broadcast, summed over the other axis."""
+    return jnp.sum(
+        jnp.where(eye, jnp.broadcast_to(x, eye.shape), 0.0),
+        axis=int(x.shape[0] == 1), keepdims=True)
+
+
+def _chunk_decays(g, beta, masks):
+    """A chunk's ``g`` and ``beta`` (1, C) float32 rows -> (G (C, 1),
+    the decay matrix D (C, C), beta (C, 1)): G cumulated from the
+    chunk's first token in float32 (a masked sum over the lanes where
+    ``_chunk_operands`` calls ``cumsum``: equal to float32 rounding),
+    D an exp of a masked difference (above the diagonal the difference
+    is positive and may overflow)."""
+    lower, _, eye = masks
+    cum = jnp.sum(
+        jnp.where(lower, jnp.broadcast_to(g, lower.shape), 0.0), axis=1,
+        keepdims=True)
+    decay = jnp.exp(jnp.where(lower, cum - _turned(cum, eye), -jnp.inf))
+    return cum, decay, _turned(beta, eye)
+
+
+def _paired(matrices, size):
+    """(size, size) matrices, ``pack`` side by side a (size, 128) lane
+    row, the last row filled with zero matrices."""
+    pack = _LANES // size
+    if pack == 1:
+        return list(matrices)
+    matrices = list(matrices) + [
+        jnp.zeros((size, size), jnp.float32)] * (-len(matrices) % pack)
+    return [jnp.concatenate(matrices[i:i + pack], axis=1)
+            for i in range(0, len(matrices), pack)]
+
+
+def _unpaired(rows, size, count):
+    """The first ``count`` matrices of lane rows, as ``_paired`` laid
+    them."""
+    pack = _LANES // size
+    if pack == 1:
+        return list(rows)
+    return [rows[m // pack][:, m % pack * size:(m % pack + 1) * size]
+            for m in range(count)]
+
+
+def _by_chains(fn, rows, *more):
+    """``fn`` over ``_CHAINS`` lane rows at a time."""
+    out = []
+    for i in range(0, len(rows), _CHAINS):
+        out += fn(rows[i:i + _CHAINS], *(m[i:i + _CHAINS] for m in more))
+    return out
+
+
+def _prepare_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, e_ref, w_ref,
+                        ko_ref, qi_ref, p_ref, u_ref, *t_ref):
+    """A block of chunks of one key head and its value heads: ``K K^T``
+    and ``Q K^T`` once a chunk, ``A``, ``P``, the decayed keys and
+    queries and ``exp(G_last)`` a value head, the inverses by the
+    product form ``_CHAINS`` lane rows at a time, then ``[U | W] = T
+    [beta V | beta K e^G]`` (one product, the two sharing their left
+    operand). ``beta V`` (float32) and ``beta K e^G`` wait in ``u``'s
+    and ``w``'s blocks for their ``T``."""
+    _, _, rep, chunks, chunk, dv = u_ref.shape
+    dtype = q_ref.dtype
+    masks = _chunk_masks(chunk)
+    lower, strict, _ = masks
+    heads = [(0, 0, r, c) for c in range(chunks) for r in range(rep)]
+    a = []
+    for c in range(chunks):
+        k, q = k_ref[0, 0, 0, c], q_ref[0, 0, 0, c]
+        kk, qk = _mxu(k, k, _NT), _mxu(q, k, _NT)
+        k, q = k.astype(jnp.float32), q.astype(jnp.float32)
+        for r in range(rep):
+            at = (0, 0, r, c)
+            cum, decay, beta = _chunk_decays(
+                g_ref[0, 0, r, pl.ds(c, 1), :],
+                beta_ref[0, 0, r, pl.ds(c, 1), :], masks)
+            a.append(jnp.where(strict, kk * beta * decay, 0.0))
+            p_ref[at] = jnp.where(lower, qk * decay, 0.0).astype(dtype)
+            into, last = jnp.exp(cum), cum[chunk - 1:]
+            ko_ref[at] = (jnp.exp(last - cum) * k).astype(dtype)
+            qi_ref[at] = (into * q).astype(dtype)
+            e_ref[at] = jnp.broadcast_to(jnp.exp(last), (1, dv))
+            u_ref[at] = beta * v_ref[at].astype(jnp.float32)
+            w_ref[at] = ((beta * into) * k).astype(dtype)
+    rows = _by_chains(
+        functools.partial(_inverse_rows, size=chunk), _paired(a, chunk))
+    if t_ref:
+        for i, row in enumerate(rows):
+            t_ref[0][0, 0, 0, i] = row
+    for at, t in zip(heads, _unpaired(rows, chunk, len(heads))):
+        both = _mxu(t.astype(dtype), jnp.concatenate(
+            [u_ref[at].astype(dtype), w_ref[at]], axis=1))
+        u_ref[at] = both[:, :dv]
+        w_ref[at] = both[:, dv:].astype(dtype)
+
+
+def _prepare_bwd_kernel(q_ref, k_ref, v_ref, de_ref, dw_ref, dko_ref, dqi_ref,
+                        dp_ref, du_ref, g_ref, beta_ref, t_ref, dq_ref,
+                        dk_ref, dv_ref, dg_ref, dbeta_ref):
+    """The VJP of ``_prepare_fwd_kernel`` from q, k, v, g, beta, ``T``
+    and the six cotangents, the decays and the products of the forward
+    made again in VMEM: ``dT = [dU | dW] [beta V | beta K e^G]^T``,
+    ``dA = -T^T dT T^T`` (float32, precision highest, as
+    ``gdn_inverse_bwd``), then products with the decay matrix, row sums
+    and one reverse cumulated sum a chunk; ``dq`` and ``dk`` summed over
+    the key head's value heads here. Cotangents are matmul operands in
+    the compute dtype, every sum float32."""
+    _, _, rep, chunks, chunk, dv = v_ref.shape
+    dtype = q_ref.dtype
+    f32 = jnp.float32
+    masks = _chunk_masks(chunk)
+    lower, strict, eye = masks
+    heads = [(0, 0, r, c) for c in range(chunks) for r in range(rep)]
+    ts = [t_ref[0, 0, 0, i] for i in range(t_ref.shape[3])]
+    held, d_ts = [], []
+    for at, t in zip(heads, _unpaired(ts, chunk, len(heads))):
+        r, c = at[2:]
+        k = k_ref[0, 0, 0, c].astype(f32)
+        cum, decay, beta = _chunk_decays(
+            g_ref[0, 0, r, pl.ds(c, 1), :],
+            beta_ref[0, 0, r, pl.ds(c, 1), :], masks)
+        into = jnp.exp(cum)
+        right = jnp.concatenate(
+            [(beta * v_ref[at].astype(f32)).astype(dtype),
+             ((beta * into) * k).astype(dtype)], axis=1)
+        left = jnp.concatenate([du_ref[at], dw_ref[at]], axis=1)
+        d_ts.append(_mxu(left, right, _NT))
+        # [dX | dY] = T^T [dU | dW]
+        held.append((cum, decay, beta, into, _mxu(t.astype(dtype), left, _TN)))
+    d_as = _by_chains(
+        functools.partial(_inverse_grad_rows, size=chunk), ts,
+        _paired(d_ts, chunk))
+    d_as = [d for row in d_as for d in row]
+    tail = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    for c in range(chunks):
+        k_low, q_low = k_ref[0, 0, 0, c], q_ref[0, 0, 0, c]
+        kk, qk = _mxu(k_low, k_low, _NT), _mxu(q_low, k_low, _NT)
+        k, q = k_low.astype(f32), q_low.astype(f32)
+        d_kk = d_qk = d_k = d_q = 0.0
+        for r in range(rep):
+            at = (0, 0, r, c)
+            cum, decay, beta, into, both = held[c * rep + r]
+            d_x, d_y = both[:, :dv], both[:, dv:]
+            onto = jnp.exp(cum[chunk - 1:] - cum)
+            # A = beta . K K^T . D below the diagonal, P = Q K^T . D on
+            # and below it; Z = dD . D
+            d_a = jnp.where(strict, d_as[c * rep + r], 0.0)
+            d_p = jnp.where(lower, dp_ref[at].astype(f32), 0.0)
+            by_kk, by_qk = d_a * decay, d_p * decay
+            by_beta = by_kk * kk
+            d_kk = d_kk + beta * by_kk
+            d_qk = d_qk + by_qk
+            z = beta * by_beta + by_qk * qk
+            d_ko, d_qi = dko_ref[at].astype(f32), dqi_ref[at].astype(f32)
+            rows = lambda x: jnp.sum(x, axis=1, keepdims=True)
+            by_y = rows(d_y * k)
+            d_beta = (
+                rows(by_beta) + rows(d_x * v_ref[at].astype(f32))
+                + into * by_y)
+            left_over = rows(d_ko * k) * onto
+            d_last = (
+                jnp.sum(left_over, axis=0, keepdims=True)
+                + rows(de_ref[at]) * jnp.exp(cum[chunk - 1:]))
+            d_cum = (
+                rows(z) - _turned(jnp.sum(z, axis=0, keepdims=True), eye)
+                + into * (beta * by_y + rows(d_qi * q))
+                - left_over + jnp.where(tail, d_last, 0.0))
+            # g reaches G_i for every i at or after its token
+            dg_ref[0, 0, r, pl.ds(c, 1), :] = jnp.sum(
+                jnp.where(lower, jnp.broadcast_to(d_cum, lower.shape), 0.0),
+                axis=0, keepdims=True)
+            dbeta_ref[0, 0, r, pl.ds(c, 1), :] = _turned(d_beta, eye)
+            dv_ref[at] = (beta * d_x).astype(dv_ref.dtype)
+            d_k = d_k + (beta * into) * d_y + onto * d_ko
+            d_q = d_q + into * d_qi
+        d_kk, d_qk = d_kk.astype(dtype), d_qk.astype(dtype)
+        # K K^T and Q K^T: [dKK; dQK] K, dKK^T K, dQK^T Q
+        both = _mxu(jnp.concatenate([d_kk, d_qk], axis=0), k_low)
+        dk_ref[0, 0, 0, c] = (
+            d_k + both[:chunk] + _mxu(d_kk, k_low, _TN)
+            + _mxu(d_qk, q_low, _TN)).astype(dk_ref.dtype)
+        dq_ref[0, 0, 0, c] = (d_q + both[chunk:]).astype(dq_ref.dtype)
+
+
+def _prepare_call(kernel, name, key_like, value_like, rows, inverse,
+                  out_key, out_value, out_rows, out_inverse, interpret):
+    """``kernel`` over the grid (batch, key heads, blocks of chunks),
+    all parallel. Operands and results by their blocks: ``key_like``
+    (B, Hk, 1, N, C, Dk); ``value_like`` (B, Hk, R, N, rows, cols);
+    ``rows`` (B, Hk, R, N, C) float32; ``inverse``: ``T`` (B, Hk, grid
+    steps, lane rows, C, 128) or nothing. ``out_key`` / ``out_rows``:
+    how many results like the first of their operands; ``out_value``:
+    (rows, cols, dtype) each; ``out_inverse``: whether ``T`` is the
+    last result."""
+    batch, hk, rep, chunks, chunk, dv = value_like[0].shape
+    dk, dtype = key_like[0].shape[-1], key_like[0].dtype
+    step = prepare_block(rep, chunks, chunk, dk, dv, dtype.itemsize)
+    steps = chunks // step
+    lane_rows = -(-rep * step // (_LANES // chunk))
+    key_spec = pl.BlockSpec(
+        (1, 1, 1, step, chunk, dk), lambda b, a, n: (b, a, 0, n, 0, 0))
+    value_spec = lambda rows, cols: pl.BlockSpec(
+        (1, 1, rep, step, rows, cols), lambda b, a, n: (b, a, 0, n, 0, 0))
+    rows_spec = pl.BlockSpec(
+        (1, 1, rep, step, chunk), lambda b, a, n: (b, a, 0, n, 0))
+    inverse_spec = pl.BlockSpec(
+        (1, 1, 1, lane_rows, chunk, _LANES),
+        lambda b, a, n: (b, a, n, 0, 0, 0))
+    operands = list(key_like) + list(value_like) + list(rows) + list(inverse)
+    struct = lambda shape, dtype: jax_compat.out_struct(
+        shape, dtype, *operands)
+    return pl.pallas_call(
+        kernel,
+        grid=(batch, hk, steps),
+        in_specs=(
+            [key_spec] * len(key_like)
+            + [value_spec(*x.shape[4:]) for x in value_like]
+            + [rows_spec] * len(rows) + [inverse_spec] * len(inverse)),
+        out_specs=(
+            [key_spec] * out_key
+            + [value_spec(rows, cols) for rows, cols, _ in out_value]
+            + [rows_spec] * out_rows + [inverse_spec] * out_inverse),
+        out_shape=(
+            [struct(key_like[0].shape, dtype)] * out_key
+            + [struct((batch, hk, rep, chunks, rows, cols), dtype)
+               for rows, cols, dtype in out_value]
+            + [struct(rows[0].shape, jnp.float32)] * out_rows
+            + [struct((batch, hk, steps, lane_rows, chunk, _LANES),
+                      jnp.float32)] * out_inverse),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=_PREPARE_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name=name,
+    )(*operands)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("residuals", "interpret"))
+def gdn_prepare_fwd(q, k, v, g, beta, residuals=False, interpret=False):
+    """A segment's operands of ``gdn_scan_fwd`` from q, k (B, Hk, 1, N,
+    C, Dk), v (B, Hk, R, N, C, Dv) in the compute dtype and g, beta (B,
+    Hk, R, N, C) float32: -> (decay (B, Hk, R, N, 1, Dv) float32,
+    exp(G_last) on every lane; W, the decayed keys, Q~ (B, Hk, R, N, C,
+    Dk) and P (B, Hk, R, N, C, C) in the compute dtype; U (B, Hk, R, N,
+    C, Dv) float32) and, with ``residuals`` (the call under
+    differentiation), ``T`` float32 with two 64 x 64 matrices (or one of
+    128) a 128-lane row, (B, Hk, grid steps, lane rows, C, 128), which
+    ``gdn_prepare_bwd`` reads as it lies."""
+    chunk, dk = q.shape[4:]
+    dv, dtype = v.shape[5], q.dtype
+    return _prepare_call(
+        _prepare_fwd_kernel, "gdn_prepare_fwd", [q, k], [v], [g, beta], [],
+        0, [(1, dv, jnp.float32)] + [(chunk, dk, dtype)] * 3
+        + [(chunk, chunk, dtype), (chunk, dv, jnp.float32)], 0,
+        int(residuals), interpret)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("interpret",))
+def gdn_prepare_bwd(q, k, v, g, beta, inverse, d_decay, dw, d_k, dq, dp, du,
+                    interpret=False):
+    """The VJP of ``gdn_prepare_fwd`` from its operands, ``T`` and the
+    six cotangents (``du`` in the compute dtype, as ``gdn_scan_bwd``
+    hands it on; ``d_decay`` float32, summed over its lanes here): ->
+    (dq, dk (B, Hk, 1, N, C, Dk), dv in their operands' dtype, dg,
+    dbeta float32)."""
+    chunk, dv = v.shape[4:]
+    return _prepare_call(
+        _prepare_bwd_kernel, "gdn_prepare_bwd", [q, k],
+        [v, d_decay, dw, d_k, dq, dp, du], [g, beta], [inverse],
+        2, [(chunk, dv, v.dtype)], 2, 0, interpret)
+
+
+@jax.custom_vjp
+def _chunks_pallas(state, q, k, v, g, beta):
+    """The rule over a segment's chunks by the four kernels, shapes as
+    ``_chunks``: -> (the leaving state, o)."""
+    return gdn_scan_fwd(state, *gdn_prepare_fwd(q, k, v, g, beta))
+
+
+def _chunks_vjp_fwd(state, q, k, v, g, beta):
+    *operands, u, inverse = gdn_prepare_fwd(
+        q, k, v, g, beta, residuals=True)
+    leaving, o, new_v, states = gdn_scan_fwd(
+        state, *operands, u, residuals=True)
+    return (leaving, o), (
+        q, k, v, g, beta, inverse, *operands, states, new_v)
+
+
+def _chunks_vjp_bwd(residuals, cotangents):
+    *inputs, inverse = residuals[:6]
+    d_state, d_o = cotangents
+    d_state, *grads = gdn_scan_bwd(*residuals[6:], d_o, d_state)
+    # du stays in the compute dtype between the two kernels
+    return (d_state, *gdn_prepare_bwd(*inputs, inverse, *grads))
+
+
+_chunks_pallas.defvjp(_chunks_vjp_fwd, _chunks_vjp_bwd)
+
+
 @functools.lru_cache(maxsize=None)
-def _log_once(hk, hv, dk, chunk, impl, scan, tokens):
+def _log_once(hk, hv, dk, chunk, impl, scan, prep, tokens):
     """One line per distinct call of the rule (this runs at trace time),
     beside the attention line of ``ops/attention.py``, from where the
     paths are chosen. ``impl``: what runs the chunks' inverses,
     ``pallas`` (the ``gdn_inverse_*`` kernels) or ``xla``; ``scan``:
     what carries the state from chunk to chunk, ``pallas`` (the
-    ``gdn_scan_*`` kernels) or ``xla`` (a ``lax.scan``)."""
+    ``gdn_scan_*`` kernels) or ``xla`` (a ``lax.scan``); ``prep``: what
+    makes the chunks' operands, ``pallas`` (the ``gdn_prepare_*``
+    kernels, the inverses inside them) or ``xla`` (``_chunk_operands``
+    around the inverses ``impl`` names)."""
     logger.info(
         "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
-        "scan=%s (tokens=%d)", hk, hv, dk, chunk, impl, scan, tokens)
+        "scan=%s prep=%s (tokens=%d)", hk, hv, dk, chunk, impl, scan, prep,
+        tokens)
 
 
 def _chunk_operands(q, k, v, g, beta, decay_dtype, impl):
@@ -809,24 +1290,33 @@ def _scan_xla(state, last, w, k_onto, q_into, attn, u, dtype):
         _matmul(q_into, states, dtype) + _matmul(attn, new_v, dtype))
 
 
-def _scan_pallas(state, last, w, k_onto, q_into, attn, u, dtype):
-    """The same recurrence by the ``gdn_scan_*`` kernels, the operands
-    read where they lie (no chunks-first copy, no stacked states): Q~
-    and P rounded to the compute dtype here as ``_matmul`` would round
-    them, exp(G_last) on the lanes of a row a chunk; o comes back
-    rounded to the compute dtype, as the rule's caller would round it
-    next."""
+def _scan_operands(last, w, k_onto, q_into, attn, u, dtype):
+    """``_chunk_operands``' results as ``gdn_scan_fwd`` reads them (and
+    as ``gdn_prepare_fwd`` writes them): exp(G_last) on the lanes of a
+    row a chunk, Q~ and P rounded to the compute dtype as ``_matmul``
+    would round them."""
     leaves = jnp.broadcast_to(
         jnp.exp(last)[..., None], last.shape + u.shape[-1:])
-    return _scan(state, leaves, w, k_onto, q_into.astype(dtype),
-                 attn.astype(dtype), u)
+    return leaves, w, k_onto, q_into.astype(dtype), attn.astype(dtype), u
 
 
-def _chunks(state, q, k, v, g, beta, decay_dtype, impl, scan):
+def _scan_pallas(state, last, w, k_onto, q_into, attn, u, dtype):
+    """The same recurrence by the ``gdn_scan_*`` kernels, the operands
+    read where they lie (no chunks-first copy, no stacked states); o
+    comes back rounded to the compute dtype, as the rule's caller would
+    round it next."""
+    return _scan(state, *_scan_operands(
+        last, w, k_onto, q_into, attn, u, dtype))
+
+
+def _chunks(state, q, k, v, g, beta, decay_dtype, impl, scan, prep):
     """The rule over whole chunks from the state ``state`` (in the
     dtype it is carried in): -> (the state after them, o (B, Hk, R, N,
     C, Dv)). ``impl``: what runs the inverses; ``scan``: what carries
-    the state."""
+    the state; ``prep``: what makes the chunks' operands (``pallas``:
+    the four kernels under one VJP, nothing of XLA's between them)."""
+    if prep == "pallas":
+        return _chunks_pallas(state, q, k, v, g, beta)
     carry = _scan_pallas if scan == "pallas" else _scan_xla
     return carry(
         state, *_chunk_operands(q, k, v, g, beta, decay_dtype, impl),
@@ -867,7 +1357,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     impl = inverse_impl(wide, chunk, mesh)
     scan = scan_impl(
         q.dtype, chunk, dk, dv, state_dtype, decay_dtype, v.dtype, mesh)
-    _log_once(hk, hv, dk, chunk, impl, scan, batch * seq)
     span = chunk if seq <= chunk * segment else chunk * segment
     pad = -seq % span
     if pad:
@@ -876,6 +1365,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
         q, k, v, g, beta = map(widen, (q, k, v, g, beta))
     segments = max(1, (seq + pad) // (chunk * segment))
     num = (seq + pad) // (segments * chunk)  # chunks a segment
+    prep = prepare_impl(
+        q.dtype, chunk, dk, dv, rep, num, state_dtype, decay_dtype, v.dtype,
+        mesh)
+    _log_once(hk, hv, dk, chunk, impl, scan, prep, batch * seq)
     # segments first; key-like (B, Hk, 1, N, C, Dk), value-like (B, Hk,
     # R, N, C, ...)
     split = lambda x, heads, *rest: jnp.moveaxis(
@@ -887,7 +1380,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
         split(g.astype(wide), (hk, rep)), split(beta.astype(wide), (hk, rep)),
     )
     run = lambda state, xs: _chunks(
-        state, *xs, decay_dtype, impl, scan)
+        state, *xs, decay_dtype, impl, scan, prep)
     state0 = jnp.zeros((batch, hk, rep, dk, dv), state_dtype)
     if segments == 1:
         _, o = run(state0, tuple(x[0] for x in xs))

@@ -10,6 +10,7 @@ and a shape over the budget must compile to the split pair.
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
 
+import functools
 import re
 
 import jax
@@ -167,28 +168,36 @@ def _square_float32_dots(hlo, size=64):
     return found
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "inverse"])
 def test_the_chunked_rule_compiles_at_the_cell_s_shape(
         chip, monkeypatch, impl):
     """``gated_delta_rule``'s gradient at 32,768 tokens, 16 key and 32
     value heads of 128, chunk 64, for a described v5e: the segments'
     ``jax.checkpoint`` keeps its temporaries under 2.5 GB (4 GB and a
     refused step without it, PERF.md Section 6). With the backend a TPU
-    (``pallas``: what the chip gets, ISSUE 32 and 34) the inverses and
-    the chunk-to-chunk scan are the four kernels, every
-    ``tpu_custom_call`` named, no float32 64 x 64 dot and no loop over a
+    (``pallas``: what the chip gets, ISSUE 32, 34 and 39) a segment is
+    four kernels, the chunks' operands with the inverses in them and the
+    chunk-to-chunk scan, every ``tpu_custom_call`` named, no float32 64 x
+    64 dot, no 64 x 64 float32 array of the chunks' and no loop over a
     segment's chunks is left outside them, and the VMEM they ask for is
-    under the limits they state. The kernels' operands are row-major, 64 lanes padded to
-    128, where XLA kept some of its own matrices with the chunks on the
-    lanes: the rule alone reads 2.67 GiB for 2.45 and is held just
-    above that (the cell's whole step counts 4 MB less than the
-    parent's: PERF.md Section 6, PR 32)."""
+    under the limits they state. ``inverse``: the same with the
+    operands' chooser held to XLA's lines, PR 34's program, in which
+    the inverses are kernels of their own (their operands row-major, 64
+    lanes padded to 128, where XLA kept some of its own matrices with
+    the chunks on the lanes: the rule alone reads 2.67 GiB for 2.45)."""
     from elasticdl_tpu.ops import gated_delta
 
-    if impl == "pallas":
+    if impl != "xla":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert gated_delta.inverse_impl(jnp.float32, 64) == impl
-    assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == impl
+    if impl == "inverse":
+        monkeypatch.setattr(
+            gated_delta, "prepare_impl", lambda *a, **kw: "xla")
+    chosen = "xla" if impl == "xla" else "pallas"
+    assert gated_delta.inverse_impl(jnp.float32, 64) == chosen
+    assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == chosen
+    assert gated_delta.prepare_impl(
+        jnp.bfloat16, 64, 128, 128, 2, 128) == (
+            "pallas" if impl == "pallas" else "xla")
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
     args = (
@@ -211,12 +220,21 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
         # the step's forward, the segment's again, and the VJP's two
         assert len(_square_float32_dots(hlo)) == 22
         return
-    assert temporaries < 2.7 * 2**30
     # the forward in the step and in the segment's recompute; a kernel
     # inside a loop body counts once
-    assert device_obs.pallas_kernels(hlo) == {
-        "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1,
-        "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
+    if impl == "pallas":
+        # four padded float32 matrix arrays a segment fewer, ``T`` at
+        # half its padded size
+        assert temporaries < 2.3 * 2**30
+        assert device_obs.pallas_kernels(hlo) == {
+            "gdn_prepare_fwd": 2, "gdn_prepare_bwd": 1,
+            "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
+        assert not re.search(r"f32\[[\d,]*,64,64\]", hlo)
+    else:
+        assert temporaries < 2.7 * 2**30
+        assert device_obs.pallas_kernels(hlo) == {
+            "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1,
+            "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
     assert hlo.count("tpu_custom_call") == 6
     assert not _square_float32_dots(hlo)
     # the chunk-to-chunk recurrence is inside the scan's kernels (ISSUE
@@ -228,10 +246,13 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     # matrices and of a grid step's heads and chunks, operands and
     # results double-buffered, count under it
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
+    limits = {"gdn_scan": gated_delta._SCAN_VMEM_LIMIT,
+              "gdn_prepare": gated_delta._PREPARE_VMEM_LIMIT,
+              "gdn_inverse": gated_delta._INVERSE_VMEM_LIMIT}
     for line in calls:
-        limit = (gated_delta._SCAN_VMEM_LIMIT if "gdn_scan" in line
-                 else gated_delta._INVERSE_VMEM_LIMIT)
-        assert '"size":"%d"' % limit in line
+        # the call's own name; its source locations name its callers
+        name, = device_obs.pallas_kernels(line)
+        assert '"size":"%d"' % limits[name[:name.rindex("_")]] in line
     for arrays in (2, 3):
         block = gated_delta.inverse_block(4096, 64, arrays)
         assert gated_delta.inverse_vmem_bytes(
@@ -240,6 +261,47 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
         block, step = gated_delta.scan_block(32, 128, 64, 128, 128, 2, kind)
         assert gated_delta.scan_vmem_bytes(
             block, step, 64, 128, 128, 2, kind) < gated_delta._SCAN_VMEM_LIMIT
+        step = gated_delta.prepare_block(2, 128, 64, 128, 128, 2)
+        assert gated_delta.prepare_vmem_bytes(
+            2, step, 64, 128, 128, 2, kind) < gated_delta._PREPARE_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("chunk,rep,heads,chunks,dtype", [
+    (64, 2, 16, 128, "bfloat16"),   # the cell's segment
+    (128, 2, 16, 64, "bfloat16"),
+    (64, 1, 4, 16, "float32"),
+    (128, 1, 2, 3, "float32"),      # a block of all the chunks, no tile
+], ids=lambda v: str(v))
+def test_the_operands_kernels_compile(chip, chunk, rep, heads, chunks, dtype):
+    """``gdn_prepare_fwd`` (with and without ``T``) and
+    ``gdn_prepare_bwd`` alone for a described v5e: the lane-row
+    concatenations, the masked sums over lanes and rows, the one-row
+    loads of ``g`` and the transposed products are what the interpreter
+    never refuses."""
+    from elasticdl_tpu.ops import gated_delta
+
+    dtype = jnp.dtype(dtype)
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=chip)
+    args = (
+        struct((1, heads, 1, chunks, chunk, 128), dtype),
+        struct((1, heads, 1, chunks, chunk, 128), dtype),
+        struct((1, heads, rep, chunks, chunk, 128), dtype),
+        struct((1, heads, rep, chunks, chunk), jnp.float32),
+        struct((1, heads, rep, chunks, chunk), jnp.float32),
+    )
+    for residuals in (False, True):
+        hlo = jax.jit(functools.partial(
+            gated_delta.gdn_prepare_fwd, residuals=residuals)).lower(
+                *args).compile().as_text()
+        assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_fwd": 1}
+    outs = jax.eval_shape(functools.partial(
+        gated_delta.gdn_prepare_fwd, residuals=True), *args)
+    *operands, u, inverse = [struct(o.shape, o.dtype) for o in outs]
+    hlo = jax.jit(gated_delta.gdn_prepare_bwd).lower(
+        *args, inverse, *operands, struct(u.shape, dtype)
+    ).compile().as_text()
+    assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_bwd": 1}
 
 
 def test_the_chunked_rule_stays_partitionable_over_a_mesh(
