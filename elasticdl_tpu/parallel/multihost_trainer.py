@@ -63,6 +63,8 @@ class LockstepMixin:
     ``self.mesh`` exists; ``self._state_shardings`` is owned by the
     concrete trainer."""
 
+    lockstep = True
+
     def _init_lockstep(self):
         self._process_count = jax.process_count()
         self._replicated = NamedSharding(self.mesh, P())
@@ -182,56 +184,22 @@ class LockstepMixin:
             isinstance(leaf, jax.Array) and leaf.sharding == sharding
             for leaf, sharding in pairs
         ):
-            # the restore_shardings path: orbax already materialized
-            # every leaf into the current mesh's layout (true at any
-            # world size — a host-numpy round trip here would double
-            # restore latency for nothing)
+            # the worker restored into ``state_shardings``: orbax
+            # already materialized every leaf into the current mesh's
+            # layout (true at any world size, since it materializes
+            # into these shardings and not the save-time layout — a
+            # host-numpy round trip here would double restore latency
+            # for nothing)
             return restored
         restored = jax.tree_util.tree_map(np.asarray, restored)
         return self._put_global(restored, self._state_shardings)
-
-    @property
-    def restore_shardings(self):
-        """Restore directly into the current mesh's global shardings
-        (orbax reads are cross-process collectives; every rank calls
-        restore at the same point — the first-batch hook does). A
-        checkpoint written by a different world size re-shards
-        implicitly because orbax materializes into these shardings,
-        not the save-time layout."""
-        return self._state_shardings
 
 
 class MultiHostSpmdTrainer(LockstepMixin, SpmdTrainer):
     """SpmdTrainer whose mesh spans every jax process."""
 
-    # explicit signature (not *args/**kwargs): the Worker feeds
-    # sharding_rules/batch_spec/mesh_config by inspecting the factory's
-    # parameters (worker.py), which a splat signature would hide
-    def __init__(
-        self,
-        model,
-        loss_fn,
-        optimizer,
-        compute_dtype=None,
-        seed=0,
-        mesh=None,
-        mesh_config=None,
-        sharding_rules=None,
-        batch_spec=None,
-        grad_accum_steps=1,
-    ):
-        super().__init__(
-            model,
-            loss_fn,
-            optimizer,
-            compute_dtype=compute_dtype,
-            seed=seed,
-            mesh=mesh,
-            mesh_config=mesh_config,
-            sharding_rules=sharding_rules,
-            batch_spec=batch_spec,
-            grad_accum_steps=grad_accum_steps,
-        )
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
         self._init_lockstep()
 
     def create_state(self, sample_features):

@@ -36,11 +36,16 @@ from elasticdl_tpu.train.train_state import (
     create_train_state,
     resolve_dtype,
 )
+from elasticdl_tpu.worker.trainer import Trainer
 
 logger = _logger_factory("elasticdl_tpu.parallel.spmd_trainer")
 
 
-class SpmdTrainer:
+class SpmdTrainer(Trainer):
+    takes = frozenset(
+        {"mesh", "sharding_rules", "batch_spec", "grad_accum_steps"}
+    )
+
     def __init__(
         self,
         model,
@@ -288,12 +293,6 @@ class SpmdTrainer:
             lambda leaf: jax.device_put(leaf, self._leaf_sharding(leaf)),
             batch,
         )
-
-    def ensure_state(self, state, batch):
-        if state is None:
-            with timing_utils.current().phase("state_init"):
-                return self.create_state(batch["features"])
-        return state
 
     def train_step(self, state, batch):
         """One step, in the phases of the loop thread's ledger: the

@@ -45,6 +45,7 @@ from elasticdl_tpu.train.train_state import (
     create_train_state,
     resolve_dtype,
 )
+from elasticdl_tpu.worker.trainer import Trainer
 
 logger = _logger_factory("elasticdl_tpu.train.sparse")
 
@@ -590,9 +591,12 @@ def make_row_grads_fn(model, loss_fn, specs, compute_dtype=None):
     return row_grads
 
 
-class SparseTrainer:
+class SparseTrainer(Trainer):
     """Trainer surface (create_state/train_step/eval_step) over dense
     on-device params + host-PS sparse tables."""
+
+    sparse = True
+    streams = True
 
     # the reference retried a rejected minibatch up to 64 times against
     # the sync PS (worker/worker.py:49,608)

@@ -84,6 +84,8 @@ class SparseSpmdTrainer(SparseTrainer):
     batch so state/batch shardings can be attached.
     """
 
+    takes = frozenset({"mesh", "sharding_rules"})
+
     def __init__(
         self,
         model,
@@ -430,37 +432,8 @@ class MultiHostSparseSpmdTrainer(LockstepMixin, SparseSpmdTrainer):
     # round-r and round-r+1 pushes paired with each other
     ROUND_SCOPED_PUSH = True
 
-    def __init__(
-        self,
-        model,
-        loss_fn,
-        optimizer,
-        specs,
-        ps_client,
-        compute_dtype=None,
-        seed=0,
-        mesh=None,
-        mesh_config=None,
-        sharding_rules=None,
-        cache_staleness=0,
-        cache_capacity=1_000_000,
-        device_tier=None,
-    ):
-        super().__init__(
-            model,
-            loss_fn,
-            optimizer,
-            specs,
-            ps_client,
-            compute_dtype=compute_dtype,
-            seed=seed,
-            mesh=mesh,
-            mesh_config=mesh_config,
-            sharding_rules=sharding_rules,
-            cache_staleness=cache_staleness,
-            cache_capacity=cache_capacity,
-            device_tier=device_tier,
-        )
+    def __init__(self, *args, **kwargs):
+        super().__init__(*args, **kwargs)
         self._init_lockstep()
         nproc = jax.process_count()
         if self.mesh.shape["dp"] != nproc:
@@ -590,39 +563,3 @@ class MultiHostSparseSpmdTrainer(LockstepMixin, SparseSpmdTrainer):
             self._eval_cache[1], prepared["features"]
         )
         return jax.tree_util.tree_map(np.asarray, outputs)
-
-
-def sparse_trainer_for(dense_factory):
-    """Map the worker's dense trainer choice onto the sparse
-    composition (replaces the round-3 silent fallback that forced every
-    sparse model onto the single-device SparseTrainer,
-    worker/worker.py:107-111)."""
-    if dense_factory is None:
-        return SparseTrainer
-    import inspect
-
-    try:
-        factory_params = inspect.signature(dense_factory).parameters
-    except (TypeError, ValueError):
-        factory_params = ()
-    if "specs" in factory_params:
-        return dense_factory  # already sparse-capable
-    from elasticdl_tpu.parallel.multihost_trainer import (
-        MultiHostSpmdTrainer,
-    )
-    from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
-    from elasticdl_tpu.worker.trainer import JaxTrainer
-
-    if isinstance(dense_factory, type):
-        if issubclass(dense_factory, MultiHostSpmdTrainer):
-            return MultiHostSparseSpmdTrainer
-        if issubclass(dense_factory, SpmdTrainer):
-            return SparseSpmdTrainer
-        if issubclass(dense_factory, JaxTrainer):
-            return SparseTrainer
-    raise ValueError(
-        "trainer factory %r cannot drive the host-PS sparse path and "
-        "has no sparse composition; use SparseTrainer, SpmdTrainer, or "
-        "MultiHostSpmdTrainer (or a factory accepting specs=)"
-        % (dense_factory,)
-    )

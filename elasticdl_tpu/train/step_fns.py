@@ -8,8 +8,11 @@ while replicating (or fsdp-sharding) parameters makes XLA place the
 collectives on ICI automatically.
 """
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from elasticdl_tpu.common.annotations import hot_path
 from elasticdl_tpu.data.pipeline import MASK_KEY
@@ -53,25 +56,68 @@ def guard_nonfinite_state(old_state, new_state, nonfinite):
     )
 
 
-# what a model's training outputs may carry besides logits and losses,
-# as device scalars the step hands out beside the health scalars: an
-# MoE LM's expert-load counters, a block-diffusion LM's noise facts and
-# a hyper-connected LM's facts, one a block
-# (``models/moe_transformer.py``)
-OUTPUT_KEYS = ("routing", "noise", "mhc")
-# and what the loss function may name of its own sum: a second loss
-# (a multi-token-prediction module's), by name
-LOSS_TERMS = "loss_terms"
-COUNTER_KEYS = OUTPUT_KEYS + (LOSS_TERMS,)
+class Fact(NamedTuple):
+    """A dict of device values a training step may hand out beside the
+    health scalars, and what the journal calls it."""
+
+    # the model's training outputs carry it under this key (the loss
+    # function's named terms have no key there), and so do the step's
+    # ``scalars`` and a trainer's ``facts``
+    key: str
+    # the journal's event (``observability/events.py:EVENT_TYPES``)
+    event: str
+    # (the step's name, the event's) for the fields the event takes,
+    # in its order; empty: all of them, under their own names
+    fields: tuple = ()
+    # parts of the loss: the worker's line names them after ``loss``
+    # and the event carries ``loss=`` beside them
+    of_loss: bool = False
+
+    def journal(self, value):
+        """The event's fields from the fetched dict: floats, and a
+        list where the fact is an array (one entry a block)."""
+        names = self.fields or tuple((name, name) for name in value)
+        return {
+            ours: np.asarray(value[theirs], float).tolist()
+            for theirs, ours in names if theirs in value}
 
 
-def _counters_of(outputs, terms=None):
-    """``(routing, noise, mhc, loss terms)``: each a dict of device
-    values or None, an empty pytree, so a model without them compiles
-    the program it compiled before."""
+# the one table of them. An MoE LM's expert-load counters (a layer
+# that keeps a balancing bias also reports its magnitude, one that
+# holds a share of its experts the pairs that share got, the rows of
+# its layers' buffers and those of them the step ran), a
+# block-diffusion LM's noise facts, a hyper-connected LM's, one a
+# block (``models/moe_transformer.py``), and what the loss function
+# names of its own sum (a multi-token-prediction module's loss)
+FACTS = (
+    Fact("routing", "moe_routing", (
+        ("load_max", "tokens_per_expert_max"),
+        ("load_mean", "tokens_per_expert_mean"),
+        ("entropy", "router_entropy"),
+        ("dropped", "dropped_pairs"),
+        ("bias_abs_max", "bias_abs_max"),
+        ("held", "held_pairs"),
+        ("rows_run", "held_rows_run"),
+        ("rows_buffer", "held_rows_buffer"))),
+    Fact("noise", "bd_noise"),
+    Fact("mhc", "mhc"),
+    Fact("loss_terms", "loss_terms", of_loss=True),
+)
+
+
+def facts_of(outputs, terms=None):
+    """``{key: dict of device values}`` for the facts that are there:
+    the table's keys among a model's training outputs (or a step's
+    scalars), the loss function's ``terms`` under the row that is
+    ``of_loss``. A model without them adds nothing to its step."""
     if not isinstance(outputs, dict):
-        return (None,) * len(OUTPUT_KEYS) + (terms,)
-    return tuple(outputs.get(key) for key in OUTPUT_KEYS) + (terms,)
+        outputs = {}
+    found = {
+        fact.key: outputs[fact.key] for fact in FACTS
+        if outputs.get(fact.key) is not None}
+    if terms is not None:
+        found.update((fact.key, terms) for fact in FACTS if fact.of_loss)
+    return found
 
 
 def _split_terms(value):
@@ -123,15 +169,12 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
     flag); with ``guard_nonfinite`` a nonfinite batch keeps the
     previous state in-graph (the skip sentinel). ``health=False`` is
     the exact pre-health program: no extra outputs (test-asserted).
-    Where the model's training outputs carry ``"routing"`` (an MoE
-    LM's expert-load counters), ``"noise"`` (a block-diffusion LM's
-    noise facts) or ``"mhc"`` (a hyper-connected LM's, an array a
-    fact, one entry a block), or the loss function returns named terms
-    beside its per-sample losses (``"loss_terms"``: a prediction
-    module's ``mtp_loss``), the dict has them under those names as
-    device values: they leave the step with the health scalars and cost no
-    fetch until someone reads them (the worker does on the steps it
-    logs).
+    Where the model's training outputs carry a fact of ``FACTS``, or
+    the loss function returns named terms beside its per-sample losses
+    (a prediction module's ``mtp_loss``), the dict has them under the
+    table's keys as device values: they leave the step with the health
+    scalars and cost no fetch until someone reads them (the worker
+    does on the steps it logs).
 
     ``grad_accum_steps=k`` splits the batch into k equal microbatches
     scanned sequentially, accumulating MASK-WEIGHTED gradient sums and
@@ -148,8 +191,8 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
 
     def _loss_sum(params, model_state, features, labels, mask, rngs):
         """(masked loss SUM, (mask weight, new model state, the model's
-        counters: ``_counters_of``)) — summed (not averaged) so
-        microbatch grads add linearly."""
+        facts: ``facts_of``)) — summed (not averaged) so microbatch
+        grads add linearly."""
         compute_params = params
         compute_features = features
         if compute_dtype is not None:
@@ -184,7 +227,7 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                         / jnp.maximum(weight, 1.0))
                     for name, term in terms.items()}
             return loss_sum, (
-                weight, new_model_state, _counters_of(outputs, terms)
+                weight, new_model_state, facts_of(outputs, terms)
             )
 
     def _apply_update(state, grads, loss, new_model_state):
@@ -215,13 +258,11 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         )
         rngs = step_rngs(state.step)
 
-        def finish(new_state, loss, grads, counters):
+        def finish(new_state, loss, grads, facts):
             if not health:
                 return new_state, loss
             scalars = health_scalars(loss, global_grad_norm(grads))
-            scalars.update(
-                (key, value) for key, value in zip(COUNTER_KEYS, counters)
-                if value is not None)
+            scalars.update(facts)
             if guard_nonfinite:
                 new_state = guard_nonfinite_state(
                     state, new_state, scalars["nonfinite"]
@@ -230,21 +271,21 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
 
         if grad_accum_steps == 1:
             def compute_loss(params):
-                loss_sum, (weight, new_model_state, counters) = _loss_sum(
+                loss_sum, (weight, new_model_state, facts) = _loss_sum(
                     params, state.model_state, features, labels, mask,
                     rngs,
                 )
                 return loss_sum / jnp.maximum(weight, 1.0), (
-                    new_model_state, counters
+                    new_model_state, facts
                 )
 
-            (loss, (new_model_state, counters)), grads = jax.value_and_grad(
+            (loss, (new_model_state, facts)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True
             )(state.params)
             new_state, loss = _apply_update(
                 state, grads, loss, new_model_state
             )
-            return finish(new_state, loss, grads, counters)
+            return finish(new_state, loss, grads, facts)
 
         k = int(grad_accum_steps)
 
@@ -281,7 +322,7 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 name: jax.random.fold_in(key, i)
                 for name, key in rngs.items()
             }
-            (loss_sum, (weight, model_state, counters)), grads = grad_fn(
+            (loss_sum, (weight, model_state, facts)), grads = grad_fn(
                 state.params, model_state, m_features, m_labels, m_mask,
                 micro_rngs,
             )
@@ -296,9 +337,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 weight_acc + weight,
                 model_state,
                 i + 1,
-            ), counters
+            ), facts
 
-        (grads_sum, loss_sum, weight, new_model_state, _), counters = (
+        (grads_sum, loss_sum, weight, new_model_state, _), facts = (
             jax.lax.scan(
                 body,
                 (zero_grads, 0.0, 0.0, state.model_state, 0),
@@ -312,9 +353,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         new_state, loss = _apply_update(
             state, grads, loss_sum / weight, new_model_state
         )
-        # the counters of the last microbatch stand for the step
-        counters = jax.tree_util.tree_map(lambda leaf: leaf[-1], counters)
-        return finish(new_state, loss, grads, counters)
+        # the facts of the last microbatch stand for the step
+        facts = jax.tree_util.tree_map(lambda leaf: leaf[-1], facts)
+        return finish(new_state, loss, grads, facts)
 
     return train_step
 

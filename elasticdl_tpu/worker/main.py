@@ -43,6 +43,7 @@ from elasticdl_tpu.common.log_utils import (  # noqa: E402
 )
 from elasticdl_tpu.data.readers import create_data_reader  # noqa: E402
 from elasticdl_tpu.worker.master_client import MasterClient  # noqa: E402
+from elasticdl_tpu.worker.trainer import trainer_class  # noqa: E402
 from elasticdl_tpu.worker.worker import Worker  # noqa: E402
 
 
@@ -172,21 +173,6 @@ def main(argv=None):
             args.training_data or args.validation_data or args.prediction_data
         )
         reader = create_data_reader(data_origin, **reader_params)
-        # More than one local device: run the SPMD trainer over the chip mesh
-        # (gradients ride ICI inside the compiled step). A jax.distributed
-        # world of >1 processes gets the lockstep multi-host trainer — the
-        # mesh spans the processes and dp psums ride DCN.
-        trainer_factory = None
-        if jax.process_count() > 1:
-            from elasticdl_tpu.parallel.multihost_trainer import (
-                MultiHostSpmdTrainer,
-            )
-
-            trainer_factory = MultiHostSpmdTrainer
-        elif jax.device_count() > 1:
-            from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
-
-            trainer_factory = SpmdTrainer
         # --mesh "fsdp=4" etc: explicit axis sizes; dp=-1 absorbs whatever
         # devices remain, so the same flag survives elastic world-size
         # changes (a relaunch at a smaller world just gets a smaller dp).
@@ -205,7 +191,9 @@ def main(argv=None):
             mode=args.mode,
             compute_dtype=args.compute_dtype or None,
             report_version_steps=args.report_version_steps,
-            trainer_factory=trainer_factory,
+            trainer_factory=trainer_class(
+                jax.process_count(), jax.device_count()
+            ),
             ps_addrs=args.ps_addrs or None,
             checkpoint_dir=args.checkpoint_dir,
             checkpoint_steps=args.checkpoint_steps,

@@ -1,4 +1,6 @@
-"""Single-chip trainer: plain jit around the shared step functions.
+"""What the worker knows of a trainer (``Trainer``), the one place that
+picks and builds one (``trainer_class``, ``build_trainer``), and the
+single-chip trainer: plain jit around the shared step functions.
 
 This replaces the reference's TF2-eager worker step + gRPC
 push_gradients/pull_variables round trip (worker/worker.py:517-649,
@@ -8,12 +10,19 @@ parallel/spmd_trainer.py — both wrap the same step functions
 (train/step_fns.py).
 """
 
+import inspect
+
 import jax
 import numpy as np
 
 from elasticdl_tpu.common import timing_utils
+from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.observability import device as device_obs
-from elasticdl_tpu.train.step_fns import make_eval_step, make_train_step
+from elasticdl_tpu.train.step_fns import (
+    facts_of,
+    make_eval_step,
+    make_train_step,
+)
 from elasticdl_tpu.train.train_state import (
     TrainState,
     abstract_train_state,
@@ -21,8 +30,185 @@ from elasticdl_tpu.train.train_state import (
     resolve_dtype,
 )
 
+logger = _logger_factory("elasticdl_tpu.worker.trainer")
 
-class JaxTrainer:
+
+class Trainer:
+    """The contract between the worker's loop (``worker/worker.py``)
+    and what it drives: every member the loop reads, with the value a
+    trainer that has nothing to say leaves in place."""
+
+    # what ``build_trainer`` may hand the constructor besides the
+    # model, the loss, the optimizer, ``compute_dtype`` and ``seed``:
+    # of "mesh", "sharding_rules", "batch_spec", "grad_accum_steps"
+    takes = frozenset()
+    # the constructor takes ``specs`` and ``ps_client``: embedding
+    # tables on parameter servers, the dense model on the device
+    sparse = False
+    # the mesh spans jax processes: the loop keeps them in step through
+    # ``consensus`` and ``process_count`` and saves what
+    # ``checkpoint_state`` returns (``parallel/multihost_trainer.py``)
+    lockstep = False
+    # ``train_stream`` pipelines the parameter servers' pulls and
+    # pushes under the device step (``train/sparse.py``)
+    streams = False
+
+    # the newest step's facts still on the device, by the keys of
+    # ``train/step_fns.py:FACTS``; the loop fetches them on the steps
+    # it logs
+    facts = None
+    # a ``train/health.py:HealthTracker`` where the step is watched
+    health = None
+    # a ``train/device_tier.py:DeviceEmbeddingTier`` where rows live
+    # on the device
+    device_tier = None
+    # a ledger of the trainer's own (``common/timing_utils.Timing``)
+    # where the step has phases the loop cannot see
+    timing = None
+    # the compiled step by XLA's cost model, 0.0 until it compiled
+    cost_step_flops = 0.0
+    cost_step_bytes = 0.0
+    # the dense plane's mesh and modelled traffic, where there is one
+    mesh_shape_str = ""
+    collective_bytes_per_step = 0.0
+    brownout_skipped_pushes = 0
+    # a TrainState-shaped tree of shardings a restore lays the state
+    # out with; None: on the default device
+    state_shardings = None
+
+    def create_state(self, sample_features):
+        raise NotImplementedError
+
+    def ensure_state(self, state, batch):
+        if state is None:
+            with timing_utils.current().phase("state_init"):
+                return self.create_state(batch["features"])
+        return state
+
+    def train_step(self, state, batch):
+        """``(new state, loss)``, the loss still on the device."""
+        raise NotImplementedError
+
+    def eval_step(self, state, batch):
+        """The model's outputs for the batch, on the host."""
+        raise NotImplementedError
+
+    def abstract_state(self, sample_features):
+        """The restore's template as shapes alone, or None where the
+        trainer has to initialise a state to know them."""
+        return None
+
+    def adopt_restored(self, restored):
+        return restored
+
+    def join_pushes(self):
+        """Wait for gradients still on their way to a parameter
+        server."""
+
+    def flush_device_tier(self):
+        """Write the device tier's dirty rows back."""
+
+    def close(self):
+        """Release what the trainer holds, at the end of its life."""
+
+
+def trainer_class(processes=1, devices=1, sparse=False, factory=None):
+    """The class that trains a dense or a ``sparse`` model on
+    ``devices`` devices of ``processes`` jax processes. More than one
+    device: the SPMD trainer over the chip mesh (gradients ride ICI
+    inside the compiled step); more than one process: the lockstep
+    trainer, whose mesh spans them (dp psums ride DCN). ``factory``
+    overrides the count's choice; a dense class handed in for a sparse
+    model stands for its sparse composition."""
+    from elasticdl_tpu.parallel.multihost_trainer import (
+        MultiHostSpmdTrainer,
+    )
+    from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
+    from elasticdl_tpu.train.sparse import SparseTrainer
+    from elasticdl_tpu.train.sparse_spmd import (
+        MultiHostSparseSpmdTrainer,
+        SparseSpmdTrainer,
+    )
+
+    if factory is None:
+        factory = (
+            MultiHostSpmdTrainer if processes > 1
+            else SpmdTrainer if devices > 1
+            else JaxTrainer
+        )
+    if not sparse or getattr(factory, "sparse", False):
+        return factory
+    for dense, composed in (
+        (MultiHostSpmdTrainer, MultiHostSparseSpmdTrainer),
+        (SpmdTrainer, SparseSpmdTrainer),
+        (JaxTrainer, SparseTrainer),
+    ):
+        if isinstance(factory, type) and issubclass(factory, dense):
+            return composed
+    raise ValueError(
+        "trainer factory %r cannot drive the host-PS sparse path and "
+        "has no sparse composition; use SparseTrainer, SpmdTrainer, or "
+        "MultiHostSpmdTrainer (or a Trainer that is sparse)"
+        % (factory,)
+    )
+
+
+def build_trainer(spec, factory=None, *, minibatch_size, compute_dtype=None,
+                  seed=0, mesh_config=None, grad_accum_steps=1,
+                  ps_client=None, cache_staleness=0):
+    """The trainer for a zoo's ``spec``: ``factory`` (None: the
+    single-device trainer) or its sparse composition where the model
+    declares embedding tables, handed what its class ``takes``."""
+    sparse = bool(spec.sparse_embedding_specs)
+    factory = trainer_class(sparse=sparse, factory=factory)
+    kwargs = dict(
+        loss_fn=spec.loss,
+        optimizer=spec.optimizer(),
+        compute_dtype=compute_dtype,
+        seed=seed,
+    )
+    if sparse:
+        kwargs["specs"] = spec.sparse_embedding_specs(
+            batch_size=minibatch_size
+        )
+        kwargs["ps_client"] = ps_client
+        if cache_staleness > 0:
+            kwargs["cache_staleness"] = cache_staleness
+    if grad_accum_steps > 1:
+        if "grad_accum_steps" in factory.takes:
+            kwargs["grad_accum_steps"] = grad_accum_steps
+        else:
+            logger.warning(
+                "--grad_accum_steps ignored: trainer %s does not "
+                "support it", factory.__name__,
+            )
+    if "sharding_rules" in factory.takes and spec.sharding_rules:
+        kwargs["sharding_rules"] = spec.sharding_rules()
+    if "batch_spec" in factory.takes and spec.batch_spec:
+        kwargs["batch_spec"] = spec.batch_spec()
+    mesh = None
+    if "mesh" in factory.takes:
+        from elasticdl_tpu.parallel.mesh import build_mesh
+
+        if mesh_config is None and spec.mesh_config:
+            mesh_config = spec.mesh_config(jax.device_count())
+        # built here even without a mesh flag (every device on dp) so
+        # a mesh-aware model always receives the mesh its trainer
+        # shards over
+        mesh = kwargs["mesh"] = build_mesh(mesh_config)
+    # Mesh-aware models (pipeline stages over pp, ring attention over
+    # sp) take the mesh at construction so their internal shard_map
+    # schedules target the same mesh the trainer shards over.
+    if "mesh" in inspect.signature(spec.custom_model).parameters:
+        kwargs["model"] = spec.custom_model(mesh=mesh)
+    else:
+        kwargs["model"] = spec.custom_model()
+    return factory(**kwargs)
+
+
+class JaxTrainer(Trainer):
+    takes = frozenset({"grad_accum_steps"})
+
     def __init__(
         self,
         model,
@@ -49,15 +235,6 @@ class JaxTrainer:
         else:
             self.health = health
         self._health_on = self.health is not None
-        # an MoE model's routing counters of the newest step, still on
-        # the device (train/step_fns.py); None for any other model
-        self.routing = None
-        # a block-diffusion model's noise facts of the newest step, the
-        # same way; a hyper-connected model's facts; and what the loss
-        # function named of its sum (a prediction module's loss)
-        self.noise = None
-        self.mhc = None
-        self.loss_terms = None
         compute_dtype = resolve_dtype(compute_dtype)
         # recompile sentinels (ISSUE 18): instrumented_jit IS jax.jit
         # when EDL_DEVICE_OBS=0; on, each compile is counted, timed,
@@ -92,12 +269,6 @@ class JaxTrainer:
             self._model, self._tx, init_rng, sample_features
         )
 
-    def ensure_state(self, state, batch):
-        if state is None:
-            with timing_utils.current().phase("state_init"):
-                return self.create_state(batch["features"])
-        return state
-
     def train_step(self, state, batch):
         """One step, in the phases of the loop thread's ledger: the
         call of the jitted step until it returns (``dispatch``; the
@@ -114,10 +285,7 @@ class JaxTrainer:
                 return self._train_step(state, batch)
         with phase("dispatch"):
             state, loss, scalars = self._train_step(state, batch)
-        self.routing = scalars.get("routing")
-        self.noise = scalars.get("noise")
-        self.mhc = scalars.get("mhc")
-        self.loss_terms = scalars.get("loss_terms")
+        self.facts = facts_of(scalars)
         # one small host transfer per batch; a skip-sentinel batch
         # already kept its state in-graph (nothing else to drop on
         # the dense path — there is no PS push); halt raises

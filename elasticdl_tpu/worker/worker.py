@@ -32,9 +32,10 @@ from elasticdl_tpu.data.pipeline import (
 )
 from elasticdl_tpu.models.registry import get_model_spec
 from elasticdl_tpu.proto import elasticdl_tpu_pb2 as pb
+from elasticdl_tpu.train import step_fns
 from elasticdl_tpu.train.health import HealthSentinelError
 from elasticdl_tpu.worker.task_data_service import TaskDataService
-from elasticdl_tpu.worker.trainer import JaxTrainer
+from elasticdl_tpu.worker.trainer import build_trainer
 
 logger = _logger_factory("elasticdl_tpu.worker.worker")
 
@@ -106,27 +107,6 @@ class _BatchPoller:
         return item, False
 
 
-def emit_moe_routing(step, routing):
-    """The ``moe_routing`` journal event from a step's merged routing
-    counters (host floats). Only a layer that keeps a balancing bias
-    (sigmoid scoring) reports its magnitude, and only one that holds a
-    share of its experts the pairs that share got, the rows of all its
-    layers' buffers and those of them the step ran."""
-    events.emit(
-        "moe_routing",
-        step=step,
-        tokens_per_expert_max=routing["load_max"],
-        tokens_per_expert_mean=routing["load_mean"],
-        router_entropy=routing["entropy"],
-        dropped_pairs=routing["dropped"],
-        **{name: routing[k] for k, name in (
-            ("bias_abs_max", "bias_abs_max"),
-            ("held", "held_pairs"),
-            ("rows_run", "held_rows_run"),
-            ("rows_buffer", "held_rows_buffer")) if k in routing},
-    )
-
-
 class Worker:
     def __init__(
         self,
@@ -175,14 +155,7 @@ class Worker:
         self.tds = TaskDataService(
             master_client, data_reader, wait_sleep_secs=wait_sleep_secs
         )
-        trainer_kwargs = dict(
-            loss_fn=self.spec.loss,
-            optimizer=self.spec.optimizer(),
-            compute_dtype=compute_dtype,
-            seed=seed,
-        )
-        import inspect
-
+        ps_client = None
         if self.spec.sparse_embedding_specs:
             # Sparse model: host-PS embedding tables + dense on device.
             if not ps_addrs:
@@ -191,82 +164,36 @@ class Worker:
                     "needs --ps_addrs pointing at parameter servers"
                     % model_zoo_module
                 )
-            from elasticdl_tpu.train.sparse_spmd import sparse_trainer_for
             from elasticdl_tpu.worker.ps_client import PSClient
 
-            # Map the dense trainer choice onto the sparse composition:
-            # SpmdTrainer -> SparseSpmdTrainer (dense plane over the
-            # local mesh), MultiHostSpmdTrainer ->
-            # MultiHostSparseSpmdTrainer (N workers share one dense
-            # model via lockstep psum while embeddings ride the PS).
-            # Round 3 silently forced every sparse model onto the
-            # single-device SparseTrainer here; that restriction is
-            # gone (round-3 VERDICT missing #1 / weak #2).
-            factory = sparse_trainer_for(trainer_factory)
-            trainer_kwargs["specs"] = self.spec.sparse_embedding_specs(
-                batch_size=minibatch_size
-            )
-            trainer_kwargs["ps_client"] = PSClient(
+            ps_client = PSClient(
                 ps_addrs, worker_id=self._mc.worker_id,
                 # master-assigned relaunch epoch (reset_worker in
                 # worker/main.py) so a relaunch on a clock-skewed host
                 # still orders after its dead predecessor at the sync PS
                 incarnation=getattr(self._mc, "incarnation", None),
             )
-            if sparse_cache_staleness > 0:
-                trainer_kwargs["cache_staleness"] = sparse_cache_staleness
-        else:
-            factory = trainer_factory or JaxTrainer
-        # SPMD-capable factories take the model's sharding rules; the
-        # single-chip trainer does not.
-        factory_params = inspect.signature(factory).parameters
-        if grad_accum_steps > 1:
-            if "grad_accum_steps" in factory_params:
-                trainer_kwargs["grad_accum_steps"] = grad_accum_steps
-            else:
-                logger.warning(
-                    "--grad_accum_steps ignored: trainer %s does not "
-                    "support it", factory.__name__,
-                )
-        if "sharding_rules" in factory_params and self.spec.sharding_rules:
-            trainer_kwargs["sharding_rules"] = self.spec.sharding_rules()
-        if "batch_spec" in factory_params and self.spec.batch_spec:
-            trainer_kwargs["batch_spec"] = self.spec.batch_spec()
-        mesh = None
-        if "mesh_config" in factory_params or "mesh" in factory_params:
-            if mesh_config is None and self.spec.mesh_config:
-                import jax
-
-                mesh_config = self.spec.mesh_config(jax.device_count())
-            if "mesh" in factory_params:
-                from elasticdl_tpu.parallel.mesh import build_mesh
-
-                # built here even without a mesh flag (every device on
-                # dp) so a mesh-aware model always receives the mesh
-                # its trainer shards over
-                mesh = build_mesh(mesh_config)
-                trainer_kwargs["mesh"] = mesh
-            elif mesh_config is not None:
-                trainer_kwargs["mesh_config"] = mesh_config
-        # Mesh-aware models (pipeline stages over pp, ring attention over
-        # sp) take the mesh at construction so their internal shard_map
-        # schedules target the same mesh the trainer shards over.
-        model_params = inspect.signature(self.spec.custom_model).parameters
-        if "mesh" in model_params:
-            trainer_kwargs["model"] = self.spec.custom_model(mesh=mesh)
-        else:
-            trainer_kwargs["model"] = self.spec.custom_model()
-        self.trainer = factory(**trainer_kwargs)
+        self.trainer = build_trainer(
+            self.spec,
+            trainer_factory,
+            minibatch_size=minibatch_size,
+            compute_dtype=compute_dtype,
+            seed=seed,
+            mesh_config=mesh_config,
+            grad_accum_steps=grad_accum_steps,
+            ps_client=ps_client,
+            cache_staleness=sparse_cache_staleness,
+        )
         # lockstep multi-host SPMD: the trainer's mesh spans jax
         # processes and exposes the consensus collective
         # (parallel/multihost_trainer.py)
-        self._lockstep = hasattr(self.trainer, "consensus")
+        self._lockstep = self.trainer.lockstep
         # pipelined sparse stream only where it exists AND the model is
         # sparse (async-PS staleness envelope; sparse.py train_stream)
         self._sparse_pipeline = bool(
             sparse_pipeline
             and self.spec.sparse_embedding_specs
-            and hasattr(self.trainer, "train_stream")
+            and self.trainer.streams
         )
         self._sparse_push_interval = max(1, sparse_push_interval)
         self.state = None
@@ -436,7 +363,7 @@ class Worker:
         )
         # device embedding tier (ISSUE 6): hot-set health rides the
         # same piggyback into the master's /statusz fleet view
-        tier = getattr(self.trainer, "device_tier", None)
+        tier = self.trainer.device_tier
         if tier is not None:
             stats = tier.stats()
             blob.tier_hit_rate = stats["hit_rate"]
@@ -448,7 +375,7 @@ class Worker:
         # this worker's model — loss EWMA, grad norm, nonfinite tallies
         # — feeding the master's nonfinite_loss / loss_spike /
         # grad_explosion detectors
-        tracker = getattr(self.trainer, "health", None)
+        tracker = self.trainer.health
         if tracker is not None:
             stats = tracker.stats()
             blob.health_loss_ewma = stats["loss_ewma"]
@@ -474,12 +401,8 @@ class Worker:
             blob.device_live_buffers = dev["device_live_buffers"]
             blob.h2d_bytes = dev["h2d_bytes"]
             blob.d2h_bytes = dev["d2h_bytes"]
-            blob.cost_step_flops = float(
-                getattr(self.trainer, "cost_step_flops", 0.0) or 0.0
-            )
-            blob.cost_step_bytes = float(
-                getattr(self.trainer, "cost_step_bytes", 0.0) or 0.0
-            )
+            blob.cost_step_flops = self.trainer.cost_step_flops
+            blob.cost_step_bytes = self.trainer.cost_step_bytes
             if tier is not None:
                 blob.tier_hbm_bytes = tier.hbm_bytes()
         # overload plane (ISSUE 19): this process's circuit-breaker /
@@ -489,8 +412,8 @@ class Worker:
         blob.circuit_open_count = ostats["circuit_open_count"]
         blob.degraded_pulls = ostats["degraded_pulls"]
         blob.retry_budget_exhausted = ostats["retry_budget_exhausted"]
-        blob.brownout_skipped_pushes = getattr(
-            self.trainer, "brownout_skipped_pushes", 0
+        blob.brownout_skipped_pushes = (
+            self.trainer.brownout_skipped_pushes
         )
         # dense data plane (ISSUE 20): mesh topology + collective
         # traffic of the GSPMD dense step, so /statusz and the
@@ -499,16 +422,13 @@ class Worker:
         # training under (-1 until the first heartbeat lands); the
         # share is the device-step fraction of batch wall time (1.0 on
         # a pure-dense trainer — the PS carries nothing).
-        blob.mesh_shape = str(
-            getattr(self.trainer, "mesh_shape_str", "") or ""
-        )
+        blob.mesh_shape = self.trainer.mesh_shape_str
         blob.mesh_epoch = (
             -1 if self._seen_mesh_epoch is None
             else int(self._seen_mesh_epoch)
         )
-        blob.collective_bytes_per_step = float(
-            getattr(self.trainer, "collective_bytes_per_step", 0.0)
-            or 0.0
+        blob.collective_bytes_per_step = (
+            self.trainer.collective_bytes_per_step
         )
         blob.dense_step_share = self._dense_share_ewma
         return blob
@@ -557,7 +477,7 @@ class Worker:
         # portion in their own ledger ("batch_process" there
         # excludes PS pull/push); a trainer without one (JaxTrainer,
         # SpmdTrainer) IS the device step end-to-end, share 1.0.
-        trainer_timing = getattr(self.trainer, "timing", None)
+        trainer_timing = self.trainer.timing
         dense_secs = (
             trainer_timing.last_seconds.get("batch_process")
             if trainer_timing is not None
@@ -738,9 +658,7 @@ class Worker:
         train-end export — so an in-flight push either lands or raises
         here instead of silently outliving the boundary. No-op for
         dense trainers and with async push off."""
-        join = getattr(self.trainer, "join_pushes", None)
-        if join is not None:
-            join()
+        self.trainer.join_pushes()
 
     def _flush_device_tier(self):
         """Device-tier writeback barrier (train/device_tier.py):
@@ -748,9 +666,7 @@ class Worker:
         set's dirty rows back to the PS first, so the PS-side state
         those artifacts derive from carries the tier's updates. No-op
         for dense trainers and with the tier off."""
-        flush = getattr(self.trainer, "flush_device_tier", None)
-        if flush is not None:
-            flush()
+        self.trainer.flush_device_tier()
 
     def _save_checkpoint(self):
         # in-flight sparse pushes land before the version is stamped
@@ -848,42 +764,26 @@ class Worker:
             # nothing (SpmdTrainer) this is the loop's first read of a
             # device value and waits for the step; after JaxTrainer's
             # health fetch the value is already on the host
-            routing = getattr(self.trainer, "routing", None)
-            noise = getattr(self.trainer, "noise", None)
-            mhc = getattr(self.trainer, "mhc", None)
-            terms = getattr(self.trainer, "loss_terms", None)
+            facts = self.trainer.facts or {}
             with phase("device_wait"):
                 loss_value = float(loss)
-                if routing:
-                    # the expert layers' counters come with the loss,
-                    # on the steps that log and on no other
-                    routing = {k: float(v) for k, v in routing.items()}
-                if noise:
-                    # and a block-diffusion step's noise facts
-                    noise = {k: float(v) for k, v in noise.items()}
-                if mhc:
-                    # a hyper-connected path's facts, one a block
-                    mhc = {k: [float(x) for x in np.asarray(v)]
-                           for k, v in mhc.items()}
-                if terms:
-                    # what the loss function named of its sum
-                    terms = {k: float(v) for k, v in terms.items()}
+                # what the model handed out of the step comes with the
+                # loss, on the steps that log and on no other
+                fetched = [
+                    (fact, fact.journal(facts[fact.key]))
+                    for fact in step_fns.FACTS if facts.get(fact.key)
+                ]
             with phase("log"):
                 logger.info(
                     "step %d loss %.6f%s", self._version, loss_value,
                     "".join(" %s %.6f" % item
-                            for item in sorted((terms or {}).items())),
+                            for fact, fields in fetched if fact.of_loss
+                            for item in sorted(fields.items())),
                 )
-                if routing:
-                    emit_moe_routing(self._version, routing)
-                if noise:
-                    events.emit("bd_noise", step=self._version, **noise)
-                if mhc:
-                    events.emit("mhc", step=self._version, **mhc)
-                if terms:
-                    events.emit(
-                        "loss_terms", step=self._version, loss=loss_value,
-                        **terms)
+                for fact, fields in fetched:
+                    if fact.of_loss:
+                        fields = dict(loss=loss_value, **fields)
+                    events.emit(fact.event, step=self._version, **fields)
         with phase("callbacks"):
             for cb in self._callbacks:
                 cb.on_batch_end(self._version, loss)
@@ -1192,9 +1092,8 @@ class Worker:
             self.tds.report_pending_failed(str(e))
         finally:
             self._timing.report("training stream")
-            trainer_timing = getattr(self.trainer, "timing", None)
-            if trainer_timing is not None:
-                trainer_timing.report("sparse trainer")
+            if self.trainer.timing is not None:
+                self.trainer.timing.report("sparse trainer")
 
     def _restore_from_checkpoint(self, batch):
         """Resume from --checkpoint_dir_for_init on the first batch.
@@ -1210,11 +1109,11 @@ class Worker:
         """
         from elasticdl_tpu.train.checkpoint import DenseCheckpointManager
 
-        if hasattr(self.trainer, "abstract_state"):
-            # Shape-only template: never hold init + restored state at
-            # once (a ZeRO-sharded model near HBM capacity would OOM).
-            template = self.trainer.abstract_state(batch["features"])
-        else:
+        # Shape-only template where the trainer has one: never hold
+        # init + restored state at once (a ZeRO-sharded model near HBM
+        # capacity would OOM).
+        template = self.trainer.abstract_state(batch["features"])
+        if template is None:
             self.state = self.trainer.ensure_state(self.state, batch)
             template = self.state
         import os as _os
@@ -1245,11 +1144,10 @@ class Worker:
             # mesh's shardings (a cross-process collective — every rank
             # reaches this first-batch hook); adopt_restored below
             # passes the already-global result through
-            if hasattr(self.trainer, "restore_shardings"):
-                shardings = self.trainer.restore_shardings
-            else:
-                shardings = getattr(self.trainer, "state_shardings", None)
-            restored = mgr.restore(template=template, shardings=shardings)
+            restored = mgr.restore(
+                template=template,
+                shardings=self.trainer.state_shardings,
+            )
         except Exception as e:
             raise CheckpointRestoreError(
                 "restore from --checkpoint_dir_for_init=%r failed: %s"
@@ -1274,9 +1172,7 @@ class Worker:
                 "checkpoint" % self._init_checkpoint_dir
             )
         self._restore_attempted = True
-        if hasattr(self.trainer, "adopt_restored"):
-            restored = self.trainer.adopt_restored(restored)
-        self.state = restored
+        self.state = restored = self.trainer.adopt_restored(restored)
         self._version = int(restored.step)
         logger.info(
             "Resumed from checkpoint at version %d", self._version
@@ -1456,9 +1352,7 @@ class Worker:
                 # release the sparse trainer's async-push executor
                 # (joins its in-flight push; failures were already
                 # surfaced at the stream boundary, so close only logs)
-                close = getattr(self.trainer, "close", None)
-                if close is not None:
-                    close()
+                self.trainer.close()
                 if self._checkpoint_mgr is not None:
                     # Flush any in-flight orbax commit before process
                     # exit.

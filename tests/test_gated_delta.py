@@ -4,7 +4,6 @@ equations, at small sizes on the CPU with seeded inputs. Float64 where
 the comparison is exact (the chunked form reorders sums, nothing else),
 bfloat16 where the precision rules are the subject."""
 
-import functools
 import logging
 
 import jax
@@ -22,31 +21,12 @@ from elasticdl_tpu.ops.gated_delta import (
     gated_delta_rule,
     unit_lower_inverse,
 )
-
-
-@pytest.fixture
-def x64():
-    jax.config.update("jax_enable_x64", True)
-    yield
-    jax.config.update("jax_enable_x64", False)
-
-
-def _inputs(seq, dtype, decay=1.0, seed=0, batch=2, hk=2, hv=4, dim=16):
-    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    q = unit(jax.random.normal(keys[0], (batch, hk, seq, dim))) * dim ** -0.5
-    k = unit(jax.random.normal(keys[1], (batch, hk, seq, dim)))
-    v = jax.random.normal(keys[2], (batch, hv, seq, dim))
-    g = -decay * jax.random.uniform(keys[3], (batch, hv, seq))
-    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, hv, seq)))
-    return tuple(x.astype(dtype) for x in (q, k, v)) + (
-        g.astype(jnp.promote_types(dtype, jnp.float32)),
-        beta.astype(jnp.promote_types(dtype, jnp.float32)))
-
-
-def _value_and_grads(rule, args):
-    loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
-    return (rule(*args),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+from tests.gdn_common import (  # noqa: F401
+    _force_pallas,
+    _inputs,
+    _value_and_grads,
+    x64,
+)
 
 
 @pytest.mark.parametrize("decay", [1e-3, 1.0, 30.0],
@@ -179,15 +159,6 @@ def test_the_kernel_s_vjp(monkeypatch, size, count):
     np.testing.assert_allclose(got, solve, rtol=0, atol=1e-5 * scale)
 
 
-def _force_pallas(monkeypatch):
-    """What a TPU backend would choose, run by the interpreter."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for name in ("gdn_inverse_fwd", "gdn_inverse_bwd", "gdn_scan_fwd",
-                 "gdn_scan_bwd", "gdn_prepare_fwd", "gdn_prepare_bwd"):
-        monkeypatch.setattr(gated_delta, name, functools.partial(
-            getattr(gated_delta, name), interpret=True))
-
-
 def test_the_rule_s_gradients_are_equal_between_the_two_paths(monkeypatch):
     """``jax.grad`` of the rule at 512 tokens, chunk 64, float32: the
     kernels' path against XLA's, to float32 rounding."""
@@ -277,520 +248,6 @@ def test_the_block_fits_its_budget():
                 block, size, arrays) <= gated_delta._INVERSE_BLOCK_BYTES
     assert (gated_delta._INVERSE_BLOCK_BYTES
             < gated_delta._INVERSE_VMEM_LIMIT)
-
-
-# ---------------------------------------------- the scan's kernels
-# ``gdn_scan_fwd`` / ``gdn_scan_bwd`` in interpret mode on the CPU
-# (ISSUE 34): the chunk-to-chunk recurrence with the state in VMEM
-# against the ``lax.scan`` over the same operands, and the rule by them
-# against the per-token loop. Key and value widths of 128: the kernels
-# take whole lane rows.
-
-
-def _split_inputs(num, chunk, rep, dtype, decay=2.0, hk=2, seed=0):
-    """q, k, v, g, beta of ``num`` chunks as ``gated_delta_rule`` splits
-    them for a segment: key-like (1, Hk, 1, N, C, 128), value-like (1,
-    Hk, R, N, C, ...)."""
-    q, k, v, g, beta = _inputs(
-        num * chunk, dtype, decay=decay, seed=seed, batch=1, hk=hk,
-        hv=hk * rep, dim=128)
-    split = lambda x, heads, *rest: x.reshape(
-        (1,) + heads + (num, chunk) + rest)
-    return (split(q, (hk, 1), 128), split(k, (hk, 1), 128),
-            split(v, (hk, rep), 128), split(g, (hk, rep)),
-            split(beta, (hk, rep)))
-
-
-def _segment_operands(chunk, rep, dtype, num=4, seed=0):
-    """A segment's operands as ``_chunk_operands`` builds them (batch 1,
-    2 key heads, ``num`` chunks) and a non-zero entering state."""
-    operands = gated_delta._chunk_operands(
-        *_split_inputs(num, chunk, rep, dtype, seed=seed), jnp.float32, "xla")
-    state = 0.3 * jax.random.normal(
-        jax.random.PRNGKey(seed + 1), (1, 2, rep, 128, 128))
-    return (state,) + operands
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("chunk,rep", [(64, 1), (64, 2), (128, 1), (128, 2)],
-                         ids=["64-rep1", "64-rep2", "128-rep1", "128-rep2"])
-def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, dtype):
-    """``O``, the leaving state and ``V'`` from a non-zero entering
-    state, and the gradients of all six operands and the entering
-    state's: in float32 equal to rounding; in bfloat16 the forward bit
-    for bit (the same four products at the same precision) and the
-    backward to the operands' rounding (the kernel sums ``dV'`` and
-    ``dS`` in float32 and rounds once where autodiff rounds each
-    term)."""
-    args = _segment_operands(chunk, rep, dtype)
-    weight = jax.random.normal(jax.random.PRNGKey(9), args[-1].shape)
-
-    def outputs(carry):
-        def loss(*a):
-            leaving, o = carry(*a, dtype)
-            return ((o.astype(jnp.float32) * weight).sum()
-                    + (leaving * leaving).sum())
-        return carry(*args, dtype) + jax.grad(
-            loss, argnums=tuple(range(7)))(*args)
-
-    want = outputs(gated_delta._scan_xla)
-    _force_pallas(monkeypatch)
-    got = outputs(gated_delta._scan_pallas)
-    exact = dtype == jnp.float32
-    names = ("leaving", "o", "d_state", "d_last", "d_w", "d_k_onto",
-             "d_q_into", "d_attn", "d_u")
-    for name, a, b in zip(names, got, want):
-        assert a.shape == b.shape, name
-        a, b = np.float32(a), np.float32(b)
-        scale = float(np.abs(b).max())
-        if name == "o" and not exact:
-            np.testing.assert_array_equal(
-                a, np.float32(jnp.asarray(b).astype(dtype)), err_msg=name)
-            continue
-        if name == "leaving":
-            np.testing.assert_allclose(a, b, rtol=0, atol=2e-6 * scale)
-            continue
-        if name == "d_attn":
-            # above the diagonal P is masked: its gradient there is
-            # dropped by the mask's own transpose, outside the scan
-            a, b = np.tril(a), np.tril(b)
-        np.testing.assert_allclose(
-            a, b, rtol=0, atol=(2e-5 if exact else 2e-2) * scale,
-            err_msg=name)
-    # V' and the states the backward reads, as the scan hands them on
-    state, last, w, k_onto, q_into, attn, u = args
-    decay = jnp.broadcast_to(jnp.exp(last)[..., None], last.shape + (128,))
-    leaving, o, new_v, states = gated_delta.gdn_scan_fwd(
-        state, decay, w, k_onto, q_into.astype(dtype), attn.astype(dtype),
-        u, residuals=True)
-    np.testing.assert_array_equal(np.float32(leaving), np.float32(got[0]))
-    assert new_v.dtype == dtype and states.dtype == jnp.float32
-    np.testing.assert_array_equal(
-        np.float32(states[:, :, :, 0]), np.float32(state))
-    first = u[..., 0, :, :] - gated_delta._matmul(
-        w[..., 0, :, :], state, dtype)
-    np.testing.assert_allclose(
-        np.float32(new_v[:, :, :, 0]), np.float32(first), rtol=0,
-        atol=(1e-5 if exact else 1e-2) * float(jnp.abs(first).max()))
-
-
-@pytest.mark.parametrize("seq,chunk,segment,hk,hv", [
-    (512, 64, 128, 2, 4),   # one segment of eight chunks: two grid steps
-    (256, 64, 1, 2, 2),     # four segments, the state carried between
-    (300, 64, 2, 1, 2),     # a length the segment does not divide
-    (256, 128, 1, 1, 1),
-    (200, 128, 128, 2, 2),  # one segment, the chunk does not divide
-], ids=["512-64", "256-64-seg1", "300-64-seg2", "256-128-seg1", "200-128"])
-@pytest.mark.parametrize("prep", ["pallas", "xla"])
-def test_the_rule_by_the_scan_s_kernels(monkeypatch, seq, chunk, segment,
-                                        hk, hv, prep):
-    """``gated_delta_rule`` by the kernels against the ``lax.scan`` path
-    and against the per-token recurrence, float32: values and all five
-    gradients, over one and several segments and lengths that the chunk
-    or the segment does not divide. ``prep=pallas``: what a TPU chooses,
-    the operands' and the scan's kernels under one VJP; ``prep=xla``:
-    the scan's kernels after ``_chunk_operands`` with the inverses'
-    kernels in it (PR 34's program). Padded tokens write nothing: the
-    cut output and the gradients are the unpadded recurrence's."""
-    args = _inputs(seq, jnp.float32, decay=2.0, batch=1, hk=hk, hv=hv,
-                   dim=128)
-    rule = lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment)
-    by_xla = _value_and_grads(rule, args)
-    by_token = _value_and_grads(gated_delta_recurrence, args)
-    _force_pallas(monkeypatch)
-    if prep == "xla":
-        monkeypatch.setattr(
-            gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    text = str(jax.make_jaxpr(jax.grad(
-        lambda *a: rule(*a).sum(), argnums=(0, 1, 2, 3, 4)))(*args))
-    assert "gdn_scan_fwd" in text and "gdn_scan_bwd" in text
-    for name in ("gdn_prepare_fwd", "gdn_prepare_bwd"):
-        assert (name in text) == (prep == "pallas")
-    for name in ("gdn_inverse_fwd", "gdn_inverse_bwd"):
-        assert (name in text) == (prep == "xla")
-    got = _value_and_grads(rule, args)
-    for a, b, c in zip(got, by_xla, by_token):
-        assert a.shape == c.shape and a.dtype == c.dtype
-        scale = 1e-3 + float(jnp.abs(c).max())
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * scale)
-        np.testing.assert_allclose(a, c, rtol=0, atol=1e-4 * scale)
-
-
-def test_the_scan_s_kernels_hold_bfloat16_s_rounding(monkeypatch):
-    """The cell's dtypes: bfloat16 operands, float32 state and decay.
-    The kernels' output is the ``lax.scan``'s bit for bit, and their
-    gradients stay as close to the float32 recurrence's as its own."""
-    args = _inputs(256, jnp.float32, decay=2.0, batch=1, dim=128)
-    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    rule = lambda *a: gated_delta_rule(*a, chunk=64, segment=2)
-    want = _value_and_grads(gated_delta_recurrence, args)
-    by_xla = _value_and_grads(rule, low)
-    _force_pallas(monkeypatch)
-    # the scan's kernels after the XLA lines (the operands' kernels
-    # cumulate g in another order: their own test below)
-    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    got = _value_and_grads(rule, low)
-    assert got[0].dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        np.float32(got[0]), np.float32(by_xla[0]))
-    err = lambda a, b: float(jnp.sqrt(
-        jnp.mean((a.astype(jnp.float32) - b) ** 2) / jnp.mean(b ** 2)))
-    for a, b, c in zip(got[1:], by_xla[1:], want[1:]):
-        assert err(a, c) < 1.25 * err(b, c) + 1e-4
-
-
-_MESH4 = "a four-device mesh"
-_MANUAL = "a region manual over it"
-
-
-@pytest.mark.parametrize(
-    "backend,dtype,chunk,dim,state,decay,out,place,scan", [
-        ("tpu", "bfloat16", 64, 128, None, None, None, None, "pallas"),
-        ("tpu", "float32", 128, 256, None, None, None, None, "pallas"),
-        ("tpu", "bfloat16", 64, 128, None, None, None, _MANUAL, "pallas"),
-        ("cpu", "bfloat16", 64, 128, None, None, None, None, "xla"),
-        ("tpu", "float64", 64, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, None, None, None, _MESH4, "xla"),
-        ("tpu", "bfloat16", 64, 128, "bfloat16", None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, None, "bfloat16", None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, None, None, "float32", None, "xla"),
-        ("tpu", "bfloat16", 32, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 64, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 192, None, None, None, None, "xla"),
-    ], ids=lambda v: str(v))
-def test_the_choice_of_the_scan(monkeypatch, caplog, x64, backend, dtype,
-                                chunk, dim, state, decay, out, place, scan):
-    """From the backend, the dtypes, the widths, the chunk and the
-    placement alone, and the rule's line says which: the kernels on a
-    TPU for bfloat16 or float32 operands with the float32 state and
-    decay, whole lane rows and a chunk of 64 or 128, on one device or
-    inside a region already manual over the mesh; the ``lax.scan`` on
-    the CPU, in float64, on a mesh of several devices (no partitioning
-    rule), under the tests' ``state_dtype`` / ``decay_dtype``
-    experiments, for an output of another dtype, at other widths."""
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    mesh = None if place is None else Mesh(
-        np.array(jax.devices()[:4]), ("data",))
-    dtype = jnp.dtype(dtype)
-    given = {name: jnp.dtype(value) for name, value in (
-        ("state_dtype", state), ("decay_dtype", decay)) if value}
-    seen = []
-
-    def trace(x):
-        struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype)
-        wide = jnp.promote_types(dtype, jnp.float32)
-        seen.append(gated_delta.scan_impl(
-            dtype, chunk, dim, dim, out_dtype=out and jnp.dtype(out),
-            mesh=mesh, **given))
-        gated_delta._log_once.cache_clear()
-        jax.eval_shape(
-            functools.partial(
-                gated_delta_rule, chunk=chunk, mesh=mesh, **given),
-            struct((1, 1, 2 * chunk, dim), dtype),
-            struct((1, 1, 2 * chunk, dim), dtype),
-            struct((1, 2, 2 * chunk, dim), out or dtype),
-            struct((1, 2, 2 * chunk), wide), struct((1, 2, 2 * chunk), wide))
-        return x
-
-    with caplog.at_level(logging.INFO):
-        if place == _MANUAL:
-            jax.eval_shape(jax_compat.shard_map(
-                trace, mesh=mesh, in_specs=P("data"), out_specs=P("data")),
-                jnp.zeros(4))
-        else:
-            trace(None)
-    gated_delta._log_once.cache_clear()
-    assert seen == [scan]
-    # the operands' kernels go where the scan's do
-    assert " scan=%s prep=%s (tokens=%d)" % (
-        scan, scan, 2 * chunk) in caplog.text
-
-
-def test_the_scan_s_grid_step_fits_its_budget():
-    """The heads and chunks a grid step takes, from shapes: both divide
-    what they are taken of, every double-buffered block and the carried
-    states inside the budget, the budget inside the limit the kernels
-    state; the cell's blocks by name."""
-    kinds = ("fwd", "fwd_residuals", "bwd")
-    assert [gated_delta.scan_block(32, 128, 64, 128, 128, 2, kind, 2)
-            for kind in kinds] == [(8, 4), (8, 4), (8, 2)]
-    for heads, rep, chunks, chunk, dk, dv, itemsize in (
-            (32, 2, 128, 64, 128, 128, 2), (32, 1, 64, 128, 128, 128, 2),
-            (6, 3, 9, 64, 256, 128, 4), (7, 1, 5, 128, 256, 256, 4),
-            (1, 1, 1, 64, 128, 128, 2), (64, 16, 128, 128, 256, 512, 4),
-            (48, 16, 8, 64, 128, 128, 2)):
-        for kind in kinds:
-            block, step = gated_delta.scan_block(
-                heads, chunks, chunk, dk, dv, itemsize, kind, rep)
-            assert heads % block == 0 and chunks % step == 0
-            # whole groups of a key head's value heads, or part of one
-            assert block % rep == 0 or rep % block == 0
-            assert 1 <= block <= gated_delta._SCAN_HEADS
-            assert 1 <= step <= gated_delta._SCAN_CHUNKS
-            assert gated_delta.scan_vmem_bytes(
-                block, step, chunk, dk, dv, itemsize, kind
-            ) <= gated_delta._SCAN_BLOCK_BYTES, (heads, chunks, kind)
-    # a bfloat16 (64, 64) block holds whole 128-lane rows in VMEM, a
-    # decay row whole 8-row tiles
-    assert gated_delta._tile_bytes(64, 64, 2) == 64 * 128 * 2
-    assert gated_delta._tile_bytes(1, 128, 4) == 8 * 128 * 4
-    assert gated_delta._SCAN_BLOCK_BYTES < gated_delta._SCAN_VMEM_LIMIT
-
-
-# ------------------------------------------- the operands' kernels
-# ``gdn_prepare_fwd`` / ``gdn_prepare_bwd`` in interpret mode on the CPU
-# (ISSUE 39): everything of the rule that does not meet the state, a
-# block of a key head's chunks in VMEM, against ``_chunk_operands`` and
-# autodiff of it.
-
-
-def _xla_lines(q, k, v, g, beta):
-    """What the scan's kernels are handed with ``prep=xla``:
-    ``_chunk_operands`` and the casts and the broadcast of
-    ``_scan_operands``."""
-    return gated_delta._scan_operands(*gated_delta._chunk_operands(
-        q, k, v, g, beta, jnp.float32, "xla"), q.dtype)
-
-
-_OPERANDS = ("decay", "w", "k_onto", "q_into", "p", "u")
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("chunk,rep,num", [
-    (64, 1, 3),     # three matrices: the second lane row half empty
-    (64, 2, 16),    # sixteen lane rows a key head: two groups of eight in
-                    # one grid step (bfloat16) or in two (float32)
-    (128, 1, 2),
-    (128, 2, 3),
-], ids=["64-rep1", "64-rep2-16-chunks", "128-rep1", "128-rep2"])
-def test_the_operands_kernel_is_chunk_operands(chunk, rep, num, dtype):
-    """The six operands of ``gdn_scan_fwd`` in its layout and dtypes,
-    and ``T`` with two 64 x 64 matrices (one of 128) a lane row: equal
-    to float32 rounding of the decays (the kernel cumulates ``g`` by a
-    masked sum where XLA calls ``cumsum``), so an operand in bfloat16
-    may differ by one rounding, ``U`` by ``T``'s."""
-    args = _split_inputs(num, chunk, rep, dtype)
-    want = _xla_lines(*args)
-    plain = gated_delta.gdn_prepare_fwd(*args, interpret=True)
-    *got, inverse = gated_delta.gdn_prepare_fwd(
-        *args, residuals=True, interpret=True)
-    assert len(plain) == len(got) == 6
-    exact = dtype == jnp.float32
-    for name, a, b, c in zip(_OPERANDS, got, want, plain):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        np.testing.assert_array_equal(
-            np.float32(a), np.float32(c), err_msg=name)
-        scale = float(jnp.abs(b.astype(jnp.float32)).max())
-        np.testing.assert_allclose(
-            np.float32(a), np.float32(b), rtol=0,
-            atol=(2e-5 if exact or name == "decay" else 1e-2) * scale,
-            err_msg=name)
-    # T as the backward reads it: the grid step's matrices, chunks
-    # first and a key head's value heads within, ``pack`` a lane row
-    step = gated_delta.prepare_block(rep, num, chunk, 128, 128,
-                                     jnp.dtype(dtype).itemsize)
-    pack = 128 // chunk
-    rows = -(-rep * step // pack)
-    assert inverse.shape == (1, 2, num // step, rows, chunk, 128)
-    assert inverse.dtype == jnp.float32
-    q, k, v, g, beta = args
-    cum = jnp.cumsum(g, axis=-1)
-    lower = np.tril(np.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(
-        lower, cum[..., :, None] - cum[..., None, :], -jnp.inf))
-    kk = gated_delta._matmul(k, jnp.swapaxes(k, -1, -2), dtype)
-    a = jnp.where(np.tril(lower, -1), kk * beta[..., :, None] * decay, 0.0)
-    t = np.asarray(gated_delta._inverse_product(a))  # (1, Hk, R, N, C, C)
-    for head in range(2):
-        for n in range(num):
-            for r in range(rep):
-                m = (n % step) * rep + r
-                found = inverse[0, head, n // step, m // pack, :,
-                                m % pack * chunk:(m % pack + 1) * chunk]
-                np.testing.assert_allclose(
-                    found, t[0, head, r, n], rtol=0,
-                    atol=2e-5 * np.abs(t[0, head, r, n]).max())
-    if rep * step % pack:
-        # the lane row's spare half holds the inverse of a zero matrix
-        np.testing.assert_array_equal(
-            inverse[0, :, :, -1, :, chunk:],
-            np.broadcast_to(np.eye(chunk, dtype=np.float32),
-                            (2, num // step, chunk, chunk)))
-
-
-@pytest.mark.parametrize("chunk,rep,num,dtype,decay", [
-    (64, 1, 3, "float32", 2.0),
-    (64, 2, 16, "float32", 2.0),
-    (64, 2, 4, "bfloat16", 2.0),
-    (64, 2, 4, "float32", 30.0),     # exp(G) underflows inside a chunk
-    (128, 1, 2, "bfloat16", 2.0),
-    (128, 2, 3, "float32", 1e-3),
-    (128, 2, 3, "bfloat16", 30.0),
-], ids=lambda v: str(v))
-def test_the_operands_kernel_s_vjp(chunk, rep, num, dtype, decay):
-    """dq, dk (summed over the key head's value heads in the kernel),
-    dv, dg and dbeta from random cotangents of all six operands against
-    autodiff of the XLA lines: in float32 equal to rounding; in bfloat16
-    to the operands' rounding (the kernel keeps ``dX``, ``dY`` and every
-    sum in float32 where autodiff rounds the transposed products'
-    results to the compute dtype). A strongly negative ``g`` leaves
-    every gradient finite: the decays are exps of differences ``<= 0``
-    in the backward too."""
-    dtype = jnp.dtype(dtype)
-    args = _split_inputs(num, chunk, rep, dtype, decay=decay)
-    primal, vjp = jax.vjp(_xla_lines, *args)
-    keys = jax.random.split(jax.random.PRNGKey(7), len(primal))
-    cotangents = [
-        jax.random.normal(key, x.shape).astype(x.dtype)
-        for key, x in zip(keys, primal)]
-    # du arrives in the compute dtype, as ``gdn_scan_bwd`` hands it on
-    low = cotangents[:-1] + [cotangents[-1].astype(dtype)]
-    cotangents[-1] = low[-1].astype(jnp.float32)
-    want = vjp(tuple(cotangents))
-    *_, inverse = gated_delta.gdn_prepare_fwd(
-        *args, residuals=True, interpret=True)
-    got = gated_delta.gdn_prepare_bwd(*args, inverse, *low, interpret=True)
-    exact = dtype == jnp.float32
-    for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), got, want):
-        assert a.shape == b.shape and a.dtype == b.dtype, name
-        a, b = np.float32(a), np.float32(b)
-        assert np.isfinite(a).all(), name
-        np.testing.assert_allclose(
-            a, b, rtol=0, atol=(3e-5 if exact else 2e-2) * np.abs(b).max(),
-            err_msg=name)
-
-
-def test_the_operands_kernels_hold_bfloat16_s_rounding(monkeypatch):
-    """The cell's dtypes over two segments and a padded length: the
-    rule's output and gradients with ``prep=pallas`` stay as close to
-    the float32 recurrence's as ``prep=xla``'s."""
-    args = _inputs(200, jnp.float32, decay=2.0, batch=1, dim=128)
-    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    rule = lambda *a: gated_delta_rule(*a, chunk=64, segment=2)
-    want = _value_and_grads(gated_delta_recurrence, args)
-    _force_pallas(monkeypatch)
-    got = _value_and_grads(rule, low)
-    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    by_xla = _value_and_grads(rule, low)
-    assert got[0].dtype == jnp.bfloat16
-    err = lambda a, b: float(jnp.sqrt(
-        jnp.mean((a.astype(jnp.float32) - b) ** 2) / jnp.mean(b ** 2)))
-    for a, b, c in zip(got, by_xla, want):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        assert err(a, c) < 1.25 * err(b, c) + 1e-4
-
-
-@pytest.mark.parametrize(
-    "backend,dtype,chunk,dim,rep,chunks,state,decay,out,place,prep", [
-        ("tpu", "bfloat16", 64, 128, 2, 128, None, None, None, None,
-         "pallas"),
-        ("tpu", "float32", 128, 128, 1, 3, None, None, None, None, "pallas"),
-        ("tpu", "bfloat16", 64, 128, 2, 128, None, None, None, _MANUAL,
-         "pallas"),
-        ("cpu", "bfloat16", 64, 128, 2, 128, None, None, None, None, "xla"),
-        ("tpu", "float64", 64, 128, 2, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, 2, 128, None, None, None, _MESH4,
-         "xla"),
-        # whatever keeps the scan's kernels away keeps these away: they
-        # write what ``gdn_scan_fwd`` reads
-        ("tpu", "bfloat16", 64, 128, 2, 128, "bfloat16", None, None, None,
-         "xla"),
-        ("tpu", "bfloat16", 64, 128, 2, 128, None, "bfloat16", None, None,
-         "xla"),
-        ("tpu", "bfloat16", 64, 128, 2, 128, None, None, "float32", None,
-         "xla"),
-        ("tpu", "bfloat16", 32, 128, 2, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 64, 2, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 192, 2, 128, None, None, None, None, "xla"),
-        # no block of whole 8-row tiles of g fits the VMEM budget: 100
-        # chunks a segment; 16 value heads a key head
-        ("tpu", "bfloat16", 64, 128, 4, 100, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, 16, 128, None, None, None, None, "xla"),
-        ("tpu", "bfloat16", 64, 128, 4, 128, None, None, None, None,
-         "pallas"),
-    ], ids=lambda v: str(v))
-def test_the_choice_of_the_operands_kernels(
-        monkeypatch, caplog, x64, backend, dtype, chunk, dim, rep, chunks,
-        state, decay, out, place, prep):
-    """A third chooser beside ``inverse_impl`` and ``scan_impl``, from
-    the same things and the segment's shape: the kernels wherever the
-    scan's run and a block of the segment's chunks fits their VMEM;
-    ``_chunk_operands`` everywhere else. The rule's line says which,
-    after ``scan=``."""
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    mesh = None if place is None else Mesh(
-        np.array(jax.devices()[:4]), ("data",))
-    dtype = jnp.dtype(dtype)
-    given = {name: jnp.dtype(value) for name, value in (
-        ("state_dtype", state), ("decay_dtype", decay)) if value}
-    seen = []
-
-    def trace(x):
-        struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype)
-        wide = jnp.promote_types(dtype, jnp.float32)
-        seen.append(gated_delta.prepare_impl(
-            dtype, chunk, dim, dim, rep, chunks,
-            out_dtype=out and jnp.dtype(out), mesh=mesh, **given))
-        gated_delta._log_once.cache_clear()
-        seq = chunks * chunk
-        jax.eval_shape(
-            functools.partial(
-                gated_delta_rule, chunk=chunk, segment=chunks, mesh=mesh,
-                **given),
-            struct((1, 1, seq, dim), dtype), struct((1, 1, seq, dim), dtype),
-            struct((1, rep, seq, dim), out or dtype),
-            struct((1, rep, seq), wide), struct((1, rep, seq), wide))
-        return x
-
-    with caplog.at_level(logging.INFO):
-        if place == _MANUAL:
-            jax.eval_shape(jax_compat.shard_map(
-                trace, mesh=mesh, in_specs=P("data"), out_specs=P("data")),
-                jnp.zeros(4))
-        else:
-            trace(None)
-    gated_delta._log_once.cache_clear()
-    assert seen == [prep]
-    assert " prep=%s (tokens=%d)" % (prep, chunks * chunk) in caplog.text
-
-
-def test_the_operands_grid_step_fits_its_budget():
-    """The chunks a grid step takes, from shapes: a divisor of the
-    segment's in whole 8-row tiles of ``g`` (or all of them), the
-    smallest that gives the inverses two groups of ``_CHAINS`` lane rows
-    (or the largest that fits), every double-buffered block inside the
-    budget, the budget inside the limit the kernels state; the cell's
-    block by name."""
-    assert gated_delta.prepare_block(2, 128, 64, 128, 128, 2) == 16
-    assert gated_delta.prepare_block(1, 128, 64, 128, 128, 2) == 16
-    assert gated_delta.prepare_block(2, 128, 64, 128, 128, 4) == 8
-    assert gated_delta.prepare_block(2, 64, 128, 128, 128, 2) == 8
-    assert gated_delta.prepare_block(2, 3, 64, 128, 128, 4) == 3
-    assert gated_delta.prepare_block(2, 12, 64, 128, 128, 2) == 12
-    assert gated_delta.prepare_block(2, 12, 64, 128, 128, 4) is None
-    assert gated_delta.prepare_block(2, 24, 64, 128, 128, 4) == 8
-    assert gated_delta.prepare_block(16, 100, 64, 128, 128, 2) is None
-    kinds = ("fwd", "fwd_residuals", "bwd")
-    for rep, chunks, chunk, dk, dv, itemsize in (
-            (2, 128, 64, 128, 128, 2), (1, 128, 64, 128, 128, 2),
-            (2, 64, 128, 128, 128, 2), (3, 9, 64, 256, 128, 4),
-            (1, 5, 128, 256, 256, 4), (1, 1, 64, 128, 128, 2),
-            (16, 128, 128, 256, 512, 4), (16, 8, 64, 128, 128, 2)):
-        step = gated_delta.prepare_block(rep, chunks, chunk, dk, dv, itemsize)
-        if step is None:
-            continue
-        assert chunks % step == 0 and (step % 8 == 0 or step == chunks)
-        for kind in kinds:
-            assert gated_delta.prepare_vmem_bytes(
-                rep, step, chunk, dk, dv, itemsize, kind
-            ) <= gated_delta._PREPARE_BLOCK_BYTES, (rep, chunks, kind)
-    # the cell's: under 11 MiB of double-buffered blocks a grid step
-    assert gated_delta.prepare_vmem_bytes(
-        2, 16, 64, 128, 128, 2, "bwd") < 11 * 2**20
-    assert (gated_delta._PREPARE_BLOCK_BYTES
-            < gated_delta._PREPARE_VMEM_LIMIT)
 
 
 def test_precision_rules_in_bfloat16():

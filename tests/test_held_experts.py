@@ -497,23 +497,20 @@ def test_held_counters_reach_the_model_s_routing():
     assert float(merged["rows_run"]) == 8 + float(roomy["rows_run"])
 
 
-def test_the_rows_run_reach_the_moe_routing_event(monkeypatch):
-    """``Worker`` journals the merged counters: ``held_rows_run`` and
+def test_the_rows_run_reach_the_moe_routing_event():
+    """The journal's row of the merged counters
+    (``train/step_fns.py:FACTS``): ``held_rows_run`` and
     ``held_rows_buffer`` beside ``held_pairs``, only from a model whose
     layers hold a share."""
-    from elasticdl_tpu.worker import worker as worker_module
+    from elasticdl_tpu.train import step_fns
 
-    seen = []
-    monkeypatch.setattr(
-        worker_module.events, "emit",
-        lambda name, **fields: seen.append((name, fields)))
+    fact, = [f for f in step_fns.FACTS if f.key == "routing"]
     routing = {"load_max": 9.0, "load_mean": 2.0, "entropy": 1.5,
                "dropped": 0.0}
-    worker_module.emit_moe_routing(7, routing)
-    worker_module.emit_moe_routing(
-        8, dict(routing, held=300.0, rows_run=1024.0, rows_buffer=4096.0))
-    (_, whole), (name, share) = seen
-    assert name == "moe_routing" and share["step"] == 8
+    whole = fact.journal(routing)
+    share = fact.journal(
+        dict(routing, held=300.0, rows_run=1024.0, rows_buffer=4096.0))
+    assert fact.event == "moe_routing"
     assert "held_pairs" not in whole and "held_rows_run" not in whole
     assert share["held_pairs"] == 300 and share["dropped_pairs"] == 0
     assert share["held_rows_run"] == 1024

@@ -503,7 +503,8 @@ def test_routing_counters_leave_the_step_only_for_a_model_that_has_them():
             _small_moe(attention_impl="xla", dispatch_impl="sorted"),
             moe_transformer.loss, tx, grad_accum_steps=accum)
         trainer.train_step(None, batch)
-        routing = {k: float(v) for k, v in trainer.routing.items()}
+        routing = {
+            k: float(v) for k, v in trainer.facts["routing"].items()}
         assert set(routing) == {
             "load_max", "load_mean", "entropy", "dropped"}
         assert routing["dropped"] == 0.0
@@ -520,4 +521,4 @@ def test_routing_counters_leave_the_step_only_for_a_model_that_has_them():
     ):
         trainer = JaxTrainer(model, loss, tx)
         trainer.train_step(None, batch)
-        assert trainer.routing is None
+        assert trainer.facts == {}

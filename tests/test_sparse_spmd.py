@@ -19,7 +19,6 @@ from elasticdl_tpu.train.sparse import SparseTrainer
 from elasticdl_tpu.train.sparse_spmd import (
     MultiHostSparseSpmdTrainer,
     SparseSpmdTrainer,
-    sparse_trainer_for,
 )
 from elasticdl_tpu.worker.ps_client import PSClient
 
@@ -129,7 +128,10 @@ def test_sparse_trainer_for_mapping():
         MultiHostSpmdTrainer,
     )
     from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
-    from elasticdl_tpu.worker.trainer import JaxTrainer
+    from elasticdl_tpu.worker.trainer import JaxTrainer, trainer_class
+
+    def sparse_trainer_for(factory):
+        return trainer_class(sparse=True, factory=factory)
 
     assert sparse_trainer_for(None) is SparseTrainer
     assert sparse_trainer_for(JaxTrainer) is SparseTrainer
