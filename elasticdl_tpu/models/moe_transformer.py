@@ -36,6 +36,7 @@ from elasticdl_tpu.models.transformer import (
     HyperConnection,
     HyperDims,
     LatentDims,
+    MixerKind,
     YarnScaling,
     make_attention,
     make_norm,
@@ -94,6 +95,14 @@ def _log_tiles_once(rows, num_experts, dim, width, dtype, act):
         "gate/up" if act == "swiglu" else "up", show(into), show(out),
         100 * fill,
     )
+
+
+@functools.lru_cache(maxsize=None)
+def _log_kinds_once(kinds):
+    """One line at model build for a model that mixes kinds of layer:
+    each kind with its count and what it has of its own."""
+    logger.info("layer kinds: %s", ", ".join(
+        "%s x%d (%s)" % kind for kind in kinds))
 
 
 class MoeMlp(nn.Module):
@@ -436,9 +445,10 @@ class MoeBlock(nn.Module):
     # the attention mask's layout where it is not causal, and with it
     # the call's ``positions`` (``Attention``'s own of those names)
     mask: Optional[Any] = None
-    # ``Block``'s fields of these names: YaRN for a latent mixer, and
-    # the hyper-connected residual path (``x`` the n streams; the
-    # block's facts under ``aux["mhc"]``)
+    kind_scope: Optional[str] = None
+    # ``Block``'s fields of these names: YaRN by the mixer's own
+    # convention, and the hyper-connected residual path (``x`` the n
+    # streams; the block's facts under ``aux["mhc"]``)
     rope_scaling: Optional[YarnScaling] = None
     hc: Optional[HyperDims] = None
     layer_index: int = 0
@@ -462,6 +472,7 @@ class MoeBlock(nn.Module):
             rotary_dim=self.rotary_dim,
             output_gate=self.output_gate,
             mask=self.mask,
+            kind_scope=self.kind_scope,
             rope_scaling=self.rope_scaling,
         )
         experts = MoeMlp(
@@ -618,10 +629,18 @@ class MoeTransformerLM(nn.Module):
     shared_gate: bool = False
     # the mixers' kinds as a pattern with a period: layer i is
     # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
-    # of ``linear``'s sizes) or "full" (softmax attention). None: every
-    # layer "full". The five fields after ``linear`` are ``Attention``'s
-    # own of those names, for the "full" layers
+    # of ``linear``'s sizes), "full" (softmax attention over the causal
+    # prefix) or "window" (softmax attention over a band,
+    # ``ops/flash_attention.py:Band``). None: every layer "full". The
+    # five fields after ``linear`` are ``Attention``'s own of those
+    # names, for every softmax layer. What a KIND of softmax layer has
+    # of its own is ``kind_fields``, {kind: ``MixerKind``}: heads,
+    # rotary base, rotating lanes, YaRN, window; a kind without an
+    # entry has the model's ``num_heads``, ``rope_theta``,
+    # ``rotary_dim`` and ``rope_scaling`` and sees the causal prefix,
+    # and "window" needs one (``_kind_fields`` says all of it, once)
     layer_kinds: Optional[Any] = None
+    kind_fields: Optional[Any] = None
     linear: Optional[GatedDeltaDims] = None
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
@@ -650,6 +669,64 @@ class MoeTransformerLM(nn.Module):
     hc: Optional[HyperDims] = None
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.1
+
+    def _kind_fields(self, kind, layout=None):
+        """What a layer's KIND decides of its mixer, stated once: the
+        query heads, the rotary base, the lanes that rotate, YaRN, the
+        mask's layout and the scope its operations lie under. A kind
+        without an entry in ``kind_fields`` (every kind of every model
+        built before there were any) has the model's own four and the
+        call's ``layout`` (None: causal; block diffusion's), and no
+        scope: the mixer, the tree and the program it always had."""
+        own = dict(self.kind_fields or {}).get(kind)
+        if own is None:
+            own = MixerKind(self.num_heads, self.rope_theta,
+                            self.rotary_dim, self.rope_scaling)
+        return dict(
+            num_heads=own.num_heads,
+            rope_theta=own.rope_theta,
+            rotary_dim=own.rotary_dim,
+            rope_scaling=own.rope_scaling,
+            mask=(flash_attention.Band(own.window) if own.window
+                  else layout),
+            kind_scope="attn_" + kind if self.kind_fields else None,
+        )
+
+    def _check_kinds(self, kinds, denoise):
+        """Refuses, by name, a pattern the blocks cannot run."""
+        by_kind = dict(self.kind_fields or {})
+        if set(kinds) - {"full", "linear", "window"} or (
+                "linear" in kinds and self.linear is None):
+            raise ValueError(
+                "layer_kinds=%r: each is 'full', 'linear' or 'window', "
+                "and 'linear' needs the mixer's sizes (linear)"
+                % (self.layer_kinds,))
+        if set(by_kind) - {"full", "window"}:
+            raise ValueError(
+                "kind_fields=%r: only the softmax kinds 'full' and "
+                "'window' have fields of their own" % (sorted(by_kind),))
+        windows = {kind: own.window for kind, own in by_kind.items()}
+        if ("window" in kinds) != bool(windows.get("window")) or (
+                windows.get("full")):
+            raise ValueError(
+                "a 'window' layer needs kind_fields['window'] with its "
+                "window, and no other kind takes one; layer_kinds=%r, "
+                "windows=%r" % (self.layer_kinds, windows))
+        if by_kind and (
+                denoise or self.latent is not None or self.mtp_layers
+                or self.attention_impl in ("ring", "ulysses")):
+            raise ValueError(
+                "kind_fields (a band, heads or a rotary table by layer "
+                "kind) under objective=\"block_diffusion\", with latent "
+                "attention, beside the prediction module (mtp_layers) "
+                "or under attention_impl='ring' / 'ulysses': not built, "
+                "so not run")
+        if by_kind:
+            _log_kinds_once(tuple(
+                (kind, sum(kinds[i % len(kinds)] == kind
+                           for i in range(self.num_layers)),
+                 str(by_kind.get(kind, "the model's own")))
+                for kind in sorted(set(kinds))))
 
     def _block_diffusion_inputs(self, tokens, training, noisy, weights):
         """``(inputs (B, 2 L), positions, mask layout, weights, the
@@ -745,33 +822,22 @@ class MoeTransformerLM(nn.Module):
             norm=self.norm,
             norm_eps=self.norm_eps,
             qk_norm=self.qk_norm,
-            rope_theta=self.rope_theta,
             latent=self.latent,
-            rope_scaling=self.rope_scaling,
             hc=self.hc,
-        )
-        kinds = tuple(self.layer_kinds or ("full",))
-        if set(kinds) - {"full", "linear"} or (
-                "linear" in kinds and self.linear is None):
-            raise ValueError(
-                "layer_kinds=%r: each is 'full' or 'linear', and 'linear' "
-                "needs the mixer's sizes (linear)" % (self.layer_kinds,))
-        # what only an expert block's mixer takes
-        mixer = dict(
+            # the softmax mixer's own fields, for every kind alike
             head_dim=self.head_dim,
             num_kv_heads=self.num_kv_heads,
             head_norm=self.head_norm,
-            rotary_dim=self.rotary_dim,
             output_gate=self.output_gate,
-            mask=layout,
         )
+        kinds = tuple(self.layer_kinds or ("full",))
+        self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
         routing, mhc = [], []
 
-        def expert_block(name, index, linear=None):
+        def expert_block(name, index, kind="full"):
             return wrap(MoeBlock)(
-                self.num_heads,
-                self.num_experts,
+                num_experts=self.num_experts,
                 top_k=self.top_k,
                 capacity_factor=self.capacity_factor,
                 dispatch_impl=self.dispatch_impl,
@@ -786,11 +852,11 @@ class MoeTransformerLM(nn.Module):
                 held_experts=self.held_experts,
                 held_rows=self.held_rows,
                 shared_gate=self.shared_gate,
-                linear=linear,
+                linear=self.linear if kind == "linear" else None,
                 layer_index=index,
                 name=name,
                 **shared,
-                **mixer,
+                **self._kind_fields(kind, layout),
             )
 
         def count(aux):
@@ -804,23 +870,23 @@ class MoeTransformerLM(nn.Module):
                 mhc.append(aux["mhc"])
 
         for i in range(self.num_layers):
-            linear = (
-                self.linear if kinds[i % len(kinds)] == "linear" else None)
+            kind = kinds[i % len(kinds)]
             if (i >= self.first_k_dense
                     and i % self.moe_every == self.moe_every - 1):
-                x, aux = expert_block("block_%d" % i, i, linear)(
+                x, aux = expert_block("block_%d" % i, i, kind)(
                     x, training, positions)
                 count(aux)
             else:
-                if linear is not None or any(mixer.values()):
+                if kind == "linear":
                     raise ValueError(
-                        "a dense block's mixer is Attention at its "
-                        "defaults or LatentAttention; layer %d asks for "
-                        "more" % i)
+                        "a dense block's mixer is softmax or latent "
+                        "attention; layer %d asks for a Gated DeltaNet, "
+                        "which only an expert block takes" % i)
                 x = wrap(Block)(
-                    self.num_heads, mlp_act=self.dense_act,
+                    mlp_act=self.dense_act,
                     mlp_dim=self.dense_dim, layer_index=i,
-                    name="block_%d" % i, **shared
+                    name="block_%d" % i, **shared,
+                    **self._kind_fields(kind, layout),
                 )(x, training)
                 if self.hc is not None:
                     x, block_facts = x

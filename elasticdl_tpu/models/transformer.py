@@ -21,6 +21,7 @@ Design notes (TPU-first):
   under ``fsdp`` moved the batch instead of the weights.
 """
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -67,6 +68,31 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
+class MixerKind:
+    """What a KIND of softmax layer has of its own in a model that
+    mixes kinds (``MoeTransformerLM.kind_fields``): its query heads,
+    its rotary base, how many lanes of a head rotate (None: all), YaRN
+    over those lanes (None: none), and the window of a sliding-window
+    layer (None: the whole causal prefix). Laguna-XS.2: ``full`` 48
+    heads, 500,000, 64 of 128 lanes under YaRN; ``window`` 64 heads,
+    10,000, the whole head, 512."""
+
+    num_heads: int
+    rope_theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    rope_scaling: Optional[YarnScaling] = None
+    window: Optional[int] = None
+
+    def __str__(self):
+        return " ".join(filter(None, (
+            "heads=%d" % self.num_heads,
+            "theta=%g" % self.rope_theta,
+            self.rotary_dim and "rotary=%d" % self.rotary_dim,
+            self.rope_scaling and "yarn=%g" % self.rope_scaling.factor,
+            self.window and "window=%d" % self.window)))
 
 
 def yarn_mscale(factor, mscale):
@@ -198,19 +224,41 @@ class Attention(nn.Module):
     # half, a gate of the head's width, multiplies the attention's
     # output through a sigmoid before the output projection
     output_gate: Optional[str] = None
-    # the mask's layout (``ops/flash_attention.py``: ``BlockDiffusion``)
-    # where it is not the causal diagonal; ``__call__``'s ``positions``
-    # then say what each row rotates by
+    # the mask's layout (``ops/flash_attention.py``: ``BlockDiffusion``,
+    # ``Band``) where it is not the causal diagonal; block diffusion's
+    # ``__call__`` also says by ``positions`` what each row rotates by
     mask: Optional[Any] = None
+    # YaRN (``YarnScaling``) on the lanes that rotate: the blended
+    # frequency table over ``rotary_dim`` (None: the head's width) and
+    # cos and sin times ``yarn_mscale(factor, mscale)`` over
+    # ``yarn_mscale(factor, mscale_all_dim)``, as ``rotary_embedding``
+    # has them; the lanes that pass through and the softmax scale are
+    # left alone (the ``transformers`` library's generic ``yarn`` rope,
+    # not DeepSeek-V3's, which ``LatentAttention`` follows)
+    rope_scaling: Optional[YarnScaling] = None
+    # a name for the mixer's kind in a model that mixes kinds
+    # (``attn_full``, ``attn_window``): the mixer's operations then lie
+    # under the named scopes ``<kind_scope>/qkv``, ``/rotary``,
+    # ``/flash``, ``/gate`` and ``/out_proj``, forward and backward,
+    # and its attention line starts ``heads=``.
+    # None: no scope, the program and the line the mixer always had
+    kind_scope: Optional[str] = None
 
     def _rotate(self, t, positions=None):
         rotate = functools.partial(
-            rotary_embedding, base=self.rope_theta, positions=positions)
+            rotary_embedding, base=self.rope_theta, positions=positions,
+            scaling=self.rope_scaling)
         if self.rotary_dim is None:
             return rotate(t)
         return jnp.concatenate([
             rotate(t[..., :self.rotary_dim]), t[..., self.rotary_dim:],
         ], axis=-1)
+
+    def _scoped(self, part):
+        """The named scope of one part of the mixer, or none."""
+        if self.kind_scope is None:
+            return contextlib.nullcontext()
+        return jax.named_scope("%s/%s" % (self.kind_scope, part))
 
     @nn.compact
     def __call__(self, x, training=False, positions=None):
@@ -258,17 +306,19 @@ class Attention(nn.Module):
             t.transpose(0, 2, 1, 3), self.mesh, spec
         )
         gate = None
-        if self.output_gate:
-            q = dense("query", width=2 * head_dim)
-            q, gate = q[..., :head_dim], q[..., head_dim:]
-        else:
-            q = dense("query", "q_norm")
-        q = to_bhsd(head_norm(q, "q_norm"))
-        k = to_bhsd(head_norm(
-            dense("key", "k_norm", heads=kv_heads), "k_norm"))
-        v = to_bhsd(dense("value", heads=kv_heads))
-        q = self._rotate(q, positions)
-        k = self._rotate(k, positions)
+        with self._scoped("qkv"):
+            if self.output_gate:
+                q = dense("query", width=2 * head_dim)
+                q, gate = q[..., :head_dim], q[..., head_dim:]
+            else:
+                q = dense("query", "q_norm")
+            q = to_bhsd(head_norm(q, "q_norm"))
+            k = to_bhsd(head_norm(
+                dense("key", "k_norm", heads=kv_heads), "k_norm"))
+            v = to_bhsd(dense("value", heads=kv_heads))
+        with self._scoped("rotary"):
+            q = self._rotate(q, positions)
+            k = self._rotate(k, positions)
 
         if self.attention_impl in ("ring", "ulysses"):
             if (kv_heads != self.num_heads or self.mask is not None
@@ -281,21 +331,27 @@ class Attention(nn.Module):
                         else ulysses_attention)
             out = schedule(q, k, v, self.mesh, causal=True)
         else:
+            rotary = self.rotary_dim or head_dim
             note = " ".join(filter(None, (
+                self.kind_scope and "heads=%d" % self.num_heads,
                 self.output_gate and "gate=%s" % self.output_gate,
-                self.rotary_dim and "rotary=%d/%d" % (
-                    self.rotary_dim, head_dim),
+                (self.rotary_dim or self.rope_scaling
+                 or self.kind_scope) and "rotary=%d/%d" % (rotary, head_dim),
+                self.rope_scaling and "yarn=%g" % self.rope_scaling.factor,
             )))
-            out = dot_product_attention(
-                q, k, v, causal=True, impl=self.attention_impl,
-                mesh=self.mesh, spec=spec, note=note, mask=self.mask,
-            )
-        out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
-        if gate is not None:
-            out = out * jax.nn.sigmoid(gate)
-        out = nn.DenseGeneral(
-            dim, axis=(-2, -1), use_bias=False, name="out_proj"
-        )(out)
+            with self._scoped("flash"):
+                out = dot_product_attention(
+                    q, k, v, causal=True, impl=self.attention_impl,
+                    mesh=self.mesh, spec=spec, note=note, mask=self.mask,
+                )
+        with self._scoped("gate"):
+            out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
+            if gate is not None:
+                out = out * jax.nn.sigmoid(gate)
+        with self._scoped("out_proj"):
+            out = nn.DenseGeneral(
+                dim, axis=(-2, -1), use_bias=False, name="out_proj"
+            )(out)
         if self.dropout:
             out = nn.Dropout(
                 self.dropout, deterministic=not training
@@ -729,32 +785,35 @@ def merge_hyper_facts(sublayers):
     }
 
 
+# ``Attention``'s own fields, which a latent mixer has none of
+SOFTMAX_ONLY = (
+    "qk_norm", "dropout", "head_dim", "num_kv_heads", "head_norm",
+    "rotary_dim", "output_gate", "mask", "kind_scope")
+
+
 def make_attention(num_heads, latent=None, linear=None, **fields):
     """The block's mixer, ``name="attn"``, by the layer's kind:
     ``GatedDeltaNet`` where the layer is a linear-attention one
     (``linear``: its ``GatedDeltaDims``), ``LatentAttention`` where the
     model names latent widths (``LatentDims``), else ``Attention``.
-    ``fields``: ``norm_eps``, which all take, what the two softmax ones
-    take, and what only ``Attention`` has (``qk_norm``, ``dropout`` and
-    the grouped-query fields); ``rope_scaling`` (``YarnScaling``) is a
-    latent mixer's alone."""
-    scaling = fields.pop("rope_scaling", None)
-    if scaling is not None and (linear is not None or latent is None):
-        raise ValueError("only latent attention takes a rope_scaling")
+    ``fields``: ``norm_eps``, which all take; what the two softmax ones
+    take (``rope_theta``, ``rope_scaling``: YaRN, each by its own
+    convention); and what only ``Attention`` has (``SOFTMAX_ONLY``: the
+    grouped-query fields, the mask's layout, the kind's scope). A
+    Gated DeltaNet rotates nothing and masks nothing, and says so."""
     if linear is not None:
-        if fields.get("mask") is not None:
-            raise ValueError("a Gated DeltaNet mixer has no mask")
+        for name in ("mask", "rope_scaling"):
+            if fields.get(name) is not None:
+                raise ValueError("a Gated DeltaNet mixer has no %s" % name)
         return GatedDeltaNet(
             linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
             name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
-    for name in ("qk_norm", "dropout", "head_dim", "num_kv_heads",
-                 "head_norm", "rotary_dim", "output_gate", "mask"):
+    for name in SOFTMAX_ONLY:
         if fields.pop(name, None):
             raise ValueError("latent attention has no %s" % name)
-    return LatentAttention(
-        num_heads, latent, name="attn", rope_scaling=scaling, **fields)
+    return LatentAttention(num_heads, latent, name="attn", **fields)
 
 
 class Block(nn.Module):
@@ -772,7 +831,8 @@ class Block(nn.Module):
     # down), of width ``mlp_dim`` (``mlp_ratio x dim`` when None)
     mlp_act: str = "gelu"
     mlp_dim: Optional[int] = None
-    # YaRN, for a latent mixer (``LatentAttention.rope_scaling``)
+    # YaRN, by the mixer's own convention (``LatentAttention`` /
+    # ``Attention``'s ``rope_scaling``)
     rope_scaling: Optional[YarnScaling] = None
     # a hyper-connected residual path: ``x`` is then the n streams
     # (B, n, S, D), each sublayer goes through a ``HyperConnection``
@@ -781,6 +841,18 @@ class Block(nn.Module):
     # the program the block always had
     hc: Optional[HyperDims] = None
     layer_index: int = 0
+    # ``Attention``'s own fields of these names (a head width, kv
+    # heads, a norm a head, partial rotary, an output gate, a mask's
+    # layout, the kind's scope), for a dense block whose mixer is the
+    # expert blocks': Laguna-XS.2's layer 0. At their defaults the
+    # mixer, the tree and the program are what they always were
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    head_norm: Optional[str] = None
+    rotary_dim: Optional[int] = None
+    output_gate: Optional[str] = None
+    mask: Optional[Any] = None
+    kind_scope: Optional[str] = None
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -813,6 +885,13 @@ class Block(nn.Module):
             norm_eps=self.norm_eps,
             rope_theta=self.rope_theta,
             rope_scaling=self.rope_scaling,
+            head_dim=self.head_dim,
+            num_kv_heads=self.num_kv_heads,
+            head_norm=self.head_norm,
+            rotary_dim=self.rotary_dim,
+            output_gate=self.output_gate,
+            mask=self.mask,
+            kind_scope=self.kind_scope,
         )
         if self.hc is not None:
             x = constrain(x, self.mesh, STREAMS_SPEC)

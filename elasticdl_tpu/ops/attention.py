@@ -178,8 +178,10 @@ def dot_product_attention(
 ):
     """q/k/v are (batch, heads, seq, dim); k and v may have a head for
     every ``group`` query heads. ``mask``: a layout of
-    ``ops/flash_attention.py`` (``BlockDiffusion(half_len, block)``) in
-    the place of the boolean ``causal``; both implementations read it. ``mesh`` and ``spec``: the mesh the
+    ``ops/flash_attention.py`` (``BlockDiffusion(half_len, block)``,
+    ``Band(window)``) in the place of the boolean ``causal``; both
+    implementations read it, and the resolution's line names it
+    (``mask=window(512)``). ``mesh`` and ``spec``: the mesh the
     caller's step is sharded over and the PartitionSpec of q/k/v on it;
     the Pallas kernel then runs inside a shard_map over them. ``note``:
     what the caller wants on the resolution's log line beside the

@@ -48,21 +48,26 @@ three classes took the forward from 10.54 to 8.48 ms and ``flash_bwd``
 from 17.02 to 16.47, every output bit unchanged (PERF.md, PR 28).
 
 The diagonal is one LAYOUT of the mask among several (``Causal``,
-``Full``, ``BlockDiffusion``; ``as_layout`` takes the boolean
+``Full``, ``BlockDiffusion``, ``Band``; ``as_layout`` takes the boolean
 ``causal`` every caller had). A layout is a small static description
 that says, of two positions, whether the query may see the key
 (``keep``: the kernels' select on a masked tile and the XLA path's
 dense mask are this one function) and, of a (q-block, k-block) pair
 and the two block sizes, its class (``pair``) and which block a
 skipped grid step names (``k_named``, ``q_named``). The kernels, the
-index maps and ``causal_pairs`` read nothing else, so a band or
-document boundaries are further layouts and no further kernels.
+index maps and ``causal_pairs`` read nothing else, so a band is a
+further layout (``Band``), document boundaries would be another, and
+neither a further kernel.
 ``BlockDiffusion(half_len, block)`` is block diffusion's training mask
 over ``[noisy copy ; clean copy]`` of a sequence (BD3-LMs,
 arXiv:2503.09573): a noisy query sees its own noisy block, both
 directions, and the clean blocks before it; a clean query the clean
 blocks up to its own; L^2 + L B of the (2 L)^2 score entries, with
 tiles that divide L the clean -> noisy quadrant skipped whole.
+``Band(window)`` is sliding-window attention: a query sees itself and
+the ``window - 1`` keys before it, S W - W (W - 1) / 2 of the S^2
+entries, on a grid that still has a step for every pair of blocks
+(``Band`` says what that costs at 32,768 x 512).
 
 Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
 into one parallel grid axis and see one head's (seq, head_dim) rows.
@@ -428,6 +433,81 @@ class BlockDiffusion:
         return ""
 
 
+@dataclasses.dataclass(frozen=True)
+class Band:
+    """Sliding-window attention: position ``q`` sees the ``window`` keys
+    that end at itself, ``k <= q`` and ``q - k < window`` (the
+    ``transformers`` library's convention: the window counts the
+    query). ``seq x window - window (window - 1) / 2`` entries a head.
+
+    A row of tiles runs on ONE run of k-blocks, from the block of its
+    first row's first key to the diagonal's; a column on the q-blocks
+    from the diagonal's to that of its last key's last reader. A
+    skipped step names the run's nearer end, so the steps before the
+    run fetch its first block once and the steps after it nothing. The
+    grid is still the whole (q-block, k-block) rectangle: at 32,768
+    with 1024 / 1024 blocks (``_blocks`` chooses them from the shapes,
+    as for every layout) a window of 512 runs 63 of a head's 1024
+    pairs, every one masked, and keeps a quarter of their entries;
+    the 961 others cost a grid step each (~0.4 us, PR 28). Smaller
+    tiles keep more of what they compute and pay four times the
+    steps; a grid as long as the band is ROADMAP.md Queue 1 item 3
+    (i)."""
+
+    window: int
+    # the word a band's kernels carry in their names (``_kernel_name``)
+    kernels = "band"
+
+    def __str__(self):
+        return "window(%d)" % self.window
+
+    def keep(self, q_pos, k_pos):
+        # ``q_pos - window`` is a column's work, the tile's two compares
+        return (k_pos <= q_pos) & (k_pos > q_pos - self.window)
+
+    def _runs(self, q_block, k_block, block_q, block_k):
+        """(first k-block of the row's run, last; first q-block of the
+        column's run, last), none held to the grid."""
+        xp, div = _ops(q_block, k_block)
+        q0, k0 = q_block * block_q, k_block * block_k
+        first_k = div(xp.maximum(q0 - (self.window - 1), 0), block_k)
+        last_k = div(q0 + block_q - 1, block_k)
+        first_q = div(k0, block_q)
+        last_q = div(k0 + block_k - 1 + self.window - 1, block_q)
+        return first_k, last_k, first_q, last_q
+
+    def pair(self, q_block, k_block, block_q, block_k):
+        q0, k0 = q_block * block_q, k_block * block_k
+        q1, k1 = q0 + block_q - 1, k0 + block_k - 1
+        # some row's window meets a column; every row's holds them all
+        some = (k0 <= q1) & (k1 > q0 - self.window)
+        every = (k1 <= q0) & (k0 > q1 - self.window)
+        return some, ~every
+
+    def k_named(self, q_block, k_block, block_q, block_k):
+        xp, _ = _ops(q_block, k_block)
+        first_k, last_k, _, _ = self._runs(
+            q_block, k_block, block_q, block_k)
+        return _clip(xp, k_block, first_k, last_k)
+
+    def q_named(self, q_block, k_block, block_q, block_k, num_q):
+        xp, _ = _ops(q_block, k_block)
+        _, _, first_q, last_q = self._runs(
+            q_block, k_block, block_q, block_k)
+        # held to the grid where seq_q ends before the run does
+        return xp.minimum(_clip(xp, q_block, first_q, last_q), num_q - 1)
+
+    def refusal(self, seq_q, seq_k, block_q, block_k):
+        if self.window < 1:
+            return "%s: a window holds at least the query" % (self,)
+        if seq_q != seq_k:
+            return (
+                "%s over q and k of (%d, %d) positions: a band across "
+                "the shards of a sequence is not built" % (
+                    self, seq_q, seq_k))
+        return ""
+
+
 FULL, CAUSAL = Full(), Causal()
 
 
@@ -567,6 +647,16 @@ def _fwd_kernel(
         )
 
 
+def _kernel_name(causal, kernel):
+    """The name a kernel has in the compiled program and in a device
+    trace: ``flash_<kernel>`` (``benchmark/metrics/flash_time_share.py``
+    finds "flash"), and ``flash_<word>_<kernel>`` under a layout that
+    names its kernels (``Band``: ``flash_band_fwd``), so that a trace
+    tells them from the diagonal's."""
+    word = getattr(as_layout(causal), "kernels", "")
+    return "flash_%s%s" % (word + "_" if word else "", kernel)
+
+
 def _index_maps(causal, block_q, block_k, num_q, k_outer=False):
     """(q-ish, k-ish, lse-ish) index maps of the merged "(bh, seq, d)"
     view, on a ``(bh, q-block, k-block)`` grid or, ``k_outer``, on
@@ -670,9 +760,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        # the kernel's name in the compiled program and in a device
-        # trace (benchmark/metrics/flash_time_share.py finds "flash")
-        name="flash_fwd",
+        name=_kernel_name(causal, "fwd"),
     )(q, k, v)
     return o, lse
 
@@ -973,7 +1061,7 @@ def _bwd(
                 vmem_limit_bytes=_FUSED_VMEM_BYTES,
             ),
             interpret=interpret,
-            name="flash_bwd",
+            name=_kernel_name(causal, "bwd"),
         )(*operands)
         return (dq,) + _sum_groups(dk, dv)
 
@@ -998,7 +1086,7 @@ def _bwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_dq",
+        name=_kernel_name(causal, "dq"),
     )(*operands)
 
     dk, dv = pl.pallas_call(
@@ -1012,7 +1100,7 @@ def _bwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-        name="flash_dkv",
+        name=_kernel_name(causal, "dkv"),
     )(*operands)
     return (dq,) + _sum_groups(dk, dv)
 
@@ -1103,8 +1191,9 @@ def flash_attention(
     mask=None,
 ):
     """Blockwise attention over (batch, heads, seq, head_dim) inputs.
-    ``mask``: a layout (``BlockDiffusion(half_len, block)``) in the
-    place of the boolean ``causal``; tiles it cannot carry are refused.
+    ``mask``: a layout (``BlockDiffusion(half_len, block)``,
+    ``Band(window)``) in the place of the boolean ``causal``; tiles or
+    shapes it cannot carry are refused.
     k and v may have fewer heads than q (grouped-query attention: a
     head count that divides q's; query head ``h`` reads kv head ``h //
     group``, through the index maps, no copy; dk and dv are summed over

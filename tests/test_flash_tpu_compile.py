@@ -153,6 +153,33 @@ def test_the_block_diffusion_layout_compiles(chip, half_len, block, blocks):
     assert device_obs.pallas_kernels(hlo) == {"flash_fwd": 1, "flash_bwd": 1}
 
 
+@pytest.mark.parametrize("heads,mask,kernels", [
+    (64, F.Band(512), {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+    (48, None, {"flash_fwd": 1, "flash_bwd": 1}),
+    (64, F.Band(700), {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+], ids=["window-64-over-8", "full-48-over-8", "window-no-multiple"])
+def test_the_band_layout_compiles_at_the_cell_s_shapes(
+        chip, heads, mask, kernels):
+    """``laguna-xs2-s32k``'s two kinds of layer (PR 42) under Mosaic:
+    32,768 positions, heads of 128, groups of 8 and of 6 over 8 kv
+    heads, the fused backward (33.5 MB of dq accumulator), the band's
+    kernels under names of their own (``Band.keep`` compares integers
+    and ands two compares, as the block-diffusion layout's does)."""
+    q = jax.ShapeDtypeStruct(
+        (1, heads, 32768, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct(
+        (1, 8, 32768, 128), jnp.bfloat16, sharding=chip)
+    assert F.backward_schedule(32768, 32768, 128, jnp.bfloat16) == "fused"
+
+    def loss(q, k, v):
+        out = F.flash_attention(q, k, v, causal=True, mask=mask)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert device_obs.pallas_kernels(hlo) == kernels
+
+
 def _square_float32_dots(hlo, size=64):
     """The dots of a compiled program whose operands and result are all
     float32 [..., size, size]: the product form's and its VJP's."""
