@@ -56,7 +56,7 @@ def _pallas_refusal(q, k, v, block_q, block_k, layout=_flash.CAUSAL):
     tiles = [
         _flash._blocks(
             seq_q, seq_k, q.shape[-1], q.dtype, block_q, block_k,
-            backward=backward, v_dim=v.shape[-1])
+            backward=backward, v_dim=v.shape[-1], layout=layout)
         for backward in (False, True)]
     block_q, block_k = tiles[0]
     if seq_q % block_q or seq_k % block_k:
@@ -82,7 +82,10 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     (q-block, k-block) grid steps compute a tile, how many of those
     apply the causal mask, and how many are skipped
     (``flash_attention.causal_pairs``): the forward's, and the
-    backward's where ``_blocks`` gives it other blocks."""
+    backward's where ``_blocks`` gives it other blocks. A layout that
+    walks runs (``Band``) counts the steps of the grid that runs, the
+    backward's on its (k-block, q-block) grid, and says the length of
+    the inner axis after them (``run_len=2``)."""
     shapes = (q.shape[2], k.shape[2], q.shape[-1], q.dtype)
     v_dim = v.shape[-1]
     layout = _flash.as_layout(causal)
@@ -92,24 +95,32 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
 
     def pairs(backward):
         blocks = _flash._blocks(
-            *shapes, block_q, block_k, backward=backward, v_dim=v_dim)
+            *shapes, block_q, block_k, backward=backward, v_dim=v_dim,
+            layout=layout)
         counts = "run=%d masked=%d skipped=%d" % _flash.causal_pairs(
-            *shapes[:2], *blocks, causal=layout)
-        return counts + (" blocks=%dx%d" % blocks if other else "")
+            *shapes[:2], *blocks, causal=layout, k_outer=backward)
+        steps = _flash._inner_steps(
+            layout, *blocks, shapes[0] // blocks[0], shapes[1] // blocks[1],
+            k_outer=backward)
+        return counts + (" blocks=%dx%d" % blocks if other else ""), steps
 
-    forward, backward = pairs(False), pairs(True)
+    (forward, steps), (backward, back_steps) = pairs(False), pairs(True)
+    run_len = "" if not hasattr(layout, "run") else " run_len=%d%s" % (
+        steps, "" if back_steps == steps else " (backward %d)" % back_steps)
     widths = "" if v_dim == q.shape[-1] else (
         "head q/k=%d v=%d layout=%s, " % (
             q.shape[-1], v_dim, _flash.QK_LAYOUT))
     if k.shape[1] != q.shape[1]:
         widths = "kv_heads=%d group=%d, %s" % (
             k.shape[1], q.shape[1] // k.shape[1], widths)
-    return "%sflash backward=%s, %spairs %s%s" % (
+    return "%sflash backward=%s, %spairs %s%s%s" % (
         widths,
-        _flash.backward_schedule(*shapes, block_q, block_k, v_dim),
+        _flash.backward_schedule(
+            *shapes, block_q, block_k, v_dim, layout=layout),
         "mask=%s " % layout if other else "",
         forward,
         " (backward %s)" % backward if backward != forward else "",
+        run_len,
     )
 
 

@@ -52,12 +52,13 @@ The diagonal is one LAYOUT of the mask among several (``Causal``,
 ``causal`` every caller had). A layout is a small static description
 that says, of two positions, whether the query may see the key
 (``keep``: the kernels' select on a masked tile and the XLA path's
-dense mask are this one function) and, of a (q-block, k-block) pair
-and the two block sizes, its class (``pair``) and which block a
-skipped grid step names (``k_named``, ``q_named``). The kernels, the
-index maps and ``causal_pairs`` read nothing else, so a band is a
-further layout (``Band``), document boundaries would be another, and
-neither a further kernel.
+dense mask are this one function), of a (q-block, k-block) pair and the
+two block sizes, its class (``pair``), and how a grid walks a head's
+pairs: over the whole rectangle, with the block a skipped step names
+(``k_named``, ``q_named``), or over each row's (column's) own run of
+blocks (``run``). The kernels, the index maps and ``causal_pairs`` read
+nothing else, so a band is a further layout (``Band``), document
+boundaries would be another, and neither a further kernel.
 ``BlockDiffusion(half_len, block)`` is block diffusion's training mask
 over ``[noisy copy ; clean copy]`` of a sequence (BD3-LMs,
 arXiv:2503.09573): a noisy query sees its own noisy block, both
@@ -66,8 +67,13 @@ blocks up to its own; L^2 + L B of the (2 L)^2 score entries, with
 tiles that divide L the clean -> noisy quadrant skipped whole.
 ``Band(window)`` is sliding-window attention: a query sees itself and
 the ``window - 1`` keys before it, S W - W (W - 1) / 2 of the S^2
-entries, on a grid that still has a step for every pair of blocks
-(``Band`` says what that costs at 32,768 x 512).
+entries, on a grid as long as the band: the inner axis of each of its
+four kernels' grids is the longest run of blocks a row (on the k-outer
+grids a column) of tiles has, and the inner index an offset into the
+outer block's own run (``_grid_step``), so a head walks 128 steps at
+32,768 x 512 under 512 x 512 tiles, 127 of them running, where the
+rectangle has 4,096 (``Band``; ``_blocks`` picks a band's tiles by its
+window).
 
 Layout: (batch, heads, seq, head_dim); the kernels flatten batch*heads
 into one parallel grid axis and see one head's (seq, head_dim) rows.
@@ -138,7 +144,7 @@ def _lanes(width):
 
 
 def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
-            backward=False, v_dim=None):
+            backward=False, v_dim=None, layout=None):
     """The blocks a kernel runs with: ``None`` is the largest power of
     two, up to a cap chosen from the shapes, that divides the sequence.
     The forward and the backward of one call each ask for their own.
@@ -173,7 +179,41 @@ def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
       0.96 ms a layer alone: twice the rescales of its accumulator),
       so with 512 / 512 in both the step gains half as much (+0.26%);
       at 4096 x 128 they cost the forward 58%.
+
+    Under a ``Band`` (``layout``; every caller that asks passes the
+    call's, so the gate, the log line and the kernels get one answer)
+    the grid is as long as the band, no tile pays for steps that
+    compute nothing, and the choice is what a tile keeps against what a
+    step costs. The q-rows are the window rounded up to a power of two
+    (no fewer than 256, no more than the rule above gives); the k-rows
+    the same in the BACKWARD, and the rule's own in the forward. Read
+    on a v5e at 64 heads over 8 kv heads x 32,768 x 128, bfloat16,
+    window 512 (``laguna-xs2-s32k``; ``scripts/band_flash.py``, PR 43,
+    PERF.md Section 6), a call alone, forward / backward ms, where the
+    rectangle's 1024 / 1024 read 29.3 / 54.8:
+
+        1024 x 1024   20.5 / 37.2   fill 25%    512 x 512   22.0 / 23.8   50%
+         512 x 1024   18.5 / 30.5        33%    256 x 512   24.6 / 29.6   50%
+        1024 x  512   29.1 / 32.6        33%    256 x 256   34.7 / 32.9   67%
+
+    A backward tile costs its area (five MXU products: the call's time
+    over its tiles is 2.9 us at 512 x 512, 9.2 at 1024 x 1024, 1.35 at
+    256 x 256, ~4.7 ms of XLA beside the kernel included; the kernel
+    alone reads 2.36 us a 512 x 512 tile in the step) plus 0.5-0.8 us a
+    step, so it wants the fill and stops at the step's cost: 512 x 512. A forward step costs its
+    q-rows whatever its width (2.7-2.9 us at 512 rows over 512 or 1024
+    keys, 4.8-5.0 at 1024, 1.4-1.5 at 256: not the MXU's time; the
+    online softmax's row maxima, sums and rescales scale so, and no
+    trace of PR 43 splits a step), so it wants few (row, step)
+    visits: k-blocks as long as the rule gives, under q-blocks a
+    window long, 1.5 live steps a row for 2. IN THE STEP
+    (``--step``, ms a step; the rectangle 2167.8): 1024 / 1024 in both
+    2067.3; 512 / 512 in both 2036.1; 256 / 256 2139.0; the forward 1024
+    / 1024 over a backward of 512 / 512 2028.3 and 2027.5; 256 / 1024
+    over it 2037.2; 512 / 2048 over it 2054.1; the choice, 512 / 1024
+    over 512 / 512, 2015.0 twice.
     """
+    picks_q, picks_k = block_q is None, block_k is None
     if block_k is None:
         short = backward and seq_k <= 2048
         block_k = _auto_block(seq_k, 512 if short else 1024)
@@ -189,6 +229,12 @@ def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
             ) <= _FUSED_VMEM_BYTES
         ):
             block_q = tall
+    if isinstance(layout, Band):
+        side = max(1 << (layout.window - 1).bit_length(), 256)
+        if picks_q:
+            block_q = _auto_block(seq_q, min(block_q, side))
+        if picks_k and backward:
+            block_k = _auto_block(seq_k, min(block_k, side))
     return min(block_q, seq_q), min(block_k, seq_k)
 
 
@@ -240,11 +286,27 @@ def _causal_pair(q_block, k_block, block_q, block_k):
 #   keep(q_pos, k_pos)                        may the query see the key
 #   pair(q_block, k_block, block_q, block_k)  (runs, masked): does the
 #       tile keep any element; does a tile that runs drop some
+#
+# and how a grid walks a head's pairs, one of two ways. On the
+# RECTANGLE (``Causal``, ``Full``, ``BlockDiffusion``) the inner grid
+# axis is every block of the moving side, and the layout says which
+# block a step that computes nothing names:
+#
 #   k_named(q_block, k_block, ...)            on a (q-block, k-block)
 #       grid, the k-block step ``k_block`` names: its own if the pair
 #       runs, else that of a neighbouring step that runs
 #   q_named(q_block, k_block, ..., num_q)     the same of a (k-block,
 #       q-block) grid's q-blocks
+#
+# On a grid of RUNS (``Band``) the inner axis is as long as the longest
+# run of moving blocks any outer block has, and its index is an offset
+# into the outer block's own run (``_grid_step``); the layout says where
+# that run lies:
+#
+#   run(outer, block_q, block_k, k_outer)     (first, last) k-block of
+#       the q-block ``outer``'s row of tiles or, ``k_outer``, q-block
+#       of the k-block ``outer``'s column; ``first`` is one of the
+#       grid's, ``last`` may lie past its end
 #
 # and ``refusal(seq_q, seq_k, block_q, block_k)``: why these tiles
 # cannot carry it, "" when they can.
@@ -442,17 +504,20 @@ class Band:
 
     A row of tiles runs on ONE run of k-blocks, from the block of its
     first row's first key to the diagonal's; a column on the q-blocks
-    from the diagonal's to that of its last key's last reader. A
-    skipped step names the run's nearer end, so the steps before the
-    run fetch its first block once and the steps after it nothing. The
-    grid is still the whole (q-block, k-block) rectangle: at 32,768
-    with 1024 / 1024 blocks (``_blocks`` chooses them from the shapes,
-    as for every layout) a window of 512 runs 63 of a head's 1024
-    pairs, every one masked, and keeps a quarter of their entries;
-    the 961 others cost a grid step each (~0.4 us, PR 28). Smaller
-    tiles keep more of what they compute and pay four times the
-    steps; a grid as long as the band is ROADMAP.md Queue 1 item 3
-    (i)."""
+    from the diagonal's to that of its last key's last reader
+    (``run``). The grid is as long as the band: its inner axis is the
+    longest run a row (a column) has, and step ``inner`` of an outer
+    block names block ``first + inner`` of that block's own run
+    (``_grid_step``; every kernel of both backward schedules walks so).
+    The steps that compute nothing are the slots past a run's end: of
+    a run the sequence cuts (row 0 has no block before the diagonal's,
+    the last columns no reader after the last q-block) and, where the
+    two block sizes differ, of a run shorter than the longest. At
+    32,768 under a window of 512, 1024 / 1024 blocks walk 64 steps a
+    head for the rectangle's 1,024, 63 of them running; 512 / 512
+    blocks 128, 127 running, and keep half of what they compute where
+    1024 / 1024 keep a quarter (``_blocks`` chooses, and says what was
+    read on the chip)."""
 
     window: int
     # the word a band's kernels carry in their names (``_kernel_name``)
@@ -465,16 +530,18 @@ class Band:
         # ``q_pos - window`` is a column's work, the tile's two compares
         return (k_pos <= q_pos) & (k_pos > q_pos - self.window)
 
-    def _runs(self, q_block, k_block, block_q, block_k):
-        """(first k-block of the row's run, last; first q-block of the
-        column's run, last), none held to the grid."""
-        xp, div = _ops(q_block, k_block)
-        q0, k0 = q_block * block_q, k_block * block_k
-        first_k = div(xp.maximum(q0 - (self.window - 1), 0), block_k)
-        last_k = div(q0 + block_q - 1, block_k)
-        first_q = div(k0, block_q)
-        last_q = div(k0 + block_k - 1 + self.window - 1, block_q)
-        return first_k, last_k, first_q, last_q
+    def run(self, outer, block_q, block_k, k_outer=False):
+        """(first, last) k-block of the q-block ``outer``'s row of
+        tiles or, ``k_outer``, q-block of the k-block ``outer``'s
+        column; a column's ``last`` may lie past the grid's end."""
+        xp, div = _ops(outer)
+        if k_outer:
+            k0 = outer * block_k
+            return div(k0, block_q), div(
+                k0 + block_k - 1 + self.window - 1, block_q)
+        q0 = outer * block_q
+        return div(xp.maximum(q0 - (self.window - 1), 0), block_k), div(
+            q0 + block_q - 1, block_k)
 
     def pair(self, q_block, k_block, block_q, block_k):
         q0, k0 = q_block * block_q, k_block * block_k
@@ -483,19 +550,6 @@ class Band:
         some = (k0 <= q1) & (k1 > q0 - self.window)
         every = (k1 <= q0) & (k0 > q1 - self.window)
         return some, ~every
-
-    def k_named(self, q_block, k_block, block_q, block_k):
-        xp, _ = _ops(q_block, k_block)
-        first_k, last_k, _, _ = self._runs(
-            q_block, k_block, block_q, block_k)
-        return _clip(xp, k_block, first_k, last_k)
-
-    def q_named(self, q_block, k_block, block_q, block_k, num_q):
-        xp, _ = _ops(q_block, k_block)
-        _, _, first_q, last_q = self._runs(
-            q_block, k_block, block_q, block_k)
-        # held to the grid where seq_q ends before the run does
-        return xp.minimum(_clip(xp, q_block, first_q, last_q), num_q - 1)
 
     def refusal(self, seq_q, seq_k, block_q, block_k):
         if self.window < 1:
@@ -536,34 +590,77 @@ def _mask_tile(layout, s, q_block, k_block, block_q, block_k):
     return jnp.where(layout.keep(q_pos, k_pos), s, NEG_INF)
 
 
-def causal_pairs(seq_q, seq_k, block_q, block_k, causal=True):
-    """``(run, masked, skipped)``: of one head's (q-block, k-block)
-    pairs, how many compute a tile, how many of those apply the mask,
-    and how many are grid steps that compute and fetch nothing, under
-    the layout ``causal`` (``as_layout``). A function of shapes
+def _inner_steps(causal, block_q, block_k, num_q, num_k, k_outer=False):
+    """Steps of a grid's inner axis under the layout ``causal``: on
+    ``(bh, q-block, k-block)`` or, ``k_outer``, ``(bh, k-block,
+    q-block)``. Every block of the moving side on the rectangle; the
+    longest run an outer block has, held to the grid, where the layout
+    walks runs (a window as long as the sequence: the rectangle's
+    side)."""
+    layout = as_layout(causal)
+    num_outer, num_inner = (num_k, num_q) if k_outer else (num_q, num_k)
+    if not hasattr(layout, "run"):
+        return num_inner
+    first, last = layout.run(
+        np.arange(num_outer), block_q, block_k, k_outer)
+    return int((np.minimum(last, num_inner - 1) - first + 1).max())
+
+
+def _grid_step(causal, outer, inner, block_q, block_k, num_inner,
+               k_outer=False):
+    """``(block, live)`` of step ``inner`` of the outer block ``outer``:
+    the moving block the step names, and whether it is one of the outer
+    block's run. On the rectangle the step's own index, and ``None``
+    (``pair`` alone says which steps run). On a grid of runs block
+    ``first + inner`` of the outer block's own run; a slot past the
+    run's end (or the grid's) names the run's last block, which the
+    step before it fetched, and computes nothing."""
+    layout = as_layout(causal)
+    if not hasattr(layout, "run"):
+        return inner, None
+    xp, _ = _ops(outer, inner)
+    first, last = layout.run(outer, block_q, block_k, k_outer)
+    last = xp.minimum(last, num_inner - 1)
+    block = first + inner
+    return xp.minimum(block, last), block <= last
+
+
+def causal_pairs(seq_q, seq_k, block_q, block_k, causal=True,
+                 k_outer=False):
+    """``(run, masked, skipped)``: of the steps one head's grid walks
+    (``k_outer``: the k-outer grid's, whose count differs only where a
+    layout walks runs), how many compute a tile, how many of those
+    apply the mask, and how many compute and fetch nothing, under the
+    layout ``causal`` (``as_layout``). A function of shapes
     (``ops/attention.py`` logs it)."""
     num_q, num_k = seq_q // block_q, seq_k // block_k
     q_block = np.arange(num_q)[:, None]
     k_block = np.arange(num_k)[None, :]
     run, masked = as_layout(causal).pair(q_block, k_block, block_q, block_k)
     run = np.broadcast_to(run, (num_q, num_k))
+    steps = (num_k if k_outer else num_q) * _inner_steps(
+        causal, block_q, block_k, num_q, num_k, k_outer)
     return (
-        int(run.sum()), int((run & masked).sum()), int((~run).sum())
+        int(run.sum()), int((run & masked).sum()), steps - int(run.sum())
     )
 
 
-def _each_class(causal, q_block, k_block, block_q, block_k, tile):
+def _each_class(causal, q_block, k_block, block_q, block_k, tile,
+                live=None):
     """``tile(masked)`` once for the pair's class under the layout
     ``causal``: a masked pair with the select (``masked`` is the layout
     whose mask the tile takes), an interior one without (``None``: a
     body of its own, so the iotas, the compare and the select are not
     in it), nothing for a skipped pair. A call without a mask has the
-    one unmasked body."""
+    one unmasked body. ``live``: on a grid of runs, whether the step
+    is one of its run's (``_grid_step``)."""
     layout = as_layout(causal)
     if layout == FULL:
         tile(None)
         return
     run, masked = layout.pair(q_block, k_block, block_q, block_k)
+    if live is not None:
+        run = jnp.logical_and(live, run)
     pl.when(jnp.logical_and(run, masked))(
         functools.partial(tile, layout))
     pl.when(jnp.logical_and(run, jnp.logical_not(masked)))(
@@ -589,12 +686,15 @@ def _fwd_kernel(
     causal,
     block_q,
     block_k,
+    num_k,
 ):
     q_block = pl.program_id(1)
-    k_block = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    inner = pl.program_id(2)
+    steps = pl.num_programs(2)
+    k_block, live = _grid_step(
+        causal, q_block, inner, block_q, block_k, num_k)
 
-    @pl.when(k_block == 0)
+    @pl.when(inner == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -633,9 +733,9 @@ def _fwd_kernel(
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile, live)
 
-    @pl.when(k_block == num_k - 1)
+    @pl.when(inner == steps - 1)
     def _finalize():
         l_final = l_ref[:, :1]
         # Fully-masked rows (can't happen causally, but keep the kernel
@@ -657,30 +757,38 @@ def _kernel_name(causal, kernel):
     return "flash_%s%s" % (word + "_" if word else "", kernel)
 
 
-def _index_maps(causal, block_q, block_k, num_q, k_outer=False):
+def _index_maps(causal, block_q, block_k, num_q, k_outer=False, num_k=None):
     """(q-ish, k-ish, lse-ish) index maps of the merged "(bh, seq, d)"
     view, on a ``(bh, q-block, k-block)`` grid or, ``k_outer``, on
     ``(bh, k-block, q-block)``, under the layout ``causal``.
 
-    A masked grid's skipped steps name the block of a neighbouring
-    step that runs, so the pipeline, which copies a block only when its
-    index changes, fetches nothing for them: the inner axis is clamped
-    by the layout (``k_named``, ``q_named``; the diagonal's are
-    ``_causal_pair``'s bounds: k-blocks from above by ``last_k``,
-    q-blocks from below by ``first_q``). Only inputs move with the
-    inner axis; outputs follow the outer one, which is never clamped.
-    A call without a mask gets the identity (``Full``'s)."""
+    A masked grid's steps that compute nothing name the block of a
+    neighbouring step that runs, so the pipeline, which copies a block
+    only when its index changes, fetches nothing for them. On the
+    rectangle the inner axis is clamped by the layout (``k_named``,
+    ``q_named``; the diagonal's are ``_causal_pair``'s bounds: k-blocks
+    from above by ``last_k``, q-blocks from below by ``first_q``); on a
+    grid of runs (``num_k`` is read there alone) it is an offset into
+    the outer block's run, ``_grid_step``, the arithmetic the kernels
+    do. Only inputs move with the inner axis; outputs follow the outer
+    one, which is never clamped. A call without a mask gets the
+    identity (``Full``'s)."""
     layout = as_layout(causal)
 
+    def moving(outer, inner):
+        if hasattr(layout, "run"):
+            return _grid_step(
+                layout, outer, inner, block_q, block_k,
+                num_q if k_outer else num_k, k_outer)[0]
+        if k_outer:
+            return layout.q_named(inner, outer, block_q, block_k, num_q)
+        return layout.k_named(outer, inner, block_q, block_k)
+
     def q_block(outer, inner):
-        if not k_outer:
-            return outer
-        return layout.q_named(inner, outer, block_q, block_k, num_q)
+        return moving(outer, inner) if k_outer else outer
 
     def k_block(outer, inner):
-        if k_outer:
-            return outer
-        return layout.k_named(outer, inner, block_q, block_k)
+        return outer if k_outer else moving(outer, inner)
 
     q_idx = lambda b, outer, inner: (b, q_block(outer, inner), 0)
     k_idx = lambda b, outer, inner: (b, k_block(outer, inner), 0)
@@ -717,10 +825,11 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     bh, seq_q, head_dim = q.shape
     seq_k, v_dim = k.shape[1], v.shape[2]
     block_q, block_k = _blocks(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim)
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim,
+        layout=as_layout(causal))
     num_q = seq_q // block_q
     num_k = seq_k // block_k
-    grid = (bh, num_q, num_k)
+    grid = (bh, num_q, _inner_steps(causal, block_q, block_k, num_q, num_k))
 
     kernel = functools.partial(
         _fwd_kernel,
@@ -728,8 +837,10 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         causal=causal,
         block_q=block_q,
         block_k=block_k,
+        num_k=num_k,
     )
-    q_idx, k_idx, stat_idx = _index_maps(causal, block_q, block_k, num_q)
+    q_idx, k_idx, stat_idx = _index_maps(
+        causal, block_q, block_k, num_q, num_k=num_k)
     kv_idx = _kv_index_map(k_idx, bh // k.shape[0])
     # lse rides in (bh, 1, seq) — the singleton axis makes the block's
     # second-minor dim equal the full array dim, satisfying the TPU
@@ -815,12 +926,15 @@ def _dq_kernel(
     causal,
     block_q,
     block_k,
+    num_k,
 ):
     q_block = pl.program_id(1)
-    k_block = pl.program_id(2)
-    num_k = pl.num_programs(2)
+    inner = pl.program_id(2)
+    steps = pl.num_programs(2)
+    k_block, live = _grid_step(
+        causal, q_block, inner, block_q, block_k, num_k)
 
-    @pl.when(k_block == 0)
+    @pl.when(inner == 0)
     def _init():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
@@ -834,9 +948,9 @@ def _dq_kernel(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
 
-    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile, live)
 
-    @pl.when(k_block == num_k - 1)
+    @pl.when(inner == steps - 1)
     def _finalize():
         dq_ref[0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
@@ -853,10 +967,13 @@ def _dkv_kernel(
     causal,
     block_q,
     block_k,
+    num_q,
+    num_k,
     with_dq,
 ):
     """dk and dv of one k-block, accumulated in float32 over the
-    q-blocks (grid ``(bh, k-block, q-block)``).
+    q-blocks (grid ``(bh, k-block, q-block)``; under a layout that
+    walks runs, the q-blocks of the k-block's run).
 
     ``with_dq`` (the fused backward, ``flash_bwd``): dq is accumulated
     from the same ``ds``. Its accumulator must outlive the k-blocks, so
@@ -864,26 +981,36 @@ def _dkv_kernel(
     and ``dq_ref`` the whole dq of this ``bh`` (an output block whose
     index depends on ``bh`` alone stays in VMEM until ``bh`` moves on).
     A q-block's rows are zeroed when the first k-block meets them and
-    cast to the output, once, when the last one has; in between the
-    ``ds @ k`` terms arrive in ascending k, as in ``_dq_kernel``."""
+    cast to the output, once, when the last one has: the grid's first
+    and last on the rectangle, those of the q-block's own run on a grid
+    of runs, where no other k-block has a step for these rows. In
+    between the ``ds @ k`` terms arrive in ascending k, as in
+    ``_dq_kernel``."""
     if with_dq:
         (dk_ref, dv_ref, dq_ref,
          dk_acc_ref, dv_acc_ref, dq_acc_ref) = out_and_scratch
     else:
         dk_ref, dv_ref, dk_acc_ref, dv_acc_ref = out_and_scratch
     k_block = pl.program_id(1)
-    q_block = pl.program_id(2)
-    num_k = pl.num_programs(1)
-    num_q = pl.num_programs(2)
+    inner = pl.program_id(2)
+    grid_k = pl.num_programs(1)
+    steps = pl.num_programs(2)
+    q_block, live = _grid_step(
+        causal, k_block, inner, block_q, block_k, num_q, k_outer=True)
     rows = pl.ds(pl.multiple_of(q_block * block_q, block_q), block_q)
+    if with_dq and live is not None:
+        first_k, last_k = as_layout(causal).run(q_block, block_q, block_k)
+        last_k = jnp.minimum(last_k, num_k - 1)
 
-    @pl.when(q_block == 0)
+    @pl.when(inner == 0)
     def _init():
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
     if with_dq:
-        @pl.when(k_block == 0)
+        @pl.when(
+            k_block == 0 if live is None
+            else jnp.logical_and(live, k_block == first_k))
         def _init_dq():
             dq_acc_ref[rows, :] = jnp.zeros(
                 (block_q, dq_acc_ref.shape[1]), jnp.float32
@@ -915,15 +1042,17 @@ def _dkv_kernel(
                 ds, k, preferred_element_type=jnp.float32
             )
 
-    _each_class(causal, q_block, k_block, block_q, block_k, _tile)
+    _each_class(causal, q_block, k_block, block_q, block_k, _tile, live)
 
-    @pl.when(q_block == num_q - 1)
+    @pl.when(inner == steps - 1)
     def _finalize():
         dk_ref[0] = dk_acc_ref[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
     if with_dq:
-        @pl.when(k_block == num_k - 1)
+        @pl.when(
+            k_block == grid_k - 1 if live is None
+            else jnp.logical_and(live, k_block == last_k))
         def _finalize_dq():
             dq_ref[0, rows, :] = dq_acc_ref[rows, :].astype(dq_ref.dtype)
 
@@ -955,7 +1084,7 @@ def fused_bwd_vmem_bytes(seq_q, head_dim, block_q, block_k, itemsize,
 
 
 def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
-                      block_k=None, v_dim=None):
+                      block_k=None, v_dim=None, layout=None):
     """Which backward these shapes get: ``"fused"`` (one kernel,
     ``flash_bwd``: the scores rebuilt once) where dq's accumulator fits
     the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``: rebuilt
@@ -963,7 +1092,7 @@ def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
     decides by this and ``ops/attention.py`` logs it."""
     block_q, block_k = _blocks(
         seq_q, seq_k, head_dim, dtype, block_q, block_k, backward=True,
-        v_dim=v_dim)
+        v_dim=v_dim, layout=layout)
     held = fused_bwd_vmem_bytes(
         seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize,
         v_dim,
@@ -978,7 +1107,7 @@ def _bwd(
     seq_k, v_dim = k.shape[1], v.shape[2]
     block_q, block_k = _blocks(
         seq_q, seq_k, head_dim, q.dtype, block_q, block_k, backward=True,
-        v_dim=v_dim)
+        v_dim=v_dim, layout=as_layout(causal))
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, None, :]  # (bh, 1, seq): same tiling-friendly layout as lse
@@ -987,8 +1116,14 @@ def _bwd(
     num_k = seq_k // block_k
     operands = (q, k, v, do, lse, delta)
     statics = dict(
-        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k
+        sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+        num_k=num_k,
     )
+    # the inner axis of the (bh, k-block, q-block) grids and of the
+    # split pair's (bh, q-block, k-block) one
+    q_steps = _inner_steps(
+        causal, block_q, block_k, num_q, num_k, k_outer=True)
+    k_steps = _inner_steps(causal, block_q, block_k, num_q, num_k)
     fuse = backward_schedule(
         seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim
     ) == "fused"
@@ -1005,7 +1140,7 @@ def _bwd(
     group = bh // k.shape[0]
     # the dkv grid iterates (bh, k-block, q-block)
     q_idx, k_idx, stat_idx = _index_maps(
-        causal, block_q, block_k, num_q, k_outer=True)
+        causal, block_q, block_k, num_q, k_outer=True, num_k=num_k)
     kv_idx = _kv_index_map(k_idx, group)
 
     dq_struct = _out_struct(q.shape, q.dtype, *operands)
@@ -1043,8 +1178,9 @@ def _bwd(
 
     if fuse:
         dk, dv, dq = pl.pallas_call(
-            functools.partial(_dkv_kernel, with_dq=True, **statics),
-            grid=(bh, num_k, num_q),
+            functools.partial(
+                _dkv_kernel, with_dq=True, num_q=num_q, **statics),
+            grid=(bh, num_k, q_steps),
             in_specs=dkv_in_specs,
             out_specs=dkv_out_specs + (
                 pl.BlockSpec((1, seq_q, head_dim), dq_idx),
@@ -1066,11 +1202,12 @@ def _bwd(
         return (dq,) + _sum_groups(dk, dv)
 
     # the split pair's dq grid iterates (bh, q-block, k-block)
-    q_idx, k_idx, stat_idx = _index_maps(causal, block_q, block_k, num_q)
+    q_idx, k_idx, stat_idx = _index_maps(
+        causal, block_q, block_k, num_q, num_k=num_k)
     kv_idx = _kv_index_map(k_idx, group)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **statics),
-        grid=(bh, num_q, num_k),
+        grid=(bh, num_q, k_steps),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
             pl.BlockSpec((1, block_k, head_dim), kv_idx),
@@ -1090,8 +1227,9 @@ def _bwd(
     )(*operands)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, with_dq=False, **statics),
-        grid=(bh, num_k, num_q),
+        functools.partial(
+            _dkv_kernel, with_dq=False, num_q=num_q, **statics),
+        grid=(bh, num_k, q_steps),
         in_specs=dkv_in_specs,
         out_specs=dkv_out_specs,
         scratch_shapes=dkv_scratch,
@@ -1212,9 +1350,9 @@ def flash_attention(
     blocks dividing the sequence, up to caps ``_blocks`` chooses from
     the shapes for the forward and for the backward apart (512 / 1024;
     1024 / 1024 for long sequences; 512 / 512 for a short one's
-    backward): measured on v5e at S=16k, (512, 1024) runs 4.6x faster
-    than (128, 128) — bigger k-blocks amortize the online-softmax
-    rescale and keep the MXU fed.
+    backward; under a ``Band`` by its window): measured on v5e at
+    S=16k, (512, 1024) runs 4.6x faster than (128, 128) — bigger
+    k-blocks amortize the online-softmax rescale and keep the MXU fed.
     """
     if q.ndim != 4:
         raise ValueError("expected 4-D q/k/v")
@@ -1228,19 +1366,20 @@ def flash_attention(
         raise ValueError(
             "k and v must share a head count that divides q's, got "
             "%d, %d and %d" % (k.shape[1], v.shape[1], heads))
+    layout = as_layout(causal if mask is None else mask)
     # the forward's blocks; the backward's are these or their halves
     fwd_q, fwd_k = _blocks(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim)
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim=v_dim,
+        layout=layout)
     if seq_q % fwd_q or seq_k % fwd_k:
         raise ValueError(
             "seq lengths (%d, %d) must be multiples of the block sizes "
             "(%d, %d)" % (seq_q, seq_k, fwd_q, fwd_k)
         )
-    layout = as_layout(causal if mask is None else mask)
     for backward in (False, True):
         refusal = layout.refusal(seq_q, seq_k, *_blocks(
             seq_q, seq_k, head_dim, q.dtype, block_q, block_k,
-            backward=backward, v_dim=v_dim))
+            backward=backward, v_dim=v_dim, layout=layout))
         if refusal:
             raise ValueError(refusal)
     if sm_scale is None:

@@ -1,13 +1,17 @@
 """The band as a mask layout (``ops/flash_attention.py:Band``):
-``keep`` against the dense mask, the pair classifier and the skipped
-steps' clamps against brute force with the window under, at and over a
-block and no multiple of one, the kernels in interpret mode against
-dense masked softmax at groups 6 and 8, the counts of the cell, the
+``keep`` against the dense mask, the pair classifier and the grid of
+runs against brute force with the window under, at and over a block
+and no multiple of one, the kernels in interpret mode against dense
+masked softmax at groups 6 and 8 and, bit for bit, against the walk
+over the whole rectangle the band had before its grid was its own
+length (``RectangleBand``, kept here alone), the counts of the cell,
+the attention line under the benchmark's own regular expression, the
 refusals by name, the kernels' names, and the older layouts' programs
 against what they were (``tests/test_mask_layouts.py`` is the
 protocol's own file; ``tests/test_block_diffusion.py`` pins the
 diagonal's kernels at the cells' shapes)."""
 
+import dataclasses
 import hashlib
 import re
 
@@ -16,7 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.lib import window_trace
 from elasticdl_tpu.models import transformer as T
+from elasticdl_tpu.ops import attention as A
 from elasticdl_tpu.ops import flash_attention as F
 from elasticdl_tpu.ops.attention import (
     _flash_facts,
@@ -52,6 +58,8 @@ BAND_CASES = {
 }
 band_cases = pytest.mark.parametrize(
     "case", list(BAND_CASES.values()), ids=list(BAND_CASES))
+# the tiles ``_blocks`` gives the cell's band: (forward's, backward's)
+CELL_BLOCKS = ((512, 1024), (512, 512))
 
 
 def _tiles(case):
@@ -94,7 +102,9 @@ def test_keep_is_the_equation(case):
 def test_pair_classes_match_the_position_matrix(case):
     """Every tile's class against the dense mask: skipped iff it keeps
     nothing, interior iff it keeps everything; ``causal_pairs`` counts
-    what the enumeration counts; traced scalars say what numpy says."""
+    the pairs the enumeration counts (its third number is the run
+    grid's, ``test_the_run_grid_names_every_pair...``); traced scalars
+    say what numpy says."""
     seq, window, block_q, block_k = case
     layout = F.Band(window)
     assert layout.refusal(seq, seq, block_q, block_k) == ""
@@ -105,8 +115,9 @@ def test_pair_classes_match_the_position_matrix(case):
         block_q, block_k)
     np.testing.assert_array_equal(run, some)
     np.testing.assert_array_equal(masked[some], ~every[some])
-    assert F.causal_pairs(seq, seq, block_q, block_k, causal=layout) == (
-        int(some.sum()), int((some & ~every).sum()), int((~some).sum()))
+    assert F.causal_pairs(
+        seq, seq, block_q, block_k, causal=layout)[:2] == (
+            int(some.sum()), int((some & ~every).sum()))
     traced = jax.jit(lambda i, j: layout.pair(i, j, block_q, block_k))
     for i, j in [(0, 0), (0, num_k - 1), (num_q - 1, 0),
                  (num_q - 1, num_k - 1), (num_q // 2, num_k // 2),
@@ -120,67 +131,172 @@ def test_pair_classes_match_the_position_matrix(case):
 def test_the_cell_s_counts():
     """32,768 positions under a window of 512 (``laguna-xs2-s32k``): at
     1024 x 1024 a row of tiles runs its diagonal tile and the one
-    before it, both masked; half the tiles' size runs as many a row."""
+    before it, both masked; half the tiles' size runs as many a row.
+    The grid is the band's length: one step a head computes nothing
+    (row 0's second slot; the last column's), where the rectangle had
+    961."""
     band = F.Band(512)
-    assert F.causal_pairs(32768, 32768, 1024, 1024, causal=band) == (
-        63, 63, 1024 - 63)
-    assert F.causal_pairs(32768, 32768, 512, 512, causal=band) == (
-        127, 127, 4096 - 127)
+    for k_outer in (False, True):
+        assert F.causal_pairs(
+            32768, 32768, 1024, 1024, causal=band, k_outer=k_outer) == (
+                63, 63, 1)
+        assert F.causal_pairs(
+            32768, 32768, 512, 512, causal=band, k_outer=k_outer) == (
+                127, 127, 1)
+        assert F._inner_steps(band, 1024, 1024, 32, 32, k_outer) == 2
+        assert F._inner_steps(band, 512, 512, 64, 64, k_outer) == 2
+    # where the blocks differ a shorter run has slots to spare, and
+    # the two grid orders have their own counts
+    assert F.causal_pairs(32768, 32768, 512, 1024, causal=band) == (
+        95, 95, 64 * 2 - 95)
+    assert F.causal_pairs(
+        32768, 32768, 512, 1024, causal=band, k_outer=True) == (
+            95, 95, 32 * 3 - 95)
     # a window of a block and one key more reaches a third tile
     assert F.causal_pairs(4096, 4096, 512, 512, causal=F.Band(514))[0] == (
         8 + 7 + 6)
-    # the blocks the cell's shapes get, forward and backward alike
+    # the blocks the cell's shapes get, by the window and not by the
+    # sequence, the forward's and the backward's (``_blocks``' table)
     for backward in (False, True):
         assert F._blocks(32768, 32768, 128, jnp.bfloat16, None, None,
+                         backward=backward, layout=band) == CELL_BLOCKS[
+                             backward]
+        # another layout's are what they were; a caller's are kept
+        assert F._blocks(32768, 32768, 128, jnp.bfloat16, None, None,
                          backward=backward) == (1024, 1024)
-    assert F.backward_schedule(32768, 32768, 128, jnp.bfloat16) == "fused"
+        assert F._blocks(32768, 32768, 128, jnp.bfloat16, 1024, 256,
+                         backward=backward, layout=band) == (1024, 256)
+    assert F.backward_schedule(
+        32768, 32768, 128, jnp.bfloat16, layout=band) == "fused"
+
+
+def _walk(layout, case, k_outer):
+    """One head's grid, step by step: ``{outer: [(q-block, k-block,
+    live, named)]}`` with ``named`` the (q, k, stat) blocks the index
+    maps give the step and ``live`` whether the kernels compute it."""
+    seq, _, block_q, block_k = case
+    num_q, num_k = seq // block_q, seq // block_k
+    num_outer, num_inner = (num_k, num_q) if k_outer else (num_q, num_k)
+    steps = F._inner_steps(layout, block_q, block_k, num_q, num_k, k_outer)
+    q_idx, k_idx, stat_idx = F._index_maps(
+        layout, block_q, block_k, num_q, k_outer=k_outer, num_k=num_k)
+    walk = {}
+    for outer in range(num_outer):
+        walk[outer] = []
+        for inner in range(steps):
+            block, live = F._grid_step(
+                layout, outer, inner, block_q, block_k, num_inner, k_outer)
+            i, j = (block, outer) if k_outer else (outer, block)
+            named = (int(q_idx(0, outer, inner)[1]),
+                     int(k_idx(0, outer, inner)[1]),
+                     int(stat_idx(0, outer, inner)[2]))
+            walk[outer].append((int(i), int(j), bool(live), named))
+    return walk
 
 
 @pytest.mark.parametrize("k_outer", [False, True], ids=["q-outer", "k-outer"])
 @band_cases
-def test_skipped_steps_name_a_block_already_there(case, k_outer):
-    """A step that runs names its own blocks, and over a head's walk
-    the moving operand's block index changes as often as over the steps
-    that run alone, so nothing is fetched for a skipped step: the
-    clamps work at both ends of the run."""
+def test_the_run_grid_names_every_pair_that_runs_exactly_once(case, k_outer):
+    """The grid of runs against the pair classifier: every pair that
+    runs has exactly one live step and no other pair has one; a row's
+    (column's) live steps come first and name ascending blocks of the
+    moving side; every index the maps give lies inside the arrays; a
+    step off the run's end names the block the step before it fetched;
+    the inner axis is as long as the longest run and no longer; and the
+    traced arithmetic of the kernels says what numpy says."""
     seq, window, block_q, block_k = case
     if seq > 8192:
-        seq = 8192  # the same tiles and window, a shorter walk
+        case = (8192, window, block_q, block_k)  # the same tiles, shorter
+        seq = 8192
     layout = F.Band(window)
     num_q, num_k = seq // block_q, seq // block_k
-    q_idx, k_idx, stat_idx = F._index_maps(
-        layout, block_q, block_k, num_q, k_outer=k_outer)
     run, _ = layout.pair(
         np.arange(num_q)[:, None], np.arange(num_k)[None, :],
         block_q, block_k)
+    walk = _walk(layout, case, k_outer)
     moving = 0 if k_outer else 1
-    for outer in range(num_k if k_outer else num_q):
-        walked, ran = [], []
-        for inner in range(num_q if k_outer else num_k):
-            i, j = (inner, outer) if k_outer else (outer, inner)
-            named = (int(q_idx(0, outer, inner)[1]),
-                     int(k_idx(0, outer, inner)[1]),
-                     int(stat_idx(0, outer, inner)[2]))
+    lives = np.zeros((num_q, num_k), int)
+    longest = 0
+    for outer, steps in walk.items():
+        live_blocks = [step[moving] for step in steps if step[2]]
+        # live steps first, on ascending blocks, one step each
+        assert [step[2] for step in steps] == sorted(
+            (step[2] for step in steps), reverse=True)
+        assert live_blocks == sorted(set(live_blocks)) and live_blocks
+        longest = max(longest, len(live_blocks))
+        for at, (i, j, live, named) in enumerate(steps):
             assert named[0] == named[2]
-            assert named[1 - moving] == (i, j)[1 - moving]
-            assert 0 <= named[moving] < (num_q, num_k)[moving]
-            walked.append(named[moving])
-            if run[i, j]:
+            assert 0 <= named[0] < num_q and 0 <= named[1] < num_k
+            assert named[1 - moving] == outer
+            if live:
                 assert named[:2] == (i, j)
-                ran.append(named[moving])
-        assert ran, "a row or column of tiles that never runs"
-        assert set(walked) == set(ran)
-        assert _changes(walked) == _changes(ran)
+                lives[i, j] += 1
+            else:
+                assert named == steps[at - 1][3]
+    np.testing.assert_array_equal(lives, run.astype(int))
+    assert longest == len(walk[0])
+    assert F.causal_pairs(
+        seq, seq, block_q, block_k, causal=layout, k_outer=k_outer
+    )[2] == len(walk) * longest - int(run.sum())
+    # a window as long as the sequence: the rectangle's side
+    if window >= seq:
+        assert longest == (num_q if k_outer else num_k)
+    traced = jax.jit(lambda outer, inner: F._grid_step(
+        layout, outer, inner, block_q, block_k,
+        num_q if k_outer else num_k, k_outer))
+    for outer in {0, len(walk) // 2, len(walk) - 1}:
+        for inner in range(longest):
+            block, live = traced(jnp.int32(outer), jnp.int32(inner))
+            i, j, want, named = walk[outer][inner]
+            assert bool(live) == want and int(block) == named[moving]
 
 
-def test_the_clamp_holds_a_column_to_the_grid():
-    """``seq_q`` may end before a column's run does: the q-block a
-    skipped step names is one of the grid's."""
+@band_cases
+def test_dq_s_rows_are_zeroed_and_rounded_once(case):
+    """The fused backward's predicates on its (k-block, q-block) grid
+    of runs (``_dkv_kernel``): over a head's walk every q-block's rows
+    of the dq accumulator are zeroed at exactly one live step, before
+    any term is added, and rounded out at exactly one, after the last;
+    the terms between arrive in ascending k."""
+    seq, window, block_q, block_k = case
+    if seq > 8192:
+        case = (8192, window, block_q, block_k)
+        seq = 8192
+    layout = F.Band(window)
+    num_q, num_k = seq // block_q, seq // block_k
+    first_k, last_k = layout.run(np.arange(num_q), block_q, block_k)
+    last_k = np.minimum(last_k, num_k - 1)
+    events = {i: [] for i in range(num_q)}
+    for k_block, steps in _walk(layout, case, True).items():
+        for i, j, live, _ in steps:
+            if not live:
+                continue
+            assert j == k_block
+            if j == first_k[i]:
+                events[i].append("zero")
+            events[i].append(j)
+            if j == last_k[i]:
+                events[i].append("round")
+    for i, seen in events.items():
+        terms = [e for e in seen if not isinstance(e, str)]
+        assert seen == ["zero"] + terms + ["round"]
+        assert terms == list(range(first_k[i], last_k[i] + 1))
+
+
+def test_a_run_the_sequence_cuts_stays_on_the_grid():
+    """``seq_q`` may end before a column's run does: the last columns'
+    spare slots are dead and name the grid's last q-block; row 0 has no
+    block before the diagonal's."""
     layout = F.Band(512)
-    for k_block in range(8):
-        named = layout.q_named(
-            np.arange(4), np.int64(k_block), 256, 256, 4)
-        assert named.min() >= 0 and named.max() <= 3
+    case = (1024, 512, 256, 256)
+    columns = _walk(layout, case, True)
+    assert [sum(step[2] for step in steps) for steps in columns.values()] == [
+        3, 3, 2, 1]
+    assert all(named[0] == 3 for *_, live, named in columns[3] if not live)
+    rows = _walk(layout, case, False)
+    assert [sum(step[2] for step in steps) for steps in rows.values()] == [
+        1, 2, 3, 3]
+    assert [named[1] for *_, named in rows[0]] == [0, 0, 0]
 
 
 def test_the_refusals_by_name():
@@ -217,12 +333,68 @@ def test_the_attention_line_says_the_band_and_the_group():
         q = jnp.zeros((1, heads, 32768, 128), jnp.bfloat16)
         assert _flash_facts(q, k, k, F.Band(512), None, None) == (
             "kv_heads=8 group=%d, flash backward=fused, mask=window(512) "
-            "pairs run=63 masked=63 skipped=961 blocks=1024x1024" % group)
+            "%s" % (group, LINES[None][0]))
     # the full layers' line is the diagonal's, at their own group
     q = jnp.zeros((1, 48, 32768, 128), jnp.bfloat16)
     assert _flash_facts(q, k, k, True, None, None) == (
         "kv_heads=8 group=6, flash backward=fused, "
         "pairs run=528 masked=32 skipped=496")
+
+
+# (block_q, block_k): the line's tail, what ``fill`` reads from it
+LINES = {
+    None: ("pairs run=95 masked=95 skipped=33 blocks=512x1024 (backward "
+           "run=127 masked=127 skipped=1 blocks=512x512) run_len=2",
+           (95, 95, 33, 512, 1024), (127, 127, 1, 512, 512)),
+    (512, 512): (
+        "pairs run=127 masked=127 skipped=1 blocks=512x512 run_len=2",
+        (127, 127, 1, 512, 512), (127, 127, 1, 512, 512)),
+    (1024, 1024): (
+        "pairs run=63 masked=63 skipped=1 blocks=1024x1024 run_len=2",
+        (63, 63, 1, 1024, 1024), (63, 63, 1, 1024, 1024)),
+    (512, 1024): (
+        "pairs run=95 masked=95 skipped=33 blocks=512x1024 (backward "
+        "run=95 masked=95 skipped=1 blocks=512x1024) run_len=2 "
+        "(backward 3)",
+        (95, 95, 33, 512, 1024), (95, 95, 1, 512, 1024)),
+}
+
+
+@pytest.mark.parametrize("blocks", list(LINES), ids=str)
+def test_the_benchmark_s_reader_takes_the_line(blocks, monkeypatch):
+    """The worker's line as ``ops/attention.py`` logs it, under the
+    regular expression and the fill ``benchmark/lib/window_trace.py``
+    has (imported, not copied): the counts are the run grid's, forward
+    and backward each on its own, nothing stands between ``blocks=``
+    and the backward's parenthesis, and ``window_flash_fill`` is the
+    kept entries over the tiles that run."""
+    tail, forward, backward = LINES[blocks]
+    logged = []
+    monkeypatch.setattr(A.logger, "info", lambda *a: logged.append(a[0] % a[1:]))
+    q = jnp.zeros((1, 64, 32768, 128), jnp.bfloat16)
+    k = jnp.zeros((1, 8, 32768, 128), jnp.bfloat16)
+    block_q, block_k = blocks or (None, None)
+    A._log_auto_once.__wrapped__(
+        "tpu", "pallas", "", q.shape, q.dtype.name,
+        "heads=64 gate=sigmoid rotary=128/128, " + _flash_facts(
+            q, k, k, F.Band(512), block_q, block_k))
+    assert len(logged) == 1 and logged[0].endswith(tail + ")")
+    line = window_trace.attention_line("\n".join(
+        ["attention impl=auto resolved to xla (backend=cpu)"] + logged))
+    assert line == {"seq": 32768, "window": 512,
+                    "forward": forward, "backward": backward}
+    kept = 32768 * 512 - 512 * 511 / 2.0
+    computed = sum(
+        products * run * block_q * block_k
+        for products, (run, _, _, block_q, block_k) in (
+            (2, forward), (5, backward)))
+    assert window_trace.fill(line) == pytest.approx(
+        100.0 * 7 * kept / computed, rel=1e-12)
+    # what the cell's ``window_flash_fill`` reads; a quarter at the
+    # rectangle's 1024 x 1024, half at 512 x 512 in both directions
+    want = {None: 43.79, (1024, 1024): 25.2, (512, 512): 50.0}
+    if blocks in want:
+        assert round(window_trace.fill(line), 2) == want[blocks]
 
 
 # (seq, window, heads, kv heads, width, block_q, block_k, dtype)
@@ -233,6 +405,14 @@ KERNEL_CASES = {
     "group-1-window-over-a-block": (512, 300, 2, 2, 64, 128, 128,
                                     jnp.float32),
     "group-6-window-of-one": (256, 1, 6, 1, 32, 128, 128, jnp.float32),
+    "window-of-one-128-256": (512, 1, 2, 1, 32, 128, 256, jnp.float32),
+    "window-is-the-sequence": (512, 512, 2, 2, 32, 128, 128, jnp.float32),
+    "window-over-the-sequence": (512, 5000, 8, 1, 32, 256, 128,
+                                 jnp.float32),
+    "group-8-window-is-a-block": (768, 128, 8, 1, 32, 128, 128,
+                                  jnp.bfloat16),
+    "group-8-two-kv-heads-128-256": (512, 130, 16, 2, 32, 128, 256,
+                                     jnp.float32),
 }
 
 
@@ -278,6 +458,101 @@ def test_flash_under_the_band_is_dense_masked_softmax(
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=tol, rtol=tol)
+
+
+@dataclasses.dataclass(frozen=True)
+class RectangleBand:
+    """The band as it walked before its grid was its own length (PR 42):
+    the whole (q-block, k-block) rectangle, a step outside a row's
+    (column's) run clamped to the run's nearer end. The kernels take it
+    down the rectangle's path, ``Causal``'s and ``BlockDiffusion``'s;
+    nothing but these tests builds one."""
+
+    window: int
+
+    def __str__(self):
+        return "rectangle_window(%d)" % self.window
+
+    def keep(self, q_pos, k_pos):
+        return F.Band(self.window).keep(q_pos, k_pos)
+
+    def pair(self, q_block, k_block, block_q, block_k):
+        return F.Band(self.window).pair(q_block, k_block, block_q, block_k)
+
+    def k_named(self, q_block, k_block, block_q, block_k):
+        first, last = F.Band(self.window).run(q_block, block_q, block_k)
+        return jnp.clip(k_block, first, last)
+
+    def q_named(self, q_block, k_block, block_q, block_k, num_q):
+        first, last = F.Band(self.window).run(
+            k_block, block_q, block_k, k_outer=True)
+        return jnp.minimum(jnp.clip(q_block, first, last), num_q - 1)
+
+    def refusal(self, seq_q, seq_k, block_q, block_k):
+        return ""
+
+
+def _outputs(layout, case, q, k, v, do):
+    """(o, lse, dq, dk, dv) of the kernels in interpret mode."""
+    _, _, heads, kv_heads, dim, block_q, block_k, _ = case
+    merge = lambda t: t.reshape((-1,) + t.shape[2:])
+    o, lse = F._fwd(merge(q), merge(k), merge(v), dim ** -0.5, layout,
+                    block_q, block_k, True)
+    dq, dk, dv = F._bwd(
+        merge(q), merge(k), merge(v), o, lse, merge(do), dim ** -0.5,
+        layout, block_q, block_k, True)
+    return o, lse, dq, dk, dv
+
+
+@pytest.mark.parametrize("schedule", ["fused", "split"])
+@pytest.mark.parametrize(
+    "case", list(KERNEL_CASES.values()), ids=list(KERNEL_CASES))
+def test_the_run_grid_has_the_rectangle_s_bits(case, schedule, monkeypatch):
+    """At equal tiles the grid of runs computes the tiles the rectangle
+    computed, in its order: o, lse and the three gradients are the
+    parent's walk's to the last bit, under both backward schedules."""
+    seq, window, heads, kv_heads, dim, _, _, dtype = case
+    if schedule == "split":
+        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
+    q, k, v, do = _qkv(seq, heads, kv_heads, dim, dtype)
+    got = _outputs(F.Band(window), case, q, k, v, do)
+    want = _outputs(RectangleBand(window), case, q, k, v, do)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), name)
+
+
+@pytest.mark.parametrize("lit", [0, 2, 3], ids=["first", "inner", "last"])
+@pytest.mark.parametrize("schedule", ["fused", "split"])
+def test_a_gradient_from_one_q_block_reaches_its_rows_alone(
+        lit, schedule, monkeypatch):
+    """A backward whose ``do`` is zero but for one q-block, in the
+    second of two heads that share dq's accumulator (the first leaves
+    every row of it dirty): dq is exactly zero outside that block's
+    rows, so every other row was zeroed and rounded out, and dense
+    masked softmax's inside them; dk and dv are the dense ones."""
+    seq, window, dim, block = 512, 200, 32, 128
+    if schedule == "split":
+        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
+    q, k, v, do = _qkv(seq, 2, 2, dim, jnp.float32)
+    rows = slice(lit * block, (lit + 1) * block)
+    do = do.at[:, 1].set(0.0).at[:, 1, rows].set(do[:, 1, rows])
+    layout = F.Band(window)
+    got = _value_and_grads(
+        lambda q, k, v: F.flash_attention(
+            q, k, v, mask=layout, block_q=block, block_k=block,
+            interpret=True), q, k, v, do)
+    want = _value_and_grads(
+        lambda q, k, v: xla_attention(q, k, v, mask=layout), q, k, v, do)
+    dq = np.asarray(got[1])
+    dark = np.ones(seq, bool)
+    dark[rows] = False
+    assert np.abs(dq[:, 0]).min(axis=-1).max() > 0
+    np.testing.assert_array_equal(dq[:, 1, dark], 0.0)
+    assert np.abs(dq[:, 1, rows]).max() > 1e-3
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)
 
 
 def test_a_causal_mask_in_the_band_s_place_is_another_function():

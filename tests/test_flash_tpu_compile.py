@@ -169,10 +169,48 @@ def test_the_band_layout_compiles_at_the_cell_s_shapes(
         (1, heads, 32768, 128), jnp.bfloat16, sharding=chip)
     kv = jax.ShapeDtypeStruct(
         (1, 8, 32768, 128), jnp.bfloat16, sharding=chip)
-    assert F.backward_schedule(32768, 32768, 128, jnp.bfloat16) == "fused"
+    assert F.backward_schedule(
+        32768, 32768, 128, jnp.bfloat16, layout=mask) == "fused"
 
     def loss(q, k, v):
         out = F.flash_attention(q, k, v, causal=True, mask=mask)
+        return out.astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert device_obs.pallas_kernels(hlo) == kernels
+
+
+@pytest.mark.parametrize("blocks,schedule,kernels", [
+    ((1024, 1024), "fused", {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+    ((512, 1024), "fused", {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+    ((1024, 512), "fused", {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+    ((256, 256), "fused", {"flash_band_fwd": 1, "flash_band_bwd": 1}),
+    ((None, None), "split",
+     {"flash_band_fwd": 1, "flash_band_dq": 1, "flash_band_dkv": 1}),
+    ((512, 1024), "split",
+     {"flash_band_fwd": 1, "flash_band_dq": 1, "flash_band_dkv": 1}),
+], ids=lambda value: str(value) if isinstance(value, (tuple, str)) else "")
+def test_the_band_s_grid_of_runs_compiles(
+        chip, monkeypatch, blocks, schedule, kernels):
+    """The band's four kernels on a grid as long as the band (PR 43)
+    under Mosaic, at the cell's window layers' shapes: the inner index
+    becomes a block by scalar arithmetic in the index maps and in the
+    kernels (``_grid_step``: a division, a maximum, a minimum), at
+    equal and unequal tiles (runs of different lengths: slots to
+    spare), and under the split schedule, which no cell reaches with a
+    band."""
+    if schedule == "split":
+        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
+    q = jax.ShapeDtypeStruct(
+        (1, 64, 32768, 128), jnp.bfloat16, sharding=chip)
+    kv = jax.ShapeDtypeStruct(
+        (1, 8, 32768, 128), jnp.bfloat16, sharding=chip)
+
+    def loss(q, k, v):
+        out = F.flash_attention(
+            q, k, v, mask=F.Band(512), block_q=blocks[0],
+            block_k=blocks[1])
         return out.astype(jnp.float32).sum()
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
