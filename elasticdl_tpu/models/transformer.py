@@ -34,7 +34,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.data.example import decode_example
-from elasticdl_tpu.ops import gated_delta, hyper_connection
+from elasticdl_tpu.ops import gated_delta, hyper_connection, qkv_conv
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
     ring_attention,
@@ -516,11 +516,21 @@ class GatedDeltaNet(nn.Module):
         y = o W_o
 
     Scopes: ``gdn/in_proj`` (the two input matmuls), ``gdn/conv``,
-    ``gdn/gates`` (beta, g, the l2 norms, the transposes), ``gdn/scan``
-    (the chunked rule, whole), ``gdn/out_norm``, ``gdn/out_proj``. The
-    columns of ``in_proj_qkvz`` lie q | k | v | z where the published
-    code interleaves them by key head: with seeded weights a fixed
-    permutation."""
+    ``gdn/gates`` (beta, g and their transposes; on the XLA lines the
+    l2 norms and the heads' transposes too), ``gdn/scan`` (the chunked
+    rule, whole), ``gdn/out_norm``, ``gdn/out_proj``. What lies between
+    the projection and the rule for q, k and v runs where
+    ``ops/qkv_conv.py:conv_impl`` says, from the backend, the dtype,
+    the heads' widths, the sequence and the mesh, no flag: on a TPU
+    with heads of whole 128-lane rows the kernel pair ``qkv_conv_fwd``
+    / ``qkv_conv_bwd`` under one VJP, both under ``gdn/conv`` (one read
+    of ``qkvz``'s first columns where they lie, one write of q, k, v in
+    the rule's layout); everywhere else the lines of
+    ``conv_silu_xla`` (``gdn/conv``) and ``split_heads_xla``
+    (``gdn/gates``). The log's ``linear attention conv ... impl=`` line
+    says which. The columns of ``in_proj_qkvz`` lie q | k | v | z where
+    the published code interleaves them by key head: with seeded
+    weights a fixed permutation."""
 
     dims: GatedDeltaDims
     norm_eps: float = 1e-6
@@ -541,6 +551,12 @@ class GatedDeltaNet(nn.Module):
                 name="in_proj_qkvz")(x)
             ba = nn.Dense(2 * hv, use_bias=False, name="in_proj_ba")(x)
         conv_dim = 2 * key_dim + value_dim
+        impl = qkv_conv.conv_impl(
+            x.dtype, dk, dv, seq, dims.conv_kernel_dim, mesh=self.mesh)
+        segments = qkv_conv.rule_segments(seq, dims.chunk)
+        qkv_conv.log_choice(
+            hk, hv, dk, dims.conv_kernel_dim, impl, seq,
+            qkv_conv.row_tile(seq // segments) if impl == "pallas" else None)
         with jax.named_scope("gdn/conv"):
             taps = self.param(
                 "conv_kernel",
@@ -548,36 +564,22 @@ class GatedDeltaNet(nn.Module):
                     1.0, "fan_in", "normal", in_axis=0, out_axis=1),
                 (dims.conv_kernel_dim, conv_dim),
             ).astype(x.dtype)
-            qkv = qkvz[..., :conv_dim]
-            padded = jnp.pad(
-                qkv, ((0, 0), (dims.conv_kernel_dim - 1, 0), (0, 0)))
-            # y_t = sum_j taps[j] x_(t - (taps - 1) + j): four shifted
-            # multiply-adds, one fusion
-            qkv = nn.silu(sum(
-                taps[j] * padded[:, j:j + seq]
-                for j in range(dims.conv_kernel_dim)))
+            if impl == "pallas":
+                q, k, v = qkv_conv.qkv_conv(
+                    qkvz, taps, (hk, hv, dk), segments)
+            else:
+                qkv = qkv_conv.conv_silu_xla(qkvz, taps, conv_dim)
         with jax.named_scope("gdn/gates"):
             a_log = self.param("A_log", _a_log_init, (hv,))
             dt_bias = self.param("dt_bias", nn.initializers.ones, (hv,))
             ba = ba.astype(jnp.float32)
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-
-            def heads(t, num, width, normalise=None):
-                """(B, S, num x width) -> (B, num, S, width); with
-                ``normalise``, l2-normalised over the lanes (float32,
-                eps 1e-6) and scaled by it."""
-                t = t.reshape(batch, seq, num, width)
-                if normalise is not None:
-                    lanes = t.astype(jnp.float32)
-                    t = (lanes * jax.lax.rsqrt(
-                        jnp.sum(lanes * lanes, axis=-1, keepdims=True) + 1e-6
-                    ) * normalise).astype(t.dtype)
-                return t.transpose(0, 2, 1, 3)
-
-            q = heads(qkv[..., :key_dim], hk, dk, dk ** -0.5)
-            k = heads(qkv[..., key_dim:2 * key_dim], hk, dk, 1.0)
-            v = heads(qkv[..., 2 * key_dim:], hv, dv)
+            if impl != "pallas":
+                heads = qkv_conv.split_heads_xla
+                q = heads(qkv[..., :key_dim], hk, dk, dk ** -0.5)
+                k = heads(qkv[..., key_dim:2 * key_dim], hk, dk, 1.0)
+                v = heads(qkv[..., 2 * key_dim:], hv, dv)
             g, beta = g.transpose(0, 2, 1), beta.transpose(0, 2, 1)
         with jax.named_scope("gdn/scan"):
             o = gated_delta.gated_delta_rule(

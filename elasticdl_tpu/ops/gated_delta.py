@@ -1323,6 +1323,15 @@ def _chunks(state, q, k, v, g, beta, decay_dtype, impl, scan, prep):
         q.dtype)
 
 
+def segments_of(seq, chunk=DEFAULT_CHUNK, segment=DEFAULT_SEGMENT):
+    """(the tokens ``gated_delta_rule`` pads a sequence of ``seq`` by,
+    the segments it then runs one at a time): what a producer needs to
+    write q, k, v where the rule's scan over segments reads them."""
+    span = chunk if seq <= chunk * segment else chunk * segment
+    pad = -seq % span
+    return pad, max(1, (seq + pad) // (chunk * segment))
+
+
 def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
                      segment=DEFAULT_SEGMENT, state_dtype=None,
                      decay_dtype=None, mesh=None):
@@ -1357,13 +1366,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     impl = inverse_impl(wide, chunk, mesh)
     scan = scan_impl(
         q.dtype, chunk, dk, dv, state_dtype, decay_dtype, v.dtype, mesh)
-    span = chunk if seq <= chunk * segment else chunk * segment
-    pad = -seq % span
+    pad, segments = segments_of(seq, chunk, segment)
     if pad:
         widen = lambda x: jnp.pad(
             x, [(0, 0), (0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 3))
         q, k, v, g, beta = map(widen, (q, k, v, g, beta))
-    segments = max(1, (seq + pad) // (chunk * segment))
     num = (seq + pad) // (segments * chunk)  # chunks a segment
     prep = prepare_impl(
         q.dtype, chunk, dk, dv, rep, num, state_dtype, decay_dtype, v.dtype,

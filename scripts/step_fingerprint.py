@@ -35,6 +35,15 @@ def main(argv=None):
     parser.add_argument("--batch", type=int, required=True)
     parser.add_argument("--seq", type=int, required=True)
     parser.add_argument("--remat", default="none")
+    parser.add_argument(
+        "--as-tpu", action="store_true",
+        help="answer jax.default_backend() with 'tpu' while the model is "
+             "built and traced, so that the choosers take the branches a "
+             "chip gets (Pallas kernels); without it the step is the "
+             "CPU's choice of paths compiled for the chip")
+    parser.add_argument(
+        "--hlo-out", default=None,
+        help="write the compiled HLO, metadata and all, to this file")
     args = parser.parse_args(argv)
     root = os.path.abspath(args.root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -52,6 +61,8 @@ def main(argv=None):
     from elasticdl_tpu.train.train_state import abstract_train_state
 
     jax.config.update("jax_traceback_in_locations_limit", 0)
+    if args.as_tpu:
+        jax.default_backend = lambda: "tpu"
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -74,7 +85,11 @@ def main(argv=None):
     step = make_train_step(model, zoo.loss, tx, jnp.bfloat16, health=True)
     lowered = jax.jit(step, donate_argnums=(0,)).lower(state, batch)
     compiled = lowered.compile()
-    hlo = re.sub(r", metadata=\{[^}]*\}", "", compiled.as_text())
+    text = compiled.as_text()
+    if args.hlo_out:
+        with open(args.hlo_out, "w") as f:
+            f.write(text)
+    hlo = re.sub(r", metadata=\{[^}]*\}", "", text)
     memory = compiled.memory_analysis()
     print(json.dumps({
         "stablehlo_sha256": hashlib.sha256(
