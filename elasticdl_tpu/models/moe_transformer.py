@@ -10,10 +10,12 @@ expert MLP (ops/moe.py). Two dispatches:
   become all-to-alls under GSPMD, and within each expert the FFN is
   still tensor-parallel over ``tp``;
 - sorted and dropless (``dispatch_impl="sorted"``): the (token, choice)
-  pairs ordered by expert around a grouped matmul, on one device's
-  tokens. This is what a published small-expert model (OLMoE-1B-7B: 64
-  SwiGLU experts of width 1024, top-8, nothing dropped) needs; the
-  benchmark's ``olmoe-1b-7b-1chip`` configuration builds it.
+  pairs ordered by expert around a grouped matmul. This is what a
+  published small-expert model (OLMoE-1B-7B: 64 SwiGLU experts of width
+  1024, top-8, nothing dropped) needs; the benchmark's
+  ``olmoe-1b-7b-1chip`` configuration builds it on one chip, and
+  ``mellum2-12b-a2.5b-ep4`` over ``--mesh ep=4``, where each rank sorts
+  its own tokens and the rows are exchanged over ``ep``.
 """
 
 import functools
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.models.transformer import (
@@ -45,7 +48,8 @@ from elasticdl_tpu.models.transformer import (
 )
 from elasticdl_tpu.ops import block_diffusion, flash_attention
 from elasticdl_tpu.ops import moe as moe_ops
-from elasticdl_tpu.parallel.mesh import DATA_AXES
+from elasticdl_tpu.parallel.collectives import mesh_all_gather, mesh_psum
+from elasticdl_tpu.parallel.mesh import DATA_AXES, REPLICA_AXES
 from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
 from elasticdl_tpu.train import metrics
 from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy
@@ -122,9 +126,20 @@ class MoeMlp(nn.Module):
       grouped matmul over the ragged groups, gather back
       (ops/moe.py). No capacity, no one-hot, every token reaches
       exactly ``top_k`` experts. The load-balancing loss counts all
-      ``top_k`` choices (OLMoE's). One device's tokens: a mesh whose
-      ``ep`` axis is larger than 1 is refused, never served by a
-      silent fallback.
+      ``top_k`` choices (OLMoE's). On a mesh whose ``ep`` axis is
+      larger than 1 the layer's experts are spread over its ranks,
+      ``num_experts / ep`` each, and the path runs in a region manual
+      over the mesh (``_sorted_over_ep``): every rank routes and sorts
+      its own tokens, the rows are exchanged over ``ep``
+      (``ops/moe.py:exchange_rows``) into a buffer of ``exchange_rows``
+      rows a rank (None: all the ranks' pairs, which no routing
+      overfills; a stated size counts what it had no row for in
+      ``routing["dropped"]``), run the Pallas grouped matmul there and
+      come back; the balance loss, the counters and the balancing bias
+      read the loads summed over the ranks. Such a mesh may divide
+      over ``dp`` besides; an axis the region divides nothing over
+      (``fsdp``, ``tp``, ``sp``, ``pp``) is refused by name, never
+      served by a silent fallback.
 
     Experts are ``expert_act`` = ``"gelu"`` (up, GELU, down) or
     ``"swiglu"`` (silu(gate) x up, down) of width ``expert_dim``
@@ -184,6 +199,7 @@ class MoeMlp(nn.Module):
     held_experts: Optional[Any] = None
     held_rows: Optional[int] = None
     shared_gate: bool = False
+    exchange_rows: Optional[int] = None
 
     def _expert_param(self, name, rows, cols):
         # the expert axis is a batch of kernels, not fan-in: without
@@ -255,6 +271,18 @@ class MoeMlp(nn.Module):
                    "rows=%d" % rows)
         if self.shared_gate:
             held += " shared_gate=sigmoid"
+        ranks = self._ranks() if impl == "sorted" else 1
+        if ranks > 1:
+            # a rank's own tokens and the rows it can receive: the
+            # grouped matmuls run on those, each rank's alone
+            own = groups // self.mesh.size * seq
+            rows = self._received_rows(own * self.top_k)
+            one_device = True
+            held += " ep=%d held=%d received_rows=%d exchange=%s" % (
+                ranks, self.num_experts // ranks, rows,
+                moe_ops.resolve_exchange())
+            run = ("; a rank sorts its own %d tokens and runs the rows "
+                   "it received" % own)
         matmul = moe_ops.resolve_grouped_matmul(
             rows, x.dtype, one_device) if impl == "sorted" else "einsum"
         _log_dispatch_once(
@@ -264,8 +292,8 @@ class MoeMlp(nn.Module):
         )
         if matmul == "pallas_gmm":
             _log_tiles_once(
-                rows, weights[0].shape[0], dim, width, x.dtype,
-                self.expert_act)
+                rows, weights[0].shape[0] // ranks, dim, width,
+                x.dtype, self.expert_act)
         if impl == "sorted":
             y, aux = self._sorted(
                 x, router_logits, weights, one_device, training)
@@ -314,7 +342,7 @@ class MoeMlp(nn.Module):
             # (E, G, C, M): the dispatch einsum is the dp→ep all-to-all.
             expert_in = constrain(
                 moe_ops.moe_dispatch(x, dispatch),
-                self.mesh, P("ep", DATA_AXES, None, None),
+                self.mesh, P("ep", REPLICA_AXES, None, None),
             )
         with jax.named_scope("moe/experts"):
             hidden = [
@@ -323,29 +351,19 @@ class MoeMlp(nn.Module):
             ]
             out = jnp.einsum("egcf,efm->egcm", self._act(hidden), weights[-1])
             out = constrain(
-                out, self.mesh, P("ep", DATA_AXES, None, None)
+                out, self.mesh, P("ep", REPLICA_AXES, None, None)
             )
         with jax.named_scope("moe/combine"):
             y = moe_ops.moe_combine(out, combine)  # ep→dp all-to-all back
         return y, {"load_balancing": balance, "routing": None}
 
     def _sorted(self, x, router_logits, weights, one_device, training):
-        if self.mesh is not None and self.mesh.shape.get("ep", 1) > 1:
-            raise ValueError(
-                'dispatch_impl="sorted" sorts one device\'s tokens and '
-                "has no all-to-all over ep yet (this mesh has ep=%d): "
-                "that is ROADMAP.md Reach 2. Use the one-hot dispatch "
-                "on an ep mesh." % self.mesh.shape["ep"]
-            )
+        if self._ranks() > 1:
+            return self._sorted_over_ep(x, router_logits, weights, training)
         groups, seq, dim = x.shape
         tokens = x.reshape(groups * seq, dim)
         logits = router_logits.reshape(groups * seq, self.num_experts)
-        bias = None
-        if self.bias_update_speed is not None:
-            bias = self.variable(
-                "moe_state", "e_score_correction_bias",
-                lambda: jnp.zeros((self.num_experts,), jnp.float32),
-            )
+        bias = self._balancing_bias()
         with jax.named_scope("moe/router"):
             gates, experts, probs = moe_ops.route_top_k(
                 logits, self.top_k, normalize=self.normalize_gates,
@@ -396,17 +414,151 @@ class MoeMlp(nn.Module):
                     probs, loads, self.top_k, **share
                 ),
             }
-            if bias is not None:
-                # selection and update are one step's work: the bias
-                # that chose this step's experts moves by the load they
-                # got, where the call may write the collection
-                if (training and not self.is_initializing()
-                        and self.is_mutable_collection("moe_state")):
-                    bias.value = moe_ops.balancing_bias_update(
-                        bias.value, loads, self.bias_update_speed
-                    )
-                aux["routing"]["bias_abs_max"] = jnp.abs(bias.value).max()
+            self._move_bias(bias, loads, training, aux["routing"])
         return y.reshape(x.shape), aux
+
+    def _balancing_bias(self):
+        """The balancing bias's variable, or None for a layer that
+        keeps none."""
+        if self.bias_update_speed is None:
+            return None
+        return self.variable(
+            "moe_state", "e_score_correction_bias",
+            lambda: jnp.zeros((self.num_experts,), jnp.float32),
+        )
+
+    def _move_bias(self, bias, loads, training, routing):
+        """Selection and update are one step's work: the bias that
+        chose this step's experts moves by the load they got, where the
+        call may write the collection; its magnitude joins the
+        counters."""
+        if bias is None:
+            return
+        if (training and not self.is_initializing()
+                and self.is_mutable_collection("moe_state")):
+            bias.value = moe_ops.balancing_bias_update(
+                bias.value, loads, self.bias_update_speed
+            )
+        routing["bias_abs_max"] = jnp.abs(bias.value).max()
+
+
+    def _ranks(self):
+        """The ranks an expert layer's experts are spread over."""
+        return 1 if self.mesh is None else self.mesh.shape.get("ep", 1)
+
+    def _received_rows(self, pairs):
+        """The rows of a rank's receive buffer under ``ep``, for
+        ``pairs`` (token, choice) pairs on each rank: ``exchange_rows``
+        where the model states it, and never more than all the ranks'
+        pairs, which no routing overfills."""
+        most = self._ranks() * pairs
+        return min(self.exchange_rows or most, most)
+
+    def _sorted_over_ep(self, x, router_logits, weights, training):
+        """The sorted path with the experts spread over ``ep``: a
+        region manual over the mesh in which every rank routes and
+        sorts its own tokens, the rows travel to the ranks that hold
+        their experts (``ops/moe.py:exchange_rows``), are regrouped by
+        those experts, run the grouped matmuls there and come back the
+        way they went. The loads are summed over the ranks, so the
+        balance loss, the counters and the balancing bias see the
+        batch, not a rank's part of it."""
+        mesh, ranks = self.mesh, self._ranks()
+        if self.held_experts is not None:
+            raise ValueError(
+                "held_experts is one chip's share of a layer WITHOUT "
+                "its exchange; a mesh with ep=%d spreads all %d experts "
+                "and exchanges their rows: one or the other"
+                % (ranks, self.num_experts))
+        others = sorted(
+            axis for axis, size in mesh.shape.items()
+            if size > 1 and axis not in ("dp", "ep"))
+        if others or self.num_experts % ranks:
+            raise ValueError(
+                'dispatch_impl="sorted" over ep=%d runs in a region '
+                "manual over the whole mesh: %d experts have to divide "
+                "over ep, and nothing divides over %s there yet "
+                "(ROADMAP.md M1: ep beside fsdp on one mesh)"
+                % (ranks, self.num_experts, others or "another axis"))
+        groups, seq, dim = x.shape
+        if groups % mesh.size:
+            raise ValueError(
+                "a batch of %d sequences does not divide over the %d "
+                "data shards of mesh %s" % (
+                    groups, mesh.size, dict(mesh.shape)))
+        bias = self._balancing_bias()
+        pairs = groups // mesh.size * seq * self.top_k
+        buffer_rows = self._received_rows(pairs)
+        # the axes whose ranks' tokens are one batch, and those of them
+        # that are not the exchange's own
+        axes = tuple(a for a in DATA_AXES if mesh.shape[a] > 1)
+        replicas = tuple(a for a in axes if a != "ep")
+
+        def layer(x, logits, weights, bias):
+            tokens = x.reshape(-1, dim)
+            logits = logits.reshape(-1, self.num_experts)
+            with jax.named_scope("moe/router"):
+                gates, experts, probs = moe_ops.route_top_k(
+                    logits, self.top_k, normalize=self.normalize_gates,
+                    scoring=self.scoring, bias=bias, scale=self.gate_scale,
+                )
+            with jax.named_scope("moe/dispatch"):
+                order, inverse, group_sizes = moe_ops.sort_by_expert(
+                    experts, self.num_experts)
+                rows = moe_ops.dispatch_sorted(tokens, order, inverse)
+                counts = mesh_all_gather(group_sizes, "ep", tiled=False)
+                plan = moe_ops.exchange_plan(
+                    counts, jax.lax.axis_index("ep"), buffer_rows)
+                by_expert, by_sender, held_sizes = moe_ops.regroup_plan(
+                    plan["received"], buffer_rows)
+            received = moe_ops.exchange_rows(
+                rows, plan["there"], plan["back"], buffer_rows, "ep")
+            with jax.named_scope("moe/dispatch"):
+                held = moe_ops.permute_rows(received, by_expert, by_sender)
+            with jax.named_scope("moe/experts"):
+                hidden = [
+                    moe_ops.grouped_matmul(held, w, held_sizes)
+                    for w in weights[:-1]
+                ]
+                out = moe_ops.grouped_matmul(
+                    self._act(hidden), weights[-1], held_sizes)
+            with jax.named_scope("moe/combine"):
+                out = moe_ops.permute_rows(out, by_sender, by_expert)
+            returned = moe_ops.exchange_rows(
+                out, plan["back"], plan["there"], rows.shape[0], "ep")
+            with jax.named_scope("moe/combine"):
+                y = moe_ops.combine_sorted(returned, gates, order, inverse)
+            with jax.named_scope("moe/router"):
+                # the group's loads are in the gathered table already
+                # (integers: nothing differentiates through these two)
+                loads = mesh_psum(counts.sum(axis=0), replicas)
+                balance = (
+                    moe_ops.sequence_balance_loss(
+                        probs, experts, x.shape[0], axes)
+                    if self.seq_aux
+                    else moe_ops.load_balancing_loss(probs, loads, axes))
+                stats = moe_ops.routing_stats(
+                    probs, loads, self.top_k,
+                    dropped=mesh_psum(plan["dropped"], replicas),
+                    axes=axes)
+                stats.update(moe_ops.exchange_stats(
+                    plan["sent"], dim * x.dtype.itemsize, replicas))
+            return (y.reshape(x.shape), experts.reshape(x.shape[:2] + (-1,)),
+                    balance, stats, loads)
+
+        batch, whole = P(DATA_AXES), P()
+        y, experts, balance, stats, loads = jax_compat.shard_map(
+            layer, mesh=mesh,
+            in_specs=(batch, batch, P("ep"), whole),
+            out_specs=(batch, batch, whole, whole, whole),
+            # the backend's grouped matmul declares no vma for its
+            # results (``jax_compat.shard_map``)
+            check_vma=False,
+        )(x, router_logits, weights, None if bias is None else bias.value)
+        # for whoever asks with mutable=["intermediates"], as ``_sorted``
+        self.sow("intermediates", "experts", experts)
+        self._move_bias(bias, loads, training, stats)
+        return y, {"load_balancing": balance, "routing": stats}
 
 
 class MoeBlock(nn.Module):
@@ -434,6 +586,7 @@ class MoeBlock(nn.Module):
     held_experts: Optional[Any] = None
     held_rows: Optional[int] = None
     shared_gate: bool = False
+    exchange_rows: Optional[int] = None
     # the mixer: a Gated DeltaNet of these sizes where given, else
     # softmax attention with ``Attention``'s own fields of these names
     linear: Optional[GatedDeltaDims] = None
@@ -493,6 +646,7 @@ class MoeBlock(nn.Module):
             held_experts=self.held_experts,
             held_rows=self.held_rows,
             shared_gate=self.shared_gate,
+            exchange_rows=self.exchange_rows,
             name="moe_mlp",
         )
         if self.hc is not None:
@@ -521,7 +675,8 @@ def merge_routing(layers):
     """One set of ``moe_routing`` counters from the expert layers' own:
     the largest load of any expert in any layer, the mean load, the
     mean entropy, all dropped pairs and, where the layers keep a
-    balancing bias, its largest magnitude."""
+    balancing bias, its largest magnitude; a held share's and an
+    exchange's counters where the layers have them."""
     merged = {
         "load_max": jnp.stack([r["load_max"] for r in layers]).max(),
         "load_mean": jnp.stack([r["load_mean"] for r in layers]).mean(),
@@ -539,6 +694,14 @@ def merge_routing(layers):
         # and of all the layers' buffers' rows, those the step ran
         for name in ("rows_run", "rows_buffer"):
             merged[name] = jnp.stack([r[name] for r in layers]).sum()
+    if "sent" in layers[0]:
+        # the exchange's (``ops/moe.py:exchange_stats``): what a rank
+        # sends a step over all the layers, and the rows of the rank
+        # that received the most in the layer where it did
+        for name, over in (("sent", jnp.sum), ("exchange_bytes", jnp.sum),
+                           ("received_max", jnp.max),
+                           ("received_mean", jnp.mean)):
+            merged[name] = over(jnp.stack([r[name] for r in layers]))
     return merged
 
 
@@ -627,6 +790,9 @@ class MoeTransformerLM(nn.Module):
     held_experts: Optional[Any] = None
     held_rows: Optional[int] = None
     shared_gate: bool = False
+    # the rows of a rank's receive buffer where the experts are spread
+    # over ``ep`` (``MoeMlp``); None: all the ranks' pairs
+    exchange_rows: Optional[int] = None
     # the mixers' kinds as a pattern with a period: layer i is
     # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
     # of ``linear``'s sizes), "full" (softmax attention over the causal
@@ -852,6 +1018,7 @@ class MoeTransformerLM(nn.Module):
                 held_experts=self.held_experts,
                 held_rows=self.held_rows,
                 shared_gate=self.shared_gate,
+                exchange_rows=self.exchange_rows,
                 linear=self.linear if kind == "linear" else None,
                 layer_index=index,
                 name=name,
@@ -999,8 +1166,13 @@ def moe_sharding_rules():
             # the shared experts are a dense MLP (Megatron over tp)
             (r"(mlp|shared)_(gate|up)/kernel$", P("fsdp", "tp")),
             (r"(mlp|shared)_down/kernel$", P("tp", "fsdp")),
-            (r"wte/embedding$", P("tp", "fsdp")),
-            (r"lm_head/kernel$", P("fsdp", "tp")),
+            # the vocabulary's two matrices are the largest tensors
+            # outside the experts: an expert group's ranks, which are
+            # data shards here, each store a slice of them as fsdp's do
+            # (gathered where they are used, their gradients
+            # reduce-scattered)
+            (r"wte/embedding$", P("tp", ("fsdp", "ep"))),
+            (r"lm_head/kernel$", P(("fsdp", "ep"), "tp")),
             # the norms' scales and biases: small, replicated
             (r"(scale|bias)$", P()),
             (r".*", P()),

@@ -262,7 +262,10 @@ EVENT_TYPES = frozenset({
                              #   the logged loss (+ step,
                              #   tokens_per_expert_max and _mean over
                              #   the expert layers, router_entropy in
-                             #   nats, dropped_pairs)
+                             #   nats, dropped_pairs; where the experts
+                             #   are spread over ep: sent_pairs a rank
+                             #   a step, received_pairs_max and _mean
+                             #   by rank, exchange_bytes a rank a step)
     "bd_noise",              # the same steps of a model trained by
                              #   block diffusion
                              #   (ops/block_diffusion.py): what the

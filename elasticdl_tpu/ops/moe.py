@@ -27,15 +27,22 @@ rows are gathered back and summed under their gates. Every token
 reaches exactly ``k`` experts. Dispatch and combine are permutations,
 so their backward passes are gathers too (custom VJPs below): nothing
 on this path is a scatter, and nothing costs a matmul FLOP that the
-experts themselves do not need. One device's tokens only: there is no
-``ep`` all-to-all on this path yet (ROADMAP.md Reach 2). What there is
-of expert parallelism is its first half (``sort_held``,
-``dispatch_held``, ``combine_held``): a layer that holds a stated share
-of the experts routes over all of them, gives only the pairs of its own
-experts a row (in a buffer of a static size, of which a step runs the
-rows that carry a pair and no more) and returns its own experts' part
-of the result; nothing stands in for the absent chips or their
-traffic.
+experts themselves do not need. On one device, and on a mesh whose
+``ep`` is 1, the sort is over all the tokens the call sees. On a mesh
+with ``ep > 1`` the experts are spread over the ranks of ``ep`` and the
+path runs in a region manual over the mesh (``exchange_plan``,
+``regroup_plan``, ``exchange_rows``, ``permute_rows``;
+``MoeMlp._sorted_over_ep``): each rank sorts its own pairs, the rows
+travel to the ranks that hold their experts in one ragged all-to-all,
+are regrouped there by expert, multiplied, and come back the way they
+went; still nothing is dropped and no backward is a scatter. A layer
+may also hold a stated share of the experts WITHOUT an exchange
+(``sort_held``, ``dispatch_held``, ``combine_held``; one chip's part of
+a deployment that a benchmark cell cuts out): it routes over all of
+them, gives only the pairs of its own experts a row (in a buffer of a
+static size, of which a step runs the rows that carry a pair and no
+more) and returns its own experts' part of the result; nothing stands
+in for the absent chips or their traffic.
 
 Everything is shape-static and jit-friendly: k is a Python int, the
 sorted path's only data-dependent quantity is ``group_sizes``, an
@@ -522,6 +529,187 @@ def _combine_sorted_bwd(res, dy):
 combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
+# ---------------------------------------------------------------------
+# the exchange: the sorted path with its experts spread over ``ep``
+#
+# Inside a region manual over the data axes every rank sorts its own
+# (token, choice) pairs by expert, so the pairs of one destination rank
+# are one contiguous chunk of its sorted rows. The ranks all-gather
+# their (E,) group sizes (``ranks x E`` integers a layer), and from
+# that one table every rank knows the whole exchange: what each rank
+# sends to each (``exchange_plan``), where it lands, and how the rows a
+# rank received, which lie sender by sender, regroup by its own experts
+# (``regroup_plan``). The rows travel by ``exchange_rows`` and come
+# back the way they went; its transpose is the exchange reversed, and
+# the regrouping's is a gather through the inverse permutation, so no
+# backward on this path is a scatter either.
+
+EXCHANGE_SCOPE = "moe/exchange"
+
+
+def resolve_exchange():
+    """``"ragged_all_to_all"`` on a TPU: one collective that moves each
+    chunk's true rows and no padding. XLA's CPU backend has no such
+    operation (``UNIMPLEMENTED: HLO opcode ragged-all-to-all``), so
+    there, for the tests, ``"all_gather"``: the same offsets and sizes
+    read out of the gathered operands."""
+    return (
+        "ragged_all_to_all" if jax.default_backend() == "tpu"
+        else "all_gather")
+
+
+def exchange_plan(counts, me, buffer_rows):
+    """Who sends what where, from ``counts`` (ranks, E): every rank's
+    pairs per expert, rank r holding experts ``r E / ranks`` onward.
+    ``me``: this rank's index along the axis. ``buffer_rows``: the rows
+    a rank can receive. A destination's buffer fills sender by sender;
+    what finds no row is not sent (the tail of the last senders'
+    chunks, their highest experts first) and is counted in
+    ``dropped``, 0 unless the buffer is too small for the step's
+    routing, and always 0 at ``ranks x`` a rank's pairs.
+
+    Returns a dict: ``there`` and ``back``, the two directions' (offset
+    in the sender's rows, rows sent, offset in the receiver's rows,
+    rows received), each (ranks,) and one entry a peer; ``received``
+    (ranks, E / ranks): this rank's received rows by sender and held
+    expert; ``sent`` (ranks, ranks): rows by sender and destination,
+    the same on every rank; ``dropped``: over all ranks."""
+    ranks = counts.shape[0]
+    by_dest = counts.reshape(ranks, ranks, -1)  # sender, dest, expert
+    want = by_dest.sum(-1)
+    ends = jnp.minimum(jnp.cumsum(want, axis=0), buffer_rows)
+    sent = jnp.diff(ends, axis=0, prepend=0)
+    lands = ends - sent  # in the destination's buffer
+    lies = jnp.cumsum(want, axis=1) - want  # in the sender's sorted rows
+    kept = jnp.diff(
+        jnp.minimum(jnp.cumsum(by_dest, axis=-1), sent[..., None]),
+        axis=-1, prepend=0)
+    return {
+        "there": (lies[me], sent[me], lands[me], sent[:, me]),
+        "back": (lands[:, me], sent[:, me], lies[:, me], sent[me]),
+        "received": kept[:, me],
+        "sent": sent,
+        "dropped": (want - sent).sum(),
+    }
+
+
+def regroup_plan(received, buffer_rows):
+    """The permutation between a receive buffer's order (sender by
+    sender, each sender's rows by expert) and the grouped matmul's
+    (expert by expert), from ``received`` (ranks, held experts).
+    Returns ``(by_expert, by_sender, group_sizes)``: ``take(buffer,
+    by_expert)`` groups the rows by expert, ``take(rows, by_sender)``
+    puts them back, and ``group_sizes`` (held experts,) sum to the rows
+    that carry a pair. The rows past those map to themselves: whatever
+    they hold stays among them."""
+    ranks, held = received.shape
+    by_sender_sizes = received.reshape(-1)
+    by_expert_sizes = received.T.reshape(-1)
+    sender_starts = (
+        jnp.cumsum(by_sender_sizes) - by_sender_sizes).reshape(ranks, held)
+    expert_starts = (
+        jnp.cumsum(by_expert_sizes) - by_expert_sizes).reshape(held, ranks)
+    at = jnp.arange(buffer_rows, dtype=jnp.int32)
+    carries = at < by_sender_sizes.sum()
+
+    def segment(sizes):
+        # the segment a position lies in: a compare fused into its
+        # reduction over ranks x held ends, no search
+        ends = jnp.cumsum(sizes)
+        return jnp.minimum(
+            jnp.sum(at[:, None] >= ends[None], axis=1, dtype=jnp.int32),
+            sizes.shape[0] - 1)
+
+    seg = segment(by_expert_sizes)
+    expert, sender = seg // ranks, seg % ranks
+    by_expert = jnp.where(
+        carries,
+        sender_starts[sender, expert] + at - expert_starts[expert, sender],
+        at)
+    seg = segment(by_sender_sizes)
+    sender, expert = seg // held, seg % held
+    by_sender = jnp.where(
+        carries,
+        expert_starts[expert, sender] + at - sender_starts[sender, expert],
+        at)
+    return checkpoint_name(
+        (by_expert.astype(jnp.int32), by_sender.astype(jnp.int32),
+         received.sum(axis=0).astype(jnp.int32)), MOE_ROUTE_NAME)
+
+
+@jax.custom_vjp
+def permute_rows(rows, index, inverse):
+    """``take(rows, index)`` for a permutation ``index`` of the rows
+    with its ``inverse``: the transpose is the gather through the
+    inverse."""
+    return jnp.take(rows, index, axis=0)
+
+
+def _permute_rows_fwd(rows, index, inverse):
+    return permute_rows(rows, index, inverse), inverse
+
+
+def _permute_rows_bwd(inverse, d_rows):
+    return jnp.take(d_rows, inverse, axis=0), None, None
+
+
+permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def _gathered_all_to_all(rows, out_rows, route, axis):
+    """``ragged_all_to_all``'s result into a zeroed buffer, read out of
+    the gathered operands: row r of the result is the row of the sender
+    whose chunk covers r."""
+    me = jax.lax.axis_index(axis)
+    everyone = jax.lax.all_gather(rows, axis)
+    lies, sent, lands, _ = (
+        jax.lax.all_gather(part, axis)[:, me] for part in route)
+    at = jnp.arange(out_rows, dtype=jnp.int32)
+    covers = (at[None] >= lands[:, None]) & (at[None] < (lands + sent)[:, None])
+    sender = jnp.argmax(covers, axis=0)
+    source = jnp.clip(
+        lies[sender] + at - lands[sender], 0, rows.shape[0] - 1)
+    return jnp.where(
+        covers.any(axis=0)[:, None], everyone[sender, source], 0
+    ).astype(rows.dtype)
+
+
+def _all_to_all_rows(rows, out_rows, route, axis):
+    """Chunk ``j`` of this rank's ``rows`` (``route``'s offset and
+    size) to rank ``j``'s buffer of ``out_rows`` rows at the offset
+    ``route`` names there; the rows nothing is written to are zeros."""
+    with jax.named_scope(EXCHANGE_SCOPE):
+        if resolve_exchange() == "all_gather":
+            return _gathered_all_to_all(rows, out_rows, route, axis)
+        return jax.lax.ragged_all_to_all(
+            rows, jnp.zeros((out_rows,) + rows.shape[1:], rows.dtype),
+            *route, axis_name=axis)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def exchange_rows(rows, route, back, out_rows, axis):
+    """The all-to-all over ``axis``: ``rows`` (N, M), this rank's rows
+    with each peer's chunk contiguous, -> (out_rows, M), what the peers
+    sent here. ``route`` and ``back`` are a plan's two directions
+    (``exchange_plan``), whichever way this call goes. Its transpose is
+    the exchange reversed: the cotangent's chunks travel ``back`` to
+    the rows they came from, and a row that was not sent gets zero."""
+    return _all_to_all_rows(rows, out_rows, route, axis)
+
+
+def _exchange_rows_fwd(rows, route, back, out_rows, axis):
+    return exchange_rows(rows, route, back, out_rows, axis), (
+        back, rows.shape[0])
+
+
+def _exchange_rows_bwd(out_rows, axis, res, d_out):
+    back, in_rows = res
+    return _all_to_all_rows(d_out, in_rows, back, axis), None, None
+
+
+exchange_rows.defvjp(_exchange_rows_fwd, _exchange_rows_bwd)
+
+
 # The Pallas grouped matmul's tiles (tm rows, tk of K, tn of N), chosen
 # for each call from that call's own shapes (``gmm_tiles``).
 # Rows: 512 (1024 are refused by the TPU compiler at any K / N tile
@@ -649,9 +837,11 @@ def resolve_grouped_matmul(num_rows, dtype, one_device=True):
     """``"pallas_gmm"`` or ``"ragged_dot"`` for a grouped matmul over
     ``num_rows`` rows of ``dtype``. The Pallas kernel takes bfloat16
     rows in whole row tiles on a TPU, and like every ``pallas_call`` it
-    cannot be partitioned automatically: on a mesh of several devices
-    the caller would have to run it inside a ``shard_map``, which the
-    sorted path does not do yet (ROADMAP.md Reach 2)."""
+    cannot be partitioned automatically: ``one_device`` says that the
+    call sees one device's rows, on one device or inside a region
+    manual over the mesh (the exchange's, ``MoeMlp._sorted_over_ep``).
+    A global sort under GSPMD (a mesh whose ``ep`` is 1) keeps
+    ``ragged_dot``."""
     fits = (
         one_device
         and jax.default_backend() == "tpu"
@@ -734,23 +924,38 @@ def grouped_matmul(rows, weights, group_sizes, one_device=True):
     return checkpoint_name(out, MOE_MATMUL_NAME)
 
 
-def load_balancing_loss(probs, group_sizes):
+def load_balancing_loss(probs, group_sizes, axes=()):
     """E x sum_e f_e P_e (OLMoE, arXiv:2409.02060 eq. 3; Switch's form
     over all k choices): f_e the share of the tokens that chose expert
     e among their k (so the f sum to k), P_e the mean router
-    probability of e. 1 x k for a uniform router."""
+    probability of e. 1 x k for a uniform router. ``axes``: inside a
+    manual region, the mesh axes whose ranks' tokens are one batch;
+    ``group_sizes`` are then the loads summed over them and ``probs``
+    this rank's."""
     tokens, num_experts = probs.shape
+    if axes:
+        # jax's own psum / pmean here and below: the region is
+        # differentiated from OUTSIDE (``MoeMlp._sorted_over_ep``'s
+        # unchecked ``shard_map``), whose transpose expects them
+        # (``parallel/collectives.py`` pins the other convention, for a
+        # vjp taken inside a region)
+        tokens = tokens * jax.lax.psum(1, axes)
     share = group_sizes.astype(jnp.float32) / tokens
-    return num_experts * jnp.sum(share * probs.mean(axis=0))
+    mean = probs.mean(axis=0)
+    if axes:
+        mean = jax.lax.pmean(mean, axes)
+    return num_experts * jnp.sum(share * mean)
 
 
-def sequence_balance_loss(probs, experts, num_sequences):
+def sequence_balance_loss(probs, experts, num_sequences, axes=()):
     """DeepSeek-V3's complementary sequence-wise balance loss
     (arXiv:2412.19437 eq. 17-20), the mean over the sequences of
     ``sum_e f_e P_e``: within ONE sequence of T tokens ``f_e = E / (k
     T) x`` the (token, choice) pairs that chose expert e and ``P_e`` the
     mean over its tokens of the normalised scores. 1 for a uniform
-    router. probs (B*T, E) float32, experts (B*T, k)."""
+    router. probs (B*T, E) float32, experts (B*T, k). ``axes`` as
+    ``load_balancing_loss`` takes them: the mean is then over all
+    those ranks' sequences, as many on each."""
     num_experts, k = probs.shape[-1], experts.shape[-1]
     probs = probs.reshape(num_sequences, -1, num_experts)
     tokens = probs.shape[1]
@@ -762,7 +967,8 @@ def sequence_balance_loss(probs, experts, num_sequences):
         axis=1, dtype=jnp.float32,
     )
     share = counts * (num_experts / (k * tokens))
-    return jnp.sum(share * probs.mean(axis=1), axis=-1).mean()
+    loss = jnp.sum(share * probs.mean(axis=1), axis=-1).mean()
+    return jax.lax.pmean(loss, axes) if axes else loss
 
 
 def balancing_bias_update(bias, group_sizes, speed):
@@ -783,7 +989,7 @@ def router_z_loss(router_logits):
 
 
 def routing_stats(probs, group_sizes, k, held=None, dropped=None,
-                  buffer_rows=None):
+                  buffer_rows=None, axes=()):
     """What the ``moe_routing`` journal event reports of one expert
     layer, as device scalars: pairs per expert (largest and mean, over
     ALL experts), the router's mean entropy in nats, and the pairs that
@@ -793,9 +999,12 @@ def routing_stats(probs, group_sizes, k, held=None, dropped=None,
     here, its ``dropped`` are those of them its buffer had no row for,
     and of the ``rows_buffer`` rows of its buffer (``buffer_rows``) the
     ``rows_run`` that this step ran (``rows_run``: the prefix that
-    holds the pairs)."""
+    holds the pairs). ``axes`` as ``load_balancing_loss`` takes them:
+    ``group_sizes`` and ``dropped`` are then over all those ranks."""
     tokens = probs.shape[0]
     entropy = -jnp.sum(probs * jnp.log(probs + 1e-30), axis=-1).mean()
+    if axes:
+        entropy = jax.lax.pmean(entropy, axes)
     stats = {
         "load_max": group_sizes.max().astype(jnp.float32),
         "load_mean": group_sizes.astype(jnp.float32).mean(),
@@ -808,4 +1017,33 @@ def routing_stats(probs, group_sizes, k, held=None, dropped=None,
         stats["held"] = held.astype(jnp.float32)
         stats["rows_run"] = rows_run(held, buffer_rows).astype(jnp.float32)
         stats["rows_buffer"] = jnp.float32(buffer_rows)
+    return stats
+
+
+
+def exchange_stats(sent, row_bytes, axes=()):
+    """What the ``moe_routing`` event reports of one expert layer's
+    exchange, from a plan's ``sent`` (ranks, ranks; ``exchange_plan``):
+    ``sent`` the pairs a rank sent to OTHER ranks, ``received_max`` and
+    ``received_mean`` the rows a rank received, its own pairs among
+    them (what its grouped matmuls run), all three by rank: the mean,
+    the largest and the mean; ``exchange_bytes``: what one rank sends a
+    step in this layer's four passes (the dispatch and the combine,
+    forward and backward), ``sent`` x ``row_bytes`` x 4. ``axes``: the
+    data axes besides the exchange's own, over whose expert groups the
+    ranks are counted."""
+    sent = sent.astype(jnp.float32)
+    received = sent.sum(axis=0)
+    left = (sent.sum(axis=1) - jnp.diagonal(sent)).mean()
+    stats = {
+        "sent": left,
+        "received_max": received.max(),
+        "received_mean": received.mean(),
+        "exchange_bytes": left * (4.0 * row_bytes),
+    }
+    if axes:
+        stats = {
+            name: (jax.lax.pmax if name == "received_max"
+                   else jax.lax.pmean)(value, axes)
+            for name, value in stats.items()}
     return stats

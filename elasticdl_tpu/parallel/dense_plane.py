@@ -40,7 +40,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-from elasticdl_tpu.parallel.mesh import DATA_AXES
+from elasticdl_tpu.parallel.mesh import REPLICA_AXES
 from elasticdl_tpu.parallel.sharding import _tree_paths, fsdp_auto_spec
 
 logger = _logger_factory("elasticdl_tpu.parallel.dense_plane")
@@ -128,34 +128,45 @@ def plan_dense_plane(params, mesh, rules=None):
     plan = DensePlan(mesh_shape=shape, mesh_axes=tuple(mesh.axis_names))
     fsdp = shape.get("fsdp", 1)
     dp = shape.get("dp", 1)
+    ep = shape.get("ep", 1)
     for path, leaf in _tree_paths(params):
         if rules is not None:
             spec = rules.spec_for(path, leaf.shape)
         else:
             spec = fsdp_auto_spec(leaf.shape, mesh)
         spec = spec if spec is not None else P()
-        spec_axes = set()
+        spec_axes, zero_axes = set(), set()
         for entry in spec:
             if entry is None:
                 continue
             names = entry if isinstance(entry, tuple) else (entry,)
             spec_axes.update(names)
+            if "fsdp" in names:
+                # what shares a dimension with fsdp stores a slice of
+                # the parameter as fsdp does (("fsdp", "ep"): the
+                # vocabulary's matrices of an expert model)
+                zero_axes.update(names)
         nbytes = int(np.prod(leaf.shape or (1,))) * int(
             np.dtype(leaf.dtype).itemsize
         )
-        data_extent = dp * (1 if "fsdp" in spec_axes else fsdp)
-        if "fsdp" in spec_axes:
-            # grad reduce-scatters over fsdp; each scattered slice then
-            # all-reduces over the dp extent (if any)
+        zero = int(np.prod([shape.get(axis, 1) for axis in zero_axes]))
+        # the ranks of an expert group are data shards for whatever is
+        # not divided over them (parallel/mesh.py)
+        replicas = dp * (1 if "ep" in spec_axes else ep)
+        data_extent = replicas * (1 if "fsdp" in spec_axes else fsdp)
+        if zero_axes:
+            # grad reduce-scatters over fsdp (and what stores with it);
+            # each scattered slice then all-reduces over the data
+            # extent that is left (if any)
             mode = "reduce_scatter"
-            grad_bytes = _ring(nbytes, fsdp) + 2 * _ring(
-                nbytes // max(fsdp, 1), dp
+            grad_bytes = _ring(nbytes, zero) + 2 * _ring(
+                nbytes // max(zero, 1), replicas
             )
-        elif spec_axes - set(DATA_AXES):
+        elif spec_axes - set(REPLICA_AXES):
             # tp/pp/sp/ep-sharded: each model shard is a distinct
             # value; only the data extent carries partials to reduce
             shard = nbytes
-            for axis in spec_axes - set(DATA_AXES):
+            for axis in spec_axes - set(REPLICA_AXES):
                 shard //= max(shape.get(axis, 1), 1)
             if data_extent > 1:
                 mode = "psum"

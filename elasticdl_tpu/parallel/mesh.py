@@ -9,7 +9,12 @@ by gRPC; its only "mesh" is the Horovod ring. On TPU the topology is a
 - ``pp``   — pipeline parallelism (stage-sharded layer stacks)
 - ``tp``   — tensor parallelism (within-layer sharding)
 - ``sp``   — sequence/context parallelism (ring attention)
-- ``ep``   — expert parallelism (MoE expert-sharded FFNs)
+- ``ep``   — expert parallelism: an expert layer's experts (and their
+  optimizer state) are divided over it. Outside the expert layer its
+  ranks are data shards like ``dp``'s: the batch divides over ``ep``
+  too, everything but the experts is reduced over it as over a data
+  axis, and the expert layer's exchange (``ops/moe.py``) carries each
+  rank's tokens to the ranks that hold their experts and back
 
 Axis sizes multiply to the device count. Defaults put every device on
 ``dp`` (the reference's data-parallel-only world); model code opts into
@@ -28,8 +33,10 @@ from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 logger = _logger_factory("elasticdl_tpu.parallel.mesh")
 
 AXES = ("dp", "fsdp", "pp", "tp", "sp", "ep")
-# Batch is sharded over both flavors of data parallelism.
-DATA_AXES = ("dp", "fsdp")
+# The batch is sharded over both flavors of data parallelism and over
+# the ranks of an expert group, which see tokens of their own.
+REPLICA_AXES = ("dp", "fsdp")
+DATA_AXES = REPLICA_AXES + ("ep",)
 
 
 @dataclass

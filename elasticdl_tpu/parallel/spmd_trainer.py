@@ -68,6 +68,9 @@ class SpmdTrainer(Trainer):
         self._train_step_fn = make_train_step(
             model, loss_fn, optimizer, compute_dtype,
             grad_accum_steps=grad_accum_steps,
+            # the model's facts (``train/step_fns.py:FACTS``) leave the
+            # step replicated and unfetched; no health scalars here
+            with_facts=True,
         )
         self._eval_step_fn = make_eval_step(model, compute_dtype)
         # batch_spec overrides the default dim-0-over-data-axes layout
@@ -225,7 +228,7 @@ class SpmdTrainer(Trainer):
             self._train_step_fn,
             name="spmd_train_step",
             in_shardings=(self._state_shardings, self._shard_tree(batch)),
-            out_shardings=(self._state_shardings, replicated),
+            out_shardings=(self._state_shardings, replicated, replicated),
             donate_argnums=(0,),
         )
         self._eval_step = device_obs.instrumented_jit(
@@ -309,10 +312,10 @@ class SpmdTrainer(Trainer):
             self._build_steps(batch)
             self._log_batch_split(sharded["features"])
         with phase("dispatch"):
-            out = self._train_step(state, sharded)
+            state, loss, self.facts = self._train_step(state, sharded)
         if first:
             self._log_program_collectives()
-        return out
+        return state, loss
 
     @staticmethod
     def _log_batch_split(features):

@@ -85,7 +85,9 @@ class Fact(NamedTuple):
 # the one table of them. An MoE LM's expert-load counters (a layer
 # that keeps a balancing bias also reports its magnitude, one that
 # holds a share of its experts the pairs that share got, the rows of
-# its layers' buffers and those of them the step ran), a
+# its layers' buffers and those of them the step ran, one whose experts
+# are spread over ``ep`` the pairs a rank sent, the rows a rank received
+# and the exchange's bytes), a
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
 # block (``models/moe_transformer.py``), and what the loss function
 # names of its own sum (a multi-token-prediction module's loss)
@@ -98,7 +100,11 @@ FACTS = (
         ("bias_abs_max", "bias_abs_max"),
         ("held", "held_pairs"),
         ("rows_run", "held_rows_run"),
-        ("rows_buffer", "held_rows_buffer"))),
+        ("rows_buffer", "held_rows_buffer"),
+        ("sent", "sent_pairs"),
+        ("received_max", "received_pairs_max"),
+        ("received_mean", "received_pairs_mean"),
+        ("exchange_bytes", "exchange_bytes"))),
     Fact("noise", "bd_noise"),
     Fact("mhc", "mhc"),
     Fact("loss_terms", "loss_terms", of_loss=True),
@@ -161,7 +167,7 @@ def _apply_model(model, params, model_state, features, training, rngs):
 @hot_path
 def make_train_step(model, loss_fn, tx, compute_dtype=None,
                     grad_accum_steps=1, health=False,
-                    guard_nonfinite=False):
+                    guard_nonfinite=False, with_facts=False):
     """Returns train_step(state, batch) -> (new_state, loss).
 
     ``health=True`` (ISSUE 15) additionally returns a third output —
@@ -174,7 +180,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
     (a prediction module's ``mtp_loss``), the dict has them under the
     table's keys as device values: they leave the step with the health
     scalars and cost no fetch until someone reads them (the worker
-    does on the steps it logs).
+    does on the steps it logs). ``with_facts`` without ``health`` hands
+    out the facts alone, as a third output (the SPMD trainer's: a model
+    without facts adds an empty dict and no operation to its step).
 
     ``grad_accum_steps=k`` splits the batch into k equal microbatches
     scanned sequentially, accumulating MASK-WEIGHTED gradient sums and
@@ -260,7 +268,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
 
         def finish(new_state, loss, grads, facts):
             if not health:
-                return new_state, loss
+                return (
+                    (new_state, loss, facts) if with_facts
+                    else (new_state, loss))
             scalars = health_scalars(loss, global_grad_norm(grads))
             scalars.update(facts)
             if guard_nonfinite:

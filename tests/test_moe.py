@@ -426,11 +426,15 @@ def test_sorted_dispatch_under_dp_mesh_matches_single_device():
     np.testing.assert_allclose(got, expected, atol=1e-4, rtol=1e-4)
 
 
-def test_sorted_dispatch_refuses_an_ep_mesh():
+def test_sorted_dispatch_refuses_what_its_exchange_does_not_divide():
+    """Over ``ep`` the sorted path exchanges rows in a region manual
+    over the whole mesh (tests/test_moe_exchange.py); an axis it
+    divides nothing over is refused by name, never served by a silent
+    fallback."""
     mesh = build_mesh(MeshConfig(dp=2, tp=2, ep=2))
     model = _small_moe(
         attention_impl="xla", mesh=mesh, dispatch_impl="sorted")
-    with pytest.raises(ValueError, match="ROADMAP.md Reach 2"):
+    with pytest.raises(ValueError, match=r"nothing divides over \['tp'\]"):
         model.init(jax.random.PRNGKey(0), _batch()["features"])
     with pytest.raises(ValueError, match="dispatch_impl"):
         _small_moe(dispatch_impl="compact").init(

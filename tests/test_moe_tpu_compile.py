@@ -31,14 +31,18 @@ LAYERS = {"olmoe": (WIDTH, TOP_K), "moonlight": (1408, 6)}
 
 
 @pytest.fixture(scope="module")
-def chip():
+def topo():
     from jax.experimental import topologies
 
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - whatever says "no compiler"
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -155,6 +159,61 @@ def test_a_held_layer_compiles_with_its_loop_and_its_prefixes(
     assert any(
         " while(" in line and "moe/dispatch" in line
         for line in hlo.splitlines())
+
+
+def test_the_layer_over_ep_compiles_for_the_four_chips(topo, monkeypatch):
+    """``mellum2-ep4-s8k``'s expert layer at its real size (4 x 8,192
+    tokens, 64 experts of 896 top-8 over ``ep=4``, a receive buffer of
+    131,072 rows) for the four described chips: the chip's compiler
+    takes the exchange as ``ragged-all-to-all`` (the dispatch's and the
+    combine's, and their transposes, under the ``moe/exchange`` scope),
+    and the grouped matmuls inside the manual region are the same nine
+    Pallas kernels, at tiles of 2304 and 896's own."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from elasticdl_tpu.parallel.mesh import DATA_AXES, MeshConfig, build_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = build_mesh(MeshConfig(ep=4, devices=list(topo.devices)))
+    dim, width = 2304, 896
+    layer = MoeMlp(
+        64, top_k=8, dispatch_impl="sorted", expert_dim=width,
+        expert_act="swiglu", normalize_gates=True, mesh=mesh,
+        exchange_rows=131072)
+    x = jax.ShapeDtypeStruct(
+        (4, TOKENS, dim), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P(DATA_AXES)))
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(
+            x.shape, x.dtype)))["params"]
+    assert params["w_gate"].shape == (64, dim, width)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.bfloat16, sharding=NamedSharding(
+                mesh, P("ep") if a.ndim == 3 else P())),
+        params)
+
+    def loss(params, x):
+        y, aux = layer.apply({"params": params}, x)
+        return (y.astype(jnp.float32) ** 2).mean() + aux["load_balancing"]
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    names = kernels(hlo)
+    assert len(names) == 9, names
+    assert all("moe/experts" in n and "gmm" in n for n in names), names
+    assert "ragged-dot" not in hlo
+    exchanges = [
+        line for line in hlo.splitlines() if " ragged-all-to-all(" in line]
+    assert len(exchanges) == 4, len(exchanges)
+    assert all("moe/exchange" in line for line in exchanges)
+    assert sum("transpose(" in line for line in exchanges) == 2
+    # a rank's rows and what it can receive: no array over all the
+    # ranks' 262,144 pairs
+    assert "[131072,%d]" % dim in hlo and "[262144," not in hlo
+    assert moe_ops.projection_tiles(131072, dim, width, jnp.bfloat16) == {
+        "fwd": (512, 1152, 896), "d_rows": (512, 896, 1152),
+        "d_weights": (512, 1152, 896)}
 
 
 def test_a_tile_the_byte_count_refuses_the_compiler_refuses_too(chip):
