@@ -69,7 +69,9 @@ def _log_dispatch_once(impl, matmul, tokens, num_experts, top_k, width,
     bracket what it runs of that buffer (``; a step runs the rows that
     carry a held pair, up to rows=65536``: written at trace time, the
     line can name no count; the ``moe_routing`` event's
-    ``held_rows_run`` does)."""
+    ``held_rows_run`` does; over ``ep`` ``; a rank sorts its own 8192
+    tokens and runs the rows it received, regroup=chunks of 4096``, and
+    the event's ``received_rows_run``)."""
     logger.info(
         "moe dispatch resolved to %s (tokens=%d experts=%d top_k=%d "
         "expert_width=%d act=%s score=%s shared=%d%s, experts' matmul=%s)%s",
@@ -282,7 +284,8 @@ class MoeMlp(nn.Module):
                 ranks, self.num_experts // ranks, rows,
                 moe_ops.resolve_exchange())
             run = ("; a rank sorts its own %d tokens and runs the rows "
-                   "it received" % own)
+                   "it received, regroup=chunks of %d"
+                   % (own, moe_ops.held_chunk_rows(rows)))
         matmul = moe_ops.resolve_grouped_matmul(
             rows, x.dtype, one_device) if impl == "sorted" else "einsum"
         _log_dispatch_once(
@@ -509,12 +512,13 @@ class MoeMlp(nn.Module):
                 counts = mesh_all_gather(group_sizes, "ep", tiled=False)
                 plan = moe_ops.exchange_plan(
                     counts, jax.lax.axis_index("ep"), buffer_rows)
-                by_expert, by_sender, held_sizes = moe_ops.regroup_plan(
-                    plan["received"], buffer_rows)
+                by_expert, by_sender, held_sizes, carried = (
+                    moe_ops.regroup_plan(plan["received"], buffer_rows))
             received = moe_ops.exchange_rows(
                 rows, plan["there"], plan["back"], buffer_rows, "ep")
             with jax.named_scope("moe/dispatch"):
-                held = moe_ops.permute_rows(received, by_expert, by_sender)
+                held = moe_ops.permute_rows(
+                    received, by_expert, by_sender, carried)
             with jax.named_scope("moe/experts"):
                 hidden = [
                     moe_ops.grouped_matmul(held, w, held_sizes)
@@ -523,7 +527,8 @@ class MoeMlp(nn.Module):
                 out = moe_ops.grouped_matmul(
                     self._act(hidden), weights[-1], held_sizes)
             with jax.named_scope("moe/combine"):
-                out = moe_ops.permute_rows(out, by_sender, by_expert)
+                out = moe_ops.permute_rows(
+                    out, by_sender, by_expert, carried)
             returned = moe_ops.exchange_rows(
                 out, plan["back"], plan["there"], rows.shape[0], "ep")
             with jax.named_scope("moe/combine"):
@@ -542,7 +547,8 @@ class MoeMlp(nn.Module):
                     dropped=mesh_psum(plan["dropped"], replicas),
                     axes=axes)
                 stats.update(moe_ops.exchange_stats(
-                    plan["sent"], dim * x.dtype.itemsize, replicas))
+                    plan["sent"], dim * x.dtype.itemsize, buffer_rows,
+                    replicas))
             return (y.reshape(x.shape), experts.reshape(x.shape[:2] + (-1,)),
                     balance, stats, loads)
 
@@ -696,11 +702,14 @@ def merge_routing(layers):
             merged[name] = jnp.stack([r[name] for r in layers]).sum()
     if "sent" in layers[0]:
         # the exchange's (``ops/moe.py:exchange_stats``): what a rank
-        # sends a step over all the layers, and the rows of the rank
-        # that received the most in the layer where it did
+        # sends a step over all the layers, the rows of the rank that
+        # received the most in the layer where it did, and those of
+        # its buffer that its regrouping ran there
         for name, over in (("sent", jnp.sum), ("exchange_bytes", jnp.sum),
                            ("received_max", jnp.max),
-                           ("received_mean", jnp.mean)):
+                           ("received_mean", jnp.mean),
+                           ("received_run", jnp.max),
+                           ("received_buffer", jnp.max)):
             merged[name] = over(jnp.stack([r[name] for r in layers]))
     return merged
 

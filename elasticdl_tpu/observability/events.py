@@ -265,7 +265,12 @@ EVENT_TYPES = frozenset({
                              #   nats, dropped_pairs; where the experts
                              #   are spread over ep: sent_pairs a rank
                              #   a step, received_pairs_max and _mean
-                             #   by rank, exchange_bytes a rank a step)
+                             #   by rank, exchange_bytes a rank a step,
+                             #   received_rows_run of
+                             #   received_rows_buffer: the rows of its
+                             #   receive buffer that the busiest rank's
+                             #   regrouping ran, whole chunks up to the
+                             #   last that carries a pair)
     "bd_noise",              # the same steps of a model trained by
                              #   block diffusion
                              #   (ops/block_diffusion.py): what the

@@ -34,8 +34,10 @@ path runs in a region manual over the mesh (``exchange_plan``,
 ``regroup_plan``, ``exchange_rows``, ``permute_rows``;
 ``MoeMlp._sorted_over_ep``): each rank sorts its own pairs, the rows
 travel to the ranks that hold their experts in one ragged all-to-all,
-are regrouped there by expert, multiplied, and come back the way they
-went; still nothing is dropped and no backward is a scatter. A layer
+are regrouped there by expert (a gather that runs the chunks of the
+receive buffer that carry a pair, not the buffer), multiplied, and come
+back the way they went; still nothing is dropped and no backward is a
+scatter. A layer
 may also hold a stated share of the experts WITHOUT an exchange
 (``sort_held``, ``dispatch_held``, ``combine_held``; one chip's part of
 a deployment that a benchmark cell cuts out): it routes over all of
@@ -281,7 +283,8 @@ def sort_held(experts, num_experts, first, count, buffer_rows):
 # What a held layer runs of its buffer follows the step's held pairs,
 # each stage in the way it won alone on the chip (PERF.md, PR 36).
 # The dispatch's gather runs chunks of this many rows, as many as hold a
-# pair (a loop with a traced trip count: a gather costs its rows).
+# pair (a loop with a traced trip count: a gather costs its rows); so
+# does the regrouping of a receive buffer under ``ep`` (``permute_rows``).
 HELD_CHUNK_ROWS = 4096
 # The two scatter-adds run a PREFIX of the buffer, the shortest of this
 # many equal steps that holds every held pair (a ``lax.switch`` over the
@@ -347,19 +350,29 @@ def _over_held_prefix(valid, lengths, fn, *operands):
 # argument.
 
 
+def _gather_chunks(x, index, divisor, filled, chunk):
+    """``take(x, index // divisor)`` into a zeroed buffer, in chunks of
+    ``chunk`` of the index up to the last that holds one of its first
+    ``filled`` entries: a loop whose trip count the step decides (a
+    gather costs its rows). Nothing differentiates through it: its
+    callers are custom VJPs' forwards and backwards."""
+    def gather(i, buffer):
+        at = jax.lax.dynamic_slice_in_dim(index, i * chunk, chunk)
+        if divisor != 1:
+            at = at // divisor
+        return jax.lax.dynamic_update_slice_in_dim(
+            buffer, jnp.take(x, at, axis=0), i * chunk, 0)
+
+    return jax.lax.fori_loop(
+        0, -(-filled // chunk), gather,
+        jnp.zeros((index.shape[0], x.shape[1]), x.dtype))
+
+
 @functools.partial(  # edlint: disable=obs-bare-jit
     jax.jit, static_argnums=(3, 4))
 def _gather_held(x, pairs, valid, k, chunk):
-    def gather(i, buffer):
-        at = jax.lax.dynamic_slice_in_dim(pairs, i * chunk, chunk)
-        return jax.lax.dynamic_update_slice_in_dim(
-            buffer, jnp.take(x, at // k, axis=0), i * chunk, 0)
-
-    # a loop whose trip count the step decides: nothing differentiates
-    # through it (this is a custom VJP's forward)
-    return jax.lax.fori_loop(
-        0, -(-jnp.sum(valid, dtype=jnp.int32) // chunk), gather,
-        jnp.zeros((pairs.shape[0], x.shape[1]), x.dtype))
+    return _gather_chunks(
+        x, pairs, k, jnp.sum(valid, dtype=jnp.int32), chunk)
 
 
 @functools.partial(  # edlint: disable=obs-bare-jit (as above)
@@ -597,11 +610,12 @@ def regroup_plan(received, buffer_rows):
     """The permutation between a receive buffer's order (sender by
     sender, each sender's rows by expert) and the grouped matmul's
     (expert by expert), from ``received`` (ranks, held experts).
-    Returns ``(by_expert, by_sender, group_sizes)``: ``take(buffer,
-    by_expert)`` groups the rows by expert, ``take(rows, by_sender)``
-    puts them back, and ``group_sizes`` (held experts,) sum to the rows
-    that carry a pair. The rows past those map to themselves: whatever
-    they hold stays among them."""
+    Returns ``(by_expert, by_sender, group_sizes, carried)``:
+    ``take(buffer, by_expert)`` groups the rows by expert, ``take(rows,
+    by_sender)`` puts them back, ``group_sizes`` (held experts,) sum to
+    ``carried``, the rows that carry a pair. The rows past those map to
+    themselves: whatever they hold stays among them, and
+    ``permute_rows`` runs none past the last chunk that carries one."""
     ranks, held = received.shape
     by_sender_sizes = received.reshape(-1)
     by_expert_sizes = received.T.reshape(-1)
@@ -610,7 +624,8 @@ def regroup_plan(received, buffer_rows):
     expert_starts = (
         jnp.cumsum(by_expert_sizes) - by_expert_sizes).reshape(held, ranks)
     at = jnp.arange(buffer_rows, dtype=jnp.int32)
-    carries = at < by_sender_sizes.sum()
+    carried = by_sender_sizes.sum()
+    carries = at < carried
 
     def segment(sizes):
         # the segment a position lies in: a compare fused into its
@@ -634,23 +649,47 @@ def regroup_plan(received, buffer_rows):
         at)
     return checkpoint_name(
         (by_expert.astype(jnp.int32), by_sender.astype(jnp.int32),
-         received.sum(axis=0).astype(jnp.int32)), MOE_ROUTE_NAME)
+         received.sum(axis=0).astype(jnp.int32), carried.astype(jnp.int32)),
+        MOE_ROUTE_NAME)
+
+
+def received_rows_run(carried, buffer_rows):
+    """The rows of a ``buffer_rows`` receive buffer that a rank with
+    ``carried`` received rows permutes: whole chunks of
+    ``held_chunk_rows`` up to the last that carries a pair."""
+    chunk = held_chunk_rows(buffer_rows)
+    return -(-carried // chunk) * chunk
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the held permutes)
+    jax.jit, static_argnums=(3,))
+def _gather_carried(rows, index, carried, chunk):
+    return _gather_chunks(rows, index, 1, carried, chunk)
 
 
 @jax.custom_vjp
-def permute_rows(rows, index, inverse):
-    """``take(rows, index)`` for a permutation ``index`` of the rows
-    with its ``inverse``: the transpose is the gather through the
-    inverse."""
-    return jnp.take(rows, index, axis=0)
+def permute_rows(rows, index, inverse, carried):
+    """``take(rows, index)`` for a permutation ``index`` of a receive
+    buffer's rows with its ``inverse`` (``regroup_plan``), of which the
+    first ``carried`` carry a pair and the others map to themselves: a
+    gather in chunks of ``held_chunk_rows`` that stops at the last
+    chunk that carries one, the rows past it zeros (nothing reads
+    them: the grouped matmuls stop at the group sizes' sum and the
+    exchange sends from offsets below ``carried``). The transpose is
+    the same through the inverse."""
+    return _gather_carried(
+        rows, index, carried, held_chunk_rows(rows.shape[0]))
 
 
-def _permute_rows_fwd(rows, index, inverse):
-    return permute_rows(rows, index, inverse), inverse
+def _permute_rows_fwd(rows, index, inverse, carried):
+    return permute_rows(rows, index, inverse, carried), (inverse, carried)
 
 
-def _permute_rows_bwd(inverse, d_rows):
-    return jnp.take(d_rows, inverse, axis=0), None, None
+def _permute_rows_bwd(res, d_rows):
+    inverse, carried = res
+    back = _gather_carried(
+        d_rows, inverse, carried, held_chunk_rows(d_rows.shape[0]))
+    return back, None, None, None
 
 
 permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
@@ -1021,7 +1060,7 @@ def routing_stats(probs, group_sizes, k, held=None, dropped=None,
 
 
 
-def exchange_stats(sent, row_bytes, axes=()):
+def exchange_stats(sent, row_bytes, buffer_rows, axes=()):
     """What the ``moe_routing`` event reports of one expert layer's
     exchange, from a plan's ``sent`` (ranks, ranks; ``exchange_plan``):
     ``sent`` the pairs a rank sent to OTHER ranks, ``received_max`` and
@@ -1029,9 +1068,12 @@ def exchange_stats(sent, row_bytes, axes=()):
     them (what its grouped matmuls run), all three by rank: the mean,
     the largest and the mean; ``exchange_bytes``: what one rank sends a
     step in this layer's four passes (the dispatch and the combine,
-    forward and backward), ``sent`` x ``row_bytes`` x 4. ``axes``: the
-    data axes besides the exchange's own, over whose expert groups the
-    ranks are counted."""
+    forward and backward), ``sent`` x ``row_bytes`` x 4;
+    ``received_run`` of ``received_buffer``: the rows of its
+    ``buffer_rows`` receive buffer that the busiest rank's regrouping
+    ran (``received_rows_run``; the ranks meet at every collective, so
+    the busiest sets the pace). ``axes``: the data axes besides the
+    exchange's own, over whose expert groups the ranks are counted."""
     sent = sent.astype(jnp.float32)
     received = sent.sum(axis=0)
     left = (sent.sum(axis=1) - jnp.diagonal(sent)).mean()
@@ -1046,4 +1088,7 @@ def exchange_stats(sent, row_bytes, axes=()):
             name: (jax.lax.pmax if name == "received_max"
                    else jax.lax.pmean)(value, axes)
             for name, value in stats.items()}
+    stats["received_run"] = received_rows_run(
+        stats["received_max"], buffer_rows)
+    stats["received_buffer"] = jnp.float32(buffer_rows)
     return stats

@@ -86,8 +86,9 @@ class Fact(NamedTuple):
 # that keeps a balancing bias also reports its magnitude, one that
 # holds a share of its experts the pairs that share got, the rows of
 # its layers' buffers and those of them the step ran, one whose experts
-# are spread over ``ep`` the pairs a rank sent, the rows a rank received
-# and the exchange's bytes), a
+# are spread over ``ep`` the pairs a rank sent, the rows a rank
+# received, those of its receive buffer that its regrouping ran and the
+# exchange's bytes), a
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
 # block (``models/moe_transformer.py``), and what the loss function
 # names of its own sum (a multi-token-prediction module's loss)
@@ -104,6 +105,8 @@ FACTS = (
         ("sent", "sent_pairs"),
         ("received_max", "received_pairs_max"),
         ("received_mean", "received_pairs_mean"),
+        ("received_run", "received_rows_run"),
+        ("received_buffer", "received_rows_buffer"),
         ("exchange_bytes", "exchange_bytes"))),
     Fact("noise", "bd_noise"),
     Fact("mhc", "mhc"),

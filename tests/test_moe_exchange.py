@@ -105,8 +105,10 @@ def test_regrouping_is_a_permutation_that_groups_by_expert():
     received = jnp.asarray(rng.randint(0, 6, (4, 3)), jnp.int32)
     received = received.at[2, 1].set(0).at[0, 0].set(0)
     total, buffer_rows = int(received.sum()), int(received.sum()) + 11
-    by_expert, by_sender, sizes = moe_ops.regroup_plan(received, buffer_rows)
+    by_expert, by_sender, sizes, carried = moe_ops.regroup_plan(
+        received, buffer_rows)
     np.testing.assert_array_equal(sizes, np.asarray(received).sum(axis=0))
+    assert int(carried) == total
     np.testing.assert_array_equal(
         np.sort(by_expert), np.arange(buffer_rows))
     np.testing.assert_array_equal(
@@ -127,14 +129,53 @@ def test_regrouping_is_a_permutation_that_groups_by_expert():
         assert (np.diff(senders[np.asarray(by_expert)[:total]][
             grouped == e]) >= 0).all()
     rows = jnp.asarray(rng.randn(buffer_rows, 5), jnp.float32)
+    # 4,096 does not divide this buffer: one chunk, the buffer whole
     out, vjp = jax.vjp(
-        lambda r: moe_ops.permute_rows(r, by_expert, by_sender), rows)
+        lambda r: moe_ops.permute_rows(r, by_expert, by_sender, carried),
+        rows)
     np.testing.assert_array_equal(out, np.asarray(rows)[by_expert])
     cotangent = jnp.asarray(rng.randn(buffer_rows, 5), jnp.float32)
     (d_rows,) = vjp(cotangent)
     (expected,) = jax.vjp(lambda r: jnp.take(r, by_expert, axis=0), rows)[1](
         cotangent)
     np.testing.assert_allclose(d_rows, expected, rtol=1e-6)
+
+
+@pytest.mark.parametrize("buffer_rows, carried", [
+    (3 * 4096, 0), (3 * 4096, 1), (3 * 4096, 4096), (3 * 4096, 5000),
+    (3 * 4096, 2 * 4096), (3 * 4096, 3 * 4096), (1000, 0), (1000, 300),
+    (1000, 1000)])
+def test_the_regrouping_gathers_the_chunks_that_carry_a_pair(
+        buffer_rows, carried):
+    """``permute_rows`` is ``take(rows, index)`` on every row below the
+    end of the last chunk of ``held_chunk_rows`` that carries a pair
+    and zero past it, its transpose ``take``'s on the same rows: with
+    no row, one, exactly a chunk, the buffer full, and a buffer that
+    4,096 does not divide (one chunk: the buffer whole)."""
+    rng = np.random.RandomState(buffer_rows + carried)
+    received = jnp.asarray(
+        rng.multinomial(carried, np.full(12, 1 / 12)).reshape(4, 3),
+        jnp.int32)
+    by_expert, by_sender, sizes, count = moe_ops.regroup_plan(
+        received, buffer_rows)
+    assert int(count) == int(sizes.sum()) == carried
+    chunk = moe_ops.held_chunk_rows(buffer_rows)
+    assert chunk == (4096 if buffer_rows % 4096 == 0 else buffer_rows)
+    end = -(-carried // chunk) * chunk
+    assert int(moe_ops.received_rows_run(count, buffer_rows)) == end
+    rows = jnp.asarray(rng.randn(buffer_rows, 4), jnp.float32)
+    cotangent = jnp.asarray(rng.randn(buffer_rows, 4), jnp.float32)
+    for index, inverse in ((by_expert, by_sender), (by_sender, by_expert)):
+        out, vjp = jax.jit(lambda r, index=index, inverse=inverse: jax.vjp(
+            lambda r: moe_ops.permute_rows(r, index, inverse, count), r))(
+                rows)
+        np.testing.assert_array_equal(out[:end], np.asarray(rows)[index[:end]])
+        assert not np.asarray(out[end:]).any()
+        (d_rows,) = vjp(cotangent)
+        (expected,) = jax.vjp(
+            lambda r: jnp.take(r, index, axis=0), rows)[1](cotangent)
+        np.testing.assert_array_equal(d_rows[:end], expected[:end])
+        assert not np.asarray(d_rows[end:]).any()
 
 
 # --- the exchange -----------------------------------------------------
@@ -530,6 +571,10 @@ def test_the_counters_reach_the_journal_s_fields_from_the_spmd_trainer():
         assert name in fields, name
     assert fields["dropped_pairs"] == 0
     assert fields["received_pairs_mean"] == 32 * 2
+    # no stated buffer: all the ranks' pairs, which 4,096 does not
+    # divide, so the regrouping runs it whole
+    assert (fields["received_rows_run"] == fields["received_rows_buffer"]
+            == 4 * 32 * 2)
     # the experts' state is divided over ep and nothing else's is
     specs = {
         "/".join(str(k.key) for k in path): leaf.sharding.spec
@@ -537,3 +582,28 @@ def test_the_counters_reach_the_journal_s_fields_from_the_spmd_trainer():
     assert specs["block_0/moe_mlp/w_gate"][0] == "ep"
     assert specs["lm_head/kernel"][0] == ("fsdp", "ep")
     assert "ep" not in str(specs["block_0/attn/query/kernel"])
+
+
+def test_the_rows_the_regrouping_ran_reach_the_moe_routing_event(
+        monkeypatch):
+    """``received_rows_run`` of ``received_rows_buffer``: the busiest
+    rank's received rows, in the layer where it received the most,
+    rounded up to a chunk (16 rows here, so that the tiny buffer has
+    chunks to stop at), from a training step over ``ep`` whose loss and
+    gradients are the one-device program's."""
+    monkeypatch.setattr(moe_ops, "HELD_CHUNK_ROWS", 16)
+    zoo, config, model, tokens, params = _tiny_case(ep_mesh())
+    (loss, (_, routing, _)), grads = _system(zoo, model, tokens)(params)
+    # stopping short of the buffer changes nothing that is read
+    one = zoo.model_from_config(config, attention_impl="xla")
+    (loss_one, _), grads_one = _system(zoo, one, tokens)(params)
+    np.testing.assert_allclose(loss, loss_one, rtol=1e-5)
+    _assert_trees_close(grads, grads_one, rtol=1e-4, atol=1e-5)
+    (fact,) = [f for f in step_fns.FACTS if f.key == "routing"]
+    fields = fact.journal(routing)
+    buffer_rows = 4 * 32 * 2
+    assert fields["received_rows_buffer"] == buffer_rows
+    busiest = fields["received_pairs_max"]
+    assert 32 * 2 <= busiest <= buffer_rows
+    assert fields["received_rows_run"] == -(-busiest // 16) * 16
+    assert fields["received_rows_run"] <= fields["received_rows_buffer"]

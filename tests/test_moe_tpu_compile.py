@@ -139,8 +139,9 @@ def test_a_held_layer_compiles_with_its_loop_and_its_prefixes(
         y, aux = layer.apply({"params": params}, x)
         return (y.astype(jnp.float32) ** 2).mean() + aux["load_balancing"]
 
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    step = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    compiled = step.lower(params, x).compile()
+    hlo = compiled.as_text()
     names = kernels(hlo)
     assert len(names) == 9, names
     assert all("moe/experts" in n and "gmm" in n for n in names), names
@@ -167,8 +168,10 @@ def test_the_layer_over_ep_compiles_for_the_four_chips(topo, monkeypatch):
     131,072 rows) for the four described chips: the chip's compiler
     takes the exchange as ``ragged-all-to-all`` (the dispatch's and the
     combine's, and their transposes, under the ``moe/exchange`` scope),
-    and the grouped matmuls inside the manual region are the same nine
-    Pallas kernels, at tiles of 2304 and 896's own."""
+    the grouped matmuls inside the manual region are the same nine
+    Pallas kernels, at tiles of 2304 and 896's own, and the regrouping
+    is loops of gathers over chunks of the buffer whose peak memory is
+    no higher than one gather's over the whole of it."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from elasticdl_tpu.parallel.mesh import DATA_AXES, MeshConfig, build_mesh
@@ -197,8 +200,9 @@ def test_the_layer_over_ep_compiles_for_the_four_chips(topo, monkeypatch):
         y, aux = layer.apply({"params": params}, x)
         return (y.astype(jnp.float32) ** 2).mean() + aux["load_balancing"]
 
-    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    step = jax.jit(jax.grad(loss, argnums=(0, 1)))
+    compiled = step.lower(params, x).compile()
+    hlo = compiled.as_text()
     names = kernels(hlo)
     assert len(names) == 9, names
     assert all("moe/experts" in n and "gmm" in n for n in names), names
@@ -214,6 +218,36 @@ def test_the_layer_over_ep_compiles_for_the_four_chips(topo, monkeypatch):
     assert moe_ops.projection_tiles(131072, dim, width, jnp.bfloat16) == {
         "fwd": (512, 1152, 896), "d_rows": (512, 896, 1152),
         "d_weights": (512, 1152, 896)}
+    # the regrouping (two permutes and their transposes) is four loops
+    # over chunks of 4,096 rows, each body a gather, and no gather runs
+    # the receive buffer whole
+    loops = [line for line in hlo.splitlines()
+             if " while(" in line and "_gather_carried" in line]
+    assert len(loops) == 4, len(loops)
+    assert sum("moe/dispatch" in line for line in loops) == 2
+    assert sum("moe/combine" in line for line in loops) == 2
+    assert "bf16[4096,%d]" % dim in hlo
+    whole = re.compile(r"= bf16\[131072,%d\]\S* gather\(" % dim)
+    assert not whole.search(hlo)
+
+    # ... and the loops update their buffers in place: the compiler's
+    # peak is no higher than under one ``take`` over the whole buffer
+    # (the form before PR 46)
+    @jax.custom_vjp
+    def take_whole(rows, index, inverse, carried):
+        return jnp.take(rows, index, axis=0)
+
+    take_whole.defvjp(
+        lambda rows, index, inverse, carried: (
+            jnp.take(rows, index, axis=0), inverse),
+        lambda inverse, d_rows: (
+            jnp.take(d_rows, inverse, axis=0), None, None, None))
+    monkeypatch.setattr(moe_ops, "permute_rows", take_whole)
+    before = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    assert whole.search(before.as_text())
+    assert (compiled.memory_analysis().peak_memory_in_bytes
+            <= before.memory_analysis().peak_memory_in_bytes)
 
 
 def test_a_tile_the_byte_count_refuses_the_compiler_refuses_too(chip):
