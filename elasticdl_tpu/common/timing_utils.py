@@ -27,7 +27,9 @@ never waits for the device:
   ``master_teardown``, filled through ``end_record`` alone); the
   loop's first iteration lands in the start-up record under start-up
   names (``first_task``, ``first_step``). Both carry ``start_ts``,
-  their start on the epoch clock of the journal's ``ts``. While the
+  their start on the epoch clock of the journal's ``ts``. The worker's
+  two are also where the device's memory is journaled
+  (``device_memory`` at ``first_step`` and at ``teardown``). While the
   start-up record is open, and only then, a phase that closes also
   takes what jax traced, lowered, compiled or loaded from its cache
   since the last one did (``device_obs.compile_totals``): the
@@ -69,6 +71,13 @@ SLOW_WINDOW = 64
 SLOW_MIN_SAMPLES = 8
 # steps a ``loop_phases`` event covers where the caller names none
 DEFAULT_INTERVAL = 100
+
+# the worker's two records outside the loop. At the close of the first
+# and the start of the second the device's memory is journaled
+# (``device_memory``); never at a master's, whose process must not ask
+# jax for its devices: that would open the chip inside it
+WORKER_STARTUP = "worker_startup"
+WORKER_TEARDOWN = "worker_teardown"
 
 # while the start-up record is open the loop's first iteration goes by
 # the names start-up has for it
@@ -116,7 +125,7 @@ def process_age_ns():
 
 
 def start_ledger(module_start_ns, main_start_ns, interval=0,
-                 event="worker_startup"):
+                 event=WORKER_STARTUP):
     """A role's ledger with its start-up record (``event``) opened at
     the process's start and holding ``imports``: from there to the
     first statement of the role's ``main`` (``main_start_ns``).
@@ -476,7 +485,7 @@ class Timing:
 
     # -- outside the loop ----------------------------------------------
 
-    def begin_startup(self, start_ns, event="worker_startup"):
+    def begin_startup(self, start_ns, event=WORKER_STARTUP):
         """Opens the start-up record, journaled as ``event`` and
         back-dated to ``start_ns`` on the ``perf_counter_ns`` clock
         (the process's start)."""
@@ -552,6 +561,8 @@ class Timing:
             }
         events.emit(event, start_ts=start_ts, wall_ns=wall, phases=record,
                     **observed)
+        if event == WORKER_STARTUP:
+            device_obs.journal_memory("first_step")
         logger.info(
             "%s %.3fs: %s; compiles %s (%s listener calls)",
             event.replace("_startup", " start-up"), wall / 1e9,
@@ -560,13 +571,16 @@ class Timing:
             observed.get("listener_calls", "no"),
         )
 
-    def begin_teardown(self, event="worker_teardown"):
+    def begin_teardown(self, event=WORKER_TEARDOWN):
         """Opens the teardown record, journaled as ``event``
         (idempotent); a start-up that never saw a step is closed
         first."""
         if self._teardown_start is not None:
             return
         self.end_startup()
+        if event == WORKER_TEARDOWN:
+            # before the teardown's clock starts: the process's peaks
+            device_obs.journal_memory("teardown")
         self._teardown_start = time.perf_counter_ns()
         self._teardown_event = (event, time.time())
         self._record = {}

@@ -55,9 +55,11 @@ compile ledger and HBM gauges (TelemetryBlob fields 40-51):
   ``EDL_RECOMPILE_STORM_SECS`` (default 60 s): steady-state shape
   churn, each hit a full XLA compile on the step path. Clears by
   itself as the recency window drains.
-- **hbm_pressure**    — a worker's device bytes-in-use exceeds
-  ``EDL_HBM_PRESSURE_MAX`` (default 0.9) of its reported device
-  limit; a limit of 0 (unknown capacity) never fires.
+- **hbm_pressure**    — a worker's fullest device holds more (its
+  buffers plus what its loaded programs reserve:
+  ``observability/device.py:memory_snapshot``) than
+  ``EDL_HBM_PRESSURE_MAX`` (default 0.9) of that device's limit; a
+  limit of 0 (unknown capacity) never fires.
 
 Everything is plain dict/float work under one lock, sized for a scan
 thread ticking at 1 Hz over hundreds of roles — no numpy, no RPC.
@@ -747,8 +749,9 @@ class FleetMonitor:
                             "xla_compile_secs_total"
                         ],
                     }
-                # hbm_pressure: bytes-in-use over the reported device
-                # limit (limit 0 = unknown capacity, never fires)
+                # hbm_pressure: the fullest device's bytes in use and
+                # reserved over its limit (limit 0 = unknown capacity,
+                # never fires)
                 limit = state.blob["hbm_limit_bytes"]
                 in_use = state.blob["hbm_bytes_in_use"]
                 if limit > 0 and in_use / limit > self._hbm_pressure_max:

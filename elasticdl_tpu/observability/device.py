@@ -15,12 +15,17 @@ runtime through three instruments:
    leaves changed — the flight-recorder answer to "why did step 4127
    take 40 s".
 2. **Device-memory accounting** — ``memory_snapshot`` reads the
-   runtime allocator (``device.memory_stats()``) where it exists and
-   falls back to walking ``jax.live_arrays()`` on backends without an
-   HBM allocator (CPU CI), keeping a process-lifetime peak watermark.
-   ``EDL_HBM_LIMIT_BYTES`` supplies a synthetic limit where the
-   backend reports none, so the ``hbm_pressure`` fleet alert is
-   drillable on any box.
+   runtime allocator (``device.memory_stats()``) of every local
+   device where it exists, keeps the devices apart and sums nothing:
+   its summary is the fullest device's buffers PLUS what its loaded
+   programs reserve for their temporaries, which is what fills a chip
+   (ISSUE 47). It falls back to walking ``jax.live_arrays()`` on
+   backends without an HBM allocator (CPU CI), keeping a
+   process-lifetime peak watermark there. ``EDL_HBM_LIMIT_BYTES``
+   supplies a synthetic limit where the backend reports none, so the
+   ``hbm_pressure`` fleet alert is drillable on any box. A worker
+   journals the snapshot three times a process (``journal_memory``:
+   ``device_memory``), never on the step path.
 3. **Cost-model step attribution** — on a compile the wrapper
    AOT-relowers the function (``jitted.lower(*args).compile()``) and
    keeps the executable's ``cost_analysis()`` FLOPs/bytes. jax serves
@@ -40,7 +45,12 @@ runtime through three instruments:
    be read against what the program does; and for the Pallas kernels
    it holds, by name (``pallas_kernels``), in the same two places: a
    step whose flash backward fell back from ``flash_bwd`` to
-   ``flash_dq`` + ``flash_dkv`` says so there.
+   ``flash_dq`` + ``flash_dkv`` says so there. And (ISSUE 47) for the
+   program's memory: the compiler's own count of the same executable
+   (``compiled_memory``) and, from the same text, which is the
+   SCHEDULED module, what is live at the program's fullest point by
+   the program's scopes (``peak_live``); both in ``xla_compile`` and
+   on a log line of their own after the compile line.
 4. **The compile split** (ISSUE 33) -- jax times its own tracing,
    lowering and backend compile, and says whether the persistent
    compilation cache was asked, hit or missed (``jax.monitoring``).
@@ -69,6 +79,7 @@ Knobs (all via common/env_utils, documented in docs/OBSERVABILITY.md):
 
 import collections
 import contextlib
+import functools
 import math
 import re
 import threading
@@ -109,7 +120,12 @@ _totals = {
     "h2d_bytes": 0,
     "d2h_bytes": 0,
 }
-_hbm_peak = 0  # host-side watermark across memory_snapshot() polls
+# host-side watermark across memory_snapshot() polls, where the backend
+# has no allocator to keep a peak of its own
+_hbm_peak = 0
+# a device's limit as the last memory_snapshot() read it: the memory
+# log line's ``of 16.90``, without asking the allocator again
+_hbm_limit = 0
 
 # jax's own account of a compile (jax.monitoring, jax 0.9.0): each of
 # the three is reported as a scalar when it starts (its epoch start
@@ -189,12 +205,14 @@ _m_transfer_bytes = obs_metrics.lazy_counter(
 )
 _m_hbm_in_use = obs_metrics.lazy_gauge(
     "edl_device_hbm_bytes_in_use",
-    "Device-memory bytes in use (allocator stats, or live-buffer "
-    "fallback where the backend has no allocator)",
+    "Device-memory bytes in use on the fullest local device: its "
+    "buffers plus what its loaded programs reserve (allocator stats; "
+    "the live-buffer sum where the backend has no allocator)",
 )
 _m_hbm_peak = obs_metrics.lazy_gauge(
     "edl_device_hbm_peak_bytes",
-    "Peak device-memory bytes observed (allocator peak, or the "
+    "Peak device-memory bytes of the fullest local device (the "
+    "allocator's peak in use plus its peak reserved, or the "
     "process-lifetime watermark of the fallback)",
 )
 _m_live_buffers = obs_metrics.lazy_gauge(
@@ -309,6 +327,452 @@ def pallas_kernels(hlo_text):
     program's HLO text, in the program's order. A static count, like
     the collectives': a kernel inside a loop body counts once."""
     return dict(collections.Counter(_PALLAS_KERNEL_RE.findall(hlo_text)))
+
+
+# ---------------------------------------------------------------------------
+# the compiler's count of a program's memory, and what is live at its peak
+
+def compiled_memory(compiled):
+    """The compiler's count of one executable's memory in bytes a
+    device: ``arguments``, ``outputs``, ``aliased`` (outputs that share
+    a donated argument's buffer), ``temporaries``, ``code`` and
+    ``peak``, with ``peak_from``: ``"compiler"`` where the runtime
+    gives a peak of its own (the TPU's), else ``"sum"``: arguments +
+    outputs - aliased + temporaries. None where the backend has no
+    memory analysis."""
+    analysis = compiled.memory_analysis()
+    if analysis is None:
+        return None
+    memory = {
+        key: int(getattr(analysis, field, 0) or 0)
+        for key, field in (
+            ("arguments", "argument_size_in_bytes"),
+            ("outputs", "output_size_in_bytes"),
+            ("aliased", "alias_size_in_bytes"),
+            ("temporaries", "temp_size_in_bytes"),
+            ("code", "generated_code_size_in_bytes"),
+        )
+    }
+    peak = int(getattr(analysis, "peak_memory_in_bytes", 0) or 0)
+    memory["peak_from"] = "compiler" if peak > 0 else "sum"
+    memory["peak"] = peak if peak > 0 else (
+        memory["arguments"] + memory["outputs"] - memory["aliased"]
+        + memory["temporaries"])
+    return memory
+
+
+def memory_text(memory, limit=0):
+    """``compiled_memory`` on one line: ``arguments 6.21 GB (aliased
+    6.21), temporaries 8.93 GB, outputs 6.21 GB, code 0.04 GB, peak
+    15.18 GB of 16.90`` (the last where a device's limit is known)."""
+    return (
+        "arguments %.2f GB (aliased %.2f), temporaries %.2f GB, outputs "
+        "%.2f GB, code %.2f GB, peak %.2f GB%s" % (
+            memory["arguments"] / 1e9, memory["aliased"] / 1e9,
+            memory["temporaries"] / 1e9, memory["outputs"] / 1e9,
+            memory["code"] / 1e9, memory["peak"] / 1e9,
+            " of %.2f" % (limit / 1e9) if limit else "")
+    )
+
+
+PEAK_GROUPS_MAX = 12
+# how far back a buffer without an ``op_name`` looks for its operand's
+_NAME_HOPS = 4
+# opcodes whose result is their operand's buffer: no bytes of their
+# own, and a use of the result is a use of the operand
+_VIEW_OPCODES = frozenset({
+    "bitcast", "get-tuple-element", "tuple", "optimization-barrier",
+})
+# opcodes that run a computation of their own on the schedule
+_BODY_OPCODES = frozenset({"while", "conditional", "call"})
+_ENTRY_RE = re.compile(r"^ENTRY [^\n]*\{\n", re.MULTILINE)
+_INSTRUCTION_RE = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = ")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_SHAPE_RE = re.compile(r"\b([a-z]+\d*\w*)\[([\d,]*)\](\{[^}]*\})?")
+_LAYOUT_RE = re.compile(r"\{([\d,]*)(?::T\(([\d,]+)\))?[^}]*?(?:S\((\d+)\))?\}")
+_PARAMETER_ALIAS_RE = re.compile(r"\{(\d*)\}: \((\d+), ")
+_OPERAND_ALIAS_RE = re.compile(r'\{"indices":\[([^\]]*)\]\}')
+_BODY_RE = re.compile(
+    r"\b(?:body|to_apply|true_computation|false_computation)=%([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+_JIT_PART_RE = re.compile(r"\bp?jit\([^()]*\)")
+_WRAPPER_RE = re.compile(r"[\w.]+\(")
+_INDEX_RE = re.compile(r"_\d+$")
+
+
+@functools.lru_cache(maxsize=4096)
+def _array_bytes(dtype, dims, layout):
+    """Bytes one array of a scheduled program's text holds in HBM: its
+    dimensions padded to the layout's first tile; 0 for an array whose
+    layout names another memory space (``S(n)``, n > 0) and for a
+    token."""
+    if dtype in ("token", "opaque"):
+        return 0
+    dims = [int(d) for d in dims.split(",") if d]
+    if layout:
+        found = _LAYOUT_RE.match(layout)
+        if found is not None:
+            order, tile, space = found.groups()
+            if space and int(space) > 0:
+                return 0
+            if tile and order:
+                # the tile's last number pads the minor-most dimension
+                order = [int(d) for d in order.split(",")]
+                tile = [int(t) for t in tile.split(",")]
+                for dim, size in zip(order, reversed(tile)):
+                    dims[dim] = -(-dims[dim] // size) * size
+    return _hlo_array_bytes(dtype, dims)
+
+
+def _shape_bytes(text):
+    return sum(_array_bytes(*array) for array in _SHAPE_RE.findall(text))
+
+
+def _split_result(rest):
+    """(result shape, what follows it) of an instruction's text after
+    ``= ``; a tuple's shape is its balanced parentheses."""
+    if not rest.startswith("("):
+        shape, _, tail = rest.partition(" ")
+        return shape, tail
+    depth = 0
+    for at, char in enumerate(rest):
+        if char == "(":
+            depth += 1
+        elif char == ")":
+            depth -= 1
+            if depth == 0:
+                return rest[:at + 1], rest[at + 2:]
+    return rest, ""
+
+
+def _result_elements(shape):
+    """HBM bytes of a result's elements: one entry an array, a tuple's
+    top-level elements one each (a nested tuple summed)."""
+    if not shape.startswith("("):
+        return [_shape_bytes(shape)]
+    elements, depth, start = [], 0, 1
+    for at, char in enumerate(shape):
+        if char in "({[":
+            depth += 1
+        elif char in ")}]":
+            depth -= 1
+        if (char == "," and depth == 1) or (char == ")" and depth == 0):
+            elements.append(_shape_bytes(shape[start:at]))
+            start = at + 1
+    return elements
+
+
+def op_scope(op_name):
+    """(scope, direction) of an instruction's ``op_name``: the path cut
+    to the program's own names (``jit(...)`` parts and the primitive at
+    the end dropped, ``jvp(`` / ``transpose(`` unwrapped, a trailing
+    index folded so that every block's buffers are one group:
+    ``forward/TransformerLM/block_*/attn``), and ``backward`` under a
+    ``transpose(``, ``recompute`` under ``checkpoint`` or
+    ``rematted_computation``, else ``forward``."""
+    if "checkpoint" in op_name or "rematted_computation" in op_name:
+        direction = "recompute"
+    elif "transpose(" in op_name:
+        direction = "backward"
+    else:
+        direction = "forward"
+    # ``transpose(jvp(forward))/M/jit(_take)/gather`` -> forward/M
+    path = _WRAPPER_RE.sub("", _JIT_PART_RE.sub("", op_name))
+    names = [
+        _INDEX_RE.sub("_*", name)
+        for name in path.replace(")", "").split("/")[:-1]
+        if name and name not in ("checkpoint", "rematted_computation")
+    ]
+    # the forward's scopes repeat inside a backward that recomputes
+    # them (``transpose(jvp(forward))/M/jvp(forward)/M/checkpoint``)
+    if names and names[0] in names[1:]:
+        names = names[len(names) - 1 - names[::-1].index(names[0]):]
+    return "/".join(names) or "unscoped", direction
+
+
+def _argument_scope(op_name):
+    """The root of an argument's path: ``state.params`` of
+    ``state.params['wte']['embedding']``, ``batch`` of
+    ``batch['features']``."""
+    return re.split(r"[\[\\]", op_name, maxsplit=1)[0] or "unscoped"
+
+
+def _op_name(record, by_name):
+    """A record's ``op_name``; a copy the compiler put in (out of fast
+    memory, into another layout) has none and takes its operand's, up
+    to ``_NAME_HOPS`` copies back. None without one."""
+    for _ in range(_NAME_HOPS):
+        name = _OP_NAME_RE.search(record["attributes"])
+        if name is not None:
+            return name.group(1)
+        if not record["operands"] or not (
+                record["opcode"] == "copy"
+                or record["opcode"].endswith("-start")):
+            return None
+        record = by_name.get(record["operands"][0])
+        if record is None:
+            return None
+    return None
+
+
+def _parse_computation(text, start):
+    """The instructions of the computation whose first line ends at
+    ``start``: dicts in schedule order."""
+    end = text.find("\n}", start)
+    records = []
+    for line in text[start:end if end >= 0 else len(text)].split("\n"):
+        head = _INSTRUCTION_RE.match(line)
+        if head is None:
+            continue
+        shape, tail = _split_result(line[head.end():])
+        opcode, _, tail = tail.partition("(")
+        operands, _, attributes = tail.partition(")")
+        records.append({
+            "name": head.group(2), "root": bool(head.group(1)),
+            "shape": shape, "opcode": opcode,
+            "operands": _OPERAND_RE.findall(operands),
+            "attributes": attributes,
+        })
+    return records
+
+
+def _walk(records, aliased_outputs=()):
+    """Buffer lifetimes over one computation's schedule. Returns
+    ``(peak bytes, position, live)``: ``live`` the buffers alive at the
+    peak as ``(record index, bytes)``. ``aliased_outputs``: the root's
+    operand indices whose buffer is a donated parameter's."""
+    # a value is a list of elements, an element a tuple of buffer ids;
+    # a buffer is [bytes, defining record, last use]
+    buffers = []
+    values = {}
+    done_of = {
+        r["operands"][0]: r for r in records
+        if r["opcode"].endswith("-done") and r["operands"]
+    }
+
+    def new(nbytes, index):
+        buffers.append([nbytes, index, index])
+        return (len(buffers) - 1,)
+
+    def flat(name):
+        return tuple(b for element in values.get(name, ()) for b in element)
+
+    for index, record in enumerate(records):
+        opcode, operands = record["opcode"], record["operands"]
+        for name in operands:
+            for b in flat(name):
+                buffers[b][2] = index
+        if opcode == "get-tuple-element":
+            found = re.search(r"index=(\d+)", record["attributes"])
+            source = values.get(operands[0], ()) if operands else ()
+            at = int(found.group(1)) if found else 0
+            value = [source[at]] if at < len(source) else [()]
+        elif opcode == "tuple":
+            value = [flat(name) for name in operands]
+        elif (opcode in _VIEW_OPCODES or opcode == "while"
+              or opcode.endswith("-update")):
+            # a loop's result is its operand's buffers, updated in place
+            value = list(values.get(operands[0], ())) if operands else []
+        elif opcode.endswith("-done"):
+            start = values.get(operands[0], [()]) if operands else [()]
+            value = [(b,) for b in start[0]] or [()]
+        elif opcode.endswith("-start"):
+            # born here: what the ``-done`` will return. The rest of a
+            # start's tuple repeats its operands or is context, and the
+            # operands live until the ``-done``
+            done = done_of.get(record["name"])
+            born = tuple(
+                b for nbytes in _result_elements(
+                    (done or record)["shape"])
+                for b in new(nbytes, index))
+            value = [born, tuple(b for n in operands for b in flat(n))]
+        else:
+            sizes = _result_elements(record["shape"])
+            value = [None] * len(sizes)
+            if "aliasing_operands" in record["attributes"]:
+                # a fusion that writes into an operand's buffer: the
+                # indices count the operands, then the results
+                for group in _OPERAND_ALIAS_RE.findall(
+                        record["attributes"]):
+                    indices = [int(i) for i in re.findall(r"\d+", group)]
+                    held = tuple(
+                        b for i in indices if i < len(operands)
+                        for b in flat(operands[i]))
+                    for i in indices:
+                        out = i - len(operands)
+                        if 0 <= out < len(sizes) and held:
+                            value[out] = held
+            value = [
+                new(nbytes, index) if element is None else element
+                for element, nbytes in zip(value, sizes)
+            ]
+        values[record["name"]] = value
+        if record["root"]:
+            # what the computation returns lives to the end; an output
+            # in a donated argument's buffer is that argument, counted
+            # as a parameter already
+            kept = (
+                [flat(name) for name in operands]
+                if opcode == "tuple" else [flat(record["name"])])
+            for out, element in enumerate(kept):
+                for b in element:
+                    buffers[b][2] = len(records)
+                    if (out in aliased_outputs and records[
+                            buffers[b][1]]["opcode"] != "parameter"):
+                        buffers[b][0] = 0
+    for buffer in buffers:
+        if records[buffer[1]]["opcode"] == "parameter":
+            buffer[2] = len(records)
+    # sweep: bytes born at a position less bytes that died before it
+    delta = [0] * (len(records) + 2)
+    for nbytes, born, last in buffers:
+        delta[born] += nbytes
+        delta[last + 1] -= nbytes
+    peak, position, live_now = 0, 0, 0
+    for index in range(len(records)):
+        live_now += delta[index]
+        if live_now > peak:
+            peak, position = live_now, index
+    live = [
+        (born, nbytes) for nbytes, born, last in buffers
+        if nbytes and born <= position <= last
+    ]
+    return peak, position, live
+
+
+def peak_live(hlo_text, compiler_peak=0):
+    """What a compiled program holds in HBM at its fullest point, by
+    the program's own scopes: one pass over the ENTRY computation of a
+    scheduled module's text, which lists one instruction a line in
+    execution order (None for a module that is not
+    ``is_scheduled=true``).
+
+    A buffer is born at its instruction with the bytes of its result
+    (``_array_bytes``; a tuple's elements apart) and dies after its
+    last use; parameters and what the root returns live throughout,
+    and an output that shares a donated parameter's buffer
+    (``input_output_alias``) is counted once. Views
+    (``_VIEW_OPCODES``) and a ``-done`` add nothing and pass a use on
+    to their operand; an asynchronous pair's buffer is born at its
+    ``-start``; a fusion that writes into an operand's buffer
+    (``aliasing_operands``) and a ``while`` (its state is updated in
+    place) add nothing. What a ``while``, ``conditional`` or ``call``
+    holds INSIDE its body is not counted: ``bodies_not_counted`` says
+    how many sit on the schedule and the largest body's own peak
+    beside its parameters by the same pass.
+
+    Returns ``walk_peak`` (bytes), ``position`` of ``instructions`` and
+    the ``instruction`` / ``op_name`` there, ``walk_over_compiler``
+    (against ``compiler_peak``, None without one) and at most
+    ``PEAK_GROUPS_MAX`` ``groups`` in order of bytes: ``{scope,
+    direction, bytes, buffers}`` with ``op_scope``'s scope and
+    direction, a parameter under the root of its argument's path with
+    direction ``argument``, the groups past the largest summed as
+    ``other`` and buffers without an ``op_name`` as ``unnamed``."""
+    end = hlo_text.find("\n")
+    header = hlo_text if end < 0 else hlo_text[:end]
+    if "is_scheduled=true" not in header:
+        return None
+    entry = _ENTRY_RE.search(hlo_text)
+    if entry is None:
+        return None
+    records = _parse_computation(hlo_text, entry.end())
+    if not records:
+        return None
+    donated = set()
+    aliases = header.find("input_output_alias={")
+    if aliases >= 0:
+        donated = {
+            int(out or 0) for out, _ in _PARAMETER_ALIAS_RE.findall(
+                header[aliases:])
+        }
+    peak, position, live = _walk(records, donated)
+    by_name = {record["name"]: record for record in records}
+    groups = {}
+    for index, nbytes in live:
+        record = records[index]
+        name = _op_name(record, by_name)
+        if name is None:
+            key = ("unnamed", "")
+        elif record["opcode"] == "parameter":
+            key = (_argument_scope(name), "argument")
+        else:
+            key = op_scope(name)
+        entry = groups.setdefault(key, [0, 0])
+        entry[0] += nbytes
+        entry[1] += 1
+    unnamed = groups.pop(("unnamed", ""), None)
+    ranked = sorted(groups.items(), key=lambda kv: -kv[1][0])
+    room = PEAK_GROUPS_MAX - (unnamed is not None)
+    if len(ranked) > room:
+        rest = ranked[room - 1:]
+        ranked = ranked[:room - 1] + [(("other", ""), [
+            sum(e[0] for _, e in rest), sum(e[1] for _, e in rest)])]
+    if unnamed is not None:
+        ranked.append((("unnamed", ""), unnamed))
+    bodies = [r for r in records if r["opcode"] in _BODY_OPCODES]
+    at = records[position]
+    return {
+        "walk_peak": peak,
+        "position": position,
+        "instructions": len(records),
+        "instruction": at["name"],
+        "op_name": _op_name(at, by_name),
+        "walk_over_compiler": (
+            round(peak / compiler_peak, 4) if compiler_peak else None),
+        "groups": [
+            {"scope": scope, "direction": direction, "bytes": nbytes,
+             "buffers": count}
+            for (scope, direction), (nbytes, count) in ranked
+        ],
+        "bodies_not_counted": {
+            "instructions": len(bodies),
+            "largest_body_peak": _largest_body_peak(hlo_text, bodies),
+        },
+    }
+
+
+def _largest_body_peak(hlo_text, bodies):
+    """The largest peak, beside its parameters, of the computations
+    that ``bodies`` (``while`` / ``conditional`` / ``call``
+    instructions) run, one level down; None without any."""
+    names = set()
+    for record in bodies:
+        for single, several in _BODY_RE.findall(record["attributes"]):
+            names.update(_OPERAND_RE.findall(several) or [single])
+    names.discard("")
+    if not names:
+        return None
+    largest = 0
+    for name in names:
+        # the computation's own first line: ``%name (parameters) -> ... {``
+        at = hlo_text.find("\n%%%s (" % name)
+        body = hlo_text.find("{\n", at) if at >= 0 else -1
+        if body < 0:
+            continue
+        records = _parse_computation(hlo_text, body + 2)
+        peak, position, live = _walk(records)
+        largest = max(largest, sum(
+            nbytes for index, nbytes in live
+            if records[index]["opcode"] != "parameter"))
+    return largest
+
+
+def peak_live_text(live, shown=4):
+    """``peak_live`` on the memory log line: ``live at the peak
+    (<op_name>): <scope> 2.10 GB x12, ...`` for the largest groups."""
+    return "live at the peak (%s): %s" % (
+        live["op_name"] or live["instruction"],
+        ", ".join(
+            "%s%s %.2f GB x%d" % (
+                group["scope"],
+                " " + group["direction"]
+                if group["direction"] in ("backward", "recompute") else "",
+                group["bytes"] / 1e9, group["buffers"])
+            for group in live["groups"][:shown]
+        ),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -572,6 +1036,9 @@ class _InstrumentedJit:
         self.collectives = None
         # pallas_kernels() of the same program
         self.kernels = {}
+        # compiled_memory() and peak_live() of the same program
+        self.memory = None
+        self.peak_live = None
         self._cost_fetches = 0
         self._cost_on = env_bool(COST_ANALYSIS_ENV, True)
         self._cache_size = 0
@@ -674,6 +1141,15 @@ class _InstrumentedJit:
             else "; kernels " + ", ".join(
                 "%s x%d" % item for item in self.kernels.items()),
         )
+        if self.memory is not None:
+            # a line of its own: the compile line is read by the
+            # benchmark's log parser and by pinned tests
+            logger.info(
+                "xla memory of %s: %s%s", self.name,
+                memory_text(self.memory, _hbm_limit),
+                "" if self.peak_live is None
+                else "; " + peak_live_text(self.peak_live),
+            )
         events.emit(
             "xla_compile",
             fn=self.name,
@@ -683,6 +1159,8 @@ class _InstrumentedJit:
             stages=stages,
             collectives=self.collectives,
             kernels=self.kernels,
+            memory=self.memory,
+            peak_live=self.peak_live,
         )
 
     def _fetch_cost(self, args, kwargs):
@@ -703,9 +1181,12 @@ class _InstrumentedJit:
             self.cost_bytes = float(
                 cost.get("bytes accessed", 0.0) or 0.0
             )
+            self.memory = compiled_memory(compiled)
             hlo_text = compiled.as_text()
             self.collectives = collective_stats(hlo_text)
             self.kernels = pallas_kernels(hlo_text)
+            self.peak_live = peak_live(
+                hlo_text, self.memory["peak"] if self.memory else 0)
         except Exception as e:
             logger.debug("cost analysis unavailable for %s: %s",
                          self.name, e)
@@ -777,51 +1258,93 @@ def transfer_span(direction, nbytes=0):
 # ---------------------------------------------------------------------------
 # device-memory accounting
 
+def _allocator_devices():
+    """Each local device's allocator counters, in bytes: ``id``,
+    ``in_use``, ``reserved`` (what the loaded programs reserve for
+    their temporaries), ``peak_in_use``, ``peak_reserved``, ``limit``
+    and ``largest_free_block`` where the allocator reports one; empty
+    where the backend has no allocator (the CPU)."""
+    import jax
+
+    devices = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        if stats.get("bytes_in_use") is None:
+            continue
+        entry = {"id": int(dev.id)}
+        for key, stat in (
+            ("in_use", "bytes_in_use"),
+            ("reserved", "bytes_reserved"),
+            ("peak_in_use", "peak_bytes_in_use"),
+            ("peak_reserved", "peak_bytes_reserved"),
+            ("limit", "bytes_limit"),
+        ):
+            entry[key] = int(stats.get(stat) or 0)
+        if stats.get("largest_free_block_bytes") is not None:
+            entry["largest_free_block"] = int(
+                stats["largest_free_block_bytes"])
+        devices.append(entry)
+    return devices
+
+
+def _device_peak(entry):
+    # on the TPU runtime ``peak_bytes_in_use`` counts buffers (state,
+    # batch) and a loaded program's temporaries are counted apart as
+    # reserved (PR 22, on the chip; benchmark/lib/window.py)
+    return entry["peak_in_use"] + entry["peak_reserved"]
+
+
 def memory_snapshot():
     """Allocator view of this process's device memory, JSON-ready.
 
     ``source`` is ``"allocator"`` where ``device.memory_stats()``
-    exists (TPU/GPU), ``"live_arrays"`` on backends without one (CPU
-    CI): there the in-use number is the sum of live jax array nbytes
-    and the peak is a host-side watermark across polls. ``limit``
-    comes from the allocator, or ``EDL_HBM_LIMIT_BYTES`` when it
-    reports none."""
-    global _hbm_peak
+    exists (TPU/GPU): ``devices`` then holds every local device apart
+    (``_allocator_devices``) and the summary is ONE device's, the
+    ``fullest`` (its index in ``devices``: the largest peak, then the
+    most in use now): ``bytes_in_use`` its buffers plus what its
+    loaded programs reserve, ``peak_bytes`` its peak of both,
+    ``limit_bytes`` its limit. Nothing is summed over devices: a mesh
+    runs out of memory on one of them. On backends without an
+    allocator (CPU CI) ``source`` is ``"live_arrays"``: the in-use
+    number is the sum of live jax array nbytes and the peak is a
+    host-side watermark across polls. ``limit`` comes from the
+    allocator, or ``EDL_HBM_LIMIT_BYTES`` when it reports none."""
+    global _hbm_peak, _hbm_limit
     if not device_obs_enabled():
         return {}
     import jax
 
-    in_use = 0
-    peak = 0
-    limit = 0
-    source = "live_arrays"
+    devices = []
     try:
-        for dev in jax.local_devices():
-            stats = dev.memory_stats() or {}
-            if stats.get("bytes_in_use") is not None:
-                source = "allocator"
-                in_use += int(stats.get("bytes_in_use", 0))
-                peak += int(stats.get("peak_bytes_in_use", 0))
-                limit += int(stats.get("bytes_limit", 0))
+        devices = _allocator_devices()
     except Exception as e:
         # degrade to the live-array fallback below; a backend without
         # allocator stats is the expected CPU case, not a fault
         logger.debug("allocator memory_stats unavailable: %s", e)
-    arrays = 0
+    in_use = peak = limit = arrays = 0
+    fullest = None
     try:
         live = jax.live_arrays()
         arrays = len(live)
-        if source != "allocator":
+        if not devices:
             in_use = sum(getattr(a, "nbytes", 0) for a in live)
     except Exception as e:
         logger.debug("live_arrays unavailable: %s", e)
-    with _lock:
-        if in_use > _hbm_peak:
-            _hbm_peak = in_use
-        if source != "allocator":
-            peak = _hbm_peak
+    if devices:
+        fullest = max(
+            range(len(devices)), key=lambda i: (
+                _device_peak(devices[i]),
+                devices[i]["in_use"] + devices[i]["reserved"]))
+        entry = devices[fullest]
+        in_use = entry["in_use"] + entry["reserved"]
+        peak = _device_peak(entry)
+        limit = entry["limit"]
+    else:
+        with _lock:
+            _hbm_peak = peak = max(_hbm_peak, in_use)
     if limit <= 0:
         limit = env_int(HBM_LIMIT_ENV, 0)
+    _hbm_limit = limit
     _m_hbm_in_use.set(in_use)
     _m_hbm_peak.set(peak)
     _m_live_buffers.set(arrays)
@@ -830,8 +1353,21 @@ def memory_snapshot():
         "peak_bytes": int(peak),
         "limit_bytes": int(limit),
         "live_buffers": int(arrays),
-        "source": source,
+        "source": "allocator" if devices else "live_arrays",
+        "devices": devices,
+        "fullest": fullest,
     }
+
+
+def journal_memory(at):
+    """One ``device_memory`` journal event from a fresh snapshot: the
+    WORKER's, at the three points its loop thread passes once a
+    process (``state_init``, ``first_step``, ``teardown``). Never a
+    master's: asking jax for its devices would open the chip inside
+    the master's process."""
+    snapshot = memory_snapshot()
+    if snapshot:
+        events.emit("device_memory", at=at, **snapshot)
 
 
 # ---------------------------------------------------------------------------
@@ -920,12 +1456,12 @@ def telemetry():
 
 def reset_for_tests():
     """Test isolation only: drop wrapper registry and totals."""
-    global _hbm_peak
+    global _hbm_peak, _hbm_limit
     with _lock:
         _wrappers[:] = []
         for key in _totals:
             _totals[key] = 0.0 if key == "compile_secs" else 0
         for key, value in _stage_totals.items():
             _stage_totals[key] = type(value)()
-        _hbm_peak = 0
+        _hbm_peak = _hbm_limit = 0
     _stage_tls.__dict__.clear()

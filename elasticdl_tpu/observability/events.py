@@ -209,7 +209,38 @@ EVENT_TYPES = frozenset({
                              #   retrieval_s and saved_s on a hit,
                              #   spans [{stage, start, end}] on the
                              #   epoch clock}: the call as jax split
-                             #   it)
+                             #   it; memory {arguments, outputs,
+                             #   aliased, temporaries, code, peak,
+                             #   peak_from compiler|sum}: the
+                             #   compiler's count of the program in
+                             #   bytes a device; peak_live {walk_peak,
+                             #   position, instructions, instruction,
+                             #   op_name, walk_over_compiler, groups
+                             #   [{scope, direction, bytes, buffers}]
+                             #   (at most 12), bodies_not_counted
+                             #   {instructions, largest_body_peak}}:
+                             #   what the scheduled program holds in
+                             #   HBM at its fullest point, by the
+                             #   program's scopes; both null when the
+                             #   program was not read)
+    "device_memory",         # the worker's allocator, read at three
+                             #   points a process: at state_init (the
+                             #   state is on the device, no step
+                             #   program loaded), first_step (the close
+                             #   of worker_startup), teardown (the
+                             #   start of worker_teardown: the
+                             #   process's peaks) (+ at, source
+                             #   allocator|live_arrays, devices [{id,
+                             #   in_use, reserved, peak_in_use,
+                             #   peak_reserved, limit,
+                             #   largest_free_block}] in bytes, one
+                             #   entry a local device, fullest: the
+                             #   index in devices of the one with the
+                             #   largest peak, and that device's
+                             #   bytes_in_use = in_use + reserved,
+                             #   peak_bytes = peak_in_use +
+                             #   peak_reserved, limit_bytes;
+                             #   live_buffers)
     "xla_cache_miss",        # a program, wrapped or eager, that the
                              #   persistent compilation cache was asked
                              #   for and did not hold (+ module,

@@ -82,7 +82,10 @@ class Trainer:
     def ensure_state(self, state, batch):
         if state is None:
             with timing_utils.current().phase("state_init"):
-                return self.create_state(batch["features"])
+                state = self.create_state(batch["features"])
+            # the state is on the device and no step program is
+            # loaded: what the state costs
+            device_obs.journal_memory("state_init")
         return state
 
     def train_step(self, state, batch):

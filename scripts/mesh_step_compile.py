@@ -10,7 +10,8 @@ which collectives and how many Mosaic kernels the program holds.
 ``jax.default_backend()`` answers ``tpu`` while the model is built and
 traced, so the choosers take the branches a chip gets (the flash and
 grouped-matmul kernels, ``ragged_all_to_all``). Prints one JSON object:
-the compiler's argument and temporary bytes a device, or its refusal
+the compiler's count of memory a device (``observability/device.py:
+compiled_memory``; ``--peak-live``: what is live at the peak), or its refusal
 (``Used 16.68G of 15.75G hbm``), and the counts of the program's
 collectives and ``tpu_custom_call``s. PR 45 chose ``mellum2-ep4-s8k``'s
 ``remat_policy`` by it.
@@ -34,6 +35,10 @@ def main(argv=None):
     parser.add_argument("--seq", type=int, required=True)
     parser.add_argument("--remat", default="none")
     parser.add_argument("--hlo-out", default=None)
+    parser.add_argument(
+        "--peak-live", action="store_true",
+        help="also print what is live at the step's peak by scope "
+             "(observability/device.py:peak_live)")
     args = parser.parse_args(argv)
     root = os.path.abspath(args.root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -47,6 +52,7 @@ def main(argv=None):
 
     from benchmark.lib.refcheck import load_by_path
     from elasticdl_tpu.data.pipeline import MASK_KEY
+    from elasticdl_tpu.observability import device as device_obs
     from elasticdl_tpu.parallel.mesh import build_mesh, parse_mesh_spec
     from elasticdl_tpu.parallel.sharding import infer_state_shardings
     from elasticdl_tpu.train.step_fns import make_train_step
@@ -97,18 +103,15 @@ def main(argv=None):
     if args.hlo_out:
         with open(args.hlo_out, "w") as f:
             f.write(text)
-    memory = compiled.memory_analysis()
+    memory = device_obs.compiled_memory(compiled)
     ops = collections.Counter(re.findall(
         r" (all-gather|all-reduce|reduce-scatter|ragged-all-to-all|"
         r"all-to-all|collective-permute)(?:-start)?\(", text))
     out.update(
-        argument_bytes=memory.argument_size_in_bytes,
-        temp_bytes=memory.temp_size_in_bytes,
-        output_bytes=memory.output_size_in_bytes,
-        alias_bytes=memory.alias_size_in_bytes,
-        peak_bytes=getattr(memory, "peak_memory_in_bytes", None),
-        collectives=dict(ops),
+        memory=memory, collectives=dict(ops),
         tpu_custom_calls=text.count("tpu_custom_call"))
+    if args.peak_live:
+        out["peak_live"] = device_obs.peak_live(text, memory["peak"])
     print(json.dumps(out, indent=1))
     return 0
 

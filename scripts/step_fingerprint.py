@@ -10,7 +10,8 @@ Builds the zoo's model as the cell does (attention ``pallas``), makes
 compute, health scalars on), lowers it for one described v5e chip and
 compiles it with the TPU compiler installed here. Prints the sha256 of
 the lowered StableHLO, of the compiled HLO without its ``metadata``,
-and the compiler's memory and FLOPs. Source locations are cut to the
+the compiler's memory (``observability/device.py:compiled_memory``) and
+FLOPs, and with ``--peak-live`` what is live at the step's peak. Source locations are cut to the
 innermost frame (``jax_traceback_in_locations_limit`` 0): a Mosaic
 kernel's serialized body carries its callers' file and line, so an
 edit ABOVE a kernel's call site would otherwise change the bytes of a
@@ -44,6 +45,10 @@ def main(argv=None):
     parser.add_argument(
         "--hlo-out", default=None,
         help="write the compiled HLO, metadata and all, to this file")
+    parser.add_argument(
+        "--peak-live", action="store_true",
+        help="also print what is live at the step's peak by scope "
+             "(observability/device.py:peak_live)")
     args = parser.parse_args(argv)
     root = os.path.abspath(args.root)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -57,6 +62,7 @@ def main(argv=None):
 
     from benchmark.lib.refcheck import load_by_path
     from elasticdl_tpu.data.pipeline import MASK_KEY
+    from elasticdl_tpu.observability import device as device_obs
     from elasticdl_tpu.train.step_fns import make_train_step
     from elasticdl_tpu.train.train_state import abstract_train_state
 
@@ -90,15 +96,17 @@ def main(argv=None):
         with open(args.hlo_out, "w") as f:
             f.write(text)
     hlo = re.sub(r", metadata=\{[^}]*\}", "", text)
-    memory = compiled.memory_analysis()
-    print(json.dumps({
+    memory = device_obs.compiled_memory(compiled)
+    out = {
         "stablehlo_sha256": hashlib.sha256(
             lowered.as_text().encode()).hexdigest(),
         "compiled_hlo_sha256": hashlib.sha256(hlo.encode()).hexdigest(),
-        "argument_bytes": memory.argument_size_in_bytes,
-        "temp_bytes": memory.temp_size_in_bytes,
+        "memory": memory,
         "flops": compiled.cost_analysis()["flops"],
-    }, indent=1))
+    }
+    if args.peak_live:
+        out["peak_live"] = device_obs.peak_live(text, memory["peak"])
+    print(json.dumps(out, indent=1))
 
 
 if __name__ == "__main__":

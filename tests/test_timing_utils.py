@@ -218,11 +218,16 @@ def test_ledger_events_pass_the_schema(journal, clock):
         pass
     ledger.end_teardown()
     kinds = [r["event"] for r in journal()]
+    # ``device_memory``: the worker's two records journal the device's
+    # memory at their edge (tests/test_device_memory.py)
     assert set(kinds) == {
-        "worker_startup", "loop_phases", "slow_step", "worker_teardown"}
+        "worker_startup", "loop_phases", "slow_step", "worker_teardown",
+        "device_memory"}
     assert set(kinds) <= events.EVENT_TYPES
     for record in journal():
         assert {"ts", "role", "pid", "seq", "event"} <= set(record)
+        if record["event"] == "device_memory":
+            continue
         assert isinstance(record["wall_ns"], int)
         assert all(isinstance(ns, int)
                    for ns in record["phases"].values())
