@@ -556,6 +556,15 @@ combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 # back the way they went; its transpose is the exchange reversed, and
 # the regrouping's is a gather through the inverse permutation, so no
 # backward on this path is a scatter either.
+#
+# The regrouping's two index vectors are as long as the receive buffer
+# and are built again in the rematerialised forward, so they are built
+# without a lookup by position: each is the position plus a shift that
+# only changes at the ``ranks x held`` (sender, expert) runs' ends, a
+# compare and a select fused into one sum over those ends. A gather
+# costs ~11 ns a position on a v5e whatever the table's size: four
+# lookups a plan in tables of 64 entries were 47 ms a step at 131,072
+# positions, the compares 0.3 (PERF.md, PR 48).
 
 EXCHANGE_SCOPE = "moe/exchange"
 
@@ -615,41 +624,40 @@ def regroup_plan(received, buffer_rows):
     by_sender)`` puts them back, ``group_sizes`` (held experts,) sum to
     ``carried``, the rows that carry a pair. The rows past those map to
     themselves: whatever they hold stays among them, and
-    ``permute_rows`` runs none past the last chunk that carries one."""
+    ``permute_rows`` runs none past the last chunk that carries one.
+
+    Each vector is the position plus a shift that is constant on every
+    (sender, expert) run, so it is built with no lookup by position (a
+    gather costs ~11 ns a position on a v5e whatever the table's
+    size): ``p + shift[0] + sum_j where(p >= ends[j], step[j], 0)``
+    over the ``ranks x held`` runs' ends, ``step`` the change of the
+    shift from a run to the next and, after the last, back to 0. An
+    empty run's end is its predecessor's, so both steps engage
+    together; past ``carried`` all engage and the position stands."""
     ranks, held = received.shape
+    received = received.astype(jnp.int32)
     by_sender_sizes = received.reshape(-1)
     by_expert_sizes = received.T.reshape(-1)
-    sender_starts = (
-        jnp.cumsum(by_sender_sizes) - by_sender_sizes).reshape(ranks, held)
-    expert_starts = (
-        jnp.cumsum(by_expert_sizes) - by_expert_sizes).reshape(held, ranks)
+    sender_ends = jnp.cumsum(by_sender_sizes)
+    expert_ends = jnp.cumsum(by_expert_sizes)
+    # a run's place in the buffer less its place among the grouped rows
+    shifts = (
+        (sender_ends - by_sender_sizes).reshape(ranks, held).T
+        - (expert_ends - by_expert_sizes).reshape(held, ranks))
     at = jnp.arange(buffer_rows, dtype=jnp.int32)
-    carried = by_sender_sizes.sum()
-    carries = at < carried
 
-    def segment(sizes):
-        # the segment a position lies in: a compare fused into its
-        # reduction over ranks x held ends, no search
-        ends = jnp.cumsum(sizes)
-        return jnp.minimum(
-            jnp.sum(at[:, None] >= ends[None], axis=1, dtype=jnp.int32),
-            sizes.shape[0] - 1)
+    def shifted(ends, shifts):
+        # a compare and a select fused into their reduction over the
+        # runs' ends
+        steps = jnp.diff(shifts, append=0)
+        return at + shifts[0] + jnp.sum(
+            jnp.where(at[:, None] >= ends[None], steps[None], 0),
+            axis=1, dtype=jnp.int32)
 
-    seg = segment(by_expert_sizes)
-    expert, sender = seg // ranks, seg % ranks
-    by_expert = jnp.where(
-        carries,
-        sender_starts[sender, expert] + at - expert_starts[expert, sender],
-        at)
-    seg = segment(by_sender_sizes)
-    sender, expert = seg // held, seg % held
-    by_sender = jnp.where(
-        carries,
-        expert_starts[expert, sender] + at - sender_starts[sender, expert],
-        at)
     return checkpoint_name(
-        (by_expert.astype(jnp.int32), by_sender.astype(jnp.int32),
-         received.sum(axis=0).astype(jnp.int32), carried.astype(jnp.int32)),
+        (shifted(expert_ends, shifts.reshape(-1)),
+         shifted(sender_ends, -shifts.T.reshape(-1)),
+         received.sum(axis=0), sender_ends[-1]),
         MOE_ROUTE_NAME)
 
 

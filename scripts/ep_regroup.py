@@ -2,7 +2,8 @@
 ``permute_rows`` costs at a receive buffer that is partly spare, as one
 ``take`` over the buffer whole (the form before PR 46) and as
 ``ops/moe.py`` runs it (a loop of gathers over chunks of
-``held_chunk_rows`` that stops at the last chunk that carries a pair).
+``held_chunk_rows`` that stops at the last chunk that carries a pair),
+and what the plan of the two index vectors costs before any row moves.
 
     python scripts/ep_regroup.py            # on one TPU chip, ~2 min
 
@@ -16,15 +17,24 @@ a pair, it times (ms a call over 20 calls):
   ``loop_back`` the same through the inverse (the transpose's gather);
 - ``zeros``: the buffer's zero fill alone (what the loop pays before
   its first chunk);
+- ``plan``: ``regroup_plan`` alone, the two index vectors from the
+  (sender, expert) sizes, as ``ops/moe.py`` builds them (the position
+  plus a sum of steps at the 64 runs' ends: compares, no lookup) and,
+  kept here for the comparison, as it built them before PR 48
+  (``lookup_plan``: the run a position lies in, then four gathers in
+  tables of 64 entries, one lookup a position each), each ``--calls``
+  plans in ONE program (``plans_in_a_loop``: the host takes longer to
+  launch a program here than the plan runs);
 - ``runs``, for the record only (ships nothing): the regrouping as
   copies of its 64 contiguous (sender, expert) runs, in blocks of
   ``--block`` rows, in place of a row gather: whether rows that lie
   together are cheaper to move together (PERF.md Section 7).
 
 It checks the loop against the ``take`` on the chip: equal on every row
-below the last chunk's end, zero past it. ``--rows 2048 --width 128
---chunk 256 --block 64 --calls 2`` rehearses it on the CPU. Writes
-``chiprun_out/ep_regroup.json``.
+below the last chunk's end, zero past it; and the plan against
+``lookup_plan``: both vectors, the group sizes and the count equal.
+``--rows 2048 --width 128 --chunk 256 --block 64 --calls 2`` rehearses
+it on the CPU. Writes ``chiprun_out/ep_regroup.json``.
 """
 
 import argparse
@@ -64,6 +74,58 @@ def zipf_received(carried, seed):
     weights = rng.permutation(weights / weights.sum())
     return jnp.asarray(
         rng.multinomial(carried, weights).reshape(RANKS, HELD), jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def lookup_plan(received, buffer_rows):
+    """``regroup_plan`` as it stood before PR 48: the run of each
+    position by a compare against the runs' ends, then the run's two
+    starts looked up in (ranks, held) tables, a gather a position."""
+    ranks, held = received.shape
+    by_sender_sizes = received.reshape(-1)
+    by_expert_sizes = received.T.reshape(-1)
+    sender_starts = (
+        jnp.cumsum(by_sender_sizes) - by_sender_sizes).reshape(ranks, held)
+    expert_starts = (
+        jnp.cumsum(by_expert_sizes) - by_expert_sizes).reshape(held, ranks)
+    at = jnp.arange(buffer_rows, dtype=jnp.int32)
+    carried = by_sender_sizes.sum()
+    carries = at < carried
+
+    def segment(sizes):
+        ends = jnp.cumsum(sizes)
+        return jnp.minimum(
+            jnp.sum(at[:, None] >= ends[None], axis=1, dtype=jnp.int32),
+            sizes.shape[0] - 1)
+
+    seg = segment(by_expert_sizes)
+    expert, sender = seg // ranks, seg % ranks
+    by_expert = jnp.where(
+        carries,
+        sender_starts[sender, expert] + at - expert_starts[expert, sender],
+        at)
+    seg = segment(by_sender_sizes)
+    sender, expert = seg // held, seg % held
+    by_sender = jnp.where(
+        carries,
+        expert_starts[expert, sender] + at - sender_starts[sender, expert],
+        at)
+    return (by_expert.astype(jnp.int32), by_sender.astype(jnp.int32),
+            received.sum(axis=0).astype(jnp.int32), carried.astype(jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2, 3))
+def plans_in_a_loop(plan, received, buffer_rows, calls):
+    """``calls`` + 1 plans in one program, each from the last one's
+    table turned by an expert, through a value of its vectors that the
+    compiler cannot fold."""
+    def turn(_, received):
+        by_expert, by_sender, _, _ = plan(received, buffer_rows)
+        nothing = jnp.minimum(by_expert.max() + by_sender.max(), 0)
+        return jnp.roll(received, 1, axis=1) + nothing
+
+    return plan(
+        jax.lax.fori_loop(0, calls, turn, received), buffer_rows)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -116,17 +178,23 @@ def main():
         jax.random.PRNGKey(args.seed), (rows_n, args.width), jnp.float32)
     ).astype(jnp.bfloat16)
     take = jax.jit(lambda rows, index: jnp.take(rows, index, axis=0))
+    plan = jax.jit(moe_ops.regroup_plan, static_argnums=(1,))
     loop = jax.jit(moe_ops.permute_rows)
     zeros = jax.jit(lambda rows: jnp.zeros_like(rows))
     record["zeros_ms"] = ms_per_call(zeros, args.calls, rows)
     print("zero fill of the buffer: %.3f ms" % record["zeros_ms"])
     for carried in (rows_n // 2, rows_n * 5 // 8, rows_n * 11 // 16, rows_n):
         received = zipf_received(carried, args.seed + carried)
-        by_expert, by_sender, _, count = jax.jit(
-            moe_ops.regroup_plan, static_argnums=(1,))(received, rows_n)
+        by_expert, by_sender, _, count = plan(received, rows_n)
         end = int(moe_ops.received_rows_run(count, rows_n))
         case = {
             "carried": carried, "rows_run": end,
+            "plan_ms": ms_per_call(
+                plans_in_a_loop, 1, plan, received, rows_n, args.calls
+            ) / (args.calls + 1),
+            "plan_lookup_ms": ms_per_call(
+                plans_in_a_loop, 1, lookup_plan, received, rows_n,
+                args.calls) / (args.calls + 1),
             "take_ms": ms_per_call(take, args.calls, rows, by_expert),
             "loop_ms": ms_per_call(
                 loop, args.calls, rows, by_expert, by_sender, count),
@@ -140,6 +208,9 @@ def main():
             loop(rows, by_expert, by_sender, count).astype(jnp.float32))
         case["loop_equal"] = bool(
             (got[:end] == want[:end]).all() and not got[end:].any())
+        case["plan_equal"] = all(
+            bool((ours == theirs).all()) for ours, theirs in zip(
+                plan(received, rows_n), lookup_plan(received, rows_n)))
         runs = np.asarray(
             copy_runs(rows, received, args.block).astype(jnp.float32))
         case["runs_equal"] = bool((runs[:carried] == want[:carried]).all())
@@ -148,7 +219,7 @@ def main():
     os.makedirs("chiprun_out", exist_ok=True)
     with open(os.path.join("chiprun_out", "ep_regroup.json"), "w") as f:
         json.dump(record, f, indent=1)
-    ok = all(c["loop_equal"] for c in record["cases"])
+    ok = all(c["loop_equal"] and c["plan_equal"] for c in record["cases"])
     print(json.dumps({"ok": ok, "device": record["device"]}))
     return 0 if ok else 1
 

@@ -141,6 +141,111 @@ def test_regrouping_is_a_permutation_that_groups_by_expert():
     np.testing.assert_allclose(d_rows, expected, rtol=1e-6)
 
 
+def _plan_by_loops(received, buffer_rows):
+    """``regroup_plan``'s two vectors by plain loops over the (sender,
+    expert) runs: a run's rows lie in the buffer after every run of an
+    earlier sender and every earlier run of its own, and go, expert by
+    expert and sender by sender, to the next free places."""
+    ranks, held = received.shape
+    by_expert = []
+    for expert in range(held):
+        for sender in range(ranks):
+            lies = sum(
+                int(received[s, e]) for s in range(ranks)
+                for e in range(held) if (s, e) < (sender, expert))
+            by_expert.extend(range(lies, lies + int(received[sender, expert])))
+    by_expert.extend(range(len(by_expert), buffer_rows))
+    by_sender = [None] * buffer_rows
+    for place, source in enumerate(by_expert):
+        by_sender[source] = place
+    return np.asarray(by_expert), np.asarray(by_sender)
+
+
+def _received_table(kind, buffer_rows):
+    rng = np.random.RandomState(0)
+    ranks, held = 4, 16
+
+    def drawn(carried, weights):
+        return rng.multinomial(
+            carried, weights / weights.sum()).reshape(ranks, held)
+
+    zipf = rng.permutation(1.0 / np.arange(1, ranks * held + 1) ** 1.2)
+    even = np.ones(ranks * held)
+    if kind == "zipf_skewed":
+        return drawn(buffer_rows * 5 // 8, zipf)
+    if kind == "empty_runs":
+        return drawn(buffer_rows // 2, zipf * (rng.rand(ranks * held) < 0.4))
+    if kind == "first_and_last_run_empty":
+        table = drawn(buffer_rows // 2, even)
+        table[0, 0] = table[-1, -1] = 0
+        return table
+    if kind == "a_sender_sent_nothing":
+        table = drawn(buffer_rows // 2, even)
+        table[2] = 0
+        return table
+    if kind == "an_expert_received_nothing":
+        table = drawn(buffer_rows // 2, even)
+        table[:, 5] = 0
+        return table
+    if kind == "one_run_has_it_all":
+        table = np.zeros((ranks, held), np.int64)
+        table[1, 9] = buffer_rows // 3
+        return table
+    if kind == "nothing_carried":
+        return np.zeros((ranks, held), np.int64)
+    if kind == "the_buffer_full":
+        return drawn(buffer_rows, zipf)
+    assert kind == "under_one_chunk"
+    return drawn(moe_ops.held_chunk_rows(buffer_rows) // 3, zipf)
+
+
+@pytest.mark.parametrize("kind", [
+    "zipf_skewed", "empty_runs", "first_and_last_run_empty",
+    "a_sender_sent_nothing", "an_expert_received_nothing",
+    "one_run_has_it_all", "nothing_carried", "the_buffer_full",
+    "under_one_chunk"])
+def test_the_plan_is_what_plain_loops_over_the_runs_give(kind):
+    buffer_rows = 2 * 4096
+    received = _received_table(kind, buffer_rows)
+    by_expert, by_sender, sizes, carried = jax.jit(
+        moe_ops.regroup_plan, static_argnums=(1,))(
+            jnp.asarray(received, jnp.int32), buffer_rows)
+    assert by_expert.dtype == by_sender.dtype == jnp.int32
+    assert sizes.dtype == carried.dtype == jnp.int32
+    want_by_expert, want_by_sender = _plan_by_loops(received, buffer_rows)
+    np.testing.assert_array_equal(by_expert, want_by_expert)
+    np.testing.assert_array_equal(by_sender, want_by_sender)
+    at = np.arange(buffer_rows)
+    np.testing.assert_array_equal(np.asarray(by_sender)[by_expert], at)
+    np.testing.assert_array_equal(np.asarray(by_expert)[by_sender], at)
+    total = int(received.sum())
+    np.testing.assert_array_equal(by_expert[total:], at[total:])
+    np.testing.assert_array_equal(by_sender[total:], at[total:])
+    np.testing.assert_array_equal(sizes, received.sum(axis=0))
+    assert int(carried) == int(np.asarray(sizes).sum()) == total
+
+
+def _primitives(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _primitives(inner)
+
+
+def test_the_plan_looks_nothing_up_by_position():
+    """Both vectors are the position plus a sum of steps at the runs'
+    ends: a table lookup a position costs ~11 ns each on a v5e whatever
+    the table's size, 47 ms a step at the cell's buffer (PERF.md, PR
+    48)."""
+    jaxpr = jax.make_jaxpr(moe_ops.regroup_plan, static_argnums=(1,))(
+        jnp.zeros((4, 16), jnp.int32), 8192)
+    names = set(_primitives(jaxpr.jaxpr))
+    assert "reduce_sum" in names and "select_n" in names
+    assert not {n for n in names if "gather" in n or "sort" in n}, names
+
+
 @pytest.mark.parametrize("buffer_rows, carried", [
     (3 * 4096, 0), (3 * 4096, 1), (3 * 4096, 4096), (3 * 4096, 5000),
     (3 * 4096, 2 * 4096), (3 * 4096, 3 * 4096), (1000, 0), (1000, 300),
