@@ -40,6 +40,7 @@ from elasticdl_tpu.models.transformer import (
     HyperDims,
     LatentDims,
     MixerKind,
+    ShortConvDims,
     YarnScaling,
     make_attention,
     make_norm,
@@ -596,6 +597,8 @@ class MoeBlock(nn.Module):
     # the mixer: a Gated DeltaNet of these sizes where given, else
     # softmax attention with ``Attention``'s own fields of these names
     linear: Optional[GatedDeltaDims] = None
+    # or a gated short convolution of these (``ShortConv``)
+    conv: Optional[ShortConvDims] = None
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
     head_norm: Optional[str] = None
@@ -620,6 +623,7 @@ class MoeBlock(nn.Module):
             self.num_heads,
             self.latent,
             self.linear,
+            self.conv,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             qk_norm=self.qk_norm,
@@ -746,6 +750,15 @@ class MoeTransformerLM(nn.Module):
     holds ``held_experts=(0, 32)`` in ``held_rows`` rows, and one
     shared expert behind ``shared_gate``.
 
+    LFM2-8B-A1B's is ``layer_kinds`` as the list itself (``conv
+    conv full conv conv conv ...``: no period) with ``conv`` (a gated
+    short convolution's taps, ``ShortConv``), full layers of
+    ``head_dim=64`` over ``num_kv_heads=8`` with ``head_norm``,
+    ``first_k_dense=2`` (both of them ``conv`` layers) and a sigmoid
+    router with a balancing bias over 32 experts of which a chip holds
+    8; ``mixer_kinds()`` says what such a model is made of (the
+    journal's event of that name).
+
     ``objective="block_diffusion"`` (SDAR's: ``ops/block_diffusion.py``)
     trains the same blocks to denoise and not to predict the next
     token: a training call draws the step's noise from the ``noise``
@@ -817,6 +830,7 @@ class MoeTransformerLM(nn.Module):
     layer_kinds: Optional[Any] = None
     kind_fields: Optional[Any] = None
     linear: Optional[GatedDeltaDims] = None
+    conv: Optional[ShortConvDims] = None
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
     head_norm: Optional[str] = None
@@ -828,6 +842,10 @@ class MoeTransformerLM(nn.Module):
     # the context's mean: every token's router then sees one direction
     # and picks the same experts (PERF.md Section 6, PR 29)
     embed_init_std: Optional[float] = None
+    # the output head is the token embedding, transposed (LFM2's
+    # ``tie_word_embeddings``): no ``lm_head``, and the embedding's
+    # gradient is the gather's plus the head's
+    tie_embeddings: bool = False
     # per-block rematerialization, as TransformerLM has it
     # (models/transformer.py:remat_block)
     remat: bool = False
@@ -870,12 +888,16 @@ class MoeTransformerLM(nn.Module):
     def _check_kinds(self, kinds, denoise):
         """Refuses, by name, a pattern the blocks cannot run."""
         by_kind = dict(self.kind_fields or {})
-        if set(kinds) - {"full", "linear", "window"} or (
-                "linear" in kinds and self.linear is None):
+        if set(kinds) - {"full", "linear", "window", "conv"} or (
+                "linear" in kinds and self.linear is None) or (
+                "conv" in kinds and self.conv is None):
             raise ValueError(
-                "layer_kinds=%r: each is 'full', 'linear' or 'window', "
-                "and 'linear' needs the mixer's sizes (linear)"
+                "layer_kinds=%r: each is 'full', 'linear' or 'window', or "
+                "'conv' (a gated short convolution), and 'linear' and "
+                "'conv' need their mixer's sizes (linear, conv)"
                 % (self.layer_kinds,))
+        if "conv" in kinds:
+            self._check_conv(denoise, by_kind)
         if set(by_kind) - {"full", "window"}:
             raise ValueError(
                 "kind_fields=%r: only the softmax kinds 'full' and "
@@ -902,6 +924,53 @@ class MoeTransformerLM(nn.Module):
                            for i in range(self.num_layers)),
                  str(by_kind.get(kind, "the model's own")))
                 for kind in sorted(set(kinds))))
+
+    def mixer_kinds(self):
+        """What a model with gated short convolutions is made of, for
+        the journal's ``mixer_kinds`` event (the worker emits it once,
+        when the state is made: ``worker/trainer.py:ensure_state``);
+        None for a model without the kind. Read from the fields alone."""
+        if self.conv is None:
+            return None
+        kinds = tuple(self.layer_kinds or ("full",))
+        built = [kinds[i % len(kinds)] for i in range(self.num_layers)]
+        return {
+            "conv_layers": built.count("conv"),
+            "full_layers": built.count("full"),
+            "dense_layers": self.first_k_dense,
+            "conv_taps": self.conv.taps,
+            "conv_channels": self.embed_dim,
+            "head_dim": self.head_dim or self.embed_dim // self.num_heads,
+            "kv_heads": self.num_kv_heads or self.num_heads,
+        }
+
+    def _check_conv(self, denoise, by_kind):
+        """A gated short convolution runs beside causal softmax layers
+        of one kind, in dense and in expert blocks, on one device or
+        under a data axis. What it was not built beside is refused, each
+        by its name."""
+        ranks = {} if self.mesh is None else dict(self.mesh.shape)
+        for what, asked in (
+                ("objective=\"block_diffusion\" (its two copies of a "
+                 "sequence are one axis, and a convolution would read "
+                 "across their seam)", denoise),
+                ("kind_fields (a band, heads or a rotary table by layer "
+                 "kind)", bool(by_kind)),
+                ("latent attention (latent)", self.latent is not None),
+                ("a Gated DeltaNet mixer (linear)", self.linear is not None),
+                ("hyper-connections (hc: the mixer reads one stream)",
+                 self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("attention_impl='ring' / 'ulysses' (the sequence over "
+                 "sp: a shard's first positions read the shard before)",
+                 self.attention_impl in ("ring", "ulysses")
+                 or ranks.get("sp", 1) > 1),
+                ("experts spread over ep (the mesh's ep=%d)"
+                 % ranks.get("ep", 1), ranks.get("ep", 1) > 1)):
+            if asked:
+                raise ValueError(
+                    "a 'conv' layer (a gated short convolution) beside "
+                    "%s: not built, so not run" % what)
 
     def _block_diffusion_inputs(self, tokens, training, noisy, weights):
         """``(inputs (B, 2 L), positions, mask layout, weights, the
@@ -1029,6 +1098,7 @@ class MoeTransformerLM(nn.Module):
                 shared_gate=self.shared_gate,
                 exchange_rows=self.exchange_rows,
                 linear=self.linear if kind == "linear" else None,
+                conv=self.conv if kind == "conv" else None,
                 layer_index=index,
                 name=name,
                 **shared,
@@ -1055,12 +1125,14 @@ class MoeTransformerLM(nn.Module):
             else:
                 if kind == "linear":
                     raise ValueError(
-                        "a dense block's mixer is softmax or latent "
-                        "attention; layer %d asks for a Gated DeltaNet, "
-                        "which only an expert block takes" % i)
+                        "a dense block's mixer is softmax attention, "
+                        "latent attention or a gated short convolution; "
+                        "layer %d asks for a Gated DeltaNet, which only "
+                        "an expert block takes" % i)
                 x = wrap(Block)(
                     mlp_act=self.dense_act,
                     mlp_dim=self.dense_dim, layer_index=i,
+                    conv=self.conv if kind == "conv" else None,
                     name="block_%d" % i, **shared,
                     **self._kind_fields(kind, layout),
                 )(x, training)
@@ -1078,7 +1150,8 @@ class MoeTransformerLM(nn.Module):
                 streams.dtype)
 
         x = reduce_streams(x)
-        head = nn.Dense(self.vocab_size, use_bias=False, name="lm_head")
+        head = embed.attend if self.tie_embeddings else nn.Dense(
+            self.vocab_size, use_bias=False, name="lm_head")
         logits = head(make_norm(self.norm, self.norm_eps, "ln_f")(x))
         mtp_logits = None
         # the module's parameters are made by ``init``, which is no
@@ -1169,6 +1242,10 @@ def moe_sharding_rules():
             # are tiny
             (r"in_proj_(qkvz|ba)/kernel$", P("fsdp", "tp")),
             (r"conv_kernel$", P(None, "tp")),
+            # a gated short convolution's two projections: stored over
+            # fsdp; B | C | X lie side by side, so nothing splits over tp
+            (r"attn/in_proj/kernel$", P("fsdp", None)),
+            (r"attn/proj_out/kernel$", P(None, "fsdp")),
             (r"(A_log|dt_bias)$", P()),
             (r"shared_expert_gate/kernel$", P()),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),

@@ -34,7 +34,12 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.data.example import decode_example
-from elasticdl_tpu.ops import gated_delta, hyper_connection, qkv_conv
+from elasticdl_tpu.ops import (
+    gated_delta,
+    hyper_connection,
+    qkv_conv,
+    short_conv,
+)
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
     ring_attention,
@@ -596,6 +601,53 @@ class GatedDeltaNet(nn.Module):
 
 
 @dataclasses.dataclass(frozen=True)
+class ShortConvDims:
+    """A gated short convolution mixer as LFM2's ``config.json`` names
+    its one size: ``conv_L_cache``, the taps (3: a position reads itself
+    and the two before it). The channels are the model's width;
+    ``conv_bias`` true is not built."""
+
+    taps: int
+
+
+class ShortConv(nn.Module):
+    """LFM2's ``conv`` mixer (``lfm2`` / ``lfm2_moe``'s ``ShortConv``),
+    for the tokens x (S, d) of one sequence:
+
+        B | C | X = x W_in            (d -> 3 d, no bias)
+        y = C * conv_K(B * X)         (``ops/short_conv.py``: causal,
+                                       depthwise over the d channels, K
+                                       taps, no bias, no activation)
+        out = y W_out                 (d -> d, no bias)
+
+    No position enters it and no state beyond K - 1 positions. Scopes:
+    ``short_conv/in_proj``, ``short_conv/gate`` (the two gates and the
+    K shifted multiply-adds, forward and backward), ``short_conv/
+    out_proj``. The output projection's parameter is ``proj_out``:
+    ``out_proj/kernel`` is the softmax mixers' (heads, width, d) in the
+    sharding rules."""
+
+    dims: ShortConvDims
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        dim = x.shape[-1]
+        short_conv.log_choice(dim, self.dims.taps, x.shape[-2])
+        with jax.named_scope("short_conv/in_proj"):
+            bcx = nn.Dense(3 * dim, use_bias=False, name="in_proj")(x)
+        with jax.named_scope("short_conv/gate"):
+            taps = self.param(
+                "conv_kernel",
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                (self.dims.taps, dim),
+            ).astype(x.dtype)
+            y = short_conv.gated_short_conv(bcx, taps)
+        with jax.named_scope("short_conv/out_proj"):
+            return nn.Dense(dim, use_bias=False, name="proj_out")(y)
+
+
+@dataclasses.dataclass(frozen=True)
 class HyperDims:
     """A residual path of ``streams`` streams mixed by manifold-
     constrained hyper-connections, as Xing4.0's ``config.json`` names
@@ -793,20 +845,29 @@ SOFTMAX_ONLY = (
     "rotary_dim", "output_gate", "mask", "kind_scope")
 
 
-def make_attention(num_heads, latent=None, linear=None, **fields):
+def make_attention(num_heads, latent=None, linear=None, conv=None,
+                   **fields):
     """The block's mixer, ``name="attn"``, by the layer's kind:
-    ``GatedDeltaNet`` where the layer is a linear-attention one
-    (``linear``: its ``GatedDeltaDims``), ``LatentAttention`` where the
-    model names latent widths (``LatentDims``), else ``Attention``.
+    ``ShortConv`` where the layer is a gated short convolution
+    (``conv``: its ``ShortConvDims``), ``GatedDeltaNet`` where it is a
+    linear-attention one (``linear``: its ``GatedDeltaDims``),
+    ``LatentAttention`` where the model names latent widths
+    (``LatentDims``), else ``Attention``.
     ``fields``: ``norm_eps``, which all take; what the two softmax ones
     take (``rope_theta``, ``rope_scaling``: YaRN, each by its own
     convention); and what only ``Attention`` has (``SOFTMAX_ONLY``: the
     grouped-query fields, the mask's layout, the kind's scope). A
-    Gated DeltaNet rotates nothing and masks nothing, and says so."""
-    if linear is not None:
+    Gated DeltaNet and a short convolution rotate nothing and mask
+    nothing, and say so."""
+    if conv is not None or linear is not None:
+        what = ("a gated short convolution" if conv is not None
+                else "a Gated DeltaNet mixer")
         for name in ("mask", "rope_scaling"):
             if fields.get(name) is not None:
-                raise ValueError("a Gated DeltaNet mixer has no %s" % name)
+                raise ValueError("%s has no %s" % (what, name))
+    if conv is not None:
+        return ShortConv(conv, name="attn")
+    if linear is not None:
         return GatedDeltaNet(
             linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
             name="attn")
@@ -855,6 +916,9 @@ class Block(nn.Module):
     output_gate: Optional[str] = None
     mask: Optional[Any] = None
     kind_scope: Optional[str] = None
+    # the mixer a gated short convolution of these sizes and not
+    # attention (LFM2's two leading layers)
+    conv: Optional[ShortConvDims] = None
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -866,20 +930,27 @@ class Block(nn.Module):
         width = self.mlp_dim or dim * self.mlp_ratio
 
         def mlp(h):
-            if self.mlp_act == "swiglu":
-                gate = nn.Dense(width, use_bias=False, name="mlp_gate")(h)
-                gate = constrain(gate, self.mesh, HIDDEN_SPEC)
-            h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
-            h = constrain(h, self.mesh, HIDDEN_SPEC)
-            h = nn.silu(gate) * h if self.mlp_act == "swiglu" else nn.gelu(h)
-            h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
-            if self.dropout:
-                h = nn.Dropout(self.dropout, deterministic=not training)(h)
-            return h
+            # one scope for the MLP's operations, forward and backward
+            # (a trace reads a dense block's share of a step by it)
+            with jax.named_scope("dense_mlp"):
+                if self.mlp_act == "swiglu":
+                    gate = nn.Dense(
+                        width, use_bias=False, name="mlp_gate")(h)
+                    gate = constrain(gate, self.mesh, HIDDEN_SPEC)
+                h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
+                h = constrain(h, self.mesh, HIDDEN_SPEC)
+                h = (nn.silu(gate) * h if self.mlp_act == "swiglu"
+                     else nn.gelu(h))
+                h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
+                if self.dropout:
+                    h = nn.Dropout(
+                        self.dropout, deterministic=not training)(h)
+                return h
 
         attention = make_attention(
             self.num_heads,
             self.latent,
+            conv=self.conv,
             attention_impl=self.attention_impl,
             mesh=self.mesh,
             dropout=self.dropout,

@@ -317,6 +317,14 @@ EVENT_TYPES = frozenset({
                              #   last (+ step, row_err: the largest
                              #   |row sum - 1| of H_res over the
                              #   tokens; diag_mean: its mean diagonal)
+    "mixer_kinds",           # once a worker, when the state is made
+                             #   (worker/trainer.py:ensure_state), of
+                             #   a model with gated short convolutions
+                             #   (models/moe_transformer.py:
+                             #   mixer_kinds): what it is made of
+                             #   (+ conv_layers, full_layers,
+                             #   dense_layers, conv_taps,
+                             #   conv_channels, head_dim, kv_heads)
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction

@@ -136,10 +136,10 @@ QK_LAYOUT = "whole"
 
 def _lanes(width):
     """What VMEM holds of a row of ``width`` elements: whole 128-lane
-    tiles from one tile on (192 -> 256). A narrower row is counted as
-    it is, as the counts below always did."""
-    if width <= _LANES:
-        return width
+    tiles (192 -> 256, and since PR 49 64 -> 128: half of every q, k,
+    v, o, dq, dk, dv tile of a 64-wide head is padding, so its dq
+    accumulator costs what a 128-wide head's does: 32,768 x 64 counts
+    52 of the 64 MiB and stays fused, 65,536 x 64 would not)."""
     return -(-width // _LANES) * _LANES
 
 
@@ -212,6 +212,34 @@ def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
     / 1024 over a backward of 512 / 512 2028.3 and 2027.5; 256 / 1024
     over it 2037.2; 512 / 2048 over it 2054.1; the choice, 512 / 1024
     over 512 / 512, 2015.0 twice.
+
+    A 64-wide head (``lfm2-8b-s32k``: 32 heads over 8 kv heads x 32,768
+    x 64) keeps the rule's 1024 / 1024 in both kernels, on purpose. Its
+    tiles' VMEM is a 128-wide head's (``_lanes``), the compiler takes
+    2048-row tiles at this width (it refuses the forward at 2048 x 1024
+    x 128: 16.33 of 16 MiB scoped), and none of them wins. A call alone,
+    forward / backward ms by the HOST's clock around ten calls on a
+    ``TPU v5 lite`` (``scripts/flash_head64.py``, which refuses to time
+    on another backend; PR 49, PERF.md Section 6):
+
+        1024 x 1024   72.0 / 133.2      2048 x  512   117.8 / 137.4
+         512 x 1024   84.6 / 141.1       512 x 2048    74.3 / 134.0
+        1024 x  512  144.1 / 139.5      2048 x 1024    70.6 / refused
+
+    (2048 x 1024 ran 70.6 / 137.3 while ``_lanes`` still counted a
+    64-wide row as 64; under the honest count its backward is the split
+    pair, and the compiler refuses ``flash_dkv`` there at 22.34 of 16
+    MiB scoped; a second run of the sweep read the other five pairs to
+    0.1%)
+
+    and the call of equal FLOPs at a 128-wide head (16 heads over 4)
+    35.5 / 66.3 at 1024 x 1024: half, tile for tile. A tile's cost is
+    the vector unit's work on its (block_q, block_k) scores, which is
+    the same at either width, and the two products fill half an MXU
+    pass at 64; no choice of tiles changes either. The forward's 2%
+    under 2048 x 1024 is 2.8 ms of the cell's 1,335 ms step and would
+    need a branch by width that a 128-wide head may not take (the
+    compiler refuses its forward) and a backward of other tiles.
     """
     picks_q, picks_k = block_q is None, block_k is None
     if block_k is None:

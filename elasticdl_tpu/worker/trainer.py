@@ -18,6 +18,7 @@ import numpy as np
 from elasticdl_tpu.common import timing_utils
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.observability import device as device_obs
+from elasticdl_tpu.observability import events
 from elasticdl_tpu.train.step_fns import (
     facts_of,
     make_eval_step,
@@ -86,6 +87,12 @@ class Trainer:
             # the state is on the device and no step program is
             # loaded: what the state costs
             device_obs.journal_memory("state_init")
+            # and what a model of several kinds of mixer is made of
+            # (``MoeTransformerLM.mixer_kinds``): constants, said once
+            kinds = getattr(
+                getattr(self, "_model", None), "mixer_kinds", lambda: None)()
+            if kinds:
+                events.emit("mixer_kinds", **kinds)
         return state
 
     def train_step(self, state, batch):

@@ -99,7 +99,10 @@ def test_the_count_is_above_what_the_compiler_needs(
      {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}),
     # a grouped call under the fused backward's budget
     ((2, 8, 4096, 128), 2, {"flash_fwd": 1, "flash_bwd": 1}),
-], ids=["s32k-16-over-2", "s4k-8-over-2"])
+    # lfm2-8b-s32k: 32 query heads over 8 kv heads at a 64-wide head,
+    # whose dq accumulator (half a 128-wide head's) fits the fused one
+    ((1, 32, 32768, 64), 8, {"flash_fwd": 1, "flash_bwd": 1}),
+], ids=["s32k-16-over-2", "s4k-8-over-2", "s32k-32-over-8-at-64"])
 def test_grouped_query_heads_compile_without_a_copy_of_k_or_v(
         chip, shape, kv_heads, kernels):
     """k and v keep their own head count up to the kernels' operands:
