@@ -47,7 +47,7 @@ from elasticdl_tpu.models.transformer import (
     merge_hyper_facts,
     remat_block,
 )
-from elasticdl_tpu.ops import block_diffusion, flash_attention
+from elasticdl_tpu.ops import block_diffusion, flash_attention, short_conv
 from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.parallel.collectives import mesh_all_gather, mesh_psum
 from elasticdl_tpu.parallel.mesh import DATA_AXES, REPLICA_AXES
@@ -925,16 +925,26 @@ class MoeTransformerLM(nn.Module):
                  str(by_kind.get(kind, "the model's own")))
                 for kind in sorted(set(kinds))))
 
-    def mixer_kinds(self):
+    def mixer_kinds(self, seq=None, dtype=None):
         """What a model with gated short convolutions is made of, for
         the journal's ``mixer_kinds`` event (the worker emits it once,
         when the state is made: ``worker/trainer.py:ensure_state``);
-        None for a model without the kind. Read from the fields alone."""
+        None for a model without the kind. Read from the fields alone;
+        given a batch's length ``seq`` and the step's compute ``dtype``
+        (None: float32), also what runs the convolutions there
+        (``ops/short_conv.py:conv_choice``: ``conv_impl``,
+        ``conv_tile``)."""
         if self.conv is None:
             return None
         kinds = tuple(self.layer_kinds or ("full",))
         built = [kinds[i % len(kinds)] for i in range(self.num_layers)]
+        runs = {}
+        if seq is not None:
+            runs["conv_impl"], runs["conv_tile"] = short_conv.conv_choice(
+                dtype or jnp.float32, self.embed_dim, seq, self.conv.taps,
+                self.mesh)
         return {
+            **runs,
             "conv_layers": built.count("conv"),
             "full_layers": built.count("full"),
             "dense_layers": self.first_k_dense,

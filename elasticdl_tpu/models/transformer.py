@@ -623,16 +623,21 @@ class ShortConv(nn.Module):
     No position enters it and no state beyond K - 1 positions. Scopes:
     ``short_conv/in_proj``, ``short_conv/gate`` (the two gates and the
     K shifted multiply-adds, forward and backward), ``short_conv/
-    out_proj``. The output projection's parameter is ``proj_out``:
-    ``out_proj/kernel`` is the softmax mixers' (heads, width, d) in the
-    sharding rules."""
+    out_proj``. The gates run where ``ops/short_conv.py:conv_impl``
+    says, from the backend, the dtype, the shapes and the mesh, no
+    flag: on a TPU with channels in whole 128-lane rows the kernel pair
+    ``short_conv_fwd`` / ``short_conv_bwd``, which read B, C and X
+    where ``in_proj`` wrote them; everywhere else the module's lines.
+    The log's ``short conv ... impl=`` line says which. The output
+    projection's parameter is ``proj_out``: ``out_proj/kernel`` is the
+    softmax mixers' (heads, width, d) in the sharding rules."""
 
     dims: ShortConvDims
+    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, x, training=False):
         dim = x.shape[-1]
-        short_conv.log_choice(dim, self.dims.taps, x.shape[-2])
         with jax.named_scope("short_conv/in_proj"):
             bcx = nn.Dense(3 * dim, use_bias=False, name="in_proj")(x)
         with jax.named_scope("short_conv/gate"):
@@ -642,7 +647,7 @@ class ShortConv(nn.Module):
                     1.0, "fan_in", "normal", in_axis=0, out_axis=1),
                 (self.dims.taps, dim),
             ).astype(x.dtype)
-            y = short_conv.gated_short_conv(bcx, taps)
+            y = short_conv.gated_short_conv(bcx, taps, mesh=self.mesh)
         with jax.named_scope("short_conv/out_proj"):
             return nn.Dense(dim, use_bias=False, name="proj_out")(y)
 
@@ -866,7 +871,7 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
             if fields.get(name) is not None:
                 raise ValueError("%s has no %s" % (what, name))
     if conv is not None:
-        return ShortConv(conv, name="attn")
+        return ShortConv(conv, mesh=fields.get("mesh"), name="attn")
     if linear is not None:
         return GatedDeltaNet(
             linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),

@@ -325,6 +325,11 @@ EVENT_TYPES = frozenset({
                              #   (+ conv_layers, full_layers,
                              #   dense_layers, conv_taps,
                              #   conv_channels, head_dim, kv_heads)
+                             #   and what runs the convolutions at the
+                             #   batch's length (ops/short_conv.py:
+                             #   conv_choice; + conv_impl: pallas |
+                             #   xla, conv_tile: rows a grid step, null
+                             #   under xla)
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction

@@ -64,7 +64,7 @@ class SpmdTrainer(Trainer):
         self._rng = jax.random.PRNGKey(seed)
         self.mesh = mesh if mesh is not None else build_mesh(mesh_config)
         self._rules = sharding_rules
-        compute_dtype = resolve_dtype(compute_dtype)
+        self.compute_dtype = compute_dtype = resolve_dtype(compute_dtype)
         self._train_step_fn = make_train_step(
             model, loss_fn, optimizer, compute_dtype,
             grad_accum_steps=grad_accum_steps,

@@ -54,6 +54,8 @@ class Trainer:
     # pushes under the device step (``train/sparse.py``)
     streams = False
 
+    # the dtype the step computes in (None: the parameters' float32)
+    compute_dtype = None
     # the newest step's facts still on the device, by the keys of
     # ``train/step_fns.py:FACTS``; the loop fetches them on the steps
     # it logs
@@ -88,9 +90,11 @@ class Trainer:
             # loaded: what the state costs
             device_obs.journal_memory("state_init")
             # and what a model of several kinds of mixer is made of
+            # and what runs them at this batch's length
             # (``MoeTransformerLM.mixer_kinds``): constants, said once
-            kinds = getattr(
-                getattr(self, "_model", None), "mixer_kinds", lambda: None)()
+            kinds = getattr(getattr(self, "_model", None), "mixer_kinds", None)
+            kinds = kinds and kinds(
+                seq=np.shape(batch["features"])[-1], dtype=self.compute_dtype)
             if kinds:
                 events.emit("mixer_kinds", **kinds)
         return state
@@ -245,7 +249,7 @@ class JaxTrainer(Trainer):
         else:
             self.health = health
         self._health_on = self.health is not None
-        compute_dtype = resolve_dtype(compute_dtype)
+        self.compute_dtype = compute_dtype = resolve_dtype(compute_dtype)
         # recompile sentinels (ISSUE 18): instrumented_jit IS jax.jit
         # when EDL_DEVICE_OBS=0; on, each compile is counted, timed,
         # provenance-diffed, and cost-analyzed
