@@ -38,6 +38,7 @@ from elasticdl_tpu.models.transformer import (
     GatedDeltaDims,
     HyperConnection,
     HyperDims,
+    IndexerDims,
     LatentDims,
     MixerKind,
     ShortConvDims,
@@ -47,7 +48,12 @@ from elasticdl_tpu.models.transformer import (
     merge_hyper_facts,
     remat_block,
 )
-from elasticdl_tpu.ops import block_diffusion, flash_attention, short_conv
+from elasticdl_tpu.ops import (
+    block_diffusion,
+    flash_attention,
+    short_conv,
+    sparse_attention,
+)
 from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.parallel.collectives import mesh_all_gather, mesh_psum
 from elasticdl_tpu.parallel.mesh import DATA_AXES, REPLICA_AXES
@@ -614,6 +620,9 @@ class MoeBlock(nn.Module):
     rope_scaling: Optional[YarnScaling] = None
     hc: Optional[HyperDims] = None
     layer_index: int = 0
+    # ``Attention``'s field of that name: a learned indexer picks the
+    # keys a query attends over; the layer's facts under ``aux["dsa"]``
+    indexer: Optional[IndexerDims] = None
 
     @nn.compact
     def __call__(self, x, training=False, positions=None):
@@ -637,6 +646,7 @@ class MoeBlock(nn.Module):
             mask=self.mask,
             kind_scope=self.kind_scope,
             rope_scaling=self.rope_scaling,
+            indexer=self.indexer,
         )
         experts = MoeMlp(
             self.num_experts,
@@ -675,9 +685,15 @@ class MoeBlock(nn.Module):
             return constrain(write(y), self.mesh, STREAMS_SPEC), aux
         x = constrain(x, self.mesh, RESIDUAL_SPEC)
         h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        x = x + attention(h, training, *where)
+        mixed = attention(h, training, *where)
+        dsa = None
+        if self.indexer is not None:
+            mixed, dsa = mixed
+        x = x + mixed
         h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
         y, aux = experts(h, training)
+        if dsa is not None:
+            aux["dsa"] = dsa
         return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
 
 
@@ -862,6 +878,13 @@ class MoeTransformerLM(nn.Module):
     hc: Optional[HyperDims] = None
     mtp_layers: int = 0
     mtp_loss_weight: float = 0.1
+    # a learned sparse-attention indexer in every layer (``IndexerDims``;
+    # ``models/transformer.py:Attention.indexer``): a training call then
+    # also returns ``indexer_loss`` (a sample's KL term summed over the
+    # layers, which ``loss`` adds times ``indexer_loss_coef`` and names)
+    # and ``dsa`` (the ``dsa_select`` event's facts, one entry a layer)
+    indexer: Optional[IndexerDims] = None
+    indexer_loss_coef: float = 1.0
 
     def _kind_fields(self, kind, layout=None):
         """What a layer's KIND decides of its mixer, stated once: the
@@ -898,6 +921,8 @@ class MoeTransformerLM(nn.Module):
                 % (self.layer_kinds,))
         if "conv" in kinds:
             self._check_conv(denoise, by_kind)
+        if self.indexer is not None:
+            self._check_indexer(kinds, denoise, by_kind)
         if set(by_kind) - {"full", "window"}:
             raise ValueError(
                 "kind_fields=%r: only the softmax kinds 'full' and "
@@ -953,6 +978,39 @@ class MoeTransformerLM(nn.Module):
             "head_dim": self.head_dim or self.embed_dim // self.num_heads,
             "kv_heads": self.num_kv_heads or self.num_heads,
         }
+
+    def _check_indexer(self, kinds, denoise, by_kind):
+        """A learned indexer picks the keys of causal softmax layers,
+        every layer an expert block, on one device. What it was not
+        built beside is refused, each by its name."""
+        for what, asked in (
+                ("objective=\"block_diffusion\" (its mask is a layout of "
+                 "two copies; the selection is over one causal prefix)",
+                 denoise),
+                ("a 'window', 'linear' or 'conv' layer (layer_kinds=%r: the "
+                 "indexer picks keys for full softmax attention)"
+                 % (self.layer_kinds,), set(kinds) != {"full"}),
+                ("kind_fields (a band, heads or a rotary table by layer "
+                 "kind)", bool(by_kind)),
+                ("latent attention (latent: its keys are one latent a "
+                 "position, which the kernels do not read)",
+                 self.latent is not None),
+                ("hyper-connections (hc: the mixer reads one stream)",
+                 self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("a dense block (first_k_dense, moe_every: only an expert "
+                 "block hands the indexer's term out)",
+                 bool(self.first_k_dense) or self.moe_every != 1),
+                ("attention_impl='ring' / 'ulysses' (a query's picks lie "
+                 "on every shard of the sequence)",
+                 self.attention_impl in ("ring", "ulysses")),
+                ("a mesh of %d devices (ROADMAP M15: the kernels are one "
+                 "device's)" % (1 if self.mesh is None else self.mesh.size),
+                 self.mesh is not None and self.mesh.size > 1)):
+            if asked:
+                raise ValueError(
+                    "%s beside %s: not built, so not run"
+                    % (self.indexer, what))
 
     def _check_conv(self, denoise, by_kind):
         """A gated short convolution runs beside causal softmax layers
@@ -1087,7 +1145,7 @@ class MoeTransformerLM(nn.Module):
         kinds = tuple(self.layer_kinds or ("full",))
         self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
-        routing, mhc = [], []
+        routing, mhc, dsa = [], [], []
 
         def expert_block(name, index, kind="full"):
             return wrap(MoeBlock)(
@@ -1111,6 +1169,7 @@ class MoeTransformerLM(nn.Module):
                 conv=self.conv if kind == "conv" else None,
                 layer_index=index,
                 name=name,
+                indexer=self.indexer,
                 **shared,
                 **self._kind_fields(kind, layout),
             )
@@ -1124,6 +1183,8 @@ class MoeTransformerLM(nn.Module):
                 routing.append(aux["routing"])
             if "mhc" in aux:
                 mhc.append(aux["mhc"])
+            if "dsa" in aux:
+                dsa.append(aux["dsa"])
 
         for i in range(self.num_layers):
             kind = kinds[i % len(kinds)]
@@ -1200,6 +1261,22 @@ class MoeTransformerLM(nn.Module):
             outputs["mhc"] = {
                 name: jnp.stack([block_facts[name] for block_facts in mhc])
                 for name in ("row_err", "diag_mean")}
+        if dsa:
+            # a sample's term over the layers, and one fact a layer
+            outputs["indexer_loss"] = sum(d["indexer_loss"] for d in dsa)
+            outputs["indexer_loss_coef"] = self.indexer_loss_coef
+            tiles = sparse_attention.tiles_facts(
+                tokens.shape[-1], self.indexer.topk,
+                self.head_dim or self.embed_dim // self.num_heads, x.dtype)
+            outputs["dsa"] = {
+                **{name: jnp.stack([jnp.mean(d[name]) for d in dsa])
+                   for name in ("indexer_loss", "kept_mean", "entropy",
+                                "near_share")},
+                # of one head's forward grid: the first version runs
+                # every tile of the causal prefix
+                "tiles_run": jnp.float32(tiles["forward"][0]),
+                "tiles_causal": jnp.float32(tiles["forward"][0]),
+            }
         if denoise:
             outputs["weights"] = weights
             if facts is not None:
@@ -1317,6 +1394,15 @@ def loss(labels, predictions):
             ).mean(axis=-1)
             return (main + predictions["mtp_loss_weight"] * mtp + aux,
                     {"mtp_loss": mtp})
+        if "indexer_loss" in predictions:
+            # a learned indexer's own term (its KL to the attention's
+            # probabilities, summed over the layers), times its
+            # coefficient in the sum and unweighted by its name
+            term = predictions["indexer_loss"]
+            main = sparse_softmax_cross_entropy(
+                labels[:, 1:], logits[:, :-1]).mean(axis=-1)
+            return (main + aux + predictions["indexer_loss_coef"] * term,
+                    {"indexer_loss": term})
     else:
         logits, aux = predictions, 0.0
     per_token = sparse_softmax_cross_entropy(

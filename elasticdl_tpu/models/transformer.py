@@ -39,6 +39,7 @@ from elasticdl_tpu.ops import (
     hyper_connection,
     qkv_conv,
     short_conv,
+    sparse_attention,
 )
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
@@ -98,6 +99,22 @@ class MixerKind:
             self.rotary_dim and "rotary=%d" % self.rotary_dim,
             self.rope_scaling and "yarn=%g" % self.rope_scaling.factor,
             self.window and "window=%d" % self.window)))
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexerDims:
+    """A learned sparse-attention indexer (DeepSeek Sparse Attention,
+    the DeepSeek-V3.2-Exp report; Keye-VL-2.0's ``sa_config``):
+    ``heads`` query heads of ``head_dim`` over ONE key a position, and
+    the ``topk`` keys a query keeps (``ops/sparse_attention.py``)."""
+
+    heads: int
+    head_dim: int
+    topk: int
+
+    def __str__(self):
+        return "indexer heads=%d dim=%d topk=%d" % (
+            self.heads, self.head_dim, self.topk)
 
 
 def yarn_mscale(factor, mscale):
@@ -248,6 +265,16 @@ class Attention(nn.Module):
     # and its attention line starts ``heads=``.
     # None: no scope, the program and the line the mixer always had
     kind_scope: Optional[str] = None
+    # a learned indexer picks the keys a query attends over
+    # (``IndexerDims``; ``ops/sparse_attention.py``): three projections
+    # and a LayerNorm of their own (``indexer_q``, ``indexer_k``,
+    # ``indexer_k_norm``, ``indexer_w``) read the DETACHED input, the
+    # softmax runs over the picked keys alone, and the call returns
+    # ``(out, facts)``: the indexer's KL term a sample
+    # (``indexer_loss``, which alone teaches those parameters) and the
+    # ``dsa_select`` event's fields. None: the mixer, the tree and the
+    # program the block always had
+    indexer: Optional[IndexerDims] = None
 
     def _rotate(self, t, positions=None):
         rotate = functools.partial(
@@ -325,7 +352,10 @@ class Attention(nn.Module):
             q = self._rotate(q, positions)
             k = self._rotate(k, positions)
 
-        if self.attention_impl in ("ring", "ulysses"):
+        facts = None
+        if self.indexer is not None:
+            out, facts = self._attend_selected(x, q, k, v, positions)
+        elif self.attention_impl in ("ring", "ulysses"):
             if (kv_heads != self.num_heads or self.mask is not None
                     or positions is not None):
                 raise ValueError(
@@ -361,7 +391,53 @@ class Attention(nn.Module):
             out = nn.Dropout(
                 self.dropout, deterministic=not training
             )(out)
-        return out
+        return out if facts is None else (out, facts)
+
+    def _attend_selected(self, x, q, k, v, positions):
+        """``(out (B, H, S, d), facts)`` where an indexer picks each
+        query's keys. The indexer reads ``stop_gradient(x)``: its
+        parameters learn from ``facts["indexer_loss"]`` alone, and that
+        term reaches nothing else. The scopes ``dsa/indexer_proj``,
+        ``dsa/scores``, ``dsa/select``, ``dsa/attend`` and
+        ``dsa/indexer_loss`` hold its operations, forward and
+        backward."""
+        idx = self.indexer
+        if (self.attention_impl in ("ring", "ulysses")
+                or self.mask is not None or positions is not None
+                or self.rotary_dim is not None or self.rope_scaling
+                or self.output_gate or self.dropout
+                or (self.mesh is not None and self.mesh.size > 1)):
+            raise ValueError(
+                "%s selects keys for causal softmax attention on one "
+                "device, the whole head rotated by the plain table: no "
+                "other mask, ring / ulysses, partial rotary, YaRN, "
+                "output gate, dropout or mesh of several devices" % (idx,))
+        detached = jax.lax.stop_gradient(x)
+        with jax.named_scope("dsa/indexer_proj"):
+            qi = nn.DenseGeneral(
+                (idx.heads, idx.head_dim), use_bias=False,
+                name="indexer_q")(detached).transpose(0, 2, 1, 3)
+            ki = nn.LayerNorm(epsilon=self.norm_eps, name="indexer_k_norm")(
+                nn.Dense(idx.head_dim, use_bias=False, name="indexer_k")(
+                    detached))
+            # the whole indexer head rotates by the model's own table
+            qi = self._rotate(qi)
+            ki = self._rotate(ki[:, None])[:, 0]
+            w = nn.Dense(idx.heads, use_bias=False, name="indexer_w")(
+                detached).astype(jnp.float32) * (
+                    idx.heads ** -0.5 * idx.head_dim ** -0.5)
+        impl = self.attention_impl
+        # for whoever asks with mutable=["intermediates"] (the
+        # benchmark's reference check): the kept set and the scores of
+        # a stated block of queries; nothing otherwise
+        probe = self.is_mutable_collection("intermediates")
+        out, kl, facts = sparse_attention.dsa_attention(
+            q, k, v, qi, ki, w, idx.topk,
+            impl=impl if impl in ("xla", "pallas") else "auto", probe=probe)
+        for name in ("kept_bits", "kept_after", "scores_tail"):
+            if name in facts:
+                self.sow("intermediates", name, facts.pop(name))
+        return out, {"indexer_loss": kl, **facts}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -847,7 +923,7 @@ def merge_hyper_facts(sublayers):
 # ``Attention``'s own fields, which a latent mixer has none of
 SOFTMAX_ONLY = (
     "qk_norm", "dropout", "head_dim", "num_kv_heads", "head_norm",
-    "rotary_dim", "output_gate", "mask", "kind_scope")
+    "rotary_dim", "output_gate", "mask", "kind_scope", "indexer")
 
 
 def make_attention(num_heads, latent=None, linear=None, conv=None,
@@ -867,7 +943,7 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
     if conv is not None or linear is not None:
         what = ("a gated short convolution" if conv is not None
                 else "a Gated DeltaNet mixer")
-        for name in ("mask", "rope_scaling"):
+        for name in ("mask", "rope_scaling", "indexer"):
             if fields.get(name) is not None:
                 raise ValueError("%s has no %s" % (what, name))
     if conv is not None:
@@ -1020,6 +1096,7 @@ def remat_block(block_cls, remat_policy, attention_impl):
         FLASH_OUT_NAME,
     )
     from elasticdl_tpu.ops.moe import MOE_SAVE_NAMES
+    from elasticdl_tpu.ops.sparse_attention import DSA_SAVE_NAMES
 
     if remat_policy not in ("full", "dots", "flash"):
         raise ValueError(
@@ -1030,7 +1107,8 @@ def remat_block(block_cls, remat_policy, attention_impl):
         policy = jax.checkpoint_policies.save_from_both_policies(
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
             jax.checkpoint_policies.save_only_these_names(
-                FLASH_OUT_NAME, FLASH_LSE_NAME, *MOE_SAVE_NAMES
+                FLASH_OUT_NAME, FLASH_LSE_NAME, *MOE_SAVE_NAMES,
+                *DSA_SAVE_NAMES
             ),
         )
     elif remat_policy == "flash":
@@ -1049,8 +1127,10 @@ def remat_block(block_cls, remat_policy, attention_impl):
                 "nothing and degrade to \"full\")"
                 % (attention_impl,)
             )
+        # and where an indexer picks the keys, what its bisection found
+        # and its own term with its cotangents (``DSA_SAVE_NAMES``)
         policy = jax.checkpoint_policies.save_only_these_names(
-            FLASH_OUT_NAME, FLASH_LSE_NAME
+            FLASH_OUT_NAME, FLASH_LSE_NAME, *DSA_SAVE_NAMES
         )
     else:
         policy = None

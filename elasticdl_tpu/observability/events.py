@@ -330,10 +330,22 @@ EVENT_TYPES = frozenset({
                              #   conv_choice; + conv_impl: pallas |
                              #   xla, conv_tile: rows a grid step, null
                              #   under xla)
+    "dsa_select",            # the same steps of a model whose keys a
+                             #   learned indexer picks
+                             #   (ops/sparse_attention.py): a list a
+                             #   fact, one entry a layer (+ step,
+                             #   kept_mean: kept keys a query;
+                             #   indexer_loss: the layer's KL term;
+                             #   entropy of softmax(I) over the kept
+                             #   set, nats; near_share: kept keys among
+                             #   the query's nearest topk) and, of one
+                             #   head's grid, tiles_run of tiles_causal
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction
-                             #   module's cross-entropy, unweighted)
+                             #   module's cross-entropy, unweighted;
+                             #   indexer_loss: a learned indexer's KL
+                             #   term, unweighted)
 })
 
 

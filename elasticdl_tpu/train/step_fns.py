@@ -90,8 +90,9 @@ class Fact(NamedTuple):
 # received, those of its receive buffer that its regrouping ran and the
 # exchange's bytes), a
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
-# block (``models/moe_transformer.py``), and what the loss function
-# names of its own sum (a multi-token-prediction module's loss)
+# block (``models/moe_transformer.py``), a learned sparse-attention
+# indexer's, one a layer, and what the loss function names of its own
+# sum (a multi-token-prediction module's loss, an indexer's term)
 FACTS = (
     Fact("routing", "moe_routing", (
         ("load_max", "tokens_per_expert_max"),
@@ -110,6 +111,7 @@ FACTS = (
         ("exchange_bytes", "exchange_bytes"))),
     Fact("noise", "bd_noise"),
     Fact("mhc", "mhc"),
+    Fact("dsa", "dsa_select"),
     Fact("loss_terms", "loss_terms", of_loss=True),
 )
 
