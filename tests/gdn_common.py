@@ -1,7 +1,9 @@
 """Shared by the gated delta rule's test files
 (``test_gated_delta.py``: the rule, the inverse and the module;
 ``test_gated_delta_scan.py``: the scan's kernels;
-``test_gated_delta_operands.py``: the operands' kernels): seeded
+``test_gated_delta_scan_rule.py``: the rule through them;
+``test_gated_delta_operands.py``, ``test_gated_delta_operands_vjp.py``:
+the operands' kernels): seeded
 inputs, the rule's value and gradients, and how a test runs what a TPU
 backend would choose under the interpreter."""
 
@@ -34,9 +36,23 @@ def _inputs(seq, dtype, decay=1.0, seed=0, batch=2, hk=2, hv=4, dim=16):
         beta.astype(jnp.promote_types(dtype, jnp.float32)))
 
 
-def _value_and_grads(rule, args):
-    loss = lambda *a: jnp.sum(rule(*a).astype(jnp.float32) ** 2)
-    return (rule(*args),) + jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+def _value_and_grads(rule, args, jaxpr=False):
+    """``rule``'s output and the gradients of its five arguments, one
+    program (a new one a call: ``rule`` is traced under whatever the
+    test has patched by then) and one forward pass, the differentiated
+    one; an interpreted kernel costs by the trace, and what a kernel
+    returns without residuals is its own file's comparison. With
+    ``jaxpr``, (the traced program's text, the values): the kernels'
+    names are read from the trace that runs."""
+    def loss(*a):
+        out = rule(*a)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    traced = jax.jit(jax.grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).trace(*args)
+    grads, out = traced.lower().compile()(*args)
+    values = (out,) + grads
+    return (str(traced.jaxpr), values) if jaxpr else values
 
 
 def _force_pallas(monkeypatch):
@@ -60,6 +76,17 @@ def _split_inputs(num, chunk, rep, dtype, decay=2.0, hk=2, seed=0):
     return (split(q, (hk, 1), 128), split(k, (hk, 1), 128),
             split(v, (hk, rep), 128), split(g, (hk, rep)),
             split(beta, (hk, rep)))
+
+
+@jax.jit
+def _xla_lines(q, k, v, g, beta):
+    """What the scan's kernels are handed with ``prep=xla``:
+    ``_chunk_operands`` and the casts and the broadcast of
+    ``_scan_operands``. One program a shape, as the rule's own step
+    runs them; dispatched an operation at a time they cost more than
+    the kernel they are held against."""
+    return gated_delta._scan_operands(*gated_delta._chunk_operands(
+        q, k, v, g, beta, jnp.float32, "xla"), q.dtype)
 
 
 _MESH4 = "a four-device mesh"

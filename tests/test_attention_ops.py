@@ -16,6 +16,7 @@ from elasticdl_tpu.ops.ring_attention import (
     ulysses_attention,
 )
 from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
+from tests.kernel_common import traced_flash
 
 
 def _inputs(batch=2, heads=2, seq=256, dim=64, seed=0):
@@ -317,16 +318,6 @@ def _flash_grads(case):
     return flash, loss("xla"), (q, k, v)
 
 
-def _kernel_names(fn, args):
-    """Names of the pallas_calls a function traces to."""
-    import re
-
-    return sorted(set(re.findall(
-        r"name=(flash_(?:fwd|bwd|dq|dkv))\b",
-        str(jax.make_jaxpr(fn)(*args)),
-    )))
-
-
 @pytest.mark.parametrize(
     "case", list(FUSED_BACKWARD_CASES.values()),
     ids=list(FUSED_BACKWARD_CASES),
@@ -335,10 +326,10 @@ def test_fused_backward_matches_xla_and_the_split_pair(case, monkeypatch):
     from elasticdl_tpu.ops import flash_attention as F
 
     flash, xla, args = _flash_grads(case)
-    assert _kernel_names(flash, args) == ["flash_bwd", "flash_fwd"]
-    fused = flash(*args)
+    names, fused = traced_flash(flash, args)
+    assert names == ["flash_bwd", "flash_fwd"]
     bfloat16 = case[-1] == jnp.bfloat16
-    for got, ref in zip(fused, xla(*args)):
+    for got, ref in zip(fused, jax.jit(xla)(*args)):
         assert got.dtype == ref.dtype and got.shape == ref.shape
         tol = 5e-2 if bfloat16 else 3e-4
         np.testing.assert_allclose(
@@ -349,9 +340,9 @@ def test_fused_backward_matches_xla_and_the_split_pair(case, monkeypatch):
     # new function: jax keeps the traces of the old one)
     monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
     flash, _, _ = _flash_grads(case)
-    assert _kernel_names(flash, args) == [
-        "flash_dkv", "flash_dq", "flash_fwd"]
-    dq, dk, dv = (np.asarray(g, np.float32) for g in flash(*args))
+    names, pair = traced_flash(flash, args)
+    assert names == ["flash_dkv", "flash_dq", "flash_fwd"]
+    dq, dk, dv = (np.asarray(g, np.float32) for g in pair)
     # dk and dv are the pair's statements unchanged; dq's terms arrive
     # in ascending k in both schedules
     np.testing.assert_array_equal(np.asarray(fused[1], np.float32), dk)
@@ -527,9 +518,10 @@ CLASS_CASES = {
 }
 
 
-def _forward_and_backward(case, seed=11):
+def _forward_and_backward(case, seed=11, backward=True):
     """o, lse, dq, dk, dv of one causal call through the kernels'
-    own entry points (what ``ops/ring_attention.py`` calls too)."""
+    own entry points (what ``ops/ring_attention.py`` calls too); o and
+    lse alone where the backward is not read."""
     from elasticdl_tpu.ops import flash_attention as F
 
     seq_q, seq_k, dim, block_q, block_k, dtype = case
@@ -539,6 +531,8 @@ def _forward_and_backward(case, seed=11):
     q, k, v, do = mk(seq_q), mk(seq_k), mk(seq_k), mk(seq_q)
     sm_scale = dim ** -0.5
     o, lse = F._fwd(q, k, v, sm_scale, True, block_q, block_k, True)
+    if not backward:
+        return (q, k, v, do), (o, lse)
     grads = F._bwd(
         q, k, v, o, lse, do, sm_scale, True, block_q, block_k, True)
     return (q, k, v, do), (o, lse) + tuple(grads)
@@ -575,7 +569,7 @@ def test_interior_pairs_without_the_mask_change_no_bit(
     # the patch reaches the kernels: with no pair masked the diagonal
     # tiles attend to the future and the output moves
     monkeypatch.setattr(F, "_causal_pair", force(lambda m: m & False))
-    _, never_masked = _forward_and_backward(case)
+    _, never_masked = _forward_and_backward(case, backward=False)
     assert not np.array_equal(
         np.asarray(got[0], np.float32),
         np.asarray(never_masked[0], np.float32))
@@ -584,9 +578,12 @@ def test_interior_pairs_without_the_mask_change_no_bit(
         return xla_attention(q[:, None], k[:, None], v[:, None],
                              causal=True)[:, 0]
 
-    o_ref, vjp = jax.vjp(ref, q, k, v)
+    def ref_and_vjp(q, k, v, do):
+        o_ref, vjp = jax.vjp(ref, q, k, v)
+        return (o_ref,) + vjp(do)
+
     tol = 5e-2 if case[-1] == jnp.bfloat16 else 3e-4
-    for a, b in zip((got[0],) + got[2:], (o_ref,) + vjp(do)):
+    for a, b in zip((got[0],) + got[2:], jax.jit(ref_and_vjp)(q, k, v, do)):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=tol, rtol=tol)

@@ -153,16 +153,22 @@ def _model(**changes):
         "bd_mask_id": MASK_ID, **changes})
 
 
+def _training(model, params, tokens, noisy, weights):
+    """The training call's outputs, one program (a new one a call: the
+    model is traced under what the test has patched by then)."""
+    return jax.jit(lambda params: model.apply(
+        {"params": params}, tokens, training=True, noisy=noisy,
+        weights=weights))(params)
+
+
 @pytest.fixture(scope="module")
 def trained():
     tokens = _tokens(5)
     model = _model()
-    params = model.init(jax.random.PRNGKey(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(1), tokens)["params"]
     noisy, weights = bd.noise(
         jax.random.PRNGKey(2), tokens, BLOCK, MASK_ID, 1e-3)
-    outputs = model.apply(
-        {"params": params}, tokens, training=True, noisy=noisy,
-        weights=weights)
+    outputs = _training(model, params, tokens, noisy, weights)
     return model, params, tokens, noisy, weights, outputs
 
 
@@ -204,11 +210,14 @@ def test_the_one_pass_is_the_definition_block_by_block(trained):
     block b]`` gives block b's logits of the one 2 L pass."""
     _, params, tokens, noisy, _, outputs = trained
     assert outputs["logits"].shape == (2, LENGTH, VOCAB)
+    # a program a length: eight, each the whole definition
+    definition = jax.jit(
+        lambda sequence: Definition().apply({"params": params}, sequence))
     for b in range(LENGTH // BLOCK):
         lo, hi = b * BLOCK, (b + 1) * BLOCK
         sequence = jnp.concatenate(
             [tokens[:, :lo], noisy[:, lo:hi]], axis=1)
-        want = Definition().apply({"params": params}, sequence)[:, lo:hi]
+        want = definition(sequence)[:, lo:hi]
         np.testing.assert_allclose(
             outputs["logits"][:, lo:hi], want, atol=2e-5, rtol=2e-5)
 
@@ -222,17 +231,19 @@ def test_the_training_outputs_and_the_eval_surface(trained):
     assert float(outputs["routing"]["load_mean"]) == (
         2 * 2 * LENGTH * FIELDS["top_k"] / FIELDS["num_experts"])
     # the same noise passed to an eval call: bare logits, the same ones
-    logits = model.apply(
-        {"params": params}, tokens, noisy=noisy, weights=weights)
+    logits = jax.jit(lambda params: model.apply(
+        {"params": params}, tokens, noisy=noisy, weights=weights))(params)
     np.testing.assert_allclose(logits, outputs["logits"], atol=1e-6)
     # an eval call that brings none is a function of its tokens
-    a = model.apply({"params": params}, tokens)
-    np.testing.assert_array_equal(a, model.apply({"params": params}, tokens))
+    bare = jax.jit(lambda params: model.apply({"params": params}, tokens))
+    a = bare(params)
+    np.testing.assert_array_equal(a, bare(params))
     assert a.shape == (2, LENGTH, VOCAB)
     # a training call draws from the stream it is handed, and says what
-    drawn, sown = model.apply(
+    drawn, sown = jax.jit(lambda params: model.apply(
         {"params": params}, tokens, training=True,
-        rngs={"noise": jax.random.PRNGKey(9)}, mutable=["intermediates"])
+        rngs={"noise": jax.random.PRNGKey(9)},
+        mutable=["intermediates"]))(params)
     assert set(drawn["noise"]) == {"masked_share", "mean_t", "weight_mean"}
     key = sown["intermediates"]["noise_key"][0]
     want = bd.noise(key, tokens, BLOCK, MASK_ID, 1e-3)
@@ -249,9 +260,7 @@ def test_the_mask_and_the_positions_matter(trained):
     real = F.BlockDiffusion
 
     def run():
-        return model.apply(
-            {"params": params}, tokens, training=True, noisy=noisy,
-            weights=weights)["logits"]
+        return _training(model, params, tokens, noisy, weights)["logits"]
 
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(F, "BlockDiffusion", lambda half, block: F.CAUSAL)
@@ -298,7 +307,9 @@ def test_the_step_hands_the_model_a_noise_stream_folded_from_the_step():
     tokens = _tokens(6)
     model = _model()
     tx = moe_transformer.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    new_state = jax.jit(lambda: create_train_state(
+        model, tx, jax.random.PRNGKey(0), tokens))
+    state = new_state()
     step = jax.jit(make_train_step(
         model, moe_transformer.loss, tx, health=True))
     batch = {"features": tokens, "labels": tokens,
@@ -313,7 +324,7 @@ def test_the_step_hands_the_model_a_noise_stream_folded_from_the_step():
         seen.append(float(scalars["noise"]["mean_t"]))
     # new noise every step, the same noise for the same step
     assert len(set(seen)) == 3
-    again = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    again = new_state()
     _, _, scalars = step(again, batch)
     assert float(scalars["noise"]["mean_t"]) == seen[0]
     # without the health scalars the step returns what it always did
@@ -333,7 +344,8 @@ def _next_token_step_jaxpr():
                                     remat_policy="dots"))
     tokens = _tokens(7)
     tx = moe_transformer.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    state = jax.jit(lambda: create_train_state(
+        model, tx, jax.random.PRNGKey(0), tokens))()
     batch = {"features": tokens, "labels": tokens,
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = make_train_step(

@@ -445,18 +445,32 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
                     pass  # torn tail (SIGKILL mid-write) is expected
         return ops
 
-    # the in-process worker outlives the finished master by its whole
-    # get_task retry budget before concluding job-over; trim the
-    # default 120 s tail while still covering a cold master relaunch
-    # (python + jax imports take tens of seconds on a loaded CI box)
+    def done_ops():
+        return [op for op in journal_ops() if op["op"] == "done"]
+
+    # one patience for the whole test, under tests/conftest.py's LIMIT:
+    # whichever wait runs it out fails with its own message, not the
+    # limit's
+    patience = time.time() + 200
+
+    def left():
+        return max(1.0, patience - time.time())
+
+    # what the worker's get_task retries wait for is the relaunched
+    # master answering, however long python and jax take to import on a
+    # loaded machine: through the outage the budget is the test's own
+    # patience, no stopwatch on the relaunch. Once the worker has
+    # reported to the new master it is trimmed (below): the in-process
+    # worker outlives the finished master by its whole budget before
+    # concluding job-over
     from elasticdl_tpu.worker import master_client as mc_module
 
-    monkeypatch.setattr(mc_module, "MASTER_RETRY_BUDGET_SECS", 60.0)
+    monkeypatch.setattr(mc_module, "MASTER_RETRY_BUDGET_SECS", left())
 
     master = spawn_master("first")
     runner = None
     try:
-        _wait_port(master_port)
+        _wait_port(master_port, timeout=min(90, left()))
         mc = MasterClient("localhost:%d" % master_port, worker_id=0)
         mc.reset_worker()
         worker = Worker(
@@ -472,23 +486,34 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
         # let the job make real progress, then kill the master cold
         # while tasks are still in flight (mid-epoch by construction:
         # 16 tasks over 2 epochs, we kill before 8 are done)
-        deadline = time.time() + 120
+        deadline = time.time() + min(120, left())
         while time.time() < deadline:
-            done = [op for op in journal_ops() if op["op"] == "done"]
+            done = done_ops()
             if len(done) >= 3:
                 break
             time.sleep(0.1)
         assert len(done) >= 3, "job made no progress before the kill"
         master.send_signal(signal.SIGKILL)
         master.wait(timeout=30)
-        time.sleep(1.0)  # the worker is now inside the outage window
+        killed_at = len(done_ops())
+        # the worker is inside the outage window from here until the
+        # relaunch has imported what a master imports: seconds
 
         master = spawn_master("relaunch")
-        _wait_port(master_port)  # a bind failure surfaces here, loudly
+        # a bind failure surfaces here, loudly
+        _wait_port(master_port, timeout=left())
+        # a task done beyond those the first master saw: the worker's
+        # retries reached the new one and it re-registered. Calls from
+        # here on meet a master that answers until the job is over
+        deadline = time.time() + left()
+        while (time.time() < deadline and master.poll() is None
+               and len(done_ops()) <= killed_at):
+            time.sleep(0.1)
+        monkeypatch.setattr(mc_module, "MASTER_RETRY_BUDGET_SECS", 20.0)
         # the relaunched master replays the journal, serves the rest of
         # the job, and exits 0 when the dispatcher reports finished
         try:
-            rc = master.wait(timeout=240)
+            rc = master.wait(timeout=left())
         except subprocess.TimeoutExpired:
             master.kill()
             raise AssertionError(
@@ -500,7 +525,7 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
             % open(str(tmp_path / "master-relaunch.log")).read()[-4000:]
         )
         # the worker exits after its retry budget concludes job-over
-        runner.join(timeout=120)
+        runner.join(timeout=left() + 20)
         assert not runner.is_alive(), "worker never finished"
     finally:
         if master.poll() is None:

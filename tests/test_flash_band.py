@@ -1,17 +1,16 @@
 """The band as a mask layout (``ops/flash_attention.py:Band``):
 ``keep`` against the dense mask, the pair classifier and the grid of
 runs against brute force with the window under, at and over a block
-and no multiple of one, the kernels in interpret mode against dense
-masked softmax at groups 6 and 8 and, bit for bit, against the walk
-over the whole rectangle the band had before its grid was its own
-length (``RectangleBand``, kept here alone), the counts of the cell,
-the attention line under the benchmark's own regular expression, the
-refusals by name, the kernels' names, and the older layouts' programs
-against what they were (``tests/test_mask_layouts.py`` is the
-protocol's own file; ``tests/test_block_diffusion.py`` pins the
-diagonal's kernels at the cells' shapes)."""
+and no multiple of one, the counts of the cell, the attention line
+under the benchmark's own regular expression, the refusals by name, the
+kernels' names, and the older layouts' programs against what they were
+(``tests/test_mask_layouts.py`` is the protocol's own file;
+``tests/test_block_diffusion.py`` pins the diagonal's kernels at the
+cells' shapes). The kernels in interpret mode are
+``tests/test_flash_band_kernels.py``'s (against dense masked softmax)
+and ``tests/test_flash_band_grid.py``'s (against the rectangle's
+walk)."""
 
-import dataclasses
 import hashlib
 import re
 
@@ -24,21 +23,8 @@ from benchmark.lib import window_trace
 from elasticdl_tpu.models import transformer as T
 from elasticdl_tpu.ops import attention as A
 from elasticdl_tpu.ops import flash_attention as F
-from elasticdl_tpu.ops.attention import (
-    _flash_facts,
-    _pallas_refusal,
-    dot_product_attention,
-    xla_attention,
-)
-from tests.test_mask_layouts import _changes, _qkv, _value_and_grads
-
-
-def dense_band(seq, window):
-    """ISSUE 42's equation, position by position, in numpy: a query
-    sees itself and the ``window - 1`` keys before it."""
-    pos = np.arange(seq)
-    return (pos[None, :] <= pos[:, None]) & (
-        pos[:, None] - pos[None, :] < window)
+from elasticdl_tpu.ops.attention import _flash_facts, _pallas_refusal
+from tests.kernel_common import dense_band
 
 
 # (seq, window, block_q, block_k)
@@ -395,186 +381,6 @@ def test_the_benchmark_s_reader_takes_the_line(blocks, monkeypatch):
     want = {None: 43.79, (1024, 1024): 25.2, (512, 512): 50.0}
     if blocks in want:
         assert round(window_trace.fill(line), 2) == want[blocks]
-
-
-# (seq, window, heads, kv heads, width, block_q, block_k, dtype)
-KERNEL_CASES = {
-    "group-6-float32": (512, 100, 6, 1, 64, 128, 128, jnp.float32),
-    "group-8-bfloat16": (512, 128, 8, 1, 64, 128, 256, jnp.bfloat16),
-    "group-8-256-128": (512, 200, 16, 2, 32, 256, 128, jnp.float32),
-    "group-1-window-over-a-block": (512, 300, 2, 2, 64, 128, 128,
-                                    jnp.float32),
-    "group-6-window-of-one": (256, 1, 6, 1, 32, 128, 128, jnp.float32),
-    "window-of-one-128-256": (512, 1, 2, 1, 32, 128, 256, jnp.float32),
-    "window-is-the-sequence": (512, 512, 2, 2, 32, 128, 128, jnp.float32),
-    "window-over-the-sequence": (512, 5000, 8, 1, 32, 256, 128,
-                                 jnp.float32),
-    "group-8-window-is-a-block": (768, 128, 8, 1, 32, 128, 128,
-                                  jnp.bfloat16),
-    "group-8-two-kv-heads-128-256": (512, 130, 16, 2, 32, 128, 256,
-                                     jnp.float32),
-}
-
-
-@pytest.mark.parametrize("schedule", ["fused", "split"])
-@pytest.mark.parametrize(
-    "case", list(KERNEL_CASES.values()), ids=list(KERNEL_CASES))
-def test_flash_under_the_band_is_dense_masked_softmax(
-        case, schedule, monkeypatch):
-    """Forward and the three gradients of the kernels in interpret mode
-    against softmax over the dense mask built from the equation (not
-    from the layout), under both backward schedules."""
-    seq, window, heads, kv_heads, dim, block_q, block_k, dtype = case
-    if schedule == "split":
-        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
-    layout = F.Band(window)
-    q, k, v, do = _qkv(seq, heads, kv_heads, dim, dtype)
-    kept = jnp.asarray(dense_band(seq, window))
-
-    def dense(q, k, v):
-        group = heads // kv_heads
-        k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * dim ** -0.5
-        p = jax.nn.softmax(jnp.where(kept, s, -jnp.inf), axis=-1)
-        return jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v)
-
-    got = _value_and_grads(
-        lambda q, k, v: F.flash_attention(
-            q, k, v, mask=layout, block_q=block_q, block_k=block_k,
-            interpret=True), q, k, v, do)
-    want = _value_and_grads(dense, q, k, v, do)
-    tol = 5e-2 if dtype == jnp.bfloat16 else 3e-4
-    for a, b in zip(got, want):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            atol=tol, rtol=tol)
-    # and the XLA path builds its dense mask from the same layout
-    xla = _value_and_grads(
-        lambda q, k, v: dot_product_attention(
-            q, k, v, mask=layout, impl="xla"), q, k, v, do)
-    for a, b in zip(xla, want):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            atol=tol, rtol=tol)
-
-
-@dataclasses.dataclass(frozen=True)
-class RectangleBand:
-    """The band as it walked before its grid was its own length (PR 42):
-    the whole (q-block, k-block) rectangle, a step outside a row's
-    (column's) run clamped to the run's nearer end. The kernels take it
-    down the rectangle's path, ``Causal``'s and ``BlockDiffusion``'s;
-    nothing but these tests builds one."""
-
-    window: int
-
-    def __str__(self):
-        return "rectangle_window(%d)" % self.window
-
-    def keep(self, q_pos, k_pos):
-        return F.Band(self.window).keep(q_pos, k_pos)
-
-    def pair(self, q_block, k_block, block_q, block_k):
-        return F.Band(self.window).pair(q_block, k_block, block_q, block_k)
-
-    def k_named(self, q_block, k_block, block_q, block_k):
-        first, last = F.Band(self.window).run(q_block, block_q, block_k)
-        return jnp.clip(k_block, first, last)
-
-    def q_named(self, q_block, k_block, block_q, block_k, num_q):
-        first, last = F.Band(self.window).run(
-            k_block, block_q, block_k, k_outer=True)
-        return jnp.minimum(jnp.clip(q_block, first, last), num_q - 1)
-
-    def refusal(self, seq_q, seq_k, block_q, block_k):
-        return ""
-
-
-def _outputs(layout, case, q, k, v, do):
-    """(o, lse, dq, dk, dv) of the kernels in interpret mode."""
-    _, _, heads, kv_heads, dim, block_q, block_k, _ = case
-    merge = lambda t: t.reshape((-1,) + t.shape[2:])
-    o, lse = F._fwd(merge(q), merge(k), merge(v), dim ** -0.5, layout,
-                    block_q, block_k, True)
-    dq, dk, dv = F._bwd(
-        merge(q), merge(k), merge(v), o, lse, merge(do), dim ** -0.5,
-        layout, block_q, block_k, True)
-    return o, lse, dq, dk, dv
-
-
-@pytest.mark.parametrize("schedule", ["fused", "split"])
-@pytest.mark.parametrize(
-    "case", list(KERNEL_CASES.values()), ids=list(KERNEL_CASES))
-def test_the_run_grid_has_the_rectangle_s_bits(case, schedule, monkeypatch):
-    """At equal tiles the grid of runs computes the tiles the rectangle
-    computed, in its order: o, lse and the three gradients are the
-    parent's walk's to the last bit, under both backward schedules."""
-    seq, window, heads, kv_heads, dim, _, _, dtype = case
-    if schedule == "split":
-        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
-    q, k, v, do = _qkv(seq, heads, kv_heads, dim, dtype)
-    got = _outputs(F.Band(window), case, q, k, v, do)
-    want = _outputs(RectangleBand(window), case, q, k, v, do)
-    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
-        assert a.dtype == b.dtype and a.shape == b.shape
-        np.testing.assert_array_equal(
-            np.asarray(a, np.float32), np.asarray(b, np.float32), name)
-
-
-@pytest.mark.parametrize("lit", [0, 2, 3], ids=["first", "inner", "last"])
-@pytest.mark.parametrize("schedule", ["fused", "split"])
-def test_a_gradient_from_one_q_block_reaches_its_rows_alone(
-        lit, schedule, monkeypatch):
-    """A backward whose ``do`` is zero but for one q-block, in the
-    second of two heads that share dq's accumulator (the first leaves
-    every row of it dirty): dq is exactly zero outside that block's
-    rows, so every other row was zeroed and rounded out, and dense
-    masked softmax's inside them; dk and dv are the dense ones."""
-    seq, window, dim, block = 512, 200, 32, 128
-    if schedule == "split":
-        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
-    q, k, v, do = _qkv(seq, 2, 2, dim, jnp.float32)
-    rows = slice(lit * block, (lit + 1) * block)
-    do = do.at[:, 1].set(0.0).at[:, 1, rows].set(do[:, 1, rows])
-    layout = F.Band(window)
-    got = _value_and_grads(
-        lambda q, k, v: F.flash_attention(
-            q, k, v, mask=layout, block_q=block, block_k=block,
-            interpret=True), q, k, v, do)
-    want = _value_and_grads(
-        lambda q, k, v: xla_attention(q, k, v, mask=layout), q, k, v, do)
-    dq = np.asarray(got[1])
-    dark = np.ones(seq, bool)
-    dark[rows] = False
-    assert np.abs(dq[:, 0]).min(axis=-1).max() > 0
-    np.testing.assert_array_equal(dq[:, 1, dark], 0.0)
-    assert np.abs(dq[:, 1, rows]).max() > 1e-3
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, atol=3e-4, rtol=3e-4)
-
-
-def test_a_causal_mask_in_the_band_s_place_is_another_function():
-    q, k, v, _ = _qkv(512, 2, 2, 64, jnp.float32)
-    band = xla_attention(q, k, v, mask=F.Band(64))
-    causal = xla_attention(q, k, v, causal=True)
-    assert float(jnp.abs(band - causal).max()) > 0.1
-    # and a window that holds the whole prefix is the causal mask
-    np.testing.assert_allclose(
-        xla_attention(q, k, v, mask=F.Band(512)), causal, atol=1e-6)
-
-
-def test_the_flash_policy_names_the_band_call_s_outputs():
-    """``remat_block``'s ``flash`` policy saves ``flash_out`` /
-    ``flash_lse``: the band's call names its outputs so too."""
-    q = jnp.zeros((1, 2, 256, 64), jnp.float32)
-    text = str(jax.make_jaxpr(lambda q: F.flash_attention(
-        q, q, q, mask=F.Band(64), block_q=128, block_k=128,
-        interpret=True))(q))
-    assert "name=" + F.FLASH_OUT_NAME in text
-    assert "name=" + F.FLASH_LSE_NAME in text
-    assert "flash_band_fwd" in text and "name=flash_fwd" not in text
 
 
 def _sha(text):

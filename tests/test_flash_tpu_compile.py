@@ -7,35 +7,19 @@ hold one forward and one backward kernel, both named ``flash...``
 (``benchmark/metrics/flash_time_share.py`` finds them by that word),
 and a shape over the budget must compile to the split pair.
 
+The gated delta rule's kernels for the same described chip are
+``tests/test_gated_delta_tpu_compile.py``'s.
+
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
-
-import functools
-import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.observability import device as device_obs
 from elasticdl_tpu.ops import flash_attention as F
-
-
-@pytest.fixture(scope="module")
-def topology():
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever says "no compiler"
-        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
-
-
-@pytest.fixture(scope="module")
-def chip(topology):
-    return SingleDeviceSharding(topology.devices[0])
+from tests.kernel_common import chip, topology  # noqa: F401 (fixtures)
 
 
 def flash_kernels(chip, shape):
@@ -219,208 +203,3 @@ def test_the_band_s_grid_of_runs_compiles(
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile().as_text()
     assert device_obs.pallas_kernels(hlo) == kernels
-
-
-def _square_float32_dots(hlo, size=64):
-    """The dots of a compiled program whose operands and result are all
-    float32 [..., size, size]: the product form's and its VJP's."""
-    types = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", hlo))
-    square = re.compile(r"f32\[(?:\d+,)*%d,%d\]$" % (size, size))
-    found = []
-    for out, operands in re.findall(
-            r"= (\w+\[[\d,]*\])\S* convolution\(([^)]*)\)", hlo):
-        names = re.findall(r"%([\w.\-]+)", operands)
-        if all(square.match(t) for t in
-               [out] + [types.get(n, "") for n in names]):
-            found.append(out)
-    return found
-
-
-@pytest.mark.parametrize("impl", ["xla", "pallas", "inverse"])
-def test_the_chunked_rule_compiles_at_the_cell_s_shape(
-        chip, monkeypatch, impl):
-    """``gated_delta_rule``'s gradient at 32,768 tokens, 16 key and 32
-    value heads of 128, chunk 64, for a described v5e: the segments'
-    ``jax.checkpoint`` keeps its temporaries under 2.5 GB (4 GB and a
-    refused step without it, PERF.md Section 6). With the backend a TPU
-    (``pallas``: what the chip gets, ISSUE 32, 34 and 39) a segment is
-    four kernels, the chunks' operands with the inverses in them and the
-    chunk-to-chunk scan, every ``tpu_custom_call`` named, no float32 64 x
-    64 dot, no 64 x 64 float32 array of the chunks' and no loop over a
-    segment's chunks is left outside them, and the VMEM they ask for is
-    under the limits they state. ``inverse``: the same with the
-    operands' chooser held to XLA's lines, PR 34's program, in which
-    the inverses are kernels of their own (their operands row-major, 64
-    lanes padded to 128, where XLA kept some of its own matrices with
-    the chunks on the lanes: the rule alone reads 2.67 GiB for 2.45)."""
-    from elasticdl_tpu.ops import gated_delta
-
-    if impl != "xla":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if impl == "inverse":
-        monkeypatch.setattr(
-            gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    chosen = "xla" if impl == "xla" else "pallas"
-    assert gated_delta.inverse_impl(jnp.float32, 64) == chosen
-    assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == chosen
-    assert gated_delta.prepare_impl(
-        jnp.bfloat16, 64, 128, 128, 2, 128) == (
-            "pallas" if impl == "pallas" else "xla")
-    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=chip)
-    args = (
-        struct((1, 16, 32768, 128), jnp.bfloat16),
-        struct((1, 16, 32768, 128), jnp.bfloat16),
-        struct((1, 32, 32768, 128), jnp.bfloat16),
-        struct((1, 32, 32768), jnp.float32),
-        struct((1, 32, 32768), jnp.float32),
-    )
-    compiled = jax.jit(jax.grad(
-        lambda *a: gated_delta.gated_delta_rule(*a).astype(
-            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4))
-    ).lower(*args).compile()
-    hlo = compiled.as_text()
-    assert "while" in hlo
-    temporaries = compiled.memory_analysis().temp_size_in_bytes
-    if impl == "xla":
-        assert temporaries < 2.5 * 2**30
-        assert "tpu_custom_call" not in hlo
-        # the step's forward, the segment's again, and the VJP's two
-        assert len(_square_float32_dots(hlo)) == 22
-        return
-    # the forward in the step and in the segment's recompute; a kernel
-    # inside a loop body counts once
-    if impl == "pallas":
-        # four padded float32 matrix arrays a segment fewer, ``T`` at
-        # half its padded size
-        assert temporaries < 2.3 * 2**30
-        assert device_obs.pallas_kernels(hlo) == {
-            "gdn_prepare_fwd": 2, "gdn_prepare_bwd": 1,
-            "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
-        assert not re.search(r"f32\[[\d,]*,64,64\]", hlo)
-    else:
-        assert temporaries < 2.7 * 2**30
-        assert device_obs.pallas_kernels(hlo) == {
-            "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1,
-            "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
-    assert hlo.count("tpu_custom_call") == 6
-    assert not _square_float32_dots(hlo)
-    # the chunk-to-chunk recurrence is inside the scan's kernels (ISSUE
-    # 34): the two loops left are the forward's and the backward's over
-    # the four segments, none over a segment's 128 chunks
-    assert hlo.count(" while(") == 2
-    # the compiler held each kernel to the limit it states (it refuses
-    # a body that needs more), and the blocks of a segment's 4,096
-    # matrices and of a grid step's heads and chunks, operands and
-    # results double-buffered, count under it
-    calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
-    limits = {"gdn_scan": gated_delta._SCAN_VMEM_LIMIT,
-              "gdn_prepare": gated_delta._PREPARE_VMEM_LIMIT,
-              "gdn_inverse": gated_delta._INVERSE_VMEM_LIMIT}
-    for line in calls:
-        # the call's own name; its source locations name its callers
-        name, = device_obs.pallas_kernels(line)
-        assert '"size":"%d"' % limits[name[:name.rindex("_")]] in line
-    for arrays in (2, 3):
-        block = gated_delta.inverse_block(4096, 64, arrays)
-        assert gated_delta.inverse_vmem_bytes(
-            block, 64, arrays) < gated_delta._INVERSE_VMEM_LIMIT
-    for kind in ("fwd", "fwd_residuals", "bwd"):
-        block, step = gated_delta.scan_block(32, 128, 64, 128, 128, 2, kind)
-        assert gated_delta.scan_vmem_bytes(
-            block, step, 64, 128, 128, 2, kind) < gated_delta._SCAN_VMEM_LIMIT
-        step = gated_delta.prepare_block(2, 128, 64, 128, 128, 2)
-        assert gated_delta.prepare_vmem_bytes(
-            2, step, 64, 128, 128, 2, kind) < gated_delta._PREPARE_VMEM_LIMIT
-
-
-@pytest.mark.parametrize("chunk,rep,heads,chunks,dtype", [
-    (64, 2, 16, 128, "bfloat16"),   # the cell's segment
-    (128, 2, 16, 64, "bfloat16"),
-    (64, 1, 4, 16, "float32"),
-    (128, 1, 2, 3, "float32"),      # a block of all the chunks, no tile
-], ids=lambda v: str(v))
-def test_the_operands_kernels_compile(chip, chunk, rep, heads, chunks, dtype):
-    """``gdn_prepare_fwd`` (with and without ``T``) and
-    ``gdn_prepare_bwd`` alone for a described v5e: the lane-row
-    concatenations, the masked sums over lanes and rows, the one-row
-    loads of ``g`` and the transposed products are what the interpreter
-    never refuses."""
-    from elasticdl_tpu.ops import gated_delta
-
-    dtype = jnp.dtype(dtype)
-    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=chip)
-    args = (
-        struct((1, heads, 1, chunks, chunk, 128), dtype),
-        struct((1, heads, 1, chunks, chunk, 128), dtype),
-        struct((1, heads, rep, chunks, chunk, 128), dtype),
-        struct((1, heads, rep, chunks, chunk), jnp.float32),
-        struct((1, heads, rep, chunks, chunk), jnp.float32),
-    )
-    for residuals in (False, True):
-        hlo = jax.jit(functools.partial(
-            gated_delta.gdn_prepare_fwd, residuals=residuals)).lower(
-                *args).compile().as_text()
-        assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_fwd": 1}
-    outs = jax.eval_shape(functools.partial(
-        gated_delta.gdn_prepare_fwd, residuals=True), *args)
-    *operands, u, inverse = [struct(o.shape, o.dtype) for o in outs]
-    hlo = jax.jit(gated_delta.gdn_prepare_bwd).lower(
-        *args, inverse, *operands, struct(u.shape, dtype)
-    ).compile().as_text()
-    assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_bwd": 1}
-
-
-def test_the_chunked_rule_stays_partitionable_over_a_mesh(
-        topology, monkeypatch):
-    """A ``pallas_call`` has no GSPMD partitioning rule, and the rule
-    opens no ``shard_map``: with its inputs sharded by the batch over
-    the four chips of a described v5e:2x2 (data parallel or FSDP) the
-    inverses stay XLA's product form, and the gradient compiles with
-    nothing gathered. Told of no mesh the same call takes the kernels,
-    which jax refuses to partition. The model's layer hands the rule
-    its mesh."""
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    from elasticdl_tpu.models.transformer import (
-        GatedDeltaDims,
-        make_attention,
-    )
-    from elasticdl_tpu.ops import gated_delta
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    mesh = Mesh(np.array(topology.devices), ("data",))
-    assert gated_delta.inverse_impl(jnp.float32, 64, mesh) == "xla"
-    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=NamedSharding(mesh, P("data")))
-    args = (
-        struct((4, 2, 1024, 128), jnp.bfloat16),
-        struct((4, 2, 1024, 128), jnp.bfloat16),
-        struct((4, 4, 1024, 128), jnp.bfloat16),
-        struct((4, 4, 1024), jnp.float32),
-        struct((4, 4, 1024), jnp.float32),
-    )
-    grad = lambda mesh: jax.jit(jax.grad(
-        lambda *a: gated_delta.gated_delta_rule(*a, mesh=mesh).astype(
-            jnp.float32).sum(), argnums=(0, 1, 2, 3, 4)))
-    hlo = grad(mesh).lower(*args).compile().as_text()
-    assert "tpu_custom_call" not in hlo
-    assert "all-gather" not in hlo and "all-to-all" not in hlo
-    with pytest.raises(NotImplementedError, match="shard_map"):
-        grad(None).lower(*args)
-
-    layer = make_attention(4, linear=GatedDeltaDims(
-        num_key_heads=2, num_value_heads=4, key_head_dim=128,
-        value_head_dim=128, conv_kernel_dim=4), norm_eps=1e-6, mesh=mesh)
-    x = struct((4, 1024, 256), jnp.bfloat16)
-    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
-    hlo = jax.jit(jax.grad(
-        lambda v, x: layer.apply(v, x).astype(jnp.float32).sum())).lower(
-            jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(
-                    a.shape, a.dtype, sharding=NamedSharding(mesh, P())),
-                variables), x).compile().as_text()
-    assert "tpu_custom_call" not in hlo

@@ -1,8 +1,12 @@
 """``gdn_scan_fwd`` / ``gdn_scan_bwd`` (``ops/gated_delta.py``, ISSUE
 34) in interpret mode on the CPU: the chunk-to-chunk recurrence with the
-state in VMEM against the ``lax.scan`` over the same operands, and the
-rule by them against the per-token loop. Key and value widths of 128:
-the kernels take whole lane rows."""
+state in VMEM against the ``lax.scan`` over the same operands; where
+``scan_impl`` chooses them; what a grid step takes. The rule by them
+against the per-token loop is ``test_gated_delta_scan_rule.py``'s. Key
+and value widths of 128: the kernels take whole lane rows. An
+interpreted kernel costs by what its body unrolls (heads x chunks a
+grid step) and by the trace, so a case is as many chunks and heads as
+its assertion reads."""
 
 import functools
 import logging
@@ -16,36 +20,40 @@ from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.ops import gated_delta
-from elasticdl_tpu.ops.gated_delta import (
-    gated_delta_recurrence,
-    gated_delta_rule,
-)
+from elasticdl_tpu.ops.gated_delta import gated_delta_rule
 from tests.gdn_common import (  # noqa: F401
     _MANUAL,
     _MESH4,
     _force_pallas,
-    _inputs,
     _split_inputs,
-    _value_and_grads,
     x64,
 )
 
 
-def _segment_operands(chunk, rep, dtype, num=4, seed=0):
+def _segment_operands(chunk, rep, dtype, hk=2, num=2, seed=0):
     """A segment's operands as ``_chunk_operands`` builds them (batch 1,
-    2 key heads, ``num`` chunks) and a non-zero entering state."""
-    operands = gated_delta._chunk_operands(
-        *_split_inputs(num, chunk, rep, dtype, seed=seed), jnp.float32, "xla")
+    ``hk`` key heads, ``num`` chunks: two, one grid step, show the
+    state handed from a chunk to the next; the carry between grid steps
+    is the rule's file's) and a non-zero entering state."""
+    operands = jax.jit(lambda *a: gated_delta._chunk_operands(
+        *a, jnp.float32, "xla"))(
+            *_split_inputs(num, chunk, rep, dtype, hk=hk, seed=seed))
     state = 0.3 * jax.random.normal(
-        jax.random.PRNGKey(seed + 1), (1, 2, rep, 128, 128))
+        jax.random.PRNGKey(seed + 1), (1, hk, rep, 128, 128))
     return (state,) + operands
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("chunk,rep", [(64, 1), (64, 2), (128, 1), (128, 2)],
-                         ids=["64-rep1", "64-rep2", "128-rep1", "128-rep2"])
-def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, dtype):
+# the last column, the key heads: a block of two heads either way (two
+# key heads with a value head each, one with its two) but at the cell's
+# chunk and ratio, where two key heads with two each make a block of
+# four, key heads and their value heads both counted past one
+@pytest.mark.parametrize("chunk,rep,hk", [
+    (64, 1, 2), (64, 2, 2), (128, 1, 2), (128, 2, 1),
+], ids=["64-rep1", "64-rep2", "128-rep1", "128-rep2"])
+def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, hk,
+                                             dtype):
     """``O``, the leaving state and ``V'`` from a non-zero entering
     state, and the gradients of all six operands and the entering
     state's: in float32 equal to rounding; in bfloat16 the forward bit
@@ -53,7 +61,7 @@ def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, dtype):
     backward to the operands' rounding (the kernel sums ``dV'`` and
     ``dS`` in float32 and rounds once where autodiff rounds each
     term)."""
-    args = _segment_operands(chunk, rep, dtype)
+    args = _segment_operands(chunk, rep, dtype, hk)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[-1].shape)
 
     def outputs(carry):
@@ -61,8 +69,9 @@ def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, dtype):
             leaving, o = carry(*a, dtype)
             return ((o.astype(jnp.float32) * weight).sum()
                     + (leaving * leaving).sum())
-        return carry(*args, dtype) + jax.grad(
-            loss, argnums=tuple(range(7)))(*args)
+        # the call alone (no residuals kept) and the differentiated one
+        return jax.jit(lambda *a: carry(*a, dtype) + jax.grad(
+            loss, argnums=tuple(range(7)))(*a))(*args)
 
     want = outputs(gated_delta._scan_xla)
     _force_pallas(monkeypatch)
@@ -103,71 +112,6 @@ def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, dtype):
     np.testing.assert_allclose(
         np.float32(new_v[:, :, :, 0]), np.float32(first), rtol=0,
         atol=(1e-5 if exact else 1e-2) * float(jnp.abs(first).max()))
-
-
-@pytest.mark.parametrize("seq,chunk,segment,hk,hv", [
-    (512, 64, 128, 2, 4),   # one segment of eight chunks: two grid steps
-    (256, 64, 1, 2, 2),     # four segments, the state carried between
-    (300, 64, 2, 1, 2),     # a length the segment does not divide
-    (256, 128, 1, 1, 1),
-    (200, 128, 128, 2, 2),  # one segment, the chunk does not divide
-], ids=["512-64", "256-64-seg1", "300-64-seg2", "256-128-seg1", "200-128"])
-@pytest.mark.parametrize("prep", ["pallas", "xla"])
-def test_the_rule_by_the_scan_s_kernels(monkeypatch, seq, chunk, segment,
-                                        hk, hv, prep):
-    """``gated_delta_rule`` by the kernels against the ``lax.scan`` path
-    and against the per-token recurrence, float32: values and all five
-    gradients, over one and several segments and lengths that the chunk
-    or the segment does not divide. ``prep=pallas``: what a TPU chooses,
-    the operands' and the scan's kernels under one VJP; ``prep=xla``:
-    the scan's kernels after ``_chunk_operands`` with the inverses'
-    kernels in it (PR 34's program). Padded tokens write nothing: the
-    cut output and the gradients are the unpadded recurrence's."""
-    args = _inputs(seq, jnp.float32, decay=2.0, batch=1, hk=hk, hv=hv,
-                   dim=128)
-    rule = lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment)
-    by_xla = _value_and_grads(rule, args)
-    by_token = _value_and_grads(gated_delta_recurrence, args)
-    _force_pallas(monkeypatch)
-    if prep == "xla":
-        monkeypatch.setattr(
-            gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    text = str(jax.make_jaxpr(jax.grad(
-        lambda *a: rule(*a).sum(), argnums=(0, 1, 2, 3, 4)))(*args))
-    assert "gdn_scan_fwd" in text and "gdn_scan_bwd" in text
-    for name in ("gdn_prepare_fwd", "gdn_prepare_bwd"):
-        assert (name in text) == (prep == "pallas")
-    for name in ("gdn_inverse_fwd", "gdn_inverse_bwd"):
-        assert (name in text) == (prep == "xla")
-    got = _value_and_grads(rule, args)
-    for a, b, c in zip(got, by_xla, by_token):
-        assert a.shape == c.shape and a.dtype == c.dtype
-        scale = 1e-3 + float(jnp.abs(c).max())
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * scale)
-        np.testing.assert_allclose(a, c, rtol=0, atol=1e-4 * scale)
-
-
-def test_the_scan_s_kernels_hold_bfloat16_s_rounding(monkeypatch):
-    """The cell's dtypes: bfloat16 operands, float32 state and decay.
-    The kernels' output is the ``lax.scan``'s bit for bit, and their
-    gradients stay as close to the float32 recurrence's as its own."""
-    args = _inputs(256, jnp.float32, decay=2.0, batch=1, dim=128)
-    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    rule = lambda *a: gated_delta_rule(*a, chunk=64, segment=2)
-    want = _value_and_grads(gated_delta_recurrence, args)
-    by_xla = _value_and_grads(rule, low)
-    _force_pallas(monkeypatch)
-    # the scan's kernels after the XLA lines (the operands' kernels
-    # cumulate g in another order: their own test below)
-    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    got = _value_and_grads(rule, low)
-    assert got[0].dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        np.float32(got[0]), np.float32(by_xla[0]))
-    err = lambda a, b: float(jnp.sqrt(
-        jnp.mean((a.astype(jnp.float32) - b) ** 2) / jnp.mean(b ** 2)))
-    for a, b, c in zip(got[1:], by_xla[1:], want[1:]):
-        assert err(a, c) < 1.25 * err(b, c) + 1e-4
 
 
 @pytest.mark.parametrize(

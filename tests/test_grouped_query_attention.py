@@ -6,8 +6,8 @@ traced; and ``Attention`` with a head width of its own, 2 kv heads, a
 norm a head, partial rotary and a sigmoid output gate against its
 equations."""
 
+import functools
 import logging
-import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +23,7 @@ from elasticdl_tpu.models.transformer import (
 from elasticdl_tpu.ops import attention as attention_ops
 from elasticdl_tpu.ops import flash_attention as F
 from elasticdl_tpu.ops.attention import xla_attention
+from tests.kernel_common import traced_flash
 
 
 def _qkv(seq, heads, kv_heads, dim, dtype, seed=0):
@@ -41,6 +42,24 @@ def _value_and_grads(attention, q, k, v):
     return (out,) + grads
 
 
+@functools.lru_cache(maxsize=None)
+def _repeated(heads, kv_heads, dtype):
+    """(q, k, v, the XLA reference on k and v repeated over the group,
+    the XLA path on the unrepeated ones): neither knows of a backward
+    schedule, so once for both."""
+    q, k, v = _qkv(256, heads, kv_heads, 32, dtype)
+    group = heads // kv_heads
+
+    def repeated(q, k, v):
+        return xla_attention(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
+            causal=True)
+
+    return (q, k, v), jax.jit(functools.partial(
+        _value_and_grads, repeated))(q, k, v), jax.jit(functools.partial(
+            xla_attention, causal=True))(q, k, v)
+
+
 @pytest.mark.parametrize("schedule", ["fused", "split"])
 @pytest.mark.parametrize("heads,kv_heads,dtype", [
     (16, 2, jnp.float32),   # Qwen3-Next's 16 over 2
@@ -53,24 +72,14 @@ def test_flash_reads_kv_head_h_over_group(
     group; against the XLA reference on repeated k, v; causal."""
     if schedule == "split":
         monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
-    q, k, v = _qkv(256, heads, kv_heads, 32, dtype)
+    (q, k, v), want, unrepeated = _repeated(heads, kv_heads, dtype)
     flash = lambda q, k, v: F.flash_attention(
         q, k, v, causal=True, block_q=128, block_k=128, interpret=True)
-    names = sorted(set(re.findall(
-        r"name=(flash_(?:fwd|bwd|dq|dkv))\b", str(jax.make_jaxpr(
-            lambda *a: _value_and_grads(flash, *a))(q, k, v)))))
+    # one trace: the names are read from the program that runs
+    names, got = traced_flash(functools.partial(_value_and_grads, flash), (q, k, v))
     assert names == (
         ["flash_bwd", "flash_fwd"] if schedule == "fused"
         else ["flash_dkv", "flash_dq", "flash_fwd"])
-    got = _value_and_grads(flash, q, k, v)
-    group = heads // kv_heads
-
-    def repeated(q, k, v):
-        return xla_attention(
-            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1),
-            causal=True)
-
-    want = _value_and_grads(repeated, q, k, v)
     assert [g.shape[1] for g in got] == [heads, heads, kv_heads, kv_heads]
     tol = 6e-2 if dtype == jnp.bfloat16 else 3e-4
     for a, b in zip(got, want):
@@ -80,7 +89,7 @@ def test_flash_reads_kv_head_h_over_group(
             atol=tol, rtol=tol)
     # the XLA path takes the same unrepeated operands
     np.testing.assert_allclose(
-        np.asarray(xla_attention(q, k, v, causal=True), np.float32),
+        np.asarray(unrepeated, np.float32),
         np.asarray(want[0], np.float32), atol=1e-6)
 
 
@@ -136,7 +145,9 @@ def test_head_counts_must_divide():
 
 
 def test_the_attention_line_names_the_group_and_the_note(caplog):
-    q, k, v = _qkv(32768, 16, 2, 256, jnp.bfloat16)
+    # the line reads shapes and dtypes: the cell's, with no array made
+    q = jax.ShapeDtypeStruct((1, 16, 32768, 256), jnp.bfloat16)
+    k = v = jax.ShapeDtypeStruct((1, 2, 32768, 256), jnp.bfloat16)
     facts = attention_ops._flash_facts(q, k, v, True, None, None)
     # 32,768 x 256 is over the fused backward's budget: the pair
     assert facts.startswith(

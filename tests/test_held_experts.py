@@ -463,8 +463,8 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(case):
 def test_a_share_holds_its_experts_kernels_only():
     x = jnp.zeros((1, 8, 16))
     shapes = jax.tree_util.tree_map(
-        lambda a: a.shape,
-        _layer((4, 4)).init(jax.random.PRNGKey(0), x)["params"])
+        lambda a: a.shape, jax.eval_shape(
+            _layer((4, 4)).init, jax.random.PRNGKey(0), x)["params"])
     assert shapes["w_gate"] == shapes["w_up"] == (4, 16, 8)
     assert shapes["w_down"] == (4, 8, 16)
     assert shapes["router"]["kernel"] == (16, 16)  # all experts
@@ -474,8 +474,8 @@ def test_a_share_holds_its_experts_kernels_only():
 def test_held_counters_reach_the_model_s_routing():
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 12, 16))
     layer = _layer((0, 4), rows=8)
-    variables = layer.init(jax.random.PRNGKey(3), x)
-    _, aux = layer.apply(variables, x)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(3), x)
+    _, aux = jax.jit(layer.apply)(variables, x)
     routing = aux["routing"]
     held = float(routing["held"])
     assert float(routing["load_mean"]) == 24 * 3 / 16  # over all experts
@@ -564,8 +564,10 @@ def test_the_layer_kinds_are_a_pattern_with_a_period():
 def test_the_model_trains_and_reports_its_share():
     model = _small_lm()
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 48), 0, 97)
-    variables = model.init(jax.random.PRNGKey(0), tokens, training=False)
-    out = model.apply(variables, tokens, training=True)
+    variables = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), tokens, training=False))()
+    out = jax.jit(lambda variables: model.apply(
+        variables, tokens, training=True))(variables)
     assert out["logits"].shape == (2, 48, 97)
     assert set(out["routing"]) == {
         "load_max", "load_mean", "entropy", "dropped", "held", "rows_run",
@@ -575,14 +577,15 @@ def test_the_model_trains_and_reports_its_share():
     # four layers' buffers of 128 rows, and what the step ran of them
     assert float(out["routing"]["rows_buffer"]) == 4 * 128
     assert 0 < float(out["routing"]["rows_run"]) <= 4 * 128
-    grads = jax.grad(lambda p: moe_transformer.loss(
-        tokens, model.apply({"params": p}, tokens, training=True)).mean())(
+    grads = jax.jit(jax.grad(lambda p: moe_transformer.loss(
+        tokens, model.apply({"params": p}, tokens, training=True)).mean()))(
             variables["params"])
     for path, leaf in jax.tree_util.tree_leaves_with_path(grads):
         assert bool(jnp.isfinite(leaf).all()), path
         assert float(jnp.abs(leaf).max()) > 0, path
     # eval returns bare logits, as every model of the family
-    assert model.apply(variables, tokens).shape == (2, 48, 97)
+    assert jax.eval_shape(
+        lambda: model.apply(variables, tokens)).shape == (2, 48, 97)
 
 
 def test_no_parameter_of_the_new_model_falls_to_the_catch_all_rule():

@@ -45,17 +45,16 @@ def moved():
         {"hc": dict(params)}, jax.random.PRNGKey(7))["hc"]
 
 
-def sublayer(n, dtype, seq, move=None):
+@functools.lru_cache(maxsize=None)
+def sublayer(n, dtype, seq):
     """(f, its arguments): value, ``H_res`` and facts, and through
     ``jax.grad`` every gradient, of ``sum(target . X')`` for the
     hyper-connected sublayer ``X' = write(F(u))`` with ``F(u) = tanh(u
-    W)``."""
+    W)``. Made once a shape: the cases differ in where the gates are."""
     x = jax.random.normal(
         jax.random.PRNGKey(1), (2, n, seq, DIM), jnp.float32).astype(dtype)
     module = T.HyperConnection(T.HyperDims(n), select=1)
-    params = module.init(jax.random.PRNGKey(0), x)["params"]
-    if move is not None:
-        params = move(params)
+    params = jax.jit(module.init)(jax.random.PRNGKey(0), x)["params"]
     w = jax.random.normal(
         jax.random.PRNGKey(5), (DIM, DIM), jnp.float32) / DIM ** 0.5
     target = jax.random.normal(jax.random.PRNGKey(6), x.shape, jnp.float32)
@@ -71,6 +70,18 @@ def sublayer(n, dtype, seq, move=None):
     return f, (params, x, w)
 
 
+@functools.lru_cache(maxsize=None)
+def both_ways(n, dtype, seq):
+    """The sublayer's value and gradients as two programs of their own:
+    a case calls (and so traces) the first before ``force_pallas`` and
+    the second after it, and the case with the gates moved runs at the
+    same shapes what the first one compiled."""
+    f, _ = sublayer(n, dtype, seq)
+    program = lambda: jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))
+    return program(), program()
+
+
 def worst(got, want):
     """The largest difference over the largest wanted magnitude."""
     got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
@@ -84,15 +95,17 @@ def worst(got, want):
 @pytest.mark.parametrize("n", [2, 4])
 def test_the_composed_sublayer_is_the_module_s_lines(
         monkeypatch, moved, n, dtype, seq, gates):
-    f, args = sublayer(n, dtype, seq, moved if gates == "moved" else None)
-    run = lambda: jax.jit(jax.value_and_grad(
-        f, argnums=(0, 1, 2), has_aux=True))(*args)
+    _, (params, x, w) = sublayer(n, dtype, seq)
+    if gates == "moved":
+        params = moved(params)
+    by_xla, by_pallas = both_ways(n, dtype, seq)
     assert H.mix_impl(dtype, n, DIM, seq) == "xla"
-    (_, (want_out, want_u, want_res, want_facts)), want_grads = run()
+    (_, (want_out, want_u, want_res, want_facts)), want_grads = by_xla(
+        params, x, w)
     force_pallas(monkeypatch)
     assert H.mix_impl(dtype, n, DIM, seq) == "pallas"
     # (the value is a sum of cancelling terms: ``out`` is its check)
-    (_, (out, u, h_res, facts)), grads = run()
+    (_, (out, u, h_res, facts)), grads = by_pallas(params, x, w)
     # float32: summation order; bfloat16: a last bit of the streams'
     # dtype where a sum rounds the other way
     tight, loose = (2e-5, 2e-5) if dtype == jnp.float32 else (1e-5, 2e-2)

@@ -18,7 +18,7 @@
 model against ``reference.py`` and the three wrong variants.
 """
 
-import re
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from elasticdl_tpu.ops.attention import xla_attention
 from elasticdl_tpu.train.optimizers import create_optimizer
 from elasticdl_tpu.train.step_fns import make_train_step
 from elasticdl_tpu.train.train_state import create_train_state
+from tests.kernel_common import traced_flash
 
 DIMS = LatentDims(
     kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16)
@@ -61,6 +62,16 @@ def _value_and_grads(attention, q, k, v):
     return (out,) + grads
 
 
+@functools.lru_cache(maxsize=None)
+def _xla_reference(widths, dtype):
+    """(q, k, v, the XLA attention's forward and gradients): it knows
+    of no backward schedule, so once for both."""
+    q, k, v = _qkv(512, *widths, dtype)
+    return (q, k, v), jax.jit(functools.partial(
+        _value_and_grads, functools.partial(xla_attention, causal=True)))(
+            q, k, v)
+
+
 @pytest.mark.parametrize("schedule", ["fused", "split"])
 @pytest.mark.parametrize("widths,dtype,blocks", [
     ((192, 128), jnp.float32, (128, 128)),   # MLA's widths
@@ -74,19 +85,15 @@ def test_flash_with_two_head_widths_matches_xla(
     dk, dv against the XLA reference, causal, interpret mode."""
     if schedule == "split":
         monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", 0)
-    q, k, v = _qkv(512, *widths, dtype)
+    (q, k, v), want = _xla_reference(widths, dtype)
     flash = lambda q, k, v: F.flash_attention(
         q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1],
         interpret=True)
-    names = sorted(set(re.findall(
-        r"name=(flash_(?:fwd|bwd|dq|dkv))\b", str(jax.make_jaxpr(
-            lambda *a: _value_and_grads(flash, *a))(q, k, v)))))
+    # one trace: the names are read from the program that runs
+    names, got = traced_flash(functools.partial(_value_and_grads, flash), (q, k, v))
     assert names == (
         ["flash_bwd", "flash_fwd"] if schedule == "fused"
         else ["flash_dkv", "flash_dq", "flash_fwd"])
-    got = _value_and_grads(flash, q, k, v)
-    want = _value_and_grads(
-        lambda q, k, v: xla_attention(q, k, v, causal=True), q, k, v)
     assert [g.shape[-1] for g in got] == [
         widths[1], widths[0], widths[0], widths[1]]
     tol = 6e-2 if dtype == jnp.bfloat16 else 3e-4
@@ -153,7 +160,7 @@ def test_latent_attention_against_its_equations():
     layer = LatentAttention(
         4, DIMS, attention_impl="xla", rope_theta=50000.0, norm_eps=1e-5)
     x = jnp.asarray(np.random.RandomState(0).randn(2, 64, 48), jnp.float32)
-    variables = layer.init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
     p = jax.tree_util.tree_map(np.asarray, variables["params"])
     shapes = {
         "/".join(k.key for k in path): leaf.shape
@@ -162,7 +169,7 @@ def test_latent_attention_against_its_equations():
         "q_proj/kernel": (48, 4, 24), "kv_down/kernel": (48, 40),
         "kv_norm/scale": (32,), "kv_up/kernel": (32, 4, 32),
         "out_proj/kernel": (4, 16, 48)}
-    got = np.asarray(layer.apply(variables, x))
+    got = np.asarray(jax.jit(layer.apply)(variables, x))
 
     xs = np.asarray(x, np.float64)
     q = np.einsum("bsd,dhk->bhsk", xs, p["q_proj"]["kernel"])
@@ -188,7 +195,8 @@ def test_latent_attention_against_its_equations():
 def test_latent_attention_refuses_what_it_does_not_build():
     x = jnp.zeros((1, 128, 48))
     with pytest.raises(ValueError, match="one device's sequence"):
-        LatentAttention(4, DIMS, attention_impl="ring").init(
+        jax.eval_shape(
+            LatentAttention(4, DIMS, attention_impl="ring").init,
             jax.random.PRNGKey(0), x)
     with pytest.raises(ValueError, match="no qk_norm"):
         transformer.make_attention(4, DIMS, qk_norm=True)
@@ -203,7 +211,9 @@ def test_the_scopes_are_in_the_lowered_step():
     ``moe/shared``: the names the per-layer metrics read."""
     model = _small_lm()
     tokens = jnp.zeros((2, 128), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    # lowering reads shapes and dtypes: no parameter is initialised
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens))
     text = jax.jit(
         lambda v, t: model.apply(v, t, training=False)
     ).lower(variables, tokens).as_text(debug_info=True)
@@ -299,7 +309,9 @@ def _small_lm(**changes):
 def test_the_first_k_blocks_are_dense_and_the_rest_share_experts():
     model = _small_lm()
     tokens = jnp.zeros((2, 128), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    # names, shapes and dtypes are read: nothing is drawn
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens))
     params = variables["params"]
     assert set(params["block_0"]) == {
         "attn", "ln_attn", "ln_mlp", "mlp_gate", "mlp_up", "mlp_down"}
@@ -327,8 +339,8 @@ def test_the_embedding_is_drawn_at_the_stated_deviation(std, want):
     that default a seeded model's routers all see the context's mean
     (PERF.md Section 6, PR 29); the zoo of Moonlight states 1."""
     model = _small_lm(embed_init_std=std)
-    params = model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))["params"]
+    params = jax.jit(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32)))()["params"]
     assert float(params["wte"]["embedding"].std()) == pytest.approx(
         want, rel=0.02)
     # nothing else is drawn differently
@@ -342,11 +354,11 @@ def test_shared_experts_are_added_to_the_routed_output():
         top_k=3, dispatch_impl="sorted", expert_dim=32, expert_act="swiglu",
         scoring="sigmoid", gate_scale=2.446)
     with_shared = MoeMlp(8, shared_experts=2, **common)
-    variables = with_shared.init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(with_shared.init)(jax.random.PRNGKey(0), x)
     params = dict(variables["params"])
-    y_both, _ = with_shared.apply({"params": params}, x)
+    y_both, _ = jax.jit(with_shared.apply)({"params": params}, x)
     routed = {k: v for k, v in params.items() if not k.startswith("shared")}
-    y_routed, _ = MoeMlp(8, **common).apply({"params": routed}, x)
+    y_routed, _ = jax.jit(MoeMlp(8, **common).apply)({"params": routed}, x)
     gate, up, down = (
         params["shared_" + n]["kernel"] for n in ("gate", "up", "down"))
     shared = (jax.nn.silu(x @ gate) * (x @ up)) @ down
@@ -376,14 +388,17 @@ def test_the_bias_moves_toward_underloaded_experts_and_gets_no_gradient():
                                    health=True))
     batch = {"features": tokens, "labels": tokens, MASK_KEY: jnp.ones((2,))}
 
-    def loads(st):
-        """Pairs per expert that ``st``'s parameters and bias choose."""
+    @jax.jit
+    def chosen(st):
         _, sown = model.apply(
             {"params": st.params, **st.model_state}, tokens, training=True,
             mutable=["intermediates"])
-        chosen = np.asarray(
-            sown["intermediates"]["block_1"]["moe_mlp"]["experts"][0])
-        return np.bincount(chosen.reshape(-1), minlength=8)
+        return sown["intermediates"]["block_1"]["moe_mlp"]["experts"][0]
+
+    def loads(st):
+        """Pairs per expert that ``st``'s parameters and bias choose."""
+        return np.bincount(
+            np.asarray(chosen(st)).reshape(-1), minlength=8)
 
     for n in range(1, 4):
         load, before = loads(state), bias_of(state)
@@ -405,22 +420,23 @@ def test_the_bias_moves_toward_underloaded_experts_and_gets_no_gradient():
         return moe_transformer.loss(tokens, out).mean()
 
     np.testing.assert_array_equal(
-        np.asarray(jax.grad(loss_of)(jnp.asarray(after))), 0.0)
+        np.asarray(jax.jit(jax.grad(loss_of))(jnp.asarray(after))), 0.0)
 
 
 def test_eval_and_init_leave_the_bias_alone():
     model = _small_lm(num_layers=2)
     tokens = jnp.zeros((2, 128), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     np.testing.assert_array_equal(
         np.asarray(variables["moe_state"]["block_1"]["moe_mlp"][
             "e_score_correction_bias"]), 0.0)
     # a training call that may not write the collection reads it
-    out = model.apply(variables, tokens, training=True)
+    out = jax.jit(lambda v: model.apply(v, tokens, training=True))(variables)
     assert set(out) == {"logits", "aux_loss", "routing"}
     assert set(out["routing"]) == {
         "load_max", "load_mean", "entropy", "dropped", "bias_abs_max"}
-    logits = model.apply(variables, tokens, training=False)
+    logits = jax.jit(lambda v: model.apply(v, tokens, training=False))(
+        variables)
     assert logits.shape == (2, 128, 256)
 
 
@@ -433,9 +449,9 @@ def test_the_legacy_and_olmoe_models_keep_their_counters_and_state():
         moe_every=1, dispatch_impl="sorted", normalize_gates=False,
         attention_impl="xla")
     tokens = jnp.zeros((1, 128), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), tokens)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
     assert set(variables) == {"params"}
-    out = model.apply(variables, tokens, training=True)
+    out = jax.jit(lambda v: model.apply(v, tokens, training=True))(variables)
     assert set(out["routing"]) == {
         "load_max", "load_mean", "entropy", "dropped"}
 
@@ -443,7 +459,8 @@ def test_the_legacy_and_olmoe_models_keep_their_counters_and_state():
 def test_sigmoid_needs_the_sorted_dispatch():
     x = jnp.zeros((1, 128, 32))
     with pytest.raises(ValueError, match="sorted"):
-        MoeMlp(4, scoring="sigmoid").init(jax.random.PRNGKey(0), x)
+        jax.eval_shape(
+            MoeMlp(4, scoring="sigmoid").init, jax.random.PRNGKey(0), x)
 
 
 def test_no_parameter_of_the_new_model_falls_to_the_catch_all_rule():

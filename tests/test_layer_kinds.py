@@ -104,9 +104,9 @@ def test_attention_by_kind_against_the_direct_formula(kind, own):
         rotary_dim=own.rotary_dim, rope_scaling=own.rope_scaling,
         mask=mask, kind_scope="attn_" + kind)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 96, 64))
-    params = mixer.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"]
     assert params["query"]["kernel"].shape == (64, own.num_heads, 32)
-    got = mixer.apply({"params": params}, x)[0]
+    got = jax.jit(mixer.apply)({"params": params}, x)[0]
     want = direct_attention(
         x[0], params, own.num_heads, 2, 16, own.rotary_dim or 16,
         own.rope_theta, own.rope_scaling, own.window)
@@ -119,8 +119,8 @@ def test_yarn_without_its_parts_is_another_function():
     def out(**fields):
         mixer = Attention(4, attention_impl="xla", head_dim=16,
                           rotary_dim=8, rope_theta=500000.0, **fields)
-        params = mixer.init(jax.random.PRNGKey(1), x)["params"]
-        return mixer.apply({"params": params}, x)
+        params = jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"]
+        return jax.jit(mixer.apply)({"params": params}, x)
 
     import dataclasses
 
@@ -162,29 +162,30 @@ def test_the_dense_block_takes_the_mixer_s_fields():
         mlp_dim=96, head_dim=16, num_kv_heads=2, rotary_dim=8,
         output_gate="sigmoid", rope_theta=500000.0, rope_scaling=YARN,
         kind_scope="attn_full")
-    params = block.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(block.init)(jax.random.PRNGKey(1), x)["params"]
     assert params["attn"]["query"]["kernel"].shape == (64, 6, 32)
     assert params["attn"]["key"]["kernel"].shape == (64, 2, 16)
     assert params["attn"]["out_proj"]["kernel"].shape == (6, 16, 64)
     assert params["mlp_gate"]["kernel"].shape == (64, 96)
-    y = block.apply({"params": params}, x)
+    y = jax.jit(block.apply)({"params": params}, x)
     assert y.shape == x.shape and bool(jnp.isfinite(y).all())
-    h = transformer.make_norm("rmsnorm", 1e-6, "n").apply(
+    h = jax.jit(transformer.make_norm("rmsnorm", 1e-6, "n").apply)(
         {"params": params["ln_attn"]}, x)
     want = direct_attention(
         h[0], params["attn"], 6, 2, 16, 8, 500000.0, YARN, None)
-    mixed = Attention(
+    mixed = jax.jit(Attention(
         6, attention_impl="xla", head_dim=16, num_kv_heads=2, rotary_dim=8,
         output_gate="sigmoid", rope_theta=500000.0, rope_scaling=YARN,
-    ).apply({"params": params["attn"]}, h)
+    ).apply)({"params": params["attn"]}, h)
     np.testing.assert_allclose(mixed[0], want, atol=2e-5)
     # a band in a dense block
     banded = Block(4, attention_impl="xla", mask=F.Band(8))
     plain = Block(4, attention_impl="xla")
-    p = plain.init(jax.random.PRNGKey(1), x)
+    p = jax.jit(plain.init)(jax.random.PRNGKey(1), x)
     assert jax.tree_util.tree_structure(p) == jax.tree_util.tree_structure(
-        banded.init(jax.random.PRNGKey(1), x))
-    assert float(jnp.abs(plain.apply(p, x) - banded.apply(p, x)).max()) > 1e-3
+        jax.eval_shape(banded.init, jax.random.PRNGKey(1), x))
+    assert float(jnp.abs(jax.jit(plain.apply)(p, x)
+                         - jax.jit(banded.apply)(p, x)).max()) > 1e-3
     assert set(p["params"]["attn"]) == {"query", "key", "value", "out_proj"}
 
 
@@ -210,7 +211,7 @@ TOKENS = jnp.asarray(
 
 def test_layer_kinds_with_a_window_kind():
     model = laguna_like()
-    variables = model.init(jax.random.PRNGKey(0), TOKENS)
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), TOKENS)
     params = variables["params"]
     for block, heads in enumerate((6, 8, 8, 8, 6)):
         attn = params["block_%d" % block]["attn"]
@@ -225,13 +226,13 @@ def test_layer_kinds_with_a_window_kind():
     assert model._kind_fields("full") == dict(
         num_heads=6, rope_theta=500000.0, rotary_dim=8, rope_scaling=YARN,
         mask=None, kind_scope="attn_full")
-    logits = model.apply(variables, TOKENS)
+    logits = jax.jit(model.apply)(variables, TOKENS)
     assert logits.shape == (2, 128, 128)
     # the window decides: another window, another function; a window
     # that holds the whole prefix is the causal model
     wide = {"full": FULL,
             "window": MixerKind(8, 10000.0, None, None, 2 ** 20)}
-    causal = laguna_like(kind_fields=wide).apply(variables, TOKENS)
+    causal = jax.jit(laguna_like(kind_fields=wide).apply)(variables, TOKENS)
     assert float(jnp.abs(logits - causal).max()) > 1e-3
     # a token inside every window reads the same either way
     np.testing.assert_allclose(logits[:, :24], causal[:, :24], atol=1e-4)
@@ -240,7 +241,8 @@ def test_layer_kinds_with_a_window_kind():
 def test_the_scopes_and_the_lines_by_kind(caplog):
     model = laguna_like(remat=True, remat_policy="full")
     tx = moe_transformer.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), TOKENS)
+    state = jax.jit(lambda: create_train_state(
+        model, tx, jax.random.PRNGKey(0), TOKENS))()
     batch = {"features": TOKENS, "labels": TOKENS,
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = jax.jit(make_train_step(
@@ -317,7 +319,9 @@ def test_the_attention_line_by_kind(monkeypatch, caplog):
         "block-diffusion", "latent", "prediction-module", "ring"])
 def test_the_refusals_by_name(changes, match):
     with pytest.raises(ValueError, match=match):
-        laguna_like(**changes).init(jax.random.PRNGKey(0), TOKENS)
+        # raised while the model is traced: no operation need run
+        jax.eval_shape(
+            laguna_like(**changes).init, jax.random.PRNGKey(0), TOKENS)
 
 
 def test_a_linear_layer_in_a_dense_block_is_refused():
@@ -327,7 +331,7 @@ def test_a_linear_layer_in_a_dense_block_is_refused():
         linear=GatedDeltaDims(2, 4, 16, 16, 4), first_k_dense=1,
         moe_every=1, attention_impl="xla")
     with pytest.raises(ValueError, match="layer 0 asks for a Gated DeltaNet"):
-        model.init(jax.random.PRNGKey(0), TOKENS)
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), TOKENS)
 
 
 # --- what was there is what it was -----------------------------------
@@ -375,7 +379,9 @@ OLDER_MODELS = {
 
 def _step_and_tree(model, zoo, tokens):
     tx = zoo.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    # shapes alone are read: the state is never drawn
+    state = jax.eval_shape(lambda: create_train_state(
+        model, tx, jax.random.PRNGKey(0), tokens))
     batch = {"features": tokens, "labels": tokens,
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = make_train_step(model, zoo.loss, tx, jnp.bfloat16, health=True)

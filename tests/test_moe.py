@@ -313,8 +313,10 @@ def test_sorted_dispatch_matches_bruteforce_with_gradients():
     def loss(path):
         return lambda *a: (path(*a, k) ** 2).sum()
 
-    got = jax.grad(loss(_sorted_path), argnums=(0, 1, 2))(x, logits, w)
-    want = jax.grad(loss(_loop_path), argnums=(0, 1, 2))(x, logits, w)
+    got = jax.jit(jax.grad(loss(_sorted_path), argnums=(0, 1, 2)))(
+        x, logits, w)
+    want = jax.jit(jax.grad(loss(_loop_path), argnums=(0, 1, 2)))(
+        x, logits, w)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
@@ -344,12 +346,12 @@ def test_sorted_dispatch_matches_onehot_where_nothing_is_dropped():
     np.testing.assert_allclose(
         np.asarray(onehot_path(x, logits, w)),
         np.asarray(sorted_path(x, logits, w)), atol=1e-5)
-    got = jax.grad(
+    got = jax.jit(jax.grad(
         lambda *a: (sorted_path(*a) ** 2).sum(), argnums=(0, 1, 2)
-    )(x, logits, w)
-    want = jax.grad(
+    ))(x, logits, w)
+    want = jax.jit(jax.grad(
         lambda *a: (onehot_path(*a) ** 2).sum(), argnums=(0, 1, 2)
-    )(x, logits, w)
+    ))(x, logits, w)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
 
@@ -426,6 +428,13 @@ def test_sorted_dispatch_under_dp_mesh_matches_single_device():
     np.testing.assert_allclose(got, expected, atol=1e-4, rtol=1e-4)
 
 
+def _trace_init(model):
+    """A refusal is raised while the model is traced: ``eval_shape``
+    traces the init and runs no operation."""
+    return jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), _batch()["features"])
+
+
 def test_sorted_dispatch_refuses_what_its_exchange_does_not_divide():
     """Over ``ep`` the sorted path exchanges rows in a region manual
     over the whole mesh (tests/test_moe_exchange.py); an axis it
@@ -435,20 +444,17 @@ def test_sorted_dispatch_refuses_what_its_exchange_does_not_divide():
     model = _small_moe(
         attention_impl="xla", mesh=mesh, dispatch_impl="sorted")
     with pytest.raises(ValueError, match=r"nothing divides over \['tp'\]"):
-        model.init(jax.random.PRNGKey(0), _batch()["features"])
+        _trace_init(model)
     with pytest.raises(ValueError, match="dispatch_impl"):
-        _small_moe(dispatch_impl="compact").init(
-            jax.random.PRNGKey(0), _batch()["features"])
+        _trace_init(_small_moe(dispatch_impl="compact"))
 
 
 def test_onehot_dispatch_refuses_unnormalised_gates():
     """``top_k_routing`` always renormalises: the field must not be
     taken and ignored."""
     with pytest.raises(ValueError, match="normalize_gates=False needs"):
-        _small_moe(normalize_gates=False).init(
-            jax.random.PRNGKey(0), _batch()["features"])
-    _small_moe(normalize_gates=False, dispatch_impl="sorted").init(
-        jax.random.PRNGKey(0), _batch()["features"])
+        _trace_init(_small_moe(normalize_gates=False))
+    _trace_init(_small_moe(normalize_gates=False, dispatch_impl="sorted"))
 
 
 def _avals(jaxpr):

@@ -8,6 +8,7 @@ skewed routing, a tiny Mellum2 over ``ep=4`` against its plain
 reference and against the one-device program, what is still refused,
 and the counters' way to the journal through ``SpmdTrainer``."""
 
+import functools
 import importlib.util
 import json
 import os
@@ -572,21 +573,22 @@ def test_without_ep_the_layer_traces_what_it_traced():
 
 def test_what_is_still_refused():
     variables, x = _layer_case()
+    # each is raised while the layer is traced: no operation need run
+    init = lambda layer, x=x: jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), x)
     with pytest.raises(ValueError, match="one or the other"):
-        MoeMlp(mesh=ep_mesh(), held_experts=(0, 2), held_rows=64,
-               **LAYER).init(jax.random.PRNGKey(0), x)
+        init(MoeMlp(mesh=ep_mesh(), held_experts=(0, 2), held_rows=64,
+                    **LAYER))
     both = build_mesh(MeshConfig(fsdp=2, ep=2), num_devices=4)
     with pytest.raises(ValueError, match="ep beside fsdp"):
-        MoeMlp(mesh=both, **LAYER).init(jax.random.PRNGKey(0), x)
+        init(MoeMlp(mesh=both, **LAYER))
     with pytest.raises(ValueError, match="have to divide over ep"):
-        MoeMlp(mesh=ep_mesh(), **dict(LAYER, num_experts=6)).init(
-            jax.random.PRNGKey(0), x)
+        init(MoeMlp(mesh=ep_mesh(), **dict(LAYER, num_experts=6)))
     with pytest.raises(ValueError, match="does not divide over the 4"):
-        MoeMlp(mesh=ep_mesh(), **LAYER).init(jax.random.PRNGKey(0), x[:3])
+        init(MoeMlp(mesh=ep_mesh(), **LAYER), x[:3])
     with pytest.raises(ValueError, match="normalize_gates=False needs"):
-        MoeMlp(mesh=ep_mesh(), **dict(
-            LAYER, dispatch_impl="onehot", normalize_gates=False)).init(
-                jax.random.PRNGKey(0), x)
+        init(MoeMlp(mesh=ep_mesh(), **dict(
+            LAYER, dispatch_impl="onehot", normalize_gates=False)))
 
 
 # --- a tiny Mellum2 ---------------------------------------------------
@@ -595,20 +597,41 @@ def test_what_is_still_refused():
 def tiny_config():
     with open(TINY) as f:
         config = json.load(f)
-    # two periods, a window of 8
-    config.update(num_hidden_layers=8, sliding_window=8)
+    # the file's one period (three layers under the window, one
+    # without; what is held against what here is the exchange, a layer
+    # at a time), a window of 8
+    config.update(sliding_window=8)
+    assert config["num_hidden_layers"] == 4
     return config
+
+
+@functools.lru_cache(maxsize=None)
+def _tiny_inputs():
+    """(tokens, the tiny model's parameters), the parameters initialised
+    once, one program and not an operation at a time."""
+    config = tiny_config()
+    tokens = jnp.asarray(np.random.RandomState(4).randint(
+        0, config["vocab_size"], (4, 32)), jnp.int32)
+    one = load("zoo").model_from_config(config, attention_impl="xla")
+    return tokens, jax.jit(lambda: one.init(
+        jax.random.PRNGKey(5), tokens, training=False))()["params"]
+
+
+@functools.lru_cache(maxsize=None)
+def _one_device():
+    """Loss, logits and gradients of the program on ONE device with all
+    the experts, once: two tests hold the mesh's against them."""
+    zoo = load("zoo")
+    one = zoo.model_from_config(tiny_config(), attention_impl="xla")
+    tokens, params = _tiny_inputs()
+    return _system(zoo, one, tokens)(params)
 
 
 def _tiny_case(mesh=None):
     zoo = load("zoo")
     config = tiny_config()
     model = zoo.model_from_config(config, mesh=mesh, attention_impl="xla")
-    tokens = jnp.asarray(np.random.RandomState(4).randint(
-        0, config["vocab_size"], (4, 32)), jnp.int32)
-    params = zoo.model_from_config(config, attention_impl="xla").init(
-        jax.random.PRNGKey(5), tokens, training=False)["params"]
-    return zoo, config, model, tokens, params
+    return (zoo, config, model) + _tiny_inputs()
 
 
 def _system(zoo, model, tokens):
@@ -628,9 +651,7 @@ def test_tiny_mellum2_over_ep_is_its_reference_and_the_one_device_program():
         params)
     assert float(routing["dropped"]) == 0
     # the program on ONE device with all the experts
-    one = zoo.model_from_config(config, attention_impl="xla")
-    (loss_one, (logits_one, _, _)), grads_one = _system(zoo, one, tokens)(
-        params)
+    (loss_one, (logits_one, _, _)), grads_one = _one_device()
     np.testing.assert_allclose(loss, loss_one, rtol=1e-5)
     np.testing.assert_allclose(logits, logits_one, rtol=1e-3, atol=1e-4)
     _assert_trees_close(grads, grads_one, rtol=1e-4, atol=1e-5)
@@ -700,8 +721,7 @@ def test_the_rows_the_regrouping_ran_reach_the_moe_routing_event(
     zoo, config, model, tokens, params = _tiny_case(ep_mesh())
     (loss, (_, routing, _)), grads = _system(zoo, model, tokens)(params)
     # stopping short of the buffer changes nothing that is read
-    one = zoo.model_from_config(config, attention_impl="xla")
-    (loss_one, _), grads_one = _system(zoo, one, tokens)(params)
+    (loss_one, _), grads_one = _one_device()
     np.testing.assert_allclose(loss, loss_one, rtol=1e-5)
     _assert_trees_close(grads, grads_one, rtol=1e-4, atol=1e-5)
     (fact,) = [f for f in step_fns.FACTS if f.key == "routing"]

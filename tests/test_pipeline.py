@@ -188,7 +188,9 @@ def test_pipelined_lm_runs_the_flash_kernel_in_its_manual_region(
         model = pipeline_transformer.PipelinedTransformerLM(
             attention_impl=impl, mesh=mesh, **kwargs
         )
-        variables = model.init(jax.random.PRNGKey(0), tokens)
+        # one program: an operation at a time the init dispatches the
+        # interpreted kernel and the pipeline's region piece by piece
+        variables = jax.jit(model.init)(jax.random.PRNGKey(0), tokens)
 
         def loss_fn(params):
             logits = model.apply({"params": params}, tokens)

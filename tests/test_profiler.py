@@ -75,11 +75,16 @@ def _burn_thread(stop, span_names=(), trace_dir=None):
     return thread
 
 
-def _wait_for_samples(sampler, minimum=5, timeout=5.0):
+def _wait_for_samples(sampler, minimum=5, timeout=5.0, frame=None):
+    """The sampler's snapshot once it holds ``minimum`` samples and,
+    with ``frame``, a stack through that frame: on a loaded machine the
+    first five samples (25 ms at 200 Hz) can all precede the burn
+    thread's first call."""
     deadline = time.time() + timeout
     while time.time() < deadline:
         snap = sampler.snapshot()
-        if snap["samples"] >= minimum:
+        if snap["samples"] >= minimum and (frame is None or any(
+                frame in f for e in snap["stacks"] for f in e["stack"])):
             return snap
         time.sleep(0.05)
     return sampler.snapshot()
@@ -128,7 +133,7 @@ def test_sampler_collects_hot_frames(clean_profiler):
     stop = threading.Event()
     thread = _burn_thread(stop)
     try:
-        snap = _wait_for_samples(sampler)
+        snap = _wait_for_samples(sampler, frame="burn_hot_loop")
     finally:
         stop.set()
         thread.join()

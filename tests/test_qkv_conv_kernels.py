@@ -55,8 +55,13 @@ def operands(dtype, hk, hv, seq, batch, taps=TAPS, dim=DIM, seed=0):
 
 
 def value_and_vjp(fn, heads, qkvz, w, *grads, **kw):
-    results, vjp = jax.vjp(lambda x, w: fn(x, w, heads, **kw), qkvz, w)
-    return tuple(results) + tuple(vjp(tuple(grads)))
+    """One program a call (a new one: ``fn`` is traced under what the
+    test has patched by then)."""
+    def both(qkvz, w, *grads):
+        results, vjp = jax.vjp(lambda x, w: fn(x, w, heads, **kw), qkvz, w)
+        return tuple(results) + tuple(vjp(tuple(grads)))
+
+    return jax.jit(both)(qkvz, w, *grads)
 
 
 def worst(got, want):
@@ -220,11 +225,11 @@ WIDE = T.GatedDeltaDims(
 def layer_gradients(dims, seq=128):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, 32))
     layer = T.GatedDeltaNet(dims)
-    params = layer.init(jax.random.PRNGKey(2), x)["params"]
+    params = jax.jit(layer.init)(jax.random.PRNGKey(2), x)["params"]
     target = jax.random.normal(jax.random.PRNGKey(3), x.shape)
     loss = lambda params, x: jnp.sum(
         layer.apply({"params": params}, x) * target)
-    return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(params, x)
 
 
 def test_the_layer_both_ways_over_two_of_the_rule_s_segments(monkeypatch):
@@ -321,7 +326,9 @@ def test_a_tiny_qwen3_next_traces_the_parent_s_step_on_the_cpu(width):
         remat_policy="full")
     tokens = jnp.zeros((2, 128), jnp.int32)
     tx = moe_transformer.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    # the trace reads shapes and dtypes: no parameter is initialised
+    state = jax.eval_shape(
+        lambda: create_train_state(model, tx, jax.random.PRNGKey(0), tokens))
     batch = {"features": tokens, "labels": tokens,
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = make_train_step(

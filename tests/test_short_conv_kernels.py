@@ -394,7 +394,9 @@ def test_a_tiny_lfm2_traces_the_parent_s_step_on_the_cpu(width):
         attention_impl="xla", remat=True, remat_policy="full")
     tokens = jnp.zeros((2, 128), jnp.int32)
     tx = moe_transformer.optimizer()
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    # the trace reads shapes and dtypes: no parameter is initialised
+    state = jax.eval_shape(
+        lambda: create_train_state(model, tx, jax.random.PRNGKey(0), tokens))
     batch = {"features": tokens, "labels": tokens,
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = make_train_step(

@@ -304,7 +304,7 @@ def model(**changes):
 def trained():
     net = model()
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 64), 0, 128)
-    params = net.init(jax.random.PRNGKey(1), tokens)["params"]
+    params = jax.jit(net.init)(jax.random.PRNGKey(1), tokens)["params"]
     return net, tokens, params
 
 
@@ -321,7 +321,7 @@ def by_term(trained, term):
         total, _ = loss(tokens, dict(out, indexer_loss_coef=0.0))
         return total.sum()
 
-    return jax.grad(value)(params)
+    return jax.jit(jax.grad(value))(params)
 
 
 def test_the_indexer_s_parameters_and_their_names(trained):
@@ -357,7 +357,8 @@ def test_the_other_losses_reach_the_indexer_not_at_all(trained):
 
 def test_the_loss_names_the_term_and_weighs_it(trained):
     net, tokens, params = trained
-    out = net.apply({"params": params}, tokens, training=True)
+    out = jax.jit(lambda params: net.apply(
+        {"params": params}, tokens, training=True))(params)
     assert out["indexer_loss"].shape == (2,)
     total, terms = loss(tokens, out)
     assert (np.asarray(terms["indexer_loss"])
@@ -374,29 +375,40 @@ def test_the_loss_names_the_term_and_weighs_it(trained):
     assert float(facts["kept_mean"][0]) == pytest.approx(
         np.minimum(16, np.arange(64) + 1).mean())
     # an evaluation call returns the logits alone
-    assert net.apply({"params": params}, tokens).shape == (2, 64, 128)
+    assert jax.eval_shape(
+        lambda: net.apply({"params": params}, tokens)).shape == (2, 64, 128)
+
+
+def _whole_loss(net, tokens, params):
+    out = net.apply({"params": params}, tokens, training=True)
+    return loss(tokens, out)[0].sum()
+
+
+@pytest.fixture(scope="module")
+def without_remat(trained):
+    """The whole loss's gradient with nothing made again: what every
+    policy is held against, so once."""
+    net, tokens, params = trained
+    return jax.jit(jax.grad(functools.partial(_whole_loss, net, tokens)))(
+        params)
 
 
 @pytest.mark.parametrize("policy", ["full", "flash"])
-def test_remat_changes_no_gradient(trained, policy):
-    net, tokens, params = trained
+def test_remat_changes_no_gradient(trained, without_remat, policy):
+    _, tokens, params = trained
     other = model(remat=True, remat_policy=policy)
-
-    def value(net, params):
-        out = net.apply({"params": params}, tokens, training=True)
-        return loss(tokens, out)[0].sum()
-
-    want = jax.grad(functools.partial(value, net))(params)
-    got = jax.grad(functools.partial(value, other))(params)
+    got = jax.jit(jax.grad(functools.partial(_whole_loss, other, tokens)))(
+        params)
     for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
+                    jax.tree_util.tree_leaves(without_remat)):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-6)
 
 
 def test_a_probe_is_sown_only_when_asked(trained):
     net, tokens, params = trained
-    _, sown = net.apply(
-        {"params": params}, tokens, training=True, mutable=["intermediates"])
+    _, sown = jax.jit(lambda params: net.apply(
+        {"params": params}, tokens, training=True,
+        mutable=["intermediates"]))(params)
     attn = sown["intermediates"]["block_1"]["attn"]
     assert attn["kept_bits"][0].shape == (2, 64, 8)
     assert attn["scores_tail"][0].shape == (2, 64, 64)

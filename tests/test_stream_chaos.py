@@ -38,6 +38,20 @@ def _wait_port(port, timeout=90):
     raise TimeoutError("port %d never opened" % port)
 
 
+def _journal(events_dir):
+    """Every event the roles under ``events_dir`` have written so far
+    (write-through NDJSON, a file a process)."""
+    found = []
+    for path in sorted(events_dir.glob("*.events.ndjson")):
+        with open(str(path)) as f:
+            for line in f:
+                try:
+                    found.append(json.loads(line))
+                except ValueError:
+                    pass  # a line half written
+    return found
+
+
 def test_ps_sigkill_midstream_lifecycle_restore(tmp_path, monkeypatch):
     """SIGKILL a real lifecycle-enabled PS, relaunch on the same port
     and checkpoint dir: admitted rows restore with their trained
@@ -45,8 +59,12 @@ def test_ps_sigkill_midstream_lifecycle_restore(tmp_path, monkeypatch):
     (no phantom rows), and the admission sketch re-anchors empty — a
     novel id must re-earn its k sightings. The worker-side resync path
     is the ordinary PSClient machinery, unchanged."""
+    from elasticdl_tpu.observability import events
+
     ckpt_dir = tmp_path / "ckpt"
     ckpt_dir.mkdir()
+    events_dir = tmp_path / "events"
+    monkeypatch.setenv(events.EVENTS_DIR_ENV, str(events_dir))
     monkeypatch.setenv("EDL_EMB_ADMIT_K", "2")
     monkeypatch.setenv("EDL_EMB_MAX_ROWS", "6")
     monkeypatch.setenv("EDL_EMB_SWEEP_SECS", "0.3")
@@ -72,18 +90,35 @@ def test_ps_sigkill_midstream_lifecycle_restore(tmp_path, monkeypatch):
 
         for _ in range(6):
             push(hot)                 # hot: freq ~6 each
-        for _ in range(2):
-            push(cold)                # cold: admitted at exactly k=2
+        # cold: admitted, and trained, at exactly k=2 pushes on a quiet
+        # machine; a sweep tick between two sightings halves the sketch
+        # and costs one more (a pull is a sighting too), and the rows'
+        # frequency stays under the hot rows' either way
+        push(cold)
+        for _ in range(4):
+            push(cold)
+            cold_rows = client.pull_embedding_vectors("t", cold)
+            if not np.allclose(cold_rows, 0.0):
+                break
         # both sets are admitted and trained now
         assert not np.allclose(
             client.pull_embedding_vectors("t", hot), 0.0
         )
-        assert not np.allclose(
-            client.pull_embedding_vectors("t", cold), 0.0
-        )
+        assert not np.allclose(cold_rows, 0.0)
         # resident 10 > max_rows 6: the sweep LFU-evicts the 4
-        # lowest-frequency (cold) rows; wait out a few sweep ticks
-        time.sleep(1.5)
+        # lowest-frequency (cold) rows, once the pulls above are a
+        # second old (lfu_protect_secs). Wait for the sweep's own word
+        # in the PS's journal, not for a time: a pull of the cold rows
+        # is a sighting and would keep them protected, and a loaded
+        # machine runs the 0.3 s timer when it can
+        deadline = time.time() + 60
+        swept = []
+        while not swept and time.time() < deadline:
+            time.sleep(0.1)
+            swept = [(event["reason"], event["count"])
+                     for event in _journal(events_dir)
+                     if event["event"] == "row_evicted"]
+        assert swept == [("lfu", 4)], swept
         evicted_rows = client.pull_embedding_vectors("t", cold)
         assert np.allclose(evicted_rows[:4], 0.0), (
             "LFU sweep did not evict the cold tail: %r" % evicted_rows

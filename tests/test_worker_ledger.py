@@ -53,6 +53,11 @@ def test_loop_journals_its_phases_and_metrics_change_nothing(
     import jax
 
     monkeypatch.setenv("EDL_METRICS", "1")
+    # the detector's floor in seconds, not its 20 ms: beside five other
+    # workers the machine holds a 9 ms step up for 30 ms (33 involuntary
+    # context switches in the one that showed), never for a second, and
+    # a step that compiled again or waited on the master would be
+    monkeypatch.setattr(timing_utils, "SLOW_MIN_NS", 1_000_000_000)
     obs_metrics.reset_default_registry()
     blocked = []
     real_block = jax.block_until_ready
@@ -96,7 +101,7 @@ def test_loop_journals_its_phases_and_metrics_change_nothing(
     seen = set().union(*(e["phases"] for e in intervals))
     # no worker.main opened a start-up record here, so the first step
     # is the loop's, state init and compile with it; it carried a
-    # compile, so it is not judged slow
+    # compile, so it is not judged slow, and none of the sixteen is
     assert seen == LOOP_PHASES | {"state_init"}
     assert set(intervals[1]["phases"]) == LOOP_PHASES
     assert worker_journal("slow_step") == []
@@ -175,9 +180,14 @@ def test_profiler_session_holds_the_phases_on_the_ops_clock(tmp_path):
         assert sum(
             1 for s in steps if s[1] <= phase[1] and phase[2] <= s[2]
         ) == 1, phase
+    # the client's threads also mark where their pool opens and closes a
+    # region (``ThreadpoolListener::``, no duration): a thread closes
+    # step N's whenever the machine next runs it, inside step N + 1 on
+    # a loaded one, and that is no operation of either step
     ops = [
         e for name, evs in lines if name.startswith("tf_XLAPjRtCpuClient")
-        for e in evs if not e[0].startswith("end: ")
+        for e in evs
+        if not e[0].startswith(("end: ", "ThreadpoolListener::"))
     ]
     for step in steps:
         (dispatch,) = [
