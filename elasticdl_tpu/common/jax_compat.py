@@ -6,12 +6,14 @@ No version probing: support for a JAX that is not installed is not
 kept. What remains is here because each call site would otherwise
 repeat a detail — when ``check_vma`` may be switched off, and the
 empty-axes no-op of a vary-cast, how to ask whether a region is
-already manual.
+already manual, whether anything is left to partition and whether a
+Pallas kernel may run.
 """
 
 import jax
 
-__all__ = ["shard_map", "pvary", "manual_over"]
+__all__ = ["shard_map", "pvary", "manual_over", "nothing_to_partition",
+           "kernels_can_run"]
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
@@ -44,6 +46,22 @@ def manual_over(mesh):
     refused (they are of type Manual)."""
     manual = jax.sharding.get_abstract_mesh().manual_axes
     return set(mesh.axis_names) <= set(manual)
+
+
+def nothing_to_partition(mesh):
+    """Whether the arrays of a step over ``mesh`` are whole where the
+    caller is being traced: no mesh, one device, or a region already
+    manual over the whole mesh. A sharding constraint has nothing to
+    say there and a second ``shard_map`` nothing to open."""
+    return mesh is None or mesh.size == 1 or manual_over(mesh)
+
+
+def kernels_can_run(mesh):
+    """Whether a ``pallas_call`` may run as it is in a step over
+    ``mesh``: on a TPU with nothing to partition. A Mosaic kernel has
+    no GSPMD partitioning rule (``ops/attention.py:_shard_over_mesh``),
+    so every kernel's chooser asks this before anything of its own."""
+    return nothing_to_partition(mesh) and jax.default_backend() == "tpu"
 
 
 def out_struct(shape, dtype, *operands):

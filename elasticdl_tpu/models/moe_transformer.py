@@ -18,6 +18,7 @@ expert MLP (ops/moe.py). Two dispatches:
   its own tokens and the rows are exchanged over ``ep``.
 """
 
+import dataclasses
 import functools
 from typing import Any, Optional
 
@@ -32,20 +33,15 @@ from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.models.transformer import (
     HIDDEN_SPEC,
-    RESIDUAL_SPEC,
-    STREAMS_SPEC,
     Block,
     GatedDeltaDims,
-    HyperConnection,
     HyperDims,
     IndexerDims,
     LatentDims,
     MixerKind,
     ShortConvDims,
     YarnScaling,
-    make_attention,
     make_norm,
-    merge_hyper_facts,
     remat_block,
 )
 from elasticdl_tpu.ops import (
@@ -261,7 +257,7 @@ class MoeMlp(nn.Module):
                 self.num_experts, use_bias=False, name="router"
             )(x)
         weights = self._weights(dim, x.dtype)
-        one_device = self.mesh is None or self.mesh.size == 1
+        one_device = jax_compat.nothing_to_partition(self.mesh)
         rows, width = groups * seq * self.top_k, weights[0].shape[-1]
         held = run = ""
         if self.held_experts is not None:
@@ -574,127 +570,12 @@ class MoeMlp(nn.Module):
         return y, {"load_balancing": balance, "routing": stats}
 
 
-class MoeBlock(nn.Module):
-    num_heads: int
-    num_experts: int
-    mlp_ratio: int = 4
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    attention_impl: str = "auto"
-    dispatch_impl: str = "auto"
-    mesh: Optional[Any] = None
-    expert_dim: Optional[int] = None
-    expert_act: str = "gelu"
-    normalize_gates: bool = True
-    norm: str = "layernorm"
-    norm_eps: float = 1e-6
-    qk_norm: bool = False
-    rope_theta: float = 10000.0
-    latent: Optional[LatentDims] = None
-    scoring: str = "softmax"
-    gate_scale: float = 1.0
-    bias_update_speed: Optional[float] = None
-    seq_aux: bool = False
-    shared_experts: int = 0
-    held_experts: Optional[Any] = None
-    held_rows: Optional[int] = None
-    shared_gate: bool = False
-    exchange_rows: Optional[int] = None
-    # the mixer: a Gated DeltaNet of these sizes where given, else
-    # softmax attention with ``Attention``'s own fields of these names
-    linear: Optional[GatedDeltaDims] = None
-    # or a gated short convolution of these (``ShortConv``)
-    conv: Optional[ShortConvDims] = None
-    head_dim: Optional[int] = None
-    num_kv_heads: Optional[int] = None
-    head_norm: Optional[str] = None
-    rotary_dim: Optional[int] = None
-    output_gate: Optional[str] = None
-    # the attention mask's layout where it is not causal, and with it
-    # the call's ``positions`` (``Attention``'s own of those names)
-    mask: Optional[Any] = None
-    kind_scope: Optional[str] = None
-    # ``Block``'s fields of these names: YaRN by the mixer's own
-    # convention, and the hyper-connected residual path (``x`` the n
-    # streams; the block's facts under ``aux["mhc"]``)
-    rope_scaling: Optional[YarnScaling] = None
-    hc: Optional[HyperDims] = None
-    layer_index: int = 0
-    # ``Attention``'s field of that name: a learned indexer picks the
-    # keys a query attends over; the layer's facts under ``aux["dsa"]``
-    indexer: Optional[IndexerDims] = None
-
-    @nn.compact
-    def __call__(self, x, training=False, positions=None):
-        # only ``Attention`` takes the rows' positions
-        where = () if positions is None else (positions,)
-        attention = make_attention(
-            self.num_heads,
-            self.latent,
-            self.linear,
-            self.conv,
-            attention_impl=self.attention_impl,
-            mesh=self.mesh,
-            qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps,
-            rope_theta=self.rope_theta,
-            head_dim=self.head_dim,
-            num_kv_heads=self.num_kv_heads,
-            head_norm=self.head_norm,
-            rotary_dim=self.rotary_dim,
-            output_gate=self.output_gate,
-            mask=self.mask,
-            kind_scope=self.kind_scope,
-            rope_scaling=self.rope_scaling,
-            indexer=self.indexer,
-        )
-        experts = MoeMlp(
-            self.num_experts,
-            mlp_ratio=self.mlp_ratio,
-            top_k=self.top_k,
-            capacity_factor=self.capacity_factor,
-            dispatch_impl=self.dispatch_impl,
-            mesh=self.mesh,
-            expert_dim=self.expert_dim,
-            expert_act=self.expert_act,
-            normalize_gates=self.normalize_gates,
-            scoring=self.scoring,
-            gate_scale=self.gate_scale,
-            bias_update_speed=self.bias_update_speed,
-            seq_aux=self.seq_aux,
-            shared_experts=self.shared_experts,
-            held_experts=self.held_experts,
-            held_rows=self.held_rows,
-            shared_gate=self.shared_gate,
-            exchange_rows=self.exchange_rows,
-            name="moe_mlp",
-        )
-        if self.hc is not None:
-            x = constrain(x, self.mesh, STREAMS_SPEC)
-            u, write, attn_facts = HyperConnection(
-                self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)
-            x = write(attention(
-                make_norm(self.norm, self.norm_eps, "ln_attn")(u),
-                training, *where))
-            u, write, mlp_facts = HyperConnection(
-                self.hc, 2 * self.layer_index + 1, self.mesh,
-                name="hc_mlp")(x)
-            y, aux = experts(
-                make_norm(self.norm, self.norm_eps, "ln_mlp")(u), training)
-            aux["mhc"] = merge_hyper_facts([attn_facts, mlp_facts])
-            return constrain(write(y), self.mesh, STREAMS_SPEC), aux
-        x = constrain(x, self.mesh, RESIDUAL_SPEC)
-        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        mixed = attention(h, training, *where)
-        dsa = None
-        if self.indexer is not None:
-            mixed, dsa = mixed
-        x = x + mixed
-        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
-        y, aux = experts(h, training)
-        if dsa is not None:
-            aux["dsa"] = dsa
-        return constrain(x + y, self.mesh, RESIDUAL_SPEC), aux
+# What a model names again (nine zoo files build ``MoeTransformerLM``
+# with flat keywords) and hands to its expert blocks whole: every field
+# the layer declares but the mesh, which is the block's
+EXPERT_FIELDS = tuple(
+    f.name for f in dataclasses.fields(MoeMlp)
+    if f.name not in ("mesh", "parent", "name"))
 
 
 def merge_routing(layers):
@@ -842,7 +723,7 @@ class MoeTransformerLM(nn.Module):
     # rotary base, rotating lanes, YaRN, window; a kind without an
     # entry has the model's ``num_heads``, ``rope_theta``,
     # ``rotary_dim`` and ``rope_scaling`` and sees the causal prefix,
-    # and "window" needs one (``_kind_fields`` says all of it, once)
+    # and "window" needs one (``_mixer`` says all of it, once)
     layer_kinds: Optional[Any] = None
     kind_fields: Optional[Any] = None
     linear: Optional[GatedDeltaDims] = None
@@ -886,20 +767,34 @@ class MoeTransformerLM(nn.Module):
     indexer: Optional[IndexerDims] = None
     indexer_loss_coef: float = 1.0
 
-    def _kind_fields(self, kind, layout=None):
-        """What a layer's KIND decides of its mixer, stated once: the
-        query heads, the rotary base, the lanes that rotate, YaRN, the
-        mask's layout and the scope its operations lie under. A kind
-        without an entry in ``kind_fields`` (every kind of every model
-        built before there were any) has the model's own four and the
-        call's ``layout`` (None: causal; block diffusion's), and no
-        scope: the mixer, the tree and the program it always had."""
+    def _mixer(self, kind, layout=None):
+        """A layer's mixer by its KIND, stated once: what a block hands
+        to ``make_attention`` unopened. The kind decides the mixer (a
+        Gated DeltaNet of ``linear``'s sizes, a short convolution of
+        ``conv``'s, else softmax or latent attention) and, of a softmax
+        one, the query heads, the rotary base, the lanes that rotate,
+        YaRN, the mask's layout and the scope its operations lie under;
+        the rest is the model's, for every kind alike. A kind without
+        an entry in ``kind_fields`` (every kind of every model built
+        before there were any) has the model's own four and the call's
+        ``layout`` (None: causal; block diffusion's), and no scope: the
+        mixer, the tree and the program it always had."""
         own = dict(self.kind_fields or {}).get(kind)
         if own is None:
             own = MixerKind(self.num_heads, self.rope_theta,
                             self.rotary_dim, self.rope_scaling)
         return dict(
             num_heads=own.num_heads,
+            latent=self.latent,
+            linear=self.linear if kind == "linear" else None,
+            conv=self.conv if kind == "conv" else None,
+            attention_impl=self.attention_impl,
+            qk_norm=self.qk_norm,
+            head_dim=self.head_dim,
+            num_kv_heads=self.num_kv_heads,
+            head_norm=self.head_norm,
+            output_gate=self.output_gate,
+            indexer=self.indexer,
             rope_theta=own.rope_theta,
             rotary_dim=own.rotary_dim,
             rope_scaling=own.rope_scaling,
@@ -1127,60 +1022,30 @@ class MoeTransformerLM(nn.Module):
             )
             if self.remat else (lambda cls: cls)
         )
-        shared = dict(
-            mlp_ratio=self.mlp_ratio,
-            attention_impl=self.attention_impl,
-            mesh=self.mesh,
-            norm=self.norm,
-            norm_eps=self.norm_eps,
-            qk_norm=self.qk_norm,
-            latent=self.latent,
-            hc=self.hc,
-            # the softmax mixer's own fields, for every kind alike
-            head_dim=self.head_dim,
-            num_kv_heads=self.num_kv_heads,
-            head_norm=self.head_norm,
-            output_gate=self.output_gate,
-        )
         kinds = tuple(self.layer_kinds or ("full",))
         self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
         routing, mhc, dsa = [], [], []
 
-        def expert_block(name, index, kind="full"):
-            return wrap(MoeBlock)(
-                num_experts=self.num_experts,
-                top_k=self.top_k,
-                capacity_factor=self.capacity_factor,
-                dispatch_impl=self.dispatch_impl,
-                expert_dim=self.expert_dim,
-                expert_act=self.expert_act,
-                normalize_gates=self.normalize_gates,
-                scoring=self.scoring,
-                gate_scale=self.gate_scale,
-                bias_update_speed=self.bias_update_speed,
-                seq_aux=self.seq_aux,
-                shared_experts=self.shared_experts,
-                held_experts=self.held_experts,
-                held_rows=self.held_rows,
-                shared_gate=self.shared_gate,
-                exchange_rows=self.exchange_rows,
-                linear=self.linear if kind == "linear" else None,
-                conv=self.conv if kind == "conv" else None,
-                layer_index=index,
-                name=name,
-                indexer=self.indexer,
-                **shared,
-                **self._kind_fields(kind, layout),
-            )
+        experts = {name: getattr(self, name) for name in EXPERT_FIELDS}
+
+        def block(name, index, kind="full", dense=False):
+            second = (
+                dict(mlp_act=self.dense_act, mlp_dim=self.dense_dim)
+                if dense else dict(experts=experts))
+            return wrap(Block)(
+                self._mixer(kind, layout), mlp_ratio=self.mlp_ratio,
+                norm=self.norm, norm_eps=self.norm_eps, hc=self.hc,
+                layer_index=index, mesh=self.mesh, name=name, **second)
 
         def count(aux):
-            """An expert block's losses and facts into the model's."""
+            """A block's losses and facts into the model's."""
             nonlocal balance, z_loss
-            balance = balance + aux["load_balancing"]
-            z_loss = z_loss + aux["router_z"]
-            if aux["routing"] is not None:
-                routing.append(aux["routing"])
+            if "router_z" in aux:
+                balance = balance + aux["load_balancing"]
+                z_loss = z_loss + aux["router_z"]
+                if aux["routing"] is not None:
+                    routing.append(aux["routing"])
             if "mhc" in aux:
                 mhc.append(aux["mhc"])
             if "dsa" in aux:
@@ -1188,28 +1053,17 @@ class MoeTransformerLM(nn.Module):
 
         for i in range(self.num_layers):
             kind = kinds[i % len(kinds)]
-            if (i >= self.first_k_dense
-                    and i % self.moe_every == self.moe_every - 1):
-                x, aux = expert_block("block_%d" % i, i, kind)(
-                    x, training, positions)
-                count(aux)
-            else:
-                if kind == "linear":
-                    raise ValueError(
-                        "a dense block's mixer is softmax attention, "
-                        "latent attention or a gated short convolution; "
-                        "layer %d asks for a Gated DeltaNet, which only "
-                        "an expert block takes" % i)
-                x = wrap(Block)(
-                    mlp_act=self.dense_act,
-                    mlp_dim=self.dense_dim, layer_index=i,
-                    conv=self.conv if kind == "conv" else None,
-                    name="block_%d" % i, **shared,
-                    **self._kind_fields(kind, layout),
-                )(x, training)
-                if self.hc is not None:
-                    x, block_facts = x
-                    mhc.append(block_facts)
+            dense = (i < self.first_k_dense
+                     or i % self.moe_every != self.moe_every - 1)
+            if dense and kind == "linear":
+                raise ValueError(
+                    "a dense block's mixer is softmax attention, "
+                    "latent attention or a gated short convolution; "
+                    "layer %d asks for a Gated DeltaNet, which only "
+                    "an expert block takes" % i)
+            x, aux = block("block_%d" % i, i, kind, dense)(
+                x, training, positions)
+            count(aux)
         if denoise:
             x = block_diffusion.noisy_half(x)
 
@@ -1238,7 +1092,7 @@ class MoeTransformerLM(nn.Module):
                 h = expand_streams(nn.Dense(
                     self.embed_dim, use_bias=False, name="mtp_proj")(merged))
             with jax.named_scope("mtp/block"):
-                h, aux = expert_block("mtp_block", self.num_layers)(
+                h, aux = block("mtp_block", self.num_layers)(
                     h, training, positions)
                 count(aux)
                 h = reduce_streams(h)

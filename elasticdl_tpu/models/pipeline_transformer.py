@@ -103,9 +103,8 @@ class PipelinedTransformerLM:
         self._ln_f = nn.LayerNorm(name="ln_f")
         self._head = nn.Dense(vocab_size, use_bias=False, name="lm_head")
         self._block = transformer.Block(
-            num_heads,
+            dict(num_heads=num_heads, attention_impl=attention_impl),
             mlp_ratio=mlp_ratio,
-            attention_impl=attention_impl,
             mesh=mesh,
         )
 
@@ -196,7 +195,7 @@ class PipelinedTransformerLM:
 
         def stage_fn(stage_params, h):
             def layer(carry, layer_params):
-                out = self._block.apply(
+                out, _ = self._block.apply(
                     {"params": layer_params}, carry, training=training
                 )
                 return out, None

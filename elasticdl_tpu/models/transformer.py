@@ -616,7 +616,7 @@ class GatedDeltaNet(nn.Module):
     dims: GatedDeltaDims
     norm_eps: float = 1e-6
     # the mesh the step is sharded over, if any: the rule's kernels
-    # run on one device (``ops/gated_delta.py:inverse_impl``)
+    # run on one device (``ops/gated_delta.py:scan_impl``)
     mesh: Optional[Any] = None
 
     @nn.compact
@@ -961,109 +961,103 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
 
 
 class Block(nn.Module):
-    num_heads: int
+    """The residual block of every language model here: a mixer, then a
+    second sublayer, each behind its own norm (``ln_attn``, ``ln_mlp``).
+
+    ``mixer`` is what ``make_attention`` takes beside the block's own
+    ``mesh`` and ``norm_eps``, as one mapping the block does not open
+    (the model's ``_mixer`` builds it). The second sublayer is the
+    experts' layer ``MoeMlp(name="moe_mlp")`` where ``experts`` holds
+    its fields (one mapping again, built from ``EXPERT_FIELDS``), else
+    a dense MLP in the block's own scope: ``mlp_act`` "gelu" (up, GELU,
+    down) or "swiglu" (silu(gate) x up, down) of width ``mlp_dim``
+    (``mlp_ratio x dim`` when None), under the scope ``dense_mlp``.
+
+    ``hc``: a hyper-connected residual path. ``x`` is then the n
+    streams (B, n, S, D) and each sublayer goes through a
+    ``HyperConnection`` (``hc_attn``, ``hc_mlp``). None: ``x +
+    f(norm(x))``, no module, the tree and the program the block always
+    had.
+
+    Returns ``(x, aux)``. ``aux`` is empty for a dense block on the
+    plain path and holds ``mhc`` (the block's facts) under
+    hyper-connections, ``dsa`` where the mixer's indexer hands out its
+    facts, and the experts' own keys (``MoeMlp``) where there are
+    experts."""
+
+    mixer: Any
+    experts: Optional[Any] = None
+    mlp_act: str = "gelu"
+    mlp_dim: Optional[int] = None
     mlp_ratio: int = 4
-    attention_impl: str = "auto"
-    mesh: Optional[Any] = None
     dropout: float = 0.0
     norm: str = "layernorm"
     norm_eps: float = 1e-6
-    qk_norm: bool = False
-    rope_theta: float = 10000.0
-    latent: Optional[LatentDims] = None
-    # the MLP: "gelu" (up, GELU, down) or "swiglu" (silu(gate) x up,
-    # down), of width ``mlp_dim`` (``mlp_ratio x dim`` when None)
-    mlp_act: str = "gelu"
-    mlp_dim: Optional[int] = None
-    # YaRN, by the mixer's own convention (``LatentAttention`` /
-    # ``Attention``'s ``rope_scaling``)
-    rope_scaling: Optional[YarnScaling] = None
-    # a hyper-connected residual path: ``x`` is then the n streams
-    # (B, n, S, D), each sublayer goes through a ``HyperConnection``
-    # (``hc_attn``, ``hc_mlp``) and the block returns ``(streams, its
-    # mhc facts)``. None: ``x + f(norm(x))``, no module, the tree and
-    # the program the block always had
     hc: Optional[HyperDims] = None
     layer_index: int = 0
-    # ``Attention``'s own fields of these names (a head width, kv
-    # heads, a norm a head, partial rotary, an output gate, a mask's
-    # layout, the kind's scope), for a dense block whose mixer is the
-    # expert blocks': Laguna-XS.2's layer 0. At their defaults the
-    # mixer, the tree and the program are what they always were
-    head_dim: Optional[int] = None
-    num_kv_heads: Optional[int] = None
-    head_norm: Optional[str] = None
-    rotary_dim: Optional[int] = None
-    output_gate: Optional[str] = None
-    mask: Optional[Any] = None
-    kind_scope: Optional[str] = None
-    # the mixer a gated short convolution of these sizes and not
-    # attention (LFM2's two leading layers)
-    conv: Optional[ShortConvDims] = None
+    mesh: Optional[Any] = None
+
+    def _dense_mlp(self, h, training):
+        """The dense second sublayer, ``(y, {})`` as the experts' is
+        ``(y, aux)``."""
+        width = self.mlp_dim or h.shape[-1] * self.mlp_ratio
+        # one scope for the MLP's operations, forward and backward
+        # (a trace reads a dense block's share of a step by it)
+        with jax.named_scope("dense_mlp"):
+            if self.mlp_act == "swiglu":
+                gate = nn.Dense(width, use_bias=False, name="mlp_gate")(h)
+                gate = constrain(gate, self.mesh, HIDDEN_SPEC)
+            y = nn.Dense(width, use_bias=False, name="mlp_up")(h)
+            y = constrain(y, self.mesh, HIDDEN_SPEC)
+            y = nn.silu(gate) * y if self.mlp_act == "swiglu" else nn.gelu(y)
+            y = nn.Dense(h.shape[-1], use_bias=False, name="mlp_down")(y)
+            if self.dropout:
+                y = nn.Dropout(self.dropout, deterministic=not training)(y)
+            return y, {}
 
     @nn.compact
-    def __call__(self, x, training=False):
-        dim = x.shape[-1]
+    def __call__(self, x, training=False, positions=None):
         if self.mlp_act not in ("gelu", "swiglu"):
             raise ValueError(
                 "mlp_act must be 'gelu' or 'swiglu', got %r"
                 % (self.mlp_act,))
-        width = self.mlp_dim or dim * self.mlp_ratio
+        mixer = make_attention(
+            mesh=self.mesh, norm_eps=self.norm_eps, **self.mixer)
+        second = self._dense_mlp
+        if self.experts is not None:
+            # the layer lives beside the model that names its fields,
+            # and that module imports this one
+            from elasticdl_tpu.models.moe_transformer import MoeMlp
 
-        def mlp(h):
-            # one scope for the MLP's operations, forward and backward
-            # (a trace reads a dense block's share of a step by it)
-            with jax.named_scope("dense_mlp"):
-                if self.mlp_act == "swiglu":
-                    gate = nn.Dense(
-                        width, use_bias=False, name="mlp_gate")(h)
-                    gate = constrain(gate, self.mesh, HIDDEN_SPEC)
-                h = nn.Dense(width, use_bias=False, name="mlp_up")(h)
-                h = constrain(h, self.mesh, HIDDEN_SPEC)
-                h = (nn.silu(gate) * h if self.mlp_act == "swiglu"
-                     else nn.gelu(h))
-                h = nn.Dense(dim, use_bias=False, name="mlp_down")(h)
-                if self.dropout:
-                    h = nn.Dropout(
-                        self.dropout, deterministic=not training)(h)
-                return h
+            second = MoeMlp(mesh=self.mesh, name="moe_mlp", **self.experts)
+        aux = {}
 
-        attention = make_attention(
-            self.num_heads,
-            self.latent,
-            conv=self.conv,
-            attention_impl=self.attention_impl,
-            mesh=self.mesh,
-            dropout=self.dropout,
-            qk_norm=self.qk_norm,
-            norm_eps=self.norm_eps,
-            rope_theta=self.rope_theta,
-            rope_scaling=self.rope_scaling,
-            head_dim=self.head_dim,
-            num_kv_heads=self.num_kv_heads,
-            head_norm=self.head_norm,
-            rotary_dim=self.rotary_dim,
-            output_gate=self.output_gate,
-            mask=self.mask,
-            kind_scope=self.kind_scope,
-        )
-        if self.hc is not None:
-            x = constrain(x, self.mesh, STREAMS_SPEC)
-            u, write, attn_facts = HyperConnection(
-                self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)
-            x = write(attention(
-                make_norm(self.norm, self.norm_eps, "ln_attn")(u), training))
-            u, write, mlp_facts = HyperConnection(
-                self.hc, 2 * self.layer_index + 1, self.mesh,
-                name="hc_mlp")(x)
-            x = write(mlp(make_norm(self.norm, self.norm_eps, "ln_mlp")(u)))
-            return constrain(x, self.mesh, STREAMS_SPEC), merge_hyper_facts(
-                [attn_facts, mlp_facts])
-        x = constrain(x, self.mesh, RESIDUAL_SPEC)
-        h = make_norm(self.norm, self.norm_eps, "ln_attn")(x)
-        x = x + attention(h, training)
-        h = make_norm(self.norm, self.norm_eps, "ln_mlp")(x)
-        return constrain(x + mlp(h), self.mesh, RESIDUAL_SPEC)
+        def mix(h):
+            # only ``Attention`` takes the rows' positions, and with an
+            # indexer it hands its facts out beside its output
+            out = mixer(h, training, *(
+                () if positions is None else (positions,)))
+            if isinstance(out, tuple):
+                out, aux["dsa"] = out
+            return out
+
+        norm = lambda name: make_norm(self.norm, self.norm_eps, name)
+        if self.hc is None:
+            x = constrain(x, self.mesh, RESIDUAL_SPEC)
+            x = x + mix(norm("ln_attn")(x))
+            y, of_second = second(norm("ln_mlp")(x), training)
+            return constrain(x + y, self.mesh, RESIDUAL_SPEC), {
+                **of_second, **aux}
+        x = constrain(x, self.mesh, STREAMS_SPEC)
+        u, write, attn_facts = HyperConnection(
+            self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)
+        x = write(mix(norm("ln_attn")(u)))
+        u, write, mlp_facts = HyperConnection(
+            self.hc, 2 * self.layer_index + 1, self.mesh, name="hc_mlp")(x)
+        y, of_second = second(norm("ln_mlp")(u), training)
+        aux["mhc"] = merge_hyper_facts([attn_facts, mlp_facts])
+        return constrain(write(y), self.mesh, STREAMS_SPEC), {
+            **of_second, **aux}
 
 
 def remat_block(block_cls, remat_policy, attention_impl):
@@ -1171,13 +1165,15 @@ class TransformerLM(nn.Module):
             )
         else:
             block_cls = Block
+        mixer = dict(
+            num_heads=self.num_heads, attention_impl=self.attention_impl,
+            dropout=self.dropout)
         for i in range(self.num_layers):
-            x = block_cls(
-                self.num_heads,
+            x, _ = block_cls(
+                mixer,
                 mlp_ratio=self.mlp_ratio,
-                attention_impl=self.attention_impl,
-                mesh=self.mesh,
                 dropout=self.dropout,
+                mesh=self.mesh,
                 name="block_%d" % i,
             )(x, training)
         x = constrain(nn.LayerNorm(name="ln_f")(x), self.mesh, RESIDUAL_SPEC)

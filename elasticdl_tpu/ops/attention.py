@@ -154,7 +154,7 @@ def _shard_over_mesh(kernel, mesh, spec, q):
     shard's attention. Inside a region that is already manual over the
     whole mesh (the pipeline's stage body) q/k/v are one shard and the
     kernel runs on them as is: a second shard_map cannot open there."""
-    if mesh.size == 1 or jax_compat.manual_over(mesh):
+    if jax_compat.nothing_to_partition(mesh):
         return kernel
     for dim, axes in enumerate(spec):
         if axes is None:

@@ -49,11 +49,11 @@ What is a kernel and what is not. On a TPU a segment of the rule is
 four Pallas kernels under one VJP (``_chunks_pallas``) and nothing of
 XLA's between them: ``gdn_prepare_fwd`` makes a chunk's operands,
 ``gdn_scan_fwd`` carries the state over them, and ``gdn_scan_bwd`` and
-``gdn_prepare_bwd`` are their VJPs (``prep=pallas scan=pallas`` on the
-rule's linear-attention line; ``impl=`` names what runs the inverses
-where the operands are XLA's). Everywhere else the same lines are jnp:
-``_chunk_operands`` with ``unit_lower_inverse`` in it, and
-``_scan_xla``.
+``gdn_prepare_bwd`` are their VJPs (``impl=pallas scan=pallas
+prep=pallas`` on the rule's linear-attention line; ``impl=`` says what
+runs the inverses, the kernels where ``prep`` is and XLA elsewhere).
+Everywhere else the same lines are jnp: ``_chunk_operands`` with
+``unit_lower_inverse`` in it, and ``_scan_xla``.
 
 The operands (PR 39). Left to XLA, everything above that does not meet
 the state is a dozen fusions around the inverse, each writing a
@@ -98,7 +98,7 @@ to the operands' rounding and not bit for bit (on the chip, against
 the XLA lines: dq 0.2%, dk 0.4%, dv 0.001%, dg 0.15%, dbeta 0.01% rms,
 PERF.md Section 6, PR 39). A segment's operands take 1.84 ms where the
 XLA lines took 3.79, their VJP 1.61 where autodiff took 4.71.
-``prepare_impl`` decides beside the two choosers below, from the same
+``prepare_impl`` decides beside ``scan_impl`` below, from the same
 things and with no switch for a user: wherever ``scan_impl`` says
 ``pallas`` (the kernel's results are laid out for ``gdn_scan_fwd``) and
 a block of the segment's chunks in whole 8-row tiles of ``g`` fits
@@ -106,10 +106,10 @@ a block of the segment's chunks in whole 8-row tiles of ``g`` fits
 ``_chunk_operands``, which stays as the path of the CPU, float64, other
 chunks, widths and meshes and as the tests' oracle.
 
-The inverse inside the kernel is the loop body ``gdn_inverse_fwd`` has
-(PR 32; ``_inverse_rows``, shared). Two 64 x 64 matrices lie side by
-side on the 128 lanes and meet a block-diagonal right operand, so a
-pass fills the MXU's depth (each output element stays the same sum of
+The inverse lives inside ``gdn_prepare_fwd`` (``_inverse_rows``). Two
+64 x 64 matrices lie side by side on the 128 lanes and meet a
+block-diagonal right operand, so a pass fills the MXU's depth (each
+output element stays the same sum of
 the same products; the other matrix's lanes meet zeros, so a nan or inf
 in one matrix reaches its lane neighbour's result too, where XLA kept
 it to its own: the step's health check sees either); ``inverse`` and
@@ -121,16 +121,9 @@ one pair's five dependent spans alone leave the MXU waiting (3.9 ms for
 6, PR 32). Its VJP (``_inverse_grad_rows``) runs ``T^T (dT T^T)``
 where the XLA path runs ``(T^T dT) T^T``: the same two products at the
 same precision in the other association, equal to float32 rounding and
-not bit for bit. ``gdn_inverse_fwd`` / ``gdn_inverse_bwd`` are that body
-over ``A`` and ``T`` in HBM, for ``unit_lower_inverse`` inside
-``_chunk_operands``: ``inverse_impl`` says ``pallas`` for a TPU,
-float32, chunk 64 or 128 and one device, ``xla`` (the product form in
-jnp) for the CPU, float64, another chunk or a mesh of several devices.
-Since PR 39 the rule itself calls them only where ``inverse_impl`` says
-``pallas`` and ``prepare_impl`` does not: key or value widths that are
-no whole 128-lane rows, a ``state_dtype`` / ``decay_dtype`` experiment,
-a ``v`` of another dtype than ``q``, or a segment whose chunks fit no
-block. A ``pallas_call`` has no GSPMD partitioning rule
+not bit for bit. Where the operands are XLA's, the inverse is
+``unit_lower_inverse``, the product form in jnp under its own VJP. A
+``pallas_call`` has no GSPMD partitioning rule
 (``ops/attention.py:_shard_over_mesh``), and the rule opens no
 ``shard_map`` of its own yet, so on a mesh it stays what GSPMD can
 partition.
@@ -168,9 +161,9 @@ entering state's on its right). Cotangents are rounded to the compute
 dtype where they are matmul operands, as the TPU's default precision
 rounds them for autodiff of the scan; the kernel sums ``dV'`` and
 ``dS`` in float32 and rounds once where autodiff rounds each term: equal
-to the operands' rounding and not bit for bit. ``scan_impl`` decides
-beside ``inverse_impl``, with no switch either: a TPU, operands
-bfloat16 or float32 with ``v`` in the same dtype, the float32 state and
+to the operands' rounding and not bit for bit. ``scan_impl`` decides,
+with no switch either: a TPU, operands bfloat16 or float32 with ``v``
+in the same dtype, the float32 state and
 decay (``state_dtype`` / ``decay_dtype`` at their defaults), key and
 value widths in whole 128-lane rows, chunk 64 or 128, one device or a
 region already manual over the mesh -> the kernels (``scan=pallas`` on
@@ -215,16 +208,10 @@ GDN_OUT_NAME = "gdn_out"
 DEFAULT_CHUNK = 64
 # chunks a segment: 8192 tokens at chunk 64
 DEFAULT_SEGMENT = 128
-# the chunks the inverse kernels take: a 128-lane row holds two
-# matrices of 64 or one of 128
+# the chunks the kernels take: a 128-lane row holds two matrices of 64
+# or one of 128
 _KERNEL_CHUNKS = (64, 128)
 _LANES = 128
-# VMEM a grid step's blocks may take (every operand and the result,
-# double-buffered), and the limit the kernels' pallas_calls state: the
-# v5e compiler's default, which the blocks and the loop body's spilled
-# chains (about 3 MiB) stay under
-_INVERSE_BLOCK_BYTES = 8 * 2**20
-_INVERSE_VMEM_LIMIT = 16 * 2**20
 # independent lane rows (pairs of 64 x 64 matrices) a loop iteration
 # interleaves
 _CHAINS = 8
@@ -272,42 +259,19 @@ def _exact(x, y):
     return jnp.matmul(x, y, precision=jax.lax.Precision.HIGHEST)
 
 
-def inverse_impl(dtype, size, mesh=None):
-    """``"pallas"`` or ``"xla"``: what runs the inverse of ``size`` x
-    ``size`` matrices of ``dtype`` in a step sharded over ``mesh``
-    (None: one device). Like every ``pallas_call`` the kernels cannot be
-    partitioned automatically, so they run where there is nothing to
-    partition: on one device, or inside a region that is already manual
-    over the whole mesh (module docstring)."""
-    fits = (
-        _kernels_can_run(mesh)
-        and dtype == jnp.float32
-        and size in _KERNEL_CHUNKS
-    )
-    return "pallas" if fits else "xla"
-
-
-def _kernels_can_run(mesh):
-    """A TPU backend with nothing to partition: one device, or a region
-    already manual over the whole mesh."""
-    one_device = (
-        mesh is None or mesh.size == 1 or jax_compat.manual_over(mesh))
-    return one_device and jax.default_backend() == "tpu"
-
-
 def scan_impl(dtype, chunk, dk, dv, state_dtype=jnp.float32,
               decay_dtype=jnp.float32, out_dtype=None, mesh=None):
     """``"pallas"`` or ``"xla"``: what carries the state from chunk to
-    chunk, from what ``inverse_impl`` sees and the rule's own shapes:
-    the ``gdn_scan_*`` kernels take operands of ``dtype`` bfloat16 or
-    float32 with the float32 state and decay, key and value widths in
-    whole 128-lane rows and a chunk of 64 or 128, and write ``o`` in
-    ``dtype`` (``out_dtype``, ``v``'s, has to be it: the rounding is
-    then the cast the rule ends with). Everything else, the tests'
-    ``state_dtype`` / ``decay_dtype`` experiments among it, is the
-    ``lax.scan``."""
+    chunk, from the backend, the mesh (``jax_compat.kernels_can_run``)
+    and the rule's own shapes: the ``gdn_scan_*`` kernels take operands
+    of ``dtype`` bfloat16 or float32 with the float32 state and decay,
+    key and value widths in whole 128-lane rows and a chunk of 64 or
+    128, and write ``o`` in ``dtype`` (``out_dtype``, ``v``'s, has to be
+    it: the rounding is then the cast the rule ends with). Everything
+    else, the tests' ``state_dtype`` / ``decay_dtype`` experiments among
+    it, is the ``lax.scan``."""
     fits = (
-        _kernels_can_run(mesh)
+        jax_compat.kernels_can_run(mesh)
         and dtype in (jnp.bfloat16, jnp.float32)
         and out_dtype in (None, dtype)
         and state_dtype == jnp.float32
@@ -319,35 +283,10 @@ def scan_impl(dtype, chunk, dk, dv, state_dtype=jnp.float32,
     return "pallas" if fits else "xla"
 
 
-def inverse_block(count, size, arrays):
-    """Matrices a grid step takes of ``count`` ``size`` x ``size``
-    float32 ones: as many as ``arrays`` double-buffered blocks (operands
-    and result; a matrix holds whole 128-lane rows in VMEM) fit in
-    ``_INVERSE_BLOCK_BYTES``, in whole loop iterations of ``_CHAINS``
-    lane rows, and no more than ``count`` needs."""
-    unit = _CHAINS * (_LANES // size)
-    fit = _INVERSE_BLOCK_BYTES // inverse_vmem_bytes(1, size, arrays)
-    return max(unit, min(fit, count + -count % unit) // unit * unit)
-
-
-def inverse_vmem_bytes(block, size, arrays):
-    """VMEM of ``arrays`` double-buffered blocks of ``block``
-    matrices."""
-    return 2 * arrays * block * size * _LANES * 4
-
-
 def _dot(x, y, contract=((1,), (0,))):
     return jax.lax.dot_general(
         x, y, (contract, ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
-
-
-def _lane_row(ref, first, pack):
-    """``pack`` matrices of ``ref`` from ``first`` on, side by side on
-    the lanes: (size, pack x size)."""
-    if pack == 1:
-        return ref[first]
-    return jnp.concatenate([ref[first + p] for p in range(pack)], axis=1)
 
 
 def _block_diagonal(x, size):
@@ -408,122 +347,32 @@ def _inverse_grad_rows(ts, ds, size):
     return grads
 
 
-def _inverse_fwd_kernel(a_ref, t_ref, *, size):
-    pack = _LANES // size
-
-    def body(step, carry):
-        first = [(step * _CHAINS + c) * pack for c in range(_CHAINS)]
-        rows = _inverse_rows([_lane_row(a_ref, f, pack) for f in first], size)
-        for f, i in zip(first, rows):
-            for m in range(pack):
-                t_ref[f + m] = i[:, m * size:(m + 1) * size]
-        return carry
-
-    jax.lax.fori_loop(0, a_ref.shape[0] // (_CHAINS * pack), body, 0)
-
-
-def _inverse_bwd_kernel(t_ref, d_ref, da_ref, *, size):
-    pack = _LANES // size
-
-    def body(step, carry):
-        first = [(step * _CHAINS + c) * pack for c in range(_CHAINS)]
-        grads = _inverse_grad_rows(
-            [_lane_row(t_ref, f, pack) for f in first],
-            [_lane_row(d_ref, f, pack) for f in first], size)
-        for f, das in zip(first, grads):
-            for m, da in enumerate(das):
-                da_ref[f + m] = da
-        return carry
-
-    jax.lax.fori_loop(0, t_ref.shape[0] // (_CHAINS * pack), body, 0)
-
-
-def _inverse_call(kernel, name, operands, interpret):
-    """``kernel`` over blocks of the (M, C, C) float32 ``operands``,
-    one (M, C, C) result. M is padded with zero matrices to whole
-    blocks (their inverse is I, their gradient 0) and cut again."""
-    count, size, _ = operands[0].shape
-    block = inverse_block(count, size, len(operands) + 1)
-    pad = -count % block
-    if pad:
-        operands = [
-            jnp.pad(x, ((0, pad), (0, 0), (0, 0))) for x in operands]
-    spec = pl.BlockSpec((block, size, size), lambda i: (i, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(kernel, size=size),
-        grid=((count + pad) // block,),
-        in_specs=[spec] * len(operands),
-        out_specs=spec,
-        out_shape=jax_compat.out_struct(
-            (count + pad, size, size), jnp.float32, *operands),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-            vmem_limit_bytes=_INVERSE_VMEM_LIMIT,
-        ),
-        interpret=interpret,
-        name=name,
-    )(*operands)
-    return out[:count] if pad else out
-
-
-# jitted so that every layer and segment of a model shares one trace of
-# the kernel's body; always inside the step's own trace, where the
-# recompile sentinel's host bookkeeping cannot run
-@functools.partial(  # edlint: disable=obs-bare-jit
-    jax.jit, static_argnames=("interpret",))
-def gdn_inverse_fwd(a, interpret=False):
-    """``(I + a)^-1`` of strictly lower ``a`` (M, C, C) float32, C 64
-    or 128, by the product form at matmul precision highest, a block
-    of matrices held in VMEM through all of its products."""
-    return _inverse_call(
-        _inverse_fwd_kernel, "gdn_inverse_fwd", [a], interpret)
-
-
-@functools.partial(  # edlint: disable=obs-bare-jit (as above)
-    jax.jit, static_argnames=("interpret",))
-def gdn_inverse_bwd(inverse, d_inverse, interpret=False):
-    """``-T^T dT T^T`` for ``T = inverse``, both (M, C, C) float32."""
-    return _inverse_call(
-        _inverse_bwd_kernel, "gdn_inverse_bwd", [inverse, d_inverse],
-        interpret)
-
-
-def _flat(x):
-    return x.reshape((-1,) + x.shape[-2:])
-
-
-def unit_lower_inverse(a, mesh=None):
+def unit_lower_inverse(a):
     """``(I + a)^-1`` for strictly lower triangular ``a`` (..., C, C),
-    C a power of two, by the product form (module docstring), run by
-    what ``inverse_impl`` says for ``a`` in a step over ``mesh``. Its
+    C a power of two, by the product form (module docstring). Its
     gradient is the inverse's own, ``da = -T^T dT T^T``, from ``T``
     alone: autodiff of the product would keep every square and every
     partial product, ten C x C float32 matrices a chunk and head where
     this keeps one."""
-    return _inverse(a, inverse_impl(a.dtype, a.shape[-1], mesh))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _inverse(a, impl):
-    """``unit_lower_inverse`` by ``impl``, as ``inverse_impl`` gave it
-    for ``a``."""
     size = a.shape[-1]
     if size & (size - 1):
         raise ValueError("the chunk must be a power of two, got %d" % size)
-    if impl == "pallas":
-        return gdn_inverse_fwd(_flat(a)).reshape(a.shape)
+    return _inverse(a)
+
+
+# a traced step names the call by this function (the recorded jaxprs of
+# ``tests/test_qkv_conv_kernels.py`` read ``name=_inverse``)
+@jax.custom_vjp
+def _inverse(a):
     return _inverse_product(a)
 
 
-def _inverse_vjp_fwd(a, impl):
-    inverse = _inverse(a, impl)
+def _inverse_vjp_fwd(a):
+    inverse = _inverse(a)
     return inverse, inverse
 
 
-def _inverse_vjp_bwd(impl, inverse, d_inverse):
-    if impl == "pallas":
-        return (gdn_inverse_bwd(_flat(inverse), _flat(d_inverse)).reshape(
-            inverse.shape),)
+def _inverse_vjp_bwd(inverse, d_inverse):
     t = jnp.swapaxes(inverse, -1, -2)
     return (-_exact(_exact(t, d_inverse), t),)
 
@@ -888,7 +737,7 @@ def prepare_impl(dtype, chunk, dk, dv, rep, chunks, state_dtype=jnp.float32,
                  decay_dtype=jnp.float32, out_dtype=None, mesh=None):
     """``"pallas"`` or ``"xla"``: what makes a chunk's operands (``A``,
     its inverse, ``U``, ``W``, the decayed keys and queries, ``P``),
-    from what ``inverse_impl`` and ``scan_impl`` see: the
+    from what ``scan_impl`` sees: the
     ``gdn_prepare_*`` kernels write what ``gdn_scan_fwd`` reads where it
     reads it, so they run where the scan's kernels do (a TPU, one device
     or a manual region, operands bfloat16 or float32, float32 decay and
@@ -1015,7 +864,7 @@ def _prepare_bwd_kernel(q_ref, k_ref, v_ref, de_ref, dw_ref, dko_ref, dqi_ref,
     and the six cotangents, the decays and the products of the forward
     made again in VMEM: ``dT = [dU | dW] [beta V | beta K e^G]^T``,
     ``dA = -T^T dT T^T`` (float32, precision highest, as
-    ``gdn_inverse_bwd``), then products with the decay matrix, row sums
+    ``_inverse_vjp_bwd``), then products with the decay matrix, row sums
     and one reverse cumulated sum a chunk; ``dq`` and ``dk`` summed over
     the key head's value heads here. Cotangents are matmul operands in
     the compute dtype, every sum float32."""
@@ -1215,28 +1064,27 @@ _chunks_pallas.defvjp(_chunks_vjp_fwd, _chunks_vjp_bwd)
 
 
 @functools.lru_cache(maxsize=None)
-def _log_once(hk, hv, dk, chunk, impl, scan, prep, tokens):
+def _log_once(hk, hv, dk, chunk, scan, prep, tokens):
     """One line per distinct call of the rule (this runs at trace time),
     beside the attention line of ``ops/attention.py``, from where the
-    paths are chosen. ``impl``: what runs the chunks' inverses,
-    ``pallas`` (the ``gdn_inverse_*`` kernels) or ``xla``; ``scan``:
-    what carries the state from chunk to chunk, ``pallas`` (the
-    ``gdn_scan_*`` kernels) or ``xla`` (a ``lax.scan``); ``prep``: what
-    makes the chunks' operands, ``pallas`` (the ``gdn_prepare_*``
-    kernels, the inverses inside them) or ``xla`` (``_chunk_operands``
-    around the inverses ``impl`` names)."""
+    paths are chosen. ``scan``: what carries the state from chunk to
+    chunk, ``pallas`` (the ``gdn_scan_*`` kernels) or ``xla`` (a
+    ``lax.scan``); ``prep``: what makes the chunks' operands, ``pallas``
+    (the ``gdn_prepare_*`` kernels, the inverses inside them) or ``xla``
+    (``_chunk_operands`` around ``unit_lower_inverse``); ``impl``: what
+    runs the chunks' inverses, which is what ``prep`` says."""
     logger.info(
         "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
-        "scan=%s prep=%s (tokens=%d)", hk, hv, dk, chunk, impl, scan, prep,
+        "scan=%s prep=%s (tokens=%d)", hk, hv, dk, chunk, prep, scan, prep,
         tokens)
 
 
-def _chunk_operands(q, k, v, g, beta, decay_dtype, impl):
+def _chunk_operands(q, k, v, g, beta, decay_dtype):
     """Everything of the rule that does not meet the state, batched
     over all chunks: q, k (B, Hk, 1, N, C, Dk), v (B, Hk, R, N, C, Dv),
     g, beta (B, Hk, R, N, C) float32 -> (G_last (B, Hk, R, N, 1), W, the
     decayed keys, Q~, P, U), float32 but ``W`` and the keys, which are
-    matmul operands only. ``impl``: what runs the inverses."""
+    matmul operands only."""
     dtype, chunk = q.dtype, q.shape[-2]
     # G, from the chunk's first token
     cum = jnp.cumsum(g.astype(decay_dtype), axis=-1).astype(g.dtype)
@@ -1253,7 +1101,7 @@ def _chunk_operands(q, k, v, g, beta, decay_dtype, impl):
     kt = jnp.swapaxes(k, -1, -2)
     kk = _matmul(k, kt, dtype)  # (B, Hk, 1, N, C, C)
     a = jnp.where(row > col, kk * beta[..., :, None] * decay, 0.0)
-    t = _inverse(a, impl)
+    t = unit_lower_inverse(a)
     u = _matmul(t, beta[..., None] * v, dtype)
     # matmul operands only from here on: kept in the compute dtype
     w = _matmul(t, (beta * into)[..., None] * k, dtype).astype(dtype)
@@ -1309,18 +1157,17 @@ def _scan_pallas(state, last, w, k_onto, q_into, attn, u, dtype):
         last, w, k_onto, q_into, attn, u, dtype))
 
 
-def _chunks(state, q, k, v, g, beta, decay_dtype, impl, scan, prep):
+def _chunks(state, q, k, v, g, beta, decay_dtype, scan, prep):
     """The rule over whole chunks from the state ``state`` (in the
     dtype it is carried in): -> (the state after them, o (B, Hk, R, N,
-    C, Dv)). ``impl``: what runs the inverses; ``scan``: what carries
-    the state; ``prep``: what makes the chunks' operands (``pallas``:
-    the four kernels under one VJP, nothing of XLA's between them)."""
+    C, Dv)). ``scan``: what carries the state; ``prep``: what makes
+    the chunks' operands (``pallas``: the four kernels under one VJP,
+    nothing of XLA's between them)."""
     if prep == "pallas":
         return _chunks_pallas(state, q, k, v, g, beta)
     carry = _scan_pallas if scan == "pallas" else _scan_xla
     return carry(
-        state, *_chunk_operands(q, k, v, g, beta, decay_dtype, impl),
-        q.dtype)
+        state, *_chunk_operands(q, k, v, g, beta, decay_dtype), q.dtype)
 
 
 def segments_of(seq, chunk=DEFAULT_CHUNK, segment=DEFAULT_SEGMENT):
@@ -1350,8 +1197,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     ``S`` in and what the decay is cumulated in (None: float32);
     anything else is for the tests and the benchmark's precision
     experiment (``scripts/gdn_precision.py``). ``mesh``: the mesh the
-    caller's step is sharded over, if any (``inverse_impl``: the
-    inverses' kernels run on one device); the rule itself places
+    caller's step is sharded over, if any (``scan_impl``: the kernels
+    run where nothing is left to partition); the rule itself places
     nothing and leaves its layout over the mesh to GSPMD."""
     batch, hk, seq, dk = q.shape
     hv, dv = v.shape[1], v.shape[3]
@@ -1362,8 +1209,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     wide = jnp.promote_types(q.dtype, jnp.float32)
     state_dtype = state_dtype or wide
     decay_dtype = decay_dtype or wide
-    # the chunks' matrices are ``wide`` whatever q is
-    impl = inverse_impl(wide, chunk, mesh)
     scan = scan_impl(
         q.dtype, chunk, dk, dv, state_dtype, decay_dtype, v.dtype, mesh)
     pad, segments = segments_of(seq, chunk, segment)
@@ -1375,7 +1220,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     prep = prepare_impl(
         q.dtype, chunk, dk, dv, rep, num, state_dtype, decay_dtype, v.dtype,
         mesh)
-    _log_once(hk, hv, dk, chunk, impl, scan, prep, batch * seq)
+    _log_once(hk, hv, dk, chunk, scan, prep, batch * seq)
     # segments first; key-like (B, Hk, 1, N, C, Dk), value-like (B, Hk,
     # R, N, C, ...)
     split = lambda x, heads, *rest: jnp.moveaxis(
@@ -1386,8 +1231,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
         split(v, (hk, rep), dv),
         split(g.astype(wide), (hk, rep)), split(beta.astype(wide), (hk, rep)),
     )
-    run = lambda state, xs: _chunks(
-        state, *xs, decay_dtype, impl, scan, prep)
+    run = lambda state, xs: _chunks(state, *xs, decay_dtype, scan, prep)
     state0 = jnp.zeros((batch, hk, rep, dk, dv), state_dtype)
     if segments == 1:
         _, o = run(state0, tuple(x[0] for x in xs))

@@ -92,7 +92,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-from elasticdl_tpu.ops.gated_delta import _kernels_can_run
 
 logger = _logger_factory("elasticdl_tpu.ops.hyper_connection")
 
@@ -139,7 +138,7 @@ def mix_impl(dtype, streams, dim, tokens, mesh=None):
     ``streams`` streams of ``dtype``, ``dim`` wide, ``tokens`` a
     sequence, in a step sharded over ``mesh`` (None: one device)."""
     fits = (
-        _kernels_can_run(mesh)
+        jax_compat.kernels_can_run(mesh)
         and dtype in (jnp.bfloat16, jnp.float32)
         and 1 <= streams <= _MAX_STREAMS
         and dim % _LANES == 0

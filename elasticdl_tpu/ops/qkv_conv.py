@@ -78,7 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-from elasticdl_tpu.ops.gated_delta import _kernels_can_run, segments_of
+from elasticdl_tpu.ops.gated_delta import segments_of
 
 logger = _logger_factory("elasticdl_tpu.ops.qkv_conv")
 
@@ -150,7 +150,7 @@ def conv_impl(dtype, dk, dv, seq, taps, mesh=None):
     XLA's 32.3.
     """
     fits = (
-        _kernels_can_run(mesh)
+        jax_compat.kernels_can_run(mesh)
         and dtype in (jnp.bfloat16, jnp.float32)
         and dk == dv
         and dk % _LANES == 0

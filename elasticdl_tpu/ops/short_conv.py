@@ -67,7 +67,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-from elasticdl_tpu.ops.gated_delta import _kernels_can_run
 from elasticdl_tpu.ops.qkv_conv import (
     _HALO,
     _LANES,
@@ -171,7 +170,7 @@ def conv_impl(dtype, channels, seq, taps, mesh=None):
     """
     dtype = jnp.dtype(dtype)
     fits = (
-        _kernels_can_run(mesh)
+        jax_compat.kernels_can_run(mesh)
         and dtype in (jnp.bfloat16, jnp.float32)
         and channels % _LANES == 0
         and row_tile(seq, channels, dtype.itemsize) is not None

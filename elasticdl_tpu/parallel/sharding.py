@@ -58,7 +58,7 @@ def constrain(x, mesh, spec):
     constraint there names axes that are of type Manual and is
     refused; the test ``ops/attention.py:_shard_over_mesh`` makes).
     """
-    if mesh is None or mesh.size == 1 or jax_compat.manual_over(mesh):
+    if jax_compat.nothing_to_partition(mesh):
         return x
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 

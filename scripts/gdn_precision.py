@@ -1,7 +1,7 @@
 """The Qwen3-Next configuration's reference check over several seeds and
 under two lower precisions of the chunked gated delta rule, and the
-inverse alone, the chunk-to-chunk scan alone and the rule alone against
-the clock, as this backend runs them and by XLA (one chip, ~20 min).
+chunk-to-chunk scan alone and the rule alone against the clock, as this
+backend runs them and by XLA (one chip, ~20 min).
 
     python scripts/gdn_precision.py --seeds 8 --variant-seeds 2
 
@@ -42,33 +42,6 @@ def _clock(jax, fn, args, repeats):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.time() - t0) / repeats * 1e3
-
-
-def time_inverse(jax, jnp, gated_delta, chunk, count=4096, repeats=30):
-    """The inverse alone at a segment's batch (``count`` matrices with
-    entries of the cell's size), ms: what ``unit_lower_inverse`` runs on
-    this backend (the ``gdn_inverse_*`` kernels on a TPU) beside XLA's
-    product form, forward and VJP, and the largest difference."""
-    a = jnp.tril(0.2 * jax.random.normal(
-        jax.random.PRNGKey(0), (count, chunk, chunk)), -1)
-    d = jax.random.normal(jax.random.PRNGKey(1), a.shape)
-    impl = gated_delta.inverse_impl(a.dtype, chunk)
-    out = {"impl": impl}
-    forward = jax.jit(gated_delta.unit_lower_inverse)
-    xla = jax.jit(gated_delta._inverse_product)
-    vjp = lambda impl: jax.jit(
-        lambda t, d: gated_delta._inverse_vjp_bwd(impl, t, d)[0])
-    t = xla(a)
-    out["forward_ms"] = _clock(jax, forward, (a,), repeats)
-    out["forward_xla_ms"] = _clock(jax, xla, (a,), repeats)
-    out["forward_max_abs_difference"] = float(
-        jnp.abs(forward(a) - t).max())
-    out["backward_ms"] = _clock(jax, vjp(impl), (t, d), repeats)
-    out["backward_xla_ms"] = _clock(jax, vjp("xla"), (t, d), repeats)
-    got, want = vjp(impl)(t, d), vjp("xla")(t, d)
-    out["backward_max_relative_difference"] = float(
-        jnp.abs(got - want).max() / jnp.abs(want).max())
-    return out
 
 
 def rule_inputs(jax, jnp, tokens=32768):
@@ -116,8 +89,8 @@ def time_scan(jax, jnp, gated_delta, chunk, repeats=20):
     q, k, v, g, beta = rule_inputs(jax, jnp, chunk * gated_delta.DEFAULT_SEGMENT)
     split = lambda x, heads, *rest: x.reshape(
         (1,) + heads + (gated_delta.DEFAULT_SEGMENT, chunk) + rest)
-    operands = jax.jit(lambda *a: gated_delta._chunk_operands(
-        *a, jnp.float32, gated_delta.inverse_impl(jnp.float32, chunk)))(
+    operands = jax.jit(
+        lambda *a: gated_delta._chunk_operands(*a, jnp.float32))(
             split(q, (16, 1), 128), split(k, (16, 1), 128),
             split(v, (16, 2), 128), split(g, (16, 2)), split(beta, (16, 2)))
     dtype = q.dtype
@@ -174,23 +147,20 @@ def time_scan(jax, jnp, gated_delta, chunk, repeats=20):
 
 def time_rule(jax, jnp, gated_delta, chunk, repeats=3):
     """The rule alone at the cell's shape, forward and forward +
-    backward, ms: as this backend runs it (``chosen``: the inverse's and
-    the scan's kernels on a TPU), with the ``lax.scan`` in the kernels'
-    place (``scan_xla``), and all by XLA with the inverse's product form
-    at matmul precision highest and high."""
+    backward, ms: as this backend runs it (``chosen``: the operands'
+    and the scan's kernels on a TPU), and all by XLA with the inverse's
+    product form at matmul precision highest and high."""
     args = rule_inputs(jax, jnp)
-    out = {"impl": gated_delta.inverse_impl(jnp.float32, chunk)}
-    chosen, chosen_scan = gated_delta.inverse_impl, gated_delta.scan_impl
+    chosen = gated_delta.scan_impl
+    out = {"impl": chosen(jnp.bfloat16, chunk, 128, 128)}
     xla = lambda *a, **kw: "xla"
-    for name, impl, scan, precision in (
-            ("chosen", chosen, chosen_scan, jax.lax.Precision.HIGHEST),
-            ("scan_xla", chosen, xla, jax.lax.Precision.HIGHEST),
-            ("highest", xla, xla, jax.lax.Precision.HIGHEST),
-            ("high", xla, xla, jax.lax.Precision.HIGH)):
+    for name, scan, precision in (
+            ("chosen", chosen, jax.lax.Precision.HIGHEST),
+            ("highest", xla, jax.lax.Precision.HIGHEST),
+            ("high", xla, jax.lax.Precision.HIGH)):
         exact = lambda x, y, p=precision: jnp.matmul(x, y, precision=p)
         saved = gated_delta._exact
-        gated_delta._exact, gated_delta.inverse_impl = exact, impl
-        gated_delta.scan_impl = scan
+        gated_delta._exact, gated_delta.scan_impl = exact, scan
         try:
             grad = jax.jit(jax.grad(
                 lambda *a: gated_delta.gated_delta_rule(
@@ -202,8 +172,7 @@ def time_rule(jax, jnp, gated_delta, chunk, repeats=3):
                 out["%s_%s_ms" % (label, name)] = _clock(
                     jax, fn, args, repeats)
         finally:
-            gated_delta._exact, gated_delta.inverse_impl = saved, chosen
-            gated_delta.scan_impl = chosen_scan
+            gated_delta._exact, gated_delta.scan_impl = saved, chosen
     return out
 
 
@@ -245,9 +214,6 @@ def main(argv=None):
     report = {"device": [device.platform, device.device_kind], "runs": []}
     if not args.no_timing:
         chunk = config["assumed"]["gdn_chunk"]
-        report["inverse_alone"] = time_inverse(jax, jnp, gated_delta, chunk)
-        print("inverse alone:", json.dumps(report["inverse_alone"]),
-              flush=True)
         report["scan_alone"] = time_scan(jax, jnp, gated_delta, chunk)
         print("scan alone:", json.dumps(report["scan_alone"]), flush=True)
         report["rule_alone"] = time_rule(jax, jnp, gated_delta, chunk)

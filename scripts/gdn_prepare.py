@@ -10,8 +10,8 @@ Times, ms a call: ``gdn_prepare_fwd`` (as the step's forward calls it
 and, with ``T`` written, as the segment's recompute does) and
 ``gdn_prepare_bwd`` with the bytes their operands and results hold and
 their GB/s; ``_chunk_operands`` with the casts ``_scan_pallas`` adds
-(the ``gdn_inverse_*`` kernels in the middle, as PR 32-38 ran it), its
-forward and its forward + VJP; then ``gated_delta_rule`` at 32,768
+(the inverses the product form by XLA), its forward and its forward +
+VJP; then ``gated_delta_rule`` at 32,768
 tokens, forward and gradient, as ``prepare_impl`` chooses on the chip
 (``prep=pallas``) and with the choice held to the XLA lines
 (``prep=xla``). Checks the kernels' results and gradients against the
@@ -90,9 +90,8 @@ def segment_operands(chunk):
 
 def xla_lines(q, k, v, g, beta):
     """What ``_chunks`` hands ``gdn_scan_fwd`` with ``prep=xla``."""
-    return G._scan_operands(*G._chunk_operands(
-        q, k, v, g, beta, jnp.float32,
-        G.inverse_impl(jnp.float32, q.shape[4])), q.dtype)
+    return G._scan_operands(
+        *G._chunk_operands(q, k, v, g, beta, jnp.float32), q.dtype)
 
 
 def kernels_alone(chunk, calls):

@@ -58,8 +58,8 @@ def _value_and_grads(rule, args, jaxpr=False):
 def _force_pallas(monkeypatch):
     """What a TPU backend would choose, run by the interpreter."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    for name in ("gdn_inverse_fwd", "gdn_inverse_bwd", "gdn_scan_fwd",
-                 "gdn_scan_bwd", "gdn_prepare_fwd", "gdn_prepare_bwd"):
+    for name in ("gdn_scan_fwd", "gdn_scan_bwd", "gdn_prepare_fwd",
+                 "gdn_prepare_bwd"):
         monkeypatch.setattr(gated_delta, name, functools.partial(
             getattr(gated_delta, name), interpret=True))
 
@@ -86,7 +86,7 @@ def _xla_lines(q, k, v, g, beta):
     runs them; dispatched an operation at a time they cost more than
     the kernel they are held against."""
     return gated_delta._scan_operands(*gated_delta._chunk_operands(
-        q, k, v, g, beta, jnp.float32, "xla"), q.dtype)
+        q, k, v, g, beta, jnp.float32), q.dtype)
 
 
 _MESH4 = "a four-device mesh"

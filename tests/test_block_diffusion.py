@@ -16,7 +16,8 @@ import pytest
 
 from elasticdl_tpu.data.pipeline import MASK_KEY
 from elasticdl_tpu.models import moe_transformer
-from elasticdl_tpu.models.moe_transformer import MoeBlock, MoeTransformerLM
+from elasticdl_tpu.models.moe_transformer import MoeTransformerLM
+from elasticdl_tpu.models.transformer import Block
 from elasticdl_tpu.ops import block_diffusion as bd
 from elasticdl_tpu.ops import flash_attention as F
 from elasticdl_tpu.train.step_fns import make_train_step, step_rngs
@@ -193,14 +194,15 @@ class Definition(nn.Module):
     def __call__(self, tokens):
         x = nn.Embed(VOCAB, FIELDS["embed_dim"], name="wte")(tokens)
         for i in range(FIELDS["num_layers"]):
-            x, _ = MoeBlock(
-                FIELDS["num_heads"], FIELDS["num_experts"],
-                **{k: FIELDS[k] for k in (
-                    "top_k", "dispatch_impl", "expert_dim", "expert_act",
-                    "norm", "rope_theta", "held_experts", "held_rows",
-                    "head_dim", "num_kv_heads", "head_norm",
-                    "attention_impl")},
-                mask=BlockCausal(BLOCK), name="block_%d" % i)(x)
+            x, _ = Block(
+                dict({k: FIELDS[k] for k in (
+                    "num_heads", "rope_theta", "head_dim", "num_kv_heads",
+                    "head_norm", "attention_impl")},
+                    mask=BlockCausal(BLOCK)),
+                {k: FIELDS[k] for k in (
+                    "num_experts", "top_k", "dispatch_impl", "expert_dim",
+                    "expert_act", "held_experts", "held_rows")},
+                norm=FIELDS["norm"], name="block_%d" % i)(x)
         x = nn.RMSNorm(epsilon=1e-6, name="ln_f")(x)
         return nn.Dense(VOCAB, use_bias=False, name="lm_head")(x)
 

@@ -22,7 +22,6 @@ from elasticdl_tpu.ops.gated_delta import (
     unit_lower_inverse,
 )
 from tests.gdn_common import (  # noqa: F401
-    _force_pallas,
     _inputs,
     _value_and_grads,
     x64,
@@ -95,158 +94,72 @@ def test_the_inverse_takes_a_power_of_two():
         unit_lower_inverse(jnp.zeros((48, 48)))
 
 
-# ------------------------------------------- the inverse's kernels
-# ``gdn_inverse_fwd`` / ``gdn_inverse_bwd`` in interpret mode on the CPU
-# (float32; ISSUE 32). What the chip's compiler makes of them is
-# tests/test_flash_tpu_compile.py's.
+# ----------------------------------------------- the choice of path
+# What runs the rule is chosen from the backend, the dtype, the shapes
+# and the mesh; the rule's line says which. The kernels themselves are
+# tests/test_gated_delta_scan*.py's and test_gated_delta_operands*.py's.
 
 
-def _lower(kind, count, size, seed=0):
-    """``count`` strictly lower matrices with entries of the size the
-    cell has: ``0.2 x randn``, or ``beta K K^T`` from l2-normalised
-    keys."""
-    rng = np.random.RandomState(seed + size)
-    if kind == "randn":
-        a = 0.2 * rng.randn(count, size, size)
-    else:
-        k = rng.randn(count, size, 32)
-        k /= np.linalg.norm(k, axis=-1, keepdims=True)
-        beta = 1 / (1 + np.exp(-rng.randn(count, size, 1)))
-        a = beta * np.einsum("mik,mjk->mij", k, k)
-    return jnp.asarray(np.tril(a, -1), jnp.float32)
-
-
-@pytest.mark.parametrize("kind", ["randn", "keys"])
-@pytest.mark.parametrize("size,count", [
-    (64, 32),    # two whole loop iterations of eight pairs
-    (64, 21),    # a count neither the block nor a pair divides
-    (128, 8),
-    (128, 3),
-], ids=["64-whole", "64-ragged", "128-whole", "128-ragged"])
-def test_the_kernel_s_inverse_is_the_product_form_s(kind, size, count):
-    a = _lower(kind, count, size)
-    got = gated_delta.gdn_inverse_fwd(a, interpret=True)
-    assert got.shape == a.shape and got.dtype == jnp.float32
-    want = gated_delta._inverse_product(a)
-    np.testing.assert_allclose(got, want, rtol=0, atol=2e-6 * (
-        1 + float(jnp.abs(want).max())))
-    eye = np.eye(size)
-    np.testing.assert_allclose(
-        np.float64(got) @ (eye + np.float64(a)),
-        np.broadcast_to(eye, a.shape), atol=1e-5)
-
-
-@pytest.mark.parametrize("size,count", [(64, 21), (128, 3)],
-                         ids=["64", "128"])
-def test_the_kernel_s_vjp(monkeypatch, size, count):
-    """Against today's ``-T^T dT T^T`` and against autodiff of
-    ``jnp.linalg.inv``."""
-    a = _lower("randn", count, size)
-    rng = np.random.RandomState(size)
-    weight = jnp.asarray(rng.randn(count, size, size), jnp.float32)
-    inverse = gated_delta._inverse_product(a)
-    got = gated_delta.gdn_inverse_bwd(inverse, weight, interpret=True)
-    want, = gated_delta._inverse_vjp_bwd("xla", inverse, weight)
-    scale = float(jnp.abs(want).max())
-    np.testing.assert_allclose(got, want, rtol=0, atol=3e-6 * scale)
-    # through the custom_vjp, the kernels chosen as a TPU would
-    _force_pallas(monkeypatch)
-    eye = jnp.eye(size)
-    got = jax.grad(lambda a: jnp.sum(unit_lower_inverse(a) * weight))(a)
-    solve = jax.grad(
-        lambda a: jnp.sum(jnp.linalg.inv(eye + a) * weight))(a)
-    np.testing.assert_allclose(got, solve, rtol=0, atol=1e-5 * scale)
-
-
-def test_the_rule_s_gradients_are_equal_between_the_two_paths(monkeypatch):
-    """``jax.grad`` of the rule at 512 tokens, chunk 64, float32: the
-    kernels' path against XLA's, to float32 rounding."""
-    args = _inputs(512, jnp.float32, decay=2.0, batch=1)
-    rule = lambda *a: gated_delta_rule(*a, chunk=64)
-    assert gated_delta.inverse_impl(jnp.float32, 64) == "xla"
-    want = _value_and_grads(rule, args)
-    _force_pallas(monkeypatch)
-    assert gated_delta.inverse_impl(jnp.float32, 64) == "pallas"
-    text, got = _value_and_grads(rule, args, jaxpr=True)
-    assert "gdn_inverse_fwd" in text
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * (
-            1e-3 + float(jnp.abs(b).max())))
-
-
-@pytest.mark.parametrize("backend,dtype,size,devices,impl", [
-    ("cpu", "float32", 64, 1, "xla"),
-    ("tpu", "float64", 64, 1, "xla"),
-    ("tpu", "bfloat16", 64, 1, "xla"),
-    ("tpu", "float32", 16, 1, "xla"),
-    ("tpu", "float32", 2, 1, "xla"),
-    ("tpu", "float32", 64, 1, "pallas"),
-    ("tpu", "float32", 128, 1, "pallas"),
+@pytest.mark.parametrize("backend,dtype,chunk,dim,rep,chunks,devices,paths", [
+    ("cpu", "bfloat16", 64, 128, 2, 2, 1, "impl=xla scan=xla prep=xla"),
+    ("tpu", "bfloat16", 64, 128, 2, 2, 1,
+     "impl=pallas scan=pallas prep=pallas"),
+    ("tpu", "float32", 128, 128, 2, 2, 1,
+     "impl=pallas scan=pallas prep=pallas"),
+    ("tpu", "float16", 64, 128, 2, 2, 1, "impl=xla scan=xla prep=xla"),
+    ("tpu", "bfloat16", 16, 128, 2, 2, 1, "impl=xla scan=xla prep=xla"),
+    # heads that are no whole 128-lane rows
+    ("tpu", "bfloat16", 64, 8, 2, 2, 1, "impl=xla scan=xla prep=xla"),
+    # a segment whose chunks fit no block of the operands' kernels: the
+    # operands by XLA, the inverses among them, the state by the scan's
+    ("tpu", "bfloat16", 64, 256, 4, 8, 1, "impl=xla scan=pallas prep=xla"),
     # a pallas_call has no partitioning rule: on a mesh of several
-    # devices the product form stays what GSPMD can partition
-    ("tpu", "float32", 64, 4, "xla"),
-], ids=lambda v: str(v))
+    # devices the rule stays what GSPMD can partition
+    ("tpu", "bfloat16", 64, 128, 2, 2, 4, "impl=xla scan=xla prep=xla"),
+], ids=lambda v: str(v).replace(" ", "-"))
 def test_the_choice_of_path(
-        monkeypatch, caplog, backend, dtype, size, devices, impl):
-    """From the backend, the matrices' dtype, the chunk and the mesh
-    alone; the rule's line says which (its matrices are float32
-    whatever it computes in, bfloat16 here as in the cell)."""
+        monkeypatch, caplog, backend, dtype, chunk, dim, rep, chunks,
+        devices, paths):
+    """From the backend, the dtype, the chunk, the widths, the segment
+    and the mesh alone; ``impl=`` says what runs the inverses, which is
+    what ``prep=`` says. Shapes alone are read: nothing runs."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     mesh = Mesh(np.array(jax.devices()[:devices]), ("data",))
-    assert gated_delta.inverse_impl(jnp.dtype(dtype), size, mesh) == impl
-    if devices == 1:
-        assert gated_delta.inverse_impl(jnp.dtype(dtype), size) == impl
-    dims = GatedDeltaDims(
-        num_key_heads=1, num_value_heads=2, key_head_dim=8,
-        value_head_dim=8, conv_kernel_dim=4, chunk=size)
-    layer = GatedDeltaNet(dims, mesh=mesh)
-    x = jax.ShapeDtypeStruct((1, 2 * size, 16), jnp.bfloat16)
+    tokens = chunks * chunk
+    struct = lambda heads, *rest: jax.ShapeDtypeStruct(
+        (1, heads, tokens) + rest, jnp.dtype(dtype))
+    wide = lambda: jax.ShapeDtypeStruct((1, rep, tokens), jnp.float32)
     gated_delta._log_once.cache_clear()
     with caplog.at_level(logging.INFO):
-        jax.eval_shape(
-            lambda x: layer.init_with_output(jax.random.PRNGKey(0), x)[0], x)
+        out = jax.eval_shape(
+            lambda *a: gated_delta_rule(*a, chunk=chunk, mesh=mesh),
+            struct(1, dim), struct(1, dim), struct(rep, dim), wide(), wide())
     gated_delta._log_once.cache_clear()
-    assert "chunk=%d impl=%s scan=xla prep=xla (tokens=%d)" % (
-        size, gated_delta.inverse_impl(jnp.float32, size, mesh), 2 * size
-    ) in caplog.text
+    assert out.shape == (1, rep, tokens, dim)
+    assert "dim=%d chunk=%d %s (tokens=%d)" % (
+        dim, chunk, paths, tokens) in caplog.text
 
 
 def test_the_kernels_run_inside_a_region_manual_over_the_mesh(monkeypatch):
     """Where the caller has already opened a ``shard_map`` over the
-    whole mesh (the pipeline's stage body) the matrices are one shard,
+    whole mesh (the pipeline's stage body) the arrays are one shard,
     and the kernels take them."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    choice = lambda: (
+        jax_compat.kernels_can_run(mesh),
+        gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128, mesh=mesh))
     seen = []
 
     def shard(x):
-        seen.append(gated_delta.inverse_impl(jnp.float32, 64, mesh))
+        seen.append(choice())
         return x
 
     jax.eval_shape(jax_compat.shard_map(
         shard, mesh=mesh, in_specs=P("data"), out_specs=P("data")),
         jnp.zeros(4))
-    assert seen == ["pallas"]
-    assert gated_delta.inverse_impl(jnp.float32, 64, mesh) == "xla"
-
-
-def test_the_block_fits_its_budget():
-    """The matrices a grid step takes, from shapes: whole loop
-    iterations, every double-buffered block inside the budget, the
-    budget inside the limit the kernels state."""
-    assert gated_delta.inverse_block(4096, 64, 2) == 64
-    assert gated_delta.inverse_block(4096, 64, 3) == 32
-    assert gated_delta.inverse_block(4096, 128, 2) == 32
-    assert gated_delta.inverse_block(4096, 128, 3) == 16
-    assert gated_delta.inverse_block(3, 64, 2) == 16
-    assert gated_delta.inverse_block(3, 128, 3) == 8
-    for size in (64, 128):
-        for arrays in (2, 3):
-            block = gated_delta.inverse_block(4096, size, arrays)
-            assert gated_delta.inverse_vmem_bytes(
-                block, size, arrays) <= gated_delta._INVERSE_BLOCK_BYTES
-    assert (gated_delta._INVERSE_BLOCK_BYTES
-            < gated_delta._INVERSE_VMEM_LIMIT)
+    assert seen == [(True, "pallas")]
+    assert choice() == (False, "xla")
 
 
 def test_precision_rules_in_bfloat16():

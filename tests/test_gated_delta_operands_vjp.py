@@ -130,7 +130,7 @@ def test_the_operands_kernels_hold_bfloat16_s_rounding(monkeypatch):
 def test_the_choice_of_the_operands_kernels(
         monkeypatch, caplog, x64, backend, dtype, chunk, dim, rep, chunks,
         state, decay, out, place, prep):
-    """A third chooser beside ``inverse_impl`` and ``scan_impl``, from
+    """The chooser beside ``scan_impl``, from
     the same things and the segment's shape: the kernels wherever the
     scan's run and a block of the segment's chunks fits their VMEM;
     ``_chunk_operands`` everywhere else. The rule's line says which,

@@ -35,8 +35,8 @@ def _segment_operands(chunk, rep, dtype, hk=2, num=2, seed=0):
     ``hk`` key heads, ``num`` chunks: two, one grid step, show the
     state handed from a chunk to the next; the carry between grid steps
     is the rule's file's) and a non-zero entering state."""
-    operands = jax.jit(lambda *a: gated_delta._chunk_operands(
-        *a, jnp.float32, "xla"))(
+    operands = jax.jit(
+        lambda *a: gated_delta._chunk_operands(*a, jnp.float32))(
             *_split_inputs(num, chunk, rep, dtype, hk=hk, seed=seed))
     state = 0.3 * jax.random.normal(
         jax.random.PRNGKey(seed + 1), (1, hk, rep, 128, 128))

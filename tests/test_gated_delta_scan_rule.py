@@ -58,8 +58,8 @@ def test_the_rule_by_the_scan_s_kernels(monkeypatch, seq, chunk, segment,
     gradients, over one and several segments and lengths that the chunk
     or the segment does not divide. ``prep=pallas``: what a TPU chooses,
     the operands' and the scan's kernels under one VJP; ``prep=xla``:
-    the scan's kernels after ``_chunk_operands`` with the inverses'
-    kernels in it (PR 34's program). Padded tokens write nothing: the
+    the scan's kernels after ``_chunk_operands``, the inverses by the
+    product form in it. Padded tokens write nothing: the
     cut output and the gradients are the unpadded recurrence's."""
     args, by_xla, by_token = _references(seq, chunk, segment, hk, hv)
     rule = lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment)
@@ -71,8 +71,7 @@ def test_the_rule_by_the_scan_s_kernels(monkeypatch, seq, chunk, segment,
     assert "gdn_scan_fwd" in text and "gdn_scan_bwd" in text
     for name in ("gdn_prepare_fwd", "gdn_prepare_bwd"):
         assert (name in text) == (prep == "pallas")
-    for name in ("gdn_inverse_fwd", "gdn_inverse_bwd"):
-        assert (name in text) == (prep == "xla")
+    assert "gdn_inverse" not in text
     for a, b, c in zip(got, by_xla, by_token):
         assert a.shape == c.shape and a.dtype == c.dtype
         scale = 1e-3 + float(jnp.abs(c).max())

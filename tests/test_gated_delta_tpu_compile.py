@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.observability import device as device_obs
 from tests.kernel_common import chip, topology  # noqa: F401 (fixtures)
 
@@ -37,7 +38,7 @@ def _square_float32_dots(hlo, size=64):
     return found
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas", "inverse"])
+@pytest.mark.parametrize("impl", ["xla", "pallas", "mixed"])
 def test_the_chunked_rule_compiles_at_the_cell_s_shape(
         chip, monkeypatch, impl):
     """``gated_delta_rule``'s gradient at 32,768 tokens, 16 key and 32
@@ -49,20 +50,18 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     chunk-to-chunk scan, every ``tpu_custom_call`` named, no float32 64 x
     64 dot, no 64 x 64 float32 array of the chunks' and no loop over a
     segment's chunks is left outside them, and the VMEM they ask for is
-    under the limits they state. ``inverse``: the same with the
-    operands' chooser held to XLA's lines, PR 34's program, in which
-    the inverses are kernels of their own (their operands row-major, 64
-    lanes padded to 128, where XLA kept some of its own matrices with
-    the chunks on the lanes: the rule alone reads 2.67 GiB for 2.45)."""
+    under the limits they state. ``mixed``: the operands' chooser held
+    to XLA's lines (what a segment whose chunks fit no block gets): the
+    scan's kernels alone, the inverses the product form by XLA, no
+    kernel of their own."""
     from elasticdl_tpu.ops import gated_delta
 
     if impl != "xla":
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if impl == "inverse":
+    if impl == "mixed":
         monkeypatch.setattr(
             gated_delta, "prepare_impl", lambda *a, **kw: "xla")
     chosen = "xla" if impl == "xla" else "pallas"
-    assert gated_delta.inverse_impl(jnp.float32, 64) == chosen
     assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == chosen
     assert gated_delta.prepare_impl(
         jnp.bfloat16, 64, 128, 128, 2, 128) == (
@@ -99,13 +98,16 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
             "gdn_prepare_fwd": 2, "gdn_prepare_bwd": 1,
             "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
         assert not re.search(r"f32\[[\d,]*,64,64\]", hlo)
+        assert hlo.count("tpu_custom_call") == 6
+        assert not _square_float32_dots(hlo)
     else:
         assert temporaries < 2.7 * 2**30
         assert device_obs.pallas_kernels(hlo) == {
-            "gdn_inverse_fwd": 2, "gdn_inverse_bwd": 1,
             "gdn_scan_fwd": 2, "gdn_scan_bwd": 1}
-    assert hlo.count("tpu_custom_call") == 6
-    assert not _square_float32_dots(hlo)
+        assert hlo.count("tpu_custom_call") == 3
+        assert "gdn_inverse" not in hlo and "gdn_prepare" not in hlo
+        # the inverses are XLA's products, as on the CPU
+        assert len(_square_float32_dots(hlo)) == 22
     # the chunk-to-chunk recurrence is inside the scan's kernels (ISSUE
     # 34): the two loops left are the forward's and the backward's over
     # the four segments, none over a segment's 128 chunks
@@ -116,16 +118,11 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
     # results double-buffered, count under it
     calls = [line for line in hlo.splitlines() if "tpu_custom_call" in line]
     limits = {"gdn_scan": gated_delta._SCAN_VMEM_LIMIT,
-              "gdn_prepare": gated_delta._PREPARE_VMEM_LIMIT,
-              "gdn_inverse": gated_delta._INVERSE_VMEM_LIMIT}
+              "gdn_prepare": gated_delta._PREPARE_VMEM_LIMIT}
     for line in calls:
         # the call's own name; its source locations name its callers
         name, = device_obs.pallas_kernels(line)
         assert '"size":"%d"' % limits[name[:name.rindex("_")]] in line
-    for arrays in (2, 3):
-        block = gated_delta.inverse_block(4096, 64, arrays)
-        assert gated_delta.inverse_vmem_bytes(
-            block, 64, arrays) < gated_delta._INVERSE_VMEM_LIMIT
     for kind in ("fwd", "fwd_residuals", "bwd"):
         block, step = gated_delta.scan_block(32, 128, 64, 128, 128, 2, kind)
         assert gated_delta.scan_vmem_bytes(
@@ -194,7 +191,7 @@ def test_the_chunked_rule_stays_partitionable_over_a_mesh(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.array(topology.devices), ("data",))
-    assert gated_delta.inverse_impl(jnp.float32, 64, mesh) == "xla"
+    assert not jax_compat.kernels_can_run(mesh)
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=NamedSharding(mesh, P("data")))
     args = (

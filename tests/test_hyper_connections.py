@@ -114,8 +114,8 @@ def test_initial_values_read_and_write_one_stream():
 
 
 def block_fields():
-    return dict(num_heads=2, norm="rmsnorm", mlp_act="swiglu", mlp_dim=24,
-                attention_impl="xla")
+    return dict(mixer=dict(num_heads=2, attention_impl="xla"),
+                norm="rmsnorm", mlp_act="swiglu", mlp_dim=24)
 
 
 def test_one_stream_with_unit_coefficients_is_the_plain_residual():
@@ -131,12 +131,13 @@ def test_one_stream_with_unit_coefficients_is_the_plain_residual():
         part["b_pre"] = jnp.full((1,), 40.0)   # sigmoid -> 1
         part["b_post"] = jnp.zeros((1,))       # 2 sigmoid(0) = 1
         params = dict(params, **{name: part})
-    got, facts = hyper.apply({"params": params}, x[:, None])
+    got, aux = hyper.apply({"params": params}, x[:, None])
+    facts = aux["mhc"]
     rest = {k: v for k, v in params.items() if not k.startswith("hc_")}
     # the plain block's tree is the hyper-connected one's less the modules
     assert jax.tree_util.tree_structure(rest) == jax.tree_util.tree_structure(
         plain.init(jax.random.PRNGKey(0), x)["params"])
-    want = plain.apply({"params": rest}, x)
+    want, _ = plain.apply({"params": rest}, x)
     np.testing.assert_allclose(got[:, 0], want, rtol=1e-4, atol=1e-4)
     assert float(facts["row_err"].max()) < 1e-5
 

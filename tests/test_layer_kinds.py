@@ -158,17 +158,18 @@ def test_the_dense_block_takes_the_mixer_s_fields():
     defaults the tree is the one it always was."""
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 64))
     block = Block(
-        6, attention_impl="xla", norm="rmsnorm", mlp_act="swiglu",
-        mlp_dim=96, head_dim=16, num_kv_heads=2, rotary_dim=8,
-        output_gate="sigmoid", rope_theta=500000.0, rope_scaling=YARN,
-        kind_scope="attn_full")
+        dict(num_heads=6, attention_impl="xla", head_dim=16,
+             num_kv_heads=2, rotary_dim=8, output_gate="sigmoid",
+             rope_theta=500000.0, rope_scaling=YARN,
+             kind_scope="attn_full"),
+        norm="rmsnorm", mlp_act="swiglu", mlp_dim=96)
     params = jax.jit(block.init)(jax.random.PRNGKey(1), x)["params"]
     assert params["attn"]["query"]["kernel"].shape == (64, 6, 32)
     assert params["attn"]["key"]["kernel"].shape == (64, 2, 16)
     assert params["attn"]["out_proj"]["kernel"].shape == (6, 16, 64)
     assert params["mlp_gate"]["kernel"].shape == (64, 96)
-    y = jax.jit(block.apply)({"params": params}, x)
-    assert y.shape == x.shape and bool(jnp.isfinite(y).all())
+    y, aux = jax.jit(block.apply)({"params": params}, x)
+    assert y.shape == x.shape and bool(jnp.isfinite(y).all()) and aux == {}
     h = jax.jit(transformer.make_norm("rmsnorm", 1e-6, "n").apply)(
         {"params": params["ln_attn"]}, x)
     want = direct_attention(
@@ -179,13 +180,13 @@ def test_the_dense_block_takes_the_mixer_s_fields():
     ).apply)({"params": params["attn"]}, h)
     np.testing.assert_allclose(mixed[0], want, atol=2e-5)
     # a band in a dense block
-    banded = Block(4, attention_impl="xla", mask=F.Band(8))
-    plain = Block(4, attention_impl="xla")
+    banded = Block(dict(num_heads=4, attention_impl="xla", mask=F.Band(8)))
+    plain = Block(dict(num_heads=4, attention_impl="xla"))
     p = jax.jit(plain.init)(jax.random.PRNGKey(1), x)
     assert jax.tree_util.tree_structure(p) == jax.tree_util.tree_structure(
         jax.eval_shape(banded.init, jax.random.PRNGKey(1), x))
-    assert float(jnp.abs(jax.jit(plain.apply)(p, x)
-                         - jax.jit(banded.apply)(p, x)).max()) > 1e-3
+    assert float(jnp.abs(jax.jit(plain.apply)(p, x)[0]
+                         - jax.jit(banded.apply)(p, x)[0]).max()) > 1e-3
     assert set(p["params"]["attn"]) == {"query", "key", "value", "out_proj"}
 
 
@@ -220,12 +221,16 @@ def test_layer_kinds_with_a_window_kind():
         assert attn["out_proj"]["kernel"].shape == (heads, 16, 64)
     assert "mlp_gate" in params["block_0"] and "moe_mlp" in params["block_1"]
     # what a kind decides, stated once
-    assert model._kind_fields("window") == dict(
+    of_the_model = dict(
+        latent=None, linear=None, conv=None, attention_impl="xla",
+        qk_norm=False, head_dim=16, num_kv_heads=2, head_norm=None,
+        output_gate="sigmoid", indexer=None)
+    assert model._mixer("window") == dict(
         num_heads=8, rope_theta=10000.0, rotary_dim=None, rope_scaling=None,
-        mask=F.Band(24), kind_scope="attn_window")
-    assert model._kind_fields("full") == dict(
+        mask=F.Band(24), kind_scope="attn_window", **of_the_model)
+    assert model._mixer("full") == dict(
         num_heads=6, rope_theta=500000.0, rotary_dim=8, rope_scaling=YARN,
-        mask=None, kind_scope="attn_full")
+        mask=None, kind_scope="attn_full", **of_the_model)
     logits = jax.jit(model.apply)(variables, TOKENS)
     assert logits.shape == (2, 128, 128)
     # the window decides: another window, another function; a window
