@@ -25,7 +25,7 @@ same equations:
   (``scores_reference``, ``select_reference``, a dense masked softmax),
   differentiated by ``jax.grad``. The CPU's path, small shapes', and
   the kernels' oracle.
-- ``"pallas"``: six Mosaic kernels on the causal rectangle of tiles,
+- ``"pallas"``: five Mosaic kernels on the causal rectangle of tiles,
   none of which ever holds an ``(S, S)`` float:
 
   ``dsa_select``         a block of queries' scores into VMEM as
@@ -37,19 +37,25 @@ same equations:
                          bisection on positions): ``(threshold, tie
                          position)`` a query. Exact, no sort.
   ``dsa_mask``           the scores again, a tile at a time, compared
-                         with the query's pair: the kept set as an int8
-                         ``(S, S)`` mask (1 GB at 32,768; a layer's at a
-                         time under remat), with the rows' statistics
+                         with the query's pair: the kept set as BITS,
+                         eight keys a byte by planes (``_planes``: bit
+                         ``b`` of byte ``[t, j]`` is the pair ``(t, b *
+                         S / 8 + j)``, an int8 ``(S, S / 8)``, 134 MB at
+                         32,768, which a remat policy saves by name so
+                         that a block's backward makes nothing of the
+                         selection again), with the rows' statistics
                          (``lse`` of ``I`` over the kept set, its
                          entropy, kept keys, kept keys among the nearest
                          ``topk``).
   ``flash_sparse_fwd``   the flash forward and the fused backward of
   ``flash_sparse_bwd``   ``ops/flash_attention.py`` with the tile's mask
-                         READ, not computed: they walk every tile of the
-                         causal prefix (a seeded indexer's picks lie
-                         spread over it, so no tile is empty; a tile
-                         list for a trained indexer's clustered picks is
-                         ROADMAP M16's).
+                         READ, not computed (a tile's bytes are one
+                         aligned block of the packed array, its bit the
+                         plane its k-block lies in): they walk every
+                         tile of the causal prefix (a seeded indexer's
+                         picks lie spread over it, so no tile is empty;
+                         a tile list for a trained indexer's clustered
+                         picks is ROADMAP M16's).
   ``dsa_indexer_loss``   per tile: all heads' probabilities from
                          ``lse`` (their mean is ``p``), the scores again,
                          ``softmax(I) - p`` (the scores' cotangent), the
@@ -84,10 +90,11 @@ logger = _logger_factory("elasticdl_tpu.ops.sparse_attention")
 
 NEG_INF = _flash.NEG_INF
 _INT_MIN = -2**31
-# checkpoint_name labels: a query's (threshold, tie position) and the
-# indexer's term with its cotangents. A remat policy that saves them
+# checkpoint_name labels: the kept set in bits and the indexer's term
+# with its cotangents. A remat policy that saves them
 # (``models/transformer.py:remat_block``: "flash", "dots") re-runs
-# neither the bisection nor the term's kernel in a block's backward.
+# neither the selection's two kernels nor the term's in a block's
+# backward.
 DSA_SELECT_NAME = "dsa_select"
 DSA_LOSS_NAME = "dsa_indexer_loss"
 DSA_SAVE_NAMES = (DSA_SELECT_NAME, DSA_LOSS_NAME)
@@ -200,6 +207,25 @@ def _kept(key, threshold, tie, q_pos, k_pos):
     to it no later than the tie position; never after the query."""
     return (k_pos <= q_pos) & (
         (key > threshold) | ((key == threshold) & (k_pos <= tie)))
+
+
+def pack_planes(keep, planes):
+    """``keep`` (..., S) bool as int8 (..., S / planes): bit ``b`` of
+    byte ``j`` is key ``b * S / planes + j``. What ``dsa_mask`` writes
+    as ``jax.numpy`` lines; one plane is a byte a key."""
+    width = keep.shape[-1] // planes
+    bits = keep.reshape(keep.shape[:-1] + (planes, width)).astype(jnp.uint8)
+    shifts = jnp.arange(planes, dtype=jnp.uint8)[:, None]
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(bits << shifts, axis=-2, dtype=jnp.uint8), jnp.int8)
+
+
+def unpack_planes(packed, planes):
+    """``pack_planes`` back: (..., S / planes) int8 to (..., S) bool."""
+    bits = jax.lax.bitcast_convert_type(packed, jnp.uint8)[..., None, :]
+    shifts = jnp.arange(planes, dtype=jnp.uint8)[:, None]
+    return (((bits >> shifts) & 1) != 0).reshape(
+        packed.shape[:-1] + (planes * packed.shape[-1],))
 
 
 def indexer_kl(probs_mean, scores, keep):
@@ -382,10 +408,21 @@ def _tiles(seq):
 def _flash_tiles(seq, head_dim, dtype, backward=False):
     """The tiles of ``flash_sparse_fwd`` / ``flash_sparse_bwd``: the
     causal kernels' own rule (``flash_attention._blocks``: 1024 x 1024
-    from 8,192 positions on). The mask is whole, zeros above the
-    diagonal, so any tiling reads it."""
+    from 8,192 positions on)."""
     return _flash._blocks(
         seq, seq, head_dim, dtype, None, None, backward=backward)
+
+
+def _planes(seq, head_dim, dtype):
+    """The keys a byte of the kept set holds: 8 where an eighth of the
+    sequence is whole tiles of the widest of the three readers, else 4,
+    2 or 1 (a byte a key); 0 where the sequence is not whole tiles of
+    it. A reader's tile is then ONE block of the packed ``(S, S /
+    planes)`` array and one bit, its k-block's plane."""
+    widest = max(
+        _tiles(seq)[1], _flash_tiles(seq, head_dim, dtype)[1],
+        _flash_tiles(seq, head_dim, dtype, backward=True)[1])
+    return next((p for p in (8, 4, 2, 1) if seq % (p * widest) == 0), 0)
 
 
 def _causal_maps(block_q, block_k, num_q, k_outer=False):
@@ -397,13 +434,20 @@ def _mask_kernel(qi_ref, ki_ref, w_ref, thr_ref, tie_ref,
                  mask_ref, lse_ref, ent_ref, kept_ref, near_ref,
                  m_scr, l_scr, e_scr, kept_scr, near_scr,
                  *, topk, block_q, block_k):
+    """``mask_ref`` is the q-block's whole packed row block (1,
+    block_q, S / planes), resident over the k-blocks: zeroed at the
+    first, and a tile ORs its kept set into its columns at its plane's
+    bit. The k-blocks come in the order of their positions, so a row's
+    statistics sum in one order whatever the planes."""
     q_block = pl.program_id(1)
     k_block = pl.program_id(2)
     steps = pl.num_programs(2)
     last_k, _, _ = _flash._causal_pair(q_block, k_block, block_q, block_k)
+    per_plane = mask_ref.shape[2] // block_k
 
     @pl.when(k_block == 0)
     def _init():
+        mask_ref[...] = jnp.zeros_like(mask_ref)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         for ref in (l_scr, e_scr, kept_scr, near_scr):
             ref[:] = jnp.zeros_like(ref)
@@ -416,7 +460,12 @@ def _mask_kernel(qi_ref, ki_ref, w_ref, thr_ref, tie_ref,
         key = jnp.where(k_pos <= q_pos, _sortable(scores), _INT_MIN)
         keep = _kept(key, thr_ref[0, 0][:, None], tie_ref[0, 0][:, None],
                      q_pos, k_pos)
-        mask_ref[0] = keep.astype(jnp.int8)
+        cols = pl.ds(pl.multiple_of(
+            jax.lax.rem(k_block, per_plane) * block_k, block_k), block_k)
+        bits = jax.lax.shift_left(
+            keep.astype(jnp.int32), jax.lax.div(k_block, per_plane))
+        mask_ref[0, :, cols] = (
+            mask_ref[0, :, cols].astype(jnp.int32) | bits).astype(jnp.int8)
         kept = keep.astype(jnp.float32)
         near = jnp.where(q_pos - k_pos < topk, kept, 0.0)
         s = jnp.where(keep, scores, NEG_INF)
@@ -435,10 +484,6 @@ def _mask_kernel(qi_ref, ki_ref, w_ref, thr_ref, tie_ref,
             near_scr[:, :1] + jnp.sum(near, axis=1, keepdims=True))
         m_scr[:] = wide(m_new)
 
-    @pl.when(k_block > last_k)
-    def _above():
-        mask_ref[0] = jnp.zeros(mask_ref.shape[1:], jnp.int8)
-
     @pl.when(k_block == steps - 1)
     def _finalize():
         l_final = jnp.maximum(l_scr[:, 0], 1e-30)
@@ -450,15 +495,15 @@ def _mask_kernel(qi_ref, ki_ref, w_ref, thr_ref, tie_ref,
         near_ref[0, 0] = near_scr[:, 0]
 
 
-def _mask_call(qi, ki, w, threshold, tie, topk, interpret):
-    """``(mask (B, S, S) int8, lse_I, entropy, kept, near)``, the last
-    four (B, 1, S) float32. A tile above the diagonal computes nothing,
-    fetches nothing and writes zeros."""
+def _mask_call(qi, ki, w, threshold, tie, topk, planes, interpret):
+    """``(kept set (B, S, S / planes) int8 by planes, lse_I, entropy,
+    kept, near)``, the last four (B, 1, S) float32. A tile above the
+    diagonal computes nothing and fetches nothing; its bits stay 0."""
     batch, heads, seq, dim = qi.shape
     block_q, block_k = _tiles(seq)
     num_q = seq // block_q
+    width = seq // planes
     q_idx, k_idx, stat_idx = _causal_maps(block_q, block_k, num_q)
-    tile_idx = lambda b, i, j: (b, i, j)
     operands = (qi, ki, w, threshold, tie)
     stat = _flash._out_struct((batch, 1, seq), jnp.float32, *operands)
     stat_spec = pl.BlockSpec((1, 1, block_q), stat_idx)
@@ -475,12 +520,12 @@ def _mask_call(qi, ki, w, threshold, tie, topk, interpret):
             stat_spec, stat_spec,
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, block_k), tile_idx),
+            pl.BlockSpec((1, block_q, width), lambda b, i, j: (b, i, 0)),
             stat_spec, stat_spec, stat_spec, stat_spec,
         ),
         scratch_shapes=[scratch] * 5,
         out_shape=(
-            _flash._out_struct((batch, seq, seq), jnp.int8, *operands),
+            _flash._out_struct((batch, seq, width), jnp.int8, *operands),
             stat, stat, stat, stat,
         ),
         compiler_params=pltpu.CompilerParams(
@@ -491,12 +536,20 @@ def _mask_call(qi, ki, w, threshold, tie, topk, interpret):
     )(*operands)
 
 
-def _masked(s, mask_ref):
-    return jnp.where(mask_ref[0].astype(jnp.int32) != 0, s, NEG_INF)
+def _kept_tile(mask_ref, k_block, per_plane):
+    """The tile's kept set, bool, from its block of the packed array:
+    the bit of the plane that ``k_block`` lies in."""
+    bit = jax.lax.shift_left(1, jax.lax.div(k_block, per_plane))
+    return (mask_ref[0].astype(jnp.int32) & bit) != 0
+
+
+def _masked(s, mask_ref, k_block, per_plane):
+    return jnp.where(_kept_tile(mask_ref, k_block, per_plane), s, NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k):
+                acc_ref, m_ref, l_ref, *, sm_scale, block_q, block_k,
+                per_plane):
     """``flash_attention._fwd_kernel`` on the causal rectangle, the
     tile's mask read from ``mask_ref``."""
     q_block = pl.program_id(1)
@@ -515,7 +568,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         v = v_ref[0]
         s = _masked(jax.lax.dot_general(
             q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale, mask_ref)
+            preferred_element_type=jnp.float32) * sm_scale,
+            mask_ref, k_block, per_plane)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         correction = jnp.exp(m_prev - m_new)
@@ -536,14 +590,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
             m_ref[:, 0] + jnp.log(jnp.maximum(l_ref[:, 0], 1e-30)))
 
 
-def _mask_index_map(idx, heads):
+def _mask_index_map(idx, heads, per_plane):
     """A mask tile's index map from the q-ish / k-ish ones of merged
-    head ``b``: the batch is ``b // heads``."""
+    head ``b``: the batch is ``b // heads`` (``heads`` 1 on a grid over
+    the batch), the columns the k-block's place in its plane."""
     q_idx, k_idx = idx
 
     def tile(b, outer, inner):
         return (jax.lax.div(b, heads), q_idx(b, outer, inner)[1],
-                k_idx(b, outer, inner)[1])
+                jax.lax.rem(k_idx(b, outer, inner)[1], per_plane))
 
     return tile
 
@@ -553,12 +608,13 @@ def _fwd_call(q, k, v, mask, sm_scale, interpret):
     heads = bh // mask.shape[0]
     block_q, block_k = _flash_tiles(seq, head_dim, q.dtype)
     num_q = seq // block_q
+    per_plane = mask.shape[2] // block_k
     q_idx, k_idx, stat_idx = _causal_maps(block_q, block_k, num_q)
     kv_idx = _flash._kv_index_map(k_idx, bh // k.shape[0])
     return pl.pallas_call(
         functools.partial(
             _fwd_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k),
+            block_k=block_k, per_plane=per_plane),
         grid=(bh, num_q, seq // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
@@ -566,7 +622,7 @@ def _fwd_call(q, k, v, mask, sm_scale, interpret):
             pl.BlockSpec((1, block_k, head_dim), kv_idx),
             pl.BlockSpec(
                 (1, block_q, block_k),
-                _mask_index_map((q_idx, k_idx), heads)),
+                _mask_index_map((q_idx, k_idx), heads, per_plane)),
         ],
         out_specs=(
             pl.BlockSpec((1, block_q, head_dim), q_idx),
@@ -591,7 +647,7 @@ def _fwd_call(q, k, v, mask, sm_scale, interpret):
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
                 dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc,
-                *, sm_scale, block_q, block_k):
+                *, sm_scale, block_q, block_k, per_plane):
     """``flash_attention._dkv_kernel(with_dq=True)`` on the causal
     rectangle, the tile's mask read: five score-sized products a tile,
     dq's accumulator one head's whole ``(S, d)`` in VMEM."""
@@ -616,7 +672,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         q, k, do = q_ref[0], k_ref[0], do_ref[0]
         s = _masked(jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale, mask_ref)
+            preferred_element_type=jnp.float32) * sm_scale,
+            mask_ref, k_block, per_plane)
         p = jnp.exp(s - lse_ref[0, 0][:, None])
         dp = jax.lax.dot_general(
             do, v_ref[0], (((1,), (1,)), ((), ())),
@@ -648,6 +705,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, sm_scale, interpret):
     group = bh // k.shape[0]
     block_q, block_k = _flash_tiles(seq, head_dim, q.dtype, backward=True)
     num_q = seq // block_q
+    per_plane = mask.shape[2] // block_k
     delta = jnp.sum(
         o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1
     )[:, None, :]
@@ -661,7 +719,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, sm_scale, interpret):
     dk, dv, dq = pl.pallas_call(
         functools.partial(
             _bwd_kernel, sm_scale=sm_scale, block_q=block_q,
-            block_k=block_k),
+            block_k=block_k, per_plane=per_plane),
         grid=(bh, seq // block_k, num_q),
         in_specs=[
             pl.BlockSpec((1, block_q, head_dim), q_idx),
@@ -672,7 +730,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, sm_scale, interpret):
             pl.BlockSpec((1, 1, block_q), stat_idx),
             pl.BlockSpec(
                 (1, block_q, block_k),
-                _mask_index_map((q_idx, k_idx), heads)),
+                _mask_index_map((q_idx, k_idx), heads, per_plane)),
         ],
         out_specs=(
             pl.BlockSpec((1, block_k, head_dim), k_idx),
@@ -707,7 +765,7 @@ def _bwd_call(q, k, v, o, lse, do, mask, sm_scale, interpret):
 def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, ki_ref, w_ref,
                  lsei_ref, kl_ref, dqi_ref, dw_ref, dki_ref,
                  kl_acc, dqi_acc, dw_acc, dki_acc,
-                 *, sm_scale, group, block_q, block_k):
+                 *, sm_scale, group, block_q, block_k, per_plane):
     """The indexer's term and its gradient on one tile, grid (batch,
     q-block, k-block). ``p`` is the mean over the heads of ``exp(s_h -
     lse_h)`` on the kept entries; the scores' cotangent (before the
@@ -734,7 +792,7 @@ def _loss_kernel(q_ref, k_ref, lse_ref, mask_ref, qi_ref, ki_ref, w_ref,
 
     @pl.when(k_block <= last_k)
     def _tile():
-        keep = mask_ref[0].astype(jnp.int32) != 0
+        keep = _kept_tile(mask_ref, k_block, per_plane)
 
         def head(h, total):
             s = jax.lax.dot_general(
@@ -790,6 +848,7 @@ def _loss_call(q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret):
     idx_heads, idx_dim = qi.shape[1], qi.shape[3]
     block_q, block_k = _tiles(seq)
     num_q = seq // block_q
+    per_plane = mask.shape[2] // block_k
     q_idx, k_idx, stat_idx = _causal_maps(block_q, block_k, num_q)
     moving = lambda b, i, j: k_idx(b, i, j)[1]
     operands = (q, k, lse, mask, qi, ki, w, lse_i)
@@ -797,7 +856,7 @@ def _loss_call(q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret):
     return pl.pallas_call(
         functools.partial(
             _loss_kernel, sm_scale=sm_scale, group=heads // kv_heads,
-            block_q=block_q, block_k=block_k),
+            block_q=block_q, block_k=block_k, per_plane=per_plane),
         grid=(batch, num_q, seq // block_k),
         in_specs=[
             pl.BlockSpec(
@@ -808,7 +867,8 @@ def _loss_call(q, k, lse, mask, qi, ki, w, lse_i, sm_scale, interpret):
             pl.BlockSpec(
                 (1, heads, 1, block_q), lambda b, i, j: (b, 0, 0, i)),
             pl.BlockSpec(
-                (1, block_q, block_k), lambda b, i, j: (b, i, moving(b, i, j))),
+                (1, block_q, block_k),
+                _mask_index_map((q_idx, k_idx), 1, per_plane)),
             pl.BlockSpec(
                 (1, idx_heads, block_q, idx_dim),
                 lambda b, i, j: (b, 0, i, 0)),
@@ -897,13 +957,13 @@ def _by_kernels(q, k, v, qi, ki, w, topk, sm_scale, interpret,
     batch, heads, seq, head_dim = q.shape
     stop = jax.lax.stop_gradient
     qi_, ki_, w_ = stop(qi), stop(ki), stop(w)
+    planes = _planes(seq, head_dim, q.dtype)
     with jax.named_scope("dsa/select"):
         threshold, tie = _select_call(qi_, ki_, w_, topk, interpret)
-        threshold = checkpoint_name(threshold, DSA_SELECT_NAME)
-        tie = checkpoint_name(tie, DSA_SELECT_NAME)
     with jax.named_scope("dsa/scores"):
         mask, lse_i, entropy, kept, near = _mask_call(
-            qi_, ki_, w_, threshold, tie, topk, interpret)
+            qi_, ki_, w_, threshold, tie, topk, planes, interpret)
+        mask = checkpoint_name(mask, DSA_SELECT_NAME)
     merge = lambda t: t.reshape((-1,) + t.shape[2:])
     with jax.named_scope("dsa/attend"):
         o, lse = _fwd_call(
@@ -935,7 +995,7 @@ def _by_kernels(q, k, v, qi, ki, w, topk, sm_scale, interpret,
         # what the mask keeps after a query is counted, not cut away
         q_pos = jnp.arange(seq)[:, None]
         k_pos = jnp.arange(seq)[None, :]
-        kept_any = mask != 0
+        kept_any = unpack_planes(mask, planes)
         facts.update(_probe(
             kept_any & (k_pos <= q_pos), (kept_any & (k_pos > q_pos)).sum(),
             qi, ki, w))
@@ -976,10 +1036,10 @@ def _refusal(q):
     """Why the kernels cannot take ``q`` (B, H, S, d); "" when they
     can."""
     seq = q.shape[2]
-    block_q, block_k = _tiles(seq)
-    if seq % block_q or seq % block_k or seq % min(_SELECT_ROWS, seq) or (
-            seq % min(_SELECT_CHUNK, seq)) or seq < 128:
-        return "seq %d is not whole tiles (%d, %d)" % (seq, block_q, block_k)
+    # no planes: some reader's tiles do not divide the sequence
+    if not _planes(seq, q.shape[-1], q.dtype) or seq % min(
+            _SELECT_ROWS, seq) or seq % min(_SELECT_CHUNK, seq) or seq < 128:
+        return "seq %d is not whole tiles (%d, %d)" % ((seq,) + _tiles(seq))
     if _flash.backward_schedule(seq, seq, q.shape[-1], q.dtype) != "fused":
         return "dq's accumulator at (%d, %d) %s is over the VMEM budget" % (
             seq, q.shape[-1], q.dtype.name)
@@ -991,15 +1051,20 @@ def _log_once(impl, backend, reason, q_shape, q_dtype, kv_heads, idx, topk):
     seq = q_shape[2]
     facts = tiles_facts(seq, topk, q_shape[3], jnp.dtype(q_dtype))
     pairs = "run=%d masked=%d skipped=%d blocks=%dx%d"
+    held = "dense"
+    if impl == "pallas":
+        planes = _planes(seq, q_shape[3], jnp.dtype(q_dtype))
+        held = "bits planes=%d saved_bytes=%d" % (
+            planes, q_shape[0] * seq * seq // planes)
     logger.info(
         "attention impl=auto resolved to %s (backend=%s, q=%s %s%s, "
         "kv_heads=%d group=%d, indexer heads=%d dim=%d, flash "
         "backward=fused, mask=selected(%d) pairs " + pairs + " (backward "
-        + pairs + ") kept=%d fill=%.4f)",
+        + pairs + ") kept=%d fill=%.4f kept_set=%s)",
         impl, backend, q_shape, q_dtype,
         ", reason: %s" % reason if reason else "", kv_heads,
         q_shape[1] // kv_heads, idx[0], idx[1], topk, *facts["forward"],
-        *facts["backward"], facts["kept"], facts["fill"])
+        *facts["backward"], facts["kept"], facts["fill"], held)
 
 
 def dsa_attention(q, k, v, qi, ki, w, topk, sm_scale=None, impl="auto",

@@ -11,20 +11,27 @@ Times, ms a call by the host's clock around ``--calls`` calls:
 - ``jax.lax.top_k`` at ``(512, seq) -> topk`` (what a selection by
   XLA's own sort would cost a block of 512 queries; a layer needs
   ``seq / 512`` of them, forward and recomputed),
-- each kernel of ``ops/sparse_attention.py`` alone (``dsa_select``,
-  ``dsa_mask``, ``flash_sparse_fwd``, ``flash_sparse_bwd``,
-  ``dsa_indexer_loss``),
+- each kernel of ``ops/sparse_attention.py`` alone (``dsa_select``;
+  ``dsa_mask``, which writes the kept set eight keys a byte by planes;
+  ``flash_sparse_fwd``, ``flash_sparse_bwd`` and ``dsa_indexer_loss``,
+  which read a tile's bit of it),
 - the dense causal ``flash_fwd`` / ``flash_bwd`` at the same shape.
 
 Checks, ON the chip at ``--check-seq`` (dense scores fit there): the
-kernels' kept set against ``select_reference`` (``jax.lax.top_k``
-itself), every query's count ``min(topk, t + 1)``, and the output, the
-term and the six gradients against the ``jax.numpy`` lines. Off a TPU
-it refuses to time unless ``--interpret``; every line and the JSON
-carry ``device_kind``. Writes ``chiprun_out/dsa_kernels.json``.
+kernels' kept set, unpacked, against ``select_reference``
+(``jax.lax.top_k`` itself), every query's count ``min(topk, t + 1)``,
+and the output, the term and the six gradients against the
+``jax.numpy`` lines. Prints sha256 digests of the whole call's output,
+term, facts and gradients at the timed shape (``digest``), to hold two
+trees' kernels to the same floats. Off a TPU it refuses to time unless
+``--interpret``; every line and the JSON carry ``device_kind``. Writes
+``chiprun_out/dsa_kernels.json``, with the figures of the kernels that
+held the kept set a byte a key beside (``ms_a_byte_a_key``: PR 51's
+chip run at the default shape).
 """
 
 import argparse
+import hashlib
 import json
 import os
 import sys
@@ -42,6 +49,11 @@ from elasticdl_tpu.ops import flash_attention as F  # noqa: E402
 from elasticdl_tpu.ops import sparse_attention as S  # noqa: E402
 
 HEADS, KV_HEADS, HEAD_DIM, IDX_HEADS, IDX_DIM = 32, 4, 128, 16, 64
+# PERF.md Section 6, PR 51: the same calls on a TPU v5 lite at 32,768 /
+# 2,048 / bfloat16 while the kept set was an int8 (S, S)
+A_BYTE_A_KEY_MS = {
+    "dsa_mask": 20.4, "flash_sparse_fwd": 73.0, "flash_sparse_bwd": 134.0,
+    "dsa_indexer_loss": 104.0}
 
 
 def timed(fn, args, calls):
@@ -74,16 +86,43 @@ def relative(got, want):
         / max(np.sqrt(np.mean(want ** 2)), 1e-30))
 
 
+def digest(seq, topk, dtype, interpret):
+    """sha256 of what the call hands back at seed-0 operands, a name a
+    value: the output, the term, the three facts and the six gradients
+    of ``sum(out^2) + sum(kl)``. Two trees whose kernels compute the
+    same floats from the same kept set print the same lines."""
+    def fn(*args):
+        o, kl, facts = S.dsa_attention(
+            *args, topk, impl="pallas", interpret=interpret)
+        return (o.astype(jnp.float32) ** 2).sum() + kl.sum(), (
+            o, kl, facts)
+
+    (_, (o, kl, facts)), grads = jax.jit(jax.value_and_grad(
+        fn, argnums=tuple(range(6)), has_aux=True))(
+            *operands(0, seq, dtype))
+    values = dict(
+        zip(("dq", "dk", "dv", "dqi", "dki", "dw"), grads), out=o, kl=kl,
+        **facts)
+    return {
+        name: hashlib.sha256(np.asarray(
+            value.astype(jnp.float32)).tobytes()).hexdigest()[:16]
+        for name, value in sorted(values.items())}
+
+
 def check(seq, topk, dtype, interpret):
     q, k, v, qi, ki, w = operands(1, seq, dtype)
     scores = S.scores_reference(qi, ki, w)
     want = S.select_reference(scores, topk)
+    planes = S._planes(seq, HEAD_DIM, dtype)
     threshold, tie = S._select_call(qi, ki, w, topk, interpret)
-    mask = S._mask_call(qi, ki, w, threshold, tie, topk, interpret)[0]
+    mask = S._mask_call(qi, ki, w, threshold, tie, topk, planes, interpret)[0]
     causal = np.tril(np.ones((seq, seq), bool))
-    got = (np.asarray(mask[0]) != 0) & causal
+    kept = np.asarray(S.unpack_planes(mask, planes)[0])
+    got = kept & causal
     counts = got.sum(-1)
     out = {
+        "planes": planes,
+        "kept_after": int((kept & ~causal).sum()),
         "kept_disagreements": int((got != np.asarray(want[0])).sum()),
         "counts_exact": bool(
             (counts == np.minimum(topk, np.arange(seq) + 1)).all()),
@@ -146,12 +185,16 @@ def main():
     select = jax.jit(lambda *a: S._select_call(*a, topk, interp))
     ms, (threshold, tie) = timed(select, (qi, ki, w), args.calls)
     say("dsa_select", ms)
-    mask_fn = jax.jit(lambda *a: S._mask_call(*a, topk, interp))
+    planes = S._planes(seq, HEAD_DIM, dtype)
+    result["planes"] = planes
+    mask_fn = jax.jit(lambda *a: S._mask_call(*a, topk, planes, interp))
     ms, (mask, lse_i, _, kept, _) = timed(
         mask_fn, (qi, ki, w, threshold, tie), args.calls)
     say("dsa_mask", ms)
     result["kept_mean"] = float(kept.mean())
-    print("dsa_kernels: kept keys a query %.3f" % result["kept_mean"])
+    result["kept_set_bytes"] = mask.nbytes
+    print("dsa_kernels: kept keys a query %.3f, the kept set %s int8 by %d "
+          "planes" % (result["kept_mean"], mask.shape, planes))
     merge = lambda t: t.reshape((-1,) + t.shape[2:])
     scale = HEAD_DIM ** -0.5
     fwd = jax.jit(lambda q, k, v, m: S._fwd_call(
@@ -177,11 +220,15 @@ def main():
             None, False))
         ms, _ = timed(dense_bwd, (q, k, v, o, lse), args.calls)
         say("flash_bwd (dense causal)", ms)
+    result["digest"] = digest(seq, topk, dtype, interp)
+    print("dsa_kernels: digest at %d: %s" % (seq, json.dumps(result["digest"])))
     result["check"] = check(
         args.check_seq, args.check_topk or min(topk, args.check_seq // 4),
         dtype, interp)
     print("dsa_kernels: check at %d: %s" % (
         args.check_seq, json.dumps(result["check"])))
+    if (seq, topk, dtype.name) == (32768, 2048, "bfloat16"):
+        result["ms_a_byte_a_key"] = A_BYTE_A_KEY_MS
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/dsa_kernels.json", "w") as f:
         json.dump(result, f, indent=1)

@@ -109,6 +109,48 @@ def test_one_level_keeps_the_first_positions():
         assert not keep[:, topk:].any()
 
 
+@pytest.mark.parametrize("seq,topk,planes,levels", [
+    (64, 8, 1, 0), (64, 16, 2, 0), (96, 32, 4, 0), (128, 24, 8, 0),
+    (64, 16, 8, 3), (64, 16, 2, 1)])
+def test_the_kept_set_packs_by_planes_and_comes_back(
+        seq, topk, planes, levels):
+    """``dsa_mask``'s layout as ``jax.numpy`` lines: bit ``b`` of byte
+    ``[t, j]`` is the pair ``(t, b * S / planes + j)``; ``levels``:
+    scores of so few values that thresholds are cut among equals."""
+    if levels:
+        rng = np.random.RandomState(levels)
+        values = rng.randint(0, levels, size=(2, seq, seq)).astype(np.float32)
+        scores = jnp.where(
+            np.tril(np.ones((seq, seq), bool)), values, S.NEG_INF)
+    else:
+        _, _, _, qi, ki, w = operands(seq + planes, seq, batch=2)
+        scores = S.scores_reference(qi, ki, w)
+    want = S.select_reference(scores, topk)
+    packed = S.pack_planes(want, planes)
+    width = seq // planes
+    assert packed.shape == (2, seq, width) and packed.dtype == jnp.int8
+    assert (np.asarray(S.unpack_planes(packed, planes))
+            == np.asarray(want)).all()
+    bytes_ = np.asarray(packed).view(np.uint8)
+    for b in range(planes):
+        assert ((bytes_ >> b & 1).astype(bool) == np.asarray(
+            want)[..., b * width:(b + 1) * width]).all()
+    assert not (bytes_ >> planes).any()
+
+
+@pytest.mark.parametrize("seq,planes", [
+    (512, 1), (1024, 1), (2048, 2), (4096, 4), (8192, 8), (32768, 8),
+    (1000, 0)])
+def test_the_planes_follow_from_the_shape(seq, planes):
+    """Eight keys a byte where an eighth of the sequence is whole tiles
+    of the widest reader (the forward's 1,024 keys from 1,024 positions
+    on), fewer below; no packing, and a refusal, where a tile does not
+    divide the sequence."""
+    assert S._planes(seq, 128, jnp.bfloat16) == planes
+    q = jnp.zeros((1, 4, seq, 128), jnp.bfloat16)
+    assert bool(S._refusal(q)) == (planes == 0)
+
+
 @pytest.mark.parametrize("value", [0.0, -0.0, 1.5, -1.5, 3e38, -3e38, 1e-45])
 def test_sortable_keeps_the_order_of_floats(value):
     others = np.array([-2.0, -1e-30, -0.0, 0.0, 1e-30, 2.0], np.float32)
@@ -195,7 +237,8 @@ def grads(impl, args, topk, weight=3.0, interpret=True):
     return aux, g
 
 
-@pytest.mark.parametrize("seq,topk,batch", [(512, 128, 2), (1024, 96, 1)])
+@pytest.mark.parametrize("seq,topk,batch", [
+    (512, 128, 2), (1024, 96, 1), (2048, 96, 1)])
 def test_the_kernels_against_the_lines_float32(seq, topk, batch):
     args = operands(seq, seq, batch=batch)
     (out_x, kl_x, facts_x), g_x = grads("xla", args, topk)
@@ -225,13 +268,23 @@ def test_the_kernels_against_the_lines_bfloat16():
         assert rel(got, want) < 0.02
 
 
-def test_the_kernels_kept_set_is_top_k_s():
-    seq, topk = 512, 64
-    _, _, _, qi, ki, w = operands(9, seq, batch=2)
+def kept_by_kernels(qi, ki, w, topk):
+    """The kept set of ``dsa_select`` + ``dsa_mask`` in interpret mode,
+    unpacked (the planes as ``operands``' 32-wide heads give them)."""
+    planes = S._planes(qi.shape[2], 32, qi.dtype)
     threshold, tie = S._select_call(qi, ki, w, topk, True)
-    mask = S._mask_call(qi, ki, w, threshold, tie, topk, True)[0]
-    want = S.select_reference(S.scores_reference(qi, ki, w), topk)
-    assert (np.asarray(mask != 0) == np.asarray(want)).all()
+    packed = S._mask_call(qi, ki, w, threshold, tie, topk, planes, True)[0]
+    assert packed.shape == ki.shape[:2] + (qi.shape[2] // planes,)
+    return np.asarray(S.unpack_planes(packed, planes))
+
+
+@pytest.mark.parametrize("seq,topk,batch", [(512, 64, 2), (2048, 96, 1)],
+                         ids=["one-plane", "two-planes"])
+def test_the_kernels_kept_set_is_top_k_s(seq, topk, batch):
+    _, _, _, qi, ki, w = operands(9, seq, batch=batch)
+    assert (kept_by_kernels(qi, ki, w, topk)
+            == np.asarray(S.select_reference(
+                S.scores_reference(qi, ki, w), topk))).all()
 
 
 def test_the_kernels_cut_ties_where_top_k_does():
@@ -244,10 +297,8 @@ def test_the_kernels_cut_ties_where_top_k_does():
     scores = S.scores_reference(qi, ki, w)
     want = np.asarray(S.select_reference(scores, topk))
     assert (want == kept_by_loop(scores, topk)).all()
-    threshold, tie = S._select_call(qi, ki, w, topk, True)
-    assert int((tie < seq).sum()) > 0
-    mask = S._mask_call(qi, ki, w, threshold, tie, topk, True)[0]
-    assert (np.asarray(mask != 0) == want).all()
+    assert int((S._select_call(qi, ki, w, topk, True)[1] < seq).sum()) > 0
+    assert (kept_by_kernels(qi, ki, w, topk) == want).all()
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -283,6 +334,27 @@ def test_tiles_facts_from_shapes(seq, topk, want):
         assert facts["forward"] == (528, 528, 496, 1024, 1024)
         assert facts["backward"] == (528, 528, 496, 1024, 1024)
         assert facts["fill"] == pytest.approx(0.1174, abs=1e-4)
+
+
+@pytest.mark.parametrize("impl,held", [
+    ("pallas", "kept_set=bits planes=8 saved_bytes=134217728)"),
+    ("xla", "kept_set=dense)")])
+def test_the_attention_line_says_how_the_kept_set_is_held(
+        caplog, impl, held):
+    """After ``fill=``, so that ``benchmark/lib/dsa_trace.py:LINE_RE``
+    reads the line as it did."""
+    import logging
+
+    from benchmark.lib import dsa_trace
+
+    S._log_once.cache_clear()
+    with caplog.at_level(logging.INFO, logger=S.logger.name):
+        S._log_once(impl, "tpu", "", (1, 32, 32768, 128), "bfloat16", 4,
+                    (16, 64), 2048)
+    S._log_once.cache_clear()
+    line = caplog.records[-1].getMessage()
+    assert line.endswith("kept=65012736 fill=0.1174 " + held)
+    assert dsa_trace.attention_line(line)["kept"] == 65012736
 
 
 # ---------------------------------------------------------------------------

@@ -83,13 +83,15 @@ def test_the_call_s_gradient_compiles_to_each_kernel_once(
         device_obs._PALLAS_KERNEL_RE.findall(hlo))
 
 
-def test_under_the_flash_policy_only_the_mask_is_made_again(chip):
-    """A block's backward under ``remat_policy="flash"``: what the
-    bisection found, the flash outputs and the indexer's term with its
-    cotangents are saved; ``dsa_mask`` alone runs twice."""
-    counts = device_obs.pallas_kernels(
-        gradient_hlo(chip, SEQ, jnp.bfloat16, remat=True))
-    assert counts == dict(dict.fromkeys(KERNELS, 1), dsa_mask=2)
+def test_under_the_flash_policy_nothing_of_the_selection_is_made_again(chip):
+    """A block's backward under ``remat_policy="flash"``: the kept set
+    in bits, the flash outputs and the indexer's term with its
+    cotangents are saved, so every kernel runs once; and the kept set
+    is nowhere a byte a pair."""
+    hlo = gradient_hlo(chip, SEQ, jnp.bfloat16, remat=True)
+    assert device_obs.pallas_kernels(hlo) == dict.fromkeys(KERNELS, 1)
+    assert "s8[1,%d,%d]" % (SEQ, SEQ) not in hlo
+    assert "s8[1,%d,%d]" % (SEQ, SEQ // 8) in hlo
 
 
 def test_the_trace_reader_charges_every_kernel_to_its_scope(chip):
