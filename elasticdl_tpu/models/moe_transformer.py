@@ -38,6 +38,7 @@ from elasticdl_tpu.models.transformer import (
     HyperDims,
     IndexerDims,
     LatentDims,
+    LoopedDims,
     MixerKind,
     ShortConvDims,
     YarnScaling,
@@ -47,6 +48,7 @@ from elasticdl_tpu.models.transformer import (
 from elasticdl_tpu.ops import (
     block_diffusion,
     flash_attention,
+    looped_exit,
     short_conv,
     sparse_attention,
 )
@@ -615,6 +617,20 @@ def merge_routing(layers):
     return merged
 
 
+class HeadKernel(nn.Module):
+    """The output head's kernel (D, V) as ``nn.Dense`` names and draws
+    it (``<name>/kernel``), handed out unapplied: a looped model's loss
+    applies it to every exit a chunk of positions at a time."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self, width):
+        return self.param(
+            "kernel", nn.initializers.lecun_normal(),
+            (width, self.features))
+
+
 class MoeTransformerLM(nn.Module):
     """Decoder-only LM with an MoE FFN in every ``moe_every``-th block
     (block i is an expert block when ``i % moe_every == moe_every - 1``:
@@ -655,6 +671,21 @@ class MoeTransformerLM(nn.Module):
     router with a balancing bias over 32 experts of which a chip holds
     8; ``mixer_kinds()`` says what such a model is made of (the
     journal's event of that name).
+
+    Ouro-2.6B's is every layer dense (``first_k_dense=num_layers``,
+    ``dense_act="swiglu"``), ``sandwich`` (a norm on each sublayer's
+    output too) and ``looped`` (a ``LoopedDims``): the blocks run
+    ``passes`` times over ONE parameter tree, ``ln_f`` ends every pass
+    (the normed state is that pass's exit and the next pass's input),
+    and one gate (``early_exit_gate``, a ``Dense(1)`` with bias shared
+    by the passes) gives every position a distribution over the exits
+    (``ops/looped_exit.py``). A training call then returns no
+    ``logits``: ``exits`` (the passes' states, (B, S, D) each),
+    ``exit_log_probs`` (passes, B, S) in float32, ``head_kernel`` and
+    ``exit_beta`` for ``loss``, which applies the one head to every
+    exit a chunk of positions at a time, and ``looped`` (the
+    ``looped_exit`` event's facts). An eval call returns the LAST
+    pass's logits, bare.
 
     ``objective="block_diffusion"`` (SDAR's: ``ops/block_diffusion.py``)
     trains the same blocks to denoise and not to predict the next
@@ -766,6 +797,12 @@ class MoeTransformerLM(nn.Module):
     # and ``dsa`` (the ``dsa_select`` event's facts, one entry a layer)
     indexer: Optional[IndexerDims] = None
     indexer_loss_coef: float = 1.0
+    # a norm on each sublayer's OUTPUT too (``Block.sandwich``), and
+    # the blocks run ``looped.passes`` times over one parameter tree
+    # (``LoopedDims``; the class's docstring). None: the stack is
+    # walked once, the tree and the program every model always had
+    sandwich: bool = False
+    looped: Optional[LoopedDims] = None
 
     def _mixer(self, kind, layout=None):
         """A layer's mixer by its KIND, stated once: what a block hands
@@ -907,6 +944,92 @@ class MoeTransformerLM(nn.Module):
                     "%s beside %s: not built, so not run"
                     % (self.indexer, what))
 
+    def _check_looped(self, kinds, denoise):
+        """A looped stack runs dense blocks with softmax attention under
+        next-token prediction. What it was not built beside is refused,
+        each by its name."""
+        dense = all(
+            i < self.first_k_dense
+            or i % self.moe_every != self.moe_every - 1
+            for i in range(self.num_layers))
+        if self.looped.passes < 1:
+            raise ValueError("%s: at least one pass" % (self.looped,))
+        for what, asked in (
+                ("an expert block (first_k_dense=%d of %d layers, "
+                 "moe_every=%d: the passes share dense blocks)"
+                 % (self.first_k_dense, self.num_layers, self.moe_every),
+                 not dense),
+                ("hyper-connections (hc)", self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("objective=\"block_diffusion\"", denoise),
+                ("a learned indexer (indexer)", self.indexer is not None),
+                ("a 'linear' or 'conv' mixer (layer_kinds=%r)"
+                 % (self.layer_kinds,), bool({"linear", "conv"} & set(kinds))),
+                ("a head tied to the embedding (tie_embeddings)",
+                 self.tie_embeddings)):
+            if asked:
+                raise ValueError(
+                    "%s beside %s: not built, so not run"
+                    % (self.looped, what))
+
+    def _looped(self, x, make_blocks, training, positions):
+        """The looped stack from the embedded tokens on: ONE ``nn.scan``
+        over a pass (the blocks ``make_blocks()`` builds, ``ln_f``, the
+        gate) with the parameters broadcast, so every pass reads the
+        same tree and the compiler sees one pass (against the passes
+        unrolled, on the chip at Ouro's cell: the step 4% faster, the
+        compile 36 s for 91, the peak 0.7 GB lower; PERF.md Section 6,
+        PR 55). The gate reads every exit; the last pass's reading is
+        not used (the last exit takes what is left)."""
+        passes = self.looped.passes
+
+        def end_of_pass(model, x):
+            with jax.named_scope("looped/exit_norm"):
+                x = make_norm(self.norm, self.norm_eps, "ln_f")(x)
+            if passes == 1:
+                return x, jnp.zeros(x.shape[:-1], jnp.float32)
+            with jax.named_scope("exit/gate"):
+                # float32: a position's probabilities come from it
+                gate = nn.Dense(
+                    1, dtype=jnp.float32, name="early_exit_gate")(x)
+            return x, gate[..., 0]
+
+        # under remat a pass's end keeps its input alone, as a block
+        # does (the gate's float32 copy of an exit is 128 MB a pass)
+        if self.remat:
+            end_of_pass = nn.remat(end_of_pass)
+
+        def a_pass(model, x, _):
+            with jax.named_scope("looped/pass"):
+                for block in make_blocks():
+                    x, _ = block(x, training, positions)
+            x, gate = end_of_pass(model, x)
+            return x, (x, gate)
+
+        x, (exits, gate_logits) = nn.scan(
+            a_pass, variable_broadcast="params",
+            split_rngs={"params": False}, length=passes)(self, x, None)
+        kernel = HeadKernel(self.vocab_size, name="lm_head")(self.embed_dim)
+        if not training:
+            return x @ kernel
+        with jax.named_scope("exit/gate"):
+            logits = gate_logits[:passes - 1]
+            log_p = looped_exit.exit_distribution(logits)
+            p, log_p_fact, logits = jax.lax.stop_gradient(
+                (jnp.exp(log_p), log_p, logits))
+            facts = {
+                "passes": jnp.float32(passes),
+                "p_mean": p.mean(axis=(1, 2)),
+                "entropy": -(p * log_p_fact).sum(axis=0).mean(),
+                "lambda_mean": jax.nn.sigmoid(logits).mean(axis=(1, 2)),
+            }
+        return {
+            "exits": tuple(exits[t] for t in range(passes)),
+            "exit_log_probs": log_p,
+            "head_kernel": kernel, "exit_beta": self.looped.beta,
+            "looped": facts,
+        }
+
     def _check_conv(self, denoise, by_kind):
         """A gated short convolution runs beside causal softmax layers
         of one kind, in dense and in expert blocks, on one device or
@@ -993,6 +1116,9 @@ class MoeTransformerLM(nn.Module):
                 raise ValueError(
                     "%s under objective=\"block_diffusion\" or beside a "
                     "linear mixer (linear): not built, so not run" % what)
+        kinds = tuple(self.layer_kinds or ("full",))
+        if self.looped is not None:
+            self._check_looped(kinds, denoise)
         tokens = tokens.astype(jnp.int32)
         positions = layout = facts = None
         if denoise:
@@ -1022,7 +1148,6 @@ class MoeTransformerLM(nn.Module):
             )
             if self.remat else (lambda cls: cls)
         )
-        kinds = tuple(self.layer_kinds or ("full",))
         self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
         routing, mhc, dsa = [], [], []
@@ -1036,7 +1161,8 @@ class MoeTransformerLM(nn.Module):
             return wrap(Block)(
                 self._mixer(kind, layout), mlp_ratio=self.mlp_ratio,
                 norm=self.norm, norm_eps=self.norm_eps, hc=self.hc,
-                layer_index=index, mesh=self.mesh, name=name, **second)
+                layer_index=index, mesh=self.mesh, name=name,
+                sandwich=self.sandwich, **second)
 
         def count(aux):
             """A block's losses and facts into the model's."""
@@ -1051,6 +1177,10 @@ class MoeTransformerLM(nn.Module):
             if "dsa" in aux:
                 dsa.append(aux["dsa"])
 
+        if self.looped is not None:
+            return self._looped(x, lambda: [
+                block("block_%d" % i, i, kinds[i % len(kinds)], dense=True)
+                for i in range(self.num_layers)], training, positions)
         for i in range(self.num_layers):
             kind = kinds[i % len(kinds)]
             dense = (i < self.first_k_dense
@@ -1228,43 +1358,53 @@ def custom_model(mesh=None):
     )
 
 
+def shifted_cross_entropy(labels, logits, ahead=1, horizon=1):
+    """A sample's mean cross-entropy of position ``i``'s logits against
+    ``labels[i + ahead]``, over the positions that have every target up
+    to ``horizon`` ahead (the last ``horizon`` have not)."""
+    last = labels.shape[1] - horizon
+    return sparse_softmax_cross_entropy(
+        labels[:, ahead:last + ahead], logits[:, :last]).mean(axis=-1)
+
+
 def loss(labels, predictions):
-    if isinstance(predictions, dict):
-        logits = predictions["logits"]
-        aux = predictions["aux_loss"]
-        if "weights" in predictions:
-            # block diffusion: position-aligned, weighted by 1 / t on
-            # the masked positions (ops/block_diffusion.py)
-            return block_diffusion.weighted_loss(
-                labels, logits, predictions["weights"]) + aux
-        if "mtp_logits" in predictions:
-            # a multi-token-prediction module: over the positions that
-            # have both targets, CE(main, t_(i+1)) + weight x CE(module,
-            # t_(i+2)); the second term goes out by name as well
-            main = sparse_softmax_cross_entropy(
-                labels[:, 1:-1], logits[:, :-2]).mean(axis=-1)
-            mtp = sparse_softmax_cross_entropy(
-                labels[:, 2:], predictions["mtp_logits"][:, :-2]
-            ).mean(axis=-1)
-            return (main + predictions["mtp_loss_weight"] * mtp + aux,
-                    {"mtp_loss": mtp})
-        if "indexer_loss" in predictions:
-            # a learned indexer's own term (its KL to the attention's
-            # probabilities, summed over the layers), times its
-            # coefficient in the sum and unweighted by its name
-            term = predictions["indexer_loss"]
-            main = sparse_softmax_cross_entropy(
-                labels[:, 1:], logits[:, :-1]).mean(axis=-1)
-            return (main + aux + predictions["indexer_loss_coef"] * term,
-                    {"indexer_loss": term})
-    else:
-        logits, aux = predictions, 0.0
-    per_token = sparse_softmax_cross_entropy(
-        labels[:, 1:], logits[:, :-1]
-    )
+    if not isinstance(predictions, dict):
+        return shifted_cross_entropy(labels, predictions)
+    if "exits" in predictions:
+        # a looped stack: the expected cross-entropy over the passes'
+        # exits less beta x the exit distribution's entropy, the one
+        # head applied a chunk of positions at a time
+        # (ops/looped_exit.py); its parts go out by name
+        return looped_exit.expected_loss(
+            labels, predictions["exits"], predictions["head_kernel"],
+            predictions["exit_log_probs"], predictions["exit_beta"])
+    logits = predictions["logits"]
     # aux is a scalar: adding it to every per-sample loss leaves the
     # masked mean shifted by exactly aux.
-    return per_token.mean(axis=-1) + aux
+    aux = predictions["aux_loss"]
+    if "weights" in predictions:
+        # block diffusion: position-aligned, weighted by 1 / t on
+        # the masked positions (ops/block_diffusion.py)
+        return block_diffusion.weighted_loss(
+            labels, logits, predictions["weights"]) + aux
+    if "mtp_logits" in predictions:
+        # a multi-token-prediction module: over the positions that
+        # have both targets, CE(main, t_(i+1)) + weight x CE(module,
+        # t_(i+2)); the second term goes out by name as well
+        main = shifted_cross_entropy(labels, logits, horizon=2)
+        mtp = shifted_cross_entropy(
+            labels, predictions["mtp_logits"], ahead=2, horizon=2)
+        return (main + predictions["mtp_loss_weight"] * mtp + aux,
+                {"mtp_loss": mtp})
+    main = shifted_cross_entropy(labels, logits)
+    if "indexer_loss" in predictions:
+        # a learned indexer's own term (its KL to the attention's
+        # probabilities, summed over the layers), times its
+        # coefficient in the sum and unweighted by its name
+        term = predictions["indexer_loss"]
+        return (main + aux + predictions["indexer_loss_coef"] * term,
+                {"indexer_loss": term})
+    return main + aux
 
 
 def optimizer():

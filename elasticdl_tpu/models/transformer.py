@@ -117,6 +117,22 @@ class IndexerDims:
             self.heads, self.head_dim, self.topk)
 
 
+@dataclasses.dataclass(frozen=True)
+class LoopedDims:
+    """A looped stack (weight-shared depth; Ouro's ``total_ut_steps``):
+    the model's blocks run ``passes`` times over ONE set of parameters,
+    the final norm ends every pass, one gate shared by the passes gives
+    every position a distribution over the ``passes`` exits, and the
+    loss is the expected cross-entropy over the exits less ``beta`` x
+    that distribution's entropy (``MoeTransformerLM.looped``)."""
+
+    passes: int
+    beta: float
+
+    def __str__(self):
+        return "looped passes=%d beta=%g" % (self.passes, self.beta)
+
+
 def yarn_mscale(factor, mscale):
     """``0.1 mscale ln(factor) + 1`` over a factor above 1, else 1."""
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
@@ -973,6 +989,11 @@ class Block(nn.Module):
     down) or "swiglu" (silu(gate) x up, down) of width ``mlp_dim``
     (``mlp_ratio x dim`` when None), under the scope ``dense_mlp``.
 
+    ``sandwich``: a second norm a sublayer, on its OUTPUT and inside
+    the residual branch (``ln_attn_out``, ``ln_mlp_out``): ``x +
+    norm_out(f(norm(x)))``, Ouro's block. False: no such norm, the tree
+    and the program the block always had.
+
     ``hc``: a hyper-connected residual path. ``x`` is then the n
     streams (B, n, S, D) and each sublayer goes through a
     ``HyperConnection`` (``hc_attn``, ``hc_mlp``). None: ``x +
@@ -996,6 +1017,7 @@ class Block(nn.Module):
     hc: Optional[HyperDims] = None
     layer_index: int = 0
     mesh: Optional[Any] = None
+    sandwich: bool = False
 
     def _dense_mlp(self, h, training):
         """The dense second sublayer, ``(y, {})`` as the experts' is
@@ -1042,12 +1064,19 @@ class Block(nn.Module):
             return out
 
         norm = lambda name: make_norm(self.norm, self.norm_eps, name)
+        # a sublayer's output norm, where the block has one
+        after = lambda name: norm(name) if self.sandwich else (lambda y: y)
         if self.hc is None:
             x = constrain(x, self.mesh, RESIDUAL_SPEC)
-            x = x + mix(norm("ln_attn")(x))
+            x = x + after("ln_attn_out")(mix(norm("ln_attn")(x)))
             y, of_second = second(norm("ln_mlp")(x), training)
-            return constrain(x + y, self.mesh, RESIDUAL_SPEC), {
+            return constrain(
+                x + after("ln_mlp_out")(y), self.mesh, RESIDUAL_SPEC), {
                 **of_second, **aux}
+        if self.sandwich:
+            raise ValueError(
+                "a sandwich-normed block (sandwich) under "
+                "hyper-connections (hc): not built, so not run")
         x = constrain(x, self.mesh, STREAMS_SPEC)
         u, write, attn_facts = HyperConnection(
             self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)

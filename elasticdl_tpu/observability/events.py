@@ -340,12 +340,29 @@ EVENT_TYPES = frozenset({
                              #   set, nats; near_share: kept keys among
                              #   the query's nearest topk) and, of one
                              #   head's grid, tiles_run of tiles_causal
+    "looped_exit",           # the same steps of a looped stack
+                             #   (models/moe_transformer.py:
+                             #   MoeTransformerLM.looped,
+                             #   ops/looped_exit.py): a list a fact,
+                             #   one entry a pass (+ step, passes;
+                             #   p_mean: the exit distribution's mean
+                             #   over positions, sums to 1;
+                             #   lambda_mean: the gate's mean, passes
+                             #   - 1 entries) and entropy: the mean
+                             #   H(p), nats; the cross-entropy an exit
+                             #   is loss_terms' ce_exit_<t> of the
+                             #   same step
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction
                              #   module's cross-entropy, unweighted;
                              #   indexer_loss: a learned indexer's KL
-                             #   term, unweighted)
+                             #   term, unweighted; expected_ce,
+                             #   exit_entropy, ce_exit_<t>: a looped
+                             #   stack's expected cross-entropy over
+                             #   its exits, the exit distribution's
+                             #   entropy, unweighted, and the
+                             #   cross-entropy an exit)
 })
 
 

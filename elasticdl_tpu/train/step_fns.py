@@ -91,8 +91,10 @@ class Fact(NamedTuple):
 # exchange's bytes), a
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
 # block (``models/moe_transformer.py``), a learned sparse-attention
-# indexer's, one a layer, and what the loss function names of its own
-# sum (a multi-token-prediction module's loss, an indexer's term)
+# indexer's, one a layer, a looped stack's exit distribution, one entry
+# a pass, and what the loss function names of its own sum (a
+# multi-token-prediction module's loss, an indexer's term, a looped
+# stack's expected cross-entropy, entropy and cross-entropy an exit)
 FACTS = (
     Fact("routing", "moe_routing", (
         ("load_max", "tokens_per_expert_max"),
@@ -112,6 +114,7 @@ FACTS = (
     Fact("noise", "bd_noise"),
     Fact("mhc", "mhc"),
     Fact("dsa", "dsa_select"),
+    Fact("looped", "looped_exit"),
     Fact("loss_terms", "loss_terms", of_loss=True),
 )
 
