@@ -24,7 +24,6 @@ Design notes (TPU-first):
 import contextlib
 import dataclasses
 import functools
-import math
 from typing import Any, Optional
 
 import flax.linen as nn
@@ -45,6 +44,12 @@ from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
     ring_attention,
     ulysses_attention,
+)
+from elasticdl_tpu.ops.rotary import (  # noqa: F401 - this module's names
+    rotary_embedding,
+    rotate,
+    yarn_frequencies,
+    yarn_mscale,
 )
 from elasticdl_tpu.parallel.mesh import DATA_AXES
 from elasticdl_tpu.parallel.sharding import ShardingRules, constrain
@@ -131,68 +136,6 @@ class LoopedDims:
 
     def __str__(self):
         return "looped passes=%d beta=%g" % (self.passes, self.beta)
-
-
-def yarn_mscale(factor, mscale):
-    """``0.1 mscale ln(factor) + 1`` over a factor above 1, else 1."""
-    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
-
-
-def yarn_frequencies(dim, base, scaling):
-    """The ``dim // 2`` rotary frequencies under YaRN, as the published
-    DeepSeek-V3 code builds them (``DeepseekV3YarnRotaryEmbedding``):
-    pair i keeps ``base^(-2i/dim)`` below the correction dimension of
-    ``beta_fast`` rotations over the original context, takes that over
-    ``factor`` above the one of ``beta_slow``, and a linear blend of
-    the two between them."""
-    half = dim // 2
-
-    def correction_dim(rotations):
-        return dim * math.log(
-            scaling.original_max_position_embeddings
-            / (rotations * 2 * math.pi)) / (2 * math.log(base))
-
-    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
-    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    extrapolated = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    ramp = jnp.clip(
-        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
-    return extrapolated / scaling.factor * ramp + extrapolated * (1 - ramp)
-
-
-def rotary_embedding(x, base=10000.0, seq_axis=2, positions=None,
-                     scaling=None):
-    """Apply RoPE; seq_axis=2 for (B, H, S, d), 1 for (B, S, H, d).
-    ``positions`` (S,): the position each row rotates by where it is
-    not its index (block diffusion's two copies of one sequence).
-    ``scaling`` (``YarnScaling``): YaRN's frequency table in place of
-    ``base^(-2i/d)``; cos and sin are multiplied by ``mscale`` over
-    ``mscale_all_dim``'s (1 where the two are equal, DeepSeek-V3's)."""
-    seq, dim = x.shape[seq_axis], x.shape[-1]
-    half = dim // 2
-    if scaling is None:
-        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-        amplitude = 1.0
-    else:
-        freqs = yarn_frequencies(dim, base, scaling)
-        amplitude = (yarn_mscale(scaling.factor, scaling.mscale)
-                     / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
-    positions = (
-        jnp.arange(seq, dtype=jnp.float32) if positions is None
-        else positions.astype(jnp.float32))
-    angles = positions[:, None] * freqs[None, :]
-    shape = [1] * x.ndim
-    shape[seq_axis], shape[-1] = seq, half
-    cos = jnp.cos(angles).reshape(shape)
-    sin = jnp.sin(angles).reshape(shape)
-    if amplitude != 1.0:
-        cos, sin = cos * amplitude, sin * amplitude
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
 
 
 class ZeroCentredRMSNorm(nn.Module):
@@ -292,16 +235,6 @@ class Attention(nn.Module):
     # program the block always had
     indexer: Optional[IndexerDims] = None
 
-    def _rotate(self, t, positions=None):
-        rotate = functools.partial(
-            rotary_embedding, base=self.rope_theta, positions=positions,
-            scaling=self.rope_scaling)
-        if self.rotary_dim is None:
-            return rotate(t)
-        return jnp.concatenate([
-            rotate(t[..., :self.rotary_dim]), t[..., self.rotary_dim:],
-        ], axis=-1)
-
     def _scoped(self, part):
         """The named scope of one part of the mixer, or none."""
         if self.kind_scope is None:
@@ -365,8 +298,10 @@ class Attention(nn.Module):
                 dense("key", "k_norm", heads=kv_heads), "k_norm"))
             v = to_bhsd(dense("value", heads=kv_heads))
         with self._scoped("rotary"):
-            q = self._rotate(q, positions)
-            k = self._rotate(k, positions)
+            q, k = rotate(
+                q, k, rotary_dim=self.rotary_dim, base=self.rope_theta,
+                positions=positions, scaling=self.rope_scaling,
+                mesh=self.mesh)
 
         facts = None
         if self.indexer is not None:
@@ -437,8 +372,8 @@ class Attention(nn.Module):
                 nn.Dense(idx.head_dim, use_bias=False, name="indexer_k")(
                     detached))
             # the whole indexer head rotates by the model's own table
-            qi = self._rotate(qi)
-            ki = self._rotate(ki[:, None])[:, 0]
+            qi = rotary_embedding(qi, base=self.rope_theta)
+            ki = rotary_embedding(ki[:, None], base=self.rope_theta)[:, 0]
             w = nn.Dense(idx.heads, use_bias=False, name="indexer_w")(
                 detached).astype(jnp.float32) * (
                     idx.heads ** -0.5 * idx.head_dim ** -0.5)

@@ -80,13 +80,18 @@ def test_the_step_compiles_one_pass_that_runs_four_times(compiled):
     forward and backward, under ``looped/pass`` inside a loop's body,
     and the loops over the passes have ``PASSES`` trips (28 forwards and
     28 backwards a step in the device trace at the cell's seven
-    layers)."""
+    layers). Since PR 56 the rotation of q and k is a kernel pair too
+    (``ops/rotary.py``): forward, in the policy's recompute and
+    backward, a layer."""
     text = compiled.as_text()
     names = kernels(text)
     forward = [n for n in names if n.endswith("flash_fwd/pallas_call")]
     backward = [n for n in names if n.endswith("flash_bwd/pallas_call")]
     assert len(forward) == len(backward) == LAYERS, names
-    assert len(names) == 2 * LAYERS
+    turned = [n for n in names if n.endswith("rotary_fwd/pallas_call")]
+    turned_back = [n for n in names if n.endswith("rotary_bwd/pallas_call")]
+    assert len(turned) == 2 * LAYERS and len(turned_back) == LAYERS, names
+    assert len(names) == 5 * LAYERS
     assert all(re.search(
         r"\._looped/while/body/.*looped/pass/.*block_\d+/attn/", n)
         for n in names), names
