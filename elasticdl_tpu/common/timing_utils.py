@@ -21,6 +21,10 @@ never waits for the device:
   ``edl_step_time_seconds``, a step far above the running median is
   journaled and logged as ``slow_step`` at once, and every
   ``interval`` steps one ``loop_phases`` event leaves the process.
+  The loop reads a step's device values one step late, after it has
+  dispatched the next (``worker/worker.py``): ``read_ahead`` counts
+  the steps read so and ``drained`` those it had to read with nothing
+  queued behind them, by reason; both leave with ``loop_phases``.
 - ``begin_startup`` / ``begin_teardown`` open the two records that
   cover the process outside the loop (``worker_startup``,
   ``worker_teardown``; the master's are ``master_startup`` and
@@ -63,7 +67,7 @@ STEP_PHASE = "batch_process"
 
 # a step is slow when its wall time exceeds SLOW_FACTOR times the
 # median of the last SLOW_WINDOW steps and exceeds it by SLOW_MIN_NS
-# (Timing._judge says what a step is where the loop runs ahead)
+# (Timing._judge says what a step's wall time is under the late read)
 SLOW_FACTOR = 1.5
 SLOW_MIN_NS = 20_000_000
 SLOW_WINDOW = 64
@@ -303,6 +307,11 @@ class Timing:
         self._exempt = 0
         self._run = None  # the steps since the loop last read the device
         self._interval_open = None  # the loop_phases being summed
+        # since the last loop_phases: steps read with a later one
+        # already dispatched, and steps read with none, by reason
+        self._ahead = 0
+        self._drains = {}
+        self._last_number = 0
         self._faults = _faults()
         self._metrics_on = obs_metrics.metrics_enabled()
         self._phase_series = {}
@@ -384,6 +393,21 @@ class Timing:
             jax.block_until_ready(result)
         self.end_record(phase, start)
 
+    # -- the late read -------------------------------------------------
+
+    def read_ahead(self):
+        """The loop is about to read a step's device values with a
+        later step already dispatched: the device has work queued
+        while the host does its turn."""
+        self._ahead += 1
+
+    def drained(self, reason):
+        """The loop is about to read the step in flight with nothing
+        queued behind it, because of ``reason`` (a checkpoint, the end
+        of the batches, ...): the device idles through the host's
+        turn, once."""
+        self._drains[reason] = self._drains.get(reason, 0) + 1
+
     # -- a step closes -------------------------------------------------
 
     def _close_step(self, step, wall, record):
@@ -399,7 +423,7 @@ class Timing:
                 "phases": {}, "slowest_step": step.number,
                 "slowest_wall_ns": 0,
             }
-        span["last_step"] = step.number
+        span["last_step"] = self._last_number = step.number
         span["steps"] += 1
         span["wall_ns"] += wall
         phases = span["phases"]
@@ -412,13 +436,16 @@ class Timing:
             self._emit_interval()
 
     def _judge(self, step, wall, record):
-        """Is this step slow? A loop that runs ahead of the device
-        (``SpmdTrainer``: nothing fetched until a step is logged) pays
-        for several steps in the one that reads a device value, so
-        steps are judged in runs that end at a ``device_wait``: a run's
-        wall time against its number of steps times the median step.
-        Where every step fetches (``JaxTrainer``'s health scalars) a
-        run is one step."""
+        """Is this step slow? The loop reads a step one step late:
+        iteration N dispatches step N and then waits for step N - 1
+        (``device_wait``), so where every step is read (``JaxTrainer``'s
+        health scalars) an iteration's wall time is still one device
+        step, the one before its own, and a run is one step. Where
+        nothing is read until a step is logged (``SpmdTrainer``) the
+        loop runs further ahead and pays for several steps in the
+        iteration that reads, so steps are judged in runs that end at
+        a ``device_wait``: a run's wall time against its number of
+        steps times the median step."""
         run = self._run
         if run is None:
             run = self._run = {"steps": 0, "wall_ns": 0, "phases": {},
@@ -476,7 +503,17 @@ class Timing:
         an interval."""
         span, self._interval_open = self._interval_open, None
         if span is None:
-            return
+            if not self._drains:
+                return
+            # a drain after the interval's last step closed (the end
+            # of the batches): an event of no steps carries it
+            span = {
+                "first_step": self._last_number, "steps": 0, "wall_ns": 0,
+                "phases": {"other": 0}, "slowest_step": self._last_number,
+                "slowest_wall_ns": 0, "last_step": self._last_number,
+            }
+        span["ahead_steps"], self._ahead = self._ahead, 0
+        span["drains"], self._drains = self._drains, {}
         switches, faults = _faults()
         span["invol_ctx_switches"] = switches - self._faults[0]
         span["major_faults"] = faults - self._faults[1]

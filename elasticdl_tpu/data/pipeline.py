@@ -41,6 +41,54 @@ class _Flush:
 FLUSH = _Flush()
 
 
+class _Prefetched:
+    """Iterator over what a producer thread has made of a source: the
+    thread starts with the first ``next``. ``ready()`` says whether
+    ``next`` would return without waiting (an item, the end or the
+    producer's error): the worker's loop asks before it waits for a
+    batch with a step still unread (``worker/worker.py``)."""
+
+    _END = object()
+
+    def __init__(self, source_fn, depth):
+        self._source_fn = source_fn
+        self._queue = queue.Queue(maxsize=depth)
+        self._error = None
+        self._thread = None
+        self._ended = False
+
+    def _produce(self):
+        try:
+            for item in self._source_fn():
+                self._queue.put(item)
+        # propagated: ``__next__`` re-raises it on the consumer
+        except BaseException as e:  # edlint: disable=ft-swallowed-except
+            self._error = e
+        finally:
+            self._queue.put(self._END)
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._ended:
+            raise StopIteration
+        if self._thread is None:
+            self._thread = threading.Thread(
+                target=self._produce, daemon=True)
+            self._thread.start()
+        item = self._queue.get()
+        if item is self._END:
+            self._ended = True
+            if self._error is not None:
+                raise self._error
+            raise StopIteration
+        return item
+
+    def ready(self):
+        return self._ended or not self._queue.empty()
+
+
 class Dataset:
     """A re-iterable stream of examples with functional combinators."""
 
@@ -136,32 +184,10 @@ class Dataset:
         return Dataset(gen)
 
     def prefetch(self, depth=2):
-        def gen():
-            q = queue.Queue(maxsize=depth)
-            sentinel = object()
-            error = []
-
-            def producer():
-                try:
-                    for item in self._source_fn():
-                        q.put(item)
-                # propagated: the consumer loop re-raises error[0]
-                except BaseException as e:  # edlint: disable=ft-swallowed-except
-                    error.append(e)
-                finally:
-                    q.put(sentinel)
-
-            t = threading.Thread(target=producer, daemon=True)
-            t.start()
-            while True:
-                item = q.get()
-                if item is sentinel:
-                    if error:
-                        raise error[0]
-                    return
-                yield item
-
-        return Dataset(gen)
+        """Items made ahead on a background thread, ``depth`` of them;
+        the iterator's ``ready()`` says whether the next one is
+        there."""
+        return Dataset(lambda: _Prefetched(self._source_fn, depth))
 
     def take(self, n):
         def gen():

@@ -253,11 +253,22 @@ EVENT_TYPES = frozenset({
                              #   thread's time by phase (+ first_step,
                              #   last_step, steps, wall_ns, phases{},
                              #   slowest_step, slowest_wall_ns,
+                             #   ahead_steps: the steps whose read
+                             #   [device_wait] began with a later
+                             #   step already dispatched,
+                             #   drains{checkpoint, eval, mesh, stop,
+                             #   end, input, error}: the steps read
+                             #   with nothing queued behind them, by
+                             #   what made the loop read them; an
+                             #   event of 0 steps carries a drain that
+                             #   came after the last step closed,
                              #   invol_ctx_switches, major_faults)
     "slow_step",             # a step far above the running median, at
-                             #   once (+ step, task, steps [the run
-                             #   it closes: 1 where every step
-                             #   fetches], wall_ns and phases{} of
+                             #   once (+ step [the iteration: it
+                             #   dispatched this step and waited for
+                             #   the one before], task, steps [the run
+                             #   it closes: 1 where every step is
+                             #   read], wall_ns and phases{} of
                              #   the run, median_ns a step,
                              #   invol_ctx_switches, major_faults
                              #   since the last loop_phases)

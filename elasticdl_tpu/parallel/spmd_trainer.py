@@ -300,9 +300,11 @@ class SpmdTrainer(Trainer):
     def train_step(self, state, batch):
         """One step, in the phases of the loop thread's ledger: the
         batch's transfer (``h2d``) and the call of the jitted step
-        until it returns (``dispatch``). Nothing is fetched here, so
-        the loop runs ahead of the device and the runtime's
-        back-pressure is time inside ``dispatch``."""
+        until it returns (``dispatch``). Nothing is fetched here: the
+        step's facts go with its ``pending_step`` to ``read_step``
+        (``worker/trainer.py``), a step late and on the steps the loop
+        logs alone, so between them the loop runs ahead of the device
+        and the runtime's back-pressure is time inside ``dispatch``."""
         phase = timing_utils.current().phase
         state = self.ensure_state(state, batch)
         with phase("h2d"):

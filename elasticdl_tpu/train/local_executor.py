@@ -162,8 +162,12 @@ class LocalExecutor:
                     self.state, loss = self.trainer.train_step(
                         self.state, batch
                     )
-                    with ledger.phase("device_wait"):
-                        losses.append(float(loss))
+                    # a local run reads every step at once (the
+                    # trainer's one read: ``device_wait``, ``health``)
+                    value, _ = self.trainer.read_step(
+                        self.trainer.pending_step(loss)
+                    )
+                    losses.append(value)
                 step += 1
             logger.info(
                 "Epoch %d done; last-batch loss %.4f", epoch, losses[-1]

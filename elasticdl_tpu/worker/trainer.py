@@ -11,6 +11,7 @@ parallel/spmd_trainer.py — both wrap the same step functions
 """
 
 import inspect
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
@@ -32,6 +33,20 @@ from elasticdl_tpu.train.train_state import (
 )
 
 logger = _logger_factory("elasticdl_tpu.worker.trainer")
+
+
+class PendingStep(NamedTuple):
+    """What a dispatched step left on the device for the host to read:
+    nothing of it has been fetched. The loop keeps one while the next
+    step is dispatched and hands it to ``Trainer.read_step`` a step
+    late (``worker/worker.py:Worker._after_train_batch``)."""
+
+    loss: Any
+    # ``(grad_norm, nonfinite)`` where the step computes the health
+    # scalars, else None
+    health: Any = None
+    # the step's own facts, by the keys of ``train/step_fns.py:FACTS``
+    facts: Any = None
 
 
 class Trainer:
@@ -57,9 +72,12 @@ class Trainer:
     # the dtype the step computes in (None: the parameters' float32)
     compute_dtype = None
     # the newest step's facts still on the device, by the keys of
-    # ``train/step_fns.py:FACTS``; the loop fetches them on the steps
-    # it logs
+    # ``train/step_fns.py:FACTS``; they travel with the step's
+    # ``PendingStep`` and are fetched on the steps the loop logs
     facts = None
+    # the newest step's health scalars still on the device,
+    # ``(grad_norm, nonfinite)``, where the step computes them
+    health_scalars = None
     # a ``train/health.py:HealthTracker`` where the step is watched
     health = None
     # a ``train/device_tier.py:DeviceEmbeddingTier`` where rows live
@@ -100,8 +118,41 @@ class Trainer:
         return state
 
     def train_step(self, state, batch):
-        """``(new state, loss)``, the loss still on the device."""
+        """``(new state, loss)``: the step is dispatched and NOTHING of
+        it is fetched, so the call returns while the device still runs
+        it. What the step hands out beside the state (its loss, its
+        health scalars, its facts) stays on the device until
+        ``read_step`` is given the step's ``pending_step``; the
+        worker's loop does that one step late, with the next step
+        already queued."""
         raise NotImplementedError
+
+    def pending_step(self, loss):
+        """The record of the step ``train_step`` just dispatched, to be
+        taken before the next call: its loss, and what the trainer
+        holds of it on the device."""
+        return PendingStep(loss, self.health_scalars, self.facts)
+
+    def read_step(self, pending, with_facts=False):
+        """``(loss, facts)`` of a dispatched step on the host: ONE
+        transfer of one tree (the loss, the health scalars where the
+        step has them, the facts' leaves on a step that is logged),
+        which waits for the device to finish that step
+        (``device_wait``), then the sentinels over it (``health``:
+        ``train/health.py:HealthTracker.observe``, which raises
+        ``HealthSentinelError`` under ``halt``). Each step is read
+        once, in the order of the steps."""
+        phase = timing_utils.current().phase
+        with phase("device_wait"):
+            loss, health, facts = jax.device_get((
+                pending.loss, pending.health,
+                pending.facts if with_facts else None,
+            ))
+        loss = float(loss)
+        if health is not None:
+            with phase("health"):
+                self.health.observe(loss, *health)
+        return loss, facts or {}
 
     def eval_step(self, state, batch):
         """The model's outputs for the batch, on the host."""
@@ -284,11 +335,17 @@ class JaxTrainer(Trainer):
         )
 
     def train_step(self, state, batch):
-        """One step, in the phases of the loop thread's ledger: the
-        call of the jitted step until it returns (``dispatch``; the
-        batch's transfer to the device is implicit in it), the fetch of
-        the health scalars, which waits for the device to finish the
-        step (``device_wait``), and the sentinels (``health``)."""
+        """One step's dispatch: the call of the jitted step until it
+        returns (``dispatch`` in the loop thread's ledger; the batch's
+        transfer to the device is implicit in it). Nothing is fetched:
+        the health scalars and the facts stay on the device for
+        ``read_step``, which the loop calls a step late. A
+        skip-sentinel batch already kept its state in-graph (nothing
+        else to drop on the dense path: there is no PS push), so a
+        skip is only COUNTED when it is read; under ``halt`` the read
+        raises, at most one step after the step that tripped and
+        before any checkpoint of its state (the loop reads the step in
+        flight before it saves)."""
         phase = timing_utils.current().phase
         state = self.ensure_state(state, batch)
         from elasticdl_tpu.testing import faults
@@ -300,17 +357,7 @@ class JaxTrainer(Trainer):
         with phase("dispatch"):
             state, loss, scalars = self._train_step(state, batch)
         self.facts = facts_of(scalars)
-        # one small host transfer per batch; a skip-sentinel batch
-        # already kept its state in-graph (nothing else to drop on
-        # the dense path — there is no PS push); halt raises
-        with phase("device_wait"):
-            observed = (
-                float(loss),
-                float(scalars["grad_norm"]),
-                bool(scalars["nonfinite"]),
-            )
-        with phase("health"):
-            self.health.observe(*observed)
+        self.health_scalars = (scalars["grad_norm"], scalars["nonfinite"])
         return state, loss
 
     @property

@@ -208,6 +208,10 @@ class Worker:
         # in), noted by begin_drain and journaled by _finish_drain
         self._drain_requested = None
         self._version = 0
+        # the newest dispatched step while nothing of it has been read:
+        # (its number, its batch, its ``trainer.PendingStep``); see
+        # ``_after_train_batch``
+        self._in_flight = None
         # Dense full-state checkpoints (params + model_state + optimizer
         # slots + step; the reference drops slot state,
         # ps/parameters.py:194-199). Restore happens lazily on the first
@@ -501,6 +505,8 @@ class Worker:
         if self._multihost is not None and self._multihost.epoch_moved(
             self._seen_mesh_epoch
         ):
+            # the step in flight is this process's last: read first
+            self._finish_in_flight("mesh")
             raise MeshEpochChanged(
                 "mesh epoch moved to %s at version %d"
                 % (self._seen_mesh_epoch, self._version)
@@ -669,6 +675,10 @@ class Worker:
         self.trainer.flush_device_tier()
 
     def _save_checkpoint(self):
+        # the step in flight produced the state this saves: it is read,
+        # observed (a halt raises here, before anything is written) and
+        # counted first
+        self._finish_in_flight("checkpoint")
         # in-flight sparse pushes land before the version is stamped
         # durable: a checkpoint claiming version V must not precede
         # V's gradients reaching the PS; device-tier rows flush for
@@ -716,6 +726,7 @@ class Worker:
             # no dense checkpoint configured: the barriers still run —
             # the PS's own stream checkpoint (cadenced off the same
             # watermark) must carry the async push and tier rows
+            self._finish_in_flight("checkpoint")
             self._join_trainer_pushes()
             self._flush_device_tier()
         events.emit(
@@ -725,12 +736,41 @@ class Worker:
         return True
 
     def _after_train_batch(self, batch, loss):
-        """Per-batch bookkeeping shared by every loop shape: version,
-        checkpoint, record accounting, liveness, callbacks. Each part
-        is a phase of the ledger; the gauges between them read the
-        last whole iteration and fall to ``other``."""
+        """Per-batch bookkeeping shared by every loop shape, called
+        right after the step's dispatch. The loop reads a step one
+        step LATE: the step just dispatched becomes the step in flight
+        (its number, its batch and its ``PendingStep``, nothing of it
+        fetched), and only then is the step BEFORE it finished
+        (``_finish_step``: its scalars fetched in one transfer and
+        observed, its records reported, its line logged), so the device
+        holds a queued program while the host does its turn. Then what
+        belongs to the newest step: checkpoint, liveness, callbacks.
+        Each part is a phase of the ledger.
+
+        The step in flight is finished at once, with nothing queued
+        behind it (a DRAIN, counted by reason in ``loop_phases``),
+        before anything that reads or persists the state or ends the
+        stream: a step-cadence or stream checkpoint (``checkpoint``),
+        a moved mesh epoch (``mesh``), ``stop_training`` (``stop``),
+        the end of the batches (``end``; ``eval`` where a parked
+        evaluation ended them), a batch that is not there yet when the
+        loop comes for it (``input``: the master may be waiting for the
+        unread step's task before it hands out another) and an
+        exception leaving the loop (``error``). Every step is read
+        exactly once, in order: under ``halt`` ``HealthSentinelError``
+        leaves the loop no later than one step after the non-finite
+        step and before a checkpoint holds its state, and a batch's
+        records are reported only after its scalars were observed."""
         phase = self._timing.phase
         self._version += 1
+        previous = self._in_flight
+        self._in_flight = (
+            self._version, batch, self.trainer.pending_step(loss)
+        )
+        if previous is not None:
+            self._timing.read_ahead()
+            self._finish_step(*previous)
+        self._m_version.set(self._version)
         with phase("checkpoint"):
             if (
                 self._checkpoint_mgr is not None
@@ -738,6 +778,44 @@ class Worker:
             ):
                 self._save_checkpoint()
             self.maybe_stream_checkpoint()
+        with phase("mesh_check"):
+            self._check_mesh_epoch()
+        with phase("callbacks"):
+            # the newest dispatched step's number and loss: a callback
+            # that needs the step done waits on the loss itself
+            for cb in self._callbacks:
+                cb.on_batch_end(self._version, loss)
+        if self.stop_training:
+            self._finish_in_flight("stop")
+
+    def _finish_in_flight(self, reason=None):
+        """Finishes the step in flight now, if there is one, with
+        nothing queued behind it; ``reason`` names the drain in the
+        ledger (None: a loop that reads every step in its own
+        iteration, which is no drain)."""
+        in_flight, self._in_flight = self._in_flight, None
+        if in_flight is None:
+            return
+        if reason is not None:
+            self._timing.drained(reason)
+        self._finish_step(*in_flight)
+
+    def _finish_step(self, number, batch, pending):
+        """What the host owes a dispatched step once the device is
+        done with it: one fetch of its loss, its health scalars and, on
+        a step that logs, its facts (``Trainer.read_step``:
+        ``device_wait``, then ``health``), the record accounting, and
+        the step's line with its own loss and facts."""
+        phase = self._timing.phase
+        logs = bool(
+            self._log_loss_steps and number % self._log_loss_steps == 0
+        )
+        if logs or pending.health is not None:
+            # reference --log_loss_steps. Where a step has no health
+            # scalars (SpmdTrainer) it is read only if it is logged
+            loss_value, facts = self.trainer.read_step(
+                pending, with_facts=logs
+            )
         real = batch_real_count(batch)
         if self._telemetry_on:
             self._update_step_telemetry(real)
@@ -746,47 +824,32 @@ class Worker:
         )
         if step_secs:
             self._m_examples_per_sec.set(real / step_secs)
-        self._m_version.set(self._version)
         with phase("report"):
             self.tds.report_record_done(real)
             if (
                 self._report_version_steps
-                and self._version % self._report_version_steps == 0
+                and number % self._report_version_steps == 0
             ):
-                self._mc.report_version(self._version)
-        with phase("mesh_check"):
-            self._check_mesh_epoch()
-        if (
-            self._log_loss_steps
-            and self._version % self._log_loss_steps == 0
-        ):
-            # reference --log_loss_steps. Where the trainer fetched
-            # nothing (SpmdTrainer) this is the loop's first read of a
-            # device value and waits for the step; after JaxTrainer's
-            # health fetch the value is already on the host
-            facts = self.trainer.facts or {}
-            with phase("device_wait"):
-                loss_value = float(loss)
-                # what the model handed out of the step comes with the
-                # loss, on the steps that log and on no other
-                fetched = [
-                    (fact, fact.journal(facts[fact.key]))
-                    for fact in step_fns.FACTS if facts.get(fact.key)
-                ]
-            with phase("log"):
-                logger.info(
-                    "step %d loss %.6f%s", self._version, loss_value,
-                    "".join(" %s %.6f" % item
-                            for fact, fields in fetched if fact.of_loss
-                            for item in sorted(fields.items())),
-                )
-                for fact, fields in fetched:
-                    if fact.of_loss:
-                        fields = dict(loss=loss_value, **fields)
-                    events.emit(fact.event, step=self._version, **fields)
-        with phase("callbacks"):
-            for cb in self._callbacks:
-                cb.on_batch_end(self._version, loss)
+                self._mc.report_version(number)
+        if not logs:
+            return
+        with phase("log"):
+            # what the model handed out of the step comes with the
+            # loss, on the steps that log and on no other
+            fetched = [
+                (fact, fact.journal(facts[fact.key]))
+                for fact in step_fns.FACTS if facts.get(fact.key)
+            ]
+            logger.info(
+                "step %d loss %.6f%s", number, loss_value,
+                "".join(" %s %.6f" % item
+                        for fact, fields in fetched if fact.of_loss
+                        for item in sorted(fields.items())),
+            )
+            for fact, fields in fetched:
+                if fact.of_loss:
+                    fields = dict(loss=loss_value, **fields)
+                events.emit(fact.event, step=number, **fields)
 
     def _train_batches_pipelined(self, batches):
         """Drive the sparse trainer's pipelined stream: batch N+1's PS
@@ -816,6 +879,8 @@ class Worker:
             for state, loss, batch in stream:
                 self.state = state
                 self._after_train_batch(batch, loss)
+                # the stream's steps are read in their own iteration
+                self._finish_in_flight()
                 self._timing.end_record(timing_utils.STEP_PHASE, start)
                 start = self._timing.start()
                 if self.stop_training:
@@ -836,20 +901,39 @@ class Worker:
         return loss
 
     def _train_batches_sequential(self, batches):
+        """Dispatch, then read the step before (``_after_train_batch``
+        has the order and what drains it)."""
         batches = iter(batches)
-        while True:
-            with self._timing.step(
-                self._version + 1, version=self._version
-            ) as step:
-                with self._timing.phase("input_wait"):
-                    batch = next(batches, None)
-                if batch is None:
-                    step.cancel()
+        # a prefetched stream says whether its next batch is there
+        # (``data/pipeline.py``); any other iterator is asked by next()
+        ready = getattr(batches, "ready", None)
+        try:
+            while True:
+                with self._timing.step(
+                    self._version + 1, version=self._version
+                ) as step:
+                    if ready is not None and not ready():
+                        self._finish_in_flight("input")
+                    with self._timing.phase("input_wait"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        step.cancel()
+                        break
+                    loss = self._train_step(step, batch)
+                    self._after_train_batch(batch, loss)
+                if self.stop_training:
                     break
-                loss = self._train_step(step, batch)
-                self._after_train_batch(batch, loss)
-            if self.stop_training:
-                break
+        except HealthSentinelError:
+            # a halt: the step dispatched after the one that tripped it
+            # started from that step's state, and goes unread
+            self._in_flight = None
+            raise
+        except BaseException:
+            self._finish_in_flight("error")
+            raise
+        self._finish_in_flight(
+            "eval" if self.tds.out_of_band_tasks else "end"
+        )
 
     def _train_batches_lockstep(self, batches):
         """Multi-host SPMD: every process must execute the same
@@ -994,6 +1078,9 @@ class Worker:
                     step.cancel()
                     continue
                 self._after_train_batch(batch, loss)
+                # every process reads a step in the round that ran it:
+                # the same sequence of collectives and fetches on all
+                self._finish_in_flight()
 
     def _read_template_batch(self):
         """One correctly-shaped batch read straight from the reader's

@@ -207,6 +207,84 @@ def test_a_loop_that_runs_ahead_is_judged_by_runs(journal, clock):
     assert event["phases"]["dispatch"] == 4_000_000
 
 
+def run_late(ledger, clock, first, count, device_ms, between=None):
+    """The worker loop's order on the fake clock: iteration N
+    dispatches step N (0.5 ms), then waits for step N - 1, which the
+    device finishes ``device_ms(N - 1)`` after it finished step N - 2
+    or was handed it. ``between(N)`` runs after iteration N closed."""
+    done = clock.now_ns  # the device is idle
+    for number in range(first, first + count):
+        with ledger.step(number) as step:
+            step.has_batch(task_id=7)
+            with ledger.phase("input_wait"):
+                clock.sleep(0.0001)
+            with ledger.phase("dispatch"):
+                clock.sleep(0.0005)
+            before = done
+            done = max(done, clock.now_ns) + int(device_ms(number) * 1e6)
+            if number > first:
+                ledger.read_ahead()
+                with ledger.phase("device_wait"):
+                    clock.now_ns = max(clock.now_ns, before)
+            with ledger.phase("report"):
+                clock.sleep(0.0003)
+        if between is not None:
+            between(number)
+
+
+def test_a_step_read_late_is_still_judged_alone(journal, clock):
+    """Every step read one step late: an iteration's wall time is one
+    device step, the one before its own, so the device's slow step 12
+    is the one slow step, seen by iteration 13, a run of one."""
+    ledger = Timing(interval=8)
+    run_late(ledger, clock, 1, 16, lambda n: 160 if n == 12 else 100)
+    (event,) = journal("slow_step")
+    assert event["step"] == 13 and event["steps"] == 1
+    assert max(event["phases"], key=event["phases"].get) == "device_wait"
+    assert event["median_ns"] == 100_000_000
+    assert event["wall_ns"] == 160_000_000
+    intervals = journal("loop_phases")
+    assert [e["slowest_step"] for e in intervals] == [2, 13]
+    # the loop's own turn lies under the device's step: the wall time
+    # of sixteen iterations is fifteen device steps and a host's turn
+    assert sum(e["wall_ns"] for e in intervals) == (
+        14 * 100_000_000 + 160_000_000 + 900_000)
+
+
+def test_the_interval_counts_the_steps_read_ahead_and_the_drains(
+        journal, clock):
+    """``ahead_steps``: the reads that began with a later step out;
+    ``drains``: those that had none behind them, by reason. A drain
+    after the interval's last step closed leaves with an event of no
+    steps when the stream's totals are reported."""
+    ledger = Timing(interval=4)
+
+    def between(number):
+        if number == 6:
+            # a checkpoint after step 6: read with nothing queued
+            ledger.drained("checkpoint")
+            with ledger.phase("device_wait"):
+                clock.sleep(0.1)
+
+    run_late(ledger, clock, 1, 6, lambda n: 100, between)
+    run_late(ledger, clock, 7, 2, lambda n: 100)
+    ledger.drained("end")
+    ledger.report("training stream")
+    intervals = journal("loop_phases")
+    assert [(e["first_step"], e["last_step"], e["steps"], e["ahead_steps"],
+             e["drains"]) for e in intervals] == [
+        (1, 4, 4, 3, {}),
+        # step 7 follows a drain: nothing of step 6 was left to read
+        (5, 8, 4, 3, {"checkpoint": 1}),
+        (8, 8, 0, 0, {"end": 1}),
+    ]
+    assert intervals[2]["wall_ns"] == 0
+    assert sum(intervals[2]["phases"].values()) == 0
+    # nothing is pending: a second report journals nothing
+    ledger.report("again")
+    assert len(journal("loop_phases")) == 3
+
+
 def test_ledger_events_pass_the_schema(journal, clock):
     ledger = Timing(interval=4)
     ledger.begin_startup(clock.perf_counter_ns() - 5_000_000)

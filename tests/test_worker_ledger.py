@@ -115,8 +115,10 @@ def test_loop_journals_its_phases_and_metrics_change_nothing(
 def test_profiler_session_holds_the_phases_on_the_ops_clock(tmp_path):
     """Five steps of a tiny model inside ``jax.profiler``: ``edl/step``
     with its ``step_num`` and the phases nested in it sit on one thread
-    line, and every XLA operation of step N starts after ``dispatch`` N
-    started. Nothing is set in the program to get this."""
+    line, every XLA operation of step N starts after ``dispatch`` N
+    started, and all of them are over when iteration N + 1's
+    ``device_wait``, the late read of step N, returns. Nothing is set in
+    the program to get this."""
     import flax.linen as nn
     import jax
     from jax.profiler import ProfileData
@@ -143,14 +145,21 @@ def test_profiler_session_holds_the_phases_on_the_ops_clock(tmp_path):
     ledger = timing_utils.Timing()
     previous = timing_utils.bind(ledger)
     try:
-        state, _ = trainer.train_step(None, batch)  # compiles
+        state, loss = trainer.train_step(None, batch)  # compiles
+        jax.block_until_ready(loss)
+        pending = trainer.pending_step(loss)
         jax.profiler.start_trace(str(tmp_path))
         for number in range(1, 6):
             with ledger.step(number) as step:
                 with ledger.phase("input_wait"):
                     time.sleep(0.001)
                 step.has_batch()
-                state, _ = trainer.train_step(state, batch)
+                state, loss = trainer.train_step(state, batch)
+                # the loop's order: the step before is read with this
+                # one already dispatched
+                late, pending = pending, trainer.pending_step(loss)
+                trainer.read_step(late)
+        jax.block_until_ready(loss)
         jax.profiler.stop_trace()
     finally:
         timing_utils.bind(previous)
@@ -189,11 +198,24 @@ def test_profiler_session_holds_the_phases_on_the_ops_clock(tmp_path):
         for e in evs
         if not e[0].startswith(("end: ", "ThreadpoolListener::"))
     ]
-    for step in steps:
-        (dispatch,) = [
-            p for p in phases if p[0] == "edl/dispatch"
-            and step[1] <= p[1] <= step[2]
-        ]
-        mine = [op for op in ops if step[1] <= op[1] < step[2]]
-        assert mine, "no XLA operation inside step %s" % step[3]
-        assert all(op[1] >= dispatch[1] for op in mine)
+    # five runs of one program, one after the other (each takes the
+    # state of the one before): a fifth of the operations each
+    ops.sort(key=lambda op: op[1])
+    per_step, rest = divmod(len(ops), len(steps))
+    assert per_step and not rest, [op[0] for op in ops]
+
+    def phase_of(step, name):
+        (found,) = [
+            p for p in phases
+            if p[0] == name and step[1] <= p[1] <= step[2]]
+        return found
+
+    for i, step in enumerate(steps):
+        mine = ops[i * per_step:(i + 1) * per_step]
+        assert all(
+            op[1] >= phase_of(step, "edl/dispatch")[1] for op in mine)
+        if i + 1 < len(steps):
+            # read a step late: step N's operations are over when the
+            # ``device_wait`` of iteration N + 1 returns
+            wait = phase_of(steps[i + 1], "edl/device_wait")
+            assert all(op[2] <= wait[2] for op in mine)
