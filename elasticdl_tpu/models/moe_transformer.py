@@ -37,6 +37,7 @@ from elasticdl_tpu.models.transformer import (
     GatedDeltaDims,
     HyperDims,
     IndexerDims,
+    KdaDims,
     LatentDims,
     LoopedDims,
     MixerKind,
@@ -672,6 +673,16 @@ class MoeTransformerLM(nn.Module):
     8; ``mixer_kinds()`` says what such a model is made of (the
     journal's event of that name).
 
+    Kimi-Linear-48B-A3B's is ``layer_kinds=("kda", "kda", "kda",
+    "full")`` with ``kda`` (a Kimi Delta Attention mixer's sizes,
+    ``KdaDims``: a delta rule whose decay is a vector a head) and
+    ``latent`` with ``rotary=False`` for the full layers (latent
+    attention that rotates nothing: the first stack in which a latent
+    and a recurrent mixer alternate), ``first_k_dense=1`` (a ``kda``
+    layer) and Moonlight's expert layer over 256 experts of which a
+    chip holds 8; a training call also returns ``kda`` (the
+    ``kda_gates`` event's facts, one entry a KDA layer).
+
     Ouro-2.6B's is every layer dense (``first_k_dense=num_layers``,
     ``dense_act="swiglu"``), ``sandwich`` (a norm on each sublayer's
     output too) and ``looped`` (a ``LoopedDims``): the blocks run
@@ -745,8 +756,10 @@ class MoeTransformerLM(nn.Module):
     exchange_rows: Optional[int] = None
     # the mixers' kinds as a pattern with a period: layer i is
     # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
-    # of ``linear``'s sizes), "full" (softmax attention over the causal
-    # prefix) or "window" (softmax attention over a band,
+    # of ``linear``'s sizes), "kda" (a Kimi Delta Attention of
+    # ``kda``'s), "conv" (a gated short convolution of ``conv``'s),
+    # "full" (softmax or, with ``latent``, latent attention over the
+    # causal prefix) or "window" (softmax attention over a band,
     # ``ops/flash_attention.py:Band``). None: every layer "full". The
     # five fields after ``linear`` are ``Attention``'s own of those
     # names, for every softmax layer. What a KIND of softmax layer has
@@ -759,6 +772,7 @@ class MoeTransformerLM(nn.Module):
     kind_fields: Optional[Any] = None
     linear: Optional[GatedDeltaDims] = None
     conv: Optional[ShortConvDims] = None
+    kda: Optional[KdaDims] = None
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
     head_norm: Optional[str] = None
@@ -808,7 +822,8 @@ class MoeTransformerLM(nn.Module):
         """A layer's mixer by its KIND, stated once: what a block hands
         to ``make_attention`` unopened. The kind decides the mixer (a
         Gated DeltaNet of ``linear``'s sizes, a short convolution of
-        ``conv``'s, else softmax or latent attention) and, of a softmax
+        ``conv``'s, a Kimi Delta Attention of ``kda``'s, else softmax
+        or latent attention) and, of a softmax
         one, the query heads, the rotary base, the lanes that rotate,
         YaRN, the mask's layout and the scope its operations lie under;
         the rest is the model's, for every kind alike. A kind without
@@ -821,6 +836,8 @@ class MoeTransformerLM(nn.Module):
             own = MixerKind(self.num_heads, self.rope_theta,
                             self.rotary_dim, self.rope_scaling)
         return dict(
+            # (a model without the kind hands on what it always did)
+            **({"kda": self.kda} if kind == "kda" else {}),
             num_heads=own.num_heads,
             latent=self.latent,
             linear=self.linear if kind == "linear" else None,
@@ -843,16 +860,19 @@ class MoeTransformerLM(nn.Module):
     def _check_kinds(self, kinds, denoise):
         """Refuses, by name, a pattern the blocks cannot run."""
         by_kind = dict(self.kind_fields or {})
-        if set(kinds) - {"full", "linear", "window", "conv"} or (
-                "linear" in kinds and self.linear is None) or (
-                "conv" in kinds and self.conv is None):
+        sized = {"linear": self.linear, "conv": self.conv, "kda": self.kda}
+        if set(kinds) - {"full", "window", *sized} or any(
+                sized[kind] is None for kind in set(kinds) & set(sized)):
             raise ValueError(
-                "layer_kinds=%r: each is 'full', 'linear' or 'window', or "
-                "'conv' (a gated short convolution), and 'linear' and "
-                "'conv' need their mixer's sizes (linear, conv)"
+                "layer_kinds=%r: each is 'full', 'window', 'linear' (a "
+                "Gated DeltaNet), 'conv' (a gated short convolution) or "
+                "'kda' (a Kimi Delta Attention), and 'linear', 'conv' and "
+                "'kda' need their mixer's sizes (linear, conv, kda)"
                 % (self.layer_kinds,))
         if "conv" in kinds:
             self._check_conv(denoise, by_kind)
+        if "kda" in kinds:
+            self._check_kda(kinds, denoise, by_kind)
         if self.indexer is not None:
             self._check_indexer(kinds, denoise, by_kind)
         if set(by_kind) - {"full", "window"}:
@@ -883,18 +903,32 @@ class MoeTransformerLM(nn.Module):
                 for kind in sorted(set(kinds))))
 
     def mixer_kinds(self, seq=None, dtype=None):
-        """What a model with gated short convolutions is made of, for
-        the journal's ``mixer_kinds`` event (the worker emits it once,
-        when the state is made: ``worker/trainer.py:ensure_state``);
-        None for a model without the kind. Read from the fields alone;
+        """What a model with gated short convolutions or Kimi Delta
+        Attention layers is made of, for the journal's ``mixer_kinds``
+        event (the worker emits it once, when the state is made:
+        ``worker/trainer.py:ensure_state``); None for a model without
+        either kind. Read from the fields alone; for the convolutions,
         given a batch's length ``seq`` and the step's compute ``dtype``
-        (None: float32), also what runs the convolutions there
+        (None: float32), also what runs them there
         (``ops/short_conv.py:conv_choice``: ``conv_impl``,
         ``conv_tile``)."""
-        if self.conv is None:
-            return None
         kinds = tuple(self.layer_kinds or ("full",))
         built = [kinds[i % len(kinds)] for i in range(self.num_layers)]
+        if self.kda is not None:
+            return {
+                "kda_layers": built.count("kda"),
+                "full_layers": built.count("full"),
+                "dense_layers": self.first_k_dense,
+                "kda_heads": self.kda.num_heads,
+                "kda_head_dim": self.kda.head_dim,
+                "kda_taps": self.kda.conv_kernel_dim,
+                "kda_gate_rank": self.kda.gate_rank,
+                "kda_chunk": self.kda.chunk,
+                "latent": self.latent is not None,
+                "latent_rotary": bool(self.latent and self.latent.rotary),
+            }
+        if self.conv is None:
+            return None
         runs = {}
         if seq is not None:
             runs["conv_impl"], runs["conv_tile"] = short_conv.conv_choice(
@@ -919,7 +953,8 @@ class MoeTransformerLM(nn.Module):
                 ("objective=\"block_diffusion\" (its mask is a layout of "
                  "two copies; the selection is over one causal prefix)",
                  denoise),
-                ("a 'window', 'linear' or 'conv' layer (layer_kinds=%r: the "
+                ("a 'window', 'linear', 'conv' or 'kda' layer "
+                 "(layer_kinds=%r: the "
                  "indexer picks keys for full softmax attention)"
                  % (self.layer_kinds,), set(kinds) != {"full"}),
                 ("kind_fields (a band, heads or a rotary table by layer "
@@ -963,8 +998,9 @@ class MoeTransformerLM(nn.Module):
                 ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
                 ("objective=\"block_diffusion\"", denoise),
                 ("a learned indexer (indexer)", self.indexer is not None),
-                ("a 'linear' or 'conv' mixer (layer_kinds=%r)"
-                 % (self.layer_kinds,), bool({"linear", "conv"} & set(kinds))),
+                ("a 'linear', 'conv' or 'kda' mixer (layer_kinds=%r)"
+                 % (self.layer_kinds,),
+                 bool({"linear", "conv", "kda"} & set(kinds))),
                 ("a head tied to the embedding (tie_embeddings)",
                  self.tie_embeddings)):
             if asked:
@@ -1044,6 +1080,7 @@ class MoeTransformerLM(nn.Module):
                  "kind)", bool(by_kind)),
                 ("latent attention (latent)", self.latent is not None),
                 ("a Gated DeltaNet mixer (linear)", self.linear is not None),
+                ("a Kimi Delta Attention mixer (kda)", self.kda is not None),
                 ("hyper-connections (hc: the mixer reads one stream)",
                  self.hc is not None),
                 ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
@@ -1057,6 +1094,35 @@ class MoeTransformerLM(nn.Module):
                 raise ValueError(
                     "a 'conv' layer (a gated short convolution) beside "
                     "%s: not built, so not run" % what)
+
+    def _check_kda(self, kinds, denoise, by_kind):
+        """A Kimi Delta Attention layer runs beside causal softmax or
+        latent layers of one kind, in dense and in expert blocks, under
+        next-token prediction, its sequence whole on a device. What it
+        was not built beside is refused, each by its name."""
+        ranks = {} if self.mesh is None else dict(self.mesh.shape)
+        for what, asked in (
+                ("objective=\"block_diffusion\" (its two copies of a "
+                 "sequence are one axis, and a recurrence would run "
+                 "across their seam)", denoise),
+                ("kind_fields (a band, heads or a rotary table by layer "
+                 "kind)", bool(by_kind)),
+                ("a 'linear', 'conv' or 'window' layer (layer_kinds=%r)"
+                 % (self.layer_kinds,),
+                 bool(set(kinds) - {"kda", "full"})),
+                ("hyper-connections (hc: the mixer reads one stream)",
+                 self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("a learned indexer (indexer)", self.indexer is not None),
+                ("a looped stack (looped)", self.looped is not None),
+                ("attention_impl='ring' / 'ulysses' (the sequence over "
+                 "sp: a shard's state is the shard before's)",
+                 self.attention_impl in ("ring", "ulysses")
+                 or ranks.get("sp", 1) > 1)):
+            if asked:
+                raise ValueError(
+                    "a 'kda' layer (Kimi Delta Attention) beside %s: not "
+                    "built, so not run" % what)
 
     def _block_diffusion_inputs(self, tokens, training, noisy, weights):
         """``(inputs (B, 2 L), positions, mask layout, weights, the
@@ -1150,7 +1216,7 @@ class MoeTransformerLM(nn.Module):
         )
         self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
-        routing, mhc, dsa = [], [], []
+        routing, mhc, dsa, kda = [], [], [], []
 
         experts = {name: getattr(self, name) for name in EXPERT_FIELDS}
 
@@ -1176,6 +1242,8 @@ class MoeTransformerLM(nn.Module):
                 mhc.append(aux["mhc"])
             if "dsa" in aux:
                 dsa.append(aux["dsa"])
+            if "kda" in aux:
+                kda.append(aux["kda"])
 
         if self.looped is not None:
             return self._looped(x, lambda: [
@@ -1188,9 +1256,9 @@ class MoeTransformerLM(nn.Module):
             if dense and kind == "linear":
                 raise ValueError(
                     "a dense block's mixer is softmax attention, "
-                    "latent attention or a gated short convolution; "
-                    "layer %d asks for a Gated DeltaNet, which only "
-                    "an expert block takes" % i)
+                    "latent attention, a gated short convolution or a "
+                    "Kimi Delta Attention; layer %d asks for a Gated "
+                    "DeltaNet, which only an expert block takes" % i)
             x, aux = block("block_%d" % i, i, kind, dense)(
                 x, training, positions)
             count(aux)
@@ -1261,6 +1329,11 @@ class MoeTransformerLM(nn.Module):
                 "tiles_run": jnp.float32(tiles["forward"][0]),
                 "tiles_causal": jnp.float32(tiles["forward"][0]),
             }
+        if kda:
+            # one fact a Kimi Delta Attention layer
+            outputs["kda"] = {
+                name: jnp.stack([facts[name] for facts in kda])
+                for name in kda[0]}
         if denoise:
             outputs["weights"] = weights
             if facts is not None:
@@ -1313,6 +1386,14 @@ def moe_sharding_rules():
             # are tiny
             (r"in_proj_(qkvz|ba)/kernel$", P("fsdp", "tp")),
             (r"conv_kernel$", P(None, "tp")),
+            # Kimi Delta Attention: q | k | v as Gated DeltaNet's; the
+            # low-rank gates' down-projections make one latent all heads
+            # read (as kv_down does), their up-projections split their
+            # heads' columns over tp; beta's is a column a head
+            (r"in_proj_qkv/kernel$", P("fsdp", "tp")),
+            (r"(f|g)_down/kernel$", P("fsdp", None)),
+            (r"(f|g)_up/kernel$", P(None, "tp")),
+            (r"b_proj/kernel$", P("fsdp", None)),
             # a gated short convolution's two projections: stored over
             # fsdp; B | C | X lie side by side, so nothing splits over tp
             (r"attn/in_proj/kernel$", P("fsdp", None)),

@@ -398,13 +398,17 @@ class LatentDims:
     ``q_lora_rank`` None (``null`` in the file) is the form without a q
     latent, Moonlight-16B-A3B's: one ``q_proj`` from the model's width.
     A rank makes the query ``RMSNorm(x W_qa) W_qb`` (DeepSeek-V3's own,
-    Xing4.0's at 768)."""
+    Xing4.0's at 768). ``rotary`` False (Kimi Linear's ``mla_use_nope``
+    true) leaves the ``qk_rope_head_dim`` lanes of q and of the shared
+    key head where they are and rotates nothing: the layer then carries
+    no position (the model's recurrent layers do)."""
 
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
     q_lora_rank: Optional[int] = None
+    rotary: bool = True
 
 
 class LatentAttention(nn.Module):
@@ -418,8 +422,9 @@ class LatentAttention(nn.Module):
         c            = x W_kva               -> kv_lora_rank | rope
         c_kv, k_rope = RMSNorm(c[:rank]), c[rank:]
         k_nope | v   = c_kv W_kvb            -> H heads x (nope | v)
-        q_rope, k_rope rotated over their ``rope`` lanes; k_rope is ONE
-        head, shared by all H
+        q_rope, k_rope rotated over their ``rope`` lanes (left as they
+        are where ``dims.rotary`` is False); k_rope is ONE head, shared
+        by all H
         q = [q_nope | q_rope], k = [k_nope | k_rope]   (nope + rope wide)
         o = causal softmax(q k^T / sqrt(nope + rope)) v -> H x v -> W_o
 
@@ -453,12 +458,16 @@ class LatentAttention(nn.Module):
                 "latent attention runs on one device's sequence; "
                 "attention_impl=%r shards it" % (self.attention_impl,))
         heads, dims = self.num_heads, self.dims
+        if self.rope_scaling is not None and not dims.rotary:
+            raise ValueError(
+                "latent attention that rotates nothing (rotary=False) "
+                "has no rope_scaling")
         rank, nope = dims.kv_lora_rank, dims.qk_nope_head_dim
         rope = dims.qk_rope_head_dim
         spec = P(DATA_AXES, "tp", None, None)
         rotate = functools.partial(
             rotary_embedding, base=self.rope_theta,
-            scaling=self.rope_scaling)
+            scaling=self.rope_scaling) if dims.rotary else (lambda t: t)
         sm_scale = None
         if self.rope_scaling is not None:
             sm_scale = (nope + rope) ** -0.5 * yarn_mscale(
@@ -625,6 +634,151 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn/out_proj"):
             return nn.DenseGeneral(
                 dim, axis=(-2, -1), use_bias=False, name="out_proj")(o)
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaDims:
+    """The sizes of a Kimi Delta Attention mixer as Kimi Linear's
+    ``config.json`` names them (``linear_attn_config``: ``num_heads``,
+    ``head_dim``, ``short_conv_kernel_size``), the rank of its two
+    low-rank gates (the published module's: ``head_dim``) and the chunk
+    and the segment of the chunked rule (``ops/gated_delta.py``)."""
+
+    num_heads: int
+    head_dim: int
+    conv_kernel_dim: int
+    gate_rank: int
+    chunk: int = gated_delta.DEFAULT_CHUNK
+    segment: int = gated_delta.DEFAULT_SEGMENT
+
+
+def _kda_a_log_init(key, shape, dtype=jnp.float32):
+    """log of a uniform draw in (1, 16): the published module's."""
+    return jnp.log(jax.random.uniform(
+        key, shape, dtype, minval=1.0, maxval=16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of ``dt`` drawn log-uniformly in (0.001,
+    0.1), floored at 1e-4 (Mamba2's draw, flash-linear-attention's for
+    its delta-rule layers): ``softplus(dt_bias) = dt``."""
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        key, shape, dtype, minval=np.log(1e-3), maxval=np.log(0.1))), 1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692; the
+    published ``KimiDeltaAttention``), H heads of D lanes, key and value
+    alike, for one token x:
+
+        q | k | v = x W_qkv            (H x D each, no bias)
+        [q | k | v] = silu(causal depthwise conv over ``conv_kernel_dim``
+                      tokens, no bias)
+        q, k l2-normalised over their lanes (eps 1e-6), q scaled D^-1/2
+        g = -exp(A_log_h) softplus(W_fb (W_fa x) + dt_bias)   (float32)
+            the LOG decay of each of a head's D key channels, a vector
+            where Gated DeltaNet's is a number; ``W_fa`` d x rank,
+            ``W_fb`` rank x H D, ``A_log`` (H,), ``dt_bias`` (H D,)
+        beta = sigmoid(x W_b)                                 (float32, H)
+        S <- Diag(exp(g_t)) S;  u = beta_t (v_t - S^T k_t);
+        S <- S + k_t u^T;  o_t = S^T q_t       (``ops/gated_delta.py``,
+                                                 the decay's rank decides)
+        o = RMSNorm_D(o) w sigmoid(W_gb (W_ga x))   (per head, float32)
+        y = o W_o
+
+    Scopes: ``kda/in_proj``, ``kda/conv`` (convolution, SiLU, l2 norms
+    and the heads' split: ``ops/qkv_conv.py``, the kernel pair where
+    ``conv_impl`` says so, its lines elsewhere), ``kda/gates`` (both
+    low-rank gates, beta, their transposes and the layer's facts),
+    ``kda/scan`` (the chunked rule, whole), ``kda/out_norm``,
+    ``kda/out_proj``. The three projections are one matmul whose
+    columns lie q | k | v (the published module has three ``Linear``:
+    with seeded weights the same function). Returns ``(y, facts)``:
+    ``decay_mean`` / ``decay_min`` of ``exp(g)`` over tokens, heads and
+    channels, ``underflow_share`` of the (chunk, head, channel) triples
+    whose decay cumulated over the chunk is under ``e^-88`` (where
+    ``exp(-G)`` leaves float32) and ``beta_mean``."""
+
+    dims: KdaDims
+    norm_eps: float = 1e-6
+    mesh: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        dims = self.dims
+        heads, dim, taps = dims.num_heads, dims.head_dim, dims.conv_kernel_dim
+        batch, seq, width = x.shape
+        inner = heads * dim
+        dense = lambda features, name: nn.Dense(
+            features, use_bias=False, name=name)
+        with jax.named_scope("kda/in_proj"):
+            qkv = dense(3 * inner, "in_proj_qkv")(x)
+        impl = qkv_conv.conv_impl(x.dtype, dim, dim, seq, taps, mesh=self.mesh)
+        segments = qkv_conv.rule_segments(seq, dims.chunk, dims.segment)
+        qkv_conv.log_choice(
+            heads, heads, dim, taps, impl, seq,
+            qkv_conv.row_tile(seq // segments) if impl == "pallas" else None)
+        with jax.named_scope("kda/conv"):
+            kernel = self.param(
+                "conv_kernel",
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                (taps, 3 * inner),
+            ).astype(x.dtype)
+            if impl == "pallas":
+                q, k, v = qkv_conv.qkv_conv(
+                    qkv, kernel, (heads, heads, dim), segments, "kda/conv")
+            else:
+                q, k, v = qkv_conv.qkv_conv_xla(
+                    qkv, kernel, (heads, heads, dim))
+        with jax.named_scope("kda/gates"):
+            a_log = self.param("A_log", _kda_a_log_init, (heads,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
+            f = dense(inner, "f_up")(dense(dims.gate_rank, "f_down")(x))
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                (f.astype(jnp.float32) + dt_bias).reshape(
+                    batch, seq, heads, dim))
+            beta = jax.nn.sigmoid(
+                dense(heads, "b_proj")(x).astype(jnp.float32))
+            z = dense(inner, "g_up")(dense(dims.gate_rank, "g_down")(x))
+            g, beta = g.transpose(0, 2, 1, 3), beta.transpose(0, 2, 1)
+            facts = jax.lax.stop_gradient(
+                kda_gate_facts(g, beta, dims.chunk))
+        with jax.named_scope("kda/scan"):
+            o = gated_delta.gated_delta_rule(
+                q, k, v, g, beta, chunk=dims.chunk, segment=dims.segment,
+                mesh=self.mesh)
+        with jax.named_scope("kda/out_norm"):
+            o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
+                o.transpose(0, 2, 1, 3))  # (B, S, H, D)
+            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                z.reshape(o.shape).astype(jnp.float32))).astype(x.dtype)
+        with jax.named_scope("kda/out_proj"):
+            return nn.DenseGeneral(
+                width, axis=(-2, -1), use_bias=False, name="out_proj")(
+                    o), facts
+
+
+# below this a chunk's cumulated decay leaves float32 as ``exp(-G)``:
+# the regime in which a factorised ``(K e^G)(K e^-G)^T`` dies
+UNDERFLOW_LOG = -88.0
+
+
+def kda_gate_facts(g, beta, chunk):
+    """The ``kda_gates`` event's facts of one layer from its log decay
+    ``g`` (B, H, S, D) and ``beta`` (B, H, S), float32."""
+    batch, heads, seq, dim = g.shape
+    whole = jnp.pad(g, ((0, 0), (0, 0), (0, -seq % chunk), (0, 0)))
+    over_chunks = whole.reshape(batch, heads, -1, chunk, dim).sum(axis=3)
+    decay = jnp.exp(g)
+    return {
+        "decay_mean": decay.mean(),
+        "decay_min": decay.min(),
+        "underflow_share": jnp.mean(
+            (over_chunks < UNDERFLOW_LOG).astype(jnp.float32)),
+        "beta_mean": beta.mean(),
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -878,22 +1032,30 @@ SOFTMAX_ONLY = (
 
 
 def make_attention(num_heads, latent=None, linear=None, conv=None,
-                   **fields):
+                   kda=None, **fields):
     """The block's mixer, ``name="attn"``, by the layer's kind:
     ``ShortConv`` where the layer is a gated short convolution
     (``conv``: its ``ShortConvDims``), ``GatedDeltaNet`` where it is a
     linear-attention one (``linear``: its ``GatedDeltaDims``),
-    ``LatentAttention`` where the model names latent widths
-    (``LatentDims``), else ``Attention``.
+    ``KimiDeltaAttention`` where it is a Kimi Delta Attention one
+    (``kda``: its ``KdaDims``), ``LatentAttention`` where the model
+    names latent widths (``LatentDims``), else ``Attention``.
     ``fields``: ``norm_eps``, which all take; what the two softmax ones
     take (``rope_theta``, ``rope_scaling``: YaRN, each by its own
     convention); and what only ``Attention`` has (``SOFTMAX_ONLY``: the
     grouped-query fields, the mask's layout, the kind's scope). A
-    Gated DeltaNet and a short convolution rotate nothing and mask
-    nothing, and say so."""
-    if conv is not None or linear is not None:
-        what = ("a gated short convolution" if conv is not None
-                else "a Gated DeltaNet mixer")
+    Gated DeltaNet, a Kimi Delta Attention and a short convolution
+    rotate nothing and mask nothing, and say so."""
+    recurrent = [
+        what for what, dims in (
+            ("a gated short convolution", conv),
+            ("a Gated DeltaNet mixer", linear),
+            ("a Kimi Delta Attention mixer", kda)) if dims is not None]
+    if len(recurrent) > 1:
+        raise ValueError(
+            "one layer is %s: make_attention takes one of conv, linear "
+            "and kda" % " and ".join(recurrent))
+    for what in recurrent:
         for name in ("mask", "rope_scaling", "indexer"):
             if fields.get(name) is not None:
                 raise ValueError("%s has no %s" % (what, name))
@@ -902,6 +1064,10 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
     if linear is not None:
         return GatedDeltaNet(
             linear, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
+            name="attn")
+    if kda is not None:
+        return KimiDeltaAttention(
+            kda, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
             name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
@@ -938,8 +1104,9 @@ class Block(nn.Module):
     Returns ``(x, aux)``. ``aux`` is empty for a dense block on the
     plain path and holds ``mhc`` (the block's facts) under
     hyper-connections, ``dsa`` where the mixer's indexer hands out its
-    facts, and the experts' own keys (``MoeMlp``) where there are
-    experts."""
+    facts, ``kda`` where the mixer is a Kimi Delta Attention (its
+    gates' facts), and the experts' own keys (``MoeMlp``) where there
+    are experts."""
 
     mixer: Any
     experts: Optional[Any] = None
@@ -991,11 +1158,13 @@ class Block(nn.Module):
 
         def mix(h):
             # only ``Attention`` takes the rows' positions, and with an
-            # indexer it hands its facts out beside its output
+            # indexer it hands its facts out beside its output, as a
+            # Kimi Delta Attention always does
             out = mixer(h, training, *(
                 () if positions is None else (positions,)))
             if isinstance(out, tuple):
-                out, aux["dsa"] = out
+                key = "kda" if isinstance(mixer, KimiDeltaAttention) else "dsa"
+                out, aux[key] = out
             return out
 
         norm = lambda name: make_norm(self.norm, self.norm_eps, name)

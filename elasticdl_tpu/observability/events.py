@@ -331,9 +331,15 @@ EVENT_TYPES = frozenset({
     "mixer_kinds",           # once a worker, when the state is made
                              #   (worker/trainer.py:ensure_state), of
                              #   a model with gated short convolutions
+                             #   or Kimi Delta Attention layers
                              #   (models/moe_transformer.py:
                              #   mixer_kinds): what it is made of
-                             #   (+ conv_layers, full_layers,
+                             #   (a KDA model: + kda_layers,
+                             #   full_layers, dense_layers, kda_heads,
+                             #   kda_head_dim, kda_taps,
+                             #   kda_gate_rank, kda_chunk, latent,
+                             #   latent_rotary; a conv model:
+                             #   + conv_layers, full_layers,
                              #   dense_layers, conv_taps,
                              #   conv_channels, head_dim, kv_heads)
                              #   and what runs the convolutions at the
@@ -363,6 +369,16 @@ EVENT_TYPES = frozenset({
                              #   H(p), nats; the cross-entropy an exit
                              #   is loss_terms' ce_exit_<t> of the
                              #   same step
+    "kda_gates",             # the same steps of a model with Kimi Delta
+                             #   Attention layers (models/
+                             #   transformer.py:KimiDeltaAttention,
+                             #   kda_gate_facts): a list a fact, one
+                             #   entry a KDA layer (+ step, decay_mean
+                             #   and decay_min of exp(g) over tokens,
+                             #   heads and channels; underflow_share:
+                             #   the (chunk, head, channel) triples
+                             #   whose decay cumulated over the chunk
+                             #   is under e^-88; beta_mean)
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction

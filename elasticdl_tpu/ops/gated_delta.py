@@ -1,5 +1,16 @@
 """The gated delta rule in chunked form (Gated DeltaNet, arXiv:2412.06464;
-the linear-attention layers of Qwen3-Next).
+the linear-attention layers of Qwen3-Next) and, from the same lines,
+the delta rule whose decay is a vector a head (Kimi Delta Attention,
+arXiv:2510.26692; Kimi Linear's). Which of the two runs is decided by
+the rank of ``g`` and by nothing else: ``(B, H, S)``, one log decay a
+head and token, or ``(B, H, S, Dk)``, one a channel of the key (the
+last section below). ``gdn_prepare_fwd`` / ``gdn_prepare_bwd`` compute
+the SCALAR rule's operands, so a decay a channel makes its own by
+``_chunk_operands_by_channel`` on every backend (``prepare_impl``:
+``decay_rank``); the chunk-to-chunk recurrence is ``gdn_scan_fwd`` /
+``gdn_scan_bwd`` for both ranks wherever ``scan_impl`` says so, a decay
+a channel with the state transposed (``by_channel``: the kernels are
+then named ``kda_scan_fwd`` / ``kda_scan_bwd``).
 
 Per value head, with a state ``S`` (key width x value width, zero at the
 sequence's start), for each token ``t``::
@@ -187,6 +198,56 @@ The inverse has a VJP of its own from ``T`` alone. The output carries
 ``checkpoint_name`` ``GDN_OUT_NAME`` so that a remat policy can name it
 as it names flash's (today's policies do not: the backward rebuilds the
 segments' residuals whether or not ``o`` was kept).
+
+A decay a channel (PR 58). With ``g_t`` a vector over the key's
+channels the state's ROWS decay, each by its own: ``S = Diag(exp(g_t))
+S``, the rest of the token's step as above. In a chunk, with ``G_i``
+(a vector) the log decay cumulated through token ``i``, the lines
+above hold with the decay moved INSIDE the contraction over the
+channels::
+
+    A[i, j] = beta_i sum_c k_i[c] k_j[c] exp(G_i[c] - G_j[c])    (i > j)
+    P[i, j] =        sum_c q_i[c] k_j[c] exp(G_i[c] - G_j[c])    (i >= j)
+    W = T (beta K . exp(G))      Q~ = Q . exp(G)
+    S <- Diag(exp(G_last)) S + (K . exp(G_last - G))^T V'
+
+``A`` and ``P`` are no longer a product times a decay MATRIX. Written
+``(K e^G)(K e^-G)^T`` they would be matmuls again, and ``e^-G``
+overflows as soon as a channel cumulates past -88 inside a chunk. So a
+chunk is cut into sub-blocks of ``_SUB`` = 8 rows
+(``_decayed_products``): between sub-blocks ``I > J``, ``exp(G_i -
+G_j) = exp(G_i - G_r) exp(G_r - G_j)`` with ``r`` the first row of
+``I``, both exponents ``<= 0``, so those blocks are matmuls of decayed
+operands (the keys decayed once a sub-block of rows); on the diagonal
+the 8 x 8 pairs are summed over the channels directly
+(``_decayed_diagonal``, under a VJP of its own that keeps none of the
+(sub, sub, Dk) decays and runs ``_CUBE_BYTES`` of them at a time). No
+exponent of the rule is ever positive, for either rank. ``T``, the
+segments, the carried state and the ``(B, Hk, R, N, C, .)`` layouts are
+the scalar rule's; the state's update scales rows by a vector where the
+scalar rule multiplies by a number (``_scan_xla``: ``exp(G_last)`` is
+``(.., 1)`` or ``(.., Dk)``). Checked against the per-token loop in
+float64 to 1e-12, at decays of -50 a token on some channels and 0 on
+others too (``tests/test_kda_rule.py``).
+
+The scan's kernels carry that state TRANSPOSED, ``S^T`` (Dv, Dk) in
+the VMEM scratch and in the residuals (``_scan_pallas_by_channel``
+turns it on its way in and out of a segment): a channel's
+``exp(G_last)`` then lies on its own lane of the chunk's (1, Dk) row,
+where the scalar rule's one number lies on every lane of a (1, Dv)
+row, and ``e * S^T`` is the same broadcast over the sublanes. The four
+products are the scalar kernel's with the state's side of each turned
+(``[W; Q~] S`` contracts the lanes of both operands, ``S^T <- e S^T +
+V'^T K~``), at the same precision; ``d exp(G_last)`` is the sum over
+the rows of ``dS^T . S^T``, whole a channel. A column of decays
+against the untransposed state would be a (Dk, 1) block, one number a
+128-lane row in VMEM and in HBM. As a ``lax.scan`` the recurrence was
+6% of Kimi Linear's step and 82,000 of the 116,000 operations it
+executed, each an event of the profiler: ``stop_trace`` held the loop
+10 s for two traced steps (PERF.md Section 6, PR 58). The operands
+(``_decayed_products``, the inverses, ``U`` and ``W``) stay XLA's: on
+the chip the sums on the diagonals are a third of the rule's time
+(PERF.md Section 5): a kernel for them is the next step.
 """
 
 import functools
@@ -212,6 +273,18 @@ DEFAULT_SEGMENT = 128
 # or one of 128
 _KERNEL_CHUNKS = (64, 128)
 _LANES = 128
+# ``g``'s rank: (B, Hv, S) one decay a head and token, (B, Hv, S, Dk)
+# one a channel of the key (Kimi Delta Attention)
+SCALAR_DECAY, VECTOR_DECAY = 3, 4
+# rows of a sub-block of a chunk under a decay a channel
+# (``_decayed_products``: one float32 tile of 8 sublanes; the direct sums
+# on the diagonals move (C, sub, Dk) decays a chunk through HBM and the
+# matmuls between sub-blocks (C / sub, C, Dk) decayed keys: at 8 the
+# cell's step takes 2.59 s where it took 2.96 at the published kernels'
+# 16, PERF.md Section 6, PR 58), and the bytes the (sub, sub, Dk) decays of the
+# sub-blocks on the diagonals may take at a time (``_by_chunk_groups``)
+_SUB = 8
+_CUBE_BYTES = 2**28
 # independent lane rows (pairs of 64 x 64 matrices) a loop iteration
 # interleaves
 _CHAINS = 8
@@ -269,7 +342,8 @@ def scan_impl(dtype, chunk, dk, dv, state_dtype=jnp.float32,
     128, and write ``o`` in ``dtype`` (``out_dtype``, ``v``'s, has to be
     it: the rounding is then the cast the rule ends with). Everything
     else, the tests' ``state_dtype`` / ``decay_dtype`` experiments among
-    it, is the ``lax.scan``."""
+    it, is the ``lax.scan``. The decay's rank does not bear on it: the
+    kernels carry a decay a channel too (``by_channel``)."""
     fits = (
         jax_compat.kernels_can_run(mesh)
         and dtype in (jnp.bfloat16, jnp.float32)
@@ -473,11 +547,15 @@ def _segment_ends():
 
 
 def _scan_fwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, u_ref, s0_ref,
-                     o_ref, *rest, residuals):
+                     o_ref, *rest, residuals, by_channel):
     """A block of heads over a block of chunks: V' = U - W S, O = Q~ S +
     P V', S <- exp(G_last) S + K~^T V'. ``[W; Q~] S`` is one product
     (the two share their right operand, as the inverse's stacked rows
-    do); the heads' chains are interleaved stage by stage."""
+    do); the heads' chains are interleaved stage by stage.
+    ``by_channel``: the state is carried TRANSPOSED, ``S^T`` (Dv, Dk),
+    so that the decays of its rows, one a channel of the key, lie on
+    the lanes of ``e``'s row as the one number of the scalar rule does:
+    the same four products with the state's side of each turned."""
     if residuals:
         v_ref, states_ref, s1_ref, s_scr = rest
     else:
@@ -486,6 +564,12 @@ def _scan_fwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, u_ref, s0_ref,
     dtype = w_ref.dtype
     heads = _block_heads(u_ref)
     first, last = _segment_ends()
+    if by_channel:
+        onto = lambda x, state: _mxu(x, state, _NT)  # x S
+        write = lambda k, v: _mxu(v, k, _TN)  # (K~^T V')^T
+    else:
+        onto = _mxu
+        write = lambda k, v: _mxu(k, v, _TN)
 
     @pl.when(first)
     def _():
@@ -494,14 +578,14 @@ def _scan_fwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, u_ref, s0_ref,
     for c in range(chunks):
         states = [s_scr[at[1:]] for at in heads]
         both = [
-            _mxu(jnp.concatenate([w_ref[at + (c,)], q_ref[at + (c,)]],
+            onto(jnp.concatenate([w_ref[at + (c,)], q_ref[at + (c,)]],
                                  axis=0), state.astype(dtype))
             for at, state in zip(heads, states)]
         new_v = [(u_ref[at + (c,)] - b[:chunk]).astype(dtype)
                  for at, b in zip(heads, both)]
         for at, state, v in zip(heads, states, new_v):
-            s_scr[at[1:]] = e_ref[at + (c,)] * state + _mxu(
-                k_ref[at + (c,)], v, _TN)
+            s_scr[at[1:]] = e_ref[at + (c,)] * state + write(
+                k_ref[at + (c,)], v)
         for at, state, b, v in zip(heads, states, both, new_v):
             o_ref[at + (c,)] = (
                 b[chunk:] + _mxu(p_ref[at + (c,)], v)).astype(o_ref.dtype)
@@ -516,7 +600,7 @@ def _scan_fwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, u_ref, s0_ref,
 
 def _scan_bwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, s_ref, v_ref,
                      do_ref, ds1_ref, du_ref, dw_ref, dk_ref, dq_ref,
-                     dp_ref, de_ref, ds0_ref, ds_scr):
+                     dp_ref, de_ref, ds0_ref, ds_scr, *, by_channel):
     """The reverse recurrence, the chunks last to first, ``dS`` carried
     as the forward carries ``S``: dV' = P^T dO + K~ dS; dW = -dV' S^T
     and dQ~ = dO S^T (one product, ``[dV'; dO] S^T``); dK~ = V' dS^T;
@@ -524,11 +608,21 @@ def _scan_bwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, s_ref, v_ref,
     over the lanes by the caller); dS <- exp(G_last) dS + Q~^T dO -
     W^T dV' (one product, ``[Q~; W]^T [dO; -dV']``). Cotangents are
     rounded to the compute dtype where they are operands, ``dV'`` once
-    after its float32 sum."""
+    after its float32 sum. ``by_channel``: ``S^T`` and ``dS^T`` as the
+    forward carries them, and the sum over the rows of ``dS^T . S^T``
+    is the whole of a channel's ``d exp(G_last)``."""
     chunks, chunk = do_ref.shape[3:5]
     dtype = w_ref.dtype
     heads = _block_heads(do_ref)
     first, last = _segment_ends()
+    if by_channel:
+        onto = lambda x, state: _mxu(x, state, _NT)  # x S
+        off = _mxu  # x S^T
+        write = lambda rows, values: _mxu(values, rows, _TN)
+    else:
+        onto = _mxu
+        off = lambda x, state: _mxu(x, state, _NT)
+        write = lambda rows, values: _mxu(rows, values, _TN)
 
     @pl.when(first)
     def _():
@@ -539,21 +633,21 @@ def _scan_bwd_kernel(e_ref, w_ref, k_ref, q_ref, p_ref, s_ref, v_ref,
         lows = [g.astype(dtype) for g in grads]
         d_v = [
             (_mxu(p_ref[at + (c,)], do_ref[at + (c,)], _TN)
-             + _mxu(k_ref[at + (c,)], low)).astype(dtype)
+             + onto(k_ref[at + (c,)], low)).astype(dtype)
             for at, low in zip(heads, lows)]
         for at, grad, d in zip(heads, grads, d_v):
-            ds_scr[at[1:]] = e_ref[at + (c,)] * grad + _mxu(
+            ds_scr[at[1:]] = e_ref[at + (c,)] * grad + write(
                 jnp.concatenate([q_ref[at + (c,)], w_ref[at + (c,)]], axis=0),
-                jnp.concatenate([do_ref[at + (c,)], -d], axis=0), _TN)
+                jnp.concatenate([do_ref[at + (c,)], -d], axis=0))
         for at, grad, low, d in zip(heads, grads, lows, d_v):
             at = at + (c,)
             state = s_ref[at]
-            both = _mxu(jnp.concatenate([d, do_ref[at]], axis=0),
-                        state.astype(dtype), _NT)
+            both = off(jnp.concatenate([d, do_ref[at]], axis=0),
+                       state.astype(dtype))
             du_ref[at] = d
             dw_ref[at] = (-both[:chunk]).astype(dtype)
             dq_ref[at] = both[chunk:].astype(dtype)
-            dk_ref[at] = _mxu(v_ref[at], low, _NT).astype(dtype)
+            dk_ref[at] = off(v_ref[at], low).astype(dtype)
             dp_ref[at] = _mxu(do_ref[at], v_ref[at], _NT).astype(dtype)
             de_ref[at] = jnp.sum(grad * state, axis=0, keepdims=True)
 
@@ -569,11 +663,12 @@ def _scan_call(kernel, name, kind, a_chunk, a_head, out_chunk, reverse,
     first if ``reverse``), every array read and written where it lies.
     ``a_chunk``: the operands with a block a head and chunk, (B, Hk, R,
     N, rows, cols), the second of them ``w``; ``a_head``: the
-    state-like one, (B, Hk, R, Dk, Dv), followed in the results by its
-    like; ``out_chunk``: (rows, cols, dtype) of the results a head and
-    chunk."""
+    state-like one, (B, Hk, R, Dk, Dv) (a decay a channel's: (.., Dv,
+    Dk)), followed in the results by its like; ``out_chunk``: (rows,
+    cols, dtype) of the results a head and chunk."""
     batch, hk, rep, chunks, chunk, dk = a_chunk[1].shape
-    dv = a_head.shape[-1]
+    dv = a_chunk[-1].shape[-1]
+    state = a_head.shape[3:]
     block, step = scan_block(
         hk * rep, chunks, chunk, dk, dv, a_chunk[1].dtype.itemsize, kind,
         rep)
@@ -585,7 +680,7 @@ def _scan_call(kernel, name, kind, a_chunk, a_head, out_chunk, reverse,
         (1, keys, reps, step, rows, cols),
         lambda b, a, r, n: (b, a, r, at(n), 0, 0))
     head_spec = pl.BlockSpec(
-        (1, keys, reps, dk, dv), lambda b, a, r, n: (b, a, r, 0, 0))
+        (1, keys, reps) + state, lambda b, a, r, n: (b, a, r, 0, 0))
     struct = lambda shape, dtype: jax_compat.out_struct(
         shape, dtype, *a_chunk, a_head)
     return pl.pallas_call(
@@ -599,7 +694,7 @@ def _scan_call(kernel, name, kind, a_chunk, a_head, out_chunk, reverse,
             struct((batch, hk, rep, chunks, rows, cols), dtype)
             for rows, cols, dtype in out_chunk
         ] + [struct(a_head.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((keys, reps, dk, dv), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((keys, reps) + state, jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3 + ("arbitrary",),
             vmem_limit_bytes=_SCAN_VMEM_LIMIT,
@@ -610,9 +705,9 @@ def _scan_call(kernel, name, kind, a_chunk, a_head, out_chunk, reverse,
 
 
 @functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
-    jax.jit, static_argnames=("residuals", "interpret"))
+    jax.jit, static_argnames=("residuals", "interpret", "by_channel"))
 def gdn_scan_fwd(state, decay, w, k, q, p, u, residuals=False,
-                 interpret=False):
+                 interpret=False, by_channel=False):
     """The segment's recurrence from ``state`` (B, Hk, R, Dk, Dv)
     float32: decay (B, Hk, R, N, 1, Dv) float32, exp(G_last) on every
     lane of its row; w, k, q (B, Hk, R, N, C, Dk) and p (B, Hk, R, N, C,
@@ -620,59 +715,74 @@ def gdn_scan_fwd(state, decay, w, k, q, p, u, residuals=False,
     leaving state, o (B, Hk, R, N, C, Dv) in the compute dtype) and,
     with ``residuals`` (the call under differentiation), V' in the
     compute dtype and the float32 state every chunk met, which
-    ``gdn_scan_bwd`` reads."""
+    ``gdn_scan_bwd`` reads. ``by_channel`` (a decay a channel; the
+    kernel is then named ``kda_scan_fwd``): decay (B, Hk, R, N, 1, Dk),
+    a channel's exp(G_last) on its own lane, and every state TRANSPOSED,
+    (B, Hk, R, Dv, Dk), the entering, the leaving and the residuals'."""
     chunk, dv = u.shape[4:]
     dk, dtype = w.shape[5], w.dtype
     out = [(chunk, dv, dtype)]
     if residuals:
-        out += [(chunk, dv, dtype), (dk, dv, jnp.float32)]
+        out += [(chunk, dv, dtype), state.shape[3:] + (jnp.float32,)]
     *outs, state = _scan_call(
-        functools.partial(_scan_fwd_kernel, residuals=residuals),
-        "gdn_scan_fwd", "fwd_residuals" if residuals else "fwd",
+        functools.partial(
+            _scan_fwd_kernel, residuals=residuals, by_channel=by_channel),
+        "kda_scan_fwd" if by_channel else "gdn_scan_fwd",
+        "fwd_residuals" if residuals else "fwd",
         [decay, w, k, q, p, u], state, out, False, interpret)
     return (state, *outs)
 
 
 @functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
-    jax.jit, static_argnames=("interpret",))
+    jax.jit, static_argnames=("interpret", "by_channel"))
 def gdn_scan_bwd(decay, w, k, q, p, states, new_v, d_o, d_state,
-                 interpret=False):
+                 interpret=False, by_channel=False):
     """The VJP of ``gdn_scan_fwd`` from its residuals, ``d_o`` in the
     compute dtype and the leaving state's float32 ``d_state``: ->
     (the entering state's gradient, d decay, dw, dk, dq, dp, du), ``du``
     in the compute dtype (V' was rounded to it), d decay float32, the
-    others in their operands'."""
+    others in their operands'. ``by_channel`` (``kda_scan_bwd``): the
+    states and their gradients transposed as the forward's, d decay
+    (B, Hk, R, N, 1, Dk) whole a channel."""
     chunk, dv = d_o.shape[4:]
     dk, dtype = w.shape[5], w.dtype
     du, dw, d_k, dq, dp, de, d_state = _scan_call(
-        _scan_bwd_kernel, "gdn_scan_bwd", "bwd",
+        functools.partial(_scan_bwd_kernel, by_channel=by_channel),
+        "kda_scan_bwd" if by_channel else "gdn_scan_bwd", "bwd",
         [decay, w, k, q, p, states, new_v, d_o], d_state,
         [(chunk, dv, dtype)] + [(chunk, dk, dtype)] * 3
-        + [(chunk, chunk, dtype), (1, dv, jnp.float32)], True, interpret)
+        + [(chunk, chunk, dtype), (1,) + decay.shape[5:] + (jnp.float32,)],
+        True, interpret)
     return d_state, de, dw, d_k, dq, dp, du
 
 
-@jax.custom_vjp
-def _scan(state, decay, w, k, q, p, u):
-    """The chunk-to-chunk recurrence by the kernels, shapes as
-    ``gdn_scan_fwd``: -> (the leaving state, o)."""
-    return gdn_scan_fwd(state, decay, w, k, q, p, u)
+def _scan_under_vjp(by_channel):
+    """The chunk-to-chunk recurrence by the kernels under their own
+    VJP, shapes as ``gdn_scan_fwd``: (state, decay, w, k, q, p, u) ->
+    (the leaving state, o)."""
+    @jax.custom_vjp
+    def _scan(state, decay, w, k, q, p, u):
+        return gdn_scan_fwd(
+            state, decay, w, k, q, p, u, by_channel=by_channel)
+
+    def _scan_vjp_fwd(state, decay, w, k, q, p, u):
+        leaving, o, new_v, states = gdn_scan_fwd(
+            state, decay, w, k, q, p, u, residuals=True,
+            by_channel=by_channel)
+        # u is no residual: the backward reads V' in its place
+        return (leaving, o), (decay, w, k, q, p, states, new_v)
+
+    def _scan_vjp_bwd(residuals, cotangents):
+        d_state, d_o = cotangents
+        *grads, du = gdn_scan_bwd(
+            *residuals, d_o, d_state, by_channel=by_channel)
+        return (*grads, du.astype(jnp.float32))
+
+    _scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
+    return _scan
 
 
-def _scan_vjp_fwd(state, decay, w, k, q, p, u):
-    leaving, o, new_v, states = gdn_scan_fwd(
-        state, decay, w, k, q, p, u, residuals=True)
-    # u is no residual: the backward reads V' in its place
-    return (leaving, o), (decay, w, k, q, p, states, new_v)
-
-
-def _scan_vjp_bwd(residuals, cotangents):
-    d_state, d_o = cotangents
-    *grads, du = gdn_scan_bwd(*residuals, d_o, d_state)
-    return (*grads, du.astype(jnp.float32))
-
-
-_scan.defvjp(_scan_vjp_fwd, _scan_vjp_bwd)
+_scan, _scan_by_channel = _scan_under_vjp(False), _scan_under_vjp(True)
 
 
 # --------------------------------------------- the chunks' operands
@@ -734,7 +844,8 @@ def prepare_block(rep, chunks, chunk, dk, dv, itemsize):
 
 
 def prepare_impl(dtype, chunk, dk, dv, rep, chunks, state_dtype=jnp.float32,
-                 decay_dtype=jnp.float32, out_dtype=None, mesh=None):
+                 decay_dtype=jnp.float32, out_dtype=None, mesh=None,
+                 decay_rank=SCALAR_DECAY):
     """``"pallas"`` or ``"xla"``: what makes a chunk's operands (``A``,
     its inverse, ``U``, ``W``, the decayed keys and queries, ``P``),
     from what ``scan_impl`` sees: the
@@ -743,10 +854,15 @@ def prepare_impl(dtype, chunk, dk, dv, rep, chunks, state_dtype=jnp.float32,
     or a manual region, operands bfloat16 or float32, float32 decay and
     state, chunk 64 or 128, whole 128-lane rows) and a block of the
     segment's ``chunks`` chunks of ``rep`` value heads fits their VMEM;
-    anything else is ``_chunk_operands`` by XLA."""
+    anything else is ``_chunk_operands`` by XLA. ``decay_rank``: ``g``'s
+    rank as the rule got it. The kernels compute the SCALAR rule's
+    operands (``A`` and ``P`` a product times a decay matrix), so a decay
+    a channel (rank 4) is ``_chunk_operands_by_channel`` on every
+    backend."""
     fits = (
-        scan_impl(dtype, chunk, dk, dv, state_dtype, decay_dtype,
-                  out_dtype, mesh) == "pallas"
+        decay_rank == SCALAR_DECAY
+        and scan_impl(dtype, chunk, dk, dv, state_dtype, decay_dtype,
+                      out_dtype, mesh) == "pallas"
         and prepare_block(
             rep, chunks, chunk, dk, dv, jnp.dtype(dtype).itemsize) is not None
     )
@@ -1064,19 +1180,157 @@ _chunks_pallas.defvjp(_chunks_vjp_fwd, _chunks_vjp_bwd)
 
 
 @functools.lru_cache(maxsize=None)
-def _log_once(hk, hv, dk, chunk, scan, prep, tokens):
+def _log_once(hk, hv, dk, chunk, scan, prep, tokens, decay):
     """One line per distinct call of the rule (this runs at trace time),
     beside the attention line of ``ops/attention.py``, from where the
     paths are chosen. ``scan``: what carries the state from chunk to
-    chunk, ``pallas`` (the ``gdn_scan_*`` kernels) or ``xla`` (a
-    ``lax.scan``); ``prep``: what makes the chunks' operands, ``pallas``
-    (the ``gdn_prepare_*`` kernels, the inverses inside them) or ``xla``
-    (``_chunk_operands`` around ``unit_lower_inverse``); ``impl``: what
-    runs the chunks' inverses, which is what ``prep`` says."""
+    chunk, ``pallas`` (the ``gdn_scan_*`` kernels; ``kda_scan_*``, the
+    same with the state transposed, under a decay a channel) or ``xla``
+    (a ``lax.scan``); ``prep``: what makes the chunks' operands,
+    ``pallas`` (the ``gdn_prepare_*`` kernels, the inverses inside them)
+    or ``xla`` (``_chunk_operands`` around ``unit_lower_inverse``);
+    ``impl``: what runs the chunks' inverses, which is what ``prep``
+    says; ``decay``: ``scalar`` (a number a head and token) or
+    ``vector`` (a number a channel of the key, whose operands are XLA's
+    lines everywhere)."""
     logger.info(
         "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
-        "scan=%s prep=%s (tokens=%d)", hk, hv, dk, chunk, prep, scan, prep,
-        tokens)
+        "scan=%s prep=%s (tokens=%d) decay=%s", hk, hv, dk, chunk, prep,
+        scan, prep, tokens, decay)
+
+
+@jax.custom_vjp
+def _decayed_diagonal(xs, y, cum):
+    """The sub-blocks ON a chunk's diagonal under a decay a channel:
+    ``out[m, i, j] = sum_c xs[m, i, c] y[j, c] exp(cum[i, c] - cum[j,
+    c])`` for ``i >= j``, 0 above; xs (B, Hk, R, N, nb, M, sub, D), y
+    and cum (B, Hk, R, N, nb, sub, D) -> (B, Hk, R, N, nb, M, sub, sub).
+    Summed over the channels directly: every exponent is ``<= 0``
+    whatever the decay, where ``(x e^G)(y e^-G)^T`` overflows as soon
+    as a channel cumulates past -88. The (sub, sub, D) decays of a
+    sub-block are alive for ``_CUBE_BYTES`` of chunks at a time
+    (``_by_chunk_groups``), and the VJP makes them again and keeps none
+    (autodiff would keep them: 2 GB a segment at 32 heads of 128
+    lanes)."""
+    return _by_chunk_groups(_diagonal_fwd, xs, y, cum)
+
+
+def _decay_cube(cum):
+    """``exp(cum[i] - cum[j])`` a channel for ``i >= j``, 0 above:
+    (..., sub, D) -> (..., sub, sub, D)."""
+    sub = cum.shape[-2]
+    lower = jnp.arange(sub)[:, None, None] >= jnp.arange(sub)[None, :, None]
+    return jnp.exp(jnp.where(
+        lower, cum[..., :, None, :] - cum[..., None, :, :], -jnp.inf))
+
+
+def _diagonal_fwd(xs, y, cum):
+    decayed = y[..., None, :, :] * _decay_cube(cum)  # (.., i, j, c)
+    return jnp.sum(
+        xs[..., :, :, None, :] * decayed[..., None, :, :, :], axis=-1)
+
+
+def _diagonal_bwd(xs, y, cum, d_out):
+    decay = _decay_cube(cum)
+    across = y[..., None, :, :]  # y[j] beside the pair (i, j)
+    # what the pair (i, j) hands to y[j]; times y[j], to cum[i] and,
+    # negated, to cum[j]. The products ``m`` are summed term by term:
+    # everything of a pair is then elementwise in (i, j, c), and the
+    # sums over j are ONE reduction of three results, inside which XLA
+    # makes the decays (the forward's sum over c is slower so: 62 ms a
+    # call against 26 for the two fusions with the decays in HBM
+    # between them, PERF.md Section 6, PR 58)
+    pulled = decay * sum(
+        d_out[..., m, :, :, None] * xs[..., m, :, None, :]
+        for m in range(xs.shape[-3]))
+    terms = tuple(
+        d_out[..., m, :, :, None] * (across * decay)
+        for m in range(xs.shape[-3])) + (pulled * across,)
+    *d_xs, onto = jax.lax.reduce(
+        terms, (jnp.zeros((), decay.dtype),) * len(terms),
+        lambda a, b: tuple(x + y for x, y in zip(a, b)),
+        (decay.ndim - 2,))
+    d_y = jnp.sum(pulled, axis=-3)
+    return jnp.stack(d_xs, axis=-3), d_y, onto - y * d_y
+
+
+def _by_chunk_groups(fn, *operands):
+    """``fn`` over its operands' chunks (axis 3 of each, and of each
+    result) a group at a time: the fewest groups whose sub-blocks'
+    (sub, sub, D) arrays are ``_CUBE_BYTES`` each, one after the
+    other."""
+    cum = operands[2]
+    chunks, sub = cum.shape[3], cum.shape[-2]
+    cube = cum.size * sub * cum.dtype.itemsize
+    groups = next(
+        d for d in range(1, chunks + 1)
+        if chunks % d == 0 and (cube <= d * _CUBE_BYTES or d == chunks))
+    if groups == 1:
+        return fn(*operands)
+    split = lambda x: jnp.moveaxis(x.reshape(
+        x.shape[:3] + (groups, chunks // groups) + x.shape[4:]), 3, 0)
+    join = lambda x: jnp.moveaxis(x, 0, 3).reshape(
+        x.shape[1:4] + (chunks,) + x.shape[5:])
+    return jax.tree_util.tree_map(join, jax.lax.map(
+        lambda xs: fn(*xs), tuple(map(split, operands))))
+
+
+def _decayed_diagonal_fwd(xs, y, cum):
+    return _decayed_diagonal(xs, y, cum), (xs, y, cum)
+
+
+def _decayed_diagonal_bwd(residuals, d_out):
+    return _by_chunk_groups(_diagonal_bwd, *residuals, d_out)
+
+
+_decayed_diagonal.defvjp(_decayed_diagonal_fwd, _decayed_diagonal_bwd)
+
+
+def _decayed_products(q, k, cum):
+    """``K K^T`` and ``Q K^T`` of every chunk with the decay of a pair
+    INSIDE the contraction over the channels, which a decay a channel
+    asks for (``sum_c x_i[c] k_j[c] exp(G_i[c] - G_j[c])``: no decay
+    matrix factors out): q, k (B, Hk, 1, N, C, Dk), cum (B, Hk, R, N, C,
+    Dk) -> two float32 (B, Hk, R, N, C, C), what is above a chunk's
+    diagonal left to the caller's masks.
+
+    A chunk is cut into sub-blocks of ``_SUB`` rows. Between two
+    sub-blocks ``I > J``, ``exp(G_i - G_j) = exp(G_i - G_r) exp(G_r -
+    G_j)`` with ``r`` the first row of ``I``: both exponents ``<= 0``,
+    so those blocks are matmuls of decayed operands (the keys decayed
+    once a sub-block of rows, (nb, C, Dk) a chunk). On the diagonal the
+    pairs are summed over the channels directly
+    (``_decayed_diagonal``). No exponent is ever positive."""
+    dtype, (chunk, dk) = q.dtype, q.shape[-2:]
+    sub = min(_SUB, chunk)
+    blocks = lambda x: x.reshape(x.shape[:-2] + (chunk // sub, sub, dk))
+    wide = jnp.promote_types(dtype, jnp.float32)
+    whole = lambda x: jnp.broadcast_to(
+        blocks(x).astype(wide), blocks(cum).shape)
+    qk_rows = jnp.stack([whole(k), whole(q)], axis=-3)  # (.., nb, 2, sub, Dk)
+    diagonal = _decayed_diagonal(qk_rows, whole(k), blocks(cum))
+    first = blocks(cum)[..., :1, :]  # G at each sub-block's first row
+    rows = qk_rows * jnp.exp(blocks(cum) - first)[..., None, :, :]
+    # the keys as sub-block I's rows meet them: those of the sub-blocks
+    # before I (at and past I's first row the difference is positive,
+    # and those pairs are the diagonal's or nobody's)
+    block = jnp.arange(chunk) // sub
+    before = (block[None, :] < jnp.arange(chunk // sub)[:, None])[..., None]
+    cols = k[..., None, :, :] * jnp.exp(jnp.where(
+        before, first - cum[..., None, :, :], -jnp.inf))
+    below = _matmul(
+        rows.reshape(rows.shape[:-3] + (2 * sub, dk)),
+        jnp.swapaxes(cols, -1, -2), dtype)  # (.., nb, 2 sub, C)
+    same = (block[:, None] == block[None, :]).reshape(-1, sub, chunk)
+
+    def laid(m):
+        """Product ``m`` (K K^T, Q K^T) as a chunk's (C, C)."""
+        off = below[..., m * sub:(m + 1) * sub, :]
+        on = jnp.tile(diagonal[..., m, :, :], chunk // sub)
+        return jnp.where(same, on, off).reshape(
+            below.shape[:-3] + (chunk, chunk))
+
+    return laid(0), laid(1)
 
 
 def _chunk_operands(q, k, v, g, beta, decay_dtype):
@@ -1111,6 +1365,28 @@ def _chunk_operands(q, k, v, g, beta, decay_dtype):
     return last, w, k_onto, q_into, attn, u
 
 
+def _chunk_operands_by_channel(q, k, v, g, beta, decay_dtype):
+    """``_chunk_operands`` for a decay a channel, g (B, Hk, R, N, C, Dk):
+    the same operands, ``G_last`` (B, Hk, R, N, Dk) over the state's
+    rows. The decay sits inside ``A``'s and ``P``'s contractions
+    (``_decayed_products``) and scales the keys and queries a channel."""
+    dtype, chunk = q.dtype, q.shape[-2]
+    cum = jnp.cumsum(g.astype(decay_dtype), axis=4).astype(g.dtype)
+    row = jnp.arange(chunk)[:, None]
+    col = jnp.arange(chunk)[None, :]
+    into = jnp.exp(cum)  # what reaches token i of the entering state
+    last = cum[..., -1:, :]
+    onto = jnp.exp(last - cum)  # what is left of token i at the end
+    kk, attn = _decayed_products(q, k, cum)
+    a = jnp.where(row > col, kk * beta[..., :, None], 0.0)
+    t = unit_lower_inverse(a)
+    u = _matmul(t, beta[..., None] * v, dtype)
+    w = _matmul(t, (beta[..., None] * into) * k, dtype).astype(dtype)
+    k_onto = (onto * k).astype(dtype)
+    attn = jnp.where(row >= col, attn, 0.0)
+    return last[..., 0, :], w, k_onto, into * q, attn, u
+
+
 def _scan_xla(state, last, w, k_onto, q_into, attn, u, dtype):
     """The chunk-to-chunk recurrence as a ``lax.scan`` over the chunks,
     which hands back every chunk's V' and entering state for the two
@@ -1123,6 +1399,8 @@ def _scan_xla(state, last, w, k_onto, q_into, attn, u, dtype):
         u_n, w_n, k_n, end = xs
         new_v = u_n - _matmul(w_n, state, dtype)
         held = state
+        # the state's rows by ``end`` (.., 1), one number, or (.., Dk),
+        # one a channel
         state = (
             jnp.exp(end)[..., None] * state
             + _matmul(swap(k_n), new_v, dtype)
@@ -1157,6 +1435,19 @@ def _scan_pallas(state, last, w, k_onto, q_into, attn, u, dtype):
         last, w, k_onto, q_into, attn, u, dtype))
 
 
+def _scan_pallas_by_channel(state, last, w, k_onto, q_into, attn, u, dtype):
+    """``_scan_pallas`` for ``_chunk_operands_by_channel``'s operands,
+    ``last`` (B, Hk, R, N, Dk): the kernels take a channel's
+    exp(G_last) on its own lane of the chunk's row and carry the state
+    transposed, so it is turned on its way in and out (2 MB a segment
+    at 32 heads of 128 x 128)."""
+    swap = lambda x: jnp.swapaxes(x, -1, -2)
+    leaving, o = _scan_by_channel(
+        swap(state), jnp.exp(last)[..., None, :], w, k_onto,
+        q_into.astype(dtype), attn.astype(dtype), u)
+    return swap(leaving), o
+
+
 def _chunks(state, q, k, v, g, beta, decay_dtype, scan, prep):
     """The rule over whole chunks from the state ``state`` (in the
     dtype it is carried in): -> (the state after them, o (B, Hk, R, N,
@@ -1165,9 +1456,12 @@ def _chunks(state, q, k, v, g, beta, decay_dtype, scan, prep):
     nothing of XLA's between them)."""
     if prep == "pallas":
         return _chunks_pallas(state, q, k, v, g, beta)
-    carry = _scan_pallas if scan == "pallas" else _scan_xla
-    return carry(
-        state, *_chunk_operands(q, k, v, g, beta, decay_dtype), q.dtype)
+    if g.ndim == beta.ndim:
+        operands, kernels = _chunk_operands, _scan_pallas
+    else:
+        operands, kernels = _chunk_operands_by_channel, _scan_pallas_by_channel
+    carry = kernels if scan == "pallas" else _scan_xla
+    return carry(state, *operands(q, k, v, g, beta, decay_dtype), q.dtype)
 
 
 def segments_of(seq, chunk=DEFAULT_CHUNK, segment=DEFAULT_SEGMENT):
@@ -1184,8 +1478,14 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
                      decay_dtype=None, mesh=None):
     """q, k: (B, Hk, S, Dk), already normalised and scaled; v: (B, Hv,
     S, Dv) with ``Hv`` a multiple of ``Hk`` (value head ``h`` reads key
-    head ``h // (Hv / Hk)``; q and k are never repeated in memory); g,
-    beta: (B, Hv, S) float32. Returns o (B, Hv, S, Dv) in ``v``'s dtype.
+    head ``h // (Hv / Hk)``; q and k are never repeated in memory);
+    beta: (B, Hv, S) float32; g: (B, Hv, S) float32, one log decay a
+    head and token (Gated DeltaNet), or (B, Hv, S, Dk), one a channel
+    of the key (Kimi Delta Attention: ``S <- Diag(exp(g_t)) S``). The
+    operand's rank decides, no flag: a decay a channel makes its
+    operands by ``_decayed_products`` on every backend and carries its
+    state where ``scan_impl`` says, as a decay a token does. Returns o
+    (B, Hv, S, Dv) in ``v``'s dtype.
 
     A sequence longer than ``segment`` chunks runs a segment at a time,
     each under ``jax.checkpoint``, the state carried between them: the
@@ -1209,6 +1509,10 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     wide = jnp.promote_types(q.dtype, jnp.float32)
     state_dtype = state_dtype or wide
     decay_dtype = decay_dtype or wide
+    if g.shape != beta.shape and g.shape != beta.shape + (dk,):
+        raise ValueError(
+            "g is beta's shape %s (a decay a token) or that and the "
+            "key's %d channels, got %s" % (beta.shape, dk, g.shape))
     scan = scan_impl(
         q.dtype, chunk, dk, dv, state_dtype, decay_dtype, v.dtype, mesh)
     pad, segments = segments_of(seq, chunk, segment)
@@ -1219,8 +1523,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     num = (seq + pad) // (segments * chunk)  # chunks a segment
     prep = prepare_impl(
         q.dtype, chunk, dk, dv, rep, num, state_dtype, decay_dtype, v.dtype,
-        mesh)
-    _log_once(hk, hv, dk, chunk, scan, prep, batch * seq)
+        mesh, g.ndim)
+    _log_once(hk, hv, dk, chunk, scan, prep, batch * seq,
+              "scalar" if g.ndim == SCALAR_DECAY else "vector")
     # segments first; key-like (B, Hk, 1, N, C, Dk), value-like (B, Hk,
     # R, N, C, ...)
     split = lambda x, heads, *rest: jnp.moveaxis(
@@ -1229,7 +1534,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     xs = (
         split(q, (hk, 1), dk), split(k, (hk, 1), dk),
         split(v, (hk, rep), dv),
-        split(g.astype(wide), (hk, rep)), split(beta.astype(wide), (hk, rep)),
+        split(g.astype(wide), (hk, rep), *g.shape[3:]),
+        split(beta.astype(wide), (hk, rep)),
     )
     run = lambda state, xs: _chunks(state, *xs, decay_dtype, scan, prep)
     state0 = jnp.zeros((batch, hk, rep, dk, dv), state_dtype)
@@ -1242,23 +1548,27 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     return checkpoint_name(o.astype(v.dtype), GDN_OUT_NAME)
 
 
-def gated_delta_recurrence(q, k, v, g, beta):
+def gated_delta_recurrence(q, k, v, g, beta, state=None):
     """The rule one token a step, in the inputs' dtype: the definition
-    the chunked form is tested against. Shapes as
-    ``gated_delta_rule``."""
+    the chunked form is tested against, for a decay a token and for a
+    decay a channel. Shapes as ``gated_delta_rule``; ``state`` (B, Hv,
+    Dk, Dv): the state the sequence starts from (None: zero)."""
     rep = v.shape[1] // q.shape[1]
     q, k = (jnp.repeat(x, rep, axis=1) for x in (q, k))
+    # the decay over the state's rows: (B, H, S, 1 or Dk, 1)
+    g = g.reshape(g.shape[:3] + (-1, 1))
 
     def step(state, xs):
-        q_t, k_t, v_t, g_t, b_t = xs  # (B, H, D) and (B, H)
-        state = jnp.exp(g_t)[..., None, None] * state
+        q_t, k_t, v_t, g_t, b_t = xs  # (B, H, D), (B, H, 1 | Dk, 1), (B, H)
+        state = jnp.exp(g_t) * state
         u = b_t[..., None] * (
             v_t - jnp.einsum("bhkv,bhk->bhv", state, k_t))
         state = state + k_t[..., :, None] * u[..., None, :]
         return state, jnp.einsum("bhkv,bhk->bhv", state, q_t)
 
     tokens_first = lambda x: jnp.moveaxis(x, 2, 0)
-    state0 = jnp.zeros(v.shape[:2] + (q.shape[-1], v.shape[-1]), v.dtype)
+    if state is None:
+        state = jnp.zeros(v.shape[:2] + (q.shape[-1], v.shape[-1]), v.dtype)
     _, o = jax.lax.scan(
-        step, state0, tuple(map(tokens_first, (q, k, v, g, beta))))
+        step, state, tuple(map(tokens_first, (q, k, v, g, beta))))
     return jnp.moveaxis(o, 0, 2)

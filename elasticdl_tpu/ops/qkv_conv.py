@@ -78,7 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
-from elasticdl_tpu.ops.gated_delta import segments_of
+from elasticdl_tpu.ops.gated_delta import DEFAULT_SEGMENT, segments_of
 
 logger = _logger_factory("elasticdl_tpu.ops.qkv_conv")
 
@@ -537,32 +537,34 @@ def qkv_conv_bwd(qkvz, taps, dq, dk, dv, heads, segments=1, tile=None,
 
 # ------------------------------------------------------- the pair
 
-def rule_segments(seq, chunk):
+def rule_segments(seq, chunk, segment=DEFAULT_SEGMENT):
     """The equal runs of the sequence q, k, v are written by: the
-    segments ``gated_delta_rule`` scans over at ``chunk`` (whole
-    128-row tiles each), so that its turn of the sequence to
-    segments-first is no copy (PR 44: 10 ms a step of copies under
-    ``gdn/scan`` otherwise, which XLA's own producer had fused); 1
-    where the rule pads the sequence."""
-    pad, segments = segments_of(seq, chunk)
+    segments ``gated_delta_rule`` scans over at ``chunk`` and
+    ``segment`` chunks a segment (whole 128-row tiles each), so that its
+    turn of the sequence to segments-first is no copy (PR 44: 10 ms a
+    step of copies under ``gdn/scan`` otherwise, which XLA's own
+    producer had fused); 1 where the rule pads the sequence."""
+    pad, segments = segments_of(seq, chunk, segment)
     return 1 if pad else segments
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def qkv_conv(qkvz, taps, heads, segments=1):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def qkv_conv(qkvz, taps, heads, segments=1, scope=SCOPE):
     """q, k, v (B, H, S, D) of a Gated DeltaNet layer from its
     projection's output and its taps (module docstring); ``heads``
-    (Hk, Hv, D), ``segments``: ``rule_segments``."""
+    (Hk, Hv, D), ``segments``: ``rule_segments``; ``scope``: the named
+    scope the backward's operations lie under (the caller's own holds
+    the forward's: a Kimi Delta Attention layer's is ``kda/conv``)."""
     return tuple(qkv_conv_fwd(qkvz, taps, heads, segments))
 
 
-def _qkv_conv_fwd(qkvz, taps, heads, segments):
+def _qkv_conv_fwd(qkvz, taps, heads, segments, scope):
     return tuple(qkv_conv_fwd(qkvz, taps, heads, segments)), (qkvz, taps)
 
 
-def _qkv_conv_bwd(heads, segments, residuals, cotangents):
+def _qkv_conv_bwd(heads, segments, scope, residuals, cotangents):
     qkvz, taps = residuals
-    with jax.named_scope(SCOPE):
+    with jax.named_scope(scope):
         dx, dw = qkv_conv_bwd(qkvz, taps, *cotangents, heads, segments)
         width = qkvz.shape[-1] - dx.shape[-1]
         return (

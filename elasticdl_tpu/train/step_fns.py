@@ -92,7 +92,8 @@ class Fact(NamedTuple):
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
 # block (``models/moe_transformer.py``), a learned sparse-attention
 # indexer's, one a layer, a looped stack's exit distribution, one entry
-# a pass, and what the loss function names of its own sum (a
+# a pass, a Kimi Delta Attention model's gates, one entry a KDA layer,
+# and what the loss function names of its own sum (a
 # multi-token-prediction module's loss, an indexer's term, a looped
 # stack's expected cross-entropy, entropy and cross-entropy an exit)
 FACTS = (
@@ -115,6 +116,7 @@ FACTS = (
     Fact("mhc", "mhc"),
     Fact("dsa", "dsa_select"),
     Fact("looped", "looped_exit"),
+    Fact("kda", "kda_gates"),
     Fact("loss_terms", "loss_terms", of_loss=True),
 )
 

@@ -460,6 +460,73 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(case):
         assert float(jnp.abs(total).max()) > 1e-2
 
 
+def test_the_thirty_two_shares_of_kimi_s_layer_add_up():
+    """Kimi Linear's expert layer at its published counts and a small
+    width: 256 sigmoid-routed experts, top-8 by score + balancing bias,
+    gates renormalised and scaled by 2.446, one shared expert; 32 chips
+    hold 8 experts each. The shares' parts, the shared expert counted
+    once, sum to what the uncut reference gives for the whole layer:
+    the output and the gradient that reaches the layer's input."""
+    ref = _reference_of("kimi-linear-48b-a3b-1chip")
+    experts, top_k, chips = 256, 8, 32
+    config = {"num_experts_per_token": top_k, "moe_renormalize": True,
+              "routed_scaling_factor": 2.446}
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 16))
+    weight = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
+
+    def layer(held):
+        return MoeMlp(
+            experts, top_k=top_k, dispatch_impl="sorted", expert_dim=8,
+            expert_act="swiglu", normalize_gates=True, scoring="sigmoid",
+            gate_scale=2.446, bias_update_speed=0.001, seq_aux=True,
+            shared_experts=1, held_experts=held, held_rows=24 * top_k)
+
+    variables = layer(None).init(jax.random.PRNGKey(1), x)
+    params = variables["params"]
+    bias = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (experts,))
+    state = {"moe_state": {"e_score_correction_bias": bias}}
+    assert params["w_gate"].shape == (experts, 16, 8)
+
+    def uncut(x):
+        flat = x.reshape(24, 16)
+        y, _, chosen = ref.expert_layer(
+            flat, params, bias, config, (0, experts))
+        return ((y + ref.shared_expert(flat, params)) * weight).sum(), chosen
+
+    (_, chosen), want_dx = jax.value_and_grad(uncut, has_aux=True)(x)
+    # the bias chooses: without it the same router picks other experts
+    _, _, unbiased = ref.expert_layer(
+        x.reshape(24, 16), params, 0.0 * bias, config, (0, experts))
+    assert bool((jnp.sort(chosen) != jnp.sort(unbiased)).any())
+    want, _ = uncut(x)
+    count = experts // chips
+
+    def part(x, first):
+        mine = dict(params, **{
+            name: params[name][first:first + count]
+            for name in ("w_gate", "w_up", "w_down")})
+        y, aux = layer((first, count)).apply(
+            {"params": mine, **state}, x)
+        return (y.reshape(24, 16) * weight).sum(), aux["routing"]["dropped"]
+
+    shared = lambda x: (
+        ref.shared_expert(x.reshape(24, 16), params) * weight).sum()
+    total, total_dx = 0.0, 0.0
+    for chip in range(chips):
+        first = chip * count
+        (value, dropped), dx = jax.value_and_grad(
+            lambda x: part(x, first), has_aux=True)(x)
+        assert float(dropped) == 0
+        total, total_dx = total + value, total_dx + dx
+    # every share added the shared expert: count it once
+    total = total - (chips - 1) * shared(x)
+    total_dx = total_dx - (chips - 1) * jax.grad(shared)(x)
+    # float32 sums of 32 parts less 31 shared experts
+    np.testing.assert_allclose(total, want, rtol=1e-4)
+    np.testing.assert_allclose(total_dx, want_dx, atol=1e-4)
+    assert float(jnp.abs(want_dx).max()) > 1e-2
+
+
 def test_a_share_holds_its_experts_kernels_only():
     x = jnp.zeros((1, 8, 16))
     shapes = jax.tree_util.tree_map(

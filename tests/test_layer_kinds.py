@@ -304,7 +304,7 @@ def test_the_attention_line_by_kind(monkeypatch, caplog):
 
 
 @pytest.mark.parametrize("changes,match", [
-    (dict(layer_kinds=("full", "banded")), "'full', 'linear' or 'window'"),
+    (dict(layer_kinds=("full", "banded")), "each is 'full', 'window', 'linear'"),
     (dict(kind_fields={"full": FULL}), "needs kind_fields\\['window'\\]"),
     (dict(kind_fields={"full": FULL, "window": MixerKind(8)}),
      "needs kind_fields\\['window'\\]"),

@@ -491,7 +491,8 @@ def test_a_probe_is_sown_only_when_asked(trained):
 
 REFUSED = [
     ("block_diffusion", dict(objective="block_diffusion", bd_mask_id=1)),
-    ("'window', 'linear' or 'conv'", dict(layer_kinds=("full", "window"))),
+    ("'window', 'linear', 'conv' or 'kda'",
+     dict(layer_kinds=("full", "window"))),
     ("latent attention", "latent"),
     ("hyper-connections", "hc"),
     ("prediction module", dict(mtp_layers=1)),
