@@ -5,9 +5,9 @@ arXiv:2510.26692; Kimi Linear's). Which of the two runs is decided by
 the rank of ``g`` and by nothing else: ``(B, H, S)``, one log decay a
 head and token, or ``(B, H, S, Dk)``, one a channel of the key (the
 last section below). ``gdn_prepare_fwd`` / ``gdn_prepare_bwd`` compute
-the SCALAR rule's operands, so a decay a channel makes its own by
-``_chunk_operands_by_channel`` on every backend (``prepare_impl``:
-``decay_rank``); the chunk-to-chunk recurrence is ``gdn_scan_fwd`` /
+the SCALAR rule's operands, ``kda_prepare_fwd`` / ``kda_prepare_bwd`` a
+decay a channel's (PR 59), each where ``prepare_impl`` finds a TPU and
+a block in its VMEM; the chunk-to-chunk recurrence is ``gdn_scan_fwd`` /
 ``gdn_scan_bwd`` for both ranks wherever ``scan_impl`` says so, a decay
 a channel with the state transposed (``by_channel``: the kernels are
 then named ``kda_scan_fwd`` / ``kda_scan_bwd``).
@@ -245,9 +245,9 @@ against the untransposed state would be a (Dk, 1) block, one number a
 6% of Kimi Linear's step and 82,000 of the 116,000 operations it
 executed, each an event of the profiler: ``stop_trace`` held the loop
 10 s for two traced steps (PERF.md Section 6, PR 58). The operands
-(``_decayed_products``, the inverses, ``U`` and ``W``) stay XLA's: on
-the chip the sums on the diagonals are a third of the rule's time
-(PERF.md Section 5): a kernel for them is the next step.
+(``_decayed_products``, the inverses, ``U`` and ``W``) were half that
+step as XLA's lines; since PR 59 they are the ``kda_prepare_*`` pair,
+described where it stands below (PERF.md Section 6, PR 59).
 """
 
 import functools
@@ -855,15 +855,15 @@ def prepare_impl(dtype, chunk, dk, dv, rep, chunks, state_dtype=jnp.float32,
     state, chunk 64 or 128, whole 128-lane rows) and a block of the
     segment's ``chunks`` chunks of ``rep`` value heads fits their VMEM;
     anything else is ``_chunk_operands`` by XLA. ``decay_rank``: ``g``'s
-    rank as the rule got it. The kernels compute the SCALAR rule's
-    operands (``A`` and ``P`` a product times a decay matrix), so a decay
-    a channel (rank 4) is ``_chunk_operands_by_channel`` on every
-    backend."""
+    rank as the rule got it: a decay a channel (rank 4) asks the same of
+    the ``kda_prepare_*`` kernels and of their own account of a block
+    (``kda_prepare_block``: ``g`` is a (chunk, Dk) tile a chunk there),
+    and is ``_chunk_operands_by_channel`` otherwise."""
+    block = prepare_block if decay_rank == SCALAR_DECAY else kda_prepare_block
     fits = (
-        decay_rank == SCALAR_DECAY
-        and scan_impl(dtype, chunk, dk, dv, state_dtype, decay_dtype,
-                      out_dtype, mesh) == "pallas"
-        and prepare_block(
+        scan_impl(dtype, chunk, dk, dv, state_dtype, decay_dtype,
+                  out_dtype, mesh) == "pallas"
+        and block(
             rep, chunks, chunk, dk, dv, jnp.dtype(dtype).itemsize) is not None
     )
     return "pallas" if fits else "xla"
@@ -1062,7 +1062,8 @@ def _prepare_bwd_kernel(q_ref, k_ref, v_ref, de_ref, dw_ref, dko_ref, dqi_ref,
 
 
 def _prepare_call(kernel, name, key_like, value_like, rows, inverse,
-                  out_key, out_value, out_rows, out_inverse, interpret):
+                  out_key, out_value, out_rows, out_inverse, interpret,
+                  step=None, scratch=()):
     """``kernel`` over the grid (batch, key heads, blocks of chunks),
     all parallel. Operands and results by their blocks: ``key_like``
     (B, Hk, 1, N, C, Dk); ``value_like`` (B, Hk, R, N, rows, cols);
@@ -1070,10 +1071,12 @@ def _prepare_call(kernel, name, key_like, value_like, rows, inverse,
     steps, lane rows, C, 128) or nothing. ``out_key`` / ``out_rows``:
     how many results like the first of their operands; ``out_value``:
     (rows, cols, dtype) each; ``out_inverse``: whether ``T`` is the
-    last result."""
+    last result; ``step``: the chunks a grid step takes
+    (``prepare_block``'s unless given); ``scratch``: the kernel's VMEM
+    scratch, after its results."""
     batch, hk, rep, chunks, chunk, dv = value_like[0].shape
     dk, dtype = key_like[0].shape[-1], key_like[0].dtype
-    step = prepare_block(rep, chunks, chunk, dk, dv, dtype.itemsize)
+    step = step or prepare_block(rep, chunks, chunk, dk, dv, dtype.itemsize)
     steps = chunks // step
     lane_rows = -(-rep * step // (_LANES // chunk))
     key_spec = pl.BlockSpec(
@@ -1106,6 +1109,7 @@ def _prepare_call(kernel, name, key_like, value_like, rows, inverse,
             + [struct(rows[0].shape, jnp.float32)] * out_rows
             + [struct((batch, hk, steps, lane_rows, chunk, _LANES),
                       jnp.float32)] * out_inverse),
+        scratch_shapes=list(scratch),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",) * 3,
             vmem_limit_bytes=_PREPARE_VMEM_LIMIT,
@@ -1152,31 +1156,492 @@ def gdn_prepare_bwd(q, k, v, g, beta, inverse, d_decay, dw, d_k, dq, dp, du,
         2, [(chunk, dv, v.dtype)], 2, 0, interpret)
 
 
-@jax.custom_vjp
-def _chunks_pallas(state, q, k, v, g, beta):
-    """The rule over a segment's chunks by the four kernels, shapes as
-    ``_chunks``: -> (the leaving state, o)."""
-    return gdn_scan_fwd(state, *gdn_prepare_fwd(q, k, v, g, beta))
+# ------------------------------- the chunks' operands, a decay a channel
+# ``kda_prepare_fwd`` / ``kda_prepare_bwd`` (PR 59): what
+# ``_chunk_operands_by_channel`` and autodiff of it do further down, a
+# block of a key head's chunks in VMEM from the loads to the stores, the
+# siblings of ``gdn_prepare_*`` as ``kda_scan_*`` are of ``gdn_scan_*``.
+# They stand below the scalar rule's kernels, whose bodies keep their
+# lines (a Mosaic body carries its frames' file and line into the
+# program: ``scripts/step_fingerprint.py``).
+#
+# Left to XLA the vector rule's operands were half of Kimi Linear's
+# step: the float32 inverses, each product a fusion reading and writing
+# a (B, H, N, 64, 64) array, the (sub, sub, Dk) decays of the diagonals
+# written to HBM a group of chunks at a time and read back, and the
+# copies between the layouts all of that wants. Here a grid step takes a
+# block of one key head's chunks (``kda_prepare_block``); q, k, v and the
+# (chunk, Dk) float32 tile of ``g`` a chunk and head are read once and
+# nothing but the scan's six operands (and ``T`` under differentiation)
+# is written. The cut of a chunk is ``_decayed_products``': sub-blocks
+# of ``_KDA_SUB`` rows; between sub-blocks matmuls of operands decayed
+# to the sub-block's first row (``_earlier_decay``: one (16, Dk) x (Dk,
+# C) product a sub-block, its rows the sub-block's decayed k and q);
+# on the diagonal the pairs ``d`` rows apart, d = 0..7, are ONE
+# elementwise pass over the whole chunk each (``_pairs_apart``: the
+# other row comes by a load at a sublane offset from a scratch that
+# holds the chunk's k and G, its decay is masked with ``-inf`` where the
+# offset left the sub-block) and one sum over the lanes a product,
+# float32. No exponent is ever positive and none is clipped,
+# so a channel that does not decay keeps its whole gradient. ``G`` is a
+# product with a triangle of ones at precision highest (float32 sums on
+# the MXU, which the elementwise work leaves idle), ``dg`` the same with
+# the triangle turned. The inverses, their gradient, the pairing of two
+# 64 x 64 matrices a lane row and the chains are the scalar kernels'
+# own helpers (``_inverse_rows``, ``_inverse_grad_rows``, ``_paired``,
+# ``_unpaired``, ``_by_chains``), called, not copied. The loop over a
+# block's chunks is a ``fori_loop`` (the body is ~1,000 vector
+# operations a chunk forward, ~2,500 backward: unrolled 16 times it is
+# Mosaic's compile time and nothing else); ``A`` (the backward: ``G``,
+# ``dT`` and ``[dX | dY]``) waits in a VMEM scratch for the inverses,
+# which run by chains over the whole block after it. On the chip a
+# segment of the cell (2,048 chunks and heads) takes 1.74 ms forward
+# and 2.64 backward where XLA's lines took 10.3 and 12.0 more for their
+# VJP (PERF.md Section 6, PR 59).
+
+# rows of a sub-block of a chunk INSIDE the kernels: one float32 tile,
+# so a sub-block's rows are whole tiles of the (chunk, Dk) arrays and a
+# pair on the diagonal is at most 7 rows apart
+_KDA_SUB = 8
 
 
-def _chunks_vjp_fwd(state, q, k, v, g, beta):
-    *operands, u, inverse = gdn_prepare_fwd(
-        q, k, v, g, beta, residuals=True)
-    leaving, o, new_v, states = gdn_scan_fwd(
-        state, *operands, u, residuals=True)
-    return (leaving, o), (
-        q, k, v, g, beta, inverse, *operands, states, new_v)
+def kda_prepare_vmem_bytes(rep, chunks, chunk, dk, dv, itemsize, kind):
+    """``prepare_vmem_bytes`` for the vector rule's kernels: ``g`` and
+    ``dg`` are (chunk, Dk) float32 tiles a chunk and head, ``exp(G_last)``
+    a (1, Dk) row, and the scratch that hands ``A`` (the backward: ``G``,
+    ``dT`` and ``[dX | dY]``) from one phase of a grid step to the next
+    is counted once, beside the double-buffered blocks."""
+    key = _tile_bytes(chunk, dk, itemsize)
+    value = _tile_bytes(chunk, dv, itemsize)
+    square = _tile_bytes(chunk, chunk, itemsize)
+    wide = _tile_bytes(chunk, dv, 4)
+    gate = _tile_bytes(chunk, dk, 4)
+    decay = _tile_bytes(1, dk, 4)
+    inverse = chunk * chunk * 4  # two of 64 a 128-lane row
+    rows = 2 * _tile_bytes(chunks, chunk, 4)  # beta and its gradient
+    matrix = _tile_bytes(chunk, chunk, 4)
+    staged = 2 * _tile_bytes(_KDA_SUB + chunk, dk, 4)  # ``_stage_rows``'
+    a_chunk, scratch = {
+        # q, k; v, g -> decay, (w, k, q), p, u
+        "fwd": (2 * key + rep * (
+            value + gate + decay + 3 * key + square + wide), matrix),
+        # ... -> T
+        "fwd_residuals": (2 * key + rep * (
+            value + gate + decay + 3 * key + square + wide + inverse),
+            matrix),
+        # q, k; v, g, T, d decay, (dw, dk, dq), dp, du -> dq, dk; dv, dg
+        "bwd": (4 * key + rep * (
+            3 * value + 2 * gate + inverse + decay + 3 * key + square),
+            matrix + gate + _tile_bytes(chunk, dv + dk, 4)),
+    }[kind]
+    return (2 * (chunks * a_chunk + rep * rows) + rep * chunks * scratch
+            + staged)
 
 
-def _chunks_vjp_bwd(residuals, cotangents):
-    *inputs, inverse = residuals[:6]
-    d_state, d_o = cotangents
-    d_state, *grads = gdn_scan_bwd(*residuals[6:], d_o, d_state)
-    # du stays in the compute dtype between the two kernels
-    return (d_state, *gdn_prepare_bwd(*inputs, inverse, *grads))
+def kda_prepare_block(rep, chunks, chunk, dk, dv, itemsize):
+    """``prepare_block`` for the vector rule's kernels, by their own
+    account: the smallest divisor of ``chunks`` in whole 8-row tiles of
+    ``beta``'s (chunks, chunk) blocks (or all of them) that gives the
+    inverses a group of ``_CHAINS`` lane rows, inside
+    ``_PREPARE_BLOCK_BYTES``. None where no block fits."""
+    size = lambda step: max(
+        kda_prepare_vmem_bytes(rep, step, chunk, dk, dv, itemsize, kind)
+        for kind in ("fwd_residuals", "bwd"))
+    steps = [d for d in range(1, chunks + 1)
+             if chunks % d == 0 and (d % 8 == 0 or d == chunks)
+             and size(d) <= _PREPARE_BLOCK_BYTES]
+    if not steps:
+        return None
+    enough = [d for d in steps if rep * d >= _CHAINS * (_LANES // chunk)]
+    return enough[0] if enough else steps[-1]
 
 
-_chunks_pallas.defvjp(_chunks_vjp_fwd, _chunks_vjp_bwd)
+def _cumulated(g, reverse=False):
+    """The sum of a (C, Dk) float32 tile over its rows up to each row
+    (``reverse``: from each row on): a product with a triangle of ones
+    at precision highest, so float32 sums on the MXU where XLA calls
+    ``cumsum`` (equal to float32 rounding, not bit for bit)."""
+    chunk = g.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    ones = (row <= col) if reverse else (row >= col)
+    return _dot(ones.astype(jnp.float32), g)
+
+
+def _sub_firsts(x, pick=lambda block: block[:1]):
+    """(C, D) -> (C, D): on every row ``pick`` of the row's sub-block of
+    ``_KDA_SUB`` rows (its first row; a (1, D) row of it in general)."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(pick(x[i:i + _KDA_SUB]), (_KDA_SUB, x.shape[1]))
+         for i in range(0, x.shape[0], _KDA_SUB)], axis=0)
+
+
+def _chunk_indices(chunk, dk):
+    """What the loop over a block's chunks reads and never changes,
+    made once a grid step: (``apart`` (C, C): row - col for a pair of
+    ONE sub-block, -1 for any other; over a (C, Dk) array a row's index
+    in its sub-block, and its index in the chunk)."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    apart = jnp.where(
+        row // _KDA_SUB == col // _KDA_SUB, row - col, -1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, dk), 0)
+    return apart, rows & (_KDA_SUB - 1), rows
+
+
+def _stage_rows(scr, k, cum):
+    """k and cum (C, Dk) float32 into the (2, sub + C, Dk) scratch under
+    ``_KDA_SUB`` rows of zeros, where ``_pairs_apart`` reads them ``d``
+    rows up: a load at a sublane offset where a roll of the value would
+    queue on the unit that also sums over the lanes."""
+    zeros = jnp.zeros((_KDA_SUB, k.shape[1]), jnp.float32)
+    for i, x in enumerate((k, cum)):
+        scr[i, :_KDA_SUB] = zeros
+        scr[i, _KDA_SUB:] = x
+
+
+def _pairs_apart(scr, k, cum, d, sub_row):
+    """The pairs (i, i - d) of a chunk inside one sub-block: (k[i - d],
+    exp(G[i] - G[i - d])) on row i, the decay 0 where i - d lies in
+    another sub-block (a mask with ``-inf``: no exponent is positive,
+    and none is clipped). The rows ``d`` up come from ``_stage_rows``'
+    scratch."""
+    if d == 0:
+        return k, None
+    chunk = k.shape[0]
+    apart = cum - scr[1, pl.ds(_KDA_SUB - d, chunk)]
+    return scr[0, pl.ds(_KDA_SUB - d, chunk)], jnp.exp(
+        jnp.where(sub_row >= d, apart, -jnp.inf))
+
+
+def _earlier_decay(cum, block, rows):
+    """What is left of a token's key at the first row of sub-block
+    ``block``: ``exp(G_first - G)`` for the tokens of the sub-blocks
+    before it, 0 from there on (those pairs are the diagonal's or
+    nobody's); (C, Dk) float32."""
+    first = cum[block * _KDA_SUB:block * _KDA_SUB + 1]
+    return jnp.exp(jnp.where(rows < block * _KDA_SUB, first - cum, -jnp.inf))
+
+
+def _sub_rows(block, *arrays):
+    """Sub-block ``block``'s rows of each (C, .) float32 array, stacked."""
+    return jnp.concatenate(
+        [x[block * _KDA_SUB:(block + 1) * _KDA_SUB] for x in arrays], axis=0)
+
+
+def _stacked_from(pieces, part, width):
+    """Part ``part`` of every sub-block's (2 sub, width) result, one
+    under the other from sub-block 1 on, zeros for sub-block 0."""
+    return jnp.concatenate(
+        [jnp.zeros((_KDA_SUB, width), jnp.float32)] + [
+            x[part * _KDA_SUB:(part + 1) * _KDA_SUB] for x in pieces], axis=0)
+
+
+def _decayed_products_rows(k, q, cum, dtype, indices, scr):
+    """``_decayed_products`` of one chunk and head in VMEM: k, q, cum (C,
+    Dk) float32 -> ``K K^T`` strictly below the diagonal and ``Q K^T``
+    on and below it, the decay of a pair inside the contraction, 0
+    elsewhere; (C, C) float32. Between sub-blocks: matmuls of operands
+    decayed to the sub-block's first row, rounded to ``dtype`` as
+    ``_matmul`` rounds them; on the diagonal the pairs ``d`` rows apart
+    are one elementwise pass over the chunk and one sum over the lanes
+    a product, float32. ``indices``: ``_chunk_indices``'."""
+    chunk = k.shape[0]
+    apart, sub_row, rows = indices
+    lanes = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    lead = jnp.exp(cum - _sub_firsts(cum))
+    k_lead, q_lead = k * lead, q * lead
+    below = [
+        _mxu(_sub_rows(block, k_lead, q_lead).astype(dtype),
+             (k * _earlier_decay(cum, block, rows)).astype(dtype), _NT)
+        for block in range(1, chunk // _KDA_SUB)]
+    kk = _stacked_from(below, 0, chunk)
+    qk = _stacked_from(below, 1, chunk)
+    _stage_rows(scr, k, cum)
+    for d in range(_KDA_SUB):
+        other, decay = _pairs_apart(scr, k, cum, d, sub_row)
+        if d:
+            other = other * decay
+            kk = jnp.where(apart == d, lanes(k * other), kk)
+        qk = jnp.where(apart == d, lanes(q * other), qk)
+    return kk, qk
+
+
+def _kda_prepare_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, e_ref,
+                            w_ref, ko_ref, qi_ref, p_ref, u_ref, *rest):
+    """A block of chunks of one key head and its value heads. A loop
+    over the chunks: ``G`` cumulated a channel, ``K K^T`` and ``Q K^T``
+    with the decay inside (``_decayed_products_rows``), ``A`` into the
+    scratch, ``P``, the decayed keys and queries and ``exp(G_last)`` to
+    their blocks, ``beta V`` (float32) and ``beta K e^G`` waiting in
+    ``u``'s and ``w``'s blocks; then the inverses ``_CHAINS`` lane rows
+    at a time and ``[U | W] = T [beta V | beta K e^G]``, as the scalar
+    rule's kernel ends."""
+    *t_ref, a_scr, rows_scr = rest
+    _, _, rep, chunks, chunk, dv = u_ref.shape
+    dtype = q_ref.dtype
+    f32 = jnp.float32
+    lower, strict, eye = _chunk_masks(chunk)
+    indices = _chunk_indices(chunk, q_ref.shape[-1])
+
+    def a_chunk(c, carry):
+        k, q = k_ref[0, 0, 0, c].astype(f32), q_ref[0, 0, 0, c].astype(f32)
+        for r in range(rep):
+            at = (0, 0, r, c)
+            cum = _cumulated(g_ref[at])
+            beta = _turned(beta_ref[0, 0, r, pl.ds(c, 1), :], eye)
+            kk, qk = _decayed_products_rows(
+                k, q, cum, dtype, indices, rows_scr)
+            a_scr[c * rep + r] = jnp.where(strict, kk * beta, 0.0)
+            p_ref[at] = jnp.where(lower, qk, 0.0).astype(dtype)
+            into, last = jnp.exp(cum), cum[chunk - 1:]
+            ko_ref[at] = (jnp.exp(last - cum) * k).astype(dtype)
+            qi_ref[at] = (into * q).astype(dtype)
+            e_ref[at] = jnp.exp(last)
+            u_ref[at] = beta * v_ref[at].astype(f32)
+            w_ref[at] = ((beta * into) * k).astype(dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, a_chunk, 0)
+    heads = [(0, 0, r, c) for c in range(chunks) for r in range(rep)]
+    rows = _by_chains(
+        functools.partial(_inverse_rows, size=chunk),
+        _paired([a_scr[m] for m in range(len(heads))], chunk))
+    if t_ref:
+        for i, row in enumerate(rows):
+            t_ref[0][0, 0, 0, i] = row
+    for at, t in zip(heads, _unpaired(rows, chunk, len(heads))):
+        both = _mxu(t.astype(dtype), jnp.concatenate(
+            [u_ref[at].astype(dtype), w_ref[at]], axis=1))
+        u_ref[at] = both[:, :dv]
+        w_ref[at] = both[:, dv:].astype(dtype)
+
+
+def _turned_pair(a, b):
+    """``[a^T | b^T]`` of two (C, C) float32 matrices, (C, 2 C): one
+    square transpose of whole 128-lane rows a pair of 64 (two of 128)."""
+    chunk = a.shape[0]
+    if chunk == _LANES:
+        return jnp.concatenate([a.T, b.T], axis=1)
+    both = jnp.concatenate([a, b], axis=1)  # (C, 2 C = 128)
+    both = jnp.concatenate(
+        [both, jnp.zeros((_LANES - chunk, _LANES), both.dtype)], axis=0).T
+    return jnp.concatenate([both[:chunk, :chunk], both[chunk:, :chunk]],
+                           axis=1)
+
+
+def _kda_prepare_bwd_kernel(q_ref, k_ref, v_ref, g_ref, de_ref, dw_ref,
+                            dko_ref, dqi_ref, dp_ref, du_ref, beta_ref,
+                            t_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                            cum_scr, dt_scr, dxy_scr, rows_scr):
+    """The VJP of ``_kda_prepare_fwd_kernel`` from q, k, v, g, beta,
+    ``T`` and the six cotangents, every decay made again in VMEM and
+    none kept. A head at a time ``dT = [dU | dW] [beta V | beta K
+    e^G]^T`` and ``[dX | dY] = T^T [dU | dW]`` into the scratch; ``dA =
+    -T^T dT T^T`` by chains (``_inverse_grad_rows``); then a loop over
+    the chunks: the VJP of the products with the decay inside, pair by
+    pair what ``_diagonal_bwd`` sums (``d cum = x dx - y dy``), the
+    matmuls between sub-blocks transposed, ``dg`` the reverse cumulated
+    sum of ``dG`` a channel, ``dbeta`` a row; ``dq`` and ``dk`` summed
+    over the key head's value heads here. Cotangents are matmul
+    operands in the compute dtype, every sum float32."""
+    _, _, rep, chunks, chunk, dv = v_ref.shape
+    dk = k_ref.shape[-1]
+    dtype = q_ref.dtype
+    f32 = jnp.float32
+    lower, strict, eye = _chunk_masks(chunk)
+    heads = [(0, 0, r, c) for c in range(chunks) for r in range(rep)]
+    ts = [t_ref[0, 0, 0, i] for i in range(t_ref.shape[3])]
+    for m, (at, t) in enumerate(zip(heads, _unpaired(ts, chunk, len(heads)))):
+        r, c = at[2:]
+        cum = _cumulated(g_ref[at])
+        beta = _turned(beta_ref[0, 0, r, pl.ds(c, 1), :], eye)
+        right = jnp.concatenate(
+            [(beta * v_ref[at].astype(f32)).astype(dtype),
+             ((beta * jnp.exp(cum)) * k_ref[0, 0, 0, c].astype(f32)
+              ).astype(dtype)], axis=1)
+        left = jnp.concatenate([du_ref[at], dw_ref[at]], axis=1)
+        cum_scr[m] = cum
+        dt_scr[m] = _mxu(left, right, _NT)
+        dxy_scr[m] = _mxu(t.astype(dtype), left, _TN)
+    d_as = _by_chains(
+        functools.partial(_inverse_grad_rows, size=chunk), ts,
+        _paired([dt_scr[m] for m in range(len(heads))], chunk))
+    for m, d_a in enumerate([d for row in d_as for d in row][:len(heads)]):
+        dt_scr[m] = d_a
+    blocks = chunk // _KDA_SUB
+    apart, sub_row, rows = _chunk_indices(chunk, dk)
+    # the sub-block of a row of [beta K e; Q e], one under the other
+    stacked = jnp.concatenate([rows, rows], axis=0) // _KDA_SUB
+    lanes = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    over_rows = lambda x: jnp.sum(x, axis=0, keepdims=True)
+    back = lambda x, d: pltpu.roll(x, chunk - d, 0)  # row i - d gets row i's
+
+    def a_chunk(c, carry):
+        k, q = k_ref[0, 0, 0, c].astype(f32), q_ref[0, 0, 0, c].astype(f32)
+        d_k = d_q = jnp.zeros((chunk, dk), f32)
+        for r in range(rep):
+            at, m = (0, 0, r, c), c * rep + r
+            cum = cum_scr[m]
+            beta = _turned(beta_ref[0, 0, r, pl.ds(c, 1), :], eye)
+            d_a = jnp.where(strict, dt_scr[m], 0.0)  # of K K^T, less beta
+            d_p = jnp.where(lower, dp_ref[at].astype(f32), 0.0)
+            into, last = jnp.exp(cum), cum[chunk - 1:]
+            onto = jnp.exp(last - cum)
+            lead = jnp.exp(cum - _sub_firsts(cum))
+            k_lead, q_lead = k * lead, q * lead
+            # between sub-blocks: out = [K e; Q e][block] cols^T
+            turned = _turned_pair(d_a, d_p).astype(dtype)  # (C, 2 C)
+            sides = jnp.concatenate([beta * k_lead, q_lead], axis=0)
+            d_rows, d_cum, from_cols, firsts = [], 0.0, 0.0, []
+            for block in range(1, blocks):
+                decay = _earlier_decay(cum, block, rows)
+                cols = k * decay
+                d_rows.append(_mxu(
+                    _sub_rows(block, d_a, d_p).astype(dtype),
+                    cols.astype(dtype)))
+                d_cols = _mxu(turned, jnp.where(
+                    stacked == block, sides, 0.0).astype(dtype))
+                z = cols * d_cols
+                from_cols = from_cols + decay * d_cols
+                d_cum = d_cum - z
+                firsts.append(over_rows(z))
+            d_rows_k = _stacked_from(d_rows, 0, dk)
+            d_rows_q = _stacked_from(d_rows, 1, dk)
+            grow_k, grow_q = lead * d_rows_k, lead * d_rows_q
+            z = k_lead * (beta * d_rows_k) + q_lead * d_rows_q
+            # the sub-block's first row: what its rows' leads and the
+            # earlier keys' decays hand it
+            at_first = jnp.concatenate(
+                [jnp.zeros((_KDA_SUB, dk), f32)] + [
+                    jnp.broadcast_to(x, (_KDA_SUB, dk)) for x in firsts],
+                axis=0) - _sub_firsts(z, over_rows)
+            d_cum = d_cum + z + jnp.where(sub_row == 0, at_first, 0.0)
+            # on the diagonal: the pairs d rows apart
+            _stage_rows(rows_scr, k, cum)
+            for d in range(_KDA_SUB):
+                other, decay = _pairs_apart(rows_scr, k, cum, d, sub_row)
+                on = apart == d
+                d_qk = lanes(jnp.where(on, d_p, 0.0))
+                if d == 0:
+                    grow_q = grow_q + d_qk * other
+                    from_cols = from_cols + d_qk * q
+                    continue
+                d_kk = lanes(jnp.where(on, d_a, 0.0))
+                decayed = other * decay
+                grow_k = grow_k + d_kk * decayed
+                grow_q = grow_q + d_qk * decayed
+                pulled = decay * ((beta * d_kk) * k + d_qk * q)
+                from_cols = from_cols + back(pulled, d)
+                z = pulled * other
+                d_cum = d_cum + z - back(z, d)
+            both = dxy_scr[m]
+            d_x, d_y = both[:, :dv], both[:, dv:]
+            by_y = d_y * k
+            v = v_ref[at].astype(f32)
+            d_beta = lanes(k * grow_k + into * by_y) + lanes(d_x * v)
+            d_ko, d_qi = dko_ref[at].astype(f32), dqi_ref[at].astype(f32)
+            left_over = d_ko * k * onto
+            d_last = over_rows(left_over) + de_ref[at] * jnp.exp(last)
+            d_cum = (
+                d_cum + into * (beta * by_y + d_qi * q) - left_over
+                + jnp.where(rows == chunk - 1, d_last, 0.0))
+            # g reaches G_i for every i at or after its token
+            dg_ref[at] = _cumulated(d_cum, reverse=True)
+            dbeta_ref[0, 0, r, pl.ds(c, 1), :] = _turned(d_beta, eye)
+            dv_ref[at] = (beta * d_x).astype(dv_ref.dtype)
+            d_k = (d_k + beta * grow_k + from_cols + (beta * into) * d_y
+                   + onto * d_ko)
+            d_q = d_q + grow_q + into * d_qi
+        dk_ref[0, 0, 0, c] = d_k.astype(dk_ref.dtype)
+        dq_ref[0, 0, 0, c] = d_q.astype(dq_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, chunks, a_chunk, 0)
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("residuals", "interpret"))
+def kda_prepare_fwd(q, k, v, g, beta, residuals=False, interpret=False):
+    """``gdn_prepare_fwd`` for a decay a channel, g (B, Hk, R, N, C, Dk)
+    float32: a segment's operands of ``kda_scan_fwd`` where it reads
+    them -> (decay (B, Hk, R, N, 1, Dk) float32, a channel's exp(G_last)
+    on its own lane; W, the decayed keys, Q~, P in the compute dtype; U
+    float32) and, with ``residuals``, ``T`` as ``gdn_prepare_fwd`` leaves
+    it."""
+    rep, chunks, chunk, dv = v.shape[2:]
+    dk, dtype = q.shape[5], q.dtype
+    step = kda_prepare_block(rep, chunks, chunk, dk, dv, dtype.itemsize)
+    return _prepare_call(
+        _kda_prepare_fwd_kernel, "kda_prepare_fwd", [q, k], [v, g], [beta],
+        [], 0, [(1, dk, jnp.float32)] + [(chunk, dk, dtype)] * 3
+        + [(chunk, chunk, dtype), (chunk, dv, jnp.float32)], 0,
+        int(residuals), interpret, step,
+        [pltpu.VMEM((rep * step, chunk, chunk), jnp.float32),
+         pltpu.VMEM((2, _KDA_SUB + chunk, dk), jnp.float32)])
+
+
+@functools.partial(  # edlint: disable=obs-bare-jit (as the inverse's)
+    jax.jit, static_argnames=("interpret",))
+def kda_prepare_bwd(q, k, v, g, beta, inverse, d_decay, dw, d_k, dq, dp, du,
+                    interpret=False):
+    """The VJP of ``kda_prepare_fwd`` from its operands, ``T`` and the
+    six cotangents (``du`` in the compute dtype, as ``kda_scan_bwd``
+    hands it on; ``d_decay`` (B, Hk, R, N, 1, Dk) float32, whole a
+    channel): -> (dq, dk (B, Hk, 1, N, C, Dk), dv in their operands'
+    dtype, dg (B, Hk, R, N, C, Dk) and dbeta float32)."""
+    rep, chunks, chunk, dv = v.shape[2:]
+    dk = q.shape[5]
+    step = kda_prepare_block(rep, chunks, chunk, dk, dv, q.dtype.itemsize)
+    heads = rep * step
+    return _prepare_call(
+        _kda_prepare_bwd_kernel, "kda_prepare_bwd", [q, k],
+        [v, g, d_decay, dw, d_k, dq, dp, du], [beta], [inverse], 2,
+        [(chunk, dv, v.dtype), (chunk, dk, jnp.float32)], 1, 0, interpret,
+        step, [pltpu.VMEM((heads, chunk, dk), jnp.float32),
+               pltpu.VMEM((heads, chunk, chunk), jnp.float32),
+               pltpu.VMEM((heads, chunk, dv + dk), jnp.float32),
+               pltpu.VMEM((2, _KDA_SUB + chunk, dk), jnp.float32)])
+
+
+def _chunks_under_vjp(prepare_fwd, prepare_bwd, by_channel):
+    """The rule over a segment's chunks by four kernels under ONE VJP,
+    nothing of XLA's between them, shapes as ``_chunks``: (state, q, k,
+    v, g, beta) -> (the leaving state, o). ``by_channel``: the
+    ``kda_*`` kernels, ``state`` and the leaving state TRANSPOSED, (B,
+    Hk, R, Dv, Dk), as the scan's kernels carry them."""
+    @jax.custom_vjp
+    def _chunks_pallas(state, q, k, v, g, beta):
+        return gdn_scan_fwd(
+            state, *prepare_fwd(q, k, v, g, beta), by_channel=by_channel)
+
+    def _chunks_vjp_fwd(state, q, k, v, g, beta):
+        *operands, u, inverse = prepare_fwd(q, k, v, g, beta, residuals=True)
+        leaving, o, new_v, states = gdn_scan_fwd(
+            state, *operands, u, residuals=True, by_channel=by_channel)
+        return (leaving, o), (
+            q, k, v, g, beta, inverse, *operands, states, new_v)
+
+    def _chunks_vjp_bwd(residuals, cotangents):
+        *inputs, inverse = residuals[:6]
+        d_state, d_o = cotangents
+        d_state, *grads = gdn_scan_bwd(
+            *residuals[6:], d_o, d_state, by_channel=by_channel)
+        # du stays in the compute dtype between the two kernels
+        return (d_state, *prepare_bwd(*inputs, inverse, *grads))
+
+    _chunks_pallas.defvjp(_chunks_vjp_fwd, _chunks_vjp_bwd)
+    return _chunks_pallas
+
+
+# the kernels by their names in this module at the call, as a test that
+# interprets them patches them
+_chunks_pallas = _chunks_under_vjp(
+    lambda *a, **kw: gdn_prepare_fwd(*a, **kw),
+    lambda *a: gdn_prepare_bwd(*a), False)
+_chunks_pallas_by_channel = _chunks_under_vjp(
+    lambda *a, **kw: kda_prepare_fwd(*a, **kw),
+    lambda *a: kda_prepare_bwd(*a), True)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1187,12 +1652,12 @@ def _log_once(hk, hv, dk, chunk, scan, prep, tokens, decay):
     chunk, ``pallas`` (the ``gdn_scan_*`` kernels; ``kda_scan_*``, the
     same with the state transposed, under a decay a channel) or ``xla``
     (a ``lax.scan``); ``prep``: what makes the chunks' operands,
-    ``pallas`` (the ``gdn_prepare_*`` kernels, the inverses inside them)
-    or ``xla`` (``_chunk_operands`` around ``unit_lower_inverse``);
-    ``impl``: what runs the chunks' inverses, which is what ``prep``
-    says; ``decay``: ``scalar`` (a number a head and token) or
-    ``vector`` (a number a channel of the key, whose operands are XLA's
-    lines everywhere)."""
+    ``pallas`` (the ``gdn_prepare_*`` kernels, under a decay a channel
+    the ``kda_prepare_*`` ones, the inverses inside them) or ``xla``
+    (``_chunk_operands`` / ``_chunk_operands_by_channel`` around
+    ``unit_lower_inverse``); ``impl``: what runs the chunks' inverses,
+    which is what ``prep`` says; ``decay``: ``scalar`` (a number a head
+    and token) or ``vector`` (a number a channel of the key)."""
     logger.info(
         "linear attention heads k=%d v=%d dim=%d chunk=%d impl=%s "
         "scan=%s prep=%s (tokens=%d) decay=%s", hk, hv, dk, chunk, prep,
@@ -1454,8 +1919,13 @@ def _chunks(state, q, k, v, g, beta, decay_dtype, scan, prep):
     C, Dv)). ``scan``: what carries the state; ``prep``: what makes
     the chunks' operands (``pallas``: the four kernels under one VJP,
     nothing of XLA's between them)."""
-    if prep == "pallas":
+    if prep == "pallas" and g.ndim == beta.ndim:
         return _chunks_pallas(state, q, k, v, g, beta)
+    if prep == "pallas":
+        # the scan's kernels carry a decay a channel's state transposed
+        swap = lambda x: jnp.swapaxes(x, -1, -2)
+        leaving, o = _chunks_pallas_by_channel(swap(state), q, k, v, g, beta)
+        return swap(leaving), o
     if g.ndim == beta.ndim:
         operands, kernels = _chunk_operands, _scan_pallas
     else:
@@ -1482,10 +1952,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk=DEFAULT_CHUNK,
     beta: (B, Hv, S) float32; g: (B, Hv, S) float32, one log decay a
     head and token (Gated DeltaNet), or (B, Hv, S, Dk), one a channel
     of the key (Kimi Delta Attention: ``S <- Diag(exp(g_t)) S``). The
-    operand's rank decides, no flag: a decay a channel makes its
-    operands by ``_decayed_products`` on every backend and carries its
-    state where ``scan_impl`` says, as a decay a token does. Returns o
-    (B, Hv, S, Dv) in ``v``'s dtype.
+    operand's rank decides, no flag: each rank has its operands'
+    kernels where ``prepare_impl`` says (a decay a channel
+    ``_decayed_products`` on XLA's lines elsewhere) and carries its
+    state where ``scan_impl`` says. Returns o (B, Hv, S, Dv) in ``v``'s
+    dtype.
 
     A sequence longer than ``segment`` chunks runs a segment at a time,
     each under ``jax.checkpoint``, the state carried between them: the

@@ -3,7 +3,8 @@
 ``test_gated_delta_scan.py``: the scan's kernels;
 ``test_gated_delta_scan_rule.py``: the rule through them;
 ``test_gated_delta_operands.py``, ``test_gated_delta_operands_vjp.py``:
-the operands' kernels): seeded
+the operands' kernels; ``test_kda_rule.py``, ``test_kda_operands.py``:
+a decay a channel): seeded
 inputs, the rule's value and gradients, and how a test runs what a TPU
 backend would choose under the interpreter."""
 
@@ -59,7 +60,7 @@ def _force_pallas(monkeypatch):
     """What a TPU backend would choose, run by the interpreter."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     for name in ("gdn_scan_fwd", "gdn_scan_bwd", "gdn_prepare_fwd",
-                 "gdn_prepare_bwd"):
+                 "gdn_prepare_bwd", "kda_prepare_fwd", "kda_prepare_bwd"):
         monkeypatch.setattr(gated_delta, name, functools.partial(
             getattr(gated_delta, name), interpret=True))
 

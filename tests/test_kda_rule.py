@@ -4,12 +4,13 @@ recurrence in float64 on the CPU: forward, the gradients of q, k, v, g,
 beta and of the entering state; decays of -50 a token on some channels
 and 0 on others, where a factorised ``(K e^G)(K e^-G)^T`` overflows; a
 vector that is constant over the channels against the scalar rule;
-chunks of 64 and 128 over several segments; the choosers, which say
-``xla`` for a vector decay's OPERANDS on every backend and ``pallas``
-for its chunk-to-chunk recurrence where the scalar rule's is; and the
-scan's kernels carrying a decay a channel (``kda_scan_fwd`` /
-``kda_scan_bwd``, the state transposed) against the ``lax.scan``, in
-interpret mode."""
+chunks of 64 and 128 over several segments; the choosers, which read
+the decay's rank and say ``pallas`` for a vector decay's operands and
+for its chunk-to-chunk recurrence where they say so for the scalar
+rule's (the operands' kernels themselves are
+``test_kda_operands.py``'s); and the scan's kernels carrying a decay a
+channel (``kda_scan_fwd`` / ``kda_scan_bwd``, the state transposed)
+against the ``lax.scan``, in interpret mode."""
 
 import jax
 import jax.numpy as jnp
@@ -137,13 +138,13 @@ def test_the_diagonal_s_decays_go_by_groups_of_chunks(x64, monkeypatch):
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
-def test_a_vector_decay_s_operands_are_xla_s_lines_on_every_backend(
+def test_a_vector_decay_s_operands_are_chosen_by_its_rank(
         monkeypatch, caplog, backend):
     """By the operand's rank and not by a flag: on a (described) TPU
-    the scalar rule at the cell's shapes is the four kernels; the
-    vector rule's operands are XLA's lines (the ``gdn_prepare_*``
-    kernels compute the scalar rule's) and its chunk-to-chunk
-    recurrence the scan's kernels, and the rule's line says so."""
+    the scalar rule at the cell's shapes is the four ``gdn_*`` kernels
+    and the vector rule the four ``kda_*`` ones (``kda_prepare_*`` make
+    its operands, by their own account of a block), on the CPU both are
+    XLA's lines, and the rule's line says so."""
     import logging
 
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
@@ -154,7 +155,12 @@ def test_a_vector_decay_s_operands_are_xla_s_lines_on_every_backend(
     assert gated_delta.prepare_impl(
         *shape, 1, 128, decay_rank=gated_delta.SCALAR_DECAY) == pallas
     assert gated_delta.prepare_impl(
-        *shape, 1, 128, decay_rank=gated_delta.VECTOR_DECAY) == "xla"
+        *shape, 1, 128, decay_rank=gated_delta.VECTOR_DECAY) == pallas
+    # each rank by its own kernels' VMEM: 4 value heads a key head fit
+    # the scalar rule's blocks and not the vector rule's
+    assert gated_delta.prepare_impl(*shape, 4, 128) == pallas
+    assert gated_delta.prepare_impl(
+        *shape, 4, 128, decay_rank=gated_delta.VECTOR_DECAY) == "xla"
     # and the rule hands the operands' chooser its operand's rank
     seen = []
     prepare = gated_delta.prepare_impl
@@ -173,8 +179,8 @@ def test_a_vector_decay_s_operands_are_xla_s_lines_on_every_backend(
             jax.ShapeDtypeStruct((1, 2, 128), jnp.float32))
     assert out.shape == (1, 2, 128, 128) and out.dtype == jnp.bfloat16
     assert seen == [gated_delta.VECTOR_DECAY]
-    assert ("linear attention heads k=2 v=2 dim=128 chunk=64 impl=xla "
-            "scan=%s prep=xla (tokens=128) decay=vector" % pallas
+    assert ("linear attention heads k=2 v=2 dim=128 chunk=64 impl=%s "
+            "scan=%s prep=%s (tokens=128) decay=vector" % ((pallas,) * 3)
             ) in caplog.text
 
 
@@ -250,10 +256,11 @@ def test_the_scan_s_kernels_carry_a_decay_a_channel(monkeypatch, chunk, rep,
 
 
 def test_the_rule_by_the_scan_s_kernels_is_the_recurrence(monkeypatch):
-    """``gated_delta_rule`` with a decay a channel as a TPU runs it
-    (XLA's operands, the scan's kernels, interpreted) over two segments
-    of float32 operands at 128-wide heads, against the per-token loop:
-    the state crosses a segment's boundary turned and turned back."""
+    """``gated_delta_rule`` with a decay a channel where no block of
+    the operands' kernels fits (XLA's operands, the scan's kernels,
+    interpreted: the choice held to ``xla`` here) over two segments of
+    float32 operands at 128-wide heads, against the per-token loop: the
+    state crosses a segment's boundary turned and turned back."""
     keys = jax.random.split(jax.random.PRNGKey(7), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     shape = (1, 1, 256, 128)
@@ -264,10 +271,11 @@ def test_the_rule_by_the_scan_s_kernels_is_the_recurrence(monkeypatch):
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3]))
     want = gated_delta.gated_delta_recurrence(q, k, v, g, beta)
     _force_pallas(monkeypatch)
+    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
     gated_delta._log_once.cache_clear()
     rule = lambda *a: gated_delta.gated_delta_rule(*a, chunk=64, segment=2)
     text = str(jax.make_jaxpr(rule)(q, k, v, g, beta))
-    assert "kda_scan_fwd" in text and "gdn_prepare" not in text
+    assert "kda_scan_fwd" in text and "_prepare" not in text
     got = jax.jit(rule)(q, k, v, g, beta)
     np.testing.assert_allclose(
         got, want, rtol=0, atol=2e-5 * float(jnp.abs(want).max()))

@@ -1,9 +1,9 @@
 """The delta rule with a decay a channel (Kimi Delta Attention) and its
 mixer compiled for a v5e that is described, not attached (the TPU
 compiler is installed here): what a CPU run cannot see. The rule's
-gradient at the cell's shape, whose operands are XLA's lines on a TPU
-too and whose chunk-to-chunk recurrence is the scan's kernel pair with
-the state transposed, inside the memory the cell's step leaves it; and
+gradient at the cell's shape, four Mosaic kernels a segment (the
+operands' pair ``kda_prepare_*``, ISSUE 59, and the scan's pair with
+the state transposed), inside the memory the cell's step leaves it; and
 the mixer at the cell's widths, whose convolution is the kernel pair
 under the mixer's own scope.
 
@@ -20,18 +20,22 @@ from tests.kernel_common import chip, topology  # noqa: F401 (fixtures)
 def test_the_vector_rule_compiles_at_the_cell_s_shape(chip, monkeypatch):
     """``gated_delta_rule``'s gradient at 32,768 tokens, 32 heads of
     128, chunk 64, segments of 64 chunks, a decay a channel, with the
-    backend a TPU: the operands' chooser says ``xla`` by the operand's
-    rank (the ``gdn_prepare_*`` kernels compute the scalar rule's), the
-    recurrence is ``kda_scan_fwd`` / ``kda_scan_bwd`` (Mosaic takes the
-    transposed state's products), the segments and the diagonals'
-    groups are loops, and the temporaries stay under 4.5 GiB as the
-    compiler counts them (4.44 GB; it counts a loop's body more than
-    once: the cell's whole step, 9.6 GB of state beside them, compiles
-    at a peak of 15.02 GB where the ``lax.scan`` took 15.26)."""
+    backend a TPU: the operands' chooser says ``pallas`` by the
+    operand's rank and the kernels' own account of a block, the program
+    holds exactly ``kda_prepare_fwd``, ``kda_prepare_bwd``,
+    ``kda_scan_fwd`` and ``kda_scan_bwd`` (Mosaic takes both new bodies:
+    the rolls over the sublanes, the sums over the lanes, the loop over
+    a block's chunks), the segments are loops, and the temporaries stay
+    under 2.6 GiB as the compiler counts them (2.43 GiB read; 4.44 GB
+    with the operands XLA's lines: no (B, H, N, 64, 64) float32 array
+    and no group of decays is left in HBM)."""
     from elasticdl_tpu.ops import gated_delta
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert gated_delta.scan_impl(jnp.bfloat16, 64, 128, 128) == "pallas"
+    assert gated_delta.prepare_impl(
+        jnp.bfloat16, 64, 128, 128, 1, 64,
+        decay_rank=gated_delta.VECTOR_DECAY) == "pallas"
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(
         shape, dtype, sharding=chip)
     wide = (1, 32, 32768, 128)
@@ -43,17 +47,17 @@ def test_the_vector_rule_compiles_at_the_cell_s_shape(chip, monkeypatch):
     ).lower(*args).compile()
     hlo = compiled.as_text()
     assert set(device_obs.pallas_kernels(hlo)) == {
-        "kda_scan_fwd", "kda_scan_bwd"}
-    assert hlo.count(" while(") >= 3
-    assert compiled.memory_analysis().temp_size_in_bytes < 4.5 * 2**30
+        "kda_prepare_fwd", "kda_prepare_bwd", "kda_scan_fwd", "kda_scan_bwd"}
+    assert hlo.count(" while(") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6 * 2**30
 
 
 def test_the_mixer_s_convolution_is_the_kernel_pair(chip, monkeypatch):
     """``KimiDeltaAttention`` at the cell's widths over one segment of
     tokens: ``conv_impl`` says ``pallas`` (32 / 32 heads of 128 are
     whole 128-lane rows), both its kernels sit under ``kda/conv``, the
-    scan's pair under ``kda/scan`` and none under the Gated DeltaNet's
-    scope."""
+    rule's four (the operands' pair and the scan's) under ``kda/scan``
+    and none under the Gated DeltaNet's scope: six kernels."""
     from elasticdl_tpu.models.transformer import KdaDims, KimiDeltaAttention
     from tests.kernel_common import mosaic_kernels
 
@@ -73,5 +77,9 @@ def test_the_mixer_s_convolution_is_the_kernel_pair(chip, monkeypatch):
     assert sum("kda/conv" in k for k in kernels) == 2
     assert all("kda/conv" in k or "kda/scan" in k for k in kernels)
     assert not any("gdn/" in k for k in kernels)
-    assert set(device_obs.pallas_kernels(hlo)) == {
-        "qkv_conv_fwd", "qkv_conv_bwd", "kda_scan_fwd", "kda_scan_bwd"}
+    names = device_obs.pallas_kernels(hlo)
+    assert set(names) == {
+        "qkv_conv_fwd", "qkv_conv_bwd", "kda_prepare_fwd", "kda_prepare_bwd",
+        "kda_scan_fwd", "kda_scan_bwd"}
+    under_scan = [k for k in kernels if "kda/scan" in k]
+    assert len(under_scan) == len(kernels) - 2 >= 4
