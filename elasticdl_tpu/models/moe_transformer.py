@@ -40,6 +40,7 @@ from elasticdl_tpu.models.transformer import (
     KdaDims,
     LatentDims,
     LoopedDims,
+    Mamba2Dims,
     MixerKind,
     ShortConvDims,
     YarnScaling,
@@ -683,6 +684,16 @@ class MoeTransformerLM(nn.Module):
     chip holds 8; a training call also returns ``kda`` (the
     ``kda_gates`` event's facts, one entry a KDA layer).
 
+    granite-4.0-h-micro's is every layer dense again, ``layer_kinds``
+    the published list (``mamba`` x 5, ``full``, ``mamba`` x 4: nine
+    Mamba-2 state-space mixers, ``mamba``'s sizes, to one softmax layer)
+    with ``rotary=False`` and ``attention_scale`` for the softmax layer
+    (nothing rotates: the order comes from the recurrent layers),
+    ``embedding_scale``, ``residual_scale`` and ``logits_divisor`` (the
+    family's four muP multipliers) and ``tie_embeddings``; a training
+    call also returns ``mamba`` (the ``mamba_gates`` event's facts, one
+    entry a Mamba layer).
+
     Ouro-2.6B's is every layer dense (``first_k_dense=num_layers``,
     ``dense_act="swiglu"``), ``sandwich`` (a norm on each sublayer's
     output too) and ``looped`` (a ``LoopedDims``): the blocks run
@@ -758,6 +769,7 @@ class MoeTransformerLM(nn.Module):
     # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
     # of ``linear``'s sizes), "kda" (a Kimi Delta Attention of
     # ``kda``'s), "conv" (a gated short convolution of ``conv``'s),
+    # "mamba" (a Mamba-2 state-space mixer of ``mamba``'s),
     # "full" (softmax or, with ``latent``, latent attention over the
     # causal prefix) or "window" (softmax attention over a band,
     # ``ops/flash_attention.py:Band``). None: every layer "full". The
@@ -773,6 +785,7 @@ class MoeTransformerLM(nn.Module):
     linear: Optional[GatedDeltaDims] = None
     conv: Optional[ShortConvDims] = None
     kda: Optional[KdaDims] = None
+    mamba: Optional[Mamba2Dims] = None
     head_dim: Optional[int] = None
     num_kv_heads: Optional[int] = None
     head_norm: Optional[str] = None
@@ -817,13 +830,25 @@ class MoeTransformerLM(nn.Module):
     # walked once, the tree and the program every model always had
     sandwich: bool = False
     looped: Optional[LoopedDims] = None
+    # granite's four multipliers, each None for the program every older
+    # model has: the embedding times ``embedding_scale``, both of a
+    # block's branches times ``residual_scale`` before they are added
+    # (``Block.residual_scale``), the softmax of ``attention_scale x q
+    # k^T`` in the place of ``head width ** -0.5``, the logits over
+    # ``logits_divisor``; and ``rotary`` False: the softmax layers
+    # rotate nothing (``Attention.rotary``)
+    embedding_scale: Optional[float] = None
+    residual_scale: Optional[float] = None
+    attention_scale: Optional[float] = None
+    logits_divisor: Optional[float] = None
+    rotary: bool = True
 
     def _mixer(self, kind, layout=None):
         """A layer's mixer by its KIND, stated once: what a block hands
         to ``make_attention`` unopened. The kind decides the mixer (a
         Gated DeltaNet of ``linear``'s sizes, a short convolution of
-        ``conv``'s, a Kimi Delta Attention of ``kda``'s, else softmax
-        or latent attention) and, of a softmax
+        ``conv``'s, a Kimi Delta Attention of ``kda``'s, a Mamba-2 mixer
+        of ``mamba``'s, else softmax or latent attention) and, of a softmax
         one, the query heads, the rotary base, the lanes that rotate,
         YaRN, the mask's layout and the scope its operations lie under;
         the rest is the model's, for every kind alike. A kind without
@@ -838,6 +863,12 @@ class MoeTransformerLM(nn.Module):
         return dict(
             # (a model without the kind hands on what it always did)
             **({"kda": self.kda} if kind == "kda" else {}),
+            **({"mamba": self.mamba} if kind == "mamba" else {}),
+            **({"rotary": False} if not self.rotary
+               and kind in ("full", "window") else {}),
+            **({"sm_scale": self.attention_scale}
+               if self.attention_scale is not None
+               and kind in ("full", "window") else {}),
             num_heads=own.num_heads,
             latent=self.latent,
             linear=self.linear if kind == "linear" else None,
@@ -860,19 +891,24 @@ class MoeTransformerLM(nn.Module):
     def _check_kinds(self, kinds, denoise):
         """Refuses, by name, a pattern the blocks cannot run."""
         by_kind = dict(self.kind_fields or {})
-        sized = {"linear": self.linear, "conv": self.conv, "kda": self.kda}
+        sized = {"linear": self.linear, "conv": self.conv, "kda": self.kda,
+                 "mamba": self.mamba}
         if set(kinds) - {"full", "window", *sized} or any(
                 sized[kind] is None for kind in set(kinds) & set(sized)):
             raise ValueError(
                 "layer_kinds=%r: each is 'full', 'window', 'linear' (a "
-                "Gated DeltaNet), 'conv' (a gated short convolution) or "
-                "'kda' (a Kimi Delta Attention), and 'linear', 'conv' and "
-                "'kda' need their mixer's sizes (linear, conv, kda)"
-                % (self.layer_kinds,))
+                "Gated DeltaNet), 'conv' (a gated short convolution), "
+                "'kda' (a Kimi Delta Attention) or 'mamba' (a Mamba-2 "
+                "state-space mixer), and 'linear', 'conv', 'kda' and "
+                "'mamba' need their mixer's sizes (linear, conv, kda, "
+                "mamba)" % (self.layer_kinds,))
         if "conv" in kinds:
             self._check_conv(denoise, by_kind)
         if "kda" in kinds:
             self._check_kda(kinds, denoise, by_kind)
+        if "mamba" in kinds:
+            self._check_mamba(kinds, denoise)
+        self._check_multipliers(denoise)
         if self.indexer is not None:
             self._check_indexer(kinds, denoise, by_kind)
         if set(by_kind) - {"full", "window"}:
@@ -903,17 +939,33 @@ class MoeTransformerLM(nn.Module):
                 for kind in sorted(set(kinds))))
 
     def mixer_kinds(self, seq=None, dtype=None):
-        """What a model with gated short convolutions or Kimi Delta
-        Attention layers is made of, for the journal's ``mixer_kinds``
-        event (the worker emits it once, when the state is made:
-        ``worker/trainer.py:ensure_state``); None for a model without
-        either kind. Read from the fields alone; for the convolutions,
+        """What a model with gated short convolutions, Kimi Delta
+        Attention or Mamba-2 layers is made of, for the journal's
+        ``mixer_kinds`` event (the worker emits it once, when the state
+        is made: ``worker/trainer.py:ensure_state``); None for a model
+        without any of those kinds. Read from the fields alone; for the
+        convolutions,
         given a batch's length ``seq`` and the step's compute ``dtype``
         (None: float32), also what runs them there
         (``ops/short_conv.py:conv_choice``: ``conv_impl``,
         ``conv_tile``)."""
         kinds = tuple(self.layer_kinds or ("full",))
         built = [kinds[i % len(kinds)] for i in range(self.num_layers)]
+        if self.mamba is not None:
+            return {
+                "mamba_layers": built.count("mamba"),
+                "full_layers": built.count("full"),
+                "dense_layers": self.first_k_dense,
+                "mamba_heads": self.mamba.num_heads,
+                "mamba_head_dim": self.mamba.head_dim,
+                "mamba_state": self.mamba.state,
+                "mamba_groups": self.mamba.groups,
+                "mamba_taps": self.mamba.conv_kernel,
+                "mamba_chunk": self.mamba.chunk,
+                "head_dim": self.head_dim or self.embed_dim // self.num_heads,
+                "kv_heads": self.num_kv_heads or self.num_heads,
+                "rotary": self.rotary,
+            }
         if self.kda is not None:
             return {
                 "kda_layers": built.count("kda"),
@@ -953,7 +1005,7 @@ class MoeTransformerLM(nn.Module):
                 ("objective=\"block_diffusion\" (its mask is a layout of "
                  "two copies; the selection is over one causal prefix)",
                  denoise),
-                ("a 'window', 'linear', 'conv' or 'kda' layer "
+                ("a 'window', 'linear', 'conv', 'kda' or 'mamba' layer "
                  "(layer_kinds=%r: the "
                  "indexer picks keys for full softmax attention)"
                  % (self.layer_kinds,), set(kinds) != {"full"}),
@@ -998,9 +1050,9 @@ class MoeTransformerLM(nn.Module):
                 ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
                 ("objective=\"block_diffusion\"", denoise),
                 ("a learned indexer (indexer)", self.indexer is not None),
-                ("a 'linear', 'conv' or 'kda' mixer (layer_kinds=%r)"
-                 % (self.layer_kinds,),
-                 bool({"linear", "conv", "kda"} & set(kinds))),
+                ("a 'linear', 'conv', 'mamba' or 'kda' mixer "
+                 "(layer_kinds=%r)" % (self.layer_kinds,),
+                 bool({"linear", "conv", "kda", "mamba"} & set(kinds))),
                 ("a head tied to the embedding (tie_embeddings)",
                  self.tie_embeddings)):
             if asked:
@@ -1081,6 +1133,7 @@ class MoeTransformerLM(nn.Module):
                 ("latent attention (latent)", self.latent is not None),
                 ("a Gated DeltaNet mixer (linear)", self.linear is not None),
                 ("a Kimi Delta Attention mixer (kda)", self.kda is not None),
+                ("a Mamba-2 mixer (mamba)", self.mamba is not None),
                 ("hyper-connections (hc: the mixer reads one stream)",
                  self.hc is not None),
                 ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
@@ -1107,8 +1160,8 @@ class MoeTransformerLM(nn.Module):
                  "across their seam)", denoise),
                 ("kind_fields (a band, heads or a rotary table by layer "
                  "kind)", bool(by_kind)),
-                ("a 'linear', 'conv' or 'window' layer (layer_kinds=%r)"
-                 % (self.layer_kinds,),
+                ("a 'linear', 'conv', 'mamba' or 'window' layer "
+                 "(layer_kinds=%r)" % (self.layer_kinds,),
                  bool(set(kinds) - {"kda", "full"})),
                 ("hyper-connections (hc: the mixer reads one stream)",
                  self.hc is not None),
@@ -1123,6 +1176,66 @@ class MoeTransformerLM(nn.Module):
                 raise ValueError(
                     "a 'kda' layer (Kimi Delta Attention) beside %s: not "
                     "built, so not run" % what)
+
+    def _check_mamba(self, kinds, denoise):
+        """A Mamba-2 layer runs beside causal softmax layers of one
+        kind, in dense and in expert blocks, under next-token
+        prediction, its sequence whole on a device and its experts (if
+        any) on this one. What it was not built beside is refused, each
+        by its name."""
+        ranks = {} if self.mesh is None else dict(self.mesh.shape)
+        for what, asked in (
+                ("objective=\"block_diffusion\" (its two copies of a "
+                 "sequence are one axis, and a recurrence would run "
+                 "across their seam)", denoise),
+                ("a 'linear', 'conv', 'kda' or 'window' layer "
+                 "(layer_kinds=%r)" % (self.layer_kinds,),
+                 bool(set(kinds) - {"mamba", "full"})),
+                ("latent attention (latent)", self.latent is not None),
+                ("hyper-connections (hc: the mixer reads one stream)",
+                 self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("a learned indexer (indexer)", self.indexer is not None),
+                ("a looped stack (looped)", self.looped is not None),
+                ("attention_impl='ring' / 'ulysses' (the sequence over "
+                 "sp: a shard's state is the shard before's)",
+                 self.attention_impl in ("ring", "ulysses")
+                 or ranks.get("sp", 1) > 1),
+                ("experts spread over ep (the mesh's ep=%d)"
+                 % ranks.get("ep", 1), ranks.get("ep", 1) > 1)):
+            if asked:
+                raise ValueError(
+                    "a 'mamba' layer (a Mamba-2 state-space mixer) beside "
+                    "%s: not built, so not run" % what)
+
+    def _check_multipliers(self, denoise):
+        """The multipliers and the softmax layers that rotate nothing
+        were built for the plain stack: refused, by name, beside what
+        was not tried with them."""
+        asked = [
+            name for name, stated in (
+                ("embedding_scale", self.embedding_scale is not None),
+                ("residual_scale", self.residual_scale is not None),
+                ("attention_scale", self.attention_scale is not None),
+                ("logits_divisor", self.logits_divisor is not None),
+                ("rotary=False", not self.rotary))
+            if stated]
+        if not asked:
+            return
+        for what, beside in (
+                ("objective=\"block_diffusion\"", denoise),
+                ("latent attention (latent: its own scale and "
+                 "LatentDims.rotary)", self.latent is not None),
+                ("hyper-connections (hc)", self.hc is not None),
+                ("the prediction module (mtp_layers)", bool(self.mtp_layers)),
+                ("a learned indexer (indexer)", self.indexer is not None),
+                ("a looped stack (looped)", self.looped is not None),
+                ("attention_impl='ring' / 'ulysses'",
+                 self.attention_impl in ("ring", "ulysses"))):
+            if beside:
+                raise ValueError(
+                    "%s beside %s: not built, so not run"
+                    % (", ".join(asked), what))
 
     def _block_diffusion_inputs(self, tokens, training, noisy, weights):
         """``(inputs (B, 2 L), positions, mask layout, weights, the
@@ -1206,7 +1319,10 @@ class MoeTransformerLM(nn.Module):
             return jnp.broadcast_to(
                 x[:, None], (x.shape[0], self.hc.streams) + x.shape[1:])
 
-        x = expand_streams(embed(tokens))
+        x = embed(tokens)
+        if self.embedding_scale is not None:
+            x = x * self.embedding_scale
+        x = expand_streams(x)
         wrap = (
             functools.partial(
                 remat_block, remat_policy=self.remat_policy,
@@ -1216,7 +1332,7 @@ class MoeTransformerLM(nn.Module):
         )
         self._check_kinds(kinds, denoise)
         balance = z_loss = jnp.float32(0.0)
-        routing, mhc, dsa, kda = [], [], [], []
+        routing, mhc, dsa, kda, mamba = [], [], [], [], []
 
         experts = {name: getattr(self, name) for name in EXPERT_FIELDS}
 
@@ -1228,7 +1344,12 @@ class MoeTransformerLM(nn.Module):
                 self._mixer(kind, layout), mlp_ratio=self.mlp_ratio,
                 norm=self.norm, norm_eps=self.norm_eps, hc=self.hc,
                 layer_index=index, mesh=self.mesh, name=name,
-                sandwich=self.sandwich, **second)
+                sandwich=self.sandwich,
+                # (a model without the multiplier builds the block it
+                # always did)
+                **({} if self.residual_scale is None
+                   else {"residual_scale": self.residual_scale}),
+                **second)
 
         def count(aux):
             """A block's losses and facts into the model's."""
@@ -1244,6 +1365,8 @@ class MoeTransformerLM(nn.Module):
                 dsa.append(aux["dsa"])
             if "kda" in aux:
                 kda.append(aux["kda"])
+            if "mamba" in aux:
+                mamba.append(aux["mamba"])
 
         if self.looped is not None:
             return self._looped(x, lambda: [
@@ -1256,9 +1379,10 @@ class MoeTransformerLM(nn.Module):
             if dense and kind == "linear":
                 raise ValueError(
                     "a dense block's mixer is softmax attention, "
-                    "latent attention, a gated short convolution or a "
-                    "Kimi Delta Attention; layer %d asks for a Gated "
-                    "DeltaNet, which only an expert block takes" % i)
+                    "latent attention, a gated short convolution, a "
+                    "Kimi Delta Attention or a Mamba-2 mixer; layer %d "
+                    "asks for a Gated DeltaNet, which only an expert "
+                    "block takes" % i)
             x, aux = block("block_%d" % i, i, kind, dense)(
                 x, training, positions)
             count(aux)
@@ -1276,6 +1400,8 @@ class MoeTransformerLM(nn.Module):
         head = embed.attend if self.tie_embeddings else nn.Dense(
             self.vocab_size, use_bias=False, name="lm_head")
         logits = head(make_norm(self.norm, self.norm_eps, "ln_f")(x))
+        if self.logits_divisor is not None:
+            logits = logits / self.logits_divisor
         mtp_logits = None
         # the module's parameters are made by ``init``, which is no
         # training call
@@ -1334,6 +1460,11 @@ class MoeTransformerLM(nn.Module):
             outputs["kda"] = {
                 name: jnp.stack([facts[name] for facts in kda])
                 for name in kda[0]}
+        if mamba:
+            # one fact a Mamba-2 layer
+            outputs["mamba"] = {
+                name: jnp.stack([facts[name] for facts in mamba])
+                for name in mamba[0]}
         if denoise:
             outputs["weights"] = weights
             if facts is not None:
@@ -1398,6 +1529,12 @@ def moe_sharding_rules():
             # fsdp; B | C | X lie side by side, so nothing splits over tp
             (r"attn/in_proj/kernel$", P("fsdp", None)),
             (r"attn/proj_out/kernel$", P(None, "fsdp")),
+            # a Mamba-2 mixer: z | x | B | C | dt lie side by side in
+            # ``in_proj`` (the short convolution's row above stores it
+            # over fsdp and splits nothing over tp); the convolution's
+            # bias follows its channels as the taps do; a number a head
+            (r"conv_bias$", P("tp")),
+            (r"attn/(D|out_norm_scale)$", P()),
             (r"(A_log|dt_bias)$", P()),
             (r"shared_expert_gate/kernel$", P()),
             (r"out_proj/kernel$", P("tp", None, "fsdp")),

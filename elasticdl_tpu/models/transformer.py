@@ -39,6 +39,7 @@ from elasticdl_tpu.ops import (
     qkv_conv,
     short_conv,
     sparse_attention,
+    ssd,
 )
 from elasticdl_tpu.ops.attention import dot_product_attention
 from elasticdl_tpu.ops.ring_attention import (
@@ -199,7 +200,10 @@ class Attention(nn.Module):
     # before the rotation (``q_norm`` / ``k_norm``, as ``qk_norm``,
     # which norms the whole projection, names its own)
     head_norm: Optional[str] = None
-    # rotary on the first ``rotary_dim`` lanes of a head (None: all)
+    # rotary on the first ``rotary_dim`` lanes of a head (None: all).
+    # A layer that rotates NOTHING says so by ``rotary`` False below,
+    # not by a width here: q and k then go to the softmax as the
+    # projections made them and the layer carries no position
     rotary_dim: Optional[int] = None
     # "sigmoid": the query projection is twice as wide and its second
     # half, a gate of the head's width, multiplies the attention's
@@ -234,6 +238,16 @@ class Attention(nn.Module):
     # ``dsa_select`` event's fields. None: the mixer, the tree and the
     # program the block always had
     indexer: Optional[IndexerDims] = None
+    # False: q and k are not rotated at all (granite-4.0-h's
+    # ``position_embedding_type`` ``nope``: the order of the tokens
+    # comes from the model's recurrent layers), and no operation lies
+    # under ``<kind_scope>/rotary``. True: the rotation every older
+    # model has, its tree and its program letter for letter
+    rotary: bool = True
+    # the softmax scale where it is not ``head width ** -0.5``
+    # (granite's ``attention_multiplier``, 1 / 64 at heads of 64);
+    # both the flash kernels and the XLA path take it
+    sm_scale: Optional[float] = None
 
     def _scoped(self, part):
         """The named scope of one part of the mixer, or none."""
@@ -297,11 +311,25 @@ class Attention(nn.Module):
             k = to_bhsd(head_norm(
                 dense("key", "k_norm", heads=kv_heads), "k_norm"))
             v = to_bhsd(dense("value", heads=kv_heads))
-        with self._scoped("rotary"):
-            q, k = rotate(
-                q, k, rotary_dim=self.rotary_dim, base=self.rope_theta,
-                positions=positions, scaling=self.rope_scaling,
-                mesh=self.mesh)
+        if self.rotary:
+            with self._scoped("rotary"):
+                q, k = rotate(
+                    q, k, rotary_dim=self.rotary_dim, base=self.rope_theta,
+                    positions=positions, scaling=self.rope_scaling,
+                    mesh=self.mesh)
+        elif (self.rotary_dim is not None or self.rope_scaling
+              or positions is not None or self.indexer is not None):
+            raise ValueError(
+                "a layer that rotates nothing (rotary=False) has no "
+                "rotary_dim, no rope_scaling, no positions of its own and "
+                "no indexer (which rotates its own heads)")
+        if self.sm_scale is not None and (
+                self.indexer is not None
+                or self.attention_impl in ("ring", "ulysses")):
+            raise ValueError(
+                "a softmax scale of its own (sm_scale) beside an indexer "
+                "or under attention_impl='ring' / 'ulysses': not built, "
+                "so not run")
 
         facts = None
         if self.indexer is not None:
@@ -317,18 +345,20 @@ class Attention(nn.Module):
                         else ulysses_attention)
             out = schedule(q, k, v, self.mesh, causal=True)
         else:
-            rotary = self.rotary_dim or head_dim
+            rotary = (self.rotary_dim or head_dim) if self.rotary else 0
             note = " ".join(filter(None, (
                 self.kind_scope and "heads=%d" % self.num_heads,
                 self.output_gate and "gate=%s" % self.output_gate,
-                (self.rotary_dim or self.rope_scaling
-                 or self.kind_scope) and "rotary=%d/%d" % (rotary, head_dim),
+                (self.rotary_dim or self.rope_scaling or self.kind_scope
+                 or not self.rotary) and "rotary=%d/%d" % (rotary, head_dim),
                 self.rope_scaling and "yarn=%g" % self.rope_scaling.factor,
+                self.sm_scale is not None and "scale=%g" % self.sm_scale,
             )))
             with self._scoped("flash"):
                 out = dot_product_attention(
                     q, k, v, causal=True, impl=self.attention_impl,
                     mesh=self.mesh, spec=spec, note=note, mask=self.mask,
+                    sm_scale=self.sm_scale,
                 )
         with self._scoped("gate"):
             out = out.transpose(0, 2, 1, 3)  # back to (B, S, H, d)
@@ -782,6 +812,142 @@ def kda_gate_facts(g, beta, chunk):
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2Dims:
+    """The sizes of a Mamba-2 mixer as granite-4.0-h's ``config.json``
+    names them (``mamba_n_heads``, ``mamba_d_head``, ``mamba_d_state``,
+    ``mamba_n_groups``, ``mamba_d_conv``, ``mamba_chunk_size``), and the
+    chunks of a checkpointed segment of the scan (``ops/ssd.py``).
+    ``conv_bias`` and ``proj_bias`` are the published module's two
+    switches: the convolution's bias is built, the projections' is
+    not."""
+
+    num_heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv_kernel: int
+    chunk: int = ssd.DEFAULT_CHUNK
+    segment: int = ssd.DEFAULT_SEGMENT
+    conv_bias: bool = True
+
+
+def _conv_bias_init(taps):
+    """A uniform draw in +-``taps ** -0.5``: what the published module's
+    ``nn.Conv1d`` draws its bias from (fan-in: a depthwise convolution's
+    taps)."""
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, dtype, minval=-taps ** -0.5, maxval=taps ** -0.5)
+
+
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer (arXiv:2405.21060; the ``transformers``
+    library's ``GraniteMoeHybridMambaLayer``, Bamba's), H heads of P
+    lanes over a state of N, ``B`` and ``C`` shared by the heads of a
+    group, for one token x:
+
+        z | xBC | dt = x W_in          (H P, H P + 2 groups N, H; no bias)
+        xBC = silu(causal depthwise conv over ``conv_kernel`` tokens
+              + bias);  x | B | C its three parts
+        dt = softplus(dt + dt_bias);  a = -exp(A_log) dt     (float32, H)
+        S <- exp(a_t) S + dt_t x_t B_t^T;  y_t = S C_t + D x_t
+                                       (``ops/ssd.py``, a chunk at a time)
+        y = RMSNorm_(H P)(y silu(z)) w (the gate BEFORE the norm, the
+            norm over all H P lanes where there is one group, over a
+            group's lanes where there are more; float32 statistics)
+        out = y W_out
+
+    Scopes: ``mamba/in_proj``, ``mamba/conv`` (convolution, bias, SiLU,
+    the split), ``mamba/gates`` (softplus, ``A``, the log decay and the
+    layer's facts), ``mamba/scan`` (the chunked scan and the skip,
+    whole), ``mamba/out_norm``, ``mamba/out_proj``. The convolution is
+    ``ops/qkv_conv.py:conv_silu_xla``'s lines with a bias (its kernel
+    pair is laid out for the delta rules' heads). Returns ``(out,
+    facts)``: ``dt_mean`` / ``dt_max`` of the step, ``decay_mean`` /
+    ``decay_min`` of ``exp(a)`` over tokens and heads, and
+    ``underflow_share`` of the (chunk, head) pairs whose decay cumulated
+    over the chunk is under ``e^-88``."""
+
+    dims: Mamba2Dims
+    norm_eps: float = 1e-6
+    mesh: Optional[Any] = None
+
+    @nn.compact
+    def __call__(self, x, training=False):
+        dims = self.dims
+        heads, dim, state, groups = (
+            dims.num_heads, dims.head_dim, dims.state, dims.groups)
+        batch, seq, width = x.shape
+        inner, conv_dim = heads * dim, heads * dim + 2 * groups * state
+        if heads % groups:
+            raise ValueError(
+                "%d heads do not divide over %d groups" % (heads, groups))
+        with jax.named_scope("mamba/in_proj"):
+            zxbcdt = nn.Dense(
+                inner + conv_dim + heads, use_bias=False, name="in_proj")(x)
+        with jax.named_scope("mamba/conv"):
+            taps = self.param(
+                "conv_kernel",
+                nn.initializers.variance_scaling(
+                    1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                (dims.conv_kernel, conv_dim),
+            ).astype(x.dtype)
+            bias = self.param(
+                "conv_bias", _conv_bias_init(dims.conv_kernel), (conv_dim,)
+            ).astype(x.dtype) if dims.conv_bias else None
+            xbc = qkv_conv.conv_silu_xla(
+                zxbcdt[..., inner:inner + conv_dim], taps, conv_dim, bias)
+            xs = xbc[..., :inner].reshape(batch, seq, heads, dim)
+            b = xbc[..., inner:inner + groups * state].reshape(
+                batch, seq, groups, state)
+            c = xbc[..., inner + groups * state:].reshape(
+                batch, seq, groups, state)
+        with jax.named_scope("mamba/gates"):
+            a_log = self.param("A_log", _kda_a_log_init, (heads,))
+            dt_bias = self.param("dt_bias", _dt_bias_init, (heads,))
+            skip = self.param("D", nn.initializers.ones, (heads,))
+            dt = jax.nn.softplus(
+                zxbcdt[..., inner + conv_dim:].astype(jnp.float32) + dt_bias)
+            a = -jnp.exp(a_log) * dt
+            facts = jax.lax.stop_gradient(
+                mamba_gate_facts(dt, a, dims.chunk))
+        with jax.named_scope("mamba/scan"):
+            y = ssd.ssd_scan(
+                xs, dt, a, b, c, skip, chunk=dims.chunk,
+                segment=dims.segment, mesh=self.mesh)
+        with jax.named_scope("mamba/out_norm"):
+            gated = (
+                y.astype(jnp.float32).reshape(batch, seq, inner)
+                * nn.silu(zxbcdt[..., :inner].astype(jnp.float32)))
+            scale = self.param(
+                "out_norm_scale", nn.initializers.ones, (inner,))
+            lanes = gated.reshape(batch, seq, groups, inner // groups)
+            var = jnp.mean(lanes * lanes, axis=-1, keepdims=True)
+            y = ((lanes * jax.lax.rsqrt(var + self.norm_eps)).reshape(
+                gated.shape) * scale).astype(x.dtype)
+        with jax.named_scope("mamba/out_proj"):
+            return nn.DenseGeneral(
+                width, axis=(-2, -1), use_bias=False, name="out_proj")(
+                    y.reshape(batch, seq, heads, dim)), facts
+
+
+def mamba_gate_facts(dt, a, chunk):
+    """The ``mamba_gates`` event's facts of one layer from its step
+    ``dt`` and its log decay ``a`` (B, S, H), float32."""
+    batch, seq, heads = a.shape
+    whole = jnp.pad(a, ((0, 0), (0, -seq % chunk), (0, 0)))
+    over_chunks = whole.reshape(batch, -1, chunk, heads).sum(axis=2)
+    decay = jnp.exp(a)
+    return {
+        "dt_mean": dt.mean(),
+        "dt_max": dt.max(),
+        "decay_mean": decay.mean(),
+        "decay_min": decay.min(),
+        "underflow_share": jnp.mean(
+            (over_chunks < UNDERFLOW_LOG).astype(jnp.float32)),
+    }
+
+
+@dataclasses.dataclass(frozen=True)
 class ShortConvDims:
     """A gated short convolution mixer as LFM2's ``config.json`` names
     its one size: ``conv_L_cache``, the taps (3: a position reads itself
@@ -1028,35 +1194,39 @@ def merge_hyper_facts(sublayers):
 # ``Attention``'s own fields, which a latent mixer has none of
 SOFTMAX_ONLY = (
     "qk_norm", "dropout", "head_dim", "num_kv_heads", "head_norm",
-    "rotary_dim", "output_gate", "mask", "kind_scope", "indexer")
+    "rotary_dim", "output_gate", "mask", "kind_scope", "indexer",
+    "sm_scale")
 
 
 def make_attention(num_heads, latent=None, linear=None, conv=None,
-                   kda=None, **fields):
+                   kda=None, mamba=None, **fields):
     """The block's mixer, ``name="attn"``, by the layer's kind:
     ``ShortConv`` where the layer is a gated short convolution
     (``conv``: its ``ShortConvDims``), ``GatedDeltaNet`` where it is a
     linear-attention one (``linear``: its ``GatedDeltaDims``),
     ``KimiDeltaAttention`` where it is a Kimi Delta Attention one
-    (``kda``: its ``KdaDims``), ``LatentAttention`` where the model
-    names latent widths (``LatentDims``), else ``Attention``.
+    (``kda``: its ``KdaDims``), ``Mamba2Mixer`` where it is a Mamba-2
+    state-space one (``mamba``: its ``Mamba2Dims``), ``LatentAttention``
+    where the model names latent widths (``LatentDims``), else
+    ``Attention``.
     ``fields``: ``norm_eps``, which all take; what the two softmax ones
     take (``rope_theta``, ``rope_scaling``: YaRN, each by its own
     convention); and what only ``Attention`` has (``SOFTMAX_ONLY``: the
     grouped-query fields, the mask's layout, the kind's scope). A
-    Gated DeltaNet, a Kimi Delta Attention and a short convolution
-    rotate nothing and mask nothing, and say so."""
+    Gated DeltaNet, a Kimi Delta Attention, a Mamba-2 mixer and a short
+    convolution rotate nothing and mask nothing, and say so."""
     recurrent = [
         what for what, dims in (
             ("a gated short convolution", conv),
             ("a Gated DeltaNet mixer", linear),
-            ("a Kimi Delta Attention mixer", kda)) if dims is not None]
+            ("a Kimi Delta Attention mixer", kda),
+            ("a Mamba-2 mixer", mamba)) if dims is not None]
     if len(recurrent) > 1:
         raise ValueError(
-            "one layer is %s: make_attention takes one of conv, linear "
-            "and kda" % " and ".join(recurrent))
+            "one layer is %s: make_attention takes one of conv, linear, "
+            "kda and mamba" % " and ".join(recurrent))
     for what in recurrent:
-        for name in ("mask", "rope_scaling", "indexer"):
+        for name in ("mask", "rope_scaling", "indexer", "sm_scale"):
             if fields.get(name) is not None:
                 raise ValueError("%s has no %s" % (what, name))
     if conv is not None:
@@ -1069,11 +1239,19 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
         return KimiDeltaAttention(
             kda, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
             name="attn")
+    if mamba is not None:
+        return Mamba2Mixer(
+            mamba, norm_eps=fields["norm_eps"], mesh=fields.get("mesh"),
+            name="attn")
     if latent is None:
         return Attention(num_heads, name="attn", **fields)
     for name in SOFTMAX_ONLY:
         if fields.pop(name, None):
             raise ValueError("latent attention has no %s" % name)
+    if not fields.pop("rotary", True):
+        raise ValueError(
+            "latent attention rotates nothing by LatentDims.rotary, not "
+            "by the softmax mixer's rotary")
     return LatentAttention(num_heads, latent, name="attn", **fields)
 
 
@@ -1105,8 +1283,13 @@ class Block(nn.Module):
     plain path and holds ``mhc`` (the block's facts) under
     hyper-connections, ``dsa`` where the mixer's indexer hands out its
     facts, ``kda`` where the mixer is a Kimi Delta Attention (its
-    gates' facts), and the experts' own keys (``MoeMlp``) where there
-    are experts."""
+    gates' facts), ``mamba`` where it is a Mamba-2 mixer (its gates'
+    facts), and the experts' own keys (``MoeMlp``) where there
+    are experts.
+
+    ``residual_scale``: both branches are multiplied by it before they
+    are added (``x + s f(norm(x))``: granite's ``residual_multiplier``).
+    None: ``x + f(norm(x))``, the program every block always had."""
 
     mixer: Any
     experts: Optional[Any] = None
@@ -1120,6 +1303,7 @@ class Block(nn.Module):
     layer_index: int = 0
     mesh: Optional[Any] = None
     sandwich: bool = False
+    residual_scale: Optional[float] = None
 
     def _dense_mlp(self, h, training):
         """The dense second sublayer, ``(y, {})`` as the experts' is
@@ -1159,28 +1343,32 @@ class Block(nn.Module):
         def mix(h):
             # only ``Attention`` takes the rows' positions, and with an
             # indexer it hands its facts out beside its output, as a
-            # Kimi Delta Attention always does
+            # Kimi Delta Attention and a Mamba-2 mixer always do
             out = mixer(h, training, *(
                 () if positions is None else (positions,)))
             if isinstance(out, tuple):
-                key = "kda" if isinstance(mixer, KimiDeltaAttention) else "dsa"
+                key = {KimiDeltaAttention: "kda", Mamba2Mixer: "mamba"}.get(
+                    type(mixer), "dsa")
                 out, aux[key] = out
             return out
 
         norm = lambda name: make_norm(self.norm, self.norm_eps, name)
         # a sublayer's output norm, where the block has one
         after = lambda name: norm(name) if self.sandwich else (lambda y: y)
+        scaled = (lambda y: y) if self.residual_scale is None else (
+            lambda y: y * self.residual_scale)
         if self.hc is None:
             x = constrain(x, self.mesh, RESIDUAL_SPEC)
-            x = x + after("ln_attn_out")(mix(norm("ln_attn")(x)))
+            x = x + scaled(after("ln_attn_out")(mix(norm("ln_attn")(x))))
             y, of_second = second(norm("ln_mlp")(x), training)
             return constrain(
-                x + after("ln_mlp_out")(y), self.mesh, RESIDUAL_SPEC), {
-                **of_second, **aux}
-        if self.sandwich:
+                x + scaled(after("ln_mlp_out")(y)), self.mesh,
+                RESIDUAL_SPEC), {**of_second, **aux}
+        if self.sandwich or self.residual_scale is not None:
             raise ValueError(
-                "a sandwich-normed block (sandwich) under "
-                "hyper-connections (hc): not built, so not run")
+                "a sandwich-normed block (sandwich) or a scaled residual "
+                "branch (residual_scale) under hyper-connections (hc): "
+                "not built, so not run")
         x = constrain(x, self.mesh, STREAMS_SPEC)
         u, write, attn_facts = HyperConnection(
             self.hc, 2 * self.layer_index, self.mesh, name="hc_attn")(x)

@@ -330,11 +330,16 @@ EVENT_TYPES = frozenset({
                              #   tokens; diag_mean: its mean diagonal)
     "mixer_kinds",           # once a worker, when the state is made
                              #   (worker/trainer.py:ensure_state), of
-                             #   a model with gated short convolutions
-                             #   or Kimi Delta Attention layers
+                             #   a model with gated short convolutions,
+                             #   Kimi Delta Attention or Mamba-2 layers
                              #   (models/moe_transformer.py:
                              #   mixer_kinds): what it is made of
-                             #   (a KDA model: + kda_layers,
+                             #   (a Mamba-2 model: + mamba_layers,
+                             #   full_layers, dense_layers,
+                             #   mamba_heads, mamba_head_dim,
+                             #   mamba_state, mamba_groups, mamba_taps,
+                             #   mamba_chunk, head_dim, kv_heads,
+                             #   rotary; a KDA model: + kda_layers,
                              #   full_layers, dense_layers, kda_heads,
                              #   kda_head_dim, kda_taps,
                              #   kda_gate_rank, kda_chunk, latent,
@@ -379,6 +384,17 @@ EVENT_TYPES = frozenset({
                              #   the (chunk, head, channel) triples
                              #   whose decay cumulated over the chunk
                              #   is under e^-88; beta_mean)
+    "mamba_gates",           # the same steps of a model with Mamba-2
+                             #   layers (models/transformer.py:
+                             #   Mamba2Mixer, mamba_gate_facts): a
+                             #   list a fact, one entry a Mamba layer
+                             #   (+ step, dt_mean and dt_max of the
+                             #   step after its softplus; decay_mean
+                             #   and decay_min of exp(a) over tokens
+                             #   and heads; underflow_share: the
+                             #   (chunk, head) pairs whose decay
+                             #   cumulated over the chunk is under
+                             #   e^-88)
     "loss_terms",            # the same steps where the loss function
                              #   names parts of its sum (+ step, loss,
                              #   mtp_loss: a multi-token-prediction

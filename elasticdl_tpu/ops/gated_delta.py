@@ -1,16 +1,16 @@
 """The gated delta rule in chunked form (Gated DeltaNet, arXiv:2412.06464;
-the linear-attention layers of Qwen3-Next) and, from the same lines,
-the delta rule whose decay is a vector a head (Kimi Delta Attention,
-arXiv:2510.26692; Kimi Linear's). Which of the two runs is decided by
-the rank of ``g`` and by nothing else: ``(B, H, S)``, one log decay a
-head and token, or ``(B, H, S, Dk)``, one a channel of the key (the
-last section below). ``gdn_prepare_fwd`` / ``gdn_prepare_bwd`` compute
-the SCALAR rule's operands, ``kda_prepare_fwd`` / ``kda_prepare_bwd`` a
-decay a channel's (PR 59), each where ``prepare_impl`` finds a TPU and
-a block in its VMEM; the chunk-to-chunk recurrence is ``gdn_scan_fwd`` /
-``gdn_scan_bwd`` for both ranks wherever ``scan_impl`` says so, a decay
-a channel with the state transposed (``by_channel``: the kernels are
-then named ``kda_scan_fwd`` / ``kda_scan_bwd``).
+Qwen3-Next's linear-attention layers) and, from the same lines, the
+delta rule whose decay is a vector a head (Kimi Delta Attention,
+arXiv:2510.26692). The rank of ``g`` decides which runs, nothing else:
+``(B, H, S)``, a log decay a head and token, or ``(B, H, S, Dk)``, one a
+key channel (last section). ``gdn_prepare_fwd`` / ``_bwd`` compute the
+SCALAR rule's operands, ``kda_prepare_fwd`` / ``_bwd`` a decay a
+channel's (PR 59), each where ``prepare_impl`` finds a TPU and a block in
+its VMEM; the chunk-to-chunk recurrence is ``gdn_scan_fwd`` / ``_bwd``
+wherever ``scan_impl`` says so, a channel's transposed (``kda_scan_*``).
+``ops/ssd.py`` computes ANOTHER recurrence, Mamba-2's selective scan ``S
+= exp(a) S + dt x B^T``: no ``(I - beta k k^T)``, so no inverse and no ``W
+S`` here serves it, and its masked matmuls do not serve this rule.
 
 Per value head, with a state ``S`` (key width x value width, zero at the
 sequence's start), for each token ``t``::

@@ -171,16 +171,16 @@ def log_choice(hk, hv, dim, taps, impl, tokens, tile):
 
 # --------------------------------------------------- the module's lines
 
-def conv_silu_xla(qkvz, taps, conv_dim):
-    """``silu`` of the causal depthwise convolution of ``qkvz``'s first
-    ``conv_dim`` columns with ``taps`` (K, conv_dim), as XLA runs it."""
+def conv_silu_xla(qkvz, taps, conv_dim, bias=None):
+    """``silu`` of the causal depthwise convolution (+ ``bias``, if any) of
+    ``qkvz``'s first ``conv_dim`` columns with ``taps`` (K, conv_dim), as
+    XLA runs it."""
     seq, k = qkvz.shape[1], taps.shape[0]
-    qkv = qkvz[..., :conv_dim]
-    padded = jnp.pad(qkv, ((0, 0), (k - 1, 0), (0, 0)))
+    padded = jnp.pad(qkvz[..., :conv_dim], ((0, 0), (k - 1, 0), (0, 0)))
     # y_t = sum_j taps[j] x_(t - (taps - 1) + j): four shifted
-    # multiply-adds, one fusion
-    return jax.nn.silu(sum(
-        taps[j] * padded[:, j:j + seq] for j in range(k)))
+    # multiply-adds, one fusion; None: the sum every older layer has
+    terms = (taps[j] * padded[:, j:j + seq] for j in range(k))
+    return jax.nn.silu(sum(terms) if bias is None else sum(terms, bias))
 
 
 def split_heads_xla(t, num, width, normalise=None):

@@ -93,6 +93,7 @@ class Fact(NamedTuple):
 # block (``models/moe_transformer.py``), a learned sparse-attention
 # indexer's, one a layer, a looped stack's exit distribution, one entry
 # a pass, a Kimi Delta Attention model's gates, one entry a KDA layer,
+# a Mamba-2 model's gates, one entry a Mamba layer,
 # and what the loss function names of its own sum (a
 # multi-token-prediction module's loss, an indexer's term, a looped
 # stack's expected cross-entropy, entropy and cross-entropy an exit)
@@ -117,6 +118,7 @@ FACTS = (
     Fact("dsa", "dsa_select"),
     Fact("looped", "looped_exit"),
     Fact("kda", "kda_gates"),
+    Fact("mamba", "mamba_gates"),
     Fact("loss_terms", "loss_terms", of_loss=True),
 )
 

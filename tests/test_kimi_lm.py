@@ -257,7 +257,7 @@ def test_what_a_kda_layer_was_not_built_beside_is_refused(fields, match):
 
 
 def test_make_attention_takes_one_recurrent_kind_and_no_mask():
-    with pytest.raises(ValueError, match="one of conv, linear and kda"):
+    with pytest.raises(ValueError, match="one of conv, linear, kda and mamba"):
         make_attention(4, kda=KDA, conv=ShortConvDims(3), norm_eps=1e-6)
     with pytest.raises(
             ValueError, match="a Kimi Delta Attention mixer has no mask"):

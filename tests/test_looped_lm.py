@@ -311,9 +311,9 @@ def test_the_step_hands_out_the_loop_s_facts(tokens, params):
     ("the prediction module", dict(mtp_layers=1)),
     ("block_diffusion", dict(objective="block_diffusion", bd_mask_id=0)),
     ("a learned indexer", dict(indexer=IndexerDims(2, 8, 16))),
-    ("a 'linear', 'conv' or 'kda' mixer", dict(
+    ("a 'linear', 'conv', 'mamba' or 'kda' mixer", dict(
         layer_kinds=("conv", "full"), conv=ShortConvDims(taps=3))),
-    ("a 'linear', 'conv' or 'kda' mixer", dict(
+    ("a 'linear', 'conv', 'mamba' or 'kda' mixer", dict(
         layer_kinds=("linear", "full"), linear=GatedDeltaDims(2, 2, 16, 16, 4))),
     ("tied to the embedding", dict(tie_embeddings=True)),
     ("at least one pass", dict(passes=0)),

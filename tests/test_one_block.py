@@ -84,7 +84,8 @@ def test_the_block_states_fewer_fields_than_a_mixer_has():
     own = {f.name for f in dataclasses.fields(T.Block)} - {"parent", "name"}
     assert own == {
         "mixer", "experts", "mlp_act", "mlp_dim", "mlp_ratio", "dropout",
-        "norm", "norm_eps", "hc", "layer_index", "mesh", "sandwich"}
+        "norm", "norm_eps", "hc", "layer_index", "mesh", "sandwich",
+        "residual_scale"}
     attention = {f.name for f in dataclasses.fields(T.Attention)}
     assert not own & (attention - {"mesh", "dropout", "norm_eps",
                                    "parent", "name"})

@@ -263,14 +263,14 @@ def test_conv_beside_ep_is_refused(tokens):
 
 def test_the_kinds_are_checked_by_name(tokens):
     with pytest.raises(
-            ValueError, match="'conv' and 'kda' need their mixer's sizes"):
+            ValueError, match="'kda' and 'mamba' need their mixer's sizes"):
         model(conv=None).init(jax.random.PRNGKey(0), tokens, training=False)
     with pytest.raises(ValueError, match="each is 'full', 'window', 'linear'"):
         model(layer_kinds=("conv", "mamba")).init(
             jax.random.PRNGKey(0), tokens, training=False)
     # a dense block takes softmax, latent or conv, and no Gated DeltaNet
     with pytest.raises(
-            ValueError, match="Kimi Delta Attention; layer 0 asks for a Gated"):
+            ValueError, match="Mamba-2 mixer; layer 0 asks for a Gated"):
         model(layer_kinds=("linear", "full"), conv=None,
               linear=T.GatedDeltaDims(2, 2, 8, 8, 4)).init(
                   jax.random.PRNGKey(0), tokens, training=False)

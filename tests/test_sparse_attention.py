@@ -491,7 +491,7 @@ def test_a_probe_is_sown_only_when_asked(trained):
 
 REFUSED = [
     ("block_diffusion", dict(objective="block_diffusion", bd_mask_id=1)),
-    ("'window', 'linear', 'conv' or 'kda'",
+    ("'window', 'linear', 'conv', 'kda' or 'mamba'",
      dict(layer_kinds=("full", "window"))),
     ("latent attention", "latent"),
     ("hyper-connections", "hc"),
