@@ -78,9 +78,12 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     ``flash_attention.QK_LAYOUT``); which backward
     (``flash_attention.backward_schedule``; a model's float32 init
     trace may read ``split`` where its bfloat16 step reads ``fused``:
-    the dtype is on the line for that); and how many of one head's
-    (q-block, k-block) grid steps compute a tile, how many of those
-    apply the causal mask, and how many are skipped
+    the dtype is on the line for that; ``fused dq_buffers=1`` where
+    the fused kernel holds dq's whole-head output block in one buffer
+    to fit its budget, ``flash_attention.fused_dq_buffers``, and the
+    bare word wherever it has the pipeline's two); and how many of
+    one head's (q-block, k-block) grid steps compute a tile, how many
+    of those apply the causal mask, and how many are skipped
     (``flash_attention.causal_pairs``): the forward's, and the
     backward's where ``_blocks`` gives it other blocks. A layout that
     walks runs (``Band``) counts the steps of the grid that runs, the
@@ -113,10 +116,12 @@ def _flash_facts(q, k, v, causal, block_q, block_k):
     if k.shape[1] != q.shape[1]:
         widths = "kv_heads=%d group=%d, %s" % (
             k.shape[1], q.shape[1] // k.shape[1], widths)
+    dq_buffers = _flash.fused_dq_buffers(
+        *shapes, block_q, block_k, v_dim, layout=layout)
+    schedule = ("split", "fused dq_buffers=1", "fused")[dq_buffers]
     return "%sflash backward=%s, %spairs %s%s%s" % (
         widths,
-        _flash.backward_schedule(
-            *shapes, block_q, block_k, v_dim, layout=layout),
+        schedule,
         "mask=%s " % layout if other else "",
         forward,
         " (backward %s)" % backward if backward != forward else "",

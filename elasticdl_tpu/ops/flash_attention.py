@@ -16,14 +16,19 @@ a head, halved by the causal skip): the forward runs 2 (``q k^T``,
 k-block) pair: dk and dv accumulate in float32 scratch across the
 q-blocks of one k-block, and dq, whose sum runs across the k-blocks,
 in a float32 scratch of one head's whole ``(seq_q, head_dim)`` that
-stays in VMEM for that head's sweep. That scratch is 16 MiB at
-16,384 x 256, so the call states its own VMEM limit
-(``_FUSED_VMEM_BYTES``), and ``backward_schedule`` keeps the older pair
-for shapes whose count (``fused_bwd_vmem_bytes``) is over it:
-``flash_dq`` + ``flash_dkv``, 3 + 4 matmuls, the scores rebuilt twice,
-no state that grows with the sequence. One algorithm under two
-schedules, chosen by shape: gradients agree to the last bit in
-interpret mode (tests/test_attention_ops.py).
+stays in VMEM for that head's sweep, beside dq's output block of the
+same rows, which is written back once a head. That scratch is 16 MiB
+at 16,384 x 256, so the call states its own VMEM limit
+(``_FUSED_VMEM_BYTES``). The output block has the pipeline's two
+buffers where the count (``fused_bwd_vmem_bytes``) has room for them
+and ONE (``pl.Buffered(1)``; ``fused_dq_buffers``) where only that
+keeps the kernel within the limit: 32,768 x 256 and 32,768 x 192 /
+128 (PR 61). ``backward_schedule`` keeps the older pair for shapes
+over the limit even so (65,536 x 256): ``flash_dq`` + ``flash_dkv``,
+3 + 4 matmuls, the scores rebuilt twice, no state that grows with the
+sequence. One algorithm under two schedules, chosen by shape:
+gradients agree to the last bit in interpret mode
+(tests/test_attention_ops.py).
 
 A causal call's grid still has a step for every (q-block, k-block)
 pair, and each pair is one of three classes, decided from its two block
@@ -139,7 +144,8 @@ def _lanes(width):
     tiles (192 -> 256, and since PR 49 64 -> 128: half of every q, k,
     v, o, dq, dk, dv tile of a 64-wide head is padding, so its dq
     accumulator costs what a 128-wide head's does: 32,768 x 64 counts
-    52 of the 64 MiB and stays fused, 65,536 x 64 would not)."""
+    52 of the 64 MiB and stays fused, 65,536 x 64 only with dq's
+    output block in one buffer, ``fused_dq_buffers``)."""
     return -(-width // _LANES) * _LANES
 
 
@@ -166,8 +172,12 @@ def _blocks(seq_q, seq_k, head_dim, dtype, block_q, block_k,
       272 and 240 were. Not where a q-row is over 512 bytes (256 x
       float32: the v5e compiler refuses the forward, its blocks and
       score temporaries pass the 16 MiB scoped VMEM), and not where the
-      taller block would push ``flash_bwd`` over its budget (24,576 x
-      256: the fused backward is worth more than the block; at 16,384 x
+      taller block would push ``flash_bwd`` over its budget, counted
+      with the pipeline's two buffers of dq's output block as every
+      shape had them before PR 61 (24,576 x 256 keeps 512 q-rows and
+      two buffers, 63 MiB: the fused backward is worth more than the
+      block, and whether the block is worth more than the second
+      buffer, 60 MiB counted, nobody has read on a chip; at 16,384 x
       256 ``fused_bwd_vmem_bytes`` says 56 of 64 MiB). At 4096 x 128
       (``olmoe1b7b-s4k``) 1024 / 1024 read inside the cell's spread:
       left.
@@ -1092,40 +1102,66 @@ _FUSED_VMEM_BYTES = 64 * 2**20
 
 
 def fused_bwd_vmem_bytes(seq_q, head_dim, block_q, block_k, itemsize,
-                         v_dim=None):
+                         v_dim=None, dq_buffers=2):
     """VMEM of ``flash_bwd`` from its shapes, counted generously: dq of
-    one ``bh`` as float32 accumulator and double-buffered output block;
+    one ``bh`` as float32 accumulator and ``dq_buffers`` buffers of its
+    output block (2: the pipeline's pair, what every block gets unless
+    it says otherwise; 1: ``pl.Buffered(1)``, ``fused_dq_buffers``);
     the float32 dk / dv accumulators; q, do, k, v, dk, dv blocks, two
     buffers each; four score-sized float32 temporaries (s, p, dp, ds).
     q, k, dq, dk are ``head_dim`` wide, v, do, dv ``v_dim`` (``None``:
     the same), each rounded up to whole 128-lane tiles, which is what
     VMEM holds of a 192-wide row. The v5e compiler takes 16,384 x 256
     (blocks 512 / 1024, bfloat16) under a limit of 36 MiB and refuses
-    it under 32; this says 47."""
+    it under 32; this says 47. With ONE buffer of dq's block (blocks
+    512 / 1024, bfloat16, compiled for a described v5e, PR 61) it
+    takes 32,768 x 256 under a limit of 59 MiB and refuses it under
+    58 where this says 63, and 32,768 x 192 / 128 under 56, not 55,
+    where this says 61.25; with two it refuses both under 72."""
     head_dim = _lanes(head_dim)
     v_dim = head_dim if v_dim is None else _lanes(v_dim)
-    dq = seq_q * head_dim * (4 + 2 * itemsize)
+    dq = seq_q * head_dim * (4 + dq_buffers * itemsize)
     kv = block_k * (head_dim + v_dim) * (4 + 2 * 2 * itemsize)
     q_do = block_q * (head_dim + v_dim) * 2 * itemsize
     scores = 4 * block_q * block_k * 4
     return dq + kv + q_do + scores
 
 
-def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
-                      block_k=None, v_dim=None, layout=None):
-    """Which backward these shapes get: ``"fused"`` (one kernel,
-    ``flash_bwd``: the scores rebuilt once) where dq's accumulator fits
-    the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``: rebuilt
-    twice, no state that grows with the sequence) above it. ``_bwd``
-    decides by this and ``ops/attention.py`` logs it."""
+def fused_dq_buffers(seq_q, seq_k, head_dim, dtype, block_q=None,
+                     block_k=None, v_dim=None, layout=None):
+    """How many buffers dq's whole-head output block of ``flash_bwd``
+    has at these shapes: 2 where the count with the pipeline's pair is
+    within ``_FUSED_VMEM_BYTES`` (the program every such shape always
+    got), else 1 where the count with one is, else 0: no fused kernel.
+    The block's index depends on ``bh`` alone, so it is written back
+    once a head and the second buffer hides one write of ``seq_q x
+    head_dim`` a head (16 MiB, ~20 us at a v5e's HBM peak, at 32,768 x
+    256) behind the next head's first tile; at 32,768 x 256 and 32,768
+    x 192 / 128 it is what kept the fused kernel out (79 and 77.25 MiB
+    counted with two, 63 and 61.25 with one)."""
     block_q, block_k = _blocks(
         seq_q, seq_k, head_dim, dtype, block_q, block_k, backward=True,
         v_dim=v_dim, layout=layout)
-    held = fused_bwd_vmem_bytes(
-        seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize,
-        v_dim,
-    )
-    return "fused" if held <= _FUSED_VMEM_BYTES else "split"
+    for dq_buffers in (2, 1):
+        held = fused_bwd_vmem_bytes(
+            seq_q, head_dim, block_q, block_k, jnp.dtype(dtype).itemsize,
+            v_dim, dq_buffers)
+        if held <= _FUSED_VMEM_BYTES:
+            return dq_buffers
+    return 0
+
+
+def backward_schedule(seq_q, seq_k, head_dim, dtype, block_q=None,
+                      block_k=None, v_dim=None, layout=None):
+    """Which backward these shapes get: ``"fused"`` (one kernel,
+    ``flash_bwd``: the scores rebuilt once) where dq's accumulator and
+    its output block, in two buffers or in one (``fused_dq_buffers``),
+    fit the VMEM budget, ``"split"`` (``flash_dq`` + ``flash_dkv``:
+    rebuilt twice, no state that grows with the sequence) above it.
+    ``_bwd`` decides by this and ``ops/attention.py`` logs it."""
+    return "fused" if fused_dq_buffers(
+        seq_q, seq_k, head_dim, dtype, block_q, block_k, v_dim, layout
+    ) else "split"
 
 
 def _bwd(
@@ -1152,9 +1188,8 @@ def _bwd(
     q_steps = _inner_steps(
         causal, block_q, block_k, num_q, num_k, k_outer=True)
     k_steps = _inner_steps(causal, block_q, block_k, num_q, num_k)
-    fuse = backward_schedule(
-        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim
-    ) == "fused"
+    dq_buffers = fused_dq_buffers(
+        seq_q, seq_k, head_dim, q.dtype, block_q, block_k, v_dim)
 
     # grouped-query attention: k and v have a head for every ``group``
     # query heads. The kernels read head ``b // group`` through the
@@ -1204,14 +1239,19 @@ def _bwd(
         pltpu.VMEM((block_k, v_dim), jnp.float32),
     ]
 
-    if fuse:
+    if dq_buffers:
         dk, dv, dq = pl.pallas_call(
             functools.partial(
                 _dkv_kernel, with_dq=True, num_q=num_q, **statics),
             grid=(bh, num_k, q_steps),
             in_specs=dkv_in_specs,
             out_specs=dkv_out_specs + (
-                pl.BlockSpec((1, seq_q, head_dim), dq_idx),
+                # written back once a head: a second buffer only where
+                # the budget has room for it (``fused_dq_buffers``)
+                pl.BlockSpec(
+                    (1, seq_q, head_dim), dq_idx,
+                    pipeline_mode=(
+                        None if dq_buffers == 2 else pl.Buffered(1))),
             ),
             scratch_shapes=dkv_scratch + [
                 pltpu.VMEM((seq_q, head_dim), jnp.float32),

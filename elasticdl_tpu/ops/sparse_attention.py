@@ -1040,7 +1040,9 @@ def _refusal(q):
     if not _planes(seq, q.shape[-1], q.dtype) or seq % min(
             _SELECT_ROWS, seq) or seq % min(_SELECT_CHUNK, seq) or seq < 128:
         return "seq %d is not whole tiles (%d, %d)" % ((seq,) + _tiles(seq))
-    if _flash.backward_schedule(seq, seq, q.shape[-1], q.dtype) != "fused":
+    # ``flash_sparse_bwd`` holds dq's whole-head block in the pipeline's
+    # two buffers: a shape the dense kernel fits only with one is over
+    if _flash.fused_dq_buffers(seq, seq, q.shape[-1], q.dtype) != 2:
         return "dq's accumulator at (%d, %d) %s is over the VMEM budget" % (
             seq, q.shape[-1], q.dtype.name)
     return ""

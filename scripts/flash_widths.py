@@ -2,7 +2,8 @@
 attention: q and k of 192 = 128 nope + 64 rope, v of 128), kernel alone
 on one TPU chip: timed, and checked against the XLA reference.
 
-    python scripts/flash_widths.py        # on one TPU chip, ~2 min
+    python scripts/flash_widths.py        # on one TPU chip, ~4 min
+    python scripts/flash_widths.py --rehearse   # the CPU: control flow
 
 Times ``flash_fwd`` and ``flash_bwd`` alone (``_fwd`` / ``_bwd`` under
 one jit each; ms a call over 20 calls) at the ``moonlight16b-s8k``
@@ -15,10 +16,21 @@ HBM) and 128 / 128 (the work if the rope part were dropped: a floor,
 not a candidate). PR 29 ran this with two more layouts of the 192 lanes
 inside the kernel (zero-padded to 256 in VMEM; the score as two
 contractions, 128 + 64); neither beat 192 as it is and their code was
-not kept (PERF.md Section 6, PR 29, has the table). Writes
+not kept (PERF.md Section 6, PR 29, has the table).
+
+Since PR 61 also the backward at the two 32,768-token cells' shapes,
+32 heads x 32,768 x 192 / 128 (``kimi-linear48b-s32k``) and 16 heads
+over 2 kv heads x 32,768 x 256 (``qwen3next80b-s32k``), where
+``flash_bwd`` keeps dq's whole-head output block in ONE buffer
+(``fused_dq_buffers``): the schedule the tree gives against the split
+pair (a budget of 0 while it is traced), ms a call, and dq, dk, dv of
+the two compared element for element; beside them two forms no shape
+gets, under a limit only a v5e / v6e core has room for: the pipeline's
+two buffers (what the single one costs) and 1024 q-rows. Writes
 ``chiprun_out/flash_widths.json``.
 """
 
+import contextlib
 import json
 import math
 import os
@@ -39,31 +51,33 @@ from elasticdl_tpu.ops.attention import xla_attention  # noqa: E402
 CALLS = 20
 
 
-def inputs(bh, seq, qk_dim, v_dim, seed=0):
+def inputs(bh, seq, qk_dim, v_dim, seed=0, kv_bh=None):
     rng = np.random.RandomState(seed)
-    make = lambda d: jnp.asarray(
-        rng.randn(bh, seq, d) * 0.5, jnp.bfloat16)
-    return make(qk_dim), make(qk_dim), make(v_dim), make(v_dim)
+    make = lambda heads, d: jnp.asarray(
+        rng.randn(heads, seq, d) * 0.5, jnp.bfloat16)
+    kv_bh = kv_bh or bh
+    return (make(bh, qk_dim), make(kv_bh, qk_dim), make(kv_bh, v_dim),
+            make(bh, v_dim))
 
 
-def ms_per_call(fn, *args):
+def ms_per_call(fn, *args, calls=CALLS):
     jax.block_until_ready(fn(*args))
     start = time.perf_counter()
-    for _ in range(CALLS):
+    for _ in range(calls):
         out = fn(*args)
     jax.block_until_ready(out)
-    return (time.perf_counter() - start) / CALLS * 1e3
+    return (time.perf_counter() - start) / calls * 1e3
 
 
-def kernels(blocks):
+def kernels(blocks, interpret=False):
     """(forward, backward) jitted on (bh, seq, width) operands."""
     scale = 1.0 / math.sqrt(192)
 
     def fwd(q, k, v):
-        return F._fwd(q, k, v, scale, True, *blocks, False)
+        return F._fwd(q, k, v, scale, True, *blocks, interpret)
 
     def bwd(q, k, v, o, lse, do):
-        return F._bwd(q, k, v, o, lse, do, scale, True, *blocks, False)
+        return F._bwd(q, k, v, o, lse, do, scale, True, *blocks, interpret)
 
     return jax.jit(fwd), jax.jit(bwd)
 
@@ -83,6 +97,68 @@ def time_case(bh, seq, qk_dim, v_dim, blocks=(None, None)):
         "schedule": F.backward_schedule(
             seq, seq, qk_dim, q.dtype, *blocks, v_dim=v_dim),
     }
+
+
+@contextlib.contextmanager
+def budget(mib):
+    """``_FUSED_VMEM_BYTES`` at ``mib`` MiB while a form is traced and
+    compiled (``None``: as the tree has it)."""
+    real = F._FUSED_VMEM_BYTES
+    if mib is not None:
+        F._FUSED_VMEM_BYTES = mib * 2**20
+    try:
+        yield
+    finally:
+        F._FUSED_VMEM_BYTES = real
+
+
+# (name, budget in MiB while traced, blocks asked): the tree's own form
+# first; the pair; then what no shape gets
+FORMS = (
+    ("tree", None, (None, None)),
+    ("split", 0, (None, None)),
+    ("two-buffers-under-96MiB", 96, (512, 1024)),
+    ("1024-q-rows-under-96MiB", 96, (1024, 1024)),
+)
+
+
+def schedules_case(bh, kv_bh, seq, qk_dim, v_dim, forms=FORMS, calls=10,
+                   interpret=False):
+    """The backward at one long shape under each of ``forms``: ms a
+    call, and dq, dk, dv against the first form's (the tree's): the
+    largest |difference| and whether every element is equal."""
+    q, k, v, do = inputs(bh, seq, qk_dim, v_dim, 2, kv_bh)
+    fwd, _ = kernels((None, None), interpret)
+    o, lse = fwd(q, k, v)
+    rows, first = [], None
+    for name, mib, blocks in forms:
+        # a new function: jax keeps the traces of the last one
+        _, bwd = kernels(blocks, interpret)
+        row = {"form": name, "asked": blocks, "budget_mib": mib}
+        with budget(mib):
+            row["schedule"] = F.backward_schedule(
+                seq, seq, qk_dim, q.dtype, *blocks, v_dim=v_dim)
+            row["dq_buffers"] = F.fused_dq_buffers(
+                seq, seq, qk_dim, q.dtype, *blocks, v_dim=v_dim)
+            row["blocks_bwd"] = F._blocks(
+                seq, seq, qk_dim, q.dtype, *blocks, backward=True,
+                v_dim=v_dim)
+            try:
+                grads = jax.block_until_ready(bwd(q, k, v, o, lse, do))
+            except Exception as e:  # noqa: BLE001 - the compiler's refusal
+                row["refused"] = str(e)[:300]
+                rows.append(row)
+                continue
+        row["bwd_ms"] = ms_per_call(bwd, q, k, v, o, lse, do, calls=calls)
+        grads = [np.asarray(g.astype(jnp.float32)) for g in grads]
+        if first is None:
+            first = grads
+        else:
+            for label, a, b in zip(("dq", "dk", "dv"), grads, first):
+                row[label + "_equal"] = bool(np.array_equal(a, b))
+                row[label + "_max_abs_diff"] = float(np.abs(a - b).max())
+        rows.append(row)
+    return rows
 
 
 def check_case():
@@ -111,6 +187,15 @@ def check_case():
 
 def main():
     device = jax.devices()[0]
+    if "--rehearse" in sys.argv:
+        # the long shapes' control flow, tiny, interpreted: no timing
+        # is kept and nothing is written
+        for row in schedules_case(
+                4, 2, 1024, 192, 128, calls=1, interpret=True,
+                forms=FORMS[:2] + (("512-q-rows", None, (512, 512)),)):
+            del row["bwd_ms"]  # the interpreter's, no device's
+            print(json.dumps(row), flush=True)
+        return
     if device.platform != "tpu":
         sys.exit("flash_widths: needs a TPU, found %s" % device.platform)
     report = {"device": device.device_kind, "calls": CALLS, "cases": []}
@@ -130,6 +215,12 @@ def main():
                **time_case(bh, 8192, 256, 128))
         record(bh=bh, seq=8192, widths="128/128", layout="floor",
                **time_case(bh, 8192, 128, 128))
+    for cell, shape in (
+            ("kimi-linear48b-s32k", (32, 32, 32768, 192, 128)),
+            ("qwen3next80b-s32k", (16, 2, 32768, 256, 256))):
+        for row in schedules_case(*shape):
+            record(cell=cell, bh=shape[0], kv_bh=shape[1], seq=shape[2],
+                   widths="%d/%d" % shape[3:], **row)
     out = os.path.join("chiprun_out", "flash_widths.json")
     os.makedirs("chiprun_out", exist_ok=True)
     with open(out, "w") as f:

@@ -44,14 +44,36 @@ def mosaic_kernels(hlo):
     ]
 
 
+def flash_names(jaxpr):
+    """Names of the flash pallas_calls in a jaxpr, sorted."""
+    return sorted(set(re.findall(
+        r"name=(flash_(?:fwd|bwd|dq|dkv))\b", str(jaxpr))))
+
+
 def traced_flash(fn, args):
     """(names of the flash pallas_calls ``fn`` traces to, its value)
     from ONE trace: the jaxpr is read and then compiled, where
     ``make_jaxpr`` and a call would trace the kernels twice."""
     traced = jax.jit(fn).trace(*args)
-    names = sorted(set(re.findall(
-        r"name=(flash_(?:fwd|bwd|dq|dkv))\b", str(traced.jaxpr))))
-    return names, traced.lower().compile()(*args)
+    return flash_names(traced.jaxpr), traced.lower().compile()(*args)
+
+
+def dq_block_buffers(jaxpr):
+    """Of every fused flash backward in ``jaxpr`` (a ``Traced.jaxpr``),
+    how many buffers dq's whole-head output block has: the mode the
+    block states (``pl.Buffered(1)``), 2 where it states none."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(inner)
+
+    modes = [
+        params["grid_mapping"].block_mappings[-1].pipeline_mode
+        for params in calls(jaxpr.jaxpr)
+        if re.fullmatch(r"flash_(?:\w+_)?bwd", params["name"])]
+    return [2 if mode is None else mode.buffer_count for mode in modes]
 
 
 def dense_band(seq, window):

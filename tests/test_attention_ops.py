@@ -16,7 +16,11 @@ from elasticdl_tpu.ops.ring_attention import (
     ulysses_attention,
 )
 from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
-from tests.kernel_common import traced_flash
+from tests.kernel_common import (
+    dq_block_buffers,
+    flash_names,
+    traced_flash,
+)
 
 
 def _inputs(batch=2, heads=2, seq=256, dim=64, seed=0):
@@ -351,24 +355,78 @@ def test_fused_backward_matches_xla_and_the_split_pair(case, monkeypatch):
         np.asarray(fused[0], np.float32), dq, atol=1e-6, rtol=1e-6)
 
 
-@pytest.mark.parametrize("shape,dtype,schedule", [
+@pytest.mark.parametrize("case", [
+    "causal-several-blocks-bfloat16-d256", "full-seq-q-shorter-than-seq-k"])
+def test_one_buffer_of_dq_s_block_changes_no_gradient(case, monkeypatch):
+    """A budget between the two counts (``fused_bwd_vmem_bytes`` with
+    one and with two buffers of dq's whole-head output block) gives
+    the fused kernel with that block under ``pl.Buffered(1)``, which is
+    what 32,768 x 256 and 32,768 x 192 / 128 get under the real budget
+    (PR 61): the same body, so dq, dk, dv are the two-buffer form's to
+    the last bit, and the split pair's as the fused kernel's always
+    were (dk, dv equal, dq's terms in ascending k in both)."""
+    from elasticdl_tpu.ops import flash_attention as F
+
+    case = FUSED_BACKWARD_CASES[case]
+    _, dim, seq_q, seq_k, block_q, block_k, dtype = case
+    shapes = (seq_q, seq_k, dim, dtype, block_q, block_k)
+    one, two = (
+        F.fused_bwd_vmem_bytes(
+            seq_q, dim, block_q, block_k, jnp.dtype(dtype).itemsize,
+            dq_buffers=buffers) for buffers in (1, 2))
+    assert one < two and F.fused_dq_buffers(*shapes) == 2
+
+    def grads(budget, buffers, kernels):
+        # a new function a budget: jax keeps the traces of the last
+        monkeypatch.setattr(F, "_FUSED_VMEM_BYTES", budget)
+        assert F.fused_dq_buffers(*shapes) == buffers
+        flash, _, args = _flash_grads(case)
+        traced = jax.jit(flash).trace(*args)
+        assert flash_names(traced.jaxpr) == kernels
+        # the block's mode is in the program that is lowered
+        assert dq_block_buffers(traced.jaxpr) == [buffers] * bool(buffers)
+        return [np.asarray(g, np.float32)
+                for g in traced.lower().compile()(*args)]
+
+    single = grads(one, 1, ["flash_bwd", "flash_fwd"])
+    double = grads(two, 2, ["flash_bwd", "flash_fwd"])
+    pair = grads(one - 1, 0, ["flash_dkv", "flash_dq", "flash_fwd"])
+    for got, want in zip(single, double):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(single[1], pair[1])
+    np.testing.assert_array_equal(single[2], pair[2])
+    np.testing.assert_allclose(single[0], pair[0], atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("shape,dtype,schedule,dq_buffers", [
     # the benchmark's cells: pythia-1b at 2k and 16k, OLMoE at 4k
-    ((2048, 2048, 256), jnp.bfloat16, "fused"),
-    ((16384, 16384, 256), jnp.bfloat16, "fused"),
-    ((4096, 4096, 128), jnp.bfloat16, "fused"),
+    ((2048, 2048, 256), jnp.bfloat16, "fused", 2),
+    ((16384, 16384, 256), jnp.bfloat16, "fused", 2),
+    ((4096, 4096, 128), jnp.bfloat16, "fused", 2),
+    # at 512 q-rows (`_blocks`) the two buffers still fit: 63 MiB
+    ((24576, 24576, 256), jnp.bfloat16, "fused", 2),
+    # dq's accumulator and two buffers of its output block are over the
+    # budget, the accumulator and one are not (PR 61: 79 -> 63 MiB at
+    # qwen3next80b-s32k's shape, 75.5 -> 59.5 at 65,536 x 128)
+    ((32768, 32768, 256), jnp.bfloat16, "fused", 1),
+    ((65536, 65536, 128), jnp.bfloat16, "fused", 1),
     # dq's accumulator alone is the whole budget
-    ((32768, 32768, 256), jnp.bfloat16, "split"),
-    ((65536, 65536, 128), jnp.bfloat16, "split"),
+    ((65536, 65536, 256), jnp.bfloat16, "split", 0),
+    ((131072, 131072, 128), jnp.bfloat16, "split", 0),
     # a ring block: dq's size follows seq_q, not seq_k
-    ((4096, 65536, 128), jnp.bfloat16, "fused"),
-    # float32 doubles dq's output block (a model's init trace at 16k)
-    ((16384, 16384, 256), jnp.float32, "split"),
-    ((2048, 2048, 256), jnp.float32, "fused"),
+    ((4096, 65536, 128), jnp.bfloat16, "fused", 2),
+    # float32 doubles dq's output block (a model's init trace at 16k:
+    # 68 MiB with two buffers, 52 with one)
+    ((16384, 16384, 256), jnp.float32, "fused", 1),
+    ((32768, 32768, 256), jnp.float32, "split", 0),
+    ((2048, 2048, 256), jnp.float32, "fused", 2),
 ])
-def test_backward_schedule_is_chosen_from_the_shapes(shape, dtype, schedule):
+def test_backward_schedule_is_chosen_from_the_shapes(
+        shape, dtype, schedule, dq_buffers):
     from elasticdl_tpu.ops import flash_attention as F
 
     assert F.backward_schedule(*shape, dtype) == schedule
+    assert F.fused_dq_buffers(*shape, dtype) == dq_buffers
 
 
 # The three classes of a causal call's (q-block, k-block) pairs

@@ -374,13 +374,14 @@ def test_a_next_token_model_traces_no_part_of_the_objective():
 # causal flash call's gradient at the five cells' shapes, recorded on
 # the parent of PR 35 (ff36309) with the pinned jax: the diagonal's
 # kernels are what they were. A change to the kernels changes these
-# knowingly.
+# knowingly: since PR 61 32,768 x 256 traces ``flash_bwd`` with dq's
+# output block in one buffer where it traced the split pair (bdb00a75...).
 CAUSAL_CALLS = {
     "pythia1b-s2k": ((4, 8, 2048, 256), 8, 256, "2bcfa253fd64326f"),
     "pythia1b-s16k": ((1, 8, 16384, 256), 8, 256, "e41c6909a11789f4"),
     "olmoe1b7b-s4k": ((8, 16, 4096, 128), 16, 128, "02c2fb4f97787774"),
     "moonlight16b-s8k": ((2, 16, 8192, 192), 16, 128, "fc9b9be8ca36bd1b"),
-    "qwen3next80b-s32k": ((1, 16, 32768, 256), 2, 256, "bdb00a75b27f3cb0"),
+    "qwen3next80b-s32k": ((1, 16, 32768, 256), 2, 256, "731ab0f65612c0f5"),
 }
 
 

@@ -5,7 +5,9 @@ must accept the fused backward (its dq accumulator and whole-``bh`` dq
 block need more VMEM than the default scoped limit), the program must
 hold one forward and one backward kernel, both named ``flash...``
 (``benchmark/metrics/flash_time_share.py`` finds them by that word),
-and a shape over the budget must compile to the split pair.
+the two 32,768-token cells' shapes must compile to the fused kernel
+with dq's output block in one buffer (PR 61), and a shape over the
+budget even so must compile to the split pair.
 
 The gated delta rule's kernels for the same described chip are
 ``tests/test_gated_delta_tpu_compile.py``'s.
@@ -22,18 +24,20 @@ from elasticdl_tpu.ops import flash_attention as F
 from tests.kernel_common import chip, topology  # noqa: F401 (fixtures)
 
 
-def flash_kernels(chip, shape):
+def flash_kernels(chip, shape, v_dim=None, dtype=jnp.bfloat16):
     """The Mosaic kernels, as the compile ledger names and counts them,
     of the compiled causal attention's gradient at (batch, heads, seq,
-    head width), bfloat16."""
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    head width), bfloat16; ``v_dim``: v's own width."""
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    v = jax.ShapeDtypeStruct(
+        shape[:3] + (v_dim or shape[3],), dtype, sharding=chip)
 
     def loss(q, k, v):
         out = F.flash_attention(q, k, v, causal=True)
         return out.astype(jnp.float32).sum()
 
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        x, x, x).compile().as_text()
+        x, x, v).compile().as_text()
     assert hlo.count("tpu_custom_call") == len(
         device_obs._PALLAS_KERNEL_RE.findall(hlo))
     return device_obs.pallas_kernels(hlo)
@@ -52,11 +56,39 @@ def test_the_cells_compile_to_one_forward_and_one_backward(chip, shape):
 
 
 def test_over_the_budget_compiles_to_the_split_pair(chip):
-    shape = (1, 2, 32768, 256)
+    # dq's float32 accumulator alone is the 64 MiB
+    shape = (1, 2, 65536, 256)
     assert F.backward_schedule(
         shape[2], shape[2], shape[3], jnp.bfloat16) == "split"
     assert flash_kernels(chip, shape) == {
         "flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}
+
+
+@pytest.mark.parametrize("shape,v_dim,dtype,mib", [
+    ((1, 2, 32768, 256), None, jnp.bfloat16, (79.0, 63.0)),
+    ((1, 2, 32768, 192), 128, jnp.bfloat16, (77.25, 61.25)),
+    # a model's float32 init trace at pythia1b-s16k's shape
+    ((1, 2, 16384, 256), None, jnp.float32, (68.0, 52.0)),
+], ids=["qwen3next80b-s32k", "kimi-linear48b-s32k", "s16k-float32"])
+def test_one_buffer_of_dq_s_block_keeps_the_fused_kernel(
+        chip, shape, v_dim, dtype, mib):
+    """Where the count with the pipeline's two buffers of dq's
+    whole-head output block is over the budget and the count with one
+    is not, the block is ``pl.Buffered(1)`` and the chip's compiler
+    takes the fused kernel under the budget it states (PR 61: with two
+    buffers it refuses 32,768 x 256 under 72 MiB; with one it takes it
+    under 59 and 32,768 x 192 / 128 under 56)."""
+    seq, dim = shape[2:]
+    blocks = F._blocks(
+        seq, seq, dim, dtype, None, None, backward=True, v_dim=v_dim)
+    assert blocks == (512, 1024)
+    assert tuple(
+        F.fused_bwd_vmem_bytes(
+            seq, dim, *blocks, jnp.dtype(dtype).itemsize, v_dim, buffers)
+        / 2**20 for buffers in (2, 1)) == mib
+    assert F.fused_dq_buffers(seq, seq, dim, dtype, v_dim=v_dim) == 1
+    assert flash_kernels(chip, shape, v_dim, dtype) == {
+        "flash_fwd": 1, "flash_bwd": 1}
 
 
 @pytest.mark.parametrize("mib,blocks", [
@@ -78,9 +110,9 @@ def test_the_count_is_above_what_the_compiler_needs(
 
 
 @pytest.mark.parametrize("shape,kv_heads,kernels", [
-    # qwen3next80b-s32k: 16 query heads over 2 kv heads, the split pair
-    ((1, 16, 32768, 256), 2,
-     {"flash_fwd": 1, "flash_dq": 1, "flash_dkv": 1}),
+    # qwen3next80b-s32k: 16 query heads over 2 kv heads, the fused
+    # kernel with dq's output block in one buffer (PR 61; the pair before)
+    ((1, 16, 32768, 256), 2, {"flash_fwd": 1, "flash_bwd": 1}),
     # a grouped call under the fused backward's budget
     ((2, 8, 4096, 128), 2, {"flash_fwd": 1, "flash_bwd": 1}),
     # lfm2-8b-s32k: 32 query heads over 8 kv heads at a 64-wide head,

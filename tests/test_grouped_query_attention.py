@@ -149,9 +149,10 @@ def test_the_attention_line_names_the_group_and_the_note(caplog):
     q = jax.ShapeDtypeStruct((1, 16, 32768, 256), jnp.bfloat16)
     k = v = jax.ShapeDtypeStruct((1, 2, 32768, 256), jnp.bfloat16)
     facts = attention_ops._flash_facts(q, k, v, True, None, None)
-    # 32,768 x 256 is over the fused backward's budget: the pair
+    # 32,768 x 256 fits the fused backward's budget with dq's
+    # whole-head output block in one buffer (PR 61; the pair before)
     assert facts.startswith(
-        "kv_heads=2 group=8, flash backward=split, pairs run=")
+        "kv_heads=2 group=8, flash backward=fused dq_buffers=1, pairs run=")
     same = attention_ops._flash_facts(q, q, q, True, None, None)
     assert same.startswith("flash backward=")  # the other cells' line
     attention_ops._log_auto_once.cache_clear()
@@ -160,7 +161,7 @@ def test_the_attention_line_names_the_group_and_the_note(caplog):
             "tpu", "pallas", "", (1, 16, 32768, 256), "bfloat16",
             "gate=sigmoid rotary=64/256, " + facts)
     assert ("bfloat16, gate=sigmoid rotary=64/256, kv_heads=2 group=8, "
-            "flash backward=split") in caplog.text
+            "flash backward=fused dq_buffers=1, pairs run=") in caplog.text
 
 
 # ------------------------------------------------------------ the module

@@ -128,6 +128,12 @@ def test_shape_functions_take_both_widths():
     assert count(256, None) == count(256, 256)
     # the schedule follows dq's accumulator, which is q-wide
     assert F.backward_schedule(65536, 65536, 192, bf16, v_dim=128) == "split"
+    # kimi-linear48b-s32k: 77.25 MiB with the two buffers of dq's
+    # output block the count assumes by default, 61.25 with one (PR 61)
+    assert [F.fused_bwd_vmem_bytes(32768, 192, 512, 1024, 2, 128, buffers)
+            / 2**20 for buffers in (2, 1)] == [77.25, 61.25]
+    assert F.fused_dq_buffers(32768, 32768, 192, bf16, v_dim=128) == 1
+    assert F.backward_schedule(32768, 32768, 192, bf16, v_dim=128) == "fused"
     # equal widths: what the parent counted (PR 26: 47 MiB at 16k x 256)
     assert round(
         F.fused_bwd_vmem_bytes(16384, 256, 512, 1024, 2) / 2**20) == 47

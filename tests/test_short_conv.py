@@ -295,5 +295,9 @@ def test_a_64_wide_head_s_tiles_and_schedule_are_chosen_on_purpose():
     assert count(32768, 64) == count(32768, 128)
     assert count(32768, 64) == 52 * 2**20
     assert F.backward_schedule(32768, 32768, 64, bf16) == "fused"
-    assert F.backward_schedule(65536, 65536, 64, bf16) == "split"
-    assert F.backward_schedule(32768, 32768, 256, bf16) == "split"
+    # past the two-buffer count dq's output block gets one (PR 61)
+    assert F.fused_dq_buffers(32768, 32768, 64, bf16) == 2
+    assert F.fused_dq_buffers(65536, 65536, 64, bf16) == 1
+    assert F.fused_dq_buffers(32768, 32768, 256, bf16) == 1
+    assert F.backward_schedule(131072, 131072, 64, bf16) == "split"
+    assert F.backward_schedule(65536, 65536, 256, bf16) == "split"
