@@ -1,0 +1,16 @@
+"""head_loss_time_share: device time of the family ``head_loss`` of
+``observability/scopes.py`` -- the way in and out of the blocks (the
+scopes ``embed``, ``final_norm``, ``head``, ``loss``, ``exit/``,
+``mtp/proj``, ``mtp/head``, ``looped/exit_norm``) -- forward, recompute
+and backward, over device busy time, the busiest device, in percent. The
+deepest registered scope on an operation's ``op_name`` decides its
+family. ``step_account.json`` has the family's rows by scope and
+direction (lib/step_account.py). Left out for a program without the
+registry."""
+
+from benchmark.lib import step_account
+
+
+def read(run):
+    return step_account.family_share(
+        step_account.reduced(run), "head_loss")

@@ -1099,7 +1099,8 @@ class MoeTransformerLM(nn.Module):
             split_rngs={"params": False}, length=passes)(self, x, None)
         kernel = HeadKernel(self.vocab_size, name="lm_head")(self.embed_dim)
         if not training:
-            return x @ kernel
+            with jax.named_scope("head"):
+                return x @ kernel
         with jax.named_scope("exit/gate"):
             logits = gate_logits[:passes - 1]
             log_p = looped_exit.exit_distribution(logits)
@@ -1319,10 +1320,13 @@ class MoeTransformerLM(nn.Module):
             return jnp.broadcast_to(
                 x[:, None], (x.shape[0], self.hc.streams) + x.shape[1:])
 
-        x = embed(tokens)
-        if self.embedding_scale is not None:
-            x = x * self.embedding_scale
-        x = expand_streams(x)
+        # names only, here and around the head below
+        # (``observability/scopes.py``)
+        with jax.named_scope("embed"):
+            x = embed(tokens)
+            if self.embedding_scale is not None:
+                x = x * self.embedding_scale
+            x = expand_streams(x)
         wrap = (
             functools.partial(
                 remat_block, remat_policy=self.remat_policy,
@@ -1396,12 +1400,15 @@ class MoeTransformerLM(nn.Module):
             return streams.astype(jnp.float32).sum(axis=1).astype(
                 streams.dtype)
 
-        x = reduce_streams(x)
+        with jax.named_scope("final_norm"):
+            x = reduce_streams(x)
+            normed = make_norm(self.norm, self.norm_eps, "ln_f")(x)
         head = embed.attend if self.tie_embeddings else nn.Dense(
             self.vocab_size, use_bias=False, name="lm_head")
-        logits = head(make_norm(self.norm, self.norm_eps, "ln_f")(x))
-        if self.logits_divisor is not None:
-            logits = logits / self.logits_divisor
+        with jax.named_scope("head"):
+            logits = head(normed)
+            if self.logits_divisor is not None:
+                logits = logits / self.logits_divisor
         mtp_logits = None
         # the module's parameters are made by ``init``, which is no
         # training call

@@ -21,7 +21,6 @@ Design notes (TPU-first):
   under ``fsdp`` moved the batch instead of the weights.
 """
 
-import contextlib
 import dataclasses
 import functools
 from typing import Any, Optional
@@ -226,7 +225,8 @@ class Attention(nn.Module):
     # under the named scopes ``<kind_scope>/qkv``, ``/rotary``,
     # ``/flash``, ``/gate`` and ``/out_proj``, forward and backward,
     # and its attention line starts ``heads=``.
-    # None: no scope, the program and the line the mixer always had
+    # None: the scopes are ``attn_full/...`` (ISSUE 62: a step's time
+    # is read by them in every model), the line the mixer always had
     kind_scope: Optional[str] = None
     # a learned indexer picks the keys a query attends over
     # (``IndexerDims``; ``ops/sparse_attention.py``): three projections
@@ -250,10 +250,9 @@ class Attention(nn.Module):
     sm_scale: Optional[float] = None
 
     def _scoped(self, part):
-        """The named scope of one part of the mixer, or none."""
-        if self.kind_scope is None:
-            return contextlib.nullcontext()
-        return jax.named_scope("%s/%s" % (self.kind_scope, part))
+        """The named scope of one part of the mixer."""
+        return jax.named_scope(
+            "%s/%s" % (self.kind_scope or "attn_full", part))
 
     @nn.compact
     def __call__(self, x, training=False, positions=None):
@@ -1352,17 +1351,34 @@ class Block(nn.Module):
                 out, aux[key] = out
             return out
 
-        norm = lambda name: make_norm(self.norm, self.norm_eps, name)
+        def norm(name):
+            """A norm of the block under the scope ``residual/norm``
+            (names only: ``observability/scopes.py``)."""
+            module = make_norm(self.norm, self.norm_eps, name)
+
+            def scoped(h):
+                with jax.named_scope("residual/norm"):
+                    return module(h)
+            return scoped
+
         # a sublayer's output norm, where the block has one
         after = lambda name: norm(name) if self.sandwich else (lambda y: y)
         scaled = (lambda y: y) if self.residual_scale is None else (
             lambda y: y * self.residual_scale)
+
+        def add(x, y, name):
+            """``x`` plus a sublayer's output ``y`` through its output
+            norm ``name``, under the scope ``residual/add``."""
+            y = after(name)(y)
+            with jax.named_scope("residual/add"):
+                return x + scaled(y)
+
         if self.hc is None:
             x = constrain(x, self.mesh, RESIDUAL_SPEC)
-            x = x + scaled(after("ln_attn_out")(mix(norm("ln_attn")(x))))
+            x = add(x, mix(norm("ln_attn")(x)), "ln_attn_out")
             y, of_second = second(norm("ln_mlp")(x), training)
             return constrain(
-                x + scaled(after("ln_mlp_out")(y)), self.mesh,
+                add(x, y, "ln_mlp_out"), self.mesh,
                 RESIDUAL_SPEC), {**of_second, **aux}
         if self.sandwich or self.residual_scale is not None:
             raise ValueError(
@@ -1476,10 +1492,12 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, training: bool = False):
-        x = nn.Embed(
-            self.vocab_size, self.embed_dim, name="wte"
-        )(tokens.astype(jnp.int32))
-        x = constrain(x, self.mesh, RESIDUAL_SPEC)
+        # names only, as a block's (``observability/scopes.py``)
+        with jax.named_scope("embed"):
+            x = nn.Embed(
+                self.vocab_size, self.embed_dim, name="wte"
+            )(tokens.astype(jnp.int32))
+            x = constrain(x, self.mesh, RESIDUAL_SPEC)
         if self.remat:
             block_cls = remat_block(
                 Block, self.remat_policy, self.attention_impl
@@ -1497,11 +1515,14 @@ class TransformerLM(nn.Module):
                 mesh=self.mesh,
                 name="block_%d" % i,
             )(x, training)
-        x = constrain(nn.LayerNorm(name="ln_f")(x), self.mesh, RESIDUAL_SPEC)
-        logits = nn.Dense(
-            self.vocab_size, use_bias=False, name="lm_head"
-        )(x)
-        return constrain(logits, self.mesh, HIDDEN_SPEC)
+        with jax.named_scope("final_norm"):
+            x = constrain(
+                nn.LayerNorm(name="ln_f")(x), self.mesh, RESIDUAL_SPEC)
+        with jax.named_scope("head"):
+            logits = nn.Dense(
+                self.vocab_size, use_bias=False, name="lm_head"
+            )(x)
+            return constrain(logits, self.mesh, HIDDEN_SPEC)
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,11 @@ from elasticdl_tpu.common.env_utils import env_bool, env_int
 from elasticdl_tpu.common.log_utils import default_logger as _logger_factory
 from elasticdl_tpu.observability import events
 from elasticdl_tpu.observability import metrics as obs_metrics
+from elasticdl_tpu.observability import scopes
 from elasticdl_tpu.observability import trace
+# (``op_scope`` lives with the registry since ISSUE 62; its callers and
+# tests find it here as before)
+from elasticdl_tpu.observability.scopes import op_scope  # noqa: F401
 
 logger = _logger_factory("elasticdl_tpu.observability.device")
 
@@ -396,9 +400,6 @@ _OPERAND_ALIAS_RE = re.compile(r'\{"indices":\[([^\]]*)\]\}')
 _BODY_RE = re.compile(
     r"\b(?:body|to_apply|true_computation|false_computation)=%([\w.\-]+)"
     r"|\bbranch_computations=\{([^}]*)\}")
-_JIT_PART_RE = re.compile(r"\bp?jit\([^()]*\)")
-_WRAPPER_RE = re.compile(r"[\w.]+\(")
-_INDEX_RE = re.compile(r"_\d+$")
 
 
 @functools.lru_cache(maxsize=4096)
@@ -461,34 +462,6 @@ def _result_elements(shape):
             elements.append(_shape_bytes(shape[start:at]))
             start = at + 1
     return elements
-
-
-def op_scope(op_name):
-    """(scope, direction) of an instruction's ``op_name``: the path cut
-    to the program's own names (``jit(...)`` parts and the primitive at
-    the end dropped, ``jvp(`` / ``transpose(`` unwrapped, a trailing
-    index folded so that every block's buffers are one group:
-    ``forward/TransformerLM/block_*/attn``), and ``backward`` under a
-    ``transpose(``, ``recompute`` under ``checkpoint`` or
-    ``rematted_computation``, else ``forward``."""
-    if "checkpoint" in op_name or "rematted_computation" in op_name:
-        direction = "recompute"
-    elif "transpose(" in op_name:
-        direction = "backward"
-    else:
-        direction = "forward"
-    # ``transpose(jvp(forward))/M/jit(_take)/gather`` -> forward/M
-    path = _WRAPPER_RE.sub("", _JIT_PART_RE.sub("", op_name))
-    names = [
-        _INDEX_RE.sub("_*", name)
-        for name in path.replace(")", "").split("/")[:-1]
-        if name and name not in ("checkpoint", "rematted_computation")
-    ]
-    # the forward's scopes repeat inside a backward that recomputes
-    # them (``transpose(jvp(forward))/M/jvp(forward)/M/checkpoint``)
-    if names and names[0] in names[1:]:
-        names = names[len(names) - 1 - names[::-1].index(names[0]):]
-    return "/".join(names) or "unscoped", direction
 
 
 def _argument_scope(op_name):
@@ -757,6 +730,98 @@ def _largest_body_peak(hlo_text, bodies):
             nbytes for index, nbytes in live
             if records[index]["opcode"] != "parameter"))
     return largest
+
+
+# ``scope_mix``: the rows an ``xla_compile`` event carries at most (a
+# row is under 800 bytes: the event stays under 256 KB a compile); the
+# mixed fusions past them, the smallest by the bytes they hold outside
+# their root's family, are counted in ``dropped``
+SCOPE_MIX_MAX = 300
+# interior opcodes listed beside a row's bytes: where a fusion's time
+# is, when it is not in moving its bytes
+_HEAVY_OPCODES = frozenset({
+    "dot", "convolution", "reduce", "gather", "scatter"})
+# interior instructions that are no work of any family
+_NO_WORK_OPCODES = _VIEW_OPCODES | {"parameter", "constant"}
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(")
+_CALLS_RE = re.compile(r"\bcalls=%([\w.\-]+)")
+
+
+def scope_mix(hlo_text):
+    """The fusions of a compiled program that hold the work of MORE
+    THAN ONE family (``scopes.family`` of an interior instruction's
+    ``op_name``), in the one pass over the text that the train step's
+    compile already pays for. A device trace charges a fusion's whole
+    time to its own ``op_name``, which is its root's: these rows say
+    how far that can be trusted, operation by operation
+    (``benchmark/lib/step_account.py`` joins them to the trace by
+    instruction name).
+
+    Every ``fusion`` instruction of every computation counts (ENTRY, a
+    ``while`` / ``call`` / ``conditional`` body). Of its fused
+    computation, instructions without an ``op_name`` (the compiler's
+    own) and parameters, constants and views say nothing; an
+    instruction whose ``op_name`` has no registered scope is of the
+    family ``unnamed``. Returns ``fusions`` (all), ``mixed``, ``rows``:
+    at most ``SCOPE_MIX_MAX`` of ``{"op": instruction, "root": family
+    of the fusion's own op_name, "bytes": {family: result bytes of the
+    interior instructions of that family}, "heavy": {opcode:
+    [families]} for dot / convolution / reduce / gather / scatter
+    interiors}``, largest first by the bytes outside the root's
+    family, and ``dropped`` ``{"rows", "bytes"}``: the mixed fusions
+    past the cap and those bytes of theirs."""
+    families = functools.lru_cache(maxsize=None)(
+        lambda op_name: scopes.family(op_name)[0])
+    inside = {}   # computation -> ({family: bytes}, {opcode: {family}})
+    fusions = []  # (instruction, computation called, own op_name)
+    current = None
+    for line in hlo_text.split("\n"):
+        head = _INSTRUCTION_RE.match(line)
+        if head is None:
+            started = _COMPUTATION_RE.match(line)
+            if started is not None and line.endswith("{"):
+                current = inside.setdefault(started.group(1), ({}, {}))
+            continue
+        shape, tail = _split_result(line[head.end():])
+        opcode = tail.partition("(")[0]
+        # (``find`` first: a line's ``backend_config`` is long)
+        at = tail.find('op_name="')
+        name = _OP_NAME_RE.match(tail, at) if at >= 0 else None
+        if opcode == "fusion":
+            called = _CALLS_RE.search(tail)
+            if called is not None:
+                fusions.append((
+                    head.group(2), called.group(1),
+                    name.group(1) if name else None))
+        if name is None or opcode in _NO_WORK_OPCODES or current is None:
+            continue
+        family = families(name.group(1))
+        nbytes, heavy = current
+        nbytes[family] = nbytes.get(family, 0) + _shape_bytes(shape)
+        if opcode in _HEAVY_OPCODES:
+            heavy.setdefault(opcode, set()).add(family)
+    rows = []
+    for instruction, called, op_name in fusions:
+        nbytes, heavy = inside.get(called, ({}, {}))
+        if len(nbytes) < 2:
+            continue
+        root = families(op_name) if op_name else scopes.UNNAMED
+        rows.append((
+            sum(n for family, n in nbytes.items() if family != root),
+            {"op": instruction, "root": root, "bytes": dict(nbytes),
+             "heavy": {
+                 opcode: sorted(found)
+                 for opcode, found in sorted(heavy.items())}}))
+    rows.sort(key=lambda row: -row[0])
+    return {
+        "fusions": len(fusions),
+        "mixed": len(rows),
+        "rows": [row for _, row in rows[:SCOPE_MIX_MAX]],
+        "dropped": {
+            "rows": max(0, len(rows) - SCOPE_MIX_MAX),
+            "bytes": sum(foreign for foreign, _ in rows[SCOPE_MIX_MAX:]),
+        },
+    }
 
 
 def peak_live_text(live, shown=4):
@@ -1036,9 +1101,11 @@ class _InstrumentedJit:
         self.collectives = None
         # pallas_kernels() of the same program
         self.kernels = {}
-        # compiled_memory() and peak_live() of the same program
+        # compiled_memory(), peak_live() and scope_mix() of the same
+        # program
         self.memory = None
         self.peak_live = None
+        self.scope_mix = None
         self._cost_fetches = 0
         self._cost_on = env_bool(COST_ANALYSIS_ENV, True)
         self._cache_size = 0
@@ -1161,6 +1228,7 @@ class _InstrumentedJit:
             kernels=self.kernels,
             memory=self.memory,
             peak_live=self.peak_live,
+            scope_mix=self.scope_mix,
         )
 
     def _fetch_cost(self, args, kwargs):
@@ -1187,6 +1255,7 @@ class _InstrumentedJit:
             self.kernels = pallas_kernels(hlo_text)
             self.peak_live = peak_live(
                 hlo_text, self.memory["peak"] if self.memory else 0)
+            self.scope_mix = scope_mix(hlo_text)
         except Exception as e:
             logger.debug("cost analysis unavailable for %s: %s",
                          self.name, e)

@@ -221,8 +221,14 @@ EVENT_TYPES = frozenset({
                              #   {instructions, largest_body_peak}}:
                              #   what the scheduled program holds in
                              #   HBM at its fullest point, by the
-                             #   program's scopes; both null when the
-                             #   program was not read)
+                             #   program's scopes; scope_mix {fusions,
+                             #   mixed, rows [{op, root, bytes{family},
+                             #   heavy{opcode: [families]}}] (at most
+                             #   300), dropped {rows, bytes}}: the
+                             #   fusions that hold more than one
+                             #   family's work (observability/
+                             #   scopes.py); all null when the program
+                             #   was not read)
     "device_memory",         # the worker's allocator, read at three
                              #   points a process: at state_init (the
                              #   state is on the device, no step

@@ -215,13 +215,16 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
         grads add linearly."""
         compute_params = params
         compute_features = features
+        # names only (ISSUE 23, ISSUE 62): the scopes go into every
+        # operation's ``op_name``, where a device trace reads the cast,
+        # forward, loss and optimizer apart
+        # (``observability/scopes.py`` is their registry); JAX itself
+        # writes ``transpose(jvp(forward))`` on the backward. The
+        # program computes the same values
         if compute_dtype is not None:
-            compute_params = cast_floating(params, compute_dtype)
-            compute_features = cast_floating(features, compute_dtype)
-        # names only (ISSUE 23): the scopes go into every operation's
-        # ``op_name``, where a device trace reads forward, loss and
-        # optimizer apart; JAX itself writes ``transpose(jvp(forward))``
-        # on the backward. The program computes the same values
+            with jax.named_scope("cast_params"):
+                compute_params = cast_floating(params, compute_dtype)
+                compute_features = cast_floating(features, compute_dtype)
         with jax.named_scope("forward"):
             outputs, new_model_state = _apply_model(
                 model,
@@ -283,12 +286,13 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 return (
                     (new_state, loss, facts) if with_facts
                     else (new_state, loss))
-            scalars = health_scalars(loss, global_grad_norm(grads))
-            scalars.update(facts)
-            if guard_nonfinite:
-                new_state = guard_nonfinite_state(
-                    state, new_state, scalars["nonfinite"]
-                )
+            with jax.named_scope("health"):
+                scalars = health_scalars(loss, global_grad_norm(grads))
+                scalars.update(facts)
+                if guard_nonfinite:
+                    new_state = guard_nonfinite_state(
+                        state, new_state, scalars["nonfinite"]
+                    )
             return new_state, loss, scalars
 
         if grad_accum_steps == 1:
@@ -297,9 +301,9 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                     params, state.model_state, features, labels, mask,
                     rngs,
                 )
-                return loss_sum / jnp.maximum(weight, 1.0), (
-                    new_model_state, facts
-                )
+                with jax.named_scope("loss"):
+                    loss = loss_sum / jnp.maximum(weight, 1.0)
+                return loss, (new_model_state, facts)
 
             (loss, (new_model_state, facts)), grads = jax.value_and_grad(
                 compute_loss, has_aux=True
@@ -329,13 +333,14 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 (leaf.shape[0] // k, k) + leaf.shape[1:]
             ).swapaxes(0, 1)
 
-        micro = jax.tree_util.tree_map(
-            to_micro, (features, labels, mask)
-        )
+        with jax.named_scope("micro_batch"):
+            micro = jax.tree_util.tree_map(
+                to_micro, (features, labels, mask)
+            )
+            zero_grads = jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), state.params
+            )
         grad_fn = jax.value_and_grad(_loss_sum, has_aux=True)
-        zero_grads = jax.tree_util.tree_map(
-            lambda p: jnp.zeros(p.shape, jnp.float32), state.params
-        )
 
         def body(carry, micro_slice):
             grads_acc, loss_acc, weight_acc, model_state, i = carry
@@ -348,11 +353,12 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 state.params, model_state, m_features, m_labels, m_mask,
                 micro_rngs,
             )
-            grads_acc = jax.tree_util.tree_map(
-                lambda a, g: a + cast_floating(g, jnp.float32),
-                grads_acc,
-                grads,
-            )
+            with jax.named_scope("micro_batch"):
+                grads_acc = jax.tree_util.tree_map(
+                    lambda a, g: a + cast_floating(g, jnp.float32),
+                    grads_acc,
+                    grads,
+                )
             return (
                 grads_acc,
                 loss_acc + loss_sum,
@@ -368,10 +374,11 @@ def make_train_step(model, loss_fn, tx, compute_dtype=None,
                 micro,
             )
         )
-        weight = jnp.maximum(weight, 1.0)
-        grads = jax.tree_util.tree_map(
-            lambda g: g / weight, grads_sum
-        )
+        with jax.named_scope("micro_batch"):
+            weight = jnp.maximum(weight, 1.0)
+            grads = jax.tree_util.tree_map(
+                lambda g: g / weight, grads_sum
+            )
         new_state, loss = _apply_update(
             state, grads, loss_sum / weight, new_model_state
         )
