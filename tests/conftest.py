@@ -43,9 +43,14 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 # driver's command (ROADMAP.md "Tier-1 verify"): six xdist workers,
 # `--dist loadfile` (a file runs whole on one worker, so the heaviest
 # file is the run's tail) and 1,470 s for the whole run. ROADMAP.md
-# Queue 3 item 12 has the rule that keeps it there: no file outside
-# tests/benchmark_harness/ past 200 s summed, and LIMIT below on every
-# test.
+# Queue 3 item 12 has the rule that keeps it there (PR 63's): no file
+# outside tests/benchmark_harness/ past 100 s summed in the junit file
+# of the driver's command (it was 200), no case outside the
+# live-process files past 30 s, and LIMIT below, 240 s as it was, on
+# every test. What several cases of a file share (a built model, a
+# traced step, a lowered interpreted kernel, a reference's result) is
+# made once a file and every case asserts on it; a module-scoped
+# fixture's set-up counts against the limit of the first case to ask.
 # ---------------------------------------------------------------------------
 
 SLOW_BY_DURATION = {

@@ -37,22 +37,32 @@ def _inputs(seq, dtype, decay=1.0, seed=0, batch=2, hk=2, hv=4, dim=16):
         beta.astype(jnp.promote_types(dtype, jnp.float32)))
 
 
-def _value_and_grads(rule, args, jaxpr=False):
-    """``rule``'s output and the gradients of its five arguments, one
-    program (a new one a call: ``rule`` is traced under whatever the
-    test has patched by then) and one forward pass, the differentiated
-    one; an interpreted kernel costs by the trace, and what a kernel
-    returns without residuals is its own file's comparison. With
-    ``jaxpr``, (the traced program's text, the values): the kernels'
-    names are read from the trace that runs."""
+def _program(rule):
+    """``rule``'s output and the gradients of its five arguments as one
+    program, not yet traced, from one forward pass, the differentiated
+    one. A file whose cases differ in values alone keeps one a shape
+    and calls it."""
     def loss(*a):
         out = rule(*a)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
-    traced = jax.jit(jax.grad(
-        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).trace(*args)
-    grads, out = traced.lower().compile()(*args)
-    values = (out,) + grads
+    def values(*a):
+        grads, out = jax.grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(*a)
+        return (out,) + grads
+
+    return jax.jit(values)
+
+
+def _value_and_grads(rule, args, jaxpr=False):
+    """``_program(rule)`` run on ``args`` (a new program a call:
+    ``rule`` is traced under whatever the test has patched by then); an
+    interpreted kernel costs by the trace, and what a kernel returns
+    without residuals is its own file's comparison. With ``jaxpr``,
+    (the traced program's text, the values): the kernels' names are
+    read from the trace that runs."""
+    traced = _program(rule).trace(*args)
+    values = traced.lower().compile()(*args)
     return (str(traced.jaxpr), values) if jaxpr else values
 
 

@@ -212,13 +212,16 @@ def test_the_one_pass_is_the_definition_block_by_block(trained):
     block b]`` gives block b's logits of the one 2 L pass."""
     _, params, tokens, noisy, _, outputs = trained
     assert outputs["logits"].shape == (2, LENGTH, VOCAB)
-    # a program a length: eight, each the whole definition
+    # one program for the eight: under the definition's own mask a
+    # position sees no block after its own, so the blocks after b are
+    # there for the length alone and block b's logits are those of the
+    # sequence that ends with it
     definition = jax.jit(
         lambda sequence: Definition().apply({"params": params}, sequence))
     for b in range(LENGTH // BLOCK):
         lo, hi = b * BLOCK, (b + 1) * BLOCK
         sequence = jnp.concatenate(
-            [tokens[:, :lo], noisy[:, lo:hi]], axis=1)
+            [tokens[:, :lo], noisy[:, lo:hi], tokens[:, hi:]], axis=1)
         want = definition(sequence)[:, lo:hi]
         np.testing.assert_allclose(
             outputs["logits"][:, lo:hi], want, atol=2e-5, rtol=2e-5)
@@ -330,8 +333,8 @@ def test_the_step_hands_the_model_a_noise_stream_folded_from_the_step():
     _, _, scalars = step(again, batch)
     assert float(scalars["noise"]["mean_t"]) == seen[0]
     # without the health scalars the step returns what it always did
-    bare = jax.jit(make_train_step(model, moe_transformer.loss, tx))
-    assert len(bare(again, batch)) == 2
+    bare = make_train_step(model, moe_transformer.loss, tx)
+    assert len(jax.eval_shape(bare, again, batch)) == 2
 
 
 # --- what was there is what it was -----------------------------------
@@ -352,7 +355,9 @@ def _next_token_step_jaxpr():
              MASK_KEY: jnp.ones((2,), jnp.float32)}
     step = make_train_step(
         model, moe_transformer.loss, tx, jnp.bfloat16, health=True)
-    return state, batch, step, str(jax.make_jaxpr(step)(state, batch))
+    # the text is read from the trace that runs
+    traced = jax.jit(step).trace(state, batch)
+    return state, batch, traced, str(traced.jaxpr)
 
 
 def test_a_next_token_model_traces_no_part_of_the_objective():
@@ -360,10 +365,10 @@ def test_a_next_token_model_traces_no_part_of_the_objective():
     ``bd/`` scope, the causal mask; its only new operation is the
     unused ``noise`` key's ``fold_in`` beside ``dropout``'s, which the
     compiler drops."""
-    state, batch, step, text = _next_token_step_jaxpr()
+    state, batch, traced, text = _next_token_step_jaxpr()
     assert "bd/" not in text and "random_bits" not in text
     assert text.count("random_fold_in") + text.count("threefry2x32") <= 4
-    _, _, scalars = jax.jit(step)(state, batch)
+    _, _, scalars = traced.lower().compile()(state, batch)
     assert "noise" not in scalars and "routing" in scalars
     # the parameters are the ones a next_token model always had
     assert set(state.params["block_0"]["attn"]) == {

@@ -469,6 +469,7 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
 
     master = spawn_master("first")
     runner = None
+    killed = threading.Event()
     try:
         _wait_port(master_port, timeout=min(90, left()))
         mc = MasterClient("localhost:%d" % master_port, worker_id=0)
@@ -480,12 +481,26 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
             minibatch_size=32,
             wait_sleep_secs=0.1,
         )
+        # mid-epoch by construction: 16 tasks over 2 epochs, and the
+        # worker's sixth report waits for the kill. (It used to be "by
+        # construction" only on a loaded machine: on a quiet one the
+        # worker reported all 16 in the half second before the journal
+        # showed three, and the relaunched master found the job
+        # finished and left before the test looked for its port.)
+        reports = iter(range(1, 17))
+        report = mc.report_task_result
+
+        def held_at_the_sixth(*args, **kwargs):
+            if next(reports, 17) > 5:
+                killed.wait(timeout=left())
+            return report(*args, **kwargs)
+
+        monkeypatch.setattr(mc, "report_task_result", held_at_the_sixth)
         runner = threading.Thread(target=worker.run, daemon=True)
         runner.start()
 
         # let the job make real progress, then kill the master cold
-        # while tasks are still in flight (mid-epoch by construction:
-        # 16 tasks over 2 epochs, we kill before 8 are done)
+        # while tasks are still in flight
         deadline = time.time() + min(120, left())
         while time.time() < deadline:
             done = done_ops()
@@ -495,6 +510,7 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
         assert len(done) >= 3, "job made no progress before the kill"
         master.send_signal(signal.SIGKILL)
         master.wait(timeout=30)
+        killed.set()
         killed_at = len(done_ops())
         # the worker is inside the outage window from here until the
         # relaunch has imported what a master imports: seconds
@@ -528,6 +544,7 @@ def test_master_sigkill_mid_epoch_replay_no_shard_lost_or_doubled(
         runner.join(timeout=left() + 20)
         assert not runner.is_alive(), "worker never finished"
     finally:
+        killed.set()
         if master.poll() is None:
             master.kill()
         if runner is not None and runner.is_alive():

@@ -205,8 +205,14 @@ def custom_model(mesh=None):
 
 
 def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
-    """Both phases, tiny, worker on the CPU — the same run_*_phase code
-    (processes, gRPC, log parsing, checks) the chip run executes."""
+    """All three phases, tiny, worker on the CPU: the same run_*_phase
+    code (processes, gRPC, log parsing, checks) the chip run executes.
+    A phase is seconds of Python starting (a master, a worker, two PS)
+    around a step that compiles in one, and the three share neither a
+    directory nor a port nor a program, so they run side by side, each
+    with children of its own, and are checked in their order."""
+    from concurrent.futures import ThreadPoolExecutor
+
     import chip_smoke
 
     zoo = tmp_path / "tiny_transformer.py"
@@ -218,9 +224,20 @@ def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
     # one device, as on the one-chip machine (conftest gives THIS
     # process eight virtual ones, and children would inherit them)
     monkeypatch.delenv("XLA_FLAGS")
+
+    class FourDevices(chip_smoke.Children):
+        """Children of the four-chip host: four virtual devices."""
+
+        def start(self, argv, env, log_path):
+            return super().start(argv, dict(
+                env, XLA_FLAGS="--xla_force_host_platform_device_count=4"
+            ), log_path)
+
     on_cpu = dict(
         platform="cpu", worker_platforms="cpu", attention="xla",
     )
+    # eight steps: the fewest at which check_training compares the
+    # first steps' loss with the last's
     dense = dict(
         chip_smoke.DENSE, model_zoo=str(zoo), seq=128, vocab=512,
         minibatch=4, steps_per_task=4, tasks=2,
@@ -229,34 +246,34 @@ def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
         chip_smoke.SPARSE, minibatch=64, steps_per_task=4, tasks=2,
     )
     so_mtime = os.path.getmtime(chip_smoke.NATIVE_SO)
-    children = chip_smoke.Children()
+    one, ps, four = chip_smoke.Children(), chip_smoke.Children(), FourDevices()
     try:
-        problems, report, _ = chip_smoke.run_dense_phase(
-            children, str(tmp_path / "dense"), dense, on_cpu
-        )
+        with ThreadPoolExecutor(3) as pool:
+            phases = [
+                pool.submit(chip_smoke.run_dense_phase, one,
+                            str(tmp_path / "dense"), dense, on_cpu),
+                pool.submit(chip_smoke.run_sparse_phase, ps,
+                            str(tmp_path / "sparse"), sparse, on_cpu,
+                            so_mtime),
+                # the checks do bite: a chip run must not pass on a CPU
+                # worker's log
+                pool.submit(chip_smoke.run_dense_phase, four,
+                            str(tmp_path / "dense4"), dense,
+                            chip_smoke.ON_CHIP | dict(worker_platforms="cpu")),
+            ]
+        problems, report, _ = phases[0].result()
         assert not problems, problems
         assert report["steps"] == 8
         assert report["attention"] == ["xla"]
         assert "train_step" in report["compiles"]
-        children.stop_all()
-        problems, report, _ = chip_smoke.run_sparse_phase(
-            children, str(tmp_path / "sparse"), sparse, on_cpu, so_mtime
-        )
+        problems, report, _ = phases[1].result()
         assert not problems, problems
         assert report["steps"] == 8
         assert report["store_backend"] == ["native", "native"]
         assert report["tier_hits"] > 0
-        children.stop_all()
         # four devices, as on the four-chip host: the worker picks the
-        # SPMD trainer and the model receives its mesh. And the checks
-        # do bite: a chip run must not pass on a CPU worker's log.
-        monkeypatch.setenv(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
-        )
-        problems, report, logs = chip_smoke.run_dense_phase(
-            children, str(tmp_path / "dense4"), dense,
-            chip_smoke.ON_CHIP | dict(worker_platforms="cpu"),
-        )
+        # SPMD trainer and the model receives its mesh
+        problems, report, logs = phases[2].result()
         assert report["steps"] == 8 and report["device_count"] == 4
         assert "spmd_train_step" in report["compiles"]
         assert len(problems) == 2, problems
@@ -266,4 +283,5 @@ def test_chip_smoke_rehearsal_on_cpu(tmp_path, monkeypatch):
         assert "SPMD state placement" in worker_log
         assert "split into 4 shards of (1, 128)" in worker_log
     finally:
-        children.stop_all()
+        for children in (one, ps, four):
+            children.stop_all()

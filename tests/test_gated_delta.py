@@ -4,6 +4,7 @@ equations, at small sizes on the CPU with seeded inputs. Float64 where
 the comparison is exact (the chunked form reorders sums, nothing else),
 bfloat16 where the precision rules are the subject."""
 
+import functools
 import logging
 
 import jax
@@ -23,9 +24,21 @@ from elasticdl_tpu.ops.gated_delta import (
 )
 from tests.gdn_common import (  # noqa: F401
     _inputs,
+    _program,
     _value_and_grads,
     x64,
 )
+
+
+# a shape's three decays are values: a program a shape for the rule, a
+# program a length for the recurrence
+_RECURRENCE = _program(gated_delta_recurrence)
+
+
+@functools.lru_cache(maxsize=None)
+def _rule(chunk, segment):
+    return _program(
+        lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment))
 
 
 @pytest.mark.parametrize("decay", [1e-3, 1.0, 30.0],
@@ -40,9 +53,7 @@ from tests.gdn_common import (  # noqa: F401
 def test_chunked_rule_is_the_recurrence(x64, seq, chunk, segment, decay):
     """Values and all five gradients, in float64: equal to rounding."""
     args = _inputs(seq, jnp.float64, decay)
-    got = _value_and_grads(
-        lambda *a: gated_delta_rule(*a, chunk=chunk, segment=segment), args)
-    want = _value_and_grads(gated_delta_recurrence, args)
+    got, want = _rule(chunk, segment)(*args), _RECURRENCE(*args)
     for a, b in zip(got, want):
         assert a.shape == b.shape and a.dtype == b.dtype
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-11 * (
@@ -171,19 +182,20 @@ def test_precision_rules_in_bfloat16():
     a matmul operand, so a second rounding of the carry changes
     little."""
     args = _inputs(512, jnp.float32, decay=2.0, batch=1)
-    want = gated_delta_recurrence(*args)
+    want = jax.jit(gated_delta_recurrence)(*args)
     low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
     err = lambda out: float(
         jnp.sqrt(jnp.mean((out.astype(jnp.float32) - want) ** 2)
                  / jnp.mean(want ** 2)))
-    stated = err(gated_delta_rule(*low, chunk=64))
+    rule = lambda **kw: jax.jit(
+        lambda *a: gated_delta_rule(*a, chunk=64, **kw))(*low)
+    out = rule()
+    stated = err(out)
     assert stated < 0.01
-    assert err(gated_delta_rule(
-        *low, chunk=64, decay_dtype=jnp.bfloat16)) > 4 * stated
-    carried = err(gated_delta_rule(
-        *low, chunk=64, state_dtype=jnp.bfloat16))
+    assert err(rule(decay_dtype=jnp.bfloat16)) > 4 * stated
+    carried = err(rule(state_dtype=jnp.bfloat16))
     assert 0.9 * stated < carried < 1.5 * stated
-    assert gated_delta_rule(*low, chunk=64).dtype == jnp.bfloat16
+    assert out.dtype == jnp.bfloat16
 
 
 def test_the_output_is_named_for_remat_policies():

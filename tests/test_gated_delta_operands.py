@@ -18,6 +18,16 @@ from tests.gdn_common import _split_inputs, _xla_lines
 _OPERANDS = ("decay", "w", "k_onto", "q_into", "p", "u")
 
 
+@jax.jit
+def _with_and_without_residuals(*args):
+    """The kernel's two forms under one program: called an operation at
+    a time, an interpreted kernel pays its dispatches besides its
+    lowering."""
+    return (gated_delta.gdn_prepare_fwd(*args, interpret=True),
+            gated_delta.gdn_prepare_fwd(
+                *args, residuals=True, interpret=True))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("chunk,rep,num", [
@@ -35,9 +45,7 @@ def test_the_operands_kernel_is_chunk_operands(chunk, rep, num, dtype):
     may differ by one rounding, ``U`` by ``T``'s."""
     args = _split_inputs(num, chunk, rep, dtype)
     want = _xla_lines(*args)
-    plain = gated_delta.gdn_prepare_fwd(*args, interpret=True)
-    *got, inverse = gated_delta.gdn_prepare_fwd(
-        *args, residuals=True, interpret=True)
+    plain, (*got, inverse) = _with_and_without_residuals(*args)
     assert len(plain) == len(got) == 6
     exact = dtype == jnp.float32
     for name, a, b, c in zip(_OPERANDS, got, want, plain):

@@ -1,8 +1,9 @@
 """``gdn_prepare_bwd`` (``ops/gated_delta.py``, ISSUE 39) in interpret
-mode on the CPU against autodiff of ``_chunk_operands``; the rule with
-``prep=pallas`` in the cell's dtypes; where ``prepare_impl`` chooses the
-kernels; what a grid step takes. The forward kernel alone is
-``test_gated_delta_operands.py``'s."""
+mode on the CPU against autodiff of ``_chunk_operands``; where
+``prepare_impl`` chooses the kernels; what a grid step takes. The
+forward kernel alone is ``test_gated_delta_operands.py``'s, the rule
+with ``prep=pallas`` in the cell's dtypes
+``test_gated_delta_bfloat16.py``'s."""
 
 import functools
 import logging
@@ -16,20 +17,26 @@ from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import jax_compat
 from elasticdl_tpu.ops import gated_delta
-from elasticdl_tpu.ops.gated_delta import (
-    gated_delta_recurrence,
-    gated_delta_rule,
-)
+from elasticdl_tpu.ops.gated_delta import gated_delta_rule
 from tests.gdn_common import (  # noqa: F401
     _MANUAL,
     _MESH4,
-    _force_pallas,
-    _inputs,
     _split_inputs,
-    _value_and_grads,
     _xla_lines,
     x64,
 )
+
+
+@jax.jit
+def _both_vjps(args, cotangents, low):
+    """(the kernels' gradients, autodiff's of the XLA lines): one
+    program a shape and dtype, so cases that differ in values alone
+    lower the two interpreted kernels once."""
+    want = jax.vjp(_xla_lines, *args)[1](cotangents)
+    *_, inverse = gated_delta.gdn_prepare_fwd(
+        *args, residuals=True, interpret=True)
+    return gated_delta.gdn_prepare_bwd(
+        *args, inverse, *low, interpret=True), want
 
 
 @pytest.mark.parametrize("chunk,rep,num,dtype,decay", [
@@ -60,11 +67,7 @@ def test_the_operands_kernel_s_vjp(chunk, rep, num, dtype, decay):
     # du arrives in the compute dtype, as ``gdn_scan_bwd`` hands it on
     low = cotangents[:-1] + [cotangents[-1].astype(dtype)]
     cotangents[-1] = low[-1].astype(jnp.float32)
-    want = jax.jit(lambda args, cotangents: jax.vjp(
-        _xla_lines, *args)[1](cotangents))(args, tuple(cotangents))
-    *_, inverse = gated_delta.gdn_prepare_fwd(
-        *args, residuals=True, interpret=True)
-    got = gated_delta.gdn_prepare_bwd(*args, inverse, *low, interpret=True)
+    got, want = _both_vjps(args, tuple(cotangents), tuple(low))
     exact = dtype == jnp.float32
     for name, a, b in zip(("dq", "dk", "dv", "dg", "dbeta"), got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, name
@@ -73,29 +76,6 @@ def test_the_operands_kernel_s_vjp(chunk, rep, num, dtype, decay):
         np.testing.assert_allclose(
             a, b, rtol=0, atol=(3e-5 if exact else 2e-2) * np.abs(b).max(),
             err_msg=name)
-
-
-def test_the_operands_kernels_hold_bfloat16_s_rounding(monkeypatch):
-    """The cell's dtypes over two segments and a padded length: the
-    rule's output and gradients with ``prep=pallas`` stay as close to
-    the float32 recurrence's as ``prep=xla``'s."""
-    # 200 tokens: a whole segment of two chunks and a padded one; two
-    # value heads to the one key head
-    args = _inputs(200, jnp.float32, decay=2.0, batch=1, hk=1, hv=2,
-                   dim=128)
-    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    rule = lambda *a: gated_delta_rule(*a, chunk=64, segment=2)
-    want = _value_and_grads(gated_delta_recurrence, args)
-    _force_pallas(monkeypatch)
-    got = _value_and_grads(rule, low)
-    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    by_xla = _value_and_grads(rule, low)
-    assert got[0].dtype == jnp.bfloat16
-    err = lambda a, b: float(jnp.sqrt(
-        jnp.mean((a.astype(jnp.float32) - b) ** 2) / jnp.mean(b ** 2)))
-    for a, b, c in zip(got, by_xla, want):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        assert err(a, c) < 1.25 * err(b, c) + 1e-4
 
 
 @pytest.mark.parametrize(

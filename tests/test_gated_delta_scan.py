@@ -100,9 +100,10 @@ def test_the_scan_s_kernels_are_the_lax_scan(monkeypatch, chunk, rep, hk,
     # V' and the states the backward reads, as the scan hands them on
     state, last, w, k_onto, q_into, attn, u = args
     decay = jnp.broadcast_to(jnp.exp(last)[..., None], last.shape + (128,))
-    leaving, o, new_v, states = gated_delta.gdn_scan_fwd(
-        state, decay, w, k_onto, q_into.astype(dtype), attn.astype(dtype),
-        u, residuals=True)
+    leaving, o, new_v, states = jax.jit(functools.partial(
+        gated_delta.gdn_scan_fwd, residuals=True))(
+            state, decay, w, k_onto, q_into.astype(dtype),
+            attn.astype(dtype), u)
     np.testing.assert_array_equal(np.float32(leaving), np.float32(got[0]))
     assert new_v.dtype == dtype and states.dtype == jnp.float32
     np.testing.assert_array_equal(

@@ -77,31 +77,3 @@ def test_the_rule_by_the_scan_s_kernels(monkeypatch, seq, chunk, segment,
         scale = 1e-3 + float(jnp.abs(c).max())
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-5 * scale)
         np.testing.assert_allclose(a, c, rtol=0, atol=1e-4 * scale)
-
-
-def test_the_scan_s_kernels_hold_bfloat16_s_rounding(monkeypatch):
-    """The cell's dtypes: bfloat16 operands, float32 state and decay.
-    The kernels' output is the ``lax.scan``'s bit for bit, and their
-    gradients stay as close to the float32 recurrence's as its own."""
-    # two segments of two chunks, two value heads to the key head
-    args = _inputs(256, jnp.float32, decay=2.0, batch=1, hk=1, hv=2,
-                   dim=128)
-    low = tuple(x.astype(jnp.bfloat16) for x in args[:3]) + args[3:]
-    rule = lambda *a: gated_delta_rule(*a, chunk=64, segment=2)
-    want = _value_and_grads(gated_delta_recurrence, args)
-    by_xla = _value_and_grads(rule, low)
-    _force_pallas(monkeypatch)
-    # the scan's kernels after the XLA lines (the operands' kernels
-    # cumulate g in another order: their own test below)
-    monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
-    got = _value_and_grads(rule, low)
-    assert got[0].dtype == jnp.bfloat16
-    np.testing.assert_array_equal(
-        np.float32(got[0]), np.float32(by_xla[0]))
-    # the call alone keeps no residuals: other kernels, the same bits
-    np.testing.assert_array_equal(
-        np.float32(jax.jit(rule)(*low)), np.float32(got[0]))
-    err = lambda a, b: float(jnp.sqrt(
-        jnp.mean((a.astype(jnp.float32) - b) ** 2) / jnp.mean(b ** 2)))
-    for a, b, c in zip(got[1:], by_xla[1:], want[1:]):
-        assert err(a, c) < 1.25 * err(b, c) + 1e-4

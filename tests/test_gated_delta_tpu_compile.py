@@ -1,8 +1,9 @@
 """The gated delta rule (``ops/gated_delta.py``) compiled for a v5e
 that is described, not attached (the TPU compiler is installed here):
 what interpret mode cannot see. The rule at the cell's shape by each of
-its paths, the operands' kernels alone, and the rule sharded over the
-four chips of a described v5e:2x2. Every shape here is one the
+its paths and the rule sharded over the four chips of a described
+v5e:2x2 (the operands' kernels alone are
+``tests/test_gated_delta_operands_tpu_compile.py``'s). Every shape here is one the
 assertion is about (the cell's segment, a block the kernels' budget
 chooses, the mesh's shards): that THIS shape compiles inside the VMEM
 the kernels state. The flash kernels' are
@@ -11,7 +12,6 @@ the kernels state. The flash kernels' are
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
 
-import functools
 import re
 
 import jax
@@ -130,44 +130,6 @@ def test_the_chunked_rule_compiles_at_the_cell_s_shape(
         step = gated_delta.prepare_block(2, 128, 64, 128, 128, 2)
         assert gated_delta.prepare_vmem_bytes(
             2, step, 64, 128, 128, 2, kind) < gated_delta._PREPARE_VMEM_LIMIT
-
-
-@pytest.mark.parametrize("chunk,rep,heads,chunks,dtype", [
-    (64, 2, 16, 128, "bfloat16"),   # the cell's segment
-    (128, 2, 16, 64, "bfloat16"),
-    (64, 1, 4, 16, "float32"),
-    (128, 1, 2, 3, "float32"),      # a block of all the chunks, no tile
-], ids=lambda v: str(v))
-def test_the_operands_kernels_compile(chip, chunk, rep, heads, chunks, dtype):
-    """``gdn_prepare_fwd`` (with and without ``T``) and
-    ``gdn_prepare_bwd`` alone for a described v5e: the lane-row
-    concatenations, the masked sums over lanes and rows, the one-row
-    loads of ``g`` and the transposed products are what the interpreter
-    never refuses."""
-    from elasticdl_tpu.ops import gated_delta
-
-    dtype = jnp.dtype(dtype)
-    struct = lambda shape, dtype: jax.ShapeDtypeStruct(
-        shape, dtype, sharding=chip)
-    args = (
-        struct((1, heads, 1, chunks, chunk, 128), dtype),
-        struct((1, heads, 1, chunks, chunk, 128), dtype),
-        struct((1, heads, rep, chunks, chunk, 128), dtype),
-        struct((1, heads, rep, chunks, chunk), jnp.float32),
-        struct((1, heads, rep, chunks, chunk), jnp.float32),
-    )
-    for residuals in (False, True):
-        hlo = jax.jit(functools.partial(
-            gated_delta.gdn_prepare_fwd, residuals=residuals)).lower(
-                *args).compile().as_text()
-        assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_fwd": 1}
-    outs = jax.eval_shape(functools.partial(
-        gated_delta.gdn_prepare_fwd, residuals=True), *args)
-    *operands, u, inverse = [struct(o.shape, o.dtype) for o in outs]
-    hlo = jax.jit(gated_delta.gdn_prepare_bwd).lower(
-        *args, inverse, *operands, struct(u.shape, dtype)
-    ).compile().as_text()
-    assert device_obs.pallas_kernels(hlo) == {"gdn_prepare_bwd": 1}
 
 
 def test_the_chunked_rule_stays_partitionable_over_a_mesh(

@@ -8,8 +8,6 @@ without its rotation, the scale on both attention paths, each
 multiplier, the refusals by name, the convolution without a bias, and
 the trees of the older models, which this PR leaves leaf for leaf."""
 
-import hashlib
-import json
 import os
 
 import jax
@@ -17,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib.refcheck import compare, load_by_path
+from benchmark.lib.refcheck import load_by_path
 from elasticdl_tpu.models import moe_transformer
 from elasticdl_tpu.models.moe_transformer import MoeTransformerLM
 from elasticdl_tpu.models.transformer import (
@@ -32,21 +30,15 @@ from elasticdl_tpu.models.transformer import (
     mamba_gate_facts,
 )
 from elasticdl_tpu.ops import qkv_conv
+from tests.lm_common import PRESET, REPO, read_json, reference_check, tree_digest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PRESET = os.path.join(REPO, "tests", "benchmark_harness", "preset")
 GRANITE = os.path.join(
     REPO, "benchmark", "configs", "granite-4.0-h-micro-1chip")
 
 
-def _json(*parts):
-    with open(os.path.join(*parts)) as f:
-        return json.load(f)
-
-
 @pytest.fixture(scope="module")
 def config():
-    return _json(PRESET, "configs", "tiny-granite", "config.json")
+    return read_json(PRESET, "configs", "tiny-granite", "config.json")
 
 
 @pytest.fixture(scope="module")
@@ -132,29 +124,37 @@ def test_the_facts_see_a_chunk_that_underflows():
 
 
 @pytest.fixture(scope="module")
-def checked(config):
-    """The benchmark's own check of the tiny ten-layer model, run once:
-    (errors by name and whether they pass, the system's outputs)."""
-    spec = {"config": config,
-            "cell": _json(PRESET, "workloads", "tiny-granite-s128.json"),
-            "zoo": os.path.join(GRANITE, "zoo.py"),
-            "reference": os.path.join(GRANITE, "reference.py")}
-    tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, 512, size=(128,)), jnp.int32)
-    parts = load_by_path(
-        "granite_check_for_lm", os.path.join(GRANITE, "check.py")).build(
-            spec, tokens)
-    variables = jax.jit(parts["init"])(jax.random.PRNGKey(3), tokens)
-    system = jax.jit(parts["system"])(variables, tokens)
-    plain = jax.jit(parts["reference"])(variables, tokens)
-    return compare(system, plain, parts["tolerance"]), system
+def two_layers(config):
+    """The preset cut to one layer of every kind the check names: a
+    Mamba-2 layer and an attention layer (the ten-layer preset repeats
+    the Mamba-2 layer eight times more;
+    ``tests/benchmark_harness/test_granite_reference.py`` runs that
+    one)."""
+    return dict(
+        config, num_hidden_layers=2, layer_types=["mamba", "attention"],
+        check_leaves=[
+            "wte/embedding", "block_0/attn/in_proj/kernel",
+            "block_0/attn/A_log", "block_0/attn/conv_kernel",
+            "block_0/attn/conv_bias", "block_0/attn/dt_bias",
+            "block_0/attn/D", "block_0/attn/out_norm_scale",
+            "block_0/mlp_down/kernel", "block_1/attn/key/kernel",
+            "block_1/attn/out_proj/kernel"])
 
 
-def test_the_model_is_the_reference_s(checked, config):
-    (errors, ok), system = checked
+@pytest.fixture(scope="module")
+def checked(two_layers):
+    """The benchmark's own check of the tiny two-layer model, run once:
+    (errors by name and whether they pass, the system's outputs, the
+    variables)."""
+    return reference_check(
+        GRANITE, two_layers, "tiny-granite-s128.json", "granite")
+
+
+def test_the_model_is_the_reference_s(checked, two_layers):
+    (errors, ok), system, _ = checked
     assert ok, errors
     assert set(errors) == {"logits"} | {
-        "grad:" + path for path in config["check_leaves"]}
+        "grad:" + path for path in two_layers["check_leaves"]}
     # float32 on both sides: rounding, not bfloat16's
     assert max(errors.values()) < 1e-4, errors
     assert system["logits"].shape == (32, 512)
@@ -273,26 +273,35 @@ MULTIPLIERS = {
 }
 
 
-@pytest.mark.parametrize("left_out", list(MULTIPLIERS))
-def test_each_multiplier_changes_the_result(left_out):
-    base = dict(
-        vocab_size=64, num_layers=2, num_heads=4, embed_dim=32,
-        layer_kinds=("mamba", "full"),
-        mamba=Mamba2Dims(4, 8, 8, 1, 4, chunk=16), first_k_dense=2,
-        dense_act="swiglu", norm="rmsnorm", tie_embeddings=True,
-        attention_impl="xla")
+_TWO_LAYERS = dict(
+    vocab_size=64, num_layers=2, num_heads=4, embed_dim=32,
+    layer_kinds=("mamba", "full"),
+    mamba=Mamba2Dims(4, 8, 8, 1, 4, chunk=16), first_k_dense=2,
+    dense_act="swiglu", norm="rmsnorm", tie_embeddings=True,
+    attention_impl="xla")
+
+
+@pytest.fixture(scope="module")
+def with_every_multiplier():
+    """(tokens, variables, logits) of the two-layer model with all five
+    fields set, made once for the five cases."""
     tokens = jnp.arange(24, dtype=jnp.int32)[None] % 64
-    full = MoeTransformerLM(**base, **MULTIPLIERS)
-    variables = full.init(jax.random.PRNGKey(0), tokens)
+    full = MoeTransformerLM(**_TWO_LAYERS, **MULTIPLIERS)
+    variables = jax.jit(full.init)(jax.random.PRNGKey(0), tokens)
+    return tokens, variables, jax.jit(full.apply)(variables, tokens)
+
+
+@pytest.mark.parametrize("left_out", list(MULTIPLIERS))
+def test_each_multiplier_changes_the_result(left_out, with_every_multiplier):
+    tokens, variables, got = with_every_multiplier
     fields = dict(MULTIPLIERS)
     fields[left_out] = True if left_out == "rotary" else None
-    other = MoeTransformerLM(**base, **fields)
+    other = MoeTransformerLM(**_TWO_LAYERS, **fields)
     # the same tree: a multiplier is no parameter
-    assert jax.tree_util.tree_structure(
-        other.init(jax.random.PRNGKey(0), tokens)
+    assert jax.tree_util.tree_structure(jax.eval_shape(
+        other.init, jax.random.PRNGKey(0), tokens)
     ) == jax.tree_util.tree_structure(variables)
-    got, without = full.apply(variables, tokens), other.apply(
-        variables, tokens)
+    without = jax.jit(other.apply)(variables, tokens)
     assert float(jnp.abs(got - without).max()) > 1e-4
 
 
@@ -401,15 +410,4 @@ OLDER_TREES = {
 
 @pytest.mark.parametrize("name", list(OLDER_TREES))
 def test_the_older_models_trees_are_leaf_for_leaf_the_parent_s(name):
-    config = _json(PRESET, "configs", name, "config.json")
-    zoo = load_by_path(
-        "zoo_tree_" + name.replace("-", "_"), os.path.join(REPO, config["zoo"]))
-    model = zoo.model_from_config(config)
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32)))
-    leaves = sorted(
-        ("/".join(str(getattr(p, "key", p)) for p in path),
-         tuple(leaf.shape), str(leaf.dtype))
-        for path, leaf in jax.tree_util.tree_leaves_with_path(shapes))
-    assert (len(leaves), hashlib.sha256(
-        repr(leaves).encode()).hexdigest()) == OLDER_TREES[name]
+    assert tree_digest(name)[0] == OLDER_TREES[name]

@@ -41,12 +41,22 @@ def trained(params, seed):
     return params
 
 
-def coefficients(dims, x, seed=3):
+def coefficients(dims, x, seed=3, y=None):
+    """The module's reading ``u``, what it writes back of ``y`` (of
+    zeros where none is given), its facts, ``H_res`` and the
+    parameters: one program for the twenty iterations."""
     module = T.HyperConnection(dims)
-    params = trained(module.init(jax.random.PRNGKey(0), x)["params"], seed)
-    (u, write, facts), sown = module.apply(
-        {"params": params}, x, mutable=["intermediates"])
-    return u, write, facts, sown["intermediates"]["h_res"][0], params
+    params = trained(
+        jax.jit(module.init)(jax.random.PRNGKey(0), x)["params"], seed)
+
+    @jax.jit
+    def run(params, x, y):
+        (u, write, facts), sown = module.apply(
+            {"params": params}, x, mutable=["intermediates"])
+        return u, write(y), facts, sown["intermediates"]["h_res"][0]
+
+    y = jnp.zeros_like(x[:, 0]) if y is None else y
+    return run(params, x, y) + (params,)
 
 
 @pytest.mark.parametrize("n", [2, 4])
@@ -82,7 +92,8 @@ def test_fewer_sinkhorn_iterations_leave_the_rows_off():
 def test_the_mixes_are_the_equations():
     n = 4
     x = streams_of(2, n)
-    u, write, _, h_res, params = coefficients(T.HyperDims(n), x)
+    y = jax.random.normal(jax.random.PRNGKey(9), (2, SEQ, DIM))
+    u, written, _, h_res, params = coefficients(T.HyperDims(n), x, y=y)
     flat = x.transpose(0, 2, 1, 3).reshape(2, SEQ, n * DIM)
     flat = flat / jnp.sqrt((flat * flat).mean(-1, keepdims=True) + 1e-6)
     project = lambda name: flat @ params[name].reshape(n * DIM, -1)
@@ -90,10 +101,9 @@ def test_the_mixes_are_the_equations():
     h_post = 2 * jax.nn.sigmoid(0.8 * project("p_post") + params["b_post"])
     np.testing.assert_allclose(
         u, jnp.einsum("bsn,bnsc->bsc", h_pre, x), atol=1e-5)
-    y = jax.random.normal(jax.random.PRNGKey(9), (2, SEQ, DIM))
     want = (jnp.einsum("mnbs,bnsc->bmsc", h_res, x)
             + jnp.einsum("bsm,bsc->bmsc", h_post, y))
-    np.testing.assert_allclose(write(y), want, atol=1e-5)
+    np.testing.assert_allclose(written, want, atol=1e-5)
 
 
 def test_initial_values_read_and_write_one_stream():
@@ -243,8 +253,16 @@ def variables(tokens):
         jax.random.PRNGKey(0), t, training=False))(tokens)
 
 
+@pytest.fixture(scope="module")
+def outputs(tokens, variables):
+    """The training call's outputs, one program for the file: eagerly
+    the model is 2,700 dispatches and 260 small compiles a call."""
+    return jax.jit(lambda v, t: tiny_model().apply(
+        v, t, training=True, mutable=["moe_state"])[0])(variables, tokens)
+
+
 def test_the_model_owns_the_module_and_shares_embedding_and_head(
-        tokens, variables):
+        tokens, variables, outputs):
     params = variables["params"]
     assert {"mtp_proj", "mtp_hnorm", "mtp_enorm", "mtp_norm",
             "mtp_block"} <= set(params)
@@ -254,16 +272,14 @@ def test_the_model_owns_the_module_and_shares_embedding_and_head(
     assert sum(1 for name in params if "wte" in name or "lm_head" in name
                ) == 2
     assert set(variables["moe_state"]) == {"block_1", "mtp_block"}
-    model = tiny_model()
-    outputs, _ = model.apply(
-        variables, tokens, training=True, mutable=["moe_state"])
     assert outputs["mtp_logits"].shape == outputs["logits"].shape
     # a fact a block, the module's last
     assert outputs["mhc"]["row_err"].shape == (3,)
     assert outputs["mhc"]["diag_mean"].shape == (3,)
     # an eval call returns bare logits and runs no module
-    assert model.apply(variables, tokens).shape == (2, SEQ, 64)
-    total, terms = M.loss(tokens, outputs)
+    assert jax.eval_shape(
+        tiny_model().apply, variables, tokens).shape == (2, SEQ, 64)
+    total, terms = jax.jit(M.loss)(tokens, outputs)
     from elasticdl_tpu.train.losses import sparse_softmax_cross_entropy as ce
     main = ce(tokens[:, 1:-1], outputs["logits"][:, :-2]).mean(-1)
     mtp = ce(tokens[:, 2:], outputs["mtp_logits"][:, :-2]).mean(-1)
@@ -304,7 +320,8 @@ def test_what_is_not_built_is_refused_by_name(tokens, changes):
             jax.random.PRNGKey(0), tokens, training=False)
 
 
-def test_the_step_hands_out_the_second_loss_and_the_facts(tokens, variables):
+def test_the_step_hands_out_the_second_loss_and_the_facts(
+        tokens, variables, outputs):
     import optax
 
     from elasticdl_tpu.data.pipeline import MASK_KEY
@@ -325,21 +342,23 @@ def test_the_step_hands_out_the_second_loss_and_the_facts(tokens, variables):
     mtp = float(scalars["loss_terms"]["mtp_loss"])
     assert 0 < mtp and np.isfinite(float(loss))
     # the logged loss is the sum of its terms
-    outputs, _ = model.apply(
-        variables, tokens, training=True, mutable=["moe_state"])
-    total, terms = M.loss(tokens, outputs)
+    total, terms = jax.jit(M.loss)(tokens, outputs)
     assert float(loss) == pytest.approx(float(total.mean()), rel=1e-5)
     assert mtp == pytest.approx(float(terms["mtp_loss"].mean()), rel=1e-5)
     # a model without either compiles the step it compiled before: no
-    # new outputs
+    # new outputs (the step's outputs are read from its trace)
     plain = tiny_model(hc=None, mtp_layers=0)
-    init = plain.init(jax.random.PRNGKey(0), tokens, training=False)
-    plain_state = TrainState(
-        step=jnp.zeros((), jnp.int32), params=init["params"],
-        model_state={"moe_state": init["moe_state"]},
-        opt_state=tx.init(init["params"]))
-    _, _, scalars = jax.jit(step_fns.make_train_step(
-        plain, M.loss, tx, health=True))(plain_state, batch)
+
+    def plain_step(tokens):
+        init = plain.init(jax.random.PRNGKey(0), tokens, training=False)
+        plain_state = TrainState(
+            step=jnp.zeros((), jnp.int32), params=init["params"],
+            model_state={"moe_state": init["moe_state"]},
+            opt_state=tx.init(init["params"]))
+        return step_fns.make_train_step(
+            plain, M.loss, tx, health=True)(plain_state, batch)
+
+    _, _, scalars = jax.eval_shape(plain_step, tokens)
     assert "mhc" not in scalars and "loss_terms" not in scalars
 
 

@@ -67,12 +67,21 @@ def _lines(q, k, v, g, beta):
             attn.astype(q.dtype), u)
 
 
+# the kernels as programs of this file: cases of one shape and dtype
+# (``64-hard`` and ``64-mixed`` differ in values) lower them once
+_prepare = jax.jit(functools.partial(
+    gated_delta.kda_prepare_fwd, residuals=True, interpret=True))
+_prepare_bwd = jax.jit(functools.partial(
+    gated_delta.kda_prepare_bwd, interpret=True))
+_lines_vjp = jax.jit(lambda args, cotangents: jax.vjp(
+    _lines, *args)[1](cotangents))
+
+
 @functools.lru_cache(maxsize=None)
 def _case(name):
     """(inputs, the lines' operands, the kernel's operands, its T)."""
     args = _inputs(*_CASES[name])
-    *got, inverse = gated_delta.kda_prepare_fwd(
-        *args, residuals=True, interpret=True)
+    *got, inverse = _prepare(*args)
     return args, _lines(*args), got, inverse
 
 
@@ -114,7 +123,8 @@ def test_the_inverse_lies_as_the_scalar_kernel_leaves_it(name):
     layout, and without ``residuals`` the same six operands."""
     args, _, got, inverse = _case(name)
     chunk, rep, dtype, _ = _CASES[name]
-    plain = gated_delta.kda_prepare_fwd(*args, interpret=True)
+    plain = jax.jit(functools.partial(
+        gated_delta.kda_prepare_fwd, interpret=True))(*args)
     assert len(plain) == 6
     for operand, a, c in zip(_OPERANDS, got, plain):
         np.testing.assert_array_equal(
@@ -163,9 +173,8 @@ def test_the_operands_kernel_s_vjp_is_autodiff_of_the_lines(name):
     # du arrives in the compute dtype, as ``kda_scan_bwd`` hands it on
     low = cotangents[:-1] + [cotangents[-1].astype(dtype)]
     cotangents[-1] = low[-1].astype(jnp.float32)
-    want = jax.jit(lambda args, cotangents: jax.vjp(
-        _lines, *args)[1](cotangents))(args, tuple(cotangents))
-    got = gated_delta.kda_prepare_bwd(*args, inverse, *low, interpret=True)
+    want = _lines_vjp(args, tuple(cotangents))
+    got = _prepare_bwd(*args, inverse, *low)
     for grad, a, b in zip(_GRADS, got, want):
         assert a.shape == b.shape and a.dtype == b.dtype, grad
         a, b = np.float32(a), np.float32(b)

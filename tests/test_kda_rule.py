@@ -12,6 +12,8 @@ rule's (the operands' kernels themselves are
 channel (``kda_scan_fwd`` / ``kda_scan_bwd``, the state transposed)
 against the ``lax.scan``, in interpret mode."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,15 +48,29 @@ def _operands(seq, regime, seed=0, dim=DIM, dtype=jnp.float64):
     return q, k, v, g, beta
 
 
+@functools.lru_cache(maxsize=None)
+def _value_and_grads(f, argnums):
+    """One program a function: cases that hand the same function
+    object operands of the same shapes (a shape's three regimes are
+    values) trace and compile it once."""
+    return jax.jit(lambda weight, *a: (f(*a), jax.grad(
+        lambda *b: (f(*b) * weight).sum(), argnums=argnums)(*a)))
+
+
 def _both(fn, oracle, args, argnums):
     """(value, gradients) of ``fn`` and of ``oracle`` under one seeded
     weighting of the output."""
     weight = jax.random.normal(
         jax.random.PRNGKey(9), jax.eval_shape(oracle, *args).shape,
         jnp.float64)
-    run = lambda f: jax.jit(lambda *a: (f(*a), jax.grad(
-        lambda *b: (f(*b) * weight).sum(), argnums=argnums)(*a)))(*args)
-    return run(fn), run(oracle)
+    return (_value_and_grads(fn, argnums)(weight, *args),
+            _value_and_grads(oracle, argnums)(weight, *args))
+
+
+@functools.lru_cache(maxsize=None)
+def _rule(chunk, segment):
+    return lambda *a: gated_delta.gated_delta_rule(
+        *a, chunk=chunk, segment=segment)
 
 
 def _close(got, want, names):
@@ -73,10 +89,9 @@ def _close(got, want, names):
 def test_the_chunked_vector_rule_is_the_recurrence(x64, regime, chunk,
                                                    segment, seq):
     args = _operands(seq, regime)
-    rule = lambda *a: gated_delta.gated_delta_rule(
-        *a, chunk=chunk, segment=segment)
     (o, grads), (want, want_grads) = _both(
-        rule, gated_delta.gated_delta_recurrence, args, (0, 1, 2, 3, 4))
+        _rule(chunk, segment), gated_delta.gated_delta_recurrence, args,
+        (0, 1, 2, 3, 4))
     _close((o,) + grads, (want,) + want_grads,
            ("o", "dq", "dk", "dv", "dg", "dbeta"))
     if regime == "hard":
@@ -127,12 +142,13 @@ def test_the_diagonal_s_decays_go_by_groups_of_chunks(x64, monkeypatch):
     four groups): the same floats."""
     args = _operands(256, "mixed", seed=2)
     rule = lambda *a: gated_delta.gated_delta_rule(*a, chunk=64, segment=4)
-    whole = _both(rule, gated_delta.gated_delta_recurrence, args,
-                  (0, 1, 2, 3, 4))[0]
+    weight = jax.random.normal(
+        jax.random.PRNGKey(9), args[2].shape, jnp.float64)
+    whole = _value_and_grads(rule, (0, 1, 2, 3, 4))(weight, *args)
     monkeypatch.setattr(gated_delta, "_CUBE_BYTES", 64 * 1024)
     # a new function object: jax keeps a function's traces
-    grouped = _both(lambda *a: rule(*a), gated_delta.gated_delta_recurrence,
-                    args, (0, 1, 2, 3, 4))[0]
+    grouped = _value_and_grads(lambda *a: rule(*a), (0, 1, 2, 3, 4))(
+        weight, *args)
     _close((grouped[0],) + grouped[1], (whole[0],) + whole[1],
            ("o", "dq", "dk", "dv", "dg", "dbeta"))
 
@@ -226,12 +242,14 @@ def test_the_scan_s_kernels_carry_a_decay_a_channel(monkeypatch, chunk, rep,
             leaving, o = carry(*a, dtype)
             return ((o.astype(jnp.float32) * weight).sum()
                     + (leaving * leaving).sum())
-        return jax.jit(lambda *a: carry(*a, dtype) + jax.grad(
-            loss, argnums=tuple(range(7)))(*a))(*args)
+        # the program's text is read from the trace that runs
+        traced = jax.jit(lambda *a: carry(*a, dtype) + jax.grad(
+            loss, argnums=tuple(range(7)))(*a)).trace(*args)
+        return str(traced.jaxpr), traced.lower().compile()(*args)
 
-    want = outputs(gated_delta._scan_xla)
+    _, want = outputs(gated_delta._scan_xla)
     _force_pallas(monkeypatch)
-    got = outputs(gated_delta._scan_pallas_by_channel)
+    text, got = outputs(gated_delta._scan_pallas_by_channel)
     exact = dtype == jnp.float32
     names = ("leaving", "o", "d_state", "d_last", "d_w", "d_k_onto",
              "d_q_into", "d_attn", "d_u")
@@ -249,9 +267,6 @@ def test_the_scan_s_kernels_carry_a_decay_a_channel(monkeypatch, chunk, rep,
             a, b, rtol=0, atol=(2e-5 if exact else 2e-2) * scale,
             err_msg=name)
     # the kernels' names say which rule they carry
-    text = str(jax.make_jaxpr(lambda *a: jax.grad(
-        lambda *b: gated_delta._scan_pallas_by_channel(*b, dtype)[1].astype(
-            jnp.float32).sum())(*a))(*args))
     assert "kda_scan_fwd" in text and "kda_scan_bwd" in text
 
 
@@ -274,9 +289,10 @@ def test_the_rule_by_the_scan_s_kernels_is_the_recurrence(monkeypatch):
     monkeypatch.setattr(gated_delta, "prepare_impl", lambda *a, **kw: "xla")
     gated_delta._log_once.cache_clear()
     rule = lambda *a: gated_delta.gated_delta_rule(*a, chunk=64, segment=2)
-    text = str(jax.make_jaxpr(rule)(q, k, v, g, beta))
+    traced = jax.jit(rule).trace(q, k, v, g, beta)
+    text = str(traced.jaxpr)
     assert "kda_scan_fwd" in text and "_prepare" not in text
-    got = jax.jit(rule)(q, k, v, g, beta)
+    got = traced.lower().compile()(q, k, v, g, beta)
     np.testing.assert_allclose(
         got, want, rtol=0, atol=2e-5 * float(jnp.abs(want).max()))
 

@@ -6,8 +6,6 @@ own check (logits, loss, gradients, choices), latent attention with and
 without its rotation, the refusals by name, and the trees of the older
 models, which this PR leaves leaf for leaf."""
 
-import hashlib
-import json
 import os
 
 import jax
@@ -15,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib.refcheck import compare, load_by_path
+from benchmark.lib.refcheck import load_by_path
 from elasticdl_tpu.models import moe_transformer
 from elasticdl_tpu.models.moe_transformer import MoeTransformerLM
 from elasticdl_tpu.models.transformer import (
@@ -27,20 +25,14 @@ from elasticdl_tpu.models.transformer import (
     ShortConvDims,
     make_attention,
 )
+from tests.lm_common import PRESET, REPO, read_json, reference_check, tree_digest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PRESET = os.path.join(REPO, "tests", "benchmark_harness", "preset")
 KIMI = os.path.join(REPO, "benchmark", "configs", "kimi-linear-48b-a3b-1chip")
-
-
-def _json(*parts):
-    with open(os.path.join(*parts)) as f:
-        return json.load(f)
 
 
 @pytest.fixture(scope="module")
 def config():
-    return _json(PRESET, "configs", "tiny-kimi", "config.json")
+    return read_json(PRESET, "configs", "tiny-kimi", "config.json")
 
 
 @pytest.fixture(scope="module")
@@ -112,32 +104,38 @@ def test_the_facts_see_a_chunk_that_underflows():
 
 
 @pytest.fixture(scope="module")
-def checked(config):
-    """The benchmark's own check of the tiny five-layer model (KDA in a
-    dense block, three more KDA layers and a latent one in expert
-    blocks), run once: (errors by name, the system's outputs, the
-    variables, the check's parts)."""
-    spec = {"config": config,
-            "cell": _json(PRESET, "workloads", "tiny-kimi-s128.json"),
-            "zoo": os.path.join(KIMI, "zoo.py"),
-            "reference": os.path.join(KIMI, "reference.py")}
-    tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, 512, size=(128,)), jnp.int32)
-    parts = load_by_path(
-        "kimi_check_for_lm", os.path.join(KIMI, "check.py")).build(
-            spec, tokens)
-    variables = jax.jit(parts["init"])(jax.random.PRNGKey(3), tokens)
-    system = jax.jit(parts["system"])(variables, tokens)
-    plain = jax.jit(parts["reference"])(variables, tokens)
-    return compare(system, plain, parts["tolerance"]), system, variables
+def two_layers(config):
+    """The preset cut to one layer of every kind the check names: a KDA
+    mixer in the leading dense block, latent attention in an expert
+    block (the five-layer preset repeats the KDA layer three times more
+    in expert blocks; ``tests/benchmark_harness/test_kimi_reference.py``
+    runs that one)."""
+    linear = dict(config["linear_attn_config"], kda_layers=[1],
+                  full_attn_layers=[2])
+    return dict(
+        config, num_hidden_layers=2, linear_attn_config=linear,
+        check_leaves=[
+            "wte/embedding", "block_0/attn/A_log",
+            "block_0/attn/f_down/kernel", "block_0/mlp_gate/kernel",
+            "block_0/attn/dt_bias", "block_0/attn/conv_kernel",
+            "block_0/attn/in_proj_qkv/kernel", "block_0/attn/g_up/kernel",
+            "block_1/attn/kv_down/kernel", "block_1/attn/q_proj/kernel",
+            "block_1/moe_mlp/router/kernel", "block_1/moe_mlp/w_gate"])
 
 
-def test_the_model_is_the_reference_s(checked, config):
+@pytest.fixture(scope="module")
+def checked(two_layers):
+    """The benchmark's own check of the tiny two-layer model, run once:
+    (errors by name, the system's outputs, the variables)."""
+    return reference_check(KIMI, two_layers, "tiny-kimi-s128.json", "kimi")
+
+
+def test_the_model_is_the_reference_s(checked, two_layers):
     (errors, ok), system, _ = checked
     assert ok, errors
     assert set(errors) == {"logits", "loss", "choices",
                            "dropped_pairs_plus_one"} | {
-        "grad:" + path for path in config["check_leaves"]}
+        "grad:" + path for path in two_layers["check_leaves"]}
     # float32 on both sides: rounding, not bfloat16's
     assert max(errors.values()) < 1e-4, errors
     assert errors["choices"] == 0 and errors["dropped_pairs_plus_one"] == 0
@@ -279,17 +277,7 @@ OLDER_TREES = {
 
 @pytest.mark.parametrize("name", list(OLDER_TREES))
 def test_the_older_models_trees_are_leaf_for_leaf_the_parent_s(name):
-    config = _json(PRESET, "configs", name, "config.json")
-    zoo = load_by_path(
-        "zoo_tree_" + name.replace("-", "_"), os.path.join(REPO, config["zoo"]))
-    model = zoo.model_from_config(config)
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32)))
-    leaves = sorted(
-        ("/".join(str(getattr(p, "key", p)) for p in path),
-         tuple(leaf.shape), str(leaf.dtype))
-        for path, leaf in jax.tree_util.tree_leaves_with_path(shapes))
-    assert (len(leaves), hashlib.sha256(
-        repr(leaves).encode()).hexdigest()) == OLDER_TREES[name]
+    digest, model = tree_digest(name)
+    assert digest == OLDER_TREES[name]
     if name == "tiny-moonlight":
         assert model.latent.rotary is True

@@ -382,7 +382,8 @@ def test_the_bias_moves_toward_underloaded_experts_and_gets_no_gradient():
     tx = create_optimizer("AdamW", learning_rate=3e-4, weight_decay=0.01)
     tokens = jnp.asarray(
         np.random.RandomState(0).zipf(1.2, (2, 128)) % 256, jnp.int32)
-    state = create_train_state(model, tx, jax.random.PRNGKey(0), tokens)
+    state = jax.jit(lambda: create_train_state(
+        model, tx, jax.random.PRNGKey(0), tokens))()
     path = ("moe_state", "block_1", "moe_mlp", "e_score_correction_bias")
     bias_of = lambda st: np.asarray(
         st.model_state[path[0]][path[1]][path[2]][path[3]])

@@ -254,7 +254,8 @@ def test_the_scopes_and_the_lines_by_kind(caplog):
         model, moe_transformer.loss, tx, jnp.bfloat16, health=True))
     moe_transformer._log_kinds_once.cache_clear()
     with caplog.at_level(logging.INFO):
-        text = step.lower(state, batch).as_text(debug_info=True)
+        lowered = step.lower(state, batch)
+    text = lowered.as_text(debug_info=True)
     for kind in ("attn_full", "attn_window"):
         for part in ("qkv", "rotary", "flash", "gate", "out_proj"):
             assert "%s/%s" % (kind, part) in text
@@ -265,7 +266,8 @@ def test_the_scopes_and_the_lines_by_kind(caplog):
         line == "layer kinds: full x2 (heads=6 theta=500000 rotary=8 "
         "yarn=64), window x3 (heads=8 theta=10000 window=24)"
         for line in lines), lines
-    _, loss, scalars = step(state, batch)
+    # the program whose text was read, compiled and run
+    _, loss, scalars = lowered.compile()(state, batch)
     assert np.isfinite(float(loss))
     assert {"held", "dropped", "rows_run"} <= set(scalars["routing"])
 

@@ -53,7 +53,7 @@ def tokens():
 
 @pytest.fixture(scope="module")
 def params(tokens):
-    tree = looped().init(jax.random.PRNGKey(0), tokens)["params"]
+    tree = jax.jit(looped().init)(jax.random.PRNGKey(0), tokens)["params"]
     # a gate that tells positions apart, and norms that are not 1
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
     return jax.tree_util.tree_map(
@@ -62,7 +62,17 @@ def params(tokens):
 
 
 def training(model, params, tokens):
-    return model.apply({"params": params}, tokens, training=True)
+    """The training call, one program (an operation at a time the
+    model is a thousand dispatches)."""
+    return jax.jit(lambda params, tokens: model.apply(
+        {"params": params}, tokens, training=True))(params, tokens)
+
+
+@pytest.fixture(scope="module")
+def trained(tokens, params):
+    """The looped model's training outputs, made once for the tests
+    that read them."""
+    return training(looped(), params, tokens)
 
 
 # -- the system against the plain reference ---------------------------------
@@ -162,8 +172,8 @@ def test_one_pass_is_the_plain_model(tokens):
     loss of the model whose stack is walked once."""
     plain = M.MoeTransformerLM(**FIELDS)
     once = looped(passes=1, beta=0.0)
-    tree = plain.init(jax.random.PRNGKey(0), tokens)["params"]
-    own = once.init(jax.random.PRNGKey(0), tokens)["params"]
+    tree = jax.jit(plain.init)(jax.random.PRNGKey(0), tokens)["params"]
+    own = jax.jit(once.init)(jax.random.PRNGKey(0), tokens)["params"]
     assert jax.tree_util.tree_structure(own) == (
         jax.tree_util.tree_structure(tree))
     for a, b in zip(jax.tree_util.tree_leaves(own),
@@ -175,15 +185,15 @@ def test_one_pass_is_the_plain_model(tokens):
     np.testing.assert_allclose(terms["ce_exit_0"], want, rtol=1e-5)
     assert not np.asarray(terms["exit_entropy"]).any()
     np.testing.assert_allclose(
-        once.apply({"params": tree}, tokens),
-        plain.apply({"params": tree}, tokens), atol=1e-5)
+        jax.jit(once.apply)({"params": tree}, tokens),
+        jax.jit(plain.apply)({"params": tree}, tokens), atol=1e-5)
 
 
 # -- the exits --------------------------------------------------------------
 
 
-def test_the_exit_distribution_sums_to_one(tokens, params):
-    out = training(looped(), params, tokens)
+def test_the_exit_distribution_sums_to_one(tokens, trained):
+    out = trained
     log_p = out["exit_log_probs"]
     assert log_p.shape == (PASSES,) + tokens.shape
     assert log_p.dtype == jnp.float32
@@ -211,7 +221,7 @@ def test_a_shut_gate_leaves_the_last_exit_s_cross_entropy(tokens, params):
         params["early_exit_gate"], bias=jnp.full((1,), -60.0)))
     model = looped(beta=0.0)
     got, terms = M.loss(tokens, training(model, shut, tokens))
-    want = M.loss(tokens, model.apply({"params": shut}, tokens))
+    want = M.loss(tokens, jax.jit(model.apply)({"params": shut}, tokens))
     np.testing.assert_allclose(got, want, rtol=1e-5)
     np.testing.assert_allclose(
         terms["ce_exit_%d" % (PASSES - 1)], want, rtol=1e-5)
@@ -219,11 +229,11 @@ def test_a_shut_gate_leaves_the_last_exit_s_cross_entropy(tokens, params):
     assert float(jnp.abs(terms["exit_entropy"]).max()) < 1e-6
 
 
-def test_an_eval_call_returns_the_last_pass_s_bare_logits(tokens, params):
-    model = looped()
-    logits = model.apply({"params": params}, tokens)
+def test_an_eval_call_returns_the_last_pass_s_bare_logits(
+        tokens, params, trained):
+    logits = jax.jit(looped().apply)({"params": params}, tokens)
     assert logits.shape == tokens.shape + (VOCAB,)
-    out = training(model, params, tokens)
+    out = trained
     np.testing.assert_allclose(
         logits, out["exits"][-1] @ out["head_kernel"], atol=1e-5)
     assert float(jnp.abs(
@@ -254,19 +264,21 @@ def test_the_chunked_head_is_the_whole_head(monkeypatch):
             labels, exits, kernel, log_p, 0.1)
         return loss, terms
 
-    want, ce = whole(exits, kernel, log_p)
-    got, terms = chunked(exits, kernel, log_p)
+    want, ce = jax.jit(whole)(exits, kernel, log_p)
+    # the values are the trace's that is read below
+    traced = jax.jit(chunked).trace(exits, kernel, log_p)
+    got, terms = traced.lower().compile()(exits, kernel, log_p)
     np.testing.assert_allclose(got, want, rtol=1e-5)
     np.testing.assert_allclose(
         terms["ce_exit_1"], ce[1].mean(axis=-1), rtol=1e-5)
-    grads = lambda f: jax.grad(
-        lambda *args: f(*args)[0].sum(), argnums=(0, 1, 2))(
+    grads = lambda f: jax.jit(jax.grad(
+        lambda *args: f(*args)[0].sum(), argnums=(0, 1, 2)))(
             exits, kernel, log_p)
     for a, b in zip(jax.tree_util.tree_leaves(grads(chunked)),
                     jax.tree_util.tree_leaves(grads(whole))):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
     # nothing as wide as the vocabulary leaves a chunk
-    jaxpr = str(jax.make_jaxpr(chunked)(exits, kernel, log_p))
+    jaxpr = str(traced.jaxpr)
     assert "150,200]" not in jaxpr and "192,200]" not in jaxpr
 
 

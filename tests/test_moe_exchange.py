@@ -4,13 +4,13 @@
 devices: the plan's arithmetic, the exchange against what it says it
 does and its transpose against autodiff, the layer against a dense
 one-hot formulation and against itself on ONE device under even and
-skewed routing, a tiny Mellum2 over ``ep=4`` against its plain
-reference and against the one-device program, what is still refused,
-and the counters' way to the journal through ``SpmdTrainer``."""
+skewed routing, and what is still refused. A tiny Mellum2 over
+``ep=4`` against its plain reference and the one-device program, and
+the counters' way to the journal through ``SpmdTrainer``, are
+``test_moe_exchange_model.py``'s: one file summed past the rule's 100 s
+(``ROADMAP.md`` Queue 3 item 12)."""
 
-import functools
 import importlib.util
-import json
 import os
 
 import jax
@@ -20,13 +20,9 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import jax_compat
-from elasticdl_tpu.models import moe_transformer
 from elasticdl_tpu.models.moe_transformer import MoeMlp
 from elasticdl_tpu.ops import moe as moe_ops
 from elasticdl_tpu.parallel.mesh import MeshConfig, build_mesh
-from elasticdl_tpu.parallel.spmd_trainer import SpmdTrainer
-from elasticdl_tpu.train import step_fns
-from elasticdl_tpu.train.optimizers import create_optimizer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CONFIGURATION = os.path.join(
@@ -374,10 +370,12 @@ def test_the_exchange_s_transpose_is_the_exchange_reversed():
 
         return lambda rows: over_ranks(on_rank)(rows, counts).sum()
 
-    ours = jax.grad(loss(lambda rows, plan: moe_ops.exchange_rows(
-        rows, plan["there"], plan["back"], buffer_rows, "ep")))(rows)
-    plain = jax.grad(loss(lambda rows, plan: moe_ops._gathered_all_to_all(
-        rows, buffer_rows, plan["there"], "ep")))(rows)
+    ours = jax.jit(jax.grad(loss(
+        lambda rows, plan: moe_ops.exchange_rows(
+            rows, plan["there"], plan["back"], buffer_rows, "ep"))))(rows)
+    plain = jax.jit(jax.grad(loss(
+        lambda rows, plan: moe_ops._gathered_all_to_all(
+            rows, buffer_rows, plan["there"], "ep"))))(rows)
     assert float(jnp.abs(plain).max()) > 0
     np.testing.assert_allclose(ours, plain, rtol=1e-5, atol=1e-6)
 
@@ -392,7 +390,8 @@ LAYER = dict(
 def _layer_case(skew=False, batch=4, seq=16, dim=12):
     rng = np.random.RandomState(3)
     x = rng.randn(batch, seq, dim).astype(np.float32)
-    variables = MoeMlp(**LAYER).init(jax.random.PRNGKey(0), jnp.asarray(x))
+    variables = jax.jit(MoeMlp(**LAYER).init)(
+        jax.random.PRNGKey(0), jnp.asarray(x))
     if skew:
         # three tokens in four carry a lane that the router reads into
         # experts 0 and 1, rank 0's: that rank gets most of the pairs
@@ -488,7 +487,7 @@ def test_a_deepseek_style_layer_over_ep_is_itself_on_one_device():
     fields = dict(LAYER, scoring="sigmoid", gate_scale=2.5,
                   bias_update_speed=0.01, seq_aux=True, shared_experts=1)
     _, x = _layer_case()
-    variables = MoeMlp(**fields).init(jax.random.PRNGKey(0), x)
+    variables = jax.jit(MoeMlp(**fields).init)(jax.random.PRNGKey(0), x)
 
     def run(layer):
         def loss(params, x):
@@ -526,8 +525,8 @@ def test_the_layer_s_gradients_are_a_dense_one_hot_layer_s():
             y.shape)
         return jnp.sum(y * weight) + 3.0 * balance + z
 
-    want, grads_want = jax.value_and_grad(dense_loss, argnums=(0, 1))(
-        variables, x)
+    want, grads_want = jax.jit(
+        jax.value_and_grad(dense_loss, argnums=(0, 1)))(variables, x)
     (got, _), grads_got = _value_and_grads(
         MoeMlp(mesh=ep_mesh(), **LAYER), variables, x)
     np.testing.assert_allclose(got, want, rtol=1e-5)
@@ -589,146 +588,3 @@ def test_what_is_still_refused():
     with pytest.raises(ValueError, match="normalize_gates=False needs"):
         init(MoeMlp(mesh=ep_mesh(), **dict(
             LAYER, dispatch_impl="onehot", normalize_gates=False)))
-
-
-# --- a tiny Mellum2 ---------------------------------------------------
-
-
-def tiny_config():
-    with open(TINY) as f:
-        config = json.load(f)
-    # the file's one period (three layers under the window, one
-    # without; what is held against what here is the exchange, a layer
-    # at a time), a window of 8
-    config.update(sliding_window=8)
-    assert config["num_hidden_layers"] == 4
-    return config
-
-
-@functools.lru_cache(maxsize=None)
-def _tiny_inputs():
-    """(tokens, the tiny model's parameters), the parameters initialised
-    once, one program and not an operation at a time."""
-    config = tiny_config()
-    tokens = jnp.asarray(np.random.RandomState(4).randint(
-        0, config["vocab_size"], (4, 32)), jnp.int32)
-    one = load("zoo").model_from_config(config, attention_impl="xla")
-    return tokens, jax.jit(lambda: one.init(
-        jax.random.PRNGKey(5), tokens, training=False))()["params"]
-
-
-@functools.lru_cache(maxsize=None)
-def _one_device():
-    """Loss, logits and gradients of the program on ONE device with all
-    the experts, once: two tests hold the mesh's against them."""
-    zoo = load("zoo")
-    one = zoo.model_from_config(tiny_config(), attention_impl="xla")
-    tokens, params = _tiny_inputs()
-    return _system(zoo, one, tokens)(params)
-
-
-def _tiny_case(mesh=None):
-    zoo = load("zoo")
-    config = tiny_config()
-    model = zoo.model_from_config(config, mesh=mesh, attention_impl="xla")
-    return (zoo, config, model) + _tiny_inputs()
-
-
-def _system(zoo, model, tokens):
-    def loss(params):
-        outputs, sown = model.apply(
-            {"params": params}, tokens, training=True,
-            mutable=["intermediates"])
-        return zoo.loss(tokens, outputs).mean(), (
-            outputs["logits"], outputs["routing"], sown)
-
-    return jax.jit(jax.value_and_grad(loss, has_aux=True))
-
-
-def test_tiny_mellum2_over_ep_is_its_reference_and_the_one_device_program():
-    zoo, config, model, tokens, params = _tiny_case(ep_mesh())
-    (loss, (logits, routing, sown)), grads = _system(zoo, model, tokens)(
-        params)
-    assert float(routing["dropped"]) == 0
-    # the program on ONE device with all the experts
-    (loss_one, (logits_one, _, _)), grads_one = _one_device()
-    np.testing.assert_allclose(loss, loss_one, rtol=1e-5)
-    np.testing.assert_allclose(logits, logits_one, rtol=1e-3, atol=1e-4)
-    _assert_trees_close(grads, grads_one, rtol=1e-4, atol=1e-5)
-    # the plain reference: all experts in one place, no mesh
-    ref = load("reference")
-
-    def reference_loss(params):
-        logits, loss, chosen = ref.logits_loss_and_choices(
-            params, tokens, config)
-        return loss, (logits, chosen)
-
-    (loss_ref, (logits_ref, chosen)), grads_ref = jax.jit(
-        jax.value_and_grad(reference_loss, has_aux=True))(params)
-    experts = jnp.stack([
-        sown["intermediates"]["block_%d" % i]["moe_mlp"]["experts"][0]
-        for i in range(config["num_hidden_layers"])])
-    np.testing.assert_array_equal(
-        np.sort(experts, axis=-1), np.sort(chosen, axis=-1))
-    np.testing.assert_allclose(loss, loss_ref, rtol=1e-5)
-    np.testing.assert_allclose(logits, logits_ref, rtol=1e-3, atol=1e-4)
-    _assert_trees_close(grads, grads_ref, rtol=1e-4, atol=1e-5)
-
-
-def test_the_counters_reach_the_journal_s_fields_from_the_spmd_trainer():
-    """``SpmdTrainer`` keeps the step's facts (``FACTS``): the
-    ``moe_routing`` event's fields exist on a mesh, the exchange's
-    among them, and a model without facts adds nothing."""
-    zoo, config, model, tokens, _ = _tiny_case(ep_mesh())
-    trainer = SpmdTrainer(
-        model=model, loss_fn=zoo.loss,
-        optimizer=create_optimizer("AdamW", learning_rate=1e-3),
-        mesh=ep_mesh(), sharding_rules=zoo.sharding_rules(),
-        batch_spec=zoo.batch_spec())
-    batch = {"features": np.asarray(tokens), "labels": np.asarray(tokens),
-             "_mask": np.ones((4,), np.float32)}
-    state, loss = trainer.train_step(None, batch)
-    assert np.isfinite(float(loss))
-    (fact,) = [f for f in step_fns.FACTS if f.key == "routing"]
-    fields = fact.journal(trainer.facts["routing"])
-    for name in ("tokens_per_expert_max", "dropped_pairs", "sent_pairs",
-                 "received_pairs_max", "received_pairs_mean",
-                 "exchange_bytes"):
-        assert name in fields, name
-    assert fields["dropped_pairs"] == 0
-    assert fields["received_pairs_mean"] == 32 * 2
-    # no stated buffer: all the ranks' pairs, which 4,096 does not
-    # divide, so the regrouping runs it whole
-    assert (fields["received_rows_run"] == fields["received_rows_buffer"]
-            == 4 * 32 * 2)
-    # the experts' state is divided over ep and nothing else's is
-    specs = {
-        "/".join(str(k.key) for k in path): leaf.sharding.spec
-        for path, leaf in jax.tree_util.tree_leaves_with_path(state.params)}
-    assert specs["block_0/moe_mlp/w_gate"][0] == "ep"
-    assert specs["lm_head/kernel"][0] == ("fsdp", "ep")
-    assert "ep" not in str(specs["block_0/attn/query/kernel"])
-
-
-def test_the_rows_the_regrouping_ran_reach_the_moe_routing_event(
-        monkeypatch):
-    """``received_rows_run`` of ``received_rows_buffer``: the busiest
-    rank's received rows, in the layer where it received the most,
-    rounded up to a chunk (16 rows here, so that the tiny buffer has
-    chunks to stop at), from a training step over ``ep`` whose loss and
-    gradients are the one-device program's."""
-    monkeypatch.setattr(moe_ops, "HELD_CHUNK_ROWS", 16)
-    zoo, config, model, tokens, params = _tiny_case(ep_mesh())
-    (loss, (_, routing, _)), grads = _system(zoo, model, tokens)(params)
-    # stopping short of the buffer changes nothing that is read
-    (loss_one, _), grads_one = _one_device()
-    np.testing.assert_allclose(loss, loss_one, rtol=1e-5)
-    _assert_trees_close(grads, grads_one, rtol=1e-4, atol=1e-5)
-    (fact,) = [f for f in step_fns.FACTS if f.key == "routing"]
-    fields = fact.journal(routing)
-    buffer_rows = 4 * 32 * 2
-    assert fields["received_rows_buffer"] == buffer_rows
-    busiest = fields["received_pairs_max"]
-    assert 32 * 2 <= busiest <= buffer_rows
-    assert fields["received_rows_run"] == -(-busiest // 16) * 16
-    assert fields["received_rows_run"] <= fields["received_rows_buffer"]

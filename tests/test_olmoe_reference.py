@@ -88,12 +88,16 @@ def test_the_loss_has_three_parts(tokens, reference):
     _, params, want = reference
     ref = refcheck.sys.modules["edlbench_reference"]
 
-    def loss(**weights):
+    @jax.jit
+    def weighted(balance, z):
+        # the weights are values: one program for the three readings
         config = small_config()
-        config["assumed"] = {"loss_weights": dict(
-            {"router_aux_loss_coef": 0.0, "router_z_loss_coef": 0.0},
-            **weights)}
-        return float(ref.logits_loss_and_choices(params, tokens, config)[1])
+        config["assumed"] = {"loss_weights": {
+            "router_aux_loss_coef": balance, "router_z_loss_coef": z}}
+        return ref.logits_loss_and_choices(params, tokens, config)[1]
+
+    def loss(router_aux_loss_coef=0.0, router_z_loss_coef=0.0):
+        return float(weighted(router_aux_loss_coef, router_z_loss_coef))
 
     ce = loss()
     balance = loss(router_aux_loss_coef=1.0) - ce
@@ -161,8 +165,9 @@ def test_a_system_that_drops_the_lowest_gate_expert_fails(tokens, reference):
     # applying what the system chose (one expert a token)
     ref = refcheck.sys.modules["edlbench_reference"]
     applied = jnp.argmax(got["choices"], axis=-1)[..., None]
-    logits, _, chosen = ref.logits_loss_and_choices(
-        params, tokens, small_config(), forced=applied)
+    logits, _, chosen = jax.jit(
+        lambda forced: ref.logits_loss_and_choices(
+            params, tokens, small_config(), forced=forced))(applied)
     assert float(refcheck.rel_rms(got["logits"], logits)) < 1e-4
     assert chosen.shape == (2, 128, 2)
 
@@ -173,13 +178,15 @@ def test_forced_choices_change_only_what_is_applied(tokens, reference):
     _, params, want = reference
     ref = refcheck.sys.modules["edlbench_reference"]
     config = small_config()
-    free = ref.logits_loss_and_choices(params, tokens, config)
-    same = ref.logits_loss_and_choices(
-        params, tokens, config, forced=free[2])
+    free = jax.jit(
+        lambda: ref.logits_loss_and_choices(params, tokens, config))()
+    run = jax.jit(lambda forced: ref.logits_loss_and_choices(
+        params, tokens, config, forced=forced))
+    same = run(free[2])
     np.testing.assert_allclose(
         np.asarray(same[0]), np.asarray(free[0]), atol=1e-5)
     other = (free[2] + 1) % config["num_experts"]
-    forced = ref.logits_loss_and_choices(params, tokens, config, forced=other)
+    forced = run(other)
     assert float(refcheck.rel_rms(forced[0], free[0])) > 0.05
     # layer 0's router sees the same input either way
     np.testing.assert_array_equal(

@@ -54,14 +54,19 @@ def operands(dtype, hk, hv, seq, batch, taps=TAPS, dim=DIM, seed=0):
     return tuple(x.astype(dtype) for x in [qkvz, w] + grads)
 
 
-def value_and_vjp(fn, heads, qkvz, w, *grads, **kw):
-    """One program a call (a new one: ``fn`` is traced under what the
-    test has patched by then)."""
+def program(fn, heads, **kw):
+    """``fn``'s results and VJP as one program (a new one: ``fn`` is
+    traced under what the test has patched by then); a test that calls
+    it on several operands of one shape lowers its kernels once."""
     def both(qkvz, w, *grads):
         results, vjp = jax.vjp(lambda x, w: fn(x, w, heads, **kw), qkvz, w)
         return tuple(results) + tuple(vjp(tuple(grads)))
 
-    return jax.jit(both)(qkvz, w, *grads)
+    return jax.jit(both)
+
+
+def value_and_vjp(fn, heads, qkvz, w, *grads, **kw):
+    return program(fn, heads, **kw)(qkvz, w, *grads)
 
 
 def worst(got, want):
@@ -119,25 +124,22 @@ def test_a_tile_s_edges_see_their_neighbours_and_no_further(monkeypatch):
     force_pallas(monkeypatch)
     heads, edge = (1, 1, DIM), 128
     qkvz, w, *grads = operands(jnp.float32, 1, 1, 256, 2)
-    base = value_and_vjp(Q.qkv_conv, heads, qkvz, w, *grads)
+    pair = program(Q.qkv_conv, heads)
+    base = pair(qkvz, w, *grads)
     # the last row of the first tile moves the next tile's first three
-    moved = value_and_vjp(
-        Q.qkv_conv, heads, qkvz.at[:, edge - 1].add(1.0), w, *grads)
+    moved = pair(qkvz.at[:, edge - 1].add(1.0), w, *grads)
     for b, m in zip(base[:3], moved[:3]):
         changed = np.asarray(jnp.abs(b - m).max(axis=(0, 1, 3)) > 1e-6)
         assert changed[edge - 1:edge + 3].all()
         assert not changed[:edge - 1].any() and not changed[edge + 3:].any()
     # a cotangent at the second tile's first row moves dX three rows back
-    moved = value_and_vjp(
-        Q.qkv_conv, heads, qkvz, w,
-        *(g.at[:, :, edge].add(1.0) for g in grads))
+    moved = pair(qkvz, w, *(g.at[:, :, edge].add(1.0) for g in grads))
     changed = np.asarray(
         jnp.abs(base[3] - moved[3]).max(axis=(0, 2)) > 1e-6)
     assert changed[edge - 3:edge + 1].all()
     assert not changed[:edge - 3].any() and not changed[edge + 1:].any()
     # the second sequence starts from zeros, whatever the first ends on
-    alone = value_and_vjp(
-        Q.qkv_conv, heads, qkvz[1:], w, *(g[1:] for g in grads))
+    alone = pair(qkvz[1:], w, *(g[1:] for g in grads))
     for b, a in zip(base[:4], alone[:4]):
         np.testing.assert_array_equal(np.asarray(b[1:]), np.asarray(a))
 

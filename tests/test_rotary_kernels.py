@@ -57,8 +57,13 @@ def operands(dtype, batch, heads, kv_heads, seq, dim, seed=0):
 
 
 def value_and_vjp(fn, q, k, gq, gk):
-    out, vjp = jax.vjp(fn, q, k)
-    return tuple(out) + tuple(vjp((gq, gk)))
+    """One program a call (a new one: ``fn`` is traced under what the
+    test has patched by then)."""
+    def both(q, k, gq, gk):
+        out, vjp = jax.vjp(fn, q, k)
+        return tuple(out) + tuple(vjp((gq, gk)))
+
+    return jax.jit(both)(q, k, gq, gk)
 
 
 def steps_apart(got, want, dtype):
@@ -113,7 +118,7 @@ def test_the_pair_against_the_module_s_lines(
         lines(rope), *(x.astype(jnp.float32) for x in args))
     back = dict(rope, positions=-(
         jnp.arange(seq) if positions is None else positions))
-    turned_back = lines(back)(*args[2:])
+    turned_back = jax.jit(lines(back))(*args[2:])
     force_pallas(monkeypatch)
     got = value_and_vjp(
         functools.partial(R.rotate, rotary_dim=rotary_dim, **rope), *args)
@@ -244,7 +249,7 @@ def attention_gradients(dtype=jnp.float32, **fields):
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 256)).astype(dtype)
     mixer = T.Attention(
         num_heads=2, attention_impl="xla", kind_scope="attn_full", **fields)
-    params = mixer.init(jax.random.PRNGKey(2), x)["params"]
+    params = jax.jit(mixer.init)(jax.random.PRNGKey(2), x)["params"]
     target = jax.random.normal(jax.random.PRNGKey(3), x.shape)
     loss = lambda params, x: jnp.sum(
         mixer.apply({"params": params}, x) * target)

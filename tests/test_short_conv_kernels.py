@@ -51,9 +51,19 @@ def operands(dtype, channels, seq, batch, taps, seed=0):
     return tuple(x.astype(dtype) for x in (bcx, w, dy))
 
 
+def program(fn):
+    """``fn``'s value and VJP as one program (a new one: ``fn`` is
+    traced under what the test has patched by then); a test that calls
+    it on several operands of one shape lowers its kernels once."""
+    def both(bcx, w, dy):
+        y, vjp = jax.vjp(fn, bcx, w)
+        return (y,) + tuple(vjp(dy))
+
+    return jax.jit(both)
+
+
 def value_and_vjp(fn, bcx, w, dy):
-    y, vjp = jax.vjp(fn, bcx, w)
-    return (y,) + tuple(vjp(dy))
+    return program(fn)(bcx, w, dy)
 
 
 def worst(got, want):
@@ -129,18 +139,17 @@ def test_a_tile_s_edges_see_their_neighbours_and_no_further(monkeypatch):
     force_pallas(monkeypatch)
     channels, edge = 128, 128
     bcx, w, dy = operands(jnp.float32, channels, 256, 2, 3)
-    base = value_and_vjp(S.gated_short_conv, bcx, w, dy)
+    pair = program(S.gated_short_conv)
+    base = pair(bcx, w, dy)
     rows = lambda a, b: np.asarray(jnp.abs(a - b).max(axis=(0, 2)) > 1e-6)
     # B's last row of the first tile moves the next tile's first two
-    moved = value_and_vjp(
-        S.gated_short_conv, bcx.at[:, edge - 1, :channels].add(1.0), w, dy)
+    moved = pair(bcx.at[:, edge - 1, :channels].add(1.0), w, dy)
     changed = rows(base[0], moved[0])
     assert changed[edge - 1:edge + 2].all()
     assert not changed[:edge - 1].any() and not changed[edge + 2:].any()
     # a cotangent at the second tile's first row moves dB and dX two
     # rows back, and dC at its own row alone
-    moved = value_and_vjp(
-        S.gated_short_conv, bcx, w, dy.at[:, edge].add(1.0))
+    moved = pair(bcx, w, dy.at[:, edge].add(1.0))
     for part, back in ((0, 2), (1, 0), (2, 2)):
         lanes = slice(part * channels, (part + 1) * channels)
         changed = rows(base[1][..., lanes], moved[1][..., lanes])
@@ -148,15 +157,13 @@ def test_a_tile_s_edges_see_their_neighbours_and_no_further(monkeypatch):
         assert not changed[:edge - back].any(), part
         assert not changed[edge + 1:].any(), part
     # C at the second tile's first row reaches dz two rows back too
-    moved = value_and_vjp(
-        S.gated_short_conv,
-        bcx.at[:, edge, channels:2 * channels].add(1.0), w, dy)
+    moved = pair(bcx.at[:, edge, channels:2 * channels].add(1.0), w, dy)
     changed = rows(base[1][..., :channels], moved[1][..., :channels])
     assert changed[edge - 2:edge + 1].all()
     assert not changed[:edge - 2].any() and not changed[edge + 1:].any()
     # the second sequence starts from zeros and ends without
     # successors, whatever the first holds
-    alone = value_and_vjp(S.gated_short_conv, bcx[1:], w, dy[1:])
+    alone = pair(bcx[1:], w, dy[1:])
     for b, a in zip(base[:2], alone[:2]):
         np.testing.assert_array_equal(np.asarray(b[1:]), np.asarray(a))
 

@@ -7,6 +7,7 @@ several segments against one; 1, 2 and 8 groups; a state carried over
 two calls against one call; a padded length; the chooser and the log's
 line. Small on purpose: no sequence is longer than 512 tokens."""
 
+import functools
 import logging
 
 import jax
@@ -47,20 +48,31 @@ def _operands(seq, regime="drawn", groups=1, seed=0, dtype=jnp.float64):
     return x, dt, a, b, c, skip, state
 
 
+def _oracle(*a):
+    return ssd.ssd_recurrence(*a[:6], state=a[6])
+
+
+@functools.lru_cache(maxsize=None)
+def _value_and_grads(f):
+    """One program a function: cases that hand the same function object
+    operands of the same shapes (a shape's regimes are values) trace
+    and compile it once."""
+    weighted = lambda weights, *a: sum(
+        (out * w).sum() for out, w in zip(f(*a), weights))
+    return jax.jit(lambda weights, *a: f(*a) + jax.grad(
+        weighted, argnums=tuple(range(1, 8)))(weights, *a))
+
+
 def _both(fn, args):
     """(y, leaving state, gradients of all seven operands) of ``fn`` and
     of the recurrence under one seeded weighting of both outputs."""
-    oracle = lambda *a: ssd.ssd_recurrence(*a[:6], state=a[6])
-    shapes = jax.eval_shape(oracle, *args)
+    shapes = jax.eval_shape(_oracle, *args)
     keys = jax.random.split(jax.random.PRNGKey(9), 2)
     weights = tuple(
         jax.random.normal(key, shape.shape, jnp.float64)
         for key, shape in zip(keys, shapes))
-    weighted = lambda f: lambda *a: sum(
-        (out * w).sum() for out, w in zip(f(*a), weights))
-    run = lambda f: jax.jit(lambda *a: f(*a) + jax.grad(
-        weighted(f), argnums=tuple(range(7)))(*a))(*args)
-    return run(fn), run(oracle)
+    return (_value_and_grads(fn)(weights, *args),
+            _value_and_grads(_oracle)(weights, *args))
 
 
 def _close(got, want, names=NAMES):
@@ -71,6 +83,7 @@ def _close(got, want, names=NAMES):
             a, b, rtol=0, atol=TOLERANCE * scale, err_msg=name)
 
 
+@functools.lru_cache(maxsize=None)
 def _scan(chunk, segment, **kw):
     return lambda *a: ssd.ssd_scan(
         *a[:6], chunk=chunk, state=a[6], segment=segment,
@@ -125,8 +138,8 @@ def test_a_state_carried_over_two_calls_is_one_call(x64, cut):
 
 def test_without_a_state_the_sequence_starts_from_zero(x64):
     x, dt, a, b, c, skip, state = _operands(96, seed=6)
-    y = ssd.ssd_scan(x, dt, a, b, c, skip, chunk=32)
-    want, _ = ssd.ssd_recurrence(x, dt, a, b, c, skip)
+    y = jax.jit(lambda *t: ssd.ssd_scan(*t, chunk=32))(x, dt, a, b, c, skip)
+    want, _ = jax.jit(ssd.ssd_recurrence)(x, dt, a, b, c, skip)
     _close((y,), (want,), ("y",))
     assert y.shape == x.shape and y.dtype == x.dtype
 
@@ -139,19 +152,26 @@ def test_bfloat16_operands_keep_a_float32_state_and_decay():
     x, dt, a, b, c, skip, state = _operands(512, seed=7, dtype=jnp.float32)
     low = lambda t: t.astype(jnp.bfloat16)
 
-    def err(scale, **kw):
-        want, _ = ssd.ssd_recurrence(
-            x, dt, scale * a, b, c, skip, state=state)
-        y, leaving = ssd.ssd_scan(
+    oracle = jax.jit(lambda scale: ssd.ssd_recurrence(
+        x, dt, scale * a, b, c, skip, state=state)[0])
+
+    @functools.lru_cache(maxsize=None)
+    def scan(**kw):
+        return jax.jit(lambda scale: ssd.ssd_scan(
             low(x), dt, scale * a, low(b), low(c), skip, chunk=64,
-            state=state, segment=2, return_state=True, **kw)
+            state=state, segment=2, return_state=True, **kw))
+
+    def err(scale, **kw):
+        want = oracle(scale)
+        y, leaving = scan(**kw)(scale)
         assert y.dtype == jnp.bfloat16 and leaving.dtype == jnp.float32
         return float(jnp.sqrt(jnp.mean(
             (y.astype(jnp.float32) - want) ** 2) / jnp.mean(want ** 2)))
 
-    assert err(0.1) < 0.01 and err(0.01) < 0.01
-    assert err(0.1, decay_dtype=jnp.bfloat16) > 1.5 * err(0.1)
-    assert err(0.01, state_dtype=jnp.bfloat16) > 1.05 * err(0.01)
+    stated = {scale: err(scale) for scale in (0.1, 0.01)}
+    assert stated[0.1] < 0.01 and stated[0.01] < 0.01
+    assert err(0.1, decay_dtype=jnp.bfloat16) > 1.5 * stated[0.1]
+    assert err(0.01, state_dtype=jnp.bfloat16) > 1.05 * stated[0.01]
 
 
 def test_what_the_scan_refuses():
