@@ -150,9 +150,11 @@ class MoeMlp(nn.Module):
       (``fsdp``, ``tp``, ``sp``, ``pp``) is refused by name, never
       served by a silent fallback.
 
-    Experts are ``expert_act`` = ``"gelu"`` (up, GELU, down) or
-    ``"swiglu"`` (silu(gate) x up, down) of width ``expert_dim``
-    (``mlp_ratio x dim`` when None). ``normalize_gates=False`` (the
+    Experts are ``expert_act`` = ``"gelu"`` (up, GELU, down),
+    ``"swiglu"`` (silu(gate) x up, down) or ``"relu2"`` (up, the square
+    of its ReLU, down: two matrices and no gate, Nemotron-H's
+    ``mlp_hidden_act``) of width ``expert_dim`` (``mlp_ratio x dim``
+    when None). ``normalize_gates=False`` (the
     kept gates stay the softmax's own, OLMoE's ``norm_topk_prob``
     false) is the sorted path's; the one-hot path refuses it.
 
@@ -166,9 +168,17 @@ class MoeMlp(nn.Module):
     speed toward the under-loaded experts, from the group sizes the
     step has just counted (``ops/moe.py:balancing_bias_update``);
     ``seq_aux`` makes ``aux["load_balancing"]`` the sequence-wise
-    balance loss; ``shared_experts`` n adds one SwiGLU MLP of width ``n
-    x expert_dim`` that every token passes, under the scope
-    ``moe/shared``.
+    balance loss; ``shared_experts`` n adds one MLP of the experts' own
+    body (SwiGLU or ``relu2``) and of width ``n x expert_dim`` that
+    every token passes, under the scope ``moe/shared``.
+    ``router_float32``: the router's product in float32 at the highest
+    matmul precision (the published Nemotron-H router's; the scores and
+    the top-k are float32 either way).
+
+    A ``relu2`` layer's ``routing`` also counts ``relu2_active``, the
+    share of its experts' hidden units above zero after the ReLU over
+    the rows that carry a pair, and with shared experts
+    ``relu2_shared_active``, the same of the shared expert's.
 
     ``held_experts`` ``(first, count)`` makes this one chip's share of
     a layer whose experts lie over several (the first half of expert
@@ -209,6 +219,7 @@ class MoeMlp(nn.Module):
     held_rows: Optional[int] = None
     shared_gate: bool = False
     exchange_rows: Optional[int] = None
+    router_float32: bool = False
 
     def _expert_param(self, name, rows, cols):
         # the expert axis is a batch of kernels, not fan-in: without
@@ -223,14 +234,16 @@ class MoeMlp(nn.Module):
 
     def _weights(self, dim, dtype):
         """The experts' kernels in the compute dtype, input side first:
-        (w_up, w_down) for GELU, (w_gate, w_up, w_down) for SwiGLU."""
-        if self.expert_act not in ("gelu", "swiglu"):
+        (w_up, w_down) for GELU and ReLU squared, (w_gate, w_up, w_down)
+        for SwiGLU."""
+        if self.expert_act not in ("gelu", "swiglu", "relu2"):
             raise ValueError(
-                "expert_act must be 'gelu' or 'swiglu', got %r"
+                "expert_act must be 'gelu', 'swiglu' or 'relu2', got %r"
                 % (self.expert_act,)
             )
         ff = self.expert_dim or dim * self.mlp_ratio
-        names = ["w_up"] if self.expert_act == "gelu" else ["w_gate", "w_up"]
+        names = ["w_gate", "w_up"] if self.expert_act == "swiglu" else [
+            "w_up"]
         return [
             self._expert_param(name, dim, ff).astype(dtype) for name in names
         ] + [self._expert_param("w_down", ff, dim).astype(dtype)]
@@ -238,7 +251,21 @@ class MoeMlp(nn.Module):
     def _act(self, hidden):
         if self.expert_act == "gelu":
             return nn.gelu(hidden[0])
+        if self.expert_act == "relu2":
+            return jnp.square(nn.relu(hidden[0]))
         return nn.silu(hidden[0]) * hidden[1]
+
+    def _active_share(self, hidden, valid=None):
+        """Of a ``relu2`` body's hidden units (rows, width), the share
+        above zero after the ReLU, float32, no gradient; over the rows
+        ``valid`` marks where a buffer holds more rows than pairs (what
+        a row past them holds is anyone's: ``MoeMlp``)."""
+        active = jax.lax.stop_gradient(hidden) > 0
+        if valid is None:
+            return active.astype(jnp.float32).mean()
+        active = active & valid[:, None]
+        return active.sum(dtype=jnp.float32) / jnp.maximum(
+            valid.sum(dtype=jnp.float32) * hidden.shape[-1], 1.0)
 
     @nn.compact
     def __call__(self, x, training=False):
@@ -258,7 +285,12 @@ class MoeMlp(nn.Module):
         groups, seq, dim = x.shape
         with jax.named_scope("moe/router"):
             router_logits = nn.Dense(
-                self.num_experts, use_bias=False, name="router"
+                self.num_experts, use_bias=False, name="router",
+                # (a layer without the switch builds the product it
+                # always did)
+                **({"dtype": jnp.float32,
+                    "precision": jax.lax.Precision.HIGHEST}
+                   if self.router_float32 else {})
             )(x)
         weights = self._weights(dim, x.dtype)
         one_device = jax_compat.nothing_to_partition(self.mesh)
@@ -313,25 +345,39 @@ class MoeMlp(nn.Module):
             aux["router_z"] = moe_ops.router_z_loss(router_logits)
         if self.shared_experts:
             with jax.named_scope("moe/shared"):
-                y = y + self._shared(
+                shared, active = self._shared(
                     x, self.shared_experts * weights[0].shape[-1])
+                y = y + shared
+            if active is not None and aux["routing"] is not None:
+                aux["routing"]["relu2_shared_active"] = active
         return y, aux
 
     def _shared(self, x, width):
-        """The shared experts: one SwiGLU MLP of their summed width on
-        every token (n experts of one width side by side are one MLP
-        of n times the width)."""
-        if self.expert_act != "swiglu":
-            raise ValueError("shared experts are SwiGLU experts")
+        """The shared experts: one MLP of the experts' own body and of
+        their summed width on every token (n experts of one width side
+        by side are one MLP of n times the width): ``shared_gate`` |
+        ``shared_up`` | ``shared_down`` for SwiGLU, ``shared_up`` |
+        ``shared_down`` for ReLU squared. Returns ``(y, the share of a
+        relu2 body's hidden units above zero or None)``."""
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(
+                "shared experts take the experts' body where it is "
+                "SwiGLU or ReLU squared (expert_act 'swiglu', 'relu2'); "
+                "a shared expert beside expert_act=%r was not built, so "
+                "not run" % (self.expert_act,))
         dense = lambda features, name: nn.Dense(
             features, use_bias=False, name=name)
-        gate = constrain(
-            dense(width, "shared_gate")(x), self.mesh, HIDDEN_SPEC)
-        up = constrain(dense(width, "shared_up")(x), self.mesh, HIDDEN_SPEC)
-        y = dense(x.shape[-1], "shared_down")(nn.silu(gate) * up)
+        hidden = [
+            constrain(dense(width, name)(x), self.mesh, HIDDEN_SPEC)
+            for name in (("shared_gate", "shared_up")
+                         if self.expert_act == "swiglu" else ("shared_up",))]
+        y = dense(x.shape[-1], "shared_down")(self._act(hidden))
         if self.shared_gate:
             y = y * jax.nn.sigmoid(dense(1, "shared_expert_gate")(x))
-        return y
+        active = (
+            self._active_share(hidden[0].reshape(-1, width))
+            if self.expert_act == "relu2" else None)
+        return y, active
 
     def _onehot(self, x, router_logits, weights):
         if not self.normalize_gates:
@@ -408,6 +454,9 @@ class MoeMlp(nn.Module):
             out = moe_ops.grouped_matmul(
                 self._act(hidden), weights[-1], group_sizes, one_device
             )
+            relu2 = {} if self.expert_act != "relu2" else {
+                "relu2_active": self._active_share(
+                    hidden[0], None if self.held_experts is None else valid)}
         with jax.named_scope("moe/combine"):
             if self.held_experts is None:
                 y = moe_ops.combine_sorted(out, gates, order, inverse)
@@ -424,6 +473,7 @@ class MoeMlp(nn.Module):
                     probs, loads, self.top_k, **share
                 ),
             }
+            aux["routing"].update(relu2)
             self._move_bias(bias, loads, training, aux["routing"])
         return y.reshape(x.shape), aux
 
@@ -574,6 +624,11 @@ class MoeMlp(nn.Module):
         return y, {"load_balancing": balance, "routing": stats}
 
 
+# The kinds of ``MoeTransformerLM.layer_kinds`` that are no mixer but a
+# second sublayer standing alone in its layer: naming one makes the
+# stack one of layers of ONE sublayer each
+ONE_SUBLAYER_KINDS = ("experts", "mlp")
+
 # What a model names again (nine zoo files build ``MoeTransformerLM``
 # with flat keywords) and hands to its expert blocks whole: every field
 # the layer declares but the mesh, which is the block's
@@ -598,6 +653,11 @@ def merge_routing(layers):
         # the largest |balancing bias| of any expert in any layer
         merged["bias_abs_max"] = jnp.stack(
             [r["bias_abs_max"] for r in layers]).max()
+    for name in ("relu2_active", "relu2_shared_active"):
+        # a ReLU-squared body's share of hidden units above zero, the
+        # layers' mean
+        if name in layers[0]:
+            merged[name] = jnp.stack([r[name] for r in layers]).mean()
     if "held" in layers[0]:
         # the pairs of the layer whose held experts got the most: what
         # the row buffer has to hold
@@ -694,6 +754,18 @@ class MoeTransformerLM(nn.Module):
     call also returns ``mamba`` (the ``mamba_gates`` event's facts, one
     entry a Mamba layer).
 
+    NVIDIA-Nemotron-3-Nano-30B-A3B's (``nemotron_h``) is a stack of
+    layers of ONE sublayer each (``Block.only``): ``layer_kinds`` the
+    published pattern's letters as ``mamba`` (M), ``experts`` (E: the
+    expert layer ALONE, no mixer) and ``full`` (*), ``moe_every=1`` and
+    ``first_k_dense=0`` (the kinds say which layers hold experts),
+    ``mamba`` at 8 groups, ``rotary=False`` with ``head_dim=128`` over
+    ``num_kv_heads=2``, and an expert layer of ``expert_act="relu2"``
+    (two matrices an expert, the shared expert in the same body),
+    ``scoring="sigmoid"`` with a balancing bias over 128 experts of
+    which a chip holds 8, ``router_float32``; every block's tree is
+    ``ln`` and ``attn`` or ``moe_mlp``.
+
     Ouro-2.6B's is every layer dense (``first_k_dense=num_layers``,
     ``dense_act="swiglu"``), ``sandwich`` (a norm on each sublayer's
     output too) and ``looped`` (a ``LoopedDims``): the blocks run
@@ -765,6 +837,8 @@ class MoeTransformerLM(nn.Module):
     # the rows of a rank's receive buffer where the experts are spread
     # over ``ep`` (``MoeMlp``); None: all the ranks' pairs
     exchange_rows: Optional[int] = None
+    # the router's product in float32 (``MoeMlp.router_float32``)
+    router_float32: bool = False
     # the mixers' kinds as a pattern with a period: layer i is
     # ``layer_kinds[i % len(layer_kinds)]``, "linear" (a Gated DeltaNet
     # of ``linear``'s sizes), "kda" (a Kimi Delta Attention of
@@ -772,7 +846,12 @@ class MoeTransformerLM(nn.Module):
     # "mamba" (a Mamba-2 state-space mixer of ``mamba``'s),
     # "full" (softmax or, with ``latent``, latent attention over the
     # causal prefix) or "window" (softmax attention over a band,
-    # ``ops/flash_attention.py:Band``). None: every layer "full". The
+    # ``ops/flash_attention.py:Band``). None: every layer "full".
+    # "experts" is no mixer: a pattern that names it is a stack of
+    # layers of ONE sublayer each (``Block.only``), a mixer kind's layer
+    # the mixer alone and an "experts" layer the expert layer alone
+    # (``_check_one_sublayer``; "mlp", a dense MLP alone, is refused:
+    # not built). The
     # five fields after ``linear`` are ``Attention``'s own of those
     # names, for every softmax layer. What a KIND of softmax layer has
     # of its own is ``kind_fields``, {kind: ``MixerKind``}: heads,
@@ -893,6 +972,9 @@ class MoeTransformerLM(nn.Module):
         by_kind = dict(self.kind_fields or {})
         sized = {"linear": self.linear, "conv": self.conv, "kda": self.kda,
                  "mamba": self.mamba}
+        if set(kinds) & set(ONE_SUBLAYER_KINDS):
+            self._check_one_sublayer(kinds, denoise)
+            kinds = tuple(k for k in kinds if k != "experts")
         if set(kinds) - {"full", "window", *sized} or any(
                 sized[kind] is None for kind in set(kinds) & set(sized)):
             raise ValueError(
@@ -938,6 +1020,45 @@ class MoeTransformerLM(nn.Module):
                  str(by_kind.get(kind, "the model's own")))
                 for kind in sorted(set(kinds))))
 
+    def _check_one_sublayer(self, kinds, denoise):
+        """A stack of layers of one sublayer each runs Mamba-2 mixers,
+        causal softmax layers of one kind and expert layers, each alone
+        in its layer, under next-token prediction on one device. What it
+        was not built beside is refused, each by its name."""
+        ranks = {} if self.mesh is None else dict(self.mesh.shape)
+        for what, asked in (
+                ("a layer that is a dense MLP alone ('mlp' in "
+                 "layer_kinds=%r: only 'experts' stands alone)"
+                 % (self.layer_kinds,), "mlp" in kinds),
+                ("a 'linear', 'conv', 'kda' or 'window' layer "
+                 "(layer_kinds=%r)" % (self.layer_kinds,),
+                 bool(set(kinds) - {"mamba", "full", "experts", "mlp"})),
+                ("first_k_dense=%d or moe_every=%d (the kinds say which "
+                 "layers hold experts: first_k_dense=0, moe_every=1)"
+                 % (self.first_k_dense, self.moe_every),
+                 bool(self.first_k_dense) or self.moe_every != 1),
+                ("objective=\"block_diffusion\"", denoise),
+                ("latent attention (latent)", self.latent is not None),
+                ("kind_fields (a band, heads or a rotary table by layer "
+                 "kind)", bool(self.kind_fields)),
+                ("hyper-connections (hc)", self.hc is not None),
+                ("sandwich norms (sandwich)", self.sandwich),
+                ("a looped stack (looped)", self.looped is not None),
+                ("the prediction module (mtp_layers: its block is a "
+                 "mixer and an expert layer)", bool(self.mtp_layers)),
+                ("a learned indexer (indexer)", self.indexer is not None),
+                ("a scaled residual branch (residual_scale)",
+                 self.residual_scale is not None),
+                ("attention_impl='ring' / 'ulysses'",
+                 self.attention_impl in ("ring", "ulysses")
+                 or ranks.get("sp", 1) > 1),
+                ("experts spread over ep (the mesh's ep=%d)"
+                 % ranks.get("ep", 1), ranks.get("ep", 1) > 1)):
+            if asked:
+                raise ValueError(
+                    "a stack of layers of one sublayer each ('experts' in "
+                    "layer_kinds) beside %s: not built, so not run" % what)
+
     def mixer_kinds(self, seq=None, dtype=None):
         """What a model with gated short convolutions, Kimi Delta
         Attention or Mamba-2 layers is made of, for the journal's
@@ -956,6 +1077,11 @@ class MoeTransformerLM(nn.Module):
                 "mamba_layers": built.count("mamba"),
                 "full_layers": built.count("full"),
                 "dense_layers": self.first_k_dense,
+                # (a stack of layers of one sublayer each: its expert
+                # layers; a model without such a stack says what it
+                # always did)
+                **({"expert_layers": built.count("experts")}
+                   if "experts" in built else {}),
                 "mamba_heads": self.mamba.num_heads,
                 "mamba_head_dim": self.mamba.head_dim,
                 "mamba_state": self.mamba.state,
@@ -1340,12 +1466,20 @@ class MoeTransformerLM(nn.Module):
 
         experts = {name: getattr(self, name) for name in EXPERT_FIELDS}
 
+        one_sublayer = bool(set(kinds) & set(ONE_SUBLAYER_KINDS))
+
         def block(name, index, kind="full", dense=False):
             second = (
                 dict(mlp_act=self.dense_act, mlp_dim=self.dense_dim)
                 if dense else dict(experts=experts))
+            if one_sublayer:
+                # a layer is its mixer alone or its experts alone
+                second = (
+                    dict(experts=experts, only="second")
+                    if kind == "experts" else dict(only="mixer"))
             return wrap(Block)(
-                self._mixer(kind, layout), mlp_ratio=self.mlp_ratio,
+                None if kind == "experts" else self._mixer(kind, layout),
+                mlp_ratio=self.mlp_ratio,
                 norm=self.norm, norm_eps=self.norm_eps, hc=self.hc,
                 layer_index=index, mesh=self.mesh, name=name,
                 sandwich=self.sandwich,
@@ -1378,8 +1512,9 @@ class MoeTransformerLM(nn.Module):
                 for i in range(self.num_layers)], training, positions)
         for i in range(self.num_layers):
             kind = kinds[i % len(kinds)]
-            dense = (i < self.first_k_dense
-                     or i % self.moe_every != self.moe_every - 1)
+            dense = not one_sublayer and (
+                i < self.first_k_dense
+                or i % self.moe_every != self.moe_every - 1)
             if dense and kind == "linear":
                 raise ValueError(
                     "a dense block's mixer is softmax attention, "

@@ -1256,7 +1256,8 @@ def make_attention(num_heads, latent=None, linear=None, conv=None,
 
 class Block(nn.Module):
     """The residual block of every language model here: a mixer, then a
-    second sublayer, each behind its own norm (``ln_attn``, ``ln_mlp``).
+    second sublayer, each behind its own norm (``ln_attn``, ``ln_mlp``);
+    or ONE of the two behind the one norm ``ln`` (``only``).
 
     ``mixer`` is what ``make_attention`` takes beside the block's own
     ``mesh`` and ``norm_eps``, as one mapping the block does not open
@@ -1288,7 +1289,15 @@ class Block(nn.Module):
 
     ``residual_scale``: both branches are multiplied by it before they
     are added (``x + s f(norm(x))``: granite's ``residual_multiplier``).
-    None: ``x + f(norm(x))``, the program every block always had."""
+    None: ``x + f(norm(x))``, the program every block always had.
+
+    ``only``: a layer of ONE sublayer, ``x + f(norm(x))`` behind the
+    one norm ``ln`` (Nemotron-H's stack, where a layer is a mixer, an
+    expert layer or attention and never two of them): ``"mixer"`` (the
+    mixer alone, ``attn``; nothing of a second sublayer is built) or
+    ``"second"`` (the second sublayer alone, ``moe_mlp`` or the dense
+    MLP; ``mixer`` is then None and no mixer is built). None: both, the
+    tree and the program the block always had."""
 
     mixer: Any
     experts: Optional[Any] = None
@@ -1303,6 +1312,7 @@ class Block(nn.Module):
     mesh: Optional[Any] = None
     sandwich: bool = False
     residual_scale: Optional[float] = None
+    only: Optional[str] = None
 
     def _dense_mlp(self, h, training):
         """The dense second sublayer, ``(y, {})`` as the experts' is
@@ -1328,10 +1338,16 @@ class Block(nn.Module):
             raise ValueError(
                 "mlp_act must be 'gelu' or 'swiglu', got %r"
                 % (self.mlp_act,))
-        mixer = make_attention(
+        if self.only not in (None, "mixer", "second") or (
+                (self.only == "second") != (self.mixer is None)):
+            raise ValueError(
+                "only=%r: None (both sublayers), 'mixer' or 'second', and "
+                "a layer that is its second sublayer alone has no mixer "
+                "(mixer=None)" % (self.only,))
+        mixer = None if self.mixer is None else make_attention(
             mesh=self.mesh, norm_eps=self.norm_eps, **self.mixer)
         second = self._dense_mlp
-        if self.experts is not None:
+        if self.experts is not None and self.only != "mixer":
             # the layer lives beside the model that names its fields,
             # and that module imports this one
             from elasticdl_tpu.models.moe_transformer import MoeMlp
@@ -1373,6 +1389,20 @@ class Block(nn.Module):
             with jax.named_scope("residual/add"):
                 return x + scaled(y)
 
+        if self.only is not None:
+            if self.hc is not None or self.sandwich:
+                raise ValueError(
+                    "a layer of one sublayer (only=%r) under "
+                    "hyper-connections (hc) or with sandwich norms "
+                    "(sandwich): not built, so not run" % (self.only,))
+            x = constrain(x, self.mesh, RESIDUAL_SPEC)
+            h = norm("ln")(x)
+            y, of_second = (
+                (mix(h), {}) if self.only == "mixer"
+                else second(h, training))
+            return constrain(
+                add(x, y, "ln_out"), self.mesh,
+                RESIDUAL_SPEC), {**of_second, **aux}
         if self.hc is None:
             x = constrain(x, self.mesh, RESIDUAL_SPEC)
             x = add(x, mix(norm("ln_attn")(x)), "ln_attn_out")

@@ -318,7 +318,12 @@ EVENT_TYPES = frozenset({
                              #   received_rows_buffer: the rows of its
                              #   receive buffer that the busiest rank's
                              #   regrouping ran, whole chunks up to the
-                             #   last that carries a pair)
+                             #   last that carries a pair; where the
+                             #   experts' body is ReLU squared:
+                             #   relu2_active_share and, with shared
+                             #   experts, relu2_shared_active_share,
+                             #   the share of the hidden units above
+                             #   zero after the ReLU, the layers' mean)
     "bd_noise",              # the same steps of a model trained by
                              #   block diffusion
                              #   (ops/block_diffusion.py): what the
@@ -341,7 +346,8 @@ EVENT_TYPES = frozenset({
                              #   (models/moe_transformer.py:
                              #   mixer_kinds): what it is made of
                              #   (a Mamba-2 model: + mamba_layers,
-                             #   full_layers, dense_layers,
+                             #   full_layers, dense_layers, in a stack
+                             #   of one-sublayer layers expert_layers,
                              #   mamba_heads, mamba_head_dim,
                              #   mamba_state, mamba_groups, mamba_taps,
                              #   mamba_chunk, head_dim, kv_heads,

@@ -88,7 +88,8 @@ class Fact(NamedTuple):
 # its layers' buffers and those of them the step ran, one whose experts
 # are spread over ``ep`` the pairs a rank sent, the rows a rank
 # received, those of its receive buffer that its regrouping ran and the
-# exchange's bytes), a
+# exchange's bytes, one whose experts' body is ReLU squared the share of
+# its experts' and of its shared expert's hidden units above zero), a
 # block-diffusion LM's noise facts, a hyper-connected LM's, one a
 # block (``models/moe_transformer.py``), a learned sparse-attention
 # indexer's, one a layer, a looped stack's exit distribution, one entry
@@ -112,7 +113,9 @@ FACTS = (
         ("received_mean", "received_pairs_mean"),
         ("received_run", "received_rows_run"),
         ("received_buffer", "received_rows_buffer"),
-        ("exchange_bytes", "exchange_bytes"))),
+        ("exchange_bytes", "exchange_bytes"),
+        ("relu2_active", "relu2_active_share"),
+        ("relu2_shared_active", "relu2_shared_active_share"))),
     Fact("noise", "bd_noise"),
     Fact("mhc", "mhc"),
     Fact("dsa", "dsa_select"),

@@ -96,33 +96,58 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(case):
         assert float(jnp.abs(total).max()) > 1e-2
 
 
-def test_the_thirty_two_shares_of_kimi_s_layer_add_up():
-    """Kimi Linear's expert layer at its published counts and a small
-    width: 256 sigmoid-routed experts, top-8 by score + balancing bias,
-    gates renormalised and scaled by 2.446, one shared expert; 32 chips
-    hold 8 experts each. The shares' parts, the shared expert counted
-    once, sum to what the uncut reference gives for the whole layer:
-    the output and the gradient that reaches the layer's input."""
-    ref = _reference_of("kimi-linear-48b-a3b-1chip")
-    experts, top_k, chips = 256, 8, 32
-    config = {"num_experts_per_token": top_k, "moe_renormalize": True,
-              "routed_scaling_factor": 2.446}
+# (the configuration whose reference gives the uncut layer, experts,
+# top-k, chips, the experts' body, the layer's own fields, the
+# reference's config, the kernels a share holds its own rows of)
+SIGMOID_CASES = {
+    # Kimi Linear's expert layer at its published counts and a small
+    # width: 256 sigmoid-routed experts, top-8 by score + balancing
+    # bias, gates renormalised and scaled by 2.446, one SwiGLU shared
+    # expert; 32 chips hold 8 experts each
+    "kimi-256-top8-thirty-two-shares": (
+        "kimi-linear-48b-a3b-1chip", 256, 8, 32,
+        dict(expert_act="swiglu", gate_scale=2.446, seq_aux=True,
+             shared_experts=1),
+        {"num_experts_per_token": 8, "moe_renormalize": True,
+         "routed_scaling_factor": 2.446},
+        ("w_gate", "w_up", "w_down")),
+    # Nemotron-3-Nano's at its published counts: 128 experts, top-6,
+    # scaled by 2.5, every body ``relu(x W_up)^2 W_down``, the shared
+    # expert twice an expert's width; 16 chips hold 8 experts each
+    "nemotron-128-top6-relu2-sixteen-shares": (
+        "nemotron-3-nano-30b-a3b-1chip", 128, 6, 16,
+        dict(expert_act="relu2", gate_scale=2.5, shared_experts=2,
+             router_float32=True),
+        {"num_experts_per_tok": 6, "routed_scaling_factor": 2.5},
+        ("w_up", "w_down")),
+}
+
+
+@pytest.mark.parametrize(
+    "case", list(SIGMOID_CASES.values()), ids=list(SIGMOID_CASES))
+def test_the_shares_of_a_sigmoid_routed_layer_add_up(case):
+    """The shares' parts of a sigmoid-routed layer with a balancing
+    bias, the shared expert counted once, sum to what the uncut
+    reference gives for the whole layer: the output and the gradient
+    that reaches the layer's input."""
+    configuration, experts, top_k, chips, fields, config, stacked = case
+    ref = _reference_of(configuration)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 12, 16))
     weight = jax.random.normal(jax.random.PRNGKey(2), (24, 16))
 
     def layer(held):
         return MoeMlp(
             experts, top_k=top_k, dispatch_impl="sorted", expert_dim=8,
-            expert_act="swiglu", normalize_gates=True, scoring="sigmoid",
-            gate_scale=2.446, bias_update_speed=0.001, seq_aux=True,
-            shared_experts=1, held_experts=held, held_rows=24 * top_k)
+            normalize_gates=True, scoring="sigmoid",
+            bias_update_speed=0.001, held_experts=held,
+            held_rows=24 * top_k, **fields)
 
     variables = layer(None).init(jax.random.PRNGKey(1), x)
     params = variables["params"]
     bias = 0.1 * jax.random.normal(jax.random.PRNGKey(3), (experts,))
     state = {"moe_state": {"e_score_correction_bias": bias}}
-    assert params["w_gate"].shape == (experts, 16, 8)
-
+    assert params[stacked[0]].shape == (experts, 16, 8)
+    assert {name for name in params if name.startswith("w_")} == set(stacked)
     def uncut(x):
         flat = x.reshape(24, 16)
         y, _, chosen = ref.expert_layer(
@@ -139,8 +164,7 @@ def test_the_thirty_two_shares_of_kimi_s_layer_add_up():
 
     def part(x, first):
         mine = dict(params, **{
-            name: params[name][first:first + count]
-            for name in ("w_gate", "w_up", "w_down")})
+            name: params[name][first:first + count] for name in stacked})
         y, aux = layer((first, count)).apply(
             {"params": mine, **state}, x)
         return (y.reshape(24, 16) * weight).sum(), aux["routing"]["dropped"]
@@ -157,7 +181,7 @@ def test_the_thirty_two_shares_of_kimi_s_layer_add_up():
     # every share added the shared expert: count it once
     total = total - (chips - 1) * shared(x)
     total_dx = total_dx - (chips - 1) * jax.grad(shared)(x)
-    # float32 sums of 32 parts less 31 shared experts
+    # float32 sums of the parts less all but one of the shared experts
     np.testing.assert_allclose(total, want, rtol=1e-4)
     np.testing.assert_allclose(total_dx, want_dx, atol=1e-4)
     assert float(jnp.abs(want_dx).max()) > 1e-2

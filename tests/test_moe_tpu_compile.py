@@ -89,6 +89,43 @@ def test_pallas_grouped_matmul_is_what_a_tpu_backend_compiles(
         assert_no_capacity(hlo)
 
 
+# Nemotron-3-Nano's two projections (rows, k, n): d 2688 = 21 x 128 and
+# the experts' 1856 = 14.5 x 128, the first width that is no multiple of
+# the lane width; 8 held experts over a buffer of 8,192 rows
+NEMOTRON = {"up": (8192, 2688, 1856), "down": (8192, 1856, 2688)}
+
+
+@pytest.mark.parametrize("projection", sorted(NEMOTRON))
+def test_the_three_calls_compile_at_2688_by_1856(chip, projection):
+    """The backend's ``gmm`` / ``tgmm`` take an N or a K of 1856 as it
+    stands: forward, the rows' gradient and the weights' gradient of a
+    projection compile for the described v5e at the tiles the rule
+    chose, which cover 1856 by three tiles of 640 (1920: 96.7% needed
+    work) and 2688 by three of 896 exactly."""
+    rows, k, n = NEMOTRON[projection]
+    tiles = moe_ops.projection_tiles(rows, k, n, jnp.bfloat16)
+    covered = lambda dim, tile: -(-dim // tile) * tile
+    for call, (_, tk, tn) in tiles.items():
+        inner, outer = (n, k) if call == "d_rows" else (k, n)
+        assert {covered(inner, tk), covered(outer, tn)} == {2688, 1920}, call
+    assert moe_ops.projection_fill(k, n, tiles) == pytest.approx(
+        1856 / 1920)
+    shape = lambda *dims: jax.ShapeDtypeStruct(
+        dims, jnp.bfloat16, sharding=chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=chip)
+
+    def loss(x, w, sizes):
+        out = moe_ops.pallas_grouped_matmul(x, w, sizes)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        shape(rows, k), shape(8, k, n), sizes).compile().as_text()
+    names = kernels(hlo)
+    assert len(names) == 3 and sum("tgmm" in n for n in names) == 1, names
+    # the weights are stored at 1856, not padded to the tiles' 1920
+    assert "1920" not in "".join(re.findall(r"bf16\[[\d,]+\]", hlo))
+
+
 def test_a_tile_the_byte_count_refuses_the_compiler_refuses_too(chip):
     """(512, 1024, 1408) for the gate's weight gradient pads nothing
     and is what the rule would take if it fitted: 16.8 MiB by

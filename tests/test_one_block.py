@@ -85,7 +85,7 @@ def test_the_block_states_fewer_fields_than_a_mixer_has():
     assert own == {
         "mixer", "experts", "mlp_act", "mlp_dim", "mlp_ratio", "dropout",
         "norm", "norm_eps", "hc", "layer_index", "mesh", "sandwich",
-        "residual_scale"}
+        "residual_scale", "only"}
     attention = {f.name for f in dataclasses.fields(T.Attention)}
     assert not own & (attention - {"mesh", "dropout", "norm_eps",
                                    "parent", "name"})
@@ -124,7 +124,7 @@ def test_a_model_s_expert_fields_reach_the_layer(monkeypatch):
         normalize_gates=False, scoring="sigmoid", gate_scale=1.5,
         bias_update_speed=0.01, seq_aux=True, shared_experts=1,
         held_experts=(0, 2), held_rows=64, shared_gate=True,
-        exchange_rows=None)
+        exchange_rows=None, router_float32=True)
     assert set(stated) == set(EXPERT_FIELDS)
     model = MoeTransformerLM(
         vocab_size=64, num_layers=2, num_heads=2, embed_dim=DIM,

@@ -194,3 +194,13 @@ def test_the_chooser_says_xla_and_the_line_says_so(caplog):
     assert (
         "ssd scan heads=8x4 state=8 groups=1 chunk=16 impl=xla "
         "segments=3 (tokens=96)") in caplog.text
+    # and at eight groups (Nemotron-H's count: a head a group here)
+    x, dt, a, b, c, skip, _ = _operands(
+        96, groups=8, seed=8, dtype=jnp.float32)
+    with caplog.at_level(logging.INFO, logger="elasticdl_tpu.ops.ssd"):
+        jax.eval_shape(
+            lambda *t: ssd.ssd_scan(*t, chunk=16, segment=2),
+            x, dt, a, b, c, skip)
+    assert (
+        "ssd scan heads=8x4 state=8 groups=8 chunk=16 impl=xla "
+        "segments=3 (tokens=96)") in caplog.text
