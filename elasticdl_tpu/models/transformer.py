@@ -34,6 +34,7 @@ from jax.sharding import PartitionSpec as P
 from elasticdl_tpu.data.example import decode_example
 from elasticdl_tpu.ops import (
     gated_delta,
+    gated_norm,
     hyper_connection,
     qkv_conv,
     short_conv,
@@ -155,6 +156,18 @@ class ZeroCentredRMSNorm(nn.Module):
         return (
             wide * jax.lax.rsqrt(var + self.epsilon) * (1.0 + scale)
         ).astype(x.dtype)
+
+
+class _NormScale(nn.Module):
+    """The ``scale`` leaf of an ``nn.RMSNorm`` of this name over
+    ``features`` lanes, alone: what ``ops/gated_norm.py``'s kernels are
+    handed where they run a delta rule's output norm in its place."""
+
+    features: int
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, (self.features,))
 
 
 def make_norm(kind, eps, name):
@@ -598,7 +611,12 @@ class GatedDeltaNet(nn.Module):
     the rule's layout); everywhere else the lines of
     ``conv_silu_xla`` (``gdn/conv``) and ``split_heads_xla``
     (``gdn/gates``). The log's ``linear attention conv ... impl=`` line
-    says which. The columns of ``in_proj_qkvz`` lie q | k | v | z where
+    says which. Likewise the output norm and its gate: where
+    ``ops/gated_norm.py:gated_norm_impl`` says so the kernel pair
+    ``gated_norm_fwd`` / ``gated_norm_bwd`` under ``gdn/out_norm`` (``o``
+    read where the rule wrote it, z's columns read in place), the lines
+    below elsewhere; the log's ``gated norm ... impl=`` line says
+    which. The columns of ``in_proj_qkvz`` lie q | k | v | z where
     the published code interleaves them by key head: with seeded
     weights a fixed permutation."""
 
@@ -654,12 +672,21 @@ class GatedDeltaNet(nn.Module):
         with jax.named_scope("gdn/scan"):
             o = gated_delta.gated_delta_rule(
                 q, k, v, g, beta, chunk=dims.chunk, mesh=self.mesh)
+        norm_impl = gated_norm.choose(
+            "norm_silu", o, qkvz, dv, hv, self.mesh, conv_dim,
+            segments=segments)
         with jax.named_scope("gdn/out_norm"):
-            z = qkvz[..., conv_dim:].reshape(batch, seq, hv, dv)
-            o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
-                o.transpose(0, 2, 1, 3))  # (B, S, Hv, Dv)
-            o = (o.astype(jnp.float32)
-                 * nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+            if norm_impl == "pallas":
+                o = gated_norm.gated_norm(
+                    o, qkvz, _NormScale(dv, name="out_norm")(), "norm_silu",
+                    dv, self.norm_eps, conv_dim, "gdn/out_norm",
+                    segments=segments).reshape(batch, seq, hv, dv)
+            else:
+                z = qkvz[..., conv_dim:].reshape(batch, seq, hv, dv)
+                o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
+                    o.transpose(0, 2, 1, 3))  # (B, S, Hv, Dv)
+                o = (o.astype(jnp.float32)
+                     * nn.silu(z.astype(jnp.float32))).astype(x.dtype)
         with jax.named_scope("gdn/out_proj"):
             return nn.DenseGeneral(
                 dim, axis=(-2, -1), use_bias=False, name="out_proj")(o)
@@ -720,10 +747,11 @@ class KimiDeltaAttention(nn.Module):
     and the heads' split: ``ops/qkv_conv.py``, the kernel pair where
     ``conv_impl`` says so, its lines elsewhere), ``kda/gates`` (both
     low-rank gates, beta, their transposes and the layer's facts),
-    ``kda/scan`` (the chunked rule, whole), ``kda/out_norm``,
-    ``kda/out_proj``. The three projections are one matmul whose
-    columns lie q | k | v (the published module has three ``Linear``:
-    with seeded weights the same function). Returns ``(y, facts)``:
+    ``kda/scan`` (the chunked rule, whole), ``kda/out_norm`` (the
+    kernel pair of ``ops/gated_norm.py`` where ``gated_norm_impl`` says
+    so, the lines elsewhere), ``kda/out_proj``. The three projections
+    are one matmul whose columns lie q | k | v (the published module
+    has three ``Linear``: with seeded weights the same function). Returns ``(y, facts)``:
     ``decay_mean`` / ``decay_min`` of ``exp(g)`` over tokens, heads and
     channels, ``underflow_share`` of the (chunk, head, channel) triples
     whose decay cumulated over the chunk is under ``e^-88`` (where
@@ -778,11 +806,19 @@ class KimiDeltaAttention(nn.Module):
             o = gated_delta.gated_delta_rule(
                 q, k, v, g, beta, chunk=dims.chunk, segment=dims.segment,
                 mesh=self.mesh)
+        norm_impl = gated_norm.choose(
+            "norm_sigmoid", o, z, dim, heads, self.mesh, segments=segments)
         with jax.named_scope("kda/out_norm"):
-            o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
-                o.transpose(0, 2, 1, 3))  # (B, S, H, D)
-            o = (o.astype(jnp.float32) * jax.nn.sigmoid(
-                z.reshape(o.shape).astype(jnp.float32))).astype(x.dtype)
+            if norm_impl == "pallas":
+                o = gated_norm.gated_norm(
+                    o, z, _NormScale(dim, name="out_norm")(), "norm_sigmoid",
+                    dim, self.norm_eps, 0, "kda/out_norm",
+                    segments=segments).reshape(batch, seq, heads, dim)
+            else:
+                o = nn.RMSNorm(epsilon=self.norm_eps, name="out_norm")(
+                    o.transpose(0, 2, 1, 3))  # (B, S, H, D)
+                o = (o.astype(jnp.float32) * jax.nn.sigmoid(
+                    z.reshape(o.shape).astype(jnp.float32))).astype(x.dtype)
         with jax.named_scope("kda/out_proj"):
             return nn.DenseGeneral(
                 width, axis=(-2, -1), use_bias=False, name="out_proj")(
@@ -858,7 +894,10 @@ class Mamba2Mixer(nn.Module):
     Scopes: ``mamba/in_proj``, ``mamba/conv`` (convolution, bias, SiLU,
     the split), ``mamba/gates`` (softplus, ``A``, the log decay and the
     layer's facts), ``mamba/scan`` (the chunked scan and the skip,
-    whole), ``mamba/out_norm``, ``mamba/out_proj``. The convolution is
+    whole), ``mamba/out_norm`` (the kernel pair of
+    ``ops/gated_norm.py`` where ``gated_norm_impl`` says so, taken by
+    columns: the scan's chunks with their rows in the lanes; the lines
+    elsewhere), ``mamba/out_proj``. The convolution is
     ``ops/qkv_conv.py:conv_silu_xla``'s lines with a bias (its kernel
     pair is laid out for the delta rules' heads). Returns ``(out,
     facts)``: ``dt_mean`` / ``dt_max`` of the step, ``decay_mean`` /
@@ -913,16 +952,25 @@ class Mamba2Mixer(nn.Module):
             y = ssd.ssd_scan(
                 xs, dt, a, b, c, skip, chunk=dims.chunk,
                 segment=dims.segment, mesh=self.mesh)
+        norm_impl = gated_norm.choose(
+            "silu_norm", y, zxbcdt, inner // groups, groups, self.mesh,
+            rows=dims.chunk)
         with jax.named_scope("mamba/out_norm"):
-            gated = (
-                y.astype(jnp.float32).reshape(batch, seq, inner)
-                * nn.silu(zxbcdt[..., :inner].astype(jnp.float32)))
             scale = self.param(
                 "out_norm_scale", nn.initializers.ones, (inner,))
-            lanes = gated.reshape(batch, seq, groups, inner // groups)
-            var = jnp.mean(lanes * lanes, axis=-1, keepdims=True)
-            y = ((lanes * jax.lax.rsqrt(var + self.norm_eps)).reshape(
-                gated.shape) * scale).astype(x.dtype)
+            if norm_impl == "pallas":
+                y = gated_norm.gated_norm(
+                    y.reshape(batch, seq, inner), zxbcdt, scale, "silu_norm",
+                    inner // groups, self.norm_eps, 0, "mamba/out_norm",
+                    rows=dims.chunk)
+            else:
+                gated = (
+                    y.astype(jnp.float32).reshape(batch, seq, inner)
+                    * nn.silu(zxbcdt[..., :inner].astype(jnp.float32)))
+                lanes = gated.reshape(batch, seq, groups, inner // groups)
+                var = jnp.mean(lanes * lanes, axis=-1, keepdims=True)
+                y = ((lanes * jax.lax.rsqrt(var + self.norm_eps)).reshape(
+                    gated.shape) * scale).astype(x.dtype)
         with jax.named_scope("mamba/out_proj"):
             return nn.DenseGeneral(
                 width, axis=(-2, -1), use_bias=False, name="out_proj")(

@@ -57,7 +57,11 @@ CONTAINERS = ("forward", "looped/pass", "mtp/block")
 
 # Mosaic kernel-name prefix -> scope, for a kernel whose ``op_name``
 # lost its scope (the backward of a ``custom_vjp`` runs outside the
-# forward's ``named_scope``); the longest prefix wins
+# forward's ``named_scope``); the longest prefix wins. ``gated_norm_*``
+# (``ops/gated_norm.py``) is deliberately absent: one kernel pair runs
+# under three scopes (``mamba/out_norm``, ``gdn/out_norm``,
+# ``kda/out_norm``), so its name can say none of them; its backward
+# opens the caller's scope inside the VJP and the ``op_name`` decides
 KERNELS = {
     "flash_band": "attn_window/flash",
     "flash_sparse": "dsa/attend",

@@ -5,7 +5,8 @@ gradient at the cell's shape, four Mosaic kernels a segment (the
 operands' pair ``kda_prepare_*``, ISSUE 59, and the scan's pair with
 the state transposed), inside the memory the cell's step leaves it; and
 the mixer at the cell's widths, whose convolution is the kernel pair
-under the mixer's own scope.
+under the mixer's own scope and whose gated norm is ``gated_norm_*``
+under ``kda/out_norm`` (PR 65).
 
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
@@ -56,8 +57,11 @@ def test_the_mixer_s_convolution_is_the_kernel_pair(chip, monkeypatch):
     """``KimiDeltaAttention`` at the cell's widths over one segment of
     tokens: ``conv_impl`` says ``pallas`` (32 / 32 heads of 128 are
     whole 128-lane rows), both its kernels sit under ``kda/conv``, the
-    rule's four (the operands' pair and the scan's) under ``kda/scan``
-    and none under the Gated DeltaNet's scope: six kernels."""
+    rule's four (the operands' pair and the scan's) under ``kda/scan``,
+    the gated norm's two (``ops/gated_norm.py``, PR 65) under
+    ``kda/out_norm``, the backward's inside the VJP, and none under the
+    Gated DeltaNet's scope: eight kernels. Neither of the norm's is
+    named as ``benchmark/lib/kda_trace.py`` names the scan's."""
     from elasticdl_tpu.models.transformer import KdaDims, KimiDeltaAttention
     from tests.kernel_common import mosaic_kernels
 
@@ -75,11 +79,19 @@ def test_the_mixer_s_convolution_is_the_kernel_pair(chip, monkeypatch):
     ).lower(placed, x).compile().as_text()
     kernels = mosaic_kernels(hlo)
     assert sum("kda/conv" in k for k in kernels) == 2
-    assert all("kda/conv" in k or "kda/scan" in k for k in kernels)
+    under_norm = sorted((k for k in kernels if "kda/out_norm" in k), key=len)
+    assert [k.split("/")[-2] for k in under_norm] == [
+        "gated_norm_fwd", "gated_norm_bwd"]
+    assert "transpose(" in under_norm[1] and "transpose(" not in under_norm[0]
+    assert all("kda/conv" in k or "kda/scan" in k or k in under_norm
+               for k in kernels)
     assert not any("gdn/" in k for k in kernels)
     names = device_obs.pallas_kernels(hlo)
     assert set(names) == {
         "qkv_conv_fwd", "qkv_conv_bwd", "kda_prepare_fwd", "kda_prepare_bwd",
-        "kda_scan_fwd", "kda_scan_bwd"}
+        "kda_scan_fwd", "kda_scan_bwd", "gated_norm_fwd", "gated_norm_bwd"}
+    assert names["gated_norm_fwd"] == names["gated_norm_bwd"] == 1
+    for word in ("gdn", "kda"):
+        assert word not in "gated_norm_fwd gated_norm_bwd"
     under_scan = [k for k in kernels if "kda/scan" in k]
-    assert len(under_scan) == len(kernels) - 2 >= 4
+    assert len(under_scan) == len(kernels) - 4 >= 4

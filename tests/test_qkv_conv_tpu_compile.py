@@ -71,11 +71,15 @@ def _kernel_instructions(hlo):
 
 def test_the_layer_s_gradient_holds_each_kernel_once_under_its_scope(
         chip, monkeypatch):
-    """At the cell's shape, the rule's own kernels beside them: the two
+    """At the cell's shape, the rule's own kernels and the gated
+    norm's pair (``ops/gated_norm.py``, PR 65) beside them: the two
     calls are there once each, and
     ``benchmark.lib.gdn_trace.classify`` reads both, the backward's
-    inside the VJP too, as ``gdn/conv``, and the rule's as
-    ``gdn/scan``."""
+    inside the VJP too, as ``gdn/conv``, the rule's as ``gdn/scan``,
+    and ``gated_norm_fwd`` / ``gated_norm_bwd``, once each, as
+    ``gdn/out_norm``: their names hold none of ``gdn``, ``kda`` or a
+    leading ``ssd``, by which the readers charge a kernel to the
+    SCAN."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     dims = T.GatedDeltaDims(
         num_key_heads=16, num_value_heads=32, key_head_dim=128,
@@ -95,10 +99,15 @@ def test_the_layer_s_gradient_holds_each_kernel_once_under_its_scope(
     hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
     counts = device_obs.pallas_kernels(hlo)
-    assert {name: counts.get(name) for name in KERNELS} == dict.fromkeys(
-        KERNELS, 1)
+    norm = ("gated_norm_fwd", "gated_norm_bwd")
+    assert {name: counts.get(name) for name in KERNELS + norm
+            } == dict.fromkeys(KERNELS + norm, 1)
+    for name in norm:
+        assert "gdn" not in name and "kda" not in name
+        assert not name.startswith("ssd")
     for name, text, op_name in _kernel_instructions(hlo):
-        want = "gdn/conv" if name in KERNELS else "gdn/scan"
+        want = ("gdn/conv" if name in KERNELS
+                else "gdn/out_norm" if name in norm else "gdn/scan")
         assert gdn_trace.classify(text, op_name) == want, (name, op_name)
-        if name == "qkv_conv_bwd":
-            assert "transpose(" in op_name
+        assert ("transpose(" in op_name) == name.endswith("_bwd") or (
+            want == "gdn/scan")

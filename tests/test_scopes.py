@@ -121,6 +121,20 @@ def test_the_deepest_registered_scope_decides(op_name, expected):
      ("mixer", "kda/conv", "backward")),
     ("rotary_fwd/bf16,bf16", FWD + "attn/attn_window/rotary/pallas_call",
      ("attention", "attn_window/rotary", "forward")),
+    # one kernel pair, three scopes (PR 65): ``KERNELS`` has no entry
+    # for the gated norm's, the caller's scope alone decides
+    ("gated_norm_bwd/bf16,bf16,f32,bf16",
+     BWD + "block_2/attn/mamba/out_norm/mamba/out_norm/"
+     "jit(gated_norm_bwd)/gated_norm_bwd/pallas_call",
+     ("mixer", "mamba/out_norm", "backward")),
+    ("gated_norm_fwd/bf16,bf16,f32", FWD + "attn/gdn/out_norm/"
+     "jit(gated_norm_fwd)/gated_norm_fwd/pallas_call",
+     ("mixer", "gdn/out_norm", "forward")),
+    ("gated_norm_bwd/bf16,bf16,f32,bf16",
+     BWD + "attn/kda/out_norm/gated_norm_bwd/pallas_call",
+     ("mixer", "kda/out_norm", "backward")),
+    ("gated_norm_fwd/bf16", FWD + "x/pallas_call",
+     ("unnamed", "forward/M/x", "forward")),
     ("some_other_kernel/f32", FWD + "x/pallas_call",
      ("unnamed", "forward/M/x", "forward")),
 ])

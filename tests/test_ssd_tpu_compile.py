@@ -3,7 +3,8 @@ mixer compiled for a v5e that is described, not attached (the TPU
 compiler is installed here): what a CPU run cannot see. The scan's
 gradient at the cell's shape inside the memory the cell's step leaves
 it, its segments a loop; and the mixer at the cell's widths with every
-operation under a ``mamba/`` scope and no Mosaic kernel in it yet.
+operation under a ``mamba/`` scope and, since PR 65, exactly two Mosaic
+kernels in it: the gated norm's pair under ``mamba/out_norm``.
 
 One file, one fixture: only the process that runs this file loads the
 TPU's library (on-chip-measurement guide, section 2)."""
@@ -45,12 +46,22 @@ def test_the_scan_compiles_at_the_cell_s_shape(chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2**30
 
 
-def test_the_mixer_s_operations_lie_under_its_scopes(chip):
-    """``Mamba2Mixer`` at the cell's widths over one segment of tokens:
-    its matmuls, the convolution, the gates, the scan and the gated norm
-    each under its ``mamba/`` scope, forward and backward."""
+def test_the_mixer_s_operations_lie_under_its_scopes(chip, monkeypatch):
+    """``Mamba2Mixer`` at the cell's widths over one segment of tokens,
+    with the backend a TPU: its matmuls, the convolution, the gates,
+    the scan and the gated norm each under its ``mamba/`` scope,
+    forward and backward; the scan stays XLA's lines, the gated norm is
+    ``gated_norm_fwd`` and ``gated_norm_bwd`` (``ops/gated_norm.py``,
+    PR 65), both under ``mamba/out_norm``, the backward's inside the
+    VJP, neither named as ``benchmark/lib/ssm_trace.py`` names the
+    scan's."""
     from elasticdl_tpu.models.transformer import Mamba2Dims, Mamba2Mixer
+    from elasticdl_tpu.observability import device as device_obs
+    from elasticdl_tpu.ops import gated_norm
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gated_norm.gated_norm_impl(
+        jnp.bfloat16, 4096, 1, 2048, rows=256) == "pallas"
     layer = Mamba2Mixer(Mamba2Dims(64, 64, 128, 1, 4), norm_eps=1e-5)
     x = jax.ShapeDtypeStruct((1, 2048, 2048), jnp.bfloat16, sharding=chip)
     variables = jax.eval_shape(
@@ -64,7 +75,13 @@ def test_the_mixer_s_operations_lie_under_its_scopes(chip):
     hlo = jax.jit(jax.grad(
         lambda v, x: layer.apply(v, x)[0].astype(jnp.float32).sum())
     ).lower(placed, x).compile().as_text()
-    assert not mosaic_kernels(hlo)
+    assert device_obs.pallas_kernels(hlo) == {
+        "gated_norm_fwd": 1, "gated_norm_bwd": 1}
+    forward, backward = sorted(mosaic_kernels(hlo), key=len)
+    assert "mamba/out_norm" in forward and "transpose(" not in forward
+    assert "mamba/out_norm" in backward and "transpose(" in backward
+    assert not any(name.startswith("ssd") for name in (
+        "gated_norm_fwd", "gated_norm_bwd"))
     scopes = set(re.findall(r"mamba/(\w+)", hlo))
     assert scopes == {
         "in_proj", "conv", "gates", "scan", "out_norm", "out_proj"}
